@@ -16,7 +16,7 @@ BEFORE any TPU time is burned
   actionable errors for malformed plan JSONs.
 * :mod:`~hetu_galvatron_tpu.analysis.census` — Pass 2: trace the hot-path
   programs with ``jax.make_jaxpr`` and count their collectives (recursing
-  into pjit/shard_map/scan subjaxprs), verify trace-marker coverage, and
+  into jit/shard_map/scan subjaxprs), verify trace-marker coverage, and
   cross-check against the plan's predicted collective counts.
 * :mod:`~hetu_galvatron_tpu.analysis.lint` — Pass 3: stdlib-``ast`` lint
   passes (host sync in hot paths, jit-in-loop, mesh-axis canon, dynamic

@@ -5,7 +5,7 @@ communicate; PR 6's plan audit checks those predictions against a measured
 device trace. This module closes the same loop from the STATIC side: trace
 the hot-path programs with ``jax.make_jaxpr`` (no devices execute, no step
 runs) and count the collectives the program actually contains, recursing
-into pjit/shard_map/scan/remat/custom-vjp subjaxprs with scan trip-count
+into jit/shard_map/scan/remat/custom-vjp subjaxprs with scan trip-count
 multipliers — so a program that silently grew an extra ring hop, lost a
 ``jax.named_scope`` trace marker, or picked up a host callback in the step
 path fails ``cli/check.py`` before any TPU time is burned.
@@ -14,7 +14,7 @@ What the census can and cannot see (documented, not hidden): jaxpr-level
 collectives are the EXPLICIT ones — the shard_map kernels' ``ppermute``
 rings (tp overlap, cp ring attention, pp stage rotation), Ulysses
 ``all_to_all``, fused-CE ``psum``. GSPMD-inserted collectives (ZeRO
-gathers, dp grad all-reduce under ``pjit``) materialize only at partition
+gathers, dp grad all-reduce under ``jit``) materialize only at partition
 time and are the measured audit's job. That split is exactly why the
 predicted side (:func:`~hetu_galvatron_tpu.observability.telemetry.
 plan_collective_counts`) predicts the explicit kernels' counts.
@@ -61,7 +61,7 @@ class CensusResult:
     # name-stack strings of unmarked permute eqns (diagnostics)
     unmarked_permutes: List[str] = field(default_factory=list)
     callbacks: List[str] = field(default_factory=list)
-    donated_args: int = 0  # donated invars of the outermost pjit, if any
+    donated_args: int = 0  # donated invars of the outermost jit, if any
     notes: List[str] = field(default_factory=list)
 
     @property
@@ -97,7 +97,7 @@ def _as_jaxpr(v: Any):
 
 def _sub_jaxprs(params: Dict[str, Any]):
     """(key, jaxpr) pairs for every subjaxpr value in an eqn's params —
-    covers pjit/shard_map/scan/remat ('jaxpr'), custom vjp/jvp
+    covers jit/shard_map/scan/remat ('jaxpr'), custom vjp/jvp
     ('call_jaxpr'/'fun_jaxpr'/'fwd_jaxpr_thunk' is a thunk and skipped),
     and tuple-valued params like cond 'branches'."""
     for key, v in params.items():
@@ -178,7 +178,7 @@ def census_jaxpr(jaxpr: Any) -> CensusResult:
                         "is dynamic so they are counted once")
                 out.merge_scaled(sub, 1)
             continue
-        if name == "pjit" and not out.counts and not out.donated_args:
+        if name == "jit" and not out.counts and not out.donated_args:
             donated = eqn.params.get("donated_invars", ())
             out.donated_args = int(sum(bool(d) for d in donated))
         for _, sj in _sub_jaxprs(eqn.params):

@@ -23,7 +23,7 @@ programs (``CompiledPipelineEngine.step_jaxpr`` /
   with differing shardings: the value moves across the mesh twice where
   once suffices). Each finding names the offending program, eqn, and
   shape.
-* **donation audit** — the outermost pjit's ``donated_invars`` weighed
+* **donation audit** — the outermost jit's ``donated_invars`` weighed
   in megabytes: the train step must donate the majority of its input
   bytes (params + optimizer state; an undonated step double-buffers the
   model), and the largest undonated buffers are named.
@@ -162,7 +162,7 @@ def flow_jaxpr(jaxpr: Any) -> FlowResult:
 
 @dataclass
 class DonationReport:
-    """Megabyte-weighed view of the outermost pjit's donated_invars."""
+    """Megabyte-weighed view of the outermost jit's donated_invars."""
 
     donated_mb: float = 0.0
     undonated_mb: float = 0.0
@@ -175,14 +175,14 @@ class DonationReport:
 
 
 def donation_report(jaxpr: Any, top: int = 4) -> DonationReport:
-    """Weigh the outermost pjit's donation decisions: which input bytes
+    """Weigh the outermost jit's donation decisions: which input bytes
     the program consumes in place vs double-buffers."""
     rep = DonationReport()
     j = _as_jaxpr(jaxpr)
     if j is None:
         return rep
     for eqn in j.eqns:
-        if eqn.primitive.name != "pjit":
+        if eqn.primitive.name != "jit":
             continue
         donated = eqn.params.get("donated_invars", ())
         undonated: List[Tuple[str, float]] = []

@@ -8,9 +8,11 @@ import sys
 
 
 def main(argv=None) -> int:
+    from hetu_galvatron_tpu.cli.compile_cache import configure_compile_cache
     from hetu_galvatron_tpu.core.arguments import args_from_cli
     from hetu_galvatron_tpu.utils.hf_config_adapter import resolve_model_config
 
+    configure_compile_cache()
     argv = list(argv if argv is not None else sys.argv[1:])
     mode = "model_profiler"
     for a in argv:

@@ -161,11 +161,13 @@ def main(argv=None) -> int:
 
     import jax
 
+    from hetu_galvatron_tpu.cli.compile_cache import configure_compile_cache
     from hetu_galvatron_tpu.core.arguments import args_from_cli
     from hetu_galvatron_tpu.cli.preprocess_data import make_tokenizer
     from hetu_galvatron_tpu.models.builder import init_causal_lm
     from hetu_galvatron_tpu.utils.hf_config_adapter import resolve_model_config
 
+    configure_compile_cache()
     args = args_from_cli(passthrough, mode="train_dist")
     args = resolve_model_config(args)
     cfg = args.model

@@ -12,7 +12,7 @@ from __future__ import annotations
 
 import sys
 import time
-from typing import Any, Dict
+from typing import Any, Dict, Optional
 
 import numpy as np
 
@@ -53,12 +53,11 @@ def _flight_dump_elastic(args, reason: str, live_world: int,
 
 def train(args) -> Dict[str, Any]:
     import jax
-    import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec
 
     from hetu_galvatron_tpu.core.profiler.runtime_profiler import RuntimeProfiler
     from hetu_galvatron_tpu.models.builder import init_causal_lm
-    from hetu_galvatron_tpu.parallel.spmd import make_spmd_train_step, shard_params
+    from hetu_galvatron_tpu.parallel.spmd import make_spmd_train_step
     from hetu_galvatron_tpu.runtime.checkpoint import (
         CheckpointCadence,
         clear_resume_pin,
@@ -151,7 +150,35 @@ def train(args) -> Dict[str, Any]:
     state.log(f"parallel plan: {hpc.describe()}")
 
     cfg = args.model
-    params, axes = init_causal_lm(jax.random.key(args.train.seed), cfg)
+    # which attention core each layer runs — decided ONCE from the plan and
+    # the run's own devices (runtime/mesh.py), logged, and returned in the
+    # result so a run on the XLA core can never pass for a kernel run
+    from collections import Counter
+
+    from hetu_galvatron_tpu.runtime.mesh import (
+        attention_core,
+        flash_kernel_runs,
+    )
+
+    use_flash = flash_kernel_runs(cfg.use_flash_attn, state.devices)
+    attention_cores = [
+        attention_core(s.cp_size > 1, bool(s.sp and s.tp_size > 1), use_flash)
+        for s in hpc.layers]
+    state.log("attention cores: " + ", ".join(
+        f"{n} x {core}" for core, n in Counter(attention_cores).items()))
+
+    # abstract init first: the plan's shardings are derived from SHAPES, so
+    # no device materializes the unsharded tree before they exist (the
+    # pp=1 path then initializes straight into its shards)
+    init_key = jax.random.key(args.train.seed)
+    axes_box: Dict[str, Any] = {}
+
+    def init_params(key):
+        p, axes_box["axes"] = init_causal_lm(key, cfg)
+        return p
+
+    params = jax.eval_shape(init_params, init_key)
+    axes = axes_box["axes"]
     tx = make_optimizer(args.train)
     schedule = make_lr_schedule(args.train)
     base_iter, valid_iter, test_iter = get_train_valid_test_data_iterators(
@@ -262,8 +289,7 @@ def train(args) -> Dict[str, Any]:
             # the pp engines keep their stage-stacked ring-cp/ulysses
             # kernels (the pp=1 SPMD path swaps them for the GSPMD core)
             hier_reason = HIER_KERNEL_REASON
-        if hier_reason is None and cfg.use_flash_attn and all(
-                d.platform == "tpu" for d in state.devices[:1]):
+        if hier_reason is None and use_flash:
             hier_reason = HIER_KERNEL_REASON
         if hier_reason is None and cfg.use_fused_ce and world > 1:
             hier_reason = HIER_KERNEL_REASON  # vocab-parallel CE shard_map
@@ -605,6 +631,12 @@ def train(args) -> Dict[str, Any]:
 
     use_dropout = (cfg.hidden_dropout > 0.0 or cfg.attention_dropout > 0.0)
     drop_key = jax.random.key(args.train.seed) if use_dropout else None
+    # what the compiled step contains (filled after the first step)
+    from hetu_galvatron_tpu.observability.trace_analysis import (
+        mosaic_custom_calls,
+    )
+
+    step_report: Dict[str, Any] = {}
 
     def run_loop(sp, so, step_fn):
         """Shared iteration driver for both execution paths. step_fn(sp, so,
@@ -923,7 +955,9 @@ def train(args) -> Dict[str, Any]:
                                  tp_overlap=tp_overlap_on,
                                  hier_dp=hier_dp_on,
                                  hier_bucket_mb=hier_bucket_mb)
-        sp = eng.split_params(params, axes)
+        # the engines slice a whole tree per stage: it lives on the default
+        # device only until every stage holds its shards
+        sp = eng.split_params(init_params(init_key), axes)
         so = eng.init_opt(sp, axes)
         sp, so, start_iter = maybe_resume(sp, so)
         if valid_iter is not None or test_iter is not None:
@@ -935,6 +969,8 @@ def train(args) -> Dict[str, Any]:
             sp, so = run_loop(sp, so, finish_tp_overlap_setup(
                 lambda sp_, so_, b: eng.train_step(
                     sp_, so_, b, num_microbatches=calc.num_micro_batches)))
+        step_report["mosaic_custom_calls"] = getattr(
+            eng, "mosaic_custom_calls", None)
     else:
         mesh = build_mesh(world, 1, devices=state.devices,
                           dcn_slices=args.parallel.dcn_slices)
@@ -945,11 +981,11 @@ def train(args) -> Dict[str, Any]:
             donate=not rerun.enabled, tp_overlap=tp_overlap_on,
             hier_dp=hier_dp_on, dcn_slices=args.parallel.dcn_slices,
             hier_bucket_mb=hier_bucket_mb, dp_schedule=dp_schedule_on)
-        nshd = jax.tree.map(
-            lambda s: NamedSharding(mesh, s), ospecs,
+        nshd = lambda specs: jax.tree.map(
+            lambda s: NamedSharding(mesh, s), specs,
             is_leaf=lambda x: isinstance(x, PartitionSpec))
-        sp = shard_params(params, pspecs, mesh)
-        so = jax.jit(tx.init, out_shardings=nshd)(sp)
+        sp = jax.jit(init_params, out_shardings=nshd(pspecs))(init_key)
+        so = jax.jit(tx.init, out_shardings=nshd(ospecs))(sp)
         sp, so, start_iter = maybe_resume(sp, so)
         # ramp: one jitted step per distinct microbatch COUNT (micro shape
         # fixed), compiled lazily as the ramp reaches each count
@@ -972,11 +1008,15 @@ def train(args) -> Dict[str, Any]:
             # the rng key is per-step scalar data: placed replicated, not
             # under the [B, ...] batch sharding
             rng = raw.pop("dropout_rng", None)
-            b = jax.device_put(jax.tree.map(jnp.asarray, raw), batch_shd)
+            b = jax.device_put(raw, batch_shd)
             if rng is not None:
                 b["dropout_rng"] = rng
             fn = step if calc is None else get_step(calc.num_micro_batches)
-            return fn(sp, so, b)
+            out = fn(sp, so, b)
+            if "mosaic_custom_calls" not in step_report:
+                step_report["mosaic_custom_calls"] = mosaic_custom_calls(
+                    fn, (out[0], out[1], b))
+            return out
 
         if valid_iter is not None or test_iter is not None:
             from hetu_galvatron_tpu.parallel.spmd import make_spmd_eval_step
@@ -988,7 +1028,7 @@ def train(args) -> Dict[str, Any]:
             def spmd_eval(sp_, raw):
                 raw = dict(raw)
                 raw.pop("dropout_rng", None)
-                b = jax.device_put(jax.tree.map(jnp.asarray, raw), eval_shd)
+                b = jax.device_put(raw, eval_shd)
                 return float(eval_fn(sp_, b))
 
             eval_box["fn"] = spmd_eval
@@ -1016,6 +1056,11 @@ def train(args) -> Dict[str, Any]:
                         "frac": goodput.goodput(),
                         "restarts_survived": goodput.restarts_survived},
             "flight_dumps": list(recorder.dumped) if recorder else [],
+            "attention_cores": attention_cores,
+            # Mosaic kernels in the compiled step's HLO (pp=1), or summed
+            # over the host engine's stage backward programs; None for
+            # the compiled engine, which does not count them
+            "mosaic_custom_calls": step_report.get("mosaic_custom_calls"),
             "exit_code": exit_code}
 
 
@@ -1031,7 +1076,12 @@ def _finish(out: Dict[str, Any]) -> int:
     return 0 if np.isfinite(final) else 1
 
 
-def main(argv=None) -> int:
+def main(argv=None, *, result: Optional[Dict[str, Any]] = None) -> int:
+    """Launcher entry. ``result``, when given, receives :func:`train`'s
+    output dict (losses, attention cores, ...) of the last in-process
+    attempt — ``chip_smoke.py`` and tests read it; the exit code is the
+    contract everyone else uses."""
+    from hetu_galvatron_tpu.cli.compile_cache import configure_compile_cache
     from hetu_galvatron_tpu.core.arguments import args_from_cli
 
     base_argv = list(argv if argv is not None else sys.argv[1:])
@@ -1047,8 +1097,12 @@ def main(argv=None) -> int:
         from hetu_galvatron_tpu.cli.supervise import run_supervised
 
         return run_supervised(args, base_argv)
+    configure_compile_cache()
     if not sup.auto_restart:
-        return _finish(train(args))
+        out = train(args)
+        if result is not None:
+            result.update(out)
+        return _finish(out)
 
     # supervised mode: checkpoint-and-exit codes (16 resume-to-
     # disambiguate, 18 preempted) and crashes auto-restart with jittered
@@ -1093,6 +1147,8 @@ def main(argv=None) -> int:
         # in train()), so it must get a fresh budget, not inherit the old
         # world's crash count
         world_fn=lambda: visible_world_size(args))
+    if result is not None:
+        result.update(last.get("out", {}))
     if rc != 0:
         return rc
     return _finish(last["out"])

@@ -106,8 +106,7 @@ def _dcn_group_devices(devices: Sequence, size: int, world: int
     Single-process runs (CPU tests, one-slice jobs) keep the maximally
     STRIDED proxy group with a warning: its hops measure intra-host
     stride, not a slice boundary, so the fitted "dcn" α/β only bound the
-    topology model until a real multi-slice fleet re-measures them
-    (tools/tpu_measure_all.py)."""
+    topology model until a real multi-slice fleet re-measures them."""
     devices = list(devices[:world])
     by_proc: Dict[int, List] = {}
     for d in devices:
@@ -157,8 +156,8 @@ class HardwareProfiler:
             jnp.ones((elems,), jnp.float32),
             NamedSharding(mesh, P(None)))
 
-        # NOTE: this jax pin has no top-level jax.shard_map; the
-        # experimental entry point (check_rep kwarg) is the one that works
+        # the deprecated spelling of jax.shard_map (its check_rep kwarg is
+        # check_vma there); ROADMAP design debt 2 migrates every call site
         from jax.experimental.shard_map import shard_map
 
         if op == "allreduce":

@@ -106,8 +106,9 @@ class TrainingTelemetry:
         if peak_tflops_per_device > 0:
             self.peak_flops = peak_tflops_per_device * 1e12 * self.world_size
         else:
-            kind = _device_kind()
-            tf = peak_device_tflops(kind) if kind else None
+            import jax
+
+            tf = peak_device_tflops(jax.devices()[0].device_kind)
             if tf:
                 self.peak_flops = tf * 1e12 * self.world_size
         self._last_t: Optional[float] = None
@@ -224,15 +225,6 @@ class TrainingTelemetry:
     def __exit__(self, *exc):
         self.close()
         return False
-
-
-def _device_kind() -> str:
-    try:
-        import jax
-
-        return jax.devices()[0].device_kind
-    except Exception:  # jax not initialized / no devices
-        return ""
 
 
 # ---------------------------------------------------------------------------
@@ -402,6 +394,15 @@ def _dp_schedule_from_plan(name: str, lanes: int, cross: int,
     return verify(synthesize_dp_schedule(fam, lanes, cross))
 
 
+def _remat_rings(model) -> int:
+    """Rings the per-layer remat recompute re-runs in the backward unit.
+    The recompute is dead-code-eliminated down to what the backward reads:
+    fc2's reduce-scatter ring feeds only the residual output, which no
+    gradient needs, so pre-norm layers replay 3 of the 4 forward rings.
+    A post-norm layer normalizes that sum, so its recompute keeps all 4."""
+    return 4 if model.post_norm else 3
+
+
 def plan_collective_counts(
     hpc,
     model,
@@ -431,9 +432,10 @@ def plan_collective_counts(
     out-proj, fc1 — the gated pair counts as ONE rotation — and fc2); the
     backward unit recomputes the stage forward from its stored input
     (``jax.vjp``) and runs the 4 transposed rings, so 8 rings, plus
-    another 4-ring forward recompute under per-layer remat. Each ring is
-    ``tp - 1`` ppermute hops. The stage rotations add 2 ppermutes per tick
-    (activations forward, cotangents backward).
+    the forward recompute under per-layer remat (:func:`_remat_rings`: 3
+    rings, 4 in post-norm layers). Each ring is ``tp - 1`` ppermute hops.
+    The stage rotations add 2 ppermutes per tick (activations forward,
+    cotangents backward).
 
     ``hier_dp=True`` adds the hierarchical dp gradient reduction's
     explicit collectives (``ops/hier_reduce.py``): the whole grad tree
@@ -484,7 +486,7 @@ def plan_collective_counts(
         out["ppermute_pp"] = 2 * T
     tp = s.tp_size
     if tp_overlap and tp > 1:
-        rings_per_tick = 4 + 8 + (4 if s.checkpoint else 0)
+        rings_per_tick = 4 + 8 + (_remat_rings(model) if s.checkpoint else 0)
         out["ppermute_tp"] = T * lps * rings_per_tick * (tp - 1)
     if hier_dp:
         if s.dp_size < 2:
@@ -577,7 +579,7 @@ def plan_collective_bytes(
     if pp > 1:
         out["ppermute_pp"] = 2 * T * act_mb / tp
     if tp_overlap and tp > 1:
-        rings_per_tick = 4 + 8 + (4 if s.checkpoint else 0)
+        rings_per_tick = 4 + 8 + (_remat_rings(model) if s.checkpoint else 0)
         out["ppermute_tp"] = (T * lps * rings_per_tick * (tp - 1)
                               * act_mb / tp)
     if hier_dp:

@@ -452,6 +452,17 @@ def jit_cost_summary(fn: Any, args: Sequence[Any] = (),
         return {}
 
 
+def mosaic_custom_calls(fn: Any, args: Sequence[Any]) -> int:
+    """Mosaic (Pallas TPU) kernels in the COMPILED program of a jitted
+    ``fn`` that was just called with ``args``-shaped inputs, counted in the
+    optimized HLO. After the call this costs no compile: ``.lower()`` and
+    ``.compile()`` return the lowering and executable the call cached.
+    Unlike the cost telemetry above it raises on failure — callers use it
+    to prove which attention core a step ran."""
+    hlo = fn.lower(*args).compile().as_text()
+    return hlo.count('custom_call_target="tpu_custom_call"')
+
+
 # one record per (registry, program): keyed on the live registry object so
 # a reused id() after GC can never suppress a fresh registry's recording
 _RECORDED: "weakref.WeakKeyDictionary[MetricsRegistry, set]" = \

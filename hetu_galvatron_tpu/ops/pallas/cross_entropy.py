@@ -26,11 +26,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# pin-compat: the CompilerParams dataclass was named TPUCompilerParams on
-# older jax releases (this toolchain's pin); same fields either way
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-
 NEG_INF = float(jnp.finfo(jnp.float32).min)
 
 
@@ -112,7 +107,7 @@ def _ce_fwd_call(logits, labels2d, *, block_t, block_v, interpret):
             pltpu.VMEM((block_t,), jnp.float32),
             pltpu.VMEM((block_t,), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(logits, labels2d)
@@ -135,7 +130,7 @@ def _ce_bwd_call(logits, labels2d, lse, a, b, *, block_t, block_v,
         ],
         out_specs=pl.BlockSpec((block_t, block_v), lambda t, v: (t, v)),
         out_shape=jax.ShapeDtypeStruct((T, V), logits.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )(logits, labels2d, lse, a, b)
@@ -193,9 +188,6 @@ def fused_ce_nll(logits: jax.Array, labels: jax.Array, *,
     if fit is None:
         return None
     bt, bv = fit
-    # Mosaic only exists on TPU; anywhere else (CPU tests, smoke runs) the
-    # kernel runs in interpret mode so the flag is safe on any backend
-    interpret = interpret or jax.default_backend() != "tpu"
     lse, gold = _ce_lse_gold(logits.reshape(T, V),
                              labels.reshape(T, 1).astype(jnp.int32),
                              bt, bv, interpret)
@@ -244,7 +236,6 @@ def make_vocab_parallel_ce(mesh, vocab_sharding, *, z_loss: float = 0.0,
         if fit is None:
             return None
         bt, bv = fit
-        interp = interpret or jax.default_backend() != "tpu"
 
         def local(lg, lb):
             Bl, Sl, Vl = lg.shape
@@ -252,7 +243,8 @@ def make_vocab_parallel_ce(mesh, vocab_sharding, *, z_loss: float = 0.0,
             for ax in vocab_axes:  # major-to-minor, matching P's layout
                 offset = offset * mesh.shape[ax] + jax.lax.axis_index(ax)
             lab = lb.reshape(-1, 1).astype(jnp.int32) - offset * Vl
-            lse, gold = _ce_lse_gold(lg.reshape(-1, Vl), lab, bt, bv, interp)
+            lse, gold = _ce_lse_gold(lg.reshape(-1, Vl), lab, bt, bv,
+                                     interpret)
             if vocab_axes:
                 # logsumexp merge across vocab shards; m is a numerical
                 # anchor only (lse is m-independent) so it takes no

@@ -24,11 +24,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# pin-compat: the CompilerParams dataclass was named TPUCompilerParams on
-# older jax releases (this toolchain's pin); same fields either way
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-
 NEG_INF = float(jnp.finfo(jnp.float32).min)
 
 
@@ -238,7 +233,7 @@ def flash_attention_hmajor(
         ],
         # only the k-block axis carries loop state (the online softmax);
         # everything else may be reordered/partitioned by Mosaic
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
@@ -468,7 +463,7 @@ def flash_attention_bwd_hmajor(
             pltpu.VMEM((block_k, D), jnp.float32),
         ],
         # dk/dv accumulate across the (g, qb) axes; kb tiles are independent
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary", "arbitrary")),
         interpret=interpret,
@@ -510,7 +505,7 @@ def flash_attention_bwd_hmajor(
         out_shape=jax.ShapeDtypeStruct((B, N, S, D), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
         # dq accumulates across k blocks only
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
@@ -518,8 +513,7 @@ def flash_attention_bwd_hmajor(
     return dq, dkdv[0], dkdv[1]
 
 
-# default tile sizes, overridable per call (swept on hardware by
-# tools/tpu_flash_check.py)
+# default tile sizes, overridable per call
 DEFAULT_BLOCK_Q = 256
 DEFAULT_BLOCK_K = 512
 
@@ -527,7 +521,8 @@ DEFAULT_BLOCK_K = 512
 def fit_block(default: int, seq: int, floor: int = 128) -> int:
     """Largest block <= default that divides seq (halving from default, so
     the result keeps the mult-of-128 lane alignment Mosaic wants). Returns 0
-    if nothing >= floor divides seq — caller falls back to the XLA core."""
+    if nothing >= floor divides seq — callers then run one whole-length
+    block."""
     b = min(default, seq)
     while b >= floor:
         if seq % b == 0:
@@ -602,9 +597,9 @@ def flash_sdpa(q, k, v, *, causal: bool = True, interpret: bool = False,
     trajectories are deterministic per seed but not bit-equal to the XLA
     core's (the reference's CUDA kernel has the same property vs torch).
 
-    Block defaults are clamped to divisors of S (e.g. S=768 runs 256-wide
-    k blocks even though the tuned default is 512)."""
-    S = q.shape[1]
+    Block defaults are clamped to divisors of the q / kv lengths (e.g.
+    S=768 runs 256-wide k blocks even though the tuned default is 512)."""
+    S, Sk = q.shape[1], k.shape[1]
     seed = None
     if dropout_rate > 0.0:
         if dropout_rng is None:
@@ -612,7 +607,7 @@ def flash_sdpa(q, k, v, *, causal: bool = True, interpret: bool = False,
         seed = seed_from_key(dropout_rng)
     return _flash_with_vjp(q, k, v, segment_ids, seed, causal, interpret,
                            block_q or fit_block(DEFAULT_BLOCK_Q, S) or S,
-                           block_k or fit_block(DEFAULT_BLOCK_K, S) or S,
+                           block_k or fit_block(DEFAULT_BLOCK_K, Sk) or Sk,
                            dropout_rate)
 
 
@@ -634,11 +629,10 @@ def make_flash_sdpa(mesh, dp_axes=(), tp_axes=(), *, interpret: bool = False,
     shard folds its (dp, tp) mesh coordinates into the seed so masks
     decorrelate across the sharded batch/head dims.
 
-    ``interpret=True`` (CPU tests / parity drills) also relaxes the block
-    floor: a sequence no tile >= 128 divides runs as one whole-sequence
-    block instead of silently falling back to the XLA core (matching
-    ``flash_sdpa``'s ``or S`` default), so CPU drills exercise the real
-    kernel arithmetic.
+    Block sizes follow ``flash_sdpa``: the tuned defaults clamped to
+    divisors of the q / kv lengths, else one whole-length block. There is
+    no fallback to the XLA core — a shape Mosaic refuses raises at compile
+    time. ``interpret`` comes only from the caller (CPU tests pass True).
 
     ``stage_axis`` (the compiled 1F1B engine): q/k/v carry a leading
     ``[pp, ...]`` stacked stage dim sharded on that mesh axis; the
@@ -647,8 +641,6 @@ def make_flash_sdpa(mesh, dp_axes=(), tp_axes=(), *, interpret: bool = False,
     nests inside the fused single-program pipeline. ``dropout_rng`` is
     then a ``[pp]`` key array (one per stage lane, matching the host
     engine's per-(microbatch, stage) keys)."""
-    from functools import partial as _partial
-
     from jax.sharding import PartitionSpec as P
 
     import jax
@@ -669,44 +661,15 @@ def make_flash_sdpa(mesh, dp_axes=(), tp_axes=(), *, interpret: bool = False,
             idx = idx * jnp.int32(mesh.shape[ax]) + jax.lax.axis_index(ax)
         return seed + idx * jnp.int32(-1640531527)  # 2654435761 as int32
 
-    def _xla_fallback(q, k, v, causal, segment_ids, dropout_rate,
-                      dropout_rng):
-        from hetu_galvatron_tpu.models.modules import xla_sdpa
-
-        if stage_axis is None:
-            return xla_sdpa(q, k, v, causal=causal, segment_ids=segment_ids,
-                            dropout_rate=dropout_rate,
-                            dropout_rng=dropout_rng)
-        # stacked operands: the XLA core is weight-free, so a plain vmap
-        # over the stage lane reproduces the per-stage host arithmetic
-        core = _partial(xla_sdpa, causal=causal, dropout_rate=dropout_rate)
-        if dropout_rng is not None:
-            return jax.vmap(lambda a, b, c, s, r: core(
-                a, b, c, segment_ids=s, dropout_rng=r))(
-                q, k, v, segment_ids, dropout_rng) \
-                if segment_ids is not None else jax.vmap(
-                    lambda a, b, c, r: core(a, b, c, dropout_rng=r))(
-                    q, k, v, dropout_rng)
-        if segment_ids is not None:
-            return jax.vmap(lambda a, b, c, s: core(a, b, c,
-                                                    segment_ids=s))(
-                q, k, v, segment_ids)
-        return jax.vmap(lambda a, b, c: core(a, b, c))(q, k, v)
-
     def sdpa(q, k, v, *, causal=True, segment_ids=None,
              dropout_rate: float = 0.0, dropout_rng=None):
-        S = q.shape[s_dim]
-        bq = fit_block(DEFAULT_BLOCK_Q, S)
-        bk = fit_block(DEFAULT_BLOCK_K, S)
-        if interpret:
-            # interpret mode has no lane-alignment constraint: run the
-            # whole sequence as one block rather than losing the kernel
-            bq, bk = bq or S, bk or S
-        # shapes the kernel can't tile (no lane-aligned block divides the
-        # sequence, or cross-attention with different q/kv lengths): XLA core
-        if not bq or not bk or k.shape[s_dim] != S:
-            return _xla_fallback(q, k, v, causal, segment_ids, dropout_rate,
-                                 dropout_rng)
+        # a length no lane-aligned block divides runs as ONE whole-length
+        # block (a block equal to the array dim satisfies Mosaic's tiling
+        # rule); what then overflows VMEM fails at compile time — there is
+        # no XLA core behind the kernel to hide it
+        S, Sk = q.shape[s_dim], k.shape[s_dim]
+        bq = fit_block(DEFAULT_BLOCK_Q, S) or S
+        bk = fit_block(DEFAULT_BLOCK_K, Sk) or Sk
         seed = None
         if dropout_rate > 0.0:
             if dropout_rng is None:

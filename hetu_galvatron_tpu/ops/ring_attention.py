@@ -97,8 +97,7 @@ def _ring_attention_local(q, k, v, seg=None, *, axis, cp, causal,
                           zigzag=False):
     """Per-shard kernel under shard_map: q/k/v are the local sequence blocks
     [B, S/cp, N|K, D]; ``seg`` [B, S/cp] packed-document segment ids.
-    ``cp`` is the static ring size (this jax pin has no jax.lax.axis_size;
-    the caller knows it from the mesh anyway)."""
+    ``cp`` is the static ring size (the caller knows it from the mesh)."""
     my_idx = jax.lax.axis_index(axis)
     B, Sq, N, D = q.shape
     K = k.shape[2]

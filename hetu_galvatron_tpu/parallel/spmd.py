@@ -25,6 +25,8 @@ from hetu_galvatron_tpu.models.builder import causal_lm_loss
 from hetu_galvatron_tpu.runtime.hybrid_config import HybridParallelConfig
 from hetu_galvatron_tpu.runtime.mesh import (
     LayerSharding,
+    attention_core,
+    flash_kernel_runs,
     lower_strategy,
     lower_vocab_strategy,
     spec_tree,
@@ -119,11 +121,13 @@ def attention_overrides(
     cp_zigzag: bool = False,
     flash_interpret: bool = False,
 ) -> Dict[int, Dict[str, Any]]:
-    """Per-layer attention-impl dispatch (reference attention.py:664-720):
+    """Per-layer attention-impl dispatch (reference attention.py:664-720),
+    branching on :func:`~hetu_galvatron_tpu.runtime.mesh.attention_core`:
     cp > 1 layers swap in the ring-attention kernel over their cp axes;
-    other layers get the Pallas flash kernel on TPU (``use_flash`` defaults
-    to platform == tpu); everything else keeps the XLA core (GSPMD inserts
-    the collectives).
+    other layers get the Pallas flash kernel when ``use_flash`` (None = the
+    shared rule :func:`~hetu_galvatron_tpu.runtime.mesh.flash_kernel_runs`:
+    every mesh device is a TPU); everything else keeps the XLA core (GSPMD
+    inserts the collectives).
 
     Ulysses layers get the explicit head-scatter all-to-all attention
     (ops/ulysses.py, reference _SeqAllToAll) instead of leaving GSPMD to
@@ -135,7 +139,7 @@ def attention_overrides(
     kernel needs equal q/kv sequence lengths and the a2a sandwich assumes
     self-attention geometry; GSPMD inserts the collectives instead), while
     flash layers reuse the flash kernel, which handles causal=False and
-    falls back internally on mismatched lengths.
+    unequal q/kv lengths.
 
     ``flash_interpret=True`` runs the Pallas kernels in interpret mode —
     CPU parity drills forcing ``use_flash=True`` on the virtual mesh (the
@@ -147,18 +151,19 @@ def attention_overrides(
     from hetu_galvatron_tpu.ops.ulysses import make_ulysses_sdpa
 
     if use_flash is None:
-        use_flash = all(d.platform == "tpu"
-                        for d in mesh.devices.flat[:1])
+        use_flash = flash_kernel_runs(True, mesh.devices.flat)
     out: Dict[int, Dict[str, Any]] = {}
     for i, sh in enumerate(per_layer):
-        if sh.cp_axes:
+        core = attention_core(bool(sh.cp_axes),
+                              bool(sh.ulysses and sh.tp_axes), use_flash)
+        if core.startswith("ring"):
             out[i] = {"sdpa_fn": make_ring_sdpa(
                 mesh, sh.cp_axes, dp_axes=sh.dp_axes, tp_axes=sh.tp_axes,
                 use_flash=use_flash, zigzag=cp_zigzag,
                 data_zigzagged=cp_zigzag, interpret=flash_interpret)}
             if with_cross:
                 out[i]["cross_sdpa_fn"] = xla_sdpa
-        elif sh.ulysses and sh.tp_axes:
+        elif core.startswith("ulysses"):
             local = None
             if use_flash:
                 from hetu_galvatron_tpu.ops.pallas.flash_attention import (
@@ -171,7 +176,7 @@ def attention_overrides(
                 mesh, sh.tp_axes, dp_axes=sh.dp_axes, local_sdpa=local)}
             if with_cross:
                 out[i]["cross_sdpa_fn"] = xla_sdpa
-        elif use_flash:
+        elif core == "flash":
             from hetu_galvatron_tpu.ops.pallas.flash_attention import (
                 make_flash_sdpa,
             )
@@ -311,6 +316,7 @@ def build_spmd_loss_fn(
     with_moe_stats: bool = False,
     tp_overlap: bool = False,
     lane_dp: bool = False,
+    kernel_interpret: bool = False,
 ):
     """The plan-lowered loss closure shared by the train and eval steps:
     per-layer shardings, boundary constraints, attention-impl dispatch,
@@ -319,6 +325,8 @@ def build_spmd_loss_fn(
     ``tp_overlap`` swaps eligible Megatron-TP layers' projection matmuls
     for the decomposed ring collectives (:func:`tp_overlap_overrides`);
     ineligible layers silently keep GSPMD — the launcher logs the reasons.
+    ``kernel_interpret`` runs the Pallas kernels (flash, fused CE) in
+    interpret mode: CPU tests pass it, nothing infers it.
 
     ``lane_dp`` builds the hierarchical-dp LANE variant: the interior
     activation constraints drop the dp axes (each lane's batch slice lives
@@ -358,10 +366,11 @@ def build_spmd_loss_fn(
         ring = attention_overrides(
             b_layers, mesh, use_flash=use_flash,
             with_cross=cfg.model_type == "t5",
-            cp_zigzag=getattr(hpc, "cp_zigzag", False))
-        enc_overrides = (attention_overrides(b_enc, mesh,
-                                             use_flash=use_flash)
-                         if b_enc else None)
+            cp_zigzag=getattr(hpc, "cp_zigzag", False),
+            flash_interpret=kernel_interpret)
+        enc_overrides = (attention_overrides(
+            b_enc, mesh, use_flash=use_flash,
+            flash_interpret=kernel_interpret) if b_enc else None)
     if tp_overlap:
         overlap_ov, _ = tp_overlap_overrides(per_layer, mesh, cfg)
         # merged UNDER ring/caller overrides per key: an explicit
@@ -394,12 +403,18 @@ def build_spmd_loss_fn(
     # across vocab shards — the reference's Triton vocab-parallel CE
     # semantics); single-device runs use the kernel directly.
     fused_ce = cfg.use_fused_ce
-    if fused_ce and mesh.size > 1:
+    if fused_ce:
+        from functools import partial
+
         from hetu_galvatron_tpu.ops.pallas.cross_entropy import (
+            fused_ce_nll,
             make_vocab_parallel_ce,
         )
 
-        fused_ce = make_vocab_parallel_ce(mesh, vocab)
+        fused_ce = (make_vocab_parallel_ce(mesh, vocab,
+                                           interpret=kernel_interpret)
+                    if mesh.size > 1
+                    else partial(fused_ce_nll, interpret=kernel_interpret))
 
     constrain_embed = make_embed_use_constraint(
         axes_tree["embed"], vocab, mesh)
@@ -459,6 +474,7 @@ def make_spmd_train_step(
     dcn_slices: int = 1,
     hier_bucket_mb: float = 0.0,
     dp_schedule: Optional[str] = None,
+    kernel_interpret: bool = False,
 ):
     """Build the jitted hybrid-parallel train step (no pipeline; pp=1).
 
@@ -479,6 +495,8 @@ def make_spmd_train_step(
     swaps the hand-implemented rs/ar/ag program for a synthesized,
     verified, emitted collective schedule (``collectives/``) — the plan
     JSON records the family the search priced cheapest.
+    ``kernel_interpret`` (CPU tests) runs the Pallas kernels in interpret
+    mode.
     """
     if hpc.pp_deg != 1:
         raise ValueError("make_spmd_train_step is the pp=1 path; use the "
@@ -493,8 +511,8 @@ def make_spmd_train_step(
         reason = plan_hier_dp_reason(cfg, hpc)
         if reason is None and tp_overlap:
             reason = HIER_KERNEL_REASON
-        if reason is None and cfg.use_flash_attn and all(
-                d.platform == "tpu" for d in mesh.devices.flat[:1]):
+        if reason is None and flash_kernel_runs(cfg.use_flash_attn,
+                                                mesh.devices.flat):
             reason = HIER_KERNEL_REASON
         if reason is None and cfg.use_fused_ce and mesh.size > 1:
             reason = HIER_KERNEL_REASON  # vocab-parallel CE is a shard_map
@@ -504,7 +522,8 @@ def make_spmd_train_step(
         build_spmd_loss_fn(
             cfg, hpc, mesh, axes_tree, compute_dtype=compute_dtype,
             layer_overrides=layer_overrides, with_moe_stats=moe_stats,
-            tp_overlap=tp_overlap, lane_dp=hier_dp))
+            tp_overlap=tp_overlap, lane_dp=hier_dp,
+            kernel_interpret=kernel_interpret))
     opt_pspecs = param_specs(axes_tree, per_layer, vocab, opt=True,
                              enc_per_layer=enc_per or None)
     opt_specs = opt_state_specs(tx, params, opt_pspecs)
@@ -566,6 +585,13 @@ def make_spmd_train_step(
                     "key; train_loop adds it automatically — manual callers "
                     "must pass one per step")
             return jitted(params, opt_state, batch, rng)
+
+        def lower(params, opt_state, batch):
+            batch = dict(batch)
+            rng = batch.pop("dropout_rng")
+            return jitted.lower(params, opt_state, batch, rng)
+
+        train_step.lower = lower  # same inspection surface as a bare jit
     else:
         train_step = jax.jit(
             step,

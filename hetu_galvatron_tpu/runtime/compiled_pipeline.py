@@ -99,6 +99,7 @@ from hetu_galvatron_tpu.runtime.hybrid_config import HybridParallelConfig
 from hetu_galvatron_tpu.runtime.mesh import (
     axes_size,
     build_mesh,
+    flash_kernel_runs,
     lower_strategy,
     lower_vocab_strategy,
     make_pp_rotation,
@@ -280,8 +281,8 @@ class CompiledPipelineEngine:
         cfg = self.cfg
         use_flash = self._use_flash
         if use_flash is None:
-            use_flash = bool(cfg.use_flash_attn) and all(
-                d.platform == "tpu" for d in self.mesh.devices.flat[:1])
+            use_flash = flash_kernel_runs(cfg.use_flash_attn,
+                                          self.mesh.devices.flat)
         if sh.cp_axes:
             from hetu_galvatron_tpu.ops.ring_attention import make_ring_sdpa
 

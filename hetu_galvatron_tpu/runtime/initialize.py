@@ -17,6 +17,7 @@ from __future__ import annotations
 import logging
 import os
 import random
+import sys
 from dataclasses import dataclass, field
 from typing import Any, List, Optional
 
@@ -71,11 +72,25 @@ def set_seed(seed: int) -> None:
     np.random.seed(seed)
 
 
+class _CurrentStderrHandler(logging.StreamHandler):
+    """A StreamHandler on whatever ``sys.stderr`` is when a record is
+    emitted, not when the handler was built: the logger outlives
+    redirections of stderr (a supervisor's log file, a test's capture)."""
+
+    @property
+    def stream(self):
+        return sys.stderr
+
+    @stream.setter
+    def stream(self, value):  # StreamHandler.__init__ assigns it
+        pass
+
+
 def _make_logger(args: CoreArgs) -> logging.Logger:
     logger = logging.getLogger("hetu_galvatron_tpu")
     logger.propagate = False  # avoid double lines via the root logger
     if not logger.handlers:
-        h = logging.StreamHandler()
+        h = _CurrentStderrHandler()
         h.setFormatter(logging.Formatter("[%(levelname)s] %(message)s"))
         logger.addHandler(h)
     logger.setLevel(getattr(logging, args.logging.log_level.upper(),
@@ -148,9 +163,21 @@ def initialize_distributed(args: CoreArgs) -> bool:
     return True
 
 
+def _world_of(num_devices: int, visible: int) -> int:
+    """``parallel.num_devices`` (0 = every visible chip) checked against
+    what the backend shows. Asking for more than is visible RAISES: a
+    plan searched for 4 chips silently training on 1 is a different run."""
+    if num_devices > visible:
+        raise ValueError(
+            f"parallel.num_devices={num_devices} but only {visible} "
+            "device(s) are visible; fix the config or the host (0 = use "
+            "every visible device)")
+    return num_devices if num_devices > 0 else visible
+
+
 def visible_world_size(args: CoreArgs) -> int:
     """The effective world size a run of ``args`` would see: every
-    visible chip, clamped by ``parallel.num_devices`` — the SAME
+    visible chip, or ``parallel.num_devices`` of them — the SAME
     derivation :func:`initialize` records in ``RunState.world_size``.
     Joins the coordination service first on multi-host pods (the backend
     must not be probed before ``jax.distributed.initialize``). THE
@@ -160,10 +187,7 @@ def visible_world_size(args: CoreArgs) -> int:
     import jax
 
     initialize_distributed(args)
-    world = len(jax.devices())
-    if args.parallel.num_devices > 0:
-        world = min(args.parallel.num_devices, world)
-    return world
+    return _world_of(args.parallel.num_devices, len(jax.devices()))
 
 
 def initialize(args: CoreArgs, devices: Optional[List[Any]] = None
@@ -177,9 +201,7 @@ def initialize(args: CoreArgs, devices: Optional[List[Any]] = None
     if devices is None:
         initialize_distributed(args)
     devices = list(devices if devices is not None else jax.devices())
-    world = (args.parallel.num_devices if args.parallel.num_devices > 0
-             else len(devices))
-    world = min(world, len(devices))
+    world = _world_of(args.parallel.num_devices, len(devices))
     validate_args(args, world)
     set_seed(args.train.seed)
     logger = _make_logger(args)

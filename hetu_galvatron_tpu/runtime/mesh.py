@@ -56,6 +56,32 @@ HIER_SLICE_AXIS = "slice"  # cross-slice / DCN level (outer dp axes)
 HIER_HOST_AXIS = "host"    # intra-host / ICI level (inner dp axes)
 
 
+def flash_kernel_runs(use_flash_attn: bool, devices: Sequence[Any]) -> bool:
+    """THE attention-kernel rule, shared by the SPMD path, both pipeline
+    engines and the launcher's eligibility checks: the Pallas flash kernel
+    runs iff the config asks for it (``model.use_flash_attn``) and EVERY
+    device the program spans is a TPU — Mosaic compiles for nothing else.
+    A device set that mixes platforms has no single answer and raises."""
+    platforms = {d.platform for d in devices}
+    if len(platforms) != 1:
+        raise ValueError(
+            f"devices span platforms {sorted(platforms)}; the attention "
+            "kernel choice needs exactly one")
+    return bool(use_flash_attn) and platforms == {"tpu"}
+
+
+def attention_core(cp: bool, ulysses: bool, flash: bool) -> str:
+    """Name of the attention core one layer runs: ``ring`` (cp > 1) or
+    ``ulysses`` (sequence-parallel a2a) around a local core, else the local
+    core alone — ``flash`` (Mosaic-compiled Pallas kernel) or ``xla``.
+    ``parallel/spmd.attention_overrides`` dispatches on this name and the
+    launcher logs it per layer, so the log cannot drift from the dispatch."""
+    outer = "ring" if cp else "ulysses" if ulysses else None
+    if outer is None:
+        return "flash" if flash else "xla"
+    return f"{outer}+flash" if flash else outer
+
+
 def _log2(n: int) -> int:
     k = n.bit_length() - 1
     if n <= 0 or (1 << k) != n:

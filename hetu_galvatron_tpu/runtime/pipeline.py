@@ -55,6 +55,7 @@ from hetu_galvatron_tpu.runtime.mesh import (
     LayerSharding,
     build_mesh,
     device_array,
+    flash_kernel_runs,
     lower_strategy,
     lower_vocab_strategy,
     spec_tree as _spec_tree,
@@ -62,6 +63,7 @@ from hetu_galvatron_tpu.runtime.mesh import (
 from hetu_galvatron_tpu.observability.registry import get_registry
 from hetu_galvatron_tpu.observability.trace_analysis import (
     maybe_record_jit_cost,
+    mosaic_custom_calls,
 )
 from hetu_galvatron_tpu.observability.tracing import span
 from hetu_galvatron_tpu.runtime.optimizer import make_lr_schedule
@@ -125,6 +127,10 @@ class PipelineEngine:
         self.hpc = hpc
         self.train = train
         self.compute_dtype = compute_dtype
+        devices = list(devices if devices is not None else jax.devices())
+        if len(devices) < hpc.world_size:
+            raise ValueError(
+                f"need {hpc.world_size} devices, have {len(devices)}")
         self._hier_bucket_mb = float(hier_bucket_mb)
         # hierarchical dp gradient reduction (ops/hier_reduce.py): stage
         # backwards run per dp LANE (vmap over the lane-split microbatch)
@@ -150,8 +156,8 @@ class PipelineEngine:
                 # swaps them for the GSPMD core under the lane vmap)
                 _reason = HIER_KERNEL_REASON
             if _reason is None and (use_flash or (
-                    use_flash is None and cfg.use_flash_attn
-                    and jax.devices()[0].platform == "tpu")):
+                    use_flash is None
+                    and flash_kernel_runs(cfg.use_flash_attn, devices))):
                 _reason = HIER_KERNEL_REASON
             if _reason is not None:
                 raise ValueError(f"hier_dp unsupported: {_reason}")
@@ -176,10 +182,6 @@ class PipelineEngine:
                 "PipelineEngine needs pp_deg >= 2; use make_spmd_train_step "
                 "for pp=1")
         self.is_t5 = cfg.model_type == "t5"
-        devices = list(devices if devices is not None else jax.devices())
-        if len(devices) < hpc.world_size:
-            raise ValueError(
-                f"need {hpc.world_size} devices, have {len(devices)}")
         # DCN-aware global arrangement BEFORE carving stage groups: with
         # dcn_slices > 1 the pp axis (and outer dp) land on slice
         # boundaries, so each stage's submesh stays ICI-local
@@ -234,6 +236,10 @@ class PipelineEngine:
         # registry lookups after the first recorded step
         self._jit_cost_done = False
         self._record_costs = False
+        # Mosaic kernels summed over the stage BACKWARD programs (each
+        # recomputes its forward, so they hold every kernel of the step);
+        # counted by the first microbatch's backward, None before
+        self.mosaic_custom_calls: Optional[int] = None
 
     def _jit(self, name: str, build) -> Any:
         """Construct-on-first-use cache for the engine's jitted helpers."""
@@ -671,7 +677,9 @@ class PipelineEngine:
                 loss, (dp, dx) = jax.value_and_grad(
                     lambda sp_, x_: lf(sp_, x_), argnums=(0, 1))(sp, x)
                 dp = jax.tree.map(lambda t: seed * t, dp)
-                dx = jax.tree.map(lambda t: seed * t, dx)
+                # the activation cotangent keeps the activation's dtype:
+                # the previous stage's vjp refuses an f32 dy for a bf16 y
+                dx = jax.tree.map(lambda t: (seed * t).astype(t.dtype), dx)
                 return dp, dx, loss
             return jax.jit(g)
 
@@ -731,7 +739,7 @@ class PipelineEngine:
                     loss, (dp, dx) = jax.value_and_grad(
                         lf, argnums=(0, 1))(sp, x_i)
                     dp = jax.tree.map(lambda t: w_i * t, dp)
-                    return dp, w_i * dx, loss
+                    return dp, (w_i * dx).astype(dx.dtype), loss
 
                 dp_l, dx_l, loss_l = vmap_lanes(
                     lane, (0, 0, ax(mskl), ax(posl), ax(segl), 0))(
@@ -967,6 +975,11 @@ class PipelineEngine:
         with span(f"pp/bwd_s{n_stages - 1}"):
             dp, dx, loss = self._bwd_jits[-1](
                 stage_params[-1], inputs[-1], lbl, msk, seed, rng, pos, seg)
+        count_kernels = self.mosaic_custom_calls is None
+        if count_kernels:
+            kernels = mosaic_custom_calls(
+                self._bwd_jits[-1],
+                (stage_params[-1], inputs[-1], lbl, msk, seed, rng, pos, seg))
         # keep loss/aux as lazy device scalars — any host sync here would
         # serialize the schedule; train_step folds them once at the end
         aux_parts = []
@@ -982,9 +995,15 @@ class PipelineEngine:
             with span(f"pp/bwd_s{s}"):
                 dp, dx, aux = self._bwd_jits[s](
                     stage_params[s], inputs[s], dy, seed, rng, pos, seg)
+            if count_kernels:
+                kernels += mosaic_custom_calls(
+                    self._bwd_jits[s],
+                    (stage_params[s], inputs[s], dy, seed, rng, pos, seg))
             if self.cfg.num_experts:
                 aux_parts.append(aux)
             grad_acc[s] = _tree_add(grad_acc[s], dp)
+        if count_kernels:
+            self.mosaic_custom_calls = kernels
         ctx["losses"][m] = loss
         ctx["aux"][m] = aux_parts
         # free stored activations for this microbatch (1F1B memory bound)

@@ -214,47 +214,8 @@ def test_main_degrades_on_garbage(tmp_path, capsys):
     assert "nothing to gate" in capsys.readouterr().err
 
 
-def test_build_bench_candidate_merges_fresh_step_logs(tmp_path, monkeypatch):
-    """tpu_measure_all gates the measurements THIS run took: bench.py's
-    result line is the base, the pipeline/TP A/B logs contribute their
-    ratio legs, and bench.py's own legs win over the standalone benches."""
-    spec2 = importlib.util.spec_from_file_location(
-        "tpu_measure_all",
-        os.path.abspath(os.path.join(ROOT, "tools", "tpu_measure_all.py")))
-    tma = importlib.util.module_from_spec(spec2)
-    spec2.loader.exec_module(tma)
-    monkeypatch.setattr(tma, "LOG_DIR", str(tmp_path))
-
-    assert tma.build_bench_candidate() is None  # bench never completed
-
-    # realistic bench.py output: it never measures compiled_vs_host itself
-    # (that ratio comes from the standalone pipeline A/B)
-    bench_line = {k: v for k, v in PARSED.items() if k != "compiled_vs_host"}
-    (tmp_path / "bench.log").write_text(
-        "some log noise\n" + json.dumps(bench_line) + "\n")
-    (tmp_path / "pipeline_ab.log").write_text(
-        json.dumps({"compiled_vs_host": 0.66, "recompiles": 0})
-        + "\ntrailing noise\n")
-    (tmp_path / "tp_overlap.log").write_text(
-        json.dumps({"overlap_vs_gspmd": 0.55}) + "\n")
-    path = tma.build_bench_candidate()
-    parsed = json.load(open(path))["parsed"]
-    assert parsed["compiled_vs_host"] == 0.66
-    # bench.py already measured its tp_overlap leg: setdefault keeps it
-    assert parsed["tp_overlap_vs_gspmd"] == PARSED["tp_overlap_vs_gspmd"]
-    # the merged candidate flows through the gate CLI end-to-end
-    baseline = tmp_path / "baseline.json"
-    assert bench_gate.main(["--baseline", str(baseline),
-                            "--candidate", path,
-                            "--update-baseline"]) == 0
-    assert bench_gate.main(["--history", str(tmp_path / "none_r*.json"),
-                            "--baseline", str(baseline),
-                            "--candidate", path]) == 0
-
-
 def test_committed_baseline_matches_gate_schema():
-    """The repo's committed baseline must stay loadable and on-schema, or
-    the tpu_measure_all wiring silently stops gating."""
+    """The repo's committed baseline must stay loadable and on-schema."""
     with open(os.path.join(ROOT, "tools", "bench_baseline.json")) as f:
         base = json.load(f)
     assert isinstance(base.get("legs"), dict) and base["legs"]

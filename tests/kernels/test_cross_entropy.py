@@ -1,5 +1,5 @@
 """Pallas fused cross-entropy vs the XLA reference path (interpret mode on
-CPU; tools/tpu_flash_check.py exercises the Mosaic compile on hardware)."""
+CPU; chip_smoke.py exercises the Mosaic compile on hardware)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -180,7 +180,7 @@ def test_spmd_train_step_fused_ce_matches(cpu_devices):
     tx = make_optimizer(train)
     step, pspecs, ospecs, batch_shd = make_spmd_train_step(
         cfg, hpc, mesh, axes, tx, params,
-        compute_dtype=jnp.float32, donate=False)
+        compute_dtype=jnp.float32, donate=False, kernel_interpret=True)
     sp = shard_params(params, pspecs, mesh)
     opt = jax.jit(tx.init, out_shardings=jax.tree.map(
         lambda s: jax.sharding.NamedSharding(mesh, s), ospecs,
@@ -190,15 +190,22 @@ def test_spmd_train_step_fused_ce_matches(cpu_devices):
 
 
 def test_cross_entropy_loss_fused_flag():
-    """The public loss with fused=True (masked mean) == XLA path."""
+    """The public loss through the fused kernel (masked mean) == XLA path.
+    ``interpret`` reaches the kernel only from here: the bare ``fused=True``
+    asks for Mosaic, which a CPU refuses."""
+    from functools import partial
+
     logits, labels = _data(B=2, S=64, V=512)
     mask = jnp.asarray(
         np.random.RandomState(1).rand(2, 64) > 0.3, jnp.float32)
+    fused = partial(fused_ce_nll, interpret=True)
     a = cross_entropy_loss(logits, labels, mask)
-    b = cross_entropy_loss(logits, labels, mask, fused=True)
+    b = cross_entropy_loss(logits, labels, mask, fused=fused)
     np.testing.assert_allclose(float(a), float(b), rtol=1e-5)
+    with pytest.raises(ValueError, match="interpret mode"):
+        cross_entropy_loss(logits, labels, mask, fused=True)
     ga = jax.grad(lambda x: cross_entropy_loss(x, labels, mask))(logits)
     gb = jax.grad(lambda x: cross_entropy_loss(x, labels, mask,
-                                               fused=True))(logits)
+                                               fused=fused))(logits)
     np.testing.assert_allclose(np.asarray(ga), np.asarray(gb),
                                rtol=1e-5, atol=1e-6)

@@ -2,7 +2,7 @@
 CPU (--smoke), complete its request budget, and report a parseable JSON
 with zero steady-state recompiles — plus the shared-prefix trace mode
 (hit/miss TTFT split) and the importable serve_prefix / spec_decode A/B
-legs bench.py and bench_gate.py consume."""
+legs bench_gate.py consumes."""
 
 import json
 import os
@@ -65,7 +65,7 @@ def test_serve_bench_shared_prefix_trace(tmp_path):
 
 
 def test_serve_bench_ab_legs_importable():
-    """run_prefix / run_spec (the bench.py legs): sane ratios, zero
+    """run_prefix / run_spec (the bench_gate legs): sane ratios, zero
     steady-state recompiles, lossless spec. Shrunk shapes — this is a
     wiring test, not a measurement."""
     sys.path.insert(0, os.path.join(REPO, "tools"))

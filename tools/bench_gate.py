@@ -21,7 +21,7 @@ human rereading them. This gate closes that loop:
   freshly committed entry),
 * the history's per-leg min/max rides along as a noise-context column.
 
-Wiring: ``tools/tpu_measure_all.py`` runs the gate after its bench step;
+Wiring: nothing runs the gate on a chip today;
 ``__graft_entry__.dryrun_multichip`` runs ``--smoke`` (a synthetic
 self-check: an unchanged run must pass, an artificially regressed leg
 must fail) so the gate itself is exercised on every CI dryrun with no

@@ -26,12 +26,12 @@ load hits both alike, summarized by medians:
   overlap, so the ratio mostly prices the bucketing overhead (slice /
   concat / extra collective dispatch) — the gate pins it at <= ~1.0 so
   the bucketed program never costs more than it hides; the overlap WIN
-  itself needs a real multi-slice fleet (tools/tpu_measure_all.py).
+  itself needs a real multi-slice fleet.
 
 Prints one JSON line. Run (virtual CPU mesh):
     JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
         python tools/hier_dp_bench.py
-On a real slice (tools/tpu_measure_all.py step): add ``--tpu``.
+On a real slice: add ``--tpu``.
 """
 
 from __future__ import annotations

@@ -7,7 +7,7 @@ replaced until the request budget is spent — the standard way to find a
 serving stack's latency/throughput operating point (open-loop arrival
 replays live in ``cli/serve.py`` via ``arrival_offset_s``).
 
-CPU-runnable smoke mode (like ``bench.py``'s probe path)::
+CPU-runnable smoke mode::
 
     JAX_PLATFORMS=cpu python tools/serve_bench.py --smoke
 
@@ -28,8 +28,8 @@ Weights are random (the bench measures the serving machinery, not the
 model); pass ``--json out.json`` for a machine-readable report and
 ``--metrics m.jsonl`` to keep the engine's own telemetry stream.
 
-``run_prefix()`` / ``run_spec()`` are the importable A/B legs ``bench.py``
-and ``tools/bench_gate.py`` consume (committed CPU baselines in
+``run_prefix()`` / ``run_spec()`` are the importable A/B legs
+``tools/bench_gate.py`` consumes (committed CPU baselines in
 ``tools/bench_baseline.json``): hit-vs-cold TTFT ratio and
 spec-vs-plain tokens/sec ratio, both at zero steady-state recompiles.
 """

@@ -27,7 +27,7 @@ summarized by medians:
 Prints one JSON line. Run (virtual CPU mesh):
     JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
         python tools/tp_overlap_bench.py [--schedule-impl compiled]
-On a real chip (tools/tpu_measure_all.py step): add ``--tpu``.
+On real chips: add ``--tpu``.
 """
 
 from __future__ import annotations
@@ -42,10 +42,10 @@ if REPO not in sys.path:
     sys.path.insert(0, REPO)
 
 _FLAG = "--xla_force_host_platform_device_count=8"
-# The CPU pin must only fire on DIRECT invocation: importers (bench.py's
-# tp_overlap leg, the tests) set their own platform env, and a leg that
-# wants the real chip would otherwise be silently forced onto 8 virtual
-# CPU devices by this module-level guard (its argv never carries --tpu)
+# The CPU pin must only fire on DIRECT invocation: importers (the tests)
+# set their own platform env, and a leg that wants the real chip would
+# otherwise be silently forced onto 8 virtual CPU devices by this
+# module-level guard (its argv never carries --tpu)
 if __name__ == "__main__" and "--tpu" not in sys.argv:
     if "xla_force_host_platform_device_count" not in os.environ.get(
             "XLA_FLAGS", ""):
