@@ -32,10 +32,11 @@ Every train leg checks: exit code 0, every loss finite, first loss within
 custom calls counted in the compiled step's HLO (three per layer). The
 four-chip plans must also reproduce the one-chip run's losses.
 
-The last line of stdout is one JSON object:
-``{"ok": true, "device": {"platform": "tpu", "kind": ..., "count": N}, ...}``.
-Any failed leg ends the run at once with a non-zero exit code and no such
-line. Times printed here are smoke timings of one run each (compile and
+The last line of stdout is one JSON object with these keys and no other:
+``{"ok": true, "device": {"platform": "tpu", "kind": ..., "count": N}}``,
+the device as JAX reports it. The line before it, ``report: {...}``, carries
+the versions and every leg's figures. Any failed leg ends the run at once
+with a non-zero exit code and neither line. Times printed here are smoke timings of one run each (compile and
 first-step seconds); they are not benchmark metrics.
 
 The leg bodies (:func:`leg_kernels`, :func:`leg_train`) are plain functions
@@ -436,6 +437,9 @@ def spawn_leg(leg: str, deadline: float) -> Dict[str, Any]:
 def run_parent() -> Dict[str, Any]:
     """Every leg in turn; returns the final report or raises
     :class:`SmokeFailure` at the first leg that fails."""
+    require(os.path.isfile(CONFIG),
+            f"{CONFIG} is missing: chip_smoke.py runs from the root of a "
+            "checkout of the repository, not on its own")
     deadline = time.monotonic() + TOTAL_BUDGET_S
     legs: Dict[str, Any] = {}
     first = legs["kernels"] = spawn_leg("kernels", deadline)
@@ -471,6 +475,13 @@ def run_parent() -> Dict[str, Any]:
                      for n, r in legs.items()}}
 
 
+def result_line(report: Dict[str, Any]) -> str:
+    """The driver's line: ``ok`` and the device, exactly those keys."""
+    d = report["device"]
+    return json.dumps({"ok": report["ok"], "device": {
+        "platform": d["platform"], "kind": d["kind"], "count": d["count"]}})
+
+
 def main(argv: Optional[Sequence[str]] = None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--leg", choices=["kernels"] + list(PLANS),
@@ -479,7 +490,9 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
     args = ap.parse_args(argv)
     try:
         if args.leg is None:
-            print(json.dumps(run_parent()), flush=True)
+            report = run_parent()
+            print("report: " + json.dumps(report), flush=True)
+            print(result_line(report), flush=True)
         else:
             run_child(args.leg)
         return 0

@@ -128,6 +128,7 @@ def test_parent_flow_with_canned_children(monkeypatch):
     assert calls == ["kernels", "train1", "train1"]
     assert rep["ok"] and rep["device"]["count"] == 1
     assert set(rep["legs"]) == {"kernels", "train1", "train1_cached"}
+    assert rep["versions"] == {"jax": "0.9.0"}
     json.dumps(rep)
 
     calls.clear()
@@ -144,6 +145,37 @@ def test_parent_flow_with_canned_children(monkeypatch):
     monkeypatch.setattr(chip_smoke, "spawn_leg", fake_spawn(4, stray=0.5))
     with pytest.raises(chip_smoke.SmokeFailure, match="stray"):
         chip_smoke.run_parent()
+
+
+def test_last_stdout_line_is_ok_and_device_and_nothing_else(
+        monkeypatch, capsys):
+    """The driver reads the last line: exactly ``ok`` and ``device``, the
+    device exactly platform, kind and count. Everything else the smoke
+    learned goes on the ``report:`` line before it."""
+    monkeypatch.setattr(
+        chip_smoke, "spawn_leg",
+        lambda leg, deadline: _canned_leg(leg, 1, hits=2))
+    assert chip_smoke.main([]) == 0
+    lines = capsys.readouterr().out.splitlines()
+    last = json.loads(lines[-1])
+    assert last == {"ok": True, "device": {
+        "platform": "tpu", "kind": "TPU v5 lite", "count": 1}}
+    assert isinstance(last["device"]["count"], int)
+    assert lines[-2].startswith("report: ")
+    assert set(json.loads(lines[-2][len("report: "):])) == {
+        "ok", "device", "versions", "legs"}
+
+
+def test_script_alone_fails_before_it_starts_a_child(tmp_path):
+    """In a directory that holds chip_smoke.py and nothing else of the
+    repo: non-zero, no result, whatever devices the host shows."""
+    alone = tmp_path / "chip_smoke.py"
+    alone.write_text(open(SMOKE).read())
+    proc = subprocess.run([sys.executable, str(alone)], cwd=tmp_path,
+                          capture_output=True, text=True, timeout=60)
+    assert proc.returncode != 0
+    assert "is missing" in proc.stderr
+    assert proc.stdout == ""
 
 
 # ---------------------------------------------------------------------------
