@@ -1,0 +1,138 @@
+"""The window logic and the result line at a tiny size on the CPU, through
+the harness's own functions (the command itself refuses a CPU)."""
+
+import json
+import math
+import os
+import subprocess
+import sys
+
+import pytest
+
+from benchmark import check, manifest, run, window
+from benchmark.tests import tiny
+
+RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+
+
+def _measure(tmp_path, cell_name, **kw):
+    root = tiny.make_root(tmp_path)
+    man = manifest.load_manifest(root)
+    cell = manifest.resolve_cell(man, cell_name, root)
+    return run.measure(cell, seed=7, seconds=0.5, trace=0,
+                       chip=tiny.FAKE_CHIP, root=root,
+                       out_dir=str(tmp_path / "out"), expect_mosaic=False,
+                       **kw)
+
+
+@pytest.mark.parametrize("cell_name,chips", [("tiny_gpt2_c1", 1),
+                                             ("tiny_mistral_c4", 4)])
+def test_cell_runs_through_train_dist_and_ends_its_own_window(
+        tmp_path, cell_name, chips):
+    line, report = _measure(tmp_path, cell_name)
+    assert set(line) == RESULT_KEYS
+    assert set(line["metrics"]) == {"tokens_per_s", "mfu_pct", "setup_s"}
+    assert all(set(m) == {"value", "unit"} and m["value"] > 0
+               for m in line["metrics"].values())
+    assert line["failed"] == 0 and line["attempted"] == len(report["steps_ms"])
+    assert line["attempted"] >= 5
+    # the window closed at the first step that ended 0.5 s after its start
+    w = report["window"]
+    assert w["wall_s"] >= 0.5
+    assert w["wall_s"] - report["steps_ms"][-1] / 1e3 < 0.5
+    assert math.isclose(
+        line["metrics"]["tokens_per_s"]["value"],
+        report["tokens_per_step"] / w["median_period_s"])
+    # warm-up steps are not in the window; their losses are kept
+    assert len(report["losses"]) == window.WARMUP_STEPS + line["attempted"]
+    checks = report["checks"]
+    assert checks["ended_by_harness"] and checks["losses_finite"]
+    assert checks["step0_matches_reference"], (
+        report["losses"][0], report["reference"])
+    assert line["correct"] is True, checks
+    assert line["device"]["count"] >= chips
+    json.dumps(line)
+
+
+def test_reference_comparison_fails_for_a_dropped_block(tmp_path):
+    root = tiny.make_root(tmp_path)
+    man = manifest.load_manifest(root)
+    cell = manifest.resolve_cell(man, "tiny_gpt2_c1", root)
+    argv = manifest.train_argv(cell, 7, root)
+    full = check.reference_loss(cell, argv, 7, root, str(tmp_path))["loss"]
+    short = check.reference_loss(cell, argv, 7, root, str(tmp_path),
+                                 layers=1)["loss"]
+    assert full != short
+    facts = {"losses": [full], "window_losses": [], "rc": 18,
+             "signalled": True, "raised": None, "attention_cores": ["xla"],
+             "mosaic_custom_calls": 0}
+    ok = check.judge(facts, reference=full, tolerance=1e-6,
+                     expect_mosaic=False)["checks"]
+    bad = check.judge(facts, reference=short,
+                      tolerance=abs(full - short) / 2,
+                      expect_mosaic=False)["checks"]
+    assert ok["step0_matches_reference"]
+    assert not bad["step0_matches_reference"]
+
+
+def test_judge_names_each_failed_condition():
+    good = {"losses": [5.0] * 8, "window_losses": [4.9] * 5, "rc": 18,
+            "signalled": True, "raised": None,
+            "attention_cores": ["flash"] * 2, "mosaic_custom_calls": 6,
+            "compile": {"window": {"cache_writes": 0,
+                                   "backend_compiles": 0}}}
+    assert check.judge(good, reference=5.0, tolerance=0.01)["correct"]
+    for change, failed in [
+            ({"rc": 0}, "ended_by_harness"),
+            ({"losses": [5.0, float("nan")] + [5.0] * 6}, "losses_finite"),
+            ({"attention_cores": ["flash", "xla"]}, "flash_core_everywhere"),
+            ({"mosaic_custom_calls": 5}, "flash_core_everywhere"),
+            ({"compile": {"window": {"cache_writes": 1,
+                                     "backend_compiles": 1}}},
+             "no_compile_in_window"),
+            ({"window_losses": [9.0] * 5}, "loss_not_rising")]:
+        v = check.judge({**good, **change}, reference=5.0, tolerance=0.01)
+        assert not v["correct"] and not v["checks"][failed], (change, v)
+    assert not check.judge(good, reference=5.5,
+                           tolerance=0.01)["checks"]["step0_matches_reference"]
+
+
+def test_window_signals_once_at_the_first_sample_past_its_length():
+    stops = []
+    w = window.Window(1.0, stop=lambda: stops.append(1))
+    w.on_sample(10.4, 400.0)    # step [10.0, 10.4]
+    w.on_sample(10.9, 400.0)
+    assert not stops
+    w.on_sample(11.3, 400.0)    # 1.3 s after the first start
+    w.on_sample(11.7, 400.0)
+    assert stops == [1] and w.signalled
+    assert w.start == pytest.approx(10.0) and w.end == 11.7
+
+
+def test_one_stalled_step_does_not_move_the_rate():
+    steps = [(k * 0.23, k * 0.23 + 0.222) for k in range(44)]
+    calm = window.steady_rate(steps)
+    assert calm["median_period_s"] == pytest.approx(0.23)
+    assert calm["loop_overhead_ms"] == pytest.approx(8.0)
+    assert calm["stall_pct"] == pytest.approx(0.0, abs=1e-9)
+    # step 7 stalls for 1.1 s (seen on the chip in gpt2xl_c1_b4)
+    late = [(s + (1.1 if k > 7 else 0), e + (1.1 if k >= 7 else 0))
+            for k, (s, e) in enumerate(steps)]
+    stalled = window.steady_rate(late)
+    assert stalled["median_period_s"] == pytest.approx(0.23)
+    assert stalled["stall_pct"] == pytest.approx(
+        100 * 1.1 / (43 * 0.23 + 1.1))
+    with pytest.raises(ValueError):
+        window.steady_rate(steps[:1])
+
+
+def test_command_refuses_a_cpu():
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    p = subprocess.run(
+        [sys.executable, os.path.join(manifest.ROOT, "benchmark", "run.py"),
+         "--workload", "gpt2xl_c1_b4", "--seed", "1", "--seconds", "1",
+         "--trace", "0"], env=env, capture_output=True, text=True,
+        cwd=manifest.ROOT)
+    assert p.returncode == 3, p.stderr[-2000:]
+    assert "refusing to run" in p.stderr
+    assert not p.stdout.strip().startswith("{")
