@@ -52,190 +52,210 @@ def _flight_dump_elastic(args, reason: str, live_world: int,
 
 
 def train(args) -> Dict[str, Any]:
-    import jax
-    from jax.sharding import NamedSharding, PartitionSpec
+    from hetu_galvatron_tpu.observability.tracing import span
 
-    from hetu_galvatron_tpu.core.profiler.runtime_profiler import RuntimeProfiler
-    from hetu_galvatron_tpu.models.builder import init_causal_lm
-    from hetu_galvatron_tpu.parallel.spmd import make_spmd_train_step
-    from hetu_galvatron_tpu.runtime.checkpoint import (
-        CheckpointCadence,
-        clear_resume_pin,
-        latest_checkpoint,
-        load_latest_resilient,
-        save_checkpoint,
-        try_read_checkpoint_meta,
-    )
-    from hetu_galvatron_tpu.runtime.chaos import make_chaos
-    from hetu_galvatron_tpu.runtime.dataloader import (
-        get_train_valid_test_data_iterators,
-        skip_batches,
-    )
-    from hetu_galvatron_tpu.runtime.hybrid_config import get_hybrid_parallel_config
-    from hetu_galvatron_tpu.runtime.initialize import initialize
-    from hetu_galvatron_tpu.runtime.mesh import build_mesh
-    from hetu_galvatron_tpu.runtime.optimizer import make_lr_schedule, make_optimizer
-    from hetu_galvatron_tpu.runtime.pipeline import PipelineEngine
-    from hetu_galvatron_tpu.runtime.rerun_machine import (
-        FaultDrill,
-        RerunDataIterator,
-        RerunStateMachine,
-    )
-    from hetu_galvatron_tpu.runtime.supervisor import PreemptionGuard
-    from hetu_galvatron_tpu.utils.hf_config_adapter import resolve_model_config
+    # one-shot set-up spans (registry only: no profiler window is open
+    # yet); with the first iteration's train/dispatch they tile the time
+    # from here to the loop. The imports are timed where they run.
+    with span("setup/imports"):
+        import jax
+        from jax.sharding import NamedSharding, PartitionSpec
 
-    args = resolve_model_config(args)
-
-    # goodput accounting (observability/goodput.py): wall-clock
-    # partitioned into productive / recompile / save / resume-replay /
-    # reshard / restart-lost; snapshots ride every checkpoint's
-    # train_state, so the goodput/* gauges survive preemption with the
-    # model state. Constructed before the elastic pre-pass so topology
-    # changes bill their re-search + reshard wall into the new bucket.
-    from hetu_galvatron_tpu.observability.goodput import GoodputTracker
-
-    goodput = GoodputTracker()
-
-    # ----- elastic pre-pass: detect a topology-changed resume -----------
-    # BEFORE initialize/plan construction: the preserved CLI plan (or the
-    # checkpoint's JSON plan) describes the OLD world and may not even
-    # validate on the new one. When the live world differs from the
-    # checkpoint's recorded world_size, re-search a plan for the new
-    # topology (cli/search_dist.py internals), gate it through the memory
-    # doctor's HBM budget, and remember to reshard instead of plain-load.
-    elastic = None
-    if args.ckpt.load:
-        from hetu_galvatron_tpu.runtime.initialize import (
-            visible_world_size,
+        from hetu_galvatron_tpu.core.profiler.runtime_profiler import (
+            RuntimeProfiler,
+            compiled_memory_bytes,
+        )
+        from hetu_galvatron_tpu.models.builder import init_causal_lm
+        from hetu_galvatron_tpu.parallel.spmd import make_spmd_train_step
+        from hetu_galvatron_tpu.runtime.checkpoint import (
+            CheckpointCadence,
+            clear_resume_pin,
+            latest_checkpoint,
+            load_latest_resilient,
+            save_checkpoint,
+            try_read_checkpoint_meta,
+        )
+        from hetu_galvatron_tpu.runtime.chaos import make_chaos
+        from hetu_galvatron_tpu.runtime.dataloader import (
+            get_train_valid_test_data_iterators,
+            skip_batches,
+        )
+        from hetu_galvatron_tpu.runtime.hybrid_config import (
+            get_hybrid_parallel_config,
+        )
+        from hetu_galvatron_tpu.runtime.initialize import initialize
+        from hetu_galvatron_tpu.runtime.mesh import build_mesh
+        from hetu_galvatron_tpu.runtime.optimizer import (
+            make_lr_schedule,
+            make_optimizer,
+        )
+        from hetu_galvatron_tpu.runtime.pipeline import PipelineEngine
+        from hetu_galvatron_tpu.runtime.rerun_machine import (
+            FaultDrill,
+            RerunDataIterator,
+            RerunStateMachine,
+        )
+        from hetu_galvatron_tpu.runtime.supervisor import PreemptionGuard
+        from hetu_galvatron_tpu.utils.hf_config_adapter import (
+            resolve_model_config,
         )
 
-        live_world = visible_world_size(args)
-        ckdir0 = latest_checkpoint(args.ckpt.load)
-        stored_plan = (try_read_checkpoint_meta(ckdir0)[0]
-                       .get("hybrid_parallel_config") if ckdir0 else None)
-        stored_world = (stored_plan or {}).get("world_size")
-        if stored_world and int(stored_world) != live_world:
-            from hetu_galvatron_tpu.cli.search_dist import replan_for_world
-            from hetu_galvatron_tpu.runtime.rerun_machine import (
-                EXIT_CODE_FAILED_ON_RESULT_VALIDATION,
+    with span("setup/runtime"):
+        args = resolve_model_config(args)
+
+        # goodput accounting (observability/goodput.py): wall-clock
+        # partitioned into productive / recompile / save / resume-replay /
+        # reshard / restart-lost; snapshots ride every checkpoint's
+        # train_state, so the goodput/* gauges survive preemption with the
+        # model state. Constructed before the elastic pre-pass so topology
+        # changes bill their re-search + reshard wall into the new bucket.
+        from hetu_galvatron_tpu.observability.goodput import GoodputTracker
+
+        goodput = GoodputTracker()
+
+        # ----- elastic pre-pass: detect a topology-changed resume -----------
+        # BEFORE initialize/plan construction: the preserved CLI plan (or the
+        # checkpoint's JSON plan) describes the OLD world and may not even
+        # validate on the new one. When the live world differs from the
+        # checkpoint's recorded world_size, re-search a plan for the new
+        # topology (cli/search_dist.py internals), gate it through the memory
+        # doctor's HBM budget, and remember to reshard instead of plain-load.
+        elastic = None
+        if args.ckpt.load:
+            from hetu_galvatron_tpu.runtime.initialize import (
+                visible_world_size,
             )
 
-            print(f"elastic resume: {ckdir0} was committed by a "
-                  f"{stored_world}-device world; live world is "
-                  f"{live_world} — re-planning", flush=True)
-            with goodput.measure("reshard"):
-                reason = replan_for_world(args, live_world, stored_plan)
-            if reason is not None:
-                # terminal by contract: an infeasible or OOM-rejected
-                # target plan reproduces on every restart — exit 17 with
-                # a flight-recorder postmortem, never a restart loop
-                print(f"elastic resume failed terminally: {reason}",
-                      flush=True)
-                dump = _flight_dump_elastic(args, reason, live_world,
-                                            stored_world,
-                                            "elastic_plan_rejected")
-                return {"losses": [], "val_losses": [], "test_loss": None,
-                        "iter_ms": 0.0, "rerun": None,
-                        "goodput": {"totals": dict(goodput.totals),
-                                    "frac": goodput.goodput(),
-                                    "restarts_survived":
-                                        goodput.restarts_survived},
-                        "flight_dumps": [dump] if dump else [],
-                        "exit_code": EXIT_CODE_FAILED_ON_RESULT_VALIDATION}
-            elastic = {"ckdir": ckdir0, "stored_world": int(stored_world)}
+            live_world = visible_world_size(args)
+            ckdir0 = latest_checkpoint(args.ckpt.load)
+            stored_plan = (try_read_checkpoint_meta(ckdir0)[0]
+                           .get("hybrid_parallel_config") if ckdir0 else None)
+            stored_world = (stored_plan or {}).get("world_size")
+            if stored_world and int(stored_world) != live_world:
+                from hetu_galvatron_tpu.cli.search_dist import replan_for_world
+                from hetu_galvatron_tpu.runtime.rerun_machine import (
+                    EXIT_CODE_FAILED_ON_RESULT_VALIDATION,
+                )
 
-    state = initialize(args)
-    world = state.world_size
-    hpc = get_hybrid_parallel_config(args, world)
-    state.log(f"parallel plan: {hpc.describe()}")
+                print(f"elastic resume: {ckdir0} was committed by a "
+                      f"{stored_world}-device world; live world is "
+                      f"{live_world} — re-planning", flush=True)
+                with goodput.measure("reshard"):
+                    reason = replan_for_world(args, live_world, stored_plan)
+                if reason is not None:
+                    # terminal by contract: an infeasible or OOM-rejected
+                    # target plan reproduces on every restart — exit 17 with
+                    # a flight-recorder postmortem, never a restart loop
+                    print(f"elastic resume failed terminally: {reason}",
+                          flush=True)
+                    dump = _flight_dump_elastic(args, reason, live_world,
+                                                stored_world,
+                                                "elastic_plan_rejected")
+                    return {"losses": [], "val_losses": [], "test_loss": None,
+                            "iter_ms": 0.0, "rerun": None,
+                            "goodput": {"totals": dict(goodput.totals),
+                                        "frac": goodput.goodput(),
+                                        "restarts_survived":
+                                            goodput.restarts_survived},
+                            "flight_dumps": [dump] if dump else [],
+                            "exit_code": EXIT_CODE_FAILED_ON_RESULT_VALIDATION}
+                elastic = {"ckdir": ckdir0, "stored_world": int(stored_world)}
 
-    cfg = args.model
-    # which attention core each layer runs — decided ONCE from the plan and
-    # the run's own devices (runtime/mesh.py), logged, and returned in the
-    # result so a run on the XLA core can never pass for a kernel run
-    from collections import Counter
+        state = initialize(args)
+        world = state.world_size
+        hpc = get_hybrid_parallel_config(args, world)
+        state.log(f"parallel plan: {hpc.describe()}")
 
-    from hetu_galvatron_tpu.runtime.mesh import (
-        attention_core,
-        flash_kernel_runs,
-    )
+        cfg = args.model
+        # which attention core each layer runs — decided ONCE from the plan
+        # and the run's own devices (runtime/mesh.py), logged, and returned in
+        # the result so a run on the XLA core can never pass for a kernel run
+        from collections import Counter
 
-    use_flash = flash_kernel_runs(cfg.use_flash_attn, state.devices)
-    attention_cores = [
-        attention_core(s.cp_size > 1, bool(s.sp and s.tp_size > 1), use_flash)
-        for s in hpc.layers]
-    state.log("attention cores: " + ", ".join(
-        f"{n} x {core}" for core, n in Counter(attention_cores).items()))
-
-    # abstract init first: the plan's shardings are derived from SHAPES, so
-    # no device materializes the unsharded tree before they exist (the
-    # pp=1 path then initializes straight into its shards)
-    init_key = jax.random.key(args.train.seed)
-    axes_box: Dict[str, Any] = {}
-
-    def init_params(key):
-        p, axes_box["axes"] = init_causal_lm(key, cfg)
-        return p
-
-    params = jax.eval_shape(init_params, init_key)
-    axes = axes_box["axes"]
-    tx = make_optimizer(args.train)
-    schedule = make_lr_schedule(args.train)
-    base_iter, valid_iter, test_iter = get_train_valid_test_data_iterators(
-        args, global_batch_size=hpc.global_bsz, hpc=hpc)
-    data_iter = RerunDataIterator(base_iter)
-    # unified telemetry (observability/): configures the process-wide
-    # registry with JSONL (+optional TensorBoard) sinks, so the profiler's
-    # histograms, the rerun machine's counters, and the derived
-    # throughput/MFU stats all land in one metrics stream
-    telemetry = None
-    # rank-gated like the profiler's printing and TraceCapture: on a
-    # multi-host pod only process 0 writes the metrics stream (every
-    # process appending to one shared-storage JSONL would interleave)
-    if args.observability.enabled and jax.process_index() == 0:
-        from hetu_galvatron_tpu.observability.telemetry import (
-            emit_plan_telemetry,
+        from hetu_galvatron_tpu.runtime.mesh import (
+            attention_core,
+            flash_kernel_runs,
         )
-        from hetu_galvatron_tpu.runtime.trainer import make_telemetry
 
-        telemetry = make_telemetry(args, world_size=world,
-                                   global_batch_size=hpc.global_bsz)
-        emit_plan_telemetry(
-            telemetry.registry, hpc, cfg,
-            mixed_precision=args.parallel.mixed_precision != "fp32")
-    # crash-forensics flight recorder (observability/recorder.py): dumps
-    # flight_<ts>.json on crash / trapped signal / rerun halt. Directory:
-    # observability.flight_dir, else (when telemetry owns a stream) the
-    # metrics file's directory
-    recorder = None
-    if jax.process_index() == 0 and (telemetry is not None
-                                     or args.observability.flight_dir):
-        from hetu_galvatron_tpu.observability.recorder import FlightRecorder
+        use_flash = flash_kernel_runs(cfg.use_flash_attn, state.devices)
+        attention_cores = [
+            attention_core(s.cp_size > 1, bool(s.sp and s.tp_size > 1),
+                           use_flash)
+            for s in hpc.layers]
+        state.log("attention cores: " + ", ".join(
+            f"{n} x {core}" for core, n in Counter(attention_cores).items()))
 
-        recorder = FlightRecorder(
-            registry=(telemetry.registry if telemetry is not None
-                      else None),
-            out_dir=_flight_dir_of(args),
-            capacity=args.observability.flight_events)
-        recorder.note("run_start", plan=hpc.describe(), world=world)
-    profiler = RuntimeProfiler(args, world_size=world,
-                               rank=jax.process_index())
-    rerun = RerunStateMachine(args.rerun)
-    # preemption guard + at-step-k fault drill (runtime/supervisor.py):
-    # SIGTERM/SIGINT become a checkpoint-and-exit at the next step boundary
-    guard = PreemptionGuard(enabled=args.supervisor.graceful_signals,
-                            recorder=recorder)
-    drill = FaultDrill(args.rerun)
-    # chaos fault plan (runtime/chaos.py): step-targeted crashes/signals
-    # plus mid-save and retry-seam faults, one-shot across process
-    # restarts via marker files next to the checkpoints
-    chaos = make_chaos(args,
-                       registry=(telemetry.registry if telemetry is not None
-                                 else None),
-                       log=state.log)
-    if chaos is not None:
-        chaos.install()
-        state.log(f"chaos: armed faults {chaos.pending()}")
+        # abstract init first: the plan's shardings are derived from SHAPES, so
+        # no device materializes the unsharded tree before they exist (the
+        # pp=1 path then initializes straight into its shards)
+        init_key = jax.random.key(args.train.seed)
+        axes_box: Dict[str, Any] = {}
+
+        def init_params(key):
+            p, axes_box["axes"] = init_causal_lm(key, cfg)
+            return p
+
+        params = jax.eval_shape(init_params, init_key)
+        axes = axes_box["axes"]
+        tx = make_optimizer(args.train)
+        schedule = make_lr_schedule(args.train)
+        base_iter, valid_iter, test_iter = get_train_valid_test_data_iterators(
+            args, global_batch_size=hpc.global_bsz, hpc=hpc)
+        data_iter = RerunDataIterator(base_iter)
+        # unified telemetry (observability/): configures the process-wide
+        # registry with JSONL (+optional TensorBoard) sinks, so the profiler's
+        # histograms, the rerun machine's counters, and the derived
+        # throughput/MFU stats all land in one metrics stream
+        telemetry = None
+        # rank-gated like the profiler's printing and TraceCapture: on a
+        # multi-host pod only process 0 writes the metrics stream (every
+        # process appending to one shared-storage JSONL would interleave)
+        if args.observability.enabled and jax.process_index() == 0:
+            from hetu_galvatron_tpu.observability.telemetry import (
+                emit_plan_telemetry,
+            )
+            from hetu_galvatron_tpu.runtime.trainer import make_telemetry
+
+            telemetry = make_telemetry(args, world_size=world,
+                                       global_batch_size=hpc.global_bsz)
+            emit_plan_telemetry(
+                telemetry.registry, hpc, cfg,
+                mixed_precision=args.parallel.mixed_precision != "fp32")
+        # crash-forensics flight recorder (observability/recorder.py): dumps
+        # flight_<ts>.json on crash / trapped signal / rerun halt. Directory:
+        # observability.flight_dir, else (when telemetry owns a stream) the
+        # metrics file's directory
+        recorder = None
+        if jax.process_index() == 0 and (telemetry is not None
+                                         or args.observability.flight_dir):
+            from hetu_galvatron_tpu.observability.recorder import (
+                FlightRecorder,
+            )
+
+            recorder = FlightRecorder(
+                registry=(telemetry.registry if telemetry is not None
+                          else None),
+                out_dir=_flight_dir_of(args),
+                capacity=args.observability.flight_events)
+            recorder.note("run_start", plan=hpc.describe(), world=world)
+        profiler = RuntimeProfiler(args, world_size=world,
+                                   rank=jax.process_index())
+        rerun = RerunStateMachine(args.rerun)
+        # preemption guard + at-step-k fault drill (runtime/supervisor.py):
+        # SIGTERM/SIGINT become a checkpoint-and-exit at the next step boundary
+        guard = PreemptionGuard(enabled=args.supervisor.graceful_signals,
+                                recorder=recorder)
+        drill = FaultDrill(args.rerun)
+        # chaos fault plan (runtime/chaos.py): step-targeted crashes/signals
+        # plus mid-save and retry-seam faults, one-shot across process
+        # restarts via marker files next to the checkpoints
+        chaos = make_chaos(
+            args,
+            registry=(telemetry.registry if telemetry is not None else None),
+            log=state.log)
+        if chaos is not None:
+            chaos.install()
+            state.log(f"chaos: armed faults {chaos.pending()}")
     start_iter = 0
 
     # overlapped-TP collectives (tp_overlap.enable, ops/overlap.py):
@@ -447,11 +467,12 @@ def train(args) -> Dict[str, Any]:
 
     def maybe_save(it, sp, so):
         if cadence.due(it):
-            cadence.save(it + 1, sp, so,
-                         train_state=train_state_at(
-                             it + 1, consumed_box[0],
-                             batches=data_iter.batches_consumed))
-            state.log(f"saved checkpoint at iter {it + 1}")
+            with span("train/save", step=it):
+                cadence.save(it + 1, sp, so,
+                             train_state=train_state_at(
+                                 it + 1, consumed_box[0],
+                                 batches=data_iter.batches_consumed))
+                state.log(f"saved checkpoint at iter {it + 1}")
 
     def maybe_resume(sp, so):
         """Restore (sp, so, start_iter) and fast-forward the data stream so
@@ -632,11 +653,21 @@ def train(args) -> Dict[str, Any]:
     use_dropout = (cfg.hidden_dropout > 0.0 or cfg.attention_dropout > 0.0)
     drop_key = jax.random.key(args.train.seed) if use_dropout else None
     # what the compiled step contains (filled after the first step)
+    from hetu_galvatron_tpu.observability.registry import get_registry
     from hetu_galvatron_tpu.observability.trace_analysis import (
-        mosaic_custom_calls,
+        mosaic_calls_in,
     )
 
     step_report: Dict[str, Any] = {}
+    it_box = [0]  # the iteration run_loop is in, for the spans below
+
+    def phase(name):
+        """One of the flat sibling spans that tile an iteration of
+        run_loop (``train/data`` ... ``train/check``): ``span_ms{path=
+        <name>}`` in the registry on every run, and inside a profiler
+        window a TraceMe on the device trace's clock that carries the
+        iteration as ``step``. None adds a device sync or moves a line."""
+        return span(name, step=it_box[0])
 
     def run_loop(sp, so, step_fn):
         """Shared iteration driver for both execution paths. step_fn(sp, so,
@@ -649,71 +680,93 @@ def train(args) -> Dict[str, Any]:
             for it in range(start_iter, args.train.train_iters):
                 profiler.time_start(it)
                 it_t0 = time.perf_counter()
-                consumed_prev = consumed_box[0]
-                if chaos is not None:
-                    # fault plan fires BEFORE the update: 'crash at step
-                    # k' loses exactly the steps since the last commit —
-                    # the RPO the drill asserts on
-                    chaos.on_step(it)
-                if calc is not None:
-                    if calc.update(consumed_box[0]):
-                        state.log(f"ramping global batch size to "
-                                  f"{calc.current_running_global_batch_size} "
-                                  f"({calc.num_micro_batches} microbatches)")
-                    batch = rebatch.next_batch(
-                        calc.current_running_global_batch_size)
-                    consumed_box[0] += calc.current_running_global_batch_size
-                else:
-                    batch = next(data_iter)
-                if use_dropout:
-                    # per-iteration rng; captured by the batch so a rerun-machine
-                    # re-execution replays the SAME dropout mask (deterministic
-                    # fault attribution)
-                    batch = dict(batch)
-                    batch["dropout_rng"] = jax.random.fold_in(drop_key, it)
-                # keep pre-update state alive only when the rerun machine may
-                # re-execute the step for fault attribution
-                prev = (sp, so) if rerun.enabled else None
+                it_box[0] = it
+                with phase("train/data"):
+                    consumed_prev = consumed_box[0]
+                    if chaos is not None:
+                        # fault plan fires BEFORE the update: 'crash at
+                        # step k' loses exactly the steps since the last
+                        # commit — the RPO the drill asserts on
+                        chaos.on_step(it)
+                    if calc is not None:
+                        if calc.update(consumed_box[0]):
+                            state.log(
+                                f"ramping global batch size to "
+                                f"{calc.current_running_global_batch_size} "
+                                f"({calc.num_micro_batches} microbatches)")
+                        batch = rebatch.next_batch(
+                            calc.current_running_global_batch_size)
+                        consumed_box[0] += \
+                            calc.current_running_global_batch_size
+                    else:
+                        batch = next(data_iter)
+                    if use_dropout:
+                        # per-iteration rng; captured by the batch so a
+                        # rerun-machine re-execution replays the SAME
+                        # dropout mask (deterministic fault attribution)
+                        batch = dict(batch)
+                        batch["dropout_rng"] = jax.random.fold_in(
+                            drop_key, it)
+                    # keep pre-update state alive only when the rerun
+                    # machine may re-execute the step for fault attribution
+                    prev = (sp, so) if rerun.enabled else None
+                # train/h2d and train/dispatch are inside spmd_step; the
+                # pp>1 engines keep their own pp/* spans
                 sp, so, metrics = step_fn(sp, so, batch)
                 if telemetry is not None:
                     # before any sync below: the hook's own timing must see
                     # the async cadence, and it never touches device values.
                     # During a batch-size ramp the tokens-per-step must
                     # track the RUNNING batch size, not the target
-                    if calc is not None:
-                        telemetry.global_batch_size = \
-                            calc.current_running_global_batch_size
-                    telemetry(it, metrics)
-                profiler.time_end(it, sync=metrics.get("loss"))
-                # goodput: the synced step wall (profiler.time_end blocks
-                # on the loss). Each attempt's first iteration pays the
-                # jit compile, booked as recompile, not productive;
-                # checkpoint saves are measured separately below
-                goodput.add(
-                    "recompile" if it == start_iter else "productive_step",
-                    time.perf_counter() - it_t0)
-                profiler.iteration_log(it, metrics, lr=float(schedule(it)))
-                # at-step-k fault drill: may corrupt the loss (nan/spike,
-                # exercising the rerun machine), raise InjectedCrash, or
-                # deliver a real SIGTERM the guard converts to a
-                # boundary stop — all AFTER the update, BEFORE any save
-                lossf = drill.apply(float(metrics["loss"]), it)
-                rerun.validate_result(
-                    lossf, it,
-                    rerun_fn=(
-                        (lambda: float(step_fn(*prev, batch)[2]["loss"]))
-                        if prev is not None else None),
-                    data_iterator=data_iter if calc is None else None)
-                if calc is None:
-                    data_iter.advance()
-                losses.append(lossf)
+                    with phase("train/telemetry"):
+                        if calc is not None:
+                            telemetry.global_batch_size = \
+                                calc.current_running_global_batch_size
+                        telemetry(it, metrics)
+                with phase("train/sync"):
+                    profiler.time_end(it, sync=metrics.get("loss"))
+                    # goodput: the synced step wall (profiler.time_end
+                    # blocks on the loss). Each attempt's first iteration
+                    # pays the jit compile, booked as recompile, not
+                    # productive; checkpoint saves are measured separately
+                    # below
+                    goodput.add(
+                        "recompile" if it == start_iter
+                        else "productive_step",
+                        time.perf_counter() - it_t0)
+                with phase("train/lr"):
+                    # the schedule runs on the device: with the profiler
+                    # off (nothing blocked in train/sync) this read-back is
+                    # where the host first waits for the step
+                    lr = float(schedule(it))
+                with phase("train/log"):
+                    profiler.iteration_log(it, metrics, lr=lr)
+                with phase("train/check"):
+                    # at-step-k fault drill: may corrupt the loss
+                    # (nan/spike, exercising the rerun machine), raise
+                    # InjectedCrash, or deliver a real SIGTERM the guard
+                    # converts to a boundary stop — all AFTER the update,
+                    # BEFORE any save
+                    lossf = drill.apply(float(metrics["loss"]), it)
+                    rerun.validate_result(
+                        lossf, it,
+                        rerun_fn=(
+                            (lambda: float(
+                                step_fn(*prev, batch)[2]["loss"]))
+                            if prev is not None else None),
+                        data_iterator=data_iter if calc is None else None)
+                    if calc is None:
+                        data_iter.advance()
+                    losses.append(lossf)
                 if (valid_iter is not None and "fn" in eval_box
                         and args.train.eval_interval
                         and (it + 1) % args.train.eval_interval == 0):
-                    v = run_eval(sp, valid_iter)
-                    val_losses.append({"iter": it + 1, "loss": v})
-                    state.log(f"iter {it + 1}: validation loss {v:.4f} "
-                              f"({args.train.eval_iters} held-out batches)")
+                    with phase("train/eval"):
+                        v = run_eval(sp, valid_iter)
+                        val_losses.append({"iter": it + 1, "loss": v})
+                        state.log(
+                            f"iter {it + 1}: validation loss {v:.4f} "
+                            f"({args.train.eval_iters} held-out batches)")
                 # check for a fault BEFORE the interval save: the faulty update
                 # must never be persisted (a step_{it+1} checkpoint would shadow
                 # the pre-fault step_{it} one on resume)
@@ -957,9 +1010,11 @@ def train(args) -> Dict[str, Any]:
                                  hier_bucket_mb=hier_bucket_mb)
         # the engines slice a whole tree per stage: it lives on the default
         # device only until every stage holds its shards
-        sp = eng.split_params(init_params(init_key), axes)
-        so = eng.init_opt(sp, axes)
-        sp, so, start_iter = maybe_resume(sp, so)
+        with span("setup/init"):
+            sp = eng.split_params(init_params(init_key), axes)
+            so = eng.init_opt(sp, axes)
+        with span("setup/resume"):
+            sp, so, start_iter = maybe_resume(sp, so)
         if valid_iter is not None or test_iter is not None:
             eval_box["fn"] = lambda sp_, raw: eng.eval_step(sp_, raw)["loss"]
         if calc is None:
@@ -972,21 +1027,25 @@ def train(args) -> Dict[str, Any]:
         step_report["mosaic_custom_calls"] = getattr(
             eng, "mosaic_custom_calls", None)
     else:
-        mesh = build_mesh(world, 1, devices=state.devices,
-                          dcn_slices=args.parallel.dcn_slices)
-        # donation halves live model-state memory but is only safe when the
-        # rerun machine will never re-call the step on pre-update buffers
-        step, pspecs, ospecs, batch_shd = make_spmd_train_step(
-            cfg, hpc, mesh, axes, tx, params, compute_dtype=compute_dtype,
-            donate=not rerun.enabled, tp_overlap=tp_overlap_on,
-            hier_dp=hier_dp_on, dcn_slices=args.parallel.dcn_slices,
-            hier_bucket_mb=hier_bucket_mb, dp_schedule=dp_schedule_on)
-        nshd = lambda specs: jax.tree.map(
-            lambda s: NamedSharding(mesh, s), specs,
-            is_leaf=lambda x: isinstance(x, PartitionSpec))
-        sp = jax.jit(init_params, out_shardings=nshd(pspecs))(init_key)
-        so = jax.jit(tx.init, out_shardings=nshd(ospecs))(sp)
-        sp, so, start_iter = maybe_resume(sp, so)
+        with span("setup/init"):
+            mesh = build_mesh(world, 1, devices=state.devices,
+                              dcn_slices=args.parallel.dcn_slices)
+            # donation halves live model-state memory but is only safe when
+            # the rerun machine will never re-call the step on pre-update
+            # buffers
+            step, pspecs, ospecs, batch_shd = make_spmd_train_step(
+                cfg, hpc, mesh, axes, tx, params,
+                compute_dtype=compute_dtype,
+                donate=not rerun.enabled, tp_overlap=tp_overlap_on,
+                hier_dp=hier_dp_on, dcn_slices=args.parallel.dcn_slices,
+                hier_bucket_mb=hier_bucket_mb, dp_schedule=dp_schedule_on)
+            nshd = lambda specs: jax.tree.map(
+                lambda s: NamedSharding(mesh, s), specs,
+                is_leaf=lambda x: isinstance(x, PartitionSpec))
+            sp = jax.jit(init_params, out_shardings=nshd(pspecs))(init_key)
+            so = jax.jit(tx.init, out_shardings=nshd(ospecs))(sp)
+        with span("setup/resume"):
+            sp, so, start_iter = maybe_resume(sp, so)
         # ramp: one jitted step per distinct microbatch COUNT (micro shape
         # fixed), compiled lazily as the ramp reaches each count
         step_cache = {max(hpc.chunks, 1): step}
@@ -1008,14 +1067,29 @@ def train(args) -> Dict[str, Any]:
             # the rng key is per-step scalar data: placed replicated, not
             # under the [B, ...] batch sharding
             rng = raw.pop("dropout_rng", None)
-            b = jax.device_put(raw, batch_shd)
+            with phase("train/h2d"):
+                b = jax.device_put(raw, batch_shd)
             if rng is not None:
                 b["dropout_rng"] = rng
             fn = step if calc is None else get_step(calc.num_micro_batches)
-            out = fn(sp, so, b)
+            # on the first iteration: tracing, lowering, compile or cache load
+            with phase("train/dispatch"):
+                out = fn(sp, so, b)
             if "mosaic_custom_calls" not in step_report:
-                step_report["mosaic_custom_calls"] = mosaic_custom_calls(
-                    fn, (out[0], out[1], b))
+                # what the compiled step contains and needs, from the one
+                # executable the call above made (.lower() and .compile()
+                # return what that call cached: 0.05 to 0.4 s on the chip):
+                # Mosaic calls in its HLO, and XLA's static memory as
+                # step/static_bytes{part=...} gauges
+                with span("setup/step_report"):
+                    compiled = fn.lower(out[0], out[1], b).compile()
+                    step_report["mosaic_custom_calls"] = mosaic_calls_in(
+                        compiled)
+                    step_report["static_memory"] = compiled_memory_bytes(
+                        compiled)
+                    for part, v in step_report["static_memory"].items():
+                        get_registry().gauge("step/static_bytes",
+                                             part=part).set(v)
             return out
 
         if valid_iter is not None or test_iter is not None:
@@ -1061,6 +1135,9 @@ def train(args) -> Dict[str, Any]:
             # over the host engine's stage backward programs; None for
             # the compiled engine, which does not count them
             "mosaic_custom_calls": step_report.get("mosaic_custom_calls"),
+            # XLA's static memory of the compiled pp=1 step, per device, in
+            # bytes (the step/static_bytes gauges); None for the pp engines
+            "static_memory": step_report.get("static_memory"),
             "exit_code": exit_code}
 
 

@@ -2,9 +2,9 @@
 
 Capability parity with the reference runtime profiler
 (core/profiler/runtime_profiler.py:12-370): wall-clock per-iteration timing
-with warmup and a 3-sigma outlier filter, per-phase device memory peaks, an
-iteration log line, and the computation/memory JSON writers the model
-profiler post-processes.
+with warmup and a 3-sigma outlier filter, device and compiled-program memory
+figures, an iteration log line, and the computation/memory JSON writers the
+model profiler post-processes.
 
 TPU-native measurement: timing is host wall-clock around `block_until_ready`
 (XLA has no CUDA events; dispatch is async so this measures true device
@@ -15,10 +15,11 @@ allocator stats (CPU tests).
 
 Everything measured here is also routed through the observability metrics
 registry (``observability/registry.py``): iteration times land in the
-``profiler/iter_time_ms`` histogram, memory probes in ``profiler/mem_mb``
-gauges, and the MoE balance tracker in ``moe/*`` gauges, so a configured
-JSONL/TensorBoard sink sees the profiler's view of the run without any
-extra plumbing. The XLA trace window is delegated to
+``profiler/iter_time_ms`` histogram and the MoE balance tracker in
+``moe/*`` gauges (the compiled step's static memory is the launcher's
+``step/static_bytes`` gauges, from :func:`compiled_memory_bytes`), so a
+configured JSONL/TensorBoard sink sees the profiler's view of the run
+without any extra plumbing. The XLA trace window is delegated to
 ``observability.tracing.TraceCapture``.
 """
 
@@ -73,10 +74,34 @@ def compiled_memory_mb(compiled) -> Dict[str, float]:
     }
 
 
+def compiled_memory_bytes(compiled) -> Dict[str, int]:
+    """XLA's static accounting of one compiled program, per device, in
+    bytes, with the peak a step needs while it runs: donated arguments are
+    reused for the outputs (``aliased``), so ``live_peak = arguments +
+    outputs - aliased + temporaries + generated_code``. The allocator's
+    ``peak_bytes_in_use`` never sees a program's temporaries; this is the
+    figure that says whether a step fits. Empty when the backend has no
+    analysis."""
+    m = compiled.memory_analysis()
+    if m is None:
+        return {}
+    parts = {
+        "arguments": int(m.argument_size_in_bytes),
+        "outputs": int(m.output_size_in_bytes),
+        "aliased": int(m.alias_size_in_bytes),
+        "temporaries": int(m.temp_size_in_bytes),
+        "generated_code": int(m.generated_code_size_in_bytes),
+    }
+    parts["live_peak"] = (parts["arguments"] + parts["outputs"]
+                          - parts["aliased"] + parts["temporaries"]
+                          + parts["generated_code"])
+    return parts
+
+
 class RuntimeProfiler:
-    """Hooks into the train loop: time_start/time_end around the step,
-    memory probes at phase boundaries (reference profile_memory :105,
-    post_profile_memory :134, profile_time_start :218)."""
+    """Hooks into the train loop: time_start/time_end around the step
+    (reference profile_time_start :218), the XLA trace window, the
+    iteration log line."""
 
     def __init__(self, args: CoreArgs, world_size: int = 1, rank: int = 0,
                  registry: Optional[MetricsRegistry] = None):
@@ -88,7 +113,6 @@ class RuntimeProfiler:
         # lands its metrics in the configured stream
         self._registry = registry
         self.time_samples: List[float] = []
-        self.memory_samples: Dict[str, Dict[str, float]] = {}
         self._t0: Optional[float] = None
         self.enabled = bool(args.profile.profile)
         p = args.profile
@@ -140,6 +164,11 @@ class RuntimeProfiler:
 
     def time_end(self, it: int, sync: Any = None) -> None:
         if self._t0 is None:
+            if self._tracing_now and self.enabled and sync is not None:
+                # a traced iteration records no sample but blocks where a
+                # measured one does, so the trace shows the loop the
+                # window runs (not one that first blocks a statement later)
+                jax.block_until_ready(sync)
             return
         if sync is not None:
             jax.block_until_ready(sync)
@@ -157,27 +186,6 @@ class RuntimeProfiler:
         mean, std = arr.mean(), arr.std()
         keep = arr[np.abs(arr - mean) <= 3 * std] if std > 0 else arr
         return float(keep.mean())
-
-    # -- memory -------------------------------------------------------------
-
-    def probe_memory(self, phase: str, device=None) -> None:
-        if not self.enabled:
-            return
-        stats = device_memory_mb(device)
-        if stats is not None:
-            self.memory_samples[phase] = stats
-            for stat, v in stats.items():
-                self.registry.gauge("profiler/mem_mb", phase=phase,
-                                    stat=stat).set(v)
-
-    def record_static_memory(self, compiled) -> None:
-        if not self.enabled:
-            return
-        mem = compiled_memory_mb(compiled)
-        self.memory_samples["compiled"] = mem
-        for stat, v in mem.items():
-            self.registry.gauge("profiler/mem_mb", phase="compiled",
-                                stat=stat).set(v)
 
     # -- logging + output ---------------------------------------------------
 

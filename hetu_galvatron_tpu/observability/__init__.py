@@ -9,6 +9,12 @@ The measurement substrate the ROADMAP's "measurably faster" contract needs:
 * :mod:`tracing` — host-side ``span("fwd")`` context managers that also
   emit ``jax.profiler.TraceAnnotation`` so the same names show up inside
   XLA device traces, plus the windowed ``jax.profiler.start_trace`` hook.
+  The launcher's loop is tiled by ``train/data``, ``train/h2d``,
+  ``train/dispatch``, ``train/sync``, ``train/lr``, ``train/log``,
+  ``train/check`` (``span_ms{path=...}``, and TraceMes with the iteration
+  as ``step``); its set-up by ``setup/imports|runtime|init|resume|
+  step_report``; the compiled step's static memory is the gauges
+  ``step/static_bytes{part=...}`` (listed in :mod:`tracing`'s docstring).
 * :mod:`telemetry` — derived training stats: tokens/sec, step-time
   percentiles, model-FLOPs utilization (FLOPs accounting lives in
   ``core/cost_model/cost.py``), device memory gauges, and per-strategy

@@ -452,6 +452,11 @@ def jit_cost_summary(fn: Any, args: Sequence[Any] = (),
         return {}
 
 
+def mosaic_calls_in(compiled: Any) -> int:
+    """Mosaic (Pallas TPU) kernels in a compiled program's optimized HLO."""
+    return compiled.as_text().count('custom_call_target="tpu_custom_call"')
+
+
 def mosaic_custom_calls(fn: Any, args: Sequence[Any]) -> int:
     """Mosaic (Pallas TPU) kernels in the COMPILED program of a jitted
     ``fn`` that was just called with ``args``-shaped inputs, counted in the
@@ -459,8 +464,7 @@ def mosaic_custom_calls(fn: Any, args: Sequence[Any]) -> int:
     ``.compile()`` return the lowering and executable the call cached.
     Unlike the cost telemetry above it raises on failure — callers use it
     to prove which attention core a step ran."""
-    hlo = fn.lower(*args).compile().as_text()
-    return hlo.count('custom_call_target="tpu_custom_call"')
+    return mosaic_calls_in(fn.lower(*args).compile())
 
 
 # one record per (registry, program): keyed on the live registry object so

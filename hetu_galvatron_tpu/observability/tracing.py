@@ -13,13 +13,34 @@ Span durations aggregate into the registry as ``span_ms`` histograms
 labelled by the nesting path (``train/step``, ``pp/fwd_s0``, ...), so
 per-iteration spans cost one histogram observe — no per-span records, no
 unbounded JSONL growth.
+
+The spans of the launcher (``cli/train_dist.py``), all flat siblings:
+
+* per iteration of ``run_loop``, tiling the loop body, each carrying the
+  iteration as ``step`` on its TraceAnnotation: ``train/data`` (fault
+  plan, batch-size ramp, next batch, dropout key), ``train/h2d``
+  (``device_put`` of the batch), ``train/dispatch`` (the call of the
+  jitted step; the first one traces, lowers and compiles or loads it),
+  ``train/sync`` (``profiler.time_end``: blocks on the loss when
+  ``profile.profile=1``), ``train/lr`` (the LR schedule read back from
+  the device; with the profiler off, where the host first waits for the
+  step), ``train/log``, ``train/check`` (loss read-back, fault drill,
+  rerun validation), and only when that work is done ``train/telemetry``,
+  ``train/eval``, ``train/save``. The pp>1 engines keep their own
+  ``pp/*`` spans in place of ``train/h2d`` and ``train/dispatch``;
+* once per ``train()``, registry only: ``setup/imports``,
+  ``setup/runtime`` (model config, TPU client, plan, data iterators,
+  telemetry), ``setup/init`` (mesh, building the step, jitted parameter
+  and optimizer init), ``setup/resume``, ``setup/step_report`` (the
+  compiled step's HLO text and ``memory_analysis()`` after the first
+  call, which also sets the gauges ``step/static_bytes{part=arguments|outputs|
+  aliased|temporaries|generated_code|live_peak}``).
 """
 
 from __future__ import annotations
 
 import threading
 import time
-from contextlib import contextmanager
 from typing import Optional
 
 from hetu_galvatron_tpu.observability.registry import (
@@ -35,27 +56,49 @@ def current_span_path() -> str:
     return "/".join(getattr(_tls, "stack", []))
 
 
-@contextmanager
-def span(name: str, registry: Optional[MetricsRegistry] = None):
+class span:
     """Measure a region; nests ('train/step' inside 'train' -> path
     'train/train/step' is avoided by naming spans hierarchically at the
-    call site). Re-entrant and thread-safe (per-thread stacks)."""
-    import jax
+    call site). Re-entrant and thread-safe (per-thread stacks).
 
-    reg = registry or get_registry()
-    stack = getattr(_tls, "stack", None)
-    if stack is None:
-        stack = _tls.stack = []
-    stack.append(name)
-    path = "/".join(stack)
-    t0 = time.perf_counter()
-    try:
-        with jax.profiler.TraceAnnotation(name):
-            yield
-    finally:
-        dur_ms = (time.perf_counter() - t0) * 1000.0
-        stack.pop()
-        reg.histogram("span_ms", path=path).observe(dur_ms)
+    ``attrs`` go onto the TraceAnnotation only (``span("train/lr",
+    step=it)``): a trace reader groups the spans of one iteration by them;
+    the registry path stays ``name``. The registry is looked up when the
+    span ENDS, so a span that is open while the launcher configures its
+    sinks still lands in the configured stream.
+
+    A class and not a ``contextmanager`` generator: the TraceAnnotation is
+    entered first and left last, so the span's own bookkeeping lies inside
+    it and sibling spans that tile a loop leave next to nothing between
+    them on the trace (under the profiler's Python tracer every Python
+    call between two spans costs microseconds)."""
+
+    __slots__ = ("name", "registry", "attrs", "_t0", "_path", "_ann")
+
+    def __init__(self, name: str, registry: Optional[MetricsRegistry] = None,
+                 **attrs):
+        self.name, self.registry, self.attrs = name, registry, attrs
+
+    def __enter__(self) -> "span":
+        self._t0 = time.perf_counter()  # before the import: it may be timed
+        import jax
+
+        self._ann = jax.profiler.TraceAnnotation(self.name, **self.attrs)
+        self._ann.__enter__()
+        stack = getattr(_tls, "stack", None)
+        if stack is None:
+            stack = _tls.stack = []
+        stack.append(self.name)
+        self._path = "/".join(stack)
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        dur_ms = (time.perf_counter() - self._t0) * 1000.0
+        _tls.stack.pop()
+        (self.registry or get_registry()).histogram(
+            "span_ms", path=self._path).observe(dur_ms)
+        self._ann.__exit__(*exc)
+        return False
 
 
 class TraceCapture:

@@ -237,6 +237,10 @@ def flash_attention_hmajor(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
+        # the kernel's instruction name on a trace's ``XLA Ops`` line; the
+        # three kernels differ after ``flash_attention`` so that a trace
+        # tells them apart and a ``^flash_attention`` pattern finds all
+        name="flash_attention_fwd",
     )(*operands)
 
 
@@ -467,6 +471,7 @@ def flash_attention_bwd_hmajor(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary", "arbitrary")),
         interpret=interpret,
+        name="flash_attention_bwd_dkv",
     )(*dkdv_operands)
 
     dq_in_specs = [
@@ -509,6 +514,7 @@ def flash_attention_bwd_hmajor(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
+        name="flash_attention_bwd_dq",
     )(*dq_operands)
     return dq, dkdv[0], dkdv[1]
 

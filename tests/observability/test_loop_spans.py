@@ -1,0 +1,256 @@
+"""The launcher loop says what it is doing: flat sibling phase spans that
+tile one iteration of ``run_loop``, one-shot set-up spans, and the compiled
+step's static memory as gauges — all through ``tracing.span`` and the
+process-wide registry, on the CPU at a tiny size through
+``train_dist.main``."""
+
+import os
+
+import pytest
+
+from hetu_galvatron_tpu.observability.registry import (
+    MetricsRegistry,
+    get_registry,
+    set_registry,
+)
+from hetu_galvatron_tpu.observability.tracing import span
+
+pytestmark = pytest.mark.observability
+
+ZOO = os.path.join(os.path.dirname(__file__), "..", "..",
+                   "hetu_galvatron_tpu", "models", "configs")
+LOOP_SPANS = ["train/data", "train/h2d", "train/dispatch", "train/sync",
+              "train/lr", "train/log", "train/check"]
+SETUP_SPANS = ["setup/imports", "setup/runtime", "setup/init",
+               "setup/resume", "setup/step_report"]
+PARTS = ["arguments", "outputs", "aliased", "temporaries",
+         "generated_code", "live_peak"]
+ITERS, WARMUP, TRACED = 6, 2, 3
+# large enough that the step, not the interpreter between two spans, is
+# what an iteration's time is made of
+TINY = [
+    "model.hidden_size=64", "model.num_hidden_layers=2",
+    "model.num_attention_heads=2", "model.vocab_size=256",
+    "model.seq_length=64", "model.max_position_embeddings=64",
+    "model.make_vocab_size_divisible_by=1", f"train.train_iters={ITERS}",
+    "parallel.mixed_precision=fp32", "parallel.global_train_batch_size=8",
+    "parallel.num_devices=1", "data.dataset=random"]
+
+
+class _RecordingStep:
+    """The jitted step, keeping the text of every lowering made of it."""
+
+    def __init__(self, fn, texts):
+        self.fn, self.texts = fn, texts
+
+    def __call__(self, *args):
+        return self.fn(*args)
+
+    def lower(self, *args):
+        lowered = self.fn.lower(*args)
+        self.texts.append(lowered.as_text())
+        return lowered
+
+
+def _run(extra):
+    """One tiny run on a registry of its own; returns (registry, result,
+    the step's lowered text)."""
+    from hetu_galvatron_tpu.cli import train_dist
+    from hetu_galvatron_tpu.parallel import spmd
+
+    texts = []
+    make = spmd.make_spmd_train_step
+
+    def recording(*args, **kwargs):
+        step, *rest = make(*args, **kwargs)
+        return (_RecordingStep(step, texts), *rest)
+
+    before = get_registry()
+    reg = set_registry(MetricsRegistry())
+    spmd.make_spmd_train_step = recording
+    try:
+        out = {}
+        rc = train_dist.main(
+            [os.path.join(ZOO, "gpt2-small.yaml")] + TINY + extra,
+            result=out)
+    finally:
+        spmd.make_spmd_train_step = make
+        set_registry(before)
+    assert rc == 0 and len(out["losses"]) == ITERS
+    (text,) = texts   # the step report lowers the step once
+    return reg, out, text
+
+
+@pytest.fixture(scope="module")
+def traced(tmp_path_factory):
+    tdir = str(tmp_path_factory.mktemp("loop_spans") / "trace")
+    reg, out, hlo = _run(["profile.profile=1",
+                          f"profile.profile_warmup={WARMUP}",
+                          f"profile.trace_iters={TRACED}",
+                          f"profile.trace_dir={tdir}"])
+    return {"registry": reg, "result": out, "hlo": hlo, "trace_dir": tdir}
+
+
+@pytest.fixture(scope="module")
+def host_events(traced):
+    """name -> [(start ns, end ns, step)] of the ``train/*`` and
+    ``setup/*`` TraceMes on ``/host:CPU``."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(os.path.join(
+        traced["trace_dir"], "plugins", "profile", "*", "*.xplane.pb"))
+    events = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name != "/host:CPU":
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith(("train/", "setup/")):
+                    events.setdefault(e.name, []).append(
+                        (e.start_ns, e.start_ns + e.duration_ns,
+                         dict(e.stats).get("step")))
+    return events
+
+
+def _histogram(reg, path):
+    found = [m for m in reg.metrics()
+             if m.name == "span_ms" and m.labels == {"path": path}]
+    assert len(found) == 1, (path, [m.labels for m in reg.metrics()
+                                    if m.name == "span_ms"])
+    return found[0]
+
+
+# (a) the registry side: on every run, traced or not ------------------------
+
+@pytest.mark.parametrize("path,count",
+                         [(p, ITERS) for p in LOOP_SPANS]
+                         + [(p, 1) for p in SETUP_SPANS])
+def test_span_histogram_counts_every_entry(traced, path, count):
+    h = _histogram(traced["registry"], path)
+    assert h.count == count
+    assert h.total > 0.0
+
+
+@pytest.mark.parametrize("path", ["train/telemetry", "train/eval",
+                                  "train/save"])
+def test_spans_of_work_not_done_are_absent(traced, path):
+    assert not [m for m in traced["registry"].metrics()
+                if m.labels.get("path") == path]
+
+
+# (b) the trace side: on the profiler's clock, with the iteration ----------
+
+@pytest.mark.parametrize("name", LOOP_SPANS)
+def test_phase_span_is_on_the_host_plane_with_its_step(host_events, name):
+    steps = [step for _, _, step in host_events[name]]
+    assert steps == list(range(WARMUP, WARMUP + TRACED))
+
+
+def test_setup_spans_end_before_the_trace_window_opens(host_events):
+    # all but the step report (made after the first step, long before the
+    # window) are over before the loop begins; none is on the trace
+    assert not [n for n in host_events if n.startswith("setup/")]
+
+
+@pytest.mark.parametrize("it", range(WARMUP, WARMUP + TRACED))
+def test_phase_spans_tile_the_iteration(host_events, it):
+    mine = sorted((s, e, name) for name, evs in host_events.items()
+                  for s, e, step in evs if step == it)
+    assert [name for _, _, name in mine] == LOOP_SPANS
+    assert all(a[1] <= b[0] for a, b in zip(mine, mine[1:])), mine
+    covered = sum(e - s for s, e, _ in mine)
+    assert covered >= 0.95 * (mine[-1][1] - mine[0][0]), mine
+
+
+# (c) the compiled step's static memory -------------------------------------
+
+@pytest.mark.parametrize("part", PARTS)
+def test_static_memory_gauge(traced, part):
+    (g,) = [m for m in traced["registry"].metrics()
+            if m.name == "step/static_bytes" and m.labels == {"part": part}]
+    assert g.value == traced["result"]["static_memory"][part]
+    assert g.value >= 0
+    if part in ("arguments", "outputs", "temporaries", "live_peak"):
+        assert g.value > 0
+
+
+def test_static_live_peak_is_the_stated_sum(traced):
+    m = traced["result"]["static_memory"]
+    assert set(m) == set(PARTS)
+    assert m["live_peak"] == (m["arguments"] + m["outputs"] - m["aliased"]
+                              + m["temporaries"] + m["generated_code"])
+    # donated parameters and optimizer state are reused for the outputs
+    assert 0 < m["aliased"] <= m["outputs"]
+
+
+# (d) spans change nothing on the device -------------------------------------
+
+def test_lowered_step_is_the_same_with_and_without_a_trace_window(traced):
+    reg, out, hlo = _run([])
+    assert hlo == traced["hlo"]
+    assert out["losses"] == traced["result"]["losses"]
+    # no profiler: no sample, and the same spans all the same
+    assert _histogram(reg, "train/sync").count == ITERS
+
+
+# (e) span attributes go onto the TraceMe -----------------------------------
+
+@pytest.mark.parametrize("attrs", [{"step": 3}, {"step": 4, "chunk": 1}, {}])
+def test_span_attributes_reach_the_trace_annotation(tmp_path, attrs):
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    reg = MetricsRegistry()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with span("probe/attrs", registry=reg, **attrs):
+            pass
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                            / "*.xplane.pb"))
+    found = [dict(e.stats) for plane in ProfileData.from_file(path).planes
+             for line in plane.lines for e in line.events
+             if e.name == "probe/attrs"]
+    assert found == [attrs]
+    # the registry path is the name alone
+    assert _histogram(reg, "probe/attrs").count == 1
+
+
+# the repair: a traced iteration blocks where a measured one does -----------
+
+class _Loss:
+    def __init__(self):
+        self.blocked = 0
+
+    def block_until_ready(self):
+        self.blocked += 1
+        return self
+
+
+@pytest.mark.parametrize("profile,tracing,blocks,samples", [
+    (1, True, 1, 0),     # traced: blocks on the loss, records no sample
+    (1, False, 1, 1),    # measured: blocks and records
+    (0, True, 0, 0),     # profiler off: the loop never blocks here
+])
+def test_time_end_blocks_in_traced_iterations_too(tmp_path, profile, tracing,
+                                                  blocks, samples):
+    from hetu_galvatron_tpu.core.arguments import args_from_cli
+    from hetu_galvatron_tpu.core.profiler.runtime_profiler import (
+        RuntimeProfiler,
+    )
+
+    args = args_from_cli(
+        [os.path.join(ZOO, "gpt2-small.yaml"), f"profile.profile={profile}",
+         "profile.profile_warmup=0"], mode="train_dist")
+    prof = RuntimeProfiler(args, registry=MetricsRegistry())
+    prof._trace.step = lambda it: tracing   # no real capture
+    loss = _Loss()
+    prof.time_start(0)
+    prof.time_end(0, sync=loss)
+    assert loss.blocked == blocks
+    assert len(prof.time_samples) == samples
