@@ -16,8 +16,8 @@ one process; this parent never imports JAX, so it never holds one):
 
   kernels        Mosaic-compiles the Pallas kernels at the model's own shapes
                  and compares them with the XLA reference, forward and
-                 backward: flash attention (MHA and one GQA case) and the
-                 fused cross-entropy.
+                 backward: flash attention (MHA, GQA at head 64, and GQA
+                 4:1 at head 128 and S=4096) and the fused cross-entropy.
   train1         the trainer on ONE chip, a few steps, run twice: the second
                  process must find the first one's programs in the
                  persistent compile cache (cli/compile_cache.py).
@@ -67,6 +67,9 @@ TOTAL_BUDGET_S = 1100  # all children, inside the driver's 1200 s
 FULL: Dict[str, Any] = {
     "flash_mha": (8, 1024, 12, 12, 64),   # B, S, heads, kv heads, head dim
     "flash_gqa": (2, 1024, 12, 4, 64),
+    # Mistral's attention (GQA 4:1, head 128, S=4096) on a quarter of its
+    # heads: the dense f32 reference holds [heads, S, S] scores
+    "flash_gqa_h128": (1, 4096, 8, 2, 128),
     "ce": (8 * 1024, 50304),              # tokens, padded vocab
     "model": [],                          # no width or depth override
     "iters": 5,
@@ -287,10 +290,8 @@ def leg_kernels(sizes: Dict[str, Any], *, interpret: bool) -> Dict[str, Any]:
     """Compile the Pallas kernels (Mosaic unless ``interpret``) and compare
     them with the XLA reference, forward and backward."""
     return {
-        "flash_mha": _flash_parity("flash_mha", sizes["flash_mha"],
-                                   interpret),
-        "flash_gqa": _flash_parity("flash_gqa", sizes["flash_gqa"],
-                                   interpret),
+        **{name: _flash_parity(name, sizes[name], interpret)
+           for name in ("flash_mha", "flash_gqa", "flash_gqa_h128")},
         "fused_ce": _ce_parity(sizes["ce"], interpret),
         "device_memory": device_memory(),
     }

@@ -2,16 +2,35 @@
 
 Replaces the reference's external flash-attn CUDA ops (SURVEY §2 native-code
 checklist item 4; installed by galvatron/scripts/flash_attn_ops_install.sh)
-with a TPU kernel: the grid runs (batch, q-head, q-block, k-block) with the
-k-block axis innermost, so each k/v tile is DMA'd into VMEM on demand while
-running-max/normalizer/accumulator scratch persists across k-steps — the
-[S, S] score matrix never exists and VMEM holds only O(block) tiles, so
-sequence length is bounded by HBM, not VMEM.
+with three TPU kernels. The [S, S] score matrix never exists: every kernel
+works on one ``block_q x block_k`` score tile at a time.
 
 Layout: q [B, N, S, D], k/v [B, K, S, D] (heads-major so a grid cell's tiles
 are contiguous); GQA maps q-head n to kv-head n // (N // K) in the index map.
-The backward is fused too (dq and dk/dv kernels recompute p per tile from the
-saved logsumexp), so neither direction materializes [S, S].
+
+The tile loop. A grid step of the forward and of the dq kernel holds one q
+tile and a MAJOR block of K and V (the whole length where it fits
+``_RESIDENT_BYTES``, so the next step of the same head fetches nothing) and
+loops inside the kernel over its ``block_k``-row chunks, up to the diagonal
+when causal: first the chunks wholly at or below it, with no mask built at
+all, then the one or two that cross it, with the mask. Chunks above the
+diagonal are never visited and major blocks above it never fetched (their
+block index repeats the last needed one). The dk/dv kernel is the mirror
+image: a k/v tile stays, q / dO / lse / delta come as a major block and the
+loop runs over q chunks from the diagonal down; its score tiles are [k, q]
+(keys along sublanes), so that p^T.dO and ds^T.q are plain products and lse
+and delta broadcast along sublanes from (1, block_q) rows. Segment and
+dropout masks are applied on every tile they are given for.
+
+Arithmetic: matmul operands stay in the inputs' dtype with f32 accumulation
+(``preferred_element_type``); scores, the softmax statistics, exp, lse,
+delta and all accumulators are f32; p and ds are cast to the inputs' dtype
+for their products, as ``modules.xla_sdpa`` casts its probabilities. The
+forward's running max lives replicated along 128 lanes and its normalizer
+as 128 partial sums a row (one cross-lane reduction a chunk, one more at the
+end). The backward recomputes p per tile from the saved logsumexp, so
+neither direction materializes [S, S]. Block sizes come from the call's
+shapes (``choose_blocks``).
 """
 
 from __future__ import annotations
@@ -69,19 +88,103 @@ def keep_mask(seed, bn, qpos, kpos, rate: float):
     return x < threshold
 
 
-def _tile_keep(seed_ref, bn, qi, ki, block_q: int, block_k: int,
-               rate: float):
-    qpos = qi * block_q + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 0)
-    kpos = ki * block_k + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 1)
-    return keep_mask(seed_ref[0], bn, qpos, kpos, rate)
+def _tile_pos(q0, k0, shape, q_axis: int = 0):
+    """Global (q position, k position) of every element of a score tile
+    that starts at q position q0 and k position k0; q runs along
+    ``q_axis`` of ``shape``, k along the other."""
+    qpos = q0 + jax.lax.broadcasted_iota(jnp.int32, shape, q_axis)
+    kpos = k0 + jax.lax.broadcasted_iota(jnp.int32, shape, 1 - q_axis)
+    return qpos, kpos
+
+
+# a @ b.T and a @ b: operands in their own dtype, f32 accumulation
+_NT = (((1,), (1,)), ((), ()))
+_NN = (((1,), (0,)), ((), ()))
+
+
+def _dot(a, b, dims):
+    return jax.lax.dot_general(a, b, dims,
+                               preferred_element_type=jnp.float32)
+
+
+def _scores(q, k, q0, k0, qseg, kseg, *, masked: bool, scale: float,
+            k_rows: bool = False):
+    """f32 score tile q.k^T * scale (k.q^T, keys along rows, with
+    ``k_rows``) with the causal mask (only where the caller says the tile
+    crosses the diagonal) and the segment mask (on every tile it is given
+    for; qseg and kseg broadcast against each other to the tile). A masked
+    score is NEG_INF: once a query has seen one finite score its masked
+    entries give exp(NEG_INF - m) = 0, and whatever it gathered while all
+    it had seen was masked is wiped by the rescale exp(NEG_INF - m) = 0 at
+    its first finite score; causal and segment masks both leave every
+    query its own position."""
+    s = (_dot(k, q, _NT) if k_rows else _dot(q, k, _NT)) * scale
+    if masked:
+        qpos, kpos = _tile_pos(q0, k0, s.shape, int(k_rows))
+        s = jnp.where(qpos >= kpos, s, NEG_INF)
+    if qseg is not None:
+        s = jnp.where(qseg == kseg, s, NEG_INF)
+    return s
+
+
+_LANES = 128
+
+
+def _across(x, width: int):
+    """A lane-replicated (rows, _LANES) array as (rows, width): the same
+    registers again where ``width`` is whole lane tiles, no relayout."""
+    if width % _LANES == 0:
+        return jnp.concatenate([x] * (width // _LANES), axis=1)
+    return jnp.broadcast_to(x[:, :1], (x.shape[0], width))
+
+
+def _lane_sums(p):
+    """Row sums of p left as _LANES partial sums a row (register adds of
+    p's lane tiles; a ragged width is summed across lanes into lane 0)."""
+    rows, width = p.shape
+    if width % _LANES == 0:
+        return sum(p[:, j:j + _LANES] for j in range(0, width, _LANES))
+    lane0 = jax.lax.broadcasted_iota(jnp.int32, (rows, _LANES), 1) == 0
+    return jnp.where(lane0, jnp.sum(p, axis=1, keepdims=True), 0.0)
+
+
+def _for(lo, hi, body):
+    """``body(c)`` for c in [lo, hi); the bounds may be traced (an empty
+    range costs a compare)."""
+    jax.lax.fori_loop(lo, hi, lambda c, _: body(c), None)
+
+
+def _for_k_chunks(chunk, q0, block_q: int, block_k: int, lo, chunks: int,
+                  causal: bool):
+    """``chunk(c, masked)`` over those of the ``chunks`` k chunks from global
+    chunk ``lo`` on that the q rows [q0, q0 + block_q) see: first the ones
+    wholly at or below the diagonal, without the mask, then the ones that
+    cross it, with it; all of them, unmasked, when not causal."""
+    if not causal:
+        _for(0, chunks, lambda c: chunk(c, False))
+        return
+    full = jnp.clip((q0 + 1) // block_k - lo, 0, chunks)
+    some = jnp.clip((q0 + block_q - 1) // block_k + 1 - lo, 0, chunks)
+    _for(0, full, lambda c: chunk(c, False))
+    _for(full, some, lambda c: chunk(c, True))
+
+
+def _needed_k_major(qi, kj, block_q: int, major: int, causal: bool):
+    """Index map of a k major block: one wholly past the diagonal repeats
+    the last that is needed, and an unchanged block index elides the copy."""
+    if causal:
+        return jnp.minimum(kj, (qi * block_q + block_q - 1) // major)
+    return kj
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, *rest,
-                  block_q: int, block_k: int, num_k: int, causal: bool,
-                  scale: float, has_seg: bool = False,
+                  block_q: int, block_k: int, chunks: int, num_major: int,
+                  causal: bool, scale: float, has_seg: bool = False,
                   dropout_rate: float = 0.0):
+    """Grid (B, N, q block, k major block). One step holds a q tile and
+    ``chunks`` k/v chunks of ``block_k`` rows and loops over the chunks the
+    causal mask leaves, so a step past the diagonal neither fetches nor
+    computes and only the chunks that cross the diagonal build a mask."""
     if dropout_rate > 0.0:
         seed_ref, rest = rest[0], rest[1:]
     else:
@@ -91,66 +194,101 @@ def _flash_kernel(q_ref, k_ref, v_ref, *rest,
     else:
         qseg_ref = kseg_ref = None
         o_ref, lse_ref, m_ref, l_ref, acc_ref = rest
-    qi = pl.program_id(2)
-    ki = pl.program_id(3)
+    kj = pl.program_id(3)
+    q0 = pl.program_id(2) * block_q
+    lo = kj * chunks
     # flat batch*heads index for the dropout mask; program_id must be read
     # at kernel top level (the interpret-mode executor does not rewrite it
     # inside pl.when bodies)
     bn = pl.program_id(0) * pl.num_programs(1) + pl.program_id(1)
 
-    @pl.when(ki == 0)
+    @pl.when(kj == 0)
     def _init():
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    # blocks entirely past the causal diagonal contribute nothing
-    diag_last = (qi * block_q + block_q - 1) // block_k if causal else num_k
-
-    @pl.when(ki <= diag_last)
-    def _update():
-        q = q_ref[0, 0].astype(jnp.float32) * scale  # [bq, D]
-        k = k_ref[0, 0].astype(jnp.float32)  # [bk, D]
-        v = v_ref[0, 0].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        if causal:
-            qpos = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            kpos = ki * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(qpos >= kpos, s, NEG_INF)
-        if qseg_ref is not None:
-            # packed documents: mask cross-segment pairs (reference
-            # reset_attention_mask; same trailing-singleton layout as lse)
-            s = jnp.where(qseg_ref[0, :, 0][:, None]
-                          == kseg_ref[0, :, 0][None, :], s, NEG_INF)
+    def chunk(c, masked):
+        rows = pl.ds(pl.multiple_of(c * block_k, block_k), block_k)
+        k0 = (lo + c) * block_k
+        v = v_ref[0, 0, rows, :]
+        s = _scores(q_ref[0, 0], k_ref[0, 0, rows, :], q0, k0,
+                    qseg_ref[0] if has_seg else None,
+                    kseg_ref[0, c] if has_seg else None,
+                    masked=masked, scale=scale)
+        # the running max is kept replicated along _LANES lanes, so it
+        # meets the score tile and the accumulator without a relayout; the
+        # cross-lane max is the one reduction a chunk pays
         m = m_ref[...]
-        block_max = jnp.max(s, axis=1)
-        new_m = jnp.maximum(m, block_max)
-        corr = jnp.exp(jnp.where(m == NEG_INF, NEG_INF, m - new_m))
-        p = jnp.exp(s - new_m[:, None])
-        p = jnp.where(s == NEG_INF, 0.0, p)
+        new_m = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+        corr = jnp.exp(m - new_m)
+        p = jnp.exp(s - _across(new_m, block_k))
         m_ref[...] = new_m
-        # the normalizer uses the UNdropped p: out = dropout(softmax(s)) @ v
-        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1)
+        # the normalizer (of the UNdropped p: out = dropout(softmax(s)) @ v)
+        # is kept as _LANES partial sums a row, added up once at the end
+        l_ref[...] = l_ref[...] * corr + _lane_sums(p)
         if dropout_rate > 0.0:
-            keep = _tile_keep(seed_ref, bn, qi, ki, block_q, block_k,
-                              dropout_rate)
+            keep = keep_mask(seed_ref[0], bn, *_tile_pos(q0, k0, s.shape),
+                             dropout_rate)
             p = jnp.where(keep, p / (1.0 - dropout_rate), 0.0)
-        acc_ref[...] = acc_ref[...] * corr[:, None] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        acc_ref[...] = (acc_ref[...] * _across(corr, acc_ref.shape[1])
+                        + _dot(p.astype(v.dtype), v, _NN))
 
-    @pl.when(ki == num_k - 1)
+    _for_k_chunks(chunk, q0, block_q, block_k, lo, chunks, causal)
+
+    @pl.when(kj == num_major - 1)
     def _finalize():
-        l = jnp.maximum(l_ref[...], 1e-20)
-        o_ref[0, 0] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+        l = jnp.maximum(jnp.sum(l_ref[...], axis=1, keepdims=True), 1e-20)
+        o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
         # logsumexp per row, consumed by the backward kernels; stored with a
         # trailing singleton lane dim — Mosaic requires the last two block
         # dims to be (mult-of-8, mult-of-128) or equal to the array dims, so
         # a rank-3 (1, 1, block_q) lse block cannot lower on hardware
-        lse_ref[0, 0] = (m_ref[...] + jnp.log(l))[:, None]
+        lse_ref[0, 0] = m_ref[:, :1] + jnp.log(l)
+
+
+# what one grid step may keep resident of each streamed operand (bytes): K
+# and V (forward, dq) or q and dO (dk/dv), each double-buffered by the
+# pipeline, beside the tiles and the f32 score temporaries
+_RESIDENT_BYTES = 1 << 20
+
+
+def _major_chunks(seq: int, block: int, row_bytes: int) -> int:
+    """How many ``block``-row chunks of a ``seq``-row operand one grid step
+    holds: the largest divisor of ``seq // block`` that keeps ``row_bytes``
+    a row within _RESIDENT_BYTES (at least one chunk)."""
+    n = seq // block
+    cap = max(1, _RESIDENT_BYTES // (block * row_bytes))
+    return max(c for c in range(1, n + 1) if n % c == 0 and c <= cap)
+
+
+def _check_call(S, Sk, block_q, block_k, causal, segments, dropout_rate,
+                dropout_seed):
+    if S % block_q or Sk % block_k:
+        raise ValueError(
+            f"seq {S}/{Sk} must divide by blocks {block_q}/{block_k}")
+    if causal and Sk != S:
+        raise ValueError("causal flash needs equal q/k lengths")
+    if segments is not None and Sk != S:
+        raise ValueError("segment masking needs equal q/k lengths")
+    if dropout_rate > 0.0 and dropout_seed is None:
+        raise ValueError("dropout_rate > 0 needs a dropout_seed")
+
+
+def _chunk_rows(x, block: int):
+    """[..., S] as [..., S // block, 1, block]: one chunk along lanes,
+    picked by a leading index (a (1, block) block keeps Mosaic's (8, 128)-
+    or-equal tiling rule, and a kernel cannot slice lanes at a traced
+    offset)."""
+    return x.reshape(*x.shape[:-1], x.shape[-1] // block, 1, block)
+
+
+def _segment_operands(segments, block: int):
+    """Segment ids as the kernels read them: along sublanes as a [B, S, 1]
+    column (the trailing singleton for the tiling rule, as for lse), and
+    along lanes in chunks of ``block``."""
+    seg = segments.astype(jnp.int32)
+    return seg[:, :, None], _chunk_rows(seg, block)
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "block_q", "block_k",
@@ -174,29 +312,28 @@ def flash_attention_hmajor(
     G = N // K
     block_q = min(block_q, S)
     block_k = min(block_k, Sk)
-    if S % block_q or Sk % block_k:
-        raise ValueError(
-            f"seq {S}/{Sk} must divide by blocks {block_q}/{block_k}")
-    if causal and Sk != S:
-        raise ValueError("causal flash needs equal q/k lengths")
-    if segments is not None and Sk != S:
-        raise ValueError("segment masking needs equal q/k lengths")
-    if dropout_rate > 0.0 and dropout_seed is None:
-        raise ValueError("dropout_rate > 0 needs a dropout_seed")
-    num_k = Sk // block_k
-    grid = (B, N, S // block_q, num_k)  # k-block axis innermost
+    _check_call(S, Sk, block_q, block_k, causal, segments, dropout_rate,
+                dropout_seed)
+    chunks = _major_chunks(Sk, block_k, D * k.dtype.itemsize)
+    major = chunks * block_k
+    num_major = Sk // major
+    grid = (B, N, S // block_q, num_major)  # k major axis innermost
     has_seg = segments is not None
     kernel = functools.partial(
-        _flash_kernel, block_q=block_q, block_k=block_k, num_k=num_k,
-        causal=causal, scale=1.0 / math.sqrt(D), has_seg=has_seg,
-        dropout_rate=dropout_rate)
+        _flash_kernel, block_q=block_q, block_k=block_k, chunks=chunks,
+        num_major=num_major, causal=causal, scale=1.0 / math.sqrt(D),
+        has_seg=has_seg, dropout_rate=dropout_rate)
+
+    def kj_of(qi, kj):
+        return _needed_k_major(qi, kj, block_q, major, causal)
+
     in_specs = [
         pl.BlockSpec((1, 1, block_q, D),
-                     lambda b, n, qi, ki: (b, n, qi, 0)),
-        pl.BlockSpec((1, 1, block_k, D),
-                     lambda b, n, qi, ki: (b, n // G, ki, 0)),
-        pl.BlockSpec((1, 1, block_k, D),
-                     lambda b, n, qi, ki: (b, n // G, ki, 0)),
+                     lambda b, n, qi, kj: (b, n, qi, 0)),
+        pl.BlockSpec((1, 1, major, D),
+                     lambda b, n, qi, kj: (b, n // G, kj_of(qi, kj), 0)),
+        pl.BlockSpec((1, 1, major, D),
+                     lambda b, n, qi, kj: (b, n // G, kj_of(qi, kj), 0)),
     ]
     operands = [q, k, v]
     if dropout_rate > 0.0:
@@ -204,34 +341,32 @@ def flash_attention_hmajor(
         in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
         operands.append(dropout_seed.astype(jnp.int32).reshape(1))
     if has_seg:
-        # [B, S, 1]: trailing singleton keeps Mosaic's (8, 128)-or-equal
-        # tiling rule satisfied (same layout trick as lse)
-        seg3 = segments.astype(jnp.int32)[:, :, None]
         in_specs += [
-            pl.BlockSpec((1, block_q, 1), lambda b, n, qi, ki: (b, qi, 0)),
-            pl.BlockSpec((1, block_k, 1), lambda b, n, qi, ki: (b, ki, 0)),
+            pl.BlockSpec((1, block_q, 1), lambda b, n, qi, kj: (b, qi, 0)),
+            pl.BlockSpec((1, chunks, 1, block_k),
+                         lambda b, n, qi, kj: (b, kj_of(qi, kj), 0, 0)),
         ]
-        operands += [seg3, seg3]
+        operands += list(_segment_operands(segments, block_k))
     return pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=in_specs,
         out_specs=[
             pl.BlockSpec((1, 1, block_q, D),
-                         lambda b, n, qi, ki: (b, n, qi, 0)),
+                         lambda b, n, qi, kj: (b, n, qi, 0)),
             pl.BlockSpec((1, 1, block_q, 1),
-                         lambda b, n, qi, ki: (b, n, qi, 0)),
+                         lambda b, n, qi, kj: (b, n, qi, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B, N, S, D), q.dtype),
             jax.ShapeDtypeStruct((B, N, S, 1), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q,), jnp.float32),
-            pltpu.VMEM((block_q,), jnp.float32),
+            pltpu.VMEM((block_q, _LANES), jnp.float32),
+            pltpu.VMEM((block_q, _LANES), jnp.float32),
             pltpu.VMEM((block_q, D), jnp.float32),
         ],
-        # only the k-block axis carries loop state (the online softmax);
+        # only the k axis carries loop state (the online softmax);
         # everything else may be reordered/partitioned by Mosaic
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
@@ -244,13 +379,39 @@ def flash_attention_hmajor(
     )(*operands)
 
 
+def _p_and_ds(q, k, v, do, lse, delta, q0, k0, qseg, kseg, seed_ref, bn, *,
+              masked: bool, scale: float, dropout_rate: float,
+              k_rows: bool = False):
+    """What both backward kernels recompute for one score tile from the
+    saved logsumexp: p (dropped and rescaled where dropout is on, as the
+    forward fed it to p.v) and ds = p * (dp - delta) * scale, both f32.
+    With ``k_rows`` the tile is [k, q] and lse / delta / qseg are rows
+    (1, block_q), else [q, k] and they are columns (block_q, 1)."""
+    s = _scores(q, k, q0, k0, qseg, kseg, masked=masked, scale=scale,
+                k_rows=k_rows)
+    p = jnp.exp(s - lse)
+    dp = _dot(v, do, _NT) if k_rows else _dot(do, v, _NT)
+    pd = p
+    if dropout_rate > 0.0:
+        keep = keep_mask(seed_ref[0], bn,
+                         *_tile_pos(q0, k0, s.shape, int(k_rows)),
+                         dropout_rate)
+        pd = jnp.where(keep, p / (1.0 - dropout_rate), 0.0)
+        dp = jnp.where(keep, dp / (1.0 - dropout_rate), 0.0)
+    # delta = rowsum(dropout(P) . dP') = dO . O, so the flash delta trick
+    # survives dropout unchanged
+    return pd, p * (dp - delta) * scale
+
+
 def _flash_bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                           *rest, block_q: int, block_k: int, num_q: int,
-                           G: int, causal: bool, scale: float,
-                           has_seg: bool = False,
+                           *rest, block_q: int, block_k: int, chunks: int,
+                           num_major: int, G: int, causal: bool,
+                           scale: float, has_seg: bool = False,
                            dropout_rate: float = 0.0):
-    """Grid (B, KV, kb, G, qb): accumulate dk/dv for one k/v tile across the
-    G query heads of this kv head and all q blocks."""
+    """Grid (B, KV, k block, G, q major block): accumulate dk/dv for one k/v
+    tile across the G query heads of this kv head and all q rows; one step
+    holds ``chunks`` q chunks and loops over those at or below the
+    diagonal, masking only the ones that cross it."""
     if dropout_rate > 0.0:
         seed_ref, rest = rest[0], rest[1:]
     else:
@@ -260,72 +421,58 @@ def _flash_bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     else:
         qseg_ref = kseg_ref = None
         dk_ref, dv_ref, dk_acc, dv_acc = rest
-    kb = pl.program_id(2)
+    k0 = pl.program_id(2) * block_k
     g = pl.program_id(3)
-    qb = pl.program_id(4)
+    qj = pl.program_id(4)
+    lo = qj * chunks
     # flat head index n = kh*G + g (N = KV*G heads); top-level program_id
     bn = pl.program_id(0) * (pl.num_programs(1) * G) + pl.program_id(1) * G + g
 
-    @pl.when((g == 0) & (qb == 0))
+    @pl.when((g == 0) & (qj == 0))
     def _init():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    # q blocks entirely above the causal diagonal contribute nothing
-    first_q = (kb * block_k) // block_q if causal else 0
+    def chunk(c, masked):
+        rows = pl.ds(pl.multiple_of(c * block_q, block_q), block_q)
+        q = q_ref[0, 0, rows, :]
+        do = do_ref[0, 0, rows, :]
+        # the tile is [k, q]: p^T.dO and ds^T.q are then plain products,
+        # and lse / delta broadcast along sublanes from (1, block_q) rows
+        pd, ds = _p_and_ds(
+            q, k_ref[0, 0], v_ref[0, 0], do, lse_ref[0, 0, c],
+            delta_ref[0, 0, c], (lo + c) * block_q, k0,
+            qseg_ref[0, c] if has_seg else None,
+            kseg_ref[0] if has_seg else None, seed_ref, bn,
+            masked=masked, scale=scale, dropout_rate=dropout_rate,
+            k_rows=True)
+        dv_acc[...] += _dot(pd.astype(do.dtype), do, _NN)
+        dk_acc[...] += _dot(ds.astype(q.dtype), q, _NN)
 
-    @pl.when(qb >= first_q)
-    def _update():
-        q = q_ref[0, 0].astype(jnp.float32)
-        k = k_ref[0, 0].astype(jnp.float32)
-        v = v_ref[0, 0].astype(jnp.float32)
-        do = do_ref[0, 0].astype(jnp.float32)
-        lse = lse_ref[0, 0]  # (block_q, 1): broadcasts over block_k
-        delta = delta_ref[0, 0]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        if causal:
-            qpos = qb * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            kpos = kb * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(qpos >= kpos, s, NEG_INF)
-        if qseg_ref is not None:
-            s = jnp.where(qseg_ref[0, :, 0][:, None]
-                          == kseg_ref[0, :, 0][None, :], s, NEG_INF)
-        p = jnp.exp(s - lse)
-        p = jnp.where(s == NEG_INF, 0.0, p)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        pd = p
-        if dropout_rate > 0.0:
-            # mask is (qpos, kpos)-indexed; this kernel's tile is q=qb, k=kb
-            keep = _tile_keep(seed_ref, bn, qb, kb, block_q, block_k,
-                              dropout_rate)
-            pd = jnp.where(keep, p / (1.0 - dropout_rate), 0.0)
-            dp = jnp.where(keep, dp / (1.0 - dropout_rate), 0.0)
-        dv_acc[...] += jax.lax.dot_general(
-            pd, do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        # delta = rowsum(dropout(P) . dP') = dO . O, so the flash delta
-        # trick survives dropout unchanged
-        ds = p * (dp - delta) * scale
-        dk_acc[...] += jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    if causal:
+        # q chunks from the first with a visible row; those from ``clear``
+        # on lie wholly at or below the diagonal
+        first = jnp.clip(k0 // block_q - lo, 0, chunks)
+        clear = jnp.clip((k0 + block_k + block_q - 2) // block_q - lo,
+                         0, chunks)
+        _for(first, clear, lambda c: chunk(c, True))
+        _for(clear, chunks, lambda c: chunk(c, False))
+    else:
+        _for(0, chunks, lambda c: chunk(c, False))
 
-    @pl.when((g == G - 1) & (qb == num_q - 1))
+    @pl.when((g == G - 1) & (qj == num_major - 1))
     def _finalize():
         dk_ref[0, 0] = dk_acc[...].astype(dk_ref.dtype)
         dv_ref[0, 0] = dv_acc[...].astype(dv_ref.dtype)
 
 
-def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                         *rest, block_q: int, block_k: int,
-                         num_k: int, causal: bool, scale: float,
-                         has_seg: bool = False,
-                         dropout_rate: float = 0.0):
-    """Grid (B, N, qb, kb): accumulate dq for one q tile across k blocks."""
+def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, o_ref,
+                         *rest, block_q: int, block_k: int, chunks: int,
+                         num_major: int, causal: bool, scale: float,
+                         has_seg: bool = False, dropout_rate: float = 0.0):
+    """Grid (B, N, q block, k major block): accumulate dq for one q tile
+    over the k chunks the causal mask leaves (the forward's loop); ``o_ref``
+    is the forward's output tile, for delta."""
     if dropout_rate > 0.0:
         seed_ref, rest = rest[0], rest[1:]
     else:
@@ -335,49 +482,35 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     else:
         qseg_ref = kseg_ref = None
         dq_ref, dq_acc = rest
-    qb = pl.program_id(2)
-    kb = pl.program_id(3)
+    kj = pl.program_id(3)
+    q0 = pl.program_id(2) * block_q
+    lo = kj * chunks
     bn = pl.program_id(0) * pl.num_programs(1) + pl.program_id(1)
 
-    @pl.when(kb == 0)
+    @pl.when(kj == 0)
     def _init():
         dq_acc[...] = jnp.zeros_like(dq_acc)
 
-    diag_last = (qb * block_q + block_q - 1) // block_k if causal else num_k
+    # delta = rowsum(dO . O) of this q tile, once a grid step from the tiles
+    # themselves: as a [B, N, S, 1] column in HBM it would cost a 128-lane
+    # row an element (the dk/dv kernel reads it as rows)
+    delta = jnp.sum(do_ref[0, 0].astype(jnp.float32)
+                    * o_ref[0, 0].astype(jnp.float32), axis=1, keepdims=True)
 
-    @pl.when(kb <= diag_last)
-    def _update():
-        q = q_ref[0, 0].astype(jnp.float32)
-        k = k_ref[0, 0].astype(jnp.float32)
-        v = v_ref[0, 0].astype(jnp.float32)
-        do = do_ref[0, 0].astype(jnp.float32)
-        lse = lse_ref[0, 0]  # (block_q, 1): broadcasts over block_k
-        delta = delta_ref[0, 0]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        if causal:
-            qpos = qb * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            kpos = kb * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(qpos >= kpos, s, NEG_INF)
-        if qseg_ref is not None:
-            s = jnp.where(qseg_ref[0, :, 0][:, None]
-                          == kseg_ref[0, :, 0][None, :], s, NEG_INF)
-        p = jnp.exp(s - lse)
-        p = jnp.where(s == NEG_INF, 0.0, p)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        if dropout_rate > 0.0:
-            keep = _tile_keep(seed_ref, bn, qb, kb,
-                              block_q, block_k, dropout_rate)
-            dp = jnp.where(keep, dp / (1.0 - dropout_rate), 0.0)
-        ds = p * (dp - delta) * scale
-        dq_acc[...] += jax.lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    def chunk(c, masked):
+        rows = pl.ds(pl.multiple_of(c * block_k, block_k), block_k)
+        k = k_ref[0, 0, rows, :]
+        _, ds = _p_and_ds(
+            q_ref[0, 0], k, v_ref[0, 0, rows, :], do_ref[0, 0],
+            lse_ref[0, 0], delta, q0, (lo + c) * block_k,
+            qseg_ref[0] if has_seg else None,
+            kseg_ref[0, c] if has_seg else None, seed_ref, bn,
+            masked=masked, scale=scale, dropout_rate=dropout_rate)
+        dq_acc[...] += _dot(ds.astype(k.dtype), k, _NN)
 
-    @pl.when(kb == num_k - 1)
+    _for_k_chunks(chunk, q0, block_q, block_k, lo, chunks, causal)
+
+    @pl.when(kj == num_major - 1)
     def _finalize():
         dq_ref[0, 0] = dq_acc[...].astype(dq_ref.dtype)
 
@@ -400,64 +533,68 @@ def flash_attention_bwd_hmajor(
     G = N // KV
     block_q = min(block_q, S)
     block_k = min(block_k, Sk)
-    num_q = S // block_q
-    num_k = Sk // block_k
+    _check_call(S, Sk, block_q, block_k, causal, segments, dropout_rate,
+                dropout_seed)
     scale = 1.0 / math.sqrt(D)
-    if causal and Sk != S:
-        raise ValueError("causal flash needs equal q/k lengths")
     has_seg = segments is not None
-    if has_seg and Sk != S:
-        raise ValueError("segment masking needs equal q/k lengths")
-    # (B, N, S, 1): same trailing-singleton layout as lse (Mosaic tiling)
-    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1,
-                    keepdims=True)
-
-    if dropout_rate > 0.0 and dropout_seed is None:
-        raise ValueError("dropout_rate > 0 needs a dropout_seed")
+    # for dk/dv, as rows; the dq kernel takes its own from the o / dO tiles
+    delta = _chunk_rows(
+        jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1),
+        block_q)
     seed_arr = (dropout_seed.astype(jnp.int32).reshape(1)
                 if dropout_rate > 0.0 else None)
+    if has_seg:
+        seg_col, kseg_rows = _segment_operands(segments, block_k)
+        _, qseg_rows = _segment_operands(segments, block_q)
 
-    dkdv_in_specs = [
-        pl.BlockSpec((1, 1, block_q, D),
-                     lambda b, kh, kb, g, qb: (b, kh * G + g, qb, 0)),
-        pl.BlockSpec((1, 1, block_k, D),
-                     lambda b, kh, kb, g, qb: (b, kh, kb, 0)),
-        pl.BlockSpec((1, 1, block_k, D),
-                     lambda b, kh, kb, g, qb: (b, kh, kb, 0)),
-        pl.BlockSpec((1, 1, block_q, D),
-                     lambda b, kh, kb, g, qb: (b, kh * G + g, qb, 0)),
-        pl.BlockSpec((1, 1, block_q, 1),
-                     lambda b, kh, kb, g, qb: (b, kh * G + g, qb, 0)),
-        pl.BlockSpec((1, 1, block_q, 1),
-                     lambda b, kh, kb, g, qb: (b, kh * G + g, qb, 0)),
-    ]
-    dkdv_operands = [q, k, v, do, lse, delta]
+    # dk/dv: a k/v tile stays, q / dO / lse / delta stream by major blocks;
+    # its score tiles are [k, q], so lse and delta come as rows
+    q_chunks = _major_chunks(S, block_q, D * q.dtype.itemsize)
+    q_major = q_chunks * block_q
+
+    def qj_of(kb, qj):
+        # a q major block wholly above the diagonal repeats the first one
+        # that is needed (no copy for an unchanged block index)
+        if causal:
+            return jnp.maximum(qj, (kb * block_k) // q_major)
+        return qj
+
+    q_rows = pl.BlockSpec(
+        (1, 1, q_major, D),
+        lambda b, kh, kb, g, qj: (b, kh * G + g, qj_of(kb, qj), 0))
+    q_stat = pl.BlockSpec(
+        (1, 1, q_chunks, 1, block_q),
+        lambda b, kh, kb, g, qj: (b, kh * G + g, qj_of(kb, qj), 0, 0))
+
+    def k_tile(b, kh, kb, g, qj):
+        return (b, kh, kb, 0)
+
+    dkdv_in_specs = [q_rows, pl.BlockSpec((1, 1, block_k, D), k_tile),
+                     pl.BlockSpec((1, 1, block_k, D), k_tile), q_rows,
+                     q_stat, q_stat]
+    dkdv_operands = [q, k, v, do, _chunk_rows(lse[..., 0], block_q), delta]
     if dropout_rate > 0.0:
         dkdv_in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
         dkdv_operands.append(seed_arr)
     if has_seg:
-        seg3 = segments.astype(jnp.int32)[:, :, None]
         dkdv_in_specs += [
-            pl.BlockSpec((1, block_q, 1),
-                         lambda b, kh, kb, g, qb: (b, qb, 0)),
+            pl.BlockSpec((1, q_chunks, 1, block_q),
+                         lambda b, kh, kb, g, qj: (b, qj_of(kb, qj), 0, 0)),
             pl.BlockSpec((1, block_k, 1),
-                         lambda b, kh, kb, g, qb: (b, kb, 0)),
+                         lambda b, kh, kb, g, qj: (b, kb, 0)),
         ]
-        dkdv_operands += [seg3, seg3]
+        dkdv_operands += [qseg_rows, seg_col]
 
     dkdv = pl.pallas_call(
         functools.partial(_flash_bwd_dkdv_kernel, block_q=block_q,
-                          block_k=block_k, num_q=num_q, G=G, causal=causal,
+                          block_k=block_k, chunks=q_chunks,
+                          num_major=S // q_major, G=G, causal=causal,
                           scale=scale, has_seg=has_seg,
                           dropout_rate=dropout_rate),
-        grid=(B, KV, num_k, G, num_q),
+        grid=(B, KV, Sk // block_k, G, S // q_major),
         in_specs=dkdv_in_specs,
-        out_specs=[
-            pl.BlockSpec((1, 1, block_k, D),
-                         lambda b, kh, kb, g, qb: (b, kh, kb, 0)),
-            pl.BlockSpec((1, 1, block_k, D),
-                         lambda b, kh, kb, g, qb: (b, kh, kb, 0)),
-        ],
+        out_specs=[pl.BlockSpec((1, 1, block_k, D), k_tile),
+                   pl.BlockSpec((1, 1, block_k, D), k_tile)],
         out_shape=[
             jax.ShapeDtypeStruct((B, KV, Sk, D), k.dtype),
             jax.ShapeDtypeStruct((B, KV, Sk, D), v.dtype),
@@ -466,7 +603,7 @@ def flash_attention_bwd_hmajor(
             pltpu.VMEM((block_k, D), jnp.float32),
             pltpu.VMEM((block_k, D), jnp.float32),
         ],
-        # dk/dv accumulate across the (g, qb) axes; kb tiles are independent
+        # dk/dv accumulate across the (g, q) axes; k tiles are independent
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary", "arbitrary")),
@@ -474,42 +611,47 @@ def flash_attention_bwd_hmajor(
         name="flash_attention_bwd_dkv",
     )(*dkdv_operands)
 
-    dq_in_specs = [
-        pl.BlockSpec((1, 1, block_q, D),
-                     lambda b, n, qb, kb: (b, n, qb, 0)),
-        pl.BlockSpec((1, 1, block_k, D),
-                     lambda b, n, qb, kb: (b, n // G, kb, 0)),
-        pl.BlockSpec((1, 1, block_k, D),
-                     lambda b, n, qb, kb: (b, n // G, kb, 0)),
-        pl.BlockSpec((1, 1, block_q, D),
-                     lambda b, n, qb, kb: (b, n, qb, 0)),
-        pl.BlockSpec((1, 1, block_q, 1),
-                     lambda b, n, qb, kb: (b, n, qb, 0)),
-        pl.BlockSpec((1, 1, block_q, 1),
-                     lambda b, n, qb, kb: (b, n, qb, 0)),
-    ]
-    dq_operands = [q, k, v, do, lse, delta]
+    # dq: a q tile stays, k / v stream by major blocks (the forward's grid)
+    k_chunks = _major_chunks(Sk, block_k, D * k.dtype.itemsize)
+    k_major = k_chunks * block_k
+
+    def kj_of(qi, kj):
+        return _needed_k_major(qi, kj, block_q, k_major, causal)
+
+    def q_tile(width):
+        return pl.BlockSpec((1, 1, block_q, width),
+                            lambda b, n, qi, kj: (b, n, qi, 0))
+
+    def k_rows():
+        return pl.BlockSpec(
+            (1, 1, k_major, D),
+            lambda b, n, qi, kj: (b, n // G, kj_of(qi, kj), 0))
+
+    dq_in_specs = [q_tile(D), k_rows(), k_rows(), q_tile(D), q_tile(1),
+                   q_tile(D)]
+    dq_operands = [q, k, v, do, lse, o]
     if dropout_rate > 0.0:
         dq_in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
         dq_operands.append(seed_arr)
     if has_seg:
         dq_in_specs += [
-            pl.BlockSpec((1, block_q, 1), lambda b, n, qb, kb: (b, qb, 0)),
-            pl.BlockSpec((1, block_k, 1), lambda b, n, qb, kb: (b, kb, 0)),
+            pl.BlockSpec((1, block_q, 1), lambda b, n, qi, kj: (b, qi, 0)),
+            pl.BlockSpec((1, k_chunks, 1, block_k),
+                         lambda b, n, qi, kj: (b, kj_of(qi, kj), 0, 0)),
         ]
-        dq_operands += [seg3, seg3]
+        dq_operands += [seg_col, kseg_rows]
     dq = pl.pallas_call(
         functools.partial(_flash_bwd_dq_kernel, block_q=block_q,
-                          block_k=block_k, num_k=num_k, causal=causal,
+                          block_k=block_k, chunks=k_chunks,
+                          num_major=Sk // k_major, causal=causal,
                           scale=scale, has_seg=has_seg,
                           dropout_rate=dropout_rate),
-        grid=(B, N, num_q, num_k),
+        grid=(B, N, S // block_q, Sk // k_major),
         in_specs=dq_in_specs,
-        out_specs=pl.BlockSpec((1, 1, block_q, D),
-                               lambda b, n, qb, kb: (b, n, qb, 0)),
+        out_specs=q_tile(D),
         out_shape=jax.ShapeDtypeStruct((B, N, S, D), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
-        # dq accumulates across k blocks only
+        # dq accumulates across k only
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
@@ -519,8 +661,8 @@ def flash_attention_bwd_hmajor(
     return dq, dkdv[0], dkdv[1]
 
 
-# default tile sizes, overridable per call
-DEFAULT_BLOCK_Q = 256
+# the largest score tile a call takes; ``choose_blocks`` fits it to the call
+DEFAULT_BLOCK_Q = 512
 DEFAULT_BLOCK_K = 512
 
 
@@ -535,6 +677,25 @@ def fit_block(default: int, seq: int, floor: int = 128) -> int:
             return b
         b //= 2
     return 0
+
+
+def choose_blocks(D: int, S: int, Sk: int, floor: int = 128):
+    """(block_q, block_k) of a call from its shapes alone: head width D, q
+    length S, k/v length Sk. A 512 x 512 score tile where the lengths allow
+    it, else the largest halving of 512 (down to ``floor``) that divides the
+    length, else the whole length as one block (a block equal to the array
+    dim keeps Mosaic's tiling rule; what then overflows VMEM fails at
+    compile time). Measured on the v5e at D=64 / S=1024 and D=128 / S=4096
+    (PERF.md, PR 25): a chunk pays one cross-lane max a row and a pass over
+    the accumulator whatever its width, so 512 x 512 beats every smaller
+    tile in all three kernels at both widths although at S=1024 it computes
+    3/4 of the square where 256 x 256 computes 5/8; 1024-wide tiles lose
+    more to the diagonal than they save. D does not move the choice at the
+    widths measured: K and V are held by the major block, which the
+    kernels size from D themselves (``_major_chunks``)."""
+    del D
+    return (fit_block(DEFAULT_BLOCK_Q, S, floor) or S,
+            fit_block(DEFAULT_BLOCK_K, Sk, floor) or Sk)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
@@ -603,18 +764,17 @@ def flash_sdpa(q, k, v, *, causal: bool = True, interpret: bool = False,
     trajectories are deterministic per seed but not bit-equal to the XLA
     core's (the reference's CUDA kernel has the same property vs torch).
 
-    Block defaults are clamped to divisors of the q / kv lengths (e.g.
-    S=768 runs 256-wide k blocks even though the tuned default is 512)."""
+    Blocks not given come from ``choose_blocks``: a function of the head
+    width and the q / kv lengths alone."""
     S, Sk = q.shape[1], k.shape[1]
+    bq, bk = choose_blocks(q.shape[-1], S, Sk)
     seed = None
     if dropout_rate > 0.0:
         if dropout_rng is None:
             raise ValueError("flash dropout_rate > 0 needs dropout_rng")
         seed = seed_from_key(dropout_rng)
     return _flash_with_vjp(q, k, v, segment_ids, seed, causal, interpret,
-                           block_q or fit_block(DEFAULT_BLOCK_Q, S) or S,
-                           block_k or fit_block(DEFAULT_BLOCK_K, Sk) or Sk,
-                           dropout_rate)
+                           block_q or bq, block_k or bk, dropout_rate)
 
 
 # the fwd + both bwd kernels mask cross-document tiles in-kernel
@@ -635,9 +795,8 @@ def make_flash_sdpa(mesh, dp_axes=(), tp_axes=(), *, interpret: bool = False,
     shard folds its (dp, tp) mesh coordinates into the seed so masks
     decorrelate across the sharded batch/head dims.
 
-    Block sizes follow ``flash_sdpa``: the tuned defaults clamped to
-    divisors of the q / kv lengths, else one whole-length block. There is
-    no fallback to the XLA core — a shape Mosaic refuses raises at compile
+    Block sizes follow ``flash_sdpa`` (``choose_blocks``). There is no
+    fallback to the XLA core — a shape Mosaic refuses raises at compile
     time. ``interpret`` comes only from the caller (CPU tests pass True).
 
     ``stage_axis`` (the compiled 1F1B engine): q/k/v carry a leading
@@ -670,12 +829,9 @@ def make_flash_sdpa(mesh, dp_axes=(), tp_axes=(), *, interpret: bool = False,
     def sdpa(q, k, v, *, causal=True, segment_ids=None,
              dropout_rate: float = 0.0, dropout_rng=None):
         # a length no lane-aligned block divides runs as ONE whole-length
-        # block (a block equal to the array dim satisfies Mosaic's tiling
-        # rule); what then overflows VMEM fails at compile time — there is
+        # block; what then overflows VMEM fails at compile time — there is
         # no XLA core behind the kernel to hide it
-        S, Sk = q.shape[s_dim], k.shape[s_dim]
-        bq = fit_block(DEFAULT_BLOCK_Q, S) or S
-        bk = fit_block(DEFAULT_BLOCK_K, Sk) or Sk
+        bq, bk = choose_blocks(q.shape[-1], q.shape[s_dim], k.shape[s_dim])
         seed = None
         if dropout_rate > 0.0:
             if dropout_rng is None:

@@ -140,18 +140,15 @@ def _ring_attention_local(q, k, v, seg=None, *, axis, cp, causal,
 # ---------------------------------------------------------------------------
 
 
-def _fit_or_die(seq: int, floor: int) -> Tuple[int, int]:
-    from hetu_galvatron_tpu.ops.pallas.flash_attention import (
-        DEFAULT_BLOCK_K,
-        DEFAULT_BLOCK_Q,
-        fit_block,
-    )
+def _blocks_or_die(q, k, floor: int) -> Tuple[int, int]:
+    """The flash blocks of one ring step on heads-major q / k blocks."""
+    from hetu_galvatron_tpu.ops.pallas.flash_attention import choose_blocks
 
-    bq = fit_block(DEFAULT_BLOCK_Q, seq, floor)
-    bk = fit_block(DEFAULT_BLOCK_K, seq, floor)
-    if not bq or not bk:
-        raise ValueError(f"no flash block >= {floor} divides seq {seq}")
-    return bq, bk
+    if not ring_flash_blocks_fit(q.shape[2], False, floor) \
+            or not ring_flash_blocks_fit(k.shape[2], False, floor):
+        raise ValueError(f"no flash block >= {floor} divides seq "
+                         f"{q.shape[2]} / {k.shape[2]}")
+    return choose_blocks(q.shape[3], q.shape[2], k.shape[2], floor)
 
 
 def ring_flash_blocks_fit(s_local: int, zigzag: bool, floor: int) -> bool:
@@ -175,8 +172,7 @@ def _fa_block(q, k, v, causal, interpret, floor):
         flash_attention_hmajor,
     )
 
-    bq, _ = _fit_or_die(q.shape[2], floor)
-    _, bk = _fit_or_die(k.shape[2], floor)
+    bq, bk = _blocks_or_die(q, k, floor)
     o, lse = flash_attention_hmajor(q, k, v, None, causal=causal,
                                     block_q=bq, block_k=bk,
                                     interpret=interpret)
@@ -189,8 +185,7 @@ def _fa_block_bwd(q, k, v, o, lse, do, causal, interpret, floor):
         flash_attention_bwd_hmajor,
     )
 
-    bq, _ = _fit_or_die(q.shape[2], floor)
-    _, bk = _fit_or_die(k.shape[2], floor)
+    bq, bk = _blocks_or_die(q, k, floor)
     dq, dk, dv = flash_attention_bwd_hmajor(
         q, k, v, o, lse, do, None, causal=causal,
         block_q=bq, block_k=bk, interpret=interpret)

@@ -25,6 +25,7 @@ spec.loader.exec_module(chip_smoke)
 TINY = {
     "flash_mha": (2, 128, 2, 2, 16),
     "flash_gqa": (1, 128, 4, 2, 16),
+    "flash_gqa_h128": (1, 128, 4, 1, 32),
     "ce": (64, 512),
     "model": ["model.hidden_size=32", "model.num_hidden_layers=2",
               "model.num_attention_heads=2", "model.vocab_size=64",
@@ -59,8 +60,8 @@ def test_script_entry_refuses_a_cpu_within_seconds():
 
 def test_kernel_leg_body_tiny_interpret():
     rep = chip_smoke.leg_kernels(TINY, interpret=True)
-    assert set(rep) == {"flash_mha", "flash_gqa", "fused_ce",
-                        "device_memory"}
+    assert set(rep) == {"flash_mha", "flash_gqa", "flash_gqa_h128",
+                        "fused_ce", "device_memory"}
     assert set(rep["flash_mha"]["max_err"]) == {"out", "dq", "dk", "dv"}
     json.dumps(rep)  # what the child prints
 

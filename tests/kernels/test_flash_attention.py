@@ -396,3 +396,223 @@ def test_keep_mask_tile_invariance_property():
                                 (32 + jnp.arange(16))[:, None],
                                 (64 + jnp.arange(16))[None, :], 0.3))
     np.testing.assert_array_equal(full[32:48, 64:80], tile)
+
+
+# ---------------------------------------------------------------------------
+# PR 25: the tile loop (in-kernel k / q chunk loops, mask only on the
+# diagonal, operands in their own dtype) and the block chooser
+# ---------------------------------------------------------------------------
+
+
+def _fwd_and_grads(fn, q, k, v, do):
+    out, vjp = jax.vjp(fn, q, k, v)
+    return (out,) + tuple(vjp(do))
+
+
+def _assert_f32_parity(got, ref):
+    """f32 inputs keep the tolerances the older cases set."""
+    np.testing.assert_allclose(np.asarray(got[0]), np.asarray(ref[0]),
+                               rtol=2e-5, atol=2e-5)
+    for a, b in zip(ref[1:], got[1:]):
+        np.testing.assert_allclose(np.asarray(b), np.asarray(a),
+                                   rtol=5e-5, atol=5e-5)
+
+
+def _assert_close_to_f32_reference(got, ref, what):
+    """``chip_smoke.py``'s rule for a bf16 kernel against the f32 reference:
+    within two bf16 roundings of the reference's largest magnitude."""
+    for name, a, b in zip(("out", "dq", "dk", "dv"), got, ref):
+        b = np.asarray(b, np.float32)
+        tol = 2 * 2.0 ** -7 * np.abs(b).max()
+        err = np.abs(np.asarray(a.astype(jnp.float32)) - b).max()
+        assert err <= tol, f"{what} {name}: max abs err {err} > {tol}"
+
+
+@pytest.mark.parametrize("shape", [
+    dict(B=1, S=512, N=2, K=2, D=64),    # MHA, head 64 (GPT-2 XL's)
+    dict(B=1, S=512, N=4, K=1, D=128),   # GQA 4:1, head 128 (Mistral's)
+], ids=["mha_h64", "gqa4_h128"])
+def test_flash_bf16_matches_f32_reference(shape):
+    """bf16 operands go into the matmuls as they are (f32 accumulation, f32
+    softmax statistics, p and ds cast like ``xla_sdpa`` casts its probs):
+    forward and all three gradients stay within the bf16 tolerance of the
+    dense core run on f32 copies."""
+    q, k, v = _qkv(**shape, dtype=jnp.bfloat16)
+    do = jax.random.normal(jax.random.key(7), q.shape, jnp.bfloat16)
+    f32 = lambda a: a.astype(jnp.float32)  # noqa: E731
+    ref = _fwd_and_grads(lambda a, b, c: xla_sdpa(a, b, c, causal=True),
+                         f32(q), f32(k), f32(v), f32(do))
+    got = _fwd_and_grads(
+        lambda a, b, c: flash_sdpa(a, b, c, causal=True, interpret=True,
+                                   block_q=128, block_k=256), q, k, v, do)
+    assert got[0].dtype == jnp.bfloat16
+    _assert_close_to_f32_reference(got, ref, str(shape))
+
+
+@pytest.mark.parametrize("block_q,block_k", [(128, 256), (256, 128)])
+def test_flash_causal_interior_diagonal_and_skipped_tiles(block_q, block_k):
+    """S=512 with unequal blocks: each of the three kernels meets a chunk
+    wholly below the diagonal (no mask built), chunks that cross it (masked)
+    and chunks wholly above it (never visited)."""
+    q, k, v = _qkv(B=1, S=512, N=4, K=2)
+    do = jax.random.normal(jax.random.key(3), q.shape, q.dtype)
+    ref = _fwd_and_grads(lambda a, b, c: xla_sdpa(a, b, c, causal=True),
+                         q, k, v, do)
+    got = _fwd_and_grads(
+        lambda a, b, c: flash_sdpa(a, b, c, causal=True, interpret=True,
+                                   block_q=block_q, block_k=block_k),
+        q, k, v, do)
+    _assert_f32_parity(got, ref)
+
+
+@pytest.mark.parametrize("causal", [True, False])
+def test_flash_several_major_blocks(monkeypatch, causal):
+    """A sequence longer than one grid step may hold: the chunk loops run
+    per major block, the running statistics carry across them and, causal,
+    a major block past the diagonal is neither fetched nor computed. The
+    residency budget is shrunk so that 768 rows already need 3 k and 6 q
+    major blocks (shapes no other test traces, so no cached trace with the
+    real budget is reused)."""
+    from hetu_galvatron_tpu.ops.pallas import flash_attention as fa
+
+    monkeypatch.setattr(fa, "_RESIDENT_BYTES", 2 * 128 * 24 * 4)
+    assert fa._major_chunks(768, 128, 24 * 4) == 2
+    q, k, v = _qkv(B=1, S=768, N=2, K=1, D=24)
+    do = jax.random.normal(jax.random.key(5), q.shape, q.dtype)
+    ref = _fwd_and_grads(lambda a, b, c: xla_sdpa(a, b, c, causal=causal),
+                         q, k, v, do)
+    got = _fwd_and_grads(
+        lambda a, b, c: flash_sdpa(a, b, c, causal=causal, interpret=True,
+                                   block_q=128, block_k=128), q, k, v, do)
+    _assert_f32_parity(got, ref)
+
+
+def test_flash_unequal_lengths_as_the_ring_calls_it():
+    """Non-causal, Sk != S, heads-major, (o, lse) out and the backward fed
+    the same lse: a ring step on an off-diagonal block."""
+    from hetu_galvatron_tpu.ops.pallas.flash_attention import (
+        flash_attention_bwd_hmajor,
+        flash_attention_hmajor,
+    )
+
+    ks = jax.random.split(jax.random.key(11), 4)
+    B, N, K, S, Sk, D = 1, 4, 2, 256, 512, 32
+    q = jax.random.normal(ks[0], (B, N, S, D))
+    k = jax.random.normal(ks[1], (B, K, Sk, D))
+    v = jax.random.normal(ks[2], (B, K, Sk, D))
+    do = jax.random.normal(ks[3], (B, N, S, D))
+
+    def dense(q, k, v):
+        kk, vv = (jnp.repeat(a, N // K, axis=1) for a in (k, v))
+        s = jnp.einsum("bnsd,bntd->bnst", q, kk) / np.sqrt(D)
+        return (jnp.einsum("bnst,bntd->bnsd", jax.nn.softmax(s, -1), vv),
+                jax.nn.logsumexp(s, axis=-1)[..., None])
+
+    (o_ref, lse_ref), vjp = jax.vjp(dense, q, k, v)
+    g_ref = vjp((do, jnp.zeros_like(lse_ref)))
+    o, lse = flash_attention_hmajor(q, k, v, None, causal=False,
+                                    block_q=128, block_k=256, interpret=True)
+    assert lse.shape == (B, N, S, 1) and lse.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(o), np.asarray(o_ref),
+                               rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(np.asarray(lse), np.asarray(lse_ref),
+                               rtol=2e-5, atol=2e-5)
+    g = flash_attention_bwd_hmajor(q, k, v, o, lse, do, None, causal=False,
+                                   block_q=128, block_k=256, interpret=True)
+    for a, b in zip(g_ref, g):
+        np.testing.assert_allclose(np.asarray(b), np.asarray(a),
+                                   rtol=5e-5, atol=5e-5)
+
+
+@pytest.mark.parametrize("causal", [True, False])
+def test_flash_segment_boundary_inside_interior_tile(causal):
+    """Segment boundaries at 60 and 300 with 128-blocks over S=512: the
+    first lies inside k chunk 0, which is wholly below the diagonal for
+    every later q block, so the segment mask must hold on tiles that build
+    no causal mask (and, for the rows of later segments, on chunks where a
+    row sees nothing at all before its own segment begins)."""
+    q, k, v = _qkv(B=2, S=512, N=2, K=2)
+    do = jax.random.normal(jax.random.key(9), q.shape, q.dtype)
+    seg = jnp.asarray(np.repeat(
+        np.concatenate([np.zeros(60, np.int32), np.ones(240, np.int32),
+                        np.full(212, 2, np.int32)])[None], 2, axis=0))
+    ref = _fwd_and_grads(
+        lambda a, b, c: xla_sdpa(a, b, c, causal=causal, segment_ids=seg),
+        q, k, v, do)
+    got = _fwd_and_grads(
+        lambda a, b, c: flash_sdpa(a, b, c, causal=causal, interpret=True,
+                                   segment_ids=seg, block_q=128,
+                                   block_k=128), q, k, v, do)
+    _assert_f32_parity(got, ref)
+
+
+def test_flash_dropout_gradients_with_unequal_blocks():
+    """The dk/dv kernel walks [k, q] tiles: its regenerated dropout mask
+    must be the forward's at every global (q, k) position."""
+    from hetu_galvatron_tpu.ops.pallas.flash_attention import (
+        keep_mask,
+        seed_from_key,
+    )
+
+    q, k, v = _qkv(B=1, S=256, N=2, K=1)
+    rate, rng = 0.25, jax.random.key(21)
+    seed = seed_from_key(rng)
+    pos = jnp.arange(256, dtype=jnp.int32)
+    keep = jnp.stack([keep_mask(seed[0], jnp.int32(n), pos[:, None],
+                                pos[None, :], rate) for n in range(2)])
+
+    def dense(q, k, v):
+        kk, vv = (jnp.repeat(a, 2, axis=2) for a in (k, v))
+        s = jnp.einsum("bsnd,btnd->bnst", q, kk) / np.sqrt(q.shape[-1])
+        s = jnp.where(pos[:, None] >= pos[None, :], s, -1e30)
+        p = jnp.where(keep[None], jax.nn.softmax(s, -1) / (1 - rate), 0.0)
+        return jnp.einsum("bnst,btnd->bsnd", p, vv)
+
+    do = jax.random.normal(jax.random.key(4), q.shape, q.dtype)
+    ref = _fwd_and_grads(dense, q, k, v, do)
+    got = _fwd_and_grads(
+        lambda a, b, c: flash_sdpa(a, b, c, causal=True, interpret=True,
+                                   dropout_rate=rate, dropout_rng=rng,
+                                   block_q=128, block_k=64), q, k, v, do)
+    _assert_f32_parity(got, ref)
+
+
+# (D, S, Sk): the four benchmark cells (GPT-2 XL at S=1024, Mistral at
+# S=4096, one and four chips share the per-chip attention shape), the ring
+# tests' local blocks (floor 8 in interpret mode, full and zigzag halves,
+# off-diagonal Sk != S), T5's encoder / decoder / cross attention, and
+# lengths no 128-multiple divides
+_CHOOSER_SHAPES = [
+    (64, 1024, 1024, 128), (128, 4096, 4096, 128),
+    (8, 32, 32, 8), (8, 16, 16, 8), (8, 8, 8, 8), (8, 16, 8, 8),
+    (8, 8, 16, 8), (16, 16, 16, 128), (32, 384, 384, 128),
+    (32, 768, 768, 128), (64, 2048, 1024, 128), (128, 32768, 32768, 128),
+    (32, 100, 100, 128),
+]
+
+
+@pytest.mark.parametrize("D,S,Sk,floor", _CHOOSER_SHAPES)
+def test_choose_blocks_divide_and_keep_the_tiling_rule(D, S, Sk, floor):
+    from hetu_galvatron_tpu.ops.pallas.flash_attention import choose_blocks
+
+    bq, bk = choose_blocks(D, S, Sk, floor)
+    assert S % bq == 0 and Sk % bk == 0
+    for block, seq in ((bq, S), (bk, Sk)):
+        # Mosaic's rule for the second-minor dim of a [block, D] tile and
+        # the minor dim of a (1, block) row: a multiple of (8, 128) or the
+        # whole array dim; interpret-mode floors only ask for the divisor
+        assert block == seq or block % max(floor, 8) == 0
+        if floor >= 128:
+            assert block == seq or block % 128 == 0
+    # a function of (D, S, Sk) alone: same answer again, nothing else read
+    assert choose_blocks(D, S, Sk, floor) == (bq, bk)
+
+
+def test_choose_blocks_for_the_benchmark_cells():
+    from hetu_galvatron_tpu.ops.pallas.flash_attention import choose_blocks
+
+    assert choose_blocks(64, 1024, 1024) == (512, 512)     # gpt2xl_c1_*
+    assert choose_blocks(128, 4096, 4096) == (512, 512)    # mistral7b_*
+    assert choose_blocks(32, 768, 768) == (256, 256)       # halved to fit
+    assert choose_blocks(32, 384, 384) == (384, 384)       # under one tile
+    assert choose_blocks(32, 100, 100) == (100, 100)       # one whole block

@@ -1,0 +1,73 @@
+"""The flash kernels compiled by the real Mosaic / XLA:TPU compilers for a
+described (not attached) TPU v5e, at the widths the benchmark's cells run and
+with every optional operand: what interpret mode cannot refuse (a slice off
+the tiling, a relayout Mosaic has no rule for, too much VMEM) fails here, on
+the CPU, in seconds. Nothing runs, so nothing here is a result or a time.
+
+All such compiles live in THIS file and describe the topology inside a
+fixture: one process may hold libtpu at a time, and a module that touched it
+while being imported would do so in every xdist worker."""
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from hetu_galvatron_tpu.ops.pallas.flash_attention import flash_sdpa
+
+pytestmark = pytest.mark.kernels
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu here, or another process holds it
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+# B, S, Sk, heads, kv heads, head dim, dtype, causal, segments, dropout
+_CASES = {
+    "gpt2xl_cell": (8, 1024, 1024, 25, 25, 64, jnp.bfloat16, True, False, 0.0),
+    "mistral_cell": (1, 4096, 4096, 32, 8, 128, jnp.bfloat16, True, False,
+                     0.0),
+    "two_major_blocks_f32": (1, 4096, 4096, 8, 2, 128, jnp.float32, True,
+                             False, 0.0),
+    "ring_off_diagonal": (2, 2048, 1024, 4, 4, 64, jnp.bfloat16, False,
+                          False, 0.0),
+    "segments_and_dropout": (1, 4096, 4096, 8, 2, 128, jnp.bfloat16, True,
+                             True, 0.1),
+    "whole_length_block": (2, 384, 384, 4, 4, 64, jnp.bfloat16, True, False,
+                           0.0),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_CASES))
+def test_flash_forward_and_backward_compile_for_v5e(one_chip, case):
+    B, S, Sk, N, K, D, dtype, causal, seg, drop = _CASES[case]
+
+    def spec(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def fwd_bwd(q, k, v, do, segments, key):
+        out, vjp = jax.vjp(
+            lambda a, b, c: flash_sdpa(
+                a, b, c, causal=causal,
+                segment_ids=segments if seg else None, dropout_rate=drop,
+                dropout_rng=key if drop else None), q, k, v)
+        return (out,) + vjp(do)
+
+    compiled = jax.jit(fwd_bwd).lower(
+        spec((B, S, N, D), dtype), spec((B, Sk, K, D), dtype),
+        spec((B, Sk, K, D), dtype), spec((B, S, N, D), dtype),
+        spec((B, S), jnp.int32),
+        spec((), jax.random.key(0).dtype)).compile()
+    hlo = compiled.as_text()
+    for kernel in ("flash_attention_fwd", "flash_attention_bwd_dq",
+                   "flash_attention_bwd_dkv"):
+        assert kernel in hlo, f"{kernel} is not in the compiled program"
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 3
