@@ -5,12 +5,15 @@
 1. the run ended the way the harness ended it (exit code 18 after the
    harness's own SIGTERM) and no step raised;
 2. every loss is finite;
-3. the loss of step 0 agrees with ``benchmark/reference/decoder_lm.py`` on
-   the same weights and the same first global batch, within the tolerance
-   the configuration's file states;
-4. every layer ran on the flash core and the compiled step holds at least
-   three Mosaic custom calls a layer (forward, dq, dk/dv), so a run on the
-   XLA core can never pass for a kernel run;
+3. the loss of step 0 agrees with the configuration's reference family
+   (``benchmark/reference/<family>.py``) on the same weights and the same
+   first global batch, within the tolerance the configuration's file states;
+4. every layer ran on an attention core the configuration's file allows
+   (``program.expects.attention_cores``; the flash core alone where it says
+   nothing) and the compiled step holds at least
+   ``program.expects.mosaic_calls_per_layer`` Mosaic custom calls a layer
+   (3 where it says nothing: forward, dq, dk/dv), so a run on the XLA core
+   can never pass for a kernel run;
 5. nothing compiled and no cache entry was written inside the window;
 6. the mean loss of the last five measured steps (or as many as the window
    has) is not above that of as many first steps by more than ``LOSS_RISE_SLACK`` (on random tokens the
@@ -24,7 +27,7 @@ import hashlib
 import json
 import math
 import os
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, Mapping, Optional
 
 from benchmark.window import EXIT_PREEMPTED
 
@@ -35,6 +38,10 @@ REFERENCE_ROWS_PER_CALL = 1
 # tokens a step (PR 21's smoke losses); an update that blows up moves it by
 # tenths or makes it infinite
 LOSS_RISE_SLACK = 0.05
+# what ``program.expects`` of a configuration's file defaults to: every layer
+# on the flash core, whose forward, dq and dk/dv kernels are three Mosaic
+# calls a layer
+DEFAULT_EXPECTS = {"attention_cores": ["flash"], "mosaic_calls_per_layer": 3}
 
 
 def _code_hash(root: str) -> str:
@@ -85,7 +92,7 @@ def reference_loss(cell, argv: List[str], seed: int, root: str,
                    out_dir: str, **variant) -> Dict[str, Any]:
     """The reference's step-0 loss for this cell and seed, from the file
     kept under ``out_dir`` when the code has not changed since."""
-    from benchmark.reference import decoder_lm
+    from benchmark import reference
 
     key = f"{cell.name}.seed{seed}.{_code_hash(root)}"
     path = os.path.join(out_dir, "reference", key + ".json")
@@ -93,9 +100,10 @@ def reference_loss(cell, argv: List[str], seed: int, root: str,
         with open(path) as f:
             return {**json.load(f), "cached": True}
     weights, tokens, labels = first_batch_and_weights(argv)
-    loss = decoder_lm.mean_loss(
+    loss = reference.mean_loss(
         cell.config["reference"]["family"], weights, cell.config,
-        tokens, labels, rows_per_call=REFERENCE_ROWS_PER_CALL, **variant)
+        tokens, labels, root=root, rows_per_call=REFERENCE_ROWS_PER_CALL,
+        **variant)
     res = {"loss": loss, "tokens": int(labels.size)}
     if not variant:
         os.makedirs(os.path.dirname(path), exist_ok=True)
@@ -105,8 +113,13 @@ def reference_loss(cell, argv: List[str], seed: int, root: str,
 
 
 def judge(facts: Dict[str, Any], *, reference: Optional[float],
-          tolerance: float, expect_mosaic: bool = True) -> Dict[str, Any]:
-    """Each condition of ``correct`` by name, and their conjunction."""
+          tolerance: float, expect_mosaic: bool = True,
+          expects: Optional[Mapping[str, Any]] = None) -> Dict[str, Any]:
+    """Each condition of ``correct`` by name, and their conjunction.
+    ``expects`` is ``program.expects`` of the configuration's file: the
+    attention cores its layers may run on, and the least Mosaic custom calls
+    a layer, over the whole stack, that the compiled step has to hold."""
+    expects = {**DEFAULT_EXPECTS, **(expects or {})}
     losses = facts["losses"]
     win = facts["window_losses"]
     cores = facts["attention_cores"] or []
@@ -122,9 +135,10 @@ def judge(facts: Dict[str, Any], *, reference: Optional[float],
             reference is not None and bool(losses)
             and abs(losses[0] - reference) <= tolerance),
         "flash_core_everywhere": (
-            (set(cores) == {"flash"} and mosaic is not None
-             and mosaic >= 3 * len(cores)) if expect_mosaic
-            else bool(cores)),
+            (bool(cores) and set(cores) <= set(expects["attention_cores"])
+             and mosaic is not None
+             and mosaic >= expects["mosaic_calls_per_layer"] * len(cores))
+            if expect_mosaic else bool(cores)),
         "no_compile_in_window": (
             bool(comp) and comp["cache_writes"] == 0
             and comp["backend_compiles"] == 0),

@@ -1,7 +1,9 @@
 """Operations and bytes, from shapes: the model step and the flash kernel.
 
 These are the operations the ALGORITHM requires, which is what a
-utilisation or a roofline share is measured against:
+utilisation or a roofline share is measured against. Every count is held
+to these rules, the one here and the one a reference family exports
+(``benchmark/reference/<family>.py::forward_flops_per_token``):
 
 * matmuls only (2 x m x n x k each); norms, activations, softmax and the
   optimizer update are not counted;
@@ -12,6 +14,14 @@ utilisation or a roofline share is measured against:
 * recomputation (per-layer remat, the flash backward's recomputed scores)
   is not counted: it is work the implementation chose, not work the model
   needs.
+
+The model's count comes from its family, because only the family knows its
+layer: ``gpt2`` and ``mistral`` return the dense count below; a family with
+experts adds its experts per token and its router, one with latent
+attention its own projections, from ``attention_flops_per_token`` and
+``head_flops_per_token`` and the keys of its configuration's file. The dense
+count refuses a program that has experts, so that a configuration cannot
+name a dense family and have one expert counted where a token uses several.
 
 Where this differs from ``hetu_galvatron_tpu/observability/telemetry.py``
 and ``models/builder.py::model_flops_per_token``: those count the S x S
@@ -40,6 +50,7 @@ class Sizes:
     ffn_matrices: int   # 3 for a gated MLP (gate, up, down), else 2
     vocab: int          # published rows; padding rows are not required work
     seq: int
+    experts: int = 0    # routed experts a layer; 0 for a dense MLP
 
     @classmethod
     def of(cls, cfg: Any) -> "Sizes":
@@ -49,30 +60,54 @@ class Sizes:
             heads=cfg.num_attention_heads, kv_heads=cfg.kv_heads,
             head_dim=cfg.head_dim, ffn=cfg.ffn_dim,
             ffn_matrices=3 if cfg.hidden_act in ("swiglu", "geglu") else 2,
-            vocab=cfg.vocab_size, seq=cfg.seq_length)
+            vocab=cfg.vocab_size, seq=cfg.seq_length,
+            experts=cfg.num_experts)
 
 
 def causal_keys_per_query(seq: int) -> float:
     return (seq + 1) / 2.0
 
 
-def forward_flops_per_token(s: Sizes) -> float:
+def attention_flops_per_token(s: Sizes) -> float:
+    """q, k, v and output projections and causal attention, one block."""
     qkv = 2 * s.hidden * (s.heads + 2 * s.kv_heads) * s.head_dim
     out = 2 * s.heads * s.head_dim * s.hidden
-    mlp = 2 * s.hidden * s.ffn * s.ffn_matrices
     # q.k^T and p.v, each 2 x head_dim per (query, key) pair and head
     attn = 2 * 2 * s.heads * s.head_dim * causal_keys_per_query(s.seq)
-    head = 2 * s.hidden * s.vocab
-    return s.layers * (qkv + out + mlp + attn) + head
+    return qkv + out + attn
+
+
+def head_flops_per_token(s: Sizes) -> float:
+    return 2 * s.hidden * s.vocab
+
+
+def forward_flops_per_token(s: Sizes) -> float:
+    """The dense decoder: attention and one MLP of ``ffn x ffn_matrices`` a
+    block, and the head."""
+    if s.experts:
+        raise ValueError(
+            f"the program runs {s.experts} experts a layer and the "
+            "configuration's reference family returns the dense FLOP count "
+            "(one MLP a block, no router): the family's own "
+            "forward_flops_per_token has to count its experts per token")
+    mlp = 2 * s.hidden * s.ffn * s.ffn_matrices
+    return (s.layers * (attention_flops_per_token(s) + mlp)
+            + head_flops_per_token(s))
+
+
+def train_from_forward(forward_flops: float) -> float:
+    """Forward + backward: each forward matmul has two in the backward."""
+    return 3.0 * forward_flops
 
 
 def train_flops_per_token(s: Sizes) -> float:
-    return 3.0 * forward_flops_per_token(s)
+    return train_from_forward(forward_flops_per_token(s))
 
 
-def mfu_pct(tokens_per_s: float, s: Sizes, chips: int,
+def mfu_pct(tokens_per_s: float, train_flops_per_token: float, chips: int,
             peak_flops_per_s: float) -> float:
-    return 100.0 * tokens_per_s * train_flops_per_token(s) / (
+    """``train_flops_per_token``: three times the family's forward count."""
+    return 100.0 * tokens_per_s * train_flops_per_token / (
         chips * peak_flops_per_s)
 
 

@@ -5,19 +5,25 @@ is found by the name in ``BENCHMARK.json``:
 
 * a configuration   -> its ``file`` (``benchmark/configs/<name>.json``)
 * a traffic mix     -> ``benchmark/workloads/<traffic>.json``
-* a per-layer metric -> ``benchmark/layer_metrics/<name>.json``
+* a per-layer metric -> ``benchmark/layer_metrics/<name>.json``, and the
+  Python file its reader may name beside it
+* a reference family -> ``benchmark/reference/<family>.py`` (the plain
+  reference and the model's FLOPs; ``reference.family`` of a configuration)
 
-so a later PR adds a cell, a configuration or a metric with new files and
-new entries, and edits no file that is there.
+so a later PR adds a cell, a configuration, a metric or an architecture with
+new files and new entries, and edits no file that is there.
 """
 
 from __future__ import annotations
 
+import importlib.util
 import json
 import os
 import re
 from dataclasses import dataclass
 from typing import Any, Dict, List
+
+from benchmark import flops
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BENCH_DIR = "benchmark"
@@ -80,6 +86,21 @@ def traffic_path(root: str, traffic: str) -> str:
 
 def layer_metric_path(root: str, name: str) -> str:
     return os.path.join(root, BENCH_DIR, "layer_metrics", name + ".json")
+
+
+def family_path(root: str, family: str) -> str:
+    return os.path.join(root, BENCH_DIR, "reference", family + ".py")
+
+
+def load_python(path: str) -> Any:
+    """The Python file at ``path`` as a module of its own: how the harness
+    runs code that is found by name (a reference family, a reader or a
+    kernel's cost beside a per-layer metric)."""
+    name = "benchmark_file_" + os.path.splitext(os.path.basename(path))[0]
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
 def resolve_cell(manifest: Dict[str, Any], name: str,
@@ -190,6 +211,12 @@ def check_manifest(manifest: Dict[str, Any], root: str = ROOT) -> List[str]:
         if sorted(body.get("reduced_from", {})) != sorted(c["reduced"]):
             bad.append(f"{c['name']}: reduced {c['reduced']} != the file's "
                        f"reduced_from {sorted(body.get('reduced_from', {}))}")
+        family = body.get("reference", {}).get("family", "")
+        if not NAME_RE.match(family) or not os.path.isfile(
+                family_path(root, family)):
+            where = os.path.relpath(family_path(root, family), root)
+            bad.append(f"{c['name']}: reference family {family!r} has no "
+                       f"file {where}")
     # cells
     cells = manifest["workloads"]
     if not 2 <= len(cells) <= MAX_CELLS:
@@ -238,9 +265,22 @@ def check_manifest(manifest: Dict[str, Any], root: str = ROOT) -> List[str]:
         if m.get("moves") not in e2e:
             bad.append(f"{m['name']}: moves {m.get('moves')!r}, not an "
                        "end-to-end metric")
-        if not os.path.isfile(layer_metric_path(root, m["name"])):
+        path = layer_metric_path(root, m["name"])
+        if not os.path.isfile(path):
             bad.append(f"{m['name']}: no reader file in "
                        f"{BENCH_DIR}/layer_metrics")
+            continue
+        reader = read_json(path)["reader"]
+        beside = reader.get("file")
+        if beside and not os.path.isfile(
+                os.path.join(os.path.dirname(path), beside)):
+            bad.append(f"{m['name']}: its reader names {beside!r}, which is "
+                       f"not beside it in {BENCH_DIR}/layer_metrics")
+        if (reader["kind"] == "roofline" and not beside
+                and not callable(getattr(flops, reader["cost"], None))):
+            bad.append(f"{m['name']}: cost {reader['cost']!r} is no function "
+                       f"of {BENCH_DIR}/flops.py and the reader names no "
+                       "file beside it")
     for w in cells:   # every cell: setup_s, another end-to-end, a per-layer
         mine = [m["name"] for m in manifest["end_to_end"]
                 if _applies(m, w["name"])]

@@ -5,8 +5,11 @@
 trace offer today; a metric that needs more names a function in a Python
 file beside its JSON (``{"kind": "python", "file": "x.py", "function":
 "read"}``), which gets the same ``facts`` and returns a number or ``None``.
-A reader that finds nothing to read returns ``None`` and the harness leaves
-that metric out of the line.
+A ``roofline`` reader takes its kernel's operations and bytes from the
+function ``cost`` names: of ``benchmark/flops.py``, or of the ``file`` beside
+the JSON where the reader names one, so a new kernel brings its cost and
+shares the roofline arithmetic. A reader that finds nothing to read returns
+``None`` and the harness leaves that metric out of the line.
 
 ``facts`` holds: ``trace`` (``xplane.facts_of``; absent in an untraced
 run), ``window``, ``compile``, ``memory``, ``sizes`` (``flops.Sizes``),
@@ -15,7 +18,6 @@ run), ``window``, ``compile``, ``memory``, ``sizes`` (``flops.Sizes``),
 
 from __future__ import annotations
 
-import importlib.util
 import os
 from typing import Any, Dict, Optional
 
@@ -58,10 +60,13 @@ def read_op_time(facts, *, pattern: str, report: str,
     raise ValueError(f"op_time: unknown report {report!r}")
 
 
-def read_roofline(facts, *, pattern: str, cost: str, **_) -> Optional[float]:
+def read_roofline(facts, *, pattern: str, cost: str, _dir: str,
+                  file: Optional[str] = None, **_) -> Optional[float]:
     """Least time by the roofline over measured kernel time, in percent.
-    ``cost`` names the function of ``benchmark/flops.py`` that gives the
-    kernel's operations and bytes per step from the model's sizes."""
+    ``cost`` names the function that gives the kernel's operations and
+    bytes per step, ``cost(sizes, sequences_per_step) -> {"flops":,
+    "bytes":}``: of ``file`` beside the metric's JSON, or of
+    ``benchmark/flops.py`` when the reader names no file."""
     trace = facts.get("trace")
     if not trace:
         return None
@@ -69,17 +74,16 @@ def read_roofline(facts, *, pattern: str, cost: str, **_) -> Optional[float]:
     measured_s = xplane.matching_ns(r, pattern) / r.periods / 1e9
     if measured_s <= 0:
         return None
-    need = getattr(flops, cost)(facts["sizes"], facts["sequences_per_step"])
+    where = (manifest.load_python(os.path.join(_dir, file)) if file
+             else flops)
+    need = getattr(where, cost)(facts["sizes"], facts["sequences_per_step"])
     least = flops.roofline_least_s(need, facts["peaks"], facts["chips"])
     facts.setdefault("roofline_bounds", {})[cost] = least["bound"]
     return 100.0 * least["least_s"] / measured_s
 
 
 def read_python(facts, *, file: str, function: str, _dir: str, **_):
-    spec = importlib.util.spec_from_file_location(
-        "layer_metric_" + os.path.splitext(file)[0], os.path.join(_dir, file))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
+    mod = manifest.load_python(os.path.join(_dir, file))
     return getattr(mod, function)(facts)
 
 

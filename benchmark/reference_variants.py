@@ -22,6 +22,20 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
 
+def depth_key(config) -> str:
+    """The key of the configuration's file that holds its depth:
+    ``reference.depth_key``, or the only key of ``reduced_from``."""
+    named = config["reference"].get("depth_key")
+    if named:
+        return named
+    cut = list(config.get("reduced_from", {}))
+    if len(cut) != 1:
+        raise SystemExit(
+            f"reduced_from has the keys {cut}: name the one that is the "
+            "depth as reference.depth_key in the configuration's file")
+    return cut[0]
+
+
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", required=True)
@@ -29,20 +43,19 @@ def main() -> int:
     a = ap.parse_args()
     import jax.numpy as jnp
 
-    from benchmark import check, manifest
-    from benchmark.reference import decoder_lm
+    from benchmark import check, manifest, reference
 
     cell = manifest.resolve_cell(manifest.load_manifest(), a.workload)
     argv = manifest.train_argv(cell, a.seed)
     weights, tokens, labels = check.first_batch_and_weights(argv)
     family = cell.config["reference"]["family"]
-    depth = cell.config[next(iter(cell.config["reduced_from"]))]
+    depth = cell.config[depth_key(cell.config)]
     out = {"cell": cell.name, "seed": a.seed}
     for name, kw in (("as_published", {}),
                      ("one_block_fewer", {"layers": depth - 1}),
                      ("bfloat16", {"dtype": jnp.bfloat16})):
-        out[name] = decoder_lm.mean_loss(family, weights, cell.config,
-                                         tokens, labels, **kw)
+        out[name] = reference.mean_loss(family, weights, cell.config,
+                                        tokens, labels, **kw)
         print(json.dumps(out), flush=True)
     return 0
 

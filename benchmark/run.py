@@ -45,7 +45,15 @@ def measure(cell, *, seed: int, seconds: float, trace: int, chip: dict,
     the device's row of ``peaks.py``. Platform-neutral so that
     ``benchmark/tests`` can drive it at a tiny size; ``main`` is what
     refuses anything but a TPU."""
-    from benchmark import check, flops, manifest, readers, window, xplane
+    from benchmark import (
+        check,
+        flops,
+        manifest,
+        readers,
+        reference,
+        window,
+        xplane,
+    )
 
     from hetu_galvatron_tpu.core.arguments import args_from_cli
     from hetu_galvatron_tpu.utils.hf_config_adapter import resolve_model_config
@@ -60,6 +68,11 @@ def measure(cell, *, seed: int, seconds: float, trace: int, chip: dict,
                 f"{getattr(cfg, attr)!r}, the configuration's file says "
                 f"{key}={cell.config[key]!r}")
     sizes = flops.Sizes.of(cfg)
+    # the model's FLOPs are its family's; a family or an export that is not
+    # there, or a dense count for a program with experts, stops the run here
+    family = reference.load_family(cell.config["reference"]["family"], root)
+    train_flops = flops.train_from_forward(
+        family.forward_flops_per_token(sizes, cell.config))
     sequences = args.parallel.global_train_batch_size
     tokens_per_step = sequences * cfg.seq_length
 
@@ -99,7 +112,7 @@ def measure(cell, *, seed: int, seconds: float, trace: int, chip: dict,
     setup_s = win["start"] - t_process_start
     tokens_per_s = tokens_per_step / win["median_period_s"]
     e2e = {"tokens_per_s": tokens_per_s,
-           "mfu_pct": flops.mfu_pct(tokens_per_s, sizes, cell.chips,
+           "mfu_pct": flops.mfu_pct(tokens_per_s, train_flops, cell.chips,
                                     chip["bf16_flops_per_s"]),
            "setup_s": setup_s}
 
@@ -126,7 +139,8 @@ def measure(cell, *, seed: int, seconds: float, trace: int, chip: dict,
         f"{facts['losses'][0] if facts['losses'] else None}")
     verdict = check.judge(facts, reference=ref["loss"],
                           tolerance=cell.config["reference"]["loss_tolerance"],
-                          expect_mosaic=expect_mosaic)
+                          expect_mosaic=expect_mosaic,
+                          expects=cell.config["program"].get("expects"))
 
     # ---- the metrics of this run ---------------------------------------
     metrics = {}
@@ -162,7 +176,7 @@ def measure(cell, *, seed: int, seconds: float, trace: int, chip: dict,
         "mosaic_custom_calls": facts["mosaic_custom_calls"],
         "roofline_bounds": facts.get("roofline_bounds"),
         "tokens_per_step": tokens_per_step,
-        "train_flops_per_token": flops.train_flops_per_token(sizes),
+        "train_flops_per_token": train_flops,
         "trace_facts": ({k: v for k, v in facts["trace"].items()
                          if k != "reduced"} if trace else None),
         "total_s": time.perf_counter() - t_process_start,

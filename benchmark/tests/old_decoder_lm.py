@@ -1,4 +1,9 @@
-"""Plain reference: forward pass and mean token cross-entropy of a GPT-2
+"""ORACLE for ``test_the_moved_references_return_the_old_loss_bit_for_bit``:
+``benchmark/reference/decoder_lm.py`` as it was before PR 26 split it into
+``reference/plain.py``, ``gpt2.py``, ``mistral.py`` and a family-neutral
+``mean_loss``. Nothing but that test imports it. Its own words follow.
+
+Plain reference: forward pass and mean token cross-entropy of a GPT-2
 block stack and of a Mistral block stack.
 
 Straightforward ``jax.numpy`` in float32 under

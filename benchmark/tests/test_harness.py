@@ -54,6 +54,90 @@ def test_cell_runs_through_train_dist_and_ends_its_own_window(
     json.dumps(line)
 
 
+def test_a_new_architecture_is_new_files_and_new_entries_only(tmp_path):
+    """A family the harness has never seen (a sparse mixture of experts,
+    which the program trains) is added to a copy of the benchmark by its
+    reference file, a configuration, a cell and a metric, without editing a
+    file that was there, the Python among it; the harness finds all of it by
+    name, step 0 agrees with the new reference, and ``mfu_pct`` follows the
+    family's own FLOP count."""
+    import dataclasses
+
+    from benchmark import flops, reference
+
+    root = tiny.make_root(tmp_path)
+    tiny.add_new_family(root)
+    tiny.assert_nothing_that_was_there_is_edited(root)
+    man = manifest.load_manifest(root)
+    assert manifest.check_manifest(man, root) == []
+    cell = manifest.resolve_cell(man, tiny.NEW_FAMILY_CELL[0], root)
+    kw = dict(seed=7, seconds=0.5, trace=0, chip=tiny.FAKE_CHIP, root=root,
+              out_dir=str(tmp_path / "out"), expect_mosaic=False)
+    line, report = run.measure(cell, **kw)
+    checks = report["checks"]
+    assert checks["step0_matches_reference"], (
+        report["losses"][0], report["reference"])
+    assert line["correct"] is True, checks
+    assert not report["reference"]["cached"]
+
+    # two experts of four and the router a token and layer, not one MLP
+    sizes = flops.Sizes(layers=2, hidden=32, heads=4, kv_heads=2, head_dim=8,
+                        ffn=64, ffn_matrices=3, vocab=64, seq=16, experts=4)
+    family = reference.load_family("tiny_mixtral", root)
+    forward = family.forward_flops_per_token(sizes, cell.config)
+    dense = flops.forward_flops_per_token(
+        dataclasses.replace(sizes, experts=0))
+    assert forward - dense == 2 * (2 * 32 * 4 + 2 * 32 * 64 * 3)
+    assert report["train_flops_per_token"] == 3 * forward
+    m = line["metrics"]
+    assert m["mfu_pct"]["value"] == pytest.approx(
+        100 * m["tokens_per_s"]["value"] * 3 * forward
+        / tiny.FAKE_CHIP["bf16_flops_per_s"], rel=1e-12)
+
+    # the same program under a dense family is refused before the window
+    path = os.path.join(root, "benchmark", "configs", "tiny-mixtral.json")
+    with open(path, "w") as f:
+        json.dump({**cell.config, "reference": {
+            **cell.config["reference"], "family": "mistral"}}, f)
+    cell = manifest.resolve_cell(man, tiny.NEW_FAMILY_CELL[0], root)
+    with pytest.raises(ValueError, match="4 experts a layer"):
+        run.measure(cell, **kw)
+    # and a family whose file lacks an export, by the file's name
+    with open(manifest.family_path(root, "half_written"), "w") as f:
+        f.write("def nll_sum(w, cfg, tokens, labels, *, layers=None): ...\n")
+    with pytest.raises(AttributeError,
+                       match="half_written.py does not export "
+                             "forward_flops_per_token"):
+        reference.load_family("half_written", root)
+    with pytest.raises(FileNotFoundError, match="never_written.py"):
+        reference.load_family("never_written", root)
+
+
+@pytest.mark.parametrize("cell_name", ["tiny_gpt2_c1", "tiny_mistral_c1"])
+def test_the_moved_references_return_the_old_loss_bit_for_bit(
+        tmp_path, cell_name):
+    """``reference/gpt2.py`` and ``reference/mistral.py`` against
+    ``decoder_lm.py`` as it was before the split (kept beside this file as
+    the oracle), on the tiny cells' own weights and first batch: as they
+    are, with a block fewer, and in bfloat16."""
+    import jax.numpy as jnp
+
+    from benchmark import reference
+    from benchmark.tests import old_decoder_lm
+
+    root = tiny.make_root(tmp_path)
+    cell = manifest.resolve_cell(manifest.load_manifest(root), cell_name,
+                                 root)
+    weights, tokens, labels = check.first_batch_and_weights(
+        manifest.train_argv(cell, 7, root))
+    family = cell.config["reference"]["family"]
+    for kw in ({}, {"layers": 1}, {"dtype": jnp.bfloat16}):
+        assert reference.mean_loss(
+            family, weights, cell.config, tokens, labels, **kw
+        ) == old_decoder_lm.mean_loss(
+            family, weights, cell.config, tokens, labels, **kw), kw
+
+
 def test_reference_comparison_fails_for_a_dropped_block(tmp_path):
     root = tiny.make_root(tmp_path)
     man = manifest.load_manifest(root)
@@ -95,6 +179,32 @@ def test_judge_names_each_failed_condition():
         assert not v["correct"] and not v["checks"][failed], (change, v)
     assert not check.judge(good, reference=5.5,
                            tolerance=0.01)["checks"]["step0_matches_reference"]
+
+
+def test_judge_takes_cores_and_mosaic_calls_from_the_configuration():
+    """A hybrid stack: its configuration's file allows a second core beside
+    flash and states the Mosaic calls a layer that its step has to hold."""
+    hybrid = {"losses": [5.0] * 8, "window_losses": [4.9] * 5, "rc": 18,
+              "signalled": True, "raised": None,
+              "attention_cores": ["flash", "window"] * 2,
+              "mosaic_custom_calls": 8,
+              "compile": {"window": {"cache_writes": 0,
+                                     "backend_compiles": 0}}}
+    judge = lambda facts, expects: check.judge(
+        facts, reference=5.0, tolerance=0.01,
+        expects=expects)["checks"]["flash_core_everywhere"]
+    assert not judge(hybrid, None)     # today's rule: flash alone, 3 a layer
+    assert not judge(hybrid, {})
+    two = {"attention_cores": ["flash", "window"],
+           "mosaic_calls_per_layer": 2}
+    assert judge(hybrid, two)
+    assert not judge({**hybrid, "mosaic_custom_calls": 7}, two)
+    assert not judge({**hybrid, "attention_cores": ["flash", "xla"] * 2}, two)
+    assert not judge({**hybrid, "attention_cores": []}, two)
+    assert not judge(hybrid, {"attention_cores": ["flash", "window"]})  # 3 x 4
+    # one key of the two: the other keeps its default
+    assert judge({**hybrid, "attention_cores": ["flash"] * 4},
+                 {"mosaic_calls_per_layer": 2})
 
 
 def test_window_signals_once_at_the_first_sample_past_its_length():

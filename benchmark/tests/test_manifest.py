@@ -71,11 +71,7 @@ def test_a_new_cell_is_new_files_and_new_entries_only(tmp_path):
     root = tiny.make_root(tmp_path)
     man = manifest.load_manifest(root)
     assert manifest.check_manifest(man, root) == []
-    for d in ("configs", "workloads", "layer_metrics"):
-        for f in os.listdir(os.path.join(manifest.ROOT, "benchmark", d)):
-            with open(os.path.join(manifest.ROOT, "benchmark", d, f)) as a, \
-                    open(os.path.join(root, "benchmark", d, f)) as b:
-                assert a.read() == b.read(), f
+    tiny.assert_nothing_that_was_there_is_edited(root)
     cell = manifest.resolve_cell(man, "tiny_mistral_c4", root)
     assert cell.chips == 4 and cell.config["hidden_size"] == 32
     argv = manifest.train_argv(cell, seed=3, root=root)
@@ -108,6 +104,43 @@ def test_a_new_per_layer_metric_is_a_new_file(tmp_path):
     assert readers.read_metric("flash_roofline", facts, root) is None
 
 
+def _unknown_family(root):
+    path = os.path.join(root, "benchmark", "configs", "tiny-mixtral.json")
+    body = manifest.read_json(path)
+    body["reference"]["family"] = "never_written"
+    with open(path, "w") as f:
+        json.dump(body, f)
+
+
+def _roofline_cost_nowhere(root):
+    path = manifest.layer_metric_path(root, "router_roofline")
+    body = manifest.read_json(path)
+    del body["reader"]["file"]
+    with open(path, "w") as f:
+        json.dump(body, f)
+
+
+@pytest.mark.parametrize("how,says", [
+    (_unknown_family, "reference family 'never_written' has no file "
+                      "benchmark/reference/never_written.py"),
+    (lambda root: os.remove(os.path.join(
+        root, "benchmark", "layer_metrics", "router_cost.py")),
+     "router_roofline: its reader names 'router_cost.py'"),
+    (_roofline_cost_nowhere, "cost 'router_step_cost' is no function of "
+                             "benchmark/flops.py"),
+])
+def test_manifest_check_names_a_missing_family_or_cost_file(
+        tmp_path, how, says):
+    """What a new architecture brings is found by name, and what is not
+    there is named before any chip time is spent."""
+    root = tiny.make_root(tmp_path)
+    tiny.add_new_family(root)
+    assert manifest.check_manifest(manifest.load_manifest(root), root) == []
+    how(root)
+    problems = manifest.check_manifest(manifest.load_manifest(root), root)
+    assert any(says in p for p in problems), problems
+
+
 def test_peaks_raise_for_an_unknown_chip():
     from benchmark import peaks
 
@@ -132,10 +165,69 @@ def test_flops_count_attention_causal_and_no_recomputation():
     assert flops.train_flops_per_token(m) / 1e9 == pytest.approx(12.06, abs=0.01)
     # 100 % MFU at the rate that needs exactly the peak
     rate = 4 * 197e12 / flops.train_flops_per_token(m)
-    assert flops.mfu_pct(rate, m, 4, 197e12) == pytest.approx(100.0)
+    assert flops.mfu_pct(rate, flops.train_flops_per_token(m), 4,
+                         197e12) == pytest.approx(100.0)
     cost = flops.flash_step_cost(g, sequences=16)
     assert cost["flops"] == pytest.approx(
         16 * 7 * 2 * 25 * 64 * 16 * 1024 * 512.5)
     assert flops.roofline_least_s(cost, {"bf16_flops_per_s": 197e12,
                                          "hbm_bytes_per_s": 819e9}
                                   )["bound"] == "compute"
+
+
+def _model_args(cell):
+    from hetu_galvatron_tpu.core.arguments import args_from_cli
+    from hetu_galvatron_tpu.utils.hf_config_adapter import resolve_model_config
+
+    return resolve_model_config(args_from_cli(
+        manifest.train_argv(cell, seed=0), mode="train_dist")).model
+
+
+@pytest.mark.parametrize("config", ["gpt2-xl", "mistral-7b-d8",
+                                    "mistral-7b-d2"])
+def test_a_dense_family_s_flops_are_the_dense_count_exactly(config):
+    """The count moved from ``run.py`` into the family's file and did not
+    change: ``mfu_pct / tokens_per_s`` of every cell is the constant it was."""
+    from benchmark import flops, reference
+
+    man = manifest.load_manifest()
+    name = next(w["name"] for w in man["workloads"] if w["config"] == config)
+    cell = manifest.resolve_cell(man, name)
+    sizes = flops.Sizes.of(_model_args(cell))
+    family = reference.load_family(cell.config["reference"]["family"])
+    assert flops.train_from_forward(family.forward_flops_per_token(
+        sizes, cell.config)) == flops.train_flops_per_token(sizes)
+    assert sizes.experts == 0
+
+
+def test_the_dense_count_refuses_a_program_with_experts():
+    import dataclasses
+
+    from benchmark import flops
+
+    m = flops.Sizes(layers=1, hidden=2048, heads=16, kv_heads=16,
+                    head_dim=128, ffn=1024, ffn_matrices=3, vocab=50304,
+                    seq=4096)
+    flops.forward_flops_per_token(m)
+    with pytest.raises(ValueError, match="64 experts a layer"):
+        flops.forward_flops_per_token(dataclasses.replace(m, experts=64))
+
+
+@pytest.mark.parametrize("config,key", [
+    ({"reference": {}, "reduced_from": {"n_layer": 48}}, "n_layer"),
+    ({"reference": {"depth_key": "num_hidden_layers"},
+      "reduced_from": {"num_hidden_layers": 16, "num_experts": 64}},
+     "num_hidden_layers"),
+    ({"reference": {}, "reduced_from": {"num_hidden_layers": 16,
+                                        "num_experts": 64}}, None),
+    ({"reference": {}}, None),
+])
+def test_reference_variants_take_the_depth_key_from_the_configuration(
+        config, key):
+    from benchmark import reference_variants
+
+    if key is None:
+        with pytest.raises(SystemExit, match="reference.depth_key"):
+            reference_variants.depth_key(config)
+    else:
+        assert reference_variants.depth_key(config) == key

@@ -53,13 +53,17 @@ def test_breakdown_names_the_kernels_and_classes_the_gaps(trace):
                for name, _ in gaps)
 
 
-def test_the_flash_readers_find_the_kernels_by_the_pattern_in_their_files(
-        trace):
+def _facts_of_the_run(trace):
     sizes = flops.Sizes(layers=2, hidden=4096, heads=32, kv_heads=8,
                         head_dim=128, ffn=14336, ffn_matrices=3,
                         vocab=32000, seq=4096)
-    facts = {"trace": trace, "sizes": sizes, "sequences_per_step": 4,
-             "chips": 1, "peaks": peaks.peaks_of("TPU v5 lite")}
+    return {"trace": trace, "sizes": sizes, "sequences_per_step": 4,
+            "chips": 1, "peaks": peaks.peaks_of("TPU v5 lite")}
+
+
+def test_the_flash_readers_find_the_kernels_by_the_pattern_in_their_files(
+        trace):
+    facts = _facts_of_the_run(trace)
     share = readers.read_metric("flash_time_share_pct", facts)
     roof = readers.read_metric("flash_roofline", facts)
     assert share == pytest.approx(20.13007353663258)
@@ -70,6 +74,38 @@ def test_the_flash_readers_find_the_kernels_by_the_pattern_in_their_files(
         trace["idle_pct"])
     # one chip: no collective runs, and the reader says 0, not nothing
     assert readers.read_metric("collective_ms", facts) == 0.0
+
+
+def test_a_kernel_s_roofline_cost_may_live_in_a_file_beside_its_metric(
+        trace, tmp_path):
+    """``reader.cost`` names a function of the ``file`` beside the metric's
+    JSON: a new kernel brings its operations and bytes and shares the
+    roofline arithmetic."""
+    import json
+
+    from benchmark.tests import tiny
+
+    root = tiny.make_root(tmp_path)
+    d = os.path.join(root, "benchmark", "layer_metrics")
+    with open(os.path.join(d, "flash_again_roofline.json"), "w") as f:
+        json.dump({"what": "the flash kernels, their cost in a file",
+                   "reader": {"kind": "roofline",
+                              "pattern": "^flash_attention",
+                              "cost": "half_of_flash",
+                              "file": "flash_again_cost.py"}}, f)
+    with open(os.path.join(d, "flash_again_cost.py"), "w") as f:
+        f.write("from benchmark import flops\n\n\n"
+                "def half_of_flash(sizes, sequences):\n"
+                "    c = flops.flash_step_cost(sizes, sequences)\n"
+                "    return {k: v / 2 for k, v in c.items()}\n")
+    facts = _facts_of_the_run(trace)
+    assert readers.read_metric("flash_again_roofline", facts, root) == \
+        pytest.approx(readers.read_metric("flash_roofline", facts, root) / 2)
+    assert facts["roofline_bounds"] == {"half_of_flash": "compute",
+                                        "flash_step_cost": "compute"}
+    # nothing of that name ran: nothing to read
+    tiny.add_new_family(root)
+    assert readers.read_metric("router_roofline", facts, root) is None
 
 
 def test_async_operations_are_kept_apart_from_the_core_s_operations(trace):
