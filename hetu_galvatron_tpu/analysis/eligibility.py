@@ -22,6 +22,7 @@ them — so adapters must not rephrase.
 
 from __future__ import annotations
 
+import math
 from typing import Any, List, Optional, Sequence
 
 # ---------------------------------------------------------------------------
@@ -39,6 +40,7 @@ def compiled_schedule_unsupported_reason(
     pp_division: Sequence[int] = (),
     uniform_strategies: bool = True,
     packed_docs: bool = False,
+    qk_norm: bool = False,
 ) -> Optional[str]:
     """None when the compiled 1F1B schedule can express a plan with these
     properties; otherwise the human-readable reason every caller logs.
@@ -67,6 +69,9 @@ def compiled_schedule_unsupported_reason(
         return "heterogeneous per-layer strategies"
     if packed_docs:
         return "packed-document position/segment fields"
+    if qk_norm:
+        return ("q/k norm (model.qk_norm): the stage-stacked attention "
+                "has no norm between the qkv product and RoPE")
     return None
 
 
@@ -87,6 +92,7 @@ def compiled_unsupported_reason(cfg: Any, hpc: Any,
         packed_docs=data is not None and (
             getattr(data, "reset_position_ids", False)
             or getattr(data, "reset_attention_mask", False)),
+        qk_norm=bool(getattr(cfg, "qk_norm", False)),
     )
 
 
@@ -435,3 +441,48 @@ def plan_structure_reasons(
         if r is not None:
             out.append(r)
     return out
+
+
+# ---------------------------------------------------------------------------
+# MoE capacity dispatch: do its one-hot tensors fit a chip at all
+# ---------------------------------------------------------------------------
+
+
+def moe_capacity_of(tokens: int, topk: int, experts: int,
+                    capacity_factor: float) -> int:
+    """Rows an expert's capacity buffer holds for ``tokens`` routed tokens
+    (``models/moe.py::moe_capacity``)."""
+    return max(int(math.ceil(tokens * topk / experts * capacity_factor)),
+               topk)
+
+
+def capacity_dispatch_reason(
+    *,
+    dispatcher: str,
+    tokens: int,
+    topk: int,
+    experts: int,
+    capacity_factor: float,
+    devices: int,
+    hbm_gb: float,
+) -> Optional[str]:
+    """None when the GShard ``capacity`` dispatcher can hold its position
+    one-hot ``[T*K, E, C]`` float32 (``models/moe.py::_capacity_dispatch``)
+    for a microbatch of ``tokens`` tokens, even spread evenly over the
+    ``devices`` chips of a stage, within ``hbm_gb`` GB a chip; otherwise the
+    reason, which names the dispatcher that has no such tensor. It grows
+    with T squared: 5.4 GB at 4096 tokens, 64 experts, top-8."""
+    if dispatcher != "capacity" or not experts:
+        return None
+    cap = moe_capacity_of(tokens, topk, experts, capacity_factor)
+    total_gb = tokens * topk * experts * cap * 4 / 1e9
+    chips = max(devices, 1)
+    if total_gb / chips <= hbm_gb:
+        return None
+    return (f"moe_dispatcher=capacity builds a [T*K, E, C] float32 one-hot "
+            f"of {tokens} x {topk} x {experts} x {cap} x 4 B = "
+            f"{total_gb:.1f} GB a microbatch ({total_gb / chips:.1f} GB a "
+            f"chip over {chips}), over the {hbm_gb:g} GB a chip holds: set "
+            "model.moe_dispatcher=dropless (sorted grouped matmuls, no "
+            "capacity buffer), or lower the tokens a microbatch "
+            "(parallel.chunks)")

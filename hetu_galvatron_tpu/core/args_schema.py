@@ -95,6 +95,18 @@ class ModelArgs(BaseModel):
     moe_router_type: Literal["topk", "sinkhorn"] = "topk"
     moe_router_enable_expert_bias: bool = False
     moe_expert_bias_update_rate: float = 1e-3
+    # HF ``norm_topk_prob``: True divides the k chosen router probabilities
+    # by their sum (Mixtral); False combines with the raw softmax values,
+    # which sum to less than one (OLMoE)
+    moe_norm_topk_prob: bool = True
+    # which public names an expert layer is exported / imported under:
+    # "mixtral" = block_sparse_moe.gate / experts.{e}.w1,w3,w2; "olmoe" =
+    # mlp.gate / mlp.experts.{e}.{gate,up,down}_proj
+    moe_hf_layout: Literal["mixtral", "olmoe"] = "mixtral"
+    # RMSNorm over the WHOLE projected q and k widths (all heads together,
+    # one learned scale each), after the qkv product and before the split
+    # into heads and RoPE (OLMoE; HF ``self_attn.{q,k}_norm``)
+    qk_norm: bool = False
 
     @property
     def kv_heads(self) -> int:

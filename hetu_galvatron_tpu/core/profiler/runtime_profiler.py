@@ -217,7 +217,8 @@ class RuntimeProfiler:
             # per-layer balance tracker (reference moe_utils.py:608-644
             # track_moe_metrics log lines): aux/z-loss per MoE layer plus
             # the tokens-per-expert imbalance max/mean; the converted
-            # scalars also land in the registry as moe/* gauges
+            # scalars also land in the registry as moe/* gauges, with the
+            # most loaded expert's rows (the longest grouped matmul)
             for name in sorted(metrics["moe"]):
                 st = metrics["moe"][name]
                 tpe = np.asarray(st["tokens_per_expert"], dtype=float)
@@ -229,6 +230,8 @@ class RuntimeProfiler:
                 self.registry.gauge("moe/aux_loss", layer=name).set(aux)
                 self.registry.gauge("moe/z_loss", layer=name).set(z)
                 self.registry.gauge("moe/imbalance", layer=name).set(imb)
+                self.registry.gauge("moe/rows_per_expert", layer=name,
+                                    stat="max").set(float(tpe.max()))
         line = " | ".join(bits)
         print(line, flush=True)
         return line

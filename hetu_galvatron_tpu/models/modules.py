@@ -221,6 +221,17 @@ def init_attention(key: jax.Array, cfg: ModelArgs) -> Tuple[Params, Axes]:
     if cfg.add_bias_linear:
         p["bo"] = jnp.zeros((h,), jnp.float32)
         a["bo"] = ("embed",)
+    if cfg.qk_norm:
+        if cfg.normalization != "rmsnorm" or cfg.norm_zero_centered:
+            raise ValueError("model.qk_norm is an RMSNorm with plain scales "
+                             "(normalization=rmsnorm, norm_zero_centered "
+                             "off), applied by apply_norm")
+        # one scale over the whole projected width each; replicated (the
+        # axis name is none of mesh.py's sharded ones), so under tp the
+        # mean over the sharded width is GSPMD's all-reduce
+        for name, width in (("q_norm", nq * hd), ("k_norm", nkv * hd)):
+            p[name] = {"scale": jnp.ones((width,), jnp.float32)}
+            a[name] = {"scale": ("qk_norm",)}
     return p, a
 
 
@@ -341,6 +352,10 @@ def apply_attention(
         qkv = qkv + p["bqkv"]
     qkv = qkv.astype(compute_dtype)
     q, k, v = jnp.split(qkv, [nq * hd, (nq + nkv) * hd], axis=-1)
+    if "q_norm" in p:
+        with jax.named_scope("attn/qk_norm"):
+            q = apply_norm(p["q_norm"], q, cfg)
+            k = apply_norm(p["k_norm"], k, cfg)
     q = q.reshape(B, S, nq, hd)
     k = k.reshape(B, S, nkv, hd)
     v = v.reshape(B, S, nkv, hd)

@@ -35,6 +35,7 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from hetu_galvatron_tpu.analysis.eligibility import moe_capacity_of
 from hetu_galvatron_tpu.core.args_schema import ModelArgs
 from hetu_galvatron_tpu.models import modules as M
 
@@ -55,8 +56,7 @@ def moe_capacity(cfg: ModelArgs, tokens: int,
     """Per-expert token capacity (reference capacity-factor dispatch)."""
     cf = capacity_factor if capacity_factor is not None \
         else cfg.moe_capacity_factor
-    return max(int(math.ceil(tokens * cfg.moe_topk / cfg.num_experts
-                             * cf)), cfg.moe_topk)
+    return moe_capacity_of(tokens, cfg.moe_topk, cfg.num_experts, cf)
 
 
 def sinkhorn(logits: jax.Array, n_iters: int = 8) -> jax.Array:
@@ -89,7 +89,8 @@ def route_tokens(
     topk: softmax probs; selection optionally corrected by a no-grad expert
     bias (p["expert_bias"], reference moe_router_enable_expert_bias — the
     bias steers WHICH experts are picked, never the combine weights);
-    weights renormalized over the selected k (HF Mixtral convention).
+    weights renormalized over the selected k (HF Mixtral convention)
+    unless ``cfg.moe_norm_topk_prob`` is off (HF OLMoE).
     sinkhorn: selection from a no-grad sinkhorn normalization; weights are
     sigmoid (k=1) / softmax (k>1) of the raw logits (reference
     sinkhorn_load_balancing; aux loss unsupported there)."""
@@ -144,10 +145,13 @@ def route_tokens(
         term = jnp.sum(jax.lax.stop_gradient(-update) * p["expert_bias"])
         bias_term = term - jax.lax.stop_gradient(term)
     topk_probs = jnp.take_along_axis(probs, topk_idx, axis=-1)
-    # renormalize over the selected k (HF Mixtral convention; the reference's
-    # moe_router_topk_scaling path covers the same role)
-    topk_probs = topk_probs / jnp.maximum(
-        jnp.sum(topk_probs, axis=-1, keepdims=True), 1e-9)
+    if cfg.moe_norm_topk_prob:
+        # renormalize over the selected k (HF Mixtral convention; the
+        # reference's moe_router_topk_scaling path covers the same role).
+        # Off (HF OLMoE, norm_topk_prob false) the raw softmax values
+        # combine, and a token's weights sum to less than one
+        topk_probs = topk_probs / jnp.maximum(
+            jnp.sum(topk_probs, axis=-1, keepdims=True), 1e-9)
 
     # aux losses (reference router.py aux/z-loss; moe_utils.py:166 scaling)
     sel = jax.nn.one_hot(topk_idx, E, dtype=jnp.float32)  # [T, K, E]
@@ -273,22 +277,25 @@ def _dropless_dispatch(
     HF Mixtral numerics exact."""
     T, H = xt.shape
     E, K = cfg.num_experts, cfg.moe_topk
-    eid = topk_idx.reshape(T * K)
-    order = jnp.argsort(eid, stable=True)
-    tok = jnp.arange(T * K, dtype=jnp.int32) // K  # slot -> token
-    tok_sorted = tok[order]
-    xs = xt[tok_sorted].astype(compute_dtype)  # [T*K, H]
-    group_sizes = jnp.bincount(eid, length=E).astype(jnp.int32)
-    hproj = jax.lax.ragged_dot(xs, p["win"].astype(compute_dtype),
-                               group_sizes,
-                               preferred_element_type=jnp.float32)
-    hproj = _expert_act(hproj, cfg, compute_dtype)
-    ys = jax.lax.ragged_dot(hproj, p["wout"].astype(compute_dtype),
-                            group_sizes,
-                            preferred_element_type=jnp.float32)
-    ws = w.reshape(T * K)[order]
-    return jnp.zeros((T, H), jnp.float32).at[tok_sorted].add(
-        ys * ws[:, None])
+    with jax.named_scope("moe/dispatch"):
+        eid = topk_idx.reshape(T * K)
+        order = jnp.argsort(eid, stable=True)
+        tok = jnp.arange(T * K, dtype=jnp.int32) // K  # slot -> token
+        tok_sorted = tok[order]
+        xs = xt[tok_sorted].astype(compute_dtype)  # [T*K, H]
+        group_sizes = jnp.bincount(eid, length=E).astype(jnp.int32)
+    with jax.named_scope("moe/experts"):
+        hproj = jax.lax.ragged_dot(xs, p["win"].astype(compute_dtype),
+                                   group_sizes,
+                                   preferred_element_type=jnp.float32)
+        hproj = _expert_act(hproj, cfg, compute_dtype)
+        ys = jax.lax.ragged_dot(hproj, p["wout"].astype(compute_dtype),
+                                group_sizes,
+                                preferred_element_type=jnp.float32)
+    with jax.named_scope("moe/combine"):
+        ws = w.reshape(T * K)[order]
+        return jnp.zeros((T, H), jnp.float32).at[tok_sorted].add(
+            ys * ws[:, None])
 
 
 def apply_moe_mlp(
@@ -306,7 +313,8 @@ def apply_moe_mlp(
     """
     B, S, H = x.shape
     xt = x.reshape(B * S, H)
-    topk_idx, w, aux, stats = route_tokens(p, xt, cfg, compute_dtype)
+    with jax.named_scope("moe/route"):
+        topk_idx, w, aux, stats = route_tokens(p, xt, cfg, compute_dtype)
     if cfg.moe_dispatcher == "dropless":
         y = _dropless_dispatch(p, xt, topk_idx, w, cfg, compute_dtype)
     else:

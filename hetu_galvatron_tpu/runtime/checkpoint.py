@@ -870,6 +870,20 @@ class CheckpointCadence:
 # ---------------------------------------------------------------------------
 
 
+# public names of an expert layer, by ``cfg.moe_hf_layout``: the router, and
+# one expert's gate, up and down projections
+_MOE_HF_NAMES = {
+    "mixtral": ("block_sparse_moe.gate.weight",
+                "block_sparse_moe.experts.{e}.w1.weight",
+                "block_sparse_moe.experts.{e}.w3.weight",
+                "block_sparse_moe.experts.{e}.w2.weight"),
+    "olmoe": ("mlp.gate.weight",
+              "mlp.experts.{e}.gate_proj.weight",
+              "mlp.experts.{e}.up_proj.weight",
+              "mlp.experts.{e}.down_proj.weight"),
+}
+
+
 def hf_to_params(state_dict: Dict[str, Any], cfg: ModelArgs) -> Params:
     """HF torch state dict -> our params pytree (reference h2g converters,
     tools/checkpoint_convert_h2g.py + llama_adapter.py:51-163). Supports the
@@ -928,6 +942,7 @@ def hf_to_params(state_dict: Dict[str, Any], cfg: ModelArgs) -> Params:
     def lin(name):
         return sd[name].T
 
+    router, gate, up, down = _MOE_HF_NAMES[cfg.moe_hf_layout]
     layers = []
     for i in range(n):
         pre = f"model.layers.{i}."
@@ -940,33 +955,35 @@ def hf_to_params(state_dict: Dict[str, Any], cfg: ModelArgs) -> Params:
             "attn": {"wqkv": wqkv, "wo": lin(pre + "self_attn.o_proj.weight")},
             "ln2": {"scale": sd[pre + "post_attention_layernorm.weight"]},
         }
-        if pre + "block_sparse_moe.gate.weight" in sd:
-            # mixtral-style MoE FFN (reference moe_adapter.py:58-266):
-            # experts.{e}.w1/w3 fuse into win [E, H, 2F], w2 -> wout [E, F, H]
+        if cfg.qk_norm:
+            lp["attn"]["q_norm"] = {
+                "scale": sd[pre + "self_attn.q_norm.weight"]}
+            lp["attn"]["k_norm"] = {
+                "scale": sd[pre + "self_attn.k_norm.weight"]}
+        if pre + router in sd:
+            # MoE FFN (reference moe_adapter.py:58-266): each expert's gate
+            # and up fuse into win [E, H, 2F], down -> wout [E, F, H]; the
+            # names are the layout's that cfg.moe_hf_layout says
             if cfg.num_shared_experts:
                 raise NotImplementedError(
-                    "the Mixtral HF layout has no shared-expert slot; "
+                    f"the {cfg.moe_hf_layout} HF layout "
+                    f"({pre}{router}) has no shared-expert slot; "
                     "import with num_shared_experts=0")
             E = 0
-            while pre + f"block_sparse_moe.experts.{E}.w1.weight" in sd:
+            while pre + gate.format(e=E) in sd:
                 E += 1
             if E != cfg.num_experts:
                 raise ValueError(
                     f"layer {i}: checkpoint has {E} experts but "
                     f"cfg.num_experts is {cfg.num_experts}")
-            win = np.stack([
-                np.concatenate(
-                    [lin(pre + f"block_sparse_moe.experts.{e}.w1.weight"),
-                     lin(pre + f"block_sparse_moe.experts.{e}.w3.weight")],
-                    axis=1)
-                for e in range(E)])
-            wout = np.stack([
-                lin(pre + f"block_sparse_moe.experts.{e}.w2.weight")
-                for e in range(E)])
             lp["moe"] = {
-                "router": lin(pre + "block_sparse_moe.gate.weight"),
-                "win": win,
-                "wout": wout,
+                "router": lin(pre + router),
+                "win": np.stack([
+                    np.concatenate([lin(pre + gate.format(e=e)),
+                                    lin(pre + up.format(e=e))], axis=1)
+                    for e in range(E)]),
+                "wout": np.stack([lin(pre + down.format(e=e))
+                                  for e in range(E)]),
             }
         else:
             win = np.concatenate(
@@ -1295,6 +1312,7 @@ def params_to_hf(params: Params, cfg: ModelArgs) -> Dict[str, np.ndarray]:
 
     sd["model.embed_tokens.weight"] = get(params["embed"]["wte"])[:V]
     hd, nq, nkv = cfg.head_dim, cfg.num_attention_heads, cfg.kv_heads
+    router, e_gate, e_up, e_down = _MOE_HF_NAMES[cfg.moe_hf_layout]
     for i, lp in enumerate(params["layers"]):
         pre = f"model.layers.{i}."
         wqkv = get(lp["attn"]["wqkv"])
@@ -1309,21 +1327,25 @@ def params_to_hf(params: Params, cfg: ModelArgs) -> Dict[str, np.ndarray]:
             sd[pre + "self_attn.q_proj.bias"] = bq
             sd[pre + "self_attn.k_proj.bias"] = bk
             sd[pre + "self_attn.v_proj.bias"] = bv
+        if "q_norm" in lp["attn"]:
+            sd[pre + "self_attn.q_norm.weight"] = \
+                get(lp["attn"]["q_norm"]["scale"])
+            sd[pre + "self_attn.k_norm.weight"] = \
+                get(lp["attn"]["k_norm"]["scale"])
         if "moe" in lp:
             if "shared" in lp["moe"]:
                 raise NotImplementedError(
-                    "the Mixtral HF layout has no shared-expert slot; "
+                    f"the {cfg.moe_hf_layout} HF layout "
+                    f"({pre}{router}) has no shared-expert slot; "
                     "export models with num_shared_experts=0")
-            sd[pre + "block_sparse_moe.gate.weight"] = \
-                get(lp["moe"]["router"]).T
+            sd[pre + router] = get(lp["moe"]["router"]).T
             win = get(lp["moe"]["win"])
             wout = get(lp["moe"]["wout"])
             for e in range(win.shape[0]):
-                w1, w3 = np.split(win[e], 2, axis=1)
-                sd[pre + f"block_sparse_moe.experts.{e}.w1.weight"] = w1.T
-                sd[pre + f"block_sparse_moe.experts.{e}.w3.weight"] = w3.T
-                sd[pre + f"block_sparse_moe.experts.{e}.w2.weight"] = \
-                    wout[e].T
+                w_gate, w_up = np.split(win[e], 2, axis=1)
+                sd[pre + e_gate.format(e=e)] = w_gate.T
+                sd[pre + e_up.format(e=e)] = w_up.T
+                sd[pre + e_down.format(e=e)] = wout[e].T
         else:
             win = get(lp["mlp"]["win"])
             gate, up = np.split(win, 2, axis=1)

@@ -202,7 +202,14 @@ def get_hybrid_parallel_config(
             eligibility.pp_division_sum_reason(pp_division, n_layers),
             eligibility.pp_division_len_reason(pp_division, pp_deg, vpp),
             eligibility.batch_grain_reason(global_bsz, world_size, pp_deg,
-                                           layers, vocab)):
+                                           layers, vocab),
+            eligibility.capacity_dispatch_reason(
+                dispatcher=args.model.moe_dispatcher,
+                tokens=global_bsz // chunks * args.model.seq_length,
+                topk=args.model.moe_topk, experts=args.model.num_experts,
+                capacity_factor=args.model.moe_capacity_factor,
+                devices=world_size // pp_deg,
+                hbm_gb=args.search.memory_constraint)):
         if reason is not None:
             raise ValueError(reason)
     cp_zigzag = bool(getattr(args.parallel, "cp_zigzag", False))

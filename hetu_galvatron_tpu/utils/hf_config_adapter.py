@@ -3,7 +3,7 @@
 Capability parity with the reference's hf_config_adapter
 (utils/hf_config_adapter.py:196-393): populate our :class:`ModelArgs` from a HF
 `AutoConfig` (or a plain dict of HF-style keys), auto-detecting norm type,
-activation, rope, and GQA for llama/gpt2/qwen2/mistral/mixtral families, and
+activation, rope, and GQA for llama/gpt2/qwen2/mistral/mixtral/olmoe families, and
 expose `model_layer_configs`/`model_name` helpers for the profiler and search
 engine.
 """
@@ -34,10 +34,11 @@ _FIELD_MAP = {
 }
 
 _GEMMA_FAMILIES = {"gemma"}
-_ROPE_FAMILIES = {"llama", "qwen2", "mistral", "mixtral",
+_ROPE_FAMILIES = {"llama", "qwen2", "mistral", "mixtral", "olmoe",
                   "qwen"} | _GEMMA_FAMILIES
 _RMS_FAMILIES = _ROPE_FAMILIES | {"t5"}
-_SWIGLU_FAMILIES = {"llama", "qwen2", "mistral", "mixtral", "qwen"}
+_SWIGLU_FAMILIES = {"llama", "qwen2", "mistral", "mixtral", "olmoe",
+                    "qwen"}
 # gemma-2/3 add sandwich norms, logit softcapping, query_pre_attn_scalar,
 # alternating sliding windows (v3: q/k-norm, dual rope) — none of which this
 # stack implements; mapping them through gemma-1 numerics would silently
@@ -88,6 +89,23 @@ def populate_model_args_from_hf(
         values["hidden_act"] = "geglu"
         values["norm_zero_centered"] = True
         values["scale_embeddings"] = True
+    if family == "olmoe":
+        # OLMoE (Muennighoff et al., arXiv:2409.02060; HF modeling_olmoe):
+        # ``intermediate_size`` is the width of ONE expert (the shared field
+        # map reads it as ffn_hidden_size, which an expert layer uses when
+        # moe_ffn_hidden_size is unset); RMSNorm over the whole q and k
+        # widths; top-k router weights renormalised only if the config says
+        # so; its own expert names in a checkpoint
+        if d.get("clip_qkv") is not None:
+            raise NotImplementedError(
+                f"olmoe clip_qkv={d['clip_qkv']!r}: clamping the q/k/v "
+                "projections is not implemented; refusing rather than "
+                "producing silently-wrong numerics")
+        values["qk_norm"] = True
+        values["moe_hf_layout"] = "olmoe"
+        values["moe_norm_topk_prob"] = bool(d.get("norm_topk_prob", False))
+        if d.get("router_aux_loss_coef") is not None:
+            values["moe_aux_loss_coeff"] = float(d["router_aux_loss_coef"])
     if family == "bert":
         # HF bert uses erf gelu everywhere (BertIntermediate + the MLM
         # transform); our "gelu" is the tanh approximation (gpt2's gelu_new)

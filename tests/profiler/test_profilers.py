@@ -148,6 +148,9 @@ def test_iteration_log_consistent_and_sync_free(capsys):
     # ...and the converted stats land in the metrics registry
     assert prof.registry.gauge("moe/aux_loss", layer="layer1").value == 0.5
     assert prof.registry.gauge("moe/imbalance", layer="layer1").value == 1.5
+    # the most loaded expert's rows: the longest of the grouped matmuls
+    assert prof.registry.gauge("moe/rows_per_expert", layer="layer1",
+                               stat="max").value == 3.0
 
 
 def test_runtime_profiler_routes_registry(tmp_path):
