@@ -654,9 +654,7 @@ def train(args) -> Dict[str, Any]:
     drop_key = jax.random.key(args.train.seed) if use_dropout else None
     # what the compiled step contains (filled after the first step)
     from hetu_galvatron_tpu.observability.registry import get_registry
-    from hetu_galvatron_tpu.observability.trace_analysis import (
-        mosaic_calls_in,
-    )
+    from hetu_galvatron_tpu.observability.trace_analysis import hlo_counts
 
     step_report: Dict[str, Any] = {}
     it_box = [0]  # the iteration run_loop is in, for the spans below
@@ -1083,13 +1081,23 @@ def train(args) -> Dict[str, Any]:
                 # step/static_bytes{part=...} gauges
                 with span("setup/step_report"):
                     compiled = fn.lower(out[0], out[1], b).compile()
-                    step_report["mosaic_custom_calls"] = mosaic_calls_in(
-                        compiled)
+                    # (as_text: 0.2 s on four chips)
+                    step_report.update(hlo_counts(compiled.as_text()))
                     step_report["static_memory"] = compiled_memory_bytes(
                         compiled)
                     for part, v in step_report["static_memory"].items():
                         get_registry().gauge("step/static_bytes",
                                              part=part).set(v)
+                    for op, n in step_report["collectives"].items():
+                        get_registry().gauge("step/collectives",
+                                             op=op).set(n)
+                state.log("step report: " + ", ".join(
+                    f"{n} {op}" for op, n
+                    in step_report["collectives"].items())
+                    + f", {step_report['mosaic_custom_calls']} Mosaic calls,"
+                    f" static live peak "
+                    f"{step_report['static_memory']['live_peak'] / 2**30:.3f}"
+                    " GiB")
             return out
 
         if valid_iter is not None or test_iter is not None:
@@ -1138,6 +1146,9 @@ def train(args) -> Dict[str, Any]:
             # XLA's static memory of the compiled pp=1 step, per device, in
             # bytes (the step/static_bytes gauges); None for the pp engines
             "static_memory": step_report.get("static_memory"),
+            # collective instructions in that step's HLO, by opcode (the
+            # step/collectives gauges); None for the pp engines
+            "collectives": step_report.get("collectives"),
             "exit_code": exit_code}
 
 

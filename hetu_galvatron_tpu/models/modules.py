@@ -210,6 +210,7 @@ def init_attention(key: jax.Array, cfg: ModelArgs) -> Tuple[Params, Axes]:
     k1, k2 = jax.random.split(key)
     std = 0.02
     # fused qkv: one MXU matmul; layout [q | k | v] along the wide axis
+    # (a tp > 1 layer computes on qkv_group_major's view of it)
     p: Params = {
         "wqkv": _normal(k1, (h, (nq + 2 * nkv) * hd), std),
         "wo": _normal(k2, (nq * hd, h), std / math.sqrt(2 * cfg.num_hidden_layers)),
@@ -326,6 +327,21 @@ def xla_sdpa(
     return out.reshape(B, S, N, D).astype(q.dtype)
 
 
+def qkv_group_major(w: jax.Array, cfg: ModelArgs) -> jax.Array:
+    """The fused projection ``[..., q | k | v]`` (weight or bias) as
+    ``[..., nkv, (nq/nkv + 2) * hd]``: each key-value head with the q heads
+    that attend to it (Megatron's group-major order). Sharded over the
+    group axis, the split into q, k and v is local to a tensor-parallel
+    shard; the stored ``[q | k | v]`` order, cut in contiguous halves, puts
+    q's first heads on one chip and k and v on another."""
+    hd, nq, nkv = cfg.head_dim, cfg.num_attention_heads, cfg.kv_heads
+    q, k, v = jnp.split(w, [nq * hd, (nq + nkv) * hd], axis=-1)
+    lead = w.shape[:-1]
+    return jnp.concatenate(
+        [q.reshape(lead + (nkv, nq // nkv * hd)),
+         k.reshape(lead + (nkv, hd)), v.reshape(lead + (nkv, hd))], axis=-1)
+
+
 def apply_attention(
     p: Params,
     x: jax.Array,
@@ -337,21 +353,36 @@ def apply_attention(
     dropout_rng: Optional[jax.Array] = None,
     segment_ids: Optional[jax.Array] = None,
     matmul_fns: Optional[Dict[str, Callable]] = None,
+    shard_fn: Optional[Callable[[jax.Array, int], jax.Array]] = None,
 ) -> jax.Array:
+    """``shard_fn(a, axis)`` (a layer whose plan has tp > 1,
+    parallel/spmd.py::interior_sharding) pins an interior activation to
+    the layer's own shards, dimension ``axis`` on its tp axes. The layer it
+    is given to is given ``wqkv`` / ``bqkv`` as :func:`qkv_group_major`'s
+    view with it, so that nothing between the two projections leaves its
+    shard; without it the leaves are the stored ``[q | k | v]``."""
     B, S, H = x.shape
     hd = cfg.head_dim
     nq, nkv = cfg.num_attention_heads, cfg.kv_heads
     mm = matmul_fns or {}
     w = p["wqkv"].astype(compute_dtype)
+    group_major = shard_fn is not None
     if "qkv" in mm:
         qkv = mm["qkv"](x.astype(compute_dtype), w)
     else:
-        qkv = jnp.einsum("bsh,hf->bsf", x.astype(compute_dtype), w,
+        qkv = jnp.einsum("bsh,hkf->bskf" if group_major else "bsh,hf->bsf",
+                         x.astype(compute_dtype), w,
                          preferred_element_type=jnp.float32)
     if "bqkv" in p:
         qkv = qkv + p["bqkv"]
     qkv = qkv.astype(compute_dtype)
-    q, k, v = jnp.split(qkv, [nq * hd, (nq + nkv) * hd], axis=-1)
+    if group_major:
+        qkv = shard_fn(qkv, 2)
+        g = nq // nkv
+        q, k, v = (a.reshape(B, S, -1) for a in jnp.split(
+            qkv, [g * hd, (g + 1) * hd], axis=-1))
+    else:
+        q, k, v = jnp.split(qkv, [nq * hd, (nq + nkv) * hd], axis=-1)
     if "q_norm" in p:
         with jax.named_scope("attn/qk_norm"):
             q = apply_norm(p["q_norm"], q, cfg)
@@ -359,6 +390,8 @@ def apply_attention(
     q = q.reshape(B, S, nq, hd)
     k = k.reshape(B, S, nkv, hd)
     v = v.reshape(B, S, nkv, hd)
+    if group_major:
+        q, k, v = (shard_fn(a, 2) for a in (q, k, v))
     if rope is not None:
         cos, sin = rope
         q = apply_rope(q, cos, sin)
@@ -399,6 +432,8 @@ def apply_attention(
     else:
         out = sdpa_fn(q, k, v, causal=causal)
     out = out.reshape(B, S, nq * hd)
+    if group_major:
+        out = shard_fn(out, 2)
     wo = p["wo"].astype(compute_dtype)
     if "out" in mm:
         y = mm["out"](out, wo)
@@ -450,9 +485,23 @@ _ACTS = {
 }
 
 
+def gate_up_pairs(w: jax.Array) -> jax.Array:
+    """A gated MLP's fused ``[..., gate | up]`` (weight or bias) as
+    ``[..., 2, F]``: with F sharded, a tensor-parallel shard holds its part
+    of gate AND of up (the stored order, cut in contiguous halves, puts all
+    of gate on one chip and all of up on the other)."""
+    return w.reshape(w.shape[:-1] + (2, w.shape[-1] // 2))
+
+
 def apply_mlp(p: Params, x: jax.Array, cfg: ModelArgs,
               compute_dtype=jnp.bfloat16,
-              matmul_fns: Optional[Dict[str, Callable]] = None) -> jax.Array:
+              matmul_fns: Optional[Dict[str, Callable]] = None,
+              shard_fn: Optional[Callable[[jax.Array, int], jax.Array]] = None
+              ) -> jax.Array:
+    """``shard_fn``: as in :func:`apply_attention`; the layer it is given
+    to also gets a gated ``win`` / ``bin`` as :func:`gate_up_pairs`' view,
+    computes gate and up as ``[B, S, 2, F]`` with F on tp and takes them
+    apart by indexing."""
     act = _ACTS[cfg.hidden_act]
     mm = matmul_fns or {}
     win = p["win"].astype(compute_dtype)
@@ -471,19 +520,26 @@ def apply_mlp(p: Params, x: jax.Array, cfg: ModelArgs,
             up = up + p["bin"][F:]
         hproj = act(gate.astype(compute_dtype)) * up.astype(compute_dtype)
     else:
+        pairs = gated and shard_fn is not None
         if "fc1" in mm:
             hproj = mm["fc1"](x.astype(compute_dtype), win)
         else:
-            hproj = jnp.einsum("bsh,hf->bsf", x.astype(compute_dtype), win,
+            hproj = jnp.einsum("bsh,hgf->bsgf" if pairs else "bsh,hf->bsf",
+                               x.astype(compute_dtype), win,
                                preferred_element_type=jnp.float32)
         if "bin" in p:
             hproj = hproj + p["bin"]
         hproj = hproj.astype(compute_dtype)
-        if gated:
+        if pairs:
+            hproj = shard_fn(hproj, 3)
+            hproj = act(hproj[:, :, 0]) * hproj[:, :, 1]
+        elif gated:
             gate, up = jnp.split(hproj, 2, axis=-1)
             hproj = act(gate) * up
         else:
             hproj = act(hproj)
+        if shard_fn is not None:
+            hproj = shard_fn(hproj, 2)
     wout = p["wout"].astype(compute_dtype)
     if "fc2" in mm:
         y = mm["fc2"](hproj, wout)
@@ -523,6 +579,7 @@ def apply_decoder_layer(
     dropout_rng: Optional[jax.Array] = None,
     segment_ids: Optional[jax.Array] = None,
     matmul_fns: Optional[Dict[str, Callable]] = None,
+    shard_fn: Optional[Callable[[jax.Array, int], jax.Array]] = None,
 ) -> jax.Array:
     """Pre-norm residual block (reference GalvatronDecoderLayer,
     modules.py:233). Encoder families (bert, t5 encoder stack) run the same
@@ -530,7 +587,9 @@ def apply_decoder_layer(
     model family. ``dropout_rng`` enables attention/hidden dropout
     (HF semantics: sublayer output dropped before the residual add).
     ``matmul_fns`` ({"qkv", "out", "fc1", "fc2"}) swaps the projection
-    matmuls for overlapped tensor-parallel impls (ops/overlap.py)."""
+    matmuls for overlapped tensor-parallel impls (ops/overlap.py);
+    ``shard_fn`` keeps a tp > 1 layer's interior on its own shards
+    (:func:`apply_attention`)."""
     if causal is None:
         causal = cfg.model_type != "bert"
     r_attn = r_res1 = r_res2 = None
@@ -550,14 +609,16 @@ def apply_decoder_layer(
                                        compute_dtype=compute_dtype,
                                        causal=causal, dropout_rng=r_attn,
                                        segment_ids=segment_ids,
-                                       matmul_fns=matmul_fns),
+                                       matmul_fns=matmul_fns,
+                                       shard_fn=shard_fn),
                        r_res1),
             cfg)
         return apply_norm(
             p["ln2"],
             x + drop_h(apply_mlp(p["mlp"], x, cfg,
                                  compute_dtype=compute_dtype,
-                                 matmul_fns=matmul_fns), r_res2),
+                                 matmul_fns=matmul_fns,
+                                 shard_fn=shard_fn), r_res2),
             cfg)
     h = apply_norm(p["ln1"], x, cfg)
     x = x + drop_h(apply_attention(p["attn"], h, cfg, rope=rope,
@@ -565,10 +626,11 @@ def apply_decoder_layer(
                                    compute_dtype=compute_dtype, causal=causal,
                                    dropout_rng=r_attn,
                                    segment_ids=segment_ids,
-                                   matmul_fns=matmul_fns), r_res1)
+                                   matmul_fns=matmul_fns,
+                                   shard_fn=shard_fn), r_res1)
     h = apply_norm(p["ln2"], x, cfg)
     x = x + drop_h(apply_mlp(p["mlp"], h, cfg, compute_dtype=compute_dtype,
-                             matmul_fns=matmul_fns),
+                             matmul_fns=matmul_fns, shard_fn=shard_fn),
                    r_res2)
     return x
 

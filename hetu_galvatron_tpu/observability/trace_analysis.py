@@ -452,9 +452,36 @@ def jit_cost_summary(fn: Any, args: Sequence[Any] = (),
         return {}
 
 
-def mosaic_calls_in(compiled: Any) -> int:
-    """Mosaic (Pallas TPU) kernels in a compiled program's optimized HLO."""
-    return compiled.as_text().count('custom_call_target="tpu_custom_call"')
+COLLECTIVE_OPS = ("all-to-all", "all-gather", "all-reduce", "reduce-scatter",
+                  "collective-permute")
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) \(.*?^\}", re.M | re.S)
+
+
+def hlo_counts(hlo_text: str) -> Dict[str, Any]:
+    """What an optimized HLO text holds, counted in one place:
+    ``mosaic_custom_calls``, the Mosaic (Pallas TPU) kernels, and
+    ``collectives``, the collectives by opcode (the gauges
+    ``step/collectives{op=...}``): what GSPMD inserted, a loop body's
+    counted once however often it runs. Each is counted as what it is: an
+    async one once (a ``-start`` / ``-done`` pair, or XLA:TPU's three
+    fusions), and a fused ``all-reduce-scatter``, which is how XLA:TPU runs
+    a reduce-scatter, as that and not as the all-reduce it holds.
+    ``benchmark/aot_check.py`` counts opcodes in the whole text, so its
+    all-gather, all-reduce and collective-permute read higher on a TPU."""
+    counts = dict.fromkeys(COLLECTIVE_OPS, 0)
+    for m in _COMPUTATION.finditer(hlo_text):
+        name, text = m.group(1), m.group(0)
+        if (name.startswith("async_collective_fusion")
+                or 'custom_call_target="AsyncCollectiveDone"' in text):
+            continue    # these two repeat what the starting fusion holds
+        if name.startswith("all-reduce-scatter"):
+            counts["reduce-scatter"] += 1
+            continue
+        for op in COLLECTIVE_OPS:
+            counts[op] += text.count(f" {op}(") + text.count(f" {op}-start(")
+    return {"mosaic_custom_calls": hlo_text.count(
+                'custom_call_target="tpu_custom_call"'),
+            "collectives": counts}
 
 
 def mosaic_custom_calls(fn: Any, args: Sequence[Any]) -> int:
@@ -464,7 +491,8 @@ def mosaic_custom_calls(fn: Any, args: Sequence[Any]) -> int:
     ``.compile()`` return the lowering and executable the call cached.
     Unlike the cost telemetry above it raises on failure — callers use it
     to prove which attention core a step ran."""
-    return mosaic_calls_in(fn.lower(*args).compile())
+    return hlo_counts(
+        fn.lower(*args).compile().as_text())["mosaic_custom_calls"]
 
 
 # one record per (registry, program): keyed on the live registry object so

@@ -233,6 +233,102 @@ def tp_overlap_overrides(
     return out, fallbacks
 
 
+def interior_sharding(
+    per_layer: List[LayerSharding],
+    mesh: Mesh,
+    cfg: ModelArgs,
+    layer_overrides: Dict[int, Dict[str, Any]],
+) -> Tuple[Dict[int, Dict[str, Any]],
+           Optional[Callable[[Params], Params]]]:
+    """Keep a tensor-parallel layer's interior on its own shards, from the
+    first projection to the second. The boundary constraint leaves a layer's
+    hidden state sequence-sharded over tp (Megatron-SP); with nothing said
+    inside, GSPMD keeps the whole interior that way too, because the stored
+    ``[q | k | v]`` cannot be split on a shard, and pays an all-to-all on
+    every activation to get heads for the attention core and back.
+
+    Returns (overrides, param_view). ``overrides[i]["shard_fn"]`` pins an
+    activation of layer i: batch on its dp axes, sequence on its cp axes,
+    the named dimension on its tp axes (modules.apply_attention /
+    apply_mlp call it). ``param_view`` re-lays those layers' fused
+    projections so that a shard computes what it needs from what it holds:
+    qkv group-major with the group axis on tp (modules.qkv_group_major), a
+    gated MLP's gate | up as ``[H, 2, F]`` with F on tp
+    (modules.gate_up_pairs), the biases likewise. The step applies it once,
+    outside the microbatch scan (trainer.make_train_step; the eval step's
+    and the hier_dp lanes' loss apply it themselves); what is stored
+    stays ``[q | k | v]`` and ``[gate | up]``, which on this TPU is the
+    layout a tp = 1 layer's one fused matmul wants (a stored ``[H, 2, F]``
+    is no bitcast of ``[H, 2F]`` under (8, 128) tiling: it cost
+    ``mistral7b_c1_s4k`` 14 % more estimated cycles, AOT, PR 28). Only
+    what the plan says decides: layers with no weight-tp axes (tp = 1,
+    Ulysses), MoE and t5 layers are left as they are, and so are layers
+    whose matmuls the caller replaced (``matmul_fns``): tp_overlap's
+    shard_map kernels are cut for the stored two-axis weights, and the host
+    pipeline engine hands the same kernels to the same layer body with no
+    view (ROADMAP.md speed item 1(h): the kernels take the views, then
+    ``fc1_pair`` goes). With none left ``param_view`` is None."""
+    from dataclasses import replace as _replace
+
+    from hetu_galvatron_tpu.models.modules import (
+        _is_gated,
+        gate_up_pairs,
+        qkv_group_major,
+    )
+    from hetu_galvatron_tpu.models.moe import is_moe_layer
+
+    local = {
+        i: sh for i, sh in enumerate(per_layer)
+        if sh.weight_tp_axes and cfg.model_type != "t5"
+        and not is_moe_layer(cfg, i)
+        and "matmul_fns" not in layer_overrides.get(i, {})}
+    if not local:
+        return {}, None
+
+    def make_shard_fn(sh: LayerSharding):
+        def shard_fn(a: jax.Array, axis: int) -> jax.Array:
+            dims = [sh.dp_axes or None, sh.cp_axes or None]
+            dims += [None] * (a.ndim - 2)
+            dims[axis] = sh.tp_axes
+            return jax.lax.with_sharding_constraint(
+                a, NamedSharding(mesh, P(*dims)))
+        return shard_fn
+
+    gated = _is_gated(cfg.hidden_act)
+
+    def pin(a, sharding, axes):
+        return jax.lax.with_sharding_constraint(
+            a, NamedSharding(mesh, sharding.param_spec(axes)))
+
+    def relaid(sh, leaf, relay, lead, cut, new):
+        """``relay(leaf)`` (the stored ``lead + cut`` axes become ``lead +
+        new``) cut to the tp shard: gathered over tp, re-laid where it
+        stands, then sliced. All-gathers forward and backward, once a step;
+        left to itself GSPMD moves the columns by all-to-all."""
+        whole = _replace(sh, tp_axes=())   # the same plan, tp left out
+        a = relay(pin(leaf, whole, lead + cut))
+        return pin(pin(a, whole, lead + new), sh, lead + new)
+
+    def param_view(params: Params) -> Params:
+        layers = list(params["layers"])
+        for i, sh in local.items():
+            attn, mlp = dict(layers[i]["attn"]), dict(layers[i]["mlp"])
+            for name, lead in (("wqkv", ("embed",)), ("bqkv", ())):
+                if name in attn:
+                    attn[name] = relaid(
+                        sh, attn[name], lambda w: qkv_group_major(w, cfg),
+                        lead, ("qkv",), ("qkv", "width"))
+            for name, lead in (("win", ("embed",)), ("bin", ())):
+                if gated and name in mlp:
+                    mlp[name] = relaid(sh, mlp[name], gate_up_pairs, lead,
+                                       ("mlp",), ("pair", "mlp"))
+            layers[i] = {**layers[i], "attn": attn, "mlp": mlp}
+        return {**params, "layers": tuple(layers)}
+
+    return ({i: {"shard_fn": make_shard_fn(sh)} for i, sh in local.items()},
+            param_view)
+
+
 def make_boundary_fn(
     per_layer: List[LayerSharding],
     vocab: LayerSharding,
@@ -317,11 +413,17 @@ def build_spmd_loss_fn(
     tp_overlap: bool = False,
     lane_dp: bool = False,
     kernel_interpret: bool = False,
+    hoist_view: bool = False,
 ):
     """The plan-lowered loss closure shared by the train and eval steps:
     per-layer shardings, boundary constraints, attention-impl dispatch,
     remat flags, fused CE, and the ZeRO-3 embed use-site constraint.
-    Returns (loss_fn, pspecs, batch_shd, per_layer, vocab, enc_per).
+    Returns (loss_fn, pspecs, batch_shd, per_layer, vocab, enc_per,
+    param_view). ``loss_fn`` takes the stored parameters and re-lays a
+    tp > 1 layer's fused projections itself (:func:`interior_sharding`);
+    with ``hoist_view`` it takes ``param_view(params)`` instead, where
+    ``param_view`` is not None, so that the caller can re-lay them once
+    outside its microbatch scan.
     ``tp_overlap`` swaps eligible Megatron-TP layers' projection matmuls
     for the decomposed ring collectives (:func:`tp_overlap_overrides`);
     ineligible layers silently keep GSPMD — the launcher logs the reasons.
@@ -384,6 +486,13 @@ def build_spmd_loss_fn(
         for i, kw in ring.items():
             merged[i] = {**kw, **merged.get(i, {})}
         layer_overrides = merged
+    # b_layers: under the lane vmap the dp axes are the vmap's, in the
+    # interior's constraints as at the boundaries
+    merged = dict(layer_overrides or {})
+    interior, param_view = interior_sharding(b_layers, mesh, cfg, merged)
+    for i, kw in interior.items():
+        merged[i] = {**kw, **merged.get(i, {})}
+    layer_overrides = merged
     remat = [sh.checkpoint for sh in per_layer]
     enc_remat = [sh.checkpoint for sh in enc_per]
     batch_shd = batch_sharding(per_layer, mesh)
@@ -418,8 +527,11 @@ def build_spmd_loss_fn(
 
     constrain_embed = make_embed_use_constraint(
         axes_tree["embed"], vocab, mesh)
+    view_here = None if hoist_view else param_view
 
     def loss_fn(p, batch):
+        if view_here is not None:
+            p = view_here(p)
         p = {**p, "embed": constrain_embed(p["embed"])}
         return causal_lm_loss(
             p, batch, cfg, compute_dtype=compute_dtype,
@@ -427,7 +539,8 @@ def build_spmd_loss_fn(
             layer_overrides=layer_overrides, boundary_fn=boundary,
             fused_ce=fused_ce, with_moe_stats=with_moe_stats, **enc_kwargs)
 
-    return loss_fn, pspecs, batch_shd, per_layer, vocab, enc_per
+    return (loss_fn, pspecs, batch_shd, per_layer, vocab, enc_per,
+            param_view if hoist_view else None)
 
 
 def make_spmd_eval_step(
@@ -448,7 +561,7 @@ def make_spmd_eval_step(
     if hpc.pp_deg != 1:
         raise ValueError("make_spmd_eval_step is the pp=1 path; use "
                          "PipelineEngine.eval_step for pp>1")
-    loss_fn, pspecs, batch_shd, _, _, _ = build_spmd_loss_fn(
+    loss_fn, pspecs, batch_shd, _, _, _, _ = build_spmd_loss_fn(
         cfg, hpc, mesh, axes_tree, compute_dtype=compute_dtype,
         layer_overrides=layer_overrides, tp_overlap=tp_overlap)
     nshd = jax.tree.map(
@@ -518,12 +631,14 @@ def make_spmd_train_step(
             reason = HIER_KERNEL_REASON  # vocab-parallel CE is a shard_map
         if reason is not None:
             raise ValueError(f"hier_dp unsupported: {reason}")
-    loss_fn, pspecs, batch_shd, per_layer, vocab, enc_per = (
+    loss_fn, pspecs, batch_shd, per_layer, vocab, enc_per, param_view = (
         build_spmd_loss_fn(
             cfg, hpc, mesh, axes_tree, compute_dtype=compute_dtype,
             layer_overrides=layer_overrides, with_moe_stats=moe_stats,
             tp_overlap=tp_overlap, lane_dp=hier_dp,
-            kernel_interpret=kernel_interpret))
+            kernel_interpret=kernel_interpret,
+            # (the lane reducer takes gradients in the stored layout)
+            hoist_view=not hier_dp))
     opt_pspecs = param_specs(axes_tree, per_layer, vocab, opt=True,
                              enc_per_layer=enc_per or None)
     opt_specs = opt_state_specs(tx, params, opt_pspecs)
@@ -556,7 +671,8 @@ def make_spmd_train_step(
                 lambda x: jax.lax.with_sharding_constraint(x, mb_spec), mbs)
 
     step = make_train_step(loss_fn, tx, chunks=chunks, aux_stats=moe_stats,
-                           hier=hier, constrain_microbatches=constrain_mbs)
+                           hier=hier, constrain_microbatches=constrain_mbs,
+                           param_view=param_view)
 
     nshd = lambda tree: jax.tree.map(
         lambda s: NamedSharding(mesh, s), tree,

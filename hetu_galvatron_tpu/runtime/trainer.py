@@ -65,6 +65,7 @@ def make_train_step(
     aux_stats: bool = False,
     hier: Optional[Any] = None,
     constrain_microbatches: Optional[Callable[[Any], Any]] = None,
+    param_view: Optional[Callable[[Any], Any]] = None,
 ) -> Callable:
     """Returns train_step(params, opt_state, batch) -> (params, opt_state,
     metrics). ``chunks`` splits the global batch into microbatches scanned
@@ -97,7 +98,12 @@ def make_train_step(
     The pin makes each microbatch's embed-grad reduce-scatter
     materialize per microbatch in the plan's own layout; the hier path
     has always pinned (``hier.lane_batch``), which is why it was exact
-    where flat drifted."""
+    where flat drifted.
+
+    ``param_view`` (parallel/spmd.py::interior_sharding) maps the stored
+    parameters to the tree ``loss_fn`` takes. It runs once a step, outside
+    the microbatch scan: gradients accumulate in the view's layout and are
+    pulled back through it once, before the norm and the update."""
 
     if hier is not None and aux_stats:
         raise ValueError(
@@ -181,6 +187,9 @@ def make_train_step(
         # reshape must not touch it
         batch = dict(batch)
         rng = batch.pop("dropout_rng", None)
+        stored = params
+        if param_view is not None:
+            params, pull_back = jax.vjp(param_view, stored)
         if hier is not None:
             if rng is not None:
                 raise ValueError(
@@ -224,9 +233,11 @@ def make_train_step(
                 microbatch, zeros, (mbs, weights))
             loss = jnp.sum(wlosses)
             stats = _reduce_stats(stacked, weights) if aux_stats else {}
+        if param_view is not None:
+            grads, = pull_back(grads)
         gnorm = global_grad_norm(grads)
-        updates, new_opt = tx.update(grads, opt_state, params)
-        new_params = optax.apply_updates(params, updates)
+        updates, new_opt = tx.update(grads, opt_state, stored)
+        new_params = optax.apply_updates(stored, updates)
         metrics = {"loss": loss, "grad_norm": gnorm}
         if aux_stats:
             metrics["moe"] = stats

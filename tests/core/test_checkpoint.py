@@ -433,3 +433,57 @@ def test_hf_t5_roundtrip():
     assert len(sd) > 40
     for k, v in sd.items():
         np.testing.assert_allclose(v, ref_sd[k], atol=1e-6, err_msg=k)
+
+
+_EXPORT = dict(hidden_size=32, num_hidden_layers=2, num_attention_heads=4,
+               ffn_hidden_size=48, vocab_size=64, max_position_embeddings=16,
+               seq_length=8, make_vocab_size_divisible_by=1)
+_LLAMA = dict(model_type="llama", normalization="rmsnorm",
+              position_embedding_type="rope", tie_word_embeddings=False,
+              add_bias_linear=False, **_EXPORT)
+EXPORTED = {
+    "gpt2_mha_gelu_biases": ModelArgs(add_qkv_bias=True, **_EXPORT),
+    "llama_mha_swiglu": ModelArgs(hidden_act="swiglu", add_qkv_bias=False,
+                                  **_LLAMA),
+    "llama_gqa_swiglu": ModelArgs(hidden_act="swiglu", add_qkv_bias=False,
+                                  num_key_value_heads=2, **_LLAMA),
+    "llama_gqa_swiglu_qkv_bias": ModelArgs(
+        hidden_act="swiglu", add_qkv_bias=True, num_key_value_heads=2,
+        **_LLAMA),
+    "llama_mqa_geglu": ModelArgs(hidden_act="geglu", add_qkv_bias=False,
+                                 num_key_value_heads=1, **_LLAMA),
+    "bert_mha_gelu_biases": ModelArgs(
+        model_type="bert", post_norm=True, hidden_act="gelu_exact",
+        add_bias_linear=True, add_qkv_bias=True, **_EXPORT),
+    "t5_gated": ModelArgs(
+        model_type="t5", num_encoder_layers=2, hidden_act="geglu",
+        normalization="rmsnorm", position_embedding_type="rope",
+        tie_word_embeddings=False, add_bias_linear=False, add_qkv_bias=False,
+        **_EXPORT),
+    "t5_plain": ModelArgs(
+        model_type="t5", num_encoder_layers=2, hidden_act="relu",
+        normalization="rmsnorm", position_embedding_type="rope",
+        tie_word_embeddings=False, add_bias_linear=False, add_qkv_bias=False,
+        **_EXPORT),
+}
+
+
+@pytest.mark.parametrize("name", list(EXPORTED))
+def test_export_then_import_is_bit_exact(name):
+    """params_to_hf -> hf_to_params gives back every stored leaf, bit for
+    bit, in the layout the layers compute on ([q | k | v], [gate | up]):
+    MHA, GQA and MQA, gated and plain MLPs, with and without biases."""
+    cfg = EXPORTED[name]
+    params, _ = init_causal_lm(jax.random.key(0), cfg)
+    # zero-initialised biases would hide a misplaced one
+    leaves, tree = jax.tree.flatten(params)
+    keys = jax.random.split(jax.random.key(1), len(leaves))
+    params = jax.tree.unflatten(tree, [
+        x + jax.random.normal(k, x.shape) for x, k in zip(leaves, keys)])
+    back = hf_to_params(params_to_hf(params, cfg), cfg)
+    flat = dict(jax.tree_util.tree_leaves_with_path(back))
+    for path, leaf in jax.tree_util.tree_leaves_with_path(params):
+        assert path in flat, jax.tree_util.keystr(path)
+        np.testing.assert_array_equal(
+            np.asarray(leaf), np.asarray(flat[path]),
+            err_msg=jax.tree_util.keystr(path))

@@ -117,6 +117,25 @@ def test_hier_vs_flat_trajectory(tmp_path, cpu_devices, dp_type, chunks):
             err_msg=jax.tree_util.keystr(pa))
 
 
+@pytest.mark.parametrize("dp_type", ["ddp", "zero3"])
+def test_hier_lane_keeps_a_tp_layers_interior_on_its_shards(
+        tmp_path, cpu_devices, dp_type):
+    """The lane loss gets ``spmd.interior_sharding``'s shard_fn and views as
+    the flat loss does (which is why the two trajectories above agree to
+    reassociation): the lane step moves no activation between a layer's two
+    projections. Before PR 28 it held 16 all-to-alls there (``split``, the
+    gated product's ``mul``, their transposes); what is left is the lane
+    batch's own slice, outside the vmapped loss."""
+    from tools.aot_hlo_report import parse_hlo
+
+    step, sp, so, b, _ = _steps(tmp_path, cpu_devices, True, n=1,
+                                dp_type=dp_type)
+    hlo = step.lower(sp, so, b).compile().as_text()
+    moved = [ins["op_name"] for _, instrs in parse_hlo(hlo) for ins in instrs
+             if ins["opcode"].startswith("all-to-all")]
+    assert [name for name in moved if "vmap(" in name] == [], moved
+
+
 @pytest.mark.parametrize("dp_type", ["ddp", "zero2", "zero3"])
 def test_hier_bucketed_matches_monolithic_trajectory(tmp_path, cpu_devices,
                                                      dp_type):

@@ -37,11 +37,23 @@ TINY = [
     "parallel.num_devices=1", "data.dataset=random"]
 
 
+class _RecordingLowered:
+    """A lowering, keeping the optimized HLO of the executable made of it."""
+
+    def __init__(self, lowered, compiled_texts):
+        self.lowered, self.compiled_texts = lowered, compiled_texts
+
+    def compile(self):
+        compiled = self.lowered.compile()
+        self.compiled_texts.append(compiled.as_text())
+        return compiled
+
+
 class _RecordingStep:
     """The jitted step, keeping the text of every lowering made of it."""
 
-    def __init__(self, fn, texts):
-        self.fn, self.texts = fn, texts
+    def __init__(self, fn, texts, compiled_texts):
+        self.fn, self.texts, self.compiled_texts = fn, texts, compiled_texts
 
     def __call__(self, *args):
         return self.fn(*args)
@@ -49,21 +61,22 @@ class _RecordingStep:
     def lower(self, *args):
         lowered = self.fn.lower(*args)
         self.texts.append(lowered.as_text())
-        return lowered
+        return _RecordingLowered(lowered, self.compiled_texts)
 
 
 def _run(extra):
     """One tiny run on a registry of its own; returns (registry, result,
-    the step's lowered text)."""
+    the step's lowered text); the result also holds the optimized HLO of
+    the executable the step report read (``compiled_hlo``)."""
     from hetu_galvatron_tpu.cli import train_dist
     from hetu_galvatron_tpu.parallel import spmd
 
-    texts = []
+    texts, compiled_texts = [], []
     make = spmd.make_spmd_train_step
 
     def recording(*args, **kwargs):
         step, *rest = make(*args, **kwargs)
-        return (_RecordingStep(step, texts), *rest)
+        return (_RecordingStep(step, texts, compiled_texts), *rest)
 
     before = get_registry()
     reg = set_registry(MetricsRegistry())
@@ -78,6 +91,7 @@ def _run(extra):
         set_registry(before)
     assert rc == 0 and len(out["losses"]) == ITERS
     (text,) = texts   # the step report lowers the step once
+    (out["compiled_hlo"],) = compiled_texts
     return reg, out, text
 
 
@@ -183,6 +197,38 @@ def test_static_live_peak_is_the_stated_sum(traced):
                               + m["temporaries"] + m["generated_code"])
     # donated parameters and optimizer state are reused for the outputs
     assert 0 < m["aliased"] <= m["outputs"]
+
+
+# (c') and its collectives --------------------------------------------------
+
+COLLECTIVES = ["all-to-all", "all-gather", "all-reduce", "reduce-scatter",
+               "collective-permute"]
+
+
+@pytest.fixture(scope="module")
+def tp2_run():
+    """The same tiny model on a tp2 x dp2 ZeRO-3 mesh of four CPU devices."""
+    reg, out, _ = _run(["parallel.num_devices=4", "parallel.global_tp_deg=2",
+                        "parallel.default_dp_type=zero3"])
+    return reg, out
+
+
+@pytest.mark.parametrize("op", COLLECTIVES)
+def test_collective_gauge_is_the_count_in_the_steps_hlo(tp2_run, op):
+    reg, out = tp2_run
+    (g,) = [m for m in reg.metrics()
+            if m.name == "step/collectives" and m.labels == {"op": op}]
+    hlo = out["compiled_hlo"]
+    assert g.value == out["collectives"][op] \
+        == hlo.count(f" {op}(") + hlo.count(f" {op}-start(")
+
+
+def test_tp2_step_has_collectives_and_one_chip_step_has_none(tp2_run, traced):
+    _, out = tp2_run
+    assert set(out["collectives"]) == set(COLLECTIVES)
+    assert out["collectives"]["all-gather"] > 0
+    assert out["collectives"]["all-reduce"] > 0
+    assert traced["result"]["collectives"] == dict.fromkeys(COLLECTIVES, 0)
 
 
 # (d) spans change nothing on the device -------------------------------------
