@@ -64,7 +64,7 @@ def _force_cpu_devices(n: int = 8) -> None:
     virtual host platform BEFORE jax initializes (a no-op when the test
     harness already did). APPEND to any pre-existing XLA_FLAGS — a host
     exporting e.g. --xla_dump_to must not silently lose the device-count
-    flag (the tools/pipeline_dispatch_bench.py pattern)."""
+    flag."""
     flags = os.environ.get("XLA_FLAGS", "")
     if "xla_force_host_platform_device_count" not in flags:
         os.environ["XLA_FLAGS"] = (flags + " " if flags else "") + \

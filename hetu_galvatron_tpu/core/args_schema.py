@@ -689,7 +689,7 @@ class SearchArgs(BaseModel):
     sp_time_path: Optional[str] = None
     sequence_length: Optional[int] = None
     costmodel_coe: float = 1.0
-    # Host-dispatch overhead pricing (tools/pipeline_dispatch_bench.py):
+    # Host-dispatch overhead pricing (not measured on the chip):
     # one already-compiled stage-jit call costs ~dispatch_us of host wall
     # time, and the host-sequenced schedule pays 2 (fwd+bwd) * pp * chunks
     # of them per step. The compiled schedule (pipeline.schedule_impl=

@@ -95,7 +95,7 @@ class CostContext:
     # the host engine pays ~dispatch_us of wall time per already-compiled
     # stage-jit call — 2 (fwd + bwd) * pp * chunks calls per step — while
     # the compiled single-program schedule (pipeline.schedule_impl=
-    # compiled) pays none. Measured by tools/pipeline_dispatch_bench.py;
+    # compiled) pays none. Not measured on the chip;
     # 0.0 (the default) keeps the reference-equivalent arithmetic exact.
     dispatch_us: float = 0.0
     schedule_impl: str = "host"
@@ -1000,7 +1000,7 @@ def pipeline_time_cost(
     reduce_tail = max(stage_reduce)
     result += reduce_tail if reduce_tail > 0 else 0.0
 
-    # host-sequenced dispatch overhead (tools/pipeline_dispatch_bench.py):
+    # host-sequenced dispatch overhead:
     # every (stage, microbatch) leg costs one fwd + one bwd jitted-call
     # dispatch on the host, which the single-program compiled schedule
     # eliminates. This is what lets the search's pp choice price the two

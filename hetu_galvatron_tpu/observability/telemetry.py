@@ -1,7 +1,8 @@
 """Derived training telemetry: throughput, MFU, memory, plan comm volume.
 
-:class:`TrainingTelemetry` is a ``train_loop`` hook (``hook(it, metrics)``)
-that turns raw step metrics into the numbers the ROADMAP cares about:
+:class:`TrainingTelemetry` is a hook of ``cli/train_dist.py``'s loop
+(``hook(it, metrics)``) that turns raw step metrics into the numbers the
+ROADMAP cares about:
 
 * ``train/step_time_ms`` histogram — host wall-clock between hook calls.
   Under async dispatch the host runs ahead of the device until XLA's

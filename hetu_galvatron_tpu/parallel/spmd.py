@@ -698,8 +698,8 @@ def make_spmd_train_step(
             if rng is None:
                 raise ValueError(
                     "cfg enables dropout but the batch has no 'dropout_rng' "
-                    "key; train_loop adds it automatically — manual callers "
-                    "must pass one per step")
+                    "key; cli/train_dist.py adds it automatically — manual "
+                    "callers must pass one per step")
             return jitted(params, opt_state, batch, rng)
 
         def lower(params, opt_state, batch):

@@ -72,8 +72,7 @@ general path): causal-LM / bert families (no t5 pair carry), vpp=1, uniform
 layout), no MoE, no packed-document fields. Context parallelism (plain and
 zigzag), Megatron-SP tp with the overlapped ring matmuls, Ulysses, and the
 Pallas flash kernel all run INSIDE the program via the stage-stacked
-shard_map wrappers; `tools/pipeline_dispatch_bench.py --kernels` and
-`tools/tp_overlap_bench.py --schedule-impl compiled` measure the composition.
+shard_map wrappers.
 """
 
 from __future__ import annotations
@@ -990,8 +989,8 @@ class CompiledPipelineEngine:
         if self._use_dropout and step_rng is None:
             raise ValueError(
                 "cfg enables dropout but the batch has no 'dropout_rng' "
-                "key; train_loop/cli add it automatically — manual callers "
-                "must pass one per step")
+                "key; cli/train_dist.py adds it automatically — manual "
+                "callers must pass one per step")
         # .ndim only — np.asarray on a staged device batch would pull the
         # whole token array back to the host every step
         if batch["tokens"].ndim == 2:
@@ -1043,8 +1042,8 @@ class CompiledPipelineEngine:
         if self._use_dropout and step_rng is None:
             raise ValueError(
                 "cfg enables dropout but the batch has no 'dropout_rng' "
-                "key; train_loop/cli add it automatically — manual callers "
-                "must pass one per step")
+                "key; cli/train_dist.py adds it automatically — manual "
+                "callers must pass one per step")
         if batch["tokens"].ndim == 2:
             batch = self.put_batch(batch, m)
         if m not in self._step_jits:

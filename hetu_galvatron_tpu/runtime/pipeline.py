@@ -231,9 +231,9 @@ class PipelineEngine:
         self._lazy_jits: Dict[str, Any] = {}
         self._eval_jits = None  # built on first eval_step (dropout off)
         # one-shot cost/* recording: resolved once per step (train_step),
-        # not per microbatch — the schedule's inner loop is exactly what
-        # pipeline_dispatch_bench measures, so it must stay free of
-        # registry lookups after the first recorded step
+        # not per microbatch — the schedule's inner loop is host dispatch
+        # the devices wait on, so it must stay free of registry lookups
+        # after the first recorded step
         self._jit_cost_done = False
         self._record_costs = False
         # Mosaic kernels summed over the stage BACKWARD programs (each
@@ -1033,7 +1033,7 @@ class PipelineEngine:
                     or self.cfg.attention_dropout > 0.0):
                 raise ValueError(
                     "cfg enables dropout but the batch has no 'dropout_rng' "
-                    "key; train_loop/cli add it automatically — manual "
+                    "key; cli/train_dist.py adds it automatically — manual "
                     "callers must pass one per step")
             step_rng = jax.random.key(0)
         # resolve the one-shot cost/* recording ONCE per step: the inner

@@ -1,4 +1,4 @@
-"""Single-program training loop: jitted train_step + microbatch accumulation.
+"""Single-program training step: jitted train_step + microbatch accumulation.
 
 Capability parity with the reference's no-pipeline execution path
 (runtime/pipeline/pipeline.py:306-385 ``no_pipeline_forward_backward`` +
@@ -13,8 +13,7 @@ without host round-trips.
 
 from __future__ import annotations
 
-import time
-from typing import Any, Callable, Dict, Optional, Tuple
+from typing import Any, Callable, Dict, Optional
 
 import jax
 import jax.numpy as jnp
@@ -22,7 +21,7 @@ import optax
 
 from hetu_galvatron_tpu.core.args_schema import CoreArgs, ModelArgs
 from hetu_galvatron_tpu.models.builder import causal_lm_loss
-from hetu_galvatron_tpu.runtime.optimizer import global_grad_norm, make_optimizer
+from hetu_galvatron_tpu.runtime.optimizer import global_grad_norm
 
 
 def make_loss_fn(
@@ -244,133 +243,6 @@ def make_train_step(
         return new_params, new_opt, metrics
 
     return step
-
-
-def train_loop(
-    args: CoreArgs,
-    params: Any,
-    data_iter,
-    *,
-    train_step: Optional[Callable] = None,
-    tx: Optional[optax.GradientTransformation] = None,
-    device_put: Callable[[Dict[str, Any]], Dict[str, jax.Array]] = None,
-    hooks: Tuple[Callable, ...] = (),
-    telemetry: Optional[Any] = None,
-    preemption: Optional[Any] = None,
-    goodput: Optional[Any] = None,
-    checkpoint: Optional[Any] = None,
-) -> Tuple[Any, Any, list]:
-    """Host-side iteration driver (reference train_dist.py:49-73): fetch
-    batch, run jitted step, invoke profiler/logging hooks. Returns final
-    (params, opt_state, losses).
-
-    ``hooks`` are ``h(it, metrics)`` callables invoked after every step
-    with the step's (possibly still in-flight) device metrics — hooks must
-    not force a device sync. ``preemption`` is an optional object with a
-    ``requested() -> bool`` method (``runtime.supervisor.PreemptionGuard``)
-    checked at every step boundary: once true the loop stops cleanly after
-    the in-flight step, returning what it has — the caller checkpoints and
-    exits. ``telemetry`` is an optional
-    ``observability.TrainingTelemetry`` appended to the hooks; it is
-    final-flushed when the loop exits (even on error) and left open for
-    the caller to reuse/close. When ``args.observability.enabled`` and no
-    instance is passed, one is built from the args (JSONL sink at
-    ``observability.metrics_path``) and closed with the loop.
-    ``goodput`` is an optional
-    ``observability.goodput.GoodputTracker``: each iteration's host wall
-    is booked as ``productive_step`` (the first iteration as
-    ``recompile`` — it pays the jit), so even this minimal loop feeds
-    the goodput partition; flushing/persistence stay the caller's job.
-    ``checkpoint`` is an optional
-    ``runtime.checkpoint.CheckpointCadence``: when its cadence (step
-    interval or wall interval) is due, the post-update state is saved
-    through it (async snapshot or sync write per its config) and any
-    in-flight write is drained when the loop exits — even on error, so
-    a crashing attempt never leaks a background writer."""
-    from hetu_galvatron_tpu.models.modules import compute_dtype_of
-    from hetu_galvatron_tpu.observability.tracing import span
-
-    # rank-gated like the train_dist launcher: on a multi-host pod only
-    # process 0 may configure sinks (every process appending to one
-    # shared-storage JSONL would interleave)
-    owns_telemetry = (telemetry is None and args.observability.enabled
-                      and jax.process_index() == 0)
-    if owns_telemetry:
-        telemetry = make_telemetry(args)
-
-    tx = tx or make_optimizer(args.train)
-    if train_step is None:
-        loss_fn = make_loss_fn(
-            args.model,
-            compute_dtype=compute_dtype_of(args.parallel.mixed_precision),
-        )
-        # chunks=-1 means "auto"; the hybrid-parallel config layer resolves
-        # it properly — without a plan, auto degrades to no microbatching
-        chunks = max(args.parallel.chunks, 1)
-        train_step = jax.jit(make_train_step(loss_fn, tx, chunks=chunks))
-    opt_state = tx.init(params)
-    device_losses = []
-    put = device_put or (lambda b: jax.tree.map(jnp.asarray, b))
-    use_dropout = (args.model.hidden_dropout > 0.0
-                   or args.model.attention_dropout > 0.0)
-    drop_key = jax.random.key(args.train.seed) if use_dropout else None
-    all_hooks = hooks + ((telemetry,) if telemetry is not None else ())
-    try:
-        for it in range(args.train.train_iters):
-            it_t0 = time.perf_counter()
-            with span("train/fetch"):
-                batch = put(next(data_iter))
-            if use_dropout:
-                batch["dropout_rng"] = jax.random.fold_in(drop_key, it)
-            if it == 0:
-                # XLA's own flops/bytes for the step program (cost/* gauges;
-                # no-op unless a metrics sink is configured). BEFORE the
-                # call: lowering only reads avals, so donated buffers are
-                # still valid (and it stays lowering-only — no extra
-                # backend compile).
-                from hetu_galvatron_tpu.observability.trace_analysis import (
-                    maybe_record_jit_cost,
-                )
-
-                maybe_record_jit_cost("train/step", train_step,
-                                      (params, opt_state, batch))
-            with span("train/step"):
-                params, opt_state, metrics = train_step(
-                    params, opt_state, batch)
-            # keep losses on device — a float() here would block async
-            # dispatch and serialize host batch-prep against device compute
-            device_losses.append(metrics["loss"])
-            for h in all_hooks:
-                h(it, metrics)
-            if goodput is not None:
-                goodput.add("recompile" if it == 0 else "productive_step",
-                            time.perf_counter() - it_t0)
-            if checkpoint is not None and checkpoint.due(it):
-                # after the goodput booking: the cadence books its own
-                # wall (snapshot stall or full sync write) to
-                # checkpoint_save, not to this step's productive time
-                checkpoint.save(it + 1, params, opt_state)
-            if preemption is not None and preemption.requested():
-                # step boundary: the update above is complete and safe to
-                # checkpoint; never abandon a step mid-flight
-                break
-    finally:
-        if checkpoint is not None:
-            try:
-                checkpoint.drain()
-            except Exception as e:  # noqa: BLE001 — never mask loop error
-                print(f"warning: checkpoint drain at loop exit failed "
-                      f"({type(e).__name__}: {e})", flush=True)
-        # a loop-owned telemetry is closed here; a caller-supplied one is
-        # only final-flushed (the caller may reuse it across loops and
-        # closes it when done — close() re-arms on the next __call__)
-        if telemetry is not None:
-            if owns_telemetry:
-                telemetry.close()
-            else:
-                telemetry.flush(final=True)
-    losses = [float(l) for l in device_losses]
-    return params, opt_state, losses
 
 
 def make_telemetry(args: CoreArgs, *, registry: Any = None,

@@ -25,9 +25,7 @@ ROADMAP's "heavy traffic" north star implies:
   streams, cancellation, timeouts, and serving telemetry wired into
   ``observability/``.
 
-Front ends: ``cli/serve.py`` (file/stdin request streams) and
-``tools/serve_bench.py`` (closed-loop load generator, shared-prefix
-traces).
+Front end: ``cli/serve.py`` (file/stdin request streams).
 """
 
 from hetu_galvatron_tpu.serving.engine import ServingEngine

@@ -23,18 +23,21 @@ jax.config.update("jax_enable_compilation_cache", False)
 
 
 # ---------------------------------------------------------------------------
-# Fast/slow tiers. ``-m "not slow"`` is the CI-default quick tier (~3 min);
-# the full suite (~20 min) runs everything. Tests land here when a
-# ``--durations`` profile shows them >=7s on the reference CI shape (the
-# parity/trajectory tests dominated by 8-device jit compiles); marking is
-# centralized in this hook so test files stay unannotated.
+# Fast/slow tiers. ``-m "not slow"`` is tier-1, what the driver runs after
+# every PR: six xdist workers (``--dist loadfile``) under a 1,470 s limit,
+# held by ISSUE 30 to under 600 s. Nobody runs the slow tier as a matter of
+# course, so what guards a path one of the benchmark's cells executes (the
+# dropless MoE layer, per-layer remat, GPT-2's shape, tp2 x dp2 ZeRO-3 SPMD
+# parity, the flash kernels, the fused cross-entropy, XLA's full-remat
+# warning) is NOT listed here, whatever it costs. Listed are parity and
+# trajectory tests of features no cell turns on, and drills that train real
+# checkpoints; a test that alone takes over 60 s on six workers stays here
+# too. Marking is centralized in this hook so test files stay unannotated
+# (a few carry their own ``@pytest.mark.slow``).
 _SLOW_TESTS = {
     # moe / t5 / bert parity
     "test_expert_parallel_matches_single_device",
     "test_moe_pipeline_matches_single_device",
-    "test_moe_model_trains",
-    "test_moe_mlp_routing_and_aux",
-    "test_dropless_grads_flow",
     "test_t5_tp2_matches_single_device",
     "test_t5_pipeline_matches_single_device",
     "test_t5_interleaved_virtual_stages",
@@ -47,33 +50,20 @@ _SLOW_TESTS = {
     "test_bert_mlm_training_step_tp8",
     "test_bert_mlm_loss_trajectory_matches_hf",
     "test_bidirectional_attention",
-    # gpt model correctness / accuracy alignment
-    "test_remat_same_loss",
-    "test_forward_shapes_and_loss",
-    "test_param_count_gpt2_small",
-    "test_gpt2_loss_trajectory_matches_hf",
     # hierarchical dp reduction: the engine parity drills compile two full
     # engines each; the single-device zero3 reference adds a third build
     "test_hier_compiled_engine_parity",
     "test_hier_host_engine_parity",
     "test_hier_zero3_matches_single_device_where_flat_drifts",
     # spmd / pipeline parity
-    "test_no_involuntary_full_rematerialization",
-    "test_strategy_matches_single_device",
     "test_mixed_per_layer_strategies",
-    "test_multi_step_trajectory_matches_single_device",
     "test_pipeline_matches_single_device",
     "test_pipeline_tied_embeddings",
     "test_interleaved_virtual_stages_match_single_device",
     "test_interleaved_tied_embeddings",
     "test_uneven_pp_division",
-    # kernels repaired in round 10 (the jax.shard_map / CompilerParams pin
-    # fixes): they failed at the seed, so the fast tier never counted them
-    # — the heavy ones run in the full suite to keep tier-1 inside its
-    # budget; cheap smokes (one flash, one ring, one fused-CE) stay fast
-    "test_spmd_train_step_fused_ce_matches",
-    "test_vocab_parallel_ce_matches_single_device",
-    "test_vocab_parallel_ce_multi_axis_and_vsp",
+    # ring attention, flash dropout and flash segment ids: context
+    # parallelism, dropout and packed documents are on in no cell
     "test_ring_flash_gradients_match",
     "test_ring_flash_matches_dense",
     "test_ring_flash_with_dp_and_tp_axes",
@@ -95,10 +85,6 @@ _SLOW_TESTS = {
     "test_ring_with_dp_and_tp_axes",
     "test_ring_matches_dense",
     "test_zigzag_ring_matches_dense",
-    "test_distributed_flash_matches_dense",
-    "test_flash_gradients_match",
-    "test_flash_gradients_gqa_groups",
-    "test_flash_gradients_noncausal",
     # CLI / e2e / profilers / checkpoint
     "test_search_then_train_the_searched_plan",
     "test_train_dist_cli_pipeline_compiled",
@@ -125,8 +111,6 @@ _SLOW_TESTS = {
     "test_sp_time_profile_feeds_latency_tables",
     "test_hardware_profiler_schemas",
     "test_numpy_fallback_matches_cpp",
-    "test_microbatch_accumulation_matches_full_batch",
-    "test_microbatch_nonuniform_loss_mask_matches",
     # shared-prefix serving acceptance drill (8-device mesh, two engine
     # warmups x two variants) and secondary prefix/spec legs — the
     # single-device hit-parity, spec-losslessness, and eviction tests
@@ -138,8 +122,6 @@ _SLOW_TESTS = {
     # draft-model serve smoke trains a real draft checkpoint first (the
     # fast tier keeps the draft_model= usage-error path)
     "test_serve_cli_draft_model_smoke",
-    "test_serve_bench_ab_legs_importable",
-    "test_serve_bench_shared_prefix_trace",
     "test_prefix_engine_defrag_mid_serving",
     "test_suffix_bucket_overshoot_at_table_capacity",
     "test_spec_eos_and_budget_mid_window",
@@ -168,14 +150,13 @@ _SLOW_TESTS = {
     "test_chaos_matrix_transient_io",
     "test_chaos_matrix_hung_save",
     "test_chaos_matrix_budget",
-    # moved out so tier-1 ends inside its 870 s limit (ISSUE 21; their fate
-    # belongs to ROADMAP design debts 6 and 10): the drill asserts an
-    # ordering of CPU-TIMED residuals, the rest led --durations
+    # moved out by ISSUE 21, when tier-1 was one process under 870 s: the
+    # drill asserts an ordering of CPU-TIMED residuals (ROADMAP design debt
+    # 10), the rest led --durations and guard no cell's path
     "test_calibration_drill_mesh8",
     "test_cached_greedy_matches_naive",
     "test_t5_greedy_decode_matches_teacher_forced_forward",
     "test_hf_llama_roundtrip",
-    "test_remat_policy_parity",
     "test_expert_bias_updates_during_training",
     "test_prefill_decode_logit_parity_vs_full_forward",
     "test_generate_ragged_left_padded_batch",
@@ -185,8 +166,6 @@ _SLOW_TESTS = {
     "test_t5_decode_eos_masking_and_sampling_shapes",
     "test_cross_attention_biases_honored",
     "test_alpha_beta_algos_roundtrip",
-    # a CPU timing A/B whose ratios mean nothing (ROADMAP design debt 6)
-    "test_serve_bench_smoke",
 }
 
 

@@ -14,7 +14,7 @@ from hetu_galvatron_tpu.runtime.checkpoint import hf_to_params
 from hetu_galvatron_tpu.runtime.optimizer import make_optimizer
 from hetu_galvatron_tpu.runtime.trainer import make_loss_fn, make_train_step
 
-pytestmark = [pytest.mark.model, pytest.mark.slow]
+pytestmark = pytest.mark.model
 
 CFG = ModelArgs(
     hidden_size=32, num_hidden_layers=2, num_attention_heads=2,

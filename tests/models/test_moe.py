@@ -57,7 +57,6 @@ def test_moe_capacity():
         np.ceil(32 * 2 / 4 * 1.25))
 
 
-@pytest.mark.slow
 def test_moe_model_trains():
     params, axes = init_causal_lm(jax.random.key(0), MOE_CFG)
     assert "moe" in params["layers"][0]  # freq=1: every layer MoE

@@ -1,0 +1,129 @@
+"""The seam between the program and its benchmark is a set of names. The
+readers under ``benchmark/layer_metrics/`` find the program by the spans,
+gauges and the one histogram it writes; a run in which one is missing is
+refused on the chip as malformed. Here every such name is looked up the way
+the benchmark does it, through the readers' own code and tables (imported,
+never copied), after ``train_dist.main`` ran on the CPU at a tiny size: the
+dense preset, and an expert model with the dropless path of ``olmoe_c1_s4k``.
+One case a name, so a renamed span fails by its name."""
+
+import glob
+import json
+import os
+
+import pytest
+
+from benchmark import manifest, window, xplane
+from hetu_galvatron_tpu.observability.registry import (
+    MetricsRegistry,
+    get_registry,
+    set_registry,
+)
+
+pytestmark = pytest.mark.observability
+
+ZOO = os.path.join(manifest.ROOT, "hetu_galvatron_tpu", "models", "configs")
+READERS = os.path.join(manifest.ROOT, "benchmark", "layer_metrics")
+host_phases = manifest.load_python(os.path.join(READERS, "host_phases.py"))
+# the files whose readers look into the program's registry, not the trace
+GAUGE_FILES = ("program_gauges.py", "moe_gauges.py")
+
+TRACED, MEASURED = 3, 2
+ITERS = window.WARMUP_STEPS + TRACED + MEASURED
+SIZE = ["model.hidden_size=32", "model.num_hidden_layers=2",
+        "model.num_attention_heads=2", "model.vocab_size=64",
+        "model.seq_length=16", "model.max_position_embeddings=16",
+        "model.make_vocab_size_divisible_by=1",
+        "parallel.global_train_batch_size=4", "parallel.chunks=2",
+        "parallel.num_devices=1", "data.dataset=random"]
+PRESETS = {
+    "dense": ["gpt2-small.yaml"] + SIZE,
+    "moe": ["olmoe-1b-7b.yaml"] + SIZE + [
+        "model.num_key_value_heads=2", "model.ffn_hidden_size=32",
+        "model.num_experts=4", "model.moe_topk=2"],
+}
+# what a cell without an expert layer does not write, and is not asked for
+MOE_ONLY = {"moe_imbalance"}
+
+
+def _gauge_readers():
+    """name -> reader function of every per-layer metric that is read from
+    the program's registry, as ``benchmark/layer_metrics/<name>.json`` says."""
+    modules, readers = {}, {}
+    for path in sorted(glob.glob(os.path.join(READERS, "*.json"))):
+        with open(path) as f:
+            reader = json.load(f)["reader"]
+        if reader.get("file") not in GAUGE_FILES:
+            continue
+        mod = modules.setdefault(reader["file"], manifest.load_python(
+            os.path.join(READERS, reader["file"])))
+        name = os.path.splitext(os.path.basename(path))[0]
+        readers[name] = getattr(mod, reader["function"])
+    return readers
+
+
+GAUGE_READERS = _gauge_readers()
+
+
+@pytest.fixture(scope="module", params=sorted(PRESETS))
+def run(request, tmp_path_factory):
+    """One traced tiny run with the overrides the harness gives a cell (but
+    for the far-away ``train_iters``), its registry left in place as the
+    process's own for the readers to find, and the parent's put back after."""
+    from hetu_galvatron_tpu.cli import train_dist
+
+    tdir = str(tmp_path_factory.mktemp(request.param) / "trace")
+    harness = [w for w in window.harness_overrides(tdir)
+               if not w.startswith(("train.train_iters=",
+                                    "profile.trace_iters="))]
+    yaml, *size = PRESETS[request.param]
+    argv = ([os.path.join(ZOO, yaml)] + size + harness
+            + [f"train.train_iters={ITERS}",
+               f"profile.trace_iters={TRACED}"])
+    before = get_registry()
+    reg = set_registry(MetricsRegistry())
+    try:
+        out = {}
+        assert train_dist.main(argv, result=out) == 0
+        assert len(out["losses"]) == ITERS
+        yield {"preset": request.param, "registry": reg,
+               "trace": xplane.find_xplane(tdir),
+               # the CPU's allocator states no limit; a chip's does
+               "facts": {"memory": {"per_device": [{"bytes_limit": 2 ** 34}]}}}
+    finally:
+        set_registry(before)
+
+
+@pytest.mark.parametrize("path", sorted(host_phases.PART_OF))
+def test_every_span_a_gap_is_cut_into_is_entered_each_iteration(run, path):
+    (h,) = [m for m in run["registry"].metrics()
+            if m.name == "span_ms" and m.labels == {"path": path}]
+    assert h.count == ITERS
+
+
+@pytest.mark.parametrize("name", sorted(GAUGE_READERS))
+def test_registry_reader_finds_what_the_program_wrote(run, name):
+    assert get_registry() is run["registry"]
+    value = GAUGE_READERS[name](run["facts"])
+    if name in MOE_ONLY and run["preset"] != "moe":
+        assert value is None     # looked up, never made by asking
+    else:
+        assert value is not None and value > 0
+
+
+def test_iteration_spans_are_flat_siblings_on_the_dispatching_thread(run):
+    # read_planes keeps the train/* TraceMes of the thread that dispatches
+    spans = host_phases.read_planes(run["trace"]).spans
+    steps = sorted({step for _, _, _, step in spans})
+    assert steps == list(range(window.WARMUP_STEPS,
+                               window.WARMUP_STEPS + TRACED))
+    for it in steps:
+        mine = [s for s in spans if s[3] == it]
+        assert set(host_phases.PART_OF) <= {name for name, *_ in mine}
+        assert all(a[2] <= b[1] for a, b in zip(mine, mine[1:])), mine
+
+
+def test_iter_time_histogram_gets_one_sample_a_measured_step(run):
+    (h,) = [m for m in run["registry"].metrics()
+            if m.name == window.HISTOGRAM and not m.labels]
+    assert h.count == MEASURED

@@ -1,6 +1,6 @@
 """Unified telemetry layer: registry semantics, sink round-trips, span
 nesting, MFU math against a hand-computed GPT-2-small example, and the
-train_loop CPU smoke contract (JSONL emitted; no device sync in the hot
+launcher CPU smoke contract (JSONL emitted; no device sync in the hot
 loop; <5% hook overhead)."""
 
 import json
@@ -424,31 +424,41 @@ def test_telemetry_hook_overhead_under_5_percent(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# train_loop CPU smoke: JSONL out, summarize renders it
+# launcher CPU smoke: JSONL out, summarize renders it
 # ---------------------------------------------------------------------------
 
 
-def test_train_loop_telemetry_smoke_and_summarize(tmp_path, capsys):
+def test_launcher_telemetry_smoke_and_summarize(tmp_path, capsys):
     from hetu_galvatron_tpu.cli import summarize as S
-    from hetu_galvatron_tpu.models.builder import init_causal_lm
-    from hetu_galvatron_tpu.runtime.dataloader import synthetic_batches
-    from hetu_galvatron_tpu.runtime.trainer import train_loop
+    from hetu_galvatron_tpu.cli import train_dist
+    from hetu_galvatron_tpu.observability.registry import (
+        get_registry,
+        set_registry,
+    )
 
     path = str(tmp_path / "metrics.jsonl")
-    args = CoreArgs.model_validate({
-        "model": {"hidden_size": 32, "num_hidden_layers": 2,
-                  "num_attention_heads": 2, "vocab_size": 64,
-                  "seq_length": 8, "max_position_embeddings": 16,
-                  "make_vocab_size_divisible_by": 1},
-        "parallel": {"global_train_batch_size": 4},
-        "train": {"train_iters": 6},
-        "observability": {"enabled": True, "metrics_path": path,
-                          "flush_interval": 2, "peak_tflops": 0.001},
-    })
-    params, _ = init_causal_lm(jax.random.key(0), args.model)
-    _, _, losses = train_loop(args, params,
-                              synthetic_batches(args.model, 4))
-    assert len(losses) == 6 and np.isfinite(losses).all()
+    yaml = os.path.join(os.path.dirname(train_dist.__file__), "..", "models",
+                        "configs", "gpt2-small.yaml")
+    out = {}
+    before = get_registry()
+    try:
+        rc = train_dist.main(
+            [yaml, "model.hidden_size=32", "model.num_hidden_layers=2",
+             "model.num_attention_heads=2", "model.vocab_size=64",
+             "model.seq_length=8", "model.max_position_embeddings=16",
+             "model.make_vocab_size_divisible_by=1",
+             "parallel.global_train_batch_size=4", "parallel.num_devices=1",
+             "data.dataset=random", "train.train_iters=6",
+             "observability.enabled=true",
+             f"observability.metrics_path={path}",
+             "observability.flush_interval=2",
+             "observability.peak_tflops=0.001"], result=out)
+    finally:
+        # make_telemetry configured the process-wide registry with a sink
+        get_registry().close()
+        set_registry(before)
+    losses = out["losses"]
+    assert rc == 0 and len(losses) == 6 and np.isfinite(losses).all()
     recs = [json.loads(l) for l in open(path)]
     names = {r["name"] for r in recs}
     # the acceptance triple: step-time, tokens/sec, and MFU entries
@@ -463,7 +473,7 @@ def test_train_loop_telemetry_smoke_and_summarize(tmp_path, capsys):
     assert last["train/mfu"]["value"] > 0
     # span aggregation rode along through the same registry
     span_paths = {r["labels"]["path"] for r in recs if r["name"] == "span_ms"}
-    assert {"train/fetch", "train/step"} <= span_paths
+    assert {"train/data", "train/dispatch", "train/telemetry"} <= span_paths
 
     headline = S.summarize(path)
     out = capsys.readouterr().out
