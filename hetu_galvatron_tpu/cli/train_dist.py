@@ -86,7 +86,7 @@ def train(args) -> Dict[str, Any]:
         from hetu_galvatron_tpu.runtime.initialize import initialize
         from hetu_galvatron_tpu.runtime.mesh import build_mesh
         from hetu_galvatron_tpu.runtime.optimizer import (
-            make_lr_schedule,
+            HostSchedule,
             make_optimizer,
         )
         from hetu_galvatron_tpu.runtime.pipeline import PipelineEngine
@@ -198,7 +198,7 @@ def train(args) -> Dict[str, Any]:
         params = jax.eval_shape(init_params, init_key)
         axes = axes_box["axes"]
         tx = make_optimizer(args.train)
-        schedule = make_lr_schedule(args.train)
+        host_lr = HostSchedule(args.train)
         base_iter, valid_iter, test_iter = get_train_valid_test_data_iterators(
             args, global_batch_size=hpc.global_bsz, hpc=hpc)
         data_iter = RerunDataIterator(base_iter)
@@ -722,6 +722,9 @@ def train(args) -> Dict[str, Any]:
                                 calc.current_running_global_batch_size
                         telemetry(it, metrics)
                 with phase("train/sync"):
+                    # the log line's values travel with the step: their
+                    # host copies queue behind it on the device
+                    profiler.start_log_copies(it, metrics)
                     profiler.time_end(it, sync=metrics.get("loss"))
                     # goodput: the synced step wall (profiler.time_end
                     # blocks on the loss). Each attempt's first iteration
@@ -733,10 +736,13 @@ def train(args) -> Dict[str, Any]:
                         else "productive_step",
                         time.perf_counter() - it_t0)
                 with phase("train/lr"):
-                    # the schedule runs on the device: with the profiler
-                    # off (nothing blocked in train/sync) this read-back is
-                    # where the host first waits for the step
-                    lr = float(schedule(it))
+                    # the device is empty from here to the next dispatch,
+                    # so nothing here runs on it: the log line's learning
+                    # rate is a host look-up (the optimizer has its own
+                    # schedule inside the step). With the profiler off
+                    # nothing blocked in train/sync: train/log's read of
+                    # the loss is where the host first waits
+                    lr = host_lr(it) if profiler.prints(it) else None
                 with phase("train/log"):
                     profiler.iteration_log(it, metrics, lr=lr)
                 with phase("train/check"):

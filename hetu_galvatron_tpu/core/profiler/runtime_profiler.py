@@ -41,6 +41,8 @@ from hetu_galvatron_tpu.observability.registry import (
 from hetu_galvatron_tpu.observability.tracing import TraceCapture
 
 MB = 1024 * 1024
+# the entries of a step's metrics that the iteration log line formats
+LOG_LINE_KEYS = ("loss", "grad_norm", "moe")
 
 
 def device_memory_mb(device=None) -> Optional[Dict[str, float]]:
@@ -189,6 +191,27 @@ class RuntimeProfiler:
 
     # -- logging + output ---------------------------------------------------
 
+    def prints(self, it: int) -> bool:
+        """Whether :meth:`iteration_log` prints a line for iteration ``it``:
+        rank 0, on the log interval."""
+        interval = self.args.logging.log_interval
+        return bool(self.rank == 0 and interval and it % interval == 0)
+
+    def start_log_copies(self, it: int, metrics: Dict[str, Any]) -> None:
+        """On a printing iteration, start the device-to-host copies of
+        exactly the leaves the log line formats (:data:`LOG_LINE_KEYS`).
+        Called right after the step is dispatched, the copies queue behind
+        it on the device and land as it ends, so :meth:`iteration_log`
+        formats host values where it used to pay one blocking round trip a
+        leaf on an idle device. Off the interval: nothing."""
+        if not self.prints(it):
+            return
+        line = {k: metrics[k] for k in LOG_LINE_KEYS if k in metrics}
+        for leaf in jax.tree.leaves(line):
+            # the host pipeline engine hands its grad-norm over as a float
+            if isinstance(leaf, jax.Array):
+                leaf.copy_to_host_async()
+
     def iteration_log(self, it: int, metrics: Dict[str, Any],
                       lr: Optional[float] = None) -> str:
         """One line per iteration (reference runtime_profiler.py:333-370).
@@ -196,13 +219,17 @@ class RuntimeProfiler:
         Returns EXACTLY the line that was printed, or "" on non-printing
         iterations (rank != 0 or off the log interval) — the return value
         is consistent for every caller, and off-interval iterations pay
-        ZERO device-to-host syncs: all float()/asarray() formatting
-        (including the MoE balance tracker) is gated behind the interval,
-        never half of it.
+        ZERO device-to-host traffic: no copy is started and no value is
+        converted (the MoE balance tracker included), never half of it.
+
+        On a printing iteration the values come from the host: the loop
+        has called :meth:`start_log_copies` behind the step, so the
+        ``float()`` / ``asarray()`` below find copies that have landed
+        (a caller that did not pays the blocking read-backs here, with the
+        same line), and ``lr`` is a Python float the loop evaluated off
+        the accelerator.
         """
-        printing = (self.rank == 0 and self.args.logging.log_interval
-                    and it % self.args.logging.log_interval == 0)
-        if not printing:
+        if not self.prints(it):
             return ""
         bits = [f"iter {it}"]
         if "loss" in metrics:

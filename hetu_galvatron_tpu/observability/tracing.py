@@ -21,13 +21,17 @@ The spans of the launcher (``cli/train_dist.py``), all flat siblings:
   plan, batch-size ramp, next batch, dropout key), ``train/h2d``
   (``device_put`` of the batch), ``train/dispatch`` (the call of the
   jitted step; the first one traces, lowers and compiles or loads it),
-  ``train/sync`` (``profiler.time_end``: blocks on the loss when
-  ``profile.profile=1``), ``train/lr`` (the LR schedule read back from
-  the device; with the profiler off, where the host first waits for the
-  step), ``train/log``, ``train/check`` (loss read-back, fault drill,
-  rerun validation), and only when that work is done ``train/telemetry``,
-  ``train/eval``, ``train/save``. The pp>1 engines keep their own
-  ``pp/*`` spans in place of ``train/h2d`` and ``train/dispatch``;
+  ``train/sync`` (on a printing iteration the host copies of the log
+  line's values are started behind the step; then ``profiler.time_end``:
+  blocks on the loss when ``profile.profile=1``), ``train/lr`` (the log
+  line's learning rate looked up on the host, on printing iterations;
+  nothing runs on the accelerator), ``train/log`` (formats
+  the copies that landed; with the profiler off, where the host first
+  waits for the step), ``train/check`` (the loss already on the host,
+  fault drill, rerun validation), and only when that work is done
+  ``train/telemetry``, ``train/eval``, ``train/save``. The pp>1 engines
+  keep their own ``pp/*`` spans in place of ``train/h2d`` and
+  ``train/dispatch``;
 * once per ``train()``, registry only: ``setup/imports``,
   ``setup/runtime`` (model config, TPU client, plan, data iterators,
   telemetry), ``setup/init`` (mesh, building the step, jitted parameter

@@ -19,6 +19,7 @@ from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import optax
 
 from hetu_galvatron_tpu.core.args_schema import TrainArgs
@@ -62,6 +63,32 @@ def make_lr_schedule(train: TrainArgs) -> optax.Schedule:
     return optax.join_schedules(
         [optax.linear_schedule(0.0, peak, warmup), body], [warmup]
     )
+
+
+class HostSchedule:
+    """The learning rate of an iteration as a Python float, for the log
+    line: :func:`make_lr_schedule` (the ONE definition, which the optimizer
+    runs inside the step program on its own count) evaluated for ``BLOCK``
+    iterations in one program and one read-back, then looked up on the
+    host, so that asking for it between two steps puts nothing on the
+    accelerator. Called eagerly an iteration at a time the schedule is ten
+    scalar programs and a read-back, 6 ms of host time a step with the
+    device idle (PERF.md, PR 31). A block begins at the first iteration
+    asked for outside the one held (the run's first, a resume's), and
+    ``train.train_iters`` does not size it: a benchmark sets ten million."""
+
+    BLOCK = 1024
+
+    def __init__(self, train: TrainArgs):
+        self._block = jax.jit(jax.vmap(make_lr_schedule(train)))
+        self._first, self._values = 0, np.empty(0, np.float32)
+
+    def __call__(self, it: int) -> float:
+        if not 0 <= it - self._first < len(self._values):
+            self._first = it
+            self._values = np.asarray(self._block(
+                np.arange(it, it + self.BLOCK, dtype=np.int32)))
+        return float(self._values[it - self._first])
 
 
 def _decay_mask(params: Any) -> Any:

@@ -16,6 +16,7 @@ from hetu_galvatron_tpu.runtime.dataloader import (
     synthetic_batches,
 )
 from hetu_galvatron_tpu.runtime.optimizer import (
+    HostSchedule,
     global_grad_norm,
     make_lr_schedule,
     make_optimizer,
@@ -47,6 +48,55 @@ def test_lr_schedules():
         if style != "constant":
             assert final < 1e-3 + 1e-9
         assert final >= 0.0
+
+
+LR_STYLES = ["constant", "linear", "cosine", "inverse-square-root", "WSD"]
+
+
+def _lr_args(style, warmup):
+    return TrainArgs(lr=1e-3, min_lr=1e-5, lr_decay_style=style,
+                     lr_warmup_iters=warmup, train_iters=100,
+                     lr_wsd_decay_iters=20)
+
+
+@pytest.mark.parametrize("warmup", [0, 10])
+@pytest.mark.parametrize("style", LR_STYLES)
+def test_printed_lr_is_the_optimizers_schedule(style, warmup):
+    """The log line's learning rate (``HostSchedule``, what ``run_loop``
+    calls) is ``make_lr_schedule``'s value: the first iteration, around
+    the end of the warm-up, the last decay step and one past it."""
+    t = _lr_args(style, warmup)
+    schedule, printed = make_lr_schedule(t), HostSchedule(t)
+    in_step = jax.jit(schedule)   # as the optimizer evaluates it
+    for it in sorted({0, max(warmup - 1, 0), warmup, warmup + 1, 99, 100}):
+        got = printed(it)
+        assert isinstance(got, float)
+        assert got == pytest.approx(float(in_step(it)), rel=1e-7, abs=0)
+        # the eager evaluation rounds once more where XLA fuses a multiply
+        # and an add: one f32 ulp at the peak, which is 2.5e-6 of a value
+        # at the floor, where two numbers of the peak's size cancel
+        assert abs(got - float(schedule(it))) \
+            <= np.finfo(np.float32).eps * t.lr
+
+
+@pytest.mark.parametrize("first", [0, 7, 1500])
+def test_printed_lr_is_a_host_lookup_inside_a_block(first):
+    """One program and one read-back a block of iterations, wherever the
+    run starts (a resume); every other look-up touches no device at all."""
+    t = _lr_args("cosine", 10)
+    printed = HostSchedule(t)
+    programs, block = [], printed._block
+    printed._block = lambda its: programs.append(int(its[0])) or block(its)
+    want = np.asarray(jax.jit(jax.vmap(make_lr_schedule(t)))(
+        np.arange(first, first + HostSchedule.BLOCK + 1, dtype=np.int32)))
+    assert printed(first) == float(want[0])
+    with jax.transfer_guard("disallow"):
+        got = [printed(it) for it in range(first, first + HostSchedule.BLOCK)]
+    assert programs == [first]
+    np.testing.assert_array_equal(np.float32(got), want[:-1])
+    # past the block's end: the next block, once
+    assert printed(first + HostSchedule.BLOCK) == float(want[-1])
+    assert programs == [first, first + HostSchedule.BLOCK]
 
 
 def test_optimizer_decay_mask_and_step():

@@ -64,7 +64,7 @@ class _RecordingStep:
         return _RecordingLowered(lowered, self.compiled_texts)
 
 
-def _run(extra):
+def _run(extra, yaml="gpt2-small.yaml"):
     """One tiny run on a registry of its own; returns (registry, result,
     the step's lowered text); the result also holds the optimized HLO of
     the executable the step report read (``compiled_hlo``)."""
@@ -84,7 +84,7 @@ def _run(extra):
     try:
         out = {}
         rc = train_dist.main(
-            [os.path.join(ZOO, "gpt2-small.yaml")] + TINY + extra,
+            [os.path.join(ZOO, yaml)] + TINY + extra,
             result=out)
     finally:
         spmd.make_spmd_train_step = make
@@ -300,3 +300,120 @@ def test_time_end_blocks_in_traced_iterations_too(tmp_path, profile, tracing,
     prof.time_end(0, sync=loss)
     assert loss.blocked == blocks
     assert len(prof.time_samples) == samples
+
+
+# (f) nothing on the chip between two steps: the log line's values --------
+
+MOE_TINY = ["model.num_key_value_heads=2", "model.ffn_hidden_size=32",
+            "model.num_experts=4", "model.moe_topk=2"]
+
+
+def _logged_run(extra, yaml="gpt2-small.yaml"):
+    """A tiny run with every call of the loop into the log line recorded:
+    ``lr`` (iterations whose learning rate was asked for), ``copies`` (how
+    many host copies were started), ``logged`` ((iteration, the step's
+    metrics, the line returned)), ``printed`` (the ``iter`` lines on
+    stdout); and the run's registry."""
+    import contextlib
+    import io
+
+    from jax._src.array import ArrayImpl
+
+    from hetu_galvatron_tpu.core.profiler.runtime_profiler import (
+        RuntimeProfiler,
+    )
+    from hetu_galvatron_tpu.runtime.optimizer import HostSchedule
+
+    seen = {"lr": [], "copies": 0, "logged": []}
+    lookup, copy = HostSchedule.__call__, ArrayImpl.copy_to_host_async
+    log = RuntimeProfiler.iteration_log
+
+    def counting_lookup(self, it):
+        seen["lr"].append(it)
+        return lookup(self, it)
+
+    def counting_copy(self):
+        seen["copies"] += 1
+        return copy(self)
+
+    def recording_log(self, it, metrics, lr=None):
+        line = log(self, it, metrics, lr=lr)
+        seen["logged"].append((it, metrics, line))
+        return line
+
+    stdout = io.StringIO()
+    with pytest.MonkeyPatch.context() as mp, \
+            contextlib.redirect_stdout(stdout):
+        mp.setattr(HostSchedule, "__call__", counting_lookup)
+        mp.setattr(ArrayImpl, "copy_to_host_async", counting_copy)
+        mp.setattr(RuntimeProfiler, "iteration_log", recording_log)
+        seen["registry"], _, _ = _run(extra, yaml)
+    seen["printed"] = [ln for ln in stdout.getvalue().splitlines()
+                       if ln.startswith("iter ")]
+    return seen
+
+
+def _parents_line(it, metrics, schedule):
+    """The line as PR 30 printed it: every value read back from the device
+    where it is formatted, the schedule evaluated eagerly."""
+    import numpy as np
+
+    bits = [f"iter {it}", f"loss {float(metrics['loss']):.4f}",
+            f"grad-norm {float(metrics['grad_norm']):.3f}",
+            f"lr {float(schedule(it)):.3e}"]
+    for name in sorted(metrics.get("moe", {})):
+        st = metrics["moe"][name]
+        tpe = np.asarray(st["tokens_per_expert"], dtype=float)
+        bits.append(f"moe[{name}] aux {float(st['load_balance_loss']):.3e} "
+                    f"z {float(st['z_loss']):.3e} "
+                    f"imb {float(tpe.max() / max(tpe.mean(), 1e-9)):.2f}")
+    return " | ".join(bits)
+
+
+@pytest.fixture(scope="module")
+def every_other():
+    return _logged_run(["logging.log_interval=2"])
+
+
+@pytest.mark.parametrize("what", ["learning rate", "host copies", "lines"])
+def test_off_the_log_interval_nothing_is_computed_or_copied(every_other,
+                                                            what):
+    seen, printing = every_other, list(range(0, ITERS, 2))
+    if what == "learning rate":
+        assert seen["lr"] == printing
+    elif what == "host copies":
+        # the loss and the gradient norm, on printing iterations alone
+        assert seen["copies"] == 2 * len(printing)
+    else:
+        lines = [line for _, _, line in seen["logged"]]
+        assert [it for it, line in enumerate(lines) if line] == printing
+        assert seen["printed"] == [line for line in lines if line]
+
+
+@pytest.mark.parametrize("preset", ["dense", "moe"])
+def test_log_line_is_the_parents_to_the_character(preset):
+    from hetu_galvatron_tpu.core.arguments import args_from_cli
+    from hetu_galvatron_tpu.runtime.optimizer import make_lr_schedule
+
+    yaml, extra = {"dense": ("gpt2-small.yaml", []),
+                   "moe": ("olmoe-1b-7b.yaml", MOE_TINY)}[preset]
+    # a warm-up and a decay inside the run, so that the field moves
+    extra = extra + ["train.lr_warmup_iters=2", "train.lr_decay_iters=5"]
+    seen = _logged_run(extra, yaml)
+    schedule = make_lr_schedule(args_from_cli(
+        [os.path.join(ZOO, yaml)] + TINY + extra, mode="train_dist").train)
+    assert seen["printed"] == [_parents_line(it, metrics, schedule)
+                               for it, metrics, _ in seen["logged"]]
+    assert len(seen["printed"]) == ITERS
+    assert len({ln.split(" | ")[3] for ln in seen["printed"]}) > 2  # lr
+    # each leaf the line formats was copied behind its step, once
+    layers = sorted(seen["logged"][0][1].get("moe", {}))
+    assert seen["copies"] == ITERS * (2 + 3 * len(layers))
+    assert (preset == "moe") == bool(layers)
+    reg = seen["registry"]
+    for layer in layers:
+        tpe = seen["logged"][-1][1]["moe"][layer]["tokens_per_expert"]
+        assert reg.gauge("moe/rows_per_expert", layer=layer,
+                         stat="max").value == float(max(tpe))
+        for gauge in ("moe/aux_loss", "moe/z_loss", "moe/imbalance"):
+            assert reg.gauge(gauge, layer=layer).value > 0
