@@ -9,8 +9,10 @@ to these rules, the one here and the one a reference family exports
   optimizer update are not counted;
 * forward + backward = 3 x forward (each forward matmul has two in the
   backward pass);
-* attention is counted CAUSAL: a query at position i meets i + 1 keys, so
-  (S + 1) / 2 on average and not S;
+* attention is counted CAUSAL, and only where a stack has it: a query at
+  position i meets min(i + 1, window) keys, so (S + 1) / 2 on average and
+  not S where no window bites, in the blocks that attend and in no other
+  (``Sizes.attention``, from the family's ``attention_blocks``);
 * recomputation (per-layer remat, the flash backward's recomputed scores)
   is not counted: it is work the implementation chose, not work the model
   needs.
@@ -18,10 +20,12 @@ to these rules, the one here and the one a reference family exports
 The model's count comes from its family, because only the family knows its
 layer: ``gpt2`` and ``mistral`` return the dense count below; a family with
 experts adds its experts per token and its router, one with latent
-attention its own projections, from ``attention_flops_per_token`` and
-``head_flops_per_token`` and the keys of its configuration's file. The dense
-count refuses a program that has experts, so that a configuration cannot
-name a dense family and have one expert counted where a token uses several.
+attention its own projections, one whose blocks differ (a convolution here,
+a window there) one ``attention_flops_per_token(sizes, entry)`` a block that
+attends, from ``attention_flops_per_token`` and ``head_flops_per_token`` and
+the keys of its configuration's file. The dense count refuses a program that
+has experts, so that a configuration cannot name a dense family and have one
+expert counted where a token uses several.
 
 Where this differs from ``hetu_galvatron_tpu/observability/telemetry.py``
 and ``models/builder.py::model_flops_per_token``: those count the S x S
@@ -33,8 +37,32 @@ substring and returns ``None`` in silence for an unknown chip, where
 
 from __future__ import annotations
 
-from dataclasses import dataclass
-from typing import Any, Dict
+from dataclasses import dataclass, fields, replace
+from typing import Any, Dict, Iterable, Mapping, Optional, Tuple
+
+
+@dataclass(frozen=True)
+class Attention:
+    """One block that attends, as a family's ``attention_blocks`` states it.
+    0 = the model's own (``Sizes``): the whole causal span, its heads, its
+    key-value heads, its head width."""
+
+    window: int = 0        # keys a query meets at most, itself included
+    heads: int = 0
+    kv_heads: int = 0
+    qk_head_dim: int = 0   # what q.k^T contracts
+    v_head_dim: int = 0    # what p.v produces
+
+    @classmethod
+    def of(cls, entry: Mapping[str, int]) -> "Attention":
+        known = {f.name for f in fields(cls)}
+        bad = {k: v for k, v in entry.items() if k not in known
+               or not isinstance(v, int) or isinstance(v, bool) or v < 0}
+        if bad:
+            raise ValueError(
+                f"attention_blocks: an entry may hold {sorted(known)} as "
+                f"whole numbers from 0 on, this one holds {bad}")
+        return cls(**entry)
 
 
 @dataclass(frozen=True)
@@ -51,6 +79,9 @@ class Sizes:
     vocab: int          # published rows; padding rows are not required work
     seq: int
     experts: int = 0    # routed experts a layer; 0 for a dense MLP
+    # the blocks that attend, in order; None = every one of ``layers``, the
+    # whole causal span, the sizes above
+    attention: Optional[Tuple[Attention, ...]] = None
 
     @classmethod
     def of(cls, cfg: Any) -> "Sizes":
@@ -63,17 +94,51 @@ class Sizes:
             vocab=cfg.vocab_size, seq=cfg.seq_length,
             experts=cfg.num_experts)
 
+    def with_attention(self, entries: Iterable[Mapping[str, int]]) -> "Sizes":
+        """With a family's ``attention_blocks(config)``: one entry a block
+        of the stack as it is run that attends."""
+        blocks = tuple(Attention.of(e) for e in entries)
+        if len(blocks) > self.layers:
+            raise ValueError(
+                f"attention_blocks describes {len(blocks)} blocks that "
+                f"attend and the program runs {self.layers} blocks in all")
+        return replace(self, attention=blocks)
+
+    def attention_blocks(self) -> Tuple[Attention, ...]:
+        return ((Attention(),) * self.layers if self.attention is None
+                else self.attention)
+
 
 def causal_keys_per_query(seq: int) -> float:
     return (seq + 1) / 2.0
 
 
-def attention_flops_per_token(s: Sizes) -> float:
-    """q, k, v and output projections and causal attention, one block."""
-    qkv = 2 * s.hidden * (s.heads + 2 * s.kv_heads) * s.head_dim
-    out = 2 * s.heads * s.head_dim * s.hidden
-    # q.k^T and p.v, each 2 x head_dim per (query, key) pair and head
-    attn = 2 * 2 * s.heads * s.head_dim * causal_keys_per_query(s.seq)
+def causal_pairs(seq: int, window: int = 0) -> float:
+    """(query, key) pairs of one sequence: the sum over positions i of
+    min(i + 1, window); no window, or one of the whole sequence, leaves the
+    causal triangle."""
+    if not 0 < window < seq:
+        return seq * causal_keys_per_query(seq)
+    return float(window * (window + 1) // 2 + (seq - window) * window)
+
+
+def _block(s: Sizes, a: Attention) -> Tuple[int, int, int, int]:
+    """heads, key-value heads, q/k width and v width of one block."""
+    return (a.heads or s.heads, a.kv_heads or s.kv_heads,
+            a.qk_head_dim or s.head_dim, a.v_head_dim or s.head_dim)
+
+
+def attention_flops_per_token(s: Sizes, entry: Attention = Attention()
+                              ) -> float:
+    """q, k, v and output projections and causal attention, one block:
+    ``entry`` is the block's line of ``Sizes.attention_blocks()`` where it
+    differs from the model's sizes (a window, other heads or widths)."""
+    heads, kv_heads, qk, v = _block(s, entry)
+    qkv = 2 * s.hidden * (heads * qk + kv_heads * qk + kv_heads * v)
+    out = 2 * heads * v * s.hidden
+    # q.k^T contracts qk and p.v produces v, 2 each per (query, key) pair
+    # and head
+    attn = 2 * heads * (qk + v) * (causal_pairs(s.seq, entry.window) / s.seq)
     return qkv + out + attn
 
 
@@ -119,28 +184,35 @@ def mfu_pct(tokens_per_s: float, train_flops_per_token: float, chips: int,
 def flash_step_cost(s: Sizes, sequences: int, bytes_per_el: int = 2
                     ) -> Dict[str, float]:
     """Operations and HBM bytes that causal attention needs in one training
-    step over ``sequences`` sequences, all layers, forward and backward.
+    step over ``sequences`` sequences, every block that attends
+    (``Sizes.attention_blocks()``), forward and backward.
 
     Forward: two matmuls (q.k^T, p.v). Backward: five (the scores again,
     dp = do.v^T, dv = p^T.do, dq = ds.k, dk = ds^T.q); the recomputed
     scores are part of the flash algorithm's minimum, since it never keeps
-    them. What is NOT counted: the forward pass run a second time under
+    them. Four of the seven (q.k^T, the scores again, dq, dk) contract or
+    produce the q/k width, three (p.v, dp, dv) the v width. What is NOT
+    counted: the forward pass run a second time under
     per-layer remat, and the second recomputation that comes from splitting
     the backward into a dq kernel and a dk/dv kernel.
 
-    Bytes: every operand read once and every result written once. Forward
-    reads q, k, v and writes o and the row statistics; backward reads q, k,
-    v, o, do and the statistics and writes dq, dk, dv.
+    Bytes: every operand read once and every result written once, whatever
+    the window. Forward reads q, k, v and writes o and the row statistics;
+    backward reads q, k, v, o, do and the statistics and writes dq, dk, dv.
     """
-    pairs = sequences * s.seq * causal_keys_per_query(s.seq)  # (q, k) pairs
-    matmul = 2 * s.heads * s.head_dim * pairs
-    q_el = sequences * s.seq * s.heads * s.head_dim
-    kv_el = sequences * s.seq * s.kv_heads * s.head_dim
-    stats = sequences * s.seq * s.heads * 4          # float32 row statistics
-    fwd_bytes = (2 * q_el + 2 * kv_el) * bytes_per_el + stats
-    bwd_bytes = (4 * q_el + 4 * kv_el) * bytes_per_el + stats
-    return {"flops": s.layers * 7 * matmul,
-            "bytes": s.layers * (fwd_bytes + bwd_bytes)}
+    tokens = sequences * s.seq
+    cost = {"flops": 0.0, "bytes": 0}
+    for a in s.attention_blocks():
+        heads, kv_heads, qk, v = _block(s, a)
+        pairs = sequences * causal_pairs(s.seq, a.window)   # (q, k) pairs
+        # q and k are qk wide, v and o are v wide
+        io_el = tokens * (heads * qk + kv_heads * qk + kv_heads * v
+                          + heads * v)
+        stats = tokens * heads * 4                   # float32 row statistics
+        cost["flops"] += 2 * heads * (4 * qk + 3 * v) * pairs
+        # forward: q, k, v, o once; backward: those, and do, dq, dk, dv
+        cost["bytes"] += 3 * io_el * bytes_per_el + 2 * stats
+    return cost
 
 
 def roofline_least_s(cost: Dict[str, float], peaks: Dict[str, float],

@@ -21,12 +21,19 @@ what cannot happen: a program does not start before the host begins its
 ``DoEnqueueProgram`` (paired by ``run_id``), and the host's
 ``tpu::System::Execute=>Done`` for a core does not begin before that
 program ended (paired in order). The device's steps are shifted by the
-middle of that interval before they are laid over the spans; where the
-interval is empty, wider than ``MAX_OFFSET_WIDTH_NS`` or cannot be made,
-nothing is published. The shift moves time only between the two parts at
-a gap's ends (``gap_sync_ms``: the step's end to the host knowing it, and
-the wait for the next step to start once ``train/dispatch`` has returned;
-``gap_dispatch_ms``); the parts inside a gap are host clock alone.
+middle of that interval before they are laid over the spans (``place``).
+The shift moves time only between the two parts at a gap's ends
+(``gap_sync_ms``: the step's end to the host knowing it, and the wait for
+the next step to start once ``train/dispatch`` has returned;
+``gap_dispatch_ms``), by at most half the interval's width; the parts
+inside a gap are host clock alone. So an interval wider than
+``MAX_OFFSET_WIDTH_NS`` is still placed at its middle, with its width
+printed, and so is one that the stamps' own jitter has turned inside out
+by no more than ``STAMP_JITTER_NS``: an absent metric refuses a PR, and a
+few tenths of a millisecond moved between two of eight parts do not.
+Nothing is published where the interval cannot be made (no enqueue, or
+completions that do not pair with the programs) or is inverted by more
+than the stamps can jitter, which is no pairing either.
 
 After the shift the clocks are checked: every traced step starts on the
 device after its iteration's ``train/dispatch`` began and at most
@@ -51,7 +58,15 @@ PART_OF = {"train/sync": "sync", "train/lr": "lr", "train/log": "log",
 OTHER, UNNAMED = "other", "unnamed"
 PARTS = tuple(PART_OF.values()) + (OTHER, UNNAMED)
 CLOCK_SLACK_NS = 2e6
+# an interval wider than this is placed like any other and said to be wide:
+# five step programs bound the offset to 0.36 to 0.76 ms (26 traces, PR 31)
 MAX_OFFSET_WIDTH_NS = 1e6
+# How far one host stamp strays from another that bounds the same constant:
+# in the recorded trace beside the tests the eight tightest lower bounds
+# (of 55) lie within 46 us and so do the eight tightest upper ones, and the
+# one empty interval in 28 traced runs (PR 30) was inverted by 15.5 us with
+# every count paired. A mis-pairing is off by a program's length instead.
+STAMP_JITTER_NS = 50e3
 
 Span = Tuple[str, float, float, Optional[int]]   # name, start, end, step
 Step = Tuple[float, float]
@@ -184,6 +199,28 @@ def gap_parts_ms(steps: Sequence[Step], spans: Sequence[Span],
     return {p: sum(g[p] for g in gaps) / len(gaps) / 1e6 for p in PARTS}
 
 
+def place(bounds: Optional[Tuple[float, float]]
+          ) -> Tuple[Optional[float], str]:
+    """``(offset, rule)``: the middle of ``offset_bounds``' interval in ns
+    and which rule put it there (``bounded``, ``inverted``: inside out by
+    no more than ``STAMP_JITTER_NS``, ``wide``: over
+    ``MAX_OFFSET_WIDTH_NS``); ``(None, why)`` where there is no interval
+    or the two pairings contradict each other by more than stamps jitter."""
+    if bounds is None:
+        return None, "not bounded from both sides"
+    width = bounds[1] - bounds[0]
+    if width < -STAMP_JITTER_NS:
+        return None, (f"inverted by {-width / 1e3:.1f} us, more than the "
+                      f"stamps' jitter of {STAMP_JITTER_NS / 1e3:g} us")
+    if width < 0:
+        rule = "inverted"
+    elif width > MAX_OFFSET_WIDTH_NS:
+        rule = "wide"
+    else:
+        rule = "bounded"
+    return sum(bounds) / 2, rule
+
+
 def parts_of_trace(path: str, steps: Sequence[Step],
                    device: int = 0) -> Optional[Dict[str, float]]:
     """The eight parts of the mean gap from one ``.xplane.pb`` and the
@@ -192,13 +229,19 @@ def parts_of_trace(path: str, steps: Sequence[Step],
     if not planes.spans:
         return None
     bounds = offset_bounds(planes)
-    if bounds is None or not 0 <= bounds[1] - bounds[0] <= MAX_OFFSET_WIDTH_NS:
+    offset, rule = place(bounds)
+    counts = (f"{len(planes.programs)} programs, {len(planes.enqueued)} "
+              f"enqueues, {len(planes.done)} completions")
+    if offset is None:
         print("host_phases: no gap_* metric, the device's clock cannot be "
-              f"placed on the host's: bounds {bounds} ns from "
-              f"{len(planes.programs)} programs, {len(planes.enqueued)} "
-              f"enqueues, {len(planes.done)} completions", flush=True)
+              f"placed on the host's: {rule}: bounds {bounds} ns from "
+              f"{counts}", flush=True)
         return None
-    return gap_parts_ms(steps, planes.spans, sum(bounds) / 2)
+    print(f"host_phases: the device's clock placed {offset / 1e6:+.6f} ms "
+          f"onto the host's, rule {rule}: bounds "
+          f"{(bounds[1] - bounds[0]) / 1e6:.6f} ms apart from {counts}",
+          flush=True)
+    return gap_parts_ms(steps, planes.spans, offset)
 
 
 def _trace_dir(facts: Dict[str, Any]) -> Optional[str]:

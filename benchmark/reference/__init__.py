@@ -5,7 +5,7 @@
 architecture's published description in straightforward ``jax.numpy``
 (the shared pieces are in ``plain.py``), is fed weights under their public
 Hugging Face names, so it does not know the program's parameter tree, and
-exports two functions:
+exports two functions, and a third where its blocks differ:
 
 ``nll_sum(w, cfg, tokens, labels, *, layers=None)``
     the sum of the token negative log-likelihoods of ``tokens`` ->
@@ -17,6 +17,20 @@ exports two functions:
     ``flops.Sizes`` and the configuration's file, by the rules of
     ``benchmark/flops.py`` (matmuls only, attention causal, no
     recomputation); the harness multiplies by three for training.
+
+``attention_blocks(config)``, optional
+    one entry for each block of the configuration AS IT IS RUN that
+    attends, in order; a block that does not (a convolution, a scan) has
+    none. An entry is a dict that may hold ``window`` (the keys a query
+    meets at most, itself included; absent or 0 = the whole causal span)
+    and, where they differ from the model's, ``heads``, ``kv_heads``,
+    ``qk_head_dim`` and ``v_head_dim`` (``flops.Attention``). The harness
+    puts them into ``flops.Sizes.attention``, which ``flash_step_cost`` and
+    any cost function beside a metric sum over, and which the family's own
+    ``forward_flops_per_token`` adds up with
+    ``flops.attention_flops_per_token(sizes, entry)``. A family without the
+    export attends in every block, over the whole causal span, at the
+    model's sizes.
 
 A new architecture adds its file here and edits nothing.
 

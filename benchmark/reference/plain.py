@@ -34,13 +34,51 @@ def gelu_new(x):
         math.sqrt(2.0 / math.pi) * (x + 0.044715 * x ** 3)))
 
 
-def causal_attention(q, k, v):
-    """q, k, v: [B, heads, S, D] -> [B, heads, S, D]."""
-    S, D = q.shape[-2], q.shape[-1]
-    scores = jnp.einsum("bhsd,bhtd->bhst", q, k) / math.sqrt(D)
-    mask = jnp.tril(jnp.ones((S, S), bool))
+# Scores that may exist at once, in elements: what 32 heads over 4096
+# positions take in one call (2 GiB in float32, the largest the cells'
+# references have always made). A longer sequence is cut into blocks of
+# queries so that a block's scores against every key are no more than this.
+SCORE_ELEMENTS = 32 * 4096 * 4096
+
+
+def query_block(batch: int, heads: int, seq: int) -> int:
+    """Queries a call of ``causal_attention`` takes at once: all of them
+    where their scores fit ``SCORE_ELEMENTS``, else the largest power of two
+    that does."""
+    if batch * heads * seq * seq <= SCORE_ELEMENTS:
+        return seq
+    return 1 << max(0, (SCORE_ELEMENTS // (batch * heads * seq)).bit_length()
+                    - 1)
+
+
+def _attend(q, k, v, mask):
+    scores = jnp.einsum("bhsd,bhtd->bhst", q, k) / math.sqrt(q.shape[-1])
     scores = jnp.where(mask, scores, -jnp.inf)
     return jnp.einsum("bhst,bhtd->bhsd", jax.nn.softmax(scores, axis=-1), v)
+
+
+def causal_attention(q, k, v, window=None, block=None):
+    """q, k: [B, heads, S, D], v: [B, heads, S, Dv] -> [B, heads, S, Dv].
+    ``window``: the keys a query meets at most, itself included (``None`` or
+    0: the whole causal span). A sequence longer than ``block`` queries
+    (default ``query_block``) is taken a block at a time against the keys
+    that block may meet, so ``[B, heads, block, S]`` and never
+    ``[B, heads, S, S]`` exists; the rows of a softmax do not depend on each
+    other, so the blocks are the same mathematics as the one call."""
+    B, H, S, _ = q.shape
+    block = block or query_block(B, H, S)
+    if not window and block >= S:
+        return _attend(q, k, v, jnp.tril(jnp.ones((S, S), bool)))
+    out = []
+    for lo in range(0, S, block):
+        hi = min(lo + block, S)
+        first = max(0, lo - window + 1) if window else 0   # the oldest key
+        # key t of query s: not after it, and within the window
+        ahead = jnp.arange(first, hi)[None, :] - jnp.arange(lo, hi)[:, None]
+        mask = (ahead <= 0) & (ahead > -window) if window else ahead <= 0
+        out.append(_attend(q[:, :, lo:hi], k[:, :, first:hi],
+                           v[:, :, first:hi], mask))
+    return jnp.concatenate(out, axis=2)
 
 
 def split_heads(x, n):

@@ -69,8 +69,11 @@ def measure(cell, *, seed: int, seconds: float, trace: int, chip: dict,
                 f"{key}={cell.config[key]!r}")
     sizes = flops.Sizes.of(cfg)
     # the model's FLOPs are its family's; a family or an export that is not
-    # there, or a dense count for a program with experts, stops the run here
+    # there, a dense count for a program with experts, or more attending
+    # blocks described than the program runs, stops the run here
     family = reference.load_family(cell.config["reference"]["family"], root)
+    if hasattr(family, "attention_blocks"):
+        sizes = sizes.with_attention(family.attention_blocks(cell.config))
     train_flops = flops.train_from_forward(
         family.forward_flops_per_token(sizes, cell.config))
     sequences = args.parallel.global_train_batch_size

@@ -165,6 +165,68 @@ def test_the_device_clock_is_placed_between_enqueue_and_done(early_ms):
     assert parts["dispatch"] == pytest.approx(truth["dispatch"] + 0.075)
 
 
+@pytest.mark.parametrize("enqueue_ms,done_ms,rule,moved_ms", [
+    (0.05, 0.2, "bounded", 0.075),
+    # the stamps' jitter turned the interval inside out: PR 30's refused run
+    # read 15.5 us, with every count paired
+    (-0.0155 / 2, -0.0155 / 2, "inverted", 0.0),
+    (-0.03, -0.02, "inverted", 0.005),
+    # wider than MAX_OFFSET_WIDTH_NS: placed all the same, and said
+    (0.9, 0.9, "wide", 0.0),
+    (0.3, 1.1, "wide", 0.4),
+])
+def test_an_inverted_or_a_wide_interval_is_placed_at_its_middle(
+        monkeypatch, capsys, enqueue_ms, done_ms, rule, moved_ms):
+    """All eight parts are published and add up to the mean gap; the shift
+    moves time only between the parts at a gap's ends."""
+    steps, spans = _loop()
+    planes = _planes(steps, spans, 1.0, enqueue_ms, done_ms)
+    lower, upper = bounds = hp.offset_bounds(planes)
+    assert upper - lower == pytest.approx((enqueue_ms + done_ms) * MS)
+    offset, said = hp.place(bounds)
+    assert said == rule
+    assert offset == pytest.approx((1.0 + moved_ms) * MS)
+    monkeypatch.setattr(hp, "read_planes", lambda path, device=0: planes)
+    parts = hp.parts_of_trace(
+        "any.xplane.pb", [(s, e) for s, e, _ in planes.programs])
+    truth = hp.gap_parts_ms(steps, spans)
+    assert set(parts) == set(hp.PARTS) and len(parts) == 8
+    assert sum(parts.values()) == pytest.approx(sum(truth.values()))
+    for name in ("lr", "log", "other", "data", "h2d", "unnamed"):
+        assert parts[name] == pytest.approx(truth[name], abs=1e-9)
+    assert parts["sync"] + parts["dispatch"] == pytest.approx(
+        truth["sync"] + truth["dispatch"])
+    assert abs(parts["sync"] - truth["sync"]) <= abs(upper - lower) / 2 / MS \
+        + 1e-9
+    out = capsys.readouterr().out
+    assert f"rule {rule}: bounds {(upper - lower) / MS:.6f} ms apart" in out
+    assert out.count("\n") == 1
+
+
+@pytest.mark.parametrize("bounds,why", [
+    (None, "not bounded from both sides"),
+    # twice the jitter: two pairings that contradict each other
+    ((1.0 * MS, 0.9 * MS), "inverted by 100.0 us, more than the stamps'"),
+])
+def test_no_interval_or_one_inverted_beyond_the_jitter_publishes_nothing(
+        monkeypatch, capsys, bounds, why):
+    offset, said = hp.place(bounds)
+    assert offset is None and why in said
+    steps, spans = _loop()
+    planes = _planes(steps, spans, 1.0)
+    monkeypatch.setattr(hp, "read_planes", lambda path, device=0: planes)
+    monkeypatch.setattr(hp, "offset_bounds", lambda planes: bounds)
+    assert hp.parts_of_trace("any.xplane.pb", steps) is None
+    assert "no gap_* metric" in capsys.readouterr().out
+
+
+def test_the_one_empty_interval_of_pr_30_is_placed_now():
+    """``bounds (684410.0, 668882.0) ns from 55 programs, 55 enqueues, 55
+    completions`` (PERF.md section 7): the refused run's own line."""
+    assert hp.place((684410.0, 668882.0)) == (676646.0, "inverted")
+    assert 684410.0 - 668882.0 < hp.STAMP_JITTER_NS < hp.MAX_OFFSET_WIDTH_NS
+
+
 def test_bounds_need_both_sides_and_matching_counts():
     steps, spans = _loop()
     planes = _planes(steps, spans, 1.0)
@@ -328,9 +390,14 @@ def test_recorded_run_the_device_plane_runs_early_by_a_bounded_constant(
                for _, after_start, after_end, sync_after in shifted)
 
 
-def test_recorded_run_the_parts_are_the_ones_the_run_printed(recorded):
+def test_recorded_run_the_parts_are_the_ones_the_run_printed(recorded,
+                                                             capsys):
     path, steps = recorded
     parts = hp.parts_of_trace(path, steps)
+    assert capsys.readouterr().out == (
+        "host_phases: the device's clock placed +1.821138 ms onto the "
+        "host's, rule bounded: bounds 0.023087 ms apart from 55 programs, "
+        "55 enqueues, 55 completions\n")
     assert parts == pytest.approx({
         "sync": 0.769825625, "lr": 5.694835, "log": 1.0965425,
         "data": 0.1597475, "h2d": 1.0028375, "dispatch": 0.562925,

@@ -100,6 +100,17 @@ TINY_MIXTRAL = {
 }
 NEW_FAMILY_CELL = ("tiny_mixtral_c1", "tiny-mixtral", "tiny_c1", 1)
 
+# a stack whose blocks differ: the tiny Mistral program under a family that
+# says which blocks attend and over what span (tests/new_family/
+# tiny_hybrid.py). Both of its blocks attend, the second over a window of
+# the whole sequence, which is the mask the program (it has no window) runs
+TINY_HYBRID = {
+    **TINY_MISTRAL, "layer_types": ["attention", "sliding_attention"],
+    "sliding_window": 16,
+    "reference": {"family": "tiny_hybrid", "loss_tolerance": 0.02},
+}
+HYBRID_CELL = ("tiny_hybrid_c1", "tiny-hybrid", "tiny_c1", 1)
+
 # a stand-in row of peaks so the arithmetic runs; nothing is reported
 FAKE_CHIP = {"bf16_flops_per_s": 1e12, "hbm_bytes_per_s": 1e11,
              "hbm_bytes": 1e9, "ici_bits_per_s": 1e9}
@@ -185,4 +196,16 @@ def add_new_family(root: str) -> None:
         "name": "router_roofline", "unit": "%", "better": "higher",
         "source": "device_trace", "layer": "kernels", "moves": "mfu_pct",
         "workloads": [NEW_FAMILY_CELL[0]]})
+    _write(os.path.join(root, "BENCHMARK.json"), man)
+
+
+def add_hybrid_family(root: str, **config) -> None:
+    """A family that exports ``attention_blocks``, its configuration (keys
+    of ``config`` laid over ``TINY_HYBRID``) and a cell: new files and new
+    entries only."""
+    shutil.copy(os.path.join(NEW_FAMILY_DIR, "tiny_hybrid.py"),
+                manifest.family_path(root, "tiny_hybrid"))
+    man = manifest.load_manifest(root)
+    _add_config(root, man, "tiny-hybrid", {**TINY_HYBRID, **config})
+    _add_cell(man, *HYBRID_CELL)
     _write(os.path.join(root, "BENCHMARK.json"), man)
