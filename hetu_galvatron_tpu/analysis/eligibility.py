@@ -41,6 +41,7 @@ def compiled_schedule_unsupported_reason(
     uniform_strategies: bool = True,
     packed_docs: bool = False,
     qk_norm: bool = False,
+    mixed_stack: Optional[str] = None,
 ) -> Optional[str]:
     """None when the compiled 1F1B schedule can express a plan with these
     properties; otherwise the human-readable reason every caller logs.
@@ -60,6 +61,8 @@ def compiled_schedule_unsupported_reason(
         return "interleaved virtual stages (vpp > 1)"
     if model_type == "t5":
         return "encoder-decoder (a, b) pair carry"
+    if mixed_stack:
+        return mixed_stack
     if num_experts:
         return "MoE layers alternate tree structures across the stack"
     if len(set(pp_division)) > 1:
@@ -93,6 +96,9 @@ def compiled_unsupported_reason(cfg: Any, hpc: Any,
             getattr(data, "reset_position_ids", False)
             or getattr(data, "reset_attention_mask", False)),
         qk_norm=bool(getattr(cfg, "qk_norm", False)),
+        mixed_stack=mixed_stack_reason(
+            cfg, "the compiled pipeline engine (one stage-stacked tree "
+            "shape)"),
     )
 
 
@@ -127,6 +133,36 @@ def search_compiled_expressible(
 T5_REASON = "t5 encoder-decoder layers keep the GSPMD projection path"
 MOE_REASON = ("MoE layer: expert matmuls route through the ep/etp "
               "dispatcher, not the dense projections")
+
+
+CONV_REASON = ("conv block: the gated short convolution's projections are "
+               "not cut for the ring all-gather / reduce-scatter matmuls "
+               "(ops/overlap.py takes attention's qkv/out and the MLP's "
+               "fc1/fc2)")
+
+
+def mixed_stack_reason(cfg: Any, what: str, *,
+                       feed_forward_may_differ: bool = False
+                       ) -> Optional[str]:
+    """Why ``what`` (an engine, a profiler, the search) cannot take this
+    model: it names the block kinds of the per-layer description
+    (``ModelArgs.block_kinds``). None for a stack of attention blocks of one
+    kind (``feed_forward_may_differ``: or of dense and expert blocks, which
+    ``what`` tells apart itself). ``what`` prices, stacks or caches ONE
+    block shape and its mixer is attention, so another stack would be
+    mis-priced or crash; none may treat a conv block as attention in
+    silence."""
+    kinds = cfg.block_kinds()
+    shapes = {m if feed_forward_may_differ else (m, ff) for m, ff in kinds}
+    if len(shapes) <= 1 and all(m == "full_attention" for m, _ in kinds):
+        return None
+    from collections import Counter
+
+    said = ", ".join(f"{n} x {m}/{ff}"
+                     for (m, ff), n in Counter(kinds).items())
+    return (f"{what} takes a stack of one kind of block, with attention as "
+            f"its mixer; this model's per-layer description holds {said} "
+            "(mixer/feed-forward)")
 
 
 def overlap_unsupported_reason(
@@ -193,6 +229,9 @@ def plan_overlap_reasons(cfg: Any, hpc: Any) -> List:
             continue
         if is_moe_layer(cfg, i):
             out.append((i, MOE_REASON))
+            continue
+        if cfg.block_kinds(len(hpc.layers))[i][0] != "full_attention":
+            out.append((i, CONV_REASON))
             continue
         out.append((i, overlap_unsupported_reason(
             cfg, ulysses=s.sp, has_cp=s.cp_size > 1, tp=s.tp_size)))

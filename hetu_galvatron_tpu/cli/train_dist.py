@@ -172,18 +172,28 @@ def train(args) -> Dict[str, Any]:
         # the result so a run on the XLA core can never pass for a kernel run
         from collections import Counter
 
+        from hetu_galvatron_tpu.observability.registry import get_registry
         from hetu_galvatron_tpu.runtime.mesh import (
             attention_core,
             flash_kernel_runs,
         )
 
         use_flash = flash_kernel_runs(cfg.use_flash_attn, state.devices)
+        # a block that does not attend reports its own operator, so that
+        # "every core is flash" stays a statement about the blocks that do
+        kinds = cfg.block_kinds(len(hpc.layers))
         attention_cores = [
             attention_core(s.cp_size > 1, bool(s.sp and s.tp_size > 1),
-                           use_flash)
-            for s in hpc.layers]
+                           use_flash) if mixer == "full_attention"
+            else "short_conv"
+            for s, (mixer, _) in zip(hpc.layers, kinds)]
         state.log("attention cores: " + ", ".join(
             f"{n} x {core}" for core, n in Counter(attention_cores).items()))
+        # how many blocks of each mixer and feed-forward kind the step holds
+        blocks = {}
+        for (m, ff), n in Counter(kinds).items():
+            blocks[f"{m}/{ff}"] = n
+            get_registry().gauge("step/blocks", mixer=m, ff=ff).set(n)
 
         # abstract init first: the plan's shardings are derived from SHAPES, so
         # no device materializes the unsharded tree before they exist (the
@@ -653,7 +663,6 @@ def train(args) -> Dict[str, Any]:
     use_dropout = (cfg.hidden_dropout > 0.0 or cfg.attention_dropout > 0.0)
     drop_key = jax.random.key(args.train.seed) if use_dropout else None
     # what the compiled step contains (filled after the first step)
-    from hetu_galvatron_tpu.observability.registry import get_registry
     from hetu_galvatron_tpu.observability.trace_analysis import hlo_counts
 
     step_report: Dict[str, Any] = {}
@@ -1100,6 +1109,8 @@ def train(args) -> Dict[str, Any]:
                 state.log("step report: " + ", ".join(
                     f"{n} {op}" for op, n
                     in step_report["collectives"].items())
+                    + ", blocks " + " ".join(
+                        f"{n} x {kind}" for kind, n in blocks.items())
                     + f", {step_report['mosaic_custom_calls']} Mosaic calls,"
                     f" static live peak "
                     f"{step_report['static_memory']['live_peak'] / 2**30:.3f}"
@@ -1145,6 +1156,8 @@ def train(args) -> Dict[str, Any]:
                         "restarts_survived": goodput.restarts_survived},
             "flight_dumps": list(recorder.dumped) if recorder else [],
             "attention_cores": attention_cores,
+            # blocks by "<mixer>/<feed-forward>" kind (step/blocks gauges)
+            "blocks": blocks,
             # Mosaic kernels in the compiled step's HLO (pp=1), or summed
             # over the host engine's stage backward programs; None for
             # the compiled engine, which does not count them

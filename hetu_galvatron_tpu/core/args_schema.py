@@ -15,7 +15,7 @@ device-count fields describe chips in a `jax.sharding.Mesh`.
 
 from __future__ import annotations
 
-from typing import Any, Dict, List, Literal, Optional
+from typing import Any, Dict, List, Literal, Optional, Tuple
 
 from pydantic import BaseModel, Field, field_validator, model_validator
 
@@ -101,12 +101,71 @@ class ModelArgs(BaseModel):
     moe_norm_topk_prob: bool = True
     # which public names an expert layer is exported / imported under:
     # "mixtral" = block_sparse_moe.gate / experts.{e}.w1,w3,w2; "olmoe" =
-    # mlp.gate / mlp.experts.{e}.{gate,up,down}_proj
-    moe_hf_layout: Literal["mixtral", "olmoe"] = "mixtral"
+    # mlp.gate / mlp.experts.{e}.{gate,up,down}_proj; "lfm2" =
+    # feed_forward.gate / feed_forward.experts.{e}.w1,w3,w2 and
+    # feed_forward.expert_bias
+    moe_hf_layout: Literal["mixtral", "olmoe", "lfm2"] = "mixtral"
     # RMSNorm over the WHOLE projected q and k widths (all heads together,
     # one learned scale each), after the qkv product and before the split
     # into heads and RoPE (OLMoE; HF ``self_attn.{q,k}_norm``)
     qk_norm: bool = False
+    # the q/k RMSNorm per HEAD instead: one learned scale of head_dim a
+    # projection, applied to every head separately after the split into
+    # heads and before RoPE (LFM2; HF ``self_attn.{q,k}_layernorm``)
+    qk_norm_per_head: bool = False
+    # THE per-layer description (:meth:`block_kinds`) comes from these two
+    # published keys and ``moe_layer_freq``. ``layer_types``: each block's
+    # mixer, "full_attention" or "conv" (a gated short convolution,
+    # modules.apply_short_conv); None = every block attends.
+    # ``num_dense_layers``: so many leading blocks of an expert model keep
+    # a dense MLP of ``ffn_hidden_size``
+    layer_types: Optional[List[Literal["full_attention", "conv"]]] = None
+    num_dense_layers: int = 0
+    conv_L_cache: int = 3   # taps of a conv block's depthwise convolution
+    conv_bias: bool = False
+    # router scores: softmax over the experts (Mixtral, OLMoE), or a sigmoid
+    # each (LFM2, DeepSeek-V3): route_tokens says what each does with the
+    # bias, the renormalisation and its epsilon
+    moe_score_function: Literal["softmax", "sigmoid"] = "softmax"
+    moe_routed_scaling_factor: float = 1.0
+    # an expert layer that is told which experts it holds: the router keeps
+    # its ``num_experts`` outputs and its ``moe_topk`` a token, the layer's
+    # weights are ``[moe_held_experts, ...]`` and it computes exactly the
+    # routes that fall on [moe_first_held_expert, + moe_held_experts); what
+    # the absent experts would have added is left out. 0 = all of them
+    moe_held_experts: int = 0
+    moe_first_held_expert: int = 0
+    # which public names a block's norms, mixer and dense MLP carry:
+    # "llama" = input_layernorm / self_attn.o_proj / mlp.{gate,up,down}_proj;
+    # "lfm2" = operator_norm / ffn_norm / conv.* / self_attn.out_proj /
+    # feed_forward.w1,w3,w2 / model.embedding_norm
+    hf_layout: Literal["llama", "lfm2"] = "llama"
+
+    def block_kinds(self, n: Optional[int] = None
+                    ) -> Tuple[Tuple[str, str], ...]:
+        """The one per-layer description of a decoder stack: for each block
+        its mixer kind ("full_attention", "conv") and its feed-forward kind
+        ("dense", "experts"). The builder, the exporter, the launcher's
+        report and every engine's refusal read this and nothing else.
+        ``n``: the blocks a plan lists where that is not
+        ``num_hidden_layers`` (t5's two stacks, a pipeline stage's slice),
+        which only a model without ``layer_types`` can have."""
+        n = self.num_hidden_layers if n is None else n
+        mixers = self.layer_types or ["full_attention"] * n
+        if len(mixers) != n:
+            raise ValueError(
+                f"model.layer_types names {len(mixers)} blocks and "
+                f"the stack has {n} (model.num_hidden_layers is "
+                f"{self.num_hidden_layers})")
+        freq = max(self.moe_layer_freq, 1)
+        return tuple(
+            (m, "experts" if self.num_experts and i >= self.num_dense_layers
+             and (i + 1) % freq == 0 else "dense")
+            for i, m in enumerate(mixers))
+
+    @property
+    def held_experts(self) -> int:
+        return self.moe_held_experts or self.num_experts
 
     @property
     def kv_heads(self) -> int:

@@ -916,19 +916,31 @@ def model_flops_per_token(model: Any, seq_length: Optional[int] = None
     def mlp_flops(ffn: int) -> float:
         return (3 if gated else 2) * 2 * h * ffn
 
-    dense_layer = attn + mlp_flops(model.ffn_dim)
-    layers = model.num_hidden_layers + (model.num_encoder_layers or 0
-                                        if model.model_type == "t5" else 0)
+    dense_ff = mlp_flops(model.ffn_dim)
+    experts_ff = 0.0
     if model.num_experts:
         moe_ffn = model.moe_ffn_hidden_size or model.ffn_dim
-        active = model.moe_topk + model.num_shared_experts
-        moe_layer = (attn + 2 * h * model.num_experts  # router
-                     + active * mlp_flops(moe_ffn))
+        # a layer that holds a share of its experts computes its share of
+        # the routes
+        held = getattr(model, "held_experts", model.num_experts)
+        active = (model.moe_topk * held / model.num_experts
+                  + model.num_shared_experts)
+        experts_ff = (2 * h * model.num_experts  # router
+                      + active * mlp_flops(moe_ffn))
+    layers = model.num_hidden_layers + (model.num_encoder_layers or 0
+                                        if model.model_type == "t5" else 0)
+    if hasattr(model, "block_kinds"):
+        kinds = model.block_kinds(layers)
+    else:   # a duck-typed model: attention in all, experts every freq-th
         freq = max(model.moe_layer_freq, 1)
-        n_moe = layers // freq
-        fwd = n_moe * moe_layer + (layers - n_moe) * dense_layer
-    else:
-        fwd = layers * dense_layer
+        kinds = [("full_attention", "experts" if model.num_experts
+                  and (i + 1) % freq == 0 else "dense")
+                 for i in range(layers)]
+    # block by block, from the per-layer description: a conv block's
+    # in_proj (H x 3H) and out_proj (H x H) in place of attention
+    mixer = {"full_attention": attn, "conv": 2 * 4 * h * h}
+    fwd = sum(mixer[m] + (experts_ff if ff == "experts" else dense_ff)
+              for m, ff in kinds)
     fwd += 2 * h * model.padded_vocab_size  # LM head
     return 3.0 * fwd
 

@@ -48,6 +48,14 @@ class ModelProfiler:
         self.args = args
         self.devices = list(devices if devices is not None else jax.devices())
         self.prof = args.model_profiler
+        from hetu_galvatron_tpu.analysis.eligibility import mixed_stack_reason
+
+        reason = mixed_stack_reason(
+            args.model, "the model profiler (it times stacks of differing "
+            "depth and prices one block by their difference)",
+            feed_forward_may_differ=True)
+        if reason is not None:
+            raise NotImplementedError(reason)
 
     def _cfg(self, layernum: int, seq: int) -> ModelArgs:
         return self.args.model.model_copy(update={

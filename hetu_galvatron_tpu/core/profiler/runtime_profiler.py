@@ -249,6 +249,25 @@ class RuntimeProfiler:
             for name in sorted(metrics["moe"]):
                 st = metrics["moe"][name]
                 tpe = np.asarray(st["tokens_per_expert"], dtype=float)
+                if "rows_held" in st:
+                    # a layer that holds a share of its experts: the routes
+                    # that fell on them over all T*K, the rows its grouped
+                    # matmuls were handed against the rows that belonged to
+                    # a held expert (the cost of the static shape), and the
+                    # balance over the HELD experts, whose rows are the
+                    # matmuls' groups
+                    held, computed = (float(st["rows_held"]),
+                                      float(st["rows_computed"]))
+                    local = 100.0 * held / max(tpe.sum(), 1e-9)
+                    tpe = np.asarray(st["held_tokens_per_expert"],
+                                     dtype=float)
+                    bits.append(f"moe[{name}] local {local:.2f}% rows "
+                                f"{held:.0f}/{computed:.0f}")
+                    self.registry.gauge("moe/local_routes_pct",
+                                        layer=name).set(local)
+                    self.registry.gauge("moe/rows_held", layer=name).set(held)
+                    self.registry.gauge("moe/rows_computed",
+                                        layer=name).set(computed)
                 imb = float(tpe.max() / max(tpe.mean(), 1e-9))
                 aux = float(st["load_balance_loss"])
                 z = float(st["z_loss"])

@@ -43,9 +43,10 @@ def build_causal_lm_arch(cfg: ModelArgs) -> List[str]:
 def init_causal_lm(key: jax.Array, cfg: ModelArgs) -> Tuple[Params, Params]:
     """Returns (params, logical_axes) with layers as a per-layer tuple so the
     axes tree mirrors params exactly (required for tree-mapped shardings).
-    MoE models alternate dense/MoE layers per moe_layer_freq; t5 builds the
-    encoder-decoder pair (models/encdec.py)."""
-    from hetu_galvatron_tpu.models.moe import init_moe_decoder_layer, is_moe_layer
+    Each block's mixer and feed-forward kind come from the per-layer
+    description (``cfg.block_kinds()``); t5 builds the encoder-decoder pair
+    (models/encdec.py)."""
+    from hetu_galvatron_tpu.models.moe import init_moe_decoder_layer
 
     if cfg.model_type == "t5":
         from hetu_galvatron_tpu.models.encdec import init_encdec
@@ -56,9 +57,9 @@ def init_causal_lm(key: jax.Array, cfg: ModelArgs) -> Tuple[Params, Params]:
     keys = jax.random.split(key, n + 2)
     embed_p, embed_a = M.init_embedding(keys[0], cfg)
     layers = [
-        (init_moe_decoder_layer(keys[1 + i], cfg) if is_moe_layer(cfg, i)
-         else M.init_decoder_layer(keys[1 + i], cfg))
-        for i in range(n)
+        (init_moe_decoder_layer if ff == "experts"
+         else M.init_decoder_layer)(keys[1 + i], cfg, mixer)
+        for i, (mixer, ff) in enumerate(cfg.block_kinds())
     ]
     if cfg.post_norm:
         # post-norm families (bert) end each block already normalized; the
@@ -151,17 +152,24 @@ def forward_causal_lm(
         position_ids=position_ids)
     aux_total = jnp.zeros((), jnp.float32)
     moe_stats: Dict[str, Dict[str, jax.Array]] = {}
+    kinds = cfg.block_kinds()
+    if len(kinds) != len(params["layers"]):
+        raise ValueError(
+            f"the parameters hold {len(params['layers'])} blocks and the "
+            f"configuration describes {len(kinds)}")
     for i, lp in enumerate(params["layers"]):
         if boundary_fn is not None:
             x = boundary_fn(i, x)
-        kwargs: Dict[str, Any] = dict(rope=rope, compute_dtype=compute_dtype)
+        mixer, ff = kinds[i]
+        kwargs: Dict[str, Any] = dict(rope=rope, compute_dtype=compute_dtype,
+                                      mixer=mixer)
         if segment_ids is not None:
             kwargs["segment_ids"] = segment_ids
         if dropout_rng is not None:
             kwargs["dropout_rng"] = M.fold_dropout_rng(dropout_rng, cfg, i)
         if layer_overrides and i in layer_overrides:
             kwargs.update(layer_overrides[i])
-        if "moe" in lp:
+        if ff == "experts":
             fn = lambda p, h, kw=kwargs: apply_moe_decoder_layer(
                 p, h, cfg, **kw)
         else:

@@ -41,6 +41,12 @@ def _check_supported(cfg: ModelArgs, params: Params) -> None:
             "for t5 (encoder once + cached cross-attention decode)")
     if any("moe" in lp for lp in params["layers"]):
         raise NotImplementedError("generate(): dense layers only")
+    from hetu_galvatron_tpu.analysis.eligibility import mixed_stack_reason
+
+    reason = mixed_stack_reason(
+        cfg, "generate() (a key-value cache a block, no convolution state)")
+    if reason is not None:
+        raise NotImplementedError(reason)
 
 
 def _cached_sdpa(q, ck, cv, pos, shift=None):

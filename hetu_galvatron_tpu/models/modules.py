@@ -229,8 +229,12 @@ def init_attention(key: jax.Array, cfg: ModelArgs) -> Tuple[Params, Axes]:
                              "off), applied by apply_norm")
         # one scale over the whole projected width each; replicated (the
         # axis name is none of mesh.py's sharded ones), so under tp the
-        # mean over the sharded width is GSPMD's all-reduce
-        for name, width in (("q_norm", nq * hd), ("k_norm", nkv * hd)):
+        # mean over the sharded width is GSPMD's all-reduce. Per head
+        # (cfg.qk_norm_per_head) the scale is head_dim wide and the mean is
+        # over one head's values, local to whichever shard holds the head
+        widths = ((hd, hd) if cfg.qk_norm_per_head
+                  else (nq * hd, nkv * hd))
+        for name, width in zip(("q_norm", "k_norm"), widths):
             p[name] = {"scale": jnp.ones((width,), jnp.float32)}
             a[name] = {"scale": ("qk_norm",)}
     return p, a
@@ -383,13 +387,18 @@ def apply_attention(
             qkv, [g * hd, (g + 1) * hd], axis=-1))
     else:
         q, k, v = jnp.split(qkv, [nq * hd, (nq + nkv) * hd], axis=-1)
-    if "q_norm" in p:
+    per_head = cfg.qk_norm_per_head
+    if "q_norm" in p and not per_head:
         with jax.named_scope("attn/qk_norm"):
             q = apply_norm(p["q_norm"], q, cfg)
             k = apply_norm(p["k_norm"], k, cfg)
     q = q.reshape(B, S, nq, hd)
     k = k.reshape(B, S, nkv, hd)
     v = v.reshape(B, S, nkv, hd)
+    if "q_norm" in p and per_head:
+        with jax.named_scope("attn/qk_norm"):
+            q = apply_norm(p["q_norm"], q, cfg)
+            k = apply_norm(p["k_norm"], k, cfg)
     if group_major:
         q, k, v = (shard_fn(a, 2) for a in (q, k, v))
     if rope is not None:
@@ -443,6 +452,111 @@ def apply_attention(
     if "bo" in p:
         y = y + p["bo"]
     return y.astype(compute_dtype)
+
+
+# ---------------------------------------------------------------------------
+# gated short convolution (a mixer that is no attention)
+# ---------------------------------------------------------------------------
+
+
+def init_short_conv(key: jax.Array, cfg: ModelArgs) -> Tuple[Params, Axes]:
+    """LFM2's conv operator (HF ``Lfm2ShortConv``): ``win`` holds the three
+    thirds of ``in_proj`` (B, C, X) as ``[3, H, C]``, ``taps`` the depthwise
+    kernel ``[C, L]`` (``conv.conv.weight[:, 0, :]``), ``wout`` is
+    ``out_proj``. A depthwise convolution is per channel, so the channel
+    axis of every third, of the taps and of ``wout``'s rows shards over tp
+    together and nothing between the two projections leaves its shard."""
+    if cfg.conv_bias:
+        raise NotImplementedError(
+            "model.conv_bias: the conv operator is written without biases "
+            "(LFM2 publishes conv_bias false)")
+    h, L = cfg.hidden_size, cfg.conv_L_cache
+    k1, k2, k3 = jax.random.split(key, 3)
+    std = 0.02
+    p: Params = {
+        "win": _normal(k1, (3, h, h), std),
+        # the variance of torch's Conv1d default, U(+-1/sqrt(L)): at 0.02
+        # the operator's output would vanish beside the residual
+        "taps": _normal(k2, (h, L), 1.0 / math.sqrt(3 * L)),
+        "wout": _normal(k3, (h, h), std / math.sqrt(2 * cfg.num_hidden_layers)),
+    }
+    a: Axes = {"win": ("conv_gate", "embed", "mlp"),
+               "taps": ("mlp", "conv_tap"),
+               "wout": ("mlp", "embed")}
+    return p, a
+
+
+def apply_short_conv(
+    p: Params,
+    x: jax.Array,
+    cfg: ModelArgs,
+    compute_dtype=jnp.bfloat16,
+    shard_fn: Optional[Callable[[jax.Array, int], jax.Array]] = None,
+) -> jax.Array:
+    """``[B, C, X] = split3(x W_in)``; ``u = B * X``; ``c[t] = sum_j
+    taps[:, j] * u[t - (L - 1 - j)]``, causal, zero history before the
+    sequence; ``(C * c) W_out``. No softmax, no positions. The two
+    projections run in ``compute_dtype`` with float32 accumulation; the
+    gates and the taps between them are one elementwise pass in float32."""
+    B, S, H = x.shape
+    L = cfg.conv_L_cache
+    with jax.named_scope("mixer/short_conv"):
+        with jax.named_scope("in_proj"):
+            bcx = jnp.einsum("bsh,ghc->gbsc", x.astype(compute_dtype),
+                             p["win"].astype(compute_dtype),
+                             preferred_element_type=jnp.float32
+                             ).astype(compute_dtype)
+            thirds = [bcx[g] for g in range(3)]
+            if shard_fn is not None:
+                thirds = [shard_fn(t, 2) for t in thirds]
+        with jax.named_scope("gate_conv"):
+            gate_b, gate_c, xs = (t.astype(jnp.float32) for t in thirds)
+            u = gate_b * xs
+            taps = p["taps"].astype(jnp.float32)
+            c = u * taps[:, L - 1]
+            for back in range(1, L):
+                # u[t - back]: zeros before the sequence
+                shifted = jnp.pad(u, ((0, 0), (back, 0), (0, 0)))[:, :S]
+                c = c + shifted * taps[:, L - 1 - back]
+            y = (gate_c * c).astype(compute_dtype)
+            if shard_fn is not None:
+                y = shard_fn(y, 2)
+        with jax.named_scope("out_proj"):
+            out = jnp.einsum("bsc,ch->bsh", y,
+                             p["wout"].astype(compute_dtype),
+                             preferred_element_type=jnp.float32)
+    return out.astype(compute_dtype)
+
+
+def apply_mixer(
+    p: Params,
+    h: jax.Array,
+    cfg: ModelArgs,
+    mixer: str = "full_attention",
+    *,
+    compute_dtype=jnp.bfloat16,
+    shard_fn: Optional[Callable[[jax.Array, int], jax.Array]] = None,
+    segment_ids: Optional[jax.Array] = None,
+    **attn_kwargs: Any,
+) -> jax.Array:
+    """A block's operator on its normed input, by the block's mixer kind
+    (``ModelArgs.block_kinds``): attention from ``p["attn"]``, or the gated
+    short convolution from ``p["conv"]``, which takes no rope, no attention
+    core and no dropout of probabilities."""
+    if mixer == "full_attention":
+        return apply_attention(p["attn"], h, cfg, compute_dtype=compute_dtype,
+                               shard_fn=shard_fn, segment_ids=segment_ids,
+                               **attn_kwargs)
+    if mixer != "conv":
+        raise ValueError(f"unknown mixer kind {mixer!r} "
+                         "(full_attention | conv)")
+    if segment_ids is not None:
+        raise NotImplementedError(
+            "packed documents (segment_ids) through a conv block: the "
+            "convolution's two tokens of history would cross document "
+            "boundaries; set data.reset_attention_mask=false")
+    return apply_short_conv(p["conv"], h, cfg, compute_dtype=compute_dtype,
+                            shard_fn=shard_fn)
 
 
 # ---------------------------------------------------------------------------
@@ -556,15 +670,28 @@ def apply_mlp(p: Params, x: jax.Array, cfg: ModelArgs,
 # ---------------------------------------------------------------------------
 
 
-def init_decoder_layer(key: jax.Array, cfg: ModelArgs) -> Tuple[Params, Axes]:
+MIXER_KEYS = {"full_attention": "attn", "conv": "conv"}
+
+
+def init_mixer(key: jax.Array, cfg: ModelArgs,
+               mixer: str = "full_attention") -> Tuple[str, Params, Axes]:
+    """(the block's key for it, params, axes) of one mixer kind."""
+    init = {"full_attention": init_attention, "conv": init_short_conv}
+    if mixer not in init:
+        raise ValueError(f"unknown mixer kind {mixer!r} ({' | '.join(init)})")
+    return (MIXER_KEYS[mixer],) + init[mixer](key, cfg)
+
+
+def init_decoder_layer(key: jax.Array, cfg: ModelArgs,
+                       mixer: str = "full_attention") -> Tuple[Params, Axes]:
     k1, k2 = jax.random.split(key)
-    attn_p, attn_a = init_attention(k1, cfg)
+    name, mix_p, mix_a = init_mixer(k1, cfg, mixer)
     mlp_p, mlp_a = init_mlp(k2, cfg)
     ln1_p, ln1_a = init_norm(cfg)
     ln2_p, ln2_a = init_norm(cfg)
     return (
-        {"ln1": ln1_p, "attn": attn_p, "ln2": ln2_p, "mlp": mlp_p},
-        {"ln1": ln1_a, "attn": attn_a, "ln2": ln2_a, "mlp": mlp_a},
+        {"ln1": ln1_p, name: mix_p, "ln2": ln2_p, "mlp": mlp_p},
+        {"ln1": ln1_a, name: mix_a, "ln2": ln2_a, "mlp": mlp_a},
     )
 
 
@@ -580,6 +707,7 @@ def apply_decoder_layer(
     segment_ids: Optional[jax.Array] = None,
     matmul_fns: Optional[Dict[str, Callable]] = None,
     shard_fn: Optional[Callable[[jax.Array, int], jax.Array]] = None,
+    mixer: str = "full_attention",
 ) -> jax.Array:
     """Pre-norm residual block (reference GalvatronDecoderLayer,
     modules.py:233). Encoder families (bert, t5 encoder stack) run the same
@@ -589,7 +717,8 @@ def apply_decoder_layer(
     ``matmul_fns`` ({"qkv", "out", "fc1", "fc2"}) swaps the projection
     matmuls for overlapped tensor-parallel impls (ops/overlap.py);
     ``shard_fn`` keeps a tp > 1 layer's interior on its own shards
-    (:func:`apply_attention`)."""
+    (:func:`apply_attention`). ``mixer`` is the block's operator kind
+    (:func:`apply_mixer`; pre-norm blocks only)."""
     if causal is None:
         causal = cfg.model_type != "bert"
     r_attn = r_res1 = r_res2 = None
@@ -600,6 +729,10 @@ def apply_decoder_layer(
         return dropout(y, cfg.hidden_dropout, rng)
 
     if cfg.post_norm:
+        if mixer != "full_attention":
+            raise NotImplementedError(
+                f"a post-norm block with a {mixer!r} mixer: post-norm "
+                "families (bert) attend in every block")
         # HF BertLayer: residual-then-norm (attention.output.LayerNorm,
         # output.LayerNorm)
         x = apply_norm(
@@ -621,13 +754,13 @@ def apply_decoder_layer(
                                  shard_fn=shard_fn), r_res2),
             cfg)
     h = apply_norm(p["ln1"], x, cfg)
-    x = x + drop_h(apply_attention(p["attn"], h, cfg, rope=rope,
-                                   sdpa_fn=sdpa_fn,
-                                   compute_dtype=compute_dtype, causal=causal,
-                                   dropout_rng=r_attn,
-                                   segment_ids=segment_ids,
-                                   matmul_fns=matmul_fns,
-                                   shard_fn=shard_fn), r_res1)
+    x = x + drop_h(apply_mixer(p, h, cfg, mixer, rope=rope,
+                               sdpa_fn=sdpa_fn,
+                               compute_dtype=compute_dtype, causal=causal,
+                               dropout_rng=r_attn,
+                               segment_ids=segment_ids,
+                               matmul_fns=matmul_fns,
+                               shard_fn=shard_fn), r_res1)
     h = apply_norm(p["ln2"], x, cfg)
     x = x + drop_h(apply_mlp(p["mlp"], h, cfg, compute_dtype=compute_dtype,
                              matmul_fns=matmul_fns, shard_fn=shard_fn),

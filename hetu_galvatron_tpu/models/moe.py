@@ -43,12 +43,10 @@ Params = Dict[str, Any]
 
 
 def is_moe_layer(cfg: ModelArgs, layer_idx: int) -> bool:
-    """Dense/MoE alternation: every moe_layer_freq-th layer is MoE
-    (reference moe_layer_freq semantics, hf adapter layertype split)."""
-    if not cfg.num_experts:
-        return False
-    freq = max(cfg.moe_layer_freq, 1)
-    return (layer_idx + 1) % freq == 0
+    """Whether block ``layer_idx`` has experts, from the per-layer
+    description (``ModelArgs.block_kinds``: leading dense blocks, then
+    every moe_layer_freq-th; reference moe_layer_freq semantics)."""
+    return cfg.block_kinds()[layer_idx][1] == "experts"
 
 
 def moe_capacity(cfg: ModelArgs, tokens: int,
@@ -91,6 +89,14 @@ def route_tokens(
     bias steers WHICH experts are picked, never the combine weights);
     weights renormalized over the selected k (HF Mixtral convention)
     unless ``cfg.moe_norm_topk_prob`` is off (HF OLMoE).
+    Score function and epsilon by family (``cfg.moe_score_function``):
+    Mixtral: softmax, the k chosen divided by ``max(sum, 1e-9)``; OLMoE:
+    softmax, not renormalised, no epsilon; LFM2 (and DeepSeek-V3's form):
+    a sigmoid each at any k, the bias added to the SIGMOID for the choice
+    only, the k chosen unbiased sigmoids divided by ``sum + 1e-6`` where
+    ``moe_norm_topk_prob`` is on, then times
+    ``cfg.moe_routed_scaling_factor``. The bias's maintenance is the same
+    under both.
     sinkhorn: selection from a no-grad sinkhorn normalization; weights are
     sigmoid (k=1) / softmax (k>1) of the raw logits (reference
     sinkhorn_load_balancing; aux loss unsupported there)."""
@@ -124,7 +130,15 @@ def route_tokens(
                  "tokens_per_expert": jax.lax.stop_gradient(counts)}
         return topk_idx, w.astype(jnp.float32), aux, stats
 
-    probs = jax.nn.softmax(logits, axis=-1)  # [T, E]
+    sigmoid = cfg.moe_score_function == "sigmoid"
+    if sigmoid and cfg.moe_aux_loss_coeff:
+        raise ValueError(
+            "sigmoid router scores are not a distribution over the experts, "
+            "which the load-balancing term's P_e assumes; balance them by "
+            "the selection bias (moe_router_enable_expert_bias) and set "
+            "moe_aux_loss_coeff=0")
+    probs = (jax.nn.sigmoid(logits) if sigmoid
+             else jax.nn.softmax(logits, axis=-1))  # [T, E]
     select_scores = probs
     if "expert_bias" in p:
         select_scores = probs + jax.lax.stop_gradient(p["expert_bias"])
@@ -145,7 +159,12 @@ def route_tokens(
         term = jnp.sum(jax.lax.stop_gradient(-update) * p["expert_bias"])
         bias_term = term - jax.lax.stop_gradient(term)
     topk_probs = jnp.take_along_axis(probs, topk_idx, axis=-1)
-    if cfg.moe_norm_topk_prob:
+    if sigmoid:
+        if cfg.moe_norm_topk_prob:
+            topk_probs = topk_probs / (
+                jnp.sum(topk_probs, axis=-1, keepdims=True) + 1e-6)
+        topk_probs = topk_probs * cfg.moe_routed_scaling_factor
+    elif cfg.moe_norm_topk_prob:
         # renormalize over the selected k (HF Mixtral convention; the
         # reference's moe_router_topk_scaling path covers the same role).
         # Off (HF OLMoE, norm_topk_prob false) the raw softmax values
@@ -182,17 +201,36 @@ def update_expert_bias(expert_bias: jax.Array, tokens_per_expert: jax.Array,
     return expert_bias + update_rate * jnp.sign(err)
 
 
+def held_range(cfg: ModelArgs) -> Tuple[int, int]:
+    """(how many experts this layer holds, the first one's index)."""
+    held, first = cfg.held_experts, cfg.moe_first_held_expert
+    if not 0 <= first <= first + held <= cfg.num_experts:
+        raise ValueError(
+            f"experts [{first}, {first + held}) held of "
+            f"{cfg.num_experts}: moe_first_held_expert + moe_held_experts "
+            "must lie inside the router's width")
+    if held < cfg.num_experts and cfg.moe_dispatcher != "dropless":
+        raise NotImplementedError(
+            "an expert layer that holds a share of its experts "
+            "(moe_held_experts) runs the dropless dispatcher; the capacity "
+            "dispatcher lays out every expert's buffer")
+    return held, first
+
+
 def init_moe_mlp(key: jax.Array, cfg: ModelArgs) -> Tuple[Params, Params]:
     h = cfg.hidden_size
     f = cfg.moe_ffn_hidden_size or cfg.ffn_dim
     e = cfg.num_experts
+    # the experts this layer holds: the router is over all ``e``, the
+    # weights are the held ones' alone
+    held, _ = held_range(cfg)
     gated = M._is_gated(cfg.hidden_act)
     k1, k2, k3, k4 = jax.random.split(key, 4)
     std = 0.02
     p: Params = {
         "router": M._normal(k1, (h, e), std),
-        "win": M._normal(k2, (e, h, 2 * f if gated else f), std),
-        "wout": M._normal(k3, (e, f, h),
+        "win": M._normal(k2, (held, h, 2 * f if gated else f), std),
+        "wout": M._normal(k3, (held, f, h),
                           std / math.sqrt(2 * cfg.num_hidden_layers)),
     }
     a: Params = {
@@ -298,6 +336,57 @@ def _dropless_dispatch(
             ys * ws[:, None])
 
 
+def _held_dispatch(
+    p: Params, xt: jax.Array, topk_idx: jax.Array, w: jax.Array,
+    cfg: ModelArgs, compute_dtype,
+) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """The dropless dispatch of a layer that holds experts ``[first, first
+    + held)`` of the router's ``E``: it computes exactly the routes that
+    fall on them and leaves out what the absent experts would have added.
+
+    The static ``T*K`` slots sort by LOCAL expert id with every route to an
+    absent expert keyed ``held``, so the held experts' routes come first, in
+    groups, and the tail belongs to no group. A token can choose
+    ``min(K, held)`` held experts, so no static bound under ``T*K`` rows is
+    safe when ``held >= K``: the buffer keeps all ``T*K`` rows and no route
+    to a held expert is ever dropped, under any imbalance. The tail's rows
+    are zeroed going in and masked coming out, so that nothing the grouped
+    matmuls leave in rows outside their groups reaches the result or a
+    gradient. Returns (y [T, H] float32, stats): ``rows_held`` routes that
+    fell on a held expert, ``rows_computed`` rows the grouped matmuls were
+    handed, ``held_tokens_per_expert`` [held]."""
+    T, H = xt.shape
+    K = cfg.moe_topk
+    held, first = held_range(cfg)
+    with jax.named_scope("moe/dispatch"):
+        local = topk_idx.reshape(T * K) - first
+        mine = (local >= 0) & (local < held)
+        key = jnp.where(mine, local, held)
+        order = jnp.argsort(key, stable=True)
+        tok_sorted = (jnp.arange(T * K, dtype=jnp.int32) // K)[order]
+        mine_sorted = mine[order][:, None]
+        xs = jnp.where(mine_sorted, xt[tok_sorted].astype(compute_dtype), 0)
+        group_sizes = jnp.bincount(key, length=held + 1)[:held].astype(
+            jnp.int32)
+    with jax.named_scope("moe/experts"):
+        hproj = jax.lax.ragged_dot(xs, p["win"].astype(compute_dtype),
+                                   group_sizes,
+                                   preferred_element_type=jnp.float32)
+        hproj = _expert_act(hproj, cfg, compute_dtype)
+        ys = jax.lax.ragged_dot(hproj, p["wout"].astype(compute_dtype),
+                                group_sizes,
+                                preferred_element_type=jnp.float32)
+    with jax.named_scope("moe/combine"):
+        ws = w.reshape(T * K)[order]
+        y = jnp.zeros((T, H), jnp.float32).at[tok_sorted].add(
+            jnp.where(mine_sorted, ys * ws[:, None], 0.0))
+    stats = {
+        "rows_held": jnp.sum(group_sizes).astype(jnp.float32),
+        "rows_computed": jnp.asarray(xs.shape[0], jnp.float32),
+        "held_tokens_per_expert": group_sizes.astype(jnp.float32)}
+    return y, jax.lax.stop_gradient(stats)
+
+
 def apply_moe_mlp(
     p: Params,
     x: jax.Array,
@@ -315,7 +404,11 @@ def apply_moe_mlp(
     xt = x.reshape(B * S, H)
     with jax.named_scope("moe/route"):
         topk_idx, w, aux, stats = route_tokens(p, xt, cfg, compute_dtype)
-    if cfg.moe_dispatcher == "dropless":
+    if held_range(cfg)[0] < cfg.num_experts:
+        y, share_stats = _held_dispatch(p, xt, topk_idx, w, cfg,
+                                        compute_dtype)
+        stats = {**stats, **share_stats}
+    elif cfg.moe_dispatcher == "dropless":
         y = _dropless_dispatch(p, xt, topk_idx, w, cfg, compute_dtype)
     else:
         y = _capacity_dispatch(p, xt, topk_idx, w, cfg, compute_dtype,
@@ -326,16 +419,17 @@ def apply_moe_mlp(
     return y.reshape(B, S, H).astype(compute_dtype), aux, stats
 
 
-def init_moe_decoder_layer(key: jax.Array, cfg: ModelArgs
+def init_moe_decoder_layer(key: jax.Array, cfg: ModelArgs,
+                           mixer: str = "full_attention"
                            ) -> Tuple[Params, Params]:
     k1, k2 = jax.random.split(key)
-    attn_p, attn_a = M.init_attention(k1, cfg)
+    name, mix_p, mix_a = M.init_mixer(k1, cfg, mixer)
     moe_p, moe_a = init_moe_mlp(k2, cfg)
     ln1_p, ln1_a = M.init_norm(cfg)
     ln2_p, ln2_a = M.init_norm(cfg)
     return (
-        {"ln1": ln1_p, "attn": attn_p, "ln2": ln2_p, "moe": moe_p},
-        {"ln1": ln1_a, "attn": attn_a, "ln2": ln2_a, "moe": moe_a},
+        {"ln1": ln1_p, name: mix_p, "ln2": ln2_p, "moe": moe_p},
+        {"ln1": ln1_a, name: mix_a, "ln2": ln2_a, "moe": moe_a},
     )
 
 
@@ -348,18 +442,20 @@ def apply_moe_decoder_layer(
     compute_dtype=jnp.bfloat16,
     dropout_rng=None,
     segment_ids=None,
+    mixer: str = "full_attention",
 ) -> Tuple[jax.Array, jax.Array, Dict[str, jax.Array]]:
     """Pre-norm block with an MoE FFN; returns (x, aux_loss, router
     stats) — stats feed the per-layer balance tracker (reference
-    moe_utils.py:547-644)."""
+    moe_utils.py:547-644). ``mixer``: the block's operator kind
+    (modules.apply_mixer)."""
     r_attn = r_res1 = r_res2 = None
     if dropout_rng is not None:
         r_attn, r_res1, r_res2 = jax.random.split(dropout_rng, 3)
     h = M.apply_norm(p["ln1"], x, cfg)
     x = x + M.dropout(
-        M.apply_attention(p["attn"], h, cfg, rope=rope, sdpa_fn=sdpa_fn,
-                          compute_dtype=compute_dtype, dropout_rng=r_attn,
-                          segment_ids=segment_ids),
+        M.apply_mixer(p, h, cfg, mixer, rope=rope, sdpa_fn=sdpa_fn,
+                      compute_dtype=compute_dtype, dropout_rng=r_attn,
+                      segment_ids=segment_ids),
         cfg.hidden_dropout, r_res1)
     h = M.apply_norm(p["ln2"], x, cfg)
     y, aux, stats = apply_moe_mlp(p["moe"], h, cfg,

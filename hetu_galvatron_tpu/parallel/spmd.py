@@ -201,6 +201,7 @@ def tp_overlap_overrides(
     ``fallbacks`` lists (layer index, unsupported_reason) for layers the
     caller asked to overlap but could not — the launcher logs them."""
     from hetu_galvatron_tpu.analysis.eligibility import (
+        CONV_REASON,
         MOE_REASON,
         T5_REASON,
         layer_overlap_reason,
@@ -210,6 +211,7 @@ def tp_overlap_overrides(
     from hetu_galvatron_tpu.runtime.mesh import axes_size
 
     moe_of = is_moe_layer_fn or is_moe_layer
+    kinds = cfg.block_kinds(len(per_layer))
     out: Dict[int, Dict[str, Any]] = {}
     fallbacks: List[Tuple[int, str]] = []
     cache: Dict[Tuple, Dict[str, Any]] = {}
@@ -219,6 +221,9 @@ def tp_overlap_overrides(
             continue
         if moe_of(cfg, i):
             fallbacks.append((i, MOE_REASON))
+            continue
+        if kinds[i][0] != "full_attention":
+            fallbacks.append((i, CONV_REASON))
             continue
         tp_axes = sh.weight_tp_axes
         reason = layer_overlap_reason(cfg, sh, axes_size(mesh, tp_axes))
@@ -312,7 +317,10 @@ def interior_sharding(
     def param_view(params: Params) -> Params:
         layers = list(params["layers"])
         for i, sh in local.items():
-            attn, mlp = dict(layers[i]["attn"]), dict(layers[i]["mlp"])
+            # a conv block has no fused q | k | v to re-lay: its thirds are
+            # stored apart and shard by channel as they stand
+            attn = dict(layers[i].get("attn", {}))
+            mlp = dict(layers[i]["mlp"])
             for name, lead in (("wqkv", ("embed",)), ("bqkv", ())):
                 if name in attn:
                     attn[name] = relaid(
@@ -322,7 +330,8 @@ def interior_sharding(
                 if gated and name in mlp:
                     mlp[name] = relaid(sh, mlp[name], gate_up_pairs, lead,
                                        ("mlp",), ("pair", "mlp"))
-            layers[i] = {**layers[i], "attn": attn, "mlp": mlp}
+            layers[i] = {**layers[i], "mlp": mlp,
+                         **({"attn": attn} if attn else {})}
         return {**params, "layers": tuple(layers)}
 
     return ({i: {"shard_fn": make_shard_fn(sh)} for i, sh in local.items()},

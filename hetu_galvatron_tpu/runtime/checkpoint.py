@@ -17,6 +17,8 @@ with TP-slicing loaders (llama_adapter.py:51-163) falls out of GSPMD.
 from __future__ import annotations
 
 import json
+import contextlib
+import importlib.metadata
 import os
 import shutil
 import threading
@@ -27,7 +29,28 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 import jax
 import numpy as np
 
-import orbax.checkpoint as ocp
+
+@contextlib.contextmanager
+def _no_distribution_scan():
+    """While orbax is imported, ``importlib.metadata.packages_distributions``
+    answers with an empty map. orbax's cloud logger imports Google-cloud
+    stubs whose ``google.api_core.check_python_version`` calls it on import,
+    twice, only to word a warning it then does not issue (with an empty map
+    it names the import package instead of the distribution). The real one
+    reads the metadata and stats the files of every installed distribution:
+    4.5 and 22 to 28 s a call on a TPU VM, over half of a cached run's
+    set-up, and by how much depended on unrelated program text (PERF.md
+    section 6, PR 33)."""
+    real = importlib.metadata.packages_distributions
+    importlib.metadata.packages_distributions = dict
+    try:
+        yield
+    finally:
+        importlib.metadata.packages_distributions = real
+
+
+with _no_distribution_scan():
+    import orbax.checkpoint as ocp
 
 from hetu_galvatron_tpu.core.args_schema import ModelArgs
 from hetu_galvatron_tpu.runtime import ckpt_paths
@@ -881,7 +904,43 @@ _MOE_HF_NAMES = {
               "mlp.experts.{e}.gate_proj.weight",
               "mlp.experts.{e}.up_proj.weight",
               "mlp.experts.{e}.down_proj.weight"),
+    "lfm2": ("feed_forward.gate.weight",
+             "feed_forward.experts.{e}.w1.weight",
+             "feed_forward.experts.{e}.w3.weight",
+             "feed_forward.experts.{e}.w2.weight"),
 }
+# the selection bias of an expert layer, where the layout has a name for it
+_MOE_HF_BIAS = {"lfm2": "feed_forward.expert_bias"}
+
+# public names of a block's norms, attention projections, q/k norms, dense
+# MLP and of the final norm, by ``cfg.hf_layout``; a ``conv`` block's names
+# are LFM2's, the one published family that has one
+_BLOCK_HF_NAMES = {
+    "llama": {"ln1": "input_layernorm.weight",
+              "ln2": "post_attention_layernorm.weight",
+              "o": "self_attn.o_proj.weight",
+              "q_norm": "self_attn.q_norm.weight",
+              "k_norm": "self_attn.k_norm.weight",
+              "gate": "mlp.gate_proj.weight", "up": "mlp.up_proj.weight",
+              "down": "mlp.down_proj.weight", "final": "model.norm.weight"},
+    "lfm2": {"ln1": "operator_norm.weight", "ln2": "ffn_norm.weight",
+             "o": "self_attn.out_proj.weight",
+             "q_norm": "self_attn.q_layernorm.weight",
+             "k_norm": "self_attn.k_layernorm.weight",
+             "gate": "feed_forward.w1.weight", "up": "feed_forward.w3.weight",
+             "down": "feed_forward.w2.weight",
+             "final": "model.embedding_norm.weight"},
+}
+_CONV_HF_NAMES = ("conv.in_proj.weight", "conv.conv.weight",
+                  "conv.out_proj.weight")
+_MIXER_KINDS = ("full_attention", "conv")
+
+
+def _unknown_mixer(i: int, mixer: str) -> ValueError:
+    return ValueError(
+        f"block {i}: no public names for a {mixer!r} mixer; the exporter "
+        f"knows the mixer kinds {', '.join(_MIXER_KINDS)} and the "
+        "feed-forward kinds dense, experts")
 
 
 def hf_to_params(state_dict: Dict[str, Any], cfg: ModelArgs) -> Params:
@@ -943,54 +1002,68 @@ def hf_to_params(state_dict: Dict[str, Any], cfg: ModelArgs) -> Params:
         return sd[name].T
 
     router, gate, up, down = _MOE_HF_NAMES[cfg.moe_hf_layout]
+    names = _BLOCK_HF_NAMES[cfg.hf_layout]
     layers = []
-    for i in range(n):
+    for i, (mixer, ff) in enumerate(cfg.block_kinds()):
         pre = f"model.layers.{i}."
-        wqkv = np.concatenate(
-            [lin(pre + "self_attn.q_proj.weight"),
-             lin(pre + "self_attn.k_proj.weight"),
-             lin(pre + "self_attn.v_proj.weight")], axis=1)
-        lp = {
-            "ln1": {"scale": sd[pre + "input_layernorm.weight"]},
-            "attn": {"wqkv": wqkv, "wo": lin(pre + "self_attn.o_proj.weight")},
-            "ln2": {"scale": sd[pre + "post_attention_layernorm.weight"]},
-        }
-        if cfg.qk_norm:
-            lp["attn"]["q_norm"] = {
-                "scale": sd[pre + "self_attn.q_norm.weight"]}
-            lp["attn"]["k_norm"] = {
-                "scale": sd[pre + "self_attn.k_norm.weight"]}
-        if pre + router in sd:
+        lp = {"ln1": {"scale": sd[pre + names["ln1"]]},
+              "ln2": {"scale": sd[pre + names["ln2"]]}}
+        if mixer == "conv":
+            w_in, w_taps, w_out = (sd[pre + nm] for nm in _CONV_HF_NAMES)
+            # in_proj's rows are the thirds B | C | X; Conv1d's depthwise
+            # kernel is [channels, 1, taps]
+            lp["conv"] = {"win": np.stack([t.T for t in np.split(w_in, 3)]),
+                          "taps": w_taps[:, 0, :], "wout": w_out.T}
+        elif mixer == "full_attention":
+            lp["attn"] = {
+                "wqkv": np.concatenate(
+                    [lin(pre + "self_attn.q_proj.weight"),
+                     lin(pre + "self_attn.k_proj.weight"),
+                     lin(pre + "self_attn.v_proj.weight")], axis=1),
+                "wo": lin(pre + names["o"])}
+            if cfg.qk_norm:
+                lp["attn"]["q_norm"] = {"scale": sd[pre + names["q_norm"]]}
+                lp["attn"]["k_norm"] = {"scale": sd[pre + names["k_norm"]]}
+        else:
+            raise _unknown_mixer(i, mixer)
+        if ff == "experts":
             # MoE FFN (reference moe_adapter.py:58-266): each expert's gate
             # and up fuse into win [E, H, 2F], down -> wout [E, F, H]; the
-            # names are the layout's that cfg.moe_hf_layout says
+            # names are the layout's that cfg.moe_hf_layout says. A layer
+            # that holds a share finds its experts under their published
+            # indices
             if cfg.num_shared_experts:
                 raise NotImplementedError(
                     f"the {cfg.moe_hf_layout} HF layout "
                     f"({pre}{router}) has no shared-expert slot; "
                     "import with num_shared_experts=0")
-            E = 0
-            while pre + gate.format(e=E) in sd:
+            first, E = cfg.moe_first_held_expert, 0
+            while pre + gate.format(e=first + E) in sd:
                 E += 1
-            if E != cfg.num_experts:
+            if E != cfg.held_experts:
                 raise ValueError(
-                    f"layer {i}: checkpoint has {E} experts but "
-                    f"cfg.num_experts is {cfg.num_experts}")
+                    f"layer {i}: checkpoint has {E} experts"
+                    + (f" from expert {first} on" if first else "")
+                    + f" but cfg.num_experts is {cfg.num_experts}"
+                    + (f" and cfg.moe_held_experts {cfg.moe_held_experts}"
+                       if cfg.moe_held_experts else ""))
+            held = range(first, first + E)
             lp["moe"] = {
                 "router": lin(pre + router),
                 "win": np.stack([
                     np.concatenate([lin(pre + gate.format(e=e)),
                                     lin(pre + up.format(e=e))], axis=1)
-                    for e in range(E)]),
+                    for e in held]),
                 "wout": np.stack([lin(pre + down.format(e=e))
-                                  for e in range(E)]),
+                                  for e in held]),
             }
+            bias = _MOE_HF_BIAS.get(cfg.moe_hf_layout)
+            if cfg.moe_router_enable_expert_bias and bias:
+                lp["moe"]["expert_bias"] = sd[pre + bias]
         else:
             win = np.concatenate(
-                [lin(pre + "mlp.gate_proj.weight"),
-                 lin(pre + "mlp.up_proj.weight")], axis=1)
-            lp["mlp"] = {"win": win,
-                         "wout": lin(pre + "mlp.down_proj.weight")}
+                [lin(pre + names["gate"]), lin(pre + names["up"])], axis=1)
+            lp["mlp"] = {"win": win, "wout": lin(pre + names["down"])}
         if cfg.add_qkv_bias:
             lp["attn"]["bqkv"] = np.concatenate(
                 [sd[pre + "self_attn.q_proj.bias"],
@@ -1004,7 +1077,7 @@ def hf_to_params(state_dict: Dict[str, Any], cfg: ModelArgs) -> Params:
     out: Params = {
         "embed": {"wte": wte},
         "layers": tuple(layers),
-        "prenorm": {"scale": sd["model.norm.weight"]},
+        "prenorm": {"scale": sd[names["final"]]},
     }
     if cfg.tie_word_embeddings:
         out["head"] = {}
@@ -1313,26 +1386,39 @@ def params_to_hf(params: Params, cfg: ModelArgs) -> Dict[str, np.ndarray]:
     sd["model.embed_tokens.weight"] = get(params["embed"]["wte"])[:V]
     hd, nq, nkv = cfg.head_dim, cfg.num_attention_heads, cfg.kv_heads
     router, e_gate, e_up, e_down = _MOE_HF_NAMES[cfg.moe_hf_layout]
-    for i, lp in enumerate(params["layers"]):
+    names = _BLOCK_HF_NAMES[cfg.hf_layout]
+    kinds = cfg.block_kinds()
+    if len(kinds) != len(params["layers"]):
+        raise ValueError(
+            f"the parameters hold {len(params['layers'])} blocks and the "
+            f"configuration describes {len(kinds)}")
+    for i, (lp, (mixer, ff)) in enumerate(zip(params["layers"], kinds)):
         pre = f"model.layers.{i}."
-        wqkv = get(lp["attn"]["wqkv"])
-        q, k, v = np.split(wqkv, [nq * hd, (nq + nkv) * hd], axis=1)
-        sd[pre + "self_attn.q_proj.weight"] = q.T
-        sd[pre + "self_attn.k_proj.weight"] = k.T
-        sd[pre + "self_attn.v_proj.weight"] = v.T
-        sd[pre + "self_attn.o_proj.weight"] = get(lp["attn"]["wo"]).T
-        if "bqkv" in lp["attn"]:
-            bqkv = get(lp["attn"]["bqkv"])
-            bq, bk, bv = np.split(bqkv, [nq * hd, (nq + nkv) * hd])
-            sd[pre + "self_attn.q_proj.bias"] = bq
-            sd[pre + "self_attn.k_proj.bias"] = bk
-            sd[pre + "self_attn.v_proj.bias"] = bv
-        if "q_norm" in lp["attn"]:
-            sd[pre + "self_attn.q_norm.weight"] = \
-                get(lp["attn"]["q_norm"]["scale"])
-            sd[pre + "self_attn.k_norm.weight"] = \
-                get(lp["attn"]["k_norm"]["scale"])
-        if "moe" in lp:
+        if mixer == "conv":
+            n_in, n_taps, n_out = (pre + nm for nm in _CONV_HF_NAMES)
+            sd[n_in] = np.concatenate(
+                [t.T for t in get(lp["conv"]["win"])])
+            sd[n_taps] = get(lp["conv"]["taps"])[:, None, :]
+            sd[n_out] = get(lp["conv"]["wout"]).T
+        elif mixer == "full_attention":
+            wqkv = get(lp["attn"]["wqkv"])
+            q, k, v = np.split(wqkv, [nq * hd, (nq + nkv) * hd], axis=1)
+            sd[pre + "self_attn.q_proj.weight"] = q.T
+            sd[pre + "self_attn.k_proj.weight"] = k.T
+            sd[pre + "self_attn.v_proj.weight"] = v.T
+            sd[pre + names["o"]] = get(lp["attn"]["wo"]).T
+            if "bqkv" in lp["attn"]:
+                bqkv = get(lp["attn"]["bqkv"])
+                bq, bk, bv = np.split(bqkv, [nq * hd, (nq + nkv) * hd])
+                sd[pre + "self_attn.q_proj.bias"] = bq
+                sd[pre + "self_attn.k_proj.bias"] = bk
+                sd[pre + "self_attn.v_proj.bias"] = bv
+            if "q_norm" in lp["attn"]:
+                sd[pre + names["q_norm"]] = get(lp["attn"]["q_norm"]["scale"])
+                sd[pre + names["k_norm"]] = get(lp["attn"]["k_norm"]["scale"])
+        else:
+            raise _unknown_mixer(i, mixer)
+        if ff == "experts":
             if "shared" in lp["moe"]:
                 raise NotImplementedError(
                     f"the {cfg.moe_hf_layout} HF layout "
@@ -1341,20 +1427,25 @@ def params_to_hf(params: Params, cfg: ModelArgs) -> Dict[str, np.ndarray]:
             sd[pre + router] = get(lp["moe"]["router"]).T
             win = get(lp["moe"]["win"])
             wout = get(lp["moe"]["wout"])
-            for e in range(win.shape[0]):
-                w_gate, w_up = np.split(win[e], 2, axis=1)
+            # held experts go out under their published indices
+            for j in range(win.shape[0]):
+                e = cfg.moe_first_held_expert + j
+                w_gate, w_up = np.split(win[j], 2, axis=1)
                 sd[pre + e_gate.format(e=e)] = w_gate.T
                 sd[pre + e_up.format(e=e)] = w_up.T
-                sd[pre + e_down.format(e=e)] = wout[e].T
+                sd[pre + e_down.format(e=e)] = wout[j].T
+            bias = _MOE_HF_BIAS.get(cfg.moe_hf_layout)
+            if "expert_bias" in lp["moe"] and bias:
+                sd[pre + bias] = get(lp["moe"]["expert_bias"])
         else:
             win = get(lp["mlp"]["win"])
             gate, up = np.split(win, 2, axis=1)
-            sd[pre + "mlp.gate_proj.weight"] = gate.T
-            sd[pre + "mlp.up_proj.weight"] = up.T
-            sd[pre + "mlp.down_proj.weight"] = get(lp["mlp"]["wout"]).T
-        sd[pre + "input_layernorm.weight"] = get(lp["ln1"]["scale"])
-        sd[pre + "post_attention_layernorm.weight"] = get(lp["ln2"]["scale"])
-    sd["model.norm.weight"] = get(params["prenorm"]["scale"])
+            sd[pre + names["gate"]] = gate.T
+            sd[pre + names["up"]] = up.T
+            sd[pre + names["down"]] = get(lp["mlp"]["wout"]).T
+        sd[pre + names["ln1"]] = get(lp["ln1"]["scale"])
+        sd[pre + names["ln2"]] = get(lp["ln2"]["scale"])
+    sd[names["final"]] = get(params["prenorm"]["scale"])
     if not cfg.tie_word_embeddings and params.get("head"):
         sd["lm_head.weight"] = get(params["head"]["whead"]).T[:V]
     return sd

@@ -123,6 +123,14 @@ class PipelineEngine:
         hier_dp: bool = False,
         hier_bucket_mb: float = 0.0,
     ):
+        from hetu_galvatron_tpu.analysis.eligibility import mixed_stack_reason
+
+        reason = mixed_stack_reason(
+            cfg, "the host pipeline engine (its stage programs tell dense "
+            "and expert blocks apart by their trees and attend in every "
+            "block)", feed_forward_may_differ=True)
+        if reason is not None:
+            raise NotImplementedError(reason + "; run it at pp_deg=1")
         self.cfg = cfg
         self.hpc = hpc
         self.train = train

@@ -74,7 +74,7 @@ def make_train_step(
     ``aux_stats=True`` means loss_fn returns (loss, stats_pytree); the
     stats land in metrics["moe"] — the reference's per-layer aux-losses
     tracker (moe_utils.py:547-644). Loss-like stats are token-weighted
-    across microbatches; "tokens_per_expert" leaves are summed.
+    across microbatches; "tokens_per_expert" and "rows_*" leaves are summed.
 
     ``hier`` (an ``ops.hier_reduce.HierDpReducer``) swaps the implicit
     GSPMD dp gradient all-reduce for the explicit hierarchical path:
@@ -120,7 +120,9 @@ def make_train_step(
 
     def _reduce_stats(stacked, weights):
         def red(path, s):
-            if any("tokens_per_expert" in str(k) for k in path):
+            # counts add up over the microbatches; the rest are means
+            if any("tokens_per_expert" in str(k) or "rows_" in str(k)
+                   for k in path):
                 return jnp.sum(s, axis=0)
             w = weights.reshape((-1,) + (1,) * (s.ndim - 1))
             return jnp.sum(w * s, axis=0)

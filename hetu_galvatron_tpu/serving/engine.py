@@ -106,6 +106,13 @@ def _check_supported(cfg: ModelArgs, params: Params) -> None:
             "have no paged decode path")
     if any("moe" in lp for lp in params["layers"]):
         raise NotImplementedError("ServingEngine: dense layers only")
+    from hetu_galvatron_tpu.analysis.eligibility import mixed_stack_reason
+
+    reason = mixed_stack_reason(
+        cfg, "ServingEngine (paged key-value blocks for every layer, no "
+        "convolution state)")
+    if reason is not None:
+        raise NotImplementedError(reason)
 
 
 def default_buckets(block_size: int, cap_tokens: int) -> List[int]:

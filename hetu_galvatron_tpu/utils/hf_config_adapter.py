@@ -34,11 +34,12 @@ _FIELD_MAP = {
 }
 
 _GEMMA_FAMILIES = {"gemma"}
+_LFM2_FAMILIES = {"lfm2", "lfm2_moe"}
 _ROPE_FAMILIES = {"llama", "qwen2", "mistral", "mixtral", "olmoe",
-                  "qwen"} | _GEMMA_FAMILIES
+                  "qwen"} | _GEMMA_FAMILIES | _LFM2_FAMILIES
 _RMS_FAMILIES = _ROPE_FAMILIES | {"t5"}
 _SWIGLU_FAMILIES = {"llama", "qwen2", "mistral", "mixtral", "olmoe",
-                    "qwen"}
+                    "qwen"} | _LFM2_FAMILIES
 # gemma-2/3 add sandwich norms, logit softcapping, query_pre_attn_scalar,
 # alternating sliding windows (v3: q/k-norm, dual rope) — none of which this
 # stack implements; mapping them through gemma-1 numerics would silently
@@ -106,6 +107,46 @@ def populate_model_args_from_hf(
         values["moe_norm_topk_prob"] = bool(d.get("norm_topk_prob", False))
         if d.get("router_aux_loss_coef") is not None:
             values["moe_aux_loss_coeff"] = float(d["router_aux_loss_coef"])
+    if family in _LFM2_FAMILIES:
+        # LFM2 (LiquidAI; HF modeling_lfm2): conv and attention blocks by
+        # ``layer_types``, the q/k RMSNorm per head, its own names for the
+        # norms, the mixers and the MLP; ``lfm2_moe`` adds leading dense
+        # blocks, then sigmoid-routed experts with a selection bias
+        if d.get("layer_types") is None:
+            raise NotImplementedError(
+                f"{family}: config.json names no layer_types (which blocks "
+                "are conv and which attend)")
+        values.update(
+            hf_layout="lfm2", moe_hf_layout="lfm2", qk_norm=True,
+            qk_norm_per_head=True, layer_types=list(d["layer_types"]),
+            layernorm_epsilon=float(d.get("norm_eps", 1e-5)),
+            conv_L_cache=int(d.get("conv_L_cache", 3)),
+            conv_bias=bool(d.get("conv_bias", False)),
+            tie_word_embeddings=bool(d.get("tie_word_embeddings", True)))
+        theta = (d.get("rope_parameters") or {}).get("rope_theta")
+        if theta is not None:
+            values["rope_theta"] = float(theta)
+        if family == "lfm2" and d.get("block_auto_adjust_ff_dim", True):
+            # HF Lfm2MLP: two thirds of intermediate_size, times the
+            # multiplier, rounded up to block_multiple_of
+            ffn = int(2 * values["ffn_hidden_size"] / 3)
+            if d.get("block_ffn_dim_multiplier") is not None:
+                ffn = int(d["block_ffn_dim_multiplier"] * ffn)
+                m = int(d.get("block_multiple_of", 256))
+                ffn = m * ((ffn + m - 1) // m)
+            values["ffn_hidden_size"] = ffn
+        if family == "lfm2_moe":
+            values.update(
+                num_dense_layers=int(d.get("num_dense_layers", 0)),
+                moe_ffn_hidden_size=d.get("moe_intermediate_size"),
+                moe_score_function="sigmoid", moe_dispatcher="dropless",
+                moe_norm_topk_prob=bool(d.get("norm_topk_prob", True)),
+                moe_routed_scaling_factor=float(
+                    d.get("routed_scaling_factor", 1.0)),
+                moe_router_enable_expert_bias=bool(
+                    d.get("use_expert_bias", True)),
+                # trained with cross-entropy alone: no balancing-loss key
+                moe_aux_loss_coeff=0.0)
     if family == "bert":
         # HF bert uses erf gelu everywhere (BertIntermediate + the MLM
         # transform); our "gelu" is the tanh approximation (gpt2's gelu_new)
@@ -190,13 +231,21 @@ def model_layer_configs(model_args: ModelArgs) -> List[Dict[str, Any]]:
         dec = dict(base, seq_len=model_args.seq_length - half,
                    layer_num=model_args.num_hidden_layers)
         return ([enc] if n_enc else []) + [dec]
+    from hetu_galvatron_tpu.analysis.eligibility import mixed_stack_reason
+
+    reason = mixed_stack_reason(
+        model_args, "the model profiler and the search (one layer type "
+        "priced for a dense stack, two for dense and expert blocks)",
+        feed_forward_may_differ=True)
+    if reason is not None:
+        raise NotImplementedError(reason)
     if not model_args.num_experts:
         return [base]
-    # dense/MoE alternation: every moe_layer_freq-th layer is MoE, so layer_num
-    # is split between the two layertypes (never double-counted).
-    freq = max(model_args.moe_layer_freq, 1)
+    # dense and expert blocks, counted from the per-layer description
+    # (leading dense blocks, then every moe_layer_freq-th): layer_num is
+    # split between the two layertypes (never double-counted).
     n = model_args.num_hidden_layers
-    n_moe = n // freq
+    n_moe = sum(ff == "experts" for _, ff in model_args.block_kinds())
     if n_moe == 0:
         return [base]
     moe = dict(base)

@@ -487,3 +487,22 @@ def test_export_then_import_is_bit_exact(name):
         np.testing.assert_array_equal(
             np.asarray(leaf), np.asarray(flat[path]),
             err_msg=jax.tree_util.keystr(path))
+
+
+def test_orbax_is_imported_without_the_distribution_scan():
+    """``runtime/checkpoint.py`` answers ``packages_distributions`` with an
+    empty map only while orbax loads: afterwards the real function is back,
+    and it comes back when the import raises too."""
+    import importlib.metadata as metadata
+
+    from hetu_galvatron_tpu.runtime import checkpoint
+
+    real = metadata.packages_distributions
+    assert real is not dict and checkpoint.ocp.__name__ == "orbax.checkpoint"
+    with checkpoint._no_distribution_scan():
+        assert metadata.packages_distributions() == {}
+    assert metadata.packages_distributions is real
+    with pytest.raises(ImportError):
+        with checkpoint._no_distribution_scan():
+            raise ImportError("stands for an import that fails")
+    assert metadata.packages_distributions is real

@@ -4,8 +4,10 @@ gauges and the one histogram it writes; a run in which one is missing is
 refused on the chip as malformed. Here every such name is looked up the way
 the benchmark does it, through the readers' own code and tables (imported,
 never copied), after ``train_dist.main`` ran on the CPU at a tiny size: the
-dense preset, and an expert model with the dropless path of ``olmoe_c1_s4k``.
-One case a name, so a renamed span fails by its name."""
+dense preset, an expert model with the dropless path of ``olmoe_c1_s4k``, and
+a tiny LFM2 (conv and attention blocks, a held share of the experts) with
+the path of ``lfm2moe_c1_s8k``. One case a name, so a renamed span fails by
+its name."""
 
 import glob
 import json
@@ -26,7 +28,8 @@ ZOO = os.path.join(manifest.ROOT, "hetu_galvatron_tpu", "models", "configs")
 READERS = os.path.join(manifest.ROOT, "benchmark", "layer_metrics")
 host_phases = manifest.load_python(os.path.join(READERS, "host_phases.py"))
 # the files whose readers look into the program's registry, not the trace
-GAUGE_FILES = ("program_gauges.py", "moe_gauges.py")
+GAUGE_FILES = ("program_gauges.py", "moe_gauges.py", "lfm2_gauges.py")
+lfm2_gauges = manifest.load_python(os.path.join(READERS, "lfm2_gauges.py"))
 
 TRACED, MEASURED = 3, 2
 ITERS = window.WARMUP_STEPS + TRACED + MEASURED
@@ -41,9 +44,23 @@ PRESETS = {
     "moe": ["olmoe-1b-7b.yaml"] + SIZE + [
         "model.num_key_value_heads=2", "model.ffn_hidden_size=32",
         "model.num_experts=4", "model.moe_topk=2"],
+    "lfm2": ["lfm2-24b-a2b.yaml"] + SIZE + [
+        "model.num_hidden_layers=3",
+        "model.layer_types=[conv,full_attention,conv]",
+        "model.num_dense_layers=1", "model.num_key_value_heads=2",
+        "model.ffn_hidden_size=32", "model.moe_ffn_hidden_size=16",
+        "model.num_experts=8", "model.moe_topk=2",
+        "model.moe_held_experts=4", "model.moe_first_held_expert=0"],
 }
-# what a cell without an expert layer does not write, and is not asked for
-MOE_ONLY = {"moe_imbalance"}
+# what a cell without an expert layer does not write, and is not asked for;
+# and what only a layer that holds a share of its experts writes
+MOE_ONLY = {"moe_imbalance": ("moe",),
+            "lfm2_local_routes_pct": ("lfm2",),
+            "lfm2_moe_imbalance": ("lfm2",)}
+# the named scopes the HLO-metadata join will look for (PERF.md section 7)
+SCOPES = ("mixer/short_conv/in_proj", "mixer/short_conv/gate_conv",
+          "mixer/short_conv/out_proj", "attn/qk_norm", "moe/route",
+          "moe/dispatch", "moe/experts", "moe/combine")
 
 
 def _gauge_readers():
@@ -86,7 +103,7 @@ def run(request, tmp_path_factory):
         out = {}
         assert train_dist.main(argv, result=out) == 0
         assert len(out["losses"]) == ITERS
-        yield {"preset": request.param, "registry": reg,
+        yield {"preset": request.param, "registry": reg, "result": out,
                "trace": xplane.find_xplane(tdir),
                # the CPU's allocator states no limit; a chip's does
                "facts": {"memory": {"per_device": [{"bytes_limit": 2 ** 34}]}}}
@@ -105,10 +122,63 @@ def test_every_span_a_gap_is_cut_into_is_entered_each_iteration(run, path):
 def test_registry_reader_finds_what_the_program_wrote(run, name):
     assert get_registry() is run["registry"]
     value = GAUGE_READERS[name](run["facts"])
-    if name in MOE_ONLY and run["preset"] != "moe":
+    if name in MOE_ONLY and run["preset"] not in MOE_ONLY[name]:
         assert value is None     # looked up, never made by asking
     else:
         assert value is not None and value > 0
+
+
+@pytest.mark.parametrize("name", (lfm2_gauges.LOCAL_ROUTES_GAUGE,
+                                  lfm2_gauges.IMBALANCE_GAUGE)
+                         + lfm2_gauges.ROWS_GAUGES)
+def test_a_layer_that_holds_a_share_writes_its_gauges(run, name):
+    found = [m for m in run["registry"].metrics() if m.name == name
+             and m.labels.get("layer") == lfm2_gauges.FIRST_EXPERT_LAYER]
+    if run["preset"] == "lfm2":
+        assert len(found) == 1 and found[0].value > 0
+    elif name != lfm2_gauges.IMBALANCE_GAUGE:
+        assert not found
+
+
+@pytest.mark.parametrize("mixer,ff,blocks", [
+    ("conv", "dense", 1), ("full_attention", "experts", 1),
+    ("conv", "experts", 1)])
+def test_the_step_says_how_many_blocks_of_each_kind_it_holds(run, mixer, ff,
+                                                             blocks):
+    found = [m.value for m in run["registry"].metrics()
+             if m.name == "step/blocks"
+             and m.labels == {"mixer": mixer, "ff": ff}]
+    if run["preset"] == "lfm2":
+        assert found == [blocks]
+        assert run["result"]["blocks"][f"{mixer}/{ff}"] == blocks
+        assert run["result"]["attention_cores"].count("short_conv") == 2
+    else:
+        assert sum(run["result"]["blocks"].values()) == 2
+
+
+@pytest.mark.parametrize("scope", SCOPES)
+def test_the_lowered_step_carries_the_scope_names(scope):
+    """The HLO metadata of a tiny LFM2 loss carries every named scope a
+    per-layer metric will join on."""
+    import jax
+    import jax.numpy as jnp
+
+    from hetu_galvatron_tpu.core.arguments import args_from_cli
+    from hetu_galvatron_tpu.models.builder import (
+        causal_lm_loss,
+        init_causal_lm,
+    )
+
+    yaml, *size = PRESETS["lfm2"]
+    cfg = args_from_cli([os.path.join(ZOO, yaml)] + size,
+                        mode="train_dist").model
+    params = jax.eval_shape(lambda k: init_causal_lm(k, cfg)[0],
+                            jax.random.key(0))
+    tokens = jax.ShapeDtypeStruct((2, 16), jnp.int32)
+    text = jax.jit(lambda p, t: causal_lm_loss(
+        p, {"tokens": t, "labels": t}, cfg)).lower(params, tokens).as_text(
+            debug_info=True)
+    assert scope in text
 
 
 def test_iteration_spans_are_flat_siblings_on_the_dispatching_thread(run):

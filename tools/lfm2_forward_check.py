@@ -1,0 +1,237 @@
+#!/usr/bin/env python3
+"""The program's forward pass against the plain LFM2 reference at the
+published widths, token by token, with seven controls that must fail.
+
+    python3 tools/lfm2_forward_check.py [--seed N] [--workload lfm2moe_c1_s8k]
+
+The benchmark's ``correct`` compares ONE scalar (the step-0 loss), which at
+random weights sees a gated block only faintly. This looks closer, once,
+outside the harness: the cell's own weights for one seed and its first
+8192-token sequence go through ``forward_causal_lm`` (bfloat16, the flash
+core on the block that attends, the held experts' dropless dispatch: what
+the cell trains with) and through ``benchmark/reference/lfm2_moe.py``
+(float32 under ``jax.default_matmul_precision("highest")``), and the two
+sets of logits ``[8192, vocab]`` are compared. Then one thing is wrong at a
+time, on the side where it can be put wrong without touching the program:
+the convolution's taps reversed, softmax for sigmoid, the weights not
+renormalised, top-3 for top-4 and the held range shifted by one expert in
+the PROGRAM; the gate ``C`` left out in the REFERENCE. Each has to lie
+further from the other side than the tolerance the comparison holds itself
+to.
+
+The seventh control, the q/k norm over the whole width instead of a head,
+is judged on the attention OPERATOR alone and printed for the whole stack
+beside it. At random weights a head's RMS differs from the whole width's by
+a tenth (64 values), which moves the stack's logits by 0.6 % where bfloat16
+moves them by 1.5 %, so no limit on the stack's logits can hold the program
+and refuse that control (PERF.md section 6, PR 33). The operator sees it
+plainly: the reference's normed hidden states after the first block go
+through ``apply_attention`` (bfloat16, the flash core) and through the
+reference's ``attention`` (float32), per head and over the whole width.
+
+Prints one JSON object a line. Runs on whatever device JAX shows and takes
+no timing; the numbers that PERF.md quotes are from a TPU v5e.
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import sys
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+
+# The statistic is tools/olmoe_forward_check.py's: for every token the RMS
+# over the vocabulary of (program - reference) logits over the RMS of the
+# reference's logits, and of those 8192 numbers the MEDIAN (bfloat16 moves
+# the router's input, so a token whose fourth and fifth expert are nearly
+# tied picks the other one: a discrete change in a few per cent of the
+# tokens). The limit is written with its reason in PERF.md section 6
+# (PR 33): above what the chip reads for the program as published, with
+# room, and under the nearest control, with room.
+TOLERANCE = 0.022
+# the same statistic on the attention operator's output alone
+OPERATOR_TOLERANCE = 0.02
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--workload", default="lfm2moe_c1_s8k")
+    ap.add_argument("--seed", type=int, default=1)
+    a = ap.parse_args()
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from benchmark import check, manifest, reference
+    from hetu_galvatron_tpu.core.arguments import args_from_cli
+    from hetu_galvatron_tpu.models import modules as M
+    from hetu_galvatron_tpu.models.builder import (
+        forward_causal_lm,
+        init_causal_lm,
+    )
+    from hetu_galvatron_tpu.ops.pallas.flash_attention import flash_sdpa
+    from hetu_galvatron_tpu.utils.hf_config_adapter import resolve_model_config
+
+    cell = manifest.resolve_cell(manifest.load_manifest(), a.workload)
+    argv = manifest.train_argv(cell, a.seed)
+    cfg = resolve_model_config(args_from_cli(argv, mode="train_dist")).model
+    weights, tokens, labels = check.first_batch_and_weights(argv)
+    tokens, labels = jnp.asarray(tokens[:1]), jnp.asarray(labels[:1])
+    dev = jax.devices()[0]
+    print(json.dumps({"cell": cell.name, "seed": a.seed,
+                      "tokens": int(tokens.size), "platform": dev.platform,
+                      "device_kind": dev.device_kind,
+                      "tolerance_median_token_rel": TOLERANCE,
+                      "operator_tolerance_median_token_rel":
+                          OPERATOR_TOLERANCE}), flush=True)
+
+    family = reference.load_family(cell.config["reference"]["family"])
+    w32 = {k: jnp.asarray(v, jnp.float32) for k, v in weights.items()
+           if k != "extra_vocab_rows"}
+    del weights
+
+    def reference_logits():
+        @jax.jit
+        def run(w, t):
+            with jax.default_matmul_precision("highest"):
+                return family.logits(w, cell.config, t)[0]
+        return run(w32, tokens)
+
+    want = reference_logits()
+    published_conv = family.short_conv
+
+    def conv_without_gate_c(x, w, p, taps):
+        """``short_conv`` with ``C * c`` replaced by ``c``."""
+        S = x.shape[1]
+        gate_b, _, xs = jnp.split(x @ w[p + "in_proj.weight"].T, 3, axis=-1)
+        u, kernel = gate_b * xs, w[p + "conv.weight"][:, 0, :]
+        c = sum(kernel[:, j] * jnp.pad(
+            u, ((0, 0), (taps - 1 - j, 0), (0, 0)))[:, :S]
+            for j in range(taps))
+        return c @ w[p + "out_proj.weight"].T
+
+    family.short_conv = conv_without_gate_c
+    want_without_gate_c = reference_logits()
+    family.short_conv = published_conv
+
+    # the attention operator's input and what the reference makes of it
+    attending = cell.config["layer_types"].index("full_attention")
+
+    @jax.jit
+    def operator_reference(w, t):
+        with jax.default_matmul_precision("highest"):
+            a = family.hidden_states(w, cell.config, t, layers=attending)
+            return a, family.attention(
+                a, w, f"model.layers.{attending}.self_attn.", cell.config)
+
+    normed, want_attention = operator_reference(w32, tokens)
+    del w32
+
+    params = jax.jit(lambda k: init_causal_lm(k, cfg)[0])(
+        jax.random.key(a.seed))
+    sdpa = ({i: {"sdpa_fn": flash_sdpa} for i in range(cfg.num_hidden_layers)}
+            if dev.platform == "tpu" else None)
+
+    def program_logits(p, run_cfg):
+        return jax.jit(lambda p, t: forward_causal_lm(
+            p, t, run_cfg, compute_dtype=jnp.bfloat16,
+            layer_overrides=sdpa)[0, :, :cfg.vocab_size])(p, tokens)
+
+    def taps_reversed(p):
+        return {**p, "layers": tuple(
+            {**lp, "conv": {**lp["conv"], "taps": lp["conv"]["taps"][:, ::-1]}}
+            if "conv" in lp else lp for lp in p["layers"])}
+
+    def norm_scales_over_the_whole_width(p):
+        def tiled(attn):
+            return {**attn, "q_norm": {"scale": jnp.tile(
+                attn["q_norm"]["scale"], cfg.num_attention_heads)},
+                "k_norm": {"scale": jnp.tile(attn["k_norm"]["scale"],
+                                             cfg.kv_heads)}}
+        return {**p, "layers": tuple(
+            {**lp, "attn": tiled(lp["attn"])} if "attn" in lp else lp
+            for lp in p["layers"])}
+
+    def but(**update):
+        return cfg.model_copy(update=update)
+
+    def attention_operator(p, run_cfg):
+        rope = M.rope_cos_sin(tokens.shape[1], run_cfg.head_dim,
+                              run_cfg.rope_theta, scaling=run_cfg.rope_scaling)
+        kw = {"sdpa_fn": flash_sdpa} if dev.platform == "tpu" else {}
+        return jax.jit(lambda p, a: M.apply_attention(
+            p["layers"][attending]["attn"], a.astype(jnp.bfloat16), run_cfg,
+            rope=rope, compute_dtype=jnp.bfloat16, **kw))(p, normed)
+
+    def median_token_rel(got, ref):
+        got = got.astype(jnp.float32)
+        return float(jnp.median(
+            jnp.sqrt(jnp.mean(jnp.square(got - ref), axis=-1))
+            / jnp.sqrt(jnp.mean(jnp.square(ref), axis=-1))))
+
+    whole = (norm_scales_over_the_whole_width(params),
+             but(qk_norm_per_head=False))
+    ok = True
+    for name, (p, run_cfg) in (("attention_operator_as_published",
+                                (params, cfg)),
+                               ("attention_operator_qk_norm_over_the_whole_"
+                                "width", whole)):
+        rel = median_token_rel(attention_operator(p, run_cfg), want_attention)
+        inside = rel <= OPERATOR_TOLERANCE
+        ok &= inside == name.endswith("as_published")
+        print(json.dumps({"run": name, "median_token_rel_output_error": rel,
+                          "inside_tolerance": inside}), flush=True)
+
+    runs = (
+        ("as_published", params, cfg, want),
+        ("taps_reversed", taps_reversed(params), cfg, want),
+        ("gate_c_left_out_of_the_reference", params, cfg,
+         want_without_gate_c),
+        # printed, not judged: see the head of this file
+        ("qk_norm_over_the_whole_width_on_the_stack", *whole, want),
+        ("softmax_for_sigmoid", params, but(moe_score_function="softmax"),
+         want),
+        ("weights_not_renormalised", params, but(moe_norm_topk_prob=False),
+         want),
+        ("top_3_for_top_4", params, but(moe_topk=cfg.moe_topk - 1), want),
+        ("held_range_shifted_by_one", params,
+         but(moe_first_held_expert=cfg.moe_first_held_expert + 1), want),
+    )
+    for name, p, run_cfg, ref in runs:
+        got = program_logits(p, run_cfg)
+        ref_nll = -jnp.take_along_axis(jax.nn.log_softmax(ref, axis=-1),
+                                       labels[0][:, None], axis=-1)[:, 0]
+        nll = -jnp.take_along_axis(jax.nn.log_softmax(got, axis=-1),
+                                   labels[0][:, None], axis=-1)[:, 0]
+        per_token = (jnp.sqrt(jnp.mean(jnp.square(got - ref), axis=-1))
+                     / jnp.sqrt(jnp.mean(jnp.square(ref), axis=-1)))
+        rel = float(jnp.median(per_token))
+        inside = rel <= TOLERANCE
+        if not name.endswith("on_the_stack"):
+            ok &= inside == (name == "as_published")
+        scale = float(jnp.sqrt(jnp.mean(jnp.square(ref))))
+        print(json.dumps({
+            "run": name, "median_token_rel_logit_error": rel,
+            "inside_tolerance": inside,
+            "p10_token_rel_logit_error": float(jnp.percentile(per_token, 10)),
+            "p90_token_rel_logit_error": float(jnp.percentile(per_token, 90)),
+            "rel_rms_logit_error": float(jnp.sqrt(jnp.mean(jnp.square(
+                got - ref)))) / scale,
+            "max_abs_logit_error": float(jnp.max(jnp.abs(got - ref))),
+            "reference_logit_rms": scale,
+            "mean_abs_token_nll_error": float(jnp.mean(jnp.abs(
+                nll - ref_nll))),
+            "mean_nll_program": float(jnp.mean(nll)),
+            "mean_nll_reference": float(jnp.mean(ref_nll)),
+            "argmax_agreement": float(np.mean(np.asarray(
+                jnp.argmax(got, -1) == jnp.argmax(ref, -1))))}), flush=True)
+    print(json.dumps({"ok": bool(ok)}), flush=True)
+    return 0 if ok else 1
+
+
+if __name__ == "__main__":
+    sys.exit(main())
