@@ -251,9 +251,10 @@ class RuntimeProfiler:
                 tpe = np.asarray(st["tokens_per_expert"], dtype=float)
                 if "rows_held" in st:
                     # a layer that holds a share of its experts: the routes
-                    # that fell on them over all T*K, the rows its grouped
-                    # matmuls were handed against the rows that belonged to
-                    # a held expert (the cost of the static shape), and the
+                    # that fell on them over all T*K, the rows the body it
+                    # took handed to its grouped matmuls against the rows
+                    # that belonged to a held expert, the share of the
+                    # microbatches that took the short body, and the
                     # balance over the HELD experts, whose rows are the
                     # matmuls' groups
                     held, computed = (float(st["rows_held"]),
@@ -268,6 +269,9 @@ class RuntimeProfiler:
                     self.registry.gauge("moe/rows_held", layer=name).set(held)
                     self.registry.gauge("moe/rows_computed",
                                         layer=name).set(computed)
+                    self.registry.gauge(
+                        "moe/short_dispatch_pct", layer=name).set(
+                            100.0 * float(st["short_dispatch"]))
                 imb = float(tpe.max() / max(tpe.mean(), 1e-9))
                 aux = float(st["load_balance_loss"])
                 z = float(st["z_loss"])

@@ -29,6 +29,7 @@ sigmoid/softmax of the raw logits — reference sinkhorn_load_balancing).
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Any, Dict, Optional, Tuple
 
@@ -336,6 +337,74 @@ def _dropless_dispatch(
             ys * ws[:, None])
 
 
+# the margin over the expected share of the routes that the short buffer of
+# a layer that holds a share leaves, and the granularity of its row count
+# (a sublane tile)
+_SHORT_MARGIN, _ROW_TILE = 2, 8
+
+
+def short_rows(slots: int, held: int, num_experts: int) -> int:
+    """Rows of the short buffer of a layer that holds ``held`` of
+    ``num_experts`` experts and has ``slots`` = T*K routes: the expected
+    share of the routes times a margin of 2, rounded up to a row tile, and
+    never above ``slots`` (where it reaches ``slots`` the layer has one
+    body)."""
+    expected = -(-slots * held // num_experts)
+    return min(slots, -(-_SHORT_MARGIN * expected // _ROW_TILE) * _ROW_TILE)
+
+
+def _sorted_rows_mlp(rows: int, cfg: ModelArgs, compute_dtype, xt, win, wout,
+                     ws, tok_sorted, mine_sorted, group_sizes):
+    """The expert MLPs over the first ``rows`` of the sorted slots: gather,
+    grouped matmuls, activation, weighting, scatter-add. Rows of the prefix
+    that belong to no group are zeroed going in and masked coming out, so
+    that nothing the grouped matmuls leave there reaches the result or a
+    gradient."""
+    tok, mine = tok_sorted[:rows], mine_sorted[:rows, None]
+    with jax.named_scope("moe/dispatch"):
+        xs = jnp.where(mine, xt[tok].astype(compute_dtype), 0)
+    with jax.named_scope("moe/experts"):
+        hproj = jax.lax.ragged_dot(xs, win.astype(compute_dtype),
+                                   group_sizes,
+                                   preferred_element_type=jnp.float32)
+        hproj = _expert_act(hproj, cfg, compute_dtype)
+        ys = jax.lax.ragged_dot(hproj, wout.astype(compute_dtype),
+                                group_sizes,
+                                preferred_element_type=jnp.float32)
+    with jax.named_scope("moe/combine"):
+        return jnp.zeros(xt.shape, jnp.float32).at[tok].add(
+            jnp.where(mine, ys * ws[:rows, None], 0.0))
+
+
+def _short_or_full(short_body, full_body):
+    """``lax.cond`` between two bodies of one signature, with one backward
+    pass of its own: the forward keeps the operands alone, and the backward
+    is another ``cond`` whose branch recomputes and transposes its own body.
+    Plain reverse mode would have the forward return the residuals of BOTH
+    bodies, zero-filled for the one not taken: the full body's rows written
+    as zeros on the short path, which is the traffic the short body is
+    there to save."""
+    @jax.custom_vjp
+    def either(short, operands, slots):
+        return jax.lax.cond(short, lambda ops: short_body(*ops, *slots),
+                            lambda ops: full_body(*ops, *slots), operands)
+
+    def forward(short, operands, slots):
+        return either(short, operands, slots), (short, operands, slots)
+
+    def backward(saved, g):
+        short, operands, slots = saved
+
+        def pulled_back(body):
+            return lambda ops, g: jax.vjp(
+                lambda *o: body(*o, *slots), *ops)[1](g)
+        return None, jax.lax.cond(short, pulled_back(short_body),
+                                  pulled_back(full_body), operands, g), None
+
+    either.defvjp(forward, backward)
+    return either
+
+
 def _held_dispatch(
     p: Params, xt: jax.Array, topk_idx: jax.Array, w: jax.Array,
     cfg: ModelArgs, compute_dtype,
@@ -348,41 +417,50 @@ def _held_dispatch(
     absent expert keyed ``held``, so the held experts' routes come first, in
     groups, and the tail belongs to no group. A token can choose
     ``min(K, held)`` held experts, so no static bound under ``T*K`` rows is
-    safe when ``held >= K``: the buffer keeps all ``T*K`` rows and no route
-    to a held expert is ever dropped, under any imbalance. The tail's rows
-    are zeroed going in and masked coming out, so that nothing the grouped
-    matmuls leave in rows outside their groups reaches the result or a
-    gradient. Returns (y [T, H] float32, stats): ``rows_held`` routes that
-    fell on a held expert, ``rows_computed`` rows the grouped matmuls were
-    handed, ``held_tokens_per_expert`` [held]."""
-    T, H = xt.shape
+    safe when ``held >= K``: no route to a held expert is ever dropped,
+    under any imbalance. What the layer moves follows the COUNT instead:
+    where the routes that fell on a held expert fit the first
+    :func:`short_rows` sorted slots (decided on the device, a step and
+    microbatch at a time), gather, grouped matmuls and scatter-add run over
+    that prefix alone; where they do not, over all ``T*K``. A layer whose
+    short buffer would be the whole one has the one body. Returns (y [T, H]
+    float32, stats): ``rows_held`` routes that fell on a held expert,
+    ``rows_computed`` rows the body TAKEN handed to the grouped matmuls,
+    ``short_dispatch`` 1.0 where that was the short one,
+    ``held_tokens_per_expert`` [held]."""
+    T, _ = xt.shape
     K = cfg.moe_topk
     held, first = held_range(cfg)
+    short_len = short_rows(T * K, held, cfg.num_experts)
     with jax.named_scope("moe/dispatch"):
         local = topk_idx.reshape(T * K) - first
         mine = (local >= 0) & (local < held)
         key = jnp.where(mine, local, held)
         order = jnp.argsort(key, stable=True)
         tok_sorted = (jnp.arange(T * K, dtype=jnp.int32) // K)[order]
-        mine_sorted = mine[order][:, None]
-        xs = jnp.where(mine_sorted, xt[tok_sorted].astype(compute_dtype), 0)
+        mine_sorted = mine[order]
         group_sizes = jnp.bincount(key, length=held + 1)[:held].astype(
             jnp.int32)
-    with jax.named_scope("moe/experts"):
-        hproj = jax.lax.ragged_dot(xs, p["win"].astype(compute_dtype),
-                                   group_sizes,
-                                   preferred_element_type=jnp.float32)
-        hproj = _expert_act(hproj, cfg, compute_dtype)
-        ys = jax.lax.ragged_dot(hproj, p["wout"].astype(compute_dtype),
-                                group_sizes,
-                                preferred_element_type=jnp.float32)
+        rows_held = jnp.sum(group_sizes)
     with jax.named_scope("moe/combine"):
         ws = w.reshape(T * K)[order]
-        y = jnp.zeros((T, H), jnp.float32).at[tok_sorted].add(
-            jnp.where(mine_sorted, ys * ws[:, None], 0.0))
+    operands = (xt, p["win"], p["wout"], ws)
+    slots = (tok_sorted, mine_sorted, group_sizes)
+    full_body = functools.partial(_sorted_rows_mlp, T * K, cfg, compute_dtype)
+    if short_len < T * K:
+        short = rows_held <= short_len
+        y = _short_or_full(
+            functools.partial(_sorted_rows_mlp, short_len, cfg,
+                              compute_dtype),
+            full_body)(short, operands, slots)
+    else:
+        short = jnp.zeros((), bool)
+        y = full_body(*operands, *slots)
     stats = {
-        "rows_held": jnp.sum(group_sizes).astype(jnp.float32),
-        "rows_computed": jnp.asarray(xs.shape[0], jnp.float32),
+        "rows_held": rows_held.astype(jnp.float32),
+        "rows_computed": jnp.where(short, short_len, T * K).astype(
+            jnp.float32),
+        "short_dispatch": short.astype(jnp.float32),
         "held_tokens_per_expert": group_sizes.astype(jnp.float32)}
     return y, jax.lax.stop_gradient(stats)
 

@@ -74,7 +74,9 @@ def make_train_step(
     ``aux_stats=True`` means loss_fn returns (loss, stats_pytree); the
     stats land in metrics["moe"] — the reference's per-layer aux-losses
     tracker (moe_utils.py:547-644). Loss-like stats are token-weighted
-    across microbatches; "tokens_per_expert" and "rows_*" leaves are summed.
+    across microbatches (so a 0/1 flag such as "short_dispatch" reads as a
+    share of the microbatches); "tokens_per_expert" and "rows_*" leaves are
+    summed.
 
     ``hier`` (an ``ops.hier_reduce.HierDpReducer``) swaps the implicit
     GSPMD dp gradient all-reduce for the explicit hierarchical path:

@@ -21,6 +21,7 @@ from hetu_galvatron_tpu.models.builder import (
     forward_causal_lm,
     init_causal_lm,
 )
+from hetu_galvatron_tpu.models import moe
 from hetu_galvatron_tpu.models.moe import (
     apply_moe_mlp,
     init_moe_decoder_layer,
@@ -322,15 +323,17 @@ def _share(p, first, held=2):
             "wout": p["wout"][first:first + held]}
 
 
-def _whole_layer_by_the_reference(p, x):
-    """The uncut reference's layer output: all 8 experts held."""
+def _whole_layer_by_the_reference(p, x, first=0, held=8):
+    """The uncut reference's layer output: all 8 experts held, or what
+    experts ``[first, first + held)`` of them add."""
     w = {"gate.weight": p["router"].T, "expert_bias": p["expert_bias"]}
     for e in range(8):
         gate, up = jnp.split(p["win"][e], 2, axis=1)
         w[f"experts.{e}.w1.weight"] = gate.T
         w[f"experts.{e}.w3.weight"] = up.T
         w[f"experts.{e}.w2.weight"] = p["wout"][e].T
-    ref_cfg = {**REF_CFG, "num_experts_per_tok": 2}
+    ref_cfg = {**REF_CFG, "num_experts_per_tok": 2, "num_experts": held,
+               "first_expert_held": first}
     return _family().sparse_experts(x.reshape(-1, 32), w, "", ref_cfg
                                     ).reshape(x.shape)
 
@@ -347,7 +350,11 @@ def test_the_four_shares_add_up_to_the_uncut_layer():
         y, _, stats = apply_moe_mlp(_share(p, first), x, cfg,
                                     compute_dtype=jnp.float32)
         total, rows = total + y, rows + float(stats["rows_held"])
-        assert float(stats["rows_computed"]) == 2 * 16 * 2
+        # the rows of the body taken: 2 of 8 held of 64 slots, so 32 in the
+        # short one
+        short = float(stats["rows_held"]) <= 32
+        assert float(stats["short_dispatch"]) == short
+        assert float(stats["rows_computed"]) == (32 if short else 64)
     assert rows == 2 * 16 * 2
     # tolerance: fp32, sums in another order
     np.testing.assert_allclose(total, _whole_layer_by_the_reference(p, x),
@@ -370,18 +377,160 @@ def test_a_token_whose_routes_fall_on_one_share_loses_none():
                                     compute_dtype=jnp.float32)
         if first == 2:
             assert float(stats["rows_held"]) == 2 * 16 * 2
+            assert float(stats["rows_computed"]) == 2 * 16 * 2
             np.testing.assert_allclose(y, whole, rtol=1e-5, atol=1e-6)
         else:
             assert float(stats["rows_held"]) == 0.0
+            assert float(stats["rows_computed"]) == 16 * 2
             assert float(jnp.max(jnp.abs(y))) == 0.0
 
 
-def test_a_skewed_router_drops_no_route_and_the_gauge_says_so():
+def _routed_by_table(p, x, chosen):
+    """``p`` with a router under which token ``t`` scores high on exactly
+    the experts ``chosen[t]`` (the 32 tokens of ``x`` span the 32-wide
+    hidden space, so any table of logits has its router), and a zero
+    bias."""
+    logits = np.full((32, 8), -6.0, np.float32)
+    for t, experts in enumerate(chosen):
+        logits[t, list(experts)] = 6.0
+    router = jnp.linalg.solve(x.reshape(32, 32), jnp.asarray(logits))
+    return {**p, "router": router, "expert_bias": jnp.zeros(8)}
+
+
+def _held_dispatch_case(case):
+    """(p, x, rows that fall on experts 2 and 3) of one case of
+    test_both_bodies_of_the_held_dispatch; the short buffer of a layer that
+    holds 2 of 8 has 32 of the 64 slots."""
+    if case == "balanced":
+        return (*_layer_and_tokens(np.zeros(8)), None)
+    if case == "every_route_held":
+        bias = np.zeros(8)
+        bias[2:4] = 10.0
+        return (*_layer_and_tokens(bias), 64)
+    p, x = _layer_and_tokens()
+    # 16 tokens on the two held experts fill the short buffer to its last
+    # row; one more route is one too many
+    chosen = [(2, 3)] * 16 + [(4, 5)] * 16
+    if case == "one_row_over_the_short_buffer":
+        chosen[16] = (3, 4)
+    return _routed_by_table(p, x, chosen), x, 32 + (chosen[16] == (3, 4))
+
+
+@pytest.mark.parametrize("case,short", [
+    ("balanced", True), ("every_route_held", False),
+    ("the_short_buffer_filled", True),
+    ("one_row_over_the_short_buffer", False)])
+def test_both_bodies_of_the_held_dispatch(case, short, monkeypatch):
+    """A layer that holds experts 2 and 3 of 8 takes the short body where
+    the counted routes fit it and the full one where they do not, to the
+    row; either way its output and its gradients to the rows, the expert
+    weights and the router are the uncut reference's for those two experts,
+    and the gradients of the layer compiled with the one full body."""
+    p, x, rows = _held_dispatch_case(case)
+    cfg = LAYER.model_copy(update=dict(moe_held_experts=2,
+                                       moe_first_held_expert=2))
+    cot = jax.random.normal(jax.random.key(9), x.shape, jnp.float32)
+
+    def program(x, win, wout, router):
+        q = {**p, "router": router, "win": win[2:4], "wout": wout[2:4]}
+        y, _, stats = apply_moe_mlp(q, x, cfg, compute_dtype=jnp.float32)
+        return jnp.sum(y * cot), (y, stats)
+
+    def reference(x, win, wout, router):
+        q = {**p, "router": router, "win": win, "wout": wout}
+        return jnp.sum(_whole_layer_by_the_reference(q, x, 2, 2) * cot)
+
+    args = (x, p["win"], p["wout"], p["router"])
+    grad = jax.grad(program, argnums=(0, 1, 2, 3), has_aux=True)
+    got, (y, stats) = grad(*args)
+    if rows is not None:
+        assert float(stats["rows_held"]) == rows
+    assert float(stats["short_dispatch"]) == short
+    assert float(stats["rows_computed"]) == (32 if short else 64)
+    # tolerance: fp32, sums in another order
+    np.testing.assert_allclose(y, _whole_layer_by_the_reference(p, x, 2, 2),
+                               rtol=1e-5, atol=2e-6)
+    want = jax.grad(reference, argnums=(0, 1, 2, 3))(*args)
+    monkeypatch.setattr(moe, "short_rows", lambda slots, held, experts: slots)
+    one_body, (_, stats) = grad(*args)
+    assert float(stats["rows_computed"]) == 64
+    assert float(stats["short_dispatch"]) == 0.0
+    for name, g, w, o in zip(("x", "win", "wout", "router"), got, want,
+                             one_body):
+        scale = float(jnp.max(jnp.abs(w)))
+        assert scale > 0, name
+        np.testing.assert_allclose(g, w, rtol=1e-4, atol=1e-5 * scale,
+                                   err_msg=name)
+        np.testing.assert_allclose(g, o, rtol=1e-4, atol=1e-5 * scale,
+                                   err_msg=name)
+
+
+def _primitives(jaxpr, counts=None):
+    """How often each primitive occurs in ``jaxpr``, the bodies of its
+    calls, conditionals and loops included."""
+    counts = {} if counts is None else counts
+    for eqn in jaxpr.eqns:
+        counts[eqn.primitive.name] = counts.get(eqn.primitive.name, 0) + 1
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) else [value]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    _primitives(sub, counts)
+    return counts
+
+
+@pytest.mark.parametrize("layer,bodies", [
+    ("a_quarter_held", 2), ("a_half_held", 1), ("every_expert_held", 1),
+    ("olmoe", 1)])
+def test_only_a_share_under_a_half_has_a_second_body(layer, bodies):
+    """What keeps the cells without a held share where they are between
+    chip runs: the layer's jaxpr holds the conditional and two pairs of
+    grouped matmuls where the short buffer is shorter than ``T*K``; a layer
+    whose short buffer reaches ``T*K`` and a layer that holds every expert
+    (``_dropless_dispatch``; the OLMoE preset at a tiny size) hold one pair
+    and no conditional, forward and backward."""
+    from hetu_galvatron_tpu.core.arguments import args_from_cli
+
+    if layer == "olmoe":
+        cfg = args_from_cli([
+            os.path.join(ZOO, "olmoe-1b-7b.yaml"), "model.hidden_size=32",
+            "model.num_attention_heads=2", "model.num_key_value_heads=2",
+            "model.ffn_hidden_size=32", "model.num_experts=4",
+            "model.moe_topk=2"], mode="train_dist").model
+    else:
+        held = {"a_quarter_held": 2, "a_half_held": 4,
+                "every_expert_held": None}[layer]
+        cfg = LAYER.model_copy(update=dict(moe_held_experts=held))
+    assert moe.short_rows(64, 2, 8) == 32 and moe.short_rows(64, 4, 8) == 64
+    p = jax.eval_shape(lambda k: init_moe_mlp(k, cfg)[0], jax.random.key(0))
+    x = jax.ShapeDtypeStruct((2, 16, 32), jnp.float32)
+
+    def layer_sum(p, x):
+        return jnp.sum(apply_moe_mlp(p, x, cfg, compute_dtype=jnp.float32)[0])
+    forward = _primitives(jax.make_jaxpr(layer_sum)(p, x).jaxpr)
+    assert forward.get("cond", 0) == bodies - 1
+    assert forward["ragged_dot_general"] == 2 * bodies
+    both = _primitives(jax.make_jaxpr(jax.grad(layer_sum))(p, x).jaxpr)
+    # one conditional forward and one backward; a body's backward
+    # recomputes its two grouped matmuls and transposes each twice
+    assert both.get("cond", 0) == 2 * (bodies - 1)
+    if bodies == 2:
+        assert both["ragged_dot_general"] == 2 * (2 + 6)
+
+
+@pytest.mark.parametrize("held,skewed,short_pct", [
+    (4, True, 0.0),    # half the experts: the short buffer is the whole one
+    (2, True, 0.0), (2, False, 100.0)])
+def test_a_skewed_router_drops_no_route_and_the_gauge_says_so(held, skewed,
+                                                              short_pct):
     """Every token to two held experts, through the train step's metrics and
     ``RuntimeProfiler.iteration_log``: ``moe/rows_held`` is all T*K of the
-    step's two microbatches, ``moe/local_routes_pct`` 100, and the
-    gradient to the rows outside every group is zero, not what the grouped
-    matmuls left there."""
+    step's two microbatches, ``moe/local_routes_pct`` 100, the layer took the
+    full body (``moe/short_dispatch_pct`` 0, ``moe/rows_computed`` all T*K)
+    and the gradient to the rows outside every group is zero, not what the
+    grouped matmuls left there. Under a zero bias a layer that holds 2 of 8
+    takes the short body in both microbatches, and the line and the gauges
+    say so."""
     from hetu_galvatron_tpu.core.profiler.runtime_profiler import (
         RuntimeProfiler,
     )
@@ -389,10 +538,10 @@ def test_a_skewed_router_drops_no_route_and_the_gauge_says_so():
     from hetu_galvatron_tpu.runtime.optimizer import make_optimizer
     from hetu_galvatron_tpu.runtime.trainer import make_train_step
 
-    cfg = ModelArgs(**{**TINY, "moe_topk": 2, "moe_held_experts": 4,
+    cfg = ModelArgs(**{**TINY, "moe_topk": 2, "moe_held_experts": held,
                        "moe_first_held_expert": 2})
     params = _seeded(cfg)
-    bias = jnp.zeros(8).at[2:4].set(10.0)
+    bias = jnp.zeros(8).at[2:4].set(10.0 if skewed else 0.0)
     params = {**params, "layers": tuple(
         {**lp, "moe": {**lp["moe"], "expert_bias": bias}}
         if "moe" in lp else lp for lp in params["layers"])}
@@ -408,14 +557,25 @@ def test_a_skewed_router_drops_no_route_and_the_gauge_says_so():
     reg = MetricsRegistry()
     prof = RuntimeProfiler(CoreArgs(model=cfg.model_dump()), registry=reg)
     line = prof.iteration_log(0, metrics)
-    assert "moe[layer1] local 100.00% rows 128/128" in line
     gauges = {(m.name, m.labels.get("layer")): m.value
               for m in reg.metrics() if m.name.startswith("moe/")}
+    assert gauges[("moe/short_dispatch_pct", "layer1")] == short_pct
+    assert gauges[("moe/short_dispatch_pct", "layer4")] == short_pct
+    if not skewed:
+        # two microbatches of the short buffer's 32 rows
+        rows = gauges[("moe/rows_held", "layer1")]
+        assert 0 < rows <= 2 * 32
+        assert gauges[("moe/rows_computed", "layer1")] == 2 * 32
+        assert f"moe[layer1] local {100 * rows / 128:.2f}% " \
+               f"rows {rows:.0f}/64" in line
+        return
+    assert "moe[layer1] local 100.00% rows 128/128" in line
     assert gauges[("moe/rows_held", "layer1")] == 4 * 16 * 2
     assert gauges[("moe/rows_computed", "layer1")] == 4 * 16 * 2
     assert gauges[("moe/local_routes_pct", "layer4")] == 100.0
-    # two of the four held experts take everything: max / mean = 2
-    assert gauges[("moe/imbalance", "layer1")] == pytest.approx(2.0, abs=0.2)
+    # two of the held experts take everything: max / mean = held / 2
+    assert gauges[("moe/imbalance", "layer1")] == pytest.approx(held / 2,
+                                                                abs=0.2)
 
 
 # ---------------------------------------------------------------------------

@@ -50,7 +50,9 @@ PRESETS = {
         "model.num_dense_layers=1", "model.num_key_value_heads=2",
         "model.ffn_hidden_size=32", "model.moe_ffn_hidden_size=16",
         "model.num_experts=8", "model.moe_topk=2",
-        "model.moe_held_experts=4", "model.moe_first_held_expert=0"],
+        # a quarter of the experts: at a half and more the layer that holds
+        # a share has no short body to take
+        "model.moe_held_experts=2", "model.moe_first_held_expert=0"],
 }
 # what a cell without an expert layer does not write, and is not asked for;
 # and what only a layer that holds a share of its experts writes
@@ -130,7 +132,8 @@ def test_registry_reader_finds_what_the_program_wrote(run, name):
 
 @pytest.mark.parametrize("name", (lfm2_gauges.LOCAL_ROUTES_GAUGE,
                                   lfm2_gauges.IMBALANCE_GAUGE)
-                         + lfm2_gauges.ROWS_GAUGES)
+                         + lfm2_gauges.ROWS_GAUGES
+                         + ("moe/short_dispatch_pct",))
 def test_a_layer_that_holds_a_share_writes_its_gauges(run, name):
     found = [m for m in run["registry"].metrics() if m.name == name
              and m.labels.get("layer") == lfm2_gauges.FIRST_EXPERT_LAYER]
@@ -138,6 +141,21 @@ def test_a_layer_that_holds_a_share_writes_its_gauges(run, name):
         assert len(found) == 1 and found[0].value > 0
     elif name != lfm2_gauges.IMBALANCE_GAUGE:
         assert not found
+
+
+def test_the_rows_gauges_show_the_body_taken(run):
+    """A quarter of the experts held at random weights: both microbatches
+    of the last logged step took the short body, 32 of their 64 slots."""
+    from hetu_galvatron_tpu.models.moe import short_rows
+
+    gauge = {m.name: m.value for m in run["registry"].metrics()
+             if m.labels.get("layer") == lfm2_gauges.FIRST_EXPERT_LAYER}
+    if run["preset"] != "lfm2":
+        assert "moe/short_dispatch_pct" not in gauge
+        return
+    held, computed = (gauge[name] for name in lfm2_gauges.ROWS_GAUGES)
+    assert gauge["moe/short_dispatch_pct"] == 100.0
+    assert 0 < held <= computed == 2 * short_rows(2 * 16 * 2, 2, 8) == 64
 
 
 @pytest.mark.parametrize("mixer,ff,blocks", [
