@@ -141,6 +141,53 @@ CONV_REASON = ("conv block: the gated short convolution's projections are "
                "fc1/fc2)")
 
 
+MAMBA_REASON = ("mamba block: the state-space block's projections are not "
+                "cut for the ring all-gather / reduce-scatter matmuls, and "
+                "its recurrence runs over the whole sequence on one shard")
+
+# why a block that does not attend keeps its matmuls on GSPMD, by mixer kind
+MIXER_OVERLAP_REASON = {"conv": CONV_REASON, "mamba": MAMBA_REASON}
+
+
+def mamba_plan_reason(cfg: Any, layers: Any) -> Optional[str]:
+    """Why a plan cannot run this model's mamba blocks; None when it can
+    (or the model has none). The Mamba-2 block's parameters carry no axis
+    that tensor parallelism shards (heads on the tp axis with B and C
+    replicated is not written), and its convolution and recurrence run over
+    the whole sequence, so a block whose plan cuts the sequence (cp,
+    Ulysses) would carry a state across shards it cannot see."""
+    kinds = cfg.block_kinds(len(layers))
+    for i, (s, (mixer, _)) in enumerate(zip(layers, kinds)):
+        if mixer != "mamba":
+            continue
+        cut = [f"{name}={deg}" for name, deg in (
+            ("tp", s.tp_size), ("cp", s.cp_size)) if deg > 1]
+        if cut:
+            return (f"block {i} is a mamba block and its plan has "
+                    f"{', '.join(cut)}"
+                    + (" (Ulysses)" if s.sp and s.tp_size > 1 else "")
+                    + ": the state-space block runs with tp=1 and cp=1 "
+                    "(its heads are not cut over the tp axis and its "
+                    "recurrence needs the whole sequence on one shard); "
+                    "use dp / ZeRO for this model")
+    return None
+
+
+def own_multipliers_reason(cfg: Any, what: str) -> Optional[str]:
+    """Why ``what`` (a decoding path with its own attention core and its
+    own embedding and residual adds) cannot take a model that states a
+    softmax scale or a Granite multiplier; None for every other model."""
+    stated = [f"{k}={getattr(cfg, k)}" for k, plain in (
+        ("attention_multiplier", None), ("embedding_multiplier", 1.0),
+        ("residual_multiplier", 1.0), ("logits_scaling", 1.0))
+        if getattr(cfg, k, plain) != plain]
+    if not stated:
+        return None
+    return (f"{what} attends at 1/sqrt(head_dim) and adds its branches "
+            f"unscaled; this model states {', '.join(stated)}, which only "
+            "the training path (builder.forward_causal_lm) applies")
+
+
 def mixed_stack_reason(cfg: Any, what: str, *,
                        feed_forward_may_differ: bool = False
                        ) -> Optional[str]:
@@ -230,8 +277,9 @@ def plan_overlap_reasons(cfg: Any, hpc: Any) -> List:
         if is_moe_layer(cfg, i):
             out.append((i, MOE_REASON))
             continue
-        if cfg.block_kinds(len(hpc.layers))[i][0] != "full_attention":
-            out.append((i, CONV_REASON))
+        mixer = cfg.block_kinds(len(hpc.layers))[i][0]
+        if mixer != "full_attention":
+            out.append((i, MIXER_OVERLAP_REASON[mixer]))
             continue
         out.append((i, overlap_unsupported_reason(
             cfg, ulysses=s.sp, has_cp=s.cp_size > 1, tp=s.tp_size)))

@@ -173,6 +173,9 @@ def train(args) -> Dict[str, Any]:
         from collections import Counter
 
         from hetu_galvatron_tpu.observability.registry import get_registry
+        from hetu_galvatron_tpu.observability.trace_analysis import (
+            MIXER_SCOPES,
+        )
         from hetu_galvatron_tpu.runtime.mesh import (
             attention_core,
             flash_kernel_runs,
@@ -182,10 +185,11 @@ def train(args) -> Dict[str, Any]:
         # a block that does not attend reports its own operator, so that
         # "every core is flash" stays a statement about the blocks that do
         kinds = cfg.block_kinds(len(hpc.layers))
+        operators = {"conv": "short_conv", "mamba": "mamba2"}
         attention_cores = [
             attention_core(s.cp_size > 1, bool(s.sp and s.tp_size > 1),
                            use_flash) if mixer == "full_attention"
-            else "short_conv"
+            else operators[mixer]
             for s, (mixer, _) in zip(hpc.layers, kinds)]
         state.log("attention cores: " + ", ".join(
             f"{n} x {core}" for core, n in Counter(attention_cores).items()))
@@ -194,6 +198,18 @@ def train(args) -> Dict[str, Any]:
         for (m, ff), n in Counter(kinds).items():
             blocks[f"{m}/{ff}"] = n
             get_registry().gauge("step/blocks", mixer=m, ff=ff).set(n)
+        # what a state-space block carries: the chunks of a sequence and
+        # the float32 state one sequence hands from chunk to chunk
+        for i, (m, _) in enumerate(kinds):
+            if m == "mamba":
+                get_registry().gauge("ssd/chunks", layer=f"layer{i}").set(
+                    -(-cfg.seq_length // cfg.mamba_chunk_size))
+                get_registry().gauge("ssd/state_bytes", layer=f"layer{i}"
+                                     ).set(4 * cfg.mamba_d_inner
+                                           * cfg.mamba_d_state)
+        # named scopes whose instructions the step report keeps
+        report_scopes = tuple(s for m in dict.fromkeys(m for m, _ in kinds)
+                              for s in MIXER_SCOPES.get(m, ()))
 
         # abstract init first: the plan's shardings are derived from SHAPES, so
         # no device materializes the unsharded tree before they exist (the
@@ -663,7 +679,11 @@ def train(args) -> Dict[str, Any]:
     use_dropout = (cfg.hidden_dropout > 0.0 or cfg.attention_dropout > 0.0)
     drop_key = jax.random.key(args.train.seed) if use_dropout else None
     # what the compiled step contains (filled after the first step)
-    from hetu_galvatron_tpu.observability.trace_analysis import hlo_counts
+    from hetu_galvatron_tpu.observability.trace_analysis import (
+        hlo_counts,
+        record_step_scopes,
+        scope_instructions,
+    )
 
     step_report: Dict[str, Any] = {}
     it_box = [0]  # the iteration run_loop is in, for the spans below
@@ -1097,7 +1117,12 @@ def train(args) -> Dict[str, Any]:
                 with span("setup/step_report"):
                     compiled = fn.lower(out[0], out[1], b).compile()
                     # (as_text: 0.2 s on four chips)
-                    step_report.update(hlo_counts(compiled.as_text()))
+                    hlo_text = compiled.as_text()
+                    step_report.update(hlo_counts(hlo_text))
+                    if report_scopes:
+                        found = scope_instructions(hlo_text, report_scopes)
+                        record_step_scopes(found)
+                        step_report["scope_instructions"] = found["scopes"]
                     step_report["static_memory"] = compiled_memory_bytes(
                         compiled)
                     for part, v in step_report["static_memory"].items():
@@ -1111,6 +1136,10 @@ def train(args) -> Dict[str, Any]:
                     in step_report["collectives"].items())
                     + ", blocks " + " ".join(
                         f"{n} x {kind}" for kind, n in blocks.items())
+                    + "".join(
+                        f", {len(names)} instructions under {scope}"
+                        for scope, names in step_report.get(
+                            "scope_instructions", {}).items())
                     + f", {step_report['mosaic_custom_calls']} Mosaic calls,"
                     f" static live peak "
                     f"{step_report['static_memory']['live_peak'] / 2**30:.3f}"
@@ -1168,6 +1197,10 @@ def train(args) -> Dict[str, Any]:
             # collective instructions in that step's HLO, by opcode (the
             # step/collectives gauges); None for the pp engines
             "collectives": step_report.get("collectives"),
+            # instruction names of that step's HLO under each named scope a
+            # state-space block has (what a trace's events are joined to);
+            # None for a model without one and for the pp engines
+            "scope_instructions": step_report.get("scope_instructions"),
             "exit_code": exit_code}
 
 

@@ -43,7 +43,9 @@ class ModelArgs(BaseModel):
     # norm lives in the MLM transform head), "pre" for everything else
     norm_position: Optional[Literal["pre", "post"]] = None
     layernorm_epsilon: float = 1e-5
-    position_embedding_type: Literal["learned", "rope"] = "learned"
+    # "nope" = no positions at all: no table, no rotation (a stack whose
+    # state-space blocks carry the order, Granite-4.0-H)
+    position_embedding_type: Literal["learned", "rope", "nope"] = "learned"
     rope_theta: float = 10000.0
     # HF-style rope_scaling dict: {"rope_type": "linear"|"llama3",
     # "factor": ..., and for llama3 "low_freq_factor"/"high_freq_factor"/
@@ -115,11 +117,13 @@ class ModelArgs(BaseModel):
     qk_norm_per_head: bool = False
     # THE per-layer description (:meth:`block_kinds`) comes from these two
     # published keys and ``moe_layer_freq``. ``layer_types``: each block's
-    # mixer, "full_attention" or "conv" (a gated short convolution,
-    # modules.apply_short_conv); None = every block attends.
+    # mixer, "full_attention", "conv" (a gated short convolution,
+    # modules.apply_short_conv) or "mamba" (a Mamba-2 state-space block,
+    # modules.apply_mamba2); None = every block attends.
     # ``num_dense_layers``: so many leading blocks of an expert model keep
     # a dense MLP of ``ffn_hidden_size``
-    layer_types: Optional[List[Literal["full_attention", "conv"]]] = None
+    layer_types: Optional[
+        List[Literal["full_attention", "conv", "mamba"]]] = None
     num_dense_layers: int = 0
     conv_L_cache: int = 3   # taps of a conv block's depthwise convolution
     conv_bias: bool = False
@@ -139,13 +143,38 @@ class ModelArgs(BaseModel):
     # "llama" = input_layernorm / self_attn.o_proj / mlp.{gate,up,down}_proj;
     # "lfm2" = operator_norm / ffn_norm / conv.* / self_attn.out_proj /
     # feed_forward.w1,w3,w2 / model.embedding_norm
-    hf_layout: Literal["llama", "lfm2"] = "llama"
+    # "granite" = llama's norms and attention, shared_mlp.input_linear
+    # (gate | up in one matrix) / shared_mlp.output_linear, mamba.*
+    hf_layout: Literal["llama", "lfm2", "granite"] = "llama"
+    # a "mamba" block (Mamba-2 / SSD; HF ``GraniteMoeHybridMambaLayer``):
+    # ``mamba_n_heads`` heads of ``mamba_d_head`` channels each carry a
+    # state of ``mamba_d_head x mamba_d_state`` over the sequence; B and C
+    # are shared by the heads of a group; a depthwise causal convolution of
+    # ``mamba_d_conv`` taps runs over x, B and C before the recurrence,
+    # which is computed ``mamba_chunk_size`` positions at a time
+    mamba_n_heads: int = 0
+    mamba_d_head: int = 64
+    mamba_d_state: int = 128
+    mamba_n_groups: int = 1
+    mamba_d_conv: int = 4
+    mamba_chunk_size: int = 256
+    mamba_conv_bias: bool = True
+    mamba_proj_bias: bool = False
+    # the four Granite multipliers, each None / 1 = what every other model
+    # does: softmax(attention_multiplier * q k^T) in place of 1/sqrt(D);
+    # the embedding's rows times ``embedding_multiplier``; each of a
+    # block's two residual branches times ``residual_multiplier``; the
+    # logits divided by ``logits_scaling`` before the loss
+    attention_multiplier: Optional[float] = None
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
 
     def block_kinds(self, n: Optional[int] = None
                     ) -> Tuple[Tuple[str, str], ...]:
         """The one per-layer description of a decoder stack: for each block
-        its mixer kind ("full_attention", "conv") and its feed-forward kind
-        ("dense", "experts"). The builder, the exporter, the launcher's
+        its mixer kind ("full_attention", "conv", "mamba") and its
+        feed-forward kind ("dense", "experts"). The builder, the exporter, the launcher's
         report and every engine's refusal read this and nothing else.
         ``n``: the blocks a plan lists where that is not
         ``num_hidden_layers`` (t5's two stacks, a pipeline stage's slice),
@@ -162,6 +191,17 @@ class ModelArgs(BaseModel):
             (m, "experts" if self.num_experts and i >= self.num_dense_layers
              and (i + 1) % freq == 0 else "dense")
             for i, m in enumerate(mixers))
+
+    @property
+    def mamba_d_inner(self) -> int:
+        """Channels of a mamba block's x, z and gated norm."""
+        return self.mamba_n_heads * self.mamba_d_head
+
+    @property
+    def mamba_conv_dim(self) -> int:
+        """Channels the mamba block's convolution runs over: x | B | C."""
+        return (self.mamba_d_inner
+                + 2 * self.mamba_n_groups * self.mamba_d_state)
 
     @property
     def held_experts(self) -> int:

@@ -937,8 +937,16 @@ def model_flops_per_token(model: Any, seq_length: Optional[int] = None
                   and (i + 1) % freq == 0 else "dense")
                  for i in range(layers)]
     # block by block, from the per-layer description: a conv block's
-    # in_proj (H x 3H) and out_proj (H x H) in place of attention
+    # in_proj (H x 3H) and out_proj (H x H) in place of attention; a mamba
+    # block's in_proj (H x (z | x | B | C | dt)), out_proj and the
+    # recurrence as the recurrence (state update and read-out, 2 each per
+    # state element), whatever chunked form computes it
     mixer = {"full_attention": attn, "conv": 2 * 4 * h * h}
+    if getattr(model, "mamba_n_heads", 0):
+        inner, state = model.mamba_d_inner, model.mamba_d_state
+        mixer["mamba"] = (
+            2 * h * (inner + model.mamba_conv_dim + model.mamba_n_heads)
+            + 2 * inner * h + 4 * inner * state)
     fwd = sum(mixer[m] + (experts_ff if ff == "experts" else dense_ff)
               for m, ff in kinds)
     fwd += 2 * h * model.padded_vocab_size  # LM head

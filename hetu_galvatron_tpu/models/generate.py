@@ -41,10 +41,15 @@ def _check_supported(cfg: ModelArgs, params: Params) -> None:
             "for t5 (encoder once + cached cross-attention decode)")
     if any("moe" in lp for lp in params["layers"]):
         raise NotImplementedError("generate(): dense layers only")
-    from hetu_galvatron_tpu.analysis.eligibility import mixed_stack_reason
+    from hetu_galvatron_tpu.analysis.eligibility import (
+        mixed_stack_reason,
+        own_multipliers_reason,
+    )
 
     reason = mixed_stack_reason(
-        cfg, "generate() (a key-value cache a block, no convolution state)")
+        cfg, "generate() (a key-value cache a block, no convolution state "
+        "and no state-space state)") or own_multipliers_reason(
+        cfg, "generate() (its cached attention core)")
     if reason is not None:
         raise NotImplementedError(reason)
 

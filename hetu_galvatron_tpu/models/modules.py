@@ -51,6 +51,10 @@ Axes = Dict[str, Any]
 # ---------------------------------------------------------------------------
 
 
+# a block's key for its mixer's parameters, by mixer kind
+MIXER_KEYS = {"full_attention": "attn", "conv": "conv", "mamba": "mamba"}
+
+
 def _normal(key, shape, std, dtype=jnp.float32):
     return std * jax.random.normal(key, shape, dtype)
 
@@ -293,6 +297,7 @@ def xla_sdpa(
     q: jax.Array, k: jax.Array, v: jax.Array, *, causal: bool = True,
     dropout_rate: float = 0.0, dropout_rng: Optional[jax.Array] = None,
     segment_ids: Optional[jax.Array] = None,
+    scale: Optional[float] = None,
 ) -> jax.Array:
     """Reference attention core on XLA: [B,S,N,D] x [B,T,K,D] -> [B,S,N,D].
 
@@ -305,6 +310,8 @@ def xla_sdpa(
     the mask so packed documents cannot attend across boundaries (the
     reference's reset_attention_mask, Megatron
     get_ltor_masks_and_position_ids).
+    ``scale``: softmax(scale * q k^T) where the model states its own
+    (``ModelArgs.attention_multiplier``); ``None`` divides by sqrt(D).
     """
     B, S, N, D = q.shape
     K = k.shape[2]
@@ -312,7 +319,8 @@ def xla_sdpa(
     qg = q.reshape(B, S, K, G, D)
     scores = jnp.einsum(
         "bskgd,btkd->bkgst", qg, k, preferred_element_type=jnp.float32
-    ) / math.sqrt(D)
+    )
+    scores = scores / math.sqrt(D) if scale is None else scores * scale
     if causal:
         # queries own absolute positions [T-S, T): supports S<T (inference)
         qpos = jnp.arange(S)[:, None] + (k.shape[1] - S)
@@ -405,6 +413,19 @@ def apply_attention(
         cos, sin = rope
         q = apply_rope(q, cos, sin)
         k = apply_rope(k, cos, sin)
+    core_kwargs: Dict[str, Any] = {}
+    if cfg.attention_multiplier is not None:
+        # the model's own softmax scale is an argument of the core; a core
+        # without the argument would attend at 1/sqrt(D) in silence
+        if not (sdpa_fn is xla_sdpa
+                or getattr(sdpa_fn, "supports_scale", False)):
+            raise NotImplementedError(
+                "model.attention_multiplier (a softmax scale other than "
+                "1/sqrt(head_dim)) is an argument of the XLA attention core "
+                "and of the Pallas flash kernels; the installed ring/Ulysses "
+                "core does not take it. Avoid cp/ulysses layers for this "
+                "model")
+        core_kwargs["scale"] = float(cfg.attention_multiplier)
     use_dropout = dropout_rng is not None and cfg.attention_dropout > 0.0
     if use_dropout:
         # probability dropout lives inside the attention core: the XLA core
@@ -417,7 +438,8 @@ def apply_attention(
                                           False):
             out = sdpa_fn(q, k, v, causal=causal,
                           dropout_rate=cfg.attention_dropout,
-                          dropout_rng=dropout_rng, segment_ids=segment_ids)
+                          dropout_rng=dropout_rng, segment_ids=segment_ids,
+                          **core_kwargs)
         else:
             raise NotImplementedError(
                 "attention_dropout > 0 is only supported with the XLA "
@@ -431,7 +453,8 @@ def apply_attention(
         # with their block) implement it; Ulysses does not
         if sdpa_fn is xla_sdpa or getattr(sdpa_fn, "supports_segments",
                                           False):
-            out = sdpa_fn(q, k, v, causal=causal, segment_ids=segment_ids)
+            out = sdpa_fn(q, k, v, causal=causal, segment_ids=segment_ids,
+                          **core_kwargs)
         else:
             raise NotImplementedError(
                 "reset_attention_mask is not supported by the installed "
@@ -439,7 +462,7 @@ def apply_attention(
                 "core for packed-document layers, or set "
                 "data.reset_attention_mask=false")
     else:
-        out = sdpa_fn(q, k, v, causal=causal)
+        out = sdpa_fn(q, k, v, causal=causal, **core_kwargs)
     out = out.reshape(B, S, nq * hd)
     if group_major:
         out = shard_fn(out, 2)
@@ -486,6 +509,20 @@ def init_short_conv(key: jax.Array, cfg: ModelArgs) -> Tuple[Params, Axes]:
     return p, a
 
 
+def causal_depthwise_conv(u: jax.Array, taps: jax.Array) -> jax.Array:
+    """``c[t] = sum_j taps[:, j] * u[t - (L - 1 - j)]`` a channel: ``u``
+    [B, S, C] float32, ``taps`` [C, L]; causal, zeros before the sequence,
+    as L shifted products."""
+    S, L = u.shape[1], taps.shape[1]
+    taps = taps.astype(jnp.float32)
+    c = u * taps[:, L - 1]
+    for back in range(1, L):
+        # u[t - back]: zeros before the sequence
+        shifted = jnp.pad(u, ((0, 0), (back, 0), (0, 0)))[:, :S]
+        c = c + shifted * taps[:, L - 1 - back]
+    return c
+
+
 def apply_short_conv(
     p: Params,
     x: jax.Array,
@@ -498,8 +535,6 @@ def apply_short_conv(
     sequence; ``(C * c) W_out``. No softmax, no positions. The two
     projections run in ``compute_dtype`` with float32 accumulation; the
     gates and the taps between them are one elementwise pass in float32."""
-    B, S, H = x.shape
-    L = cfg.conv_L_cache
     with jax.named_scope("mixer/short_conv"):
         with jax.named_scope("in_proj"):
             bcx = jnp.einsum("bsh,ghc->gbsc", x.astype(compute_dtype),
@@ -511,13 +546,7 @@ def apply_short_conv(
                 thirds = [shard_fn(t, 2) for t in thirds]
         with jax.named_scope("gate_conv"):
             gate_b, gate_c, xs = (t.astype(jnp.float32) for t in thirds)
-            u = gate_b * xs
-            taps = p["taps"].astype(jnp.float32)
-            c = u * taps[:, L - 1]
-            for back in range(1, L):
-                # u[t - back]: zeros before the sequence
-                shifted = jnp.pad(u, ((0, 0), (back, 0), (0, 0)))[:, :S]
-                c = c + shifted * taps[:, L - 1 - back]
+            c = causal_depthwise_conv(gate_b * xs, p["taps"])
             y = (gate_c * c).astype(compute_dtype)
             if shard_fn is not None:
                 y = shard_fn(y, 2)
@@ -525,6 +554,217 @@ def apply_short_conv(
             out = jnp.einsum("bsc,ch->bsh", y,
                              p["wout"].astype(compute_dtype),
                              preferred_element_type=jnp.float32)
+    return out.astype(compute_dtype)
+
+
+# ---------------------------------------------------------------------------
+# Mamba-2 state-space block (a mixer that carries a state)
+# ---------------------------------------------------------------------------
+
+# float32 bytes of the decay matrix (chunks x heads x chunk x chunk) that
+# one call of the intra-chunk part may hold: the chunks of a sequence are
+# taken in groups of at most this much, one group at a time, and the
+# backward pass makes each group's matrix again. Whole, one 8192-token
+# sequence's is 64 heads x 32 chunks x 256 x 256 x 4 bytes = 512 MiB, and
+# the backward holds several tensors of that shape at once
+SSD_DECAY_BYTES = 64 * 2 ** 20
+
+
+def init_mamba2(key: jax.Array, cfg: ModelArgs) -> Tuple[Params, Axes]:
+    """HF ``GraniteMoeHybridMambaLayer`` (Bamba's Mamba-2 mixer): ``win`` is
+    ``in_proj`` with its columns ``[z | x | B | C | dt]``, ``taps`` and
+    ``conv_bias`` the depthwise kernel ``[x | B | C channels, taps]``
+    (``conv1d.weight[:, 0, :]``) and its bias, ``dt_bias``, ``A_log`` and
+    ``D`` one value a head, ``norm`` the gated RMSNorm's scale over all
+    ``mamba_d_inner`` channels, ``wout`` is ``out_proj``. No leaf carries an
+    axis name that tensor parallelism shards: a plan with tp > 1 over a
+    mamba block is refused by name (``eligibility.mamba_plan_reason``).
+
+    ``dt_bias``, ``A_log`` and ``D`` start as Mamba-2 starts them
+    (state-spaces/mamba ``Mamba2.__init__``): ``dt`` log-uniform in [1e-3,
+    1e-1] and ``dt_bias`` its inverse softplus, ``A`` uniform in [1, 16),
+    ``D`` one. (A fresh HF module holds ``dt_bias`` 1 and ``A`` = 1..heads,
+    under which a head forgets within a token or two.)"""
+    if cfg.mamba_n_heads <= 0:
+        raise ValueError("a mamba block needs model.mamba_n_heads > 0")
+    if cfg.mamba_n_groups != 1:
+        raise NotImplementedError(
+            f"model.mamba_n_groups={cfg.mamba_n_groups}: the mamba block is "
+            "written for B and C shared by all heads (one group), which is "
+            "what Granite-4.0-H publishes")
+    if cfg.mamba_proj_bias:
+        raise NotImplementedError(
+            "model.mamba_proj_bias: the mamba block's projections are "
+            "written without biases (Granite-4.0-H publishes false)")
+    h, nh, L = cfg.hidden_size, cfg.mamba_n_heads, cfg.mamba_d_conv
+    di, cd = cfg.mamba_d_inner, cfg.mamba_conv_dim
+    k1, k2, k3, k4, k5 = jax.random.split(key, 5)
+    std = 0.02
+    dt = jnp.exp(jax.random.uniform(k4, (nh,), jnp.float32,
+                                    math.log(1e-3), math.log(1e-1)))
+    p: Params = {
+        "win": _normal(k1, (h, di + cd + nh), std),
+        # the variance of torch's Conv1d default, as the conv block's taps
+        "taps": _normal(k2, (cd, L), 1.0 / math.sqrt(3 * L)),
+        "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+        "A_log": jnp.log(jax.random.uniform(k5, (nh,), jnp.float32,
+                                            1.0, 16.0)),
+        "D": jnp.ones((nh,), jnp.float32),
+        "norm": {"scale": jnp.ones((di,), jnp.float32)},
+        "wout": _normal(k3, (di, h), std / math.sqrt(2 * cfg.num_hidden_layers)),
+    }
+    a: Axes = {"win": ("embed", "mamba_proj"),
+               "taps": ("mamba_conv", "conv_tap"),
+               "dt_bias": ("mamba_head",), "A_log": ("mamba_head",),
+               "D": ("mamba_head",),
+               "norm": {"scale": ("mamba_inner",)},
+               "wout": ("mamba_inner", "embed")}
+    if cfg.mamba_conv_bias:
+        p["conv_bias"] = jnp.zeros((cd,), jnp.float32)
+        a["conv_bias"] = ("mamba_conv",)
+    return p, a
+
+
+def ssd_chunks_a_group(batch: int, chunks: int, heads: int,
+                       chunk: int) -> int:
+    """How many chunks the intra-chunk part takes at once: the most that
+    divide ``chunks`` and whose decay matrices fit ``SSD_DECAY_BYTES``. A
+    function of shapes alone."""
+    fit = max(1, SSD_DECAY_BYTES // (batch * heads * chunk * chunk * 4))
+    return max(d for d in range(1, chunks + 1)
+               if chunks % d == 0 and d <= fit)
+
+
+def ssd_chunked(x: jax.Array, dt: jax.Array, A: jax.Array, Bm: jax.Array,
+                Cm: jax.Array, chunk: int,
+                compute_dtype=jnp.bfloat16) -> jax.Array:
+    """The selective state-space recurrence of Mamba-2 in its chunked,
+    matmul form (Dao & Gu 2024, "SSD"). Per head, with state ``S`` [P, N],
+    zero before the sequence::
+
+        S_t = exp(dt_t A) S_(t-1) + dt_t x_t B_t^T ;   y_t = S_t C_t
+
+    ``x`` [B, S, H, P]; ``dt`` [B, S, H] float32, after softplus; ``A`` [H]
+    float32, negative; ``Bm``, ``Cm`` [B, S, N], shared by the heads.
+    Returns ``y`` [B, S, H, P] float32 (without the ``D x`` skip).
+
+    With ``cs`` the running sum of ``dt A`` inside a chunk of ``chunk``
+    positions: inside a chunk ``y_i += sum_(j<=i) (C_i . B_j) exp(cs_i -
+    cs_j) dt_j x_j`` (one matmul ``C B^T``, one decay matrix ``L``, one
+    matmul ``(C B^T * L) X`` a head); a chunk leaves the state ``sum_j
+    exp(cs_last - cs_j) dt_j x_j B_j^T``; the states are carried from chunk
+    to chunk by a scan (``S <- exp(cs_last) S + the chunk's``); and the
+    state ENTERING a chunk adds ``exp(cs_i) C_i . S``. Sums, decays and the
+    carried state are float32; the matmul operands are ``compute_dtype``
+    with float32 accumulation. A sequence that ``chunk`` does not divide is
+    padded with ``dt = 0`` (no decay, no input), and the padding cut off."""
+    f32 = jnp.float32
+    B_, S, H, P = x.shape
+    pad = -S % chunk
+    if pad:
+        x, dt, Bm, Cm = (
+            jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
+            for t in (x, dt, Bm, Cm))
+    Q, nC = chunk, (S + pad) // chunk
+    size = ssd_chunks_a_group(B_, nC, H, Q)
+    groups = nC // size
+
+    def grouped(t):   # [B, S, ...] -> [groups, B, size, Q, ...]
+        return jnp.moveaxis(
+            t.reshape((B_, groups, size, Q) + t.shape[2:]), 1, 0)
+
+    def whole(t):     # [groups, B, size, ...] -> [B, nC, ...]
+        t = jnp.moveaxis(t, 0, 1)
+        return t.reshape((B_, nC) + t.shape[3:])
+
+    tril = jnp.tril(jnp.ones((Q, Q), bool))
+
+    def intra(args):
+        xg, dtg, bg, cg = args   # [B, size, Q, H, P] [.., H] [.., N] [.., N]
+        cs = jnp.cumsum(jnp.swapaxes(dtg * A, 2, 3), axis=-1)  # [B,c,H,Q]
+        # L[i, j] = exp(cs_i - cs_j) for j <= i: masked BEFORE the exp,
+        # above the diagonal cs_i - cs_j is positive and may overflow
+        L = jnp.exp(jnp.where(tril, cs[..., :, None] - cs[..., None, :],
+                              -jnp.inf))
+        G = jnp.einsum("bcin,bcjn->bcij", cg, bg, preferred_element_type=f32)
+        M = (G[:, :, None] * L).astype(compute_dtype)        # [B,c,H,Q,Q]
+        xf = xg.astype(f32)
+        xdt = (xf * dtg[..., None]).astype(compute_dtype)
+        y = jnp.einsum("bchij,bcjhp->bcihp", M, xdt,
+                       preferred_element_type=f32)
+        to_end = jnp.swapaxes(jnp.exp(cs[..., -1:] - cs), 2, 3)  # [B,c,Q,H]
+        xw = (xf * (dtg * to_end)[..., None]).astype(compute_dtype)
+        st = jnp.einsum("bcjhp,bcjn->bchpn", xw, bg,
+                        preferred_element_type=f32)
+        return y, st, cs
+
+    y, states, cs = map(whole, jax.lax.map(
+        jax.checkpoint(intra), tuple(grouped(t) for t in (x, dt, Bm, Cm))))
+
+    def carry(state, chunk_of):
+        st, decay = chunk_of
+        return decay[..., None, None] * state + st, state
+
+    _, entering = jax.lax.scan(
+        carry, jnp.zeros(states.shape[:1] + states.shape[2:], f32),
+        (jnp.moveaxis(states, 1, 0),
+         jnp.moveaxis(jnp.exp(cs[..., -1]), 1, 0)))
+    entering = jnp.moveaxis(entering, 0, 1)             # [B, nC, H, P, N]
+    y_off = jnp.einsum("bcin,bchpn->bcihp",
+                       Cm.reshape(B_, nC, Q, -1).astype(compute_dtype),
+                       entering.astype(compute_dtype),
+                       preferred_element_type=f32)
+    y = y + y_off * jnp.swapaxes(jnp.exp(cs), 2, 3)[..., None]
+    return y.reshape(B_, nC * Q, H, P)[:, :S]
+
+
+def apply_mamba2(
+    p: Params,
+    x: jax.Array,
+    cfg: ModelArgs,
+    compute_dtype=jnp.bfloat16,
+) -> jax.Array:
+    """``[z | xBC | dt] = x W_in``; ``xBC = silu(conv1d_causal(xBC) + b)``
+    (depthwise, ``mamba_d_conv`` taps, zero history before the sequence);
+    ``[x | B | C] = xBC``; ``dt = softplus(dt + dt_bias)``, ``A =
+    -exp(A_log)``; ``y = SSD(x, dt, A, B, C) + D x``
+    (:func:`ssd_chunked`); ``y = RMSNorm(y * silu(z)) * w`` over all
+    channels; ``y W_out``. No softmax, no positions. The two projections
+    and the recurrence's matmuls run in ``compute_dtype`` with float32
+    accumulation; ``dt``, the decays, the state, the convolution and the
+    gated norm are float32."""
+    B, S, _ = x.shape
+    nh, hp, N = cfg.mamba_n_heads, cfg.mamba_d_head, cfg.mamba_d_state
+    di, f32 = cfg.mamba_d_inner, jnp.float32
+    with jax.named_scope("mixer/mamba"):
+        with jax.named_scope("in_proj"):
+            proj = jnp.einsum("bsh,hc->bsc", x.astype(compute_dtype),
+                              p["win"].astype(compute_dtype),
+                              preferred_element_type=f32)
+            z, xbc, dt = jnp.split(proj, [di, di + cfg.mamba_conv_dim],
+                                   axis=-1)
+            z, xbc = z.astype(compute_dtype), xbc.astype(compute_dtype)
+        with jax.named_scope("conv"):
+            c = causal_depthwise_conv(xbc.astype(f32), p["taps"])
+            if "conv_bias" in p:
+                c = c + p["conv_bias"]
+            xs, Bm, Cm = jnp.split(
+                jax.nn.silu(c).astype(compute_dtype), [di, di + N], axis=-1)
+        with jax.named_scope("ssd"):
+            xs = xs.reshape(B, S, nh, hp)
+            dt = jax.nn.softplus(dt + p["dt_bias"])
+            y = ssd_chunked(xs, dt, -jnp.exp(p["A_log"].astype(f32)), Bm, Cm,
+                            cfg.mamba_chunk_size, compute_dtype)
+            y = y + p["D"][:, None] * xs.astype(f32)
+        with jax.named_scope("gated_norm"):
+            y = y.reshape(B, S, di) * jax.nn.silu(z.astype(f32))
+            var = jnp.mean(jnp.square(y), axis=-1, keepdims=True)
+            y = (y * jax.lax.rsqrt(var + cfg.layernorm_epsilon)
+                 * p["norm"]["scale"]).astype(compute_dtype)
+        with jax.named_scope("out_proj"):
+            out = jnp.einsum("bsc,ch->bsh", y,
+                             p["wout"].astype(compute_dtype),
+                             preferred_element_type=f32)
     return out.astype(compute_dtype)
 
 
@@ -540,21 +780,27 @@ def apply_mixer(
     **attn_kwargs: Any,
 ) -> jax.Array:
     """A block's operator on its normed input, by the block's mixer kind
-    (``ModelArgs.block_kinds``): attention from ``p["attn"]``, or the gated
-    short convolution from ``p["conv"]``, which takes no rope, no attention
-    core and no dropout of probabilities."""
+    (``ModelArgs.block_kinds``): attention from ``p["attn"]``, the gated
+    short convolution from ``p["conv"]`` or the Mamba-2 state-space block
+    from ``p["mamba"]``; the last two take no rope, no attention core and
+    no dropout of probabilities."""
     if mixer == "full_attention":
         return apply_attention(p["attn"], h, cfg, compute_dtype=compute_dtype,
                                shard_fn=shard_fn, segment_ids=segment_ids,
                                **attn_kwargs)
-    if mixer != "conv":
+    if mixer not in MIXER_KEYS:
         raise ValueError(f"unknown mixer kind {mixer!r} "
-                         "(full_attention | conv)")
+                         f"({' | '.join(MIXER_KEYS)})")
     if segment_ids is not None:
         raise NotImplementedError(
-            "packed documents (segment_ids) through a conv block: the "
-            "convolution's two tokens of history would cross document "
-            "boundaries; set data.reset_attention_mask=false")
+            f"packed documents (segment_ids) through a {mixer} block: "
+            + ("the convolution's two tokens of history"
+               if mixer == "conv" else
+               "the convolution's history and the carried state")
+            + " would cross document boundaries; set "
+            "data.reset_attention_mask=false")
+    if mixer == "mamba":
+        return apply_mamba2(p["mamba"], h, cfg, compute_dtype=compute_dtype)
     return apply_short_conv(p["conv"], h, cfg, compute_dtype=compute_dtype,
                             shard_fn=shard_fn)
 
@@ -670,13 +916,13 @@ def apply_mlp(p: Params, x: jax.Array, cfg: ModelArgs,
 # ---------------------------------------------------------------------------
 
 
-MIXER_KEYS = {"full_attention": "attn", "conv": "conv"}
 
 
 def init_mixer(key: jax.Array, cfg: ModelArgs,
                mixer: str = "full_attention") -> Tuple[str, Params, Axes]:
     """(the block's key for it, params, axes) of one mixer kind."""
-    init = {"full_attention": init_attention, "conv": init_short_conv}
+    init = {"full_attention": init_attention, "conv": init_short_conv,
+            "mamba": init_mamba2}
     if mixer not in init:
         raise ValueError(f"unknown mixer kind {mixer!r} ({' | '.join(init)})")
     return (MIXER_KEYS[mixer],) + init[mixer](key, cfg)
@@ -693,6 +939,15 @@ def init_decoder_layer(key: jax.Array, cfg: ModelArgs,
         {"ln1": ln1_p, name: mix_p, "ln2": ln2_p, "mlp": mlp_p},
         {"ln1": ln1_a, name: mix_a, "ln2": ln2_a, "mlp": mlp_a},
     )
+
+
+def residual_branch(y: jax.Array, cfg: ModelArgs) -> jax.Array:
+    """A pre-norm block's branch as it is added to the stream: times
+    ``cfg.residual_multiplier`` where the model states one (Granite), else
+    as it is."""
+    if cfg.residual_multiplier == 1.0:
+        return y
+    return y * jnp.asarray(cfg.residual_multiplier, y.dtype)
 
 
 def apply_decoder_layer(
@@ -754,17 +1009,17 @@ def apply_decoder_layer(
                                  shard_fn=shard_fn), r_res2),
             cfg)
     h = apply_norm(p["ln1"], x, cfg)
-    x = x + drop_h(apply_mixer(p, h, cfg, mixer, rope=rope,
-                               sdpa_fn=sdpa_fn,
-                               compute_dtype=compute_dtype, causal=causal,
-                               dropout_rng=r_attn,
-                               segment_ids=segment_ids,
-                               matmul_fns=matmul_fns,
-                               shard_fn=shard_fn), r_res1)
+    x = x + residual_branch(
+        drop_h(apply_mixer(p, h, cfg, mixer, rope=rope, sdpa_fn=sdpa_fn,
+                           compute_dtype=compute_dtype, causal=causal,
+                           dropout_rng=r_attn, segment_ids=segment_ids,
+                           matmul_fns=matmul_fns, shard_fn=shard_fn),
+               r_res1), cfg)
     h = apply_norm(p["ln2"], x, cfg)
-    x = x + drop_h(apply_mlp(p["mlp"], h, cfg, compute_dtype=compute_dtype,
-                             matmul_fns=matmul_fns, shard_fn=shard_fn),
-                   r_res2)
+    x = x + residual_branch(
+        drop_h(apply_mlp(p["mlp"], h, cfg, compute_dtype=compute_dtype,
+                         matmul_fns=matmul_fns, shard_fn=shard_fn),
+               r_res2), cfg)
     return x
 
 
@@ -806,6 +1061,9 @@ def apply_embedding(p: Params, tokens: jax.Array, cfg: ModelArgs,
     if cfg.scale_embeddings:
         # gemma: hidden states enter the stack scaled by sqrt(hidden)
         x = x * jnp.sqrt(jnp.float32(cfg.hidden_size)).astype(x.dtype)
+    if cfg.embedding_multiplier != 1.0:
+        # granite: the rows enter the stack times a stated constant
+        x = x * jnp.asarray(cfg.embedding_multiplier, x.dtype)
     # HF GPT2Model.drop / BertEmbeddings.dropout: after sum (+LN for bert)
     x = dropout(x, cfg.hidden_dropout, dropout_rng)
     return x.astype(compute_dtype)
@@ -864,6 +1122,9 @@ def apply_lm_head(
                         preferred_element_type=jnp.float32)
     if "bias" in p:
         logits = logits + p["bias"]
+    if cfg.logits_scaling != 1.0:
+        # granite: the logits divided by a stated constant before the loss
+        logits = logits / cfg.logits_scaling
     return logits
 
 

@@ -530,12 +530,13 @@ def apply_moe_decoder_layer(
     if dropout_rng is not None:
         r_attn, r_res1, r_res2 = jax.random.split(dropout_rng, 3)
     h = M.apply_norm(p["ln1"], x, cfg)
-    x = x + M.dropout(
+    x = x + M.residual_branch(M.dropout(
         M.apply_mixer(p, h, cfg, mixer, rope=rope, sdpa_fn=sdpa_fn,
                       compute_dtype=compute_dtype, dropout_rng=r_attn,
                       segment_ids=segment_ids),
-        cfg.hidden_dropout, r_res1)
+        cfg.hidden_dropout, r_res1), cfg)
     h = M.apply_norm(p["ln2"], x, cfg)
     y, aux, stats = apply_moe_mlp(p["moe"], h, cfg,
                                   compute_dtype=compute_dtype)
-    return x + M.dropout(y, cfg.hidden_dropout, r_res2), aux, stats
+    return (x + M.residual_branch(M.dropout(y, cfg.hidden_dropout, r_res2),
+                                  cfg), aux, stats)

@@ -484,6 +484,64 @@ def hlo_counts(hlo_text: str) -> Dict[str, Any]:
             "collectives": counts}
 
 
+# named scopes whose instructions the step report keeps, by mixer kind: a
+# TPU trace names its events by HLO instruction (``fusion.12``), and the
+# scope an instruction came from is only in the HLO's ``op_name`` metadata
+MIXER_SCOPES = {"mamba": tuple(
+    f"mixer/mamba/{part}"
+    for part in ("in_proj", "conv", "ssd", "gated_norm", "out_proj"))}
+_INSTRUCTION = re.compile(r"^\s+(?:ROOT )?%?([\w.\-]+) = ")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+# ``jvp(mixer/mamba)/ssd`` and ``transpose(jvp(mixer/mamba))/ssd`` are both
+# under ``mixer/mamba/ssd``: a transformation wraps the part of the name
+# stack it was applied under
+_TRANSFORM = re.compile(r"\w+\(|\)")
+
+
+def scope_instructions(hlo_text: str, scopes: Sequence[str]
+                       ) -> Dict[str, Any]:
+    """Which instructions of an optimized HLO text lie under each of
+    ``scopes`` (``jax.named_scope`` paths): ``{"scopes": {scope: [names]},
+    "instructions": every name}``. An instruction counts by its own
+    ``op_name``, so a fusion by its root's; the instructions INSIDE a fused
+    computation are no events of a trace and are left out. With these a
+    reader lays device time over scopes: a trace event's name is an
+    instruction's."""
+    found: Dict[str, List[str]] = {s: [] for s in scopes}
+    names = set()
+    for m in _COMPUTATION.finditer(hlo_text):
+        if "fused_computation" in m.group(1):
+            continue
+        for line in m.group(0).splitlines():
+            inst = _INSTRUCTION.match(line)
+            if not inst:
+                continue
+            names.add(inst.group(1))
+            op = _OP_NAME.search(line)
+            if not op:
+                continue
+            path = _TRANSFORM.sub("", op.group(1)) + "/"
+            for s in scopes:
+                if s + "/" in path:
+                    found[s].append(inst.group(1))
+    return {"scopes": found, "instructions": frozenset(names)}
+
+
+# what ``scope_instructions`` found in the step program this process last
+# reported (cli/train_dist.py), for a reader in the same process (the
+# benchmark's per-layer readers run there); empty until a step reports
+_STEP_SCOPES: Dict[str, Any] = {}
+
+
+def record_step_scopes(found: Dict[str, Any]) -> None:
+    _STEP_SCOPES.clear()
+    _STEP_SCOPES.update(found)
+
+
+def step_scopes() -> Dict[str, Any]:
+    return dict(_STEP_SCOPES)
+
+
 def mosaic_custom_calls(fn: Any, args: Sequence[Any]) -> int:
     """Mosaic (Pallas TPU) kernels in the COMPILED program of a jitted
     ``fn`` that was just called with ``args``-shaped inputs, counted in the

@@ -292,7 +292,8 @@ def _segment_operands(segments, block: int):
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "block_q", "block_k",
-                                             "interpret", "dropout_rate"))
+                                             "interpret", "dropout_rate",
+                                             "scale"))
 def flash_attention_hmajor(
     q: jax.Array,  # [B, N, S, D]
     k: jax.Array,  # [B, K, S, D]
@@ -305,6 +306,7 @@ def flash_attention_hmajor(
     block_k: int = 256,
     interpret: bool = False,
     dropout_rate: float = 0.0,
+    scale: "float | None" = None,  # softmax(scale * q.k^T); None = D ** -0.5
 ) -> jax.Array:
     B, N, S, D = q.shape
     K = k.shape[1]
@@ -321,7 +323,8 @@ def flash_attention_hmajor(
     has_seg = segments is not None
     kernel = functools.partial(
         _flash_kernel, block_q=block_q, block_k=block_k, chunks=chunks,
-        num_major=num_major, causal=causal, scale=1.0 / math.sqrt(D),
+        num_major=num_major, causal=causal,
+        scale=1.0 / math.sqrt(D) if scale is None else scale,
         has_seg=has_seg, dropout_rate=dropout_rate)
 
     def kj_of(qi, kj):
@@ -516,7 +519,8 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, o_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "block_q", "block_k",
-                                             "interpret", "dropout_rate"))
+                                             "interpret", "dropout_rate",
+                                             "scale"))
 def flash_attention_bwd_hmajor(
     q, k, v, o, lse, do, segments=None, dropout_seed=None, *,
     causal: bool = True,
@@ -524,9 +528,11 @@ def flash_attention_bwd_hmajor(
     block_k: int = 256,
     interpret: bool = False,
     dropout_rate: float = 0.0,
+    scale: "float | None" = None,
 ):
     """Fused flash backward (heads-major layouts): recomputes p from lse per
-    tile, so nothing O(S^2) ever hits HBM. Returns (dq, dk, dv)."""
+    tile, so nothing O(S^2) ever hits HBM. Returns (dq, dk, dv). ``scale``:
+    the forward's (``None`` = ``D ** -0.5``)."""
     B, N, S, D = q.shape
     KV = k.shape[1]
     Sk = k.shape[2]  # may differ from S (ring off-diagonal blocks)
@@ -535,7 +541,7 @@ def flash_attention_bwd_hmajor(
     block_k = min(block_k, Sk)
     _check_call(S, Sk, block_q, block_k, causal, segments, dropout_rate,
                 dropout_seed)
-    scale = 1.0 / math.sqrt(D)
+    scale = 1.0 / math.sqrt(D) if scale is None else scale
     has_seg = segments is not None
     # for dk/dv, as rows; the dq kernel takes its own from the o / dO tiles
     delta = _chunk_rows(
@@ -698,38 +704,40 @@ def choose_blocks(D: int, S: int, Sk: int, floor: int = 128):
             fit_block(DEFAULT_BLOCK_K, Sk, floor) or Sk)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10))
 def _flash_with_vjp(q, k, v, segments, dropout_seed, causal, interpret,
-                    block_q, block_k, dropout_rate):
+                    block_q, block_k, dropout_rate, scale):
     qh = q.transpose(0, 2, 1, 3)
     kh = k.transpose(0, 2, 1, 3)
     vh = v.transpose(0, 2, 1, 3)
     out, _ = flash_attention_hmajor(qh, kh, vh, segments, dropout_seed,
                                     causal=causal, interpret=interpret,
                                     block_q=block_q, block_k=block_k,
-                                    dropout_rate=dropout_rate)
+                                    dropout_rate=dropout_rate, scale=scale)
     return out.transpose(0, 2, 1, 3)
 
 
 def _flash_fwd(q, k, v, segments, dropout_seed, causal, interpret, block_q,
-               block_k, dropout_rate):
+               block_k, dropout_rate, scale):
     qh = q.transpose(0, 2, 1, 3)
     kh = k.transpose(0, 2, 1, 3)
     vh = v.transpose(0, 2, 1, 3)
     out, lse = flash_attention_hmajor(qh, kh, vh, segments, dropout_seed,
                                       causal=causal, interpret=interpret,
                                       block_q=block_q, block_k=block_k,
-                                      dropout_rate=dropout_rate)
+                                      dropout_rate=dropout_rate, scale=scale)
     return (out.transpose(0, 2, 1, 3),
             (qh, kh, vh, out, lse, segments, dropout_seed))
 
 
-def _flash_bwd(causal, interpret, block_q, block_k, dropout_rate, res, g):
+def _flash_bwd(causal, interpret, block_q, block_k, dropout_rate, scale, res,
+               g):
     qh, kh, vh, out, lse, segments, dropout_seed = res
     dq, dk, dv = flash_attention_bwd_hmajor(
         qh, kh, vh, out, lse, g.transpose(0, 2, 1, 3), segments,
         dropout_seed, causal=causal, interpret=interpret,
-        block_q=block_q, block_k=block_k, dropout_rate=dropout_rate)
+        block_q=block_q, block_k=block_k, dropout_rate=dropout_rate,
+        scale=scale)
     return (dq.transpose(0, 2, 1, 3), dk.transpose(0, 2, 1, 3),
             dv.transpose(0, 2, 1, 3), None, None)  # int operands: no cotan
 
@@ -747,7 +755,7 @@ def seed_from_key(rng: jax.Array) -> jax.Array:
 def flash_sdpa(q, k, v, *, causal: bool = True, interpret: bool = False,
                block_q: int | None = None, block_k: int | None = None,
                segment_ids=None, dropout_rate: float = 0.0,
-               dropout_rng=None):
+               dropout_rng=None, scale: float | None = None):
     """Drop-in sdpa_fn for modules.apply_attention: [B, S, N, D] layout in
     and out; fully differentiable — forward and backward both run as fused
     Pallas kernels (backward recomputes p per tile from the saved
@@ -764,6 +772,10 @@ def flash_sdpa(q, k, v, *, causal: bool = True, interpret: bool = False,
     trajectories are deterministic per seed but not bit-equal to the XLA
     core's (the reference's CUDA kernel has the same property vs torch).
 
+    ``scale``: softmax(scale * q.k^T) where a model states its own
+    (``ModelArgs.attention_multiplier``); ``None`` is ``D ** -0.5``, the
+    float every kernel was given before the argument was there.
+
     Blocks not given come from ``choose_blocks``: a function of the head
     width and the q / kv lengths alone."""
     S, Sk = q.shape[1], k.shape[1]
@@ -774,13 +786,15 @@ def flash_sdpa(q, k, v, *, causal: bool = True, interpret: bool = False,
             raise ValueError("flash dropout_rate > 0 needs dropout_rng")
         seed = seed_from_key(dropout_rng)
     return _flash_with_vjp(q, k, v, segment_ids, seed, causal, interpret,
-                           block_q or bq, block_k or bk, dropout_rate)
+                           block_q or bq, block_k or bk, dropout_rate, scale)
 
 
 # the fwd + both bwd kernels mask cross-document tiles in-kernel
 flash_sdpa.supports_segments = True
 # in-kernel counter-based attention dropout (fwd + bwd regenerate the mask)
 flash_sdpa.supports_dropout = True
+# the softmax scale is an argument of all three kernels
+flash_sdpa.supports_scale = True
 
 
 def make_flash_sdpa(mesh, dp_axes=(), tp_axes=(), *, interpret: bool = False,
@@ -827,7 +841,7 @@ def make_flash_sdpa(mesh, dp_axes=(), tp_axes=(), *, interpret: bool = False,
         return seed + idx * jnp.int32(-1640531527)  # 2654435761 as int32
 
     def sdpa(q, k, v, *, causal=True, segment_ids=None,
-             dropout_rate: float = 0.0, dropout_rng=None):
+             dropout_rate: float = 0.0, dropout_rng=None, scale=None):
         # a length no lane-aligned block divides runs as ONE whole-length
         # block; what then overflows VMEM fails at compile time — there is
         # no XLA core behind the kernel to hide it
@@ -858,7 +872,7 @@ def make_flash_sdpa(mesh, dp_axes=(), tp_axes=(), *, interpret: bool = False,
             s = rest[0] if has_seg else None
             sd = _shard_seed(rest[-1]) if has_seed else None
             return _flash_with_vjp(a, b, c, s, sd, causal, interpret,
-                                   bq, bk, dropout_rate)
+                                   bq, bk, dropout_rate, scale)
 
         from jax.experimental.shard_map import shard_map
 
@@ -874,4 +888,5 @@ def make_flash_sdpa(mesh, dp_axes=(), tp_axes=(), *, interpret: bool = False,
 
     sdpa.supports_segments = True
     sdpa.supports_dropout = True
+    sdpa.supports_scale = True
     return sdpa

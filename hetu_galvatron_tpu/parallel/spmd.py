@@ -201,7 +201,7 @@ def tp_overlap_overrides(
     ``fallbacks`` lists (layer index, unsupported_reason) for layers the
     caller asked to overlap but could not — the launcher logs them."""
     from hetu_galvatron_tpu.analysis.eligibility import (
-        CONV_REASON,
+        MIXER_OVERLAP_REASON,
         MOE_REASON,
         T5_REASON,
         layer_overlap_reason,
@@ -223,7 +223,7 @@ def tp_overlap_overrides(
             fallbacks.append((i, MOE_REASON))
             continue
         if kinds[i][0] != "full_attention":
-            fallbacks.append((i, CONV_REASON))
+            fallbacks.append((i, MIXER_OVERLAP_REASON[kinds[i][0]]))
             continue
         tp_axes = sh.weight_tp_axes
         reason = layer_overlap_reason(cfg, sh, axes_size(mesh, tp_axes))

@@ -931,9 +931,25 @@ _BLOCK_HF_NAMES = {
              "down": "feed_forward.w2.weight",
              "final": "model.embedding_norm.weight"},
 }
+# granite: llama's norms and attention; the shared MLP keeps gate | up in
+# ONE matrix, as ``win`` does
+_BLOCK_HF_NAMES["granite"] = {
+    **{k: v for k, v in _BLOCK_HF_NAMES["llama"].items()
+       if k not in ("gate", "up")},
+    "gate_up": "shared_mlp.input_linear.weight",
+    "down": "shared_mlp.output_linear.weight"}
 _CONV_HF_NAMES = ("conv.in_proj.weight", "conv.conv.weight",
                   "conv.out_proj.weight")
-_MIXER_KINDS = ("full_attention", "conv")
+# a ``mamba`` block's names are Granite-4.0-H's (HF
+# ``GraniteMoeHybridMambaLayer``): the program's leaf -> the public name;
+# in_proj's rows are z | x | B | C | dt as ``win``'s columns are
+_MAMBA_HF_NAMES = {"win": "mamba.in_proj.weight",
+                   "taps": "mamba.conv1d.weight",
+                   "conv_bias": "mamba.conv1d.bias",
+                   "dt_bias": "mamba.dt_bias", "A_log": "mamba.A_log",
+                   "D": "mamba.D", "norm": "mamba.norm.weight",
+                   "wout": "mamba.out_proj.weight"}
+_MIXER_KINDS = ("full_attention", "conv", "mamba")
 
 
 def _unknown_mixer(i: int, mixer: str) -> ValueError:
@@ -1014,6 +1030,15 @@ def hf_to_params(state_dict: Dict[str, Any], cfg: ModelArgs) -> Params:
             # kernel is [channels, 1, taps]
             lp["conv"] = {"win": np.stack([t.T for t in np.split(w_in, 3)]),
                           "taps": w_taps[:, 0, :], "wout": w_out.T}
+        elif mixer == "mamba":
+            nm = {k: pre + v for k, v in _MAMBA_HF_NAMES.items()}
+            lp["mamba"] = {
+                "win": lin(nm["win"]), "taps": sd[nm["taps"]][:, 0, :],
+                "dt_bias": sd[nm["dt_bias"]], "A_log": sd[nm["A_log"]],
+                "D": sd[nm["D"]], "norm": {"scale": sd[nm["norm"]]},
+                "wout": lin(nm["wout"])}
+            if cfg.mamba_conv_bias:
+                lp["mamba"]["conv_bias"] = sd[nm["conv_bias"]]
         elif mixer == "full_attention":
             lp["attn"] = {
                 "wqkv": np.concatenate(
@@ -1061,10 +1086,11 @@ def hf_to_params(state_dict: Dict[str, Any], cfg: ModelArgs) -> Params:
             if cfg.moe_router_enable_expert_bias and bias:
                 lp["moe"]["expert_bias"] = sd[pre + bias]
         else:
-            win = np.concatenate(
-                [lin(pre + names["gate"]), lin(pre + names["up"])], axis=1)
+            win = (lin(pre + names["gate_up"]) if "gate_up" in names
+                   else np.concatenate([lin(pre + names["gate"]),
+                                        lin(pre + names["up"])], axis=1))
             lp["mlp"] = {"win": win, "wout": lin(pre + names["down"])}
-        if cfg.add_qkv_bias:
+        if cfg.add_qkv_bias and "attn" in lp:
             lp["attn"]["bqkv"] = np.concatenate(
                 [sd[pre + "self_attn.q_proj.bias"],
                  sd[pre + "self_attn.k_proj.bias"],
@@ -1400,6 +1426,14 @@ def params_to_hf(params: Params, cfg: ModelArgs) -> Dict[str, np.ndarray]:
                 [t.T for t in get(lp["conv"]["win"])])
             sd[n_taps] = get(lp["conv"]["taps"])[:, None, :]
             sd[n_out] = get(lp["conv"]["wout"]).T
+        elif mixer == "mamba":
+            mp = lp["mamba"]
+            for leaf, name in _MAMBA_HF_NAMES.items():
+                if leaf not in mp:
+                    continue
+                w = get(mp[leaf]["scale"] if leaf == "norm" else mp[leaf])
+                sd[pre + name] = (w.T if leaf in ("win", "wout")
+                                  else w[:, None, :] if leaf == "taps" else w)
         elif mixer == "full_attention":
             wqkv = get(lp["attn"]["wqkv"])
             q, k, v = np.split(wqkv, [nq * hd, (nq + nkv) * hd], axis=1)
@@ -1439,9 +1473,12 @@ def params_to_hf(params: Params, cfg: ModelArgs) -> Dict[str, np.ndarray]:
                 sd[pre + bias] = get(lp["moe"]["expert_bias"])
         else:
             win = get(lp["mlp"]["win"])
-            gate, up = np.split(win, 2, axis=1)
-            sd[pre + names["gate"]] = gate.T
-            sd[pre + names["up"]] = up.T
+            if "gate_up" in names:
+                sd[pre + names["gate_up"]] = win.T
+            else:
+                gate, up = np.split(win, 2, axis=1)
+                sd[pre + names["gate"]] = gate.T
+                sd[pre + names["up"]] = up.T
             sd[pre + names["down"]] = get(lp["mlp"]["wout"]).T
         sd[pre + names["ln1"]] = get(lp["ln1"]["scale"])
         sd[pre + names["ln2"]] = get(lp["ln2"]["scale"])

@@ -203,6 +203,7 @@ def get_hybrid_parallel_config(
             eligibility.pp_division_len_reason(pp_division, pp_deg, vpp),
             eligibility.batch_grain_reason(global_bsz, world_size, pp_deg,
                                            layers, vocab),
+            eligibility.mamba_plan_reason(args.model, layers),
             eligibility.capacity_dispatch_reason(
                 dispatcher=args.model.moe_dispatcher,
                 tokens=global_bsz // chunks * args.model.seq_length,

@@ -106,11 +106,16 @@ def _check_supported(cfg: ModelArgs, params: Params) -> None:
             "have no paged decode path")
     if any("moe" in lp for lp in params["layers"]):
         raise NotImplementedError("ServingEngine: dense layers only")
-    from hetu_galvatron_tpu.analysis.eligibility import mixed_stack_reason
+    from hetu_galvatron_tpu.analysis.eligibility import (
+        mixed_stack_reason,
+        own_multipliers_reason,
+    )
 
     reason = mixed_stack_reason(
         cfg, "ServingEngine (paged key-value blocks for every layer, no "
-        "convolution state)")
+        "convolution state and no state-space state)"
+    ) or own_multipliers_reason(
+        cfg, "ServingEngine (its paged attention cores)")
     if reason is not None:
         raise NotImplementedError(reason)
 

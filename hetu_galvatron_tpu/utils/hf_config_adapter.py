@@ -35,11 +35,12 @@ _FIELD_MAP = {
 
 _GEMMA_FAMILIES = {"gemma"}
 _LFM2_FAMILIES = {"lfm2", "lfm2_moe"}
+_GRANITE_HYBRID = "granitemoehybrid"
 _ROPE_FAMILIES = {"llama", "qwen2", "mistral", "mixtral", "olmoe",
                   "qwen"} | _GEMMA_FAMILIES | _LFM2_FAMILIES
-_RMS_FAMILIES = _ROPE_FAMILIES | {"t5"}
+_RMS_FAMILIES = _ROPE_FAMILIES | {"t5", _GRANITE_HYBRID}
 _SWIGLU_FAMILIES = {"llama", "qwen2", "mistral", "mixtral", "olmoe",
-                    "qwen"} | _LFM2_FAMILIES
+                    "qwen", _GRANITE_HYBRID} | _LFM2_FAMILIES
 # gemma-2/3 add sandwich norms, logit softcapping, query_pre_attn_scalar,
 # alternating sliding windows (v3: q/k-norm, dual rope) — none of which this
 # stack implements; mapping them through gemma-1 numerics would silently
@@ -147,6 +148,8 @@ def populate_model_args_from_hf(
                     d.get("use_expert_bias", True)),
                 # trained with cross-entropy alone: no balancing-loss key
                 moe_aux_loss_coeff=0.0)
+    if family == _GRANITE_HYBRID:
+        values.update(_granite_hybrid_values(d))
     if family == "bert":
         # HF bert uses erf gelu everywhere (BertIntermediate + the MLM
         # transform); our "gelu" is the tanh approximation (gpt2's gelu_new)
@@ -162,9 +165,10 @@ def populate_model_args_from_hf(
         values["hidden_act"] = "geglu" if "gated" in ff else "relu"
         values["tie_word_embeddings"] = bool(d.get("tie_word_embeddings",
                                                    True))
-    values["position_embedding_type"] = (
-        "rope" if family in _ROPE_FAMILIES else "learned"
-    )
+    if family != _GRANITE_HYBRID:   # which states its own
+        values["position_embedding_type"] = (
+            "rope" if family in _ROPE_FAMILIES else "learned"
+        )
     scaling = values.get("rope_scaling")
     if isinstance(scaling, dict) and "mrope_section" in scaling:
         # qwen2-vl style multimodal rope: rope_scaling carries the section
@@ -176,7 +180,8 @@ def populate_model_args_from_hf(
         values["rope_scaling"] = rest or None
     # bias detection (reference hf_config_adapter.py:196-290 reads
     # attention_bias / mlp_bias / family defaults)
-    bias_free = _ROPE_FAMILIES | {"t5"}  # llama-likes and t5 default to no biases
+    # llama-likes and t5 default to no biases
+    bias_free = _ROPE_FAMILIES | {"t5", _GRANITE_HYBRID}
     if "attention_bias" in d:
         values["add_qkv_bias"] = bool(d["attention_bias"])
     elif family in {"qwen", "qwen2"}:
@@ -188,6 +193,65 @@ def populate_model_args_from_hf(
     else:
         values["add_bias_linear"] = family not in bias_free
     return ModelArgs.model_validate(values)
+
+
+def _granite_hybrid_values(d: Dict[str, Any]) -> Dict[str, Any]:
+    """Granite-4.0-H (IBM; HF modeling_granitemoehybrid): Mamba-2 blocks
+    (Bamba's mixer) and attention blocks by ``layer_types``, the shared
+    SwiGLU MLP in every block, positions from nowhere (``nope``) or from
+    RoPE, and four stated multipliers. The published words ``mamba`` and
+    ``attention`` become the program's ``mamba`` and ``full_attention``."""
+    family = _GRANITE_HYBRID
+    if int(d.get("num_local_experts") or 0) > 0:
+        raise NotImplementedError(
+            f"{family} num_local_experts={d['num_local_experts']}: a block "
+            "whose feed-forward adds routed experts to the shared MLP is not "
+            "implemented (shared experts beside routed ones)")
+    # HF builds a rotary table only for "rope"; an absent key is no positions
+    pos = d.get("position_embedding_type") or "nope"
+    if pos not in ("nope", "rope"):
+        raise NotImplementedError(
+            f"{family} position_embedding_type={pos!r}: nope (no positions) "
+            "and rope are implemented")
+    types = d.get("layer_types") or d.get("layers_block_type")
+    if types is None:
+        raise NotImplementedError(
+            f"{family}: config.json names no layer_types (which blocks are "
+            "mamba and which attend)")
+    words = {"mamba": "mamba", "attention": "full_attention"}
+    unknown = sorted(set(types) - set(words))
+    if unknown:
+        raise NotImplementedError(
+            f"{family} layer_types holds {unknown}: mamba and attention "
+            "are implemented")
+    heads, d_head = int(d["mamba_n_heads"]), d.get("mamba_d_head", "auto")
+    inner = int(d.get("mamba_expand", 2)) * int(d["hidden_size"])
+    if d_head in (None, "auto"):
+        d_head = inner // heads
+    if heads * int(d_head) != inner:
+        raise ValueError(
+            f"{family}: mamba_n_heads {heads} x mamba_d_head {d_head} is "
+            f"not mamba_expand x hidden_size = {inner}")
+    out: Dict[str, Any] = dict(
+        model_type="llama", hf_layout="granite", num_experts=0, moe_topk=2,
+        position_embedding_type=pos,
+        layer_types=[words[t] for t in types],
+        ffn_hidden_size=int(d.get("shared_intermediate_size")
+                            or d["intermediate_size"]),
+        tie_word_embeddings=bool(d.get("tie_word_embeddings", True)),
+        mamba_n_heads=heads, mamba_d_head=int(d_head),
+        mamba_d_state=int(d.get("mamba_d_state", 256)),
+        mamba_n_groups=int(d.get("mamba_n_groups", 1)),
+        mamba_d_conv=int(d.get("mamba_d_conv", 4)),
+        mamba_chunk_size=int(d.get("mamba_chunk_size", 256)),
+        mamba_conv_bias=bool(d.get("mamba_conv_bias", True)),
+        mamba_proj_bias=bool(d.get("mamba_proj_bias", False)),
+        embedding_multiplier=float(d.get("embedding_multiplier", 1.0)),
+        residual_multiplier=float(d.get("residual_multiplier", 1.0)),
+        logits_scaling=float(d.get("logits_scaling", 1.0)))
+    if d.get("attention_multiplier") is not None:
+        out["attention_multiplier"] = float(d["attention_multiplier"])
+    return out
 
 
 def resolve_model_config(args: CoreArgs, hf_path: Optional[str] = None) -> CoreArgs:

@@ -4,10 +4,11 @@ gauges and the one histogram it writes; a run in which one is missing is
 refused on the chip as malformed. Here every such name is looked up the way
 the benchmark does it, through the readers' own code and tables (imported,
 never copied), after ``train_dist.main`` ran on the CPU at a tiny size: the
-dense preset, an expert model with the dropless path of ``olmoe_c1_s4k``, and
-a tiny LFM2 (conv and attention blocks, a held share of the experts) with
-the path of ``lfm2moe_c1_s8k``. One case a name, so a renamed span fails by
-its name."""
+dense preset, an expert model with the dropless path of ``olmoe_c1_s4k``, a
+tiny LFM2 (conv and attention blocks, a held share of the experts) with the
+path of ``lfm2moe_c1_s8k``, and a tiny Granite hybrid (a Mamba-2 block and
+an attention block) with the path of ``granite4h_c1_b1``. One case a name,
+so a renamed span fails by its name."""
 
 import glob
 import json
@@ -30,6 +31,8 @@ host_phases = manifest.load_python(os.path.join(READERS, "host_phases.py"))
 # the files whose readers look into the program's registry, not the trace
 GAUGE_FILES = ("program_gauges.py", "moe_gauges.py", "lfm2_gauges.py")
 lfm2_gauges = manifest.load_python(os.path.join(READERS, "lfm2_gauges.py"))
+granite_scopes = manifest.load_python(
+    os.path.join(READERS, "granite_scopes.py"))
 
 TRACED, MEASURED = 3, 2
 ITERS = window.WARMUP_STEPS + TRACED + MEASURED
@@ -53,6 +56,11 @@ PRESETS = {
         # a quarter of the experts: at a half and more the layer that holds
         # a share has no short body to take
         "model.moe_held_experts=2", "model.moe_first_held_expert=0"],
+    "granite": ["granite-4.0-h-micro.yaml"] + SIZE + [
+        "model.layer_types=[mamba,full_attention]",
+        "model.num_key_value_heads=2", "model.ffn_hidden_size=32",
+        "model.mamba_n_heads=4", "model.mamba_d_head=16",
+        "model.mamba_d_state=8", "model.mamba_chunk_size=8"],
 }
 # what a cell without an expert layer does not write, and is not asked for;
 # and what only a layer that holds a share of its experts writes
@@ -197,6 +205,104 @@ def test_the_lowered_step_carries_the_scope_names(scope):
         p, {"tokens": t, "labels": t}, cfg)).lower(params, tokens).as_text(
             debug_info=True)
     assert scope in text
+
+
+@pytest.mark.parametrize("name,value", [
+    ("ssd/chunks", 2),                  # 16 positions in chunks of 8
+    ("ssd/state_bytes", 4 * 64 * 8),    # 4 heads x 16 x 8 float32
+])
+def test_a_state_space_block_says_what_it_carries(run, name, value):
+    found = {m.labels["layer"]: m.value for m in run["registry"].metrics()
+             if m.name == name}
+    assert found == ({"layer0": value} if run["preset"] == "granite" else {})
+
+
+def test_a_mamba_block_reports_its_own_operator(run):
+    blocks = [m.value for m in run["registry"].metrics()
+              if m.name == "step/blocks"
+              and m.labels == {"mixer": "mamba", "ff": "dense"}]
+    if run["preset"] != "granite":
+        assert not blocks and "mamba2" not in run["result"]["attention_cores"]
+        return
+    assert blocks == [1] and run["result"]["blocks"] == {
+        "mamba/dense": 1, "full_attention/dense": 1}
+    assert run["result"]["attention_cores"] == ["mamba2", "xla"]
+
+
+@pytest.mark.parametrize("scope", granite_scopes.SCOPES)
+def test_the_step_report_keeps_the_instructions_under_a_scope(run, scope):
+    """The map the ``granite_*`` readers join a trace to: instruction names
+    of the compiled step's HLO under each ``mixer/mamba/*`` scope, in
+    ``train()``'s result and where a reader in this process finds them."""
+    from hetu_galvatron_tpu.observability import trace_analysis
+
+    kept = run["result"]["scope_instructions"]
+    if run["preset"] != "granite":
+        assert kept is None
+        return
+    assert kept[scope] and len(set(kept[scope])) == len(kept[scope])
+    mine = trace_analysis.step_scopes()
+    assert mine["scopes"][scope] == kept[scope]
+    assert set(kept[scope]) <= mine["instructions"]
+    # the scopes do not share an instruction
+    others = {n for s in granite_scopes.SCOPES if s != scope
+              for n in kept[s]}
+    assert not others & set(kept[scope])
+
+
+def _traced_by_hand(names, known_only=True):
+    """A steady window of two periods in which every named instruction ran
+    0.1 ms a step, one after the other: what ``xplane.reduce_device`` hands
+    a reader, without a TPU."""
+    ms, leaves, t = 1e6, [], 0.0
+    steps = [(0.0, 50 * ms), (60 * ms, 110 * ms), (120 * ms, 170 * ms)]
+    for start, _ in steps[:2]:
+        t = start
+        for n in list(names) + ([] if known_only else ["stranger.1"]):
+            leaves.append((n, t, t + 0.1 * ms))
+            t += 0.1 * ms
+    return xplane.Reduced(0, steps, (0.0, 120 * ms), leaves,
+                          [(n, e - s) for n, s, e in leaves], [])
+
+
+def test_the_granite_readers_join_a_trace_to_the_map(run):
+    from benchmark import flops, peaks
+
+    if run["preset"] != "granite":
+        return
+    kept = run["result"]["scope_instructions"]
+    every = [n for s in granite_scopes.SCOPES for n in kept[s]]
+    ssd = [n for s in granite_scopes.SSD_SCOPES for n in kept[s]]
+    facts = {"trace": {"reduced": [_traced_by_hand(every)]},
+             "sizes": flops.Sizes(layers=10, hidden=2048, heads=32,
+                                  kv_heads=8, head_dim=64, ffn=8192,
+                                  ffn_matrices=3, vocab=12544, seq=8192),
+             "sequences_per_step": 1, "chips": 1,
+             "peaks": peaks.peaks_of("TPU v5 lite")}
+    assert len(every) < 500    # they fit a step of the hand-made trace
+    assert granite_scopes.mamba_ms(facts) == pytest.approx(0.1 * len(every))
+    assert granite_scopes.ssd_ms(facts) == pytest.approx(0.1 * len(ssd))
+    assert granite_scopes.ssd_time_share_pct(facts) == pytest.approx(
+        100.0 * len(ssd) / len(every))
+    assert 0 < granite_scopes.ssd_roofline(facts) < 100
+    # no trace, or an operation inside a step that is no instruction of the
+    # step's HLO: nothing is published
+    assert granite_scopes.ssd_ms({}) is None
+    stranger = {**facts, "trace": {"reduced": [
+        _traced_by_hand(every, known_only=False)]}}
+    assert granite_scopes.ssd_ms(stranger) is None
+
+
+def test_without_a_map_the_granite_readers_publish_nothing(monkeypatch):
+    """What the parent commit gives them: no ``step_scopes`` in the
+    program, or an empty one."""
+    from hetu_galvatron_tpu.observability import trace_analysis
+
+    facts = {"trace": {"reduced": [_traced_by_hand(["fusion.1"])]}}
+    monkeypatch.setattr(trace_analysis, "_STEP_SCOPES", {})
+    assert granite_scopes.mamba_ms(facts) is None
+    monkeypatch.delattr(trace_analysis, "step_scopes")
+    assert granite_scopes.ssd_roofline(facts) is None
 
 
 def test_iteration_spans_are_flat_siblings_on_the_dispatching_thread(run):
