@@ -680,6 +680,7 @@ def train(args) -> Dict[str, Any]:
     drop_key = jax.random.key(args.train.seed) if use_dropout else None
     # what the compiled step contains (filled after the first step)
     from hetu_galvatron_tpu.observability.trace_analysis import (
+        SSD_SCOPE,
         hlo_counts,
         record_step_scopes,
         scope_instructions,
@@ -1123,6 +1124,13 @@ def train(args) -> Dict[str, Any]:
                         found = scope_instructions(hlo_text, report_scopes)
                         record_step_scopes(found)
                         step_report["scope_instructions"] = found["scopes"]
+                        # whether the scan's kernels engaged: the Mosaic
+                        # calls under its scope, 0 = the jax.numpy form
+                        step_report["ssd_mosaic_calls"] = sum(
+                            n in found["mosaic_calls"]
+                            for n in found["scopes"].get(SSD_SCOPE, ()))
+                        get_registry().gauge("ssd/mosaic_calls").set(
+                            step_report["ssd_mosaic_calls"])
                     step_report["static_memory"] = compiled_memory_bytes(
                         compiled)
                     for part, v in step_report["static_memory"].items():
@@ -1140,7 +1148,10 @@ def train(args) -> Dict[str, Any]:
                         f", {len(names)} instructions under {scope}"
                         for scope, names in step_report.get(
                             "scope_instructions", {}).items())
-                    + f", {step_report['mosaic_custom_calls']} Mosaic calls,"
+                    + f", {step_report['mosaic_custom_calls']} Mosaic calls"
+                    + (f" ({step_report['ssd_mosaic_calls']} under "
+                       f"{SSD_SCOPE})" if "ssd_mosaic_calls" in step_report
+                       else "") + ","
                     f" static live peak "
                     f"{step_report['static_memory']['live_peak'] / 2**30:.3f}"
                     " GiB")
@@ -1201,6 +1212,9 @@ def train(args) -> Dict[str, Any]:
             # state-space block has (what a trace's events are joined to);
             # None for a model without one and for the pp engines
             "scope_instructions": step_report.get("scope_instructions"),
+            # the Mosaic calls among those under mixer/mamba/ssd (the gauge
+            # ssd/mosaic_calls): 0 where the scan ran in its jax.numpy form
+            "ssd_mosaic_calls": step_report.get("ssd_mosaic_calls"),
             "exit_code": exit_code}
 
 

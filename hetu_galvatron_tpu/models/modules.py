@@ -637,7 +637,9 @@ def ssd_chunks_a_group(batch: int, chunks: int, heads: int,
 
 def ssd_chunked(x: jax.Array, dt: jax.Array, A: jax.Array, Bm: jax.Array,
                 Cm: jax.Array, chunk: int,
-                compute_dtype=jnp.bfloat16) -> jax.Array:
+                compute_dtype=jnp.bfloat16,
+                scan_fn: Optional[Callable[..., jax.Array]] = None
+                ) -> jax.Array:
     """The selective state-space recurrence of Mamba-2 in its chunked,
     matmul form (Dao & Gu 2024, "SSD"). Per head, with state ``S`` [P, N],
     zero before the sequence::
@@ -657,7 +659,18 @@ def ssd_chunked(x: jax.Array, dt: jax.Array, A: jax.Array, Bm: jax.Array,
     state ENTERING a chunk adds ``exp(cs_i) C_i . S``. Sums, decays and the
     carried state are float32; the matmul operands are ``compute_dtype``
     with float32 accumulation. A sequence that ``chunk`` does not divide is
-    padded with ``dt = 0`` (no decay, no input), and the padding cut off."""
+    padded with ``dt = 0`` (no decay, no input), and the padding cut off.
+
+    One algorithm run one of two ways, by what the caller hands in and the
+    shapes alone. ``scan_fn`` (the Pallas kernels of ``ops/pallas/ssd.py``,
+    which whoever knows the devices hands down:
+    ``parallel/spmd.attention_overrides``) runs where the shapes fit its
+    tiles (``ssd.tile_plan``): the decay matrix, ``C B^T * L`` and the
+    carried state then live in VMEM. Otherwise it is ``jax.numpy``, the
+    chunks in groups whose decay matrices fit ``SSD_DECAY_BYTES``, each
+    group's made again in the backward pass."""
+    from hetu_galvatron_tpu.ops.pallas.ssd import tile_plan
+
     f32 = jnp.float32
     B_, S, H, P = x.shape
     pad = -S % chunk
@@ -666,6 +679,10 @@ def ssd_chunked(x: jax.Array, dt: jax.Array, A: jax.Array, Bm: jax.Array,
             jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
             for t in (x, dt, Bm, Cm))
     Q, nC = chunk, (S + pad) // chunk
+    if scan_fn is not None and tile_plan(Q, H, P, Bm.shape[-1]) is not None:
+        return scan_fn(x.astype(compute_dtype), dt, A,
+                       Bm.astype(compute_dtype), Cm.astype(compute_dtype),
+                       Q)[:, :S]
     size = ssd_chunks_a_group(B_, nC, H, Q)
     groups = nC // size
 
@@ -723,6 +740,7 @@ def apply_mamba2(
     x: jax.Array,
     cfg: ModelArgs,
     compute_dtype=jnp.bfloat16,
+    ssd_fn: Optional[Callable[..., jax.Array]] = None,
 ) -> jax.Array:
     """``[z | xBC | dt] = x W_in``; ``xBC = silu(conv1d_causal(xBC) + b)``
     (depthwise, ``mamba_d_conv`` taps, zero history before the sequence);
@@ -732,7 +750,9 @@ def apply_mamba2(
     channels; ``y W_out``. No softmax, no positions. The two projections
     and the recurrence's matmuls run in ``compute_dtype`` with float32
     accumulation; ``dt``, the decays, the state, the convolution and the
-    gated norm are float32."""
+    gated norm are float32. ``ssd_fn``: the kernels for the
+    recurrence, where the caller's devices run them
+    (:func:`ssd_chunked`'s ``scan_fn``)."""
     B, S, _ = x.shape
     nh, hp, N = cfg.mamba_n_heads, cfg.mamba_d_head, cfg.mamba_d_state
     di, f32 = cfg.mamba_d_inner, jnp.float32
@@ -751,13 +771,17 @@ def apply_mamba2(
             xs, Bm, Cm = jnp.split(
                 jax.nn.silu(c).astype(compute_dtype), [di, di + N], axis=-1)
         with jax.named_scope("ssd"):
-            xs = xs.reshape(B, S, nh, hp)
             dt = jax.nn.softplus(dt + p["dt_bias"])
-            y = ssd_chunked(xs, dt, -jnp.exp(p["A_log"].astype(f32)), Bm, Cm,
-                            cfg.mamba_chunk_size, compute_dtype)
-            y = y + p["D"][:, None] * xs.astype(f32)
+            y = ssd_chunked(xs.reshape(B, S, nh, hp), dt,
+                            -jnp.exp(p["A_log"].astype(f32)), Bm, Cm,
+                            cfg.mamba_chunk_size, compute_dtype,
+                            scan_fn=ssd_fn)
+            # a head is hp of a row's lanes, here as in the kernels: a
+            # [.., heads, hp] view of a row is no bitcast on a TPU
+            y = (y.reshape(B, S, di)
+                 + jnp.repeat(p["D"], hp) * xs.astype(f32))
         with jax.named_scope("gated_norm"):
-            y = y.reshape(B, S, di) * jax.nn.silu(z.astype(f32))
+            y = y * jax.nn.silu(z.astype(f32))
             var = jnp.mean(jnp.square(y), axis=-1, keepdims=True)
             y = (y * jax.lax.rsqrt(var + cfg.layernorm_epsilon)
                  * p["norm"]["scale"]).astype(compute_dtype)
@@ -777,13 +801,15 @@ def apply_mixer(
     compute_dtype=jnp.bfloat16,
     shard_fn: Optional[Callable[[jax.Array, int], jax.Array]] = None,
     segment_ids: Optional[jax.Array] = None,
+    ssd_fn: Optional[Callable[..., jax.Array]] = None,
     **attn_kwargs: Any,
 ) -> jax.Array:
     """A block's operator on its normed input, by the block's mixer kind
     (``ModelArgs.block_kinds``): attention from ``p["attn"]``, the gated
     short convolution from ``p["conv"]`` or the Mamba-2 state-space block
     from ``p["mamba"]``; the last two take no rope, no attention core and
-    no dropout of probabilities."""
+    no dropout of probabilities, and the last alone takes ``ssd_fn``
+    (:func:`apply_mamba2`)."""
     if mixer == "full_attention":
         return apply_attention(p["attn"], h, cfg, compute_dtype=compute_dtype,
                                shard_fn=shard_fn, segment_ids=segment_ids,
@@ -800,7 +826,8 @@ def apply_mixer(
             + " would cross document boundaries; set "
             "data.reset_attention_mask=false")
     if mixer == "mamba":
-        return apply_mamba2(p["mamba"], h, cfg, compute_dtype=compute_dtype)
+        return apply_mamba2(p["mamba"], h, cfg, compute_dtype=compute_dtype,
+                            ssd_fn=ssd_fn)
     return apply_short_conv(p["conv"], h, cfg, compute_dtype=compute_dtype,
                             shard_fn=shard_fn)
 
@@ -963,6 +990,7 @@ def apply_decoder_layer(
     matmul_fns: Optional[Dict[str, Callable]] = None,
     shard_fn: Optional[Callable[[jax.Array, int], jax.Array]] = None,
     mixer: str = "full_attention",
+    ssd_fn: Optional[Callable[..., jax.Array]] = None,
 ) -> jax.Array:
     """Pre-norm residual block (reference GalvatronDecoderLayer,
     modules.py:233). Encoder families (bert, t5 encoder stack) run the same
@@ -973,7 +1001,8 @@ def apply_decoder_layer(
     matmuls for overlapped tensor-parallel impls (ops/overlap.py);
     ``shard_fn`` keeps a tp > 1 layer's interior on its own shards
     (:func:`apply_attention`). ``mixer`` is the block's operator kind
-    (:func:`apply_mixer`; pre-norm blocks only)."""
+    and ``ssd_fn`` a mamba block's kernels (:func:`apply_mixer`; pre-norm
+    blocks only)."""
     if causal is None:
         causal = cfg.model_type != "bert"
     r_attn = r_res1 = r_res2 = None
@@ -1013,7 +1042,8 @@ def apply_decoder_layer(
         drop_h(apply_mixer(p, h, cfg, mixer, rope=rope, sdpa_fn=sdpa_fn,
                            compute_dtype=compute_dtype, causal=causal,
                            dropout_rng=r_attn, segment_ids=segment_ids,
-                           matmul_fns=matmul_fns, shard_fn=shard_fn),
+                           matmul_fns=matmul_fns, shard_fn=shard_fn,
+                           ssd_fn=ssd_fn),
                r_res1), cfg)
     h = apply_norm(p["ln2"], x, cfg)
     x = x + residual_branch(

@@ -521,11 +521,12 @@ def apply_moe_decoder_layer(
     dropout_rng=None,
     segment_ids=None,
     mixer: str = "full_attention",
+    ssd_fn=None,
 ) -> Tuple[jax.Array, jax.Array, Dict[str, jax.Array]]:
     """Pre-norm block with an MoE FFN; returns (x, aux_loss, router
     stats) — stats feed the per-layer balance tracker (reference
-    moe_utils.py:547-644). ``mixer``: the block's operator kind
-    (modules.apply_mixer)."""
+    moe_utils.py:547-644). ``mixer``: the block's operator kind, and
+    ``ssd_fn`` a mamba block's kernels (modules.apply_mixer)."""
     r_attn = r_res1 = r_res2 = None
     if dropout_rng is not None:
         r_attn, r_res1, r_res2 = jax.random.split(dropout_rng, 3)
@@ -533,7 +534,7 @@ def apply_moe_decoder_layer(
     x = x + M.residual_branch(M.dropout(
         M.apply_mixer(p, h, cfg, mixer, rope=rope, sdpa_fn=sdpa_fn,
                       compute_dtype=compute_dtype, dropout_rng=r_attn,
-                      segment_ids=segment_ids),
+                      segment_ids=segment_ids, ssd_fn=ssd_fn),
         cfg.hidden_dropout, r_res1), cfg)
     h = M.apply_norm(p["ln2"], x, cfg)
     y, aux, stats = apply_moe_mlp(p["moe"], h, cfg,

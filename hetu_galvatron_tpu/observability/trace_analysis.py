@@ -452,6 +452,7 @@ def jit_cost_summary(fn: Any, args: Sequence[Any] = (),
         return {}
 
 
+_MOSAIC_CALL = 'custom_call_target="tpu_custom_call"'
 COLLECTIVE_OPS = ("all-to-all", "all-gather", "all-reduce", "reduce-scatter",
                   "collective-permute")
 _COMPUTATION = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) \(.*?^\}", re.M | re.S)
@@ -479,8 +480,7 @@ def hlo_counts(hlo_text: str) -> Dict[str, Any]:
             continue
         for op in COLLECTIVE_OPS:
             counts[op] += text.count(f" {op}(") + text.count(f" {op}-start(")
-    return {"mosaic_custom_calls": hlo_text.count(
-                'custom_call_target="tpu_custom_call"'),
+    return {"mosaic_custom_calls": hlo_text.count(_MOSAIC_CALL),
             "collectives": counts}
 
 
@@ -490,6 +490,9 @@ def hlo_counts(hlo_text: str) -> Dict[str, Any]:
 MIXER_SCOPES = {"mamba": tuple(
     f"mixer/mamba/{part}"
     for part in ("in_proj", "conv", "ssd", "gated_norm", "out_proj"))}
+# the scope of the state-space scan, whose Mosaic calls the step report
+# counts (``ssd/mosaic_calls``; ops/pallas/ssd.py traces under it)
+SSD_SCOPE = "mixer/mamba/ssd"
 _INSTRUCTION = re.compile(r"^\s+(?:ROOT )?%?([\w.\-]+) = ")
 _OP_NAME = re.compile(r'op_name="([^"]*)"')
 # ``jvp(mixer/mamba)/ssd`` and ``transpose(jvp(mixer/mamba))/ssd`` are both
@@ -502,13 +505,14 @@ def scope_instructions(hlo_text: str, scopes: Sequence[str]
                        ) -> Dict[str, Any]:
     """Which instructions of an optimized HLO text lie under each of
     ``scopes`` (``jax.named_scope`` paths): ``{"scopes": {scope: [names]},
-    "instructions": every name}``. An instruction counts by its own
+    "instructions": every name, "mosaic_calls": the names that are Mosaic
+    (Pallas TPU) kernels}``. An instruction counts by its own
     ``op_name``, so a fusion by its root's; the instructions INSIDE a fused
     computation are no events of a trace and are left out. With these a
     reader lays device time over scopes: a trace event's name is an
     instruction's."""
     found: Dict[str, List[str]] = {s: [] for s in scopes}
-    names = set()
+    names, mosaic = set(), set()
     for m in _COMPUTATION.finditer(hlo_text):
         if "fused_computation" in m.group(1):
             continue
@@ -517,6 +521,8 @@ def scope_instructions(hlo_text: str, scopes: Sequence[str]
             if not inst:
                 continue
             names.add(inst.group(1))
+            if _MOSAIC_CALL in line:
+                mosaic.add(inst.group(1))
             op = _OP_NAME.search(line)
             if not op:
                 continue
@@ -524,7 +530,8 @@ def scope_instructions(hlo_text: str, scopes: Sequence[str]
             for s in scopes:
                 if s + "/" in path:
                     found[s].append(inst.group(1))
-    return {"scopes": found, "instructions": frozenset(names)}
+    return {"scopes": found, "instructions": frozenset(names),
+            "mosaic_calls": frozenset(mosaic)}
 
 
 # what ``scope_instructions`` found in the step program this process last

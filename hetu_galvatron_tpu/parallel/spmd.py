@@ -13,7 +13,7 @@ through NCCL.
 
 from __future__ import annotations
 
-from typing import Any, Callable, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -120,6 +120,8 @@ def attention_overrides(
     with_cross: bool = False,
     cp_zigzag: bool = False,
     flash_interpret: bool = False,
+    mixers: Optional[Sequence[str]] = None,
+    use_ssd_kernel: Optional[bool] = None,
 ) -> Dict[int, Dict[str, Any]]:
     """Per-layer attention-impl dispatch (reference attention.py:664-720),
     branching on :func:`~hetu_galvatron_tpu.runtime.mesh.attention_core`:
@@ -143,7 +145,14 @@ def attention_overrides(
 
     ``flash_interpret=True`` runs the Pallas kernels in interpret mode —
     CPU parity drills forcing ``use_flash=True`` on the virtual mesh (the
-    compiled-vs-host kernel drills run the SAME kernel on both sides)."""
+    compiled-vs-host kernel drills run the SAME kernel on both sides).
+
+    ``mixers`` (the layers' mixer kinds, ``ModelArgs.block_kinds``): a
+    ``mamba`` layer gets ``ssd_fn``, the Pallas kernels for its chunked scan
+    (ops/pallas/ssd.py), when ``use_ssd_kernel`` (None =
+    the same rule: every mesh device is a TPU). Whether the shapes fit the
+    kernels' tiles is ``modules.ssd_chunked``'s to see; it keeps its
+    ``jax.numpy`` form where they do not, and where it is handed nothing."""
     from functools import partial as _partial
 
     from hetu_galvatron_tpu.models.modules import xla_sdpa
@@ -184,6 +193,15 @@ def attention_overrides(
             out[i] = {"sdpa_fn": make_flash_sdpa(
                 mesh, dp_axes=sh.dp_axes, tp_axes=sh.tp_axes,
                 interpret=flash_interpret)}
+    mamba = [i for i, mixer in enumerate(mixers or ()) if mixer == "mamba"]
+    if mamba and (flash_kernel_runs(True, mesh.devices.flat)
+                  if use_ssd_kernel is None else use_ssd_kernel):
+        from hetu_galvatron_tpu.ops.pallas.ssd import make_ssd_scan
+
+        for i in mamba:
+            out.setdefault(i, {})["ssd_fn"] = make_ssd_scan(
+                mesh, dp_axes=per_layer[i].dp_axes,
+                interpret=flash_interpret)
     return out
 
 
@@ -478,7 +496,8 @@ def build_spmd_loss_fn(
             b_layers, mesh, use_flash=use_flash,
             with_cross=cfg.model_type == "t5",
             cp_zigzag=getattr(hpc, "cp_zigzag", False),
-            flash_interpret=kernel_interpret)
+            flash_interpret=kernel_interpret,
+            mixers=[m for m, _ in cfg.block_kinds(len(b_layers))])
         enc_overrides = (attention_overrides(
             b_enc, mesh, use_flash=use_flash,
             flash_interpret=kernel_interpret) if b_enc else None)

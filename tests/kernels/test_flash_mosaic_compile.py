@@ -1,6 +1,6 @@
-"""The flash kernels compiled by the real Mosaic / XLA:TPU compilers for a
-described (not attached) TPU v5e, at the widths the benchmark's cells run and
-with every optional operand: what interpret mode cannot refuse (a slice off
+"""The flash kernels, and the kernels of the Mamba-2 scan, compiled by the
+real Mosaic / XLA:TPU compilers for a described (not attached) TPU v5e, at
+the widths the benchmark's cells run and with every optional operand: what interpret mode cannot refuse (a slice off
 the tiling, a relayout Mosaic has no rule for, too much VMEM) fails here, on
 the CPU, in seconds. Nothing runs, so nothing here is a result or a time.
 
@@ -12,6 +12,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
+from hetu_galvatron_tpu.ops.pallas import ssd
 from hetu_galvatron_tpu.ops.pallas.flash_attention import flash_sdpa
 
 pytestmark = pytest.mark.kernels
@@ -71,3 +72,44 @@ def test_flash_forward_and_backward_compile_for_v5e(one_chip, case):
                    "flash_attention_bwd_dkv"):
         assert kernel in hlo, f"{kernel} is not in the compiled program"
     assert hlo.count('custom_call_target="tpu_custom_call"') == 3
+
+
+# B, S, heads, head dim, state, chunk, dtype
+_SSD_CASES = {
+    "granite_cell": (1, 8192, 64, 64, 128, 256, jnp.bfloat16),
+    "two_rows_f32": (2, 1024, 16, 64, 128, 256, jnp.float32),
+    "a_head_a_lane_tile": (1, 1024, 8, 128, 128, 128, jnp.bfloat16),
+    "four_heads_a_lane_tile": (1, 512, 16, 32, 256, 256, jnp.bfloat16),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_SSD_CASES))
+def test_ssd_scan_forward_and_backward_compile_for_v5e(one_chip, case):
+    """Both kernels compile at the cell's shapes, and each is one
+    instruction under ``mixer/mamba/ssd`` in the map the ``granite_*``
+    readers lay a trace over: the forward by the scope it was called in,
+    the backward by the scope its rule opens."""
+    from hetu_galvatron_tpu.observability.trace_analysis import (
+        scope_instructions,
+    )
+
+    B, S, H, P, N, Q, dtype = _SSD_CASES[case]
+
+    def spec(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def block(*a):
+        with jax.named_scope("mixer/mamba"):
+            with jax.named_scope("ssd"):
+                return ssd.ssd_scan(*a, Q)
+
+    compiled = jax.jit(jax.grad(
+        lambda *a: jnp.sum(block(*a)), argnums=(0, 1, 2, 3, 4))).lower(
+            spec((B, S, H, P), dtype), spec((B, S, H), jnp.float32),
+            spec((H,), jnp.float32), spec((B, S, N), dtype),
+            spec((B, S, N), dtype)).compile()
+    found = scope_instructions(compiled.as_text(), (ssd.SCOPE,))
+    calls = sorted(found["mosaic_calls"])
+    assert len(calls) == 2, calls
+    assert "ssd_scan_bwd" in calls[0] and "ssd_scan_fwd" in calls[1], calls
+    assert set(calls) <= set(found["scopes"][ssd.SCOPE])

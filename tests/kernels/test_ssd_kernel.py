@@ -1,0 +1,217 @@
+"""The Pallas kernels of the chunked Mamba-2 scan (``ops/pallas/ssd.py``) in
+interpret mode on the CPU, at the widths the benchmark's cell runs (head 64,
+state 128, chunk 256) with a small batch and head count: against
+``modules.ssd_chunked``'s ``jax.numpy`` form AND against the plain
+reference's recurrence one position at a time, values and the gradients to
+all five inputs, where the chunk divides the sequence and where the last
+chunk is padded, with float32 operands (tight) and bfloat16 operands (the
+program's). Then the controls that tell a state or a decay kept in bfloat16
+from float32, pointed at the kernels; and that which path runs follows from
+shapes and devices alone."""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmark import reference
+from hetu_galvatron_tpu.models import modules as M
+from hetu_galvatron_tpu.ops.pallas import ssd
+from tools.granite_forward_check import rounding_scan
+
+pytestmark = pytest.mark.kernels
+
+BATCH, HEAD_DIM, STATE, CHUNK = 2, 64, 128, 256
+NAMES = ("y", "dx", "ddt", "dA", "dB", "dC")
+# relative RMS distance allowed, (values, gradients): float32 sides differ
+# in operation order alone; with bfloat16 operands each side rounds M, x dt
+# and the state where the other does, but the kernels also round the
+# cotangent dy for the MXU, which XLA on the CPU does not (the chip's default
+# precision does), and both stand 0.014 to 0.023 from the float32 recurrence
+LIMITS = {("float32", "chunked"): (1e-5, 1e-4),
+          ("float32", "sequential"): (1e-5, 1e-4),
+          ("bfloat16", "chunked"): (2e-3, 3e-2),
+          ("bfloat16", "sequential"): (2e-2, 5e-2)}
+
+
+def _family():
+    return reference.load_family("granite_hybrid")
+
+
+def _inputs(seq, dtype, heads=8):
+    k = jax.random.split(jax.random.key(0), 5)
+    return (jax.random.normal(k[0], (BATCH, seq, heads, HEAD_DIM)
+                              ).astype(dtype),
+            # dt as the model starts it: softplus of a bias near -3
+            jax.nn.softplus(jax.random.normal(k[1], (BATCH, seq, heads)) - 3),
+            -jnp.exp(jax.random.normal(k[2], (heads,))),
+            jax.random.normal(k[3], (BATCH, seq, STATE)).astype(dtype),
+            jax.random.normal(k[4], (BATCH, seq, STATE)).astype(dtype))
+
+
+def _value_and_grads(scan, args):
+    y, vjp = jax.vjp(scan, *args)
+    return (y,) + vjp(jnp.cos(y))
+
+
+@functools.lru_cache(maxsize=None)
+def _sides(seq, dtype_name, heads=8):
+    """(kernels, chunked in jax.numpy, sequential in float32) on one set of
+    inputs, each as (y, dx, ddt, dA, dB, dC) in float32."""
+    dtype = jnp.dtype(dtype_name)
+    args = _inputs(seq, dtype, heads)
+    kernel = lambda *a: M.ssd_chunked(
+        *a, CHUNK, dtype, scan_fn=functools.partial(ssd.ssd_scan,
+                                                    interpret=True))
+    chunked = lambda *a: M.ssd_chunked(*a, CHUNK, dtype)
+    as_f32 = lambda side: tuple(np.asarray(t, np.float32) for t in side)
+    return {"kernel": as_f32(_value_and_grads(kernel, args)),
+            "chunked": as_f32(_value_and_grads(chunked, args)),
+            "sequential": as_f32(_value_and_grads(
+                _family().selective_scan,
+                tuple(t.astype(jnp.float32) for t in args)))}
+
+
+def _apart(a, b):
+    return float(np.sqrt(np.mean(np.square(a - b)))
+                 / np.sqrt(np.mean(np.square(b))))
+
+
+@pytest.mark.parametrize("quantity", NAMES)
+@pytest.mark.parametrize("against", ["chunked", "sequential"])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+# two chunks, one step of eight heads; three chunks, the last one padded,
+# two steps of sixteen heads and of eight (the plans of ``tile_plan``)
+@pytest.mark.parametrize("seq,heads", [(512, 8), (600, 32), (600, 24)])
+def test_kernel_scan_is_the_chunked_and_the_sequential_one(
+        seq, heads, dtype, against, quantity):
+    sides = _sides(seq, dtype, heads)
+    at = NAMES.index(quantity)
+    got, want = sides["kernel"][at], sides[against][at]
+    assert got.shape == want.shape and np.isfinite(got).all()
+    limit = LIMITS[dtype, against][min(at, 1)]
+    assert _apart(got, want) < limit, (_apart(got, want), limit)
+
+
+@pytest.mark.parametrize("case", ["as_published", "state_carried_in_bf16",
+                                  "decay_in_bf16"])
+def test_a_bf16_state_or_decay_is_told_from_the_kernels(case):
+    """The tier-1 controls of ``test_granite_hybrid`` pointed at the kernel
+    path: the reference's recurrence with its carried state or its decay
+    rounded to bfloat16 at every position is what kernels that kept either
+    in bfloat16 would compute, and it lies many times farther from the
+    kernels than the float32 recurrence does, by values or by gradients."""
+    kernel = _sides(600, "float32")["kernel"]
+    want = _sides(600, "float32")["sequential"]
+    near = max(_apart(g, w) for g, w in zip(kernel, want))
+    assert near < 1e-4
+    if case == "as_published":
+        return
+    args = tuple(t.astype(jnp.float32) for t in _inputs(600, jnp.float32))
+    rounded = _value_and_grads(rounding_scan(
+        round_state=case == "state_carried_in_bf16",
+        round_decay=case == "decay_in_bf16"), args)
+    far = max(_apart(g, np.asarray(r)) for g, r in zip(kernel, rounded))
+    assert far > 10 * near and far > 1e-3, (case, near, far)
+
+
+@pytest.mark.parametrize("chunk,heads,head_dim,state,plan", [
+    (256, 64, 64, 128, 16),    # the cell: two heads a lane tile
+    (256, 8, 64, 128, 8),      # fewer heads than the widest step
+    (256, 8, 128, 128, 8),     # a head a whole lane tile
+    (128, 16, 32, 256, 16),
+    (256, 16, 256, 128, None),      # eight heads span too many lanes
+    (8, 8, 8, 16, None),            # the tests' tiny model
+    (256, 64, 64, 96, None),        # a state off the lane tiling
+    (192, 64, 64, 128, None),       # a chunk off the lane tiling
+    (256, 6, 64, 128, None),        # heads that fill no grid step
+    (256, 64, 48, 128, None),       # a head no fraction of a lane tile
+])
+def test_the_tile_plan_is_a_function_of_shapes(chunk, heads, head_dim, state,
+                                               plan):
+    assert ssd.tile_plan(chunk, heads, head_dim, state) == plan
+
+
+def test_shapes_that_fit_no_tile_take_the_jax_numpy_form():
+    """Chunk 8 with the kernels handed in: they are not called, nothing is
+    raised, and the result is the plain one's bits."""
+    def never(*a, **kw):
+        raise AssertionError("the kernels were called")
+    k = jax.random.split(jax.random.key(1), 5)
+    args = (jax.random.normal(k[0], (2, 21, 4, 8)),
+            jax.nn.softplus(jax.random.normal(k[1], (2, 21, 4))),
+            -jnp.exp(jax.random.normal(k[2], (4,))),
+            jax.random.normal(k[3], (2, 21, 16)),
+            jax.random.normal(k[4], (2, 21, 16)))
+    np.testing.assert_array_equal(
+        np.asarray(M.ssd_chunked(*args, 8, jnp.float32, scan_fn=never)),
+        np.asarray(M.ssd_chunked(*args, 8, jnp.float32)))
+    with pytest.raises(ValueError, match="fit no tile"):
+        ssd.ssd_scan(*args, 8, interpret=True)
+
+
+@pytest.mark.parametrize("forced", [None, True, False])
+def test_who_knows_the_devices_hands_the_kernels_down(forced):
+    """``attention_overrides`` gives a mamba layer ``ssd_fn`` where every
+    device of the mesh is a TPU (here: never, unless a test says so), and
+    no other layer ever."""
+    from hetu_galvatron_tpu.parallel.spmd import attention_overrides
+    from hetu_galvatron_tpu.runtime.mesh import LayerSharding, build_mesh
+
+    mesh = build_mesh(2, 1, devices=jax.devices()[:2])
+    per_layer = [LayerSharding(dp_axes=("d0",), cp_axes=(), tp_axes=())] * 3
+    got = attention_overrides(
+        per_layer, mesh, use_flash=False, flash_interpret=True,
+        mixers=["mamba", "full_attention", "conv"], use_ssd_kernel=forced)
+    assert got == {} if not forced else (
+        list(got) == [0] and list(got[0]) == ["ssd_fn"])
+    if forced:
+        # and what it hands down is the scan, under shard_map
+        args = _inputs(256, jnp.float32)
+        np.testing.assert_allclose(
+            np.asarray(got[0]["ssd_fn"](*args, CHUNK)),
+            np.asarray(M.ssd_chunked(*args, CHUNK, jnp.float32)),
+            rtol=1e-4, atol=1e-4)
+
+
+def test_forward_and_backward_are_traced_under_the_scans_scope():
+    """What lays device time over ``mixer/mamba/ssd`` is the ``op_name`` of
+    a compiled instruction (``trace_analysis.scope_instructions``). The
+    forward is called under the block's scope; the backward rule of a
+    ``custom_vjp`` is traced when the gradient is taken, outside every
+    scope of the model, and opens the scope itself (this JAX also carries
+    the forward's name stack into the rule; the kernels do not lean on
+    it). Here as the step does it: the scope around the forward only,
+    ``jax.grad`` around the whole."""
+    from hetu_galvatron_tpu.observability import trace_analysis
+
+    assert ssd.SCOPE == trace_analysis.SSD_SCOPE
+    assert ssd.SCOPE in trace_analysis.MIXER_SCOPES["mamba"]
+
+    def block(*a):
+        with jax.named_scope("mixer/mamba"):
+            with jax.named_scope("ssd"):
+                return ssd.ssd_scan(*a, CHUNK, interpret=True)
+
+    args = _inputs(CHUNK, jnp.float32)
+    text = jax.jit(jax.grad(lambda *a: jnp.sum(jnp.sin(block(*a))),
+                            argnums=(0, 1, 2, 3, 4))).lower(
+                                *args).compile().as_text()
+    found = trace_analysis.scope_instructions(text, (ssd.SCOPE,))
+    listed = set(found["scopes"][ssd.SCOPE])
+    calls = {"ssd_scan_fwd": [0, 0], "ssd_scan_bwd": [0, 0]}
+    for line in text.splitlines():
+        inst = trace_analysis._INSTRUCTION.match(line)
+        op = trace_analysis._OP_NAME.search(line)
+        if not inst or not op or inst.group(1) not in found["instructions"]:
+            continue
+        for call, (inside, outside) in calls.items():
+            if f"/{call}/" in op.group(1):
+                calls[call] = [inside + (inst.group(1) in listed),
+                               outside + (inst.group(1) not in listed)]
+    # (interpret mode: a call is the instructions it was unrolled into)
+    for call, (inside, outside) in calls.items():
+        assert inside > 0 and outside == 0, (call, inside, outside)
+    assert found["mosaic_calls"] == frozenset()   # none on a CPU

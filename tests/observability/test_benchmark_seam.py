@@ -11,7 +11,9 @@ an attention block) with the path of ``granite4h_c1_b1``. One case a name,
 so a renamed span fails by its name."""
 
 import glob
+import io
 import json
+import logging
 import os
 
 import pytest
@@ -110,10 +112,18 @@ def run(request, tmp_path_factory):
     before = get_registry()
     reg = set_registry(MetricsRegistry())
     try:
-        out = {}
-        assert train_dist.main(argv, result=out) == 0
+        out, said = {}, io.StringIO()
+        # the launcher's own logger (runtime/initialize.py), which does
+        # not propagate to the root where pytest listens
+        heard = logging.StreamHandler(said)
+        logging.getLogger("hetu_galvatron_tpu").addHandler(heard)
+        try:
+            assert train_dist.main(argv, result=out) == 0
+        finally:
+            logging.getLogger("hetu_galvatron_tpu").removeHandler(heard)
         assert len(out["losses"]) == ITERS
         yield {"preset": request.param, "registry": reg, "result": out,
+               "log": said.getvalue(),
                "trace": xplane.find_xplane(tdir),
                # the CPU's allocator states no limit; a chip's does
                "facts": {"memory": {"per_device": [{"bytes_limit": 2 ** 34}]}}}
@@ -248,6 +258,55 @@ def test_the_step_report_keeps_the_instructions_under_a_scope(run, scope):
     others = {n for s in granite_scopes.SCOPES if s != scope
               for n in kept[s]}
     assert not others & set(kept[scope])
+
+
+def test_the_step_report_says_whether_the_scan_kernels_engaged(run):
+    """``ssd/mosaic_calls``: the Mosaic calls among the instructions under
+    ``mixer/mamba/ssd``. A step compiled for a CPU holds none (the scan ran
+    in its ``jax.numpy`` form), and the gauge, the result and the ``step
+    report:`` line say 0; the block is ``mamba2`` on the ``attention
+    cores:`` line whichever way its scan runs."""
+    gauges = [m.value for m in run["registry"].metrics()
+              if m.name == "ssd/mosaic_calls"]
+    (report,) = [line for line in run["log"].splitlines()
+                 if "step report:" in line]
+    if run["preset"] != "granite":
+        assert not gauges and run["result"]["ssd_mosaic_calls"] is None
+        assert "under mixer/mamba/ssd" not in report.split("Mosaic")[1]
+        return
+    assert gauges == [0] and run["result"]["ssd_mosaic_calls"] == 0
+    assert "0 Mosaic calls (0 under mixer/mamba/ssd)," in report
+    assert "attention cores: 1 x mamba2, 1 x xla" in run["log"]
+
+
+def test_a_mosaic_call_is_counted_under_its_scope():
+    from hetu_galvatron_tpu.observability.trace_analysis import (
+        SSD_SCOPE,
+        scope_instructions,
+    )
+
+    hlo = """HloModule jit_step
+
+%fused_computation.1 (p: f32[8]) -> f32[8] {
+  %p = f32[8]{0} parameter(0)
+  ROOT %inner.1 = f32[8]{0} exponential(%p), metadata={op_name="jit(step)/mixer/mamba/ssd/exp"}
+}
+
+ENTRY %main (a: f32[8]) -> f32[8] {
+  %a = f32[8]{0} parameter(0)
+  %ssd_scan_fwd.3 = f32[8]{0} custom-call(%a), custom_call_target="tpu_custom_call", metadata={op_name="jit(step)/jvp(mixer/mamba)/ssd/ssd_scan_fwd/pallas_call"}
+  %ssd_scan_bwd.4 = f32[8]{0} custom-call(%a), custom_call_target="tpu_custom_call", metadata={op_name="jit(step)/transpose(jvp())/checkpoint/mixer/mamba/ssd/ssd_scan_bwd/pallas_call"}
+  %flash_attention_fwd.1 = f32[8]{0} custom-call(%a), custom_call_target="tpu_custom_call", metadata={op_name="jit(step)/jvp()/flash_attention_fwd/pallas_call"}
+  ROOT %fusion.7 = f32[8]{0} fusion(%ssd_scan_fwd.3), kind=kLoop, calls=%fused_computation.1, metadata={op_name="jit(step)/mixer/mamba/ssd/exp"}
+}
+"""
+    found = scope_instructions(hlo, (SSD_SCOPE,))
+    assert found["scopes"][SSD_SCOPE] == [
+        "ssd_scan_fwd.3", "ssd_scan_bwd.4", "fusion.7"]
+    assert found["mosaic_calls"] == {
+        "ssd_scan_fwd.3", "ssd_scan_bwd.4", "flash_attention_fwd.1"}
+    assert sum(n in found["mosaic_calls"]
+               for n in found["scopes"][SSD_SCOPE]) == 2
 
 
 def _traced_by_hand(names, known_only=True):

@@ -10,8 +10,9 @@ random weights the loss lies within a thousandth of ln(vocabulary) whatever
 a block does. This looks closer, once, outside the harness: the cell's own
 weights for one seed and its first 8192-token sequence go through
 ``forward_causal_lm`` (bfloat16, the recurrence in its chunked matmul form,
-the flash core at the model's own softmax scale on the block that attends:
-what the cell trains with) and through
+on a TPU through the kernels of ``ops/pallas/ssd.py``, the flash core at
+the model's own softmax scale on the block that attends: what the cell
+trains with) and through
 ``benchmark/reference/granite_hybrid.py`` (float32 under
 ``jax.default_matmul_precision("highest")``, the recurrence one position at
 a time), and the two sets of logits ``[8192, vocab]`` are compared. Then one
@@ -106,6 +107,7 @@ def main() -> int:
         init_causal_lm,
     )
     from hetu_galvatron_tpu.ops.pallas.flash_attention import flash_sdpa
+    from hetu_galvatron_tpu.ops.pallas.ssd import ssd_scan
     from hetu_galvatron_tpu.utils.hf_config_adapter import resolve_model_config
 
     cell = manifest.resolve_cell(manifest.load_manifest(), a.workload)
@@ -166,7 +168,11 @@ def main() -> int:
     params = jax.jit(lambda k: init_causal_lm(k, cfg)[0])(
         jax.random.key(a.seed))
     on_tpu = dev.platform == "tpu"
-    sdpa = ({i: {"sdpa_fn": flash_sdpa} for i in range(cfg.num_hidden_layers)}
+    # on a TPU what the cell trains with: the flash core, and the scan's
+    # kernels in the mamba blocks
+    sdpa = ({i: {"ssd_fn": ssd_scan} if mixer == "mamba"
+             else {"sdpa_fn": flash_sdpa}
+             for i, (mixer, _) in enumerate(cfg.block_kinds())}
             if on_tpu else None)
 
     def but(**update):
@@ -180,8 +186,8 @@ def main() -> int:
     def mamba_operator(leaves):
         p = {**params["layers"][mamba_at]["mamba"], **leaves}
         return jax.jit(lambda p, x: M.apply_mamba2(
-            p, x.astype(jnp.bfloat16), cfg, compute_dtype=jnp.bfloat16))(
-                p, mamba_in)
+            p, x.astype(jnp.bfloat16), cfg, compute_dtype=jnp.bfloat16,
+            ssd_fn=ssd_scan if on_tpu else None))(p, mamba_in)
 
     def attention_operator(run_cfg):
         rope = None
