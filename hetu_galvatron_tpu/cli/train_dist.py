@@ -173,9 +173,6 @@ def train(args) -> Dict[str, Any]:
         from collections import Counter
 
         from hetu_galvatron_tpu.observability.registry import get_registry
-        from hetu_galvatron_tpu.observability.trace_analysis import (
-            MIXER_SCOPES,
-        )
         from hetu_galvatron_tpu.runtime.mesh import (
             attention_core,
             flash_kernel_runs,
@@ -207,9 +204,6 @@ def train(args) -> Dict[str, Any]:
                 get_registry().gauge("ssd/state_bytes", layer=f"layer{i}"
                                      ).set(4 * cfg.mamba_d_inner
                                            * cfg.mamba_d_state)
-        # named scopes whose instructions the step report keeps
-        report_scopes = tuple(s for m in dict.fromkeys(m for m, _ in kinds)
-                              for s in MIXER_SCOPES.get(m, ()))
 
         # abstract init first: the plan's shardings are derived from SHAPES, so
         # no device materializes the unsharded tree before they exist (the
@@ -681,9 +675,8 @@ def train(args) -> Dict[str, Any]:
     # what the compiled step contains (filled after the first step)
     from hetu_galvatron_tpu.observability.trace_analysis import (
         SSD_SCOPE,
-        hlo_counts,
         record_step_scopes,
-        scope_instructions,
+        step_hlo,
     )
 
     step_report: Dict[str, Any] = {}
@@ -1113,17 +1106,24 @@ def train(args) -> Dict[str, Any]:
                 # what the compiled step contains and needs, from the one
                 # executable the call above made (.lower() and .compile()
                 # return what that call cached: 0.05 to 0.4 s on the chip):
-                # Mosaic calls in its HLO, and XLA's static memory as
-                # step/static_bytes{part=...} gauges
+                # Mosaic calls and collectives in its HLO, every
+                # instruction's scope, phase and collective class (one walk
+                # over the text, kept for a reader of a trace), and XLA's
+                # static memory as step/static_bytes{part=...} gauges
                 with span("setup/step_report"):
                     compiled = fn.lower(out[0], out[1], b).compile()
                     # (as_text: 0.2 s on four chips)
-                    hlo_text = compiled.as_text()
-                    step_report.update(hlo_counts(hlo_text))
-                    if report_scopes:
-                        found = scope_instructions(hlo_text, report_scopes)
-                        record_step_scopes(found)
-                        step_report["scope_instructions"] = found["scopes"]
+                    found = step_hlo(compiled.as_text())
+                    record_step_scopes(found)
+                    step_report.update(
+                        mosaic_custom_calls=found["mosaic_custom_calls"],
+                        collectives=found["collectives"],
+                        scope_instructions=found["scopes"])
+                    step_report["step_map"] = {
+                        "instructions": len(found["map"]["instructions"]),
+                        "inferred": len(found["map"]["inferred"]),
+                        "unnamed": len(found["map"]["tails"])}
+                    if any(m == "mamba" for m, _ in kinds):
                         # whether the scan's kernels engaged: the Mosaic
                         # calls under its scope, 0 = the jax.numpy form
                         step_report["ssd_mosaic_calls"] = sum(
@@ -1146,8 +1146,11 @@ def train(args) -> Dict[str, Any]:
                         f"{n} x {kind}" for kind, n in blocks.items())
                     + "".join(
                         f", {len(names)} instructions under {scope}"
-                        for scope, names in step_report.get(
-                            "scope_instructions", {}).items())
+                        for scope, names in step_report[
+                            "scope_instructions"].items() if names)
+                    + ", {instructions} instructions mapped ({unnamed} "
+                      "under no scope, {inferred} by what they fuse)".format(
+                          **step_report["step_map"])
                     + f", {step_report['mosaic_custom_calls']} Mosaic calls"
                     + (f" ({step_report['ssd_mosaic_calls']} under "
                        f"{SSD_SCOPE})" if "ssd_mosaic_calls" in step_report
@@ -1209,9 +1212,13 @@ def train(args) -> Dict[str, Any]:
             # step/collectives gauges); None for the pp engines
             "collectives": step_report.get("collectives"),
             # instruction names of that step's HLO under each named scope a
-            # state-space block has (what a trace's events are joined to);
-            # None for a model without one and for the pp engines
+            # state-space block has (what the granite_* readers join a
+            # trace's events to; empty lists for a model without one), and
+            # how many instructions the whole map holds (the map itself:
+            # trace_analysis.step_scopes()["map"], and step_map.json beside
+            # a trace); None for the pp engines
             "scope_instructions": step_report.get("scope_instructions"),
+            "step_map": step_report.get("step_map"),
             # the Mosaic calls among those under mixer/mamba/ssd (the gauge
             # ssd/mosaic_calls): 0 where the scan ran in its jax.numpy form
             "ssd_mosaic_calls": step_report.get("ssd_mosaic_calls"),

@@ -139,8 +139,9 @@ def forward_causal_lm(
                                sections=cfg.mrope_section,
                                scaling=cfg.rope_scaling)
     elif cfg.position_embedding_type == "rope":
-        cos, sin = M.rope_cos_sin(S, cfg.head_dim, cfg.rope_theta,
-                                  scaling=cfg.rope_scaling)
+        with jax.named_scope("attn/rope"):
+            cos, sin = M.rope_cos_sin(S, cfg.head_dim, cfg.rope_theta,
+                                      scaling=cfg.rope_scaling)
         if position_ids is not None:
             # packed samples: gather per-token rows -> [B, S, D/2]
             cos, sin = cos[position_ids], sin[position_ids]
@@ -185,7 +186,7 @@ def forward_causal_lm(
             moe_stats[f"layer{i}"] = stats
     if boundary_fn is not None:
         x = boundary_fn(len(params["layers"]), x)
-    x = M.apply_norm(params["prenorm"], x, cfg)
+    x = M.block_norm(params["prenorm"], x, cfg)
     logits = M.apply_lm_head(
         params["head"], x, cfg,
         wte=params["embed"]["wte"], compute_dtype=compute_dtype,

@@ -107,6 +107,23 @@ def apply_norm(p: Params, x: jax.Array, cfg: ModelArgs) -> jax.Array:
     return y.astype(dtype)
 
 
+def block_norm(p: Params, x: jax.Array, cfg: ModelArgs) -> jax.Array:
+    """:func:`apply_norm` where it is a block's own norm (``ln1``, ``ln2``,
+    the one before the head), under the named scope ``norm``; the q/k norm
+    and a mamba block's gated norm have scopes of their own."""
+    with jax.named_scope("norm"):
+        return apply_norm(p, x, cfg)
+
+
+def weight_view(w: jax.Array, dtype) -> jax.Array:
+    """A stored weight in the compute dtype, under the named scope
+    ``param_view``: the cast is no part of the projection it feeds (XLA
+    hoists it out of the microbatch loop, and its transpose is the cast of
+    the weight's gradient back)."""
+    with jax.named_scope("param_view"):
+        return w.astype(dtype)
+
+
 # ---------------------------------------------------------------------------
 # rotary position embedding
 # ---------------------------------------------------------------------------
@@ -377,32 +394,35 @@ def apply_attention(
     hd = cfg.head_dim
     nq, nkv = cfg.num_attention_heads, cfg.kv_heads
     mm = matmul_fns or {}
-    w = p["wqkv"].astype(compute_dtype)
+    w = weight_view(p["wqkv"], compute_dtype)
     group_major = shard_fn is not None
-    if "qkv" in mm:
-        qkv = mm["qkv"](x.astype(compute_dtype), w)
-    else:
-        qkv = jnp.einsum("bsh,hkf->bskf" if group_major else "bsh,hf->bsf",
-                         x.astype(compute_dtype), w,
-                         preferred_element_type=jnp.float32)
-    if "bqkv" in p:
-        qkv = qkv + p["bqkv"]
-    qkv = qkv.astype(compute_dtype)
-    if group_major:
-        qkv = shard_fn(qkv, 2)
-        g = nq // nkv
-        q, k, v = (a.reshape(B, S, -1) for a in jnp.split(
-            qkv, [g * hd, (g + 1) * hd], axis=-1))
-    else:
-        q, k, v = jnp.split(qkv, [nq * hd, (nq + nkv) * hd], axis=-1)
+    with jax.named_scope("attn/qkv_proj"):
+        if "qkv" in mm:
+            qkv = mm["qkv"](x.astype(compute_dtype), w)
+        else:
+            qkv = jnp.einsum(
+                "bsh,hkf->bskf" if group_major else "bsh,hf->bsf",
+                x.astype(compute_dtype), w,
+                preferred_element_type=jnp.float32)
+        if "bqkv" in p:
+            qkv = qkv + p["bqkv"]
+        qkv = qkv.astype(compute_dtype)
+        if group_major:
+            qkv = shard_fn(qkv, 2)
+            g = nq // nkv
+            q, k, v = (a.reshape(B, S, -1) for a in jnp.split(
+                qkv, [g * hd, (g + 1) * hd], axis=-1))
+        else:
+            q, k, v = jnp.split(qkv, [nq * hd, (nq + nkv) * hd], axis=-1)
     per_head = cfg.qk_norm_per_head
     if "q_norm" in p and not per_head:
         with jax.named_scope("attn/qk_norm"):
             q = apply_norm(p["q_norm"], q, cfg)
             k = apply_norm(p["k_norm"], k, cfg)
-    q = q.reshape(B, S, nq, hd)
-    k = k.reshape(B, S, nkv, hd)
-    v = v.reshape(B, S, nkv, hd)
+    with jax.named_scope("attn/qkv_proj"):
+        q = q.reshape(B, S, nq, hd)
+        k = k.reshape(B, S, nkv, hd)
+        v = v.reshape(B, S, nkv, hd)
     if "q_norm" in p and per_head:
         with jax.named_scope("attn/qk_norm"):
             q = apply_norm(p["q_norm"], q, cfg)
@@ -411,9 +431,15 @@ def apply_attention(
         q, k, v = (shard_fn(a, 2) for a in (q, k, v))
     if rope is not None:
         cos, sin = rope
-        q = apply_rope(q, cos, sin)
-        k = apply_rope(k, cos, sin)
+        with jax.named_scope("attn/rope"):
+            q = apply_rope(q, cos, sin)
+            k = apply_rope(k, cos, sin)
     core_kwargs: Dict[str, Any] = {}
+
+    def core(*a, **kw):
+        with jax.named_scope("attn/core"):
+            return sdpa_fn(*a, **kw)
+
     if cfg.attention_multiplier is not None:
         # the model's own softmax scale is an argument of the core; a core
         # without the argument would attend at 1/sqrt(D) in silence
@@ -436,10 +462,10 @@ def apply_attention(
         # cliff on the long-context plans those kernels exist for — refuse.
         if sdpa_fn is xla_sdpa or getattr(sdpa_fn, "supports_dropout",
                                           False):
-            out = sdpa_fn(q, k, v, causal=causal,
-                          dropout_rate=cfg.attention_dropout,
-                          dropout_rng=dropout_rng, segment_ids=segment_ids,
-                          **core_kwargs)
+            out = core(q, k, v, causal=causal,
+                       dropout_rate=cfg.attention_dropout,
+                       dropout_rng=dropout_rng, segment_ids=segment_ids,
+                       **core_kwargs)
         else:
             raise NotImplementedError(
                 "attention_dropout > 0 is only supported with the XLA "
@@ -453,8 +479,8 @@ def apply_attention(
         # with their block) implement it; Ulysses does not
         if sdpa_fn is xla_sdpa or getattr(sdpa_fn, "supports_segments",
                                           False):
-            out = sdpa_fn(q, k, v, causal=causal, segment_ids=segment_ids,
-                          **core_kwargs)
+            out = core(q, k, v, causal=causal, segment_ids=segment_ids,
+                       **core_kwargs)
         else:
             raise NotImplementedError(
                 "reset_attention_mask is not supported by the installed "
@@ -462,19 +488,20 @@ def apply_attention(
                 "core for packed-document layers, or set "
                 "data.reset_attention_mask=false")
     else:
-        out = sdpa_fn(q, k, v, causal=causal, **core_kwargs)
-    out = out.reshape(B, S, nq * hd)
-    if group_major:
-        out = shard_fn(out, 2)
-    wo = p["wo"].astype(compute_dtype)
-    if "out" in mm:
-        y = mm["out"](out, wo)
-    else:
-        y = jnp.einsum("bsf,fh->bsh", out, wo,
-                       preferred_element_type=jnp.float32)
-    if "bo" in p:
-        y = y + p["bo"]
-    return y.astype(compute_dtype)
+        out = core(q, k, v, causal=causal, **core_kwargs)
+    with jax.named_scope("attn/out_proj"):
+        out = out.reshape(B, S, nq * hd)
+        if group_major:
+            out = shard_fn(out, 2)
+        wo = weight_view(p["wo"], compute_dtype)
+        if "out" in mm:
+            y = mm["out"](out, wo)
+        else:
+            y = jnp.einsum("bsf,fh->bsh", out, wo,
+                           preferred_element_type=jnp.float32)
+        if "bo" in p:
+            y = y + p["bo"]
+        return y.astype(compute_dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -891,51 +918,54 @@ def apply_mlp(p: Params, x: jax.Array, cfg: ModelArgs,
     apart by indexing."""
     act = _ACTS[cfg.hidden_act]
     mm = matmul_fns or {}
-    win = p["win"].astype(compute_dtype)
     gated = _is_gated(cfg.hidden_act)
-    if gated and "fc1_pair" in mm:
-        # overlapped gated fc1: one ring over both weight halves keeps the
-        # gate/up PRODUCT shard-aligned — splitting the fused [B, S, 2F]
-        # output globally resharded activations per token; the pair form
-        # pays only a weight-half reshard instead
-        # (ops/overlap.make_ag_matmul_pair)
-        F = p["wout"].shape[0]
-        gate, up = mm["fc1_pair"](x.astype(compute_dtype),
-                                  win[:, :F], win[:, F:])
-        if "bin" in p:
-            gate = gate + p["bin"][:F]
-            up = up + p["bin"][F:]
-        hproj = act(gate.astype(compute_dtype)) * up.astype(compute_dtype)
-    else:
-        pairs = gated and shard_fn is not None
-        if "fc1" in mm:
-            hproj = mm["fc1"](x.astype(compute_dtype), win)
+    win = weight_view(p["win"], compute_dtype)
+    with jax.named_scope("mlp"):
+        if gated and "fc1_pair" in mm:
+            # overlapped gated fc1: one ring over both weight halves keeps
+            # the gate/up PRODUCT shard-aligned — splitting the fused
+            # [B, S, 2F] output globally resharded activations per token;
+            # the pair form pays only a weight-half reshard instead
+            # (ops/overlap.make_ag_matmul_pair)
+            F = p["wout"].shape[0]
+            gate, up = mm["fc1_pair"](x.astype(compute_dtype),
+                                      win[:, :F], win[:, F:])
+            if "bin" in p:
+                gate = gate + p["bin"][:F]
+                up = up + p["bin"][F:]
+            hproj = (act(gate.astype(compute_dtype))
+                     * up.astype(compute_dtype))
         else:
-            hproj = jnp.einsum("bsh,hgf->bsgf" if pairs else "bsh,hf->bsf",
-                               x.astype(compute_dtype), win,
-                               preferred_element_type=jnp.float32)
-        if "bin" in p:
-            hproj = hproj + p["bin"]
-        hproj = hproj.astype(compute_dtype)
-        if pairs:
-            hproj = shard_fn(hproj, 3)
-            hproj = act(hproj[:, :, 0]) * hproj[:, :, 1]
-        elif gated:
-            gate, up = jnp.split(hproj, 2, axis=-1)
-            hproj = act(gate) * up
+            pairs = gated and shard_fn is not None
+            if "fc1" in mm:
+                hproj = mm["fc1"](x.astype(compute_dtype), win)
+            else:
+                hproj = jnp.einsum(
+                    "bsh,hgf->bsgf" if pairs else "bsh,hf->bsf",
+                    x.astype(compute_dtype), win,
+                    preferred_element_type=jnp.float32)
+            if "bin" in p:
+                hproj = hproj + p["bin"]
+            hproj = hproj.astype(compute_dtype)
+            if pairs:
+                hproj = shard_fn(hproj, 3)
+                hproj = act(hproj[:, :, 0]) * hproj[:, :, 1]
+            elif gated:
+                gate, up = jnp.split(hproj, 2, axis=-1)
+                hproj = act(gate) * up
+            else:
+                hproj = act(hproj)
+            if shard_fn is not None:
+                hproj = shard_fn(hproj, 2)
+        wout = weight_view(p["wout"], compute_dtype)
+        if "fc2" in mm:
+            y = mm["fc2"](hproj, wout)
         else:
-            hproj = act(hproj)
-        if shard_fn is not None:
-            hproj = shard_fn(hproj, 2)
-    wout = p["wout"].astype(compute_dtype)
-    if "fc2" in mm:
-        y = mm["fc2"](hproj, wout)
-    else:
-        y = jnp.einsum("bsf,fh->bsh", hproj, wout,
-                       preferred_element_type=jnp.float32)
-    if "bout" in p:
-        y = y + p["bout"]
-    return y.astype(compute_dtype)
+            y = jnp.einsum("bsf,fh->bsh", hproj, wout,
+                           preferred_element_type=jnp.float32)
+        if "bout" in p:
+            y = y + p["bout"]
+        return y.astype(compute_dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -1019,7 +1049,7 @@ def apply_decoder_layer(
                 "families (bert) attend in every block")
         # HF BertLayer: residual-then-norm (attention.output.LayerNorm,
         # output.LayerNorm)
-        x = apply_norm(
+        x = block_norm(
             p["ln1"],
             x + drop_h(apply_attention(p["attn"], x, cfg, rope=rope,
                                        sdpa_fn=sdpa_fn,
@@ -1030,14 +1060,14 @@ def apply_decoder_layer(
                                        shard_fn=shard_fn),
                        r_res1),
             cfg)
-        return apply_norm(
+        return block_norm(
             p["ln2"],
             x + drop_h(apply_mlp(p["mlp"], x, cfg,
                                  compute_dtype=compute_dtype,
                                  matmul_fns=matmul_fns,
                                  shard_fn=shard_fn), r_res2),
             cfg)
-    h = apply_norm(p["ln1"], x, cfg)
+    h = block_norm(p["ln1"], x, cfg)
     x = x + residual_branch(
         drop_h(apply_mixer(p, h, cfg, mixer, rope=rope, sdpa_fn=sdpa_fn,
                            compute_dtype=compute_dtype, causal=causal,
@@ -1045,7 +1075,7 @@ def apply_decoder_layer(
                            matmul_fns=matmul_fns, shard_fn=shard_fn,
                            ssd_fn=ssd_fn),
                r_res1), cfg)
-    h = apply_norm(p["ln2"], x, cfg)
+    h = block_norm(p["ln2"], x, cfg)
     x = x + residual_branch(
         drop_h(apply_mlp(p["mlp"], h, cfg, compute_dtype=compute_dtype,
                          matmul_fns=matmul_fns, shard_fn=shard_fn),
@@ -1079,24 +1109,26 @@ def apply_embedding(p: Params, tokens: jax.Array, cfg: ModelArgs,
                     compute_dtype=jnp.bfloat16,
                     dropout_rng: Optional[jax.Array] = None,
                     position_ids: Optional[jax.Array] = None) -> jax.Array:
-    x = jnp.take(p["wte"], tokens, axis=0)
-    if "wpe" in p:
-        if position_ids is not None:  # packed samples: per-token positions
-            x = x + jnp.take(p["wpe"], position_ids, axis=0)
-        else:
-            S = tokens.shape[1]
-            x = x + p["wpe"][:S][None, :, :]
-    if "ln" in p:
-        x = apply_norm(p["ln"], x, cfg)
-    if cfg.scale_embeddings:
-        # gemma: hidden states enter the stack scaled by sqrt(hidden)
-        x = x * jnp.sqrt(jnp.float32(cfg.hidden_size)).astype(x.dtype)
-    if cfg.embedding_multiplier != 1.0:
-        # granite: the rows enter the stack times a stated constant
-        x = x * jnp.asarray(cfg.embedding_multiplier, x.dtype)
-    # HF GPT2Model.drop / BertEmbeddings.dropout: after sum (+LN for bert)
-    x = dropout(x, cfg.hidden_dropout, dropout_rng)
-    return x.astype(compute_dtype)
+    with jax.named_scope("embed"):
+        x = jnp.take(p["wte"], tokens, axis=0)
+        if "wpe" in p:
+            if position_ids is not None:  # packed samples: per-token positions
+                x = x + jnp.take(p["wpe"], position_ids, axis=0)
+            else:
+                S = tokens.shape[1]
+                x = x + p["wpe"][:S][None, :, :]
+        if "ln" in p:
+            x = apply_norm(p["ln"], x, cfg)
+        if cfg.scale_embeddings:
+            # gemma: hidden states enter the stack scaled by sqrt(hidden)
+            x = x * jnp.sqrt(jnp.float32(cfg.hidden_size)).astype(x.dtype)
+        if cfg.embedding_multiplier != 1.0:
+            # granite: the rows enter the stack times a stated constant
+            x = x * jnp.asarray(cfg.embedding_multiplier, x.dtype)
+        # HF GPT2Model.drop / BertEmbeddings.dropout: after sum (+LN for
+        # bert)
+        x = dropout(x, cfg.hidden_dropout, dropout_rng)
+        return x.astype(compute_dtype)
 
 
 def init_lm_head(key: jax.Array, cfg: ModelArgs) -> Tuple[Params, Axes]:
@@ -1140,22 +1172,24 @@ def apply_lm_head(
     A params tree that carries ``whead`` uses it even when the config says
     tied — the pipeline engine's last stage holds the transposed tied copy
     instead of a wte reference (runtime/pipeline.py split_params)."""
-    if "wt" in p:
-        x = jnp.einsum("bsh,hk->bsk", x.astype(compute_dtype),
-                       p["wt"].astype(compute_dtype),
-                       preferred_element_type=jnp.float32) + p["bt"]
-        x = apply_norm(p["ln"], _ACTS[cfg.hidden_act](x), cfg)
-        x = x.astype(compute_dtype)
-    w = p["whead"] if "whead" in p else wte.T
-    logits = jnp.einsum("bsh,hv->bsv", x.astype(compute_dtype),
-                        w.astype(compute_dtype),
-                        preferred_element_type=jnp.float32)
-    if "bias" in p:
-        logits = logits + p["bias"]
-    if cfg.logits_scaling != 1.0:
-        # granite: the logits divided by a stated constant before the loss
-        logits = logits / cfg.logits_scaling
-    return logits
+    with jax.named_scope("head"):
+        if "wt" in p:
+            x = jnp.einsum("bsh,hk->bsk", x.astype(compute_dtype),
+                           p["wt"].astype(compute_dtype),
+                           preferred_element_type=jnp.float32) + p["bt"]
+            x = apply_norm(p["ln"], _ACTS[cfg.hidden_act](x), cfg)
+            x = x.astype(compute_dtype)
+        w = p["whead"] if "whead" in p else wte.T
+        logits = jnp.einsum("bsh,hv->bsv", x.astype(compute_dtype),
+                            weight_view(w, compute_dtype),
+                            preferred_element_type=jnp.float32)
+        if "bias" in p:
+            logits = logits + p["bias"]
+        if cfg.logits_scaling != 1.0:
+            # granite: the logits divided by a stated constant before the
+            # loss
+            logits = logits / cfg.logits_scaling
+        return logits
 
 
 def cross_entropy_loss(
@@ -1178,21 +1212,26 @@ def cross_entropy_loss(
     ``make_vocab_parallel_ce``, matched to the head's sharding). Untileable
     shapes silently use the XLA path (both forms return None for them).
     """
-    nll = None
-    if callable(fused):
-        nll = fused(logits, labels, z_loss=z_loss)
-    elif fused:
-        from hetu_galvatron_tpu.ops.pallas.cross_entropy import fused_ce_nll
+    with jax.named_scope("head"):
+        nll = None
+        if callable(fused):
+            nll = fused(logits, labels, z_loss=z_loss)
+        elif fused:
+            from hetu_galvatron_tpu.ops.pallas.cross_entropy import (
+                fused_ce_nll,
+            )
 
-        nll = fused_ce_nll(logits, labels, z_loss=z_loss)
-    if nll is None:
-        logits = logits.astype(jnp.float32)
-        lse = jax.scipy.special.logsumexp(logits, axis=-1)
-        gold = jnp.take_along_axis(logits, labels[..., None], axis=-1)[..., 0]
-        nll = lse - gold
-        if z_loss:
-            nll = nll + z_loss * jnp.square(lse)
-    if loss_mask is None:
-        return jnp.mean(nll)
-    loss_mask = loss_mask.astype(jnp.float32)
-    return jnp.sum(nll * loss_mask) / jnp.maximum(jnp.sum(loss_mask), 1.0)
+            nll = fused_ce_nll(logits, labels, z_loss=z_loss)
+        if nll is None:
+            logits = logits.astype(jnp.float32)
+            lse = jax.scipy.special.logsumexp(logits, axis=-1)
+            gold = jnp.take_along_axis(logits, labels[..., None],
+                                       axis=-1)[..., 0]
+            nll = lse - gold
+            if z_loss:
+                nll = nll + z_loss * jnp.square(lse)
+        if loss_mask is None:
+            return jnp.mean(nll)
+        loss_mask = loss_mask.astype(jnp.float32)
+        return (jnp.sum(nll * loss_mask)
+                / jnp.maximum(jnp.sum(loss_mask), 1.0))

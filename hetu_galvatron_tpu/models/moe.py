@@ -293,10 +293,12 @@ def _capacity_dispatch(
     xe = jnp.einsum("tec,th->ech", dispatch.astype(compute_dtype),
                     xt.astype(compute_dtype),
                     preferred_element_type=jnp.float32).astype(compute_dtype)
-    hproj = jnp.einsum("ech,ehf->ecf", xe, p["win"].astype(compute_dtype),
+    hproj = jnp.einsum("ech,ehf->ecf", xe,
+                       M.weight_view(p["win"], compute_dtype),
                        preferred_element_type=jnp.float32)
     hproj = _expert_act(hproj, cfg, compute_dtype)
-    ye = jnp.einsum("ecf,efh->ech", hproj, p["wout"].astype(compute_dtype),
+    ye = jnp.einsum("ecf,efh->ech", hproj,
+                    M.weight_view(p["wout"], compute_dtype),
                     preferred_element_type=jnp.float32)
     return jnp.einsum("tec,ech->th", combine.astype(compute_dtype),
                       ye.astype(compute_dtype),
@@ -324,11 +326,13 @@ def _dropless_dispatch(
         xs = xt[tok_sorted].astype(compute_dtype)  # [T*K, H]
         group_sizes = jnp.bincount(eid, length=E).astype(jnp.int32)
     with jax.named_scope("moe/experts"):
-        hproj = jax.lax.ragged_dot(xs, p["win"].astype(compute_dtype),
+        hproj = jax.lax.ragged_dot(xs,
+                                   M.weight_view(p["win"], compute_dtype),
                                    group_sizes,
                                    preferred_element_type=jnp.float32)
         hproj = _expert_act(hproj, cfg, compute_dtype)
-        ys = jax.lax.ragged_dot(hproj, p["wout"].astype(compute_dtype),
+        ys = jax.lax.ragged_dot(hproj,
+                                M.weight_view(p["wout"], compute_dtype),
                                 group_sizes,
                                 preferred_element_type=jnp.float32)
     with jax.named_scope("moe/combine"):
@@ -364,11 +368,11 @@ def _sorted_rows_mlp(rows: int, cfg: ModelArgs, compute_dtype, xt, win, wout,
     with jax.named_scope("moe/dispatch"):
         xs = jnp.where(mine, xt[tok].astype(compute_dtype), 0)
     with jax.named_scope("moe/experts"):
-        hproj = jax.lax.ragged_dot(xs, win.astype(compute_dtype),
+        hproj = jax.lax.ragged_dot(xs, M.weight_view(win, compute_dtype),
                                    group_sizes,
                                    preferred_element_type=jnp.float32)
         hproj = _expert_act(hproj, cfg, compute_dtype)
-        ys = jax.lax.ragged_dot(hproj, wout.astype(compute_dtype),
+        ys = jax.lax.ragged_dot(hproj, M.weight_view(wout, compute_dtype),
                                 group_sizes,
                                 preferred_element_type=jnp.float32)
     with jax.named_scope("moe/combine"):
@@ -530,13 +534,13 @@ def apply_moe_decoder_layer(
     r_attn = r_res1 = r_res2 = None
     if dropout_rng is not None:
         r_attn, r_res1, r_res2 = jax.random.split(dropout_rng, 3)
-    h = M.apply_norm(p["ln1"], x, cfg)
+    h = M.block_norm(p["ln1"], x, cfg)
     x = x + M.residual_branch(M.dropout(
         M.apply_mixer(p, h, cfg, mixer, rope=rope, sdpa_fn=sdpa_fn,
                       compute_dtype=compute_dtype, dropout_rng=r_attn,
                       segment_ids=segment_ids, ssd_fn=ssd_fn),
         cfg.hidden_dropout, r_res1), cfg)
-    h = M.apply_norm(p["ln2"], x, cfg)
+    h = M.block_norm(p["ln2"], x, cfg)
     y, aux, stats = apply_moe_mlp(p["moe"], h, cfg,
                                   compute_dtype=compute_dtype)
     return (x + M.residual_branch(M.dropout(y, cfg.hidden_dropout, r_res2),

@@ -43,6 +43,7 @@ carries the schedule cost instead.
 
 from __future__ import annotations
 
+import gc
 import glob
 import gzip
 import json
@@ -455,13 +456,306 @@ def jit_cost_summary(fn: Any, args: Sequence[Any] = (),
 _MOSAIC_CALL = 'custom_call_target="tpu_custom_call"'
 COLLECTIVE_OPS = ("all-to-all", "all-gather", "all-reduce", "reduce-scatter",
                   "collective-permute")
-_COMPUTATION = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) \(.*?^\}", re.M | re.S)
+
+# ---------------------------------------------------------------------------
+# the compiled step's HLO, read once
+# ---------------------------------------------------------------------------
+
+# The ``jax.named_scope`` names the step program carries (models/,
+# runtime/trainer.py), a closed vocabulary: a TPU trace names its events by
+# HLO instruction (``fusion.12``), and the scope an instruction came from
+# is only in the optimized HLO's ``op_name``. A name is here because a
+# per-layer metric or a line of ``tools/trace_by_scope.py``'s table reads it
+# (PERF.md section 3), and tests/observability/test_step_map.py finds every
+# literal of those files in it.
+SCOPES: Tuple[str, ...] = (
+    "embed", "norm", "attn/qkv_proj", "attn/qk_norm", "attn/rope",
+    "attn/core", "attn/out_proj", "mlp", "head", "param_view",
+    "grad/accumulate", "grad/clip", "optimizer/update",
+    "moe/route", "moe/dispatch", "moe/experts", "moe/combine",
+    "mixer/short_conv/in_proj", "mixer/short_conv/gate_conv",
+    "mixer/short_conv/out_proj",
+    "mixer/mamba/in_proj", "mixer/mamba/conv", "mixer/mamba/ssd",
+    "mixer/mamba/gated_norm", "mixer/mamba/out_proj")
+PHASES = ("forward", "recompute", "backward", "update", "other")
+# the scopes ``step_scopes()["scopes"]`` lists by instruction, by mixer kind
+# (what the ``granite_*`` readers join a trace to, by PR 35's rule: an
+# instruction by its own ``op_name``)
+MIXER_SCOPES = {"mamba": tuple(s for s in SCOPES
+                               if s.startswith("mixer/mamba/"))}
+# the scope of the state-space scan, whose Mosaic calls the step report
+# counts (``ssd/mosaic_calls``; ops/pallas/ssd.py traces under it)
+SSD_SCOPE = "mixer/mamba/ssd"
+
+_HEADER = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) \(")
+_INSTRUCTION = re.compile(r"^\s+(?:ROOT )?%?([\w.\-]+) = .*? ([\w\-]+)\(")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_CALLS = re.compile(r"calls=%?([\w.\-]+)")
+_APPLIES = re.compile(r"to_apply=%?([\w.\-]+)")
+# ``jvp(mixer/mamba)/ssd`` and ``transpose(jvp(mixer/mamba))/ssd`` are both
+# under ``mixer/mamba/ssd``: a transformation wraps the part of the name
+# stack it was applied under
+_TRANSFORM = re.compile(r"\w+\(|\)")
+# ``jit(silu)`` is a function's name and no scope
+_JIT = re.compile(r"jit\([^()]*\)")
+_OPERAND = re.compile(r"%([\w.\-]+)")
+# an ``op_name`` that is no name stack: libtpu gives the Mosaic calls it
+# lowers ``lax.ragged_dot`` to these, and the program calls ``ragged_dot``
+# for the experts' grouped matmuls alone (models/moe.py, under moe/experts)
+KERNEL_SCOPES = {"ragged-dot-none": "moe/experts",
+                 "ragged-dot-metadata": "moe/experts"}
+# a pass of a step needs the ones before it
+_PASS_ORDER = ("forward", "recompute", "backward")
+
+
+def walk_hlo(hlo_text: str):
+    """The one walk over an optimized HLO text that every reader of it
+    shares (``step_hlo`` below, ``tools/aot_hlo_report.py``): yields
+    ``(computation, name, opcode, op_name, calls, line, at)`` for each
+    instruction in the text's order; ``op_name`` is "" and ``calls`` (the
+    computation a fusion runs) None where the line has none;
+    ``line[at:]`` begins with the first operand."""
+    comp = ""
+    for line in hlo_text.splitlines():
+        if not line:
+            continue
+        if not line[0].isspace():
+            m = _HEADER.match(line)
+            if m:
+                comp = m.group(1)
+            continue
+        m = _INSTRUCTION.match(line)
+        if not m:
+            continue
+        at = line.find('op_name="')
+        op_name = _OP_NAME.match(line, at).group(1) if at >= 0 else ""
+        at = line.find("calls=")
+        yield (comp, m.group(1), m.group(2), op_name,
+               _CALLS.match(line, at).group(1) if at >= 0 else None, line,
+               m.end())
+
+
+def scope_and_phase(op_name: str) -> Tuple[Optional[str], str]:
+    """The scope and the phase an ``op_name`` says.
+
+    ``scope``: the name of ``SCOPES`` that ends deepest in the name stack,
+    once the transformation wrappers (``jvp(``, ``transpose(``, ``)``) and
+    the jitted functions' names (``jit(silu)``) are taken out; None where
+    there is none.
+
+    ``phase``, the first of these that holds:
+
+    * ``recompute``: ``rematted_computation`` is in it
+      (``jit(step)/while/body/closed_call/transpose(jvp(jvp()))/checkpoint/
+      rematted_computation/bsh,hf->bsf/dot_general``: the forward made
+      again inside the backward pass);
+    * ``backward``: ``transpose(`` is
+      (``.../closed_call/transpose(jvp(jvp()))/checkpoint/
+      jit(flash_attention_bwd_hmajor)/flash_attention_bwd_dq/pallas_call``);
+    * ``forward``: ``jvp(`` is (``.../closed_call/jvp(jit(silu))/div``);
+    * ``update``: its scope lies under ``optimizer/``
+      (``jit(step)/optimizer/update/mul``);
+    * ``other``: the rest, what a step does once outside the three passes
+      (``jit(step)/grad/clip/reduce_sum``, ``jit(step)/while/body/squeeze``).
+
+    An ``op_name`` that is no name stack (none at all: a copy or a convert
+    XLA made; or a kernel's own, ``KERNEL_SCOPES``) says ``other`` here, and
+    :func:`step_hlo` asks the instruction's operands."""
+    path = "/" + _TRANSFORM.sub("", _JIT.sub("", op_name)) + "/"
+    scope, end = KERNEL_SCOPES.get(op_name), -1
+    for s in SCOPES:
+        at = path.rfind("/" + s + "/")
+        if at >= 0 and at + len(s) > end:
+            scope, end = s, at + len(s)
+    if "rematted_computation" in op_name:
+        phase = "recompute"
+    elif "transpose(" in op_name:
+        phase = "backward"
+    elif "jvp(" in op_name:
+        phase = "forward"
+    elif scope is not None and scope.startswith("optimizer/"):
+        phase = "update"
+    else:
+        phase = "other"
+    return scope, phase
+
+
+def _op_tail(op_name: str, parts: int = 3) -> str:
+    return "/".join(op_name.split("/")[-parts:])
+
+
+def step_hlo(hlo_text: str, own_scopes: Sequence[str] = MIXER_SCOPES["mamba"]
+             ) -> Dict[str, Any]:
+    """Everything the program keeps of its compiled step's optimized HLO,
+    from one walk over the text.
+
+    ``mosaic_custom_calls`` and ``collectives``: see :func:`hlo_counts`.
+    ``scopes``, ``instructions``, ``mosaic_calls``: see
+    :func:`scope_instructions` (``own_scopes`` are its ``scopes``).
+
+    ``map``: each instruction of a computation that is no fusion's and no
+    reduction's (an event of a TPU trace is one of these, named by it),
+    ``{"instructions": {name: (scope, phase, collective)}, "inferred":
+    [names], "tails": {name: op_name tail}}``:
+
+    * ``scope`` and ``phase`` by :func:`scope_and_phase` from the
+      instruction's OWN ``op_name`` (a fusion carries its root's; every
+      fusion that holds a matmul carries that matmul's). An instruction with
+      no ``op_name`` that calls a fused computation takes the commonest
+      scope and the commonest phase of the instructions inside it, and is
+      listed in ``inferred``; any other has no scope. Where the ``op_name``
+      is no name stack (no ``/`` in it: none, or ``ragged-dot-none``) and
+      nothing inside says a phase, the phase is the latest pass among the
+      instruction's operands (forward, then recompute, then backward: a
+      copy of what the backward pass made is the backward pass's), else
+      ``other``. (Found on the chip, PR 37: the experts' grouped matmuls,
+      35 ms a step of ``lfm2moe_c1_s8k``, carry ``op_name=
+      "ragged-dot-none"``.)
+    * ``collective``: None, or what the instruction moves between chips:
+      the opcode for a collective under its own name (``all-gather``; an
+      asynchronous one's halves ``all-gather.start`` / ``all-gather.done``);
+      ``reduce-scatter.fused`` for a fusion that calls an
+      ``all-reduce-scatter*`` computation (how XLA:TPU runs one);
+      ``overlapped`` for a fusion that calls an ``async_collective_fusion*``
+      (an all-gather riding a matmul: compute, with traffic behind it);
+      ``<opcode>.start`` / ``<opcode>.done`` for the fusions around XLA:TPU's
+      ``AsyncCollectiveStart`` / ``AsyncCollectiveDone`` custom calls
+      (``async-collective-start.N`` / ``-done.N``), the opcode being the
+      collective the fused computation holds beside the custom call.
+    * ``tails``: for an instruction under no scope, the last parts of its
+      ``op_name`` (its opcode where it has none): what
+      ``tools/trace_by_scope.py`` prints beside the heaviest of them."""
+    # The cyclic collector is held off: the walk makes a few hundred
+    # thousand small tuples and lists and no cycle, and the collector's
+    # passes over the trainer's whole heap, which those allocations set off,
+    # cost more than the walk itself (0.25 s against 0.14 s for an 8 MB text
+    # in a process that has imported the trainer).
+    collecting = gc.isenabled()
+    gc.disable()
+    try:
+        return _step_hlo(hlo_text, own_scopes)
+    finally:
+        if collecting:
+            gc.enable()
+
+
+def _step_hlo(hlo_text: str, own_scopes: Sequence[str]) -> Dict[str, Any]:
+    """:func:`step_hlo`'s walk."""
+    classify: Dict[str, Tuple[Optional[str], str]] = {"": (None, "other")}
+    own: Dict[str, Tuple[str, ...]] = {"": ()}
+    found: Dict[str, List[str]] = {s: [] for s in own_scopes}
+    names, mosaic = set(), set()
+    # per computation: its instructions, the collectives it holds by opcode,
+    # what is inside it by (scope, phase), and the async half it is
+    comps: Dict[str, Dict[str, Any]] = {}
+    fused, applied = set(), set()
+    for comp, name, opcode, op_name, calls, line, at in walk_hlo(hlo_text):
+        c = comps.get(comp)
+        if c is None:
+            c = comps[comp] = {"rows": [], "held": {}, "inside": {},
+                               "half": None}
+        if op_name not in classify:
+            classify[op_name] = scope_and_phase(op_name)
+            path = _TRANSFORM.sub("", op_name) + "/"
+            own[op_name] = tuple(s for s in own_scopes if s + "/" in path)
+        is_mosaic = False
+        if opcode == "custom-call":
+            is_mosaic = _MOSAIC_CALL in line
+            if 'custom_call_target="AsyncCollectiveStart"' in line:
+                c["half"] = "start"
+            elif 'custom_call_target="AsyncCollectiveDone"' in line:
+                c["half"] = "done"
+        elif opcode == "fusion":
+            if calls:
+                fused.add(calls)
+        elif opcode != "call" and "to_apply=" in line:
+            applied.add(_APPLIES.search(line).group(1))
+        base = opcode[:-6] if opcode.endswith("-start") else opcode
+        if base in COLLECTIVE_OPS:
+            c["held"][base] = c["held"].get(base, 0) + 1
+        if op_name:
+            key = classify[op_name]
+            c["inside"][key] = c["inside"].get(key, 0) + 1
+        # (operands are asked only where the op_name is no name stack)
+        c["rows"].append((name, opcode, op_name, calls,
+                          () if "/" in op_name else _OPERAND.findall(
+                              line, at, line.find(")", at))))
+        if is_mosaic:
+            mosaic.add(name)
+        if "fused_computation" not in comp:
+            names.add(name)
+            for s in own[op_name]:
+                found[s].append(name)
+
+    counts = dict.fromkeys(COLLECTIVE_OPS, 0)
+    for comp, c in comps.items():
+        if comp.startswith("async_collective_fusion") or c["half"] == "done":
+            continue    # these two repeat what the starting fusion holds
+        if comp.startswith("all-reduce-scatter"):
+            counts["reduce-scatter"] += 1
+            continue
+        for op, n in c["held"].items():
+            counts[op] += n
+
+    def commonest(inside, part):
+        tally: Dict[Any, int] = {}
+        for key, n in inside.items():
+            if key[part] is not None:
+                tally[key[part]] = tally.get(key[part], 0) + n
+        return max(tally, key=tally.get) if tally else None
+
+    def collective_of(opcode, calls):
+        for suffix in ("", "-start", "-done"):
+            base = opcode[:-len(suffix)] if suffix else opcode
+            if opcode.endswith(suffix) and base in COLLECTIVE_OPS:
+                return base + suffix.replace("-", ".")
+        if opcode != "fusion" or calls is None:
+            return None
+        if calls.startswith("all-reduce-scatter"):
+            return "reduce-scatter.fused"
+        if calls.startswith("async_collective_fusion"):
+            return "overlapped"
+        callee = comps.get(calls)
+        if callee and callee["half"]:   # (each half holds the collective)
+            return (next(iter(callee["held"]), "collective") + "."
+                    + callee["half"])
+        return None
+
+    instructions: Dict[str, Tuple[Optional[str], str, Optional[str]]] = {}
+    inferred: List[str] = []
+    tails: Dict[str, str] = {}
+    for comp, c in comps.items():
+        if comp in fused or comp in applied:
+            continue
+        for name, opcode, op_name, calls, operands in c["rows"]:
+            scope, phase = classify[op_name]
+            if not op_name and calls in comps:
+                inside = comps[calls]["inside"]
+                scope = commonest(inside, 0)
+                phase = commonest(inside, 1) or "other"
+                if scope is not None:
+                    inferred.append(name)
+            if phase == "other" and "/" not in op_name:
+                # (an operand is earlier in the same computation)
+                passes = [instructions[o][1] for o in operands
+                          if o in instructions
+                          and instructions[o][1] in _PASS_ORDER]
+                if passes:
+                    phase = max(passes, key=_PASS_ORDER.index)
+            instructions[name] = (scope, phase, collective_of(opcode, calls))
+            if scope is None:
+                tails[name] = _op_tail(op_name) if op_name else opcode
+    return {"mosaic_custom_calls": len(mosaic), "collectives": counts,
+            "scopes": found, "instructions": frozenset(names),
+            "mosaic_calls": frozenset(n for n in mosaic if n in names),
+            "map": {"instructions": instructions, "inferred": inferred,
+                    "tails": tails}}
 
 
 def hlo_counts(hlo_text: str) -> Dict[str, Any]:
-    """What an optimized HLO text holds, counted in one place:
-    ``mosaic_custom_calls``, the Mosaic (Pallas TPU) kernels, and
-    ``collectives``, the collectives by opcode (the gauges
+    """What an optimized HLO text holds, counted in one place (a view of
+    :func:`step_hlo`'s walk): ``mosaic_custom_calls``, the Mosaic (Pallas
+    TPU) kernels, and ``collectives``, the collectives by opcode (the gauges
     ``step/collectives{op=...}``): what GSPMD inserted, a loop body's
     counted once however often it runs. Each is counted as what it is: an
     async one once (a ``-start`` / ``-done`` pair, or XLA:TPU's three
@@ -469,75 +763,29 @@ def hlo_counts(hlo_text: str) -> Dict[str, Any]:
     a reduce-scatter, as that and not as the all-reduce it holds.
     ``benchmark/aot_check.py`` counts opcodes in the whole text, so its
     all-gather, all-reduce and collective-permute read higher on a TPU."""
-    counts = dict.fromkeys(COLLECTIVE_OPS, 0)
-    for m in _COMPUTATION.finditer(hlo_text):
-        name, text = m.group(1), m.group(0)
-        if (name.startswith("async_collective_fusion")
-                or 'custom_call_target="AsyncCollectiveDone"' in text):
-            continue    # these two repeat what the starting fusion holds
-        if name.startswith("all-reduce-scatter"):
-            counts["reduce-scatter"] += 1
-            continue
-        for op in COLLECTIVE_OPS:
-            counts[op] += text.count(f" {op}(") + text.count(f" {op}-start(")
-    return {"mosaic_custom_calls": hlo_text.count(_MOSAIC_CALL),
-            "collectives": counts}
-
-
-# named scopes whose instructions the step report keeps, by mixer kind: a
-# TPU trace names its events by HLO instruction (``fusion.12``), and the
-# scope an instruction came from is only in the HLO's ``op_name`` metadata
-MIXER_SCOPES = {"mamba": tuple(
-    f"mixer/mamba/{part}"
-    for part in ("in_proj", "conv", "ssd", "gated_norm", "out_proj"))}
-# the scope of the state-space scan, whose Mosaic calls the step report
-# counts (``ssd/mosaic_calls``; ops/pallas/ssd.py traces under it)
-SSD_SCOPE = "mixer/mamba/ssd"
-_INSTRUCTION = re.compile(r"^\s+(?:ROOT )?%?([\w.\-]+) = ")
-_OP_NAME = re.compile(r'op_name="([^"]*)"')
-# ``jvp(mixer/mamba)/ssd`` and ``transpose(jvp(mixer/mamba))/ssd`` are both
-# under ``mixer/mamba/ssd``: a transformation wraps the part of the name
-# stack it was applied under
-_TRANSFORM = re.compile(r"\w+\(|\)")
+    read = step_hlo(hlo_text, ())
+    return {k: read[k] for k in ("mosaic_custom_calls", "collectives")}
 
 
 def scope_instructions(hlo_text: str, scopes: Sequence[str]
                        ) -> Dict[str, Any]:
     """Which instructions of an optimized HLO text lie under each of
-    ``scopes`` (``jax.named_scope`` paths): ``{"scopes": {scope: [names]},
-    "instructions": every name, "mosaic_calls": the names that are Mosaic
-    (Pallas TPU) kernels}``. An instruction counts by its own
-    ``op_name``, so a fusion by its root's; the instructions INSIDE a fused
-    computation are no events of a trace and are left out. With these a
-    reader lays device time over scopes: a trace event's name is an
-    instruction's."""
-    found: Dict[str, List[str]] = {s: [] for s in scopes}
-    names, mosaic = set(), set()
-    for m in _COMPUTATION.finditer(hlo_text):
-        if "fused_computation" in m.group(1):
-            continue
-        for line in m.group(0).splitlines():
-            inst = _INSTRUCTION.match(line)
-            if not inst:
-                continue
-            names.add(inst.group(1))
-            if _MOSAIC_CALL in line:
-                mosaic.add(inst.group(1))
-            op = _OP_NAME.search(line)
-            if not op:
-                continue
-            path = _TRANSFORM.sub("", op.group(1)) + "/"
-            for s in scopes:
-                if s + "/" in path:
-                    found[s].append(inst.group(1))
-    return {"scopes": found, "instructions": frozenset(names),
-            "mosaic_calls": frozenset(mosaic)}
+    ``scopes`` (``jax.named_scope`` paths), a view of :func:`step_hlo`'s
+    walk: ``{"scopes": {scope: [names]}, "instructions": every name,
+    "mosaic_calls": the names that are Mosaic (Pallas TPU) kernels}``. An
+    instruction counts by its own ``op_name``, so a fusion by its root's;
+    the instructions INSIDE a fused computation are no events of a trace
+    and are left out. With these a reader lays device time over scopes: a
+    trace event's name is an instruction's."""
+    read = step_hlo(hlo_text, scopes)
+    return {k: read[k] for k in ("scopes", "instructions", "mosaic_calls")}
 
 
-# what ``scope_instructions`` found in the step program this process last
-# reported (cli/train_dist.py), for a reader in the same process (the
-# benchmark's per-layer readers run there); empty until a step reports
+# what ``step_hlo`` found in the step program this process last reported
+# (cli/train_dist.py), for a reader in the same process (the benchmark's
+# per-layer readers run there); empty until a step reports
 _STEP_SCOPES: Dict[str, Any] = {}
+STEP_MAP_FILE = "step_map.json"
 
 
 def record_step_scopes(found: Dict[str, Any]) -> None:
@@ -547,6 +795,26 @@ def record_step_scopes(found: Dict[str, Any]) -> None:
 
 def step_scopes() -> Dict[str, Any]:
     return dict(_STEP_SCOPES)
+
+
+def write_step_map(trace_dir: str) -> Optional[str]:
+    """Write the recorded step's ``map`` beside a trace
+    (``<trace_dir>/step_map.json``), so that the trace is joined to scopes,
+    phases and collective classes later without the process that made it
+    (``tools/trace_by_scope.py``). Nothing is written, and None returned,
+    where no step has reported or the directory cannot be written: a
+    profiler window closes on crash paths too."""
+    kept = _STEP_SCOPES.get("map")
+    if not kept or not trace_dir:
+        return None
+    path = os.path.join(trace_dir, STEP_MAP_FILE)
+    try:
+        os.makedirs(trace_dir, exist_ok=True)
+        with open(path, "w") as f:
+            json.dump({"vocabulary": SCOPES, "phases": PHASES, **kept}, f)
+    except OSError:
+        return None
+    return path
 
 
 def mosaic_custom_calls(fn: Any, args: Sequence[Any]) -> int:

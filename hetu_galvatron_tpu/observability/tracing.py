@@ -38,7 +38,10 @@ The spans of the launcher (``cli/train_dist.py``), all flat siblings:
   and optimizer init), ``setup/resume``, ``setup/step_report`` (the
   compiled step's HLO text and ``memory_analysis()`` after the first
   call, which also sets the gauges ``step/static_bytes{part=arguments|outputs|
-  aliased|temporaries|generated_code|live_peak}``).
+  aliased|temporaries|generated_code|live_peak}``, and keeps every
+  instruction's scope, phase and collective class for a reader of a trace:
+  ``trace_analysis.step_hlo``; :class:`TraceCapture` writes them beside the
+  trace as ``step_map.json`` when its window closes).
 """
 
 from __future__ import annotations
@@ -152,3 +155,10 @@ class TraceCapture:
 
             jax.profiler.stop_trace()
             self.active = False
+            # the compiled step's instructions by scope, phase and
+            # collective class, beside the trace whose events they name
+            from hetu_galvatron_tpu.observability.trace_analysis import (
+                write_step_map,
+            )
+
+            write_step_map(self.trace_dir)

@@ -192,7 +192,8 @@ def make_train_step(
         rng = batch.pop("dropout_rng", None)
         stored = params
         if param_view is not None:
-            params, pull_back = jax.vjp(param_view, stored)
+            with jax.named_scope("param_view"):
+                params, pull_back = jax.vjp(param_view, stored)
         if hier is not None:
             if rng is not None:
                 raise ValueError(
@@ -226,21 +227,26 @@ def make_train_step(
             def microbatch(acc, xs):
                 mb, w = xs
                 (l, st), g = grad_fn(params, mb)
-                acc = jax.tree.map(
-                    lambda a, b: a + w * b.astype(jnp.float32), acc, g)
+                with jax.named_scope("grad/accumulate"):
+                    acc = jax.tree.map(
+                        lambda a, b: a + w * b.astype(jnp.float32), acc, g)
                 return acc, (w * l, st)
 
-            zeros = jax.tree.map(
-                lambda p: jnp.zeros(p.shape, jnp.float32), params)
+            with jax.named_scope("grad/accumulate"):
+                zeros = jax.tree.map(
+                    lambda p: jnp.zeros(p.shape, jnp.float32), params)
             grads, (wlosses, stacked) = jax.lax.scan(
                 microbatch, zeros, (mbs, weights))
             loss = jnp.sum(wlosses)
             stats = _reduce_stats(stacked, weights) if aux_stats else {}
         if param_view is not None:
-            grads, = pull_back(grads)
-        gnorm = global_grad_norm(grads)
-        updates, new_opt = tx.update(grads, opt_state, stored)
-        new_params = optax.apply_updates(stored, updates)
+            with jax.named_scope("param_view"):
+                grads, = pull_back(grads)
+        with jax.named_scope("grad/clip"):
+            gnorm = global_grad_norm(grads)
+        with jax.named_scope("optimizer/update"):
+            updates, new_opt = tx.update(grads, opt_state, stored)
+            new_params = optax.apply_updates(stored, updates)
         metrics = {"loss": loss, "grad_norm": gnorm}
         if aux_stats:
             metrics["moe"] = stats

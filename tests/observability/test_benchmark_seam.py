@@ -35,6 +35,7 @@ GAUGE_FILES = ("program_gauges.py", "moe_gauges.py", "lfm2_gauges.py")
 lfm2_gauges = manifest.load_python(os.path.join(READERS, "lfm2_gauges.py"))
 granite_scopes = manifest.load_python(
     os.path.join(READERS, "granite_scopes.py"))
+step_map = manifest.load_python(os.path.join(READERS, "step_map.py"))
 
 TRACED, MEASURED = 3, 2
 ITERS = window.WARMUP_STEPS + TRACED + MEASURED
@@ -124,7 +125,7 @@ def run(request, tmp_path_factory):
         assert len(out["losses"]) == ITERS
         yield {"preset": request.param, "registry": reg, "result": out,
                "log": said.getvalue(),
-               "trace": xplane.find_xplane(tdir),
+               "trace": xplane.find_xplane(tdir), "trace_dir": tdir,
                # the CPU's allocator states no limit; a chip's does
                "facts": {"memory": {"per_device": [{"bytes_limit": 2 ** 34}]}}}
     finally:
@@ -247,11 +248,14 @@ def test_the_step_report_keeps_the_instructions_under_a_scope(run, scope):
     from hetu_galvatron_tpu.observability import trace_analysis
 
     kept = run["result"]["scope_instructions"]
+    mine = trace_analysis.step_scopes()
     if run["preset"] != "granite":
-        assert kept is None
+        # every model keeps the map now; one without a state-space block
+        # has nothing under these scopes, and the readers publish nothing
+        assert kept[scope] == [] == mine["scopes"][scope]
+        assert mine["map"]["instructions"] and mine["instructions"]
         return
     assert kept[scope] and len(set(kept[scope])) == len(kept[scope])
-    mine = trace_analysis.step_scopes()
     assert mine["scopes"][scope] == kept[scope]
     assert set(kept[scope]) <= mine["instructions"]
     # the scopes do not share an instruction
@@ -309,17 +313,17 @@ ENTRY %main (a: f32[8]) -> f32[8] {
                for n in found["scopes"][SSD_SCOPE]) == 2
 
 
-def _traced_by_hand(names, known_only=True):
+def _traced_by_hand(names, known_only=True, each_ms=0.1):
     """A steady window of two periods in which every named instruction ran
-    0.1 ms a step, one after the other: what ``xplane.reduce_device`` hands
-    a reader, without a TPU."""
+    ``each_ms`` a step, one after the other: what ``xplane.reduce_device``
+    hands a reader, without a TPU."""
     ms, leaves, t = 1e6, [], 0.0
     steps = [(0.0, 50 * ms), (60 * ms, 110 * ms), (120 * ms, 170 * ms)]
     for start, _ in steps[:2]:
         t = start
         for n in list(names) + ([] if known_only else ["stranger.1"]):
-            leaves.append((n, t, t + 0.1 * ms))
-            t += 0.1 * ms
+            leaves.append((n, t, t + each_ms * ms))
+            t += each_ms * ms
     return xplane.Reduced(0, steps, (0.0, 120 * ms), leaves,
                           [(n, e - s) for n, s, e in leaves], [])
 
@@ -362,6 +366,254 @@ def test_without_a_map_the_granite_readers_publish_nothing(monkeypatch):
     assert granite_scopes.mamba_ms(facts) is None
     monkeypatch.delattr(trace_analysis, "step_scopes")
     assert granite_scopes.ssd_roofline(facts) is None
+
+
+# the scopes a preset's step has to carry (the vocabulary is
+# trace_analysis.SCOPES), and the per-layer metrics that read them
+# (grad/clip is not asked for: XLA merges the gradient norm under it with
+# the one optax's clip computes inside optimizer/update, and keeps either)
+EVERY_STEP = {"embed", "norm", "attn/qkv_proj", "attn/core", "attn/out_proj",
+              "head", "param_view", "grad/accumulate", "optimizer/update"}
+PRESET_SCOPES = {
+    "dense": EVERY_STEP | {"mlp"},
+    "moe": EVERY_STEP | {"attn/rope", "attn/qk_norm", "moe/route",
+                         "moe/dispatch", "moe/experts", "moe/combine"},
+    "lfm2": EVERY_STEP | {"mlp", "attn/rope", "attn/qk_norm", "moe/route",
+                          "moe/dispatch", "moe/experts", "moe/combine",
+                          "mixer/short_conv/in_proj",
+                          "mixer/short_conv/gate_conv",
+                          "mixer/short_conv/out_proj"},
+    "granite": EVERY_STEP | {"mlp"} | set(granite_scopes.SCOPES),
+}
+SCOPE_READERS = {
+    "attn_proj_ms": ("attn/qkv_proj", "attn/out_proj"), "mlp_ms": ("mlp",),
+    "head_ms": ("head",), "moe_route_ms": ("moe/route",),
+    "moe_dispatch_ms": ("moe/dispatch",), "moe_combine_ms": ("moe/combine",),
+    "short_conv_ms": ("mixer/short_conv/in_proj",
+                      "mixer/short_conv/gate_conv",
+                      "mixer/short_conv/out_proj"),
+}
+
+
+def test_every_preset_keeps_a_map_of_its_step(run):
+    """``step_scopes()["map"]``: every instruction of the compiled step
+    that is an event of a trace, with exactly one phase and at most one
+    scope of the vocabulary; counted in ``train()``'s result and in the
+    ``step report:`` line, and written beside the trace when the profiler
+    window closed."""
+    from hetu_galvatron_tpu.observability import trace_analysis
+
+    kept = trace_analysis.step_scopes()["map"]
+    classes = kept["instructions"]
+    assert run["result"]["step_map"] == {
+        "instructions": len(classes), "inferred": len(kept["inferred"]),
+        "unnamed": len(kept["tails"])}
+    assert f"{len(classes)} instructions mapped" in run["log"]
+    assert all(len(c) == 3 and c[1] in trace_analysis.PHASES
+               and (c[0] is None or c[0] in trace_analysis.SCOPES)
+               for c in classes.values())
+    assert set(kept["inferred"]) <= set(classes)
+    assert {n for n, c in classes.items() if c[0] is None} == set(
+        kept["tails"])
+    assert set(classes) <= trace_analysis.step_scopes()["instructions"]
+    with open(os.path.join(run["trace_dir"],
+                           trace_analysis.STEP_MAP_FILE)) as f:
+        written = json.load(f)
+    assert written["instructions"] == {n: list(c)
+                                       for n, c in classes.items()}
+    assert written["vocabulary"] == list(trace_analysis.SCOPES)
+
+
+def test_the_map_names_the_step_by_scope_and_phase(run):
+    from hetu_galvatron_tpu.observability import trace_analysis
+
+    classes = trace_analysis.step_scopes()["map"]["instructions"].values()
+    assert PRESET_SCOPES[run["preset"]] <= {c[0] for c in classes}
+    # no layer of these presets is rematerialised; a mamba block's scan
+    # makes its groups' decay matrices again in the backward pass
+    assert {c[1] for c in classes} == {
+        "forward", "backward", "update", "other"} | (
+            {"recompute"} if run["preset"] == "granite" else set())
+    assert {c[0] for c in classes if c[1] == "recompute"} <= {
+        "mixer/mamba/ssd", None}
+    # the update is what lies under optimizer/ and no transformation
+    assert {c[0] for c in classes if c[1] == "update"} == {
+        "optimizer/update"}
+    assert not any(c[2] for c in classes)      # one device: no collective
+
+
+def test_the_step_map_readers_join_a_trace_to_the_map(run):
+    """Every traced instruction ran ``each`` ms a step: a reader's value is
+    its instructions' count times that, the five phases add up to the
+    summed leaf time of a step, and a metric whose scope the model lacks
+    (a dense MLP in the expert preset) publishes nothing."""
+    from hetu_galvatron_tpu.observability import trace_analysis
+
+    classes = trace_analysis.step_scopes()["map"]["instructions"]
+    each = 40.0 / len(classes)
+    facts = {"trace": {"reduced": [_traced_by_hand(classes, each_ms=each)]}}
+    phases = {p: getattr(step_map, f"phase_{p}_ms")(facts)
+              for p in step_map.PHASES}
+    for p, ms in phases.items():
+        assert ms == pytest.approx(
+            each * sum(c[1] == p for c in classes.values()))
+    if run["preset"] != "granite":  # a share of a partition: 0, not None
+        assert phases["recompute"] == 0.0
+    assert sum(phases.values()) == pytest.approx(each * len(classes),
+                                                 rel=1e-3)
+    assert step_map.scope_unnamed_pct(facts) == pytest.approx(
+        100.0 * sum(c[0] is None for c in classes.values()) / len(classes))
+    for name, scopes in SCOPE_READERS.items():
+        value = getattr(step_map, name)(facts)
+        if set(scopes) <= PRESET_SCOPES[run["preset"]]:
+            assert value == pytest.approx(
+                each * sum(c[0] in scopes for c in classes.values()))
+            assert value > 0
+        else:
+            assert value is None
+    for name in ("collective_all_ms", "collective_all_exposed_pct",
+                 "collective_overlapped_ms"):
+        assert getattr(step_map, name)(facts) is None
+    # no trace, or an operation inside a step that is no instruction of the
+    # step's HLO: nothing is published
+    stranger = {"trace": {"reduced": [_traced_by_hand(
+        classes, known_only=False, each_ms=each)]}}
+    for name in (*SCOPE_READERS, "phase_forward_ms", "scope_unnamed_pct"):
+        assert getattr(step_map, name)({}) is None
+        assert getattr(step_map, name)(stranger) is None
+
+
+STEP_MAP_METRICS = tuple(f"phase_{p}_ms" for p in step_map.PHASES) + (
+    "scope_unnamed_pct", *SCOPE_READERS, "collective_all_ms",
+    "collective_all_exposed_pct", "collective_overlapped_ms")
+
+
+@pytest.mark.parametrize("name", STEP_MAP_METRICS)
+def test_a_step_map_metric_has_its_file_and_its_reader(name):
+    with open(os.path.join(READERS, name + ".json")) as f:
+        declared = json.load(f)
+    assert declared["what"] and declared["reader"] == {
+        "kind": "python", "file": "step_map.py", "function": name}
+    assert callable(getattr(step_map, name))
+    (entry,) = [m for m in manifest.load_manifest()["per_layer"]
+                if m["name"] == name]
+    assert (entry["source"], entry["moves"], entry["better"]) == (
+        "device_trace", "tokens_per_s", "lower")
+
+
+@pytest.mark.parametrize("name", STEP_MAP_METRICS)
+def test_without_the_new_key_a_step_map_reader_publishes_nothing(
+        monkeypatch, name):
+    """What the parent commit gives them: a ``step_scopes()`` with the keys
+    of before and no ``map``, an empty one, or none at all: ``None``, never
+    a 0."""
+    from hetu_galvatron_tpu.observability import trace_analysis
+
+    facts = {"trace": {"reduced": [_traced_by_hand(["fusion.1"])]}}
+    reader = getattr(step_map, name)
+    monkeypatch.setattr(trace_analysis, "_STEP_SCOPES", {
+        "scopes": {"mixer/mamba/ssd": ["fusion.1"]},
+        "instructions": frozenset(["fusion.1"]),
+        "mosaic_calls": frozenset()})
+    assert reader(facts) is None
+    monkeypatch.setattr(trace_analysis, "_STEP_SCOPES", {})
+    assert reader(facts) is None
+    monkeypatch.delattr(trace_analysis, "step_scopes")
+    assert reader(facts) is None
+
+
+def test_the_collective_readers_read_the_classes(monkeypatch):
+    """Four chips by hand (``test_step_map.FOUR_CHIPS``'s classes): the
+    start, the wait, a fused reduce-scatter and an all-to-all are
+    ``collective_all_ms``; the all-gather riding a matmul is not; the
+    exposed share is the part no other leaf covers."""
+    from hetu_galvatron_tpu.observability import trace_analysis
+
+    monkeypatch.setattr(trace_analysis, "_STEP_SCOPES", {"map": {
+        "instructions": {
+            "async-collective-start.1": ("mlp", "forward",
+                                         "all-gather.start"),
+            "fusion.12": ("mlp", "forward", "overlapped"),
+            "async-collective-done.1": ("mlp", "forward", "all-gather.done"),
+            "fusion.13": ("attn/qkv_proj", "backward",
+                          "reduce-scatter.fused"),
+            "all-to-all.2": (None, "forward", "all-to-all"),
+            "fusion.14": ("mlp", "backward", None)},
+        "inferred": [], "tails": {}}})
+    ms = 1e6
+    one = [("async-collective-start.1", 0.0, 0.1), ("fusion.12", 0.1, 1.1),
+           ("async-collective-done.1", 1.1, 1.4), ("fusion.13", 1.4, 2.4),
+           # this one runs beside the second half of the reduce-scatter
+           ("fusion.14", 1.9, 2.9), ("all-to-all.2", 2.9, 3.0)]
+    steps = [(0.0, 5 * ms), (10 * ms, 15 * ms), (20 * ms, 25 * ms)]
+    leaves = [(n, (s + at) * ms, (e + at) * ms)
+              for at in (0.0, 10.0) for n, s, e in one]
+    r = xplane.Reduced(0, steps, (0.0, 20 * ms), leaves,
+                       [(n, e - s) for n, s, e in leaves], [])
+    facts = {"trace": {"reduced": [r]}}
+    assert step_map.collective_all_ms(facts) == pytest.approx(1.5)
+    assert step_map.collective_overlapped_ms(facts) == pytest.approx(1.0)
+    # exposed: 0.1 + 0.3 + 0.5 of the reduce-scatter + 0.1, of 3.0 busy
+    assert step_map.collective_all_exposed_pct(facts) == pytest.approx(
+        100.0 * 1.0 / 3.0)
+    assert step_map.mlp_ms(facts) == pytest.approx(2.4)
+
+
+def _scope_instructions_of_pr35(hlo_text, scopes):
+    """``trace_analysis.scope_instructions`` as PR 35 wrote it (its own walk
+    over the computations), kept here as the oracle of the lists the
+    ``granite_*`` metrics read."""
+    import re
+
+    computation = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) \(.*?^\}",
+                             re.M | re.S)
+    instruction = re.compile(r"^\s+(?:ROOT )?%?([\w.\-]+) = ")
+    found = {s: [] for s in scopes}
+    for m in computation.finditer(hlo_text):
+        if "fused_computation" in m.group(1):
+            continue
+        for line in m.group(0).splitlines():
+            inst = instruction.match(line)
+            op = re.search(r'op_name="([^"]*)"', line)
+            if not inst or not op:
+                continue
+            path = re.sub(r"\w+\(|\)", "", op.group(1)) + "/"
+            for s in scopes:
+                if s + "/" in path:
+                    found[s].append(inst.group(1))
+    return found
+
+
+def test_the_mamba_lists_are_what_scope_instructions_gave():
+    """The five ``mixer/mamba/*`` lists of ``step_scopes()["scopes"]`` by
+    the rule of before, on the compiled step of the tiny Granite hybrid: an
+    instruction by its OWN ``op_name`` (no inference from what it fuses),
+    whatever the vocabulary's deepest name says of it."""
+    import jax
+    import jax.numpy as jnp
+
+    from hetu_galvatron_tpu.core.arguments import args_from_cli
+    from hetu_galvatron_tpu.models.builder import (
+        causal_lm_loss,
+        init_causal_lm,
+    )
+    from hetu_galvatron_tpu.observability import trace_analysis
+
+    yaml, *size = PRESETS["granite"]
+    cfg = args_from_cli([os.path.join(ZOO, yaml)] + size,
+                        mode="train_dist").model
+    params = jax.eval_shape(lambda k: init_causal_lm(k, cfg)[0],
+                            jax.random.key(0))
+    tokens = jax.ShapeDtypeStruct((2, 16), jnp.int32)
+    text = jax.jit(jax.grad(lambda p, t: causal_lm_loss(
+        p, {"tokens": t, "labels": t}, cfg))).lower(
+            params, tokens).compile().as_text()
+    expected = _scope_instructions_of_pr35(text, granite_scopes.SCOPES)
+    assert all(expected[s] for s in granite_scopes.SCOPES)
+    assert trace_analysis.step_hlo(text)["scopes"] == expected
+    assert trace_analysis.scope_instructions(
+        text, granite_scopes.SCOPES)["scopes"] == expected
+    assert tuple(expected) == trace_analysis.MIXER_SCOPES["mamba"]
 
 
 def test_iteration_spans_are_flat_siblings_on_the_dispatching_thread(run):

@@ -1,0 +1,427 @@
+"""The one walk over the compiled step's optimized HLO
+(``observability/trace_analysis.py::step_hlo``): every instruction that is
+an event of a TPU trace gets a scope, a phase and a collective class. One
+case a rule, on ``op_name``s read off the cells' own HLO and on a few lines
+in the form XLA:TPU prints; the vocabulary against the ``jax.named_scope``
+literals of the model and step code; the file written beside a trace; and
+``tools/trace_by_scope.py``'s tables over a trace made by hand."""
+
+import ast
+import glob
+import json
+import os
+
+import pytest
+
+from benchmark import xplane
+from hetu_galvatron_tpu.analysis.lint import _callee
+from hetu_galvatron_tpu.observability import trace_analysis
+from hetu_galvatron_tpu.observability.trace_analysis import (
+    SCOPES,
+    hlo_counts,
+    scope_and_phase,
+    scope_instructions,
+    step_hlo,
+)
+from tools import trace_by_scope
+
+pytestmark = pytest.mark.observability
+
+PACKAGE = os.path.dirname(os.path.dirname(os.path.abspath(
+    trace_analysis.__file__)))
+BODY = "jit(step)/while/body/closed_call/"
+
+
+@pytest.mark.parametrize("op_name,phase", [
+    # the forward pass of a microbatch
+    (BODY + "jvp(jit(silu))/div", "forward"),
+    (BODY + "jvp(attn/qkv_proj)/bsh,hf->bsf/dot_general", "forward"),
+    # the forward made again inside the backward pass
+    (BODY + "transpose(jvp(jvp()))/checkpoint/rematted_computation/"
+     "bsh,hf->bsf/dot_general", "recompute"),
+    (BODY + "transpose(jvp(jvp()))/checkpoint/rematted_computation/"
+     "jit(silu)/exp", "recompute"),
+    # the backward pass
+    (BODY + "transpose(jvp(jvp()))/checkpoint/"
+     "jit(flash_attention_bwd_hmajor)/flash_attention_bwd_dq/pallas_call",
+     "backward"),
+    (BODY + "transpose(jvp())/div", "backward"),
+    # once a step, under the optimizer's scope and no transformation
+    ("jit(step)/optimizer/update/mul", "update"),
+    ("jit(step)/optimizer/update/jit(_where)/select_n", "update"),
+    # the rest: outside the three passes
+    ("jit(step)/grad/clip/reduce_sum", "other"),
+    ("jit(step)/while/body/grad/accumulate/add", "other"),
+    ("jit(step)/while/body/squeeze", "other"),
+    ("jit(step)/param_view/convert_element_type", "other"),
+    ("params['layers'][0]['mlp']['wout']", "other"),
+    ("", "other"),
+])
+def test_an_op_name_says_its_phase(op_name, phase):
+    assert scope_and_phase(op_name)[1] == phase
+    assert phase in trace_analysis.PHASES
+
+
+@pytest.mark.parametrize("op_name,scope", [
+    ("jit(step)/optimizer/update/mul", "optimizer/update"),
+    # a transformation wraps the part of the name stack it was applied under
+    (BODY + "jvp(mixer/mamba)/ssd/ssd_scan_fwd/pallas_call",
+     "mixer/mamba/ssd"),
+    (BODY + "transpose(jvp(attn))/qkv_proj/dot_general", "attn/qkv_proj"),
+    (BODY + "transpose(jvp(jvp(moe/dispatch)))/checkpoint/gather",
+     "moe/dispatch"),
+    # the deepest name wins: the cast of a weight inside a projection
+    (BODY + "jvp(attn/qkv_proj)/param_view/convert_element_type",
+     "param_view"),
+    (BODY + "jvp(mlp/param_view)/convert_element_type", "param_view"),
+    # a block's norm, the q/k norm and a mamba block's gated norm apart
+    (BODY + "jvp(norm)/rsqrt", "norm"),
+    (BODY + "jvp(attn/qk_norm)/rsqrt", "attn/qk_norm"),
+    (BODY + "jvp(mixer/mamba/gated_norm)/rsqrt", "mixer/mamba/gated_norm"),
+    # a jitted function's name is no scope, a parameter's path neither
+    ("jit(step)/jit(norm)/sqrt", None),
+    (BODY + "jvp(jit(head))/mul", None),
+    ("params['layers'][0]['mlp']['wout']", None),
+    ("opt_state.inner_states['adam'].inner_state[1].mu['embed']['wte']",
+     None),
+    (BODY + "jvp()/cos", None),
+])
+def test_an_op_name_says_its_scope(op_name, scope):
+    assert scope_and_phase(op_name)[0] == scope
+
+
+# a step in the form XLA:TPU prints it: a fusion that keeps its root's
+# op_name, two without one (one takes what it fuses, one fuses nothing
+# named), a reduction with its applied computation, a loop
+ONE_CHIP = """HloModule jit_step
+
+%region_0.1 (a: f32[], b: f32[]) -> f32[] {
+  %a = f32[] parameter(0)
+  %b = f32[] parameter(1)
+  ROOT %add.9 = f32[] add(%a, %b), metadata={op_name="jit(step)/while/body/closed_call/jvp(norm)/reduce_sum"}
+}
+
+%fused_computation.1 (p: f32[8]) -> f32[8] {
+  %p = f32[8]{0} parameter(0)
+  %mul.1 = f32[8]{0} multiply(%p, %p), metadata={op_name="jit(step)/while/body/closed_call/jvp(mlp)/mul"}
+  %mul.2 = f32[8]{0} multiply(%mul.1, %p), metadata={op_name="jit(step)/while/body/closed_call/jvp(mlp)/mul"}
+  ROOT %cvt.1 = f32[8]{0} convert(%mul.2), metadata={op_name="jit(step)/while/body/closed_call/jvp(mlp/param_view)/convert_element_type"}
+}
+
+%fused_computation.2 (p: f32[8]) -> f32[8] {
+  %p.1 = f32[8]{0} parameter(0)
+  ROOT %copy.7 = f32[8]{0} copy(%p.1)
+}
+
+%body.1 (t: (f32[8])) -> (f32[8]) {
+  %t = (f32[8]) parameter(0)
+  %gte.1 = f32[8]{0} get-tuple-element(%t), index=0
+  %fusion.1 = f32[8]{0} fusion(%gte.1), kind=kLoop, calls=%fused_computation.1
+  %fusion.2 = f32[8]{0} fusion(%fusion.1), kind=kLoop, calls=%fused_computation.2
+  %fusion.3 = f32[8]{0} fusion(%fusion.2), kind=kLoop, calls=%fused_computation.1, metadata={op_name="jit(step)/while/body/closed_call/transpose(jvp(attn/out_proj))/dot_general"}
+  %reduce.1 = f32[] reduce(%fusion.3), dimensions={0}, to_apply=%region_0.1, metadata={op_name="jit(step)/while/body/closed_call/jvp(norm)/reduce_sum"}
+  %flash_attention_fwd.1 = f32[8]{0} custom-call(%fusion.3), custom_call_target="tpu_custom_call", metadata={op_name="jit(step)/while/body/closed_call/jvp(attn/core)/flash_attention_fwd/pallas_call"}
+  ROOT %tuple.1 = (f32[8]) tuple(%flash_attention_fwd.1)
+}
+
+ENTRY %main (a: f32[8]) -> f32[8] {
+  %a.1 = f32[8]{0} parameter(0), metadata={op_name="params['embed']['wte']"}
+  %tuple.2 = (f32[8]) tuple(%a.1)
+  %while.1 = (f32[8]) while(%tuple.2), condition=%cond.1, body=%body.1
+  %gte.2 = f32[8]{0} get-tuple-element(%while.1), index=0
+  ROOT %fusion.4 = f32[8]{0} fusion(%gte.2), kind=kLoop, calls=%fused_computation.2, metadata={op_name="jit(step)/optimizer/update/add"}
+}
+"""
+
+
+def test_a_fusion_without_op_name_takes_the_commonest_scope_inside():
+    found = step_hlo(ONE_CHIP)["map"]
+    ins = found["instructions"]
+    # two of fused_computation.1's three instructions are under mlp
+    assert ins["fusion.1"] == ("mlp", "forward", None)
+    assert found["inferred"] == ["fusion.1"]
+    # nothing named inside: no scope, and it is not called inferred; with
+    # no op_name it is of the pass its operand is of
+    assert ins["fusion.2"] == (None, "forward", None)
+    assert found["tails"]["fusion.2"] == "fusion"
+    # a fusion WITH an op_name is what that says, whatever it fuses
+    assert ins["fusion.3"] == ("attn/out_proj", "backward", None)
+    assert ins["fusion.4"] == ("optimizer/update", "update", None)
+    assert ins["flash_attention_fwd.1"] == ("attn/core", "forward", None)
+
+
+# what carries no name stack: a copy XLA made, and the Mosaic calls libtpu
+# lowers lax.ragged_dot to, which it names itself
+NAMELESS = """HloModule jit_step
+
+ENTRY %main (a: f32[8]) -> f32[8] {
+  %a = f32[8]{0} parameter(0)
+  %fusion.1 = f32[8]{0} fusion(%a), kind=kLoop, calls=%fused_computation.1, metadata={op_name="jit(step)/jvp(moe/dispatch)/gather"}
+  %fusion.2 = f32[8]{0} fusion(%a), kind=kLoop, calls=%fused_computation.1, metadata={op_name="jit(step)/transpose(jvp())/checkpoint/rematted_computation/moe/dispatch/gather"}
+  %fusion.3 = f32[8]{0} fusion(%a), kind=kLoop, calls=%fused_computation.1, metadata={op_name="jit(step)/transpose(jvp(moe/combine))/mul"}
+  %ragged-dot-metadata.1 = s32[9]{0} custom-call(%a), custom_call_target="tpu_custom_call", metadata={op_name="ragged-dot-metadata"}
+  %ragged-dot-none.1 = f32[8]{0} custom-call(%fusion.1, %a, %ragged-dot-metadata.1), custom_call_target="tpu_custom_call", metadata={op_name="ragged-dot-none"}
+  %ragged-dot-none.2 = f32[8]{0} custom-call(%fusion.2, %fusion.3, /*index=2*/%fusion.1), custom_call_target="tpu_custom_call", metadata={op_name="ragged-dot-none"}
+  %copy.1 = f32[8]{0} copy(%fusion.2)
+  %copy.2 = f32[8]{0} copy(%a)
+  %copy-start.1 = (f32[8]{0}, f32[8]{0}, u32[]) copy-start(%copy.1)
+  ROOT %add.1 = f32[8]{0} add(%copy.1, %fusion.3), metadata={op_name="jit(step)/grad/accumulate/add"}
+}
+"""
+
+
+@pytest.mark.parametrize("name,expected", [
+    # the grouped matmuls: the scope by the kernel's own name, the phase the
+    # latest pass among the operands
+    ("ragged-dot-none.1", ("moe/experts", "forward", None)),
+    ("ragged-dot-none.2", ("moe/experts", "backward", None)),
+    ("ragged-dot-metadata.1", ("moe/experts", "other", None)),
+    # a copy without op_name: no scope, its operand's pass
+    ("copy.1", (None, "recompute", None)),
+    ("copy-start.1", (None, "recompute", None)),
+    ("copy.2", (None, "other", None)),
+    # an op_name that IS a name stack says its phase itself
+    ("add.1", ("grad/accumulate", "other", None)),
+])
+def test_an_instruction_without_a_name_stack_asks_its_operands(name,
+                                                              expected):
+    read = step_hlo(NAMELESS)
+    assert read["map"]["instructions"][name] == expected
+    assert read["map"]["inferred"] == []
+    assert scope_and_phase("ragged-dot-none") == ("moe/experts", "other")
+
+
+def test_the_map_holds_the_events_of_a_trace_and_nothing_else():
+    """Instructions of a fused computation and of a reduction's applied
+    computation are no events; the entry's and a loop body's are, each
+    once."""
+    read = step_hlo(ONE_CHIP)
+    ins = read["map"]["instructions"]
+    assert set(ins) == {
+        "t", "gte.1", "fusion.1", "fusion.2", "fusion.3", "reduce.1",
+        "flash_attention_fwd.1", "tuple.1", "a.1", "tuple.2", "while.1",
+        "gte.2", "fusion.4"}
+    assert ins["reduce.1"] == ("norm", "forward", None)
+    assert ins["a.1"] == (None, "other", None)
+    assert read["map"]["tails"]["a.1"] == "params['embed']['wte']"
+    assert read["map"]["tails"]["gte.1"] == "get-tuple-element"
+    assert all(len(c) == 3 and c[1] in trace_analysis.PHASES
+               and (c[0] is None or c[0] in SCOPES) for c in ins.values())
+    # the keys of before, from the same walk
+    assert read["mosaic_custom_calls"] == 1
+    assert read["mosaic_calls"] == {"flash_attention_fwd.1"}
+    assert set(ins) <= read["instructions"]
+    assert "add.9" in read["instructions"] and "mul.1" not in \
+        read["instructions"]
+
+
+# four chips: a collective under its own name, XLA:TPU's three fusions of an
+# asynchronous all-gather (the middle one rides a matmul), a reduce-scatter
+# fused as an all-reduce and a slice, an asynchronous pair under its own names
+FOUR_CHIPS = '''HloModule jit_step
+
+%fused_computation.7 (p: bf16[8,4]) -> (bf16[8,4], bf16[8,8], u32[]) {
+  %p = bf16[8,4]{1,0:T(8,128)(2,1)} parameter(0)
+  %all-gather.3 = bf16[8,8]{1,0:T(8,128)(2,1)} all-gather(%p), replica_groups=[2,2]<=[4], dimensions={1}, metadata={op_name="jit(step)/while/body/closed_call/jvp(mlp)/bsh,hf->bsf/dot_general"}
+  ROOT %custom-call.1 = (bf16[8,4], bf16[8,8], u32[]) custom-call(%all-gather.3), custom_call_target="AsyncCollectiveStart"
+}
+
+%async_collective_fusion.9 (p: bf16[8,4]) -> bf16[8,8] {
+  %p.1 = bf16[8,4]{1,0} parameter(0)
+  ROOT %all-gather.4 = bf16[8,8]{1,0} all-gather(%p.1), replica_groups=[2,2]<=[4], dimensions={1}
+}
+
+%fused_computation.8 (p: bf16[8,8]) -> bf16[8,8] {
+  %p.2 = bf16[8,8]{1,0} parameter(0)
+  %all-gather.5 = bf16[8,8]{1,0} all-gather(%p.2), replica_groups=[2,2]<=[4], dimensions={1}
+  ROOT %custom-call.2 = bf16[8,8] custom-call(%all-gather.5), custom_call_target="AsyncCollectiveDone"
+}
+
+%all-reduce-scatter.2.clone (p: f32[8,8]) -> f32[4,8] {
+  %p.3 = f32[8,8]{1,0} parameter(0)
+  %all-reduce.6 = f32[8,8]{1,0} all-reduce(%p.3), replica_groups={{0,2},{1,3}}, to_apply=%add
+  ROOT %dynamic-slice.1 = f32[4,8]{1,0} dynamic-slice(%all-reduce.6)
+}
+
+%body.1 (t: (bf16[8,4], f32[8,8])) -> (bf16[8,4], f32[8,8]) {
+  %t = (bf16[8,4], f32[8,8]) parameter(0)
+  %async-collective-start.1 = (bf16[8,4], bf16[8,8], u32[]) fusion(%t), kind=kCustom, calls=%fused_computation.7
+  %fusion.12 = bf16[8,8]{1,0} fusion(%async-collective-start.1), kind=kOutput, calls=%async_collective_fusion.9, metadata={op_name="jit(step)/while/body/closed_call/jvp(mlp)/bsh,hf->bsf/dot_general"}
+  %async-collective-done.1 = bf16[8,8]{1,0} fusion(%fusion.12), kind=kCustom, calls=%fused_computation.8, metadata={op_name="jit(step)/while/body/closed_call/jvp(mlp)/bsh,hf->bsf/dot_general"}
+  %fusion.13 = f32[4,8]{1,0} fusion(%async-collective-done.1), kind=kCustom, calls=%all-reduce-scatter.2.clone, metadata={op_name="jit(step)/while/body/closed_call/transpose(jvp(attn/qkv_proj))/dot_general"}
+  %all-to-all.2 = bf16[2,4,4]{2,1,0} all-to-all(%fusion.13), replica_groups=[2,2]<=[4], metadata={op_name="jit(step)/while/body/closed_call/jvp()/split"}
+  %collective-permute-start.1 = (bf16[8,4], bf16[8,4]) collective-permute-start(%all-to-all.2), source_target_pairs={{0,1}}
+  %collective-permute-done.1 = bf16[8,4] collective-permute-done(%collective-permute-start.1)
+  ROOT %tuple = (bf16[8,4], f32[8,8]) tuple(%t)
+}
+'''
+
+
+@pytest.mark.parametrize("name,expected", [
+    ("all-to-all.2", (None, "forward", "all-to-all")),
+    ("fusion.13", ("attn/qkv_proj", "backward", "reduce-scatter.fused")),
+    ("fusion.12", ("mlp", "forward", "overlapped")),
+    # the start half has no op_name: it takes what it fuses
+    ("async-collective-start.1", ("mlp", "forward", "all-gather.start")),
+    ("async-collective-done.1", ("mlp", "forward", "all-gather.done")),
+    # (no op_name: of the pass their operand is of)
+    ("collective-permute-start.1",
+     (None, "forward", "collective-permute.start")),
+    ("collective-permute-done.1",
+     (None, "forward", "collective-permute.done")),
+    ("tuple", (None, "other", None)),
+])
+def test_an_instruction_says_what_it_moves_between_chips(name, expected):
+    assert step_hlo(FOUR_CHIPS)["map"]["instructions"][name] == expected
+
+
+def test_the_counts_of_before_come_out_of_the_same_walk():
+    """``hlo_counts``: an async triple once, a fused all-reduce-scatter as
+    the reduce-scatter it is, a start / done pair once; and its old answer
+    on the case ``tests/core/test_aot_hlo_report.py`` keeps."""
+    assert hlo_counts(FOUR_CHIPS) == {
+        "mosaic_custom_calls": 0,
+        "collectives": {"all-to-all": 1, "all-gather": 1, "all-reduce": 0,
+                        "reduce-scatter": 1, "collective-permute": 1}}
+    assert FOUR_CHIPS.count(" all-gather(") == 3
+    assert hlo_counts(ONE_CHIP) == {
+        "mosaic_custom_calls": 1,
+        "collectives": dict.fromkeys(trace_analysis.COLLECTIVE_OPS, 0)}
+    assert hlo_counts("")["collectives"] == dict.fromkeys(
+        trace_analysis.COLLECTIVE_OPS, 0)
+
+
+def test_scope_instructions_is_a_view_by_the_rule_of_before():
+    """An instruction by its OWN op_name, under any scopes a caller names
+    (here two that are no part of the vocabulary's matching: ``attn`` is a
+    prefix, and the fusion that only holds ``mlp`` inside is not listed)."""
+    found = scope_instructions(ONE_CHIP, ("attn", "mlp", "norm"))
+    assert found["scopes"] == {
+        "attn": ["fusion.3", "flash_attention_fwd.1"], "mlp": [],
+        "norm": ["add.9", "reduce.1"]}
+    assert found["mosaic_calls"] == {"flash_attention_fwd.1"}
+    assert set(found) == {"scopes", "instructions", "mosaic_calls"}
+
+
+def test_every_named_scope_of_the_step_is_in_the_vocabulary():
+    """Every ``jax.named_scope`` literal of the model code and of the step
+    program (found as ``analysis/lint.py`` finds them for GAL004) is a name
+    of ``trace_analysis.SCOPES`` or, where scopes nest
+    (``mixer/mamba`` around ``ssd``), whole parts of one; and every name of
+    the vocabulary is written somewhere."""
+    files = sorted(glob.glob(os.path.join(PACKAGE, "models", "*.py"))) + [
+        os.path.join(PACKAGE, "runtime", "trainer.py"),
+        os.path.join(PACKAGE, "parallel", "spmd.py"),
+        os.path.join(PACKAGE, "ops", "pallas", "ssd.py")]
+    literals = set()
+    for path in files:
+        with open(path) as f:
+            tree = ast.parse(f.read())
+        consts = {t.id: n.value.value for n in ast.walk(tree)
+                  if isinstance(n, ast.Assign)
+                  and isinstance(n.value, ast.Constant)
+                  and isinstance(n.value.value, str)
+                  for t in n.targets if isinstance(t, ast.Name)}
+        for node in ast.walk(tree):
+            if not (isinstance(node, ast.Call) and node.args and _callee(
+                    node).rsplit(".", 1)[-1] == "named_scope"):
+                continue
+            arg = node.args[0]
+            if isinstance(arg, ast.Name):       # a module-level constant
+                literals.add(consts[arg.id])
+            else:
+                assert isinstance(arg, ast.Constant), ast.dump(arg)
+                literals.add(arg.value)
+    assert len(literals) > 20
+    for lit in sorted(literals):
+        assert any(f"/{lit}/" in f"/{s}/" for s in SCOPES), lit
+    written = lambda s: any(
+        s == lit or (s.startswith(lit + "/") and s[len(lit) + 1:] in literals)
+        for lit in literals)
+    assert [s for s in SCOPES if not written(s)] == []
+
+
+def test_the_map_is_written_beside_a_trace(tmp_path):
+    before = trace_analysis.step_scopes()
+    try:
+        trace_analysis.record_step_scopes({})
+        assert trace_analysis.write_step_map(str(tmp_path)) is None
+        assert not os.listdir(tmp_path)
+        trace_analysis.record_step_scopes(step_hlo(FOUR_CHIPS))
+        assert trace_analysis.write_step_map("") is None
+        path = trace_analysis.write_step_map(str(tmp_path / "trace"))
+        assert path == str(tmp_path / "trace" / trace_analysis.STEP_MAP_FILE)
+        with open(path) as f:
+            kept = json.load(f)
+        assert kept["vocabulary"] == list(SCOPES)
+        assert kept["phases"] == list(trace_analysis.PHASES)
+        assert kept["instructions"]["fusion.13"] == [
+            "attn/qkv_proj", "backward", "reduce-scatter.fused"]
+        assert kept["inferred"] == ["async-collective-start.1"]
+        assert kept["tails"]["all-to-all.2"] == "closed_call/jvp()/split"
+        # a directory that cannot be made: nothing, and no exception
+        blocked = tmp_path / "file"
+        blocked.write_text("")
+        assert trace_analysis.write_step_map(str(blocked / "x")) is None
+    finally:
+        trace_analysis.record_step_scopes(before)
+
+
+def _reduced(rows, steps):
+    ms = 1e6
+    leaves = [(n, s * ms, e * ms) for n, s, e in rows]
+    return xplane.Reduced(0, [(a * ms, b * ms) for a, b in steps],
+                          (steps[0][0] * ms, steps[-1][0] * ms), leaves,
+                          [(n, e - s) for n, s, e in leaves], [])
+
+
+def test_the_tool_lays_a_trace_over_the_map(capsys):
+    """Two whole periods; in each the same six leaves, a gap of half a
+    millisecond between two of them and a name the map does not hold."""
+    step_map = json.loads(json.dumps(step_hlo(FOUR_CHIPS)["map"]))
+    one = [("async-collective-start.1", 0.0, 0.1), ("fusion.12", 0.1, 1.1),
+           ("async-collective-done.1", 1.1, 1.4), ("fusion.13", 1.9, 2.9),
+           ("all-to-all.2", 2.9, 3.0), ("stranger.1", 3.0, 3.1)]
+    rows = [(n, s + at, e + at) for at in (0.0, 10.0) for n, s, e in one]
+    t = trace_by_scope.join(
+        _reduced(rows, [(0.0, 5.0), (10.0, 15.0), (20.0, 25.0)]), step_map)
+    assert t["periods"] == 2
+    assert t["leaf_ms_a_step"] == pytest.approx(2.6)
+    assert t["busy_ms_a_step"] == pytest.approx(2.6)
+    by_scope = {r["scope"]: r for r in t["by_scope_and_phase"]}
+    assert by_scope["mlp"]["ms"] == pytest.approx(1.4)
+    assert by_scope["mlp"]["forward"] == pytest.approx(1.4)
+    assert by_scope["attn/qkv_proj"]["backward"] == pytest.approx(1.0)
+    assert by_scope[trace_by_scope.NO_SCOPE]["ms"] == pytest.approx(0.1)
+    assert t["by_phase"] == pytest.approx({
+        "forward": 1.5, "recompute": 0.0, "backward": 1.0, "update": 0.0,
+        "other": 0.0})
+    assert {(r["class"], r["phase"]): (r["ms"], r["events_a_step"])
+            for r in t["collectives"]} == pytest.approx({
+                ("all-gather.start", "forward"): (0.1, 1.0),
+                ("all-gather.done", "forward"): (0.3, 1.0),
+                ("overlapped", "forward"): (1.0, 1.0),
+                ("reduce-scatter.fused", "backward"): (1.0, 1.0),
+                ("all-to-all", "forward"): (0.1, 1.0)})
+    (unnamed,) = t["unnamed"]
+    assert unnamed["instruction"] == "all-to-all.2"
+    assert unnamed["op_name_tail"] == "closed_call/jvp()/split"
+    (gap,) = t["gaps"]      # the gap between two steps is not inside one
+    assert (gap["after"], gap["before"], gap["times"]) == (
+        "async-collective-done.1", "fusion.13", 2)
+    assert gap["mean_ms"] == pytest.approx(0.5)
+    assert gap["after_is"] == "mlp/forward/all-gather.done"
+    assert gap["before_is"] == "attn/qkv_proj/backward/reduce-scatter.fused"
+    assert t["not_in_the_map"] == [["stranger.1", pytest.approx(0.1)]]
+    trace_by_scope.print_tables(t)
+    out = capsys.readouterr().out
+    assert "0.500 ms x 2  after async-collective-done.1" in out
+    assert "NO instruction of the map" in out
+
+
+def test_the_tool_says_what_is_missing(tmp_path, capsys):
+    assert trace_by_scope.main([str(tmp_path)]) == 2
+    assert "no step_map.json" in capsys.readouterr().err
+    (tmp_path / trace_analysis.STEP_MAP_FILE).write_text("{}")
+    assert trace_by_scope.main([str(tmp_path)]) == 2
+    assert "no .xplane.pb" in capsys.readouterr().err

@@ -18,7 +18,16 @@ it prints
   ``op_name`` tail, with its count. A collective that XLA:TPU wrapped in a
   fusion (an async start / overlapped fusion / done triple, an
   ``all-reduce-scatter`` fusion) is counted once, in the computation that
-  calls the fusion, as ``<opcode>@<the fused computation's stem>``.
+  calls the fusion, as ``<opcode>@<the fused computation's stem>``;
+* the instructions that are events of a trace by the collective class the
+  program's own map gives them (``trace_analysis.step_hlo``: ``all-gather``,
+  ``reduce-scatter.fused``, ``overlapped``, ``all-gather.start`` / ``.done``)
+  and by phase and scope, in cycles: what ``tools/trace_by_scope.py`` lays
+  a trace over.
+
+Instruction names, opcodes, ``op_name``s and classes come from the program's
+one walk over the text (``trace_analysis.walk_hlo`` / ``step_hlo``); this
+file adds the shapes, cycles and replica groups of the lines it is handed.
 
 Estimates are the compiler's, not a measurement: they say which instruction
 a name in a device trace is and what it was lowered from, and they rank
@@ -40,14 +49,11 @@ sys.path.insert(0, ROOT)
 
 from hetu_galvatron_tpu.observability.trace_analysis import (  # noqa: E402
     COLLECTIVE_OPS,
+    step_hlo,
+    walk_hlo,
 )
 
-_COMPUTATION = re.compile(r"^(ENTRY )?%?([\w.\-]+) \(.*\) -> .*\{\s*$")
-_INSTRUCTION = re.compile(
-    r"^\s+(?:ROOT )?%?([\w.\-]+) = (\S+(?: \S+)*?) ([\w\-]+)\(")
-_OP_NAME = re.compile(r'op_name="([^"]*)"')
 _CYCLES = re.compile(r'"estimated_cycles":"?(\d+)')
-_CALLS = re.compile(r"calls=%?([\w.\-]+)")
 _GROUPS = re.compile(r"replica_groups=(\[[\d,]+\]<=\[[\d,]+\](?:T\([\d,]+\))?"
                      r"|\{[{}\d,]*\})")
 
@@ -66,30 +72,21 @@ def parse_hlo(text: str):
     op_name, cycles (int or None), replica_groups (str or None), calls (the
     fused computation a fusion runs, or None), done (the line is an async
     collective's done half)."""
-    out, cur = [], None
-    for line in text.splitlines():
-        m = _COMPUTATION.match(line)
-        if m:
-            cur = []
-            out.append((m.group(2), cur))
-            continue
-        if cur is None:
-            continue
-        m = _INSTRUCTION.match(line)
-        if not m:
-            continue
-        name, shape, opcode = m.groups()
-        shape = re.sub(r"\{[^{}]*\}", "", shape)   # layouts and tilings
-        op = _OP_NAME.search(line)
+    out, last = [], None
+    for comp, name, opcode, op_name, calls, line, _ in walk_hlo(text):
+        if comp != last:
+            out.append((comp, []))
+            last = comp
+        shape = line.split(" = ", 1)[1].split(f" {opcode}(", 1)[0]
         cyc = _CYCLES.search(line)
         grp = _GROUPS.search(line)
-        calls = _CALLS.search(line)
-        cur.append({
-            "calls": calls.group(1) if calls else None,
+        out[-1][1].append({
+            "calls": calls,
             "done": 'custom_call_target="AsyncCollectiveDone"' in line,
             "name": name, "stem": re.sub(r"[.\d]+$", "", name),
-            "opcode": opcode, "shape": shape,
-            "op_name": op.group(1) if op else "",
+            "opcode": opcode,
+            "shape": re.sub(r"\{[^{}]*\}", "", shape),  # layouts, tilings
+            "op_name": op_name,
             "cycles": int(cyc.group(1)) if cyc else None,
             "replica_groups": grp.group(1) if grp else None})
     return out
@@ -110,6 +107,7 @@ def report(text: str, top: int = 12):
     estimated cycle, the cycle sums and the collectives."""
     parsed = parse_hlo(text)
     owners = _owners(parsed)
+    classes = step_hlo(text, ())["map"]["instructions"]
     cycles = {c: (collections.Counter(), collections.Counter())
               for c, _ in parsed}
     coll = {c: collections.Counter() for c, _ in parsed}
@@ -143,12 +141,23 @@ def report(text: str, top: int = 12):
         kinds = collections.Counter()
         for (op, _, _, _), n in coll[comp].items():
             kinds[op] += n
+        # what the program's map says of this computation's instructions
+        # (none for a fusion's or a reduction's computation)
+        by_class, by_phase = collections.Counter(), collections.Counter()
+        for ins in instrs:
+            scope, phase, cls = classes.get(ins["name"], (None, None, None))
+            if cls:
+                by_class[f"{cls} {phase}"] += 1
+            if phase and ins["cycles"]:
+                by_phase[f"{phase} {scope or '(no scope)'}"] += ins["cycles"]
         comps.append({
             "computation": comp, "instructions": len(instrs),
             "estimated_cycles": sum(by_stem.values()),
             "cycles_by_stem": by_stem.most_common(top),
             "cycles_by_op_name": by_tail.most_common(top),
             "collective_counts": dict(kinds),
+            "collective_classes": dict(sorted(by_class.items())),
+            "cycles_by_phase_and_scope": by_phase.most_common(3 * top),
             "collectives": [
                 {"op": op, "shape": shape, "replica_groups": grp,
                  "op_name": tail, "count": n}
@@ -163,8 +172,13 @@ def print_report(rep, file=None):
         print(f"== {c['computation']}: {c['instructions']} instructions, "
               f"{c['estimated_cycles'] / 1e6:.1f} M estimated cycles, "
               f"collectives {c['collective_counts']}", file=file)
+        if c["collective_classes"]:
+            print(f"  the map's collective classes: "
+                  f"{c['collective_classes']}", file=file)
         for title, rows in (("by stem", c["cycles_by_stem"]),
-                            ("by op_name", c["cycles_by_op_name"])):
+                            ("by op_name", c["cycles_by_op_name"]),
+                            ("by the map's phase and scope",
+                             c["cycles_by_phase_and_scope"])):
             if rows:
                 print(f"  cycles {title}:", file=file)
             for key, cyc in rows:
