@@ -262,6 +262,47 @@ def _expert_act(hproj: jax.Array, cfg: ModelArgs,
     return act(hproj)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _grouped_matmul(rows: jax.Array, weights: jax.Array,
+                    group_sizes: jax.Array, out_dtype) -> jax.Array:
+    """The expert layer's grouped matmul: sorted ``rows`` [M, K] through
+    ``weights`` [G, K, N] by ``group_sizes`` [G]. ``lax.ragged_dot``
+    accumulates in float32 and writes once, in ``out_dtype``: the dtype the
+    product's first consumer reads. The compute dtype is the operands' own.
+
+    The backward pass keeps both transposed products in the compute dtype:
+    the cotangent is rounded to it going in (as a dense matmul's is at
+    default precision, and as the kernel does with a float32 operand
+    anyway), and the gradients to the rows and to the weights come out in
+    it, which is what the cast behind the one and the transpose of
+    ``weight_view`` behind the other round them to. Plain reverse mode of a
+    product asked for in float32 makes both of them mixed bfloat16 x float32
+    kernels that write float32 for the next instruction to round. At
+    float32 both passes are plain reverse mode's, number for number."""
+    return jax.lax.ragged_dot(rows, weights, group_sizes,
+                              preferred_element_type=out_dtype)
+
+
+def _grouped_matmul_fwd(rows, weights, group_sizes, out_dtype):
+    return (_grouped_matmul(rows, weights, group_sizes, out_dtype),
+            (rows, weights, group_sizes))
+
+
+def _grouped_matmul_bwd(out_dtype, saved, g):
+    rows, weights, group_sizes = saved
+    # JAX's own transposes of the product in the compute dtype: the modes
+    # and dimension numbers plain reverse mode would have emitted
+    product = functools.partial(jax.lax.ragged_dot, group_sizes=group_sizes,
+                                preferred_element_type=rows.dtype)
+    g = g.astype(rows.dtype)
+    d_weights, = jax.linear_transpose(lambda w: product(rows, w), weights)(g)
+    d_rows, = jax.linear_transpose(lambda r: product(r, weights), rows)(g)
+    return d_rows, d_weights, None
+
+
+_grouped_matmul.defvjp(_grouped_matmul_fwd, _grouped_matmul_bwd)
+
+
 def _capacity_dispatch(
     p: Params, xt: jax.Array, topk_idx: jax.Array, w: jax.Array,
     cfg: ModelArgs, compute_dtype, capacity_factor: Optional[float],
@@ -326,15 +367,11 @@ def _dropless_dispatch(
         xs = xt[tok_sorted].astype(compute_dtype)  # [T*K, H]
         group_sizes = jnp.bincount(eid, length=E).astype(jnp.int32)
     with jax.named_scope("moe/experts"):
-        hproj = jax.lax.ragged_dot(xs,
-                                   M.weight_view(p["win"], compute_dtype),
-                                   group_sizes,
-                                   preferred_element_type=jnp.float32)
+        hproj = _grouped_matmul(xs, M.weight_view(p["win"], compute_dtype),
+                                group_sizes, compute_dtype)
         hproj = _expert_act(hproj, cfg, compute_dtype)
-        ys = jax.lax.ragged_dot(hproj,
-                                M.weight_view(p["wout"], compute_dtype),
-                                group_sizes,
-                                preferred_element_type=jnp.float32)
+        ys = _grouped_matmul(hproj, M.weight_view(p["wout"], compute_dtype),
+                             group_sizes, jnp.float32)
     with jax.named_scope("moe/combine"):
         ws = w.reshape(T * K)[order]
         return jnp.zeros((T, H), jnp.float32).at[tok_sorted].add(
@@ -368,13 +405,11 @@ def _sorted_rows_mlp(rows: int, cfg: ModelArgs, compute_dtype, xt, win, wout,
     with jax.named_scope("moe/dispatch"):
         xs = jnp.where(mine, xt[tok].astype(compute_dtype), 0)
     with jax.named_scope("moe/experts"):
-        hproj = jax.lax.ragged_dot(xs, M.weight_view(win, compute_dtype),
-                                   group_sizes,
-                                   preferred_element_type=jnp.float32)
+        hproj = _grouped_matmul(xs, M.weight_view(win, compute_dtype),
+                                group_sizes, compute_dtype)
         hproj = _expert_act(hproj, cfg, compute_dtype)
-        ys = jax.lax.ragged_dot(hproj, M.weight_view(wout, compute_dtype),
-                                group_sizes,
-                                preferred_element_type=jnp.float32)
+        ys = _grouped_matmul(hproj, M.weight_view(wout, compute_dtype),
+                             group_sizes, jnp.float32)
     with jax.named_scope("moe/combine"):
         return jnp.zeros(xt.shape, jnp.float32).at[tok].add(
             jnp.where(mine, ys * ws[:rows, None], 0.0))

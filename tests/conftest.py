@@ -201,3 +201,57 @@ def mesh8(cpu_devices):
     from jax.sharding import Mesh
 
     return Mesh(np.array(cpu_devices).reshape(8), ("devices",))
+
+
+@pytest.fixture
+def grouped_matmul_as_before_pr38(monkeypatch):
+    """Call it to put the expert layer's grouped matmul back to what it was
+    before PR 38 for the rest of the test: ``ragged_dot`` to float32
+    whatever reads it, plain reverse mode behind it."""
+    def patch():
+        import jax.numpy as jnp
+        from hetu_galvatron_tpu.models import moe
+
+        monkeypatch.setattr(
+            moe, "_grouped_matmul",
+            lambda rows, weights, group_sizes, out_dtype: jax.lax.ragged_dot(
+                rows, weights, group_sizes,
+                preferred_element_type=jnp.float32))
+    return patch
+
+
+@pytest.fixture
+def forward_and_loss_as_before_pr38(grouped_matmul_as_before_pr38):
+    """``check(cfg, params, dtype)``: a stack with expert layers gives, with
+    the grouped matmuls writing the dtype their first consumer reads (PR
+    38), bit for bit the logits and the loss that ``ragged_dot`` to float32
+    and a cast behind it gave; at float32 the gradients too, and at bfloat16
+    not all of them (the cotangent of the weighted expert outputs is rounded
+    going into the grouped matmuls, as a dense matmul's is)."""
+    def check(cfg, params, dtype):
+        import jax.numpy as jnp
+        import numpy as np
+        from hetu_galvatron_tpu.models.builder import (
+            causal_lm_loss,
+            forward_causal_lm,
+        )
+        from hetu_galvatron_tpu.runtime.dataloader import make_batch
+
+        dt = jnp.dtype(dtype)
+        batch = jax.tree.map(jnp.asarray, make_batch(
+            np.random.RandomState(3).randint(0, 64, (2, 17))))
+
+        def run():
+            logits = forward_causal_lm(params, batch["tokens"], cfg,
+                                       compute_dtype=dt)
+            loss, grads = jax.value_and_grad(lambda p: causal_lm_loss(
+                p, batch, cfg, compute_dtype=dt))(params)
+            return logits, loss, jax.tree.leaves(grads)
+
+        logits, loss, grads = run()
+        grouped_matmul_as_before_pr38()
+        plogits, ploss, pgrads = run()
+        assert np.array_equal(logits, plogits) and np.array_equal(loss, ploss)
+        same = [np.array_equal(g, pg) for g, pg in zip(grads, pgrads)]
+        assert all(same) if dtype == "float32" else not all(same)
+    return check

@@ -863,3 +863,14 @@ def test_a_conv_block_refuses_packed_documents_and_sigmoid_an_aux_loss():
     with pytest.raises(NotImplementedError, match="dropless dispatcher"):
         init_causal_lm(jax.random.key(0), cfg.model_copy(update=dict(
             moe_held_experts=2, moe_dispatcher="capacity")))
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_a_held_shares_forward_and_loss_are_the_parents_formulation(
+        dtype, forward_and_loss_as_before_pr38):
+    """The stack with four expert layers that each hold 2 of 8 experts: both
+    bodies of ``_held_dispatch`` in the program, the one the count picks
+    taken."""
+    cfg = ModelArgs(**{**TINY, "moe_held_experts": 2,
+                       "moe_first_held_expert": 2})
+    forward_and_loss_as_before_pr38(cfg, _seeded(cfg), dtype)

@@ -430,3 +430,148 @@ def test_per_layer_aux_tracker_in_train_metrics(cpu_devices):
     prof = RuntimeProfiler(args, world_size=8, rank=0)
     line = prof.iteration_log(0, metrics)
     assert "moe[layer1]" in line and "imb" in line
+
+
+# the grouped matmuls' dtypes, forward and backward (PR 38)
+# ---------------------------------------------------------------------------
+
+# 8 experts, 2 a token, 64 slots; a layer that holds experts 2 and 3 has a
+# short buffer of 32 rows. H = 32, F = 24, so that the three matrices a
+# grouped matmul can read ([*, H, 2F], [*, F, H] and their transposes) and
+# its three results' widths (2F, H, F) tell the six of a layer apart
+GROUPED_CFG = ModelArgs(
+    model_type="moe", hidden_size=32, num_hidden_layers=2,
+    num_attention_heads=4, vocab_size=64, max_position_embeddings=32,
+    seq_length=16, hidden_act="swiglu", normalization="rmsnorm",
+    position_embedding_type="rope", add_bias_linear=False,
+    add_qkv_bias=False, make_vocab_size_divisible_by=1, ffn_hidden_size=48,
+    moe_ffn_hidden_size=24, num_experts=8, moe_topk=2,
+    moe_score_function="sigmoid", moe_router_enable_expert_bias=True,
+    moe_dispatcher="dropless", moe_aux_loss_coeff=0.0)
+# mode -> (experts held, the selection bias on the held pair, the body taken)
+GROUPED_MODES = {"dropless": (0, 0.0, None),
+                 "held_short_body": (2, 0.0, 1.0),
+                 "held_full_body": (2, 10.0, 0.0)}
+
+
+def _grouped_case(mode):
+    """(cfg, dispatch(win, wout, xt, w, dtype) -> (y [T, H] f32, stats),
+    operands) of one mode: the expert layer below the router, as a function
+    of the four things it is differentiated by. Weights and tokens are
+    bfloat16 values held in float32, so that a bfloat16 layer and a float32
+    one read the same numbers."""
+    from hetu_galvatron_tpu.models import moe
+
+    held, bias, _ = GROUPED_MODES[mode]
+    cfg = GROUPED_CFG.model_copy(update=dict(
+        moe_held_experts=held, moe_first_held_expert=2 if held else 0))
+    p = _moe_params(cfg, seed=5)
+    p["expert_bias"] = jnp.zeros(8).at[2:4].set(bias)
+    rounded = lambda a: a.astype(jnp.bfloat16).astype(jnp.float32)  # noqa: E731
+    xt = rounded(jax.random.normal(jax.random.key(8), (32, 32)))
+    topk_idx, w, _, _ = moe.route_tokens(p, xt, cfg, jnp.float32)
+
+    def dispatch(win, wout, xt, w, dtype):
+        q = {**p, "win": win, "wout": wout}
+        if held:
+            return moe._held_dispatch(q, xt.astype(dtype), topk_idx, w, cfg,
+                                      dtype)
+        return moe._dropless_dispatch(q, xt.astype(dtype), topk_idx, w, cfg,
+                                      dtype), {}
+    return cfg, dispatch, (rounded(p["win"] * 8), rounded(p["wout"] * 8), xt,
+                           w)
+
+
+def _grouped_matmuls(jaxpr, found=None):
+    """(lhs aval, rhs aval, result aval, dimension numbers) of every
+    ``ragged_dot_general`` of a jaxpr, in order, conditionals' bodies and
+    custom derivatives included."""
+    found = [] if found is None else found
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "ragged_dot_general":
+            found.append((*(v.aval for v in eqn.invars[:2]),
+                          eqn.outvars[0].aval,
+                          str(eqn.params["ragged_dot_dimension_numbers"])))
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) else [value]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    _grouped_matmuls(sub, found)
+    return found
+
+
+def _sum_sq_and_grads(dispatch, dtype):
+    def loss(*ops):
+        y, stats = dispatch(*ops, dtype)
+        return jnp.sum(jnp.square(y)), (y, stats)
+    return jax.value_and_grad(loss, argnums=(0, 1, 2, 3), has_aux=True)
+
+
+@pytest.mark.parametrize("mode", list(GROUPED_MODES))
+def test_grouped_matmuls_read_and_write_the_compute_dtype(
+        mode, grouped_matmul_as_before_pr38):
+    """What stands in for a counter of engagement: the gradient of the
+    expert layer at bfloat16 holds no grouped matmul with a float32 operand,
+    and none with a float32 result but the forward ``wout`` product (rows
+    [*, F] through [*, F, H]; a layer with two bodies has it in each, forward
+    and recomputed). At float32 the grouped matmuls are the parent's, in the
+    parent's order, and so are the loss and the four gradients, bit for
+    bit, on the body the mode takes."""
+    cfg, dispatch, operands = _grouped_case(mode)
+    held, _, short = GROUPED_MODES[mode]
+    F, H = cfg.moe_ffn_hidden_size, cfg.hidden_size
+    grad_of = lambda dt: _sum_sq_and_grads(dispatch, dt)  # noqa: E731
+
+    calls = _grouped_matmuls(jax.make_jaxpr(grad_of(jnp.bfloat16))(
+        *operands).jaxpr)
+    # forward 2 and 4 transposes; a held share: two bodies forward, and two
+    # backward that each recompute their 2 and transpose each twice
+    assert len(calls) == (16 if held else 6)
+    forward_wout = 0
+    for lhs, rhs, out, _ in calls:
+        assert lhs.dtype == rhs.dtype == jnp.bfloat16, (lhs, rhs, out)
+        is_forward_wout = lhs.shape[1] == F and rhs.shape[1:] == (F, H)
+        forward_wout += is_forward_wout
+        assert out.dtype == (jnp.float32 if is_forward_wout
+                             else jnp.bfloat16), (lhs, rhs, out)
+    assert forward_wout == (4 if held else 1)
+
+    mine = jax.make_jaxpr(grad_of(jnp.float32))(*operands)
+    (loss, (_, stats)), grads = jax.jit(grad_of(jnp.float32))(*operands)
+    if held:
+        assert float(stats["short_dispatch"]) == short
+    grouped_matmul_as_before_pr38()
+    parents = jax.make_jaxpr(grad_of(jnp.float32))(*operands)
+    assert [tuple(map(str, c)) for c in _grouped_matmuls(mine.jaxpr)] == \
+        [tuple(map(str, c)) for c in _grouped_matmuls(parents.jaxpr)]
+    (ploss, _), pgrads = jax.jit(grad_of(jnp.float32))(*operands)
+    assert np.array_equal(loss, ploss)
+    for g, pg in zip(grads, pgrads):
+        assert g.dtype == pg.dtype == jnp.float32
+        assert np.array_equal(g, pg)
+
+
+@pytest.mark.parametrize("mode", list(GROUPED_MODES))
+def test_bf16_expert_layer_against_the_parents_and_the_f32_layer(
+        mode, grouped_matmul_as_before_pr38):
+    """At bfloat16 the layer's output is the parent's bit for bit (``hproj``
+    rounded once, in the kernel's epilogue and not in a pass behind it; on
+    the CPU both are a float32 product rounded), and the gradients to the
+    tokens, ``win``, ``wout`` and the combine weights lie within the
+    flash kernels' rule for a bfloat16 path against float32 (two roundings
+    of the reference's largest magnitude) of the float32 layer's, as the
+    parent's do: the one value that is newly rounded is the cotangent of
+    ``ys``."""
+    _, dispatch, operands = _grouped_case(mode)
+    grad_of = lambda dt: jax.jit(_sum_sq_and_grads(dispatch, dt))  # noqa: E731
+    (_, (y, _)), grads = grad_of(jnp.bfloat16)(*operands)
+    (_, (y32, _)), grads32 = grad_of(jnp.float32)(*operands)
+    grouped_matmul_as_before_pr38()
+    (_, (py, _)), pgrads = grad_of(jnp.bfloat16)(*operands)
+    assert np.array_equal(y, py) and not np.array_equal(y, y32)
+    for name, g, pg, g32 in zip(("win", "wout", "xt", "w"), grads, pgrads,
+                                grads32):
+        assert g.dtype == jnp.float32
+        tol = 2 * 2.0 ** -7 * np.abs(g32).max()
+        assert np.abs(g - g32).max() <= tol, (name, np.abs(g - g32).max(), tol)
+        assert np.abs(pg - g32).max() <= tol, name

@@ -205,6 +205,16 @@ def test_program_matches_plain_reference(case):
         assert worst > 1e-3, worst
 
 
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_forward_and_loss_are_the_parents_formulation(
+        dtype, forward_and_loss_as_before_pr38):
+    """The forward pass is not changed by PR 38
+    (``tools/olmoe_forward_check.py`` reads what it read)."""
+    cfg = ModelArgs(**TINY)
+    forward_and_loss_as_before_pr38(
+        cfg, init_causal_lm(jax.random.key(7), cfg)[0], dtype)
+
+
 def test_router_terms_are_in_the_reference_loss():
     """The reference's loss is cross-entropy PLUS both router terms: with
     the coefficients at zero it is smaller by what the program's tracker
