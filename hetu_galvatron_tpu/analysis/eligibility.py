@@ -145,8 +145,34 @@ MAMBA_REASON = ("mamba block: the state-space block's projections are not "
                 "cut for the ring all-gather / reduce-scatter matmuls, and "
                 "its recurrence runs over the whole sequence on one shard")
 
-# why a block that does not attend keeps its matmuls on GSPMD, by mixer kind
-MIXER_OVERLAP_REASON = {"conv": CONV_REASON, "mamba": MAMBA_REASON}
+LATENT_REASON = ("latent_attention block: the low-rank q and kv projections "
+                 "and their norms are not cut for the ring all-gather / "
+                 "reduce-scatter matmuls (ops/overlap.py takes one fused qkv "
+                 "product)")
+
+# why a block whose mixer is not plain attention keeps its matmuls on GSPMD,
+# by mixer kind
+MIXER_OVERLAP_REASON = {"conv": CONV_REASON, "mamba": MAMBA_REASON,
+                        "latent_attention": LATENT_REASON}
+
+
+def _uncut_mixer_reason(cfg: Any, layers: Any, mixer: str, name: str,
+                        why: str) -> Optional[str]:
+    """The first block of kind ``mixer`` whose plan cuts heads (tp) or
+    sequence (cp, Ulysses), said with ``why`` it runs uncut; None when there
+    is none."""
+    kinds = cfg.block_kinds(len(layers))
+    for i, (s, (kind, _)) in enumerate(zip(layers, kinds)):
+        if kind != mixer:
+            continue
+        cut = [f"{axis}={deg}" for axis, deg in (
+            ("tp", s.tp_size), ("cp", s.cp_size)) if deg > 1]
+        if cut:
+            return (f"block {i} is a {mixer} block and its plan has "
+                    f"{', '.join(cut)}"
+                    + (" (Ulysses)" if s.sp and s.tp_size > 1 else "")
+                    + f": {name} runs with tp=1 and cp=1 ({why})")
+    return None
 
 
 def mamba_plan_reason(cfg: Any, layers: Any) -> Optional[str]:
@@ -156,21 +182,40 @@ def mamba_plan_reason(cfg: Any, layers: Any) -> Optional[str]:
     replicated is not written), and its convolution and recurrence run over
     the whole sequence, so a block whose plan cuts the sequence (cp,
     Ulysses) would carry a state across shards it cannot see."""
-    kinds = cfg.block_kinds(len(layers))
-    for i, (s, (mixer, _)) in enumerate(zip(layers, kinds)):
-        if mixer != "mamba":
-            continue
-        cut = [f"{name}={deg}" for name, deg in (
-            ("tp", s.tp_size), ("cp", s.cp_size)) if deg > 1]
-        if cut:
-            return (f"block {i} is a mamba block and its plan has "
-                    f"{', '.join(cut)}"
-                    + (" (Ulysses)" if s.sp and s.tp_size > 1 else "")
-                    + ": the state-space block runs with tp=1 and cp=1 "
-                    "(its heads are not cut over the tp axis and its "
-                    "recurrence needs the whole sequence on one shard); "
-                    "use dp / ZeRO for this model")
-    return None
+    return _uncut_mixer_reason(
+        cfg, layers, "mamba", "the state-space block",
+        "its heads are not cut over the tp axis and its recurrence needs "
+        "the whole sequence on one shard); use dp / ZeRO for this model")
+
+
+def latent_plan_reason(cfg: Any, layers: Any) -> Optional[str]:
+    """Why a plan cannot run this model's latent-attention blocks; None when
+    it can (or the model has none). The block's projections carry no axis
+    that tensor parallelism shards (heads on the tp axis behind a replicated
+    latent is not written), and the ring and Ulysses cores take neither a
+    softmax scale nor a value width of their own, so a block whose plan cuts
+    heads or sequence is refused here, by name, and not inside a trace."""
+    return _uncut_mixer_reason(
+        cfg, layers, "latent_attention", "latent attention",
+        "its low-rank projections are not cut over the tp axis, and the "
+        "ring / Ulysses cores take no softmax scale and no value width of "
+        "their own); use dp / ZeRO and ep for this model")
+
+
+def residual_streams_reason(cfg: Any, what: str) -> Optional[str]:
+    """Why ``what`` (an engine that carries ONE [B, S, H] stream between
+    blocks or stages, or a loss with one prediction depth) cannot take a
+    model whose residual is several streams or whose loss has a further
+    prediction depth; None for every other model."""
+    stated = [f"{k}={getattr(cfg, k)}" for k, plain in (
+        ("hc_mult", 1), ("num_nextn_predict_layers", 0))
+        if getattr(cfg, k, plain) != plain]
+    if not stated:
+        return None
+    return (f"{what} hands one [B, S, H] residual stream from block to "
+            f"block and predicts one token a position; this model states "
+            f"{', '.join(stated)}, which only the pp=1 training path "
+            "(builder.forward_causal_lm / causal_lm_loss) runs")
 
 
 def own_multipliers_reason(cfg: Any, what: str) -> Optional[str]:

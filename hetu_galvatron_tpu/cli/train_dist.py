@@ -181,11 +181,13 @@ def train(args) -> Dict[str, Any]:
         use_flash = flash_kernel_runs(cfg.use_flash_attn, state.devices)
         # a block that does not attend reports its own operator, so that
         # "every core is flash" stays a statement about the blocks that do
+        from hetu_galvatron_tpu.models.modules import ATTENDING_MIXERS
+
         kinds = cfg.block_kinds(len(hpc.layers))
         operators = {"conv": "short_conv", "mamba": "mamba2"}
         attention_cores = [
             attention_core(s.cp_size > 1, bool(s.sp and s.tp_size > 1),
-                           use_flash) if mixer == "full_attention"
+                           use_flash) if mixer in ATTENDING_MIXERS
             else operators[mixer]
             for s, (mixer, _) in zip(hpc.layers, kinds)]
         state.log("attention cores: " + ", ".join(

@@ -47,10 +47,14 @@ class ModelArgs(BaseModel):
     # state-space blocks carry the order, Granite-4.0-H)
     position_embedding_type: Literal["learned", "rope", "nope"] = "learned"
     rope_theta: float = 10000.0
-    # HF-style rope_scaling dict: {"rope_type": "linear"|"llama3",
+    # HF-style rope_scaling dict: {"rope_type": "linear"|"llama3"|"yarn",
     # "factor": ..., and for llama3 "low_freq_factor"/"high_freq_factor"/
     # "original_max_position_embeddings"} — llama-3.1+ checkpoints need it
-    # for >8k contexts (BASELINE milestone 5)
+    # for >8k contexts (BASELINE milestone 5). "yarn" (Peng et al.,
+    # arXiv:2309.00071, as DeepSeek-V2/V3 publish it): "beta_fast",
+    # "beta_slow", "original_max_position_embeddings", and "mscale" /
+    # "mscale_all_dim", whose ratio scales cos and sin and whose second
+    # enters a latent-attention block's softmax scale squared
     rope_scaling: Optional[Dict[str, Any]] = None
     # multimodal rope (qwen2-vl style; reference rotary_pos_embedding.py):
     # the head_dim//2 frequency dims split into per-axis sections
@@ -106,7 +110,10 @@ class ModelArgs(BaseModel):
     # mlp.gate / mlp.experts.{e}.{gate,up,down}_proj; "lfm2" =
     # feed_forward.gate / feed_forward.experts.{e}.w1,w3,w2 and
     # feed_forward.expert_bias
-    moe_hf_layout: Literal["mixtral", "olmoe", "lfm2"] = "mixtral"
+    # "deepseek" = olmoe's names, mlp.gate.e_score_correction_bias for the
+    # selection bias and mlp.shared_experts.{gate,up,down}_proj for the
+    # shared expert: the one layout with a slot for it
+    moe_hf_layout: Literal["mixtral", "olmoe", "lfm2", "deepseek"] = "mixtral"
     # RMSNorm over the WHOLE projected q and k widths (all heads together,
     # one learned scale each), after the qkv product and before the split
     # into heads and RoPE (OLMoE; HF ``self_attn.{q,k}_norm``)
@@ -118,12 +125,15 @@ class ModelArgs(BaseModel):
     # THE per-layer description (:meth:`block_kinds`) comes from these two
     # published keys and ``moe_layer_freq``. ``layer_types``: each block's
     # mixer, "full_attention", "conv" (a gated short convolution,
-    # modules.apply_short_conv) or "mamba" (a Mamba-2 state-space block,
-    # modules.apply_mamba2); None = every block attends.
+    # modules.apply_short_conv), "mamba" (a Mamba-2 state-space block,
+    # modules.apply_mamba2) or "latent_attention" (DeepSeek-V2/V3's
+    # low-rank q and kv projections, modules.apply_latent_attention);
+    # None = every block attends.
     # ``num_dense_layers``: so many leading blocks of an expert model keep
     # a dense MLP of ``ffn_hidden_size``
     layer_types: Optional[
-        List[Literal["full_attention", "conv", "mamba"]]] = None
+        List[Literal["full_attention", "conv", "mamba",
+                     "latent_attention"]]] = None
     num_dense_layers: int = 0
     conv_L_cache: int = 3   # taps of a conv block's depthwise convolution
     conv_bias: bool = False
@@ -132,6 +142,9 @@ class ModelArgs(BaseModel):
     # bias, the renormalisation and its epsilon
     moe_score_function: Literal["softmax", "sigmoid"] = "softmax"
     moe_routed_scaling_factor: float = 1.0
+    # what a sigmoid router adds to the sum of the chosen scores before it
+    # divides by it: LFM2's 1e-6; DeepSeek-V3's 1e-20
+    moe_norm_topk_eps: float = 1e-6
     # an expert layer that is told which experts it holds: the router keeps
     # its ``num_experts`` outputs and its ``moe_topk`` a token, the layer's
     # weights are ``[moe_held_experts, ...]`` and it computes exactly the
@@ -169,11 +182,39 @@ class ModelArgs(BaseModel):
     embedding_multiplier: float = 1.0
     residual_multiplier: float = 1.0
     logits_scaling: float = 1.0
+    # a "latent_attention" block (HF ``DeepseekV3Attention``): q through a
+    # ``q_lora_rank`` bottleneck with its RMSNorm, k and v through one of
+    # ``kv_lora_rank``; a head's query and key are ``qk_nope_head_dim``
+    # values without positions beside ``qk_rope_head_dim`` rotated ones
+    # (the rotated key one for all heads), its value ``v_head_dim`` wide
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # the residual as ``hc_mult`` streams [B, S, hc_mult, H], mixed around
+    # every sub-layer by maps that depend on the token (manifold-constrained
+    # hyper-connections, arXiv:2512.24880; modules.residual): the
+    # stream-to-stream map is made doubly stochastic by ``hc_sinkhorn_iters``
+    # row-then-column normalisations with ``hc_eps`` in the divisors, from
+    # values clipped to [hc_res_clamp_min, hc_res_clamp_max]. 1 = x + f(x)
+    hc_mult: int = 1
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    hc_res_clamp_min: float = -30.0
+    hc_res_clamp_max: float = 30.0
+    # multi-token prediction (DeepSeek-V3 2.2): so many further prediction
+    # depths, each one more block fed the previous depth's hidden states and
+    # the next token's embedding, sharing embedding and head; the loss adds
+    # ``mtp_loss_coeff`` times the mean of their cross-entropies. 0 or 1
+    num_nextn_predict_layers: int = 0
+    mtp_loss_coeff: float = 0.3
 
     def block_kinds(self, n: Optional[int] = None
                     ) -> Tuple[Tuple[str, str], ...]:
         """The one per-layer description of a decoder stack: for each block
-        its mixer kind ("full_attention", "conv", "mamba") and its
+        its mixer kind ("full_attention", "conv", "mamba",
+        "latent_attention") and its
         feed-forward kind ("dense", "experts"). The builder, the exporter, the launcher's
         report and every engine's refusal read this and nothing else.
         ``n``: the blocks a plan lists where that is not
@@ -224,6 +265,17 @@ class ModelArgs(BaseModel):
         if self.head_dim_override is not None:
             return self.head_dim_override
         return self.hidden_size // self.num_attention_heads
+
+    @property
+    def qk_head_dim(self) -> int:
+        """A latent-attention head's query / key width."""
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    @property
+    def rope_dim(self) -> int:
+        """The width RoPE rotates: a latent-attention model's
+        ``qk_rope_head_dim``, else the whole head."""
+        return self.qk_rope_head_dim or self.head_dim
 
     @property
     def padded_vocab_size(self) -> int:

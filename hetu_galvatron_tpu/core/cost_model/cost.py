@@ -947,9 +947,29 @@ def model_flops_per_token(model: Any, seq_length: Optional[int] = None
         mixer["mamba"] = (
             2 * h * (inner + model.mamba_conv_dim + model.mamba_n_heads)
             + 2 * inner * h + 4 * inner * state)
-    fwd = sum(mixer[m] + (experts_ff if ff == "experts" else dense_ff)
-              for m, ff in kinds)
-    fwd += 2 * h * model.padded_vocab_size  # LM head
+    if getattr(model, "q_lora_rank", 0):
+        # latent attention: the five low-rank projections as they are, and
+        # the two batched matmuls over q/k of qk_head_dim and v of v_head_dim
+        nq, rq, rkv = (model.num_attention_heads, model.q_lora_rank,
+                       model.kv_lora_rank)
+        qk, dv = model.qk_head_dim, model.v_head_dim
+        mixer["latent_attention"] = (
+            2 * (h * rq + rq * nq * qk + h * (rkv + model.qk_rope_head_dim)
+                 + rkv * nq * (model.qk_nope_head_dim + dv) + nq * dv * h)
+            + 2 * s * nq * (qk + dv))
+    streams = getattr(model, "hc_mult", 1)
+    # a block's two residual maps over several streams (phi products)
+    maps = (2 * 2 * streams * h * (2 * streams + streams * streams)
+            if streams > 1 else 0)
+    per_block = [mixer[m] + maps
+                 + (experts_ff if ff == "experts" else dense_ff)
+                 for m, ff in kinds]
+    head = 2 * h * model.padded_vocab_size  # LM head
+    fwd = sum(per_block) + head
+    if getattr(model, "num_nextn_predict_layers", 0):
+        # one further prediction depth: eh_proj, one more block of the last
+        # block's kind, the head again
+        fwd += 2 * 2 * h * h + per_block[-1] + head
     return 3.0 * fwd
 
 

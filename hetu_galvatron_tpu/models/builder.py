@@ -81,7 +81,62 @@ def init_causal_lm(key: jax.Array, cfg: ModelArgs) -> Tuple[Params, Params]:
         "prenorm": prenorm_a,
         "head": head_a,
     }
+    if cfg.num_nextn_predict_layers:
+        # drawn from a key of its own, so the stack's leaves are what they
+        # are without it
+        params["mtp"], axes["mtp"] = init_mtp(
+            jax.random.fold_in(key, n + 2), cfg)
     return params, axes
+
+
+def mtp_block_kind(cfg: ModelArgs) -> Tuple[str, str]:
+    """The (mixer, feed-forward) kind of the multi-token-prediction block:
+    the stack's last block's (DeepSeek-V3 2.2: one more block of the
+    model's own kind)."""
+    return cfg.block_kinds()[-1]
+
+
+def init_mtp(key: jax.Array, cfg: ModelArgs) -> Tuple[Params, Params]:
+    """One further prediction depth (DeepSeek-V3 2.2): ``enorm`` / ``hnorm``
+    over the next token's embedding and the stack's output, ``eh_proj`` [2H,
+    H] over the two side by side (embedding first), one more block
+    (``layer``) and its ``norm``; embedding and head are the model's."""
+    from hetu_galvatron_tpu.models.moe import init_moe_decoder_layer
+
+    if cfg.num_nextn_predict_layers != 1:
+        raise NotImplementedError(
+            f"model.num_nextn_predict_layers={cfg.num_nextn_predict_layers}:"
+            " one further prediction depth is written")
+    if cfg.post_norm or cfg.model_type in ("t5", "bert"):
+        raise NotImplementedError(
+            "multi-token prediction is written for pre-norm causal stacks")
+    k1, k2 = jax.random.split(key)
+    mixer, ff = mtp_block_kind(cfg)
+    lp, la = (init_moe_decoder_layer if ff == "experts"
+              else M.init_decoder_layer)(k2, cfg, mixer)
+    norms = [M.init_norm(cfg) for _ in range(3)]
+    h = cfg.hidden_size
+    return (
+        {"enorm": norms[0][0], "hnorm": norms[1][0],
+         "eh_proj": M._normal(k1, (2 * h, h), 0.02), "layer": lp,
+         "norm": norms[2][0]},
+        {"enorm": norms[0][1], "hnorm": norms[1][1],
+         "eh_proj": ("mtp_in", "embed"), "layer": la, "norm": norms[2][1]},
+    )
+
+
+def _block_fn(cfg: ModelArgs, kind: Tuple[str, str], kwargs: Dict[str, Any],
+              remat: bool):
+    """``fn(block params, x) -> (x, aux loss, router stats)`` of one block
+    of ``kind`` with its keyword arguments, rematerialized where asked."""
+    from hetu_galvatron_tpu.models.moe import apply_moe_decoder_layer
+
+    if kind[1] == "experts":
+        fn = lambda p, h: apply_moe_decoder_layer(p, h, cfg, **kwargs)
+    else:
+        fn = lambda p, h: (M.apply_decoder_layer(p, h, cfg, **kwargs),
+                           jnp.zeros((), jnp.float32), {})
+    return M.remat(fn, cfg) if remat else fn
 
 
 def forward_causal_lm(
@@ -99,8 +154,13 @@ def forward_causal_lm(
     position_ids: Optional[jax.Array] = None,
     segment_ids: Optional[jax.Array] = None,
     mrope_position_ids: Optional[jax.Array] = None,
+    mtp_labels: Optional[jax.Array] = None,
 ) -> jax.Array:
     """tokens [B, S] -> logits [B, S, V].
+
+    ``mtp_labels`` [B, S] (the token after each position; a model with
+    ``params["mtp"]``): also run the further prediction depth
+    (:func:`forward_mtp`) and return (logits, aux, moe stats, mtp logits).
 
     ``dropout_rng`` (training only) enables cfg.attention_dropout /
     cfg.hidden_dropout; ``None`` (the default) is eval semantics — dropout
@@ -120,8 +180,6 @@ def forward_causal_lm(
     `with_sharding_constraint` resharding at layer boundaries, replacing the
     reference's relocation wrappers (runtime/parallel.py:272-304).
     """
-    from hetu_galvatron_tpu.models.moe import apply_moe_decoder_layer
-
     S = tokens.shape[1]
     rope = None
     if cfg.position_embedding_type == "rope" and cfg.mrope_section:
@@ -135,22 +193,22 @@ def forward_causal_lm(
                                           tokens.shape))
             mpos = jnp.broadcast_to(base[None],
                                     (len(cfg.mrope_section),) + base.shape)
-        rope = M.mrope_cos_sin(mpos, cfg.head_dim, cfg.rope_theta,
+        rope = M.mrope_cos_sin(mpos, cfg.rope_dim, cfg.rope_theta,
                                sections=cfg.mrope_section,
                                scaling=cfg.rope_scaling)
     elif cfg.position_embedding_type == "rope":
         with jax.named_scope("attn/rope"):
-            cos, sin = M.rope_cos_sin(S, cfg.head_dim, cfg.rope_theta,
+            cos, sin = M.rope_cos_sin(S, cfg.rope_dim, cfg.rope_theta,
                                       scaling=cfg.rope_scaling)
         if position_ids is not None:
             # packed samples: gather per-token rows -> [B, S, D/2]
             cos, sin = cos[position_ids], sin[position_ids]
         rope = (cos, sin)
-    x = M.apply_embedding(
+    x = M.streams_in(M.apply_embedding(
         params["embed"], tokens, cfg, compute_dtype=compute_dtype,
         dropout_rng=M.fold_dropout_rng(dropout_rng, cfg,
                                        M.DROPOUT_STREAM_EMBED),
-        position_ids=position_ids)
+        position_ids=position_ids), cfg)
     aux_total = jnp.zeros((), jnp.float32)
     moe_stats: Dict[str, Dict[str, jax.Array]] = {}
     kinds = cfg.block_kinds()
@@ -170,29 +228,73 @@ def forward_causal_lm(
             kwargs["dropout_rng"] = M.fold_dropout_rng(dropout_rng, cfg, i)
         if layer_overrides and i in layer_overrides:
             kwargs.update(layer_overrides[i])
-        if ff == "experts":
-            fn = lambda p, h, kw=kwargs: apply_moe_decoder_layer(
-                p, h, cfg, **kw)
-        else:
-            fn = lambda p, h, kw=kwargs: (
-                M.apply_decoder_layer(p, h, cfg, **kw),
-                jnp.zeros((), jnp.float32), {})
-        if remat_flags is not None and remat_flags[i]:
-            fn = M.remat(fn, cfg)
-        x, aux, stats = fn(lp, x)
+        x, aux, stats = _block_fn(
+            cfg, kinds[i], kwargs,
+            remat_flags is not None and bool(remat_flags[i]))(lp, x)
         aux_total = aux_total + aux
         if stats:
             # per-layer balance tracker (reference moe_utils.py:547-644)
             moe_stats[f"layer{i}"] = stats
     if boundary_fn is not None:
         x = boundary_fn(len(params["layers"]), x)
+    x = M.streams_out(x, cfg)
+    if mtp_labels is not None:
+        # the further prediction depth reads the stack's output before the
+        # final norm; its block is the last block's kind, with the last
+        # block's keyword arguments and remat flag
+        if dropout_rng is not None:
+            kwargs["dropout_rng"] = M.fold_dropout_rng(
+                dropout_rng, cfg, len(params["layers"]))
+        mtp_logits, aux, stats = forward_mtp(
+            params, x, mtp_labels, cfg, block_fn=_block_fn(
+                cfg, mtp_block_kind(cfg), kwargs,
+                remat_flags is not None and bool(remat_flags[-1])),
+            compute_dtype=compute_dtype)
+        aux_total = aux_total + aux
+        if stats:
+            moe_stats["mtp"] = stats
     x = M.block_norm(params["prenorm"], x, cfg)
     logits = M.apply_lm_head(
         params["head"], x, cfg,
         wte=params["embed"]["wte"], compute_dtype=compute_dtype,
     )
     logits = logits if logits_fp32 else logits.astype(compute_dtype)
+    if mtp_labels is not None:
+        return logits, aux_total, moe_stats, mtp_logits
     return (logits, aux_total, moe_stats) if with_aux else logits
+
+
+def forward_mtp(params: Params, h: jax.Array, next_tokens: jax.Array,
+                cfg: ModelArgs, *, block_fn: Callable,
+                compute_dtype=jnp.bfloat16
+                ) -> Tuple[jax.Array, jax.Array, Dict[str, jax.Array]]:
+    """The further prediction depth's logits [B, S, V] (DeepSeek-V3 2.2):
+    ``h' = W_eh [RMSNorm(Emb(t_(i+1))) ; RMSNorm(h_i)]``, one more block, a
+    norm, the model's own head. ``h`` [B, S, H] is the stack's output before
+    the final norm and ``next_tokens`` [B, S] the token after each position
+    (a batch's ``labels``). Position ``i`` predicts token ``i + 2``; the
+    last position has no such token and the loss leaves it out, and under
+    the causal mask it reaches no other. Returns (logits, the block's aux
+    loss, its router stats)."""
+    mp = params["mtp"]
+    with jax.named_scope("mtp/embed_proj"):
+        e = M.block_norm(mp["enorm"], M.apply_embedding(
+            params["embed"], next_tokens, cfg, compute_dtype=compute_dtype),
+            cfg)
+        both = jnp.concatenate([e, M.block_norm(mp["hnorm"], h, cfg)],
+                               axis=-1)
+        x = jnp.einsum("bsk,kh->bsh", both.astype(compute_dtype),
+                       M.weight_view(mp["eh_proj"], compute_dtype),
+                       preferred_element_type=jnp.float32
+                       ).astype(compute_dtype)
+    with jax.named_scope("mtp/block"):
+        x, aux, stats = block_fn(mp["layer"], M.streams_in(x, cfg))
+        x = M.streams_out(x, cfg)
+    with jax.named_scope("mtp/head"):
+        logits = M.apply_lm_head(
+            params["head"], M.block_norm(mp["norm"], x, cfg), cfg,
+            wte=params["embed"]["wte"], compute_dtype=compute_dtype)
+    return logits, aux, stats
 
 
 def causal_lm_loss(
@@ -237,7 +339,8 @@ def causal_lm_loss(
                            enc_layer_overrides=enc_layer_overrides,
                            fused_ce=fused)
         return (loss, {}) if with_moe_stats else loss
-    logits, aux, moe_stats = forward_causal_lm(
+    mtp = "mtp" in params
+    logits, aux, moe_stats, *mtp_logits = forward_causal_lm(
         params, batch["tokens"], cfg,
         compute_dtype=compute_dtype, remat_flags=remat_flags,
         layer_overrides=layer_overrides, boundary_fn=boundary_fn,
@@ -245,10 +348,22 @@ def causal_lm_loss(
         position_ids=batch.get("position_ids"),
         segment_ids=batch.get("segment_ids"),
         mrope_position_ids=batch.get("mrope_position_ids"),
+        mtp_labels=batch["labels"] if mtp else None,
     )
     ce = M.cross_entropy_loss(logits, batch["labels"], batch.get("loss_mask"),
                               fused=fused)
     loss = ce + aux
+    if mtp:
+        # position i of the further depth predicts token i + 2, which is
+        # labels[i + 1]; the last position has none
+        mask = batch.get("loss_mask")
+        mask = (jnp.ones(batch["labels"].shape, jnp.float32) if mask is None
+                else mask.astype(jnp.float32))
+        with jax.named_scope("mtp/head"):
+            loss = loss + cfg.mtp_loss_coeff * M.cross_entropy_loss(
+                mtp_logits[0], jnp.roll(batch["labels"], -1, axis=1),
+                mask.at[:, -1].set(0.0) * jnp.roll(mask, -1, axis=1),
+                fused=fused)
     return (loss, moe_stats) if with_moe_stats else loss
 
 

@@ -44,12 +44,14 @@ def _check_supported(cfg: ModelArgs, params: Params) -> None:
     from hetu_galvatron_tpu.analysis.eligibility import (
         mixed_stack_reason,
         own_multipliers_reason,
+        residual_streams_reason,
     )
 
     reason = mixed_stack_reason(
         cfg, "generate() (a key-value cache a block, no convolution state "
         "and no state-space state)") or own_multipliers_reason(
-        cfg, "generate() (its cached attention core)")
+        cfg, "generate() (its cached attention core)"
+    ) or residual_streams_reason(cfg, "generate()")
     if reason is not None:
         raise NotImplementedError(reason)
 

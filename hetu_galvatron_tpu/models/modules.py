@@ -52,7 +52,10 @@ Axes = Dict[str, Any]
 
 
 # a block's key for its mixer's parameters, by mixer kind
-MIXER_KEYS = {"full_attention": "attn", "conv": "conv", "mamba": "mamba"}
+MIXER_KEYS = {"full_attention": "attn", "conv": "conv", "mamba": "mamba",
+              "latent_attention": "attn"}
+# the mixer kinds that attend (through an attention core, ``sdpa_fn``)
+ATTENDING_MIXERS = ("full_attention", "latent_attention")
 
 
 def _normal(key, shape, std, dtype=jnp.float32):
@@ -129,12 +132,17 @@ def weight_view(w: jax.Array, dtype) -> jax.Array:
 # ---------------------------------------------------------------------------
 
 
-def _scale_inv_freq(inv_freq: jax.Array, scaling: Optional[dict]) -> jax.Array:
+def _scale_inv_freq(inv_freq: jax.Array, scaling: Optional[dict],
+                    theta: float = 10000.0) -> jax.Array:
     """HF-style ``rope_scaling``: "linear" divides frequencies by ``factor``;
     "llama3" keeps high-frequency bands, divides low-frequency bands by
     ``factor``, and smoothly interpolates between the two wavelength
     thresholds (the public llama-3.1 rope recipe; parity-tested against
-    transformers' _compute_llama3_parameters)."""
+    transformers' _compute_llama3_parameters); "yarn" keeps the bands that
+    turn more than ``beta_fast`` times within the original context, divides
+    by ``factor`` those that turn fewer than ``beta_slow`` times, and ramps
+    linearly between the two band indices (transformers'
+    _compute_yarn_parameters, truncated bounds)."""
     if not scaling:
         return inv_freq
     rope_type = scaling.get("rope_type", scaling.get("type", "linear"))
@@ -149,8 +157,49 @@ def _scale_inv_freq(inv_freq: jax.Array, scaling: Optional[dict]) -> jax.Array:
         smooth = (orig / wavelen - low) / (high - low)
         smooth = jnp.clip(smooth, 0.0, 1.0)
         return (1.0 - smooth) * inv_freq / factor + smooth * inv_freq
+    if rope_type == "yarn":
+        half = inv_freq.shape[0]
+        dim = 2 * half
+        orig = float(scaling["original_max_position_embeddings"])
+        log_base = math.log(theta)
+
+        def band(turns: float) -> float:
+            # the band index that turns ``turns`` times within ``orig``
+            return dim * math.log(orig / (turns * 2.0 * math.pi)) / (
+                2.0 * log_base)
+
+        low = max(math.floor(band(float(scaling.get("beta_fast", 32)))), 0)
+        high = min(math.ceil(band(float(scaling.get("beta_slow", 1)))),
+                   dim - 1)
+        if low == high:
+            high += 0.001  # transformers' guard against a zero-wide ramp
+        ramp = jnp.clip((jnp.arange(half, dtype=jnp.float32) - low)
+                        / (high - low), 0.0, 1.0)
+        return inv_freq / factor * ramp + inv_freq * (1.0 - ramp)
     raise ValueError(f"unsupported rope_scaling type {rope_type!r} "
-                     "(supported: linear, llama3)")
+                     "(supported: linear, llama3, yarn)")
+
+
+def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
+    """YaRN's magnitude correction ``0.1 * mscale * ln(factor) + 1`` (1 for
+    a factor of at most 1)."""
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1.0 else 1.0
+
+
+def rope_attention_factor(scaling: Optional[dict]) -> float:
+    """What "yarn" multiplies cos and sin by: ``mscale`` over
+    ``mscale_all_dim`` (DeepSeek's form, the one a configuration here
+    states; 1 where they are equal); 1 for every other scaling."""
+    if not scaling or scaling.get(
+            "rope_type", scaling.get("type")) != "yarn":
+        return 1.0
+    m, m_all = scaling.get("mscale"), scaling.get("mscale_all_dim")
+    if not m or not m_all:
+        raise ValueError(
+            "rope_scaling type yarn: mscale and mscale_all_dim are both "
+            f"required (got {scaling!r})")
+    factor = float(scaling.get("factor", 1.0))
+    return yarn_mscale(factor, float(m)) / yarn_mscale(factor, float(m_all))
 
 
 def rope_cos_sin(
@@ -162,10 +211,14 @@ def rope_cos_sin(
     inv_freq = 1.0 / (
         theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim)
     )
-    inv_freq = _scale_inv_freq(inv_freq, scaling)
+    inv_freq = _scale_inv_freq(inv_freq, scaling, theta)
     t = jnp.arange(seq_len, dtype=jnp.float32)
     freqs = jnp.outer(t, inv_freq)  # [S, D/2]
-    return jnp.cos(freqs).astype(dtype), jnp.sin(freqs).astype(dtype)
+    cos, sin = jnp.cos(freqs), jnp.sin(freqs)
+    factor = rope_attention_factor(scaling)
+    if factor != 1.0:
+        cos, sin = cos * factor, sin * factor
+    return cos.astype(dtype), sin.astype(dtype)
 
 
 def mrope_cos_sin(
@@ -193,7 +246,7 @@ def mrope_cos_sin(
     inv_freq = 1.0 / (
         theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim)
     )
-    inv_freq = _scale_inv_freq(inv_freq, scaling)
+    inv_freq = _scale_inv_freq(inv_freq, scaling, theta)
     # [3, B, S, D/2]; frequency dim d draws from position row row[d]
     freqs = position_ids.astype(jnp.float32)[..., None] * inv_freq
     row = jnp.concatenate([
@@ -316,7 +369,8 @@ def xla_sdpa(
     segment_ids: Optional[jax.Array] = None,
     scale: Optional[float] = None,
 ) -> jax.Array:
-    """Reference attention core on XLA: [B,S,N,D] x [B,T,K,D] -> [B,S,N,D].
+    """Reference attention core on XLA: [B,S,N,D] x [B,T,K,D] -> [B,S,N,D]
+    (v may have a width of its own: [B,T,K,Dv] -> [B,S,N,Dv]).
 
     GQA handled by reshaping q into [B,S,K,G,D] groups. Softmax in fp32.
     Swapped out for the Pallas flash kernel / ring attention by the strategy
@@ -353,7 +407,7 @@ def xla_sdpa(
     probs = dropout(probs, dropout_rate, dropout_rng)
     out = jnp.einsum("bkgst,btkd->bskgd", probs, v,
                      preferred_element_type=jnp.float32)
-    return out.reshape(B, S, N, D).astype(q.dtype)
+    return out.reshape(B, S, N, v.shape[-1]).astype(q.dtype)
 
 
 def qkv_group_major(w: jax.Array, cfg: ModelArgs) -> jax.Array:
@@ -502,6 +556,305 @@ def apply_attention(
         if "bo" in p:
             y = y + p["bo"]
         return y.astype(compute_dtype)
+
+
+# ---------------------------------------------------------------------------
+# latent attention (low-rank q and kv projections, a rotary key for all heads)
+# ---------------------------------------------------------------------------
+
+
+def init_latent_attention(key: jax.Array,
+                          cfg: ModelArgs) -> Tuple[Params, Axes]:
+    """HF ``DeepseekV3Attention``: ``wq_a`` / ``q_norm`` / ``wq_b`` are
+    ``q_a_proj``, ``q_a_layernorm`` and ``q_b_proj`` (a head's columns
+    ``[nope | rope]``), ``wkv_a`` is ``kv_a_proj_with_mqa`` (columns
+    ``[latent | rope key]``), ``kv_norm`` / ``wkv_b`` are ``kv_a_layernorm``
+    and ``kv_b_proj`` (a head's columns ``[nope key | value]``), ``wo`` is
+    ``o_proj``. The rotated columns are kept in the half layout
+    :func:`apply_rope` turns (first halves, then second halves); the public
+    checkpoint interleaves them, and ``params_to_hf`` / ``hf_to_params``
+    permute the columns, so nothing is permuted at run time (q.k is the same
+    under one permutation of both). No leaf carries an axis name that tensor
+    parallelism shards: a plan with tp > 1 over such a block is refused by
+    name (``eligibility.latent_plan_reason``)."""
+    if cfg.normalization != "rmsnorm" or cfg.norm_zero_centered:
+        raise ValueError("a latent-attention block's q and kv norm is an "
+                         "RMSNorm with plain scales (normalization=rmsnorm, "
+                         "norm_zero_centered off), applied by apply_norm")
+    if cfg.add_qkv_bias or cfg.add_bias_linear:
+        raise NotImplementedError(
+            "latent attention is written without biases (DeepSeek-V3 and "
+            "its descendants publish attention_bias false)")
+    if not (cfg.q_lora_rank and cfg.kv_lora_rank and cfg.qk_nope_head_dim
+            and cfg.qk_rope_head_dim and cfg.v_head_dim):
+        raise ValueError(
+            "a latent_attention block needs model.q_lora_rank, kv_lora_rank, "
+            "qk_nope_head_dim, qk_rope_head_dim and v_head_dim")
+    h, nq = cfg.hidden_size, cfg.num_attention_heads
+    rq, rkv = cfg.q_lora_rank, cfg.kv_lora_rank
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    k1, k2, k3, k4, k5 = jax.random.split(key, 5)
+    std = 0.02
+    p: Params = {
+        "wq_a": _normal(k1, (h, rq), std),
+        "q_norm": {"scale": jnp.ones((rq,), jnp.float32)},
+        "wq_b": _normal(k2, (rq, nq * (dn + dr)), std),
+        "wkv_a": _normal(k3, (h, rkv + dr), std),
+        "kv_norm": {"scale": jnp.ones((rkv,), jnp.float32)},
+        "wkv_b": _normal(k4, (rkv, nq * (dn + dv)), std),
+        "wo": _normal(k5, (nq * dv, h),
+                      std / math.sqrt(2 * cfg.num_hidden_layers)),
+    }
+    a: Axes = {
+        "wq_a": ("embed", "latent_q"),
+        "q_norm": {"scale": ("latent_q",)},
+        "wq_b": ("latent_q", "latent_heads"),
+        "wkv_a": ("embed", "latent_kv"),
+        "kv_norm": {"scale": ("latent_kv",)},
+        "wkv_b": ("latent_kv", "latent_heads"),
+        "wo": ("latent_heads", "embed"),
+    }
+    return p, a
+
+
+def latent_softmax_scale(cfg: ModelArgs) -> float:
+    """``qk_head_dim ** -0.5``, times YaRN's ``mscale_all_dim`` correction
+    squared where the model scales its rotary bands (HF
+    ``DeepseekV3Attention.scaling``)."""
+    scale = cfg.qk_head_dim ** -0.5
+    sc = cfg.rope_scaling or {}
+    if sc.get("rope_type", sc.get("type")) == "yarn" and sc.get(
+            "mscale_all_dim"):
+        m = yarn_mscale(float(sc.get("factor", 1.0)),
+                        float(sc["mscale_all_dim"]))
+        scale *= m * m
+    return scale
+
+
+def apply_latent_attention(
+    p: Params,
+    x: jax.Array,
+    cfg: ModelArgs,
+    rope: Optional[Tuple[jax.Array, jax.Array]] = None,
+    sdpa_fn: Callable[..., jax.Array] = xla_sdpa,
+    compute_dtype=jnp.bfloat16,
+    causal: bool = True,
+    dropout_rng: Optional[jax.Array] = None,
+    segment_ids: Optional[jax.Array] = None,
+) -> jax.Array:
+    """``c_q = RMSNorm(x W_qa)``, ``[q_nope | q_rope] = c_q W_qb`` a head;
+    ``[c_kv | k_rope] = x W_kva`` (``k_rope`` one for all heads), ``[k_nope
+    | v] = RMSNorm(c_kv) W_kvb`` a head; RoPE on ``q_rope`` and ``k_rope``;
+    ``softmax([q_nope | q_rope] [k_nope | k_rope]^T * scale) v`` at
+    :func:`latent_softmax_scale`; ``W_o``. The core is given q and k of
+    ``qk_head_dim`` and v of ``v_head_dim``: no operand is padded to the
+    other's width (the XLA core and the flash kernels take v's own)."""
+    B, S, _ = x.shape
+    nq, rkv = cfg.num_attention_heads, cfg.kv_lora_rank
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    f32 = jnp.float32
+    if not (sdpa_fn is xla_sdpa or getattr(sdpa_fn, "supports_scale", False)):
+        raise NotImplementedError(
+            "latent attention states its own softmax scale and a value "
+            "width of its own, which the XLA attention core and the Pallas "
+            "flash kernels take; the installed ring/Ulysses core does not. "
+            "Avoid cp/ulysses layers for this model")
+
+    def proj(a, w):
+        return jnp.einsum("bsh,hf->bsf", a, weight_view(w, compute_dtype),
+                          preferred_element_type=f32).astype(compute_dtype)
+
+    with jax.named_scope("attn/latent_proj"):
+        xc = x.astype(compute_dtype)
+        q = proj(apply_norm(p["q_norm"], proj(xc, p["wq_a"]), cfg),
+                 p["wq_b"]).reshape(B, S, nq, dn + dr)
+        ckv, k_rope = jnp.split(proj(xc, p["wkv_a"]), [rkv], axis=-1)
+        kv = proj(apply_norm(p["kv_norm"], ckv, cfg),
+                  p["wkv_b"]).reshape(B, S, nq, dn + dv)
+        q_nope, q_rope = jnp.split(q, [dn], axis=-1)
+        k_nope, v = jnp.split(kv, [dn], axis=-1)
+        k_rope = k_rope[:, :, None, :]
+    if rope is not None:
+        cos, sin = rope
+        with jax.named_scope("attn/rope"):
+            q_rope = apply_rope(q_rope, cos, sin)
+            k_rope = apply_rope(k_rope, cos, sin)
+    with jax.named_scope("attn/latent_proj"):
+        q = jnp.concatenate([q_nope, q_rope], axis=-1)
+        k = jnp.concatenate(
+            [k_nope, jnp.broadcast_to(k_rope, (B, S, nq, dr))], axis=-1)
+    kwargs: Dict[str, Any] = {"scale": latent_softmax_scale(cfg)}
+    if dropout_rng is not None and cfg.attention_dropout > 0.0:
+        kwargs.update(dropout_rate=cfg.attention_dropout,
+                      dropout_rng=dropout_rng)
+    if segment_ids is not None:
+        kwargs["segment_ids"] = segment_ids
+    with jax.named_scope("attn/core"):
+        out = sdpa_fn(q, k, v, causal=causal, **kwargs)
+    with jax.named_scope("attn/out_proj"):
+        y = jnp.einsum("bsf,fh->bsh", out.reshape(B, S, nq * dv),
+                       weight_view(p["wo"], compute_dtype),
+                       preferred_element_type=f32)
+        return y.astype(compute_dtype)
+
+
+# ---------------------------------------------------------------------------
+# the residual as several streams (manifold-constrained hyper-connections)
+# ---------------------------------------------------------------------------
+
+
+def init_hyper_maps(key: jax.Array, cfg: ModelArgs) -> Tuple[Params, Axes]:
+    """One sub-layer's maps over ``n = hc_mult`` streams: ``phi`` [n H, n +
+    n + n n], its columns ``[pre | post | res]`` (``res`` row-major: entry
+    ``i n + j`` feeds stream ``j`` into stream ``i``), ``alpha`` [3] the
+    three gates of the token-dependent term and ``bias`` the static term.
+    At the start the token-dependent term is small (``alpha`` 0.01), the
+    sub-layer reads the mean of the streams (``sigmoid(b_pre) = 1 / n``),
+    writes to each with weight one and leaves the streams almost to
+    themselves (``b_res`` 0 on the diagonal, -4 off it)."""
+    n, h = cfg.hc_mult, cfg.hidden_size
+    eye = jnp.eye(n, dtype=jnp.float32)
+    bias = jnp.concatenate([
+        jnp.full((n,), -math.log(n - 1.0), jnp.float32),
+        jnp.zeros((n,), jnp.float32),
+        ((1.0 - eye) * -4.0).reshape(n * n)])
+    p: Params = {"phi": _normal(key, (n * h, 2 * n + n * n), 0.02),
+                 "alpha": jnp.full((3,), 0.01, jnp.float32),
+                 "bias": bias}
+    a: Axes = {"phi": ("hc_in", "hc_out"), "alpha": ("hc_gate",),
+               "bias": ("hc_out",)}
+    return p, a
+
+
+def hyper_maps(p: Params, x: jax.Array, cfg: ModelArgs,
+               compute_dtype=jnp.bfloat16
+               ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """The three maps of one sub-layer from the streams ``x`` [B, S, n, H]:
+    with ``u = RMSNorm(vec(x))`` over all ``n H`` values of a token (no
+    learned scale: ``phi`` follows at once), ``pre = sigmoid(a_pre u phi_pre
+    + b_pre)`` [n, B, S], ``post = 2 sigmoid(a_post u phi_post + b_post)``
+    [n, B, S] and ``res`` [n, n, B, S] = Sinkhorn-Knopp of
+    ``exp(clip(a_res mat(u phi_res) + b_res))``: rows, then columns,
+    ``hc_sinkhorn_iters`` times, ``hc_eps`` in every divisor. The norm is a
+    scalar a token, so it is applied AFTER the product: ``x phi`` runs on the
+    streams as they are, in ``compute_dtype`` with float32 accumulation like
+    every projection, and everything from there on is float32 with the
+    tokens along lanes (a [.., n, n] tail would use 4 lanes of 128)."""
+    B, S, n, H = x.shape
+    f32 = jnp.float32
+    with jax.named_scope("hc/maps"):
+        flat = x.reshape(B, S, n * H)
+        t = jnp.moveaxis(jnp.einsum(
+            "bsk,km->bsm", flat.astype(compute_dtype),
+            weight_view(p["phi"], compute_dtype),
+            preferred_element_type=f32), -1, 0)
+        mean_sq = jnp.mean(jnp.square(flat.astype(f32)), axis=-1)
+        t = t * jax.lax.rsqrt(mean_sq + cfg.layernorm_epsilon)
+        alpha, bias = p["alpha"], p["bias"][:, None, None]
+        pre = jax.nn.sigmoid(alpha[0] * t[:n] + bias[:n])
+        post = 2.0 * jax.nn.sigmoid(alpha[1] * t[n:2 * n] + bias[n:2 * n])
+        res = (alpha[2] * t[2 * n:] + bias[2 * n:]).reshape(n, n, B, S)
+        res = jnp.exp(jnp.clip(res, cfg.hc_res_clamp_min,
+                               cfg.hc_res_clamp_max))
+        for _ in range(cfg.hc_sinkhorn_iters):
+            res = res / (jnp.sum(res, axis=1, keepdims=True) + cfg.hc_eps)
+            res = res / (jnp.sum(res, axis=0, keepdims=True) + cfg.hc_eps)
+    return pre, post, res
+
+
+def _streams_f32(x: jax.Array):
+    return [x[:, :, j].astype(jnp.float32) for j in range(x.shape[2])]
+
+
+@jax.custom_vjp
+def _mix(res: jax.Array, post: jax.Array, x: jax.Array,
+         y: jax.Array) -> jax.Array:
+    xs, yf = _streams_f32(x), y.astype(jnp.float32)
+    return jnp.stack(
+        [sum(res[i, j][..., None] * xj for j, xj in enumerate(xs))
+         + post[i][..., None] * yf for i in range(len(xs))],
+        axis=2).astype(x.dtype)
+
+
+def _mix_bwd(saved, g):
+    res, post, x, y = saved
+    gs, xs, yf = _streams_f32(g), _streams_f32(x), y.astype(jnp.float32)
+    d_res = jnp.stack([jnp.stack([jnp.sum(gi * xj, axis=-1) for xj in xs])
+                       for gi in gs])
+    d_post = jnp.stack([jnp.sum(gi * yf, axis=-1) for gi in gs])
+    d_x = jnp.stack([sum(res[i, j][..., None] * gi
+                         for i, gi in enumerate(gs))
+                     for j in range(len(xs))], axis=2)
+    d_y = sum(post[i][..., None] * gi for i, gi in enumerate(gs))
+    return d_res, d_post, d_x.astype(x.dtype), d_y.astype(y.dtype)
+
+
+_mix.defvjp(lambda res, post, x, y: (_mix(res, post, x, y),
+                                     (res, post, x, y)), _mix_bwd)
+
+
+def hyper_collect(pre: jax.Array, x: jax.Array) -> jax.Array:
+    """What the sub-layer reads: ``sum_j pre_j x_j`` [B, S, H], float32
+    arithmetic on the streams as they are read, the result in their dtype.
+    Plain reverse mode: a backward pass written out for it moved time from
+    the backward to the recomputed pass and none off the step (PERF.md
+    section 6, PR 40)."""
+    with jax.named_scope("hc/mix"):
+        return sum(p[..., None] * xj for p, xj in zip(pre, _streams_f32(x))
+                   ).astype(x.dtype)
+
+
+def hyper_mix(res: jax.Array, post: jax.Array, x: jax.Array,
+              y: jax.Array) -> jax.Array:
+    """``x'_i = sum_j res_ij x_j + post_i y``: the streams mixed among
+    themselves, plus the sub-layer's output written to each. Float32
+    arithmetic on the streams as they are read, the result in their dtype;
+    the backward pass written out likewise (``_mix_bwd``)."""
+    with jax.named_scope("hc/mix"):
+        return _mix(res, post, x, y)
+
+
+def residual(hc: Optional[Params], x: jax.Array, cfg: ModelArgs,
+             branch: Callable[[jax.Array], jax.Array],
+             compute_dtype=jnp.bfloat16) -> jax.Array:
+    """One sub-layer around the residual. ``hc`` None (one stream, ``x``
+    [B, S, H]): ``x + branch(x)``. With a sub-layer's maps (``x`` [B, S, n,
+    H]): ``res x + post (x) branch(pre x)``. ``branch`` holds the
+    sub-layer's own input norm."""
+    if hc is None:
+        return x + branch(x)
+    pre, post, res = hyper_maps(hc, x, cfg, compute_dtype)
+    return hyper_mix(res, post, x, branch(hyper_collect(pre, x)))
+
+
+def streams_in(x: jax.Array, cfg: ModelArgs) -> jax.Array:
+    """[B, S, H] copied to ``hc_mult`` streams [B, S, n, H] where the model
+    has several; as it is otherwise."""
+    if cfg.hc_mult <= 1:
+        return x
+    B, S, H = x.shape
+    return jnp.broadcast_to(x[:, :, None, :], (B, S, cfg.hc_mult, H))
+
+
+def streams_out(x: jax.Array, cfg: ModelArgs) -> jax.Array:
+    """The streams summed back to [B, S, H] (arXiv:2409.19606 leaves the
+    stack so)."""
+    if cfg.hc_mult <= 1:
+        return x
+    return jnp.sum(x.astype(jnp.float32), axis=2).astype(x.dtype)
+
+
+def init_block_maps(key: jax.Array, cfg: ModelArgs
+                    ) -> Tuple[Params, Axes]:
+    """A block's two sets of maps (``hc1`` around the mixer, ``hc2`` around
+    the feed-forward), drawn from the block's key folded once more so that
+    the block's other leaves are what they are without them; empty for a
+    model of one stream."""
+    if cfg.hc_mult <= 1:
+        return {}, {}
+    k1, k2 = jax.random.split(jax.random.fold_in(key, 2))
+    (p1, a1), (p2, a2) = init_hyper_maps(k1, cfg), init_hyper_maps(k2, cfg)
+    return {"hc1": p1, "hc2": p2}, {"hc1": a1, "hc2": a2}
 
 
 # ---------------------------------------------------------------------------
@@ -836,11 +1189,21 @@ def apply_mixer(
     short convolution from ``p["conv"]`` or the Mamba-2 state-space block
     from ``p["mamba"]``; the last two take no rope, no attention core and
     no dropout of probabilities, and the last alone takes ``ssd_fn``
-    (:func:`apply_mamba2`)."""
+    (:func:`apply_mamba2`). ``latent_attention`` is attention through
+    low-rank projections, also from ``p["attn"]``
+    (:func:`apply_latent_attention`)."""
     if mixer == "full_attention":
         return apply_attention(p["attn"], h, cfg, compute_dtype=compute_dtype,
                                shard_fn=shard_fn, segment_ids=segment_ids,
                                **attn_kwargs)
+    if mixer == "latent_attention":
+        if shard_fn is not None or attn_kwargs.pop("matmul_fns", None):
+            raise NotImplementedError(
+                "a latent_attention block's projections are not cut over "
+                "the tp axis (eligibility.latent_plan_reason)")
+        return apply_latent_attention(
+            p["attn"], h, cfg, compute_dtype=compute_dtype,
+            segment_ids=segment_ids, **attn_kwargs)
     if mixer not in MIXER_KEYS:
         raise ValueError(f"unknown mixer kind {mixer!r} "
                          f"({' | '.join(MIXER_KEYS)})")
@@ -979,7 +1342,7 @@ def init_mixer(key: jax.Array, cfg: ModelArgs,
                mixer: str = "full_attention") -> Tuple[str, Params, Axes]:
     """(the block's key for it, params, axes) of one mixer kind."""
     init = {"full_attention": init_attention, "conv": init_short_conv,
-            "mamba": init_mamba2}
+            "mamba": init_mamba2, "latent_attention": init_latent_attention}
     if mixer not in init:
         raise ValueError(f"unknown mixer kind {mixer!r} ({' | '.join(init)})")
     return (MIXER_KEYS[mixer],) + init[mixer](key, cfg)
@@ -992,9 +1355,10 @@ def init_decoder_layer(key: jax.Array, cfg: ModelArgs,
     mlp_p, mlp_a = init_mlp(k2, cfg)
     ln1_p, ln1_a = init_norm(cfg)
     ln2_p, ln2_a = init_norm(cfg)
+    hc_p, hc_a = init_block_maps(key, cfg)
     return (
-        {"ln1": ln1_p, name: mix_p, "ln2": ln2_p, "mlp": mlp_p},
-        {"ln1": ln1_a, name: mix_a, "ln2": ln2_a, "mlp": mlp_a},
+        {"ln1": ln1_p, name: mix_p, "ln2": ln2_p, "mlp": mlp_p, **hc_p},
+        {"ln1": ln1_a, name: mix_a, "ln2": ln2_a, "mlp": mlp_a, **hc_a},
     )
 
 
@@ -1032,7 +1396,9 @@ def apply_decoder_layer(
     ``shard_fn`` keeps a tp > 1 layer's interior on its own shards
     (:func:`apply_attention`). ``mixer`` is the block's operator kind
     and ``ssd_fn`` a mamba block's kernels (:func:`apply_mixer`; pre-norm
-    blocks only)."""
+    blocks only). A model of several residual streams (``cfg.hc_mult``)
+    hands ``x`` [B, S, n, H] and the block's maps ``hc1`` / ``hc2``
+    (:func:`residual`)."""
     if causal is None:
         causal = cfg.model_type != "bert"
     r_attn = r_res1 = r_res2 = None
@@ -1067,20 +1433,25 @@ def apply_decoder_layer(
                                  matmul_fns=matmul_fns,
                                  shard_fn=shard_fn), r_res2),
             cfg)
-    h = block_norm(p["ln1"], x, cfg)
-    x = x + residual_branch(
-        drop_h(apply_mixer(p, h, cfg, mixer, rope=rope, sdpa_fn=sdpa_fn,
-                           compute_dtype=compute_dtype, causal=causal,
-                           dropout_rng=r_attn, segment_ids=segment_ids,
-                           matmul_fns=matmul_fns, shard_fn=shard_fn,
-                           ssd_fn=ssd_fn),
-               r_res1), cfg)
-    h = block_norm(p["ln2"], x, cfg)
-    x = x + residual_branch(
-        drop_h(apply_mlp(p["mlp"], h, cfg, compute_dtype=compute_dtype,
-                         matmul_fns=matmul_fns, shard_fn=shard_fn),
-               r_res2), cfg)
-    return x
+    def mixer_branch(a):
+        h = block_norm(p["ln1"], a, cfg)
+        return residual_branch(
+            drop_h(apply_mixer(p, h, cfg, mixer, rope=rope, sdpa_fn=sdpa_fn,
+                               compute_dtype=compute_dtype, causal=causal,
+                               dropout_rng=r_attn, segment_ids=segment_ids,
+                               matmul_fns=matmul_fns, shard_fn=shard_fn,
+                               ssd_fn=ssd_fn),
+                   r_res1), cfg)
+
+    def mlp_branch(a):
+        h = block_norm(p["ln2"], a, cfg)
+        return residual_branch(
+            drop_h(apply_mlp(p["mlp"], h, cfg, compute_dtype=compute_dtype,
+                             matmul_fns=matmul_fns, shard_fn=shard_fn),
+                   r_res2), cfg)
+
+    x = residual(p.get("hc1"), x, cfg, mixer_branch, compute_dtype)
+    return residual(p.get("hc2"), x, cfg, mlp_branch, compute_dtype)
 
 
 # ---------------------------------------------------------------------------

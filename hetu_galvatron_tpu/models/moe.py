@@ -94,7 +94,8 @@ def route_tokens(
     Mixtral: softmax, the k chosen divided by ``max(sum, 1e-9)``; OLMoE:
     softmax, not renormalised, no epsilon; LFM2 (and DeepSeek-V3's form):
     a sigmoid each at any k, the bias added to the SIGMOID for the choice
-    only, the k chosen unbiased sigmoids divided by ``sum + 1e-6`` where
+    only, the k chosen unbiased sigmoids divided by ``sum +
+    cfg.moe_norm_topk_eps`` (LFM2's 1e-6, DeepSeek-V3's 1e-20) where
     ``moe_norm_topk_prob`` is on, then times
     ``cfg.moe_routed_scaling_factor``. The bias's maintenance is the same
     under both.
@@ -163,7 +164,8 @@ def route_tokens(
     if sigmoid:
         if cfg.moe_norm_topk_prob:
             topk_probs = topk_probs / (
-                jnp.sum(topk_probs, axis=-1, keepdims=True) + 1e-6)
+                jnp.sum(topk_probs, axis=-1, keepdims=True)
+                + cfg.moe_norm_topk_eps)
         topk_probs = topk_probs * cfg.moe_routed_scaling_factor
     elif cfg.moe_norm_topk_prob:
         # renormalize over the selected k (HF Mixtral convention; the
@@ -400,7 +402,10 @@ def _sorted_rows_mlp(rows: int, cfg: ModelArgs, compute_dtype, xt, win, wout,
     grouped matmuls, activation, weighting, scatter-add. Rows of the prefix
     that belong to no group are zeroed going in and masked coming out, so
     that nothing the grouped matmuls leave there reaches the result or a
-    gradient."""
+    gradient. The mask is on ``ys`` itself, BEFORE the weights: behind the
+    product its transpose hands the weights ``0 * ys``, which is NaN where
+    the chip left an inf or a NaN in such a row, and from there the router's
+    gradient and every block before it (PERF.md section 6, PR 40)."""
     tok, mine = tok_sorted[:rows], mine_sorted[:rows, None]
     with jax.named_scope("moe/dispatch"):
         xs = jnp.where(mine, xt[tok].astype(compute_dtype), 0)
@@ -412,7 +417,7 @@ def _sorted_rows_mlp(rows: int, cfg: ModelArgs, compute_dtype, xt, win, wout,
                              group_sizes, jnp.float32)
     with jax.named_scope("moe/combine"):
         return jnp.zeros(xt.shape, jnp.float32).at[tok].add(
-            jnp.where(mine, ys * ws[:rows, None], 0.0))
+            jnp.where(mine, ys, 0.0) * ws[:rows, None])
 
 
 def _short_or_full(short_body, full_body):
@@ -544,9 +549,10 @@ def init_moe_decoder_layer(key: jax.Array, cfg: ModelArgs,
     moe_p, moe_a = init_moe_mlp(k2, cfg)
     ln1_p, ln1_a = M.init_norm(cfg)
     ln2_p, ln2_a = M.init_norm(cfg)
+    hc_p, hc_a = M.init_block_maps(key, cfg)
     return (
-        {"ln1": ln1_p, name: mix_p, "ln2": ln2_p, "moe": moe_p},
-        {"ln1": ln1_a, name: mix_a, "ln2": ln2_a, "moe": moe_a},
+        {"ln1": ln1_p, name: mix_p, "ln2": ln2_p, "moe": moe_p, **hc_p},
+        {"ln1": ln1_a, name: mix_a, "ln2": ln2_a, "moe": moe_a, **hc_a},
     )
 
 
@@ -565,18 +571,28 @@ def apply_moe_decoder_layer(
     """Pre-norm block with an MoE FFN; returns (x, aux_loss, router
     stats) — stats feed the per-layer balance tracker (reference
     moe_utils.py:547-644). ``mixer``: the block's operator kind, and
-    ``ssd_fn`` a mamba block's kernels (modules.apply_mixer)."""
+    ``ssd_fn`` a mamba block's kernels (modules.apply_mixer). Several
+    residual streams: as modules.apply_decoder_layer."""
     r_attn = r_res1 = r_res2 = None
     if dropout_rng is not None:
         r_attn, r_res1, r_res2 = jax.random.split(dropout_rng, 3)
-    h = M.block_norm(p["ln1"], x, cfg)
-    x = x + M.residual_branch(M.dropout(
-        M.apply_mixer(p, h, cfg, mixer, rope=rope, sdpa_fn=sdpa_fn,
-                      compute_dtype=compute_dtype, dropout_rng=r_attn,
-                      segment_ids=segment_ids, ssd_fn=ssd_fn),
-        cfg.hidden_dropout, r_res1), cfg)
-    h = M.block_norm(p["ln2"], x, cfg)
-    y, aux, stats = apply_moe_mlp(p["moe"], h, cfg,
-                                  compute_dtype=compute_dtype)
-    return (x + M.residual_branch(M.dropout(y, cfg.hidden_dropout, r_res2),
-                                  cfg), aux, stats)
+    routed: Dict[str, Any] = {}
+
+    def mixer_branch(a):
+        h = M.block_norm(p["ln1"], a, cfg)
+        return M.residual_branch(M.dropout(
+            M.apply_mixer(p, h, cfg, mixer, rope=rope, sdpa_fn=sdpa_fn,
+                          compute_dtype=compute_dtype, dropout_rng=r_attn,
+                          segment_ids=segment_ids, ssd_fn=ssd_fn),
+            cfg.hidden_dropout, r_res1), cfg)
+
+    def experts_branch(a):
+        h = M.block_norm(p["ln2"], a, cfg)
+        y, routed["aux"], routed["stats"] = apply_moe_mlp(
+            p["moe"], h, cfg, compute_dtype=compute_dtype)
+        return M.residual_branch(M.dropout(y, cfg.hidden_dropout, r_res2),
+                                 cfg)
+
+    x = M.residual(p.get("hc1"), x, cfg, mixer_branch, compute_dtype)
+    x = M.residual(p.get("hc2"), x, cfg, experts_branch, compute_dtype)
+    return x, routed["aux"], routed["stats"]

@@ -470,7 +470,9 @@ COLLECTIVE_OPS = ("all-to-all", "all-gather", "all-reduce", "reduce-scatter",
 # literal of those files in it.
 SCOPES: Tuple[str, ...] = (
     "embed", "norm", "attn/qkv_proj", "attn/qk_norm", "attn/rope",
-    "attn/core", "attn/out_proj", "mlp", "head", "param_view",
+    "attn/core", "attn/out_proj", "attn/latent_proj", "hc/maps", "hc/mix",
+    "mtp/embed_proj", "mtp/block", "mtp/head",
+    "mlp", "head", "param_view",
     "grad/accumulate", "grad/clip", "optimizer/update",
     "moe/route", "moe/dispatch", "moe/experts", "moe/combine",
     "mixer/short_conv/in_proj", "mixer/short_conv/gate_conv",
@@ -483,6 +485,12 @@ PHASES = ("forward", "recompute", "backward", "update", "other")
 # instruction by its own ``op_name``)
 MIXER_SCOPES = {"mamba": tuple(s for s in SCOPES
                                if s.startswith("mixer/mamba/"))}
+# the further prediction depth's three parts: what is under them by the
+# deepest scope is the block's own (``attn/core``, ``moe/experts``, ``head``),
+# so a reader that wants the depth's whole time takes these lists
+MTP_SCOPES = tuple(s for s in SCOPES if s.startswith("mtp/"))
+# what ``step_scopes()["scopes"]`` lists by instruction
+OWN_SCOPES = MIXER_SCOPES["mamba"] + MTP_SCOPES
 # the scope of the state-space scan, whose Mosaic calls the step report
 # counts (``ssd/mosaic_calls``; ops/pallas/ssd.py traces under it)
 SSD_SCOPE = "mixer/mamba/ssd"
@@ -584,7 +592,7 @@ def _op_tail(op_name: str, parts: int = 3) -> str:
     return "/".join(op_name.split("/")[-parts:])
 
 
-def step_hlo(hlo_text: str, own_scopes: Sequence[str] = MIXER_SCOPES["mamba"]
+def step_hlo(hlo_text: str, own_scopes: Sequence[str] = OWN_SCOPES
              ) -> Dict[str, Any]:
     """Everything the program keeps of its compiled step's optimized HLO,
     from one walk over the text.

@@ -5,8 +5,12 @@ checklist item 4; installed by galvatron/scripts/flash_attn_ops_install.sh)
 with three TPU kernels. The [S, S] score matrix never exists: every kernel
 works on one ``block_q x block_k`` score tile at a time.
 
-Layout: q [B, N, S, D], k/v [B, K, S, D] (heads-major so a grid cell's tiles
-are contiguous); GQA maps q-head n to kv-head n // (N // K) in the index map.
+Layout: q [B, N, S, D], k [B, K, S, D], v [B, K, S, Dv] (heads-major so a
+grid cell's tiles are contiguous); GQA maps q-head n to kv-head n // (N // K)
+in the index map. v has a width of its own (latent attention: q/k 192, v
+128): the output, dO, dv and their accumulators are ``Dv`` wide, q, k, dq and
+dk ``D`` wide, and no operand is padded to the other's width. With
+``Dv == D`` every block and every scratch is what it was.
 
 The tile loop. A grid step of the forward and of the dq kernel holds one q
 tile and a MAJOR block of K and V (the whole length where it fits
@@ -309,6 +313,7 @@ def flash_attention_hmajor(
     scale: "float | None" = None,  # softmax(scale * q.k^T); None = D ** -0.5
 ) -> jax.Array:
     B, N, S, D = q.shape
+    Dv = v.shape[3]
     K = k.shape[1]
     Sk = k.shape[2]  # may differ from S (ring off-diagonal blocks)
     G = N // K
@@ -316,7 +321,7 @@ def flash_attention_hmajor(
     block_k = min(block_k, Sk)
     _check_call(S, Sk, block_q, block_k, causal, segments, dropout_rate,
                 dropout_seed)
-    chunks = _major_chunks(Sk, block_k, D * k.dtype.itemsize)
+    chunks = _major_chunks(Sk, block_k, max(D, Dv) * k.dtype.itemsize)
     major = chunks * block_k
     num_major = Sk // major
     grid = (B, N, S // block_q, num_major)  # k major axis innermost
@@ -335,7 +340,7 @@ def flash_attention_hmajor(
                      lambda b, n, qi, kj: (b, n, qi, 0)),
         pl.BlockSpec((1, 1, major, D),
                      lambda b, n, qi, kj: (b, n // G, kj_of(qi, kj), 0)),
-        pl.BlockSpec((1, 1, major, D),
+        pl.BlockSpec((1, 1, major, Dv),
                      lambda b, n, qi, kj: (b, n // G, kj_of(qi, kj), 0)),
     ]
     operands = [q, k, v]
@@ -355,19 +360,19 @@ def flash_attention_hmajor(
         grid=grid,
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, 1, block_q, D),
+            pl.BlockSpec((1, 1, block_q, Dv),
                          lambda b, n, qi, kj: (b, n, qi, 0)),
             pl.BlockSpec((1, 1, block_q, 1),
                          lambda b, n, qi, kj: (b, n, qi, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B, N, S, D), q.dtype),
+            jax.ShapeDtypeStruct((B, N, S, Dv), q.dtype),
             jax.ShapeDtypeStruct((B, N, S, 1), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, _LANES), jnp.float32),
             pltpu.VMEM((block_q, _LANES), jnp.float32),
-            pltpu.VMEM((block_q, D), jnp.float32),
+            pltpu.VMEM((block_q, Dv), jnp.float32),
         ],
         # only the k axis carries loop state (the online softmax);
         # everything else may be reordered/partitioned by Mosaic
@@ -534,6 +539,7 @@ def flash_attention_bwd_hmajor(
     tile, so nothing O(S^2) ever hits HBM. Returns (dq, dk, dv). ``scale``:
     the forward's (``None`` = ``D ** -0.5``)."""
     B, N, S, D = q.shape
+    Dv = v.shape[3]
     KV = k.shape[1]
     Sk = k.shape[2]  # may differ from S (ring off-diagonal blocks)
     G = N // KV
@@ -555,7 +561,7 @@ def flash_attention_bwd_hmajor(
 
     # dk/dv: a k/v tile stays, q / dO / lse / delta stream by major blocks;
     # its score tiles are [k, q], so lse and delta come as rows
-    q_chunks = _major_chunks(S, block_q, D * q.dtype.itemsize)
+    q_chunks = _major_chunks(S, block_q, max(D, Dv) * q.dtype.itemsize)
     q_major = q_chunks * block_q
 
     def qj_of(kb, qj):
@@ -565,9 +571,11 @@ def flash_attention_bwd_hmajor(
             return jnp.maximum(qj, (kb * block_k) // q_major)
         return qj
 
-    q_rows = pl.BlockSpec(
-        (1, 1, q_major, D),
-        lambda b, kh, kb, g, qj: (b, kh * G + g, qj_of(kb, qj), 0))
+    def q_rows(width):
+        return pl.BlockSpec(
+            (1, 1, q_major, width),
+            lambda b, kh, kb, g, qj: (b, kh * G + g, qj_of(kb, qj), 0))
+
     q_stat = pl.BlockSpec(
         (1, 1, q_chunks, 1, block_q),
         lambda b, kh, kb, g, qj: (b, kh * G + g, qj_of(kb, qj), 0, 0))
@@ -575,8 +583,8 @@ def flash_attention_bwd_hmajor(
     def k_tile(b, kh, kb, g, qj):
         return (b, kh, kb, 0)
 
-    dkdv_in_specs = [q_rows, pl.BlockSpec((1, 1, block_k, D), k_tile),
-                     pl.BlockSpec((1, 1, block_k, D), k_tile), q_rows,
+    dkdv_in_specs = [q_rows(D), pl.BlockSpec((1, 1, block_k, D), k_tile),
+                     pl.BlockSpec((1, 1, block_k, Dv), k_tile), q_rows(Dv),
                      q_stat, q_stat]
     dkdv_operands = [q, k, v, do, _chunk_rows(lse[..., 0], block_q), delta]
     if dropout_rate > 0.0:
@@ -600,14 +608,14 @@ def flash_attention_bwd_hmajor(
         grid=(B, KV, Sk // block_k, G, S // q_major),
         in_specs=dkdv_in_specs,
         out_specs=[pl.BlockSpec((1, 1, block_k, D), k_tile),
-                   pl.BlockSpec((1, 1, block_k, D), k_tile)],
+                   pl.BlockSpec((1, 1, block_k, Dv), k_tile)],
         out_shape=[
             jax.ShapeDtypeStruct((B, KV, Sk, D), k.dtype),
-            jax.ShapeDtypeStruct((B, KV, Sk, D), v.dtype),
+            jax.ShapeDtypeStruct((B, KV, Sk, Dv), v.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_k, D), jnp.float32),
-            pltpu.VMEM((block_k, D), jnp.float32),
+            pltpu.VMEM((block_k, Dv), jnp.float32),
         ],
         # dk/dv accumulate across the (g, q) axes; k tiles are independent
         compiler_params=pltpu.CompilerParams(
@@ -618,7 +626,7 @@ def flash_attention_bwd_hmajor(
     )(*dkdv_operands)
 
     # dq: a q tile stays, k / v stream by major blocks (the forward's grid)
-    k_chunks = _major_chunks(Sk, block_k, D * k.dtype.itemsize)
+    k_chunks = _major_chunks(Sk, block_k, max(D, Dv) * k.dtype.itemsize)
     k_major = k_chunks * block_k
 
     def kj_of(qi, kj):
@@ -628,13 +636,13 @@ def flash_attention_bwd_hmajor(
         return pl.BlockSpec((1, 1, block_q, width),
                             lambda b, n, qi, kj: (b, n, qi, 0))
 
-    def k_rows():
+    def k_rows(width):
         return pl.BlockSpec(
-            (1, 1, k_major, D),
+            (1, 1, k_major, width),
             lambda b, n, qi, kj: (b, n // G, kj_of(qi, kj), 0))
 
-    dq_in_specs = [q_tile(D), k_rows(), k_rows(), q_tile(D), q_tile(1),
-                   q_tile(D)]
+    dq_in_specs = [q_tile(D), k_rows(D), k_rows(Dv), q_tile(Dv), q_tile(1),
+                   q_tile(Dv)]
     dq_operands = [q, k, v, do, lse, o]
     if dropout_rate > 0.0:
         dq_in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))

@@ -72,6 +72,9 @@ def param_specs(
         "prenorm": _spec_tree(axes_tree["prenorm"], vocab, opt),
         "head": _spec_tree(axes_tree["head"], vocab, opt),
     }
+    if "mtp" in axes_tree:
+        # the further prediction depth's block is sharded as the last block
+        out["mtp"] = _spec_tree(axes_tree["mtp"], per_layer[-1], opt)
     if "enc_layers" in axes_tree:
         enc = (enc_per_layer if enc_per_layer is not None
                else [per_layer[0]] * len(axes_tree["enc_layers"]))

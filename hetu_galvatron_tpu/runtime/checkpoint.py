@@ -909,8 +909,16 @@ _MOE_HF_NAMES = {
              "feed_forward.experts.{e}.w3.weight",
              "feed_forward.experts.{e}.w2.weight"),
 }
+# DeepSeek-V3's released layout: olmoe's names for the router and experts
+_MOE_HF_NAMES["deepseek"] = _MOE_HF_NAMES["olmoe"]
 # the selection bias of an expert layer, where the layout has a name for it
-_MOE_HF_BIAS = {"lfm2": "feed_forward.expert_bias"}
+_MOE_HF_BIAS = {"lfm2": "feed_forward.expert_bias",
+                "deepseek": "mlp.gate.e_score_correction_bias"}
+# the shared expert's gate, up and down projections, where the layout has a
+# slot for one
+_MOE_HF_SHARED = {"deepseek": ("mlp.shared_experts.gate_proj.weight",
+                               "mlp.shared_experts.up_proj.weight",
+                               "mlp.shared_experts.down_proj.weight")}
 
 # public names of a block's norms, attention projections, q/k norms, dense
 # MLP and of the final norm, by ``cfg.hf_layout``; a ``conv`` block's names
@@ -949,7 +957,50 @@ _MAMBA_HF_NAMES = {"win": "mamba.in_proj.weight",
                    "dt_bias": "mamba.dt_bias", "A_log": "mamba.A_log",
                    "D": "mamba.D", "norm": "mamba.norm.weight",
                    "wout": "mamba.out_proj.weight"}
-_MIXER_KINDS = ("full_attention", "conv", "mamba")
+# a ``latent_attention`` block's names are DeepSeek-V3's
+# (``DeepseekV3Attention``): the program's leaf -> the public name
+_LATENT_HF_NAMES = {"wq_a": "self_attn.q_a_proj.weight",
+                    "q_norm": "self_attn.q_a_layernorm.weight",
+                    "wq_b": "self_attn.q_b_proj.weight",
+                    "wkv_a": "self_attn.kv_a_proj_with_mqa.weight",
+                    "kv_norm": "self_attn.kv_a_layernorm.weight",
+                    "wkv_b": "self_attn.kv_b_proj.weight",
+                    "wo": "self_attn.o_proj.weight"}
+# a block's two sets of residual maps (``ModelArgs.hc_mult`` > 1): no public
+# checkpoint names them, so the names are this exporter's own
+_HC_HF_NAMES = {"hc1": "attn_hc.", "hc2": "mlp_hc."}
+# the further prediction depth is DeepSeek-V3's released layout: a block at
+# index ``num_hidden_layers`` with these beside its own weights (its
+# ``embed_tokens`` and ``shared_head.head`` are the model's and not repeated)
+_MTP_HF_NAMES = {"enorm": "enorm.weight", "hnorm": "hnorm.weight",
+                 "eh_proj": "eh_proj.weight",
+                 "norm": "shared_head.norm.weight"}
+_MIXER_KINDS = ("full_attention", "conv", "mamba", "latent_attention")
+
+
+def _rope_columns_to_hf(width: int) -> np.ndarray:
+    """Column order that turns the half layout ``modules.apply_rope``
+    rotates (first halves, then second halves) into the interleaved one a
+    DeepSeek checkpoint stores: public column ``2 i`` is the program's ``i``,
+    ``2 i + 1`` its ``i + width / 2``."""
+    return np.stack([np.arange(width // 2),
+                     np.arange(width // 2) + width // 2], axis=1).reshape(-1)
+
+
+def _latent_rope_orders(cfg: ModelArgs, to_hf: bool):
+    """(column order of ``wq_b``, of ``wkv_a``) between the program's layout
+    and the public one: the rotated columns of every query head and of the
+    shared key permuted, everything else in place."""
+    dn, dr = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+    order = _rope_columns_to_hf(dr)
+    if not to_hf:
+        order = np.argsort(order)
+    head = np.concatenate([np.arange(dn), dn + order])
+    q = (np.arange(cfg.num_attention_heads)[:, None] * (dn + dr)
+         + head[None, :]).reshape(-1)
+    kv = np.concatenate([np.arange(cfg.kv_lora_rank),
+                         cfg.kv_lora_rank + order])
+    return q, kv
 
 
 def _unknown_mixer(i: int, mixer: str) -> ValueError:
@@ -1019,11 +1070,16 @@ def hf_to_params(state_dict: Dict[str, Any], cfg: ModelArgs) -> Params:
 
     router, gate, up, down = _MOE_HF_NAMES[cfg.moe_hf_layout]
     names = _BLOCK_HF_NAMES[cfg.hf_layout]
-    layers = []
-    for i, (mixer, ff) in enumerate(cfg.block_kinds()):
+
+    def read_block(i, mixer, ff):
         pre = f"model.layers.{i}."
         lp = {"ln1": {"scale": sd[pre + names["ln1"]]},
               "ln2": {"scale": sd[pre + names["ln2"]]}}
+        if cfg.hc_mult > 1:
+            for leaf, part in _HC_HF_NAMES.items():
+                lp[leaf] = {"phi": lin(pre + part + "phi.weight"),
+                            "alpha": sd[pre + part + "alpha"],
+                            "bias": sd[pre + part + "bias"]}
         if mixer == "conv":
             w_in, w_taps, w_out = (sd[pre + nm] for nm in _CONV_HF_NAMES)
             # in_proj's rows are the thirds B | C | X; Conv1d's depthwise
@@ -1049,6 +1105,14 @@ def hf_to_params(state_dict: Dict[str, Any], cfg: ModelArgs) -> Params:
             if cfg.qk_norm:
                 lp["attn"]["q_norm"] = {"scale": sd[pre + names["q_norm"]]}
                 lp["attn"]["k_norm"] = {"scale": sd[pre + names["k_norm"]]}
+        elif mixer == "latent_attention":
+            q_order, kv_order = _latent_rope_orders(cfg, to_hf=False)
+            lp["attn"] = {
+                leaf: ({"scale": sd[pre + name]} if leaf.endswith("norm")
+                       else lin(pre + name))
+                for leaf, name in _LATENT_HF_NAMES.items()}
+            lp["attn"]["wq_b"] = lp["attn"]["wq_b"][:, q_order]
+            lp["attn"]["wkv_a"] = lp["attn"]["wkv_a"][:, kv_order]
         else:
             raise _unknown_mixer(i, mixer)
         if ff == "experts":
@@ -1057,7 +1121,8 @@ def hf_to_params(state_dict: Dict[str, Any], cfg: ModelArgs) -> Params:
             # names are the layout's that cfg.moe_hf_layout says. A layer
             # that holds a share finds its experts under their published
             # indices
-            if cfg.num_shared_experts:
+            shared = _MOE_HF_SHARED.get(cfg.moe_hf_layout)
+            if cfg.num_shared_experts and not shared:
                 raise NotImplementedError(
                     f"the {cfg.moe_hf_layout} HF layout "
                     f"({pre}{router}) has no shared-expert slot; "
@@ -1085,6 +1150,11 @@ def hf_to_params(state_dict: Dict[str, Any], cfg: ModelArgs) -> Params:
             bias = _MOE_HF_BIAS.get(cfg.moe_hf_layout)
             if cfg.moe_router_enable_expert_bias and bias:
                 lp["moe"]["expert_bias"] = sd[pre + bias]
+            if cfg.num_shared_experts:
+                s_gate, s_up, s_down = (pre + nm for nm in shared)
+                lp["moe"]["shared"] = {
+                    "win": np.concatenate([lin(s_gate), lin(s_up)], axis=1),
+                    "wout": lin(s_down)}
         else:
             win = (lin(pre + names["gate_up"]) if "gate_up" in names
                    else np.concatenate([lin(pre + names["gate"]),
@@ -1095,7 +1165,10 @@ def hf_to_params(state_dict: Dict[str, Any], cfg: ModelArgs) -> Params:
                 [sd[pre + "self_attn.q_proj.bias"],
                  sd[pre + "self_attn.k_proj.bias"],
                  sd[pre + "self_attn.v_proj.bias"]])
-        layers.append(lp)
+        return lp
+
+    layers = [read_block(i, mixer, ff)
+              for i, (mixer, ff) in enumerate(cfg.block_kinds())]
     wte = sd["model.embed_tokens.weight"]
     pad = cfg.padded_vocab_size - wte.shape[0]
     if pad > 0:
@@ -1105,6 +1178,13 @@ def hf_to_params(state_dict: Dict[str, Any], cfg: ModelArgs) -> Params:
         "layers": tuple(layers),
         "prenorm": {"scale": sd[names["final"]]},
     }
+    if cfg.num_nextn_predict_layers:
+        pre = f"model.layers.{n}."
+        out["mtp"] = {
+            leaf: (lin(pre + name) if leaf == "eh_proj"
+                   else {"scale": sd[pre + name]})
+            for leaf, name in _MTP_HF_NAMES.items()}
+        out["mtp"]["layer"] = read_block(n, *cfg.block_kinds()[-1])
     if cfg.tie_word_embeddings:
         out["head"] = {}
     else:
@@ -1418,8 +1498,14 @@ def params_to_hf(params: Params, cfg: ModelArgs) -> Dict[str, np.ndarray]:
         raise ValueError(
             f"the parameters hold {len(params['layers'])} blocks and the "
             f"configuration describes {len(kinds)}")
-    for i, (lp, (mixer, ff)) in enumerate(zip(params["layers"], kinds)):
+
+    def put_block(i, lp, mixer, ff):
         pre = f"model.layers.{i}."
+        for leaf, part in _HC_HF_NAMES.items():
+            if leaf in lp:
+                sd[pre + part + "phi.weight"] = get(lp[leaf]["phi"]).T
+                sd[pre + part + "alpha"] = get(lp[leaf]["alpha"])
+                sd[pre + part + "bias"] = get(lp[leaf]["bias"])
         if mixer == "conv":
             n_in, n_taps, n_out = (pre + nm for nm in _CONV_HF_NAMES)
             sd[n_in] = np.concatenate(
@@ -1450,14 +1536,30 @@ def params_to_hf(params: Params, cfg: ModelArgs) -> Dict[str, np.ndarray]:
             if "q_norm" in lp["attn"]:
                 sd[pre + names["q_norm"]] = get(lp["attn"]["q_norm"]["scale"])
                 sd[pre + names["k_norm"]] = get(lp["attn"]["k_norm"]["scale"])
+        elif mixer == "latent_attention":
+            q_order, kv_order = _latent_rope_orders(cfg, to_hf=True)
+            for leaf, name in _LATENT_HF_NAMES.items():
+                if leaf.endswith("norm"):
+                    sd[pre + name] = get(lp["attn"][leaf]["scale"])
+                    continue
+                w = get(lp["attn"][leaf])
+                order = {"wq_b": q_order, "wkv_a": kv_order}.get(leaf)
+                sd[pre + name] = (w if order is None else w[:, order]).T
         else:
             raise _unknown_mixer(i, mixer)
         if ff == "experts":
-            if "shared" in lp["moe"]:
+            shared = _MOE_HF_SHARED.get(cfg.moe_hf_layout)
+            if "shared" in lp["moe"] and not shared:
                 raise NotImplementedError(
                     f"the {cfg.moe_hf_layout} HF layout "
                     f"({pre}{router}) has no shared-expert slot; "
                     "export models with num_shared_experts=0")
+            if "shared" in lp["moe"]:
+                s_gate, s_up = np.split(get(lp["moe"]["shared"]["win"]), 2,
+                                        axis=1)
+                sd[pre + shared[0]] = s_gate.T
+                sd[pre + shared[1]] = s_up.T
+                sd[pre + shared[2]] = get(lp["moe"]["shared"]["wout"]).T
             sd[pre + router] = get(lp["moe"]["router"]).T
             win = get(lp["moe"]["win"])
             wout = get(lp["moe"]["wout"])
@@ -1482,6 +1584,15 @@ def params_to_hf(params: Params, cfg: ModelArgs) -> Dict[str, np.ndarray]:
             sd[pre + names["down"]] = get(lp["mlp"]["wout"]).T
         sd[pre + names["ln1"]] = get(lp["ln1"]["scale"])
         sd[pre + names["ln2"]] = get(lp["ln2"]["scale"])
+
+    for i, (lp, (mixer, ff)) in enumerate(zip(params["layers"], kinds)):
+        put_block(i, lp, mixer, ff)
+    if "mtp" in params:
+        pre = f"model.layers.{len(kinds)}."
+        for leaf, name in _MTP_HF_NAMES.items():
+            sd[pre + name] = (get(params["mtp"][leaf]).T if leaf == "eh_proj"
+                              else get(params["mtp"][leaf]["scale"]))
+        put_block(len(kinds), params["mtp"]["layer"], *kinds[-1])
     sd[names["final"]] = get(params["prenorm"]["scale"])
     if not cfg.tie_word_embeddings and params.get("head"):
         sd["lm_head.weight"] = get(params["head"]["whead"]).T[:V]

@@ -204,6 +204,10 @@ def get_hybrid_parallel_config(
             eligibility.batch_grain_reason(global_bsz, world_size, pp_deg,
                                            layers, vocab),
             eligibility.mamba_plan_reason(args.model, layers),
+            eligibility.latent_plan_reason(args.model, layers),
+            (eligibility.residual_streams_reason(
+                args.model, f"a pipelined plan (pp={pp_deg})")
+             if pp_deg > 1 else None),
             eligibility.capacity_dispatch_reason(
                 dispatcher=args.model.moe_dispatcher,
                 tokens=global_bsz // chunks * args.model.seq_length,

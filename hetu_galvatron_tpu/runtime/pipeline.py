@@ -123,12 +123,16 @@ class PipelineEngine:
         hier_dp: bool = False,
         hier_bucket_mb: float = 0.0,
     ):
-        from hetu_galvatron_tpu.analysis.eligibility import mixed_stack_reason
+        from hetu_galvatron_tpu.analysis.eligibility import (
+            mixed_stack_reason,
+            residual_streams_reason,
+        )
 
         reason = mixed_stack_reason(
             cfg, "the host pipeline engine (its stage programs tell dense "
             "and expert blocks apart by their trees and attend in every "
-            "block)", feed_forward_may_differ=True)
+            "block)", feed_forward_may_differ=True
+        ) or residual_streams_reason(cfg, "the host pipeline engine")
         if reason is not None:
             raise NotImplementedError(reason + "; run it at pp_deg=1")
         self.cfg = cfg

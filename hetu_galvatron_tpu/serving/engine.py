@@ -109,13 +109,15 @@ def _check_supported(cfg: ModelArgs, params: Params) -> None:
     from hetu_galvatron_tpu.analysis.eligibility import (
         mixed_stack_reason,
         own_multipliers_reason,
+        residual_streams_reason,
     )
 
     reason = mixed_stack_reason(
         cfg, "ServingEngine (paged key-value blocks for every layer, no "
         "convolution state and no state-space state)"
     ) or own_multipliers_reason(
-        cfg, "ServingEngine (its paged attention cores)")
+        cfg, "ServingEngine (its paged attention cores)"
+    ) or residual_streams_reason(cfg, "ServingEngine")
     if reason is not None:
         raise NotImplementedError(reason)
 

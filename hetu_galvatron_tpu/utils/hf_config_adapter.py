@@ -36,11 +36,14 @@ _FIELD_MAP = {
 _GEMMA_FAMILIES = {"gemma"}
 _LFM2_FAMILIES = {"lfm2", "lfm2_moe"}
 _GRANITE_HYBRID = "granitemoehybrid"
+# DeepSeek-V3's keys (latent attention, shared beside sigmoid-routed experts,
+# multi-token prediction) with the residual as several streams
+_XING = "xing4_0"
 _ROPE_FAMILIES = {"llama", "qwen2", "mistral", "mixtral", "olmoe",
-                  "qwen"} | _GEMMA_FAMILIES | _LFM2_FAMILIES
+                  "qwen", _XING} | _GEMMA_FAMILIES | _LFM2_FAMILIES
 _RMS_FAMILIES = _ROPE_FAMILIES | {"t5", _GRANITE_HYBRID}
 _SWIGLU_FAMILIES = {"llama", "qwen2", "mistral", "mixtral", "olmoe",
-                    "qwen", _GRANITE_HYBRID} | _LFM2_FAMILIES
+                    "qwen", _GRANITE_HYBRID, _XING} | _LFM2_FAMILIES
 # gemma-2/3 add sandwich norms, logit softcapping, query_pre_attn_scalar,
 # alternating sliding windows (v3: q/k-norm, dual rope) — none of which this
 # stack implements; mapping them through gemma-1 numerics would silently
@@ -150,6 +153,8 @@ def populate_model_args_from_hf(
                 moe_aux_loss_coeff=0.0)
     if family == _GRANITE_HYBRID:
         values.update(_granite_hybrid_values(d))
+    if family == _XING:
+        values.update(_xing_values(d))
     if family == "bert":
         # HF bert uses erf gelu everywhere (BertIntermediate + the MLM
         # transform); our "gelu" is the tanh approximation (gpt2's gelu_new)
@@ -193,6 +198,52 @@ def populate_model_args_from_hf(
     else:
         values["add_bias_linear"] = family not in bias_free
     return ModelArgs.model_validate(values)
+
+
+def _xing_values(d: Dict[str, Any]) -> Dict[str, Any]:
+    """Xing4.0 (XingChen-AGI; ``DeepseekV3Config``'s keys and the ``hc_*`` /
+    ``mhc_*`` ones): latent attention in every block, ``first_k_dense_replace``
+    leading dense blocks, then ``n_routed_experts`` sigmoid-routed experts
+    beside ``n_shared_experts`` shared ones, the residual as ``hc_mult``
+    streams, ``num_nextn_predict_layers`` further prediction depths."""
+    if int(d.get("n_group") or 1) != 1 or int(d.get("topk_group") or 1) != 1:
+        raise NotImplementedError(
+            f"{_XING} n_group={d.get('n_group')} topk_group="
+            f"{d.get('topk_group')}: the group-limited choice of experts is "
+            "not implemented (one group is)")
+    if d.get("scoring_func", "sigmoid") != "sigmoid" or d.get(
+            "topk_method", "noaux_tc") != "noaux_tc":
+        raise NotImplementedError(
+            f"{_XING} scoring_func={d.get('scoring_func')!r} topk_method="
+            f"{d.get('topk_method')!r}: sigmoid scores with the selection "
+            "bias (noaux_tc) are implemented")
+    n = int(d["num_hidden_layers"])
+    return dict(
+        model_type="moe", hf_layout="llama", moe_hf_layout="deepseek",
+        layer_types=["latent_attention"] * n,
+        num_dense_layers=int(d.get("first_k_dense_replace", 0)),
+        q_lora_rank=int(d["q_lora_rank"]),
+        kv_lora_rank=int(d["kv_lora_rank"]),
+        qk_nope_head_dim=int(d["qk_nope_head_dim"]),
+        qk_rope_head_dim=int(d["qk_rope_head_dim"]),
+        v_head_dim=int(d["v_head_dim"]),
+        rope_theta=float(d.get("rope_theta", 10000.0)),
+        moe_ffn_hidden_size=int(d["moe_intermediate_size"]),
+        num_experts=int(d["n_routed_experts"]),
+        num_shared_experts=int(d.get("n_shared_experts") or 0),
+        moe_layer_freq=int(d.get("moe_layer_freq", 1)),
+        moe_score_function="sigmoid", moe_dispatcher="dropless",
+        moe_norm_topk_prob=bool(d.get("norm_topk_prob", True)),
+        moe_norm_topk_eps=1e-20,
+        moe_routed_scaling_factor=float(d.get("routed_scaling_factor", 1.0)),
+        moe_router_enable_expert_bias=True, moe_aux_loss_coeff=0.0,
+        tie_word_embeddings=bool(d.get("tie_word_embeddings", False)),
+        hc_mult=int(d.get("hc_mult", 1)),
+        hc_sinkhorn_iters=int(d.get("hc_sinkhorn_iters", 20)),
+        hc_eps=float(d.get("hc_eps", 1e-6)),
+        hc_res_clamp_min=float(d.get("mhc_h_res_clamp_min", -30.0)),
+        hc_res_clamp_max=float(d.get("mhc_h_res_clamp_max", 30.0)),
+        num_nextn_predict_layers=int(d.get("num_nextn_predict_layers", 0)))
 
 
 def _granite_hybrid_values(d: Dict[str, Any]) -> Dict[str, Any]:

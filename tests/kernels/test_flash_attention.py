@@ -616,3 +616,79 @@ def test_choose_blocks_for_the_benchmark_cells():
     assert choose_blocks(32, 768, 768) == (256, 256)       # halved to fit
     assert choose_blocks(32, 384, 384) == (384, 384)       # under one tile
     assert choose_blocks(32, 100, 100) == (100, 100)       # one whole block
+
+
+# ---------------------------------------------------------------------------
+# a value width of its own (latent attention: q/k 192, v 128)
+# ---------------------------------------------------------------------------
+
+
+def _qkv_wide(B, S, N, K, D, Dv, dtype=jnp.float32, seed=5):
+    ks = jax.random.split(jax.random.key(seed), 4)
+    return (jax.random.normal(ks[0], (B, S, N, D), dtype),
+            jax.random.normal(ks[1], (B, S, K, D), dtype),
+            jax.random.normal(ks[2], (B, S, K, Dv), dtype),
+            jax.random.normal(ks[3], (B, S, N, Dv), dtype))
+
+
+# B, S, heads, kv heads, q/k width, v width, causal, segments, scale
+_OWN_V_WIDTH = {
+    "latent_ratio": (1, 256, 4, 4, 48, 32, True, False, 0.17),
+    "v_wider_than_qk": (2, 128, 2, 2, 16, 40, True, False, None),
+    "gqa_noncausal": (1, 256, 8, 2, 24, 16, False, False, None),
+    "segments_two_q_blocks": (1, 512, 2, 2, 48, 32, True, True, 0.2),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_OWN_V_WIDTH))
+def test_flash_with_a_value_width_of_its_own(case):
+    """Forward and the three gradients at ``Dv != D`` against the XLA core:
+    the output, dO and dv are ``Dv`` wide, q, k, dq and dk ``D`` wide, and
+    nothing is padded to the other's width."""
+    B, S, N, K, D, Dv, causal, seg, scale = _OWN_V_WIDTH[case]
+    q, k, v, do = _qkv_wide(B, S, N, K, D, Dv)
+    segs = ((jnp.arange(S)[None, :] // 200).astype(jnp.int32).repeat(B, 0)
+            if seg else None)
+    kw = dict(causal=causal, segment_ids=segs, scale=scale)
+    want, want_vjp = jax.vjp(lambda a, b, c: xla_sdpa(a, b, c, **kw), q, k, v)
+    got, got_vjp = jax.vjp(
+        lambda a, b, c: flash_sdpa(a, b, c, interpret=True, **kw), q, k, v)
+    assert got.shape == (B, S, N, Dv)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
+    for name, g, w in zip(("dq", "dk", "dv"), got_vjp(do), want_vjp(do)):
+        assert g.shape == w.shape, name
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   rtol=2e-4, atol=2e-4, err_msg=name)
+
+
+# sha256 (first 16 hex) of out | dq | dk | dv in interpret mode on seeded
+# inputs, recorded from the kernels as they were before v had a width of its
+# own (commit 98a0ba2): with ``Dv == D`` every block, every scratch and every
+# instruction is what it was
+_TODAYS = {
+    "mha_f32": ((2, 256, 4, 4, 32, jnp.float32, False), "bbb22d8f65cd9718"),
+    "gqa_bf16_segments": ((1, 384, 8, 2, 64, jnp.bfloat16, True),
+                          "ccf54d8d028f60ee"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_TODAYS))
+def test_flash_at_equal_widths_is_bit_equal_to_the_kernels_before(case):
+    import hashlib
+
+    (B, S, N, K, D, dtype, seg), want = _TODAYS[case]
+    ks = jax.random.split(jax.random.key(11), 4)
+    q = jax.random.normal(ks[0], (B, S, N, D), dtype)
+    k = jax.random.normal(ks[1], (B, S, K, D), dtype)
+    v = jax.random.normal(ks[2], (B, S, K, D), dtype)
+    do = jax.random.normal(ks[3], (B, S, N, D), dtype)
+    segs = ((jnp.arange(S)[None, :] // 96).astype(jnp.int32).repeat(B, 0)
+            if seg else None)
+    out, vjp = jax.vjp(lambda a, b, c: flash_sdpa(
+        a, b, c, causal=True, interpret=True, segment_ids=segs,
+        scale=0.11 if seg else None), q, k, v)
+    h = hashlib.sha256()
+    for t in (out,) + vjp(do):
+        h.update(np.asarray(t.astype(jnp.float32)).tobytes())
+    assert h.hexdigest()[:16] == want

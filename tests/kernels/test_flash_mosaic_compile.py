@@ -31,8 +31,13 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-# B, S, Sk, heads, kv heads, head dim, dtype, causal, segments, dropout
+# B, S, Sk, heads, kv heads, head dim (q/k, or (q/k, v)), dtype, causal,
+# segments, dropout
 _CASES = {
+    "latent_cell_192_128": (1, 4096, 4096, 32, 32, (192, 128), jnp.bfloat16,
+                            True, False, 0.0),
+    "latent_f32_segments": (1, 1024, 1024, 4, 4, (192, 128), jnp.float32,
+                            True, True, 0.0),
     "gpt2xl_cell": (8, 1024, 1024, 25, 25, 64, jnp.bfloat16, True, False, 0.0),
     "mistral_cell": (1, 4096, 4096, 32, 8, 128, jnp.bfloat16, True, False,
                      0.0),
@@ -50,6 +55,7 @@ _CASES = {
 @pytest.mark.parametrize("case", sorted(_CASES))
 def test_flash_forward_and_backward_compile_for_v5e(one_chip, case):
     B, S, Sk, N, K, D, dtype, causal, seg, drop = _CASES[case]
+    D, Dv = D if isinstance(D, tuple) else (D, D)
 
     def spec(shape, dt):
         return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
@@ -64,7 +70,7 @@ def test_flash_forward_and_backward_compile_for_v5e(one_chip, case):
 
     compiled = jax.jit(fwd_bwd).lower(
         spec((B, S, N, D), dtype), spec((B, Sk, K, D), dtype),
-        spec((B, Sk, K, D), dtype), spec((B, S, N, D), dtype),
+        spec((B, Sk, K, Dv), dtype), spec((B, S, N, Dv), dtype),
         spec((B, S), jnp.int32),
         spec((), jax.random.key(0).dtype)).compile()
     hlo = compiled.as_text()

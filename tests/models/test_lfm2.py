@@ -465,6 +465,67 @@ def test_both_bodies_of_the_held_dispatch(case, short, monkeypatch):
                                    err_msg=name)
 
 
+def _leaving_nan_in_rows_of_no_group(real):
+    """``moe._grouped_matmul`` as the chip may run it: what it writes to a
+    row that belongs to no group is unspecified, forward (the product) and
+    backward (the gradient to the rows), so both hold NaN there."""
+    @jax.custom_vjp
+    def product_leaves_nan(out, tail):
+        return jnp.where(tail, jnp.nan, out)
+
+    product_leaves_nan.defvjp(
+        lambda out, tail: (jnp.where(tail, jnp.nan, out), tail),
+        lambda tail, g: (jnp.where(tail, 0.0, g).astype(g.dtype), None))
+
+    @jax.custom_vjp
+    def gradient_leaves_nan(rows, tail):
+        return rows
+
+    gradient_leaves_nan.defvjp(
+        lambda rows, tail: (rows, tail),
+        lambda tail, g: (jnp.where(tail, jnp.nan, g).astype(g.dtype), None))
+
+    def grouped_matmul(rows, weights, group_sizes, out_dtype):
+        tail = (jnp.arange(rows.shape[0])[:, None]
+                >= jnp.sum(group_sizes))
+        return product_leaves_nan(
+            real(gradient_leaves_nan(rows, tail), weights, group_sizes,
+                 out_dtype), tail)
+    return grouped_matmul
+
+
+@pytest.mark.parametrize("case", ["balanced", "the_short_buffer_filled",
+                                  "one_row_over_the_short_buffer"])
+def test_rows_of_no_group_reach_no_result_and_no_gradient(case, monkeypatch):
+    """Whatever the grouped matmuls leave in the rows of the sorted buffer
+    that belong to no group (a share's tail: the routes to absent experts),
+    forward and backward, the layer's output and its gradients to the rows,
+    the expert weights and the ROUTER are what they are with zeros there.
+    On the chip such a row once held an inf, the transpose of ``ys * ws``
+    handed the weights ``0 * inf``, and a step's gradient was NaN from the
+    router back (PERF.md section 6, PR 40)."""
+    p, x, _ = _held_dispatch_case(case)
+    cfg = LAYER.model_copy(update=dict(moe_held_experts=2,
+                                       moe_first_held_expert=2))
+    cot = jax.random.normal(jax.random.key(9), x.shape, jnp.float32)
+
+    def program(x, win, wout, router):
+        q = {**p, "router": router, "win": win[2:4], "wout": wout[2:4]}
+        y, _, _ = apply_moe_mlp(q, x, cfg, compute_dtype=jnp.float32)
+        return jnp.sum(y * cot), y
+
+    args = (x, p["win"], p["wout"], p["router"])
+    grad = jax.grad(program, argnums=(0, 1, 2, 3), has_aux=True)
+    want, y_want = grad(*args)
+    monkeypatch.setattr(moe, "_grouped_matmul",
+                        _leaving_nan_in_rows_of_no_group(moe._grouped_matmul))
+    got, y = grad(*args)
+    np.testing.assert_array_equal(y, y_want)
+    for name, g, w in zip(("x", "win", "wout", "router"), got, want):
+        assert bool(jnp.isfinite(g).all()), name
+        np.testing.assert_array_equal(g, w, err_msg=name)
+
+
 def _primitives(jaxpr, counts=None):
     """How often each primitive occurs in ``jaxpr``, the bodies of its
     calls, conditionals and loops included."""
@@ -585,7 +646,7 @@ def test_a_skewed_router_drops_no_route_and_the_gauge_says_so(held, skewed,
 
 TODAY = sorted(os.path.basename(p) for p in glob.glob(
     os.path.join(ZOO, "*.yaml"))
-    if not any(word in p for word in ("lfm2", "t5", "granite")))
+    if not any(word in p for word in ("lfm2", "t5", "granite", "xing")))
 
 
 def _the_parents_tree(key, cfg):

@@ -69,7 +69,11 @@ PRESETS = {
 # and what only a layer that holds a share of its experts writes
 MOE_ONLY = {"moe_imbalance": ("moe",),
             "lfm2_local_routes_pct": ("lfm2",),
-            "lfm2_moe_imbalance": ("lfm2",)}
+            "lfm2_moe_imbalance": ("lfm2",),
+            # the same readers under the newer cell's names: a held share's
+            # gauges, which of these presets the lfm2 one alone writes
+            "xing_moe_imbalance": ("lfm2",),
+            "xing_local_routes_pct": ("lfm2",)}
 # the named scopes the HLO-metadata join will look for (PERF.md section 7)
 SCOPES = ("mixer/short_conv/in_proj", "mixer/short_conv/gate_conv",
           "mixer/short_conv/out_proj", "attn/qk_norm", "moe/route",
@@ -610,7 +614,11 @@ def test_the_mamba_lists_are_what_scope_instructions_gave():
             params, tokens).compile().as_text()
     expected = _scope_instructions_of_pr35(text, granite_scopes.SCOPES)
     assert all(expected[s] for s in granite_scopes.SCOPES)
-    assert trace_analysis.step_hlo(text)["scopes"] == expected
+    # (beside them the step keeps the further prediction depth's lists,
+    # empty for a model without one)
+    kept = trace_analysis.step_hlo(text)["scopes"]
+    assert {s: kept[s] for s in expected} == expected
+    assert all(kept[s] == [] for s in trace_analysis.MTP_SCOPES)
     assert trace_analysis.scope_instructions(
         text, granite_scopes.SCOPES)["scopes"] == expected
     assert tuple(expected) == trace_analysis.MIXER_SCOPES["mamba"]
