@@ -677,6 +677,7 @@ def train(args) -> Dict[str, Any]:
     # what the compiled step contains (filled after the first step)
     from hetu_galvatron_tpu.observability.trace_analysis import (
         SSD_SCOPE,
+        cores_recomputed,
         record_step_scopes,
         step_hlo,
     )
@@ -1121,6 +1122,11 @@ def train(args) -> Dict[str, Any]:
                         mosaic_custom_calls=found["mosaic_custom_calls"],
                         collectives=found["collectives"],
                         scope_instructions=found["scopes"])
+                    # attention cores run again under per-layer remat: the
+                    # flash forward calls the map puts in the recompute phase
+                    step_report["cores_recomputed"] = cores_recomputed(found)
+                    get_registry().gauge("step/cores_recomputed").set(
+                        step_report["cores_recomputed"])
                     step_report["step_map"] = {
                         "instructions": len(found["map"]["instructions"]),
                         "inferred": len(found["map"]["inferred"]),
@@ -1156,7 +1162,8 @@ def train(args) -> Dict[str, Any]:
                     + f", {step_report['mosaic_custom_calls']} Mosaic calls"
                     + (f" ({step_report['ssd_mosaic_calls']} under "
                        f"{SSD_SCOPE})" if "ssd_mosaic_calls" in step_report
-                       else "") + ","
+                       else "")
+                    + f", {step_report['cores_recomputed']} cores recomputed,"
                     f" static live peak "
                     f"{step_report['static_memory']['live_peak'] / 2**30:.3f}"
                     " GiB")
@@ -1207,6 +1214,10 @@ def train(args) -> Dict[str, Any]:
             # over the host engine's stage backward programs; None for
             # the compiled engine, which does not count them
             "mosaic_custom_calls": step_report.get("mosaic_custom_calls"),
+            # flash forward kernels that step runs a second time under
+            # per-layer remat (the gauge step/cores_recomputed; 0 = every
+            # core's output is kept); None for the pp engines
+            "cores_recomputed": step_report.get("cores_recomputed"),
             # XLA's static memory of the compiled pp=1 step, per device, in
             # bytes (the step/static_bytes gauges); None for the pp engines
             "static_memory": step_report.get("static_memory"),

@@ -67,10 +67,13 @@ class ModelArgs(BaseModel):
     # runs keep the GSPMD vocab-parallel CE; see modules.cross_entropy_loss)
     use_fused_ce: bool = False
     # rematerialization policy for per-layer activation checkpointing:
-    # "full" recomputes everything (min memory); "dots" saves matmul outputs
-    # so the backward recomputes only cheap elementwise ops (MXU FLOPs are
-    # the expensive part on TPU); "dots_no_batch" saves only non-batch dots
-    # (XLA's offloading-friendly middle ground)
+    # "full" keeps the block's input and, of a block that attends through
+    # the flash kernels, the attention core's output with its row statistics
+    # (as large as the input; the layer's S x S work then runs once), and
+    # recomputes everything else (min memory); "dots" also saves matmul
+    # outputs so the backward recomputes only cheap elementwise ops (MXU
+    # FLOPs are the expensive part on TPU); "dots_no_batch" saves only
+    # non-batch dots (XLA's offloading-friendly middle ground)
     remat_policy: Literal["full", "dots", "dots_no_batch"] = "full"
     attention_dropout: float = 0.0
     hidden_dropout: float = 0.0

@@ -318,20 +318,32 @@ def remat(fn, cfg: ModelArgs):
     """Per-layer activation checkpointing with the configured policy
     (reference parallel.py:213-243 wraps with torch checkpoint_wrapper; the
     TPU lever is WHICH values the backward may keep — saving MXU outputs
-    ("dots") trades a little memory for skipping matmul recompute)."""
-    if cfg.remat_policy == "dots":
-        return jax.checkpoint(
-            fn, policy=jax.checkpoint_policies.checkpoint_dots)
-    if cfg.remat_policy == "dots_no_batch":
-        return jax.checkpoint(
-            fn,
-            policy=jax.checkpoint_policies.checkpoint_dots_with_no_batch_dims)
-    if cfg.remat_policy != "full":
+    ("dots") trades a little memory for skipping matmul recompute).
+
+    Under every policy a flash attention core's output and row statistics
+    are kept (the two values ``flash_attention._flash_fwd`` names): the
+    output is as large as the block's input, which is kept anyway, and
+    producing it again is the layer's whole S x S work. So ``full`` keeps the
+    block's input and, where the block attends through the flash kernels,
+    that pair; the recomputed forward then holds no forward kernel. A block
+    without a flash core (the XLA core, a convolution or state-space mixer,
+    an MLP alone) names nothing and is recomputed whole."""
+    from hetu_galvatron_tpu.ops.pallas.flash_attention import (
+        KEPT_LSE, KEPT_OUT)
+
+    policies = jax.checkpoint_policies
+    base = {"full": None, "dots": policies.checkpoint_dots,
+            "dots_no_batch": policies.checkpoint_dots_with_no_batch_dims}
+    if cfg.remat_policy not in base:
         # model_copy(update=...) skips pydantic validation, so a typo'd
         # policy would otherwise silently run full recompute
         raise ValueError(f"unknown remat_policy {cfg.remat_policy!r} "
                          "(full | dots | dots_no_batch)")
-    return jax.checkpoint(fn)
+    policy = policies.save_only_these_names(KEPT_OUT, KEPT_LSE)
+    if base[cfg.remat_policy] is not None:
+        policy = policies.save_from_both_policies(base[cfg.remat_policy],
+                                                  policy)
+    return jax.checkpoint(fn, policy=policy)
 
 
 # fold_in stream bases partitioning one per-step dropout key into disjoint

@@ -495,6 +495,10 @@ OWN_SCOPES = MIXER_SCOPES["mamba"] + MTP_SCOPES
 # counts (``ssd/mosaic_calls``; ops/pallas/ssd.py traces under it)
 SSD_SCOPE = "mixer/mamba/ssd"
 
+# the flash forward kernel's name (ops/pallas/flash_attention.py), which its
+# instruction in the compiled step carries (``flash_attention_fwd.3``)
+FLASH_FWD_CALL = "flash_attention_fwd"
+
 _HEADER = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) \(")
 _INSTRUCTION = re.compile(r"^\s+(?:ROOT )?%?([\w.\-]+) = .*? ([\w\-]+)\(")
 _OP_NAME = re.compile(r'op_name="([^"]*)"')
@@ -787,6 +791,18 @@ def scope_instructions(hlo_text: str, scopes: Sequence[str]
     trace event's name is an instruction's."""
     read = step_hlo(hlo_text, scopes)
     return {k: read[k] for k in ("scopes", "instructions", "mosaic_calls")}
+
+
+def cores_recomputed(found: Dict[str, Any]) -> int:
+    """The flash forward kernels of a step (``found``: :func:`step_hlo`'s
+    answer) that its map puts in the ``recompute`` phase: attention cores
+    that per-layer remat runs a second time. 0 where ``modules.remat`` keeps
+    every core's output and row statistics (the gauge
+    ``step/cores_recomputed``), and in a step without the kernels."""
+    phases = found["map"]["instructions"]
+    return sum(name.startswith(FLASH_FWD_CALL)
+               and phases[name][1] == "recompute"
+               for name in found["mosaic_calls"])
 
 
 # what ``step_hlo`` found in the step program this process last reported

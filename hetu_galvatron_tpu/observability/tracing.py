@@ -38,8 +38,11 @@ The spans of the launcher (``cli/train_dist.py``), all flat siblings:
   and optimizer init), ``setup/resume``, ``setup/step_report`` (the
   compiled step's HLO text and ``memory_analysis()`` after the first
   call, which also sets the gauges ``step/static_bytes{part=arguments|outputs|
-  aliased|temporaries|generated_code|live_peak}``, and keeps every
-  instruction's scope, phase and collective class for a reader of a trace:
+  aliased|temporaries|generated_code|live_peak}`` and
+  ``step/cores_recomputed`` (the flash forward kernels per-layer remat
+  runs a second time; 0 where ``modules.remat`` keeps every core's
+  results), and keeps every instruction's scope, phase and collective
+  class for a reader of a trace:
   ``trace_analysis.step_hlo``; :class:`TraceCapture` writes them beside the
   trace as ``step_map.json`` when its window closes).
 """

@@ -35,6 +35,24 @@ as 128 partial sums a row (one cross-lane reduction a chunk, one more at the
 end). The backward recomputes p per tile from the saved logsumexp, so
 neither direction materializes [S, S]. Block sizes come from the call's
 shapes (``choose_blocks``).
+
+What a rematted block keeps. The differentiated forward (``_flash_fwd``)
+names the two results the backward kernels read, ``KEPT_OUT`` (the output)
+and ``KEPT_LSE`` (the row statistics), and ``modules.remat`` saves what
+carries those names, so the recomputed forward of a block has no forward
+kernel left in it: the S x S work of a layer runs once. Both are kept in
+the layout HBM does not pad. The output as [B, S, N * Dv] rows, the form
+the out-projection reads and exactly the bytes of the block's input, which
+remat keeps anyway: head-major [B, N, S, Dv] at a head width of 64 is
+padded to 128 lanes, twice its numbers (0.83 GiB against 0.41 over GPT-2
+XL's 16 layers at batch 8; AOT, PR 41). lse as [B, N, S] rows, not as the
+[B, N, S, 1] column the kernel writes: a trailing singleton is padded 128
+times (64 to 105 MiB a call at the benchmark's shapes, more than the
+output). The backward turns the rows back to head-major where the
+recomputed forward used to turn the kernel's output the other way, one
+relayout either way; the dk/dv kernel reads lse as rows anyway and the dq
+kernel gets its column by ``[..., None]``. q, k and v are not named: the
+projection recomputes them as before.
 """
 
 from __future__ import annotations
@@ -44,10 +62,16 @@ import math
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = float(jnp.finfo(jnp.float32).min)
+
+# ``checkpoint_name``s of the forward's two results that the backward kernels
+# read; ``modules.remat`` keeps the values under these names
+KEPT_OUT = "flash_attention_out"
+KEPT_LSE = "flash_attention_lse"
 
 
 def keep_mask(seed, bn, qpos, kpos, rate: float):
@@ -734,16 +758,24 @@ def _flash_fwd(q, k, v, segments, dropout_seed, causal, interpret, block_q,
                                       causal=causal, interpret=interpret,
                                       block_q=block_q, block_k=block_k,
                                       dropout_rate=dropout_rate, scale=scale)
-    return (out.transpose(0, 2, 1, 3),
-            (qh, kh, vh, out, lse, segments, dropout_seed))
+    # the pair per-layer remat keeps (``modules.remat``), each in the layout
+    # HBM does not pad (module docstring); what the block goes on with is
+    # derived from the kept output, so the recomputed forward needs no kernel
+    B, N, S, Dv = out.shape
+    out_rows = checkpoint_name(
+        out.transpose(0, 2, 1, 3).reshape(B, S, N * Dv), KEPT_OUT)
+    lse_rows = checkpoint_name(lse[..., 0], KEPT_LSE)
+    return (out_rows.reshape(B, S, N, Dv),
+            (qh, kh, vh, out_rows, lse_rows, segments, dropout_seed))
 
 
 def _flash_bwd(causal, interpret, block_q, block_k, dropout_rate, scale, res,
                g):
-    qh, kh, vh, out, lse, segments, dropout_seed = res
+    qh, kh, vh, out_rows, lse_rows, segments, dropout_seed = res
+    out = out_rows.reshape(g.shape).transpose(0, 2, 1, 3)
     dq, dk, dv = flash_attention_bwd_hmajor(
-        qh, kh, vh, out, lse, g.transpose(0, 2, 1, 3), segments,
-        dropout_seed, causal=causal, interpret=interpret,
+        qh, kh, vh, out, lse_rows[..., None], g.transpose(0, 2, 1, 3),
+        segments, dropout_seed, causal=causal, interpret=interpret,
         block_q=block_q, block_k=block_k, dropout_rate=dropout_rate,
         scale=scale)
     return (dq.transpose(0, 2, 1, 3), dk.transpose(0, 2, 1, 3),

@@ -119,3 +119,49 @@ def test_ssd_scan_forward_and_backward_compile_for_v5e(one_chip, case):
     assert len(calls) == 2, calls
     assert "ssd_scan_bwd" in calls[0] and "ssd_scan_fwd" in calls[1], calls
     assert set(calls) <= set(found["scopes"][ssd.SCOPE])
+
+
+@pytest.mark.parametrize("wrapper,forwards,recomputed", [
+    ("remat", 1, 0), ("plain_checkpoint", 2, 1)])
+def test_a_rematted_block_holds_one_forward_kernel(one_chip, wrapper,
+                                                   forwards, recomputed):
+    """The real kernels under per-layer remat, at GPT-2 XL's widths: the
+    gradient of a block wrapped by ``modules.remat`` compiles to one
+    ``flash_attention_fwd`` (three Mosaic calls: what
+    ``benchmark/check.py`` asks of a layer), none of it in the recompute
+    phase; under plain ``jax.checkpoint`` there are two, and
+    ``trace_analysis.cores_recomputed`` counts the second."""
+    from hetu_galvatron_tpu.core.args_schema import ModelArgs
+    from hetu_galvatron_tpu.models import modules as M
+    from hetu_galvatron_tpu.observability.trace_analysis import (
+        FLASH_FWD_CALL,
+        cores_recomputed,
+        step_hlo,
+    )
+
+    B, S, H, N = 2, 1024, 1600, 25
+    cfg = ModelArgs(hidden_size=H, num_hidden_layers=1,
+                    num_attention_heads=N, vocab_size=128,
+                    max_position_embeddings=S, seq_length=S)
+    shapes = jax.eval_shape(
+        lambda k: M.init_decoder_layer(k, cfg)[0], jax.random.key(0))
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        shapes)
+    x = jax.ShapeDtypeStruct((B, S, H), jnp.bfloat16, sharding=one_chip)
+
+    def block(p, h):
+        return M.apply_decoder_layer(p, h, cfg, sdpa_fn=flash_sdpa)
+
+    wrapped = M.remat(block, cfg) if wrapper == "remat" else jax.checkpoint(
+        block)
+    # (a loss whose gradient needs the block's output: under a plain sum
+    # the first forward pass is dead code and only the recomputed one stays)
+    compiled = jax.jit(jax.grad(
+        lambda p, h: jnp.sum(wrapped(p, h).astype(jnp.float32) ** 2),
+        argnums=(0, 1))).lower(params, x).compile()
+    found = step_hlo(compiled.as_text())
+    fwd = [n for n in found["mosaic_calls"] if n.startswith(FLASH_FWD_CALL)]
+    assert len(fwd) == forwards, sorted(found["mosaic_calls"])
+    assert found["mosaic_custom_calls"] == forwards + 2
+    assert cores_recomputed(found) == recomputed

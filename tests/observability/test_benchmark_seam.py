@@ -287,6 +287,22 @@ def test_the_step_report_says_whether_the_scan_kernels_engaged(run):
     assert "attention cores: 1 x mamba2, 1 x xla" in run["log"]
 
 
+def test_the_step_report_counts_the_cores_remat_runs_again(run):
+    """``step/cores_recomputed``: the flash forward kernels the compiled
+    step holds in its recompute phase. The gauge, ``train()``'s result and
+    the ``step report:`` line (its reader) say the same number in every
+    preset: 0 here, where a step compiled for a CPU holds no kernel (what
+    the count is on a step that holds them:
+    ``test_step_map.py::test_cores_recomputed_*`` and the compile for a
+    described v5e in ``tests/kernels/test_flash_mosaic_compile.py``)."""
+    gauges = [m.value for m in run["registry"].metrics()
+              if m.name == "step/cores_recomputed"]
+    (report,) = [line for line in run["log"].splitlines()
+                 if "step report:" in line]
+    assert gauges == [0] and run["result"]["cores_recomputed"] == 0
+    assert ", 0 cores recomputed, static live peak " in report
+
+
 def test_a_mosaic_call_is_counted_under_its_scope():
     from hetu_galvatron_tpu.observability.trace_analysis import (
         SSD_SCOPE,

@@ -215,6 +215,42 @@ def test_the_map_holds_the_events_of_a_trace_and_nothing_else():
         read["instructions"]
 
 
+# a block's flash kernels as a step holds them: the forward in the forward
+# pass, a second forward in the recomputed one (what plain jax.checkpoint
+# leaves there), both backward kernels, and a kernel of another name
+# recomputed beside them
+_KERNEL = ('  %{name} = f32[8]{{0}} custom-call(%a), custom_call_target='
+           '"tpu_custom_call", metadata={{op_name="jit(step)/while/body/'
+           'closed_call/{stack}/pallas_call"}}\n')
+_BACKWARD = "transpose(jvp(attn/core))/checkpoint/"
+_CORES = {
+    "flash_attention_fwd.1": "jvp(attn/core)/flash_attention_fwd",
+    "flash_attention_fwd.2": _BACKWARD + "rematted_computation/"
+                             "flash_attention_fwd",
+    "flash_attention_bwd_dq.1": _BACKWARD + "flash_attention_bwd_dq",
+    "flash_attention_bwd_dkv.1": _BACKWARD + "flash_attention_bwd_dkv",
+    "ssd_scan_fwd.1": "transpose(jvp(mixer/mamba))/checkpoint/"
+                      "rematted_computation/ssd/ssd_scan_fwd",
+}
+
+
+@pytest.mark.parametrize("left_out,expected", [
+    ((), 1),                            # the core runs again
+    (("flash_attention_fwd.2",), 0),    # its results were kept
+    (tuple(_CORES), 0),                 # a step without kernels
+])
+def test_cores_recomputed_counts_the_flash_forwards_of_the_recompute_phase(
+        left_out, expected):
+    hlo = ("HloModule jit_step\n\nENTRY %main (a: f32[8]) -> f32[8] {\n"
+           "  %a = f32[8]{0} parameter(0)\n"
+           + "".join(_KERNEL.format(name=n, stack=s)
+                     for n, s in _CORES.items() if n not in left_out)
+           + "  ROOT %copy.1 = f32[8]{0} copy(%a)\n}\n")
+    found = step_hlo(hlo)
+    assert found["mosaic_custom_calls"] == len(_CORES) - len(left_out)
+    assert trace_analysis.cores_recomputed(found) == expected
+
+
 # four chips: a collective under its own name, XLA:TPU's three fusions of an
 # asynchronous all-gather (the middle one rides a matmul), a reduce-scatter
 # fused as an all-reduce and a slice, an asynchronous pair under its own names
