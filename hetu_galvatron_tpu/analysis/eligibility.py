@@ -150,10 +150,15 @@ LATENT_REASON = ("latent_attention block: the low-rank q and kv projections "
                  "reduce-scatter matmuls (ops/overlap.py takes one fused qkv "
                  "product)")
 
+KDA_REASON = ("kda block: the delta-rule block's projections are not cut "
+              "for the ring all-gather / reduce-scatter matmuls, and its "
+              "recurrence runs over the whole sequence on one shard")
+
 # why a block whose mixer is not plain attention keeps its matmuls on GSPMD,
 # by mixer kind
 MIXER_OVERLAP_REASON = {"conv": CONV_REASON, "mamba": MAMBA_REASON,
-                        "latent_attention": LATENT_REASON}
+                        "latent_attention": LATENT_REASON,
+                        "kda": KDA_REASON}
 
 
 def _uncut_mixer_reason(cfg: Any, layers: Any, mixer: str, name: str,
@@ -200,6 +205,20 @@ def latent_plan_reason(cfg: Any, layers: Any) -> Optional[str]:
         "its low-rank projections are not cut over the tp axis, and the "
         "ring / Ulysses cores take no softmax scale and no value width of "
         "their own); use dp / ZeRO and ep for this model")
+
+
+def kda_plan_reason(cfg: Any, layers: Any) -> Optional[str]:
+    """Why a plan cannot run this model's kda blocks; None when it can (or
+    the model has none). The block's parameters carry no axis that tensor
+    parallelism shards (heads on the tp axis is not written), and its
+    convolutions and its matrix-valued state run over the whole sequence,
+    so a block whose plan cuts the sequence (cp, Ulysses) would carry a
+    state across shards it cannot see."""
+    return _uncut_mixer_reason(
+        cfg, layers, "kda", "Kimi Delta Attention",
+        "its heads are not cut over the tp axis and its recurrence needs "
+        "the whole sequence on one shard); use dp / ZeRO and ep for this "
+        "model")
 
 
 def residual_streams_reason(cfg: Any, what: str) -> Optional[str]:

@@ -184,7 +184,7 @@ def train(args) -> Dict[str, Any]:
         from hetu_galvatron_tpu.models.modules import ATTENDING_MIXERS
 
         kinds = cfg.block_kinds(len(hpc.layers))
-        operators = {"conv": "short_conv", "mamba": "mamba2"}
+        operators = {"conv": "short_conv", "mamba": "mamba2", "kda": "kda"}
         attention_cores = [
             attention_core(s.cp_size > 1, bool(s.sp and s.tp_size > 1),
                            use_flash) if mixer in ATTENDING_MIXERS
@@ -678,6 +678,7 @@ def train(args) -> Dict[str, Any]:
     from hetu_galvatron_tpu.observability.trace_analysis import (
         SSD_SCOPE,
         cores_recomputed,
+        kda_loops,
         record_step_scopes,
         step_hlo,
     )
@@ -1116,7 +1117,8 @@ def train(args) -> Dict[str, Any]:
                 with span("setup/step_report"):
                     compiled = fn.lower(out[0], out[1], b).compile()
                     # (as_text: 0.2 s on four chips)
-                    found = step_hlo(compiled.as_text())
+                    hlo_text = compiled.as_text()
+                    found = step_hlo(hlo_text)
                     record_step_scopes(found)
                     step_report.update(
                         mosaic_custom_calls=found["mosaic_custom_calls"],
@@ -1131,6 +1133,17 @@ def train(args) -> Dict[str, Any]:
                         "instructions": len(found["map"]["instructions"]),
                         "inferred": len(found["map"]["inferred"]),
                         "unnamed": len(found["map"]["tails"])}
+                    if any(m == "kda" for m, _ in kinds):
+                        # how many blocks run the delta rule and at which
+                        # chunk length, by the compiled step's own loops
+                        # (exact where the chunk divides the sequence)
+                        loops = kda_loops(hlo_text)
+                        step_report["kda"] = {
+                            "blocks": loops["blocks"],
+                            "chunk": -(-cfg.seq_length
+                                       // max(loops["chunks"], 1))}
+                        for part, v in step_report["kda"].items():
+                            get_registry().gauge(f"kda/{part}").set(v)
                     if any(m == "mamba" for m, _ in kinds):
                         # whether the scan's kernels engaged: the Mosaic
                         # calls under its scope, 0 = the jax.numpy form
@@ -1162,6 +1175,9 @@ def train(args) -> Dict[str, Any]:
                     + f", {step_report['mosaic_custom_calls']} Mosaic calls"
                     + (f" ({step_report['ssd_mosaic_calls']} under "
                        f"{SSD_SCOPE})" if "ssd_mosaic_calls" in step_report
+                       else "")
+                    + (", kda/blocks {blocks} kda/chunk {chunk}".format(
+                        **step_report["kda"]) if "kda" in step_report
                        else "")
                     + f", {step_report['cores_recomputed']} cores recomputed,"
                     f" static live peak "
@@ -1235,6 +1251,10 @@ def train(args) -> Dict[str, Any]:
             # the Mosaic calls among those under mixer/mamba/ssd (the gauge
             # ssd/mosaic_calls): 0 where the scan ran in its jax.numpy form
             "ssd_mosaic_calls": step_report.get("ssd_mosaic_calls"),
+            # the blocks that run Kimi Delta Attention and their chunk
+            # length, by the compiled step's loops (the gauges kda/blocks
+            # and kda/chunk); None for a model without such a block
+            "kda": step_report.get("kda"),
             "exit_code": exit_code}
 
 

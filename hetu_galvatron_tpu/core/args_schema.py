@@ -115,8 +115,12 @@ class ModelArgs(BaseModel):
     # feed_forward.expert_bias
     # "deepseek" = olmoe's names, mlp.gate.e_score_correction_bias for the
     # selection bias and mlp.shared_experts.{gate,up,down}_proj for the
-    # shared expert: the one layout with a slot for it
-    moe_hf_layout: Literal["mixtral", "olmoe", "lfm2", "deepseek"] = "mixtral"
+    # shared expert; "kimi" = mixtral's names with
+    # block_sparse_moe.gate.e_score_correction_bias and
+    # block_sparse_moe.shared_experts.{gate,up,down}_proj: the two layouts
+    # with a slot for a shared expert
+    moe_hf_layout: Literal["mixtral", "olmoe", "lfm2", "deepseek",
+                           "kimi"] = "mixtral"
     # RMSNorm over the WHOLE projected q and k widths (all heads together,
     # one learned scale each), after the qkv product and before the split
     # into heads and RoPE (OLMoE; HF ``self_attn.{q,k}_norm``)
@@ -129,14 +133,15 @@ class ModelArgs(BaseModel):
     # published keys and ``moe_layer_freq``. ``layer_types``: each block's
     # mixer, "full_attention", "conv" (a gated short convolution,
     # modules.apply_short_conv), "mamba" (a Mamba-2 state-space block,
-    # modules.apply_mamba2) or "latent_attention" (DeepSeek-V2/V3's
-    # low-rank q and kv projections, modules.apply_latent_attention);
-    # None = every block attends.
+    # modules.apply_mamba2), "latent_attention" (DeepSeek-V2/V3's
+    # low-rank q and kv projections, modules.apply_latent_attention) or
+    # "kda" (Kimi Delta Attention: a gated delta rule with a decay a
+    # channel, modules.apply_kda); None = every block attends.
     # ``num_dense_layers``: so many leading blocks of an expert model keep
     # a dense MLP of ``ffn_hidden_size``
     layer_types: Optional[
         List[Literal["full_attention", "conv", "mamba",
-                     "latent_attention"]]] = None
+                     "latent_attention", "kda"]]] = None
     num_dense_layers: int = 0
     conv_L_cache: int = 3   # taps of a conv block's depthwise convolution
     conv_bias: bool = False
@@ -186,15 +191,27 @@ class ModelArgs(BaseModel):
     residual_multiplier: float = 1.0
     logits_scaling: float = 1.0
     # a "latent_attention" block (HF ``DeepseekV3Attention``): q through a
-    # ``q_lora_rank`` bottleneck with its RMSNorm, k and v through one of
-    # ``kv_lora_rank``; a head's query and key are ``qk_nope_head_dim``
-    # values without positions beside ``qk_rope_head_dim`` rotated ones
-    # (the rotated key one for all heads), its value ``v_head_dim`` wide
-    q_lora_rank: int = 0
+    # ``q_lora_rank`` bottleneck with its RMSNorm (None, as a config.json
+    # writes null, or 0: one full-rank projection and no norm), k and v
+    # through one of ``kv_lora_rank``; a head's query and key are
+    # ``qk_nope_head_dim`` values without positions beside
+    # ``qk_rope_head_dim`` further ones (rotated where the model has
+    # positions; the key's one for all heads), its value ``v_head_dim`` wide
+    q_lora_rank: Optional[int] = 0
     kv_lora_rank: int = 0
     qk_nope_head_dim: int = 0
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
+    # a "kda" block (Kimi Delta Attention, arXiv:2510.26692; the released
+    # ``KimiDeltaAttention``): ``kda_num_heads`` heads each carry a state of
+    # ``kda_head_dim x kda_head_dim`` (keys x values) over the sequence,
+    # decayed a channel and updated by the delta rule; q, k and v each pass
+    # a depthwise causal convolution of ``kda_conv_kernel`` taps; the
+    # recurrence is computed ``kda_chunk_size`` positions at a time
+    kda_num_heads: int = 0
+    kda_head_dim: int = 128
+    kda_conv_kernel: int = 4
+    kda_chunk_size: int = 64
     # the residual as ``hc_mult`` streams [B, S, hc_mult, H], mixed around
     # every sub-layer by maps that depend on the token (manifold-constrained
     # hyper-connections, arXiv:2512.24880; modules.residual): the
@@ -217,7 +234,7 @@ class ModelArgs(BaseModel):
                     ) -> Tuple[Tuple[str, str], ...]:
         """The one per-layer description of a decoder stack: for each block
         its mixer kind ("full_attention", "conv", "mamba",
-        "latent_attention") and its
+        "latent_attention", "kda") and its
         feed-forward kind ("dense", "experts"). The builder, the exporter, the launcher's
         report and every engine's refusal read this and nothing else.
         ``n``: the blocks a plan lists where that is not
@@ -246,6 +263,11 @@ class ModelArgs(BaseModel):
         """Channels the mamba block's convolution runs over: x | B | C."""
         return (self.mamba_d_inner
                 + 2 * self.mamba_n_groups * self.mamba_d_state)
+
+    @property
+    def kda_inner(self) -> int:
+        """Channels of a kda block's q, k, v, decay and output gate."""
+        return self.kda_num_heads * self.kda_head_dim
 
     @property
     def held_experts(self) -> int:

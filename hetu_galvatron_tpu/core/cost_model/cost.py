@@ -947,14 +947,25 @@ def model_flops_per_token(model: Any, seq_length: Optional[int] = None
         mixer["mamba"] = (
             2 * h * (inner + model.mamba_conv_dim + model.mamba_n_heads)
             + 2 * inner * h + 4 * inner * state)
-    if getattr(model, "q_lora_rank", 0):
-        # latent attention: the five low-rank projections as they are, and
-        # the two batched matmuls over q/k of qk_head_dim and v of v_head_dim
+    if getattr(model, "kda_num_heads", 0):
+        # Kimi Delta Attention: q | k | v, the three narrow projections,
+        # the decay's and the output gate's second halves, out_proj, and
+        # the recurrence as the recurrence (S~^T k, the rank-one update,
+        # S^T q: 2 each per state element)
+        inner, d = model.kda_inner, model.kda_head_dim
+        mixer["kda"] = (
+            2 * h * (3 * inner + 2 * d + model.kda_num_heads)
+            + 2 * 2 * d * inner + 2 * inner * h + 6 * inner * d)
+    if getattr(model, "kv_lora_rank", 0):
+        # latent attention: the projections as they are (q through its
+        # low-rank step where the model has one), and the two batched
+        # matmuls over q/k of qk_head_dim and v of v_head_dim
         nq, rq, rkv = (model.num_attention_heads, model.q_lora_rank,
                        model.kv_lora_rank)
         qk, dv = model.qk_head_dim, model.v_head_dim
         mixer["latent_attention"] = (
-            2 * (h * rq + rq * nq * qk + h * (rkv + model.qk_rope_head_dim)
+            2 * ((h * rq + rq * nq * qk if rq else h * nq * qk)
+                 + h * (rkv + model.qk_rope_head_dim)
                  + rkv * nq * (model.qk_nope_head_dim + dv) + nq * dv * h)
             + 2 * s * nq * (qk + dv))
     streams = getattr(model, "hc_mult", 1)

@@ -53,7 +53,7 @@ Axes = Dict[str, Any]
 
 # a block's key for its mixer's parameters, by mixer kind
 MIXER_KEYS = {"full_attention": "attn", "conv": "conv", "mamba": "mamba",
-              "latent_attention": "attn"}
+              "latent_attention": "attn", "kda": "kda"}
 # the mixer kinds that attend (through an attention core, ``sdpa_fn``)
 ATTENDING_MIXERS = ("full_attention", "latent_attention")
 
@@ -597,35 +597,40 @@ def init_latent_attention(key: jax.Array,
         raise NotImplementedError(
             "latent attention is written without biases (DeepSeek-V3 and "
             "its descendants publish attention_bias false)")
-    if not (cfg.q_lora_rank and cfg.kv_lora_rank and cfg.qk_nope_head_dim
+    if not (cfg.kv_lora_rank and cfg.qk_nope_head_dim
             and cfg.qk_rope_head_dim and cfg.v_head_dim):
         raise ValueError(
-            "a latent_attention block needs model.q_lora_rank, kv_lora_rank, "
+            "a latent_attention block needs model.kv_lora_rank, "
             "qk_nope_head_dim, qk_rope_head_dim and v_head_dim")
     h, nq = cfg.hidden_size, cfg.num_attention_heads
     rq, rkv = cfg.q_lora_rank, cfg.kv_lora_rank
     dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
     k1, k2, k3, k4, k5 = jax.random.split(key, 5)
     std = 0.02
-    p: Params = {
-        "wq_a": _normal(k1, (h, rq), std),
-        "q_norm": {"scale": jnp.ones((rq,), jnp.float32)},
-        "wq_b": _normal(k2, (rq, nq * (dn + dr)), std),
+    if rq:
+        p: Params = {
+            "wq_a": _normal(k1, (h, rq), std),
+            "q_norm": {"scale": jnp.ones((rq,), jnp.float32)},
+            "wq_b": _normal(k2, (rq, nq * (dn + dr)), std)}
+        a: Axes = {"wq_a": ("embed", "latent_q"),
+                   "q_norm": {"scale": ("latent_q",)},
+                   "wq_b": ("latent_q", "latent_heads")}
+    else:
+        p = {"wq": _normal(k1, (h, nq * (dn + dr)), std)}
+        a = {"wq": ("embed", "latent_heads")}
+    p.update({
         "wkv_a": _normal(k3, (h, rkv + dr), std),
         "kv_norm": {"scale": jnp.ones((rkv,), jnp.float32)},
         "wkv_b": _normal(k4, (rkv, nq * (dn + dv)), std),
         "wo": _normal(k5, (nq * dv, h),
                       std / math.sqrt(2 * cfg.num_hidden_layers)),
-    }
-    a: Axes = {
-        "wq_a": ("embed", "latent_q"),
-        "q_norm": {"scale": ("latent_q",)},
-        "wq_b": ("latent_q", "latent_heads"),
+    })
+    a.update({
         "wkv_a": ("embed", "latent_kv"),
         "kv_norm": {"scale": ("latent_kv",)},
         "wkv_b": ("latent_kv", "latent_heads"),
         "wo": ("latent_heads", "embed"),
-    }
+    })
     return p, a
 
 
@@ -654,9 +659,11 @@ def apply_latent_attention(
     dropout_rng: Optional[jax.Array] = None,
     segment_ids: Optional[jax.Array] = None,
 ) -> jax.Array:
-    """``c_q = RMSNorm(x W_qa)``, ``[q_nope | q_rope] = c_q W_qb`` a head;
+    """``c_q = RMSNorm(x W_qa)``, ``[q_nope | q_rope] = c_q W_qb`` a head
+    (``= x W_q`` where the block holds ``wq``: no low-rank step, no norm);
     ``[c_kv | k_rope] = x W_kva`` (``k_rope`` one for all heads), ``[k_nope
-    | v] = RMSNorm(c_kv) W_kvb`` a head; RoPE on ``q_rope`` and ``k_rope``;
+    | v] = RMSNorm(c_kv) W_kvb`` a head; RoPE on ``q_rope`` and ``k_rope``
+    (none where ``rope`` is None: a model without positions);
     ``softmax([q_nope | q_rope] [k_nope | k_rope]^T * scale) v`` at
     :func:`latent_softmax_scale`; ``W_o``. The core is given q and k of
     ``qk_head_dim`` and v of ``v_head_dim``: no operand is padded to the
@@ -678,8 +685,9 @@ def apply_latent_attention(
 
     with jax.named_scope("attn/latent_proj"):
         xc = x.astype(compute_dtype)
-        q = proj(apply_norm(p["q_norm"], proj(xc, p["wq_a"]), cfg),
-                 p["wq_b"]).reshape(B, S, nq, dn + dr)
+        q = (proj(xc, p["wq"]) if "wq" in p else
+             proj(apply_norm(p["q_norm"], proj(xc, p["wq_a"]), cfg),
+                  p["wq_b"])).reshape(B, S, nq, dn + dr)
         ckv, k_rope = jnp.split(proj(xc, p["wkv_a"]), [rkv], axis=-1)
         kv = proj(apply_norm(p["kv_norm"], ckv, cfg),
                   p["wkv_b"]).reshape(B, S, nq, dn + dv)
@@ -1017,14 +1025,21 @@ def init_mamba2(key: jax.Array, cfg: ModelArgs) -> Tuple[Params, Axes]:
     return p, a
 
 
+def most_chunks_that_fit(chunks: int, fit: int) -> int:
+    """The largest divisor of ``chunks`` that is at most ``fit`` (at least
+    one): how many chunks of a sequence a scan's intra-chunk part takes at
+    once."""
+    return max(d for d in range(1, chunks + 1)
+               if chunks % d == 0 and d <= max(1, fit))
+
+
 def ssd_chunks_a_group(batch: int, chunks: int, heads: int,
                        chunk: int) -> int:
     """How many chunks the intra-chunk part takes at once: the most that
     divide ``chunks`` and whose decay matrices fit ``SSD_DECAY_BYTES``. A
     function of shapes alone."""
-    fit = max(1, SSD_DECAY_BYTES // (batch * heads * chunk * chunk * 4))
-    return max(d for d in range(1, chunks + 1)
-               if chunks % d == 0 and d <= fit)
+    return most_chunks_that_fit(
+        chunks, SSD_DECAY_BYTES // (batch * heads * chunk * chunk * 4))
 
 
 def ssd_chunked(x: jax.Array, dt: jax.Array, A: jax.Array, Bm: jax.Array,
@@ -1184,6 +1199,392 @@ def apply_mamba2(
     return out.astype(compute_dtype)
 
 
+# ---------------------------------------------------------------------------
+# Kimi Delta Attention (a mixer that carries a matrix-valued state, decayed a
+# channel and updated by the delta rule)
+# ---------------------------------------------------------------------------
+
+# positions of a sub-block of a chunk: inside one, the decay between two
+# positions is taken element by element; between two, through a reference
+# point (:func:`kda_pairs`)
+KDA_SUB = 16
+# what the L2 norm of a head's q and k adds under its root (fla's l2norm)
+KDA_L2_EPS = 1e-6
+# float32 bytes of the element-by-element decays (chunks x heads x sub-blocks
+# x sub x sub x width) that one call of the intra-chunk part may hold: the
+# chunks of a sequence are taken in groups of at most this much, one group
+# at a time, and the backward pass makes each group's again. Whole, one
+# 8192-token sequence's are 128 chunks x 32 heads x 4 x 16 x 16 x 128 x 4
+# bytes = 2 GiB
+KDA_PAIR_BYTES = 128 * 2 ** 20
+# the float32 products of the triangular inverse: a TPU's default precision
+# would round their operands to bfloat16
+_HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def init_kda(key: jax.Array, cfg: ModelArgs) -> Tuple[Params, Axes]:
+    """The released ``KimiDeltaAttention`` (``modeling_kimi.py``; fla's
+    ``layers/kda.py``): ``wqkv`` is ``q_proj | k_proj | v_proj`` side by
+    side, ``taps`` the three depthwise kernels ``[q | k | v channels, taps]``
+    (``{q,k,v}_conv1d.weight[:, 0, :]``, no bias), ``wlow`` the three narrow
+    projections of the input side by side, ``f_a_proj | g_a_proj | b_proj``
+    (the decay's and the output gate's bottlenecks of ``kda_head_dim`` and
+    ``beta``'s one value a head), ``wf_b`` / ``wg_b`` are ``f_b_proj`` /
+    ``g_b_proj`` (no bias, as ``modeling_kimi.py`` has them), ``dt_bias`` one
+    value a channel and ``A_log`` one a head, ``norm`` the output norm's
+    scale of ``kda_head_dim`` shared by the heads, ``wout`` is ``o_proj``.
+    No leaf carries an axis name that tensor parallelism shards: a plan
+    with tp > 1 over a kda block is refused by name
+    (``eligibility.kda_plan_reason``).
+
+    ``A_log`` and ``dt_bias`` start as the released layer starts them (and
+    as Mamba-2 does): ``A`` uniform in [1, 16), ``dt`` log-uniform in [1e-3,
+    1e-1] and ``dt_bias`` its inverse softplus, so the strongest decay a
+    fresh block draws is ``exp(-16 x 0.1)`` a token and channel."""
+    if cfg.kda_num_heads <= 0:
+        raise ValueError("a kda block needs model.kda_num_heads > 0")
+    if cfg.normalization != "rmsnorm":
+        raise ValueError("a kda block's output norm is an RMSNorm "
+                         "(normalization=rmsnorm)")
+    kda_sub_blocks(cfg.kda_chunk_size)   # raises for a chunk it cannot cut
+    h, nh, d = cfg.hidden_size, cfg.kda_num_heads, cfg.kda_head_dim
+    inner, L = cfg.kda_inner, cfg.kda_conv_kernel
+    k1, k2, k3, k4, k5, k6, k7, k8 = jax.random.split(key, 8)
+    std = 0.02
+    dt = jnp.exp(jax.random.uniform(k7, (inner,), jnp.float32,
+                                    math.log(1e-3), math.log(1e-1)))
+    p: Params = {
+        "wqkv": _normal(k1, (h, 3 * inner), std),
+        # the variance of torch's Conv1d default, as the conv block's taps
+        "taps": _normal(k2, (3 * inner, L), 1.0 / math.sqrt(3 * L)),
+        "wlow": _normal(k3, (h, 2 * d + nh), std),
+        "wf_b": _normal(k4, (d, inner), std),
+        "wg_b": _normal(k5, (d, inner), std),
+        "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+        "A_log": jnp.log(jax.random.uniform(k8, (nh,), jnp.float32,
+                                            1.0, 16.0)),
+        "norm": {"scale": jnp.ones((d,), jnp.float32)},
+        "wout": _normal(k6, (inner, h),
+                        std / math.sqrt(2 * cfg.num_hidden_layers)),
+    }
+    a: Axes = {"wqkv": ("embed", "kda_proj"),
+               "taps": ("kda_proj", "conv_tap"),
+               "wlow": ("embed", "kda_low"),
+               "wf_b": ("kda_low", "kda_inner"),
+               "wg_b": ("kda_low", "kda_inner"),
+               "dt_bias": ("kda_inner",), "A_log": ("kda_head",),
+               "norm": {"scale": ("kda_width",)},
+               "wout": ("kda_inner", "embed")}
+    return p, a
+
+
+def kda_sub_blocks(chunk: int) -> Tuple[int, int]:
+    """(positions of a sub-block, sub-blocks a chunk): ``KDA_SUB`` positions
+    each, a power of two of them (the triangular inverse joins them in
+    pairs); a chunk of at most ``KDA_SUB`` is one."""
+    sub = min(KDA_SUB, chunk)
+    n = chunk // sub
+    if chunk < 1 or chunk % sub or n & (n - 1):
+        raise ValueError(
+            f"model.kda_chunk_size={chunk}: a chunk is at most {KDA_SUB} "
+            f"positions or {KDA_SUB} times a power of two")
+    return sub, n
+
+
+def kda_chunks_a_group(batch: int, chunks: int, heads: int, chunk: int,
+                       width: int) -> int:
+    """How many chunks the intra-chunk part takes at once: the most that
+    divide ``chunks`` and whose element-by-element decays fit
+    ``KDA_PAIR_BYTES``. A function of shapes alone."""
+    sub, n = kda_sub_blocks(chunk)
+    return most_chunks_that_fit(
+        chunks, KDA_PAIR_BYTES // (batch * heads * n * sub * sub * width * 4))
+
+
+def _unit_lower_inverse(N: jax.Array, sub: int) -> jax.Array:
+    n = N.shape[-1]
+    nb = n // sub
+    lead = N.shape[:-2]
+    blocks = N.reshape(lead + (nb, sub, nb, sub))
+    # the diagonal sub-blocks, all at once, by forward substitution: row i
+    # of (I + D)^-1 is e_i - D[i, :i] (I + D)^-1[:i]
+    D = jnp.stack([blocks[..., b, :, b, :] for b in range(nb)], axis=-3)
+    eye = jnp.eye(sub, dtype=N.dtype)
+    X = jnp.broadcast_to(eye, D.shape)
+    for i in range(1, sub):
+        row = eye[i] - jnp.einsum("...j,...jk->...k", D[..., i, :i],
+                                  X[..., :i, :], precision=_HIGHEST)
+        X = X.at[..., i, :].set(row)
+    # joined in pairs: [[X1, 0], [-X2 N21 X1, X2]]
+    m = sub
+    while nb > 1:
+        blocks = N.reshape(lead + (nb // 2, 2, m, nb // 2, 2, m))
+        N21 = jnp.stack([blocks[..., b, 1, :, b, 0, :]
+                         for b in range(nb // 2)], axis=-3)
+        X1, X2 = X[..., 0::2, :, :], X[..., 1::2, :, :]
+        X21 = -jnp.einsum("...ij,...jk,...kl->...il", X2, N21, X1,
+                          precision=_HIGHEST)
+        X = jnp.concatenate([
+            jnp.concatenate([X1, jnp.zeros_like(X1)], axis=-1),
+            jnp.concatenate([X21, X2], axis=-1)], axis=-2)
+        nb, m = nb // 2, 2 * m
+    return X[..., 0, :, :]
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(1,))
+def unit_lower_inverse(N: jax.Array, sub: int) -> jax.Array:
+    """``(I + N)^-1`` of ``N`` [..., C, C] float32, of which the strictly
+    lower triangle is read: the ``sub`` x ``sub`` diagonal blocks by forward
+    substitution (as stable as the recurrence it stands for: a Neumann
+    product ``(I - N)(I + N^2)(I + N^4)..`` passes through powers whose
+    entries cancel), then joined in pairs by block products, ``C / sub`` a
+    power of two. Its cotangent is ``-X^T g X^T`` on that triangle."""
+    strict = jnp.tril(jnp.ones(N.shape[-2:], bool), -1)
+    return _unit_lower_inverse(jnp.where(strict, N, 0.0), sub)
+
+
+def _unit_lower_inverse_fwd(N, sub):
+    X = unit_lower_inverse(N, sub)
+    return X, X
+
+
+def _unit_lower_inverse_bwd(sub, X, g):
+    Xt = jnp.swapaxes(X, -1, -2)
+    bar = -jnp.einsum("...ij,...jk,...kl->...il", Xt, g, Xt,
+                      precision=_HIGHEST)
+    strict = jnp.tril(jnp.ones(X.shape[-2:], bool), -1)
+    return (jnp.where(strict, bar, 0.0),)
+
+
+unit_lower_inverse.defvjp(_unit_lower_inverse_fwd, _unit_lower_inverse_bwd)
+
+
+def kda_pairs(q: jax.Array, k: jax.Array, G: jax.Array, sub: int,
+              compute_dtype=jnp.bfloat16) -> Tuple[jax.Array, jax.Array]:
+    """``sum_c a_ic k_jc exp(G_ic - G_jc)`` for ``j <= i`` inside a chunk (0
+    above the diagonal), for ``a = q`` and for ``a = k``: ``q``, ``k`` [...,
+    C, d], ``G`` [..., C, d] float32, the running sum of the log decay
+    inside the chunk (not rising along C). Returns two [..., C, C] float32.
+
+    ``exp(G_i - G_j)`` is never split over a whole chunk: ``-G_j`` reaches
+    hundreds and ``exp`` of it overflows float32. A chunk is cut into
+    sub-blocks of ``sub`` positions. Inside one, the decay is taken element
+    by element, masked BEFORE the exp. For row sub-block I and an earlier
+    position j, with ``R_I`` the sum before I's first position, ``exp(G_i -
+    G_j) = exp(G_i - R_I) exp(R_I - G_j)`` with both exponents <= 0: the
+    rows of I times the first and every key times the second, against I's
+    own reference, are matmul operands in ``compute_dtype`` (float32
+    accumulation), one product ``[sub, d] x [d, C]`` a row sub-block."""
+    f32 = jnp.float32
+    C, d = k.shape[-2:]
+    nb = C // sub
+    lead = k.shape[:-2]
+    cut = lambda t: t.reshape(lead + (nb, sub, d))
+    q5, k5, G5 = cut(q.astype(f32)), cut(k.astype(f32)), cut(G)
+    tri = jnp.tril(jnp.ones((sub, sub), bool))[..., None]
+    decay = jnp.exp(jnp.where(
+        tri, G5[..., :, None, :] - G5[..., None, :, :], -jnp.inf))
+    kd = k5[..., None, :, :] * decay                    # [.., nb, i, j, d]
+    diag = [jnp.sum(a5[..., :, None, :] * kd, axis=-1) for a5 in (q5, k5)]
+    eye = jnp.eye(nb, dtype=f32)
+    whole = [jnp.einsum("...Iij,IJ->...IiJj", t, eye).reshape(lead + (C, C))
+             for t in diag]
+    if nb == 1:
+        return tuple(whole)
+    ref = G5[..., :-1, -1, :]                # R_I of I = 1 .. nb - 1
+    rows = jnp.exp(G5[..., 1:, :, :] - ref[..., None, :])
+    # every key against I's reference; a key at or after I's first position
+    # (exponent > 0) belongs to no product of I and is masked below
+    keys = (k.astype(f32)[..., None, :, :] * jnp.exp(jnp.minimum(
+        ref[..., None, :] - G[..., None, :, :], 0.0))).astype(compute_dtype)
+    before = (jnp.arange(C)[None, :]
+              < (jnp.arange(1, nb) * sub)[:, None])[:, None, :]
+    out = []
+    for a5, on_diag in zip((q5, k5), whole):
+        off = jnp.einsum(
+            "...Iid,...Ijd->...Iij",
+            (a5[..., 1:, :, :] * rows).astype(compute_dtype), keys,
+            preferred_element_type=f32)                 # [.., nb-1, sub, C]
+        off = jnp.where(before, off, 0.0).reshape(lead + (C - sub, C))
+        out.append(on_diag + jnp.pad(
+            off, ((0, 0),) * len(lead) + ((sub, 0), (0, 0))))
+    return tuple(out)
+
+
+def kda_chunked(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
+                beta: jax.Array, chunk: int,
+                compute_dtype=jnp.bfloat16) -> jax.Array:
+    """The gated delta rule with a decay a channel (Kimi Delta Attention,
+    arXiv:2510.26692) in its chunked form. Per head, with the state ``S``
+    [d, dv] (keys x values), zero before the sequence::
+
+        S~  = Diag(exp(g_t)) S_(t-1)
+        S_t = S~ + beta_t k_t (v_t - S~^T k_t)^T ;   o_t = S_t^T q_t
+
+    ``q``, ``k`` [B, S, H, d] (``k`` of unit length, ``q`` with its scale);
+    ``v`` [B, S, H, dv]; ``g`` [B, S, H, d] float32, the log decay, <= 0;
+    ``beta`` [B, S, H] float32. Returns ``o`` [B, S, H, dv] float32.
+
+    With ``G`` the running sum of ``g`` inside a chunk of ``chunk``
+    positions, ``A_ij = beta_i sum_c k_ic k_jc exp(G_ic - G_jc)`` (j < i),
+    ``T = (I + A)^-1 Diag(beta)`` (:func:`unit_lower_inverse`), ``W = T (K *
+    exp(G))`` and ``U = T V``, a chunk that the state ``S`` enters has ``V' =
+    U - W S``, ``o_i = (q_i * exp(G_i))^T S + sum_(j<=i) (sum_c q_ic k_jc
+    exp(G_ic - G_jc)) v'_j`` and leaves ``Diag(exp(G_last)) S + sum_j (k_j *
+    exp(G_last - G_j)) v'_j^T``. The chunks of a sequence are taken in
+    groups by ``KDA_PAIR_BYTES``, a scan over the groups that carries ``S``:
+    a group makes what does not depend on ``S`` for all its chunks at once
+    (the two pair matrices of :func:`kda_pairs`, the inverse, ``W``, ``U``,
+    the decayed ``q`` and ``k``), then scans over them; the backward pass
+    makes a group again from the state that entered it, so that one
+    group's intermediates exist at a time. Sums of ``g``, the inverse and the
+    carried state are float32; the matmul operands are ``compute_dtype``
+    with float32 accumulation. A sequence that ``chunk`` does not divide is
+    padded with ``g = 0`` and ``beta = 0`` (no decay, no update), and the
+    padding cut off."""
+    f32 = jnp.float32
+    B_, S, H, d = q.shape
+    pad = -S % chunk
+    if pad:
+        q, k, v, g, beta = (
+            jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
+            for t in (q, k, v, g, beta))
+    C, nC = chunk, (S + pad) // chunk
+    sub, _ = kda_sub_blocks(C)
+    size = kda_chunks_a_group(B_, nC, H, C, d)
+
+    def grouped(t):   # [B, S, H, ...] -> [groups, B, size, H, C, ...]
+        t = jnp.swapaxes(t.reshape((B_, nC // size, size, C) + t.shape[2:]),
+                         3, 4)
+        return jnp.moveaxis(t, 1, 0)
+
+    strict = jnp.tril(jnp.ones((C, C), bool), -1)
+
+    def intra(args):
+        qg, kg, vg, gg, bg = args    # [B, size, H, C, d] .. [B, size, H, C]
+        G = jnp.cumsum(gg, axis=-2)
+        a_qk, a_kk = kda_pairs(qg, kg, G, sub, compute_dtype)
+        T = (unit_lower_inverse(
+            jnp.where(strict, a_kk, 0.0) * bg[..., None], sub)
+             * bg[..., None, :]).astype(compute_dtype)
+        kf, last = kg.astype(f32), G[..., -1:, :]
+        w = jnp.einsum("...ij,...jd->...id", T,
+                       (kf * jnp.exp(G)).astype(compute_dtype),
+                       preferred_element_type=f32).astype(compute_dtype)
+        u = jnp.einsum("...ij,...je->...ie", T, vg.astype(compute_dtype),
+                       preferred_element_type=f32)
+        return (w, u, a_qk.astype(compute_dtype),
+                (qg.astype(f32) * jnp.exp(G)).astype(compute_dtype),
+                (kf * jnp.exp(last - G)).astype(compute_dtype),
+                jnp.exp(last[..., 0, :]))
+
+    def carry(state, chunk_of):
+        w, u, a_qk, q_in, k_out, decay = chunk_of
+        s = state.astype(compute_dtype)
+        v_new = u - jnp.einsum("bhcd,bhde->bhce", w, s,
+                               preferred_element_type=f32)
+        vc = v_new.astype(compute_dtype)
+        o = (jnp.einsum("bhcd,bhde->bhce", q_in, s,
+                        preferred_element_type=f32)
+             + jnp.einsum("bhij,bhje->bhie", a_qk, vc,
+                          preferred_element_type=f32))
+        state = decay[..., None] * state + jnp.einsum(
+            "bhcd,bhce->bhde", k_out, vc, preferred_element_type=f32)
+        return state, o
+
+    def group(state, args):
+        return jax.lax.scan(carry, state, tuple(
+            jnp.moveaxis(t, 1, 0) for t in intra(args)))
+
+    _, o = jax.lax.scan(jax.checkpoint(group),
+                        jnp.zeros((B_, H, d, v.shape[-1]), f32),
+                        tuple(grouped(t) for t in (q, k, v, g, beta)))
+    # [groups, size, B, H, C, dv] -> [B, nC, C, H, dv]
+    o = jnp.swapaxes(jnp.moveaxis(o.reshape((nC,) + o.shape[2:]), 0, 1), 2, 3)
+    return o.reshape(B_, nC * C, H, -1)[:, :S]
+
+
+def apply_kda(
+    p: Params,
+    x: jax.Array,
+    cfg: ModelArgs,
+    compute_dtype=jnp.bfloat16,
+) -> jax.Array:
+    """``[q~ | k~ | v] = silu(conv1d_causal(x W_qkv))`` (depthwise,
+    ``kda_conv_kernel`` taps, zero history before the sequence, no bias); a
+    head's ``q = q~ / |q~| * d^-0.5`` and ``k = k~ / |k~|``; the log decay a
+    channel ``g = -exp(A_log) softplus(x W_fa W_fb + dt_bias)``; ``beta =
+    sigmoid(x W_b)`` a head; ``o`` by the gated delta rule
+    (:func:`kda_chunked`); ``y = RMSNorm(o) * w * sigmoid(x W_ga W_gb)`` a
+    head; ``y W_out``. No softmax, no positions. The projections and the
+    recurrence's matmuls run in ``compute_dtype`` with float32
+    accumulation; the convolution, the L2 norms, the decay, ``beta``, the
+    state and the gated norm are float32."""
+    B, S, _ = x.shape
+    nh, d, inner = cfg.kda_num_heads, cfg.kda_head_dim, cfg.kda_inner
+    f32 = jnp.float32
+
+    def proj(a, w):
+        return jnp.einsum("bsh,hc->bsc", a.astype(compute_dtype),
+                          weight_view(w, compute_dtype),
+                          preferred_element_type=f32)
+
+    # The two functions below are made again in the backward pass from the
+    # projections' outputs, q, k and v one at a time: their float32 passes
+    # would otherwise all be kept while the scan's backward runs. Each opens
+    # the mixer's scope itself and is called outside it, so that an
+    # instruction's name reads ``checkpoint/mixer/kda/conv`` and not
+    # ``mixer/kda/checkpoint/conv``, which no scope of the vocabulary ends.
+    def conv(u, taps, norm_scale):
+        """One of q, k, v: the convolution, SiLU and, for q and k
+        (``norm_scale``), a head's L2 norm."""
+        with jax.named_scope("mixer/kda"):
+            with jax.named_scope("conv"):
+                c = jax.nn.silu(causal_depthwise_conv(u.astype(f32), taps))
+                c = c.reshape(B, S, nh, d)
+            if norm_scale is not None:
+                with jax.named_scope("gates"):
+                    c = c * (jax.lax.rsqrt(jnp.sum(
+                        jnp.square(c), axis=-1, keepdims=True) + KDA_L2_EPS)
+                             * norm_scale)
+            return c.astype(compute_dtype)
+
+    def log_decay(f, dt_bias, A_log):
+        with jax.named_scope("mixer/kda"):
+            with jax.named_scope("gates"):
+                return (-jnp.repeat(jnp.exp(A_log.astype(f32)), d)
+                        * jax.nn.softplus(f + dt_bias)).reshape(B, S, nh, d)
+
+    with jax.named_scope("mixer/kda"):
+        with jax.named_scope("in_proj"):
+            qkv = jnp.split(proj(x, p["wqkv"]).astype(compute_dtype), 3,
+                            axis=-1)
+            f_a, g_a, b = jnp.split(proj(x, p["wlow"]), [d, 2 * d], axis=-1)
+            f = proj(f_a, p["wf_b"])
+            z = proj(g_a, p["wg_b"]).astype(compute_dtype)
+        with jax.named_scope("conv"):
+            taps = jnp.split(p["taps"], 3)
+    q, k, v = (jax.checkpoint(conv, static_argnums=(2,))(u, t, norm_scale)
+               for u, t, norm_scale in zip(qkv, taps, (d ** -0.5, 1.0, None)))
+    g = jax.checkpoint(log_decay)(f, p["dt_bias"], p["A_log"])
+    with jax.named_scope("mixer/kda"):
+        with jax.named_scope("gates"):
+            beta = jax.nn.sigmoid(b)
+        with jax.named_scope("scan"):
+            o = kda_chunked(q, k, v, g, beta, cfg.kda_chunk_size,
+                            compute_dtype)
+        with jax.named_scope("gated_norm"):
+            var = jnp.mean(jnp.square(o), axis=-1, keepdims=True)
+            y = (o * jax.lax.rsqrt(var + cfg.layernorm_epsilon)
+                 * p["norm"]["scale"]).reshape(B, S, inner)
+            y = (y * jax.nn.sigmoid(z.astype(f32))).astype(compute_dtype)
+        with jax.named_scope("out_proj"):
+            out = jnp.einsum("bsc,ch->bsh", y,
+                             weight_view(p["wout"], compute_dtype),
+                             preferred_element_type=f32)
+    return out.astype(compute_dtype)
+
+
 def apply_mixer(
     p: Params,
     h: jax.Array,
@@ -1198,12 +1599,12 @@ def apply_mixer(
 ) -> jax.Array:
     """A block's operator on its normed input, by the block's mixer kind
     (``ModelArgs.block_kinds``): attention from ``p["attn"]``, the gated
-    short convolution from ``p["conv"]`` or the Mamba-2 state-space block
-    from ``p["mamba"]``; the last two take no rope, no attention core and
-    no dropout of probabilities, and the last alone takes ``ssd_fn``
-    (:func:`apply_mamba2`). ``latent_attention`` is attention through
-    low-rank projections, also from ``p["attn"]``
-    (:func:`apply_latent_attention`)."""
+    short convolution from ``p["conv"]``, the Mamba-2 state-space block
+    from ``p["mamba"]`` or Kimi Delta Attention from ``p["kda"]``; the last
+    three take no rope, no attention core and no dropout of probabilities,
+    and the mamba block alone takes ``ssd_fn`` (:func:`apply_mamba2`).
+    ``latent_attention`` is attention through low-rank projections, also
+    from ``p["attn"]`` (:func:`apply_latent_attention`)."""
     if mixer == "full_attention":
         return apply_attention(p["attn"], h, cfg, compute_dtype=compute_dtype,
                                shard_fn=shard_fn, segment_ids=segment_ids,
@@ -1230,6 +1631,12 @@ def apply_mixer(
     if mixer == "mamba":
         return apply_mamba2(p["mamba"], h, cfg, compute_dtype=compute_dtype,
                             ssd_fn=ssd_fn)
+    if mixer == "kda":
+        if shard_fn is not None or attn_kwargs.get("matmul_fns"):
+            raise NotImplementedError(
+                "a kda block's projections are not cut over the tp axis "
+                "(eligibility.kda_plan_reason)")
+        return apply_kda(p["kda"], h, cfg, compute_dtype=compute_dtype)
     return apply_short_conv(p["conv"], h, cfg, compute_dtype=compute_dtype,
                             shard_fn=shard_fn)
 
@@ -1354,7 +1761,8 @@ def init_mixer(key: jax.Array, cfg: ModelArgs,
                mixer: str = "full_attention") -> Tuple[str, Params, Axes]:
     """(the block's key for it, params, axes) of one mixer kind."""
     init = {"full_attention": init_attention, "conv": init_short_conv,
-            "mamba": init_mamba2, "latent_attention": init_latent_attention}
+            "mamba": init_mamba2, "latent_attention": init_latent_attention,
+            "kda": init_kda}
     if mixer not in init:
         raise ValueError(f"unknown mixer kind {mixer!r} ({' | '.join(init)})")
     return (MIXER_KEYS[mixer],) + init[mixer](key, cfg)

@@ -478,22 +478,32 @@ SCOPES: Tuple[str, ...] = (
     "mixer/short_conv/in_proj", "mixer/short_conv/gate_conv",
     "mixer/short_conv/out_proj",
     "mixer/mamba/in_proj", "mixer/mamba/conv", "mixer/mamba/ssd",
-    "mixer/mamba/gated_norm", "mixer/mamba/out_proj")
+    "mixer/mamba/gated_norm", "mixer/mamba/out_proj",
+    "mixer/kda/in_proj", "mixer/kda/conv", "mixer/kda/gates",
+    "mixer/kda/scan", "mixer/kda/gated_norm", "mixer/kda/out_proj")
 PHASES = ("forward", "recompute", "backward", "update", "other")
 # the scopes ``step_scopes()["scopes"]`` lists by instruction, by mixer kind
 # (what the ``granite_*`` readers join a trace to, by PR 35's rule: an
 # instruction by its own ``op_name``)
-MIXER_SCOPES = {"mamba": tuple(s for s in SCOPES
-                               if s.startswith("mixer/mamba/"))}
+MIXER_SCOPES = {kind: tuple(s for s in SCOPES
+                            if s.startswith(f"mixer/{kind}/"))
+                for kind in ("mamba", "kda")}
 # the further prediction depth's three parts: what is under them by the
 # deepest scope is the block's own (``attn/core``, ``moe/experts``, ``head``),
 # so a reader that wants the depth's whole time takes these lists
 MTP_SCOPES = tuple(s for s in SCOPES if s.startswith("mtp/"))
 # what ``step_scopes()["scopes"]`` lists by instruction
-OWN_SCOPES = MIXER_SCOPES["mamba"] + MTP_SCOPES
+OWN_SCOPES = MIXER_SCOPES["mamba"] + MTP_SCOPES + MIXER_SCOPES["kda"]
 # the scope of the state-space scan, whose Mosaic calls the step report
 # counts (``ssd/mosaic_calls``; ops/pallas/ssd.py traces under it)
 SSD_SCOPE = "mixer/mamba/ssd"
+
+# the scope of a kda block's recurrence, whose loops the step report counts
+# (:func:`kda_loops`)
+KDA_SCAN_SCOPE = "mixer/kda/scan"
+_TRIP_COUNT = re.compile(r'"known_trip_count":\{"n":"(\d+)"')
+_BODY = re.compile(r"body=%?([\w.\-]+)")
+_ARRAY_DIMS = re.compile(r"\b(?:pred|[sufb]\w*\d+)\[([\d,]+)\]")
 
 # the flash forward kernel's name (ops/pallas/flash_attention.py), which its
 # instruction in the compiled step carries (``flash_attention_fwd.3``)
@@ -791,6 +801,48 @@ def scope_instructions(hlo_text: str, scopes: Sequence[str]
     trace event's name is an instruction's."""
     read = step_hlo(hlo_text, scopes)
     return {k: read[k] for k in ("scopes", "instructions", "mosaic_calls")}
+
+
+def _loop_trips(line: str) -> int:
+    """How often a ``while`` of an optimized HLO text runs: its
+    ``known_trip_count`` where the backend wrote one (XLA:CPU), else the
+    length a scan's stacked operands share: the commonest leading dimension
+    of the arrays of four dimensions and more that the loop carries
+    (XLA:TPU's text says no count; a carried state is one array among
+    several stacked ones)."""
+    known = _TRIP_COUNT.search(line)
+    if known:
+        return int(known.group(1))
+    leads = [int(dims.split(",")[0])
+             for dims in _ARRAY_DIMS.findall(line[:line.find(" while(")])
+             if dims.count(",") >= 3]
+    return max(set(leads), key=leads.count) if leads else 0
+
+
+def kda_loops(hlo_text: str) -> Dict[str, int]:
+    """What a compiled step says of its kda blocks' recurrences
+    (``modules.kda_chunked``): the loops of the forward pass under
+    ``mixer/kda/scan``. A block's recurrence is a loop over its groups of
+    chunks around a loop over a group's chunks (the inner one in the outer
+    one's body; one loop where one group holds every chunk): ``blocks``
+    counts the loops that lie in no other, and ``chunks`` is a sequence's
+    chunks, outer trips times inner trips (:func:`_loop_trips`). Zeros for
+    a step without such a block."""
+    loops = []   # (the computation it is in, its body, its trips)
+    for comp, _, opcode, op_name, _, line, _ in walk_hlo(hlo_text):
+        if opcode == "while" and scope_and_phase(op_name) == (
+                KDA_SCAN_SCOPE, "forward"):
+            body = _BODY.search(line)
+            if body:
+                loops.append((comp, body.group(1), _loop_trips(line)))
+    bodies = {body for _, body, _ in loops}
+    outer = [(body, trips) for comp, body, trips in loops
+             if comp not in bodies]
+    if not outer:
+        return {"blocks": 0, "chunks": 0}
+    body, trips = outer[0]
+    inner = [n for comp, _, n in loops if comp == body]
+    return {"blocks": len(outer), "chunks": trips * (inner[0] if inner else 1)}
 
 
 def cores_recomputed(found: Dict[str, Any]) -> int:

@@ -911,14 +911,18 @@ _MOE_HF_NAMES = {
 }
 # DeepSeek-V3's released layout: olmoe's names for the router and experts
 _MOE_HF_NAMES["deepseek"] = _MOE_HF_NAMES["olmoe"]
+# Kimi Linear's released layout (``modeling_kimi.py``): mixtral's names
+_MOE_HF_NAMES["kimi"] = _MOE_HF_NAMES["mixtral"]
 # the selection bias of an expert layer, where the layout has a name for it
 _MOE_HF_BIAS = {"lfm2": "feed_forward.expert_bias",
-                "deepseek": "mlp.gate.e_score_correction_bias"}
+                "deepseek": "mlp.gate.e_score_correction_bias",
+                "kimi": "block_sparse_moe.gate.e_score_correction_bias"}
 # the shared expert's gate, up and down projections, where the layout has a
 # slot for one
-_MOE_HF_SHARED = {"deepseek": ("mlp.shared_experts.gate_proj.weight",
-                               "mlp.shared_experts.up_proj.weight",
-                               "mlp.shared_experts.down_proj.weight")}
+_MOE_HF_SHARED = {
+    layout: tuple(f"{at}.shared_experts.{m}_proj.weight"
+                  for m in ("gate", "up", "down"))
+    for layout, at in (("deepseek", "mlp"), ("kimi", "block_sparse_moe"))}
 
 # public names of a block's norms, attention projections, q/k norms, dense
 # MLP and of the final norm, by ``cfg.hf_layout``; a ``conv`` block's names
@@ -958,8 +962,10 @@ _MAMBA_HF_NAMES = {"win": "mamba.in_proj.weight",
                    "D": "mamba.D", "norm": "mamba.norm.weight",
                    "wout": "mamba.out_proj.weight"}
 # a ``latent_attention`` block's names are DeepSeek-V3's
-# (``DeepseekV3Attention``): the program's leaf -> the public name
-_LATENT_HF_NAMES = {"wq_a": "self_attn.q_a_proj.weight",
+# (``DeepseekV3Attention``): the program's leaf -> the public name (a block
+# holds ``wq`` or the three of the low-rank step, ``ModelArgs.q_lora_rank``)
+_LATENT_HF_NAMES = {"wq": "self_attn.q_proj.weight",
+                    "wq_a": "self_attn.q_a_proj.weight",
                     "q_norm": "self_attn.q_a_layernorm.weight",
                     "wq_b": "self_attn.q_b_proj.weight",
                     "wkv_a": "self_attn.kv_a_proj_with_mqa.weight",
@@ -975,7 +981,20 @@ _HC_HF_NAMES = {"hc1": "attn_hc.", "hc2": "mlp_hc."}
 _MTP_HF_NAMES = {"enorm": "enorm.weight", "hnorm": "hnorm.weight",
                  "eh_proj": "eh_proj.weight",
                  "norm": "shared_head.norm.weight"}
-_MIXER_KINDS = ("full_attention", "conv", "mamba", "latent_attention")
+# a ``kda`` block's names are Kimi Linear's (``modeling_kimi.py``'s
+# ``KimiDeltaAttention``, under ``self_attn``): the program's leaf -> the
+# public name; ``wqkv``, ``taps`` and ``wlow`` hold three public tensors
+# each, side by side in this order
+_KDA_HF_NAMES = {"wf_b": "self_attn.f_b_proj.weight",
+                 "wg_b": "self_attn.g_b_proj.weight",
+                 "dt_bias": "self_attn.dt_bias", "A_log": "self_attn.A_log",
+                 "norm": "self_attn.o_norm.weight",
+                 "wout": "self_attn.o_proj.weight"}
+_KDA_HF_THIRDS = {
+    "wqkv": tuple(f"self_attn.{m}_proj.weight" for m in "qkv"),
+    "taps": tuple(f"self_attn.{m}_conv1d.weight" for m in "qkv"),
+    "wlow": tuple(f"self_attn.{m}_proj.weight" for m in ("f_a", "g_a", "b"))}
+_MIXER_KINDS = ("full_attention", "conv", "mamba", "latent_attention", "kda")
 
 
 def _rope_columns_to_hf(width: int) -> np.ndarray:
@@ -987,10 +1006,22 @@ def _rope_columns_to_hf(width: int) -> np.ndarray:
                      np.arange(width // 2) + width // 2], axis=1).reshape(-1)
 
 
-def _latent_rope_orders(cfg: ModelArgs, to_hf: bool):
-    """(column order of ``wq_b``, of ``wkv_a``) between the program's layout
-    and the public one: the rotated columns of every query head and of the
-    shared key permuted, everything else in place."""
+def _latent_hf_names(cfg: ModelArgs) -> Dict[str, str]:
+    """``_LATENT_HF_NAMES`` of the leaves this model's latent blocks hold:
+    ``wq`` alone, or the low-rank step's three."""
+    absent = ("wq",) if cfg.q_lora_rank else ("wq_a", "q_norm", "wq_b")
+    return {leaf: name for leaf, name in _LATENT_HF_NAMES.items()
+            if leaf not in absent}
+
+
+def _latent_rope_orders(cfg: ModelArgs, to_hf: bool) -> Dict[str, Any]:
+    """Column order, by leaf (the query's last projection and ``wkv_a``),
+    between the program's layout and the public one: the rotated columns of
+    every query head and of the shared key permuted, everything else in
+    place. Empty for a model that rotates nothing (no RoPE): its columns
+    are stored as they are."""
+    if cfg.position_embedding_type != "rope":
+        return {}
     dn, dr = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
     order = _rope_columns_to_hf(dr)
     if not to_hf:
@@ -1000,7 +1031,7 @@ def _latent_rope_orders(cfg: ModelArgs, to_hf: bool):
          + head[None, :]).reshape(-1)
     kv = np.concatenate([np.arange(cfg.kv_lora_rank),
                          cfg.kv_lora_rank + order])
-    return q, kv
+    return {"wq_b" if cfg.q_lora_rank else "wq": q, "wkv_a": kv}
 
 
 def _unknown_mixer(i: int, mixer: str) -> ValueError:
@@ -1106,13 +1137,28 @@ def hf_to_params(state_dict: Dict[str, Any], cfg: ModelArgs) -> Params:
                 lp["attn"]["q_norm"] = {"scale": sd[pre + names["q_norm"]]}
                 lp["attn"]["k_norm"] = {"scale": sd[pre + names["k_norm"]]}
         elif mixer == "latent_attention":
-            q_order, kv_order = _latent_rope_orders(cfg, to_hf=False)
             lp["attn"] = {
                 leaf: ({"scale": sd[pre + name]} if leaf.endswith("norm")
                        else lin(pre + name))
-                for leaf, name in _LATENT_HF_NAMES.items()}
-            lp["attn"]["wq_b"] = lp["attn"]["wq_b"][:, q_order]
-            lp["attn"]["wkv_a"] = lp["attn"]["wkv_a"][:, kv_order]
+                for leaf, name in _latent_hf_names(cfg).items()}
+            for leaf, order in _latent_rope_orders(cfg, to_hf=False).items():
+                lp["attn"][leaf] = lp["attn"][leaf][:, order]
+        elif mixer == "kda":
+            # Conv1d's depthwise kernel is [channels, 1, taps]; A_log is
+            # stored [1, 1, heads, 1]
+            lp["kda"] = {
+                "wqkv": np.concatenate(
+                    [lin(pre + nm) for nm in _KDA_HF_THIRDS["wqkv"]], axis=1),
+                "taps": np.concatenate(
+                    [sd[pre + nm][:, 0, :] for nm in _KDA_HF_THIRDS["taps"]]),
+                "wlow": np.concatenate(
+                    [lin(pre + nm) for nm in _KDA_HF_THIRDS["wlow"]], axis=1),
+                "wf_b": lin(pre + _KDA_HF_NAMES["wf_b"]),
+                "wg_b": lin(pre + _KDA_HF_NAMES["wg_b"]),
+                "dt_bias": sd[pre + _KDA_HF_NAMES["dt_bias"]],
+                "A_log": sd[pre + _KDA_HF_NAMES["A_log"]].reshape(-1),
+                "norm": {"scale": sd[pre + _KDA_HF_NAMES["norm"]]},
+                "wout": lin(pre + _KDA_HF_NAMES["wout"])}
         else:
             raise _unknown_mixer(i, mixer)
         if ff == "experts":
@@ -1537,14 +1583,31 @@ def params_to_hf(params: Params, cfg: ModelArgs) -> Dict[str, np.ndarray]:
                 sd[pre + names["q_norm"]] = get(lp["attn"]["q_norm"]["scale"])
                 sd[pre + names["k_norm"]] = get(lp["attn"]["k_norm"]["scale"])
         elif mixer == "latent_attention":
-            q_order, kv_order = _latent_rope_orders(cfg, to_hf=True)
-            for leaf, name in _LATENT_HF_NAMES.items():
+            orders = _latent_rope_orders(cfg, to_hf=True)
+            for leaf, name in _latent_hf_names(cfg).items():
                 if leaf.endswith("norm"):
                     sd[pre + name] = get(lp["attn"][leaf]["scale"])
                     continue
                 w = get(lp["attn"][leaf])
-                order = {"wq_b": q_order, "wkv_a": kv_order}.get(leaf)
-                sd[pre + name] = (w if order is None else w[:, order]).T
+                sd[pre + name] = (w[:, orders[leaf]] if leaf in orders
+                                  else w).T
+        elif mixer == "kda":
+            kp = lp["kda"]
+            d, inner = cfg.kda_head_dim, cfg.kda_inner
+            for nm, w in zip(_KDA_HF_THIRDS["wqkv"],
+                             np.split(get(kp["wqkv"]), 3, axis=1)):
+                sd[pre + nm] = w.T
+            for nm, w in zip(_KDA_HF_THIRDS["taps"],
+                             np.split(get(kp["taps"]), 3)):
+                sd[pre + nm] = w[:, None, :]
+            for nm, w in zip(_KDA_HF_THIRDS["wlow"],
+                             np.split(get(kp["wlow"]), [d, 2 * d], axis=1)):
+                sd[pre + nm] = w.T
+            for leaf, name in _KDA_HF_NAMES.items():
+                w = get(kp[leaf]["scale"] if leaf == "norm" else kp[leaf])
+                sd[pre + name] = (w.T if leaf.startswith("w")
+                                  else w.reshape(1, 1, -1, 1)
+                                  if leaf == "A_log" else w)
         else:
             raise _unknown_mixer(i, mixer)
         if ff == "experts":

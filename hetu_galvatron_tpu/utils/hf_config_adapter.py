@@ -39,11 +39,17 @@ _GRANITE_HYBRID = "granitemoehybrid"
 # DeepSeek-V3's keys (latent attention, shared beside sigmoid-routed experts,
 # multi-token prediction) with the residual as several streams
 _XING = "xing4_0"
+# Kimi Linear (moonshotai; ``modeling_kimi.py``): Kimi Delta Attention and
+# latent-attention blocks by number, DeepSeek-V3's expert layer
+_KIMI_LINEAR = "kimi_linear"
+# families that state for themselves whether they have positions
+_OWN_POSITIONS = {_GRANITE_HYBRID, _KIMI_LINEAR}
 _ROPE_FAMILIES = {"llama", "qwen2", "mistral", "mixtral", "olmoe",
                   "qwen", _XING} | _GEMMA_FAMILIES | _LFM2_FAMILIES
-_RMS_FAMILIES = _ROPE_FAMILIES | {"t5", _GRANITE_HYBRID}
+_RMS_FAMILIES = _ROPE_FAMILIES | {"t5", _GRANITE_HYBRID, _KIMI_LINEAR}
 _SWIGLU_FAMILIES = {"llama", "qwen2", "mistral", "mixtral", "olmoe",
-                    "qwen", _GRANITE_HYBRID, _XING} | _LFM2_FAMILIES
+                    "qwen", _GRANITE_HYBRID, _XING,
+                    _KIMI_LINEAR} | _LFM2_FAMILIES
 # gemma-2/3 add sandwich norms, logit softcapping, query_pre_attn_scalar,
 # alternating sliding windows (v3: q/k-norm, dual rope) — none of which this
 # stack implements; mapping them through gemma-1 numerics would silently
@@ -155,6 +161,8 @@ def populate_model_args_from_hf(
         values.update(_granite_hybrid_values(d))
     if family == _XING:
         values.update(_xing_values(d))
+    if family == _KIMI_LINEAR:
+        values.update(_kimi_linear_values(d))
     if family == "bert":
         # HF bert uses erf gelu everywhere (BertIntermediate + the MLM
         # transform); our "gelu" is the tanh approximation (gpt2's gelu_new)
@@ -170,7 +178,7 @@ def populate_model_args_from_hf(
         values["hidden_act"] = "geglu" if "gated" in ff else "relu"
         values["tie_word_embeddings"] = bool(d.get("tie_word_embeddings",
                                                    True))
-    if family != _GRANITE_HYBRID:   # which states its own
+    if family not in _OWN_POSITIONS:
         values["position_embedding_type"] = (
             "rope" if family in _ROPE_FAMILIES else "learned"
         )
@@ -186,7 +194,7 @@ def populate_model_args_from_hf(
     # bias detection (reference hf_config_adapter.py:196-290 reads
     # attention_bias / mlp_bias / family defaults)
     # llama-likes and t5 default to no biases
-    bias_free = _ROPE_FAMILIES | {"t5", _GRANITE_HYBRID}
+    bias_free = _ROPE_FAMILIES | {"t5", _GRANITE_HYBRID, _KIMI_LINEAR}
     if "attention_bias" in d:
         values["add_qkv_bias"] = bool(d["attention_bias"])
     elif family in {"qwen", "qwen2"}:
@@ -244,6 +252,71 @@ def _xing_values(d: Dict[str, Any]) -> Dict[str, Any]:
         hc_res_clamp_min=float(d.get("mhc_h_res_clamp_min", -30.0)),
         hc_res_clamp_max=float(d.get("mhc_h_res_clamp_max", 30.0)),
         num_nextn_predict_layers=int(d.get("num_nextn_predict_layers", 0)))
+
+
+def _kimi_linear_values(d: Dict[str, Any]) -> Dict[str, Any]:
+    """Kimi Linear (``modeling_kimi.py``): ``linear_attn_config`` numbers
+    the blocks from 1, ``kda_layers`` those that run Kimi Delta Attention
+    and ``full_attn_layers`` those that run latent attention (no low-rank
+    query where ``q_lora_rank`` is null; no rotation where ``mla_use_nope``);
+    ``first_k_dense_replace`` leading dense blocks, then ``num_experts``
+    sigmoid-routed experts beside ``num_shared_experts`` shared ones."""
+    family = _KIMI_LINEAR
+    if (int(d.get("num_expert_group") or 1) != 1
+            or int(d.get("topk_group") or 1) != 1):
+        raise NotImplementedError(
+            f"{family} num_expert_group={d.get('num_expert_group')} "
+            f"topk_group={d.get('topk_group')}: the group-limited choice of "
+            "experts is not implemented (one group is)")
+    if d.get("moe_router_activation_func", "sigmoid") != "sigmoid":
+        raise NotImplementedError(
+            f"{family} moe_router_activation_func="
+            f"{d.get('moe_router_activation_func')!r}: sigmoid scores with "
+            "the selection bias are implemented")
+    if not d.get("mla_use_nope", False):
+        raise NotImplementedError(
+            f"{family} mla_use_nope false: the latent blocks of this family "
+            "are written without positions, as it publishes them")
+    if int(d.get("num_nextn_predict_layers") or 0):
+        raise NotImplementedError(
+            f"{family} num_nextn_predict_layers="
+            f"{d['num_nextn_predict_layers']}: the family publishes 0")
+    lin = d.get("linear_attn_config") or {}
+    n = int(d["num_hidden_layers"])
+    kda = set(lin.get("kda_layers") or ())
+    full = set(lin.get("full_attn_layers") or ())
+    if kda & full or kda | full != set(range(1, n + 1)):
+        raise ValueError(
+            f"{family}: linear_attn_config.kda_layers and full_attn_layers "
+            f"have to number each of the {n} blocks, from 1, exactly once "
+            f"(got {sorted(kda)} and {sorted(full)})")
+    return dict(
+        model_type="moe", hf_layout="llama", moe_hf_layout="kimi",
+        position_embedding_type="nope",
+        layer_types=["kda" if i + 1 in kda else "latent_attention"
+                     for i in range(n)],
+        kda_num_heads=int(lin["num_heads"]),
+        kda_head_dim=int(lin["head_dim"]),
+        kda_conv_kernel=int(lin.get("short_conv_kernel_size", 4)),
+        num_dense_layers=int(d.get("first_k_dense_replace", 0)),
+        q_lora_rank=d.get("q_lora_rank"),
+        kv_lora_rank=int(d["kv_lora_rank"]),
+        qk_nope_head_dim=int(d["qk_nope_head_dim"]),
+        qk_rope_head_dim=int(d["qk_rope_head_dim"]),
+        v_head_dim=int(d["v_head_dim"]),
+        moe_ffn_hidden_size=int(d["moe_intermediate_size"]),
+        num_experts=int(d["num_experts"]),
+        moe_topk=int(d["num_experts_per_token"]),
+        num_shared_experts=int(d.get("num_shared_experts") or 0),
+        moe_layer_freq=int(d.get("moe_layer_freq", 1)),
+        moe_score_function="sigmoid", moe_dispatcher="dropless",
+        moe_norm_topk_prob=bool(d.get("moe_renormalize", True)),
+        moe_norm_topk_eps=1e-20,
+        moe_routed_scaling_factor=float(d.get("routed_scaling_factor", 1.0)),
+        moe_router_enable_expert_bias=True, moe_aux_loss_coeff=0.0,
+        tie_word_embeddings=bool(d.get("tie_word_embeddings", False)),
+        **({"max_position_embeddings": int(d["model_max_length"])}
+           if d.get("model_max_length") else {}))
 
 
 def _granite_hybrid_values(d: Dict[str, Any]) -> Dict[str, Any]:

@@ -566,9 +566,9 @@ def test_latent_attention_refuses_what_it_is_not_written_for():
     with pytest.raises(NotImplementedError, match="without biases"):
         M.init_latent_attention(jax.random.key(0), cfg.model_copy(
             update=dict(add_qkv_bias=True)))
-    with pytest.raises(ValueError, match="q_lora_rank"):
+    with pytest.raises(ValueError, match="kv_lora_rank"):
         M.init_latent_attention(jax.random.key(0), cfg.model_copy(
-            update=dict(q_lora_rank=0)))
+            update=dict(kv_lora_rank=0)))
     p, _ = M.init_latent_attention(jax.random.key(0), cfg)
     x = jnp.zeros((1, 16, 32), jnp.float32)
     with pytest.raises(NotImplementedError, match="ring/Ulysses"):
