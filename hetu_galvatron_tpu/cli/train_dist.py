@@ -678,6 +678,7 @@ def train(args) -> Dict[str, Any]:
     from hetu_galvatron_tpu.observability.trace_analysis import (
         SSD_SCOPE,
         cores_recomputed,
+        kda_kernel_calls,
         kda_loops,
         record_step_scopes,
         step_hlo,
@@ -1135,13 +1136,18 @@ def train(args) -> Dict[str, Any]:
                         "unnamed": len(found["map"]["tails"])}
                     if any(m == "kda" for m, _ in kinds):
                         # how many blocks run the delta rule and at which
-                        # chunk length, by the compiled step's own loops
-                        # (exact where the chunk divides the sequence)
-                        loops = kda_loops(hlo_text)
-                        step_report["kda"] = {
-                            "blocks": loops["blocks"],
-                            "chunk": -(-cfg.seq_length
-                                       // max(loops["chunks"], 1))}
+                        # chunk length: by the kernels' calls where the
+                        # recurrence runs in them (mosaic_calls, the Mosaic
+                        # calls under its scope; 0 = the jax.numpy form),
+                        # else by the compiled step's own loops (exact
+                        # where the chunk divides the sequence)
+                        step_report["kda"] = kda_kernel_calls(hlo_text)
+                        if not step_report["kda"]["mosaic_calls"]:
+                            loops = kda_loops(hlo_text)
+                            step_report["kda"].update(
+                                blocks=loops["blocks"],
+                                chunk=-(-cfg.seq_length
+                                        // max(loops["chunks"], 1)))
                         for part, v in step_report["kda"].items():
                             get_registry().gauge(f"kda/{part}").set(v)
                     if any(m == "mamba" for m, _ in kinds):
@@ -1176,7 +1182,8 @@ def train(args) -> Dict[str, Any]:
                     + (f" ({step_report['ssd_mosaic_calls']} under "
                        f"{SSD_SCOPE})" if "ssd_mosaic_calls" in step_report
                        else "")
-                    + (", kda/blocks {blocks} kda/chunk {chunk}".format(
+                    + (", kda/blocks {blocks} kda/chunk {chunk} "
+                       "kda/mosaic_calls {mosaic_calls}".format(
                         **step_report["kda"]) if "kda" in step_report
                        else "")
                     + f", {step_report['cores_recomputed']} cores recomputed,"
@@ -1251,9 +1258,11 @@ def train(args) -> Dict[str, Any]:
             # the Mosaic calls among those under mixer/mamba/ssd (the gauge
             # ssd/mosaic_calls): 0 where the scan ran in its jax.numpy form
             "ssd_mosaic_calls": step_report.get("ssd_mosaic_calls"),
-            # the blocks that run Kimi Delta Attention and their chunk
-            # length, by the compiled step's loops (the gauges kda/blocks
-            # and kda/chunk); None for a model without such a block
+            # the blocks that run Kimi Delta Attention, their chunk length
+            # and the Mosaic calls under mixer/kda/scan, by the compiled
+            # step's kernel calls or, where that is 0, its loops (the
+            # gauges kda/blocks, kda/chunk and kda/mosaic_calls); None for
+            # a model without such a block
             "kda": step_report.get("kda"),
             "exit_code": exit_code}
 

@@ -1413,7 +1413,9 @@ def kda_pairs(q: jax.Array, k: jax.Array, G: jax.Array, sub: int,
 
 def kda_chunked(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
                 beta: jax.Array, chunk: int,
-                compute_dtype=jnp.bfloat16) -> jax.Array:
+                compute_dtype=jnp.bfloat16,
+                scan_fn: Optional[Callable[..., jax.Array]] = None
+                ) -> jax.Array:
     """The gated delta rule with a decay a channel (Kimi Delta Attention,
     arXiv:2510.26692) in its chunked form. Per head, with the state ``S``
     [d, dv] (keys x values), zero before the sequence::
@@ -1441,7 +1443,18 @@ def kda_chunked(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
     carried state are float32; the matmul operands are ``compute_dtype``
     with float32 accumulation. A sequence that ``chunk`` does not divide is
     padded with ``g = 0`` and ``beta = 0`` (no decay, no update), and the
-    padding cut off."""
+    padding cut off.
+
+    One algorithm run one of two ways, by what the caller hands in and the
+    shapes alone, as :func:`ssd_chunked`. ``scan_fn`` (the Pallas kernels of
+    ``ops/pallas/kda.py``, which whoever knows the devices hands down:
+    ``parallel/spmd.attention_overrides``) runs where the shapes fit its
+    tiles (``kda.tile_plan``): a chunk's pair matrices, the inverse, ``W``,
+    ``U`` and the carried state then live in VMEM, the backward pass makes
+    a chunk again from the state that entered it, and nothing of group size
+    exists. Otherwise it is the ``jax.numpy`` below."""
+    from hetu_galvatron_tpu.ops.pallas.kda import tile_plan
+
     f32 = jnp.float32
     B_, S, H, d = q.shape
     pad = -S % chunk
@@ -1450,6 +1463,9 @@ def kda_chunked(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
             jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
             for t in (q, k, v, g, beta))
     C, nC = chunk, (S + pad) // chunk
+    if scan_fn is not None and tile_plan(C, H, d, v.shape[-1]) is not None:
+        return scan_fn(q.astype(compute_dtype), k.astype(compute_dtype),
+                       v.astype(compute_dtype), g, beta, C)[:, :S]
     sub, _ = kda_sub_blocks(C)
     size = kda_chunks_a_group(B_, nC, H, C, d)
 
@@ -1509,6 +1525,7 @@ def apply_kda(
     x: jax.Array,
     cfg: ModelArgs,
     compute_dtype=jnp.bfloat16,
+    kda_fn: Optional[Callable[..., jax.Array]] = None,
 ) -> jax.Array:
     """``[q~ | k~ | v] = silu(conv1d_causal(x W_qkv))`` (depthwise,
     ``kda_conv_kernel`` taps, zero history before the sequence, no bias); a
@@ -1519,7 +1536,8 @@ def apply_kda(
     head; ``y W_out``. No softmax, no positions. The projections and the
     recurrence's matmuls run in ``compute_dtype`` with float32
     accumulation; the convolution, the L2 norms, the decay, ``beta``, the
-    state and the gated norm are float32."""
+    state and the gated norm are float32. ``kda_fn``: the kernels for the
+    recurrence (:func:`kda_chunked`'s ``scan_fn``)."""
     B, S, _ = x.shape
     nh, d, inner = cfg.kda_num_heads, cfg.kda_head_dim, cfg.kda_inner
     f32 = jnp.float32
@@ -1572,7 +1590,7 @@ def apply_kda(
             beta = jax.nn.sigmoid(b)
         with jax.named_scope("scan"):
             o = kda_chunked(q, k, v, g, beta, cfg.kda_chunk_size,
-                            compute_dtype)
+                            compute_dtype, scan_fn=kda_fn)
         with jax.named_scope("gated_norm"):
             var = jnp.mean(jnp.square(o), axis=-1, keepdims=True)
             y = (o * jax.lax.rsqrt(var + cfg.layernorm_epsilon)
@@ -1595,14 +1613,16 @@ def apply_mixer(
     shard_fn: Optional[Callable[[jax.Array, int], jax.Array]] = None,
     segment_ids: Optional[jax.Array] = None,
     ssd_fn: Optional[Callable[..., jax.Array]] = None,
+    kda_fn: Optional[Callable[..., jax.Array]] = None,
     **attn_kwargs: Any,
 ) -> jax.Array:
     """A block's operator on its normed input, by the block's mixer kind
     (``ModelArgs.block_kinds``): attention from ``p["attn"]``, the gated
     short convolution from ``p["conv"]``, the Mamba-2 state-space block
     from ``p["mamba"]`` or Kimi Delta Attention from ``p["kda"]``; the last
-    three take no rope, no attention core and no dropout of probabilities,
-    and the mamba block alone takes ``ssd_fn`` (:func:`apply_mamba2`).
+    three take no rope, no attention core and no dropout of probabilities;
+    the mamba block alone takes ``ssd_fn`` (:func:`apply_mamba2`) and the
+    kda block alone ``kda_fn`` (:func:`apply_kda`).
     ``latent_attention`` is attention through low-rank projections, also
     from ``p["attn"]`` (:func:`apply_latent_attention`)."""
     if mixer == "full_attention":
@@ -1636,7 +1656,8 @@ def apply_mixer(
             raise NotImplementedError(
                 "a kda block's projections are not cut over the tp axis "
                 "(eligibility.kda_plan_reason)")
-        return apply_kda(p["kda"], h, cfg, compute_dtype=compute_dtype)
+        return apply_kda(p["kda"], h, cfg, compute_dtype=compute_dtype,
+                         kda_fn=kda_fn)
     return apply_short_conv(p["conv"], h, cfg, compute_dtype=compute_dtype,
                             shard_fn=shard_fn)
 
@@ -1805,6 +1826,7 @@ def apply_decoder_layer(
     shard_fn: Optional[Callable[[jax.Array, int], jax.Array]] = None,
     mixer: str = "full_attention",
     ssd_fn: Optional[Callable[..., jax.Array]] = None,
+    kda_fn: Optional[Callable[..., jax.Array]] = None,
 ) -> jax.Array:
     """Pre-norm residual block (reference GalvatronDecoderLayer,
     modules.py:233). Encoder families (bert, t5 encoder stack) run the same
@@ -1815,10 +1837,10 @@ def apply_decoder_layer(
     matmuls for overlapped tensor-parallel impls (ops/overlap.py);
     ``shard_fn`` keeps a tp > 1 layer's interior on its own shards
     (:func:`apply_attention`). ``mixer`` is the block's operator kind
-    and ``ssd_fn`` a mamba block's kernels (:func:`apply_mixer`; pre-norm
-    blocks only). A model of several residual streams (``cfg.hc_mult``)
-    hands ``x`` [B, S, n, H] and the block's maps ``hc1`` / ``hc2``
-    (:func:`residual`)."""
+    and ``ssd_fn`` / ``kda_fn`` a mamba / kda block's kernels
+    (:func:`apply_mixer`; pre-norm blocks only). A model of several
+    residual streams (``cfg.hc_mult``) hands ``x`` [B, S, n, H] and the
+    block's maps ``hc1`` / ``hc2`` (:func:`residual`)."""
     if causal is None:
         causal = cfg.model_type != "bert"
     r_attn = r_res1 = r_res2 = None
@@ -1860,7 +1882,7 @@ def apply_decoder_layer(
                                compute_dtype=compute_dtype, causal=causal,
                                dropout_rng=r_attn, segment_ids=segment_ids,
                                matmul_fns=matmul_fns, shard_fn=shard_fn,
-                               ssd_fn=ssd_fn),
+                               ssd_fn=ssd_fn, kda_fn=kda_fn),
                    r_res1), cfg)
 
     def mlp_branch(a):

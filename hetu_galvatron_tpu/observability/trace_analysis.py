@@ -498,9 +498,12 @@ OWN_SCOPES = MIXER_SCOPES["mamba"] + MTP_SCOPES + MIXER_SCOPES["kda"]
 # counts (``ssd/mosaic_calls``; ops/pallas/ssd.py traces under it)
 SSD_SCOPE = "mixer/mamba/ssd"
 
-# the scope of a kda block's recurrence, whose loops the step report counts
-# (:func:`kda_loops`)
+# the scope of a kda block's recurrence, whose loops or kernels the step
+# report counts (:func:`kda_loops`, :func:`kda_kernel_calls`;
+# ops/pallas/kda.py traces under it), and the forward kernel's name there
 KDA_SCAN_SCOPE = "mixer/kda/scan"
+KDA_FWD_CALL = "kda_scan_fwd"
+_OPERAND_SHAPES = "operand_layout_constraints="
 _TRIP_COUNT = re.compile(r'"known_trip_count":\{"n":"(\d+)"')
 _BODY = re.compile(r"body=%?([\w.\-]+)")
 _ARRAY_DIMS = re.compile(r"\b(?:pred|[sufb]\w*\d+)\[([\d,]+)\]")
@@ -843,6 +846,32 @@ def kda_loops(hlo_text: str) -> Dict[str, int]:
     body, trips = outer[0]
     inner = [n for comp, _, n in loops if comp == body]
     return {"blocks": len(outer), "chunks": trips * (inner[0] if inner else 1)}
+
+
+def kda_kernel_calls(hlo_text: str) -> Dict[str, int]:
+    """What a compiled step says of its kda blocks' recurrences where they
+    run in the kernels of ``ops/pallas/kda.py`` (the step then has no loop
+    for :func:`kda_loops` to read): ``mosaic_calls``, the Mosaic calls
+    under ``mixer/kda/scan`` in every phase (0 = the ``jax.numpy`` form
+    ran); ``blocks``, the forward kernels of the forward pass among them;
+    ``chunk``, the chunk length by such a call's own operands: the fifth of
+    them is ``beta`` as columns, ``[B, chunks, packs of heads, chunk, heads
+    a pack]``. Zeros for a step without the kernels."""
+    out = {"mosaic_calls": 0, "blocks": 0, "chunk": 0}
+    for _, name, opcode, op_name, _, line, _ in walk_hlo(hlo_text):
+        if opcode != "custom-call" or _MOSAIC_CALL not in line:
+            continue
+        scope, phase = scope_and_phase(op_name)
+        if scope != KDA_SCAN_SCOPE:
+            continue
+        out["mosaic_calls"] += 1
+        if phase == "forward" and name.startswith(KDA_FWD_CALL):
+            out["blocks"] += 1
+            at = line.find(_OPERAND_SHAPES)
+            shapes = _ARRAY_DIMS.findall(line, at) if at >= 0 else ()
+            if len(shapes) >= 5:
+                out["chunk"] = int(shapes[4].split(",")[-2])
+    return out
 
 
 def cores_recomputed(found: Dict[str, Any]) -> int:

@@ -125,6 +125,7 @@ def attention_overrides(
     flash_interpret: bool = False,
     mixers: Optional[Sequence[str]] = None,
     use_ssd_kernel: Optional[bool] = None,
+    use_kda_kernel: Optional[bool] = None,
 ) -> Dict[int, Dict[str, Any]]:
     """Per-layer attention-impl dispatch (reference attention.py:664-720),
     branching on :func:`~hetu_galvatron_tpu.runtime.mesh.attention_core`:
@@ -153,12 +154,17 @@ def attention_overrides(
     ``mixers`` (the layers' mixer kinds, ``ModelArgs.block_kinds``): a
     ``mamba`` layer gets ``ssd_fn``, the Pallas kernels for its chunked scan
     (ops/pallas/ssd.py), when ``use_ssd_kernel`` (None =
-    the same rule: every mesh device is a TPU). Whether the shapes fit the
-    kernels' tiles is ``modules.ssd_chunked``'s to see; it keeps its
-    ``jax.numpy`` form where they do not, and where it is handed nothing."""
+    the same rule: every mesh device is a TPU), and a ``kda`` layer
+    ``kda_fn``, those for its chunked delta rule (ops/pallas/kda.py), when
+    ``use_kda_kernel`` (None = that rule again). Whether the shapes fit the
+    kernels' tiles is ``modules.ssd_chunked``'s and ``kda_chunked``'s to
+    see; each keeps its ``jax.numpy`` form where they do not, and where it
+    is handed nothing."""
     from functools import partial as _partial
 
     from hetu_galvatron_tpu.models.modules import xla_sdpa
+    from hetu_galvatron_tpu.ops.pallas.kda import make_kda_scan
+    from hetu_galvatron_tpu.ops.pallas.ssd import make_ssd_scan
     from hetu_galvatron_tpu.ops.ring_attention import make_ring_sdpa
     from hetu_galvatron_tpu.ops.ulysses import make_ulysses_sdpa
 
@@ -196,15 +202,16 @@ def attention_overrides(
             out[i] = {"sdpa_fn": make_flash_sdpa(
                 mesh, dp_axes=sh.dp_axes, tp_axes=sh.tp_axes,
                 interpret=flash_interpret)}
-    mamba = [i for i, mixer in enumerate(mixers or ()) if mixer == "mamba"]
-    if mamba and (flash_kernel_runs(True, mesh.devices.flat)
-                  if use_ssd_kernel is None else use_ssd_kernel):
-        from hetu_galvatron_tpu.ops.pallas.ssd import make_ssd_scan
-
-        for i in mamba:
-            out.setdefault(i, {})["ssd_fn"] = make_ssd_scan(
-                mesh, dp_axes=per_layer[i].dp_axes,
-                interpret=flash_interpret)
+    for kind, arg, make, use in (
+            ("mamba", "ssd_fn", make_ssd_scan, use_ssd_kernel),
+            ("kda", "kda_fn", make_kda_scan, use_kda_kernel)):
+        layers = [i for i, mixer in enumerate(mixers or ()) if mixer == kind]
+        if layers and (flash_kernel_runs(True, mesh.devices.flat)
+                       if use is None else use):
+            for i in layers:
+                out.setdefault(i, {})[arg] = make(
+                    mesh, dp_axes=per_layer[i].dp_axes,
+                    interpret=flash_interpret)
     return out
 
 

@@ -1,5 +1,6 @@
-"""The flash kernels, and the kernels of the Mamba-2 scan, compiled by the
-real Mosaic / XLA:TPU compilers for a described (not attached) TPU v5e, at
+"""The flash kernels, the kernels of the Mamba-2 scan and those of the
+chunked delta rule, compiled by the real Mosaic / XLA:TPU compilers for a
+described (not attached) TPU v5e, at
 the widths the benchmark's cells run and with every optional operand: what interpret mode cannot refuse (a slice off
 the tiling, a relayout Mosaic has no rule for, too much VMEM) fails here, on
 the CPU, in seconds. Nothing runs, so nothing here is a result or a time.
@@ -12,7 +13,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from hetu_galvatron_tpu.ops.pallas import ssd
+from hetu_galvatron_tpu.ops.pallas import kda, ssd
 from hetu_galvatron_tpu.ops.pallas.flash_attention import flash_sdpa
 
 pytestmark = pytest.mark.kernels
@@ -119,6 +120,59 @@ def test_ssd_scan_forward_and_backward_compile_for_v5e(one_chip, case):
     assert len(calls) == 2, calls
     assert "ssd_scan_bwd" in calls[0] and "ssd_scan_fwd" in calls[1], calls
     assert set(calls) <= set(found["scopes"][ssd.SCOPE])
+
+
+# B, S, heads, keys and values a head, chunk, dtype (``G`` and ``beta`` are
+# float32 whatever the operands are)
+_KDA_CASES = {
+    "kimi_cell": (1, 8192, 32, 128, 64, jnp.bfloat16),
+    "two_rows_f32_all_heads_a_step": (2, 512, 4, 128, 64, jnp.float32),
+    "four_heads_a_pack": (1, 256, 8, 128, 32, jnp.bfloat16),
+    "a_head_a_pack": (1, 512, 2, 128, 128, jnp.bfloat16),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_KDA_CASES))
+def test_kda_scan_forward_and_backward_compile_for_v5e(one_chip, case):
+    """The kernels compile at the cell's shapes, and under per-layer remat
+    a block is three instructions under ``mixer/kda/scan`` in the map the
+    ``kimi_*`` readers lay a trace over (the forward, the forward made
+    again with the entering states kept, the backward by the scope its
+    rule opens); the step report reads its blocks and chunk from the calls
+    themselves, the compiled step having no loop to read them from."""
+    from hetu_galvatron_tpu.observability.trace_analysis import (
+        kda_kernel_calls,
+        kda_loops,
+        scope_instructions,
+    )
+
+    B, S, H, d, C, dtype = _KDA_CASES[case]
+
+    def spec(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    @jax.checkpoint
+    def block(*a):
+        with jax.named_scope("mixer/kda"):
+            with jax.named_scope("scan"):
+                return kda.kda_scan(*a, C)
+
+    compiled = jax.jit(jax.grad(
+        lambda *a: jnp.sum(jnp.square(block(*a))),
+        argnums=(0, 1, 2, 3, 4))).lower(
+            spec((B, S, H, d), dtype), spec((B, S, H, d), dtype),
+            spec((B, S, H, d), dtype), spec((B, S, H, d), jnp.float32),
+            spec((B, S, H), jnp.float32)).compile()
+    text = compiled.as_text()
+    found = scope_instructions(text, (kda.SCOPE,))
+    calls = sorted(found["mosaic_calls"])
+    assert len(calls) == 3, calls
+    assert "kda_scan_bwd" in calls[0], calls
+    assert all("kda_scan_fwd" in c for c in calls[1:]), calls
+    assert set(calls) <= set(found["scopes"][kda.SCOPE])
+    assert kda_kernel_calls(text) == {"mosaic_calls": 3, "blocks": 1,
+                                      "chunk": C}
+    assert kda_loops(text) == {"blocks": 0, "chunks": 0}
 
 
 @pytest.mark.parametrize("wrapper,forwards,recomputed", [

@@ -489,6 +489,9 @@ def test_the_step_names_the_new_parts_and_counts_its_loops():
     # 40 positions in chunks of 8
     assert trace_analysis.kda_loops(hlo) == {"blocks": 4, "chunks": 5}
     assert trace_analysis.kda_loops("") == {"blocks": 0, "chunks": 0}
+    # and no kernel: what the step report's ``kda/mosaic_calls`` says here
+    assert trace_analysis.kda_kernel_calls(hlo) == {
+        "mosaic_calls": 0, "blocks": 0, "chunk": 0}
 
 
 def test_the_loops_are_counted_through_their_groups(monkeypatch):
@@ -524,6 +527,39 @@ def test_a_loop_without_a_stated_count_is_read_by_its_stacked_operands():
 }}
 """
     assert trace_analysis.kda_loops(hlo) == {"blocks": 1, "chunks": 128}
+
+
+def test_the_kernel_form_is_read_from_its_calls():
+    """Where the recurrence runs in the kernels of ``ops/pallas/kda.py`` the
+    compiled step has no loop under ``mixer/kda/scan``: the blocks are the
+    forward kernels of the forward pass, the chunk the rows of such a
+    call's fifth operand (``beta`` as columns), and ``mosaic_calls``
+    every Mosaic call under the scope: three a block under per-layer remat
+    (the lines are the cell's own, shortened; a flash call beside them is
+    no part of the count)."""
+    from hetu_galvatron_tpu.observability import trace_analysis
+
+    shapes = ("operand_layout_constraints={bf16[1,8192,32,128]{3,2,1,0}, "
+              "bf16[1,8192,32,128]{3,2,1,0}, bf16[1,8192,32,128]{3,2,1,0}, "
+              "f32[1,8192,32,128]{3,2,1,0}, f32[1,128,16,64,2]{4,3,2,1,0}, "
+              "f32[1,128,16,1,128]{4,3,2,1,0}")
+    call = ('custom-call(%a), custom_call_target="tpu_custom_call", '
+            + shapes)
+    hlo = f"""ENTRY %main (a: f32[8]) -> f32[8] {{
+  %kda_scan_fwd.8 = (f32[1,8192,32,128]{{3,2,1,0:T(8,128)}}, f32[1,128,32,128,128]{{4,3,2,1,0:T(8,128)}}) {call}}}, metadata={{op_name="jit(step)/jvp(mixer/kda)/scan/kda_scan_fwd/pallas_call"}}
+  %kda_scan_fwd.9 = f32[1,8192,32,128]{{3,2,1,0:T(8,128)}} {call}}}, metadata={{op_name="jit(step)/jvp(mixer/kda)/scan/kda_scan_fwd/pallas_call"}}
+  %flash_attention_fwd.1 = f32[8]{{0}} custom-call(%a), custom_call_target="tpu_custom_call", metadata={{op_name="jit(step)/jvp()/attn/core/flash_attention_fwd/pallas_call"}}
+  %kda_scan_fwd.12 = f32[1,8192,32,128]{{3,2,1,0:T(8,128)}} {call}}}, metadata={{op_name="jit(step)/transpose(jvp())/checkpoint/rematted_computation/mixer/kda/scan/kda_scan_fwd/pallas_call"}}
+  %kda_scan_bwd.3 = bf16[1,8192,32,128]{{3,2,1,0:T(8,128)(2,1)}} {call}, f32[1,128,32,128,128]{{4,3,2,1,0}}, f32[1,8192,32,128]{{3,2,1,0}}}}, metadata={{op_name="jit(step)/transpose(jvp())/checkpoint/mixer/kda/scan/mixer/kda/scan/kda_scan_bwd/pallas_call"}}
+  %kda_scan_fwd.13 = f32[1,8192,32,128]{{3,2,1,0:T(8,128)}} {call}}}, metadata={{op_name="jit(step)/transpose(jvp())/checkpoint/rematted_computation/mixer/kda/scan/kda_scan_fwd/pallas_call"}}
+  %kda_scan_bwd.4 = bf16[1,8192,32,128]{{3,2,1,0:T(8,128)(2,1)}} {call}, f32[1,128,32,128,128]{{4,3,2,1,0}}, f32[1,8192,32,128]{{3,2,1,0}}}}, metadata={{op_name="jit(step)/transpose(jvp())/checkpoint/mixer/kda/scan/mixer/kda/scan/kda_scan_bwd/pallas_call"}}
+}}
+"""
+    assert trace_analysis.kda_kernel_calls(hlo) == {
+        "mosaic_calls": 6, "blocks": 2, "chunk": 64}
+    assert trace_analysis.kda_loops(hlo) == {"blocks": 0, "chunks": 0}
+    assert trace_analysis.kda_kernel_calls("") == {
+        "mosaic_calls": 0, "blocks": 0, "chunk": 0}
 
 
 # ---------------------------------------------------------------------------

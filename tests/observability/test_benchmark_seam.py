@@ -10,6 +10,7 @@ path of ``lfm2moe_c1_s8k``, and a tiny Granite hybrid (a Mamba-2 block and
 an attention block) with the path of ``granite4h_c1_b1``. One case a name,
 so a renamed span fails by its name."""
 
+import contextlib
 import glob
 import io
 import json
@@ -101,21 +102,13 @@ def _gauge_readers():
 GAUGE_READERS = _gauge_readers()
 
 
-@pytest.fixture(scope="module", params=sorted(PRESETS))
-def run(request, tmp_path_factory):
-    """One traced tiny run with the overrides the harness gives a cell (but
-    for the far-away ``train_iters``), its registry left in place as the
-    process's own for the readers to find, and the parent's put back after."""
+@contextlib.contextmanager
+def _launched(argv):
+    """``train_dist.main(argv)`` with a registry of its own, left in place
+    as the process's for the readers to find and the parent's put back
+    after: the registry, ``train()``'s result and the launcher's log."""
     from hetu_galvatron_tpu.cli import train_dist
 
-    tdir = str(tmp_path_factory.mktemp(request.param) / "trace")
-    harness = [w for w in window.harness_overrides(tdir)
-               if not w.startswith(("train.train_iters=",
-                                    "profile.trace_iters="))]
-    yaml, *size = PRESETS[request.param]
-    argv = ([os.path.join(ZOO, yaml)] + size + harness
-            + [f"train.train_iters={ITERS}",
-               f"profile.trace_iters={TRACED}"])
     before = get_registry()
     reg = set_registry(MetricsRegistry())
     try:
@@ -128,14 +121,30 @@ def run(request, tmp_path_factory):
             assert train_dist.main(argv, result=out) == 0
         finally:
             logging.getLogger("hetu_galvatron_tpu").removeHandler(heard)
-        assert len(out["losses"]) == ITERS
-        yield {"preset": request.param, "registry": reg, "result": out,
-               "log": said.getvalue(),
+        yield {"registry": reg, "result": out, "log": said.getvalue()}
+    finally:
+        set_registry(before)
+
+
+@pytest.fixture(scope="module", params=sorted(PRESETS))
+def run(request, tmp_path_factory):
+    """One traced tiny run with the overrides the harness gives a cell (but
+    for the far-away ``train_iters``), its registry left in place as the
+    process's own for the readers to find, and the parent's put back after."""
+    tdir = str(tmp_path_factory.mktemp(request.param) / "trace")
+    harness = [w for w in window.harness_overrides(tdir)
+               if not w.startswith(("train.train_iters=",
+                                    "profile.trace_iters="))]
+    yaml, *size = PRESETS[request.param]
+    argv = ([os.path.join(ZOO, yaml)] + size + harness
+            + [f"train.train_iters={ITERS}",
+               f"profile.trace_iters={TRACED}"])
+    with _launched(argv) as ran:
+        assert len(ran["result"]["losses"]) == ITERS
+        yield {"preset": request.param, **ran,
                "trace": xplane.find_xplane(tdir), "trace_dir": tdir,
                # the CPU's allocator states no limit; a chip's does
                "facts": {"memory": {"per_device": [{"bytes_limit": 2 ** 34}]}}}
-    finally:
-        set_registry(before)
 
 
 @pytest.mark.parametrize("path", sorted(host_phases.PART_OF))
@@ -287,6 +296,53 @@ def test_the_step_report_says_whether_the_scan_kernels_engaged(run):
     assert gauges == [0] and run["result"]["ssd_mosaic_calls"] == 0
     assert "0 Mosaic calls (0 under mixer/mamba/ssd)," in report
     assert "attention cores: 1 x mamba2, 1 x xla" in run["log"]
+
+
+# a tiny Kimi Linear: a kda block, a latent one, dense feed-forwards; the
+# cell ``kimilin_c1_b1_s8k``'s step report, whose ``kda`` entry the
+# benchmark's ``program.equals.kda_chunk_size`` reads
+KIMI = ["kimi-linear-48b-a3b.yaml"] + SIZE + [
+    "model.layer_types=[kda,latent_attention]", "model.num_dense_layers=2",
+    "model.num_key_value_heads=2", "model.head_dim_override=null",
+    "model.kda_num_heads=2", "model.kda_head_dim=8",
+    "model.kda_chunk_size=8", "model.kv_lora_rank=8",
+    "model.qk_nope_head_dim=8", "model.qk_rope_head_dim=4",
+    "model.v_head_dim=8", "model.ffn_hidden_size=32",
+    "model.moe_ffn_hidden_size=16", "model.num_experts=4",
+    "model.moe_topk=2", "train.train_iters=2"]
+
+
+@pytest.fixture(scope="module")
+def kimi_run():
+    yaml, *size = KIMI
+    with _launched([os.path.join(ZOO, yaml)] + size) as ran:
+        yield ran
+
+
+@pytest.mark.parametrize("part,value", [
+    ("blocks", 1), ("chunk", 8), ("mosaic_calls", 0)])
+def test_the_step_report_says_how_the_delta_rule_ran(kimi_run, part, value):
+    """``kda/blocks``, ``kda/chunk`` and ``kda/mosaic_calls`` beside
+    ``ssd/mosaic_calls``: the gauge, ``train()``'s result and the ``step
+    report:`` line say the same. A step compiled for a CPU holds no kernel
+    (``mosaic_calls`` 0: the recurrence ran in its ``jax.numpy`` form), and
+    its blocks and chunk (16 positions in chunks of 8) are read from its
+    loops; the block is ``kda`` on the ``attention cores:`` line whichever
+    way its recurrence runs."""
+    gauges = [m.value for m in kimi_run["registry"].metrics()
+              if m.name == f"kda/{part}"]
+    (report,) = [line for line in kimi_run["log"].splitlines()
+                 if "step report:" in line]
+    assert gauges == [value] and kimi_run["result"]["kda"][part] == value
+    assert f"kda/{part} {value}" in report
+    assert "attention cores: 1 x kda, 1 x xla" in kimi_run["log"]
+    assert kimi_run["result"]["ssd_mosaic_calls"] is None
+
+
+def test_a_model_without_a_kda_block_reports_none(run):
+    assert run["result"]["kda"] is None
+    assert not [m for m in run["registry"].metrics()
+                if m.name.startswith("kda/")]
 
 
 def test_the_step_report_counts_the_cores_remat_runs_again(run):
