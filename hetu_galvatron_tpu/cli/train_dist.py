@@ -677,12 +677,15 @@ def train(args) -> Dict[str, Any]:
     # what the compiled step contains (filled after the first step)
     from hetu_galvatron_tpu.observability.trace_analysis import (
         SSD_SCOPE,
+        conv_kernel_calls,
         cores_recomputed,
         kda_kernel_calls,
         kda_loops,
         record_step_scopes,
         step_hlo,
     )
+
+    from hetu_galvatron_tpu.parallel.spmd import CONV_KERNEL_MIXERS
 
     step_report: Dict[str, Any] = {}
     it_box = [0]  # the iteration run_loop is in, for the spans below
@@ -1158,6 +1161,16 @@ def train(args) -> Dict[str, Any]:
                             for n in found["scopes"].get(SSD_SCOPE, ()))
                         get_registry().gauge("ssd/mosaic_calls").set(
                             step_report["ssd_mosaic_calls"])
+                    if any(m in CONV_KERNEL_MIXERS for m, _ in kinds):
+                        # whether the convolution's kernels engaged: their
+                        # calls by phase, one a block in each where they
+                        # did, zeros = the jax.numpy form
+                        step_report["conv_kernel_calls"] = (
+                            conv_kernel_calls(found))
+                        for part, v in step_report[
+                                "conv_kernel_calls"].items():
+                            get_registry().gauge("conv/kernel_calls",
+                                                 phase=part).set(v)
                     step_report["static_memory"] = compiled_memory_bytes(
                         compiled)
                     for part, v in step_report["static_memory"].items():
@@ -1186,6 +1199,10 @@ def train(args) -> Dict[str, Any]:
                        "kda/mosaic_calls {mosaic_calls}".format(
                         **step_report["kda"]) if "kda" in step_report
                        else "")
+                    + (", conv kernels {forward} forward {recompute} "
+                       "recompute {backward} backward".format(
+                        **step_report["conv_kernel_calls"])
+                       if "conv_kernel_calls" in step_report else "")
                     + f", {step_report['cores_recomputed']} cores recomputed,"
                     f" static live peak "
                     f"{step_report['static_memory']['live_peak'] / 2**30:.3f}"
@@ -1264,6 +1281,10 @@ def train(args) -> Dict[str, Any]:
             # gauges kda/blocks, kda/chunk and kda/mosaic_calls); None for
             # a model without such a block
             "kda": step_report.get("kda"),
+            # the convolution's kernel calls of that step by phase (the
+            # gauges conv/kernel_calls{phase=...}; zeros = the jax.numpy
+            # form); None for a model no block of which convolves
+            "conv_kernel_calls": step_report.get("conv_kernel_calls"),
             "exit_code": exit_code}
 
 

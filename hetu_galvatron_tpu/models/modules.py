@@ -909,18 +909,63 @@ def init_short_conv(key: jax.Array, cfg: ModelArgs) -> Tuple[Params, Axes]:
     return p, a
 
 
-def causal_depthwise_conv(u: jax.Array, taps: jax.Array) -> jax.Array:
-    """``c[t] = sum_j taps[:, j] * u[t - (L - 1 - j)]`` a channel: ``u``
-    [B, S, C] float32, ``taps`` [C, L]; causal, zeros before the sequence,
-    as L shifted products."""
+def causal_depthwise_conv(
+    u: jax.Array,
+    taps: jax.Array,
+    bias: Optional[jax.Array] = None,
+    *,
+    pre: Optional[jax.Array] = None,
+    post: Optional[jax.Array] = None,
+    silu: bool = False,
+    head_norm: Optional[Tuple[int, float, Tuple[Optional[float], ...]]] = None,
+    out_dtype=jnp.float32,
+    conv_fn: Optional[Callable[..., Optional[jax.Array]]] = None,
+    scope: str = "",
+) -> jax.Array:
+    """``c[t] = sum_j taps[:, j] * x[t - (L - 1 - j)]`` a channel, causal,
+    zeros before the sequence, with what its callers do on both sides of it,
+    all in float32: ``x = pre * u`` (``u``, ``pre``, ``post`` [B, S, C] in
+    any dtype, ``taps`` [C, L]), ``c + bias``, SiLU, ``head_norm`` = (lanes
+    a head, epsilon, a scale for each equal part of the channels or None):
+    a head of a part with a scale is divided by its L2 norm and multiplied
+    by the scale; then ``post *``, and the cast to ``out_dtype``.
+
+    ``conv_fn`` (the Pallas kernels of ``ops/pallas/conv.py``, handed down
+    by who knows the devices, ``parallel/spmd.attention_overrides``) runs
+    the whole of it as one pass over HBM a direction where the shapes fit
+    its tiles (it answers None where they do not); ``scope`` is the
+    ``jax.named_scope`` path of the caller, for its backward. Otherwise, and
+    on a CPU, the ``jax.numpy`` form: ``L`` padded, shifted products."""
+    if conv_fn is not None:
+        out = conv_fn(u, taps, bias, pre=pre, post=post, silu=silu,
+                      head_norm=head_norm, out_dtype=out_dtype, scope=scope)
+        if out is not None:
+            return out
+    f32 = jnp.float32
     S, L = u.shape[1], taps.shape[1]
-    taps = taps.astype(jnp.float32)
-    c = u * taps[:, L - 1]
+    x = u.astype(f32) if pre is None else pre.astype(f32) * u.astype(f32)
+    taps = taps.astype(f32)
+    c = x * taps[:, L - 1]
     for back in range(1, L):
-        # u[t - back]: zeros before the sequence
-        shifted = jnp.pad(u, ((0, 0), (back, 0), (0, 0)))[:, :S]
+        # x[t - back]: zeros before the sequence
+        shifted = jnp.pad(x, ((0, 0), (back, 0), (0, 0)))[:, :S]
         c = c + shifted * taps[:, L - 1 - back]
-    return c
+    if bias is not None:
+        c = c + bias
+    if silu:
+        c = jax.nn.silu(c)
+    if head_norm is not None:
+        head, eps, scales = head_norm
+        heads = c.reshape(c.shape[:2] + (len(scales), -1, head))
+        r = jax.lax.rsqrt(
+            jnp.sum(jnp.square(heads), axis=-1, keepdims=True) + eps)
+        c = jnp.stack(
+            [heads[:, :, i] if scale is None
+             else heads[:, :, i] * (r[:, :, i] * scale)
+             for i, scale in enumerate(scales)], axis=2).reshape(c.shape)
+    if post is not None:
+        c = post.astype(f32) * c
+    return c.astype(out_dtype)
 
 
 def apply_short_conv(
@@ -929,12 +974,14 @@ def apply_short_conv(
     cfg: ModelArgs,
     compute_dtype=jnp.bfloat16,
     shard_fn: Optional[Callable[[jax.Array, int], jax.Array]] = None,
+    conv_fn: Optional[Callable[..., Optional[jax.Array]]] = None,
 ) -> jax.Array:
     """``[B, C, X] = split3(x W_in)``; ``u = B * X``; ``c[t] = sum_j
     taps[:, j] * u[t - (L - 1 - j)]``, causal, zero history before the
     sequence; ``(C * c) W_out``. No softmax, no positions. The two
     projections run in ``compute_dtype`` with float32 accumulation; the
-    gates and the taps between them are one elementwise pass in float32."""
+    gates and the taps between them are one elementwise pass in float32
+    (:func:`causal_depthwise_conv`, which takes ``conv_fn``)."""
     with jax.named_scope("mixer/short_conv"):
         with jax.named_scope("in_proj"):
             bcx = jnp.einsum("bsh,ghc->gbsc", x.astype(compute_dtype),
@@ -945,9 +992,11 @@ def apply_short_conv(
             if shard_fn is not None:
                 thirds = [shard_fn(t, 2) for t in thirds]
         with jax.named_scope("gate_conv"):
-            gate_b, gate_c, xs = (t.astype(jnp.float32) for t in thirds)
-            c = causal_depthwise_conv(gate_b * xs, p["taps"])
-            y = (gate_c * c).astype(compute_dtype)
+            gate_b, gate_c, xs = thirds
+            y = causal_depthwise_conv(
+                xs, p["taps"], pre=gate_b, post=gate_c,
+                out_dtype=compute_dtype, conv_fn=conv_fn,
+                scope="mixer/short_conv/gate_conv")
             if shard_fn is not None:
                 y = shard_fn(y, 2)
         with jax.named_scope("out_proj"):
@@ -1148,6 +1197,7 @@ def apply_mamba2(
     cfg: ModelArgs,
     compute_dtype=jnp.bfloat16,
     ssd_fn: Optional[Callable[..., jax.Array]] = None,
+    conv_fn: Optional[Callable[..., Optional[jax.Array]]] = None,
 ) -> jax.Array:
     """``[z | xBC | dt] = x W_in``; ``xBC = silu(conv1d_causal(xBC) + b)``
     (depthwise, ``mamba_d_conv`` taps, zero history before the sequence);
@@ -1159,7 +1209,8 @@ def apply_mamba2(
     accumulation; ``dt``, the decays, the state, the convolution and the
     gated norm are float32. ``ssd_fn``: the kernels for the
     recurrence, where the caller's devices run them
-    (:func:`ssd_chunked`'s ``scan_fn``)."""
+    (:func:`ssd_chunked`'s ``scan_fn``), and ``conv_fn`` those for the
+    convolution, its bias and SiLU (:func:`causal_depthwise_conv`)."""
     B, S, _ = x.shape
     nh, hp, N = cfg.mamba_n_heads, cfg.mamba_d_head, cfg.mamba_d_state
     di, f32 = cfg.mamba_d_inner, jnp.float32
@@ -1172,11 +1223,10 @@ def apply_mamba2(
                                    axis=-1)
             z, xbc = z.astype(compute_dtype), xbc.astype(compute_dtype)
         with jax.named_scope("conv"):
-            c = causal_depthwise_conv(xbc.astype(f32), p["taps"])
-            if "conv_bias" in p:
-                c = c + p["conv_bias"]
-            xs, Bm, Cm = jnp.split(
-                jax.nn.silu(c).astype(compute_dtype), [di, di + N], axis=-1)
+            xs, Bm, Cm = jnp.split(causal_depthwise_conv(
+                xbc, p["taps"], p.get("conv_bias"), silu=True,
+                out_dtype=compute_dtype, conv_fn=conv_fn,
+                scope="mixer/mamba/conv"), [di, di + N], axis=-1)
         with jax.named_scope("ssd"):
             dt = jax.nn.softplus(dt + p["dt_bias"])
             y = ssd_chunked(xs.reshape(B, S, nh, hp), dt,
@@ -1526,6 +1576,7 @@ def apply_kda(
     cfg: ModelArgs,
     compute_dtype=jnp.bfloat16,
     kda_fn: Optional[Callable[..., jax.Array]] = None,
+    conv_fn: Optional[Callable[..., Optional[jax.Array]]] = None,
 ) -> jax.Array:
     """``[q~ | k~ | v] = silu(conv1d_causal(x W_qkv))`` (depthwise,
     ``kda_conv_kernel`` taps, zero history before the sequence, no bias); a
@@ -1537,7 +1588,9 @@ def apply_kda(
     recurrence's matmuls run in ``compute_dtype`` with float32
     accumulation; the convolution, the L2 norms, the decay, ``beta``, the
     state and the gated norm are float32. ``kda_fn``: the kernels for the
-    recurrence (:func:`kda_chunked`'s ``scan_fn``)."""
+    recurrence (:func:`kda_chunked`'s ``scan_fn``); ``conv_fn``: those for
+    the convolution of the three at once, SiLU and the L2 norms
+    (:func:`causal_depthwise_conv`)."""
     B, S, _ = x.shape
     nh, d, inner = cfg.kda_num_heads, cfg.kda_head_dim, cfg.kda_inner
     f32 = jnp.float32
@@ -1547,26 +1600,14 @@ def apply_kda(
                           weight_view(w, compute_dtype),
                           preferred_element_type=f32)
 
-    # The two functions below are made again in the backward pass from the
-    # projections' outputs, q, k and v one at a time: their float32 passes
-    # would otherwise all be kept while the scan's backward runs. Each opens
-    # the mixer's scope itself and is called outside it, so that an
-    # instruction's name reads ``checkpoint/mixer/kda/conv`` and not
-    # ``mixer/kda/checkpoint/conv``, which no scope of the vocabulary ends.
-    def conv(u, taps, norm_scale):
-        """One of q, k, v: the convolution, SiLU and, for q and k
-        (``norm_scale``), a head's L2 norm."""
-        with jax.named_scope("mixer/kda"):
-            with jax.named_scope("conv"):
-                c = jax.nn.silu(causal_depthwise_conv(u.astype(f32), taps))
-                c = c.reshape(B, S, nh, d)
-            if norm_scale is not None:
-                with jax.named_scope("gates"):
-                    c = c * (jax.lax.rsqrt(jnp.sum(
-                        jnp.square(c), axis=-1, keepdims=True) + KDA_L2_EPS)
-                             * norm_scale)
-            return c.astype(compute_dtype)
-
+    # Made again in the backward pass from the projection's output: its
+    # float32 passes would otherwise be kept while the scan's backward
+    # runs. It opens the mixer's scope itself and is called outside it, so
+    # that an instruction's name reads ``checkpoint/mixer/kda/gates`` and
+    # not ``mixer/kda/checkpoint/gates``, which no scope of the vocabulary
+    # ends. (The convolution needs none: its kernels keep the projection's
+    # output alone, and the ``jax.numpy`` form runs where memory is no
+    # matter.)
     def log_decay(f, dt_bias, A_log):
         with jax.named_scope("mixer/kda"):
             with jax.named_scope("gates"):
@@ -1575,15 +1616,18 @@ def apply_kda(
 
     with jax.named_scope("mixer/kda"):
         with jax.named_scope("in_proj"):
-            qkv = jnp.split(proj(x, p["wqkv"]).astype(compute_dtype), 3,
-                            axis=-1)
+            qkv = proj(x, p["wqkv"]).astype(compute_dtype)
             f_a, g_a, b = jnp.split(proj(x, p["wlow"]), [d, 2 * d], axis=-1)
             f = proj(f_a, p["wf_b"])
             z = proj(g_a, p["wg_b"]).astype(compute_dtype)
         with jax.named_scope("conv"):
-            taps = jnp.split(p["taps"], 3)
-    q, k, v = (jax.checkpoint(conv, static_argnums=(2,))(u, t, norm_scale)
-               for u, t, norm_scale in zip(qkv, taps, (d ** -0.5, 1.0, None)))
+            # q, k and v in one pass; a head of q and of k L2-normed
+            q, k, v = (t.reshape(B, S, nh, d) for t in jnp.split(
+                causal_depthwise_conv(
+                    qkv, p["taps"], silu=True,
+                    head_norm=(d, KDA_L2_EPS, (d ** -0.5, 1.0, None)),
+                    out_dtype=compute_dtype, conv_fn=conv_fn,
+                    scope="mixer/kda/conv"), 3, axis=-1))
     g = jax.checkpoint(log_decay)(f, p["dt_bias"], p["A_log"])
     with jax.named_scope("mixer/kda"):
         with jax.named_scope("gates"):
@@ -1614,6 +1658,7 @@ def apply_mixer(
     segment_ids: Optional[jax.Array] = None,
     ssd_fn: Optional[Callable[..., jax.Array]] = None,
     kda_fn: Optional[Callable[..., jax.Array]] = None,
+    conv_fn: Optional[Callable[..., Optional[jax.Array]]] = None,
     **attn_kwargs: Any,
 ) -> jax.Array:
     """A block's operator on its normed input, by the block's mixer kind
@@ -1622,7 +1667,9 @@ def apply_mixer(
     from ``p["mamba"]`` or Kimi Delta Attention from ``p["kda"]``; the last
     three take no rope, no attention core and no dropout of probabilities;
     the mamba block alone takes ``ssd_fn`` (:func:`apply_mamba2`) and the
-    kda block alone ``kda_fn`` (:func:`apply_kda`).
+    kda block alone ``kda_fn`` (:func:`apply_kda`); all three take
+    ``conv_fn``, their convolution's kernels
+    (:func:`causal_depthwise_conv`).
     ``latent_attention`` is attention through low-rank projections, also
     from ``p["attn"]`` (:func:`apply_latent_attention`)."""
     if mixer == "full_attention":
@@ -1650,16 +1697,16 @@ def apply_mixer(
             "data.reset_attention_mask=false")
     if mixer == "mamba":
         return apply_mamba2(p["mamba"], h, cfg, compute_dtype=compute_dtype,
-                            ssd_fn=ssd_fn)
+                            ssd_fn=ssd_fn, conv_fn=conv_fn)
     if mixer == "kda":
         if shard_fn is not None or attn_kwargs.get("matmul_fns"):
             raise NotImplementedError(
                 "a kda block's projections are not cut over the tp axis "
                 "(eligibility.kda_plan_reason)")
         return apply_kda(p["kda"], h, cfg, compute_dtype=compute_dtype,
-                         kda_fn=kda_fn)
+                         kda_fn=kda_fn, conv_fn=conv_fn)
     return apply_short_conv(p["conv"], h, cfg, compute_dtype=compute_dtype,
-                            shard_fn=shard_fn)
+                            shard_fn=shard_fn, conv_fn=conv_fn)
 
 
 # ---------------------------------------------------------------------------
@@ -1827,6 +1874,7 @@ def apply_decoder_layer(
     mixer: str = "full_attention",
     ssd_fn: Optional[Callable[..., jax.Array]] = None,
     kda_fn: Optional[Callable[..., jax.Array]] = None,
+    conv_fn: Optional[Callable[..., Optional[jax.Array]]] = None,
 ) -> jax.Array:
     """Pre-norm residual block (reference GalvatronDecoderLayer,
     modules.py:233). Encoder families (bert, t5 encoder stack) run the same
@@ -1837,8 +1885,8 @@ def apply_decoder_layer(
     matmuls for overlapped tensor-parallel impls (ops/overlap.py);
     ``shard_fn`` keeps a tp > 1 layer's interior on its own shards
     (:func:`apply_attention`). ``mixer`` is the block's operator kind
-    and ``ssd_fn`` / ``kda_fn`` a mamba / kda block's kernels
-    (:func:`apply_mixer`; pre-norm blocks only). A model of several
+    and ``ssd_fn`` / ``kda_fn`` / ``conv_fn`` a mamba / kda / convolving
+    block's kernels (:func:`apply_mixer`; pre-norm blocks only). A model of several
     residual streams (``cfg.hc_mult``) hands ``x`` [B, S, n, H] and the
     block's maps ``hc1`` / ``hc2`` (:func:`residual`)."""
     if causal is None:
@@ -1882,7 +1930,8 @@ def apply_decoder_layer(
                                compute_dtype=compute_dtype, causal=causal,
                                dropout_rng=r_attn, segment_ids=segment_ids,
                                matmul_fns=matmul_fns, shard_fn=shard_fn,
-                               ssd_fn=ssd_fn, kda_fn=kda_fn),
+                               ssd_fn=ssd_fn, kda_fn=kda_fn,
+                               conv_fn=conv_fn),
                    r_res1), cfg)
 
     def mlp_branch(a):

@@ -568,12 +568,13 @@ def apply_moe_decoder_layer(
     mixer: str = "full_attention",
     ssd_fn=None,
     kda_fn=None,
+    conv_fn=None,
 ) -> Tuple[jax.Array, jax.Array, Dict[str, jax.Array]]:
     """Pre-norm block with an MoE FFN; returns (x, aux_loss, router
     stats) — stats feed the per-layer balance tracker (reference
     moe_utils.py:547-644). ``mixer``: the block's operator kind, and
-    ``ssd_fn`` / ``kda_fn`` a mamba / kda block's kernels
-    (modules.apply_mixer). Several
+    ``ssd_fn`` / ``kda_fn`` / ``conv_fn`` a mamba / kda / convolving
+    block's kernels (modules.apply_mixer). Several
     residual streams: as modules.apply_decoder_layer."""
     r_attn = r_res1 = r_res2 = None
     if dropout_rng is not None:
@@ -586,7 +587,7 @@ def apply_moe_decoder_layer(
             M.apply_mixer(p, h, cfg, mixer, rope=rope, sdpa_fn=sdpa_fn,
                           compute_dtype=compute_dtype, dropout_rng=r_attn,
                           segment_ids=segment_ids, ssd_fn=ssd_fn,
-                          kda_fn=kda_fn),
+                          kda_fn=kda_fn, conv_fn=conv_fn),
             cfg.hidden_dropout, r_res1), cfg)
 
     def experts_branch(a):

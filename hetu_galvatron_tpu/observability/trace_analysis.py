@@ -508,6 +508,13 @@ _TRIP_COUNT = re.compile(r'"known_trip_count":\{"n":"(\d+)"')
 _BODY = re.compile(r"body=%?([\w.\-]+)")
 _ARRAY_DIMS = re.compile(r"\b(?:pred|[sufb]\w*\d+)\[([\d,]+)\]")
 
+# the scopes a causal depthwise convolution is entered under, one a mixer
+# kind, and its kernels' names there (ops/pallas/conv.py), which the step
+# report counts by phase (:func:`conv_kernel_calls`)
+CONV_SCOPES = ("mixer/kda/conv", "mixer/mamba/conv",
+               "mixer/short_conv/gate_conv")
+CONV_CALLS = ("causal_conv_fwd", "causal_conv_bwd")
+
 # the flash forward kernel's name (ops/pallas/flash_attention.py), which its
 # instruction in the compiled step carries (``flash_attention_fwd.3``)
 FLASH_FWD_CALL = "flash_attention_fwd"
@@ -884,6 +891,24 @@ def cores_recomputed(found: Dict[str, Any]) -> int:
     return sum(name.startswith(FLASH_FWD_CALL)
                and phases[name][1] == "recompute"
                for name in found["mosaic_calls"])
+
+
+def conv_kernel_calls(found: Dict[str, Any]) -> Dict[str, int]:
+    """The convolution's kernels of a step (``found``: :func:`step_hlo`'s
+    answer), by the phase its map puts them in: the Mosaic calls named
+    ``CONV_CALLS`` under one of ``CONV_SCOPES``. A block whose convolution
+    runs in them counts once in each of ``forward``, ``recompute`` (the
+    forward kernel again, under per-layer remat) and ``backward``; zeros
+    where the ``jax.numpy`` form ran (the gauges
+    ``conv/kernel_calls{phase=...}``)."""
+    out = {"forward": 0, "recompute": 0, "backward": 0}
+    placed = found["map"]["instructions"]
+    for name in found["mosaic_calls"]:
+        if name.startswith(CONV_CALLS):
+            scope, phase = placed[name][:2]
+            if scope in CONV_SCOPES and phase in out:
+                out[phase] += 1
+    return out
 
 
 # what ``step_hlo`` found in the step program this process last reported

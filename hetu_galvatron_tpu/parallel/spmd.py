@@ -115,6 +115,11 @@ def opt_state_specs(
     return jax.tree_util.tree_map_with_path(for_leaf, state_shape)
 
 
+# the mixer kinds whose convolution runs in the kernels of ops/pallas/conv.py
+# where the devices run them (``attention_overrides``)
+CONV_KERNEL_MIXERS = ("mamba", "kda", "conv")
+
+
 def attention_overrides(
     per_layer: List[LayerSharding],
     mesh: Mesh,
@@ -126,6 +131,7 @@ def attention_overrides(
     mixers: Optional[Sequence[str]] = None,
     use_ssd_kernel: Optional[bool] = None,
     use_kda_kernel: Optional[bool] = None,
+    use_conv_kernel: Optional[bool] = None,
 ) -> Dict[int, Dict[str, Any]]:
     """Per-layer attention-impl dispatch (reference attention.py:664-720),
     branching on :func:`~hetu_galvatron_tpu.runtime.mesh.attention_core`:
@@ -159,10 +165,17 @@ def attention_overrides(
     ``use_kda_kernel`` (None = that rule again). Whether the shapes fit the
     kernels' tiles is ``modules.ssd_chunked``'s and ``kda_chunked``'s to
     see; each keeps its ``jax.numpy`` form where they do not, and where it
-    is handed nothing."""
+    is handed nothing. Both kinds and a ``conv`` layer
+    (``CONV_KERNEL_MIXERS``) also get ``conv_fn``, the kernels for their
+    causal depthwise convolution and what rides in its pass
+    (ops/pallas/conv.py; ``modules.causal_depthwise_conv``), when
+    ``use_conv_kernel`` (None = that rule once more) and the layer's
+    sequence is whole on a device; a layer's channels may be cut over its
+    weight-tp axes, a depthwise convolution being local to a shard."""
     from functools import partial as _partial
 
     from hetu_galvatron_tpu.models.modules import xla_sdpa
+    from hetu_galvatron_tpu.ops.pallas.conv import make_causal_conv
     from hetu_galvatron_tpu.ops.pallas.kda import make_kda_scan
     from hetu_galvatron_tpu.ops.pallas.ssd import make_ssd_scan
     from hetu_galvatron_tpu.ops.ring_attention import make_ring_sdpa
@@ -211,6 +224,14 @@ def attention_overrides(
             for i in layers:
                 out.setdefault(i, {})[arg] = make(
                     mesh, dp_axes=per_layer[i].dp_axes,
+                    interpret=flash_interpret)
+    if (flash_kernel_runs(True, mesh.devices.flat)
+            if use_conv_kernel is None else use_conv_kernel):
+        for i, mixer in enumerate(mixers or ()):
+            sh = per_layer[i]
+            if mixer in CONV_KERNEL_MIXERS and not sh.cp_axes:
+                out.setdefault(i, {})["conv_fn"] = make_causal_conv(
+                    mesh, dp_axes=sh.dp_axes, tp_axes=sh.weight_tp_axes,
                     interpret=flash_interpret)
     return out
 

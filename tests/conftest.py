@@ -255,3 +255,47 @@ def forward_and_loss_as_before_pr38(grouped_matmul_as_before_pr38):
         same = [np.array_equal(g, pg) for g, pg in zip(grads, pgrads)]
         assert all(same) if dtype == "float32" else not all(same)
     return check
+
+
+@pytest.fixture
+def conv_kernels_are_the_block(monkeypatch):
+    """``check(apply, params, x, dtype, loss_band, grad_band)``: a mixer
+    block (``apply(params, x, conv_fn=...)``, ``x`` [B, S, H] with the
+    block's channels whole lane tiles and S over one tile of 128 rows)
+    through the kernels of ``ops/pallas/conv.py`` in interpret mode gives
+    the loss and the gradients to ``x`` and to every parameter that the
+    ``jax.numpy`` form gives, the loss within ``loss_band`` and each
+    gradient within ``grad_band`` of its size (relative RMS); and the
+    kernels did run."""
+    def check(apply, params, x, dtype, loss_band, grad_band):
+        import functools
+
+        import jax.numpy as jnp
+        import numpy as np
+        from hetu_galvatron_tpu.ops.pallas import conv
+
+        # tiles of 128 rows: the sequence is several, the last one ragged
+        monkeypatch.setattr(conv, "TILE_BYTES", 128 * 512 * 2)
+        ran = []
+
+        def kernels(*a, **kw):
+            ran.append(conv.causal_conv(*a, **kw, interpret=True))
+            return ran[-1]
+
+        def loss(conv_fn, p, a):
+            y = apply(p, a, conv_fn=conv_fn).astype(jnp.float32)
+            return jnp.mean(jnp.sin(3.0 * y))
+
+        sides = [jax.value_and_grad(functools.partial(loss, fn),
+                                    argnums=(0, 1))(params, x.astype(dtype))
+                 for fn in (kernels, None)]
+        assert ran and all(out is not None for out in ran)
+        (got, ggrads), (want, wgrads) = sides
+        assert abs(float(got) - float(want)) < loss_band
+        for (path, g), w in zip(jax.tree_util.tree_leaves_with_path(ggrads),
+                                jax.tree.leaves(wgrads)):
+            g, w = np.asarray(g, np.float32), np.asarray(w, np.float32)
+            apart = np.sqrt(np.mean(np.square(g - w))) / max(
+                np.sqrt(np.mean(np.square(w))), 1e-12)
+            assert apart < grad_band, (jax.tree_util.keystr(path), apart)
+    return check

@@ -1,5 +1,5 @@
-"""The flash kernels, the kernels of the Mamba-2 scan and those of the
-chunked delta rule, compiled by the real Mosaic / XLA:TPU compilers for a
+"""The flash kernels, the kernels of the Mamba-2 scan, those of the
+chunked delta rule and those of the causal depthwise convolution, compiled by the real Mosaic / XLA:TPU compilers for a
 described (not attached) TPU v5e, at
 the widths the benchmark's cells run and with every optional operand: what interpret mode cannot refuse (a slice off
 the tiling, a relayout Mosaic has no rule for, too much VMEM) fails here, on
@@ -13,7 +13,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from hetu_galvatron_tpu.ops.pallas import kda, ssd
+from hetu_galvatron_tpu.ops.pallas import conv, kda, ssd
 from hetu_galvatron_tpu.ops.pallas.flash_attention import flash_sdpa
 
 pytestmark = pytest.mark.kernels
@@ -173,6 +173,70 @@ def test_kda_scan_forward_and_backward_compile_for_v5e(one_chip, case):
     assert kda_kernel_calls(text) == {"mosaic_calls": 3, "blocks": 1,
                                       "chunk": C}
     assert kda_loops(text) == {"blocks": 0, "chunks": 0}
+
+
+# B, S, channels, taps, bias, gates, head_norm, dtype, the caller's scope
+_CONV_CASES = {
+    "kimi_cell_three_at_once": (
+        1, 8192, 12288, 4, False, False, (128, 1e-6, (128 ** -0.5, 1.0, None)),
+        jnp.bfloat16, "mixer/kda/conv"),
+    "granite_cell": (1, 8192, 4352, 4, True, False, None, jnp.bfloat16,
+                     "mixer/mamba/conv"),
+    "lfm2_cell_between_its_gates": (
+        1, 8192, 2048, 3, False, True, None, jnp.bfloat16,
+        "mixer/short_conv/gate_conv"),
+    "two_rows_f32_a_ragged_tile": (
+        2, 1100, 768, 4, True, True, (128, 1e-6, (1.0, None, 0.5)),
+        jnp.float32, "mixer/kda/conv"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_CONV_CASES))
+def test_causal_conv_forward_and_backward_compile_for_v5e(one_chip, case):
+    """Both kernels compile at the three cells' shapes, and each is one
+    instruction under its caller's scope in the map a trace is laid over
+    (the forward by the scope it was called in, the backward by the scope
+    its rule is told), which the step report counts by phase. (Under a
+    bare ``jax.checkpoint`` XLA makes one call of the forward and the
+    forward made again, which here nothing separates; a step's layers are
+    apart, and its report reads one a phase: PERF.md section 6, PR 45.)"""
+    from hetu_galvatron_tpu.observability.trace_analysis import (
+        CONV_CALLS,
+        conv_kernel_calls,
+        step_hlo,
+    )
+
+    B, S, C, L, bias, gated, head_norm, dtype, scope = _CONV_CASES[case]
+    outer, inner = scope.rsplit("/", 1)
+
+    def spec(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    wide = spec((B, S, C), dtype)
+    args = {"u": wide, "taps": spec((C, L), jnp.float32)}
+    if bias:
+        args["bias"] = spec((C,), jnp.float32)
+    if gated:
+        args.update(pre=wide, post=wide)
+
+    def block(a):
+        with jax.named_scope(outer):
+            with jax.named_scope(inner):
+                return conv.causal_conv(
+                    a["u"], a["taps"], a.get("bias"), pre=a.get("pre"),
+                    post=a.get("post"), silu=not gated, head_norm=head_norm,
+                    out_dtype=dtype, scope=scope)
+
+    text = jax.jit(jax.grad(lambda a: jnp.sum(jnp.square(
+        block(a).astype(jnp.float32))))).lower(args).compile().as_text()
+    found = step_hlo(text, (scope,))
+    calls = sorted(found["mosaic_calls"])
+    assert len(calls) == 2, calls
+    assert calls[0].startswith(CONV_CALLS[1]), calls
+    assert calls[1].startswith(CONV_CALLS[0]), calls
+    assert set(calls) <= set(found["scopes"][scope])
+    assert conv_kernel_calls(found) == {"forward": 1, "recompute": 0,
+                                        "backward": 1}
 
 
 @pytest.mark.parametrize("wrapper,forwards,recomputed", [

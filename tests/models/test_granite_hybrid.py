@@ -647,3 +647,25 @@ def test_the_cost_model_counts_a_mamba_block():
     head = 2 * 2048 * 12544
     assert model_flops_per_token(cut) == 3.0 * (
         9 * mamba + attn + 10 * mlp + head)
+
+
+@pytest.mark.parametrize("dtype,loss_band,grad_band", [
+    ("float32", 2e-5, 1e-4), ("bfloat16", 2e-2, 5e-2)])
+def test_a_block_through_the_convolutions_kernels_is_the_block(
+        conv_kernels_are_the_block, dtype, loss_band, grad_band):
+    """A mamba block whose x | B | C are whole lane tiles (2 heads of 64,
+    a state of 64: 256 channels) over 300 positions: its convolution, bias
+    and SiLU in the kernels of ``ops/pallas/conv.py`` (interpret mode)
+    against the ``jax.numpy`` form, the scan in its ``jax.numpy`` form on
+    both sides."""
+    cfg = ModelArgs(**{**TINY, "hidden_size": 64, "mamba_n_heads": 2,
+                       "mamba_d_head": 64, "mamba_d_state": 64,
+                       "mamba_chunk_size": 64, "seq_length": 300,
+                       "max_position_embeddings": 512})
+    params, _ = M.init_mamba2(jax.random.key(5), cfg)
+    assert "conv_bias" in params
+    x = jax.random.normal(jax.random.key(6), (2, 300, 64))
+    conv_kernels_are_the_block(
+        lambda p, a, conv_fn: M.apply_mamba2(
+            p, a, cfg, compute_dtype=jnp.dtype(dtype), conv_fn=conv_fn),
+        params, x, dtype, loss_band, grad_band)

@@ -636,3 +636,22 @@ def test_kda_refuses_what_it_is_not_written_for():
     with pytest.raises(NotImplementedError, match="kda_plan_reason"):
         M.apply_mixer(params["layers"][0], jnp.zeros((1, 40, 32)), cfg, "kda",
                       shard_fn=lambda a, axis: a)
+
+
+@pytest.mark.parametrize("dtype,loss_band,grad_band", [
+    ("float32", 2e-5, 1e-4), ("bfloat16", 2e-2, 5e-2)])
+def test_a_block_through_the_convolutions_kernels_is_the_block(
+        conv_kernels_are_the_block, dtype, loss_band, grad_band):
+    """A KDA block whose q, k and v are whole lane tiles (two heads of 128)
+    over 300 positions: its convolution, SiLU and L2 norms as one call of
+    the kernels of ``ops/pallas/conv.py`` over the three at once
+    (interpret mode) against the ``jax.numpy`` form, the recurrence in its
+    ``jax.numpy`` form on both sides."""
+    cfg = ModelArgs(**{**TINY, "hidden_size": 64, "kda_head_dim": 128,
+                       "seq_length": 300, "max_position_embeddings": 512})
+    params, _ = M.init_kda(jax.random.key(5), cfg)
+    x = jax.random.normal(jax.random.key(6), (2, 300, 64))
+    conv_kernels_are_the_block(
+        lambda p, a, conv_fn: M.apply_kda(
+            p, a, cfg, compute_dtype=jnp.dtype(dtype), conv_fn=conv_fn),
+        params, x, dtype, loss_band, grad_band)

@@ -936,3 +936,20 @@ def test_a_held_shares_forward_and_loss_are_the_parents_formulation(
     cfg = ModelArgs(**{**TINY, "moe_held_experts": 2,
                        "moe_first_held_expert": 2})
     forward_and_loss_as_before_pr38(cfg, _seeded(cfg), dtype)
+
+
+@pytest.mark.parametrize("dtype,loss_band,grad_band", [
+    ("float32", 2e-5, 1e-4), ("bfloat16", 2e-2, 5e-2)])
+def test_a_block_through_the_convolutions_kernels_is_the_block(
+        conv_kernels_are_the_block, dtype, loss_band, grad_band):
+    """A conv block of 128 channels over 300 positions: its two gates and
+    three taps in the kernels of ``ops/pallas/conv.py`` (interpret mode)
+    against the ``jax.numpy`` form."""
+    cfg = ModelArgs(**{**TINY, "hidden_size": 128, "seq_length": 300,
+                       "max_position_embeddings": 512})
+    params, _ = M.init_short_conv(jax.random.key(5), cfg)
+    x = jax.random.normal(jax.random.key(6), (2, 300, 128))
+    conv_kernels_are_the_block(
+        lambda p, a, conv_fn: M.apply_short_conv(
+            p, a, cfg, compute_dtype=jnp.dtype(dtype), conv_fn=conv_fn),
+        params, x, dtype, loss_band, grad_band)

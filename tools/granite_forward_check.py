@@ -106,6 +106,7 @@ def main() -> int:
         forward_causal_lm,
         init_causal_lm,
     )
+    from hetu_galvatron_tpu.ops.pallas.conv import causal_conv
     from hetu_galvatron_tpu.ops.pallas.flash_attention import flash_sdpa
     from hetu_galvatron_tpu.ops.pallas.ssd import ssd_scan
     from hetu_galvatron_tpu.utils.hf_config_adapter import resolve_model_config
@@ -169,8 +170,10 @@ def main() -> int:
         jax.random.key(a.seed))
     on_tpu = dev.platform == "tpu"
     # on a TPU what the cell trains with: the flash core, and the scan's
-    # kernels in the mamba blocks
-    sdpa = ({i: {"ssd_fn": ssd_scan} if mixer == "mamba"
+    # and the convolution's kernels in the mamba blocks
+    mamba_kernels = ({"ssd_fn": ssd_scan, "conv_fn": causal_conv}
+                     if on_tpu else {})
+    sdpa = ({i: mamba_kernels if mixer == "mamba"
              else {"sdpa_fn": flash_sdpa}
              for i, (mixer, _) in enumerate(cfg.block_kinds())}
             if on_tpu else None)
@@ -187,7 +190,7 @@ def main() -> int:
         p = {**params["layers"][mamba_at]["mamba"], **leaves}
         return jax.jit(lambda p, x: M.apply_mamba2(
             p, x.astype(jnp.bfloat16), cfg, compute_dtype=jnp.bfloat16,
-            ssd_fn=ssd_scan if on_tpu else None))(p, mamba_in)
+            **mamba_kernels))(p, mamba_in)
 
     def attention_operator(run_cfg):
         rope = None
