@@ -181,14 +181,12 @@ def train(args) -> Dict[str, Any]:
         use_flash = flash_kernel_runs(cfg.use_flash_attn, state.devices)
         # a block that does not attend reports its own operator, so that
         # "every core is flash" stays a statement about the blocks that do
-        from hetu_galvatron_tpu.models.modules import ATTENDING_MIXERS
+        from hetu_galvatron_tpu.models.modules import MIXERS
 
         kinds = cfg.block_kinds(len(hpc.layers))
-        operators = {"conv": "short_conv", "mamba": "mamba2", "kda": "kda"}
         attention_cores = [
-            attention_core(s.cp_size > 1, bool(s.sp and s.tp_size > 1),
-                           use_flash) if mixer in ATTENDING_MIXERS
-            else operators[mixer]
+            MIXERS[mixer].logged or attention_core(
+                s.cp_size > 1, bool(s.sp and s.tp_size > 1), use_flash)
             for s, (mixer, _) in zip(hpc.layers, kinds)]
         state.log("attention cores: " + ", ".join(
             f"{n} x {core}" for core, n in Counter(attention_cores).items()))
@@ -685,7 +683,7 @@ def train(args) -> Dict[str, Any]:
         step_hlo,
     )
 
-    from hetu_galvatron_tpu.parallel.spmd import CONV_KERNEL_MIXERS
+    from hetu_galvatron_tpu.models.modules import MIXERS
 
     step_report: Dict[str, Any] = {}
     it_box = [0]  # the iteration run_loop is in, for the spans below
@@ -1161,7 +1159,7 @@ def train(args) -> Dict[str, Any]:
                             for n in found["scopes"].get(SSD_SCOPE, ()))
                         get_registry().gauge("ssd/mosaic_calls").set(
                             step_report["ssd_mosaic_calls"])
-                    if any(m in CONV_KERNEL_MIXERS for m, _ in kinds):
+                    if any(MIXERS[m].reads("conv") for m, _ in kinds):
                         # whether the convolution's kernels engaged: their
                         # calls by phase, one a block in each where they
                         # did, zeros = the jax.numpy form

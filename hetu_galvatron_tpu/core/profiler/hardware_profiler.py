@@ -22,7 +22,6 @@ from __future__ import annotations
 import os
 import time
 import warnings
-from functools import partial
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -149,40 +148,35 @@ class HardwareProfiler:
         """Time one collective over `group` with a message of `message_mb`
         MB per device (fp32)."""
         n = len(group)
-        mesh = Mesh(np.array(group), ("g",))
+        mesh = Mesh(np.array(group), (_G_AXIS,))
         elems = max(int(message_mb * 1024 * 1024 // 4), n)
         elems = (elems // n) * n
         x = jax.device_put(
             jnp.ones((elems,), jnp.float32),
             NamedSharding(mesh, P(None)))
 
-        # the deprecated spelling of jax.shard_map (its check_rep kwarg is
-        # check_vma there); ROADMAP design debt 2 migrates every call site
-        from jax.experimental.shard_map import shard_map
+        from hetu_galvatron_tpu.ops.pallas.common import on_shards
 
         if op == "allreduce":
-            fn = shard_map(lambda v: jax.lax.psum(v, "g"), mesh=mesh,
-                           in_specs=P(None), out_specs=P(None),
-                           check_rep=False)
+            fn = on_shards(lambda v: jax.lax.psum(v, _G_AXIS), mesh,
+                           P(None), P(None))
         elif op == "allgather":
             x = jax.device_put(jnp.ones((elems,), jnp.float32),
-                               NamedSharding(mesh, P("g")))
-            fn = shard_map(lambda v: jax.lax.all_gather(v, "g", tiled=True),
-                           mesh=mesh, in_specs=P("g"), out_specs=P(None),
-                           check_rep=False)
+                               NamedSharding(mesh, P(_G_AXIS)))
+            fn = on_shards(
+                lambda v: jax.lax.all_gather(v, _G_AXIS, tiled=True),
+                mesh, P(_G_AXIS), P(None))
         elif op == "all2all":
             x = jax.device_put(jnp.ones((n, elems // n), jnp.float32),
-                               NamedSharding(mesh, P("g", None)))
-            fn = shard_map(
-                lambda v: jax.lax.all_to_all(v, "g", split_axis=1,
+                               NamedSharding(mesh, P(_G_AXIS, None)))
+            fn = on_shards(
+                lambda v: jax.lax.all_to_all(v, _G_AXIS, split_axis=1,
                                              concat_axis=0, tiled=True),
-                mesh=mesh, in_specs=P("g", None), out_specs=P(None, "g"),
-                check_rep=False)
+                mesh, P(_G_AXIS, None), P(None, _G_AXIS))
         elif op == "p2p":
             perm = [(i, (i + 1) % n) for i in range(n)]
-            fn = shard_map(lambda v: jax.lax.ppermute(v, "g", perm),
-                           mesh=mesh, in_specs=P(None), out_specs=P(None),
-                           check_rep=False)
+            fn = on_shards(lambda v: jax.lax.ppermute(v, _G_AXIS, perm),
+                           mesh, P(None), P(None))
         else:
             raise ValueError(op)
         jfn = jax.jit(fn)
@@ -345,11 +339,10 @@ class HardwareProfiler:
         elems = (elems // (2 * n)) * (2 * n)
         x = jax.device_put(jnp.ones((elems,), jnp.float32),
                            NamedSharding(mesh, P(None)))
-        from jax.experimental.shard_map import shard_map
+        from hetu_galvatron_tpu.ops.pallas.common import on_shards
 
         body = handbuilt_allreduce_body(alg, n, _G_AXIS)
-        fn = jax.jit(shard_map(body, mesh=mesh, in_specs=P(None),
-                               out_specs=P(None), check_rep=False))
+        fn = jax.jit(on_shards(body, mesh, P(None), P(None)))
         return _time_fn(fn, x, warmup=self.args.warmup_iters,
                         iters=self.args.profile_iters)
 
@@ -425,34 +418,32 @@ class HardwareProfiler:
         n = self.world
         if n < 2:
             return {"overlap_coe": 1.0}
-        mesh = Mesh(np.array(self.devices[:n]), ("g",))
+        mesh = Mesh(np.array(self.devices[:n]), (_G_AXIS,))
         k = 1024
         a = jax.device_put(jnp.ones((k, k), jnp.bfloat16),
                            NamedSharding(mesh, P(None, None)))
         elems = int(message_mb * 1024 * 1024 // 4)
         x = jax.device_put(jnp.ones((elems,), jnp.float32),
                            NamedSharding(mesh, P(None)))
-        from jax.experimental.shard_map import shard_map
+        from hetu_galvatron_tpu.ops.pallas.common import on_shards
 
         def compute_only(m):
             for _ in range(8):
                 m = jnp.tanh(m @ m)
             return m
 
-        @partial(shard_map, mesh=mesh, in_specs=(P(None, None), P(None)),
-                 out_specs=(P(None, None), P(None)), check_rep=False)
         def both(m, v):
-            v = jax.lax.psum(v, "g")
-            for _ in range(8):
-                m = jnp.tanh(m @ m)
-            return m, v
+            v = jax.lax.psum(v, _G_AXIS)
+            return compute_only(m), v
+
+        both = on_shards(both, mesh, (P(None, None), P(None)),
+                         (P(None, None), P(None)))
 
         t_comp = _time_fn(jax.jit(compute_only), a,
                           warmup=self.args.warmup_iters,
                           iters=self.args.profile_iters)
-        comm_fn = jax.jit(shard_map(lambda v: jax.lax.psum(v, "g"), mesh=mesh,
-                                    in_specs=P(None), out_specs=P(None),
-                                    check_rep=False))
+        comm_fn = jax.jit(on_shards(lambda v: jax.lax.psum(v, _G_AXIS), mesh,
+                                    P(None), P(None)))
         t_comm = _time_fn(comm_fn, x, warmup=self.args.warmup_iters,
                           iters=self.args.profile_iters)
         jboth = jax.jit(lambda m, v: both(m, v))

@@ -9,8 +9,9 @@ parameterized by :class:`ModelArgs`.
 TPU design: the "model" is data, not objects — ``init_causal_lm`` returns a
 nested params dict plus a parallel tree of logical-axis names; ``forward``
 is a pure function. Per-layer heterogeneity (different sharding, remat flag,
-attention impl per layer) enters through ``layer_overrides`` rather than
-module wrappers, so one traced program covers any searched strategy.
+operators per layer) enters through ``layer_overrides``, a
+:class:`modules.LayerOps` a layer, rather than module wrappers, so one traced
+program covers any searched strategy.
 """
 
 from __future__ import annotations
@@ -35,6 +36,16 @@ MODULE_REGISTRY: Dict[str, Tuple[Callable, Callable]] = {
 }
 
 
+def init_block(key: jax.Array, cfg: ModelArgs, kind: Tuple[str, str]
+               ) -> Tuple[Params, Params]:
+    """(params, axes) of one block of ``kind``, (mixer, feed-forward)."""
+    from hetu_galvatron_tpu.models.moe import init_moe_decoder_layer
+
+    mixer, ff = kind
+    return (init_moe_decoder_layer if ff == "experts"
+            else M.init_decoder_layer)(key, cfg, mixer)
+
+
 def build_causal_lm_arch(cfg: ModelArgs) -> List[str]:
     """Arch role list (reference build_causal_lm_arch builder.py:111-121)."""
     return ["embed"] + ["decoder"] * cfg.num_hidden_layers + ["prenorm", "head"]
@@ -46,8 +57,6 @@ def init_causal_lm(key: jax.Array, cfg: ModelArgs) -> Tuple[Params, Params]:
     Each block's mixer and feed-forward kind come from the per-layer
     description (``cfg.block_kinds()``); t5 builds the encoder-decoder pair
     (models/encdec.py)."""
-    from hetu_galvatron_tpu.models.moe import init_moe_decoder_layer
-
     if cfg.model_type == "t5":
         from hetu_galvatron_tpu.models.encdec import init_encdec
 
@@ -56,11 +65,8 @@ def init_causal_lm(key: jax.Array, cfg: ModelArgs) -> Tuple[Params, Params]:
     n = cfg.num_hidden_layers
     keys = jax.random.split(key, n + 2)
     embed_p, embed_a = M.init_embedding(keys[0], cfg)
-    layers = [
-        (init_moe_decoder_layer if ff == "experts"
-         else M.init_decoder_layer)(keys[1 + i], cfg, mixer)
-        for i, (mixer, ff) in enumerate(cfg.block_kinds())
-    ]
+    layers = [init_block(keys[1 + i], cfg, kind)
+              for i, kind in enumerate(cfg.block_kinds())]
     if cfg.post_norm:
         # post-norm families (bert) end each block already normalized; the
         # MLM head's transform LayerNorm is the final norm (HF BertLayer +
@@ -101,8 +107,6 @@ def init_mtp(key: jax.Array, cfg: ModelArgs) -> Tuple[Params, Params]:
     over the next token's embedding and the stack's output, ``eh_proj`` [2H,
     H] over the two side by side (embedding first), one more block
     (``layer``) and its ``norm``; embedding and head are the model's."""
-    from hetu_galvatron_tpu.models.moe import init_moe_decoder_layer
-
     if cfg.num_nextn_predict_layers != 1:
         raise NotImplementedError(
             f"model.num_nextn_predict_layers={cfg.num_nextn_predict_layers}:"
@@ -111,9 +115,7 @@ def init_mtp(key: jax.Array, cfg: ModelArgs) -> Tuple[Params, Params]:
         raise NotImplementedError(
             "multi-token prediction is written for pre-norm causal stacks")
     k1, k2 = jax.random.split(key)
-    mixer, ff = mtp_block_kind(cfg)
-    lp, la = (init_moe_decoder_layer if ff == "experts"
-              else M.init_decoder_layer)(k2, cfg, mixer)
+    lp, la = init_block(k2, cfg, mtp_block_kind(cfg))
     norms = [M.init_norm(cfg) for _ in range(3)]
     h = cfg.hidden_size
     return (
@@ -125,8 +127,8 @@ def init_mtp(key: jax.Array, cfg: ModelArgs) -> Tuple[Params, Params]:
     )
 
 
-def _block_fn(cfg: ModelArgs, kind: Tuple[str, str], kwargs: Dict[str, Any],
-              remat: bool):
+def make_block(cfg: ModelArgs, kind: Tuple[str, str],
+               kwargs: Dict[str, Any], remat: bool):
     """``fn(block params, x) -> (x, aux loss, router stats)`` of one block
     of ``kind`` with its keyword arguments, rematerialized where asked."""
     from hetu_galvatron_tpu.models.moe import apply_moe_decoder_layer
@@ -146,7 +148,7 @@ def forward_causal_lm(
     *,
     compute_dtype=jnp.bfloat16,
     remat_flags: Optional[Sequence[bool]] = None,
-    layer_overrides: Optional[Dict[int, Dict[str, Any]]] = None,
+    layer_overrides: Optional[Dict[int, M.LayerOps]] = None,
     boundary_fn: Optional[Callable[[int, jax.Array], jax.Array]] = None,
     logits_fp32: bool = True,
     with_aux: bool = False,
@@ -173,10 +175,11 @@ def forward_causal_lm(
 
     ``remat_flags[i]`` turns on `jax.checkpoint` for layer i (the reference's
     per-layer checkpoint_flags_enc, parallel.py:213-243). ``layer_overrides``
-    maps layer index -> kwargs for :func:`modules.apply_decoder_layer`
-    (e.g. a different ``sdpa_fn`` for Ulysses/ring layers). ``boundary_fn(i,
-    x)`` is applied to the hidden state before layer i and once after the last
-    layer (i == num layers) — the SPMD layer uses it to place
+    maps layer index -> what the layer's plan swaps in the block
+    (:class:`modules.LayerOps`, e.g. the attention core of a Ulysses or ring
+    layer; a layer without an entry runs the ``jax.numpy`` / XLA forms).
+    ``boundary_fn(i, x)`` is applied to the hidden state before layer i and
+    once after the last layer (i == num layers) — the SPMD layer uses it to place
     `with_sharding_constraint` resharding at layer boundaries, replacing the
     reference's relocation wrappers (runtime/parallel.py:272-304).
     """
@@ -219,16 +222,14 @@ def forward_causal_lm(
     for i, lp in enumerate(params["layers"]):
         if boundary_fn is not None:
             x = boundary_fn(i, x)
-        mixer, ff = kinds[i]
-        kwargs: Dict[str, Any] = dict(rope=rope, compute_dtype=compute_dtype,
-                                      mixer=mixer)
+        kwargs: Dict[str, Any] = dict(
+            rope=rope, compute_dtype=compute_dtype, mixer=kinds[i][0],
+            ops=(layer_overrides or {}).get(i, M.LayerOps()))
         if segment_ids is not None:
             kwargs["segment_ids"] = segment_ids
         if dropout_rng is not None:
             kwargs["dropout_rng"] = M.fold_dropout_rng(dropout_rng, cfg, i)
-        if layer_overrides and i in layer_overrides:
-            kwargs.update(layer_overrides[i])
-        x, aux, stats = _block_fn(
+        x, aux, stats = make_block(
             cfg, kinds[i], kwargs,
             remat_flags is not None and bool(remat_flags[i]))(lp, x)
         aux_total = aux_total + aux
@@ -246,7 +247,7 @@ def forward_causal_lm(
             kwargs["dropout_rng"] = M.fold_dropout_rng(
                 dropout_rng, cfg, len(params["layers"]))
         mtp_logits, aux, stats = forward_mtp(
-            params, x, mtp_labels, cfg, block_fn=_block_fn(
+            params, x, mtp_labels, cfg, block_fn=make_block(
                 cfg, mtp_block_kind(cfg), kwargs,
                 remat_flags is not None and bool(remat_flags[-1])),
             compute_dtype=compute_dtype)
@@ -304,10 +305,10 @@ def causal_lm_loss(
     *,
     compute_dtype=jnp.bfloat16,
     remat_flags: Optional[Sequence[bool]] = None,
-    layer_overrides: Optional[Dict[int, Dict[str, Any]]] = None,
+    layer_overrides: Optional[Dict[int, M.LayerOps]] = None,
     boundary_fn: Optional[Callable[[int, jax.Array], jax.Array]] = None,
     enc_remat_flags: Optional[Sequence[bool]] = None,
-    enc_layer_overrides: Optional[Dict[int, Dict[str, Any]]] = None,
+    enc_layer_overrides: Optional[Dict[int, M.LayerOps]] = None,
     enc_boundary_fn: Optional[Callable[[int, jax.Array], jax.Array]] = None,
     fused_ce: Union[None, bool, Callable] = None,
     with_moe_stats: bool = False,

@@ -139,19 +139,19 @@ def apply_cross_decoder_layer(
     memory: jax.Array,
     cfg: ModelArgs,
     rope=None,
-    sdpa_fn: Callable[..., jax.Array] = M.xla_sdpa,
-    cross_sdpa_fn: Optional[Callable[..., jax.Array]] = None,
+    ops: M.LayerOps = M.LayerOps(),
     compute_dtype=jnp.bfloat16,
     dropout_rng=None,
     cached_cross_kv: Optional[Tuple[jax.Array, jax.Array]] = None,
 ) -> jax.Array:
     """Pre-norm: causal self-attention -> cross-attention -> MLP.
 
-    ``sdpa_fn`` drives the (causal) self-attention; cross-attention uses
-    ``cross_sdpa_fn`` when given, else ``sdpa_fn`` — the dispatch layer
+    ``ops.sdpa`` drives the (causal) self-attention; cross-attention uses
+    ``ops.cross_sdpa`` when set, else ``ops.sdpa`` — the dispatch layer
     (parallel/spmd.py attention_overrides) passes a non-causal-capable kernel
     here (flash handles causal=False; ring layers fall back to the XLA core
-    because the decoder/encoder sequence lengths differ)."""
+    because the decoder/encoder sequence lengths differ). A t5 layer's plan
+    swaps nothing else."""
     r_attn = r_xattn = r1 = r2 = r3 = None
     if dropout_rng is not None:
         r_attn, r_xattn, r1, r2, r3 = jax.random.split(dropout_rng, 5)
@@ -160,16 +160,15 @@ def apply_cross_decoder_layer(
         return M.dropout(y, cfg.hidden_dropout, rng)
 
     h = M.apply_norm(p["ln1"], x, cfg)
-    x = x + drop_h(M.apply_attention(p["attn"], h, cfg, rope=rope,
-                                     sdpa_fn=sdpa_fn,
-                                     compute_dtype=compute_dtype, causal=True,
-                                     dropout_rng=r_attn), r1)
+    x = x + drop_h(M.apply_mixer(p, h, cfg, ops=ops, rope=rope,
+                                 compute_dtype=compute_dtype, causal=True,
+                                 dropout_rng=r_attn), r1)
     h = M.apply_norm(p["lnx"], x, cfg)
-    x = x + drop_h(apply_cross_attention(p["cross"], h, memory, cfg,
-                                         sdpa_fn=cross_sdpa_fn or sdpa_fn,
-                                         compute_dtype=compute_dtype,
-                                         dropout_rng=r_xattn,
-                                         cached_kv=cached_cross_kv), r2)
+    x = x + drop_h(apply_cross_attention(
+        p["cross"], h, memory, cfg,
+        sdpa_fn=ops.cross_sdpa or ops.sdpa or M.xla_sdpa,
+        compute_dtype=compute_dtype, dropout_rng=r_xattn,
+        cached_kv=cached_cross_kv), r2)
     h = M.apply_norm(p["ln2"], x, cfg)
     x = x + drop_h(M.apply_mlp(p["mlp"], h, cfg,
                                compute_dtype=compute_dtype), r3)
@@ -268,15 +267,12 @@ def forward_encdec(
     for i, lp in enumerate(params["enc_layers"]):
         if enc_boundary_fn is not None:
             mem = enc_boundary_fn(i, mem)
-        kwargs: Dict[str, Any] = dict(rope=rope_enc,
-                                      compute_dtype=compute_dtype,
-                                      causal=False)
+        kwargs: Dict[str, Any] = dict(
+            rope=rope_enc, compute_dtype=compute_dtype, causal=False,
+            ops=(enc_layer_overrides or {}).get(i, M.LayerOps()))
         if dropout_rng is not None:
             kwargs["dropout_rng"] = M.fold_dropout_rng(
                 dropout_rng, cfg, M.DROPOUT_STREAM_ENC + i)
-        if enc_layer_overrides and i in enc_layer_overrides:
-            kwargs.update(enc_layer_overrides[i])
-        kwargs.pop("cross_sdpa_fn", None)  # encoder blocks have no cross-attn
         fn = lambda p, h, kw=kwargs: M.apply_decoder_layer(p, h, cfg, **kw)
         if enc_remat_flags is not None and enc_remat_flags[i]:
             fn = M.remat(fn, cfg)
@@ -291,11 +287,10 @@ def forward_encdec(
     for i, lp in enumerate(params["layers"]):
         if boundary_fn is not None:
             x = boundary_fn(i, x)
-        kwargs = dict(rope=rope_dec, compute_dtype=compute_dtype)
+        kwargs = dict(rope=rope_dec, compute_dtype=compute_dtype,
+                      ops=(layer_overrides or {}).get(i, M.LayerOps()))
         if dropout_rng is not None:
             kwargs["dropout_rng"] = M.fold_dropout_rng(dropout_rng, cfg, i)
-        if layer_overrides and i in layer_overrides:
-            kwargs.update(layer_overrides[i])
         fn = lambda p, h, m, kw=kwargs: apply_cross_decoder_layer(
             p, h, m, cfg, **kw)
         if remat_flags is not None and remat_flags[i]:

@@ -8,7 +8,7 @@ temperature/top-k sampling, and EOS masking — no data-dependent Python
 control flow, so the whole generate() jits.
 
 The transformer math is NOT re-implemented here: both prefill and the
-decode step run `modules.apply_decoder_layer` with an `sdpa_fn` closure
+decode step run `modules.apply_decoder_layer` with an attention-core closure
 that captures (and, when decoding, updates) the rope-applied k/v — the
 same hook the distributed layer uses for flash/ring/Ulysses attention, so
 any change to the block stays in one place.
@@ -152,7 +152,8 @@ def prefill(params: Params, tokens: jax.Array, cfg: ModelArgs, max_len: int,
                               segment_ids=segment_ids)
 
         sdpa.supports_segments = True
-        x = M.apply_decoder_layer(lp, x, cfg, rope=rope, sdpa_fn=sdpa,
+        x = M.apply_decoder_layer(lp, x, cfg, rope=rope,
+                                  ops=M.LayerOps(sdpa=sdpa),
                                   compute_dtype=compute_dtype,
                                   segment_ids=segment_ids)
         cache[i] = {
@@ -197,7 +198,8 @@ def decode_step(params: Params, cache, tokens: jax.Array, pos, cfg: ModelArgs,
             cell["k"], cell["v"] = ck, cv
             return _cached_sdpa(q, ck, cv, pos, shift=shift)
 
-        x = M.apply_decoder_layer(lp, x, cfg, rope=step_rope, sdpa_fn=sdpa,
+        x = M.apply_decoder_layer(lp, x, cfg, rope=step_rope,
+                                  ops=M.LayerOps(sdpa=sdpa),
                                   compute_dtype=compute_dtype)
         cache[i] = {"k": cell["k"], "v": cell["v"]}
     x = M.apply_norm(params["prenorm"], x, cfg)
@@ -342,8 +344,8 @@ def prefill_encdec(params: Params, mem: jax.Array, dec_tokens: jax.Array,
             return M.xla_sdpa(q, k, v, causal=causal)
 
         x = apply_cross_decoder_layer(lp, x, mem, cfg, rope=rope,
-                                      sdpa_fn=sdpa,
-                                      cross_sdpa_fn=M.xla_sdpa,
+                                      ops=M.LayerOps(
+                                          sdpa=sdpa, cross_sdpa=M.xla_sdpa),
                                       compute_dtype=compute_dtype,
                                       cached_cross_kv=cross[i])
         cache[i] = {
@@ -386,8 +388,8 @@ def decode_step_encdec(params: Params, cache, cross, mem, tokens: jax.Array,
             return _cached_sdpa(q, ck, cv, pos)
 
         x = apply_cross_decoder_layer(lp, x, mem, cfg, rope=step_rope,
-                                      sdpa_fn=sdpa,
-                                      cross_sdpa_fn=M.xla_sdpa,
+                                      ops=M.LayerOps(
+                                          sdpa=sdpa, cross_sdpa=M.xla_sdpa),
                                       compute_dtype=compute_dtype,
                                       cached_cross_kv=cross[i])
         cache[i] = {"k": cell["k"], "v": cell["v"]}

@@ -20,23 +20,27 @@ TPU-first design, deliberately unlike the torch reference:
 * **MXU-friendly shapes.** QKV is one fused matmul ((nq+2*nkv)*head_dim wide),
   SwiGLU gate+up is one fused matmul; weights live in fp32, compute runs in
   bf16 with fp32 accumulation (``preferred_element_type``).
-* **Swappable attention core.** ``apply_attention`` takes an ``sdpa_fn`` so the
-  same layer runs XLA attention, a Pallas flash kernel, Ulysses all-to-all, or
-  ring attention depending on the layer's strategy (reference dispatch:
-  attention.py:664-720).
-* **Swappable projection matmuls.** ``apply_attention`` / ``apply_mlp`` take a
-  ``matmul_fns`` dict ({"qkv", "out"} / {"fc1", "fc2"}) so tensor-parallel
-  layers can run the decomposed ring all-gather/reduce-scatter matmuls
-  (ops/overlap.py) instead of leaving the collectives to GSPMD — same
-  per-layer dispatch idiom as ``sdpa_fn``. Each fn maps (x, w) to the fp32
-  product the default einsum would produce.
+* **One record of what a plan swaps in a block** (:class:`LayerOps`: the
+  attention core, which by the layer's strategy is XLA attention, a Pallas
+  flash kernel, Ulysses all-to-all or ring attention, reference dispatch
+  attention.py:664-720; the projection matmuls; the kernels of a scan or a
+  convolution). ``parallel/spmd.py`` fills one a layer from the plan; a
+  block body (:func:`apply_decoder_layer`, models/encdec.py) takes it as
+  ``ops`` and hands it on, and only :func:`apply_mixer` and the block's
+  feed-forward branch take it apart, into the keywords of the functions
+  that call an operator (``apply_attention(sdpa_fn=, matmul_fns=,
+  shard_fn=)``, ``apply_mamba2(ssd_fn=, conv_fn=)``, ...).
+* **One table of mixer kinds.** :data:`MIXERS` has a row a kind of block
+  operator: its parameter key, ``init``, ``apply``, whether it attends and
+  which fields of the record it reads.
 """
 
 from __future__ import annotations
 
 import math
+from dataclasses import dataclass
 from functools import partial
-from typing import Any, Callable, Dict, Optional, Tuple
+from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -46,16 +50,37 @@ from hetu_galvatron_tpu.core.args_schema import ModelArgs
 Params = Dict[str, Any]
 Axes = Dict[str, Any]
 
+
+@dataclass(frozen=True)
+class LayerOps:
+    """What a plan can swap in one block; an unset field is the
+    ``jax.numpy`` / XLA form. ``sdpa(q, k, v, causal=...)`` is the attention
+    core and ``cross_sdpa`` a t5 decoder block's cross-attention core (unset
+    = ``sdpa``); ``matmuls`` ({"qkv", "out", "fc1", "fc2"}) the projection
+    matmuls (ops/overlap.py's ring all-gather / reduce-scatter ones, each
+    mapping (x, w) to the fp32 product the default einsum would produce);
+    ``shard(a, axis)`` pins an interior activation
+    of a tp > 1 layer (parallel/spmd.py::interior_sharding); ``ssd``,
+    ``kda`` and ``conv`` are the kernels of a mamba block's chunked scan, a
+    kda block's chunked delta rule and the causal depthwise convolution
+    (ops/pallas/). Which kinds of block read which field: :data:`MIXERS`."""
+
+    sdpa: Optional[Callable[..., jax.Array]] = None
+    cross_sdpa: Optional[Callable[..., jax.Array]] = None
+    matmuls: Optional[Dict[str, Callable]] = None
+    shard: Optional[Callable[[jax.Array, int], jax.Array]] = None
+    ssd: Optional[Callable[..., jax.Array]] = None
+    kda: Optional[Callable[..., jax.Array]] = None
+    conv: Optional[Callable[..., Optional[jax.Array]]] = None
+
+    def given(self) -> Dict[str, Any]:
+        """The fields that are set, by name."""
+        return {k: v for k, v in vars(self).items() if v is not None}
+
+
 # ---------------------------------------------------------------------------
 # initializers
 # ---------------------------------------------------------------------------
-
-
-# a block's key for its mixer's parameters, by mixer kind
-MIXER_KEYS = {"full_attention": "attn", "conv": "conv", "mamba": "mamba",
-              "latent_attention": "attn", "kda": "kda"}
-# the mixer kinds that attend (through an attention core, ``sdpa_fn``)
-ATTENDING_MIXERS = ("full_attention", "latent_attention")
 
 
 def _normal(key, shape, std, dtype=jnp.float32):
@@ -1647,47 +1672,78 @@ def apply_kda(
     return out.astype(compute_dtype)
 
 
+class Mixer(NamedTuple):
+    """One kind of block operator: the block's key for its parameters,
+    ``init(key, cfg) -> (params, axes)``, ``apply(params, h, cfg, ...)``,
+    whether it attends (takes positions, a causal flag, packed documents'
+    ``segment_ids`` and a dropout of probabilities), the fields of
+    :class:`LayerOps` it reads by the keyword ``apply`` takes each as, what
+    the launcher logs for a block of it (None: its attention core) and, for
+    a kind whose projections no plan may cut over tp, the name of the reason
+    in analysis/eligibility.py."""
+
+    key: str
+    init: Callable
+    apply: Callable
+    attends: bool
+    ops: Dict[str, str]
+    logged: Optional[str] = None
+    uncut_reason: Optional[str] = None
+
+    def reads(self, field: str) -> bool:
+        return field in self.ops.values()
+
+
+# a row a mixer kind (``ModelArgs.layer_types``); a new kind is its ``init``
+# and ``apply`` and a row here
+MIXERS: Dict[str, Mixer] = {
+    "full_attention": Mixer(
+        "attn", init_attention, apply_attention, True,
+        {"sdpa_fn": "sdpa", "matmul_fns": "matmuls", "shard_fn": "shard"}),
+    "conv": Mixer(
+        "conv", init_short_conv, apply_short_conv, False,
+        {"shard_fn": "shard", "conv_fn": "conv"}, "short_conv"),
+    "mamba": Mixer(
+        "mamba", init_mamba2, apply_mamba2, False,
+        {"ssd_fn": "ssd", "conv_fn": "conv"}, "mamba2"),
+    "latent_attention": Mixer(
+        "attn", init_latent_attention, apply_latent_attention, True,
+        {"sdpa_fn": "sdpa"}, uncut_reason="latent_plan_reason"),
+    "kda": Mixer(
+        "kda", init_kda, apply_kda, False,
+        {"kda_fn": "kda", "conv_fn": "conv"}, "kda",
+        uncut_reason="kda_plan_reason"),
+}
+
+
+def mixer_of(mixer: str) -> Mixer:
+    if mixer not in MIXERS:
+        raise ValueError(f"unknown mixer kind {mixer!r} "
+                         f"({' | '.join(MIXERS)})")
+    return MIXERS[mixer]
+
+
 def apply_mixer(
     p: Params,
     h: jax.Array,
     cfg: ModelArgs,
     mixer: str = "full_attention",
     *,
+    ops: LayerOps = LayerOps(),
     compute_dtype=jnp.bfloat16,
-    shard_fn: Optional[Callable[[jax.Array, int], jax.Array]] = None,
+    rope: Optional[Tuple[jax.Array, jax.Array]] = None,
+    causal: bool = True,
+    dropout_rng: Optional[jax.Array] = None,
     segment_ids: Optional[jax.Array] = None,
-    ssd_fn: Optional[Callable[..., jax.Array]] = None,
-    kda_fn: Optional[Callable[..., jax.Array]] = None,
-    conv_fn: Optional[Callable[..., Optional[jax.Array]]] = None,
-    **attn_kwargs: Any,
 ) -> jax.Array:
     """A block's operator on its normed input, by the block's mixer kind
-    (``ModelArgs.block_kinds``): attention from ``p["attn"]``, the gated
-    short convolution from ``p["conv"]``, the Mamba-2 state-space block
-    from ``p["mamba"]`` or Kimi Delta Attention from ``p["kda"]``; the last
-    three take no rope, no attention core and no dropout of probabilities;
-    the mamba block alone takes ``ssd_fn`` (:func:`apply_mamba2`) and the
-    kda block alone ``kda_fn`` (:func:`apply_kda`); all three take
-    ``conv_fn``, their convolution's kernels
-    (:func:`causal_depthwise_conv`).
-    ``latent_attention`` is attention through low-rank projections, also
-    from ``p["attn"]`` (:func:`apply_latent_attention`)."""
-    if mixer == "full_attention":
-        return apply_attention(p["attn"], h, cfg, compute_dtype=compute_dtype,
-                               shard_fn=shard_fn, segment_ids=segment_ids,
-                               **attn_kwargs)
-    if mixer == "latent_attention":
-        if shard_fn is not None or attn_kwargs.pop("matmul_fns", None):
-            raise NotImplementedError(
-                "a latent_attention block's projections are not cut over "
-                "the tp axis (eligibility.latent_plan_reason)")
-        return apply_latent_attention(
-            p["attn"], h, cfg, compute_dtype=compute_dtype,
-            segment_ids=segment_ids, **attn_kwargs)
-    if mixer not in MIXER_KEYS:
-        raise ValueError(f"unknown mixer kind {mixer!r} "
-                         f"({' | '.join(MIXER_KEYS)})")
-    if segment_ids is not None:
+    (``ModelArgs.block_kinds``, a row of :data:`MIXERS`), from the block's
+    parameters under the row's key. Here ``ops`` is taken apart: each kind
+    is handed the fields its row names, as the keywords its ``apply``
+    takes, and no other. A kind that does not attend takes no rope, no
+    attention core and no dropout of probabilities."""
+    row = mixer_of(mixer)
+    if not row.attends and segment_ids is not None:
         raise NotImplementedError(
             f"packed documents (segment_ids) through a {mixer} block: "
             + ("the convolution's two tokens of history"
@@ -1695,18 +1751,18 @@ def apply_mixer(
                "the convolution's history and the carried state")
             + " would cross document boundaries; set "
             "data.reset_attention_mask=false")
-    if mixer == "mamba":
-        return apply_mamba2(p["mamba"], h, cfg, compute_dtype=compute_dtype,
-                            ssd_fn=ssd_fn, conv_fn=conv_fn)
-    if mixer == "kda":
-        if shard_fn is not None or attn_kwargs.get("matmul_fns"):
-            raise NotImplementedError(
-                "a kda block's projections are not cut over the tp axis "
-                "(eligibility.kda_plan_reason)")
-        return apply_kda(p["kda"], h, cfg, compute_dtype=compute_dtype,
-                         kda_fn=kda_fn, conv_fn=conv_fn)
-    return apply_short_conv(p["conv"], h, cfg, compute_dtype=compute_dtype,
-                            shard_fn=shard_fn, conv_fn=conv_fn)
+    if row.uncut_reason and (ops.shard is not None or ops.matmuls):
+        raise NotImplementedError(
+            f"a {mixer} block's projections are not cut over the tp axis "
+            f"(eligibility.{row.uncut_reason})")
+    given = ops.given()
+    kwargs = {arg: given[field] for arg, field in row.ops.items()
+              if field in given}
+    if row.attends:
+        kwargs.update(rope=rope, causal=causal, dropout_rng=dropout_rng,
+                      segment_ids=segment_ids)
+    return row.apply(p[row.key], h, cfg, compute_dtype=compute_dtype,
+                     **kwargs)
 
 
 # ---------------------------------------------------------------------------
@@ -1823,30 +1879,23 @@ def apply_mlp(p: Params, x: jax.Array, cfg: ModelArgs,
 # ---------------------------------------------------------------------------
 
 
-
-
-def init_mixer(key: jax.Array, cfg: ModelArgs,
-               mixer: str = "full_attention") -> Tuple[str, Params, Axes]:
-    """(the block's key for it, params, axes) of one mixer kind."""
-    init = {"full_attention": init_attention, "conv": init_short_conv,
-            "mamba": init_mamba2, "latent_attention": init_latent_attention,
-            "kda": init_kda}
-    if mixer not in init:
-        raise ValueError(f"unknown mixer kind {mixer!r} ({' | '.join(init)})")
-    return (MIXER_KEYS[mixer],) + init[mixer](key, cfg)
-
-
 def init_decoder_layer(key: jax.Array, cfg: ModelArgs,
-                       mixer: str = "full_attention") -> Tuple[Params, Axes]:
+                       mixer: str = "full_attention",
+                       ff: Tuple[str, Callable] = ("mlp", init_mlp)
+                       ) -> Tuple[Params, Axes]:
+    """A block of one mixer kind and one feed-forward: ``ff`` is (the
+    block's key for it, ``init(key, cfg)``), a dense MLP unless the caller
+    says otherwise (models/moe.py::init_moe_decoder_layer)."""
     k1, k2 = jax.random.split(key)
-    name, mix_p, mix_a = init_mixer(k1, cfg, mixer)
-    mlp_p, mlp_a = init_mlp(k2, cfg)
+    row = mixer_of(mixer)
+    mix_p, mix_a = row.init(k1, cfg)
+    ff_p, ff_a = ff[1](k2, cfg)
     ln1_p, ln1_a = init_norm(cfg)
     ln2_p, ln2_a = init_norm(cfg)
     hc_p, hc_a = init_block_maps(key, cfg)
     return (
-        {"ln1": ln1_p, name: mix_p, "ln2": ln2_p, "mlp": mlp_p, **hc_p},
-        {"ln1": ln1_a, name: mix_a, "ln2": ln2_a, "mlp": mlp_a, **hc_a},
+        {"ln1": ln1_p, row.key: mix_p, "ln2": ln2_p, ff[0]: ff_p, **hc_p},
+        {"ln1": ln1_a, row.key: mix_a, "ln2": ln2_a, ff[0]: ff_a, **hc_a},
     )
 
 
@@ -1864,29 +1913,24 @@ def apply_decoder_layer(
     x: jax.Array,
     cfg: ModelArgs,
     rope: Optional[Tuple[jax.Array, jax.Array]] = None,
-    sdpa_fn: Callable[..., jax.Array] = xla_sdpa,
+    ops: LayerOps = LayerOps(),
     compute_dtype=jnp.bfloat16,
     causal: Optional[bool] = None,
     dropout_rng: Optional[jax.Array] = None,
     segment_ids: Optional[jax.Array] = None,
-    matmul_fns: Optional[Dict[str, Callable]] = None,
-    shard_fn: Optional[Callable[[jax.Array, int], jax.Array]] = None,
     mixer: str = "full_attention",
-    ssd_fn: Optional[Callable[..., jax.Array]] = None,
-    kda_fn: Optional[Callable[..., jax.Array]] = None,
-    conv_fn: Optional[Callable[..., Optional[jax.Array]]] = None,
+    feed_forward: Optional[Callable[[jax.Array], jax.Array]] = None,
 ) -> jax.Array:
     """Pre-norm residual block (reference GalvatronDecoderLayer,
     modules.py:233). Encoder families (bert, t5 encoder stack) run the same
     block with bidirectional attention; ``causal=None`` derives from the
     model family. ``dropout_rng`` enables attention/hidden dropout
     (HF semantics: sublayer output dropped before the residual add).
-    ``matmul_fns`` ({"qkv", "out", "fc1", "fc2"}) swaps the projection
-    matmuls for overlapped tensor-parallel impls (ops/overlap.py);
-    ``shard_fn`` keeps a tp > 1 layer's interior on its own shards
-    (:func:`apply_attention`). ``mixer`` is the block's operator kind
-    and ``ssd_fn`` / ``kda_fn`` / ``conv_fn`` a mamba / kda / convolving
-    block's kernels (:func:`apply_mixer`; pre-norm blocks only). A model of several
+    ``ops`` is what the layer's plan swaps in (:class:`LayerOps`): handed on
+    to :func:`apply_mixer`, the block's operator of kind ``mixer``, and its
+    ``matmuls`` / ``shard`` to the MLP. ``feed_forward(h)`` is the second
+    branch on its normed input where the block's is not the dense MLP of
+    ``p["mlp"]`` (models/moe.py::apply_moe_decoder_layer). A model of several
     residual streams (``cfg.hc_mult``) hands ``x`` [B, S, n, H] and the
     block's maps ``hc1`` / ``hc2`` (:func:`residual`)."""
     if causal is None:
@@ -1898,6 +1942,18 @@ def apply_decoder_layer(
     def drop_h(y, rng):
         return dropout(y, cfg.hidden_dropout, rng)
 
+    def mixed(h):
+        return drop_h(apply_mixer(p, h, cfg, mixer, ops=ops, rope=rope,
+                                  compute_dtype=compute_dtype, causal=causal,
+                                  dropout_rng=r_attn,
+                                  segment_ids=segment_ids), r_res1)
+
+    def fed(h):
+        return drop_h(
+            feed_forward(h) if feed_forward is not None else apply_mlp(
+                p["mlp"], h, cfg, compute_dtype=compute_dtype,
+                matmul_fns=ops.matmuls, shard_fn=ops.shard), r_res2)
+
     if cfg.post_norm:
         if mixer != "full_attention":
             raise NotImplementedError(
@@ -1905,44 +1961,17 @@ def apply_decoder_layer(
                 "families (bert) attend in every block")
         # HF BertLayer: residual-then-norm (attention.output.LayerNorm,
         # output.LayerNorm)
-        x = block_norm(
-            p["ln1"],
-            x + drop_h(apply_attention(p["attn"], x, cfg, rope=rope,
-                                       sdpa_fn=sdpa_fn,
-                                       compute_dtype=compute_dtype,
-                                       causal=causal, dropout_rng=r_attn,
-                                       segment_ids=segment_ids,
-                                       matmul_fns=matmul_fns,
-                                       shard_fn=shard_fn),
-                       r_res1),
-            cfg)
-        return block_norm(
-            p["ln2"],
-            x + drop_h(apply_mlp(p["mlp"], x, cfg,
-                                 compute_dtype=compute_dtype,
-                                 matmul_fns=matmul_fns,
-                                 shard_fn=shard_fn), r_res2),
-            cfg)
-    def mixer_branch(a):
-        h = block_norm(p["ln1"], a, cfg)
-        return residual_branch(
-            drop_h(apply_mixer(p, h, cfg, mixer, rope=rope, sdpa_fn=sdpa_fn,
-                               compute_dtype=compute_dtype, causal=causal,
-                               dropout_rng=r_attn, segment_ids=segment_ids,
-                               matmul_fns=matmul_fns, shard_fn=shard_fn,
-                               ssd_fn=ssd_fn, kda_fn=kda_fn,
-                               conv_fn=conv_fn),
-                   r_res1), cfg)
+        x = block_norm(p["ln1"], x + mixed(x), cfg)
+        return block_norm(p["ln2"], x + fed(x), cfg)
 
-    def mlp_branch(a):
-        h = block_norm(p["ln2"], a, cfg)
-        return residual_branch(
-            drop_h(apply_mlp(p["mlp"], h, cfg, compute_dtype=compute_dtype,
-                             matmul_fns=matmul_fns, shard_fn=shard_fn),
-                   r_res2), cfg)
+    def mixer_branch(a):
+        return residual_branch(mixed(block_norm(p["ln1"], a, cfg)), cfg)
+
+    def ff_branch(a):
+        return residual_branch(fed(block_norm(p["ln2"], a, cfg)), cfg)
 
     x = residual(p.get("hc1"), x, cfg, mixer_branch, compute_dtype)
-    return residual(p.get("hc2"), x, cfg, mlp_branch, compute_dtype)
+    return residual(p.get("hc2"), x, cfg, ff_branch, compute_dtype)
 
 
 # ---------------------------------------------------------------------------

@@ -544,59 +544,30 @@ def apply_moe_mlp(
 def init_moe_decoder_layer(key: jax.Array, cfg: ModelArgs,
                            mixer: str = "full_attention"
                            ) -> Tuple[Params, Params]:
-    k1, k2 = jax.random.split(key)
-    name, mix_p, mix_a = M.init_mixer(k1, cfg, mixer)
-    moe_p, moe_a = init_moe_mlp(k2, cfg)
-    ln1_p, ln1_a = M.init_norm(cfg)
-    ln2_p, ln2_a = M.init_norm(cfg)
-    hc_p, hc_a = M.init_block_maps(key, cfg)
-    return (
-        {"ln1": ln1_p, name: mix_p, "ln2": ln2_p, "moe": moe_p, **hc_p},
-        {"ln1": ln1_a, name: mix_a, "ln2": ln2_a, "moe": moe_a, **hc_a},
-    )
+    """modules.init_decoder_layer with the expert layer, under ``"moe"``,
+    for the dense MLP."""
+    return M.init_decoder_layer(key, cfg, mixer, ff=("moe", init_moe_mlp))
 
 
 def apply_moe_decoder_layer(
     p: Params,
     x: jax.Array,
     cfg: ModelArgs,
-    rope=None,
-    sdpa_fn=M.xla_sdpa,
+    *,
     compute_dtype=jnp.bfloat16,
-    dropout_rng=None,
-    segment_ids=None,
-    mixer: str = "full_attention",
-    ssd_fn=None,
-    kda_fn=None,
-    conv_fn=None,
+    **block: Any,
 ) -> Tuple[jax.Array, jax.Array, Dict[str, jax.Array]]:
-    """Pre-norm block with an MoE FFN; returns (x, aux_loss, router
-    stats) — stats feed the per-layer balance tracker (reference
-    moe_utils.py:547-644). ``mixer``: the block's operator kind, and
-    ``ssd_fn`` / ``kda_fn`` / ``conv_fn`` a mamba / kda / convolving
-    block's kernels (modules.apply_mixer). Several
-    residual streams: as modules.apply_decoder_layer."""
-    r_attn = r_res1 = r_res2 = None
-    if dropout_rng is not None:
-        r_attn, r_res1, r_res2 = jax.random.split(dropout_rng, 3)
+    """modules.apply_decoder_layer (``block``: its keywords) with the expert
+    layer of ``p["moe"]`` as the feed-forward branch; returns (x, aux_loss,
+    router stats) — stats feed the per-layer balance tracker (reference
+    moe_utils.py:547-644)."""
     routed: Dict[str, Any] = {}
 
-    def mixer_branch(a):
-        h = M.block_norm(p["ln1"], a, cfg)
-        return M.residual_branch(M.dropout(
-            M.apply_mixer(p, h, cfg, mixer, rope=rope, sdpa_fn=sdpa_fn,
-                          compute_dtype=compute_dtype, dropout_rng=r_attn,
-                          segment_ids=segment_ids, ssd_fn=ssd_fn,
-                          kda_fn=kda_fn, conv_fn=conv_fn),
-            cfg.hidden_dropout, r_res1), cfg)
-
-    def experts_branch(a):
-        h = M.block_norm(p["ln2"], a, cfg)
+    def experts(h):
         y, routed["aux"], routed["stats"] = apply_moe_mlp(
             p["moe"], h, cfg, compute_dtype=compute_dtype)
-        return M.residual_branch(M.dropout(y, cfg.hidden_dropout, r_res2),
-                                 cfg)
+        return y
 
-    x = M.residual(p.get("hc1"), x, cfg, mixer_branch, compute_dtype)
-    x = M.residual(p.get("hc2"), x, cfg, experts_branch, compute_dtype)
+    x = M.apply_decoder_layer(p, x, cfg, compute_dtype=compute_dtype,
+                              feed_forward=experts, **block)
     return x, routed["aux"], routed["stats"]

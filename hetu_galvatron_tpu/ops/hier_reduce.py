@@ -82,9 +82,9 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from hetu_galvatron_tpu.ops.pallas.common import on_shards
 from hetu_galvatron_tpu.runtime.mesh import (
     HIER_HOST_AXIS,
     HIER_SLICE_AXIS,
@@ -307,9 +307,8 @@ class HierDpReducer:
         self._out_specs = tuple(leaves)
         self._leaf_specs = leaves
         self._lane_dim = tuple(self.dp_axes)
-        self._fn = shard_map(self._body, self.hmesh,
-                             in_specs=self._in_specs,
-                             out_specs=self._out_specs, check_rep=False)
+        self._fn = on_shards(self._body, self.hmesh, self._in_specs,
+                             self._out_specs)
 
     # -- lane helpers -------------------------------------------------------
 

@@ -38,10 +38,9 @@ from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
-
+from hetu_galvatron_tpu.ops.pallas.common import on_shards
 from hetu_galvatron_tpu.runtime.mesh import axes_size as _axis_prod
 
 # HLO-metadata marker for the ring ppermutes (jax.named_scope): trace
@@ -215,9 +214,8 @@ def make_ag_matmul(mesh: Mesh, dp_axes: Tuple[str, ...],
     x_spec = _with_stage(P(dp_axes or None, axes, None), stage_axis)
     w_spec = _with_stage(P(None, axes), stage_axis)
     y_spec = _with_stage(P(dp_axes or None, None, axes), stage_axis)
-    return shard_map(_staged(local, stage_axis is not None), mesh,
-                     in_specs=(x_spec, w_spec),
-                     out_specs=y_spec, check_rep=False)
+    return on_shards(_staged(local, stage_axis is not None), mesh,
+                     (x_spec, w_spec), y_spec)
 
 
 def make_ag_matmul_pair(mesh: Mesh, dp_axes: Tuple[str, ...],
@@ -308,9 +306,8 @@ def make_ag_matmul_pair(mesh: Mesh, dp_axes: Tuple[str, ...],
     x_spec = _with_stage(P(dp_axes or None, axes, None), stage_axis)
     w_spec = _with_stage(P(None, axes), stage_axis)
     y_spec = _with_stage(P(dp_axes or None, None, axes), stage_axis)
-    return shard_map(_staged(local, stage_axis is not None), mesh,
-                     in_specs=(x_spec, w_spec, w_spec),
-                     out_specs=(y_spec, y_spec), check_rep=False)
+    return on_shards(_staged(local, stage_axis is not None), mesh,
+                     (x_spec, w_spec, w_spec), (y_spec, y_spec))
 
 
 def make_matmul_rs(mesh: Mesh, dp_axes: Tuple[str, ...],
@@ -345,9 +342,8 @@ def make_matmul_rs(mesh: Mesh, dp_axes: Tuple[str, ...],
     h_spec = _with_stage(P(dp_axes or None, None, axes), stage_axis)
     w_spec = _with_stage(P(axes, None), stage_axis)
     y_spec = _with_stage(P(dp_axes or None, axes, None), stage_axis)
-    return shard_map(_staged(local, stage_axis is not None), mesh,
-                     in_specs=(h_spec, w_spec),
-                     out_specs=y_spec, check_rep=False)
+    return on_shards(_staged(local, stage_axis is not None), mesh,
+                     (h_spec, w_spec), y_spec)
 
 
 # ---------------------------------------------------------------------------

@@ -42,8 +42,10 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import PartitionSpec as P
 
-_LANES = 128
+from hetu_galvatron_tpu.ops.pallas.common import LANES, batch_spec, on_shards
+
 # float32 sublanes: the rows before (after) a sub-block that a shift reads
 _SUB = 8
 # rows of the view that precedes a tile: a two-byte tile's sublanes
@@ -73,16 +75,16 @@ def tile_plan(seq: int, channels: int, itemsize: int = 2,
     channels whole lane tiles, every part of ``head_norm`` whole channel
     tiles and a head whole lane tiles within one, the sequence one tile at
     least (its last tile may be ragged)."""
-    if channels % _LANES:
+    if channels % LANES:
         return None
     head, parts = (head_norm[0], len(head_norm[2])) if head_norm else (0, 1)
-    if channels % parts or (head and head % _LANES):
+    if channels % parts or (head and head % LANES):
         return None
     for lanes in TILE_LANES:
         if (channels // parts) % lanes or (head and lanes % head):
             continue
         rows = TILE_BYTES // (lanes * itemsize)
-        while rows > seq and rows > _LANES:
+        while rows > seq and rows > LANES:
             rows //= 2
         return (rows, lanes) if rows <= seq else None
     return None
@@ -391,18 +393,15 @@ def causal_conv(u: jax.Array, taps: jax.Array,
 
 def make_causal_conv(mesh, dp_axes=(), tp_axes=(), *,
                      interpret: bool = False):
-    """The kernels under shard_map, as ``make_ssd_scan``: custom calls that
-    XLA cannot partition, the batch sharded over dp and the channels over
-    tp where the layer cuts them (a depthwise convolution is local to a
-    channel shard; the parts of a ``head_norm`` are not, and the blocks
-    that norm are never cut). None where a device's shapes fit no tile."""
-    from jax.experimental.shard_map import shard_map
-    from jax.sharding import PartitionSpec as P
-
+    """The kernels on a mesh (``common.on_shards``): the batch sharded over
+    dp and the channels over tp where the layer cuts them (a depthwise
+    convolution is local to a channel shard; the parts of a ``head_norm``
+    are not, and the blocks that norm are never cut). None where a device's
+    shapes fit no tile."""
     from hetu_galvatron_tpu.runtime.mesh import axes_size
 
-    batch, chan = dp_axes or None, tp_axes or None
-    wide = P(batch, None, chan)
+    chan = tp_axes or None
+    wide = batch_spec(3, dp_axes, (2, tp_axes))
 
     def conv(u, taps, bias=None, *, pre=None, post=None, head_norm=None,
              **static):
@@ -422,7 +421,6 @@ def make_causal_conv(mesh, dp_axes=(), tp_axes=(), *,
             return causal_conv(u, taps, head_norm=head_norm,
                                interpret=interpret,
                                **dict(zip(names, rest)), **static)
-        return shard_map(local_conv, mesh=mesh,
-                         in_specs=tuple(s for _, s in args), out_specs=wide,
-                         check_rep=False)(*(a for a, _ in args))
+        return on_shards(local_conv, mesh, tuple(s for _, s in args), wide)(
+            *(a for a, _ in args))
     return conv

@@ -26,6 +26,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from hetu_galvatron_tpu.ops.pallas.common import on_shards
+
 NEG_INF = float(jnp.finfo(jnp.float32).min)
 
 
@@ -258,11 +260,7 @@ def make_vocab_parallel_ce(mesh, vocab_sharding, *, z_loss: float = 0.0,
                 nll = nll + z_loss * jnp.square(lse[:, 0])
             return nll.reshape(Bl, Sl)
 
-        from jax.experimental.shard_map import shard_map
-
-        return shard_map(local, mesh=mesh,
-                             in_specs=(logits_spec, labels_spec),
-                             out_specs=labels_spec,
-                             check_rep=False)(logits, labels)
+        return on_shards(local, mesh, (logits_spec, labels_spec),
+                         labels_spec)(logits, labels)
 
     return nll_fn

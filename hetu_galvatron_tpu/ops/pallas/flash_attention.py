@@ -66,6 +66,8 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from hetu_galvatron_tpu.ops.pallas.common import LANES, on_shards
+
 NEG_INF = float(jnp.finfo(jnp.float32).min)
 
 # ``checkpoint_name``s of the forward's two results that the backward kernels
@@ -155,24 +157,21 @@ def _scores(q, k, q0, k0, qseg, kseg, *, masked: bool, scale: float,
     return s
 
 
-_LANES = 128
-
-
 def _across(x, width: int):
-    """A lane-replicated (rows, _LANES) array as (rows, width): the same
+    """A lane-replicated (rows, LANES) array as (rows, width): the same
     registers again where ``width`` is whole lane tiles, no relayout."""
-    if width % _LANES == 0:
-        return jnp.concatenate([x] * (width // _LANES), axis=1)
+    if width % LANES == 0:
+        return jnp.concatenate([x] * (width // LANES), axis=1)
     return jnp.broadcast_to(x[:, :1], (x.shape[0], width))
 
 
 def _lane_sums(p):
-    """Row sums of p left as _LANES partial sums a row (register adds of
+    """Row sums of p left as LANES partial sums a row (register adds of
     p's lane tiles; a ragged width is summed across lanes into lane 0)."""
     rows, width = p.shape
-    if width % _LANES == 0:
-        return sum(p[:, j:j + _LANES] for j in range(0, width, _LANES))
-    lane0 = jax.lax.broadcasted_iota(jnp.int32, (rows, _LANES), 1) == 0
+    if width % LANES == 0:
+        return sum(p[:, j:j + LANES] for j in range(0, width, LANES))
+    lane0 = jax.lax.broadcasted_iota(jnp.int32, (rows, LANES), 1) == 0
     return jnp.where(lane0, jnp.sum(p, axis=1, keepdims=True), 0.0)
 
 
@@ -244,7 +243,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, *rest,
                     qseg_ref[0] if has_seg else None,
                     kseg_ref[0, c] if has_seg else None,
                     masked=masked, scale=scale)
-        # the running max is kept replicated along _LANES lanes, so it
+        # the running max is kept replicated along LANES lanes, so it
         # meets the score tile and the accumulator without a relayout; the
         # cross-lane max is the one reduction a chunk pays
         m = m_ref[...]
@@ -253,7 +252,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, *rest,
         p = jnp.exp(s - _across(new_m, block_k))
         m_ref[...] = new_m
         # the normalizer (of the UNdropped p: out = dropout(softmax(s)) @ v)
-        # is kept as _LANES partial sums a row, added up once at the end
+        # is kept as LANES partial sums a row, added up once at the end
         l_ref[...] = l_ref[...] * corr + _lane_sums(p)
         if dropout_rate > 0.0:
             keep = keep_mask(seed_ref[0], bn, *_tile_pos(q0, k0, s.shape),
@@ -394,8 +393,8 @@ def flash_attention_hmajor(
             jax.ShapeDtypeStruct((B, N, S, 1), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q, _LANES), jnp.float32),
-            pltpu.VMEM((block_q, _LANES), jnp.float32),
+            pltpu.VMEM((block_q, LANES), jnp.float32),
+            pltpu.VMEM((block_q, LANES), jnp.float32),
             pltpu.VMEM((block_q, Dv), jnp.float32),
         ],
         # only the k axis carries loop state (the online softmax);
@@ -914,17 +913,13 @@ def make_flash_sdpa(mesh, dp_axes=(), tp_axes=(), *, interpret: bool = False,
             return _flash_with_vjp(a, b, c, s, sd, causal, interpret,
                                    bq, bk, dropout_rate, scale)
 
-        from jax.experimental.shard_map import shard_map
-
         from hetu_galvatron_tpu.ops.overlap import staged_lane
 
         # each pp row holds its stage's [1, ...] lane (the shared
         # compiled-engine adapter squeezes it around the kernel)
         local = staged_lane(local, stage_axis is not None)
 
-        fn = shard_map(local, mesh=mesh, in_specs=tuple(in_specs),
-                       out_specs=spec, check_rep=False)
-        return fn(*operands)
+        return on_shards(local, mesh, tuple(in_specs), spec)(*operands)
 
     sdpa.supports_segments = True
     sdpa.supports_dropout = True

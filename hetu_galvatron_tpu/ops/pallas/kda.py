@@ -82,10 +82,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from hetu_galvatron_tpu.ops.pallas.common import LANES, batch_spec, on_shards
 from hetu_galvatron_tpu.ops.pallas.flash_attention import _NN, _NT, _dot
 from hetu_galvatron_tpu.ops.pallas.ssd import _TN
 
-_LANES = 128
 # positions of a sub-block (``modules.KDA_SUB``) and of a float32 sublane
 # tile: a key position in a sub-block's second tile leaves the first masked
 SUB = 16
@@ -115,11 +115,11 @@ def tile_plan(chunk: int, heads: int, d: int, dv: int
     whole number of packs and of steps, a step's blocks, copies and state
     inside ``VMEM_BYTES``."""
     nb = chunk // SUB
-    if chunk % SUB or chunk > _LANES or nb & (nb - 1):
+    if chunk % SUB or chunk > LANES or nb & (nb - 1):
         return None
-    if d < _LANES or dv < _LANES or d % _LANES or dv % _LANES:
+    if d < LANES or dv < LANES or d % LANES or dv % LANES:
         return None
-    pack = _LANES // chunk
+    pack = LANES // chunk
     hb = min(heads, HEADS_A_STEP)
     if heads % hb or hb % pack:
         return None
@@ -672,19 +672,14 @@ def kda_scan(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
 
 
 def make_kda_scan(mesh, dp_axes=(), *, interpret: bool = False):
-    """The kernels under shard_map, as ``make_ssd_scan``: custom calls that
-    XLA cannot partition, the batch sharded over dp, everything else local
-    (a plan that cuts a kda block any other way is refused by name,
-    ``eligibility.kda_plan_reason``)."""
-    from jax.experimental.shard_map import shard_map
-    from jax.sharding import PartitionSpec as P
-
-    batch = dp_axes or None
-    wide = P(batch, None, None, None)
+    """The kernels on a mesh (``common.on_shards``): the batch sharded over
+    dp, everything else local (a plan that cuts a kda block any other way is
+    refused by name, ``eligibility.kda_plan_reason``)."""
+    wide = batch_spec(4, dp_axes)
 
     def scan(q, k, v, g, beta, chunk):
-        return shard_map(
-            lambda *a: kda_scan(*a, chunk, interpret=interpret), mesh=mesh,
-            in_specs=(wide, wide, wide, wide, P(batch, None, None)),
-            out_specs=wide, check_rep=False)(q, k, v, g, beta)
+        return on_shards(
+            lambda *a: kda_scan(*a, chunk, interpret=interpret), mesh,
+            (wide, wide, wide, wide, batch_spec(3, dp_axes)), wide)(
+                q, k, v, g, beta)
     return scan

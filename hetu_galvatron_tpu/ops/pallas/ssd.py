@@ -57,9 +57,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from hetu_galvatron_tpu.ops.pallas.common import LANES, batch_spec, on_shards
 from hetu_galvatron_tpu.ops.pallas.flash_attention import _NN, _NT, _dot
 
-_LANES = 128
 # heads a grid step may hold, the most first: multiples of the sublane tile
 # (a step's rows of ``cs`` are ``hb`` sublanes); and the lanes ``hb * P`` its
 # tiles may span, so that x, y, dy, the state and their double buffers stay
@@ -83,11 +83,11 @@ def tile_plan(chunk: int, heads: int, head_dim: int,
     and the state whole lane tiles, a head a whole number of lane tiles or
     a whole fraction of one, the heads a whole number of steps of at most
     ``STEP_LANES`` lanes."""
-    if chunk % _LANES or state % _LANES or head_dim < 16:
+    if chunk % LANES or state % LANES or head_dim < 16:
         return None
-    if head_dim % _LANES and _LANES % head_dim:
+    if head_dim % LANES and LANES % head_dim:
         return None
-    pack = max(1, _LANES // head_dim)    # heads a lane tile
+    pack = max(1, LANES // head_dim)    # heads a lane tile
     for hb in HEADS_A_STEP:
         if not (heads % hb or hb % pack or hb * head_dim > STEP_LANES):
             return hb
@@ -96,7 +96,7 @@ def tile_plan(chunk: int, heads: int, head_dim: int,
 
 def _tiles(hb: int, P: int):
     """The lane tiles of a step's ``hb * P`` lanes: (lane slice, its heads)."""
-    width = max(P, _LANES)
+    width = max(P, LANES)
     pack = width // P
     return width, [(slice(t * width, (t + 1) * width),
                     range(t * pack, (t + 1) * pack))
@@ -351,20 +351,15 @@ def ssd_scan(x: jax.Array, dt: jax.Array, A: jax.Array, Bm: jax.Array,
 
 
 def make_ssd_scan(mesh, dp_axes=(), *, interpret: bool = False):
-    """The kernels under shard_map, as ``make_flash_sdpa``: custom calls
-    that XLA cannot partition, the batch sharded over dp, everything else
-    local (a plan that cuts a mamba block any other way is refused by
-    name, ``eligibility.mamba_plan_reason``)."""
-    from jax.experimental.shard_map import shard_map
-    from jax.sharding import PartitionSpec as P
+    """The kernels on a mesh (``common.on_shards``): the batch sharded over
+    dp, everything else local (a plan that cuts a mamba block any other way
+    is refused by name, ``eligibility.mamba_plan_reason``)."""
+    from jax.sharding import PartitionSpec
 
-    batch = dp_axes or None
+    wide, flat = batch_spec(4, dp_axes), batch_spec(3, dp_axes)
 
     def scan(x, dt, A, Bm, Cm, chunk):
-        return shard_map(
-            lambda *a: ssd_scan(*a, chunk, interpret=interpret), mesh=mesh,
-            in_specs=(P(batch, None, None, None), P(batch, None, None), P(),
-                      P(batch, None, None), P(batch, None, None)),
-            out_specs=P(batch, None, None, None), check_rep=False)(
-                x, dt, A, Bm, Cm)
+        return on_shards(
+            lambda *a: ssd_scan(*a, chunk, interpret=interpret), mesh,
+            (wide, flat, PartitionSpec(), flat, flat), wide)(x, dt, A, Bm, Cm)
     return scan

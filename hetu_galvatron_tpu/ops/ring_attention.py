@@ -422,12 +422,9 @@ def make_ring_sdpa(
         seg_spec = P(spec[0], cp_axes) if stage_axis is None \
             else P(stage_axis, spec[1], cp_axes)
         in_specs = (spec, spec, spec) + ((seg_spec,) if has_seg else ())
-        from jax.experimental.shard_map import shard_map
+        from hetu_galvatron_tpu.ops.pallas.common import on_shards
 
-        fn = shard_map(
-            local_scoped,
-            mesh=mesh, in_specs=in_specs, out_specs=spec,
-            check_rep=False)
+        fn = on_shards(local_scoped, mesh, in_specs, spec)
         relayout = zigzag and not data_zigzagged
         if relayout:
             q, k, v = (zigzag_layout(t, cp, axis=s_dim) for t in (q, k, v))

@@ -94,17 +94,14 @@ def make_ulysses_sdpa(
             v = jnp.repeat(v, rep, axis=h_dim)
 
         def run(inner):
-            from jax.experimental.shard_map import shard_map
+            from hetu_galvatron_tpu.ops.pallas.common import on_shards
 
             from hetu_galvatron_tpu.ops.overlap import staged_lane
 
             local = partial(_ulysses_local, axis=axis, causal=causal,
                             local_sdpa=inner)
             body = staged_lane(local, stage_axis is not None)
-            return shard_map(
-                body,
-                mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
-                check_rep=False)(q, k, v)
+            return on_shards(body, mesh, (spec, spec, spec), spec)(q, k, v)
         if core is not xla_sdpa:
             try:
                 return run(core)  # e.g. flash: may reject untileable shapes

@@ -13,6 +13,7 @@ through NCCL.
 
 from __future__ import annotations
 
+from dataclasses import replace
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -22,6 +23,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from hetu_galvatron_tpu.core.args_schema import ModelArgs
 from hetu_galvatron_tpu.models.builder import causal_lm_loss
+from hetu_galvatron_tpu.models.modules import LayerOps
 from hetu_galvatron_tpu.runtime.hybrid_config import HybridParallelConfig
 from hetu_galvatron_tpu.runtime.mesh import (
     LayerSharding,
@@ -115,9 +117,37 @@ def opt_state_specs(
     return jax.tree_util.tree_map_with_path(for_leaf, state_shape)
 
 
-# the mixer kinds whose convolution runs in the kernels of ops/pallas/conv.py
-# where the devices run them (``attention_overrides``)
-CONV_KERNEL_MIXERS = ("mamba", "kda", "conv")
+def merge_ops(under: Dict[int, LayerOps],
+              over: Optional[Dict[int, LayerOps]]) -> Dict[int, LayerOps]:
+    """The one rule by which two sources of a layer's operators meet: field
+    by field ``over``'s beats ``under``'s, and a field ``over`` leaves unset
+    keeps ``under``'s (a caller's record on a cp layer does not drop the
+    plan's ring core unless it sets a core itself)."""
+    out = dict(under)
+    for i, ops in (over or {}).items():
+        out[i] = replace(out.get(i, LayerOps()), **ops.given())
+    return out
+
+
+def block_kernels():
+    """The Pallas kernels a plan hands a block beside its attention core, a
+    row a field of ``LayerOps``: (the field, its ``make_*(mesh, dp_axes=,
+    interpret=)``, whether the layer's sequence has to be whole on a device,
+    the layer's axes its operands may be cut over beside dp, as ``tp_axes=``).
+    The kinds of block that take a field are the rows of ``modules.MIXERS``
+    that name it; whether the shapes fit a kernel's tiles is the kernel's
+    caller's to see (``modules.ssd_chunked``, ``kda_chunked``,
+    ``causal_depthwise_conv``), which keeps its ``jax.numpy`` form where they
+    do not. A mamba or kda block cut any other way than over dp is refused by
+    name (analysis/eligibility.py); a depthwise convolution is local to a
+    channel shard."""
+    from hetu_galvatron_tpu.ops.pallas.conv import make_causal_conv
+    from hetu_galvatron_tpu.ops.pallas.kda import make_kda_scan
+    from hetu_galvatron_tpu.ops.pallas.ssd import make_ssd_scan
+
+    return (("ssd", make_ssd_scan, False, None),
+            ("kda", make_kda_scan, False, None),
+            ("conv", make_causal_conv, True, "weight_tp_axes"))
 
 
 def attention_overrides(
@@ -129,10 +159,8 @@ def attention_overrides(
     cp_zigzag: bool = False,
     flash_interpret: bool = False,
     mixers: Optional[Sequence[str]] = None,
-    use_ssd_kernel: Optional[bool] = None,
-    use_kda_kernel: Optional[bool] = None,
-    use_conv_kernel: Optional[bool] = None,
-) -> Dict[int, Dict[str, Any]]:
+    kernels: Optional[bool] = None,
+) -> Dict[int, LayerOps]:
     """Per-layer attention-impl dispatch (reference attention.py:664-720),
     branching on :func:`~hetu_galvatron_tpu.runtime.mesh.attention_core`:
     cp > 1 layers swap in the ring-attention kernel over their cp axes;
@@ -146,7 +174,7 @@ def attention_overrides(
     infer collectives for a sequence-sharded softmax; on TPU the local core
     inside the a2a sandwich is the flash kernel.
 
-    ``with_cross=True`` (t5 decoder layers) also sets ``cross_sdpa_fn``:
+    ``with_cross=True`` (t5 decoder layers) also sets ``cross_sdpa``:
     ring and ulysses layers pin cross-attention to the XLA core (the ring
     kernel needs equal q/kv sequence lengths and the a2a sandwich assumes
     self-attention geometry; GSPMD inserts the collectives instead), while
@@ -157,43 +185,35 @@ def attention_overrides(
     CPU parity drills forcing ``use_flash=True`` on the virtual mesh (the
     compiled-vs-host kernel drills run the SAME kernel on both sides).
 
-    ``mixers`` (the layers' mixer kinds, ``ModelArgs.block_kinds``): a
-    ``mamba`` layer gets ``ssd_fn``, the Pallas kernels for its chunked scan
-    (ops/pallas/ssd.py), when ``use_ssd_kernel`` (None =
-    the same rule: every mesh device is a TPU), and a ``kda`` layer
-    ``kda_fn``, those for its chunked delta rule (ops/pallas/kda.py), when
-    ``use_kda_kernel`` (None = that rule again). Whether the shapes fit the
-    kernels' tiles is ``modules.ssd_chunked``'s and ``kda_chunked``'s to
-    see; each keeps its ``jax.numpy`` form where they do not, and where it
-    is handed nothing. Both kinds and a ``conv`` layer
-    (``CONV_KERNEL_MIXERS``) also get ``conv_fn``, the kernels for their
-    causal depthwise convolution and what rides in its pass
-    (ops/pallas/conv.py; ``modules.causal_depthwise_conv``), when
-    ``use_conv_kernel`` (None = that rule once more) and the layer's
-    sequence is whole on a device; a layer's channels may be cut over its
-    weight-tp axes, a depthwise convolution being local to a shard."""
+    ``mixers`` (the layers' mixer kinds, ``ModelArgs.block_kinds``; None =
+    every layer attends): a layer whose kind does not attend gets no core, and
+    a layer whose kind reads a field of :func:`block_kernels` (a ``mamba``
+    layer ``ssd`` and ``conv``, a ``kda`` layer ``kda`` and ``conv``, a
+    ``conv`` layer ``conv``) gets that kernel when ``kernels`` (None = the
+    same rule: every mesh device is a TPU)."""
     from functools import partial as _partial
 
-    from hetu_galvatron_tpu.models.modules import xla_sdpa
-    from hetu_galvatron_tpu.ops.pallas.conv import make_causal_conv
-    from hetu_galvatron_tpu.ops.pallas.kda import make_kda_scan
-    from hetu_galvatron_tpu.ops.pallas.ssd import make_ssd_scan
+    from hetu_galvatron_tpu.models.modules import MIXERS, xla_sdpa
     from hetu_galvatron_tpu.ops.ring_attention import make_ring_sdpa
     from hetu_galvatron_tpu.ops.ulysses import make_ulysses_sdpa
 
-    if use_flash is None:
-        use_flash = flash_kernel_runs(True, mesh.devices.flat)
-    out: Dict[int, Dict[str, Any]] = {}
+    if use_flash is None or kernels is None:
+        on_tpu = flash_kernel_runs(True, mesh.devices.flat)
+        use_flash = on_tpu if use_flash is None else use_flash
+        kernels = on_tpu if kernels is None else kernels
+    out: Dict[int, LayerOps] = {}
     for i, sh in enumerate(per_layer):
+        if mixers is not None and not MIXERS[mixers[i]].attends:
+            continue
         core = attention_core(bool(sh.cp_axes),
                               bool(sh.ulysses and sh.tp_axes), use_flash)
+        cross = xla_sdpa if with_cross else None
         if core.startswith("ring"):
-            out[i] = {"sdpa_fn": make_ring_sdpa(
+            out[i] = LayerOps(sdpa=make_ring_sdpa(
                 mesh, sh.cp_axes, dp_axes=sh.dp_axes, tp_axes=sh.tp_axes,
                 use_flash=use_flash, zigzag=cp_zigzag,
-                data_zigzagged=cp_zigzag, interpret=flash_interpret)}
-            if with_cross:
-                out[i]["cross_sdpa_fn"] = xla_sdpa
+                data_zigzagged=cp_zigzag, interpret=flash_interpret),
+                cross_sdpa=cross)
         elif core.startswith("ulysses"):
             local = None
             if use_flash:
@@ -203,36 +223,24 @@ def attention_overrides(
 
                 local = (_partial(flash_sdpa, interpret=True)
                          if flash_interpret else flash_sdpa)
-            out[i] = {"sdpa_fn": make_ulysses_sdpa(
-                mesh, sh.tp_axes, dp_axes=sh.dp_axes, local_sdpa=local)}
-            if with_cross:
-                out[i]["cross_sdpa_fn"] = xla_sdpa
+            out[i] = LayerOps(sdpa=make_ulysses_sdpa(
+                mesh, sh.tp_axes, dp_axes=sh.dp_axes, local_sdpa=local),
+                cross_sdpa=cross)
         elif core == "flash":
             from hetu_galvatron_tpu.ops.pallas.flash_attention import (
                 make_flash_sdpa,
             )
 
-            out[i] = {"sdpa_fn": make_flash_sdpa(
+            out[i] = LayerOps(sdpa=make_flash_sdpa(
                 mesh, dp_axes=sh.dp_axes, tp_axes=sh.tp_axes,
-                interpret=flash_interpret)}
-    for kind, arg, make, use in (
-            ("mamba", "ssd_fn", make_ssd_scan, use_ssd_kernel),
-            ("kda", "kda_fn", make_kda_scan, use_kda_kernel)):
-        layers = [i for i, mixer in enumerate(mixers or ()) if mixer == kind]
-        if layers and (flash_kernel_runs(True, mesh.devices.flat)
-                       if use is None else use):
-            for i in layers:
-                out.setdefault(i, {})[arg] = make(
-                    mesh, dp_axes=per_layer[i].dp_axes,
-                    interpret=flash_interpret)
-    if (flash_kernel_runs(True, mesh.devices.flat)
-            if use_conv_kernel is None else use_conv_kernel):
+                interpret=flash_interpret))
+    for field, make, whole, cut in block_kernels() if kernels else ():
         for i, mixer in enumerate(mixers or ()):
             sh = per_layer[i]
-            if mixer in CONV_KERNEL_MIXERS and not sh.cp_axes:
-                out.setdefault(i, {})["conv_fn"] = make_causal_conv(
-                    mesh, dp_axes=sh.dp_axes, tp_axes=sh.weight_tp_axes,
-                    interpret=flash_interpret)
+            if MIXERS[mixer].reads(field) and not (whole and sh.cp_axes):
+                out[i] = replace(out.get(i, LayerOps()), **{field: make(
+                    mesh, dp_axes=sh.dp_axes, interpret=flash_interpret,
+                    **({"tp_axes": getattr(sh, cut)} if cut else {}))})
     return out
 
 
@@ -242,8 +250,8 @@ def tp_overlap_overrides(
     cfg: ModelArgs,
     *,
     is_moe_layer_fn: Optional[Any] = None,
-) -> Tuple[Dict[int, Dict[str, Any]], List[Tuple[int, str]]]:
-    """Per-layer overlapped-TP matmul dispatch (the ``matmul_fns`` analogue
+) -> Tuple[Dict[int, LayerOps], List[Tuple[int, str]]]:
+    """Per-layer overlapped-TP matmul dispatch (the ``matmuls`` analogue
     of :func:`attention_overrides`): eligible Megatron-TP layers get the
     decomposed ring all-gather/reduce-scatter matmuls (ops/overlap.py);
     everything else stays on GSPMD. Returns (overrides, fallbacks) where
@@ -261,9 +269,9 @@ def tp_overlap_overrides(
 
     moe_of = is_moe_layer_fn or is_moe_layer
     kinds = cfg.block_kinds(len(per_layer))
-    out: Dict[int, Dict[str, Any]] = {}
+    out: Dict[int, LayerOps] = {}
     fallbacks: List[Tuple[int, str]] = []
-    cache: Dict[Tuple, Dict[str, Any]] = {}
+    cache: Dict[Tuple, LayerOps] = {}
     for i, sh in enumerate(per_layer):
         if cfg.model_type == "t5":
             fallbacks.append((i, T5_REASON))
@@ -281,8 +289,8 @@ def tp_overlap_overrides(
             continue
         key = (sh.dp_axes, tp_axes)
         if key not in cache:
-            cache[key] = {"matmul_fns": make_layer_matmuls(
-                mesh, sh.dp_axes, tp_axes)}
+            cache[key] = LayerOps(matmuls=make_layer_matmuls(
+                mesh, sh.dp_axes, tp_axes))
         out[i] = cache[key]
     return out, fallbacks
 
@@ -291,9 +299,8 @@ def interior_sharding(
     per_layer: List[LayerSharding],
     mesh: Mesh,
     cfg: ModelArgs,
-    layer_overrides: Dict[int, Dict[str, Any]],
-) -> Tuple[Dict[int, Dict[str, Any]],
-           Optional[Callable[[Params], Params]]]:
+    layer_overrides: Dict[int, LayerOps],
+) -> Tuple[Dict[int, LayerOps], Optional[Callable[[Params], Params]]]:
     """Keep a tensor-parallel layer's interior on its own shards, from the
     first projection to the second. The boundary constraint leaves a layer's
     hidden state sequence-sharded over tp (Megatron-SP); with nothing said
@@ -301,7 +308,7 @@ def interior_sharding(
     ``[q | k | v]`` cannot be split on a shard, and pays an all-to-all on
     every activation to get heads for the attention core and back.
 
-    Returns (overrides, param_view). ``overrides[i]["shard_fn"]`` pins an
+    Returns (overrides, param_view). ``overrides[i].shard`` pins an
     activation of layer i: batch on its dp axes, sequence on its cp axes,
     the named dimension on its tp axes (modules.apply_attention /
     apply_mlp call it). ``param_view`` re-lays those layers' fused
@@ -317,13 +324,11 @@ def interior_sharding(
     ``mistral7b_c1_s4k`` 14 % more estimated cycles, AOT, PR 28). Only
     what the plan says decides: layers with no weight-tp axes (tp = 1,
     Ulysses), MoE and t5 layers are left as they are, and so are layers
-    whose matmuls the caller replaced (``matmul_fns``): tp_overlap's
+    whose matmuls the caller replaced (``matmuls``): tp_overlap's
     shard_map kernels are cut for the stored two-axis weights, and the host
     pipeline engine hands the same kernels to the same layer body with no
     view (ROADMAP.md speed item 1(h): the kernels take the views, then
     ``fc1_pair`` goes). With none left ``param_view`` is None."""
-    from dataclasses import replace as _replace
-
     from hetu_galvatron_tpu.models.modules import (
         _is_gated,
         gate_up_pairs,
@@ -335,18 +340,18 @@ def interior_sharding(
         i: sh for i, sh in enumerate(per_layer)
         if sh.weight_tp_axes and cfg.model_type != "t5"
         and not is_moe_layer(cfg, i)
-        and "matmul_fns" not in layer_overrides.get(i, {})}
+        and layer_overrides.get(i, LayerOps()).matmuls is None}
     if not local:
         return {}, None
 
-    def make_shard_fn(sh: LayerSharding):
-        def shard_fn(a: jax.Array, axis: int) -> jax.Array:
+    def make_shard(sh: LayerSharding):
+        def shard(a: jax.Array, axis: int) -> jax.Array:
             dims = [sh.dp_axes or None, sh.cp_axes or None]
             dims += [None] * (a.ndim - 2)
             dims[axis] = sh.tp_axes
             return jax.lax.with_sharding_constraint(
                 a, NamedSharding(mesh, P(*dims)))
-        return shard_fn
+        return shard
 
     gated = _is_gated(cfg.hidden_act)
 
@@ -359,7 +364,7 @@ def interior_sharding(
         new``) cut to the tp shard: gathered over tp, re-laid where it
         stands, then sliced. All-gathers forward and backward, once a step;
         left to itself GSPMD moves the columns by all-to-all."""
-        whole = _replace(sh, tp_axes=())   # the same plan, tp left out
+        whole = replace(sh, tp_axes=())   # the same plan, tp left out
         a = relay(pin(leaf, whole, lead + cut))
         return pin(pin(a, whole, lead + new), sh, lead + new)
 
@@ -383,7 +388,7 @@ def interior_sharding(
                          **({"attn": attn} if attn else {})}
         return {**params, "layers": tuple(layers)}
 
-    return ({i: {"shard_fn": make_shard_fn(sh)} for i, sh in local.items()},
+    return ({i: LayerOps(shard=make_shard(sh)) for i, sh in local.items()},
             param_view)
 
 
@@ -466,7 +471,7 @@ def build_spmd_loss_fn(
     axes_tree: Params,
     *,
     compute_dtype=jnp.bfloat16,
-    layer_overrides: Optional[Dict[int, Dict[str, Any]]] = None,
+    layer_overrides: Optional[Dict[int, LayerOps]] = None,
     with_moe_stats: bool = False,
     tp_overlap: bool = False,
     lane_dp: bool = False,
@@ -501,11 +506,9 @@ def build_spmd_loss_fn(
     eligibility.HIER_KERNEL_REASON): the partitioner inserts the
     sequence collectives inside each lane — same math, collective
     association differs within float tolerance."""
-    from dataclasses import replace as _replace
-
     enc_per, per_layer, vocab, pspecs = _lower_specs(hpc, mesh, axes_tree)
     if lane_dp:
-        lane = lambda sh: _replace(sh, dp_axes=())
+        lane = lambda sh: replace(sh, dp_axes=())
         b_layers = [lane(sh) for sh in per_layer]
         b_vocab = lane(vocab)
         b_enc = [lane(sh) for sh in enc_per]
@@ -533,25 +536,14 @@ def build_spmd_loss_fn(
             b_enc, mesh, use_flash=use_flash,
             flash_interpret=kernel_interpret) if b_enc else None)
     if tp_overlap:
-        overlap_ov, _ = tp_overlap_overrides(per_layer, mesh, cfg)
-        # merged UNDER ring/caller overrides per key: an explicit
-        # sdpa_fn/matmul_fns from either always wins
-        for i, kw in overlap_ov.items():
-            ring[i] = {**kw, **ring.get(i, {})}
-    if ring:
-        # per-key merge: a caller override on a cp layer must not drop the
-        # ring sdpa_fn unless it sets sdpa_fn itself
-        merged = dict(layer_overrides or {})
-        for i, kw in ring.items():
-            merged[i] = {**kw, **merged.get(i, {})}
-        layer_overrides = merged
+        # under the plan's kernels, and both under the caller's
+        ring = merge_ops(tp_overlap_overrides(per_layer, mesh, cfg)[0], ring)
+    layer_overrides = merge_ops(ring, layer_overrides)
     # b_layers: under the lane vmap the dp axes are the vmap's, in the
     # interior's constraints as at the boundaries
-    merged = dict(layer_overrides or {})
-    interior, param_view = interior_sharding(b_layers, mesh, cfg, merged)
-    for i, kw in interior.items():
-        merged[i] = {**kw, **merged.get(i, {})}
-    layer_overrides = merged
+    interior, param_view = interior_sharding(b_layers, mesh, cfg,
+                                             layer_overrides)
+    layer_overrides = merge_ops(interior, layer_overrides)
     remat = [sh.checkpoint for sh in per_layer]
     enc_remat = [sh.checkpoint for sh in enc_per]
     batch_shd = batch_sharding(per_layer, mesh)
@@ -609,7 +601,7 @@ def make_spmd_eval_step(
     axes_tree: Params,
     *,
     compute_dtype=jnp.bfloat16,
-    layer_overrides: Optional[Dict[int, Dict[str, Any]]] = None,
+    layer_overrides: Optional[Dict[int, LayerOps]] = None,
     tp_overlap: bool = False,
 ):
     """Jitted held-out loss under the SAME plan shardings as training
@@ -638,7 +630,7 @@ def make_spmd_train_step(
     params: Params,
     *,
     compute_dtype=jnp.bfloat16,
-    layer_overrides: Optional[Dict[int, Dict[str, Any]]] = None,
+    layer_overrides: Optional[Dict[int, LayerOps]] = None,
     donate: bool = True,
     chunks: Optional[int] = None,
     tp_overlap: bool = False,

@@ -994,7 +994,6 @@ _KDA_HF_THIRDS = {
     "wqkv": tuple(f"self_attn.{m}_proj.weight" for m in "qkv"),
     "taps": tuple(f"self_attn.{m}_conv1d.weight" for m in "qkv"),
     "wlow": tuple(f"self_attn.{m}_proj.weight" for m in ("f_a", "g_a", "b"))}
-_MIXER_KINDS = ("full_attention", "conv", "mamba", "latent_attention", "kda")
 
 
 def _rope_columns_to_hf(width: int) -> np.ndarray:
@@ -1035,9 +1034,11 @@ def _latent_rope_orders(cfg: ModelArgs, to_hf: bool) -> Dict[str, Any]:
 
 
 def _unknown_mixer(i: int, mixer: str) -> ValueError:
+    from hetu_galvatron_tpu.models.modules import MIXERS
+
     return ValueError(
         f"block {i}: no public names for a {mixer!r} mixer; the exporter "
-        f"knows the mixer kinds {', '.join(_MIXER_KINDS)} and the "
+        f"knows the mixer kinds {', '.join(MIXERS)} and the "
         "feed-forward kinds dense, experts")
 
 

@@ -77,6 +77,7 @@ shard_map wrappers.
 
 from __future__ import annotations
 
+from dataclasses import replace
 from functools import partial
 from typing import Any, Dict, List, Optional, Tuple
 
@@ -221,13 +222,15 @@ class CompiledPipelineEngine:
         self._use_dropout = (cfg.hidden_dropout > 0.0
                              or cfg.attention_dropout > 0.0)
         self._use_flash = use_flash
-        self._sdpa = self._build_attention_core(flash_interpret)
+        # what the (uniform) plan swaps in every decoder layer: the stage-
+        # stacked attention core and, below, the overlapped projections
+        self._ops = M.LayerOps(
+            sdpa=self._build_attention_core(flash_interpret))
         # overlapped-TP ring matmuls inside the program (the same per-layer
         # eligibility the SPMD/host paths apply; the plan is uniform, so one
         # decision covers every decoder layer)
         self.tp_overlap = False
         self.overlap_reason: Optional[str] = None
-        self._matmul_fns: Dict[str, Any] = {}
         if tp_overlap:
             from hetu_galvatron_tpu.ops.overlap import (
                 layer_overlap_reason,
@@ -238,9 +241,9 @@ class CompiledPipelineEngine:
             reason = layer_overlap_reason(
                 cfg, self.layer_sh, axes_size(self.mesh, tp_axes))
             if reason is None:
-                self._matmul_fns = make_layer_matmuls(
+                self._ops = replace(self._ops, matmuls=make_layer_matmuls(
                     self.mesh, self.layer_sh.dp_axes, tp_axes,
-                    stage_axis="pp")
+                    stage_axis="pp"))
                 self.tp_overlap = True
             else:
                 self.overlap_reason = reason
@@ -258,8 +261,8 @@ class CompiledPipelineEngine:
             )
 
             reason = plan_hier_dp_reason(cfg, hpc)
-            if reason is None and (self._matmul_fns or
-                                   self._sdpa is not None):
+            if reason is None and (self._ops.matmuls or
+                                   self._ops.sdpa is not None):
                 reason = HIER_KERNEL_REASON
             if reason is not None:
                 raise ValueError(f"hier_dp unsupported: {reason}")
@@ -473,7 +476,7 @@ class CompiledPipelineEngine:
         Mirrors the module's dtype casts and dropout dispatch rules."""
         cfg = self.cfg
         cd = self.compute_dtype
-        mm = self._matmul_fns
+        mm = self._ops.matmuls or {}
         pp_, B, S, _ = x.shape
         hd = cfg.head_dim
         nq, nkv = cfg.num_attention_heads, cfg.kv_heads
@@ -494,7 +497,7 @@ class CompiledPipelineEngine:
             cos, sin = rope
             q = M.apply_rope(q, cos, sin)
             k = M.apply_rope(k, cos, sin)
-        core = self._sdpa
+        core = self._ops.sdpa
         use_drop = attn_rngs is not None and cfg.attention_dropout > 0.0
         if use_drop:
             if core is None:
@@ -538,7 +541,7 @@ class CompiledPipelineEngine:
         and the fc1_pair overlapped form all mirrored)."""
         cfg = self.cfg
         cd = self.compute_dtype
-        mm = self._matmul_fns
+        mm = self._ops.matmuls or {}
         act = M._ACTS[cfg.hidden_act]
         win = p["win"].astype(cd)
         gated = cfg.hidden_act in ("swiglu", "geglu")

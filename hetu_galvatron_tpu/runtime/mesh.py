@@ -269,7 +269,7 @@ def make_pp_rotation(mesh: Mesh, spec: P, shift: int):
     ``shift=-1`` sends it to stage s-1 (backward cotangents). The wrap-around
     edge carries don't-care data by construction of the 1F1B schedule (lane 0
     embeds fresh tokens; the last lane seeds its cotangent from the loss)."""
-    from jax.experimental.shard_map import shard_map
+    from hetu_galvatron_tpu.ops.pallas.common import on_shards
 
     pp = mesh.shape["pp"]
     perm = [(i, (i + shift) % pp) for i in range(pp)]
@@ -282,8 +282,7 @@ def make_pp_rotation(mesh: Mesh, spec: P, shift: int):
         with jax.named_scope("pp_rotate"):
             return jax.lax.ppermute(blk, "pp", perm)
 
-    return shard_map(body, mesh, in_specs=spec, out_specs=spec,
-                     check_rep=False)
+    return on_shards(body, mesh, spec, spec)
 
 
 @dataclass(frozen=True)

@@ -480,7 +480,7 @@ class PipelineEngine:
         controller places them on every stage's submesh directly, where the
         reference ships them through multi-tensor p2p transfers
         (pipeline.py:1140 _communicate)."""
-        from hetu_galvatron_tpu.models.moe import apply_moe_decoder_layer
+        from hetu_galvatron_tpu.models.builder import make_block
 
         cfg = self.cfg
 
@@ -501,7 +501,10 @@ class PipelineEngine:
                 # packed samples: gather per-token rows -> [B, S, D/2]
                 cos, sin = cos[position_ids], sin[position_ids]
             rope = (cos, sin)
-        from hetu_galvatron_tpu.parallel.spmd import attention_overrides
+        from hetu_galvatron_tpu.parallel.spmd import (
+            attention_overrides,
+            merge_ops,
+        )
 
         overrides = attention_overrides(
             st.shardings, st.mesh,
@@ -518,8 +521,7 @@ class PipelineEngine:
             ov, _ = tp_overlap_overrides(
                 st.shardings, st.mesh, cfg,
                 is_moe_layer_fn=lambda _c, j: "moe" in sp["layers"][j])
-            for j, kw in ov.items():
-                overrides[j] = {**kw, **overrides.get(j, {})}
+            overrides = merge_ops(ov, overrides)
         seg_kw = ({"segment_ids": segment_ids}
                   if segment_ids is not None else {})
         aux_total = jnp.zeros((), jnp.float32)
@@ -527,20 +529,12 @@ class PipelineEngine:
             sh = st.shardings[j]
             x = jax.lax.with_sharding_constraint(
                 x, NamedSharding(st.mesh, sh.act_spec()))
-            if "moe" in lp:
-                fn = partial(apply_moe_decoder_layer, cfg=cfg, rope=rope,
-                             compute_dtype=self.compute_dtype,
-                             dropout_rng=layer_rng(j),
-                             **seg_kw, **overrides.get(j, {}))
-            else:
-                base = partial(M.apply_decoder_layer, cfg=cfg, rope=rope,
-                               compute_dtype=self.compute_dtype,
-                               dropout_rng=layer_rng(j),
-                               **seg_kw, **overrides.get(j, {}))
-                fn = lambda p, h, b=base: (b(p, h),
-                                           jnp.zeros((), jnp.float32), {})
-            if sh.checkpoint:
-                fn = M.remat(fn, cfg)
+            fn = make_block(
+                cfg, ("full_attention", "experts" if "moe" in lp else "dense"),
+                dict(rope=rope, compute_dtype=self.compute_dtype,
+                     dropout_rng=layer_rng(j),
+                     ops=overrides.get(j, M.LayerOps()), **seg_kw),
+                sh.checkpoint)
             # per-layer router stats are an spmd-path feature; the stage
             # programs fold only the aux scalar into the loss
             x, aux, _ = fn(lp, x)
@@ -601,8 +595,7 @@ class PipelineEngine:
                 a, NamedSharding(st.mesh, sh.act_spec()))
             kwargs = dict(rope=rope_enc, compute_dtype=self.compute_dtype,
                           causal=False, dropout_rng=layer_rng(M.DROPOUT_STREAM_ENC + j),
-                          **enc_over.get(j, {}))
-            kwargs.pop("cross_sdpa_fn", None)
+                          ops=enc_over.get(j, M.LayerOps()))
             fn = partial(M.apply_decoder_layer, cfg=cfg, **kwargs)
             if sh.checkpoint:
                 fn = M.remat(fn, cfg)
@@ -614,7 +607,8 @@ class PipelineEngine:
             b = jax.lax.with_sharding_constraint(
                 b, NamedSharding(st.mesh, sh.act_spec()))
             kwargs = dict(rope=rope_dec, compute_dtype=self.compute_dtype,
-                          dropout_rng=layer_rng(j), **dec_over.get(j, {}))
+                          dropout_rng=layer_rng(j),
+                          ops=dec_over.get(j, M.LayerOps()))
             fn = partial(apply_cross_decoder_layer, cfg=cfg, **kwargs)
             if sh.checkpoint:
                 fn = M.remat(fn, cfg)

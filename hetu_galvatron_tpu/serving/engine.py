@@ -354,7 +354,8 @@ class ServingEngine:
         for i, lp in enumerate(params["layers"]):
             cell: Dict[str, jax.Array] = {}
             sdpa = sdpa_for(i, new_pools, cell)
-            x = M.apply_decoder_layer(lp, x, cfg, rope=rope, sdpa_fn=sdpa,
+            x = M.apply_decoder_layer(lp, x, cfg, rope=rope,
+                                      ops=M.LayerOps(sdpa=sdpa),
                                       compute_dtype=self.compute_dtype)
             new_pools[i] = {"k": cell["k"], "v": cell["v"]}
         x = M.apply_norm(params["prenorm"], x, cfg)

@@ -290,7 +290,7 @@ def test_compiled_kernels_acceptance_drill(tmp_path, cpu_devices):
                                   compute_dtype=jnp.float32, **kern)
     # the rings really are live inside the compiled program
     assert comp.tp_overlap and comp.overlap_reason is None
-    assert comp._matmul_fns and comp._sdpa is not None
+    assert comp._ops.matmuls and comp._ops.sdpa is not None
     params, axes = init_causal_lm(jax.random.key(0), CFG)
     hsp = host.split_params(params, axes)
     hso = host.init_opt(hsp, axes)

@@ -120,7 +120,7 @@ def test_hier_vs_flat_trajectory(tmp_path, cpu_devices, dp_type, chunks):
 @pytest.mark.parametrize("dp_type", ["ddp", "zero3"])
 def test_hier_lane_keeps_a_tp_layers_interior_on_its_shards(
         tmp_path, cpu_devices, dp_type):
-    """The lane loss gets ``spmd.interior_sharding``'s shard_fn and views as
+    """The lane loss gets ``spmd.interior_sharding``'s ``shard`` and views as
     the flat loss does (which is why the two trajectories above agree to
     reassociation): the lane step moves no activation between a layer's two
     projections. Before PR 28 it held 16 all-to-alls there (``split``, the

@@ -170,7 +170,7 @@ def test_view_layouts():
 
 @pytest.mark.parametrize("name", list(CFGS))
 def test_layer_on_the_views_equals_layer_on_the_stored(name):
-    """One device, no mesh: a layer given a ``shard_fn`` and the views
+    """One device, no mesh: a layer given a ``shard`` and the views
     computes what the layer given the stored leaves computes."""
     cfg = CFGS[name]
     p, _ = M.init_decoder_layer(jax.random.key(0), cfg)
@@ -189,15 +189,15 @@ def test_layer_on_the_views_equals_layer_on_the_stored(name):
                 view["mlp"][k] = M.gate_up_pairs(p["mlp"][k])
     kw = dict(rope=rope, compute_dtype=jnp.float32)
     np.testing.assert_allclose(
-        np.asarray(M.apply_decoder_layer(view, x, cfg,
-                                         shard_fn=lambda a, axis: a, **kw)),
+        np.asarray(M.apply_decoder_layer(
+            view, x, cfg, ops=M.LayerOps(shard=lambda a, axis: a), **kw)),
         np.asarray(M.apply_decoder_layer(p, x, cfg, **kw)),
         rtol=1e-5, atol=1e-5)
 
 
 # sha256 of the tp = 1 layer's jaxpr at commit 2fb9159 (PR 27), bf16
 # compute, x [2, 16, 64], the configurations above: a layer with no
-# shard_fn and the stored leaves traces to that program, to the character
+# ``shard`` and the stored leaves traces to that program, to the character
 _TP1_JAXPR = {
     "gqa_swiglu_rope":
         "ff2cb4d88a1e03ed94975b317ed268baad5e1b7f5e0d16157fc6197d94d349dd",

@@ -286,8 +286,8 @@ def test_shapes_that_fit_no_tile_take_the_jax_numpy_form():
 
 @pytest.mark.parametrize("forced", [None, True, False])
 def test_who_knows_the_devices_hands_the_kernels_down(forced):
-    """``attention_overrides`` gives a layer that convolves ``conv_fn``
-    where every device of the mesh is a TPU (here: never, unless a test
+    """``attention_overrides`` gives a layer that convolves its ``conv``
+    kernels where every device of the mesh is a TPU (here: never, unless a test
     says so) and the layer's sequence is whole on a device, and no other
     layer ever."""
     from hetu_galvatron_tpu.parallel import spmd
@@ -300,18 +300,19 @@ def test_who_knows_the_devices_hands_the_kernels_down(forced):
               "mamba"]
     got = spmd.attention_overrides(
         [whole] * 5 + [cut], mesh, use_flash=False, flash_interpret=True,
-        mixers=mixers, use_conv_kernel=forced)
+        mixers=mixers, kernels=forced)
     takers = [i for i, m in enumerate(mixers[:5])
-              if m in spmd.CONV_KERNEL_MIXERS]
-    # (the layer whose sequence is cut gets its ring attention, no more)
-    assert list(got.pop(5)) == ["sdpa_fn"]
-    assert got == {} if not forced else (
-        sorted(got) == takers and all(list(kw) == ["conv_fn"]
-                                      for kw in got.values()))
+              if M.MIXERS[m].reads("conv")]
+    # (a mamba layer whose sequence is cut gets no convolution: where the
+    # kernels run, its scan alone)
+    assert getattr(got.pop(5, None), "conv", None) is None
+    assert sorted(got) == (takers if forced else [])
+    assert all(ops.conv is not None and ops.sdpa is None
+               for ops in got.values())
     if forced:
         # and what it hands down is the convolution, under shard_map over dp
         args = _inputs("mamba", 300, jnp.float32)
-        fn = got[0]["conv_fn"]
+        fn = got[0].conv
         np.testing.assert_allclose(
             np.asarray(M.causal_depthwise_conv(
                 *args[:3], silu=True, conv_fn=fn, scope="mixer/mamba/conv")),

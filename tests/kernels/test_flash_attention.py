@@ -665,11 +665,14 @@ def test_flash_with_a_value_width_of_its_own(case):
 # sha256 (first 16 hex) of out | dq | dk | dv in interpret mode on seeded
 # inputs, recorded from the kernels as they were before v had a width of its
 # own (commit 98a0ba2): with ``Dv == D`` every block, every scratch and every
-# instruction is what it was
+# instruction is what it was. The bf16 case has no hash: the bits of a bf16
+# result in interpret mode follow the machine that runs them (one tree gave
+# both answers, PERF_LEDGER.jsonl ``tests.rcs`` of PR 41 to 45), so it is
+# held, inside the one process, to the XLA core in float32 on the same
+# operands, forward and the three gradients, at what bf16 resolves
 _TODAYS = {
     "mha_f32": ((2, 256, 4, 4, 32, jnp.float32, False), "bbb22d8f65cd9718"),
-    "gqa_bf16_segments": ((1, 384, 8, 2, 64, jnp.bfloat16, True),
-                          "ccf54d8d028f60ee"),
+    "gqa_bf16_segments": ((1, 384, 8, 2, 64, jnp.bfloat16, True), None),
 }
 
 
@@ -685,10 +688,23 @@ def test_flash_at_equal_widths_is_bit_equal_to_the_kernels_before(case):
     do = jax.random.normal(ks[3], (B, S, N, D), dtype)
     segs = ((jnp.arange(S)[None, :] // 96).astype(jnp.int32).repeat(B, 0)
             if seg else None)
+    kw = dict(causal=True, segment_ids=segs, scale=0.11 if seg else None)
     out, vjp = jax.vjp(lambda a, b, c: flash_sdpa(
-        a, b, c, causal=True, interpret=True, segment_ids=segs,
-        scale=0.11 if seg else None), q, k, v)
-    h = hashlib.sha256()
-    for t in (out,) + vjp(do):
-        h.update(np.asarray(t.astype(jnp.float32)).tobytes())
-    assert h.hexdigest()[:16] == want
+        a, b, c, interpret=True, **kw), q, k, v)
+    got = [np.asarray(t.astype(jnp.float32)) for t in (out,) + vjp(do)]
+    if want is not None:
+        h = hashlib.sha256()
+        for t in got:
+            h.update(t.tobytes())
+        assert h.hexdigest()[:16] == want
+        return
+    f32 = lambda t: t.astype(jnp.float32)
+    ref, ref_vjp = jax.vjp(lambda a, b, c: xla_sdpa(a, b, c, **kw),
+                           f32(q), f32(k), f32(v))
+    for name, g, w in zip(("out", "dq", "dk", "dv"), got,
+                          (ref,) + ref_vjp(f32(do))):
+        w = np.asarray(w)
+        assert g.shape == w.shape, name
+        np.testing.assert_allclose(g, w, rtol=2e-2, atol=2e-2, err_msg=name)
+        # (measured 1.9e-3 to 2.6e-3: a bf16 result's own rounding)
+        assert np.sqrt(np.mean((g - w) ** 2) / np.mean(w ** 2)) < 5e-3, name

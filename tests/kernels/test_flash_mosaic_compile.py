@@ -269,7 +269,8 @@ def test_a_rematted_block_holds_one_forward_kernel(one_chip, wrapper,
     x = jax.ShapeDtypeStruct((B, S, H), jnp.bfloat16, sharding=one_chip)
 
     def block(p, h):
-        return M.apply_decoder_layer(p, h, cfg, sdpa_fn=flash_sdpa)
+        return M.apply_decoder_layer(p, h, cfg,
+                                     ops=M.LayerOps(sdpa=flash_sdpa))
 
     wrapped = M.remat(block, cfg) if wrapper == "remat" else jax.checkpoint(
         block)

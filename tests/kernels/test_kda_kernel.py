@@ -230,9 +230,9 @@ def test_shapes_that_fit_no_tile_take_the_jax_numpy_form():
 
 @pytest.mark.parametrize("forced", [None, True, False])
 def test_who_knows_the_devices_hands_the_kernels_down(forced):
-    """``attention_overrides`` gives a kda layer ``kda_fn`` where every
-    device of the mesh is a TPU (here: never, unless a test says so), and
-    no other layer ever."""
+    """``attention_overrides`` gives a kda layer its ``kda`` kernels where
+    every device of the mesh is a TPU (here: never, unless a test says so),
+    and no other layer ever."""
     from hetu_galvatron_tpu.parallel.spmd import attention_overrides
     from hetu_galvatron_tpu.runtime.mesh import LayerSharding, build_mesh
 
@@ -241,16 +241,16 @@ def test_who_knows_the_devices_hands_the_kernels_down(forced):
     got = attention_overrides(
         per_layer, mesh, use_flash=False, flash_interpret=True,
         mixers=["latent_attention", "kda", "mamba", "kda"],
-        use_kda_kernel=forced)
-    assert got == {} if not forced else (
-        list(got) == [1, 3] and all(list(kw) == ["kda_fn"]
-                                    for kw in got.values()))
+        kernels=forced)
+    assert {i: list(ops.given()) for i, ops in got.items()} == (
+        {1: ["kda", "conv"], 2: ["ssd", "conv"], 3: ["kda", "conv"]}
+        if forced else {})
     if forced:
         # and what it hands down is the scan, under shard_map over dp
         args = tuple(jnp.concatenate([t, t]) for t in _inputs(
             CHUNK, 2, DECAYS["strongest_init"]))
         np.testing.assert_allclose(
-            np.asarray(got[1]["kda_fn"](*args, CHUNK)),
+            np.asarray(got[1].kda(*args, CHUNK)),
             np.asarray(M.kda_chunked(*args, CHUNK, jnp.float32)),
             rtol=1e-4, atol=1e-5)
 

@@ -83,8 +83,8 @@ def _stack(blocks, policy, extras, cpu_devices):
     cfg = CFG.model_copy(update={
         "remat_policy": policy,
         "attention_dropout": 0.2 if "dropout" in extras else 0.0})
-    sdpa_fn = _core(extras, cpu_devices)
-    kwargs = {"sdpa_fn": sdpa_fn, "compute_dtype": jnp.float32}
+    kwargs = {"ops": M.LayerOps(sdpa=_core(extras, cpu_devices)),
+              "compute_dtype": jnp.float32}
     if "segments" in extras:
         # a document boundary inside a score tile
         kwargs["segment_ids"] = jnp.asarray(

@@ -154,9 +154,9 @@ def test_shapes_that_fit_no_tile_take_the_jax_numpy_form():
 
 @pytest.mark.parametrize("forced", [None, True, False])
 def test_who_knows_the_devices_hands_the_kernels_down(forced):
-    """``attention_overrides`` gives a mamba layer ``ssd_fn`` where every
-    device of the mesh is a TPU (here: never, unless a test says so), and
-    no other layer ever."""
+    """``attention_overrides`` gives a mamba layer its ``ssd`` kernels where
+    every device of the mesh is a TPU (here: never, unless a test says so),
+    and no other layer ever."""
     from hetu_galvatron_tpu.parallel.spmd import attention_overrides
     from hetu_galvatron_tpu.runtime.mesh import LayerSharding, build_mesh
 
@@ -164,14 +164,14 @@ def test_who_knows_the_devices_hands_the_kernels_down(forced):
     per_layer = [LayerSharding(dp_axes=("d0",), cp_axes=(), tp_axes=())] * 3
     got = attention_overrides(
         per_layer, mesh, use_flash=False, flash_interpret=True,
-        mixers=["mamba", "full_attention", "conv"], use_ssd_kernel=forced)
-    assert got == {} if not forced else (
-        list(got) == [0] and list(got[0]) == ["ssd_fn"])
+        mixers=["mamba", "full_attention", "conv"], kernels=forced)
+    assert {i: list(ops.given()) for i, ops in got.items()} == (
+        {0: ["ssd", "conv"], 2: ["conv"]} if forced else {})
     if forced:
         # and what it hands down is the scan, under shard_map
         args = _inputs(256, jnp.float32)
         np.testing.assert_allclose(
-            np.asarray(got[0]["ssd_fn"](*args, CHUNK)),
+            np.asarray(got[0].ssd(*args, CHUNK)),
             np.asarray(M.ssd_chunked(*args, CHUNK, jnp.float32)),
             rtol=1e-4, atol=1e-4)
 

@@ -209,7 +209,7 @@ def test_plan_overlap_reasons_from_hpc():
 
 
 def test_spmd_overrides_dispatch_and_fallback(cpu_devices):
-    """tp_overlap_overrides: eligible layers get matmul_fns; a non-dividing
+    """tp_overlap_overrides: eligible layers get ``matmuls``; a non-dividing
     tp reports the reason instead."""
     from hetu_galvatron_tpu.core.args_schema import CoreArgs
     from hetu_galvatron_tpu.parallel.spmd import (
@@ -230,8 +230,7 @@ def test_spmd_overrides_dispatch_and_fallback(cpu_devices):
     per_layer, _ = layer_shardings(hpc, mesh)
     ov, fb = tp_overlap_overrides(per_layer, mesh, cfg)
     assert sorted(ov) == [0, 1] and not fb
-    assert set(ov[0]["matmul_fns"]) == {"qkv", "out", "fc1", "fc2",
-                                        "fc1_pair"}
+    assert set(ov[0].matmuls) == {"qkv", "out", "fc1", "fc2", "fc1_pair"}
 
     bad = _cfg(seq_length=7, max_position_embeddings=8)
     ov, fb = tp_overlap_overrides(per_layer, mesh, bad)

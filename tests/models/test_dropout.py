@@ -230,5 +230,5 @@ def test_attention_dropout_refuses_custom_kernels():
         forward_causal_lm(
             params, tokens, CFG, compute_dtype=jnp.float32,
             dropout_rng=jax.random.key(0),
-            layer_overrides={i: {"sdpa_fn": fake_kernel}
+            layer_overrides={i: M.LayerOps(sdpa=fake_kernel)
                              for i in range(CFG.num_hidden_layers)})

@@ -635,7 +635,7 @@ def test_kda_refuses_what_it_is_not_written_for():
                           segment_ids=jnp.zeros_like(batch["tokens"]))
     with pytest.raises(NotImplementedError, match="kda_plan_reason"):
         M.apply_mixer(params["layers"][0], jnp.zeros((1, 40, 32)), cfg, "kda",
-                      shard_fn=lambda a, axis: a)
+                      ops=M.LayerOps(shard=lambda a, axis: a))
 
 
 @pytest.mark.parametrize("dtype,loss_band,grad_band", [

@@ -291,6 +291,7 @@ def test_t5_flash_attention_overrides():
     the XLA core. Equal enc/dec lengths so cross-attention tiles."""
     from functools import partial as fpartial
 
+    from hetu_galvatron_tpu.models.modules import LayerOps
     from hetu_galvatron_tpu.ops.pallas.flash_attention import flash_sdpa
 
     cfg = T5.model_copy(update={"num_encoder_layers": 2})
@@ -303,7 +304,7 @@ def test_t5_flash_attention_overrides():
     }
     base = causal_lm_loss(params, batch, cfg, compute_dtype=jnp.float32)
     flash = fpartial(flash_sdpa, interpret=True)
-    over = {i: {"sdpa_fn": flash} for i in range(2)}
+    over = {i: LayerOps(sdpa=flash) for i in range(2)}
     out = causal_lm_loss(params, batch, cfg, compute_dtype=jnp.float32,
                          layer_overrides=over, enc_layer_overrides=over)
     np.testing.assert_allclose(float(out), float(base), rtol=2e-5, atol=2e-5)

@@ -171,10 +171,10 @@ def main() -> int:
     on_tpu = dev.platform == "tpu"
     # on a TPU what the cell trains with: the flash core, and the scan's
     # and the convolution's kernels in the mamba blocks
-    mamba_kernels = ({"ssd_fn": ssd_scan, "conv_fn": causal_conv}
-                     if on_tpu else {})
-    sdpa = ({i: mamba_kernels if mixer == "mamba"
-             else {"sdpa_fn": flash_sdpa}
+    mamba_ops = (M.LayerOps(ssd=ssd_scan, conv=causal_conv) if on_tpu
+                 else M.LayerOps())
+    sdpa = ({i: mamba_ops if mixer == "mamba"
+             else M.LayerOps(sdpa=flash_sdpa)
              for i, (mixer, _) in enumerate(cfg.block_kinds())}
             if on_tpu else None)
 
@@ -190,7 +190,7 @@ def main() -> int:
         p = {**params["layers"][mamba_at]["mamba"], **leaves}
         return jax.jit(lambda p, x: M.apply_mamba2(
             p, x.astype(jnp.bfloat16), cfg, compute_dtype=jnp.bfloat16,
-            **mamba_kernels))(p, mamba_in)
+            ssd_fn=mamba_ops.ssd, conv_fn=mamba_ops.conv))(p, mamba_in)
 
     def attention_operator(run_cfg):
         rope = None

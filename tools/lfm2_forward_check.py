@@ -133,7 +133,8 @@ def main() -> int:
 
     params = jax.jit(lambda k: init_causal_lm(k, cfg)[0])(
         jax.random.key(a.seed))
-    sdpa = ({i: {"sdpa_fn": flash_sdpa} for i in range(cfg.num_hidden_layers)}
+    sdpa = ({i: M.LayerOps(sdpa=flash_sdpa)
+             for i in range(cfg.num_hidden_layers)}
             if dev.platform == "tpu" else None)
 
     def program_logits(p, run_cfg):

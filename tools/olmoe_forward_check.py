@@ -58,6 +58,7 @@ def main() -> int:
         forward_causal_lm,
         init_causal_lm,
     )
+    from hetu_galvatron_tpu.models.modules import LayerOps
     from hetu_galvatron_tpu.ops.pallas.flash_attention import flash_sdpa
     from hetu_galvatron_tpu.utils.hf_config_adapter import resolve_model_config
 
@@ -90,7 +91,8 @@ def main() -> int:
 
     params = jax.jit(lambda k: init_causal_lm(k, cfg)[0])(
         jax.random.key(a.seed))
-    sdpa = ({i: {"sdpa_fn": flash_sdpa} for i in range(cfg.num_hidden_layers)}
+    sdpa = ({i: LayerOps(sdpa=flash_sdpa)
+             for i in range(cfg.num_hidden_layers)}
             if dev.platform == "tpu" else None)
 
     def program_logits(p, run_cfg):

@@ -61,6 +61,7 @@ def main() -> int:
         forward_causal_lm,
         init_causal_lm,
     )
+    from hetu_galvatron_tpu.models.modules import LayerOps
     from hetu_galvatron_tpu.ops.pallas.flash_attention import flash_sdpa
     from hetu_galvatron_tpu.utils.hf_config_adapter import resolve_model_config
 
@@ -97,7 +98,7 @@ def main() -> int:
         jax.random.key(a.seed))
 
     def program_logits(p, run_cfg):
-        sdpa = ({i: {"sdpa_fn": flash_sdpa}
+        sdpa = ({i: LayerOps(sdpa=flash_sdpa)
                  for i in range(run_cfg.num_hidden_layers)}
                 if dev.platform == "tpu" else None)
 
