@@ -154,11 +154,47 @@ KDA_REASON = ("kda block: the delta-rule block's projections are not cut "
               "for the ring all-gather / reduce-scatter matmuls, and its "
               "recurrence runs over the whole sequence on one shard")
 
+WINDOW_REASON = ("a block with a window, query heads of its own or a gate a "
+                 "head attends through the XLA core or the Pallas flash "
+                 "kernels with its projections whole on a device: the ring "
+                 "and Ulysses cores take no window (a band over ring "
+                 "attention's block schedule is not written), and the tp "
+                 "interior, the ring all-gather / reduce-scatter matmuls and "
+                 "their group-major view of the fused qkv read one "
+                 "model-wide head count and no gate "
+                 "(eligibility.window_plan_reason)")
+
 # why a block whose mixer is not plain attention keeps its matmuls on GSPMD,
 # by mixer kind
 MIXER_OVERLAP_REASON = {"conv": CONV_REASON, "mamba": MAMBA_REASON,
                         "latent_attention": LATENT_REASON,
-                        "kda": KDA_REASON}
+                        "kda": KDA_REASON,
+                        "sliding_attention": WINDOW_REASON}
+
+# the fields of ``ModelArgs`` by which a block's attention differs from the
+# model-wide description: its window, its own query heads, its own rotation,
+# its gate
+BLOCK_ATTENTION_FIELDS = ("sliding_window", "num_attention_heads_per_layer",
+                          "rope_parameters", "gating")
+
+
+def block_attention_stated(cfg: Any) -> List[str]:
+    """``field=value`` of each of :data:`BLOCK_ATTENTION_FIELDS` the model
+    states (a window only where a block has one)."""
+    windowed = "sliding_attention" in (getattr(cfg, "layer_types", None)
+                                       or ())
+    return [f"{k}={getattr(cfg, k)}" for k in BLOCK_ATTENTION_FIELDS
+            if getattr(cfg, k, None) is not None
+            and (k != "sliding_window" or windowed)]
+
+
+def _cut_said(s: Any) -> str:
+    """``tp=2, cp=2 (Ulysses)``: the degrees above 1 by which a layer's
+    plan cuts heads or sequence; empty where it cuts neither."""
+    cut = [f"{axis}={deg}" for axis, deg in (
+        ("tp", s.tp_size), ("cp", s.cp_size)) if deg > 1]
+    return ", ".join(cut) + (
+        " (Ulysses)" if cut and s.sp and s.tp_size > 1 else "")
 
 
 def _uncut_mixer_reason(cfg: Any, layers: Any, mixer: str, name: str,
@@ -170,13 +206,10 @@ def _uncut_mixer_reason(cfg: Any, layers: Any, mixer: str, name: str,
     for i, (s, (kind, _)) in enumerate(zip(layers, kinds)):
         if kind != mixer:
             continue
-        cut = [f"{axis}={deg}" for axis, deg in (
-            ("tp", s.tp_size), ("cp", s.cp_size)) if deg > 1]
+        cut = _cut_said(s)
         if cut:
-            return (f"block {i} is a {mixer} block and its plan has "
-                    f"{', '.join(cut)}"
-                    + (" (Ulysses)" if s.sp and s.tp_size > 1 else "")
-                    + f": {name} runs with tp=1 and cp=1 ({why})")
+            return (f"block {i} is a {mixer} block and its plan has {cut}"
+                    f": {name} runs with tp=1 and cp=1 ({why})")
     return None
 
 
@@ -219,6 +252,26 @@ def kda_plan_reason(cfg: Any, layers: Any) -> Optional[str]:
         "its heads are not cut over the tp axis and its recurrence needs "
         "the whole sequence on one shard); use dp / ZeRO and ep for this "
         "model")
+
+
+def window_plan_reason(cfg: Any, layers: Any) -> Optional[str]:
+    """Why a plan cannot run this model's attention blocks; None when it
+    can, or the model states no window, no query heads of a block's own and
+    no gate. Such a block runs with tp = 1 and cp = 1: see
+    :data:`WINDOW_REASON`."""
+    stated = block_attention_stated(cfg)
+    if not stated:
+        return None
+    kinds = cfg.block_kinds(len(layers))
+    for i, (s, (kind, _)) in enumerate(zip(layers, kinds)):
+        if kind not in ("full_attention", "sliding_attention"):
+            continue
+        cut = _cut_said(s)
+        if cut:
+            return (f"block {i} ({kind}) of a model that states "
+                    f"{', '.join(stated)} has {cut} in its plan: "
+                    f"{WINDOW_REASON}; use dp / ZeRO and ep for this model")
+    return None
 
 
 def residual_streams_reason(cfg: Any, what: str) -> Optional[str]:
@@ -266,7 +319,13 @@ def mixed_stack_reason(cfg: Any, what: str, *,
     kinds = cfg.block_kinds()
     shapes = {m if feed_forward_may_differ else (m, ff) for m, ff in kinds}
     if len(shapes) <= 1 and all(m == "full_attention" for m, _ in kinds):
-        return None
+        stated = block_attention_stated(cfg)
+        if not stated:
+            return None
+        return (f"{what} builds every block from the model-wide head count "
+                "and rotation, with no window and no gate; this model "
+                f"states {', '.join(stated)}, which only the pp=1 training "
+                "path (builder.forward_causal_lm) gives each block")
     from collections import Counter
 
     said = ", ".join(f"{n} x {m}/{ff}"

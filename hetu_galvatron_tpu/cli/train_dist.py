@@ -183,13 +183,38 @@ def train(args) -> Dict[str, Any]:
         # "every core is flash" stays a statement about the blocks that do
         from hetu_galvatron_tpu.models.modules import MIXERS
 
+        from hetu_galvatron_tpu.ops.pallas.flash_attention import (
+            WINDOWED_CALLS,
+            band_tiles,
+            effective_window,
+        )
+
         kinds = cfg.block_kinds(len(hpc.layers))
+        # a window block's core carries its window, ``flash[w512]``; a
+        # window no shorter than the sequence is none
+        window = effective_window(cfg.sliding_window, cfg.seq_length)
         attention_cores = [
             MIXERS[mixer].logged or attention_core(
                 s.cp_size > 1, bool(s.sp and s.tp_size > 1), use_flash)
+            + (f"[w{window}]" if window and mixer == "sliding_attention"
+               else "")
             for s, (mixer, _) in zip(hpc.layers, kinds)]
         state.log("attention cores: " + ", ".join(
             f"{n} x {core}" for core, n in Counter(attention_cores).items()))
+        if "sliding_attention" in (cfg.layer_types or ()):
+            # each attending block's window (0 = the causal span) and query
+            # heads, as the configuration gives them
+            for i, (m, _) in enumerate(kinds):
+                if m not in ("full_attention", "sliding_attention"):
+                    continue
+                w = window if m == "sliding_attention" else None
+                get_registry().gauge("attn/window", layer=f"layer{i}").set(
+                    w or 0)
+                get_registry().gauge("attn/heads", layer=f"layer{i}").set(
+                    cfg.block_heads(i))
+        # the windowed flash calls this run's step is built of are recorded
+        # from here on (the step report reads them)
+        WINDOWED_CALLS.clear()
         # how many blocks of each mixer and feed-forward kind the step holds
         blocks = {}
         for (m, ff), n in Counter(kinds).items():
@@ -1169,6 +1194,23 @@ def train(args) -> Dict[str, Any]:
                                 "conv_kernel_calls"].items():
                             get_registry().gauge("conv/kernel_calls",
                                                  phase=part).set(v)
+                    if any(c.startswith("flash[w") for c in attention_cores):
+                        # the score tiles the flash kernels' loops visit in
+                        # the window blocks over those of the causal
+                        # triangle of the same calls, by the window and
+                        # tiles the calls were built with
+                        # (``flash_attention.WINDOWED_CALLS``); a window
+                        # block whose call carried no window ran the
+                        # triangle, masked or not: 100
+                        visited = triangle = 0
+                        for S, heads, bq, bk, w in WINDOWED_CALLS:
+                            v, t = band_tiles(S, bq, bk, w)
+                            visited += heads * v
+                            triangle += heads * t
+                        step_report["band_tiles_pct"] = (
+                            100.0 * visited / triangle if triangle else 100.0)
+                        get_registry().gauge("flash/band_tiles_pct").set(
+                            step_report["band_tiles_pct"])
                     step_report["static_memory"] = compiled_memory_bytes(
                         compiled)
                     for part, v in step_report["static_memory"].items():
@@ -1201,6 +1243,9 @@ def train(args) -> Dict[str, Any]:
                        "recompute {backward} backward".format(
                         **step_report["conv_kernel_calls"])
                        if "conv_kernel_calls" in step_report else "")
+                    + (", flash/band_tiles_pct "
+                       f"{step_report['band_tiles_pct']:.1f}"
+                       if "band_tiles_pct" in step_report else "")
                     + f", {step_report['cores_recomputed']} cores recomputed,"
                     f" static live peak "
                     f"{step_report['static_memory']['live_peak'] / 2**30:.3f}"
@@ -1283,6 +1328,11 @@ def train(args) -> Dict[str, Any]:
             # gauges conv/kernel_calls{phase=...}; zeros = the jax.numpy
             # form); None for a model no block of which convolves
             "conv_kernel_calls": step_report.get("conv_kernel_calls"),
+            # the score tiles the flash kernels visit in the window blocks
+            # over those of the causal triangle, percent, by the windows and
+            # tiles the step's flash calls were built with (the gauge
+            # flash/band_tiles_pct); None for a model without such a block
+            "band_tiles_pct": step_report.get("band_tiles_pct"),
             "exit_code": exit_code}
 
 

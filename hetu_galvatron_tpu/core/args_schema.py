@@ -119,8 +119,10 @@ class ModelArgs(BaseModel):
     # block_sparse_moe.gate.e_score_correction_bias and
     # block_sparse_moe.shared_experts.{gate,up,down}_proj: the two layouts
     # with a slot for a shared expert
+    # with a slot for a shared expert; "laguna" = olmoe's names and
+    # mlp.shared_expert.{gate,up,down}_proj (one shared expert, singular)
     moe_hf_layout: Literal["mixtral", "olmoe", "lfm2", "deepseek",
-                           "kimi"] = "mixtral"
+                           "kimi", "laguna"] = "mixtral"
     # RMSNorm over the WHOLE projected q and k widths (all heads together,
     # one learned scale each), after the qkv product and before the split
     # into heads and RoPE (OLMoE; HF ``self_attn.{q,k}_norm``)
@@ -136,13 +138,35 @@ class ModelArgs(BaseModel):
     # modules.apply_mamba2), "latent_attention" (DeepSeek-V2/V3's
     # low-rank q and kv projections, modules.apply_latent_attention) or
     # "kda" (Kimi Delta Attention: a gated delta rule with a decay a
-    # channel, modules.apply_kda); None = every block attends.
+    # channel, modules.apply_kda) or "sliding_attention" (attention over the
+    # ``sliding_window`` newest keys of the causal span); None = every block
+    # attends.
     # ``num_dense_layers``: so many leading blocks of an expert model keep
     # a dense MLP of ``ffn_hidden_size``
     layer_types: Optional[
         List[Literal["full_attention", "conv", "mamba",
-                     "latent_attention", "kda"]]] = None
+                     "latent_attention", "kda", "sliding_attention"]]] = None
     num_dense_layers: int = 0
+    # what a stack of window and full attention blocks publishes beside
+    # ``layer_types`` (HF ``LagunaConfig``). ``sliding_window``: the keys a
+    # query of a "sliding_attention" block meets at most, its own included.
+    # ``num_attention_heads_per_layer``: each block's query heads over the
+    # model's ``num_key_value_heads`` (None = ``num_attention_heads`` in
+    # every block; it needs ``head_dim_override``, a head's width being no
+    # quotient of one count). ``rope_parameters``: a rotation a mixer kind,
+    # {"full_attention": {...}, "sliding_attention": {...}}, each with its
+    # ``rope_theta``, a ``rope_scaling``'s keys (``rope_type`` "default" =
+    # none; "yarn" with the ``attention_factor`` that scales cos and sin
+    # stated) and ``partial_rotary_factor``, the leading share of a head
+    # that is rotated (the rest passes through); a kind without an entry
+    # takes ``rope_theta`` / ``rope_scaling`` over the whole head.
+    # ``gating`` "per-head": a sigmoid gate a query head on the attention
+    # core's output, from the block's normed input (arXiv:2505.06708's
+    # head-wise form)
+    sliding_window: Optional[int] = None
+    num_attention_heads_per_layer: Optional[List[int]] = None
+    rope_parameters: Optional[Dict[str, Dict[str, Any]]] = None
+    gating: Optional[Literal["per-head"]] = None
     conv_L_cache: int = 3   # taps of a conv block's depthwise convolution
     conv_bias: bool = False
     # router scores: softmax over the experts (Mixtral, OLMoE), or a sigmoid
@@ -230,11 +254,80 @@ class ModelArgs(BaseModel):
     num_nextn_predict_layers: int = 0
     mtp_loss_coeff: float = 0.3
 
+    @model_validator(mode="after")
+    def _check_attention_kinds(self):
+        kinds = self.layer_types or []
+        if "sliding_attention" in kinds and not (
+                self.sliding_window and self.sliding_window > 0):
+            raise ValueError(
+                "model.layer_types names sliding_attention blocks: "
+                "model.sliding_window (the keys a query meets, its own "
+                f"included) must be positive, got {self.sliding_window!r}")
+        heads = self.num_attention_heads_per_layer
+        if heads is not None:
+            if len(heads) != self.num_hidden_layers:
+                raise ValueError(
+                    f"model.num_attention_heads_per_layer names {len(heads)} "
+                    f"blocks and the stack has {self.num_hidden_layers}")
+            if self.head_dim_override is None:
+                raise ValueError(
+                    "model.num_attention_heads_per_layer needs "
+                    "model.head_dim_override: a head's width is no quotient "
+                    "of a head count that differs by block")
+            bad = [n for n in heads if n < 1 or n % self.kv_heads]
+            if bad:
+                raise ValueError(
+                    f"model.num_attention_heads_per_layer: {bad} are no "
+                    f"multiples of the {self.kv_heads} key-value heads")
+        for kind, entry in (self.rope_parameters or {}).items():
+            if kind not in ("full_attention", "sliding_attention"):
+                raise ValueError(
+                    f"model.rope_parameters[{kind!r}]: a rotation of its "
+                    "own is written for full_attention and "
+                    "sliding_attention blocks")
+            share = float(entry.get("partial_rotary_factor", 1.0))
+            if not 0.0 < share <= 1.0 or (self.head_dim * share) % 2:
+                raise ValueError(
+                    f"model.rope_parameters[{kind!r}].partial_rotary_factor "
+                    f"{share}: an even share of a head of {self.head_dim}")
+        return self
+
+    def block_heads(self, i: int) -> int:
+        """Query heads of block ``i``."""
+        heads = self.num_attention_heads_per_layer
+        return self.num_attention_heads if heads is None else heads[i]
+
+    def for_block(self, i: int) -> "ModelArgs":
+        """The model's arguments as block ``i`` reads them: its own query
+        heads as ``num_attention_heads``. The model's own where no block
+        differs."""
+        if self.num_attention_heads_per_layer is None:
+            return self
+        return self.model_copy(
+            update={"num_attention_heads": self.block_heads(i)})
+
+    def rope_of(self, kind: Optional[str]
+                ) -> Tuple[float, Optional[Dict[str, Any]], int]:
+        """(theta, rope_scaling, rotated width) of the blocks of mixer
+        ``kind``: ``rope_parameters[kind]`` where the model states one,
+        else (and for ``None``) the model's ``rope_theta`` and
+        ``rope_scaling`` over ``rope_dim``."""
+        entry = (self.rope_parameters or {}).get(kind)
+        if entry is None:
+            return self.rope_theta, self.rope_scaling, self.rope_dim
+        scaling = {k: v for k, v in entry.items()
+                   if k not in ("rope_theta", "partial_rotary_factor")}
+        if scaling.get("rope_type", "default") == "default":
+            scaling = None
+        return (float(entry.get("rope_theta", self.rope_theta)), scaling,
+                int(self.head_dim
+                    * float(entry.get("partial_rotary_factor", 1.0))))
+
     def block_kinds(self, n: Optional[int] = None
                     ) -> Tuple[Tuple[str, str], ...]:
         """The one per-layer description of a decoder stack: for each block
         its mixer kind ("full_attention", "conv", "mamba",
-        "latent_attention", "kda") and its
+        "latent_attention", "kda", "sliding_attention") and its
         feed-forward kind ("dense", "experts"). The builder, the exporter, the launcher's
         report and every engine's refusal read this and nothing else.
         ``n``: the blocks a plan lists where that is not

@@ -907,10 +907,17 @@ def model_flops_per_token(model: Any, seq_length: Optional[int] = None
     """
     h = model.hidden_size
     s = seq_length or model.seq_length
-    nd = model.num_attention_heads * model.head_dim
     kd = model.kv_heads * model.head_dim
-    # q/k/v/out projections + the two [S, S] batched matmuls (QK^T, PV)
-    attn = 2 * h * nd + 2 * 2 * h * kd + 2 * nd * h + 2 * 2 * s * nd
+
+    def attention(nq: int, span: int) -> float:
+        # q/k/v/out projections + the two batched matmuls (QK^T, PV) over
+        # the ``span`` keys a query meets (a window block's band, else the
+        # whole [S, S]) + a gate a head where the model has one
+        nd = nq * model.head_dim
+        return (2 * h * nd + 2 * 2 * h * kd + 2 * nd * h + 2 * 2 * span * nd
+                + (2 * h * nq if getattr(model, "gating", None) else 0))
+
+    attn = attention(model.num_attention_heads, s)
     gated = model.hidden_act in ("swiglu", "geglu")
 
     def mlp_flops(ffn: int) -> float:
@@ -972,9 +979,20 @@ def model_flops_per_token(model: Any, seq_length: Optional[int] = None
     # a block's two residual maps over several streams (phi products)
     maps = (2 * 2 * streams * h * (2 * streams + streams * streams)
             if streams > 1 else 0)
-    per_block = [mixer[m] + maps
+    # an attention block at its own query heads, a window block over its band
+    own_heads = getattr(model, "num_attention_heads_per_layer", None)
+    band = min(s, getattr(model, "sliding_window", None) or s)
+
+    def mixer_flops(i: int, m: str) -> float:
+        if m == "sliding_attention" or (m == "full_attention" and own_heads):
+            return attention(
+                own_heads[i] if own_heads else model.num_attention_heads,
+                band if m == "sliding_attention" else s)
+        return mixer[m]
+
+    per_block = [mixer_flops(i, m) + maps
                  + (experts_ff if ff == "experts" else dense_ff)
-                 for m, ff in kinds]
+                 for i, (m, ff) in enumerate(kinds)]
     head = 2 * h * model.padded_vocab_size  # LM head
     fwd = sum(per_block) + head
     if getattr(model, "num_nextn_predict_layers", 0):

@@ -65,7 +65,7 @@ def init_causal_lm(key: jax.Array, cfg: ModelArgs) -> Tuple[Params, Params]:
     n = cfg.num_hidden_layers
     keys = jax.random.split(key, n + 2)
     embed_p, embed_a = M.init_embedding(keys[0], cfg)
-    layers = [init_block(keys[1 + i], cfg, kind)
+    layers = [init_block(keys[1 + i], cfg.for_block(i), kind)
               for i, kind in enumerate(cfg.block_kinds())]
     if cfg.post_norm:
         # post-norm families (bert) end each block already normalized; the
@@ -115,7 +115,8 @@ def init_mtp(key: jax.Array, cfg: ModelArgs) -> Tuple[Params, Params]:
         raise NotImplementedError(
             "multi-token prediction is written for pre-norm causal stacks")
     k1, k2 = jax.random.split(key)
-    lp, la = init_block(k2, cfg, mtp_block_kind(cfg))
+    lp, la = init_block(k2, cfg.for_block(cfg.num_hidden_layers - 1),
+                        mtp_block_kind(cfg))
     norms = [M.init_norm(cfg) for _ in range(3)]
     h = cfg.hidden_size
     return (
@@ -125,6 +126,22 @@ def init_mtp(key: jax.Array, cfg: ModelArgs) -> Tuple[Params, Params]:
         {"enorm": norms[0][1], "hnorm": norms[1][1],
          "eh_proj": ("mtp_in", "embed"), "layer": la, "norm": norms[2][1]},
     )
+
+
+def rope_table(cfg: ModelArgs, seq: int, kind: Optional[str],
+               position_ids: Optional[jax.Array] = None
+               ) -> Tuple[jax.Array, jax.Array]:
+    """cos and sin of the blocks of mixer ``kind`` (``None``: the model's
+    one rotation), ``[seq, width / 2]`` or, gathered by a packed sample's
+    ``position_ids``, ``[B, seq, width / 2]``; ``width`` is the share of a
+    head the kind rotates (``ModelArgs.rope_of``)."""
+    theta, scaling, width = cfg.rope_of(kind)
+    with jax.named_scope("attn/rope"):
+        cos, sin = M.rope_cos_sin(seq, width, theta, scaling=scaling)
+    if position_ids is not None:
+        # packed samples: gather per-token rows -> [B, S, D/2]
+        cos, sin = cos[position_ids], sin[position_ids]
+    return cos, sin
 
 
 def make_block(cfg: ModelArgs, kind: Tuple[str, str],
@@ -200,13 +217,7 @@ def forward_causal_lm(
                                sections=cfg.mrope_section,
                                scaling=cfg.rope_scaling)
     elif cfg.position_embedding_type == "rope":
-        with jax.named_scope("attn/rope"):
-            cos, sin = M.rope_cos_sin(S, cfg.rope_dim, cfg.rope_theta,
-                                      scaling=cfg.rope_scaling)
-        if position_ids is not None:
-            # packed samples: gather per-token rows -> [B, S, D/2]
-            cos, sin = cos[position_ids], sin[position_ids]
-        rope = (cos, sin)
+        rope = rope_table(cfg, S, None, position_ids)
     x = M.streams_in(M.apply_embedding(
         params["embed"], tokens, cfg, compute_dtype=compute_dtype,
         dropout_rng=M.fold_dropout_rng(dropout_rng, cfg,
@@ -215,6 +226,13 @@ def forward_causal_lm(
     aux_total = jnp.zeros((), jnp.float32)
     moe_stats: Dict[str, Dict[str, jax.Array]] = {}
     kinds = cfg.block_kinds()
+    # a table a mixer kind where the model states a rotation a kind
+    ropes = {m: rope_table(cfg, S, m, position_ids)
+             for m in {m for m, _ in kinds} & set(cfg.rope_parameters or {})}
+    if ropes and cfg.mrope_section:
+        raise NotImplementedError(
+            "model.rope_parameters (a rotation a mixer kind) with "
+            "model.mrope_section: multimodal tables are one a model")
     if len(kinds) != len(params["layers"]):
         raise ValueError(
             f"the parameters hold {len(params['layers'])} blocks and the "
@@ -223,14 +241,15 @@ def forward_causal_lm(
         if boundary_fn is not None:
             x = boundary_fn(i, x)
         kwargs: Dict[str, Any] = dict(
-            rope=rope, compute_dtype=compute_dtype, mixer=kinds[i][0],
+            rope=ropes.get(kinds[i][0], rope), compute_dtype=compute_dtype,
+            mixer=kinds[i][0],
             ops=(layer_overrides or {}).get(i, M.LayerOps()))
         if segment_ids is not None:
             kwargs["segment_ids"] = segment_ids
         if dropout_rng is not None:
             kwargs["dropout_rng"] = M.fold_dropout_rng(dropout_rng, cfg, i)
         x, aux, stats = make_block(
-            cfg, kinds[i], kwargs,
+            cfg.for_block(i), kinds[i], kwargs,
             remat_flags is not None and bool(remat_flags[i]))(lp, x)
         aux_total = aux_total + aux
         if stats:
@@ -248,7 +267,7 @@ def forward_causal_lm(
                 dropout_rng, cfg, len(params["layers"]))
         mtp_logits, aux, stats = forward_mtp(
             params, x, mtp_labels, cfg, block_fn=make_block(
-                cfg, mtp_block_kind(cfg), kwargs,
+                cfg.for_block(len(kinds) - 1), mtp_block_kind(cfg), kwargs,
                 remat_flags is not None and bool(remat_flags[-1])),
             compute_dtype=compute_dtype)
         aux_total = aux_total + aux
@@ -377,10 +396,16 @@ def model_flops_per_token(cfg: ModelArgs, seq_len: Optional[int] = None) -> floa
     used by the MFU computation in bench/profilers."""
     s = seq_len or cfg.seq_length
     h, f, v = cfg.hidden_size, cfg.ffn_dim, cfg.padded_vocab_size
-    nq, nkv, hd = cfg.num_attention_heads, cfg.kv_heads, cfg.head_dim
-    per_layer = 2 * h * (nq + 2 * nkv) * hd  # qkv
-    per_layer += 2 * nq * hd * h  # proj
-    per_layer += 2 * h * f * (3 if M._is_gated(cfg.hidden_act) else 2)  # mlp
-    attn = 2 * 2 * s * nq * hd  # qk^T + pv per token
-    dense = cfg.num_hidden_layers * (per_layer + attn) + 2 * h * v
+    nkv, hd = cfg.kv_heads, cfg.head_dim
+    mlp = 2 * h * f * (3 if M._is_gated(cfg.hidden_act) else 2)
+    dense = 2 * h * v
+    for i, (mixer, _) in enumerate(cfg.block_kinds()):
+        nq = cfg.block_heads(i)
+        # a window block's queries meet its band, the others the sequence
+        span = (min(s, cfg.sliding_window) if mixer == "sliding_attention"
+                else s)
+        dense += 2 * h * (nq + 2 * nkv) * hd  # qkv
+        dense += 2 * nq * hd * h  # proj
+        dense += 2 * 2 * span * nq * hd  # qk^T + pv per token
+        dense += mlp
     return 3.0 * dense  # fwd + bwd(2x)

@@ -212,17 +212,21 @@ def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
 
 
 def rope_attention_factor(scaling: Optional[dict]) -> float:
-    """What "yarn" multiplies cos and sin by: ``mscale`` over
-    ``mscale_all_dim`` (DeepSeek's form, the one a configuration here
-    states; 1 where they are equal); 1 for every other scaling."""
+    """What "yarn" multiplies cos and sin by: the ``attention_factor`` a
+    configuration states, else ``mscale`` over ``mscale_all_dim``
+    (DeepSeek's form; 1 where they are equal); 1 for every other scaling."""
     if not scaling or scaling.get(
             "rope_type", scaling.get("type")) != "yarn":
         return 1.0
+    if scaling.get("attention_factor") is not None:
+        # stated by the configuration (transformers' yarn takes it before
+        # anything it would compute)
+        return float(scaling["attention_factor"])
     m, m_all = scaling.get("mscale"), scaling.get("mscale_all_dim")
     if not m or not m_all:
         raise ValueError(
-            "rope_scaling type yarn: mscale and mscale_all_dim are both "
-            f"required (got {scaling!r})")
+            "rope_scaling type yarn: attention_factor, or mscale and "
+            f"mscale_all_dim both, are required (got {scaling!r})")
     factor = float(scaling.get("factor", 1.0))
     return yarn_mscale(factor, float(m)) / yarn_mscale(factor, float(m_all))
 
@@ -284,7 +288,13 @@ def mrope_cos_sin(
 def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
     """x: [B, S, N, D]; rotate-half convention (llama-style). cos/sin are
     [S, D/2] (positions in order) or [B, S, D/2] (gathered per-token
-    position ids — packed samples with reset_position_ids)."""
+    position ids — packed samples with reset_position_ids). Tables
+    narrower than D/2 rotate the leading ``2 * width`` values of a head and
+    pass the rest through (a partial rotary factor)."""
+    rot = 2 * cos.shape[-1]
+    if rot < x.shape[-1]:
+        return jnp.concatenate(
+            [apply_rope(x[..., :rot], cos, sin), x[..., rot:]], axis=-1)
     d2 = x.shape[-1] // 2
     x1, x2 = x[..., :d2], x[..., d2:]
     if cos.ndim == 2:
@@ -321,6 +331,13 @@ def init_attention(key: jax.Array, cfg: ModelArgs) -> Tuple[Params, Axes]:
     if cfg.add_bias_linear:
         p["bo"] = jnp.zeros((h,), jnp.float32)
         a["bo"] = ("embed",)
+    if cfg.gating:
+        # a logit a query head from the block's normed input; a leaf of its
+        # own, replicated (a model with a gate runs with tp = 1,
+        # eligibility.window_plan_reason): inside ``wqkv`` its nq columns
+        # would end the fused product off a lane tile
+        p["wg"] = _normal(jax.random.fold_in(key, 2), (h, nq), std)
+        a["wg"] = ("embed", "attn_gate")
     if cfg.qk_norm:
         if cfg.normalization != "rmsnorm" or cfg.norm_zero_centered:
             raise ValueError("model.qk_norm is an RMSNorm with plain scales "
@@ -405,6 +422,7 @@ def xla_sdpa(
     dropout_rate: float = 0.0, dropout_rng: Optional[jax.Array] = None,
     segment_ids: Optional[jax.Array] = None,
     scale: Optional[float] = None,
+    window: Optional[int] = None,
 ) -> jax.Array:
     """Reference attention core on XLA: [B,S,N,D] x [B,T,K,D] -> [B,S,N,D]
     (v may have a width of its own: [B,T,K,Dv] -> [B,S,N,Dv]).
@@ -420,7 +438,11 @@ def xla_sdpa(
     get_ltor_masks_and_position_ids).
     ``scale``: softmax(scale * q k^T) where the model states its own
     (``ModelArgs.attention_multiplier``); ``None`` divides by sqrt(D).
+    ``window``: a query meets the ``window`` newest keys of its causal span,
+    its own included (a block of sliding-window attention).
     """
+    if window is not None and not causal:
+        raise ValueError("a window is a part of the causal span")
     B, S, N, D = q.shape
     K = k.shape[2]
     G = N // K
@@ -433,7 +455,10 @@ def xla_sdpa(
         # queries own absolute positions [T-S, T): supports S<T (inference)
         qpos = jnp.arange(S)[:, None] + (k.shape[1] - S)
         kpos = jnp.arange(k.shape[1])[None, :]
-        scores = jnp.where(qpos >= kpos, scores, jnp.finfo(jnp.float32).min)
+        seen = qpos >= kpos
+        if window is not None:
+            seen &= qpos - kpos < window
+        scores = jnp.where(seen, scores, jnp.finfo(jnp.float32).min)
     if segment_ids is not None:
         if k.shape[1] != S:
             raise ValueError("segment_ids require self-attention (S == T)")
@@ -474,8 +499,16 @@ def apply_attention(
     segment_ids: Optional[jax.Array] = None,
     matmul_fns: Optional[Dict[str, Callable]] = None,
     shard_fn: Optional[Callable[[jax.Array, int], jax.Array]] = None,
+    windowed: bool = False,
 ) -> jax.Array:
-    """``shard_fn(a, axis)`` (a layer whose plan has tp > 1,
+    """``cfg`` is the block's (``ModelArgs.for_block``: its own query
+    heads). ``windowed`` (a "sliding_attention" block): the core attends
+    over the ``cfg.sliding_window`` newest keys of the causal span, under
+    the named scope ``attn/window_core``. A model with ``cfg.gating``
+    multiplies each head's output by the sigmoid of the block's ``wg``
+    logit for it, under ``attn/gate``.
+
+    ``shard_fn(a, axis)`` (a layer whose plan has tp > 1,
     parallel/spmd.py::interior_sharding) pins an interior activation to
     the layer's own shards, dimension ``axis`` on its tp axes. The layer it
     is given to is given ``wqkv`` / ``bqkv`` as :func:`qkv_group_major`'s
@@ -528,9 +561,29 @@ def apply_attention(
     core_kwargs: Dict[str, Any] = {}
 
     def core(*a, **kw):
+        if windowed:
+            with jax.named_scope("attn/window_core"):
+                return sdpa_fn(*a, **kw)
         with jax.named_scope("attn/core"):
             return sdpa_fn(*a, **kw)
 
+    if (windowed or "wg" in p
+            or cfg.num_attention_heads_per_layer is not None):
+        from hetu_galvatron_tpu.analysis.eligibility import WINDOW_REASON
+
+        if group_major or mm:
+            raise NotImplementedError(WINDOW_REASON)
+    if windowed:
+        # the window is an argument of the core; a core without it would
+        # attend over the whole causal span in silence
+        if not (sdpa_fn is xla_sdpa
+                or getattr(sdpa_fn, "supports_window", False)):
+            raise NotImplementedError(WINDOW_REASON)
+        if not causal:
+            raise NotImplementedError(
+                "a sliding_attention block attends over a part of the "
+                "causal span; an encoder stack has none")
+        core_kwargs["window"] = int(cfg.sliding_window)
     if cfg.attention_multiplier is not None:
         # the model's own softmax scale is an argument of the core; a core
         # without the argument would attend at 1/sqrt(D) in silence
@@ -580,6 +633,12 @@ def apply_attention(
                 "data.reset_attention_mask=false")
     else:
         out = core(q, k, v, causal=causal, **core_kwargs)
+    if "wg" in p:
+        with jax.named_scope("attn/gate"):
+            gate = jnp.einsum("bsh,hn->bsn", x.astype(compute_dtype),
+                              weight_view(p["wg"], compute_dtype),
+                              preferred_element_type=jnp.float32)
+            out = out * jax.nn.sigmoid(gate).astype(compute_dtype)[..., None]
     with jax.named_scope("attn/out_proj"):
         out = out.reshape(B, S, nq * hd)
         if group_major:
@@ -1713,6 +1772,9 @@ MIXERS: Dict[str, Mixer] = {
         "kda", init_kda, apply_kda, False,
         {"kda_fn": "kda", "conv_fn": "conv"}, "kda",
         uncut_reason="kda_plan_reason"),
+    "sliding_attention": Mixer(
+        "attn", init_attention, partial(apply_attention, windowed=True),
+        True, {"sdpa_fn": "sdpa"}, uncut_reason="window_plan_reason"),
 }
 
 

@@ -470,7 +470,8 @@ COLLECTIVE_OPS = ("all-to-all", "all-gather", "all-reduce", "reduce-scatter",
 # literal of those files in it.
 SCOPES: Tuple[str, ...] = (
     "embed", "norm", "attn/qkv_proj", "attn/qk_norm", "attn/rope",
-    "attn/core", "attn/out_proj", "attn/latent_proj", "hc/maps", "hc/mix",
+    "attn/core", "attn/window_core", "attn/gate", "attn/out_proj",
+    "attn/latent_proj", "hc/maps", "hc/mix",
     "mtp/embed_proj", "mtp/block", "mtp/head",
     "mlp", "head", "param_view",
     "grad/accumulate", "grad/clip", "optimizer/update",

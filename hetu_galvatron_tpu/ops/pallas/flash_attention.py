@@ -138,7 +138,7 @@ def _dot(a, b, dims):
 
 
 def _scores(q, k, q0, k0, qseg, kseg, *, masked: bool, scale: float,
-            k_rows: bool = False):
+            k_rows: bool = False, window: "int | None" = None):
     """f32 score tile q.k^T * scale (k.q^T, keys along rows, with
     ``k_rows``) with the causal mask (only where the caller says the tile
     crosses the diagonal) and the segment mask (on every tile it is given
@@ -146,12 +146,16 @@ def _scores(q, k, q0, k0, qseg, kseg, *, masked: bool, scale: float,
     score is NEG_INF: once a query has seen one finite score its masked
     entries give exp(NEG_INF - m) = 0, and whatever it gathered while all
     it had seen was masked is wiped by the rescale exp(NEG_INF - m) = 0 at
-    its first finite score; causal and segment masks both leave every
-    query its own position."""
+    its first finite score; causal, band and segment masks all leave every
+    query its own position. With ``window`` a masked tile also loses the
+    keys at or beyond ``window`` positions before the query."""
     s = (_dot(k, q, _NT) if k_rows else _dot(q, k, _NT)) * scale
     if masked:
         qpos, kpos = _tile_pos(q0, k0, s.shape, int(k_rows))
-        s = jnp.where(qpos >= kpos, s, NEG_INF)
+        seen = qpos >= kpos
+        if window is not None:
+            seen &= qpos - kpos < window
+        s = jnp.where(seen, s, NEG_INF)
     if qseg is not None:
         s = jnp.where(qseg == kseg, s, NEG_INF)
     return s
@@ -181,14 +185,41 @@ def _for(lo, hi, body):
     jax.lax.fori_loop(lo, hi, lambda c, _: body(c), None)
 
 
+def _for_banded(chunk, lo, chunks: int, first, clear_from, clear_to, end):
+    """``chunk(c, masked)`` over the chunks [first, end) of a band, global
+    indices, of which those in [clear_from, clear_to) no edge of the band
+    cuts: the cut ones before them with the mask, they without, the cut
+    ones after them with it. ``lo`` is the global index of the grid step's
+    first chunk and ``chunks`` how many it holds; an empty middle leaves
+    every chunk masked."""
+    a = jnp.clip(first - lo, 0, chunks)
+    d = jnp.clip(end - lo, 0, chunks)
+    b = jnp.clip(clear_from - lo, a, d)
+    c = jnp.clip(clear_to - lo, b, d)
+    _for(a, b, lambda i: chunk(i, True))
+    _for(b, c, lambda i: chunk(i, False))
+    _for(c, d, lambda i: chunk(i, True))
+
+
 def _for_k_chunks(chunk, q0, block_q: int, block_k: int, lo, chunks: int,
-                  causal: bool):
+                  causal: bool, window: "int | None" = None):
     """``chunk(c, masked)`` over those of the ``chunks`` k chunks from global
     chunk ``lo`` on that the q rows [q0, q0 + block_q) see: first the ones
     wholly at or below the diagonal, without the mask, then the ones that
-    cross it, with it; all of them, unmasked, when not causal."""
+    cross it, with it; all of them, unmasked, when not causal. With a
+    ``window`` the range starts at the chunk that holds the oldest key the
+    tile's first query meets, and the chunks the band's lower edge cuts are
+    masked as well."""
     if not causal:
         _for(0, chunks, lambda c: chunk(c, False))
+        return
+    if window is not None:
+        _for_banded(
+            chunk, lo, chunks,
+            jnp.maximum(q0 - window + 1, 0) // block_k,
+            # the first chunk the tile's LAST query still holds whole
+            (jnp.maximum(q0 + block_q - window, 0) + block_k - 1) // block_k,
+            (q0 + 1) // block_k, (q0 + block_q - 1) // block_k + 1)
         return
     full = jnp.clip((q0 + 1) // block_k - lo, 0, chunks)
     some = jnp.clip((q0 + block_q - 1) // block_k + 1 - lo, 0, chunks)
@@ -196,9 +227,15 @@ def _for_k_chunks(chunk, q0, block_q: int, block_k: int, lo, chunks: int,
     _for(full, some, lambda c: chunk(c, True))
 
 
-def _needed_k_major(qi, kj, block_q: int, major: int, causal: bool):
+def _needed_k_major(qi, kj, block_q: int, major: int, causal: bool,
+                    window: "int | None" = None):
     """Index map of a k major block: one wholly past the diagonal repeats
-    the last that is needed, and an unchanged block index elides the copy."""
+    the last that is needed, and an unchanged block index elides the copy;
+    with a ``window`` one wholly before the band repeats the first."""
+    if causal and window is not None:
+        return jnp.clip(
+            kj, jnp.maximum(qi * block_q - window + 1, 0) // major,
+            (qi * block_q + block_q - 1) // major)
     if causal:
         return jnp.minimum(kj, (qi * block_q + block_q - 1) // major)
     return kj
@@ -207,7 +244,7 @@ def _needed_k_major(qi, kj, block_q: int, major: int, causal: bool):
 def _flash_kernel(q_ref, k_ref, v_ref, *rest,
                   block_q: int, block_k: int, chunks: int, num_major: int,
                   causal: bool, scale: float, has_seg: bool = False,
-                  dropout_rate: float = 0.0):
+                  dropout_rate: float = 0.0, window: "int | None" = None):
     """Grid (B, N, q block, k major block). One step holds a q tile and
     ``chunks`` k/v chunks of ``block_k`` rows and loops over the chunks the
     causal mask leaves, so a step past the diagonal neither fetches nor
@@ -242,7 +279,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, *rest,
         s = _scores(q_ref[0, 0], k_ref[0, 0, rows, :], q0, k0,
                     qseg_ref[0] if has_seg else None,
                     kseg_ref[0, c] if has_seg else None,
-                    masked=masked, scale=scale)
+                    masked=masked, scale=scale, window=window)
         # the running max is kept replicated along LANES lanes, so it
         # meets the score tile and the accumulator without a relayout; the
         # cross-lane max is the one reduction a chunk pays
@@ -261,7 +298,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, *rest,
         acc_ref[...] = (acc_ref[...] * _across(corr, acc_ref.shape[1])
                         + _dot(p.astype(v.dtype), v, _NN))
 
-    _for_k_chunks(chunk, q0, block_q, block_k, lo, chunks, causal)
+    _for_k_chunks(chunk, q0, block_q, block_k, lo, chunks, causal, window)
 
     @pl.when(kj == num_major - 1)
     def _finalize():
@@ -290,7 +327,11 @@ def _major_chunks(seq: int, block: int, row_bytes: int) -> int:
 
 
 def _check_call(S, Sk, block_q, block_k, causal, segments, dropout_rate,
-                dropout_seed):
+                dropout_seed, window=None):
+    if window is not None and (not causal or window < 1):
+        raise ValueError(
+            f"a window ({window}) is so many keys of the causal span, the "
+            "query's own included: it needs causal=True and at least 1")
     if S % block_q or Sk % block_k:
         raise ValueError(
             f"seq {S}/{Sk} must divide by blocks {block_q}/{block_k}")
@@ -320,7 +361,7 @@ def _segment_operands(segments, block: int):
 
 @functools.partial(jax.jit, static_argnames=("causal", "block_q", "block_k",
                                              "interpret", "dropout_rate",
-                                             "scale"))
+                                             "scale", "window"))
 def flash_attention_hmajor(
     q: jax.Array,  # [B, N, S, D]
     k: jax.Array,  # [B, K, S, D]
@@ -334,6 +375,7 @@ def flash_attention_hmajor(
     interpret: bool = False,
     dropout_rate: float = 0.0,
     scale: "float | None" = None,  # softmax(scale * q.k^T); None = D ** -0.5
+    window: "int | None" = None,  # keys a query meets at most, itself included
 ) -> jax.Array:
     B, N, S, D = q.shape
     Dv = v.shape[3]
@@ -343,7 +385,7 @@ def flash_attention_hmajor(
     block_q = min(block_q, S)
     block_k = min(block_k, Sk)
     _check_call(S, Sk, block_q, block_k, causal, segments, dropout_rate,
-                dropout_seed)
+                dropout_seed, window)
     chunks = _major_chunks(Sk, block_k, max(D, Dv) * k.dtype.itemsize)
     major = chunks * block_k
     num_major = Sk // major
@@ -353,10 +395,10 @@ def flash_attention_hmajor(
         _flash_kernel, block_q=block_q, block_k=block_k, chunks=chunks,
         num_major=num_major, causal=causal,
         scale=1.0 / math.sqrt(D) if scale is None else scale,
-        has_seg=has_seg, dropout_rate=dropout_rate)
+        has_seg=has_seg, dropout_rate=dropout_rate, window=window)
 
     def kj_of(qi, kj):
-        return _needed_k_major(qi, kj, block_q, major, causal)
+        return _needed_k_major(qi, kj, block_q, major, causal, window)
 
     in_specs = [
         pl.BlockSpec((1, 1, block_q, D),
@@ -412,14 +454,14 @@ def flash_attention_hmajor(
 
 def _p_and_ds(q, k, v, do, lse, delta, q0, k0, qseg, kseg, seed_ref, bn, *,
               masked: bool, scale: float, dropout_rate: float,
-              k_rows: bool = False):
+              k_rows: bool = False, window: "int | None" = None):
     """What both backward kernels recompute for one score tile from the
     saved logsumexp: p (dropped and rescaled where dropout is on, as the
     forward fed it to p.v) and ds = p * (dp - delta) * scale, both f32.
     With ``k_rows`` the tile is [k, q] and lse / delta / qseg are rows
     (1, block_q), else [q, k] and they are columns (block_q, 1)."""
     s = _scores(q, k, q0, k0, qseg, kseg, masked=masked, scale=scale,
-                k_rows=k_rows)
+                k_rows=k_rows, window=window)
     p = jnp.exp(s - lse)
     dp = _dot(v, do, _NT) if k_rows else _dot(do, v, _NT)
     pd = p
@@ -438,11 +480,14 @@ def _flash_bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                            *rest, block_q: int, block_k: int, chunks: int,
                            num_major: int, G: int, causal: bool,
                            scale: float, has_seg: bool = False,
-                           dropout_rate: float = 0.0):
+                           dropout_rate: float = 0.0,
+                           window: "int | None" = None):
     """Grid (B, KV, k block, G, q major block): accumulate dk/dv for one k/v
     tile across the G query heads of this kv head and all q rows; one step
     holds ``chunks`` q chunks and loops over those at or below the
-    diagonal, masking only the ones that cross it."""
+    diagonal, masking only the ones that cross it. With a ``window`` the
+    loop ends at the last q chunk that still meets the tile's last key, and
+    the chunks the band's lower edge cuts are masked as well."""
     if dropout_rate > 0.0:
         seed_ref, rest = rest[0], rest[1:]
     else:
@@ -476,11 +521,21 @@ def _flash_bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             qseg_ref[0, c] if has_seg else None,
             kseg_ref[0] if has_seg else None, seed_ref, bn,
             masked=masked, scale=scale, dropout_rate=dropout_rate,
-            k_rows=True)
+            k_rows=True, window=window)
         dv_acc[...] += _dot(pd.astype(do.dtype), do, _NN)
         dk_acc[...] += _dot(ds.astype(q.dtype), q, _NN)
 
-    if causal:
+    if causal and window is not None:
+        # from the first q chunk with a visible row to the last that meets
+        # the tile's last key; clear of the diagonal from the chunk below
+        # it, and of the band's edge while the chunk's last query still
+        # meets the tile's first key
+        _for_banded(
+            chunk, lo, chunks, k0 // block_q,
+            (k0 + block_k + block_q - 2) // block_q,
+            (k0 + window) // block_q,
+            (k0 + block_k + window - 2) // block_q + 1)
+    elif causal:
         # q chunks from the first with a visible row; those from ``clear``
         # on lie wholly at or below the diagonal
         first = jnp.clip(k0 // block_q - lo, 0, chunks)
@@ -500,7 +555,8 @@ def _flash_bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, o_ref,
                          *rest, block_q: int, block_k: int, chunks: int,
                          num_major: int, causal: bool, scale: float,
-                         has_seg: bool = False, dropout_rate: float = 0.0):
+                         has_seg: bool = False, dropout_rate: float = 0.0,
+                         window: "int | None" = None):
     """Grid (B, N, q block, k major block): accumulate dq for one q tile
     over the k chunks the causal mask leaves (the forward's loop); ``o_ref``
     is the forward's output tile, for delta."""
@@ -536,10 +592,11 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, o_ref,
             lse_ref[0, 0], delta, q0, (lo + c) * block_k,
             qseg_ref[0] if has_seg else None,
             kseg_ref[0, c] if has_seg else None, seed_ref, bn,
-            masked=masked, scale=scale, dropout_rate=dropout_rate)
+            masked=masked, scale=scale, dropout_rate=dropout_rate,
+            window=window)
         dq_acc[...] += _dot(ds.astype(k.dtype), k, _NN)
 
-    _for_k_chunks(chunk, q0, block_q, block_k, lo, chunks, causal)
+    _for_k_chunks(chunk, q0, block_q, block_k, lo, chunks, causal, window)
 
     @pl.when(kj == num_major - 1)
     def _finalize():
@@ -548,7 +605,7 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, o_ref,
 
 @functools.partial(jax.jit, static_argnames=("causal", "block_q", "block_k",
                                              "interpret", "dropout_rate",
-                                             "scale"))
+                                             "scale", "window"))
 def flash_attention_bwd_hmajor(
     q, k, v, o, lse, do, segments=None, dropout_seed=None, *,
     causal: bool = True,
@@ -557,10 +614,12 @@ def flash_attention_bwd_hmajor(
     interpret: bool = False,
     dropout_rate: float = 0.0,
     scale: "float | None" = None,
+    window: "int | None" = None,
 ):
     """Fused flash backward (heads-major layouts): recomputes p from lse per
-    tile, so nothing O(S^2) ever hits HBM. Returns (dq, dk, dv). ``scale``:
-    the forward's (``None`` = ``D ** -0.5``)."""
+    tile, so nothing O(S^2) ever hits HBM. Returns (dq, dk, dv). ``scale``
+    and ``window``: the forward's (``None`` = ``D ** -0.5``, the whole
+    causal span)."""
     B, N, S, D = q.shape
     Dv = v.shape[3]
     KV = k.shape[1]
@@ -569,7 +628,7 @@ def flash_attention_bwd_hmajor(
     block_q = min(block_q, S)
     block_k = min(block_k, Sk)
     _check_call(S, Sk, block_q, block_k, causal, segments, dropout_rate,
-                dropout_seed)
+                dropout_seed, window)
     scale = 1.0 / math.sqrt(D) if scale is None else scale
     has_seg = segments is not None
     # for dk/dv, as rows; the dq kernel takes its own from the o / dO tiles
@@ -589,7 +648,12 @@ def flash_attention_bwd_hmajor(
 
     def qj_of(kb, qj):
         # a q major block wholly above the diagonal repeats the first one
-        # that is needed (no copy for an unchanged block index)
+        # that is needed (no copy for an unchanged block index), and one
+        # wholly past the band the last
+        if causal and window is not None:
+            return jnp.clip(
+                qj, (kb * block_k) // q_major,
+                (kb * block_k + block_k + window - 2) // q_major)
         if causal:
             return jnp.maximum(qj, (kb * block_k) // q_major)
         return qj
@@ -627,7 +691,7 @@ def flash_attention_bwd_hmajor(
                           block_k=block_k, chunks=q_chunks,
                           num_major=S // q_major, G=G, causal=causal,
                           scale=scale, has_seg=has_seg,
-                          dropout_rate=dropout_rate),
+                          dropout_rate=dropout_rate, window=window),
         grid=(B, KV, Sk // block_k, G, S // q_major),
         in_specs=dkdv_in_specs,
         out_specs=[pl.BlockSpec((1, 1, block_k, D), k_tile),
@@ -653,7 +717,7 @@ def flash_attention_bwd_hmajor(
     k_major = k_chunks * block_k
 
     def kj_of(qi, kj):
-        return _needed_k_major(qi, kj, block_q, k_major, causal)
+        return _needed_k_major(qi, kj, block_q, k_major, causal, window)
 
     def q_tile(width):
         return pl.BlockSpec((1, 1, block_q, width),
@@ -682,7 +746,7 @@ def flash_attention_bwd_hmajor(
                           block_k=block_k, chunks=k_chunks,
                           num_major=Sk // k_major, causal=causal,
                           scale=scale, has_seg=has_seg,
-                          dropout_rate=dropout_rate),
+                          dropout_rate=dropout_rate, window=window),
         grid=(B, N, S // block_q, Sk // k_major),
         in_specs=dq_in_specs,
         out_specs=q_tile(D),
@@ -716,6 +780,35 @@ def fit_block(default: int, seq: int, floor: int = 128) -> int:
     return 0
 
 
+def effective_window(window: "int | None", S: int) -> "int | None":
+    """A window no shorter than the sequence is no window: the call is
+    the unwindowed program."""
+    return None if window is None or window >= S else int(window)
+
+
+def band_tiles(S: int, block_q: int, block_k: int,
+               window: "int | None") -> "tuple[int, int]":
+    """(score tiles the forward and dq kernels visit, score tiles of the
+    causal triangle) of one head of a causal call at these tiles: the k
+    chunks a q tile's loop runs over, by the same bounds as
+    ``_for_k_chunks``."""
+    visited = triangle = 0
+    for q0 in range(0, S, block_q):
+        end = (q0 + block_q - 1) // block_k + 1
+        first = 0 if window is None else max(q0 - window + 1, 0) // block_k
+        visited += end - first
+        triangle += end
+    return visited, triangle
+
+
+# every call with a window that ``flash_sdpa`` / ``make_flash_sdpa`` built in
+# this process, as the kernels were handed it: (q length, query heads,
+# block_q, block_k, window). A set, so tracing a call again adds nothing. A
+# launcher empties it before it builds its step and reads it once the step
+# is compiled (``cli/train_dist.py``, the gauge ``flash/band_tiles_pct``)
+WINDOWED_CALLS: "set[tuple[int, int, int, int, int]]" = set()
+
+
 def choose_blocks(D: int, S: int, Sk: int, floor: int = 128):
     """(block_q, block_k) of a call from its shapes alone: head width D, q
     length S, k/v length Sk. A 512 x 512 score tile where the lengths allow
@@ -729,34 +822,37 @@ def choose_blocks(D: int, S: int, Sk: int, floor: int = 128):
     3/4 of the square where 256 x 256 computes 5/8; 1024-wide tiles lose
     more to the diagonal than they save. D does not move the choice at the
     widths measured: K and V are held by the major block, which the
-    kernels size from D themselves (``_major_chunks``)."""
+    kernels size from D themselves (``_major_chunks``). Nor does a window:
+    at D=128 / S=8192 / window 512 the same tile won (PERF.md, PR 48)."""
     del D
     return (fit_block(DEFAULT_BLOCK_Q, S, floor) or S,
             fit_block(DEFAULT_BLOCK_K, Sk, floor) or Sk)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10, 11))
 def _flash_with_vjp(q, k, v, segments, dropout_seed, causal, interpret,
-                    block_q, block_k, dropout_rate, scale):
+                    block_q, block_k, dropout_rate, scale, window=None):
     qh = q.transpose(0, 2, 1, 3)
     kh = k.transpose(0, 2, 1, 3)
     vh = v.transpose(0, 2, 1, 3)
     out, _ = flash_attention_hmajor(qh, kh, vh, segments, dropout_seed,
                                     causal=causal, interpret=interpret,
                                     block_q=block_q, block_k=block_k,
-                                    dropout_rate=dropout_rate, scale=scale)
+                                    dropout_rate=dropout_rate, scale=scale,
+                                    window=window)
     return out.transpose(0, 2, 1, 3)
 
 
 def _flash_fwd(q, k, v, segments, dropout_seed, causal, interpret, block_q,
-               block_k, dropout_rate, scale):
+               block_k, dropout_rate, scale, window=None):
     qh = q.transpose(0, 2, 1, 3)
     kh = k.transpose(0, 2, 1, 3)
     vh = v.transpose(0, 2, 1, 3)
     out, lse = flash_attention_hmajor(qh, kh, vh, segments, dropout_seed,
                                       causal=causal, interpret=interpret,
                                       block_q=block_q, block_k=block_k,
-                                      dropout_rate=dropout_rate, scale=scale)
+                                      dropout_rate=dropout_rate, scale=scale,
+                                      window=window)
     # the pair per-layer remat keeps (``modules.remat``), each in the layout
     # HBM does not pad (module docstring); what the block goes on with is
     # derived from the kept output, so the recomputed forward needs no kernel
@@ -768,15 +864,15 @@ def _flash_fwd(q, k, v, segments, dropout_seed, causal, interpret, block_q,
             (qh, kh, vh, out_rows, lse_rows, segments, dropout_seed))
 
 
-def _flash_bwd(causal, interpret, block_q, block_k, dropout_rate, scale, res,
-               g):
+def _flash_bwd(causal, interpret, block_q, block_k, dropout_rate, scale,
+               window, res, g):
     qh, kh, vh, out_rows, lse_rows, segments, dropout_seed = res
     out = out_rows.reshape(g.shape).transpose(0, 2, 1, 3)
     dq, dk, dv = flash_attention_bwd_hmajor(
         qh, kh, vh, out, lse_rows[..., None], g.transpose(0, 2, 1, 3),
         segments, dropout_seed, causal=causal, interpret=interpret,
         block_q=block_q, block_k=block_k, dropout_rate=dropout_rate,
-        scale=scale)
+        scale=scale, window=window)
     return (dq.transpose(0, 2, 1, 3), dk.transpose(0, 2, 1, 3),
             dv.transpose(0, 2, 1, 3), None, None)  # int operands: no cotan
 
@@ -794,7 +890,8 @@ def seed_from_key(rng: jax.Array) -> jax.Array:
 def flash_sdpa(q, k, v, *, causal: bool = True, interpret: bool = False,
                block_q: int | None = None, block_k: int | None = None,
                segment_ids=None, dropout_rate: float = 0.0,
-               dropout_rng=None, scale: float | None = None):
+               dropout_rng=None, scale: float | None = None,
+               window: int | None = None):
     """Drop-in sdpa_fn for modules.apply_attention: [B, S, N, D] layout in
     and out; fully differentiable — forward and backward both run as fused
     Pallas kernels (backward recomputes p per tile from the saved
@@ -815,17 +912,27 @@ def flash_sdpa(q, k, v, *, causal: bool = True, interpret: bool = False,
     (``ModelArgs.attention_multiplier``); ``None`` is ``D ** -0.5``, the
     float every kernel was given before the argument was there.
 
+    ``window``: a query meets the ``window`` newest keys of its causal
+    span, its own included (a block of sliding-window attention); all
+    three kernels run over the band's tiles alone. ``None``, or a window no
+    shorter than the sequence, is the unwindowed program.
+
     Blocks not given come from ``choose_blocks``: a function of the head
     width and the q / kv lengths alone."""
     S, Sk = q.shape[1], k.shape[1]
+    window = effective_window(window, S)
     bq, bk = choose_blocks(q.shape[-1], S, Sk)
+    if window is not None:
+        WINDOWED_CALLS.add((S, q.shape[2], block_q or bq, block_k or bk,
+                            window))
     seed = None
     if dropout_rate > 0.0:
         if dropout_rng is None:
             raise ValueError("flash dropout_rate > 0 needs dropout_rng")
         seed = seed_from_key(dropout_rng)
     return _flash_with_vjp(q, k, v, segment_ids, seed, causal, interpret,
-                           block_q or bq, block_k or bk, dropout_rate, scale)
+                           block_q or bq, block_k or bk, dropout_rate, scale,
+                           window)
 
 
 # the fwd + both bwd kernels mask cross-document tiles in-kernel
@@ -834,6 +941,8 @@ flash_sdpa.supports_segments = True
 flash_sdpa.supports_dropout = True
 # the softmax scale is an argument of all three kernels
 flash_sdpa.supports_scale = True
+# and so is a window: the kernels visit the band's tiles alone
+flash_sdpa.supports_window = True
 
 
 def make_flash_sdpa(mesh, dp_axes=(), tp_axes=(), *, interpret: bool = False,
@@ -880,11 +989,16 @@ def make_flash_sdpa(mesh, dp_axes=(), tp_axes=(), *, interpret: bool = False,
         return seed + idx * jnp.int32(-1640531527)  # 2654435761 as int32
 
     def sdpa(q, k, v, *, causal=True, segment_ids=None,
-             dropout_rate: float = 0.0, dropout_rng=None, scale=None):
+             dropout_rate: float = 0.0, dropout_rng=None, scale=None,
+             window=None):
         # a length no lane-aligned block divides runs as ONE whole-length
         # block; what then overflows VMEM fails at compile time — there is
         # no XLA core behind the kernel to hide it
+        window = effective_window(window, q.shape[s_dim])
         bq, bk = choose_blocks(q.shape[-1], q.shape[s_dim], k.shape[s_dim])
+        if window is not None:
+            WINDOWED_CALLS.add((q.shape[s_dim], q.shape[s_dim + 1], bq, bk,
+                                window))
         seed = None
         if dropout_rate > 0.0:
             if dropout_rng is None:
@@ -911,7 +1025,7 @@ def make_flash_sdpa(mesh, dp_axes=(), tp_axes=(), *, interpret: bool = False,
             s = rest[0] if has_seg else None
             sd = _shard_seed(rest[-1]) if has_seed else None
             return _flash_with_vjp(a, b, c, s, sd, causal, interpret,
-                                   bq, bk, dropout_rate, scale)
+                                   bq, bk, dropout_rate, scale, window)
 
         from hetu_galvatron_tpu.ops.overlap import staged_lane
 
@@ -924,4 +1038,5 @@ def make_flash_sdpa(mesh, dp_axes=(), tp_axes=(), *, interpret: bool = False,
     sdpa.supports_segments = True
     sdpa.supports_dropout = True
     sdpa.supports_scale = True
+    sdpa.supports_window = True
     return sdpa

@@ -913,6 +913,8 @@ _MOE_HF_NAMES = {
 _MOE_HF_NAMES["deepseek"] = _MOE_HF_NAMES["olmoe"]
 # Kimi Linear's released layout (``modeling_kimi.py``): mixtral's names
 _MOE_HF_NAMES["kimi"] = _MOE_HF_NAMES["mixtral"]
+# Laguna's: olmoe's names again, with ONE shared expert (singular)
+_MOE_HF_NAMES["laguna"] = _MOE_HF_NAMES["olmoe"]
 # the selection bias of an expert layer, where the layout has a name for it
 _MOE_HF_BIAS = {"lfm2": "feed_forward.expert_bias",
                 "deepseek": "mlp.gate.e_score_correction_bias",
@@ -920,9 +922,12 @@ _MOE_HF_BIAS = {"lfm2": "feed_forward.expert_bias",
 # the shared expert's gate, up and down projections, where the layout has a
 # slot for one
 _MOE_HF_SHARED = {
-    layout: tuple(f"{at}.shared_experts.{m}_proj.weight"
-                  for m in ("gate", "up", "down"))
-    for layout, at in (("deepseek", "mlp"), ("kimi", "block_sparse_moe"))}
+    layout: tuple(f"{at}.{m}_proj.weight" for m in ("gate", "up", "down"))
+    for layout, at in (("deepseek", "mlp.shared_experts"),
+                       ("kimi", "block_sparse_moe.shared_experts"),
+                       ("laguna", "mlp.shared_expert"))}
+# the gate a head of an attention block (``ModelArgs.gating``)
+_ATTN_GATE_HF_NAME = "self_attn.g_proj.weight"
 
 # public names of a block's norms, attention projections, q/k norms, dense
 # MLP and of the final norm, by ``cfg.hf_layout``; a ``conv`` block's names
@@ -1127,13 +1132,15 @@ def hf_to_params(state_dict: Dict[str, Any], cfg: ModelArgs) -> Params:
                 "wout": lin(nm["wout"])}
             if cfg.mamba_conv_bias:
                 lp["mamba"]["conv_bias"] = sd[nm["conv_bias"]]
-        elif mixer == "full_attention":
+        elif mixer in ("full_attention", "sliding_attention"):
             lp["attn"] = {
                 "wqkv": np.concatenate(
                     [lin(pre + "self_attn.q_proj.weight"),
                      lin(pre + "self_attn.k_proj.weight"),
                      lin(pre + "self_attn.v_proj.weight")], axis=1),
                 "wo": lin(pre + names["o"])}
+            if cfg.gating:
+                lp["attn"]["wg"] = lin(pre + _ATTN_GATE_HF_NAME)
             if cfg.qk_norm:
                 lp["attn"]["q_norm"] = {"scale": sd[pre + names["q_norm"]]}
                 lp["attn"]["k_norm"] = {"scale": sd[pre + names["k_norm"]]}
@@ -1537,7 +1544,7 @@ def params_to_hf(params: Params, cfg: ModelArgs) -> Dict[str, np.ndarray]:
         return sd
 
     sd["model.embed_tokens.weight"] = get(params["embed"]["wte"])[:V]
-    hd, nq, nkv = cfg.head_dim, cfg.num_attention_heads, cfg.kv_heads
+    hd, nkv = cfg.head_dim, cfg.kv_heads
     router, e_gate, e_up, e_down = _MOE_HF_NAMES[cfg.moe_hf_layout]
     names = _BLOCK_HF_NAMES[cfg.hf_layout]
     kinds = cfg.block_kinds()
@@ -1548,6 +1555,9 @@ def params_to_hf(params: Params, cfg: ModelArgs) -> Dict[str, np.ndarray]:
 
     def put_block(i, lp, mixer, ff):
         pre = f"model.layers.{i}."
+        # the block's own query heads (the further prediction depth's block
+        # is the last block's kind)
+        nq = cfg.block_heads(min(i, len(kinds) - 1))
         for leaf, part in _HC_HF_NAMES.items():
             if leaf in lp:
                 sd[pre + part + "phi.weight"] = get(lp[leaf]["phi"]).T
@@ -1567,9 +1577,11 @@ def params_to_hf(params: Params, cfg: ModelArgs) -> Dict[str, np.ndarray]:
                 w = get(mp[leaf]["scale"] if leaf == "norm" else mp[leaf])
                 sd[pre + name] = (w.T if leaf in ("win", "wout")
                                   else w[:, None, :] if leaf == "taps" else w)
-        elif mixer == "full_attention":
+        elif mixer in ("full_attention", "sliding_attention"):
             wqkv = get(lp["attn"]["wqkv"])
             q, k, v = np.split(wqkv, [nq * hd, (nq + nkv) * hd], axis=1)
+            if "wg" in lp["attn"]:
+                sd[pre + _ATTN_GATE_HF_NAME] = get(lp["attn"]["wg"]).T
             sd[pre + "self_attn.q_proj.weight"] = q.T
             sd[pre + "self_attn.k_proj.weight"] = k.T
             sd[pre + "self_attn.v_proj.weight"] = v.T

@@ -42,18 +42,25 @@ _XING = "xing4_0"
 # Kimi Linear (moonshotai; ``modeling_kimi.py``): Kimi Delta Attention and
 # latent-attention blocks by number, DeepSeek-V3's expert layer
 _KIMI_LINEAR = "kimi_linear"
+# Laguna (poolside; ``LagunaConfig``): window and full attention blocks by
+# ``layer_types`` with query heads, a rotation and a gate a head of their
+# own, a dense block then softmax-free top-k experts beside a shared one
+_LAGUNA = "laguna"
 # families that state for themselves whether they have positions
 _OWN_POSITIONS = {_GRANITE_HYBRID, _KIMI_LINEAR}
 _ROPE_FAMILIES = {"llama", "qwen2", "mistral", "mixtral", "olmoe",
-                  "qwen", _XING} | _GEMMA_FAMILIES | _LFM2_FAMILIES
+                  "qwen", _XING, _LAGUNA} | _GEMMA_FAMILIES | _LFM2_FAMILIES
 _RMS_FAMILIES = _ROPE_FAMILIES | {"t5", _GRANITE_HYBRID, _KIMI_LINEAR}
 _SWIGLU_FAMILIES = {"llama", "qwen2", "mistral", "mixtral", "olmoe",
-                    "qwen", _GRANITE_HYBRID, _XING,
-                    _KIMI_LINEAR} | _LFM2_FAMILIES
-# gemma-2/3 add sandwich norms, logit softcapping, query_pre_attn_scalar,
-# alternating sliding windows (v3: q/k-norm, dual rope) — none of which this
-# stack implements; mapping them through gemma-1 numerics would silently
-# produce wrong logits, so they are refused by name
+                    "qwen", _GRANITE_HYBRID, _XING, _KIMI_LINEAR,
+                    _LAGUNA} | _LFM2_FAMILIES
+# gemma-2/3 add sandwich norms (a norm after each sub-layer as well as
+# before it), logit softcapping (attention and final) and a softmax scale
+# from query_pre_attn_scalar; v3 also a q/k norm a head with zero-centred
+# scales. Their alternating sliding windows and v3's two rotations ARE run
+# (``layer_types`` with "sliding_attention", ``rope_parameters``: the
+# ``laguna`` family); the rest is not, and mapping them through gemma-1
+# numerics would silently produce wrong logits, so they are refused by name
 _UNSUPPORTED_FAMILIES = {"gemma2", "gemma3", "gemma3_text"}
 
 
@@ -74,9 +81,11 @@ def populate_model_args_from_hf(
     if family in _UNSUPPORTED_FAMILIES:
         raise NotImplementedError(
             f"model family {family!r} has architecture features this stack "
-            "does not implement (sandwich norms, logit softcapping, "
-            "alternating sliding windows); refusing rather than producing "
-            "silently-wrong numerics")
+            "does not implement (sandwich norms around each sub-layer, "
+            "attention and final logit softcapping, a softmax scale from "
+            "query_pre_attn_scalar; its sliding windows are not the "
+            "obstacle: layer_types with sliding_attention blocks are run); "
+            "refusing rather than producing silently-wrong numerics")
     values: Dict[str, Any] = dict(base.model_dump() if base else {})
     for ours, theirs in _FIELD_MAP.items():
         for key in theirs:
@@ -163,6 +172,8 @@ def populate_model_args_from_hf(
         values.update(_xing_values(d))
     if family == _KIMI_LINEAR:
         values.update(_kimi_linear_values(d))
+    if family == _LAGUNA:
+        values.update(_laguna_values(d))
     if family == "bert":
         # HF bert uses erf gelu everywhere (BertIntermediate + the MLM
         # transform); our "gelu" is the tanh approximation (gpt2's gelu_new)
@@ -195,6 +206,7 @@ def populate_model_args_from_hf(
     # attention_bias / mlp_bias / family defaults)
     # llama-likes and t5 default to no biases
     bias_free = _ROPE_FAMILIES | {"t5", _GRANITE_HYBRID, _KIMI_LINEAR}
+    # (_LAGUNA is one of _ROPE_FAMILIES)
     if "attention_bias" in d:
         values["add_qkv_bias"] = bool(d["attention_bias"])
     elif family in {"qwen", "qwen2"}:
@@ -252,6 +264,82 @@ def _xing_values(d: Dict[str, Any]) -> Dict[str, Any]:
         hc_res_clamp_min=float(d.get("mhc_h_res_clamp_min", -30.0)),
         hc_res_clamp_max=float(d.get("mhc_h_res_clamp_max", 30.0)),
         num_nextn_predict_layers=int(d.get("num_nextn_predict_layers", 0)))
+
+
+def _laguna_values(d: Dict[str, Any]) -> Dict[str, Any]:
+    """Laguna (``LagunaConfig``): ``layer_types`` names each block
+    "full_attention" or "sliding_attention" (the ``sliding_window`` newest
+    keys), ``num_attention_heads_per_layer`` its query heads,
+    ``rope_parameters`` a rotation a kind, ``gating`` the sigmoid gate a
+    head; ``mlp_only_layers`` (leading) dense blocks, then ``num_experts``
+    experts of ``moe_intermediate_size`` at top ``num_experts_per_tok``,
+    weights renormalised and times ``moe_routed_scaling_factor``, beside a
+    shared expert of ``shared_expert_intermediate_size``."""
+    family = _LAGUNA
+    n = int(d["num_hidden_layers"])
+    types = d.get("layer_types")
+    if types is None or set(types) - {"full_attention", "sliding_attention"}:
+        raise NotImplementedError(
+            f"{family} layer_types={types!r}: full_attention and "
+            "sliding_attention blocks, named one by one, are implemented")
+    dense = sorted(d.get("mlp_only_layers") or ())
+    if dense != list(range(len(dense))) or int(
+            d.get("decoder_sparse_step", 1)) != 1:
+        raise NotImplementedError(
+            f"{family} mlp_only_layers={dense} decoder_sparse_step="
+            f"{d.get('decoder_sparse_step')}: leading dense blocks, then "
+            "experts in every block, are implemented")
+    gating = d.get("gating")
+    if gating not in (None, False, True, "per-head", "per_head"):
+        raise NotImplementedError(
+            f"{family} gating={gating!r}: a sigmoid gate a head (per-head) "
+            "is implemented")
+    if set(d.get("gating_types") or ()) - {"per_head"}:
+        raise NotImplementedError(
+            f"{family} gating_types={sorted(set(d['gating_types']))}: every "
+            "block's gate a head (per_head) is implemented")
+    if float(d.get("moe_router_logit_softcapping") or 0.0):
+        raise NotImplementedError(
+            f"{family} moe_router_logit_softcapping="
+            f"{d['moe_router_logit_softcapping']}: the router's logits "
+            "uncapped (0) are implemented")
+    if d.get("moe_apply_router_weight_on_input", False):
+        raise NotImplementedError(
+            f"{family} moe_apply_router_weight_on_input: the router's weight "
+            "on an expert's OUTPUT is implemented")
+    moe_ffn = int(d["moe_intermediate_size"])
+    shared = int(d.get("shared_expert_intermediate_size") or 0)
+    if shared % moe_ffn:
+        raise NotImplementedError(
+            f"{family} shared_expert_intermediate_size={shared}: a shared "
+            f"expert of whole experts' width ({moe_ffn}) is implemented")
+    out: Dict[str, Any] = dict(
+        model_type="moe", hf_layout="llama", moe_hf_layout="laguna",
+        layer_types=list(types), num_dense_layers=len(dense),
+        moe_ffn_hidden_size=moe_ffn, num_shared_experts=shared // moe_ffn,
+        moe_layer_freq=1, moe_score_function="sigmoid",
+        moe_dispatcher="dropless",
+        moe_norm_topk_prob=bool(d.get("norm_topk_prob", True)),
+        moe_norm_topk_eps=1e-20,
+        moe_routed_scaling_factor=float(
+            d.get("moe_routed_scaling_factor", 1.0)),
+        moe_router_enable_expert_bias=False, moe_aux_loss_coeff=0.0,
+        gating="per-head" if gating else None,
+        tie_word_embeddings=bool(d.get("tie_word_embeddings", False)))
+    if "sliding_attention" in types:
+        out["sliding_window"] = int(d["sliding_window"])
+    if d.get("num_attention_heads_per_layer") is not None:
+        out["num_attention_heads_per_layer"] = [
+            int(h) for h in d["num_attention_heads_per_layer"]]
+    if len(types) != n:
+        raise ValueError(f"{family}: layer_types names {len(types)} blocks "
+                         f"and num_hidden_layers is {n}")
+    rope = d.get("rope_parameters") or {}
+    by_kind = {k: dict(v) for k, v in rope.items()
+               if k in ("full_attention", "sliding_attention")}
+    if by_kind:
+        out["rope_parameters"] = by_kind
+    return out
 
 
 def _kimi_linear_values(d: Dict[str, Any]) -> Dict[str, Any]:

@@ -43,7 +43,7 @@ def test_the_table_names_fields_the_record_has_and_keywords_the_leaves_take():
         assert row.attends == ({"rope", "causal", "dropout_rng",
                                 "segment_ids"} <= takes), kind
     assert [k for k, row in M.MIXERS.items() if row.attends] == [
-        "full_attention", "latent_attention"]
+        "full_attention", "latent_attention", "sliding_attention"]
 
 
 @pytest.mark.parametrize("kernels", [True, None])

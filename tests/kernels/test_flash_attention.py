@@ -1,6 +1,8 @@
 """Pallas flash attention vs the dense XLA core (interpret mode on CPU; the
 kernel itself compiles with Mosaic on TPU)."""
 
+import functools
+
 import numpy as np
 import pytest
 
@@ -708,3 +710,169 @@ def test_flash_at_equal_widths_is_bit_equal_to_the_kernels_before(case):
         np.testing.assert_allclose(g, w, rtol=2e-2, atol=2e-2, err_msg=name)
         # (measured 1.9e-3 to 2.6e-3: a bf16 result's own rounding)
         assert np.sqrt(np.mean((g - w) ** 2) / np.mean(w ** 2)) < 5e-3, name
+
+
+# a window: S, block_q, block_k, window, segments, dropout. The window is
+# smaller than a tile, a tile, straddles tiles of unequal sizes, is one key,
+# is the sequence less one; the sequence is whole tiles, ragged halvings
+# (48 = 3 x 16) and one whole-length block (40)
+_WINDOWS = {
+    "under_a_tile": (64, 16, 16, 5, False, 0.0),
+    "a_tile": (64, 16, 16, 16, False, 0.0),
+    "a_tile_and_a_half": (64, 16, 16, 24, False, 0.0),
+    "wide_k_tiles": (64, 16, 32, 17, False, 0.0),
+    "wide_q_tiles": (64, 32, 16, 33, False, 0.0),
+    "itself_alone": (64, 16, 16, 1, False, 0.0),
+    "all_but_one": (64, 16, 16, 63, False, 0.0),
+    "three_tiles": (48, 16, 16, 20, False, 0.0),
+    "one_block": (40, None, None, 7, False, 0.0),
+    "segments": (64, 16, 16, 24, True, 0.0),
+    "segments_wide_q": (64, 32, 16, 10, True, 0.0),
+    "dropout": (64, 16, 32, 24, False, 0.25),
+    "segments_and_dropout": (64, 16, 16, 12, True, 0.25),
+}
+
+
+def _ref_window_attn(q, k, v, window, segs, seed, rate):
+    """The dense band: softmax over the window's keys, then the kernels'
+    own counter-based dropout mask over (head, q position, k position)."""
+    from hetu_galvatron_tpu.ops.pallas.flash_attention import keep_mask
+
+    B, S, N, D = q.shape
+    G = N // k.shape[2]
+    kf, vf = jnp.repeat(k, G, axis=2), jnp.repeat(v, G, axis=2)
+    s = jnp.einsum("bsnd,btnd->bnst", q, kf) / np.sqrt(D)
+    ahead = jnp.arange(S)[:, None] - jnp.arange(S)[None, :]
+    seen = (ahead >= 0) & (ahead < window)
+    if segs is not None:
+        seen = seen & (segs[:, None, :, None] == segs[:, None, None, :])
+    p = jax.nn.softmax(jnp.where(seen, s, -jnp.inf), axis=-1)
+    if rate:
+        bn = (jnp.arange(B)[:, None] * N + jnp.arange(N)[None, :])
+        keep = keep_mask(seed[0], bn[:, :, None, None],
+                         jnp.arange(S)[None, None, :, None],
+                         jnp.arange(S)[None, None, None, :], rate)
+        p = jnp.where(keep, p / (1.0 - rate), 0.0)
+    return jnp.einsum("bnst,btnd->bsnd", p, vf)
+
+
+@pytest.mark.parametrize("case", sorted(_WINDOWS))
+def test_flash_window_matches_the_dense_band(case):
+    """out, dq, dk and dv of the three kernels with a window against the
+    dense band, and the band against ``xla_sdpa``'s own window."""
+    from hetu_galvatron_tpu.ops.pallas.flash_attention import seed_from_key
+
+    S, bq, bk, window, seg, rate = _WINDOWS[case]
+    q, k, v = _qkv(S=S, N=4, K=2, D=32, seed=3)
+    do = jax.random.normal(jax.random.key(7), q.shape, q.dtype)
+    segs = ((jnp.arange(S)[None, :] >= jnp.array([[S // 3], [S // 2]])
+             ).astype(jnp.int32) if seg else None)
+    rng = jax.random.key(5)
+    seed = seed_from_key(rng) if rate else None
+    ref = _fwd_and_grads(
+        lambda a, b, c: _ref_window_attn(a, b, c, window, segs, seed, rate),
+        q, k, v, do)
+    got = _fwd_and_grads(
+        lambda a, b, c: flash_sdpa(
+            a, b, c, interpret=True, block_q=bq, block_k=bk, window=window,
+            segment_ids=segs, dropout_rate=rate,
+            dropout_rng=rng if rate else None), q, k, v, do)
+    _assert_f32_parity(got, ref)
+    if not rate:
+        _assert_f32_parity(_fwd_and_grads(
+            lambda a, b, c: xla_sdpa(a, b, c, window=window,
+                                     segment_ids=segs), q, k, v, do), ref)
+
+
+def test_flash_window_across_major_blocks(monkeypatch):
+    """The band over several major blocks: a k major block wholly before
+    the band is neither fetched nor computed (forward, dq), nor a q major
+    block wholly past it (dk/dv)."""
+    from hetu_galvatron_tpu.ops.pallas import flash_attention as fa
+
+    monkeypatch.setattr(fa, "_RESIDENT_BYTES", 2 * 128 * 24 * 4)
+    q, k, v = _qkv(B=1, S=768, N=2, K=1, D=24, seed=9)
+    do = jax.random.normal(jax.random.key(5), q.shape, q.dtype)
+    for window in (100, 300):
+        ref = _fwd_and_grads(
+            lambda a, b, c: xla_sdpa(a, b, c, window=window), q, k, v, do)
+        got = _fwd_and_grads(
+            lambda a, b, c: flash_sdpa(a, b, c, interpret=True, block_q=128,
+                                       block_k=128, window=window),
+            q, k, v, do)
+        _assert_f32_parity(got, ref)
+
+
+def test_a_window_no_shorter_than_the_sequence_is_no_window():
+    """``window >= S`` is the unwindowed call, jaxpr for jaxpr; a window
+    needs the causal span and at least the query's own key."""
+    q, k, v = _qkv(S=64, N=2, K=2, D=16)
+
+    def text(**kw):
+        return str(jax.make_jaxpr(lambda a, b, c: flash_sdpa(
+            a, b, c, interpret=True, **kw))(q, k, v))
+
+    assert text(window=64) == text() == text(window=1000)
+    assert text(window=63) != text()
+    with pytest.raises(ValueError, match="causal"):
+        flash_sdpa(q, k, v, interpret=True, causal=False, window=8)
+    with pytest.raises(ValueError, match="at least 1"):
+        flash_sdpa(q, k, v, interpret=True, window=0)
+    with pytest.raises(ValueError, match="causal span"):
+        xla_sdpa(q, k, v, causal=False, window=8)
+
+
+def test_band_tiles_count_the_loops_of_the_kernels():
+    from hetu_galvatron_tpu.ops.pallas.flash_attention import (
+        WINDOWED_CALLS, band_tiles)
+
+    # the cell's window blocks: 16 q tiles, two k tiles each but the first
+    assert band_tiles(8192, 512, 512, 512) == (31, 136)
+    assert band_tiles(8192, 512, 512, None) == (136, 136)
+    assert band_tiles(8192, 256, 256, 512) == (3 * 32 - 3, 32 * 33 // 2)
+    assert band_tiles(64, 16, 16, 1) == (4, 10)
+    # a call is recorded with the window and tiles the kernels are handed,
+    # once however often it is traced; one without a window is not
+    q = jax.ShapeDtypeStruct((1, 8192, 72, 128), jnp.bfloat16)
+    k = jax.ShapeDtypeStruct((1, 8192, 8, 128), jnp.bfloat16)
+    WINDOWED_CALLS.clear()
+    for window in (None, 8192, 512, 512):
+        jax.eval_shape(functools.partial(flash_sdpa, window=window), q, k, k)
+    assert WINDOWED_CALLS == {(8192, 72, 512, 512, 512)}
+    WINDOWED_CALLS.clear()
+
+
+# sha256 of the jaxpr of loss and gradients through ``flash_sdpa`` WITHOUT a
+# window, recorded from the parent of the commit that gave the kernels one
+# (f5bc594), with `` at <file>:<line>`` taken out (a kernel's source position
+# rides in its ``name_and_src_info``): the unwindowed call traces to the
+# program it was, equation for equation
+_UNWINDOWED = {
+    "gqa_128": ((1024, 4, 2, 128, False, False),
+                "04ddb1faccb3bb55bdebcd513bc16a366a6144554ae4350430d059e2"
+                "ff4ebea1"),
+    "segments_dropout_64": ((512, 2, 2, 64, True, True),
+                            "b8053a6fdd0b64890e40c6af8d0252b3ea2ccbfb7f74825a"
+                            "900d26c1096113b1"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_UNWINDOWED))
+def test_an_unwindowed_call_is_the_jaxpr_it_was(case):
+    import hashlib
+    import re
+
+    (S, N, K, D, seg, drop), want = _UNWINDOWED[case]
+    q = jax.ShapeDtypeStruct((2, S, N, D), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((2, S, K, D), jnp.bfloat16)
+    segs = jnp.zeros((2, S), jnp.int32) if seg else None
+
+    def loss(q, k, v):
+        return jnp.sum(flash_sdpa(
+            q, k, v, segment_ids=segs, dropout_rate=0.1 if drop else 0.0,
+            dropout_rng=jax.random.key(0) if drop else None
+        ).astype(jnp.float32))
+
+    text = str(jax.make_jaxpr(jax.grad(loss, (0, 1, 2)))(q, kv, kv))
+    text = re.sub(r" at [^\s:]+:\d+", "", text)
+    assert hashlib.sha256(text.encode()).hexdigest() == want

@@ -33,8 +33,12 @@ def one_chip():
 
 
 # B, S, Sk, heads, kv heads, head dim (q/k, or (q/k, v)), dtype, causal,
-# segments, dropout
+# segments, dropout[, window]
 _CASES = {
+    "window_cell_72_heads": (1, 8192, 8192, 72, 8, 128, jnp.bfloat16, True,
+                             False, 0.0, 512),
+    "window_f32_segments_dropout": (1, 2048, 2048, 6, 2, 128, jnp.float32,
+                                    True, True, 0.1, 300),
     "latent_cell_192_128": (1, 4096, 4096, 32, 32, (192, 128), jnp.bfloat16,
                             True, False, 0.0),
     "latent_f32_segments": (1, 1024, 1024, 4, 4, (192, 128), jnp.float32,
@@ -55,8 +59,9 @@ _CASES = {
 
 @pytest.mark.parametrize("case", sorted(_CASES))
 def test_flash_forward_and_backward_compile_for_v5e(one_chip, case):
-    B, S, Sk, N, K, D, dtype, causal, seg, drop = _CASES[case]
+    B, S, Sk, N, K, D, dtype, causal, seg, drop, *window = _CASES[case]
     D, Dv = D if isinstance(D, tuple) else (D, D)
+    window = window[0] if window else None
 
     def spec(shape, dt):
         return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
@@ -66,7 +71,7 @@ def test_flash_forward_and_backward_compile_for_v5e(one_chip, case):
             lambda a, b, c: flash_sdpa(
                 a, b, c, causal=causal,
                 segment_ids=segments if seg else None, dropout_rate=drop,
-                dropout_rng=key if drop else None), q, k, v)
+                dropout_rng=key if drop else None, window=window), q, k, v)
         return (out,) + vjp(do)
 
     compiled = jax.jit(fwd_bwd).lower(
