@@ -705,6 +705,7 @@ def train(args) -> Dict[str, Any]:
         kda_kernel_calls,
         kda_loops,
         record_step_scopes,
+        scans_recomputed,
         step_hlo,
     )
 
@@ -1151,11 +1152,14 @@ def train(args) -> Dict[str, Any]:
                         mosaic_custom_calls=found["mosaic_custom_calls"],
                         collectives=found["collectives"],
                         scope_instructions=found["scopes"])
-                    # attention cores run again under per-layer remat: the
-                    # flash forward calls the map puts in the recompute phase
-                    step_report["cores_recomputed"] = cores_recomputed(found)
-                    get_registry().gauge("step/cores_recomputed").set(
-                        step_report["cores_recomputed"])
+                    # attention cores and recurrent scans run again under
+                    # per-layer remat: the flash / scan forward calls the map
+                    # puts in the recompute phase
+                    for part, count in (("cores", cores_recomputed),
+                                        ("scans", scans_recomputed)):
+                        step_report[f"{part}_recomputed"] = count(found)
+                        get_registry().gauge(f"step/{part}_recomputed").set(
+                            step_report[f"{part}_recomputed"])
                     step_report["step_map"] = {
                         "instructions": len(found["map"]["instructions"]),
                         "inferred": len(found["map"]["inferred"]),
@@ -1247,6 +1251,7 @@ def train(args) -> Dict[str, Any]:
                        f"{step_report['band_tiles_pct']:.1f}"
                        if "band_tiles_pct" in step_report else "")
                     + f", {step_report['cores_recomputed']} cores recomputed,"
+                    f" {step_report['scans_recomputed']} scans recomputed,"
                     f" static live peak "
                     f"{step_report['static_memory']['live_peak'] / 2**30:.3f}"
                     " GiB")
@@ -1301,6 +1306,10 @@ def train(args) -> Dict[str, Any]:
             # per-layer remat (the gauge step/cores_recomputed; 0 = every
             # core's output is kept); None for the pp engines
             "cores_recomputed": step_report.get("cores_recomputed"),
+            # the same of the recurrent mixers' scan kernels (the gauge
+            # step/scans_recomputed; 0 = every scan's output and entering
+            # states are kept); None for the pp engines
+            "scans_recomputed": step_report.get("scans_recomputed"),
             # XLA's static memory of the compiled pp=1 step, per device, in
             # bytes (the step/static_bytes gauges); None for the pp engines
             "static_memory": step_report.get("static_memory"),

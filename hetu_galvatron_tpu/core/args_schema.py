@@ -69,7 +69,9 @@ class ModelArgs(BaseModel):
     # rematerialization policy for per-layer activation checkpointing:
     # "full" keeps the block's input and, of a block that attends through
     # the flash kernels, the attention core's output with its row statistics
-    # (as large as the input; the layer's S x S work then runs once), and
+    # (as large as the input; the layer's S x S work then runs once), of a
+    # block whose recurrence runs in the scan kernels their output and the
+    # states that entered the chunks (modules.remat), and
     # recomputes everything else (min memory); "dots" also saves matmul
     # outputs so the backward recomputes only cheap elementwise ops (MXU
     # FLOPs are the expensive part on TPU); "dots_no_batch" saves only

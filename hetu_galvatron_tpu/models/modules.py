@@ -362,16 +362,17 @@ def remat(fn, cfg: ModelArgs):
     TPU lever is WHICH values the backward may keep — saving MXU outputs
     ("dots") trades a little memory for skipping matmul recompute).
 
-    Under every policy a flash attention core's output and row statistics
-    are kept (the two values ``flash_attention._flash_fwd`` names): the
-    output is as large as the block's input, which is kept anyway, and
-    producing it again is the layer's whole S x S work. So ``full`` keeps the
-    block's input and, where the block attends through the flash kernels,
-    that pair; the recomputed forward then holds no forward kernel. A block
-    without a flash core (the XLA core, a convolution or state-space mixer,
-    an MLP alone) names nothing and is recomputed whole."""
-    from hetu_galvatron_tpu.ops.pallas.flash_attention import (
-        KEPT_LSE, KEPT_OUT)
+    Under every policy the results a forward kernel's differentiated rule
+    names are kept (each kernel file's ``KEPT``): a flash attention core's
+    output and row statistics, a delta-rule or Mamba-2 scan's output and the
+    states that entered its chunks. They are what the rest of the step reads
+    of the kernel, and producing them again is the kernel's whole run. So
+    ``full`` keeps the block's input and what its forward kernels named; the
+    recomputed forward then holds none of them. A block that ran no such
+    kernel (the XLA core, a mixer in its ``jax.numpy`` form, an MLP alone)
+    traces no name and is recomputed whole; the convolution's kernels name
+    nothing either."""
+    from hetu_galvatron_tpu.ops.pallas import flash_attention, kda, ssd
 
     policies = jax.checkpoint_policies
     base = {"full": None, "dots": policies.checkpoint_dots,
@@ -381,7 +382,8 @@ def remat(fn, cfg: ModelArgs):
         # policy would otherwise silently run full recompute
         raise ValueError(f"unknown remat_policy {cfg.remat_policy!r} "
                          "(full | dots | dots_no_batch)")
-    policy = policies.save_only_these_names(KEPT_OUT, KEPT_LSE)
+    policy = policies.save_only_these_names(
+        *flash_attention.KEPT, *kda.KEPT, *ssd.KEPT)
     if base[cfg.remat_policy] is not None:
         policy = policies.save_from_both_policies(base[cfg.remat_policy],
                                                   policy)

@@ -504,6 +504,9 @@ SSD_SCOPE = "mixer/mamba/ssd"
 # ops/pallas/kda.py traces under it), and the forward kernel's name there
 KDA_SCAN_SCOPE = "mixer/kda/scan"
 KDA_FWD_CALL = "kda_scan_fwd"
+# the forward kernels of the recurrent mixers' scans (ops/pallas/kda.py,
+# ops/pallas/ssd.py), which :func:`scans_recomputed` counts
+SCAN_FWD_CALLS = (KDA_FWD_CALL, "ssd_scan_fwd")
 _OPERAND_SHAPES = "operand_layout_constraints="
 _TRIP_COUNT = re.compile(r'"known_trip_count":\{"n":"(\d+)"')
 _BODY = re.compile(r"body=%?([\w.\-]+)")
@@ -882,16 +885,28 @@ def kda_kernel_calls(hlo_text: str) -> Dict[str, int]:
     return out
 
 
+def _recomputed(found: Dict[str, Any], calls) -> int:
+    phases = found["map"]["instructions"]
+    return sum(name.startswith(calls) and phases[name][1] == "recompute"
+               for name in found["mosaic_calls"])
+
+
 def cores_recomputed(found: Dict[str, Any]) -> int:
     """The flash forward kernels of a step (``found``: :func:`step_hlo`'s
     answer) that its map puts in the ``recompute`` phase: attention cores
     that per-layer remat runs a second time. 0 where ``modules.remat`` keeps
     every core's output and row statistics (the gauge
     ``step/cores_recomputed``), and in a step without the kernels."""
-    phases = found["map"]["instructions"]
-    return sum(name.startswith(FLASH_FWD_CALL)
-               and phases[name][1] == "recompute"
-               for name in found["mosaic_calls"])
+    return _recomputed(found, FLASH_FWD_CALL)
+
+
+def scans_recomputed(found: Dict[str, Any]) -> int:
+    """The same of the recurrent mixers' scans: the Mosaic calls named
+    ``kda_scan_fwd`` or ``ssd_scan_fwd`` that the step's map puts in the
+    ``recompute`` phase. 0 where ``modules.remat`` keeps every scan's output
+    and entering states (the gauge ``step/scans_recomputed``), and in a step
+    without the kernels."""
+    return _recomputed(found, SCAN_FWD_CALLS)
 
 
 def conv_kernel_calls(found: Dict[str, Any]) -> Dict[str, int]:

@@ -39,10 +39,11 @@ The spans of the launcher (``cli/train_dist.py``), all flat siblings:
   compiled step's HLO text and ``memory_analysis()`` after the first
   call, which also sets the gauges ``step/static_bytes{part=arguments|outputs|
   aliased|temporaries|generated_code|live_peak}`` and
-  ``step/cores_recomputed`` (the flash forward kernels per-layer remat
-  runs a second time; 0 where ``modules.remat`` keeps every core's
-  results), and keeps every instruction's scope, phase and collective
-  class for a reader of a trace:
+  ``step/cores_recomputed`` and ``step/scans_recomputed`` (the flash
+  forward kernels, and the recurrent mixers' scan forward kernels,
+  per-layer remat runs a second time; 0 where ``modules.remat`` keeps every
+  kernel's results), and keeps every instruction's scope, phase and
+  collective class for a reader of a trace:
   ``trace_analysis.step_hlo``; :class:`TraceCapture` writes them beside the
   trace as ``step_map.json`` when its window closes).
 """

@@ -71,9 +71,10 @@ from hetu_galvatron_tpu.ops.pallas.common import LANES, on_shards
 NEG_INF = float(jnp.finfo(jnp.float32).min)
 
 # ``checkpoint_name``s of the forward's two results that the backward kernels
-# read; ``modules.remat`` keeps the values under these names
+# read; ``modules.remat`` keeps the values under the names of ``KEPT``
 KEPT_OUT = "flash_attention_out"
 KEPT_LSE = "flash_attention_lse"
+KEPT = (KEPT_OUT, KEPT_LSE)
 
 
 def keep_mask(seed, bn, qpos, kpos, rate: float):

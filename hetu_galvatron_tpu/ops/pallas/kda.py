@@ -56,7 +56,9 @@ H / P, 1, P * C]`` (``beta_j`` along lanes), and the kernels return a
 cotangent for each.
 
 The forward that is differentiated also writes the state that entered each
-chunk (float32, ``[B, chunks, H, dv, d]``). The backward runs the chunks in
+chunk (float32, ``[B, chunks, H, dv, d]``), and names it and the output
+(``KEPT``): ``modules.remat`` keeps what carries those names, so a block's
+recomputed forward runs no scan kernel. The backward runs the chunks in
 reverse with the state's cotangent in VMEM, reads those states, and makes
 the pair matrices, the inverse, ``W``, ``U`` and ``V'`` again from the
 inputs, as the flash backward makes its scores again. The inverse's
@@ -79,6 +81,7 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -102,6 +105,12 @@ VMEM_BYTES = 32 * 2 ** 20
 # a backward rule does not inherit the scope its forward was called in
 # (``observability/trace_analysis.KDA_SCAN_SCOPE`` is the same words)
 SCOPE = "mixer/kda/scan"
+# ``checkpoint_name``s of the differentiated forward's two results, the
+# output the block goes on with and the states the backward kernel reads;
+# ``modules.remat`` keeps the values under the names of ``KEPT``
+KEPT_OUT = "kda_scan_out"
+KEPT_STATES = "kda_scan_states"
+KEPT = (KEPT_OUT, KEPT_STATES)
 _HIGHEST = jax.lax.Precision.HIGHEST
 _F32 = jnp.float32
 
@@ -635,6 +644,10 @@ def _scan(q, k, v, G, cols, rows, interpret):
 def _scan_fwd(q, k, v, G, cols, rows, interpret):
     o, entering = _scan_call(q, k, v, G, cols, rows, interpret,
                              keep_states=True)
+    # the pair per-layer remat keeps (``modules.remat``), as the kernel
+    # wrote them: a block's recomputed forward then holds no scan kernel
+    o = checkpoint_name(o, KEPT_OUT)
+    entering = checkpoint_name(entering, KEPT_STATES)
     return o, (q, k, v, G, cols, rows, entering)
 
 

@@ -31,8 +31,11 @@ cs)`` and ``grow = exp(cs)``; ``a = exp(cs_last)`` comes spread over its
 head's lanes. The exps outside are differentiated by JAX; the kernels
 return the cotangents of what they were given.
 
-The backward runs the chunks in reverse with the state's cotangent in VMEM,
-reads the entering states the forward kept, and makes ``G``, ``L`` and ``M``
+The forward that is differentiated names its output and the entering states
+it writes beside it (``KEPT``): ``modules.remat`` keeps what carries those
+names, so a block's recomputed forward runs no scan kernel. The backward
+runs the chunks in reverse with the state's cotangent in VMEM, reads the
+entering states the forward kept, and makes ``G``, ``L`` and ``M``
 again from the inputs, as the flash backward makes its scores again, in
 TRANSPOSED tiles (sources ``j`` along sublanes, targets ``i`` along lanes)
 so that ``M^T dy`` and ``(x dt) dy^T`` are plain products. The decay's
@@ -54,6 +57,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -70,6 +74,12 @@ STEP_LANES = 1024
 # a backward rule does not inherit the scope its forward was called in
 # (``observability/trace_analysis.SSD_SCOPE`` is the same words)
 SCOPE = "mixer/mamba/ssd"
+# ``checkpoint_name``s of the differentiated forward's two results, both of
+# which the backward kernel reads: the output and the states that entered
+# the chunks; ``modules.remat`` keeps the values under the names of ``KEPT``
+KEPT_OUT = "ssd_scan_out"
+KEPT_STATES = "ssd_scan_states"
+KEPT = (KEPT_OUT, KEPT_STATES)
 
 _TN = (((0,), (0,)), ((), ()))   # a.T @ b, beside the flash kernels' two
 # the columns' order in their packed operand, and of their cotangents
@@ -310,6 +320,10 @@ def _scan(x, cols, csr, a, Bm, Cm, interpret):
 def _scan_fwd(x, cols, csr, a, Bm, Cm, interpret):
     y, entering = _scan_call(x, cols, csr, a, Bm, Cm, interpret,
                              keep_states=True)
+    # the pair per-layer remat keeps (``modules.remat``), as the kernel
+    # wrote them: a block's recomputed forward then holds no scan kernel
+    y = checkpoint_name(y, KEPT_OUT)
+    entering = checkpoint_name(entering, KEPT_STATES)
     return y, (x, cols, csr, a, Bm, Cm, y, entering)
 
 

@@ -289,3 +289,71 @@ def test_a_rematted_block_holds_one_forward_kernel(one_chip, wrapper,
     assert len(fwd) == forwards, sorted(found["mosaic_calls"])
     assert found["mosaic_custom_calls"] == forwards + 2
     assert cores_recomputed(found) == recomputed
+
+
+# a recurrent block at its cell's widths: mixer kind, the field of
+# ``LayerOps`` its scan goes in, the scan, its forward kernel, the model's
+# sizes (``kimilin_c1_b1_s8k``, ``granite4h_c1_b1``: one sequence of 8192)
+_SCAN_BLOCKS = {
+    "kda": ("kda", kda.kda_scan, "kda_scan_fwd", dict(
+        hidden_size=2304, ffn_hidden_size=9216, kda_num_heads=32,
+        kda_head_dim=128, kda_chunk_size=64)),
+    "mamba": ("ssd", ssd.ssd_scan, "ssd_scan_fwd", dict(
+        hidden_size=2048, ffn_hidden_size=8192, mamba_n_heads=64,
+        mamba_d_head=64, mamba_d_state=128, mamba_chunk_size=256)),
+}
+
+
+@pytest.mark.parametrize("wrapper,forwards,recomputed", [
+    ("remat", 1, 0), ("plain_checkpoint", 2, 1)])
+@pytest.mark.parametrize("kind", sorted(_SCAN_BLOCKS))
+def test_a_rematted_recurrent_block_holds_one_scan_forward(
+        one_chip, kind, wrapper, forwards, recomputed):
+    """The real scan and convolution kernels under per-layer remat, at the
+    cells' widths: the gradient of a block wrapped by ``modules.remat``
+    compiles to one ``kda_scan_fwd`` / ``ssd_scan_fwd``, none of it in the
+    recompute phase (the convolution's forward names nothing and is there
+    twice); under plain ``jax.checkpoint`` there are two, and
+    ``trace_analysis.scans_recomputed`` counts the second."""
+    from hetu_galvatron_tpu.core.args_schema import ModelArgs
+    from hetu_galvatron_tpu.models import modules as M
+    from hetu_galvatron_tpu.observability.trace_analysis import (
+        conv_kernel_calls,
+        cores_recomputed,
+        scans_recomputed,
+        step_hlo,
+    )
+
+    field, scan, forward, sizes = _SCAN_BLOCKS[kind]
+    B, S = 1, 8192
+    cfg = ModelArgs(num_hidden_layers=1, num_attention_heads=32,
+                    vocab_size=128, max_position_embeddings=S, seq_length=S,
+                    hidden_act="swiglu", normalization="rmsnorm",
+                    add_bias_linear=False, **sizes)
+    shapes = jax.eval_shape(
+        lambda k: M.init_decoder_layer(k, cfg, mixer=kind)[0],
+        jax.random.key(0))
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        shapes)
+    x = jax.ShapeDtypeStruct((B, S, cfg.hidden_size), jnp.bfloat16,
+                             sharding=one_chip)
+    ops = M.LayerOps(conv=conv.causal_conv, **{field: scan})
+
+    def block(p, h):
+        return M.apply_decoder_layer(p, h, cfg, ops=ops, mixer=kind)
+
+    wrapped = M.remat(block, cfg) if wrapper == "remat" else jax.checkpoint(
+        block)
+    compiled = jax.jit(jax.grad(
+        lambda p, h: jnp.sum(wrapped(p, h).astype(jnp.float32) ** 2),
+        argnums=(0, 1))).lower(params, x).compile()
+    found = step_hlo(compiled.as_text())
+    fwd = [n for n in found["mosaic_calls"] if n.startswith(forward)]
+    assert len(fwd) == forwards, sorted(found["mosaic_calls"])
+    # the scan's backward, and the convolution's three either way
+    assert found["mosaic_custom_calls"] == forwards + 1 + 3
+    assert conv_kernel_calls(found) == {"forward": 1, "recompute": 1,
+                                        "backward": 1}
+    assert scans_recomputed(found) == recomputed
+    assert cores_recomputed(found) == 0

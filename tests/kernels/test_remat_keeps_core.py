@@ -3,14 +3,18 @@
 names), so a block's recomputed forward holds no forward kernel: one
 ``flash_attention_fwd`` call a block in the gradient's jaxpr where plain
 ``jax.checkpoint`` with the same base policy has two, and the same numbers to
-the last bit. Interpret-mode kernels on the CPU; the compile of the real
-kernels for a described v5e is in ``test_flash_mosaic_compile.py``.
+the last bit. The same of a delta-rule block's ``kda_scan_fwd`` and a
+Mamba-2 block's ``ssd_scan_fwd``, whose differentiated forward names its
+output and the states that entered the chunks (each kernel file's ``KEPT``).
+Interpret-mode kernels on the CPU; the compile of the real kernels for a
+described v5e is in ``test_flash_mosaic_compile.py``.
 
 Bit-equality is asserted op by op (no outer ``jit``): each primitive then
 runs as its own program on both sides, and what is compared is the
 arithmetic, not which elementwise neighbours XLA:CPU chose to fuse into a
 matmul in two differently shaped programs."""
 
+import functools
 import re
 
 import numpy as np
@@ -21,6 +25,7 @@ import jax.numpy as jnp
 
 from hetu_galvatron_tpu.core.args_schema import ModelArgs
 from hetu_galvatron_tpu.models import modules as M
+from hetu_galvatron_tpu.ops.pallas import kda, ssd
 from hetu_galvatron_tpu.ops.pallas.flash_attention import (
     flash_sdpa,
     make_flash_sdpa,
@@ -29,11 +34,29 @@ from hetu_galvatron_tpu.ops.pallas.flash_attention import (
 pytestmark = pytest.mark.kernels
 
 B, S, H, N = 2, 128, 64, 2
+# the recurrent mixers at shapes their kernels' tiles fit (``tile_plan``),
+# two chunks a sequence so that a state enters the second: 2 heads of 128
+# at the cell's chunk of 64, and 8 heads of 16 with a state of 128 at a
+# chunk of one lane tile over twice the positions
 CFG = ModelArgs(
     hidden_size=H, num_hidden_layers=2, num_attention_heads=N, vocab_size=64,
-    max_position_embeddings=S, seq_length=S, hidden_act="swiglu",
+    max_position_embeddings=2 * S, seq_length=S, hidden_act="swiglu",
     normalization="rmsnorm", position_embedding_type="rope",
-    add_bias_linear=False, add_qkv_bias=False, make_vocab_size_divisible_by=1)
+    add_bias_linear=False, add_qkv_bias=False, make_vocab_size_divisible_by=1,
+    kda_num_heads=2, kda_head_dim=128, kda_chunk_size=64,
+    mamba_n_heads=8, mamba_d_head=16, mamba_d_state=128,
+    mamba_chunk_size=128)
+# mixer kind -> (the field of ``LayerOps`` its kernels go in, the kernels,
+# the same on a mesh, forward and backward kernel, positions, what the
+# differentiated forward names and the shape of each)
+SCANS = {
+    "kda": ("kda", kda.kda_scan, kda.make_kda_scan, "kda_scan_fwd",
+            "kda_scan_bwd", S, dict(zip(kda.KEPT, (
+                (B, S, 2, 128), (B, S // 64, 2, 128, 128))))),
+    "mamba": ("ssd", ssd.ssd_scan, ssd.make_ssd_scan, "ssd_scan_fwd",
+              "ssd_scan_bwd", 2 * S, dict(zip(ssd.KEPT, (
+                  (B, 2 * S, 128), (B, 2 * S // 128, 128, 128))))),
+}
 BASE_POLICIES = {
     "full": None,
     "dots": jax.checkpoint_policies.checkpoint_dots,
@@ -51,6 +74,11 @@ _CASES = {
     "dropout": (1, "full", ("dropout",)),
     "shard_map": (1, "full", ("shard_map",)),
     "shard_map_stack_dropout": (2, "full", ("shard_map", "dropout")),
+    **{f"{kind}_{case}": (blocks, policy, (kind,) + extras)
+       for kind in SCANS for case, (blocks, policy, extras) in {
+           "block": (1, "full", ()), "stack": (2, "full", ()),
+           "dots": (1, "dots", ()), "dots_no_batch": (1, "dots_no_batch", ()),
+           "shard_map": (1, "full", ("shard_map",))}.items()},
 }
 
 
@@ -63,18 +91,36 @@ _flash_interpret.supports_dropout = True
 _flash_interpret.supports_scale = True
 
 
-def _core(extras, cpu_devices):
-    """The block's attention core: the XLA one, the flash kernels, or the
-    flash kernels under ``make_flash_sdpa``'s ``shard_map`` (dp2 x tp2)."""
-    if "xla" in extras:
-        return M.xla_sdpa
-    if "shard_map" not in extras:
-        return _flash_interpret
+def _kind(extras):
+    """The blocks' mixer kind: a recurrent one where a case names it."""
+    return next((k for k in SCANS if k in extras), "full_attention")
+
+
+def _ops(extras, cpu_devices):
+    """What the block is handed: the XLA attention core, the flash kernels,
+    or the flash kernels under ``make_flash_sdpa``'s ``shard_map`` (dp2 x
+    tp2); for a recurrent mixer its scan's kernels, alone or under
+    ``on_shards`` over dp2, or nothing (``numpy``: the ``jax.numpy`` form)."""
     from jax.sharding import Mesh
 
+    kind = _kind(extras)
+    if kind in SCANS:
+        field, scan, on_mesh = SCANS[kind][:3]
+        if "numpy" in extras:
+            return M.LayerOps()
+        if "shard_map" not in extras:
+            return M.LayerOps(**{field: functools.partial(scan,
+                                                          interpret=True)})
+        return M.LayerOps(**{field: on_mesh(
+            Mesh(np.array(cpu_devices[:2]), ("dp",)), dp_axes=("dp",),
+            interpret=True)})
+    if "xla" in extras:
+        return M.LayerOps(sdpa=M.xla_sdpa)
+    if "shard_map" not in extras:
+        return M.LayerOps(sdpa=_flash_interpret)
     mesh = Mesh(np.array(cpu_devices[:4]).reshape(2, 2), ("dp", "tp"))
-    return make_flash_sdpa(mesh, dp_axes=("dp",), tp_axes=("tp",),
-                           interpret=True)
+    return M.LayerOps(sdpa=make_flash_sdpa(
+        mesh, dp_axes=("dp",), tp_axes=("tp",), interpret=True))
 
 
 def _stack(blocks, policy, extras, cpu_devices):
@@ -83,16 +129,18 @@ def _stack(blocks, policy, extras, cpu_devices):
     cfg = CFG.model_copy(update={
         "remat_policy": policy,
         "attention_dropout": 0.2 if "dropout" in extras else 0.0})
-    kwargs = {"ops": M.LayerOps(sdpa=_core(extras, cpu_devices)),
+    kind = _kind(extras)
+    seq = SCANS[kind][5] if kind in SCANS else S
+    kwargs = {"ops": _ops(extras, cpu_devices), "mixer": kind,
               "compute_dtype": jnp.float32}
     if "segments" in extras:
         # a document boundary inside a score tile
         kwargs["segment_ids"] = jnp.asarray(
             np.repeat([[0, 1], [0, 2]], [40, S - 40], axis=1)
             .reshape(B, S).astype(np.int32))
-    params = [M.init_decoder_layer(jax.random.key(i), cfg)[0]
+    params = [M.init_decoder_layer(jax.random.key(i), cfg, mixer=kind)[0]
               for i in range(blocks)]
-    x = jax.random.normal(jax.random.key(9), (B, S, H), jnp.float32)
+    x = jax.random.normal(jax.random.key(9), (B, seq, H), jnp.float32)
 
     def block(i):
         rng = (jax.random.fold_in(jax.random.key(3), i)
@@ -134,9 +182,14 @@ def test_a_rematted_block_runs_its_attention_core_once(cpu_devices, case):
     plain = loss(lambda fn: jax.checkpoint(fn, policy=BASE_POLICIES[policy]))
     jaxprs = {name: jax.make_jaxpr(jax.grad(fn))(params, x).jaxpr
               for name, fn in (("kept", kept), ("plain", plain))}
-    assert {name: _kernel_calls(j) for name, j in jaxprs.items()} == {
+    forward, *backward = (SCANS[_kind(extras)][3:5] if _kind(extras) in SCANS
+                          else ("flash_attention_fwd",
+                                "flash_attention_bwd_dq",
+                                "flash_attention_bwd_dkv"))
+    assert {name: _kernel_calls(j, forward)
+            for name, j in jaxprs.items()} == {
         "kept": blocks, "plain": 2 * blocks}
-    for kernel in ("flash_attention_bwd_dq", "flash_attention_bwd_dkv"):
+    for kernel in backward:
         assert _kernel_calls(jaxprs["kept"], kernel) == blocks
     ours = jax.value_and_grad(kept, argnums=(0, 1))(params, x)
     theirs = jax.value_and_grad(plain, argnums=(0, 1))(params, x)
@@ -159,30 +212,84 @@ def _kept_beside_the_arguments(capsys, fn, *args):
                   if " from the argument " not in l)
 
 
+def _shapes(kept):
+    """The shapes of ``_kept_beside_the_arguments``'s entries."""
+    return [tuple(int(d) for d in re.findall(r"\d+", k.split("[")[1]))
+            for k in kept]
+
+
+def _named(jaxpr):
+    """``{checkpoint_name: shape}`` of every value ``jaxpr`` names, in it and
+    in every jaxpr its equations hold."""
+    found = {}
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "name":
+            found[eqn.params["name"]] = tuple(eqn.outvars[0].aval.shape)
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (list, tuple)) else [v]):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    found.update(_named(sub))
+    return found
+
+
 @pytest.mark.parametrize("core,policy", [
     ("flash", "full"), ("flash_shard_map", "full"), ("xla", "full"),
-    ("xla", "dots_no_batch")])
+    ("xla", "dots_no_batch"),
+    ("kda", "full"), ("kda_shard_map", "full"), ("kda_numpy", "full"),
+    ("kda", "dots_no_batch"),
+    ("mamba", "full"), ("mamba_shard_map", "full"), ("mamba_numpy", "full"),
+    ("mamba", "dots_no_batch")])
 def test_what_a_rematted_block_keeps(cpu_devices, capsys, core, policy):
     """``full`` keeps the block's input and, of a flash core, the output as
     [B, S, N * Dv] rows (the bytes of the input) and lse as [B, N, S] rows
-    (never the [B, N, S, 1] column HBM pads 128 times); a block on the XLA
-    core names nothing and keeps what the base policy keeps."""
-    extras = {"flash": (), "flash_shard_map": ("shard_map",),
-              "xla": ("xla",)}[core]
+    (never the [B, N, S, 1] column HBM pads 128 times); of a recurrent
+    mixer's scan kernels the output and the entering states, float32 as the
+    kernel wrote them, by the names of the kernel file's ``KEPT``; a block on
+    the XLA core or on a mixer's ``jax.numpy`` form names nothing and keeps
+    what the base policy keeps."""
+    kind, _, how = core.partition("_")
+    extras = {"flash": (), "xla": ("xla",)}.get(kind, (kind,)) + (
+        (how,) if how else ())
     _, params, x, cfg, block = _stack(1, policy, extras, cpu_devices)
     kept = _kept_beside_the_arguments(capsys, M.remat(block(0), cfg),
                                       params[0], x)
-    if core == "xla":
+    if kind == "xla" or how == "numpy":
         plain = _kept_beside_the_arguments(
             capsys, jax.checkpoint(block(0), policy=BASE_POLICIES[policy]),
             params[0], x)
-        assert kept == plain and (policy != "full" or kept == [])
+        assert kept == plain and (kind != "xla" or policy != "full"
+                                  or kept == [])
+        assert not set(_named(jax.make_jaxpr(jax.grad(
+            lambda p, h: jnp.sum(block(0)(p, h))))(params[0], x).jaxpr)) & {
+                *kda.KEPT, *ssd.KEPT}
+        return
+    if kind in SCANS:
+        # what the differentiated forward names, shard by shard under
+        # ``shard_map`` (the batch over dp2) ...
+        named = dict(SCANS[kind][6])
+        traced = _named(jax.make_jaxpr(jax.grad(
+            lambda p, h: jnp.sum(block(0)(p, h))))(params[0], x).jaxpr)
+        if how == "shard_map":
+            named = {n: (sh[0] // 2,) + sh[1:] for n, sh in named.items()}
+        assert traced == named
+        # ... and under ``full`` nothing else of the block is kept: the
+        # bytes of the pair (a shard_map's residuals are its shards' laid
+        # side by side on a new leading axis: the same numbers)
+        plain = _kept_beside_the_arguments(
+            capsys, jax.checkpoint(block(0), policy=BASE_POLICIES[policy]),
+            params[0], x)
+        sizes = sorted(int(np.prod(sh)) for sh in SCANS[kind][6].values())
+        ours, theirs = ([int(np.prod(sh)) for sh in _shapes(k)]
+                        for k in (kept, plain))
+        assert all(k.startswith("f32[") for k in kept if k not in plain)
+        assert sorted(ours) == sorted(theirs + sizes)
+        assert policy != "full" or sorted(ours) == sizes
         return
     if core == "flash":
         assert kept == sorted([f"f32[{B},{S},{H}]", f"f32[{B},{N},{S}]"])
     # (a shard_map's residuals are its shards' laid side by side on a new
     # leading axis: the same numbers, and no trailing singleton either)
-    shapes = [tuple(int(d) for d in re.findall(r"\d+", k.split("[")[1]))
-              for k in kept]
+    shapes = _shapes(kept)
     assert sorted(int(np.prod(sh)) for sh in shapes) == [B * N * S, B * S * H]
     assert all(len(sh) == 3 and sh[-1] > 1 for sh in shapes), shapes

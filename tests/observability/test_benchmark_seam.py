@@ -360,7 +360,30 @@ def test_the_step_report_counts_the_cores_remat_runs_again(run):
     (report,) = [line for line in run["log"].splitlines()
                  if "step report:" in line]
     assert gauges == [0] and run["result"]["cores_recomputed"] == 0
-    assert ", 0 cores recomputed, static live peak " in report
+    assert ", 0 cores recomputed, 0 scans recomputed, static live" in report
+
+
+def test_the_step_report_counts_the_scans_remat_runs_again(run):
+    """``step/scans_recomputed`` beside it: the recurrent mixers' scan
+    forward kernels the compiled step holds in its recompute phase, of the
+    Granite preset's mamba block as of a preset without one. 0 on a step
+    compiled for a CPU (what it is on a step that holds the kernels:
+    ``test_step_map.py::test_scans_recomputed_*`` and the compile for a
+    described v5e); the gauge and ``train()``'s result say the same."""
+    gauges = [m.value for m in run["registry"].metrics()
+              if m.name == "step/scans_recomputed"]
+    assert gauges == [0] and run["result"]["scans_recomputed"] == 0
+    if run["preset"] == "granite":
+        assert run["result"]["ssd_mosaic_calls"] == 0
+
+
+def test_the_kimi_preset_reports_its_scans_too(kimi_run):
+    gauges = [m.value for m in kimi_run["registry"].metrics()
+              if m.name == "step/scans_recomputed"]
+    (report,) = [line for line in kimi_run["log"].splitlines()
+                 if "step report:" in line]
+    assert gauges == [0] and kimi_run["result"]["scans_recomputed"] == 0
+    assert ", 0 scans recomputed, static live peak " in report
 
 
 def test_a_mosaic_call_is_counted_under_its_scope():

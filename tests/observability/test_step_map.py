@@ -251,6 +251,49 @@ def test_cores_recomputed_counts_the_flash_forwards_of_the_recompute_phase(
     assert trace_analysis.cores_recomputed(found) == expected
 
 
+# two recurrent blocks' scan kernels as a step holds them: each forward in
+# the forward pass, a second one in the recomputed forward (what plain
+# jax.checkpoint leaves there), the backward by the scope its rule opens
+_SCANS = {
+    f"{call}_scan_{way}": stack.format(scope=scope, inner=inner, call=call)
+    for call, scope, inner in (("kda", "mixer/kda", "scan"),
+                               ("ssd", "mixer/mamba", "ssd"))
+    for way, stack in (
+        ("fwd.1", "jvp({scope})/{inner}/{call}_scan_fwd"),
+        ("fwd.2", "transpose(jvp({scope}))/checkpoint/rematted_computation/"
+                  "{inner}/{call}_scan_fwd"),
+        ("bwd.1", "transpose(jvp())/checkpoint/{scope}/{inner}/"
+                  "{call}_scan_bwd"))}
+
+
+@pytest.mark.parametrize("left_out,expected", [
+    ((), 2),                                        # both scans run again
+    (("kda_scan_fwd.2",), 1),                       # the delta rule's kept
+    (("kda_scan_fwd.2", "ssd_scan_fwd.2"), 0),      # both kept
+    (tuple(_SCANS), 0),                             # a step without kernels
+])
+def test_scans_recomputed_counts_the_scan_forwards_of_the_recompute_phase(
+        left_out, expected):
+    """Over a hand-made map: forward, recompute, backward. A recomputed
+    flash forward beside them is ``cores_recomputed``'s and not counted."""
+    calls = {**_SCANS, "flash_attention_fwd.2": _CORES[
+        "flash_attention_fwd.2"]}
+    hlo = ("HloModule jit_step\n\nENTRY %main (a: f32[8]) -> f32[8] {\n"
+           "  %a = f32[8]{0} parameter(0)\n"
+           + "".join(_KERNEL.format(name=n, stack=s)
+                     for n, s in calls.items() if n not in left_out)
+           + "  ROOT %copy.1 = f32[8]{0} copy(%a)\n}\n")
+    found = step_hlo(hlo)
+    placed = found["map"]["instructions"]
+    assert {placed[n][1] for n in calls if n not in left_out} <= {
+        "forward", "recompute", "backward"}
+    assert all(placed[n][1] == {"fwd.1": "forward", "fwd.2": "recompute",
+                                "bwd.1": "backward"}[n.split("_")[-1]]
+               for n in _SCANS if n not in left_out)
+    assert trace_analysis.scans_recomputed(found) == expected
+    assert trace_analysis.cores_recomputed(found) == 1
+
+
 # four chips: a collective under its own name, XLA:TPU's three fusions of an
 # asynchronous all-gather (the middle one rides a matmul), a reduce-scatter
 # fused as an all-reduce and a slice, an asynchronous pair under its own names
