@@ -184,6 +184,7 @@ def train(args) -> Dict[str, Any]:
         from hetu_galvatron_tpu.models.modules import MIXERS
 
         from hetu_galvatron_tpu.ops.pallas.flash_attention import (
+            LAYOUT_CALLS,
             WINDOWED_CALLS,
             band_tiles,
             effective_window,
@@ -212,9 +213,11 @@ def train(args) -> Dict[str, Any]:
                     w or 0)
                 get_registry().gauge("attn/heads", layer=f"layer{i}").set(
                     cfg.block_heads(i))
-        # the windowed flash calls this run's step is built of are recorded
-        # from here on (the step report reads them)
+        # the flash calls this run's step is built of are recorded from here
+        # on: those with a window, and all of them by the layout the kernels
+        # index (the step report reads them)
         WINDOWED_CALLS.clear()
+        LAYOUT_CALLS.clear()
         # how many blocks of each mixer and feed-forward kind the step holds
         blocks = {}
         for (m, ff), n in Counter(kinds).items():
@@ -1215,6 +1218,16 @@ def train(args) -> Dict[str, Any]:
                             100.0 * visited / triangle if triangle else 100.0)
                         get_registry().gauge("flash/band_tiles_pct").set(
                             step_report["band_tiles_pct"])
+                    # the distinct flash calls the step was built with, by
+                    # what the kernels index: the projections' own rows, or
+                    # head-major copies between transposes
+                    # (``flash_attention.row_layout`` of the call's widths)
+                    for path, gauge in (("rows", "row_layout_calls"),
+                                        ("transposed", "transposed_calls")):
+                        step_report[gauge] = sum(
+                            c[0] == path for c in LAYOUT_CALLS)
+                        get_registry().gauge(f"flash/{gauge}").set(
+                            step_report[gauge])
                     step_report["static_memory"] = compiled_memory_bytes(
                         compiled)
                     for part, v in step_report["static_memory"].items():
@@ -1250,6 +1263,11 @@ def train(args) -> Dict[str, Any]:
                     + (", flash/band_tiles_pct "
                        f"{step_report['band_tiles_pct']:.1f}"
                        if "band_tiles_pct" in step_report else "")
+                    + (", flash/row_layout_calls "
+                       f"{step_report['row_layout_calls']}"
+                       ", flash/transposed_calls "
+                       f"{step_report['transposed_calls']}"
+                       if "row_layout_calls" in step_report else "")
                     + f", {step_report['cores_recomputed']} cores recomputed,"
                     f" {step_report['scans_recomputed']} scans recomputed,"
                     f" static live peak "
@@ -1342,6 +1360,12 @@ def train(args) -> Dict[str, Any]:
             # tiles the step's flash calls were built with (the gauge
             # flash/band_tiles_pct); None for a model without such a block
             "band_tiles_pct": step_report.get("band_tiles_pct"),
+            # the distinct flash calls of that step whose kernels index the
+            # projections' rows, and those that run between transposes (the
+            # gauges flash/row_layout_calls and flash/transposed_calls)
+            "flash_layout_calls": {
+                k: step_report.get(f"{k}_calls")
+                for k in ("row_layout", "transposed")},
             "exit_code": exit_code}
 
 

@@ -5,12 +5,34 @@ checklist item 4; installed by galvatron/scripts/flash_attn_ops_install.sh)
 with three TPU kernels. The [S, S] score matrix never exists: every kernel
 works on one ``block_q x block_k`` score tile at a time.
 
-Layout: q [B, N, S, D], k [B, K, S, D], v [B, K, S, Dv] (heads-major so a
-grid cell's tiles are contiguous); GQA maps q-head n to kv-head n // (N // K)
-in the index map. v has a width of its own (latent attention: q/k 192, v
-128): the output, dO, dv and their accumulators are ``Dv`` wide, q, k, dq and
-dk ``D`` wide, and no operand is padded to the other's width. With
-``Dv == D`` every block and every scratch is what it was.
+Layout. A call hands the kernels what the projections wrote: q
+[B, S, N, D] is the bytes of [B, S, N * D] rows, and a (1, block_q, 128)
+block at column block ``c`` of that array is a legal, unpadded Mosaic tile.
+At head widths of whole lane tiles (128, 256) column block ``n`` IS head
+``n`` and the kernel bodies see the [block_q, D] tiles they always saw;
+only the ``BlockSpec``s index otherwise. At width 64 a column block holds
+heads ``2p`` and ``2p + 1`` side by side and one grid step serves the pair:
+head ``h``'s scores are ``where(lane is h's, q2, 0) . k2^T`` over the whole
+128-deep contraction (the other head's lanes add exact zeros, and the
+MXU's depth, half empty at width 64, costs the passes it did), each head
+has statistics and an accumulator of its own, and its half of the result
+is selected at the end. Under GQA a pair's key/value heads are one head or
+the two halves of ONE key/value column block; a query head whose half is
+not its key/value head's is moved there once a q tile (``_half_view``).
+Selects, never products: the last column block of an odd count of heads
+(GPT-2 XL's 25) is half outside the array and holds anything there. GQA
+maps q-head n to kv-head n // (N // K) in the index map. Outputs, dq, dk
+and dv come out as rows too, so nothing is transposed around a call
+(``row_layout`` decides from the widths alone; twelve transposes a layer
+under per-layer remat until PR 50, each a copy XLA could fuse into
+nothing, of arrays that head-major at width 64 are padded to twice their
+numbers). Every other width (192 of the latent cores) and the ring
+(``ops/ring_attention.py``, on head-major blocks of its own) run the same
+kernel bodies on head-major [B, N, S, D] operands between transposes
+(``flash_attention_hmajor`` / ``flash_attention_bwd_hmajor``), the program
+they always were. v has a width of its own (latent attention: q/k 192, v
+128): the output, dO, dv and their accumulators are ``Dv`` wide, q, k, dq
+and dk ``D`` wide, and no operand is padded to the other's width.
 
 The tile loop. A grid step of the forward and of the dq kernel holds one q
 tile and a MAJOR block of K and V (the whole length where it fits
@@ -43,22 +65,31 @@ carries those names, so the recomputed forward of a block has no forward
 kernel left in it: the S x S work of a layer runs once. Both are kept in
 the layout HBM does not pad. The output as [B, S, N * Dv] rows, the form
 the out-projection reads and exactly the bytes of the block's input, which
-remat keeps anyway: head-major [B, N, S, Dv] at a head width of 64 is
-padded to 128 lanes, twice its numbers (0.83 GiB against 0.41 over GPT-2
-XL's 16 layers at batch 8; AOT, PR 41). lse as [B, N, S] rows, not as the
-[B, N, S, 1] column the kernel writes: a trailing singleton is padded 128
-times (64 to 105 MiB a call at the benchmark's shapes, more than the
-output). The backward turns the rows back to head-major where the
-recomputed forward used to turn the kernel's output the other way, one
-relayout either way; the dk/dv kernel reads lse as rows anyway and the dq
-kernel gets its column by ``[..., None]``. q, k and v are not named: the
-projection recomputes them as before.
+remat keeps anyway: on rows it is the forward kernel's own result and the
+backward kernels read it as it lies; on the head-major path it is the
+kernel's output turned once, and the backward turns it back (head-major
+[B, N, S, Dv] at a head width of 64 is padded to 128 lanes, twice its
+numbers: 0.83 GiB against 0.41 over GPT-2 XL's 16 layers at batch 8; AOT,
+PR 41). lse as [B, N, S] rows, not as the [B, N, S, 1] column the kernel
+writes: a trailing singleton is padded 128 times (64 to 105 MiB a call at
+the benchmark's shapes, more than the output); the dk/dv kernel reads lse
+as rows anyway and the dq kernel gets its column by ``[..., None]`` (two
+relayouts of a padded array a layer, which a forward that wrote the rows
+itself would save: PERF.md section 7). An odd count of paired heads keeps
+the statistics of one head more. delta = rowsum(dO . O) is dk/dv's other
+row statistic: on the head-major path XLA sums it; on rows the dq kernel,
+which forms it for its own tile anyway, writes it as the rows the dk/dv
+kernel reads (XLA would first turn the whole f32 product to put the
+positions along lanes). q, k and v are not named: the projection
+recomputes them as before.
 """
 
 from __future__ import annotations
 
 import functools
 import math
+import operator
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -242,74 +273,211 @@ def _needed_k_major(qi, kj, block_q: int, major: int, causal: bool,
     return kj
 
 
+def _tile(ref, rows=slice(None)):
+    """The [rows, width] tile of a block whose leading dims are all 1: one
+    head's of a head-major [B, N, S, width] array, or a column block's of
+    the projections' rows [B, S, N * width]."""
+    return ref[(0,) * (len(ref.shape) - 2) + (rows, slice(None))]
+
+
+def _put(ref, value):
+    """``value`` as the [rows, width] tile of a block (``_tile``)."""
+    ref[(0,) * (len(ref.shape) - 2) + (slice(None), slice(None))] = value
+
+
+# two heads of HALF lanes lie side by side in one 128-lane column block of
+# the projections' rows where a head is 64 wide
+HALF = LANES // 2
+
+
+def _half_view(x, own, at, there=True):
+    """The 64-lane half ``own`` of a [rows, 128] tile ``x`` at half ``at``,
+    zeros in the other half (and everywhere for a head that is not
+    ``there``). Selects, not products: a lane past the array's last head
+    holds anything. ``own``, ``at`` and ``there`` may be traced scalars."""
+    same = at == own
+    if same is True:
+        moved = x
+    else:
+        # halves exchanged (a lane rotation by half a tile, written as two
+        # slices: Mosaic rotates 32-bit lanes only)
+        moved = jnp.concatenate([x[:, HALF:], x[:, :HALF]], axis=1)
+        if same is not False:
+            moved = jnp.where(same, x, moved)
+    keep = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1) // HALF == at
+    if there is not True:
+        keep &= there
+    return jnp.where(keep, moved, jnp.zeros_like(moved))
+
+
+class _Pairs(NamedTuple):
+    """A call on column blocks of two 64-wide heads: query column block
+    ``p`` holds heads ``2p`` and ``2p + 1``, key/value column block ``c``
+    key/value heads ``2c`` and ``2c + 1``. A query pair's two key/value
+    heads are one head or the two halves of one column block
+    (``row_layout`` admits an odd count of key/value heads only without
+    groups), so a grid step reads one k/v block for both."""
+
+    N: int  # query heads
+    K: int  # key/value heads
+
+    @property
+    def G(self):
+        return self.N // self.K
+
+    def kv_block(self, p):
+        """Column block of the key/value heads of query pair ``p``."""
+        return (2 * p // self.G) // 2
+
+    def heads(self, b, p):
+        """The two heads of query pair ``p`` of batch row ``b``: (flat
+        batch * heads index for dropout's counter, half of the query
+        block, half of the key/value block, whether the head exists)."""
+        found = []
+        for h in range(2):
+            n = 2 * p + h
+            found.append(_Head(
+                b * self.N + n, h,
+                h if self.G == 1 else (n // self.G) % 2,
+                True if h == 0 or self.N % 2 == 0 else n < self.N))
+        return found
+
+    def kv_clean(self, x, c):
+        """A k/v tile of column block ``c`` with the lanes past the last
+        key/value head (an odd count's last block) as zeros: the tile goes
+        whole into contractions against a view's zeros."""
+        if self.K % 2 == 0:
+            return x
+        # (a select on every tile: a branch taken for the last block alone
+        # measured slower on the chip, PR 50)
+        lower = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1) < HALF
+        return jnp.where(lower | (2 * c + 1 < self.K), x, jnp.zeros_like(x))
+
+
+class _Head(NamedTuple):
+    bn: "jax.Array"          # batch * heads index, dropout's counter
+    own: "int | None" = None  # its half of a query column block
+    at: "int | jax.Array | None" = None  # its key/value head's half
+    there: "bool | jax.Array" = True
+
+    def view(self, x):
+        """The head's numbers of a query-side tile where its key/value
+        head's lie (the tile itself where a block is one head)."""
+        return x if self.own is None else _half_view(
+            x, self.own, self.at, self.there)
+
+    def back(self, x):
+        """A result computed against the key/value block, in the head's own
+        half of the query block."""
+        return x if self.own is None else _half_view(x, self.at, self.own)
+
+
+def _sum_of(parts):
+    return functools.reduce(operator.add, parts)
+
+
+def _of(ref, i: int, pair):
+    """Head ``i``'s part of a scratch buffer: the buffer where a block is
+    one head, else its ``i``-th leading slice."""
+    return ref if pair is None else ref.at[i]
+
+
 def _flash_kernel(q_ref, k_ref, v_ref, *rest,
                   block_q: int, block_k: int, chunks: int, num_major: int,
                   causal: bool, scale: float, has_seg: bool = False,
-                  dropout_rate: float = 0.0, window: "int | None" = None):
+                  dropout_rate: float = 0.0, window: "int | None" = None,
+                  pair: "_Pairs | None" = None):
     """Grid (B, N, q block, k major block). One step holds a q tile and
     ``chunks`` k/v chunks of ``block_k`` rows and loops over the chunks the
     causal mask leaves, so a step past the diagonal neither fetches nor
-    computes and only the chunks that cross the diagonal build a mask."""
+    computes and only the chunks that cross the diagonal build a mask.
+    With ``pair`` the second grid axis runs over pairs of 64-wide heads
+    and a step serves both: each head's query view (zeros in the other
+    head's lanes, so the 128-deep contraction adds exact zeros) is made
+    once a q tile, and each head has statistics and an accumulator of its
+    own, whose half under its key/value head is the result."""
     if dropout_rate > 0.0:
         seed_ref, rest = rest[0], rest[1:]
     else:
         seed_ref = None
     if has_seg:
-        qseg_ref, kseg_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref = rest
+        qseg_ref, kseg_ref, *rest = rest
     else:
         qseg_ref = kseg_ref = None
-        o_ref, lse_ref, m_ref, l_ref, acc_ref = rest
+    o_ref, lse_ref, m_ref, l_ref, acc_ref, *views = rest
     kj = pl.program_id(3)
     q0 = pl.program_id(2) * block_q
     lo = kj * chunks
     # flat batch*heads index for the dropout mask; program_id must be read
     # at kernel top level (the interpret-mode executor does not rewrite it
     # inside pl.when bodies)
-    bn = pl.program_id(0) * pl.num_programs(1) + pl.program_id(1)
+    if pair is None:
+        heads = [_Head(pl.program_id(0) * pl.num_programs(1)
+                       + pl.program_id(1))]
+    else:
+        heads = pair.heads(pl.program_id(0), pl.program_id(1))
+        kv_block = pair.kv_block(pl.program_id(1))
 
     @pl.when(kj == 0)
     def _init():
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
+        if pair is not None:
+            for i, head in enumerate(heads):
+                views[0][i] = head.view(_tile(q_ref))
 
     def chunk(c, masked):
         rows = pl.ds(pl.multiple_of(c * block_k, block_k), block_k)
         k0 = (lo + c) * block_k
-        v = v_ref[0, 0, rows, :]
-        s = _scores(q_ref[0, 0], k_ref[0, 0, rows, :], q0, k0,
-                    qseg_ref[0] if has_seg else None,
-                    kseg_ref[0, c] if has_seg else None,
-                    masked=masked, scale=scale, window=window)
-        # the running max is kept replicated along LANES lanes, so it
-        # meets the score tile and the accumulator without a relayout; the
-        # cross-lane max is the one reduction a chunk pays
-        m = m_ref[...]
-        new_m = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
-        corr = jnp.exp(m - new_m)
-        p = jnp.exp(s - _across(new_m, block_k))
-        m_ref[...] = new_m
-        # the normalizer (of the UNdropped p: out = dropout(softmax(s)) @ v)
-        # is kept as LANES partial sums a row, added up once at the end
-        l_ref[...] = l_ref[...] * corr + _lane_sums(p)
-        if dropout_rate > 0.0:
-            keep = keep_mask(seed_ref[0], bn, *_tile_pos(q0, k0, s.shape),
-                             dropout_rate)
-            p = jnp.where(keep, p / (1.0 - dropout_rate), 0.0)
-        acc_ref[...] = (acc_ref[...] * _across(corr, acc_ref.shape[1])
-                        + _dot(p.astype(v.dtype), v, _NN))
+        v = _tile(v_ref, rows)
+        if pair is None:
+            q, k = _tile(q_ref), _tile(k_ref, rows)
+        else:
+            k = pair.kv_clean(_tile(k_ref, rows), kv_block)
+            v = pair.kv_clean(v, kv_block)
+        for i, head in enumerate(heads):
+            m_i, l_i, acc_i = (_of(r, i, pair)
+                               for r in (m_ref, l_ref, acc_ref))
+            s = _scores(q if pair is None else views[0][i], k, q0, k0,
+                        qseg_ref[0] if has_seg else None,
+                        kseg_ref[0, c] if has_seg else None,
+                        masked=masked, scale=scale, window=window)
+            # the running max is kept replicated along LANES lanes, so it
+            # meets the score tile and the accumulator without a relayout;
+            # the cross-lane max is the one reduction a chunk pays
+            m = m_i[...]
+            new_m = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+            corr = jnp.exp(m - new_m)
+            p = jnp.exp(s - _across(new_m, block_k))
+            m_i[...] = new_m
+            # the normalizer (of the UNdropped p: out = dropout(softmax(s))
+            # @ v) is kept as LANES partial sums a row, added up once at
+            # the end
+            l_i[...] = l_i[...] * corr + _lane_sums(p)
+            if dropout_rate > 0.0:
+                keep = keep_mask(seed_ref[0], head.bn,
+                                 *_tile_pos(q0, k0, s.shape), dropout_rate)
+                p = jnp.where(keep, p / (1.0 - dropout_rate), 0.0)
+            acc_i[...] = (acc_i[...] * _across(corr, acc_i.shape[1])
+                          + _dot(p.astype(v.dtype), v, _NN))
 
     _for_k_chunks(chunk, q0, block_q, block_k, lo, chunks, causal, window)
 
     @pl.when(kj == num_major - 1)
     def _finalize():
-        l = jnp.maximum(jnp.sum(l_ref[...], axis=1, keepdims=True), 1e-20)
-        o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
+        ls = [jnp.maximum(jnp.sum(_of(l_ref, i, pair)[...], axis=1,
+                                  keepdims=True), 1e-20)
+              for i in range(len(heads))]
+        _put(o_ref, _sum_of(
+            head.back(_of(acc_ref, i, pair)[...] / ls[i])
+            for i, head in enumerate(heads)).astype(o_ref.dtype))
         # logsumexp per row, consumed by the backward kernels; stored with a
         # trailing singleton lane dim — Mosaic requires the last two block
         # dims to be (mult-of-8, mult-of-128) or equal to the array dims, so
         # a rank-3 (1, 1, block_q) lse block cannot lower on hardware
-        lse_ref[0, 0] = m_ref[:, :1] + jnp.log(l)
+        for i, l in enumerate(ls):
+            lse_ref[0, i] = _of(m_ref, i, pair)[:, :1] + jnp.log(l)
 
 
 # what one grid step may keep resident of each streamed operand (bytes): K
@@ -360,9 +528,139 @@ def _segment_operands(segments, block: int):
     return seg[:, :, None], _chunk_rows(seg, block)
 
 
-@functools.partial(jax.jit, static_argnames=("causal", "block_q", "block_k",
-                                             "interpret", "dropout_rate",
-                                             "scale", "window"))
+def row_layout(N: int, K: int, D: int, Dv: int) -> "int | None":
+    """Heads a column block holds where the kernels can index the
+    projections' rows ``[B, S, N * D]`` as they are: 1 at widths of whole
+    lane tiles, 2 at width 64 (a pair reads ONE key/value column block,
+    which an odd count of key/value heads allows only without groups), None
+    where the call keeps the head-major path. From the shapes alone."""
+    if D % LANES == 0 and Dv % LANES == 0:
+        return 1
+    if D == Dv == HALF and (K % 2 == 0 or N == K):
+        return 2
+    return None
+
+
+def _block(rows_layout: bool, rows: int, width: int, index):
+    """BlockSpec of a [rows, width] tile at the (batch, head or column
+    block, row block) that ``index`` makes of the grid's indices: a block
+    of a head-major [B, N, S, width] array, or of the projections' rows
+    [B, S, N * width], where a head (a pair) is a column block."""
+    if rows_layout:
+        def index_map(*grid):
+            b, col, row = index(*grid)
+            return b, row, col
+
+        return pl.BlockSpec((1, rows, width), index_map)
+    return pl.BlockSpec((1, 1, rows, width), lambda *grid: (*index(*grid), 0))
+
+
+def _kv_col(n, G: int, pair):
+    """The key/value head of query head ``n``, or the key/value column
+    block of query pair ``n``."""
+    return n // G if pair is None else pair.kv_block(n)
+
+
+def _call_shapes(q, k, v, heads):
+    """(B, N, K, S, Sk, D, Dv, heads a block, ``_Pairs`` or None) of a
+    call: head-major operands (``heads`` None), or rows with their
+    (query, key/value) head counts."""
+    if heads is None:
+        (B, N, S, D), (_, K, Sk, _), Dv = q.shape, k.shape, v.shape[3]
+        return B, N, K, S, Sk, D, Dv, 1, None
+    (B, S, _), Sk, (N, K) = q.shape, k.shape[1], heads
+    D, Dv = q.shape[2] // N, v.shape[2] // K
+    per = row_layout(N, K, D, Dv)
+    return B, N, K, S, Sk, D, Dv, per, _Pairs(N, K) if per == 2 else None
+
+
+def _forward_call(q, k, v, segments, dropout_seed, *, heads, causal, block_q,
+                  block_k, interpret, dropout_rate, scale, window):
+    B, N, K, S, Sk, D, Dv, per, pair = _call_shapes(q, k, v, heads)
+    G = N // K
+    block_q = min(block_q, S)
+    block_k = min(block_k, Sk)
+    _check_call(S, Sk, block_q, block_k, causal, segments, dropout_rate,
+                dropout_seed, window)
+    chunks = _major_chunks(Sk, block_k, max(D, Dv) * k.dtype.itemsize)
+    major = chunks * block_k
+    num_major = Sk // major
+    cols = -(-N // per)  # heads, or pairs of them
+    grid = (B, cols, S // block_q, num_major)  # k major axis innermost
+    has_seg = segments is not None
+    kernel = functools.partial(
+        _flash_kernel, block_q=block_q, block_k=block_k, chunks=chunks,
+        num_major=num_major, causal=causal,
+        scale=1.0 / math.sqrt(D) if scale is None else scale,
+        has_seg=has_seg, dropout_rate=dropout_rate, window=window, pair=pair)
+    block = functools.partial(_block, heads is not None)
+
+    def kj_of(qi, kj):
+        return _needed_k_major(qi, kj, block_q, major, causal, window)
+
+    def q_tile(b, n, qi, kj):
+        return b, n, qi
+
+    def kv_rows(b, n, qi, kj):
+        return b, _kv_col(n, G, pair), kj_of(qi, kj)
+
+    in_specs = [block(block_q, D * per, q_tile),
+                block(major, D * per, kv_rows),
+                block(major, Dv * per, kv_rows)]
+    operands = [q, k, v]
+    if dropout_rate > 0.0:
+        # kernel unpacks the seed ref FIRST from *rest (after q/k/v)
+        in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
+        operands.append(dropout_seed.astype(jnp.int32).reshape(1))
+    if has_seg:
+        in_specs += [
+            pl.BlockSpec((1, block_q, 1), lambda b, n, qi, kj: (b, qi, 0)),
+            pl.BlockSpec((1, chunks, 1, block_k),
+                         lambda b, n, qi, kj: (b, kj_of(qi, kj), 0, 0)),
+        ]
+        operands += list(_segment_operands(segments, block_k))
+    # statistics and accumulator of each head of a block, and of a pair the
+    # two query views
+    of_head = () if pair is None else (per,)
+    return pl.pallas_call(
+        kernel,
+        grid=grid,
+        in_specs=in_specs,
+        out_specs=[
+            block(block_q, Dv * per, q_tile),
+            pl.BlockSpec((1, per, block_q, 1),
+                         lambda b, n, qi, kj: (b, n, qi, 0)),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct(
+                (B, N, S, Dv) if heads is None else (B, S, N * Dv), q.dtype),
+            # (an odd count of paired heads has the statistics of one more)
+            jax.ShapeDtypeStruct((B, cols * per, S, 1), jnp.float32),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((*of_head, block_q, LANES), jnp.float32),
+            pltpu.VMEM((*of_head, block_q, LANES), jnp.float32),
+            pltpu.VMEM((*of_head, block_q, Dv * per), jnp.float32),
+        ] + ([] if pair is None else [
+            pltpu.VMEM((per, block_q, LANES), q.dtype)]),
+        # only the k axis carries loop state (the online softmax);
+        # everything else may be reordered/partitioned by Mosaic
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary")),
+        interpret=interpret,
+        # the kernel's instruction name on a trace's ``XLA Ops`` line; the
+        # three kernels differ after ``flash_attention`` so that a trace
+        # tells them apart and a ``^flash_attention`` pattern finds all
+        name="flash_attention_fwd",
+    )(*operands)
+
+
+_CALL_STATICS = ("causal", "block_q", "block_k", "interpret", "dropout_rate",
+                 "scale", "window")
+
+
+@functools.partial(jax.jit, static_argnames=_CALL_STATICS)
 def flash_attention_hmajor(
     q: jax.Array,  # [B, N, S, D]
     k: jax.Array,  # [B, K, S, D]
@@ -378,79 +676,29 @@ def flash_attention_hmajor(
     scale: "float | None" = None,  # softmax(scale * q.k^T); None = D ** -0.5
     window: "int | None" = None,  # keys a query meets at most, itself included
 ) -> jax.Array:
-    B, N, S, D = q.shape
-    Dv = v.shape[3]
-    K = k.shape[1]
-    Sk = k.shape[2]  # may differ from S (ring off-diagonal blocks)
-    G = N // K
-    block_q = min(block_q, S)
-    block_k = min(block_k, Sk)
-    _check_call(S, Sk, block_q, block_k, causal, segments, dropout_rate,
-                dropout_seed, window)
-    chunks = _major_chunks(Sk, block_k, max(D, Dv) * k.dtype.itemsize)
-    major = chunks * block_k
-    num_major = Sk // major
-    grid = (B, N, S // block_q, num_major)  # k major axis innermost
-    has_seg = segments is not None
-    kernel = functools.partial(
-        _flash_kernel, block_q=block_q, block_k=block_k, chunks=chunks,
-        num_major=num_major, causal=causal,
-        scale=1.0 / math.sqrt(D) if scale is None else scale,
-        has_seg=has_seg, dropout_rate=dropout_rate, window=window)
+    """(out [B, N, S, Dv], lse [B, N, S, 1]) on head-major operands; Sk may
+    differ from S (ring off-diagonal blocks)."""
+    return _forward_call(
+        q, k, v, segments, dropout_seed, heads=None, causal=causal,
+        block_q=block_q, block_k=block_k, interpret=interpret,
+        dropout_rate=dropout_rate, scale=scale, window=window)
 
-    def kj_of(qi, kj):
-        return _needed_k_major(qi, kj, block_q, major, causal, window)
 
-    in_specs = [
-        pl.BlockSpec((1, 1, block_q, D),
-                     lambda b, n, qi, kj: (b, n, qi, 0)),
-        pl.BlockSpec((1, 1, major, D),
-                     lambda b, n, qi, kj: (b, n // G, kj_of(qi, kj), 0)),
-        pl.BlockSpec((1, 1, major, Dv),
-                     lambda b, n, qi, kj: (b, n // G, kj_of(qi, kj), 0)),
-    ]
-    operands = [q, k, v]
-    if dropout_rate > 0.0:
-        # kernel unpacks the seed ref FIRST from *rest (after q/k/v)
-        in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
-        operands.append(dropout_seed.astype(jnp.int32).reshape(1))
-    if has_seg:
-        in_specs += [
-            pl.BlockSpec((1, block_q, 1), lambda b, n, qi, kj: (b, qi, 0)),
-            pl.BlockSpec((1, chunks, 1, block_k),
-                         lambda b, n, qi, kj: (b, kj_of(qi, kj), 0, 0)),
-        ]
-        operands += list(_segment_operands(segments, block_k))
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((1, 1, block_q, Dv),
-                         lambda b, n, qi, kj: (b, n, qi, 0)),
-            pl.BlockSpec((1, 1, block_q, 1),
-                         lambda b, n, qi, kj: (b, n, qi, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B, N, S, Dv), q.dtype),
-            jax.ShapeDtypeStruct((B, N, S, 1), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_q, LANES), jnp.float32),
-            pltpu.VMEM((block_q, LANES), jnp.float32),
-            pltpu.VMEM((block_q, Dv), jnp.float32),
-        ],
-        # only the k axis carries loop state (the online softmax);
-        # everything else may be reordered/partitioned by Mosaic
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary")),
-        interpret=interpret,
-        # the kernel's instruction name on a trace's ``XLA Ops`` line; the
-        # three kernels differ after ``flash_attention`` so that a trace
-        # tells them apart and a ``^flash_attention`` pattern finds all
-        name="flash_attention_fwd",
-    )(*operands)
+@functools.partial(jax.jit, static_argnames=_CALL_STATICS + ("heads",))
+def flash_attention_rows(q, k, v, segments=None, dropout_seed=None, *,
+                         heads: "tuple[int, int]", causal: bool = True,
+                         block_q: int = 256, block_k: int = 256,
+                         interpret: bool = False, dropout_rate: float = 0.0,
+                         scale: "float | None" = None,
+                         window: "int | None" = None):
+    """The same kernel on the projections' own rows: q [B, S, N * D], k
+    [B, Sk, K * D], v [B, Sk, K * Dv] with ``heads`` = (N, K), at the
+    widths ``row_layout`` admits. (out [B, S, N * Dv], lse [B, N', S, 1]
+    with N' = N, or N + 1 for an odd count of 64-wide heads.)"""
+    return _forward_call(
+        q, k, v, segments, dropout_seed, heads=heads, causal=causal,
+        block_q=block_q, block_k=block_k, interpret=interpret,
+        dropout_rate=dropout_rate, scale=scale, window=window)
 
 
 def _p_and_ds(q, k, v, do, lse, delta, q0, k0, qseg, kseg, seed_ref, bn, *,
@@ -482,13 +730,18 @@ def _flash_bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                            num_major: int, G: int, causal: bool,
                            scale: float, has_seg: bool = False,
                            dropout_rate: float = 0.0,
-                           window: "int | None" = None):
+                           window: "int | None" = None,
+                           pair: "_Pairs | None" = None):
     """Grid (B, KV, k block, G, q major block): accumulate dk/dv for one k/v
     tile across the G query heads of this kv head and all q rows; one step
     holds ``chunks`` q chunks and loops over those at or below the
     diagonal, masking only the ones that cross it. With a ``window`` the
     loop ends at the last q chunk that still meets the tile's last key, and
-    the chunks the band's lower edge cuts are masked as well."""
+    the chunks the band's lower edge cuts are masked as well. With ``pair``
+    the second axis runs over pairs of key/value heads and the fourth over
+    the G query pairs of theirs: each query head's q and dO chunk is viewed
+    at its key/value head's half, so ``p^T.dO`` and ``ds^T.q`` land there
+    in the one accumulator and add zeros to the other half."""
     if dropout_rate > 0.0:
         seed_ref, rest = rest[0], rest[1:]
     else:
@@ -503,7 +756,12 @@ def _flash_bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     qj = pl.program_id(4)
     lo = qj * chunks
     # flat head index n = kh*G + g (N = KV*G heads); top-level program_id
-    bn = pl.program_id(0) * (pl.num_programs(1) * G) + pl.program_id(1) * G + g
+    if pair is None:
+        heads = [_Head(pl.program_id(0) * (pl.num_programs(1) * G)
+                       + pl.program_id(1) * G + g)]
+    else:
+        heads = pair.heads(pl.program_id(0), pl.program_id(1) * G + g)
+        kv_block = pl.program_id(1)
 
     @pl.when((g == 0) & (qj == 0))
     def _init():
@@ -512,19 +770,27 @@ def _flash_bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     def chunk(c, masked):
         rows = pl.ds(pl.multiple_of(c * block_q, block_q), block_q)
-        q = q_ref[0, 0, rows, :]
-        do = do_ref[0, 0, rows, :]
-        # the tile is [k, q]: p^T.dO and ds^T.q are then plain products,
-        # and lse / delta broadcast along sublanes from (1, block_q) rows
-        pd, ds = _p_and_ds(
-            q, k_ref[0, 0], v_ref[0, 0], do, lse_ref[0, 0, c],
-            delta_ref[0, 0, c], (lo + c) * block_q, k0,
-            qseg_ref[0, c] if has_seg else None,
-            kseg_ref[0] if has_seg else None, seed_ref, bn,
-            masked=masked, scale=scale, dropout_rate=dropout_rate,
-            k_rows=True, window=window)
-        dv_acc[...] += _dot(pd.astype(do.dtype), do, _NN)
-        dk_acc[...] += _dot(ds.astype(q.dtype), q, _NN)
+        q_rows = _tile(q_ref, rows)
+        do_rows = _tile(do_ref, rows)
+        if pair is None:
+            k, v = _tile(k_ref), _tile(v_ref)
+        else:
+            k = pair.kv_clean(_tile(k_ref), kv_block)
+            v = pair.kv_clean(_tile(v_ref), kv_block)
+        for i, head in enumerate(heads):
+            q, do = head.view(q_rows), head.view(do_rows)
+            # the tile is [k, q]: p^T.dO and ds^T.q are then plain products,
+            # and lse / delta broadcast along sublanes from (1, block_q)
+            # rows
+            pd, ds = _p_and_ds(
+                q, k, v, do, lse_ref[0, i, c], delta_ref[0, i, c],
+                (lo + c) * block_q, k0,
+                qseg_ref[0, c] if has_seg else None,
+                kseg_ref[0] if has_seg else None, seed_ref, head.bn,
+                masked=masked, scale=scale, dropout_rate=dropout_rate,
+                k_rows=True, window=window)
+            dv_acc[...] += _dot(pd.astype(do.dtype), do, _NN)
+            dk_acc[...] += _dot(ds.astype(q.dtype), q, _NN)
 
     if causal and window is not None:
         # from the first q chunk with a visible row to the last that meets
@@ -549,82 +815,108 @@ def _flash_bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     @pl.when((g == G - 1) & (qj == num_major - 1))
     def _finalize():
-        dk_ref[0, 0] = dk_acc[...].astype(dk_ref.dtype)
-        dv_ref[0, 0] = dv_acc[...].astype(dv_ref.dtype)
+        _put(dk_ref, dk_acc[...].astype(dk_ref.dtype))
+        _put(dv_ref, dv_acc[...].astype(dv_ref.dtype))
 
 
 def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, o_ref,
                          *rest, block_q: int, block_k: int, chunks: int,
                          num_major: int, causal: bool, scale: float,
                          has_seg: bool = False, dropout_rate: float = 0.0,
-                         window: "int | None" = None):
+                         window: "int | None" = None,
+                         pair: "_Pairs | None" = None,
+                         delta_out: bool = False):
     """Grid (B, N, q block, k major block): accumulate dq for one q tile
     over the k chunks the causal mask leaves (the forward's loop); ``o_ref``
-    is the forward's output tile, for delta."""
+    is the forward's output tile, for delta. With ``pair`` a step serves a
+    pair of 64-wide heads as the forward does: views of q and dO made once
+    a q tile, an accumulator a head. With ``delta_out`` the q tile's delta
+    is a second result, as the (1, block_q) row the dk/dv kernel reads: on
+    the projections' rows XLA would relayout dO . O whole to sum it by
+    head with the positions along lanes."""
     if dropout_rate > 0.0:
         seed_ref, rest = rest[0], rest[1:]
     else:
         seed_ref = None
     if has_seg:
-        qseg_ref, kseg_ref, dq_ref, dq_acc = rest
+        qseg_ref, kseg_ref, *rest = rest
     else:
         qseg_ref = kseg_ref = None
-        dq_ref, dq_acc = rest
+    if delta_out:
+        dq_ref, delta_ref, dq_acc, *views = rest
+    else:
+        dq_ref, dq_acc, *views = rest
     kj = pl.program_id(3)
     q0 = pl.program_id(2) * block_q
     lo = kj * chunks
-    bn = pl.program_id(0) * pl.num_programs(1) + pl.program_id(1)
+    if pair is None:
+        heads = [_Head(pl.program_id(0) * pl.num_programs(1)
+                       + pl.program_id(1))]
+    else:
+        heads = pair.heads(pl.program_id(0), pl.program_id(1))
+        kv_block = pair.kv_block(pl.program_id(1))
 
     @pl.when(kj == 0)
     def _init():
         dq_acc[...] = jnp.zeros_like(dq_acc)
+        if pair is not None:
+            for i, head in enumerate(heads):
+                views[0][i] = head.view(_tile(q_ref))
+                views[1][i] = head.view(_tile(do_ref))
 
     # delta = rowsum(dO . O) of this q tile, once a grid step from the tiles
     # themselves: as a [B, N, S, 1] column in HBM it would cost a 128-lane
-    # row an element (the dk/dv kernel reads it as rows)
-    delta = jnp.sum(do_ref[0, 0].astype(jnp.float32)
-                    * o_ref[0, 0].astype(jnp.float32), axis=1, keepdims=True)
+    # row an element (the dk/dv kernel reads it as rows); of a pair, each
+    # head's over its own half of the lanes
+    do_o = (_tile(do_ref).astype(jnp.float32)
+            * _tile(o_ref).astype(jnp.float32))
+    deltas = [jnp.sum(do_o if head.own is None
+                      else _half_view(do_o, head.own, head.own, head.there),
+                      axis=1, keepdims=True) for head in heads]
 
     def chunk(c, masked):
         rows = pl.ds(pl.multiple_of(c * block_k, block_k), block_k)
-        k = k_ref[0, 0, rows, :]
-        _, ds = _p_and_ds(
-            q_ref[0, 0], k, v_ref[0, 0, rows, :], do_ref[0, 0],
-            lse_ref[0, 0], delta, q0, (lo + c) * block_k,
-            qseg_ref[0] if has_seg else None,
-            kseg_ref[0, c] if has_seg else None, seed_ref, bn,
-            masked=masked, scale=scale, dropout_rate=dropout_rate,
-            window=window)
-        dq_acc[...] += _dot(ds.astype(k.dtype), k, _NN)
+        k = _tile(k_ref, rows)
+        if pair is None:
+            q, v, do = _tile(q_ref), _tile(v_ref, rows), _tile(do_ref)
+        else:
+            k = pair.kv_clean(k, kv_block)
+            v = pair.kv_clean(_tile(v_ref, rows), kv_block)
+        for i, head in enumerate(heads):
+            _, ds = _p_and_ds(
+                q if pair is None else views[0][i], k, v,
+                do if pair is None else views[1][i], lse_ref[0, i],
+                deltas[i], q0, (lo + c) * block_k,
+                qseg_ref[0] if has_seg else None,
+                kseg_ref[0, c] if has_seg else None, seed_ref, head.bn,
+                masked=masked, scale=scale, dropout_rate=dropout_rate,
+                window=window)
+            _of(dq_acc, i, pair)[...] += _dot(ds.astype(k.dtype), k, _NN)
 
     _for_k_chunks(chunk, q0, block_q, block_k, lo, chunks, causal, window)
 
     @pl.when(kj == num_major - 1)
     def _finalize():
-        dq_ref[0, 0] = dq_acc[...].astype(dq_ref.dtype)
+        _put(dq_ref, _sum_of(
+            head.back(_of(dq_acc, i, pair)[...])
+            for i, head in enumerate(heads)).astype(dq_ref.dtype))
+        if delta_out:
+            # the columns along lanes: one transpose of whole (8, 128) tiles
+            # with a head's column in each of its lanes (on the chip no
+            # slower than ones . (dO . O)^T on the idle MXU)
+            across = jnp.broadcast_to(deltas[0], (block_q, LANES))
+            if pair is not None:
+                across = jnp.where(jax.lax.broadcasted_iota(
+                    jnp.int32, across.shape, 1) < HALF, across, deltas[1])
+            rows = across.T
+            for i in range(len(heads)):
+                delta_ref[0, i, 0] = rows[i * HALF:i * HALF + 1]
 
 
-@functools.partial(jax.jit, static_argnames=("causal", "block_q", "block_k",
-                                             "interpret", "dropout_rate",
-                                             "scale", "window"))
-def flash_attention_bwd_hmajor(
-    q, k, v, o, lse, do, segments=None, dropout_seed=None, *,
-    causal: bool = True,
-    block_q: int = 256,
-    block_k: int = 256,
-    interpret: bool = False,
-    dropout_rate: float = 0.0,
-    scale: "float | None" = None,
-    window: "int | None" = None,
-):
-    """Fused flash backward (heads-major layouts): recomputes p from lse per
-    tile, so nothing O(S^2) ever hits HBM. Returns (dq, dk, dv). ``scale``
-    and ``window``: the forward's (``None`` = ``D ** -0.5``, the whole
-    causal span)."""
-    B, N, S, D = q.shape
-    Dv = v.shape[3]
-    KV = k.shape[1]
-    Sk = k.shape[2]  # may differ from S (ring off-diagonal blocks)
+def _backward_call(q, k, v, o, lse, do, segments, dropout_seed, *, heads,
+                   causal, block_q, block_k, interpret, dropout_rate, scale,
+                   window):
+    B, N, KV, S, Sk, D, Dv, per, pair = _call_shapes(q, k, v, heads)
     G = N // KV
     block_q = min(block_q, S)
     block_k = min(block_k, Sk)
@@ -632,15 +924,20 @@ def flash_attention_bwd_hmajor(
                 dropout_seed, window)
     scale = 1.0 / math.sqrt(D) if scale is None else scale
     has_seg = segments is not None
-    # for dk/dv, as rows; the dq kernel takes its own from the o / dO tiles
-    delta = _chunk_rows(
-        jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1),
-        block_q)
+    if heads is None:
+        # delta for dk/dv, as [B, N, S] rows in chunks; the dq kernel takes
+        # its own from the o / dO tiles, and on the projections' rows it
+        # writes these rows too: there XLA would relayout dO . O whole to
+        # sum it by head with the positions along lanes
+        delta = _chunk_rows(
+            jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1),
+            block_q)
     seed_arr = (dropout_seed.astype(jnp.int32).reshape(1)
                 if dropout_rate > 0.0 else None)
     if has_seg:
         seg_col, kseg_rows = _segment_operands(segments, block_k)
         _, qseg_rows = _segment_operands(segments, block_q)
+    block = functools.partial(_block, heads is not None)
 
     # dk/dv: a k/v tile stays, q / dO / lse / delta stream by major blocks;
     # its score tiles are [k, q], so lse and delta come as rows
@@ -659,22 +956,23 @@ def flash_attention_bwd_hmajor(
             return jnp.maximum(qj, (kb * block_k) // q_major)
         return qj
 
-    def q_rows(width):
-        return pl.BlockSpec(
-            (1, 1, q_major, width),
-            lambda b, kh, kb, g, qj: (b, kh * G + g, qj_of(kb, qj), 0))
+    # (the query heads of key/value head kh are kh * G + g, and so are the
+    # query pairs of a key/value pair)
+    def q_rows(b, kh, kb, g, qj):
+        return b, kh * G + g, qj_of(kb, qj)
 
     q_stat = pl.BlockSpec(
-        (1, 1, q_chunks, 1, block_q),
+        (1, per, q_chunks, 1, block_q),
         lambda b, kh, kb, g, qj: (b, kh * G + g, qj_of(kb, qj), 0, 0))
 
     def k_tile(b, kh, kb, g, qj):
-        return (b, kh, kb, 0)
+        return b, kh, kb
 
-    dkdv_in_specs = [q_rows(D), pl.BlockSpec((1, 1, block_k, D), k_tile),
-                     pl.BlockSpec((1, 1, block_k, Dv), k_tile), q_rows(Dv),
-                     q_stat, q_stat]
-    dkdv_operands = [q, k, v, do, _chunk_rows(lse[..., 0], block_q), delta]
+    dkdv_in_specs = [block(q_major, D * per, q_rows),
+                     block(block_k, D * per, k_tile),
+                     block(block_k, Dv * per, k_tile),
+                     block(q_major, Dv * per, q_rows), q_stat, q_stat]
+    dkdv_operands = [q, k, v, do, _chunk_rows(lse[..., 0], block_q)]
     if dropout_rate > 0.0:
         dkdv_in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
         dkdv_operands.append(seed_arr)
@@ -687,23 +985,24 @@ def flash_attention_bwd_hmajor(
         ]
         dkdv_operands += [qseg_rows, seg_col]
 
-    dkdv = pl.pallas_call(
+    dkdv = lambda delta: pl.pallas_call(  # noqa: E731
         functools.partial(_flash_bwd_dkdv_kernel, block_q=block_q,
                           block_k=block_k, chunks=q_chunks,
                           num_major=S // q_major, G=G, causal=causal,
                           scale=scale, has_seg=has_seg,
-                          dropout_rate=dropout_rate, window=window),
-        grid=(B, KV, Sk // block_k, G, S // q_major),
+                          dropout_rate=dropout_rate, window=window,
+                          pair=pair),
+        grid=(B, -(-KV // per), Sk // block_k, G, S // q_major),
         in_specs=dkdv_in_specs,
-        out_specs=[pl.BlockSpec((1, 1, block_k, D), k_tile),
-                   pl.BlockSpec((1, 1, block_k, Dv), k_tile)],
+        out_specs=[block(block_k, D * per, k_tile),
+                   block(block_k, Dv * per, k_tile)],
         out_shape=[
-            jax.ShapeDtypeStruct((B, KV, Sk, D), k.dtype),
-            jax.ShapeDtypeStruct((B, KV, Sk, Dv), v.dtype),
+            jax.ShapeDtypeStruct(k.shape, k.dtype),
+            jax.ShapeDtypeStruct(v.shape, v.dtype),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_k, D), jnp.float32),
-            pltpu.VMEM((block_k, Dv), jnp.float32),
+            pltpu.VMEM((block_k, D * per), jnp.float32),
+            pltpu.VMEM((block_k, Dv * per), jnp.float32),
         ],
         # dk/dv accumulate across the (g, q) axes; k tiles are independent
         compiler_params=pltpu.CompilerParams(
@@ -711,7 +1010,9 @@ def flash_attention_bwd_hmajor(
                                  "arbitrary", "arbitrary")),
         interpret=interpret,
         name="flash_attention_bwd_dkv",
-    )(*dkdv_operands)
+    )(*dkdv_operands[:5], delta, *dkdv_operands[5:])
+    if heads is None:
+        dk, dv = dkdv(delta)
 
     # dq: a q tile stays, k / v stream by major blocks (the forward's grid)
     k_chunks = _major_chunks(Sk, block_k, max(D, Dv) * k.dtype.itemsize)
@@ -720,17 +1021,19 @@ def flash_attention_bwd_hmajor(
     def kj_of(qi, kj):
         return _needed_k_major(qi, kj, block_q, k_major, causal, window)
 
-    def q_tile(width):
-        return pl.BlockSpec((1, 1, block_q, width),
-                            lambda b, n, qi, kj: (b, n, qi, 0))
+    def q_tile(b, n, qi, kj):
+        return b, n, qi
 
-    def k_rows(width):
-        return pl.BlockSpec(
-            (1, 1, k_major, width),
-            lambda b, n, qi, kj: (b, n // G, kj_of(qi, kj), 0))
+    def kv_rows(b, n, qi, kj):
+        return b, _kv_col(n, G, pair), kj_of(qi, kj)
 
-    dq_in_specs = [q_tile(D), k_rows(D), k_rows(Dv), q_tile(Dv), q_tile(1),
-                   q_tile(Dv)]
+    dq_in_specs = [block(block_q, D * per, q_tile),
+                   block(k_major, D * per, kv_rows),
+                   block(k_major, Dv * per, kv_rows),
+                   block(block_q, Dv * per, q_tile),
+                   pl.BlockSpec((1, per, block_q, 1),
+                                lambda b, n, qi, kj: (b, n, qi, 0)),
+                   block(block_q, Dv * per, q_tile)]
     dq_operands = [q, k, v, do, lse, o]
     if dropout_rate > 0.0:
         dq_in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
@@ -742,17 +1045,32 @@ def flash_attention_bwd_hmajor(
                          lambda b, n, qi, kj: (b, kj_of(qi, kj), 0, 0)),
         ]
         dq_operands += [seg_col, kseg_rows]
+    dq_spec = block(block_q, D * per, q_tile)
+    dq_shape = jax.ShapeDtypeStruct(q.shape, q.dtype)
+    if heads is not None:
+        # on rows the dq kernel writes delta's rows as well, in the chunks
+        # ``q_stat`` reads (what ``_chunk_rows`` makes of [B, N, S])
+        dq_spec = [dq_spec, pl.BlockSpec(
+            (1, per, 1, 1, block_q), lambda b, n, qi, kj: (b, n, qi, 0, 0))]
+        dq_shape = [dq_shape, jax.ShapeDtypeStruct(
+            (B, lse.shape[1], S // block_q, 1, block_q), jnp.float32)]
     dq = pl.pallas_call(
         functools.partial(_flash_bwd_dq_kernel, block_q=block_q,
                           block_k=block_k, chunks=k_chunks,
                           num_major=Sk // k_major, causal=causal,
                           scale=scale, has_seg=has_seg,
-                          dropout_rate=dropout_rate, window=window),
-        grid=(B, N, S // block_q, Sk // k_major),
+                          dropout_rate=dropout_rate, window=window,
+                          pair=pair, delta_out=heads is not None),
+        grid=(B, -(-N // per), S // block_q, Sk // k_major),
         in_specs=dq_in_specs,
-        out_specs=q_tile(D),
-        out_shape=jax.ShapeDtypeStruct((B, N, S, D), q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
+        out_specs=dq_spec,
+        out_shape=dq_shape,
+        # the accumulator; of a pair one a head, and the views of q and dO
+        scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)]
+        if pair is None else [
+            pltpu.VMEM((per, block_q, LANES), jnp.float32),
+            pltpu.VMEM((per, block_q, LANES), q.dtype),
+            pltpu.VMEM((per, block_q, LANES), do.dtype)],
         # dq accumulates across k only
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
@@ -760,7 +1078,48 @@ def flash_attention_bwd_hmajor(
         interpret=interpret,
         name="flash_attention_bwd_dq",
     )(*dq_operands)
-    return dq, dkdv[0], dkdv[1]
+    if heads is not None:
+        dq, delta = dq
+        dk, dv = dkdv(delta)
+    return dq, dk, dv
+
+
+@functools.partial(jax.jit, static_argnames=_CALL_STATICS)
+def flash_attention_bwd_hmajor(
+    q, k, v, o, lse, do, segments=None, dropout_seed=None, *,
+    causal: bool = True,
+    block_q: int = 256,
+    block_k: int = 256,
+    interpret: bool = False,
+    dropout_rate: float = 0.0,
+    scale: "float | None" = None,
+    window: "int | None" = None,
+):
+    """Fused flash backward (heads-major layouts): recomputes p from lse per
+    tile, so nothing O(S^2) ever hits HBM. Returns (dq, dk, dv). ``scale``
+    and ``window``: the forward's (``None`` = ``D ** -0.5``, the whole
+    causal span)."""
+    return _backward_call(
+        q, k, v, o, lse, do, segments, dropout_seed, heads=None,
+        causal=causal, block_q=block_q, block_k=block_k, interpret=interpret,
+        dropout_rate=dropout_rate, scale=scale, window=window)
+
+
+@functools.partial(jax.jit, static_argnames=_CALL_STATICS + ("heads",))
+def flash_attention_bwd_rows(q, k, v, o, lse, do, segments=None,
+                             dropout_seed=None, *, heads: "tuple[int, int]",
+                             causal: bool = True, block_q: int = 256,
+                             block_k: int = 256, interpret: bool = False,
+                             dropout_rate: float = 0.0,
+                             scale: "float | None" = None,
+                             window: "int | None" = None):
+    """The same backward on the projections' own rows (the operands of
+    ``flash_attention_rows``, its two results, and dO [B, S, N * Dv]):
+    (dq, dk, dv) as rows."""
+    return _backward_call(
+        q, k, v, o, lse, do, segments, dropout_seed, heads=heads,
+        causal=causal, block_q=block_q, block_k=block_k, interpret=interpret,
+        dropout_rate=dropout_rate, scale=scale, window=window)
 
 
 # the largest score tile a call takes; ``choose_blocks`` fits it to the call
@@ -809,6 +1168,13 @@ def band_tiles(S: int, block_q: int, block_k: int,
 # is compiled (``cli/train_dist.py``, the gauge ``flash/band_tiles_pct``)
 WINDOWED_CALLS: "set[tuple[int, int, int, int, int]]" = set()
 
+# and every call, as the kernels were handed it (on a shard of a mesh: its
+# shard's): ("rows" | "transposed", q length, k length, query heads, key/value
+# heads, q/k width, v width, window). Which layout the kernels indexed is
+# ``row_layout``'s reading of the widths; a launcher counts the set by its
+# first entry (gauges ``flash/row_layout_calls``, ``flash/transposed_calls``)
+LAYOUT_CALLS: "set[tuple]" = set()
+
 
 def choose_blocks(D: int, S: int, Sk: int, floor: int = 128):
     """(block_q, block_k) of a call from its shapes alone: head width D, q
@@ -823,57 +1189,79 @@ def choose_blocks(D: int, S: int, Sk: int, floor: int = 128):
     3/4 of the square where 256 x 256 computes 5/8; 1024-wide tiles lose
     more to the diagonal than they save. D does not move the choice at the
     widths measured: K and V are held by the major block, which the
-    kernels size from D themselves (``_major_chunks``). Nor does a window:
+    kernels size from D themselves (``_major_chunks``), and a pair of
+    64-wide heads a step runs the two heads' tiles one after the other, at
+    the tile one head had (a column block of two heads has the VMEM
+    footprint the padded head-major block of one had). Nor does a window:
     at D=128 / S=8192 / window 512 the same tile won (PERF.md, PR 48)."""
     del D
     return (fit_block(DEFAULT_BLOCK_Q, S, floor) or S,
             fit_block(DEFAULT_BLOCK_K, Sk, floor) or Sk)
 
 
+def _forward(q, k, v, segments, dropout_seed, **call):
+    """The forward kernel on [B, S, N, D] operands: the output as
+    [B, S, N * Dv] rows, lse [B, N', S, 1] and the operands as the kernels
+    read them, which are the projections' own rows where ``row_layout``
+    admits the widths (reshapes that move nothing) and head-major copies
+    where it does not."""
+    (B, S, N, D), K, Dv = q.shape, k.shape[2], v.shape[3]
+    rows = row_layout(N, K, D, Dv) is not None
+    LAYOUT_CALLS.add(("rows" if rows else "transposed", S, k.shape[1], N, K,
+                      D, Dv, call["window"]))
+    if rows:
+        ops = tuple(x.reshape(*x.shape[:2], -1) for x in (q, k, v))
+        out, lse = flash_attention_rows(*ops, segments, dropout_seed,
+                                        heads=(N, K), **call)
+        return out, lse, ops
+    ops = tuple(x.transpose(0, 2, 1, 3) for x in (q, k, v))
+    out, lse = flash_attention_hmajor(*ops, segments, dropout_seed, **call)
+    return out.transpose(0, 2, 1, 3).reshape(B, S, N * Dv), lse, ops
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10, 11))
 def _flash_with_vjp(q, k, v, segments, dropout_seed, causal, interpret,
                     block_q, block_k, dropout_rate, scale, window=None):
-    qh = q.transpose(0, 2, 1, 3)
-    kh = k.transpose(0, 2, 1, 3)
-    vh = v.transpose(0, 2, 1, 3)
-    out, _ = flash_attention_hmajor(qh, kh, vh, segments, dropout_seed,
-                                    causal=causal, interpret=interpret,
-                                    block_q=block_q, block_k=block_k,
-                                    dropout_rate=dropout_rate, scale=scale,
-                                    window=window)
-    return out.transpose(0, 2, 1, 3)
+    out, _, _ = _forward(q, k, v, segments, dropout_seed, causal=causal,
+                         interpret=interpret, block_q=block_q,
+                         block_k=block_k, dropout_rate=dropout_rate,
+                         scale=scale, window=window)
+    return out.reshape(*q.shape[:3], v.shape[3])
 
 
 def _flash_fwd(q, k, v, segments, dropout_seed, causal, interpret, block_q,
                block_k, dropout_rate, scale, window=None):
-    qh = q.transpose(0, 2, 1, 3)
-    kh = k.transpose(0, 2, 1, 3)
-    vh = v.transpose(0, 2, 1, 3)
-    out, lse = flash_attention_hmajor(qh, kh, vh, segments, dropout_seed,
-                                      causal=causal, interpret=interpret,
-                                      block_q=block_q, block_k=block_k,
-                                      dropout_rate=dropout_rate, scale=scale,
-                                      window=window)
+    out, lse, ops = _forward(q, k, v, segments, dropout_seed, causal=causal,
+                             interpret=interpret, block_q=block_q,
+                             block_k=block_k, dropout_rate=dropout_rate,
+                             scale=scale, window=window)
     # the pair per-layer remat keeps (``modules.remat``), each in the layout
     # HBM does not pad (module docstring); what the block goes on with is
     # derived from the kept output, so the recomputed forward needs no kernel
-    B, N, S, Dv = out.shape
-    out_rows = checkpoint_name(
-        out.transpose(0, 2, 1, 3).reshape(B, S, N * Dv), KEPT_OUT)
+    out_rows = checkpoint_name(out, KEPT_OUT)
     lse_rows = checkpoint_name(lse[..., 0], KEPT_LSE)
-    return (out_rows.reshape(B, S, N, Dv),
-            (qh, kh, vh, out_rows, lse_rows, segments, dropout_seed))
+    return (out_rows.reshape(*q.shape[:3], v.shape[3]),
+            (*ops, out_rows, lse_rows, segments, dropout_seed))
 
 
 def _flash_bwd(causal, interpret, block_q, block_k, dropout_rate, scale,
                window, res, g):
-    qh, kh, vh, out_rows, lse_rows, segments, dropout_seed = res
+    q, k, v, out_rows, lse_rows, segments, dropout_seed = res
+    call = dict(causal=causal, interpret=interpret, block_q=block_q,
+                block_k=block_k, dropout_rate=dropout_rate, scale=scale,
+                window=window)
+    B, S, N, Dv = g.shape
+    if q.ndim == 3:  # the projections' rows in, their cotangents' rows out
+        Sk, K = k.shape[1], v.shape[2] // Dv
+        dq, dk, dv = flash_attention_bwd_rows(
+            q, k, v, out_rows, lse_rows[..., None], g.reshape(B, S, N * Dv),
+            segments, dropout_seed, heads=(N, K), **call)
+        return (dq.reshape(B, S, N, -1), dk.reshape(B, Sk, K, -1),
+                dv.reshape(B, Sk, K, Dv), None, None)
     out = out_rows.reshape(g.shape).transpose(0, 2, 1, 3)
     dq, dk, dv = flash_attention_bwd_hmajor(
-        qh, kh, vh, out, lse_rows[..., None], g.transpose(0, 2, 1, 3),
-        segments, dropout_seed, causal=causal, interpret=interpret,
-        block_q=block_q, block_k=block_k, dropout_rate=dropout_rate,
-        scale=scale, window=window)
+        q, k, v, out, lse_rows[..., None], g.transpose(0, 2, 1, 3),
+        segments, dropout_seed, **call)
     return (dq.transpose(0, 2, 1, 3), dk.transpose(0, 2, 1, 3),
             dv.transpose(0, 2, 1, 3), None, None)  # int operands: no cotan
 

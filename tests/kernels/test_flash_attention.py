@@ -843,18 +843,43 @@ def test_band_tiles_count_the_loops_of_the_kernels():
 
 
 # sha256 of the jaxpr of loss and gradients through ``flash_sdpa`` WITHOUT a
-# window, recorded from the parent of the commit that gave the kernels one
-# (f5bc594), with `` at <file>:<line>`` taken out (a kernel's source position
-# rides in its ``name_and_src_info``): the unwindowed call traces to the
-# program it was, equation for equation
+# window, with `` at <file>:<line>`` taken out (a kernel's source position
+# rides in its ``name_and_src_info``). The two cases at 192 keep the
+# head-major kernels between transposes and were recorded from the parent of
+# the commit that gave the kernels the projections' rows (3c3b657): such a
+# call traces to the program it was, equation for equation, as the
+# unwindowed call did across the commit that gave the kernels a window
+# (f5bc594: the digests of the two cases at 128 and 64 until their calls
+# moved to the rows, re-recorded from this commit)
 _UNWINDOWED = {
-    "gqa_128": ((1024, 4, 2, 128, False, False),
-                "04ddb1faccb3bb55bdebcd513bc16a366a6144554ae4350430d059e2"
-                "ff4ebea1"),
-    "segments_dropout_64": ((512, 2, 2, 64, True, True),
-                            "b8053a6fdd0b64890e40c6af8d0252b3ea2ccbfb7f74825a"
-                            "900d26c1096113b1"),
+    "gqa_128": ((1024, 4, 2, 128, 128, False, False),
+                "cd637775ca9bb06472f0a17f0d7b1fbd6b8855ead467bdba9de82b28"
+                "4c6ac338"),
+    "segments_dropout_64": ((512, 2, 2, 64, 64, True, True),
+                            "097a9a46b4285979c2b309f4a208a9a8f2b4ec7a487fb85f"
+                            "3843954de3b53736"),
+    "latent_192_128": ((1024, 4, 4, 192, 128, False, False),
+                       "45d7f6bd14a7f32d4728e708b9f107c8509890eacd41b07a02a9"
+                       "0040ae49a68b"),
+    "gqa_192_segments_dropout": ((512, 4, 2, 192, 192, True, True),
+                                 "ee655dee0bac03c39ed98a270f3023c00d604b7c144"
+                                 "75079e0ecd7b9b98437a9"),
 }
+
+
+def _loss_and_gradients_jaxpr(S, N, K, D, Dv, seg, drop, **kw):
+    q = jax.ShapeDtypeStruct((2, S, N, D), jnp.bfloat16)
+    k = jax.ShapeDtypeStruct((2, S, K, D), jnp.bfloat16)
+    v = jax.ShapeDtypeStruct((2, S, K, Dv), jnp.bfloat16)
+    segs = jnp.zeros((2, S), jnp.int32) if seg else None
+
+    def loss(q, k, v):
+        return jnp.sum(flash_sdpa(
+            q, k, v, segment_ids=segs, dropout_rate=0.1 if drop else 0.0,
+            dropout_rng=jax.random.key(0) if drop else None, **kw
+        ).astype(jnp.float32))
+
+    return jax.make_jaxpr(jax.grad(loss, (0, 1, 2)))(q, k, v)
 
 
 @pytest.mark.parametrize("case", sorted(_UNWINDOWED))
@@ -862,17 +887,185 @@ def test_an_unwindowed_call_is_the_jaxpr_it_was(case):
     import hashlib
     import re
 
-    (S, N, K, D, seg, drop), want = _UNWINDOWED[case]
-    q = jax.ShapeDtypeStruct((2, S, N, D), jnp.bfloat16)
-    kv = jax.ShapeDtypeStruct((2, S, K, D), jnp.bfloat16)
-    segs = jnp.zeros((2, S), jnp.int32) if seg else None
-
-    def loss(q, k, v):
-        return jnp.sum(flash_sdpa(
-            q, k, v, segment_ids=segs, dropout_rate=0.1 if drop else 0.0,
-            dropout_rng=jax.random.key(0) if drop else None
-        ).astype(jnp.float32))
-
-    text = str(jax.make_jaxpr(jax.grad(loss, (0, 1, 2)))(q, kv, kv))
-    text = re.sub(r" at [^\s:]+:\d+", "", text)
+    sizes, want = _UNWINDOWED[case]
+    text = re.sub(r" at [^\s:]+:\d+", "",
+                  str(_loss_and_gradients_jaxpr(*sizes)))
     assert hashlib.sha256(text.encode()).hexdigest() == want
+
+
+def _equations(jaxpr, found=None):
+    """Every equation of ``jaxpr`` and of the jaxprs its equations hold,
+    a kernel's own body left out."""
+    found = [] if found is None else found
+    for eqn in jaxpr.eqns:
+        found.append(eqn)
+        if eqn.primitive.name == "pallas_call":
+            continue
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (list, tuple)) else [v]):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    _equations(sub, found)
+    return found
+
+
+# S, heads, kv heads, q/k width, v width, segments, dropout, window; how
+# many transposes the loss and its gradients hold
+_RELAYOUTS = {
+    "w128_gqa": ((1024, 4, 2, 128, 128, False, False, None), 0),
+    "w64_25_heads": ((1024, 25, 25, 64, 64, False, False, None), 0),
+    "w64_g4_segments_dropout": ((512, 8, 2, 64, 64, True, True, None), 0),
+    "w128_window": ((1024, 8, 2, 128, 128, False, False, 300), 0),
+    "w256_v128": ((512, 2, 2, 256, 128, False, False, None), 0),
+    # q, k, v in, the output back; the kept rows and the cotangent in, dq,
+    # dk and dv back
+    "latent_192_128": ((512, 4, 4, 192, 128, False, False, None), 9),
+    "w64_three_kv_heads_g2": ((512, 6, 3, 64, 64, False, False, None), 9),
+    "w32": ((512, 4, 4, 32, 32, False, False, None), 9),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_RELAYOUTS))
+def test_a_call_on_rows_holds_no_transpose_around_its_kernels(case):
+    """Loss and gradients through ``flash_sdpa`` at a width the kernels
+    index as rows: three ``pallas_call``s between reshapes and no
+    ``transpose`` at all, of an operand, a result, a cotangent or a
+    statistic (dk/dv's delta comes from the dq kernel in the rows it is
+    read in). At any other width (and for an odd count of key/value heads
+    under groups, whose pairs would straddle column blocks) the nine
+    transposes of the head-major path."""
+    from hetu_galvatron_tpu.ops.pallas.flash_attention import LAYOUT_CALLS
+
+    (*sizes, window), relayouts = _RELAYOUTS[case]
+    LAYOUT_CALLS.clear()
+    eqns = _equations(
+        _loss_and_gradients_jaxpr(*sizes, window=window).jaxpr)
+    assert sum(e.primitive.name == "pallas_call" for e in eqns) == 3
+    moved = [e.invars[0].aval.shape for e in eqns
+             if e.primitive.name == "transpose"]
+    assert all(len(shape) == 4 for shape in moved)
+    S, N, K, D, Dv = sizes[:5]
+    assert LAYOUT_CALLS == {
+        ("transposed" if relayouts else "rows", S, S, N, K, D, Dv, window)}
+    assert len(moved) == relayouts
+    LAYOUT_CALLS.clear()
+
+
+# ---------------------------------------------------------------------------
+# the projections' own rows: at head widths of whole lane tiles and at width
+# 64 the kernels index [B, S, N * D] as it is (one head a 128-lane column
+# block, or two), and nothing is transposed around a call
+# ---------------------------------------------------------------------------
+
+
+# B, S, heads, kv heads, q/k width, v width, dtype, blocks, window,
+# segments, dropout
+_ROWS = {
+    "w128_mha": (2, 256, 2, 2, 128, 128, jnp.float32, None, None, False, 0.0),
+    "w128_g4": (1, 256, 8, 2, 128, 128, jnp.float32, (128, 128), None, False,
+                0.0),
+    "w128_g4_bf16": (1, 256, 4, 1, 128, 128, jnp.bfloat16, (128, 128), None,
+                     False, 0.0),
+    "w256_v128": (1, 128, 2, 2, 256, 128, jnp.float32, None, None, False,
+                  0.0),
+    "w128_v256_g2": (1, 128, 4, 2, 128, 256, jnp.float32, (64, 64), None,
+                     False, 0.0),
+    "w128_window": (1, 256, 4, 2, 128, 128, jnp.float32, (64, 64), 100,
+                    False, 0.0),
+    "w128_segments_dropout": (2, 128, 2, 1, 128, 128, jnp.float32, (64, 32),
+                              None, True, 0.2),
+    "w64_even_heads": (2, 256, 4, 4, 64, 64, jnp.float32, (128, 128), None,
+                       False, 0.0),
+    "w64_even_heads_bf16": (1, 256, 2, 2, 64, 64, jnp.bfloat16, None, None,
+                            False, 0.0),
+    "w64_5_heads": (2, 128, 5, 5, 64, 64, jnp.float32, (64, 64), None, False,
+                    0.0),
+    "w64_5_heads_bf16": (1, 256, 5, 5, 64, 64, jnp.bfloat16, (128, 128),
+                         None, False, 0.0),
+    "w64_one_head": (1, 128, 1, 1, 64, 64, jnp.float32, None, None, False,
+                     0.0),
+    "w64_g2": (1, 128, 4, 2, 64, 64, jnp.float32, (64, 64), None, False,
+               0.0),
+    "w64_g4": (1, 256, 8, 2, 64, 64, jnp.float32, (128, 128), None, False,
+               0.0),
+    "w64_g4_bf16": (1, 128, 8, 2, 64, 64, jnp.bfloat16, None, None, False,
+                    0.0),
+    "w64_g5": (1, 128, 10, 2, 64, 64, jnp.float32, (64, 64), None, False,
+               0.0),
+    "w64_g3_four_kv": (1, 128, 12, 4, 64, 64, jnp.float32, (64, 64), None,
+                       False, 0.0),
+    "w64_window": (1, 256, 4, 4, 64, 64, jnp.float32, (64, 64), 70, False,
+                   0.0),
+    "w64_5_heads_window_segments": (2, 128, 5, 5, 64, 64, jnp.float32,
+                                    (32, 64), 50, True, 0.0),
+    "w64_segments": (2, 128, 4, 2, 64, 64, jnp.float32, (64, 32), None, True,
+                     0.0),
+    "w64_dropout": (2, 128, 4, 4, 64, 64, jnp.float32, (64, 64), None, False,
+                    0.25),
+    "w64_5_heads_dropout": (1, 128, 5, 5, 64, 64, jnp.float32, (32, 32),
+                            None, False, 0.25),
+    "w64_g4_segments_dropout": (2, 128, 8, 2, 64, 64, jnp.float32, (64, 64),
+                                None, True, 0.1),
+    "w64_noncausal_unequal_lengths": (1, 128, 4, 2, 64, 64, jnp.float32,
+                                      (64, 64), None, False, 0.0),
+}
+
+
+def _rows_case(case):
+    """Operands of a ``_ROWS`` case and its call's keywords (``Sk`` twice
+    ``S`` and no causal mask where the case's name says so)."""
+    from hetu_galvatron_tpu.ops.pallas.flash_attention import (
+        choose_blocks, seed_from_key)
+
+    B, S, N, K, D, Dv, dtype, blocks, window, seg, rate = _ROWS[case]
+    Sk = 2 * S if "unequal" in case else S
+    ks = jax.random.split(jax.random.key(13), 4)
+    q = jax.random.normal(ks[0], (B, S, N, D), dtype)
+    k = jax.random.normal(ks[1], (B, Sk, K, D), dtype)
+    v = jax.random.normal(ks[2], (B, Sk, K, Dv), dtype)
+    do = jax.random.normal(ks[3], (B, S, N, Dv), dtype)
+    segs = ((jnp.arange(S)[None, :] >= jnp.array([[S // 3], [S // 2]])[:B]
+             ).astype(jnp.int32) if seg else None)
+    bq, bk = blocks or choose_blocks(D, S, Sk)
+    call = dict(causal="noncausal" not in case, block_q=bq, block_k=bk,
+                interpret=True, dropout_rate=rate, window=window)
+    seed = seed_from_key(jax.random.key(5)) if rate else None
+    return (q, k, v, do), segs, seed, call
+
+
+@pytest.mark.parametrize("case", sorted(_ROWS))
+def test_flash_on_rows_is_the_head_major_call(case):
+    """Output, statistics and the three gradients of the kernels on the
+    projections' rows against the same kernels on head-major copies
+    (``flash_attention_hmajor`` between transposes, the only path there
+    was): float32 to the accumulation order of a 128-deep contraction that
+    adds exact zeros, bf16 to a rounding of the result."""
+    from hetu_galvatron_tpu.ops.pallas.flash_attention import (
+        flash_attention_bwd_hmajor, flash_attention_bwd_rows,
+        flash_attention_hmajor, flash_attention_rows, row_layout)
+
+    (q, k, v, do), segs, seed, call = _rows_case(case)
+    (B, S, N, D), K, Dv = q.shape, k.shape[2], v.shape[3]
+    assert row_layout(N, K, D, Dv) == (2 if D == 64 else 1)
+    t = lambda a: a.transpose(0, 2, 1, 3)  # noqa: E731
+    o_h, lse_h = flash_attention_hmajor(t(q), t(k), t(v), segs, seed, **call)
+    want = (t(o_h),) + tuple(t(g) for g in flash_attention_bwd_hmajor(
+        t(q), t(k), t(v), o_h, lse_h, t(do), segs, seed, **call))
+    flat = lambda a: a.reshape(*a.shape[:2], -1)  # noqa: E731
+    o_r, lse_r = flash_attention_rows(flat(q), flat(k), flat(v), segs, seed,
+                                      heads=(N, K), **call)
+    assert o_r.shape == (B, S, N * Dv)
+    # (an odd count of paired heads carries the statistics of one more)
+    assert lse_r.shape == (B, N + (N % 2 if D == 64 else 0), S, 1)
+    got = (o_r,) + flash_attention_bwd_rows(
+        flat(q), flat(k), flat(v), o_r, lse_r, flat(do), segs, seed,
+        heads=(N, K), **call)
+    tol = (dict(rtol=2e-2, atol=2e-2) if q.dtype == jnp.bfloat16
+           else dict(rtol=1e-5, atol=1e-5))
+    np.testing.assert_allclose(np.asarray(lse_r[:, :N]), np.asarray(lse_h),
+                               rtol=1e-5, atol=1e-5)
+    for name, g, w in zip(("out", "dq", "dk", "dv"), got, want):
+        g = np.asarray(g.reshape(w.shape).astype(jnp.float32))
+        assert np.all(np.isfinite(g)), name
+        np.testing.assert_allclose(g, np.asarray(w.astype(jnp.float32)),
+                                   err_msg=name, **tol)

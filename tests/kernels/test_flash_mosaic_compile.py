@@ -54,14 +54,41 @@ _CASES = {
                              True, 0.1),
     "whole_length_block": (2, 384, 384, 4, 4, 64, jnp.bfloat16, True, False,
                            0.0),
+    # the kernels on the projections' own rows, at the cells' shapes: one
+    # head a 128-lane column block (mistral_cell, window_cell_72_heads and
+    # the others at width 128 above) or two (gpt2xl_cell above: 25 heads,
+    # the last pair half outside the array)
+    "lfm2_cell_pairs_g4": (1, 8192, 8192, 32, 8, 64, jnp.bfloat16, True,
+                           False, 0.0),
+    "granite_cell_pairs_g4": (1, 8192, 8192, 32, 8, 64, jnp.bfloat16, True,
+                              False, 0.0, None, 0.0078125),
+    "olmoe_cell_mha_128": (1, 4096, 4096, 16, 16, 128, jnp.bfloat16, True,
+                           False, 0.0),
+    "laguna_cell_full_48_heads": (1, 8192, 8192, 48, 8, 128, jnp.bfloat16,
+                                  True, False, 0.0),
+    "mistral_four_chip_shard": (2, 4096, 4096, 16, 4, 128, jnp.bfloat16,
+                                True, False, 0.0),
+    "pairs_f32_segments_dropout_window": (2, 1024, 1024, 5, 5, 64,
+                                          jnp.float32, True, True, 0.1, 300),
+    "pairs_g5_segments_dropout": (1, 1024, 1024, 10, 2, 64, jnp.bfloat16,
+                                  True, True, 0.1),
+    "pairs_off_diagonal_g2": (1, 1024, 2048, 4, 2, 64, jnp.bfloat16, False,
+                              False, 0.0),
+    "rows_256_v128": (1, 1024, 1024, 4, 2, (256, 128), jnp.bfloat16, True,
+                      False, 0.0),
 }
+# the cases whose call keeps the head-major kernels between transposes
+_TRANSPOSED = {"latent_cell_192_128", "latent_f32_segments"}
 
 
 @pytest.mark.parametrize("case", sorted(_CASES))
 def test_flash_forward_and_backward_compile_for_v5e(one_chip, case):
-    B, S, Sk, N, K, D, dtype, causal, seg, drop, *window = _CASES[case]
+    from hetu_galvatron_tpu.ops.pallas.flash_attention import row_layout
+
+    B, S, Sk, N, K, D, dtype, causal, seg, drop, *rest = _CASES[case]
     D, Dv = D if isinstance(D, tuple) else (D, D)
-    window = window[0] if window else None
+    window, scale = (rest + [None, None])[:2]
+    assert (row_layout(N, K, D, Dv) is None) == (case in _TRANSPOSED)
 
     def spec(shape, dt):
         return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
@@ -71,7 +98,8 @@ def test_flash_forward_and_backward_compile_for_v5e(one_chip, case):
             lambda a, b, c: flash_sdpa(
                 a, b, c, causal=causal,
                 segment_ids=segments if seg else None, dropout_rate=drop,
-                dropout_rng=key if drop else None, window=window), q, k, v)
+                dropout_rng=key if drop else None, window=window,
+                scale=scale), q, k, v)
         return (out,) + vjp(do)
 
     compiled = jax.jit(fwd_bwd).lower(

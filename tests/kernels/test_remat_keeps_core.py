@@ -74,6 +74,14 @@ _CASES = {
     "dropout": (1, "full", ("dropout",)),
     "shard_map": (1, "full", ("shard_map",)),
     "shard_map_stack_dropout": (2, "full", ("shard_map", "dropout")),
+    # heads of 64 and of 128: the kernels index the projections' rows, and
+    # what remat keeps of the output is the kernel's own result (under
+    # tp2 a shard holds one head of the two: half a pair)
+    "rows_pairs": (1, "full", ("w64",)),
+    "rows_pairs_dots_segments": (1, "dots", ("w64", "segments")),
+    "rows_pairs_shard_map_stack_dropout": (2, "full", (
+        "w64", "shard_map", "dropout")),
+    "rows_w128_stack": (2, "full", ("w128",)),
     **{f"{kind}_{case}": (blocks, policy, (kind,) + extras)
        for kind in SCANS for case, (blocks, policy, extras) in {
            "block": (1, "full", ()), "stack": (2, "full", ()),
@@ -126,8 +134,10 @@ def _ops(extras, cpu_devices):
 def _stack(blocks, policy, extras, cpu_devices):
     """``(loss, params, x, cfg, block)``: ``loss(wrap)(params, x)`` runs
     ``blocks`` decoder blocks ``block(i)``, each wrapped by ``wrap``."""
+    width = next((int(e[1:]) for e in extras if re.fullmatch(r"w\d+", e)),
+                 H // N)
     cfg = CFG.model_copy(update={
-        "remat_policy": policy,
+        "hidden_size": N * width, "remat_policy": policy,
         "attention_dropout": 0.2 if "dropout" in extras else 0.0})
     kind = _kind(extras)
     seq = SCANS[kind][5] if kind in SCANS else S
@@ -140,7 +150,8 @@ def _stack(blocks, policy, extras, cpu_devices):
             .reshape(B, S).astype(np.int32))
     params = [M.init_decoder_layer(jax.random.key(i), cfg, mixer=kind)[0]
               for i in range(blocks)]
-    x = jax.random.normal(jax.random.key(9), (B, seq, H), jnp.float32)
+    x = jax.random.normal(jax.random.key(9), (B, seq, cfg.hidden_size),
+                          jnp.float32)
 
     def block(i):
         rng = (jax.random.fold_in(jax.random.key(3), i)
@@ -201,6 +212,45 @@ def test_a_rematted_block_runs_its_attention_core_once(cpu_devices, case):
             err_msg=f"{case}: {jax.tree_util.keystr(path)}")
 
 
+def _transposes(jaxpr, found=None):
+    """Operand shapes of the ``transpose`` equations of ``jaxpr`` and of
+    every jaxpr its equations hold, a kernel's own body left out."""
+    found = [] if found is None else found
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "transpose":
+            found.append(tuple(eqn.invars[0].aval.shape))
+        if eqn.primitive.name == "pallas_call":
+            continue
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (list, tuple)) else [v]):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    _transposes(sub, found)
+    return found
+
+
+@pytest.mark.parametrize("case,relayouts", [
+    ("rows_pairs", 0), ("rows_w128_stack", 0),
+    ("rows_pairs_shard_map_stack_dropout", 0), ("block", 12), ("stack", 24)])
+def test_the_backward_holds_no_relayout_of_the_kept_rows(cpu_devices, case,
+                                                         relayouts):
+    """What remat keeps of a core on rows is the forward kernel's own
+    output, and the backward kernels read it, the cotangent and q, k, v as
+    they lie: the gradient of a rematted block holds no transpose of an
+    array of four axes (heads beside positions), in the forward, the
+    recomputed forward or the backward. At a head width the kernels do not
+    index as rows it holds twelve a block: q, k, v in and the output back,
+    q, k, v again in the recomputed forward, the kept rows and the
+    cotangent in, dq, dk and dv back."""
+    blocks, policy, extras = _CASES[case]
+    loss, params, x, cfg, _ = _stack(blocks, policy, extras, cpu_devices)
+    jaxpr = jax.make_jaxpr(jax.grad(loss(lambda fn: M.remat(fn, cfg))))(
+        params, x).jaxpr
+    assert _kernel_calls(jaxpr) == blocks
+    moved = [sh for sh in _transposes(jaxpr) if len(sh) >= 4]
+    assert len(moved) == relayouts, moved
+
+
 def _kept_beside_the_arguments(capsys, fn, *args):
     """Shapes of what ``fn`` (a rematted function) saves for its backward
     pass that is no argument of it (``jax.ad_checkpoint``'s own listing)."""
@@ -235,6 +285,7 @@ def _named(jaxpr):
 
 @pytest.mark.parametrize("core,policy", [
     ("flash", "full"), ("flash_shard_map", "full"), ("xla", "full"),
+    ("flash_w64", "full"), ("flash_w128", "full"),
     ("xla", "dots_no_batch"),
     ("kda", "full"), ("kda_shard_map", "full"), ("kda_numpy", "full"),
     ("kda", "dots_no_batch"),
@@ -242,7 +293,8 @@ def _named(jaxpr):
     ("mamba", "dots_no_batch")])
 def test_what_a_rematted_block_keeps(cpu_devices, capsys, core, policy):
     """``full`` keeps the block's input and, of a flash core, the output as
-    [B, S, N * Dv] rows (the bytes of the input) and lse as [B, N, S] rows
+    [B, S, N * Dv] rows (the bytes of the input; at heads of 64 and of 128
+    the kernel's own result) and lse as [B, N, S] rows
     (never the [B, N, S, 1] column HBM pads 128 times); of a recurrent
     mixer's scan kernels the output and the entering states, float32 as the
     kernel wrote them, by the names of the kernel file's ``KEPT``; a block on
@@ -286,10 +338,12 @@ def test_what_a_rematted_block_keeps(cpu_devices, capsys, core, policy):
         assert sorted(ours) == sorted(theirs + sizes)
         assert policy != "full" or sorted(ours) == sizes
         return
-    if core == "flash":
-        assert kept == sorted([f"f32[{B},{S},{H}]", f"f32[{B},{N},{S}]"])
+    width = cfg.hidden_size
+    if kind == "flash" and how != "shard_map":
+        assert kept == sorted([f"f32[{B},{S},{width}]", f"f32[{B},{N},{S}]"])
     # (a shard_map's residuals are its shards' laid side by side on a new
     # leading axis: the same numbers, and no trailing singleton either)
     shapes = _shapes(kept)
-    assert sorted(int(np.prod(sh)) for sh in shapes) == [B * N * S, B * S * H]
+    assert sorted(int(np.prod(sh)) for sh in shapes) == [B * N * S,
+                                                        B * S * width]
     assert all(len(sh) == 3 and sh[-1] > 1 for sh in shapes), shapes

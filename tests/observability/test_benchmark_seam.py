@@ -363,6 +363,25 @@ def test_the_step_report_counts_the_cores_remat_runs_again(run):
     assert ", 0 cores recomputed, 0 scans recomputed, static live" in report
 
 
+@pytest.mark.parametrize("path", ["row_layout", "transposed"])
+def test_the_step_report_counts_the_flash_calls_by_layout(run, path):
+    """``flash/row_layout_calls`` and ``flash/transposed_calls``: the
+    distinct flash calls the step was built with whose kernels index the
+    projections' own rows, and those that run on head-major copies between
+    transposes. The gauge, ``train()``'s result and the ``step report:``
+    line say the same; 0 and 0 here, where the XLA core attends (what
+    decides the path of a call that is built, and that it is recorded once:
+    ``tests/kernels/test_flash_attention.py::
+    test_a_call_on_rows_holds_no_transpose_around_its_kernels``)."""
+    gauges = [m.value for m in run["registry"].metrics()
+              if m.name == f"flash/{path}_calls"]
+    (report,) = [line for line in run["log"].splitlines()
+                 if "step report:" in line]
+    assert gauges == [0]
+    assert run["result"]["flash_layout_calls"][path] == 0
+    assert f"flash/{path}_calls 0" in report
+
+
 def test_the_step_report_counts_the_scans_remat_runs_again(run):
     """``step/scans_recomputed`` beside it: the recurrent mixers' scan
     forward kernels the compiled step holds in its recompute phase, of the
