@@ -274,6 +274,69 @@ def window_plan_reason(cfg: Any, layers: Any) -> Optional[str]:
     return None
 
 
+def ep_exchange_reason(cfg: Any, s: Any, pp_deg: int = 1) -> Optional[str]:
+    """Why an expert block whose plan ``s`` has ``ep > 1`` does NOT run its
+    dispatcher inside the expert exchange (models/moe.py::
+    make_expert_exchange); None where it does, and where it needs none: ep =
+    1, or the ``capacity`` dispatcher, whose einsums GSPMD shards over ``ep``
+    by design. The exchange serves the sorted dispatchers (``dropless`` and
+    the held share) of a block at tp = 1, cp = 1, etp = 1 on the pp = 1 SPMD
+    path; a sorted dispatcher outside that keeps the program it had
+    (``lax.ragged_dot`` left to GSPMD with its group dimension sharded,
+    which is free to gather the experts' weights), and is named here so that
+    no plan does so in silence."""
+    if s.ep_size <= 1 or cfg.moe_dispatcher != "dropless":
+        return None
+    outside = [f"{axis}={deg}" for axis, deg in (
+        ("pp", pp_deg), ("tp", s.tp_size), ("cp", s.cp_size),
+        ("etp", s.etp_size)) if deg > 1]
+    if not outside:
+        return None
+    return (f"ep={s.ep_size} beside {', '.join(outside)}: the expert "
+            "exchange (tokens all-gathered and partial results "
+            "reduce-scattered over ep) serves the sorted dispatchers at "
+            "tp=1, cp=1, etp=1 on the pp=1 SPMD path; this block's grouped "
+            "matmuls are left to GSPMD, which may gather expert weights")
+
+
+def takes_exchange(cfg: Any, s: Any, pp_deg: int = 1) -> bool:
+    """Whether an expert block of plan ``s`` runs inside the exchange."""
+    return (s.ep_size > 1 and cfg.moe_dispatcher == "dropless"
+            and ep_exchange_reason(cfg, s, pp_deg) is None)
+
+
+def ep_plan_reason(cfg: Any, layers: Any, pp_deg: int = 1) -> Optional[str]:
+    """The first expert block of the plan that :func:`ep_exchange_reason`
+    names, said with its index; None when every expert block under ``ep >
+    1`` is exchanged or needs no exchange (or the model has no experts)."""
+    if not getattr(cfg, "num_experts", 0):
+        return None
+    kinds = cfg.block_kinds(len(layers))
+    for i, (s, (_, ff)) in enumerate(zip(layers, kinds)):
+        reason = ep_exchange_reason(cfg, s, pp_deg) if ff == "experts" \
+            else None
+        if reason:
+            return f"block {i}: {reason}"
+    return None
+
+
+def ep_divides_reason(cfg: Any, layers: Any) -> Optional[str]:
+    """Why a plan's ``ep`` cannot hold this model's experts: an ``ep`` that
+    does not divide the experts a layer holds leaves a chip a ragged share,
+    which neither the exchange nor a sharded expert axis has. None when it
+    divides them (or the model has no experts)."""
+    held = getattr(cfg, "held_experts", 0)
+    if not held:
+        return None
+    for i, s in enumerate(layers):
+        if held % s.ep_size:
+            return (f"layer {i}: ep={s.ep_size} does not divide the "
+                    f"{held} experts a layer holds (model.num_experts / "
+                    "model.moe_held_experts); parallel.global_ep_deg must "
+                    "divide them")
+    return None
+
+
 def residual_streams_reason(cfg: Any, what: str) -> Optional[str]:
     """Why ``what`` (an engine that carries ONE [B, S, H] stream between
     blocks or stages, or a loss with one prediction depth) cannot take a

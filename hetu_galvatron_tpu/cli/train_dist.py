@@ -223,6 +223,34 @@ def train(args) -> Dict[str, Any]:
         for (m, ff), n in Counter(kinds).items():
             blocks[f"{m}/{ff}"] = n
             get_registry().gauge("step/blocks", mixer=m, ff=ff).set(n)
+        # expert blocks across chips: which take the exchange, what a chip
+        # sends around them a step (from the shapes), and by name any sorted
+        # dispatcher under ep > 1 that the exchange does not serve
+        from hetu_galvatron_tpu.analysis import eligibility
+        from hetu_galvatron_tpu.models.moe import exchange_bytes
+
+        ep_report: Dict[str, Any] = {}
+        exchanged = [
+            s for s, (_, ff) in zip(hpc.layers,
+                                    cfg.block_kinds(len(hpc.layers)))
+            if ff == "experts" and eligibility.takes_exchange(
+                cfg, s, hpc.pp_deg)] if cfg.num_experts else []
+        if exchanged:
+            ep_report = {
+                "axes": exchanged[0].ep_size, "blocks": len(exchanged),
+                "exchange_bytes_per_step": sum(exchange_bytes(
+                    hpc.global_bsz // s.dp_size * cfg.seq_length,
+                    cfg.hidden_size, cfg.moe_topk, s.ep_size,
+                    4 if args.parallel.mixed_precision == "fp32" else 2,
+                    3 if s.checkpoint else 2) for s in exchanged)}
+            state.log("expert exchange: ep/axes {axes} over {blocks} expert "
+                      "blocks, ep/exchange_bytes_per_step "
+                      "{exchange_bytes_per_step}".format(**ep_report))
+            for k in ("axes", "exchange_bytes_per_step"):
+                get_registry().gauge(f"ep/{k}").set(ep_report[k])
+        unserved = eligibility.ep_plan_reason(cfg, hpc.layers, hpc.pp_deg)
+        if unserved:
+            state.log(f"expert exchange not taken: {unserved}")
         # what a state-space block carries: the chunks of a sequence and
         # the float32 state one sequence hands from chunk to chunk
         for i, (m, _) in enumerate(kinds):
@@ -1314,6 +1342,10 @@ def train(args) -> Dict[str, Any]:
                         "restarts_survived": goodput.restarts_survived},
             "flight_dumps": list(recorder.dumped) if recorder else [],
             "attention_cores": attention_cores,
+            # the expert exchange: the ep degree of the blocks inside it,
+            # how many there are, bytes a chip sends around them a step
+            # (gauges ep/axes, ep/exchange_bytes_per_step); None without
+            "ep": ep_report or None,
             # blocks by "<mixer>/<feed-forward>" kind (step/blocks gauges)
             "blocks": blocks,
             # Mosaic kernels in the compiled step's HLO (pp=1), or summed

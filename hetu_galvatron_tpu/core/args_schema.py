@@ -96,9 +96,13 @@ class ModelArgs(BaseModel):
     moe_z_loss_coeff: float = 0.0
     moe_router_dtype: Literal["float32", "bfloat16"] = "float32"
     moe_layer_freq: int = 1  # every k-th layer is MoE
-    # dispatch: "capacity" = GShard one-hot (ep-shardable, drops over-capacity
-    # tokens), "dropless" = sorted ragged grouped matmuls (exact numerics,
-    # reference alltoall dropless dispatcher)
+    # dispatch: "capacity" = GShard one-hot einsums (drop over-capacity
+    # tokens; across chips GSPMD shards their expert axis over ep and inserts
+    # the all-to-alls), "dropless" = sorted ragged grouped matmuls (exact
+    # numerics, reference alltoall dropless dispatcher; across chips this
+    # and the held share run inside the expert exchange, models/moe.py::
+    # make_expert_exchange: tokens all-gathered over ep, partial results
+    # reduce-scattered back, at tp = cp = etp = 1 on the pp = 1 path)
     moe_dispatcher: Literal["capacity", "dropless"] = "capacity"
     moe_capacity_factor: float = 1.25
     # router: softmax topk (optionally expert-bias-corrected selection) or

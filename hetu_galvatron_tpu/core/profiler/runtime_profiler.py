@@ -272,6 +272,22 @@ class RuntimeProfiler:
                     self.registry.gauge(
                         "moe/short_dispatch_pct", layer=name).set(
                             100.0 * float(st["short_dispatch"]))
+                if "rows_by_chip" in st:
+                    # a layer inside the expert exchange: the routes that
+                    # fell on each chip's experts, the fullest chip's over
+                    # the mean, and the share of chips and microbatches
+                    # that took the short buffer
+                    chips = np.asarray(st["rows_by_chip"], dtype=float)
+                    for r, rows in enumerate(chips):
+                        self.registry.gauge("moe/chip_rows", layer=name,
+                                            chip=str(r)).set(float(rows))
+                    chip_imb = float(chips.max() / max(chips.mean(), 1e-9))
+                    bits.append(f"moe[{name}] chips {chip_imb:.3f}")
+                    self.registry.gauge("moe/chip_imbalance",
+                                        layer=name).set(chip_imb)
+                    self.registry.gauge("moe/short_dispatch",
+                                        layer=name).set(
+                                            float(st["short_dispatch"]))
                 imb = float(tpe.max() / max(tpe.mean(), 1e-9))
                 aux = float(st["load_balance_loss"])
                 z = float(st["z_loss"])

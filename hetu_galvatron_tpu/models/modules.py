@@ -63,7 +63,10 @@ class LayerOps:
     of a tp > 1 layer (parallel/spmd.py::interior_sharding); ``ssd``,
     ``kda`` and ``conv`` are the kernels of a mamba block's chunked scan, a
     kda block's chunked delta rule and the causal depthwise convolution
-    (ops/pallas/). Which kinds of block read which field: :data:`MIXERS`."""
+    (ops/pallas/); ``exchange`` runs an expert block's sorted dispatcher
+    across the chips of its ``ep`` group (models/moe.py::
+    make_expert_exchange). Which kinds of block read which field:
+    :data:`MIXERS`; ``exchange`` is the expert feed-forward's."""
 
     sdpa: Optional[Callable[..., jax.Array]] = None
     cross_sdpa: Optional[Callable[..., jax.Array]] = None
@@ -72,6 +75,7 @@ class LayerOps:
     ssd: Optional[Callable[..., jax.Array]] = None
     kda: Optional[Callable[..., jax.Array]] = None
     conv: Optional[Callable[..., Optional[jax.Array]]] = None
+    exchange: Optional[Callable[..., Any]] = None
 
     def given(self) -> Dict[str, Any]:
         """The fields that are set, by name."""

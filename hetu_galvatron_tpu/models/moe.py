@@ -21,6 +21,15 @@ dispatchers —
   jit-clean; HF Mixtral numerics reproduce exactly (see
   tests/models/test_moe.py Mixtral parity).
 
+Across chips (``parallel.global_ep_deg``, the ``ep`` axes a plan carves from
+dp): the ``capacity`` einsums are left to GSPMD, which turns their sharded
+``expert`` axis into all-to-alls; the sorted dispatchers (``dropless`` and
+the held share) run inside :func:`make_expert_exchange`'s ``shard_map``: a
+chip's tokens, choices and weights are all-gathered over ``ep``, each chip
+runs :func:`_held_dispatch` over the group's tokens for the experts it
+holds, and the partial results are reduce-scattered back to the tokens'
+owners. Tokens move, expert weights never do.
+
 Routers: softmax top-k (optionally with the DeepSeek-style expert-bias
 selection correction, reference router.py expert_bias) and sinkhorn load
 balancing (selection via a no-grad sinkhorn normalization, weights via
@@ -204,8 +213,12 @@ def update_expert_bias(expert_bias: jax.Array, tokens_per_expert: jax.Array,
     return expert_bias + update_rate * jnp.sign(err)
 
 
-def held_range(cfg: ModelArgs) -> Tuple[int, int]:
-    """(how many experts this layer holds, the first one's index)."""
+def held_range(cfg: ModelArgs, ep: int = 1, index: Any = 0
+               ) -> Tuple[int, Any]:
+    """(how many experts this layer holds, the first one's index). Under an
+    expert exchange over ``ep`` chips, what chip ``index`` of the group
+    holds: a whole ``ep``-th of the layer's experts, so ``first`` is traced
+    where ``index`` is (``lax.axis_index``)."""
     held, first = cfg.held_experts, cfg.moe_first_held_expert
     if not 0 <= first <= first + held <= cfg.num_experts:
         raise ValueError(
@@ -217,7 +230,11 @@ def held_range(cfg: ModelArgs) -> Tuple[int, int]:
             "an expert layer that holds a share of its experts "
             "(moe_held_experts) runs the dropless dispatcher; the capacity "
             "dispatcher lays out every expert's buffer")
-    return held, first
+    if held % ep:
+        raise ValueError(
+            f"{held} experts held over ep={ep}: parallel.global_ep_deg must "
+            "divide the experts a layer holds")
+    return held // ep, first + index * (held // ep)
 
 
 def init_moe_mlp(key: jax.Array, cfg: ModelArgs) -> Tuple[Params, Params]:
@@ -451,11 +468,13 @@ def _short_or_full(short_body, full_body):
 
 def _held_dispatch(
     p: Params, xt: jax.Array, topk_idx: jax.Array, w: jax.Array,
-    cfg: ModelArgs, compute_dtype,
+    cfg: ModelArgs, compute_dtype, ep: int = 1, index: Any = 0,
 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """The dropless dispatch of a layer that holds experts ``[first, first
     + held)`` of the router's ``E``: it computes exactly the routes that
-    fall on them and leaves out what the absent experts would have added.
+    fall on them and leaves out what the absent experts would have added
+    (under an expert exchange, ``ep`` and ``index``: what the other chips of
+    the group add, :func:`held_range`).
 
     The static ``T*K`` slots sort by LOCAL expert id with every route to an
     absent expert keyed ``held``, so the held experts' routes come first, in
@@ -474,7 +493,7 @@ def _held_dispatch(
     ``held_tokens_per_expert`` [held]."""
     T, _ = xt.shape
     K = cfg.moe_topk
-    held, first = held_range(cfg)
+    held, first = held_range(cfg, ep, index)
     short_len = short_rows(T * K, held, cfg.num_experts)
     with jax.named_scope("moe/dispatch"):
         local = topk_idx.reshape(T * K) - first
@@ -509,24 +528,117 @@ def _held_dispatch(
     return y, jax.lax.stop_gradient(stats)
 
 
+def make_expert_exchange(mesh, dp_axes: Tuple[str, ...],
+                         ep_axes: Tuple[str, ...]):
+    """The sorted dispatchers across the chips of an ``ep`` group: returns
+    ``exchange(p, xt, topk_idx, w, cfg, compute_dtype) -> (y, stats)``, the
+    signature of :func:`_held_dispatch`, for a plan that shards the batch
+    over ``dp_axes`` and the experts over their leading ``ep_axes``
+    (``runtime/mesh.py::lower_strategy``; the rest of dp is expert-dp, whose
+    groups exchange nothing).
+
+    Inside one ``shard_map``: the chip's own tokens, their chosen experts
+    and weights are all-gathered over ``ep`` (scope ``moe/exchange/gather``);
+    the chip runs :func:`_held_dispatch` over the group's tokens with ``held
+    = E / ep`` and ``first = axis_index x held``: sort, the short or the
+    full buffer by ITS OWN count (a ``lax.cond`` a chip: one chip may take
+    the full body while the others take the short one), grouped matmuls,
+    scatter-add; the float32 partial results are reduce-scattered back to
+    the tokens' owners (``moe/exchange/scatter``), so the sum of the ``ep``
+    partials is taken in float32, in the order the uncut layer's scatter-add
+    would take it. The backward pass is the transpose JAX derives: the
+    gather's is a reduce-scatter and the reverse.
+
+    Why tokens by all-gather and not routes by all-to-all: at top-K over
+    ``ep`` chips a token is wanted by ``1 - (1 - 1/ep)^K`` of the chips (90 %
+    at 8 over 4), so an exchange by route moves ``K (ep - 1) / ep`` rows a
+    token where the gather moves ``ep - 1``; an all-to-all pays where the
+    experts a token are fewer than the chips.
+
+    ``stats`` are the group's: ``rows_held`` / ``rows_computed`` /
+    ``short_dispatch`` the mean over its chips, ``held_tokens_per_expert``
+    every expert's rows, ``rows_by_chip`` [ep] the routes that fell on each
+    chip's experts."""
+    from jax.sharding import PartitionSpec as P
+
+    from hetu_galvatron_tpu.ops.pallas.common import on_shards
+
+    ep = math.prod(mesh.shape[a] for a in ep_axes)
+    tokens, weights = P(dp_axes, None), P(ep_axes, None, None)
+
+    def exchange(p, xt, topk_idx, w, cfg, compute_dtype):
+        def on_chip(win, wout, xt, topk_idx, w):
+            with jax.named_scope("moe/exchange/gather"):
+                xt, topk_idx, w = (
+                    jax.lax.all_gather(a, ep_axes, axis=0, tiled=True)
+                    for a in (xt, topk_idx, w))
+            y, stats = _held_dispatch(
+                {"win": win, "wout": wout}, xt, topk_idx, w, cfg,
+                compute_dtype, ep, jax.lax.axis_index(ep_axes))
+            with jax.named_scope("moe/exchange/scatter"):
+                y = jax.lax.psum_scatter(y, ep_axes, scatter_dimension=0,
+                                         tiled=True)
+            # a row a chip of the mesh, in the order of the dp axes
+            return y, jax.tree.map(lambda v: v[None], stats)
+
+        y, stats = on_shards(
+            on_chip, mesh, (weights, weights, tokens, tokens, tokens),
+            (tokens, P(dp_axes)))(
+                p["win"], p["wout"], xt.astype(compute_dtype), topk_idx, w)
+        # [dp, ...] -> [ep, edp, ...]: the expert-dp groups of a chip's
+        # experts add up, the chips of a group are averaged
+        by_chip = jax.tree.map(
+            lambda v: v.reshape((ep, -1) + v.shape[1:]).sum(1), stats)
+        return y, {
+            "rows_held": by_chip["rows_held"].mean(),
+            "rows_computed": by_chip["rows_computed"].mean(),
+            "short_dispatch": stats["short_dispatch"].mean(),
+            "held_tokens_per_expert":
+                by_chip["held_tokens_per_expert"].reshape(-1),
+            "rows_by_chip": by_chip["rows_held"]}
+
+    return exchange
+
+
+def exchange_bytes(tokens: int, hidden: int, topk: int, ep: int,
+                   compute_bytes: int, passes: int) -> int:
+    """What one chip sends around ONE exchanged expert layer a step, from
+    the shapes: its ``tokens`` (rows, chosen indices, weights) to the ``ep -
+    1`` others, ``(ep - 1) / ep`` of the group's float32 partial results,
+    and as much again the other way round in each backward pass; ``passes``
+    = 3 under per-layer remat (forward, recompute, backward)."""
+    gather = (ep - 1) * tokens * (hidden * compute_bytes + 2 * 4 * topk)
+    scatter = (ep - 1) * tokens * hidden * 4
+    return passes * (gather + scatter)
+
+
 def apply_moe_mlp(
     p: Params,
     x: jax.Array,
     cfg: ModelArgs,
     compute_dtype=jnp.bfloat16,
     capacity_factor: Optional[float] = None,
+    exchange: Optional[Any] = None,
 ) -> Tuple[jax.Array, jax.Array, Dict[str, jax.Array]]:
     """x [B,S,H] -> (y [B,S,H], aux_loss scalar, router stats dict).
 
     Router per ``cfg.moe_router_type`` (see :func:`route_tokens`), dispatch
-    per ``cfg.moe_dispatcher``: "capacity" (GShard, ep-shardable) or
-    "dropless" (ragged grouped matmuls, exact numerics).
+    per ``cfg.moe_dispatcher``: "capacity" (GShard one-hot einsums; across
+    chips GSPMD shards their expert axis over ``ep`` and inserts the
+    all-to-alls) or "dropless" (ragged grouped matmuls, exact numerics;
+    across chips the sorted dispatchers, this and the held share, run inside
+    ``exchange``, what a plan with ``ep`` axes hands the block as
+    ``LayerOps.exchange``: :func:`make_expert_exchange`). The router is
+    replicated and routes the chip's own tokens either way.
     """
     B, S, H = x.shape
     xt = x.reshape(B * S, H)
     with jax.named_scope("moe/route"):
         topk_idx, w, aux, stats = route_tokens(p, xt, cfg, compute_dtype)
-    if held_range(cfg)[0] < cfg.num_experts:
+    if exchange is not None:
+        y, share_stats = exchange(p, xt, topk_idx, w, cfg, compute_dtype)
+        stats = {**stats, **share_stats}
+    elif held_range(cfg)[0] < cfg.num_experts:
         y, share_stats = _held_dispatch(p, xt, topk_idx, w, cfg,
                                         compute_dtype)
         stats = {**stats, **share_stats}
@@ -562,10 +674,11 @@ def apply_moe_decoder_layer(
     router stats) — stats feed the per-layer balance tracker (reference
     moe_utils.py:547-644)."""
     routed: Dict[str, Any] = {}
+    exchange = block.get("ops", M.LayerOps()).exchange
 
     def experts(h):
         y, routed["aux"], routed["stats"] = apply_moe_mlp(
-            p["moe"], h, cfg, compute_dtype=compute_dtype)
+            p["moe"], h, cfg, compute_dtype=compute_dtype, exchange=exchange)
         return y
 
     x = M.apply_decoder_layer(p, x, cfg, compute_dtype=compute_dtype,

@@ -476,6 +476,7 @@ SCOPES: Tuple[str, ...] = (
     "mlp", "head", "param_view",
     "grad/accumulate", "grad/clip", "optimizer/update",
     "moe/route", "moe/dispatch", "moe/experts", "moe/combine",
+    "moe/exchange/gather", "moe/exchange/scatter",
     "mixer/short_conv/in_proj", "mixer/short_conv/gate_conv",
     "mixer/short_conv/out_proj",
     "mixer/mamba/in_proj", "mixer/mamba/conv", "mixer/mamba/ssd",
@@ -638,7 +639,9 @@ def step_hlo(hlo_text: str, own_scopes: Sequence[str] = OWN_SCOPES
       instruction's OWN ``op_name`` (a fusion carries its root's; every
       fusion that holds a matmul carries that matmul's). An instruction with
       no ``op_name`` that calls a fused computation takes the commonest
-      scope and the commonest phase of the instructions inside it, and is
+      scope and the commonest phase of the instructions inside it (where
+      none of them has a name, of the reductions they apply: a fused
+      reduce-scatter made of a shard_map's ``psum_scatter``), and is
       listed in ``inferred``; any other has no scope. Where the ``op_name``
       is no name stack (no ``/`` in it: none, or ``ragged-dot-none``) and
       nothing inside says a phase, the phase is the latest pass among the
@@ -689,7 +692,7 @@ def _step_hlo(hlo_text: str, own_scopes: Sequence[str]) -> Dict[str, Any]:
         c = comps.get(comp)
         if c is None:
             c = comps[comp] = {"rows": [], "held": {}, "inside": {},
-                               "half": None}
+                               "half": None, "applies": []}
         if op_name not in classify:
             classify[op_name] = scope_and_phase(op_name)
             path = _TRANSFORM.sub("", op_name) + "/"
@@ -705,7 +708,9 @@ def _step_hlo(hlo_text: str, own_scopes: Sequence[str]) -> Dict[str, Any]:
             if calls:
                 fused.add(calls)
         elif opcode != "call" and "to_apply=" in line:
-            applied.add(_APPLIES.search(line).group(1))
+            reduction = _APPLIES.search(line).group(1)
+            applied.add(reduction)
+            c["applies"].append(reduction)
         base = opcode[:-6] if opcode.endswith("-start") else opcode
         if base in COLLECTIVE_OPS:
             c["held"][base] = c["held"].get(base, 0) + 1
@@ -767,6 +772,12 @@ def _step_hlo(hlo_text: str, own_scopes: Sequence[str]) -> Dict[str, Any]:
             scope, phase = classify[op_name]
             if not op_name and calls in comps:
                 inside = comps[calls]["inside"]
+                if not inside:
+                    # a ``psum_scatter`` of a shard_map, fused: pad,
+                    # all-reduce and slice carry no name, the reduction the
+                    # all-reduce applies does
+                    for reduction in comps[calls]["applies"]:
+                        inside = {**inside, **comps[reduction]["inside"]}
                 scope = commonest(inside, 0)
                 phase = commonest(inside, 1) or "other"
                 if scope is not None:

@@ -244,6 +244,36 @@ def attention_overrides(
     return out
 
 
+def expert_exchange_overrides(
+    per_layer: List[LayerSharding],
+    mesh: Mesh,
+    cfg: ModelArgs,
+    hpc: HybridParallelConfig,
+) -> Dict[int, LayerOps]:
+    """``LayerOps.exchange`` for every expert block whose plan carves ``ep``
+    axes from dp and whose sorted dispatcher the exchange serves
+    (analysis/eligibility.py::takes_exchange: ``dropless`` or a held share,
+    tp = cp = etp = 1, the pp = 1 path): models/moe.py::
+    make_expert_exchange over the layer's dp and ep axes. A plan without
+    ``ep`` axes gets an empty dict and its blocks the program they had."""
+    from hetu_galvatron_tpu.analysis.eligibility import takes_exchange
+    from hetu_galvatron_tpu.models.moe import make_expert_exchange
+
+    strategies = hpc.layers[hpc.num_encoder_layers:]
+    kinds = cfg.block_kinds(len(per_layer))
+    cache: Dict[Tuple, LayerOps] = {}
+    out: Dict[int, LayerOps] = {}
+    for i, (sh, s) in enumerate(zip(per_layer, strategies)):
+        if kinds[i][1] != "experts" or not takes_exchange(
+                cfg, s, hpc.pp_deg):
+            continue
+        key = (sh.dp_axes, sh.ep_axes)
+        if key not in cache:
+            cache[key] = LayerOps(exchange=make_expert_exchange(mesh, *key))
+        out[i] = cache[key]
+    return out
+
+
 def tp_overlap_overrides(
     per_layer: List[LayerSharding],
     mesh: Mesh,
@@ -538,6 +568,10 @@ def build_spmd_loss_fn(
     if tp_overlap:
         # under the plan's kernels, and both under the caller's
         ring = merge_ops(tp_overlap_overrides(per_layer, mesh, cfg)[0], ring)
+    if not lane_dp and cfg.num_experts and cfg.model_type != "t5":
+        # (no shard_map under the lane vmap)
+        ring = merge_ops(ring, expert_exchange_overrides(
+            per_layer, mesh, cfg, hpc))
     layer_overrides = merge_ops(ring, layer_overrides)
     # b_layers: under the lane vmap the dp axes are the vmap's, in the
     # interior's constraints as at the boundaries

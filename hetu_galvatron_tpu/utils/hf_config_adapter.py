@@ -46,14 +46,20 @@ _KIMI_LINEAR = "kimi_linear"
 # ``layer_types`` with query heads, a rotation and a gate a head of their
 # own, a dense block then softmax-free top-k experts beside a shared one
 _LAGUNA = "laguna"
+# Mellum 2 (JetBrains; Qwen3-MoE's keys with ``layer_types``,
+# ``mlp_layer_types`` and ``rope_parameters`` by kind): window and full
+# attention blocks with a q/k norm a head, softmax top-k experts in every
+# block, no shared expert
+_MELLUM = "mellum"
 # families that state for themselves whether they have positions
 _OWN_POSITIONS = {_GRANITE_HYBRID, _KIMI_LINEAR}
 _ROPE_FAMILIES = {"llama", "qwen2", "mistral", "mixtral", "olmoe",
-                  "qwen", _XING, _LAGUNA} | _GEMMA_FAMILIES | _LFM2_FAMILIES
+                  "qwen", _XING, _LAGUNA, _MELLUM} | _GEMMA_FAMILIES \
+    | _LFM2_FAMILIES
 _RMS_FAMILIES = _ROPE_FAMILIES | {"t5", _GRANITE_HYBRID, _KIMI_LINEAR}
 _SWIGLU_FAMILIES = {"llama", "qwen2", "mistral", "mixtral", "olmoe",
                     "qwen", _GRANITE_HYBRID, _XING, _KIMI_LINEAR,
-                    _LAGUNA} | _LFM2_FAMILIES
+                    _LAGUNA, _MELLUM} | _LFM2_FAMILIES
 # gemma-2/3 add sandwich norms (a norm after each sub-layer as well as
 # before it), logit softcapping (attention and final) and a softmax scale
 # from query_pre_attn_scalar; v3 also a q/k norm a head with zero-centred
@@ -174,6 +180,8 @@ def populate_model_args_from_hf(
         values.update(_kimi_linear_values(d))
     if family == _LAGUNA:
         values.update(_laguna_values(d))
+    if family == _MELLUM:
+        values.update(_mellum_values(d))
     if family == "bert":
         # HF bert uses erf gelu everywhere (BertIntermediate + the MLM
         # transform); our "gelu" is the tanh approximation (gpt2's gelu_new)
@@ -334,6 +342,53 @@ def _laguna_values(d: Dict[str, Any]) -> Dict[str, Any]:
     if len(types) != n:
         raise ValueError(f"{family}: layer_types names {len(types)} blocks "
                          f"and num_hidden_layers is {n}")
+    rope = d.get("rope_parameters") or {}
+    by_kind = {k: dict(v) for k, v in rope.items()
+               if k in ("full_attention", "sliding_attention")}
+    if by_kind:
+        out["rope_parameters"] = by_kind
+    return out
+
+
+def _mellum_values(d: Dict[str, Any]) -> Dict[str, Any]:
+    """Mellum 2 (``model_type: mellum``): ``layer_types`` names each block
+    "full_attention" or "sliding_attention" (the ``sliding_window`` newest
+    keys) and rules where ``max_window_layers`` / ``use_sliding_window``
+    would say something else, ``rope_parameters`` a rotation a kind;
+    ``mlp_layer_types`` "sparse" in every block: ``num_experts`` experts of
+    ``moe_intermediate_size`` at top ``num_experts_per_tok``, softmax
+    weights renormalised where ``norm_topk_prob``; llama's attention names
+    with a q/k RMSNorm a head (Qwen3's ``q_norm`` / ``k_norm``, assumed: no
+    key switches it), OLMoE's expert names."""
+    family = _MELLUM
+    n = int(d["num_hidden_layers"])
+    types = d.get("layer_types")
+    if types is None or set(types) - {"full_attention", "sliding_attention"}:
+        raise NotImplementedError(
+            f"{family} layer_types={types!r}: full_attention and "
+            "sliding_attention blocks, named one by one, are implemented")
+    if len(types) != n:
+        raise ValueError(f"{family}: layer_types names {len(types)} blocks "
+                         f"and num_hidden_layers is {n}")
+    feeds = d.get("mlp_layer_types") or ["sparse"] * n
+    if set(feeds) - {"sparse"} or len(feeds) != n:
+        raise NotImplementedError(
+            f"{family} mlp_layer_types={sorted(set(feeds))} over "
+            f"{len(feeds)} of {n} blocks: experts (sparse) in every block "
+            "are implemented; the config gives no rule for which width "
+            "(intermediate_size) a dense block would take")
+    out: Dict[str, Any] = dict(
+        model_type="moe", hf_layout="llama", moe_hf_layout="olmoe",
+        layer_types=list(types), num_dense_layers=0, moe_layer_freq=1,
+        moe_ffn_hidden_size=int(d["moe_intermediate_size"]),
+        qk_norm=True, qk_norm_per_head=True,
+        moe_score_function="softmax", moe_dispatcher="dropless",
+        moe_norm_topk_prob=bool(d.get("norm_topk_prob", False)),
+        # trained with cross-entropy alone where the config has no key
+        moe_aux_loss_coeff=float(d.get("router_aux_loss_coef") or 0.0),
+        tie_word_embeddings=bool(d.get("tie_word_embeddings", False)))
+    if "sliding_attention" in types:
+        out["sliding_window"] = int(d["sliding_window"])
     rope = d.get("rope_parameters") or {}
     by_kind = {k: dict(v) for k, v in rope.items()
                if k in ("full_attention", "sliding_attention")}

@@ -66,6 +66,16 @@ _CASES = {
                            False, 0.0),
     "laguna_cell_full_48_heads": (1, 8192, 8192, 48, 8, 128, jnp.bfloat16,
                                   True, False, 0.0),
+    # mellum2_c4_ep4: a chip's one sequence, 32 query heads over 4 key-value
+    # heads of 128 (groups of 8), the window blocks' 1024 and the full
+    # block; at 8192 and at the cell's 4096
+    "mellum_cell_window_1024": (1, 8192, 8192, 32, 4, 128, jnp.bfloat16,
+                                True, False, 0.0, 1024),
+    "mellum_cell_full_g8": (1, 8192, 8192, 32, 4, 128, jnp.bfloat16, True,
+                            False, 0.0),
+    "mellum_cell_window_1024_at_4096": (1, 4096, 4096, 32, 4, 128,
+                                        jnp.bfloat16, True, False, 0.0,
+                                        1024),
     "mistral_four_chip_shard": (2, 4096, 4096, 16, 4, 128, jnp.bfloat16,
                                 True, False, 0.0),
     "pairs_f32_segments_dropout_window": (2, 1024, 1024, 5, 5, 64,

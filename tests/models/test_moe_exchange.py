@@ -1,0 +1,359 @@
+"""The expert exchange (``models/moe.py::make_expert_exchange``): the sorted
+dispatchers across the chips of an ``ep`` group, on the 8-CPU mesh. The
+exchanged layer against ``_dropless_dispatch`` on one device, the shares
+against the uncut reference's layer, a chip that receives every route, what
+the compiled step moves, which plans take the exchange, and the expert layer
+of the cells that have no ``ep`` axes as the program it was."""
+
+import hashlib
+import os
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec as P
+
+from hetu_galvatron_tpu.analysis import eligibility
+from hetu_galvatron_tpu.core.args_schema import CoreArgs, ModelArgs
+from hetu_galvatron_tpu.models import moe
+from hetu_galvatron_tpu.models.builder import init_causal_lm
+from hetu_galvatron_tpu.runtime.hybrid_config import (
+    get_hybrid_parallel_config,
+)
+from hetu_galvatron_tpu.runtime.mesh import build_mesh
+from hetu_galvatron_tpu.utils.strategy import LayerStrategy
+
+pytestmark = [pytest.mark.model]
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+H, F, E, K = 32, 24, 8, 2
+LAYER = ModelArgs(
+    model_type="moe", hidden_size=H, num_hidden_layers=2,
+    num_attention_heads=2, vocab_size=64, max_position_embeddings=32,
+    seq_length=16, hidden_act="swiglu", normalization="rmsnorm",
+    position_embedding_type="rope", tie_word_embeddings=False,
+    add_bias_linear=False, add_qkv_bias=False,
+    make_vocab_size_divisible_by=1, ffn_hidden_size=F, num_experts=E,
+    moe_topk=K, moe_aux_loss_coeff=0.0, moe_dispatcher="dropless",
+    use_flash_attn=False)
+DP = ("d0", "d1", "d2")
+
+
+@pytest.fixture
+def highest():
+    with jax.default_matmul_precision("highest"):
+        yield
+
+
+def _layer(router: str, ep: int):
+    """The layer's weights and [8, 16, H] tokens. ``skewed``: positive
+    tokens and a router whose columns are positive for the experts of the
+    group's LAST chip and negative for the rest, so every token chooses
+    there."""
+    p, _ = moe.init_moe_mlp(jax.random.key(0), LAYER)
+    p = {**p, "win": 8.0 * p["win"], "wout": 8.0 * p["wout"]}
+    x = jax.random.normal(jax.random.key(1), (8, 16, H))
+    if router == "skewed":
+        last = (jnp.arange(E) >= E - E // ep)[None, :]
+        x = jnp.abs(x)
+        p["router"] = jnp.where(last, 1.0, -1.0) * jnp.abs(p["router"])
+    return p, x
+
+
+def _loss(p, x, exchange=None):
+    y, _, stats = moe.apply_moe_mlp(p, x, LAYER, compute_dtype=jnp.float32,
+                                    exchange=exchange)
+    return jnp.sum(jnp.sin(y)), (y, stats)
+
+
+def _exchanged(ep: int):
+    """The jitted value-and-gradient of the layer inside the exchange over
+    the first ``log2(ep)`` dp axes of the 8-device mesh."""
+    mesh = build_mesh(8, 1)
+    ep_axes = DP[:ep.bit_length() - 1]
+    exchange = moe.make_expert_exchange(mesh, DP, ep_axes)
+    shd = lambda spec: NamedSharding(mesh, spec)
+    return jax.jit(
+        jax.value_and_grad(lambda p, x: _loss(p, x, exchange),
+                           argnums=(0, 1), has_aux=True),
+        in_shardings=({"router": shd(P()), "win": shd(P(ep_axes)),
+                       "wout": shd(P(ep_axes))}, shd(P(DP))))
+
+
+@pytest.mark.usefixtures("highest")
+@pytest.mark.parametrize("router", ["balanced", "skewed"])
+@pytest.mark.parametrize("ep", [2, 4])
+def test_the_exchanged_layer_is_the_dropless_layer_on_one_device(
+        ep, router, cpu_devices):
+    """Output and every gradient (router, both expert matrices, the tokens)
+    of the layer inside the exchange against ``_dropless_dispatch`` on one
+    device; with ep < dp the expert-dp groups exchange nothing and their
+    weight gradients add up."""
+    p, x = _layer(router, ep)
+    (want, (want_y, _)), want_g = jax.value_and_grad(
+        _loss, argnums=(0, 1), has_aux=True)(p, x)
+    (got, (got_y, stats)), got_g = _exchanged(ep)(p, x)
+    # tolerance: fp32, four partial sums in another order
+    np.testing.assert_allclose(got_y, want_y, rtol=1e-5, atol=1e-6)
+    assert abs(float(got) - float(want)) < 1e-4
+    for (path, a), (_, b) in zip(
+            jax.tree_util.tree_leaves_with_path(want_g),
+            jax.tree_util.tree_leaves_with_path(got_g)):
+        np.testing.assert_allclose(
+            b, a, rtol=1e-4, atol=1e-5 * float(jnp.abs(a).max()) + 1e-9,
+            err_msg=jax.tree_util.keystr(path))
+    # every route is some chip's: none dropped, none counted twice
+    rows = np.asarray(stats["rows_by_chip"])
+    assert rows.shape == (ep,) and rows.sum() == 8 * 16 * K
+    np.testing.assert_array_equal(stats["held_tokens_per_expert"],
+                                  stats["tokens_per_expert"])
+
+
+@pytest.mark.usefixtures("highest")
+@pytest.mark.parametrize("ep", [2, 4])
+def test_a_chip_that_receives_every_route_drops_none(ep, cpu_devices):
+    """Every token to the last chip's experts: that chip's count passes the
+    short buffer and it takes the full body, the others take the short one
+    (where the layer has two bodies: at ep = 2 the margin of 2 makes the
+    short buffer the whole one), and the result is the one-device layer's,
+    which the case above holds."""
+    p, x = _layer("skewed", ep)
+    (_, (y, stats)), _ = _exchanged(ep)(p, x)
+    rows = np.asarray(stats["rows_by_chip"])
+    slots = 16 * K * ep          # an expert-dp group's routes, 8 / ep groups
+    assert rows.tolist() == [0.0] * (ep - 1) + [8 * 16 * K]
+    short_len = moe.short_rows(slots, E // ep, E)
+    if short_len < slots:
+        assert float(stats["short_dispatch"]) == (ep - 1) / ep
+        assert float(stats["rows_computed"]) == (
+            (ep - 1) * short_len + slots) * (8 // ep) / ep
+    else:
+        assert float(stats["short_dispatch"]) == 0.0
+    want = moe.apply_moe_mlp(p, x, LAYER, compute_dtype=jnp.float32)[0]
+    np.testing.assert_allclose(y, want, rtol=1e-5, atol=1e-6)
+
+
+def _reference_weights(p):
+    """The layer's weights under the names benchmark/reference/mellum.py
+    reads."""
+    w = {"gate.weight": p["router"].T}
+    for e in range(E):
+        gate, up = jnp.split(p["win"][e], 2, axis=1)
+        w.update({f"experts.{e}.gate_proj.weight": gate.T,
+                  f"experts.{e}.up_proj.weight": up.T,
+                  f"experts.{e}.down_proj.weight": p["wout"][e].T})
+    return w
+
+
+@pytest.mark.usefixtures("highest")
+@pytest.mark.parametrize("ep", [2, 4])
+def test_the_shares_partial_results_add_up_to_the_uncut_reference(ep):
+    """The one test that ties a share to the model, here with every share
+    present: ``_held_dispatch`` told it is chip ``r`` of ``ep`` computes the
+    group's tokens for its experts alone; the ``ep`` partial results (what
+    the exchange reduce-scatters) add up to what the plain reference gives
+    for the whole layer, and one share left out does not."""
+    from benchmark import reference
+
+    ref = reference.load_family("mellum", ROOT)
+    ref_cfg = {"num_experts": E, "num_experts_per_tok": K,
+               "norm_topk_prob": True}
+    p, x = _layer("balanced", ep)
+    flat = x.reshape(-1, H)
+    idx, w, _, _ = moe.route_tokens(p, flat, LAYER, jnp.float32)
+    held = E // ep
+    partials, rows = [], 0.0
+    for r in range(ep):
+        share = {"win": p["win"][r * held:(r + 1) * held],
+                 "wout": p["wout"][r * held:(r + 1) * held]}
+        y, stats = moe._held_dispatch(share, flat, idx, w, LAYER,
+                                      jnp.float32, ep, r)
+        partials.append(y)
+        rows += float(stats["rows_held"])
+    assert rows == flat.shape[0] * K
+    whole = ref.experts(flat, _reference_weights(p), "", ref_cfg)
+    np.testing.assert_allclose(sum(partials), whole, rtol=1e-5, atol=2e-6)
+    # the control the share cells could not have: one chip's experts absent
+    absent = ref.experts(flat, _reference_weights(p), "", {
+        **ref_cfg, "experts_left_out": tuple(range(held))})
+    np.testing.assert_allclose(sum(partials[1:]), absent, rtol=1e-5,
+                               atol=2e-6)
+    assert float(jnp.abs(whole - absent).max()) > 1e-3
+
+
+# ---------------------------------------------------------------------------
+# what the compiled step moves
+# ---------------------------------------------------------------------------
+
+STACK = LAYER.model_copy(update=dict(num_hidden_layers=2, seq_length=16))
+
+
+def _compiled_step(ep: int, devices):
+    import optax
+
+    from hetu_galvatron_tpu.parallel.spmd import make_spmd_train_step
+
+    args = CoreArgs(model=STACK.model_dump())
+    args.parallel.global_ep_deg = ep
+    args.parallel.global_train_batch_size = 4
+    args.parallel.global_checkpoint = 1
+    hpc = get_hybrid_parallel_config(args, 4)
+    mesh = build_mesh(4, 1, devices=devices[:4])
+    box = {}
+
+    def init(key):
+        p, box["axes"] = init_causal_lm(key, STACK)
+        return p
+
+    params = jax.eval_shape(init, jax.random.key(0))
+    tx = optax.sgd(1e-2)
+    step, pspecs, ospecs, batch_shd = make_spmd_train_step(
+        STACK, hpc, mesh, box["axes"], tx, params,
+        compute_dtype=jnp.float32, donate=False)
+    shaped = lambda specs, tree: jax.tree.map(
+        lambda s, a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=NamedSharding(mesh, s)),
+        specs, tree, is_leaf=lambda v: isinstance(v, P))
+    batch = {k: jax.ShapeDtypeStruct((4, 16), dt, sharding=batch_shd)
+             for k, dt in (("tokens", jnp.int32), ("labels", jnp.int32),
+                           ("loss_mask", jnp.float32))}
+    return step.lower(shaped(pspecs, params),
+                      shaped(ospecs, jax.eval_shape(tx.init, params)),
+                      batch).compile().as_text()
+
+
+@pytest.mark.parametrize("ep", [2, 4])
+def test_the_compiled_step_moves_tokens_and_no_expert_weight(ep, cpu_devices):
+    """The compiled step of a two-block stack under ``ep``: an all-gather
+    and a reduce-scatter under ``moe/exchange/*`` in every expert block
+    (forward and backward instructions carry the scopes), and NO all-gather that makes a whole expert matrix out of its shards."""
+    from hetu_galvatron_tpu.observability.trace_analysis import (
+        walk_hlo,
+        scope_and_phase,
+    )
+
+    hlo = _compiled_step(ep, cpu_devices)
+    under = {"moe/exchange/gather": set(), "moe/exchange/scatter": set()}
+    for comp, name, opcode, op_name, calls, line, end in walk_hlo(
+            hlo):
+        scope, phase = scope_and_phase(op_name)
+        if scope in under:
+            under[scope].add((opcode.split("-start")[0], phase))
+        if opcode.startswith("all-gather"):
+            # the whole [E, H, 2F] or [E, F, H] behind an all-gather's "="
+            result = line.split("=", 1)[1].split(opcode)[0]
+            assert f"[{E},{H},{2 * F}]" not in result, line
+            assert f"[{E},{F},{H}]" not in result, line
+    # the gather and its transpose, a reduce-scatter; the scatter and its
+    # transpose, an all-gather (what the recomputed forward repeats XLA:CPU
+    # may merge with the forward's: the chip's step keeps all three passes,
+    # PERF.md section 5)
+    assert {("all-gather", "forward"), ("reduce-scatter", "backward")} <= \
+        under["moe/exchange/gather"], under
+    assert {("reduce-scatter", "forward"), ("all-gather", "backward")} <= \
+        under["moe/exchange/scatter"], under
+    # in EVERY expert block: two blocks, so two gathers of tokens a pass
+    gathers = [line for _, _, opcode, op_name, _, line, _
+               in walk_hlo(hlo)
+               if opcode.startswith("all-gather") and "-done" not in opcode
+               and scope_and_phase(op_name) == ("moe/exchange/gather",
+                                                "forward")]
+    tokens = [g for g in gathers if f",{H}]" in g.split("=", 1)[1][:40]]
+    assert len(tokens) == STACK.num_hidden_layers, gathers
+
+
+# ---------------------------------------------------------------------------
+# which plans take it
+# ---------------------------------------------------------------------------
+
+
+def _plan(**degrees):
+    base = dict(pp_deg=1, tp_size=1, cp_size=1, dp_size=4, ep_size=4)
+    return LayerStrategy(**{**base, **degrees})
+
+
+@pytest.mark.parametrize("degrees, pp, named", [
+    ({}, 1, None),
+    ({"ep_size": 1}, 1, None),
+    ({"tp_size": 2, "dp_size": 2, "ep_size": 2}, 1, "tp=2"),
+    ({"cp_size": 2, "dp_size": 2, "ep_size": 2}, 1, "cp=2"),
+    ({"tp_size": 2, "dp_size": 2, "ep_size": 2, "etp_size": 2}, 1, "etp=2"),
+    ({"dp_size": 2, "ep_size": 2, "pp_deg": 2}, 2, "pp=2"),
+])
+def test_ep_plan_reason_names_each_plan_the_exchange_does_not_serve(
+        degrees, pp, named):
+    layers = [_plan(**degrees)] * 2
+    reason = eligibility.ep_plan_reason(LAYER, layers, pp)
+    assert eligibility.takes_exchange(LAYER, layers[0], pp) == (
+        named is None and layers[0].ep_size > 1)
+    if named is None:
+        assert reason is None
+    else:
+        assert reason.startswith("block 0: ep=2") and named in reason
+    # the capacity dispatcher's einsums are GSPMD's by design: never named
+    capacity = LAYER.model_copy(update=dict(moe_dispatcher="capacity"))
+    assert eligibility.ep_plan_reason(capacity, layers, pp) is None
+    assert not eligibility.takes_exchange(capacity, layers[0], pp)
+    # a model without experts has nothing to exchange
+    dense = LAYER.model_copy(update=dict(num_experts=0))
+    assert eligibility.ep_plan_reason(dense, layers, pp) is None
+
+
+def test_an_ep_that_does_not_divide_the_experts_is_refused():
+    odd = LAYER.model_copy(update=dict(num_experts=6))
+    args = CoreArgs(model=odd.model_dump())
+    args.parallel.global_ep_deg = 4
+    args.parallel.global_train_batch_size = 4
+    with pytest.raises(ValueError, match="ep=4 does not divide the 6"):
+        get_hybrid_parallel_config(args, 4)
+    with pytest.raises(ValueError, match="must divide"):
+        moe.held_range(odd, 4, 0)
+    args.model.num_experts = 8
+    assert get_hybrid_parallel_config(args, 4).layers[0].ep_size == 4
+
+
+# ---------------------------------------------------------------------------
+# the cells without ep axes keep the program they had
+# ---------------------------------------------------------------------------
+
+# sha256 of str(jaxpr) of the first expert block's layer, value and
+# gradient, at the cell's own sizes (traced on shapes alone), recorded from
+# PR 51's parent: ``exchange`` is None there and ``held_range`` adds a
+# Python 0
+RECORDED = {
+    "laguna_c1_b1":
+        "9a745e5921108db77ee43ba60fc56227e144684c0db3627b35a0eadfe47b2043",
+    "olmoe_c1_s4k":
+        "a37cb13be146c7c1a96dcdc450b53c221589e441c8ab1ceba25115bf4bbbb8ba",
+}
+
+
+@pytest.mark.parametrize("cell_name", sorted(RECORDED))
+def test_a_cell_without_ep_axes_traces_to_the_jaxpr_it_traced_to(cell_name):
+    from benchmark import manifest as mf
+
+    from hetu_galvatron_tpu.core.arguments import args_from_cli
+    from hetu_galvatron_tpu.utils.hf_config_adapter import (
+        resolve_model_config,
+    )
+
+    cell = mf.resolve_cell(mf.load_manifest(ROOT), cell_name, ROOT)
+    cfg = resolve_model_config(args_from_cli(
+        mf.train_argv(cell, 0, ROOT), mode="train_dist")).model
+    first = [i for i, (_, ff) in enumerate(cfg.block_kinds())
+             if ff == "experts"][0]
+    layer = cfg.for_block(first)
+    p = jax.eval_shape(lambda k: moe.init_moe_mlp(k, layer)[0],
+                       jax.random.key(0))
+    x = jax.ShapeDtypeStruct((1, cfg.seq_length, cfg.hidden_size),
+                             jnp.bfloat16)
+
+    def f(p, x):
+        y, aux, _ = moe.apply_moe_mlp(p, x, layer)
+        return jnp.sum(y.astype(jnp.float32)) + aux
+
+    text = str(jax.make_jaxpr(jax.value_and_grad(f))(p, x))
+    assert hashlib.sha256(text.encode()).hexdigest() == RECORDED[cell_name]

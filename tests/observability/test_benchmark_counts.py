@@ -56,8 +56,12 @@ def test_lfm2_forward_flops_are_the_integer_the_issue_wrote_out():
 def test_the_manifest_keeps_the_contract():
     man = manifest.load_manifest()
     assert manifest.check_manifest(man) == []
-    assert [w["chips"] for w in man["workloads"]].count(4) == 1
-    assert len(man["workloads"]) >= 6
+    # a quarter of the cells, rounded down, may take four chips: the two
+    # whose plans exist only across chips (tp2 x dp2 ZeRO-3; ep4 + vocab_tp4)
+    four = [w["name"] for w in man["workloads"] if w["chips"] == 4]
+    assert four == ["mistral7b_c4_tp2dp2z3", "mellum2_c4_ep4"]
+    assert len(four) <= len(man["workloads"]) // 4
+    assert len(man["workloads"]) >= 11
 
 
 GRANITE = dict(layers=10, hidden=2048, heads=32, kv_heads=8, head_dim=64,
