@@ -251,24 +251,28 @@ class RuntimeProfiler:
                 tpe = np.asarray(st["tokens_per_expert"], dtype=float)
                 if "rows_held" in st:
                     # a layer that holds a share of its experts: the routes
-                    # that fell on them over all T*K, the rows the body it
-                    # took handed to its grouped matmuls against the rows
-                    # that belonged to a held expert, the share of the
-                    # microbatches that took the short body, and the
-                    # balance over the HELD experts, whose rows are the
-                    # matmuls' groups
-                    held, computed = (float(st["rows_held"]),
-                                      float(st["rows_computed"]))
+                    # that fell on them over all T*K, the rows its chunks
+                    # handed to the grouped matmuls against the rows that
+                    # belonged to a held expert, the counted passes behind
+                    # the first chunk, the share of the microbatches that
+                    # took none, and the balance over the HELD experts,
+                    # whose rows are the matmuls' groups
+                    held, computed, passes = (
+                        float(st[k]) for k in (
+                            "rows_held", "rows_computed", "overflow_chunks"))
                     local = 100.0 * held / max(tpe.sum(), 1e-9)
                     tpe = np.asarray(st["held_tokens_per_expert"],
                                      dtype=float)
                     bits.append(f"moe[{name}] local {local:.2f}% rows "
-                                f"{held:.0f}/{computed:.0f}")
+                                f"{held:.0f}/{computed:.0f} "
+                                f"+{passes:.0f} chunks")
                     self.registry.gauge("moe/local_routes_pct",
                                         layer=name).set(local)
                     self.registry.gauge("moe/rows_held", layer=name).set(held)
                     self.registry.gauge("moe/rows_computed",
                                         layer=name).set(computed)
+                    self.registry.gauge("moe/overflow_chunks",
+                                        layer=name).set(passes)
                     self.registry.gauge(
                         "moe/short_dispatch_pct", layer=name).set(
                             100.0 * float(st["short_dispatch"]))
@@ -276,7 +280,7 @@ class RuntimeProfiler:
                     # a layer inside the expert exchange: the routes that
                     # fell on each chip's experts, the fullest chip's over
                     # the mean, and the share of chips and microbatches
-                    # that took the short buffer
+                    # that stopped at the first chunk
                     chips = np.asarray(st["rows_by_chip"], dtype=float)
                     for r, rows in enumerate(chips):
                         self.registry.gauge("moe/chip_rows", layer=name,

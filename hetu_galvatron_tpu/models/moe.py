@@ -40,7 +40,7 @@ from __future__ import annotations
 
 import functools
 import math
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -397,73 +397,165 @@ def _dropless_dispatch(
             ys * ws[:, None])
 
 
-# the margin over the expected share of the routes that the short buffer of
-# a layer that holds a share leaves, and the granularity of its row count
-# (a sublane tile)
-_SHORT_MARGIN, _ROW_TILE = 2, 8
+# a layer that holds a share computes the sorted slots in chunks: the first,
+# which always runs, is the expected share of the routes times _FIRST_MARGIN,
+# and each counted pass behind it the expected share times _CHUNK_SHARE (both
+# as (numerator, denominator)), rounded up to a sublane tile of rows. On the
+# chip a pass of 512 or of 1,024 rows costs 1.6 ms at 8 held experts (their
+# weights read and their float32 gradients added once more) and one of 2,048
+# rows 3.9 (the scatter-adds, by the row): PERF.md section 6, PR 52
+_FIRST_MARGIN, _CHUNK_SHARE, _ROW_TILE = (5, 4), (1, 4), 8
+
+
+def _share_rows(slots: int, held: int, num_experts: int,
+                share: Tuple[int, int]) -> int:
+    expected = -(-slots * held // num_experts)
+    return min(slots, -(-share[0] * expected // (share[1] * _ROW_TILE))
+               * _ROW_TILE)
 
 
 def short_rows(slots: int, held: int, num_experts: int) -> int:
-    """Rows of the short buffer of a layer that holds ``held`` of
+    """Rows of the first chunk of a layer that holds ``held`` of
     ``num_experts`` experts and has ``slots`` = T*K routes: the expected
-    share of the routes times a margin of 2, rounded up to a row tile, and
-    never above ``slots`` (where it reaches ``slots`` the layer has one
-    body)."""
-    expected = -(-slots * held // num_experts)
-    return min(slots, -(-_SHORT_MARGIN * expected // _ROW_TILE) * _ROW_TILE)
+    share of the routes and a quarter over, rounded up to a row tile, and
+    never above ``slots`` (where it reaches ``slots`` the layer has the one
+    body and no loop)."""
+    return _share_rows(slots, held, num_experts, _FIRST_MARGIN)
 
 
-def _sorted_rows_mlp(rows: int, cfg: ModelArgs, compute_dtype, xt, win, wout,
-                     ws, tok_sorted, mine_sorted, group_sizes):
-    """The expert MLPs over the first ``rows`` of the sorted slots: gather,
-    grouped matmuls, activation, weighting, scatter-add. Rows of the prefix
-    that belong to no group are zeroed going in and masked coming out, so
-    that nothing the grouped matmuls leave there reaches the result or a
-    gradient. The mask is on ``ys`` itself, BEFORE the weights: behind the
-    product its transpose hands the weights ``0 * ys``, which is NaN where
-    the chip left an inf or a NaN in such a row, and from there the router's
-    gradient and every block before it (PERF.md section 6, PR 40)."""
-    tok, mine = tok_sorted[:rows], mine_sorted[:rows, None]
+def overflow_rows(slots: int, held: int, num_experts: int) -> int:
+    """Rows of one counted pass over what the first chunk did not reach: a
+    quarter of the expected share of the routes, rounded up to a row tile."""
+    return _share_rows(slots, held, num_experts, _CHUNK_SHARE)
+
+
+def _sorted_rows_mlp(cfg: ModelArgs, compute_dtype, rows, win, wout, w_rows,
+                     mine, group_sizes):
+    """The expert MLPs over one chunk of the sorted slots, gathered: ``rows``
+    [R, H] through the grouped matmuls (``win`` / ``wout`` in the compute
+    dtype) and the activation, times the routes' weights; [R, H] float32 for
+    the scatter-add. Rows of the chunk that belong to no group are zeroed
+    going in and masked coming out, so that nothing the grouped matmuls
+    leave there reaches the result or a gradient. The mask is on ``ys``
+    itself, BEFORE the weights: behind the product its transpose hands the
+    weights ``0 * ys``, which is NaN where the chip left an inf or a NaN in
+    such a row, and from there the router's gradient and every block before
+    it (PERF.md section 6, PR 40)."""
     with jax.named_scope("moe/dispatch"):
-        xs = jnp.where(mine, xt[tok].astype(compute_dtype), 0)
+        xs = jnp.where(mine, rows.astype(compute_dtype), 0)
     with jax.named_scope("moe/experts"):
-        hproj = _grouped_matmul(xs, M.weight_view(win, compute_dtype),
-                                group_sizes, compute_dtype)
+        hproj = _grouped_matmul(xs, win, group_sizes, compute_dtype)
         hproj = _expert_act(hproj, cfg, compute_dtype)
-        ys = _grouped_matmul(hproj, M.weight_view(wout, compute_dtype),
-                             group_sizes, jnp.float32)
+        ys = _grouped_matmul(hproj, wout, group_sizes, jnp.float32)
     with jax.named_scope("moe/combine"):
-        return jnp.zeros(xt.shape, jnp.float32).at[tok].add(
-            jnp.where(mine, ys, 0.0) * ws[:rows, None])
+        return jnp.where(mine, ys, 0.0) * w_rows[:, None]
 
 
-def _short_or_full(short_body, full_body):
-    """``lax.cond`` between two bodies of one signature, with one backward
-    pass of its own: the forward keeps the operands alone, and the backward
-    is another ``cond`` whose branch recomputes and transposes its own body.
-    Plain reverse mode would have the forward return the residuals of BOTH
-    bodies, zero-filled for the one not taken: the full body's rows written
-    as zeros on the short path, which is the traffic the short body is
-    there to save."""
+class _Sorted(NamedTuple):
+    """What the sort of a layer's ``T*K`` slots hands its chunks; nothing
+    here is differentiated."""
+    order: jax.Array    # [T*K] the slot each sorted slot came from
+    ws: jax.Array       # [T*K] the routes' weights in sorted order
+    ends: jax.Array     # [held] the sorted slot a held expert's group ends at
+    passes: jax.Array   # [] the counted passes behind the first chunk
+
+
+def _counted_rows_mlp(cfg: ModelArgs, compute_dtype, first_len: int,
+                      chunk_len: int):
+    """``layer((xt, win, wout, w), slots: _Sorted) -> y [T, H] float32``:
+    :func:`_sorted_rows_mlp` over the first ``first_len`` sorted slots, then
+    ``slots.passes`` (counted on the device) times over the next
+    ``chunk_len``, each scatter-added into the one result; ``w`` [T*K] are
+    the routes' weights in slot order. A chunk's group sizes are the counted
+    ones clipped to it, so a chunk that ends inside a group computes the
+    part of the group it holds; the last pass starts where it still fits the
+    slots, and the rows it shares with the pass before are masked out of it.
+
+    One backward pass of its own, which keeps the operands alone: the first
+    chunk's pull-back, then the same counted loop, each pass recomputing its
+    own chunk and adding to the cotangents of the rows, the expert weights
+    and ``ws``, whose cotangent a sort by ``order`` takes back to ``w``'s
+    slots. Plain reverse mode cannot transpose a loop whose trip count is
+    traced, and would keep every pass's residuals if it could."""
+    def chunk_of(xt, slots, lo, length):
+        """(the tokens of the ``length`` sorted slots from ``lo``, their
+        rows, the MLP over them as a function of what is differentiated)."""
+        ends = slots.ends
+        at = jnp.minimum(lo, slots.order.shape[0] - length)
+        sorted_slot = at + jnp.arange(length)
+        mine = ((sorted_slot >= lo) & (sorted_slot < ends[-1]))[:, None]
+        starts = jnp.concatenate([jnp.zeros_like(ends[:1]), ends[:-1]])
+        sizes = (jnp.clip(ends, at, at + length)
+                 - jnp.clip(starts, at, at + length))
+        with jax.named_scope("moe/dispatch"):
+            tok = jax.lax.dynamic_slice(
+                slots.order, (at,), (length,)) // cfg.moe_topk
+            rows = xt[tok]
+
+        def mlp(rows, win, wout, ws):
+            return _sorted_rows_mlp(
+                cfg, compute_dtype, rows, win, wout,
+                jax.lax.dynamic_slice(ws, (at,), (length,)), mine, sizes)
+        return tok, rows, mlp
+
+    def add_chunk(y, lo, length, xt, win, wout, slots):
+        tok, rows, mlp = chunk_of(xt, slots, lo, length)
+        out = mlp(rows, win, wout, slots.ws)
+        with jax.named_scope("moe/combine"):
+            return y.at[tok].add(out)
+
+    def pull_chunk(cots, g, lo, length, xt, win, wout, slots):
+        tok, rows, mlp = chunk_of(xt, slots, lo, length)
+        pull = jax.vjp(mlp, rows, win, wout, slots.ws)[1]
+        with jax.named_scope("moe/combine"):
+            d_rows, *d_rest = pull(g[tok])
+        with jax.named_scope("moe/dispatch"):
+            d_xt = cots[0].at[tok].add(d_rows)
+        with jax.named_scope("moe/experts"):
+            return (d_xt, *(c + d.astype(c.dtype)
+                            for c, d in zip(cots[1:], d_rest)))
+
+    def views(operands):
+        xt, win, wout, _ = operands
+        return (xt, M.weight_view(win, compute_dtype),
+                M.weight_view(wout, compute_dtype))
+
+    def counted(first, chunk, slots):
+        """``chunk`` folded over the passes behind ``first``."""
+        if first_len >= slots.order.shape[0]:
+            return first
+        return jax.lax.fori_loop(
+            0, slots.passes,
+            lambda i, acc: chunk(acc, first_len + i * chunk_len, chunk_len),
+            first)
+
     @jax.custom_vjp
-    def either(short, operands, slots):
-        return jax.lax.cond(short, lambda ops: short_body(*ops, *slots),
-                            lambda ops: full_body(*ops, *slots), operands)
+    def layer(operands, slots):
+        ops = views(operands)
+        add = lambda y, lo, length: add_chunk(  # noqa: E731
+            y, lo, length, *ops, slots)
+        return counted(
+            add(jnp.zeros(ops[0].shape, jnp.float32), 0, first_len), add,
+            slots)
 
-    def forward(short, operands, slots):
-        return either(short, operands, slots), (short, operands, slots)
+    def forward(operands, slots):
+        return layer(operands, slots), (operands, slots)
 
     def backward(saved, g):
-        short, operands, slots = saved
+        operands, slots = saved
+        ops = views(operands)
+        pull = lambda cots, lo, length: pull_chunk(  # noqa: E731
+            cots, g, lo, length, *ops, slots)
+        zeros = tuple(jnp.zeros(a.shape, a.dtype) for a in operands)
+        *d_ops, d_ws = counted(pull(zeros, 0, first_len), pull, slots)
+        with jax.named_scope("moe/combine"):
+            # sorted slot i came from slot order[i]: a sort by ``order``
+            # is the scatter back, at a sort's price
+            _, d_w = jax.lax.sort((slots.order, d_ws), num_keys=1)
+        return (*d_ops, d_w), None
 
-        def pulled_back(body):
-            return lambda ops, g: jax.vjp(
-                lambda *o: body(*o, *slots), *ops)[1](g)
-        return None, jax.lax.cond(short, pulled_back(short_body),
-                                  pulled_back(full_body), operands, g), None
-
-    either.defvjp(forward, backward)
-    return either
+    layer.defvjp(forward, backward)
+    return layer
 
 
 def _held_dispatch(
@@ -478,52 +570,53 @@ def _held_dispatch(
 
     The static ``T*K`` slots sort by LOCAL expert id with every route to an
     absent expert keyed ``held``, so the held experts' routes come first, in
-    groups, and the tail belongs to no group. A token can choose
-    ``min(K, held)`` held experts, so no static bound under ``T*K`` rows is
-    safe when ``held >= K``: no route to a held expert is ever dropped,
-    under any imbalance. What the layer moves follows the COUNT instead:
-    where the routes that fell on a held expert fit the first
-    :func:`short_rows` sorted slots (decided on the device, a step and
-    microbatch at a time), gather, grouped matmuls and scatter-add run over
-    that prefix alone; where they do not, over all ``T*K``. A layer whose
-    short buffer would be the whole one has the one body. Returns (y [T, H]
-    float32, stats): ``rows_held`` routes that fell on a held expert,
-    ``rows_computed`` rows the body TAKEN handed to the grouped matmuls,
-    ``short_dispatch`` 1.0 where that was the short one,
-    ``held_tokens_per_expert`` [held]."""
+    groups, and the tail belongs to no group. One stable sort carries the
+    slots' indices and the routes' weights along, so nothing is gathered by
+    a slot at a time, and the groups' ends are counted from the keys. A
+    token can choose ``min(K, held)`` held experts, so no static bound under
+    ``T*K`` rows is safe when ``held >= K``: no route to a held expert is
+    ever dropped, under any imbalance. What the layer moves follows the
+    COUNT instead, in chunks (:func:`_counted_rows_mlp`): gather, grouped
+    matmuls and scatter-add run over the first :func:`short_rows` sorted
+    slots, which hold every counted route of a balanced step, and then over
+    as many further chunks of :func:`overflow_rows` as the count asks for
+    (decided on the device, a step and microbatch at a time: none on a
+    balanced step, ``T*K`` rows in all where every route fell here). A layer
+    whose first chunk would be all ``T*K`` slots has the one body and no
+    loop. Returns (y [T, H] float32, stats): ``rows_held`` routes that fell
+    on a held expert, ``overflow_chunks`` passes taken behind the first
+    chunk, ``rows_computed`` rows handed to the grouped matmuls (the first
+    chunk and the passes), ``short_dispatch`` 1.0 where the first chunk was
+    shorter than ``T*K`` and no pass was taken, ``held_tokens_per_expert``
+    [held]."""
     T, _ = xt.shape
     K = cfg.moe_topk
     held, first = held_range(cfg, ep, index)
-    short_len = short_rows(T * K, held, cfg.num_experts)
+    first_len = short_rows(T * K, held, cfg.num_experts)
+    chunk_len = overflow_rows(T * K, held, cfg.num_experts)
+    w = w.reshape(T * K)
     with jax.named_scope("moe/dispatch"):
         local = topk_idx.reshape(T * K) - first
-        mine = (local >= 0) & (local < held)
-        key = jnp.where(mine, local, held)
-        order = jnp.argsort(key, stable=True)
-        tok_sorted = (jnp.arange(T * K, dtype=jnp.int32) // K)[order]
-        mine_sorted = mine[order]
-        group_sizes = jnp.bincount(key, length=held + 1)[:held].astype(
+        key = jnp.where((local >= 0) & (local < held), local, held).astype(
             jnp.int32)
-        rows_held = jnp.sum(group_sizes)
-    with jax.named_scope("moe/combine"):
-        ws = w.reshape(T * K)[order]
-    operands = (xt, p["win"], p["wout"], ws)
-    slots = (tok_sorted, mine_sorted, group_sizes)
-    full_body = functools.partial(_sorted_rows_mlp, T * K, cfg, compute_dtype)
-    if short_len < T * K:
-        short = rows_held <= short_len
-        y = _short_or_full(
-            functools.partial(_sorted_rows_mlp, short_len, cfg,
-                              compute_dtype),
-            full_body)(short, operands, slots)
-    else:
-        short = jnp.zeros((), bool)
-        y = full_body(*operands, *slots)
+        _, order, ws = jax.lax.sort(
+            (key, jnp.arange(T * K, dtype=jnp.int32),
+             jax.lax.stop_gradient(w)), num_keys=1, is_stable=True)
+        ends = jnp.sum(key[:, None] <= jnp.arange(held, dtype=jnp.int32),
+                       axis=0, dtype=jnp.int32)
+        group_sizes = jnp.diff(ends, prepend=0)
+        rows_held = ends[-1]
+        passes = (jnp.maximum(rows_held - first_len, 0) + chunk_len - 1
+                  ) // chunk_len
+    y = _counted_rows_mlp(cfg, compute_dtype, first_len, chunk_len)(
+        (xt, p["win"], p["wout"], w), _Sorted(order, ws, ends, passes))
     stats = {
         "rows_held": rows_held.astype(jnp.float32),
-        "rows_computed": jnp.where(short, short_len, T * K).astype(
+        "rows_computed": (first_len + passes * chunk_len).astype(
             jnp.float32),
-        "short_dispatch": short.astype(jnp.float32),
+        "overflow_chunks": passes.astype(jnp.float32),
+        "short_dispatch": ((passes == 0) & (first_len < T * K)).astype(
+            jnp.float32),
         "held_tokens_per_expert": group_sizes.astype(jnp.float32)}
     return y, jax.lax.stop_gradient(stats)
 
@@ -540,13 +633,13 @@ def make_expert_exchange(mesh, dp_axes: Tuple[str, ...],
     Inside one ``shard_map``: the chip's own tokens, their chosen experts
     and weights are all-gathered over ``ep`` (scope ``moe/exchange/gather``);
     the chip runs :func:`_held_dispatch` over the group's tokens with ``held
-    = E / ep`` and ``first = axis_index x held``: sort, the short or the
-    full buffer by ITS OWN count (a ``lax.cond`` a chip: one chip may take
-    the full body while the others take the short one), grouped matmuls,
-    scatter-add; the float32 partial results are reduce-scattered back to
-    the tokens' owners (``moe/exchange/scatter``), so the sum of the ``ep``
-    partials is taken in float32, in the order the uncut layer's scatter-add
-    would take it. The backward pass is the transpose JAX derives: the
+    = E / ep`` and ``first = axis_index x held``: sort, the first chunk and
+    as many further ones as ITS OWN count asks for (a loop a chip, with no
+    collective inside: one chip may take a pass or several while the others
+    take none), grouped matmuls, scatter-add; the float32 partial results
+    are reduce-scattered back to the tokens' owners
+    (``moe/exchange/scatter``), so the sum of the ``ep`` partials is taken in
+    float32, in the order the uncut layer's scatter-add would take it. The backward pass is the transpose JAX derives: the
     gather's is a reduce-scatter and the reverse.
 
     Why tokens by all-gather and not routes by all-to-all: at top-K over
@@ -556,7 +649,8 @@ def make_expert_exchange(mesh, dp_axes: Tuple[str, ...],
     experts a token are fewer than the chips.
 
     ``stats`` are the group's: ``rows_held`` / ``rows_computed`` /
-    ``short_dispatch`` the mean over its chips, ``held_tokens_per_expert``
+    ``overflow_chunks`` / ``short_dispatch`` the mean over its chips (the
+    share of them that took no pass), ``held_tokens_per_expert``
     every expert's rows, ``rows_by_chip`` [ep] the routes that fell on each
     chip's experts."""
     from jax.sharding import PartitionSpec as P
@@ -592,6 +686,7 @@ def make_expert_exchange(mesh, dp_axes: Tuple[str, ...],
         return y, {
             "rows_held": by_chip["rows_held"].mean(),
             "rows_computed": by_chip["rows_computed"].mean(),
+            "overflow_chunks": by_chip["overflow_chunks"].mean(),
             "short_dispatch": stats["short_dispatch"].mean(),
             "held_tokens_per_expert":
                 by_chip["held_tokens_per_expert"].reshape(-1),

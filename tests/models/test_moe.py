@@ -436,7 +436,7 @@ def test_per_layer_aux_tracker_in_train_metrics(cpu_devices):
 # ---------------------------------------------------------------------------
 
 # 8 experts, 2 a token, 64 slots; a layer that holds experts 2 and 3 has a
-# short buffer of 32 rows. H = 32, F = 24, so that the three matrices a
+# first chunk of 24 rows. H = 32, F = 24, so that the three matrices a
 # grouped matmul can read ([*, H, 2F], [*, F, H] and their transposes) and
 # its three results' widths (2F, H, F) tell the six of a layer apart
 GROUPED_CFG = ModelArgs(
@@ -448,10 +448,11 @@ GROUPED_CFG = ModelArgs(
     moe_ffn_hidden_size=24, num_experts=8, moe_topk=2,
     moe_score_function="sigmoid", moe_router_enable_expert_bias=True,
     moe_dispatcher="dropless", moe_aux_loss_coeff=0.0)
-# mode -> (experts held, the selection bias on the held pair, the body taken)
+# mode -> (experts held, the selection bias on the held pair, the counted
+# passes behind the first chunk: 24 of the 64 slots, then 8 a pass)
 GROUPED_MODES = {"dropless": (0, 0.0, None),
-                 "held_short_body": (2, 0.0, 1.0),
-                 "held_full_body": (2, 10.0, 0.0)}
+                 "held_short_body": (2, 0.0, 0.0),
+                 "held_full_body": (2, 10.0, 5.0)}
 
 
 def _grouped_case(mode):
@@ -513,19 +514,21 @@ def test_grouped_matmuls_read_and_write_the_compute_dtype(
     """What stands in for a counter of engagement: the gradient of the
     expert layer at bfloat16 holds no grouped matmul with a float32 operand,
     and none with a float32 result but the forward ``wout`` product (rows
-    [*, F] through [*, F, H]; a layer with two bodies has it in each, forward
-    and recomputed). At float32 the grouped matmuls are the parent's, in the
-    parent's order, and so are the loss and the four gradients, bit for
-    bit, on the body the mode takes."""
+    [*, F] through [*, F, H]; a layer that holds a share has it in its first
+    chunk and in a counted pass, forward and recomputed). At float32 the
+    grouped matmuls are the parent's, in the parent's order, and so are the
+    loss and the four gradients, bit for bit, on the chunks the mode
+    takes."""
     cfg, dispatch, operands = _grouped_case(mode)
-    held, _, short = GROUPED_MODES[mode]
+    held, _, passes = GROUPED_MODES[mode]
     F, H = cfg.moe_ffn_hidden_size, cfg.hidden_size
     grad_of = lambda dt: _sum_sq_and_grads(dispatch, dt)  # noqa: E731
 
     calls = _grouped_matmuls(jax.make_jaxpr(grad_of(jnp.bfloat16))(
         *operands).jaxpr)
-    # forward 2 and 4 transposes; a held share: two bodies forward, and two
-    # backward that each recompute their 2 and transpose each twice
+    # forward 2 and 4 transposes; a held share: the first chunk and the
+    # loop's pass forward, and the two again backward, where each recomputes
+    # its 2 and transposes each twice
     assert len(calls) == (16 if held else 6)
     forward_wout = 0
     for lhs, rhs, out, _ in calls:
@@ -539,7 +542,9 @@ def test_grouped_matmuls_read_and_write_the_compute_dtype(
     mine = jax.make_jaxpr(grad_of(jnp.float32))(*operands)
     (loss, (_, stats)), grads = jax.jit(grad_of(jnp.float32))(*operands)
     if held:
-        assert float(stats["short_dispatch"]) == short
+        assert float(stats["overflow_chunks"]) == passes
+        assert float(stats["short_dispatch"]) == (passes == 0)
+        assert float(stats["rows_computed"]) == 24 + 8 * passes
     grouped_matmul_as_before_pr38()
     parents = jax.make_jaxpr(grad_of(jnp.float32))(*operands)
     assert [tuple(map(str, c)) for c in _grouped_matmuls(mine.jaxpr)] == \

@@ -115,23 +115,25 @@ def test_the_exchanged_layer_is_the_dropless_layer_on_one_device(
 @pytest.mark.usefixtures("highest")
 @pytest.mark.parametrize("ep", [2, 4])
 def test_a_chip_that_receives_every_route_drops_none(ep, cpu_devices):
-    """Every token to the last chip's experts: that chip's count passes the
-    short buffer and it takes the full body, the others take the short one
-    (where the layer has two bodies: at ep = 2 the margin of 2 makes the
-    short buffer the whole one), and the result is the one-device layer's,
-    which the case above holds."""
+    """Every token to the last chip's experts: that chip's count passes its
+    first chunk and it takes every pass there is, to all ``T*K`` rows, while
+    the others stop at the first chunk and take none, and the result is the
+    one-device layer's, which the case above holds."""
     p, x = _layer("skewed", ep)
     (_, (y, stats)), _ = _exchanged(ep)(p, x)
     rows = np.asarray(stats["rows_by_chip"])
     slots = 16 * K * ep          # an expert-dp group's routes, 8 / ep groups
     assert rows.tolist() == [0.0] * (ep - 1) + [8 * 16 * K]
-    short_len = moe.short_rows(slots, E // ep, E)
-    if short_len < slots:
-        assert float(stats["short_dispatch"]) == (ep - 1) / ep
-        assert float(stats["rows_computed"]) == (
-            (ep - 1) * short_len + slots) * (8 // ep) / ep
-    else:
-        assert float(stats["short_dispatch"]) == 0.0
+    first_len = moe.short_rows(slots, E // ep, E)
+    chunk_len = moe.overflow_rows(slots, E // ep, E)
+    assert (first_len, chunk_len) == (40, 8)
+    passes = -(-(slots - first_len) // chunk_len)
+    # the mean over the group's chips of what each one's expert-dp groups
+    # add up to
+    assert float(stats["short_dispatch"]) == (ep - 1) / ep
+    assert float(stats["overflow_chunks"]) == passes * (8 // ep) / ep
+    assert float(stats["rows_computed"]) == (
+        ep * first_len + passes * chunk_len) * (8 // ep) / ep
     want = moe.apply_moe_mlp(p, x, LAYER, compute_dtype=jnp.float32)[0]
     np.testing.assert_allclose(y, want, rtol=1e-5, atol=1e-6)
 
@@ -320,12 +322,13 @@ def test_an_ep_that_does_not_divide_the_experts_is_refused():
 # ---------------------------------------------------------------------------
 
 # sha256 of str(jaxpr) of the first expert block's layer, value and
-# gradient, at the cell's own sizes (traced on shapes alone), recorded from
-# PR 51's parent: ``exchange`` is None there and ``held_range`` adds a
-# Python 0
+# gradient, at the cell's own sizes (traced on shapes alone). ``olmoe``'s
+# (``_dropless_dispatch``) is recorded from PR 51's parent: ``exchange`` is
+# None there and ``held_range`` adds a Python 0. ``laguna``'s (a held share)
+# from PR 52, which gave the share its chunks
 RECORDED = {
     "laguna_c1_b1":
-        "9a745e5921108db77ee43ba60fc56227e144684c0db3627b35a0eadfe47b2043",
+        "d51ba2076e4f176e20963d36bc4e426883f317bf1dd699b14618ef1554823be9",
     "olmoe_c1_s4k":
         "a37cb13be146c7c1a96dcdc450b53c221589e441c8ab1ceba25115bf4bbbb8ba",
 }

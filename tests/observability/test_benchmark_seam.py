@@ -57,8 +57,8 @@ PRESETS = {
         "model.num_dense_layers=1", "model.num_key_value_heads=2",
         "model.ffn_hidden_size=32", "model.moe_ffn_hidden_size=16",
         "model.num_experts=8", "model.moe_topk=2",
-        # a quarter of the experts: at a half and more the layer that holds
-        # a share has no short body to take
+        # a quarter of the experts: at four fifths and more the first chunk
+        # of a layer that holds a share is the whole buffer
         "model.moe_held_experts=2", "model.moe_first_held_expert=0"],
     "granite": ["granite-4.0-h-micro.yaml"] + SIZE + [
         "model.layer_types=[mamba,full_attention]",
@@ -169,19 +169,22 @@ def test_registry_reader_finds_what_the_program_wrote(run, name):
 @pytest.mark.parametrize("name", (lfm2_gauges.LOCAL_ROUTES_GAUGE,
                                   lfm2_gauges.IMBALANCE_GAUGE)
                          + lfm2_gauges.ROWS_GAUGES
-                         + ("moe/short_dispatch_pct",))
+                         + ("moe/short_dispatch_pct", "moe/overflow_chunks"))
 def test_a_layer_that_holds_a_share_writes_its_gauges(run, name):
     found = [m for m in run["registry"].metrics() if m.name == name
              and m.labels.get("layer") == lfm2_gauges.FIRST_EXPERT_LAYER]
     if run["preset"] == "lfm2":
-        assert len(found) == 1 and found[0].value > 0
+        # a balanced step takes no pass behind the first chunk
+        assert len(found) == 1 and (found[0].value > 0) == (
+            name != "moe/overflow_chunks")
     elif name != lfm2_gauges.IMBALANCE_GAUGE:
         assert not found
 
 
 def test_the_rows_gauges_show_the_body_taken(run):
     """A quarter of the experts held at random weights: both microbatches
-    of the last logged step took the short body, 32 of their 64 slots."""
+    of the last logged step stopped at the first chunk, 24 of their 64
+    slots, and took no counted pass behind it."""
     from hetu_galvatron_tpu.models.moe import short_rows
 
     gauge = {m.name: m.value for m in run["registry"].metrics()
@@ -191,7 +194,8 @@ def test_the_rows_gauges_show_the_body_taken(run):
         return
     held, computed = (gauge[name] for name in lfm2_gauges.ROWS_GAUGES)
     assert gauge["moe/short_dispatch_pct"] == 100.0
-    assert 0 < held <= computed == 2 * short_rows(2 * 16 * 2, 2, 8) == 64
+    assert gauge["moe/overflow_chunks"] == 0
+    assert 0 < held <= computed == 2 * short_rows(2 * 16 * 2, 2, 8) == 48
 
 
 @pytest.mark.parametrize("mixer,ff,blocks", [
