@@ -227,7 +227,11 @@ def train(args) -> Dict[str, Any]:
         # sends around them a step (from the shapes), and by name any sorted
         # dispatcher under ep > 1 that the exchange does not serve
         from hetu_galvatron_tpu.analysis import eligibility
-        from hetu_galvatron_tpu.models.moe import exchange_bytes
+        from hetu_galvatron_tpu.models.moe import (
+            exchange_bytes,
+            overflow_rows,
+            short_rows,
+        )
 
         ep_report: Dict[str, Any] = {}
         exchanged = [
@@ -243,10 +247,24 @@ def train(args) -> Dict[str, Any]:
                     cfg.hidden_size, cfg.moe_topk, s.ep_size,
                     4 if args.parallel.mixed_precision == "fp32" else 2,
                     3 if s.checkpoint else 2) for s in exchanged)}
+            # a chip's expert layer walks the group's routes of a
+            # microbatch: the first chunk that always runs and one counted
+            # pass behind it, in rows (``moe._held_dispatch``)
+            first = exchanged[0]
+            slots = (hpc.global_bsz // max(hpc.chunks, 1) // first.dp_size
+                     * cfg.seq_length * first.ep_size * cfg.moe_topk)
+            held = cfg.num_experts // first.ep_size
+            ep_report["first_chunk_rows"] = short_rows(
+                slots, held, cfg.num_experts)
+            ep_report["pass_rows"] = overflow_rows(
+                slots, held, cfg.num_experts)
             state.log("expert exchange: ep/axes {axes} over {blocks} expert "
                       "blocks, ep/exchange_bytes_per_step "
-                      "{exchange_bytes_per_step}".format(**ep_report))
-            for k in ("axes", "exchange_bytes_per_step"):
+                      "{exchange_bytes_per_step}, ep/first_chunk_rows "
+                      "{first_chunk_rows}, ep/pass_rows {pass_rows}".format(
+                          **ep_report))
+            for k in ("axes", "exchange_bytes_per_step", "first_chunk_rows",
+                      "pass_rows"):
                 get_registry().gauge(f"ep/{k}").set(ep_report[k])
         unserved = eligibility.ep_plan_reason(cfg, hpc.layers, hpc.pp_deg)
         if unserved:
@@ -315,7 +333,8 @@ def train(args) -> Dict[str, Any]:
                 capacity=args.observability.flight_events)
             recorder.note("run_start", plan=hpc.describe(), world=world)
         profiler = RuntimeProfiler(args, world_size=world,
-                                   rank=jax.process_index())
+                                   rank=jax.process_index(),
+                                   pass_rows=ep_report.get("pass_rows"))
         rerun = RerunStateMachine(args.rerun)
         # preemption guard + at-step-k fault drill (runtime/supervisor.py):
         # SIGTERM/SIGINT become a checkpoint-and-exit at the next step boundary
@@ -1120,6 +1139,21 @@ def train(args) -> Dict[str, Any]:
         with span("setup/init"):
             mesh = build_mesh(world, 1, devices=state.devices,
                               dcn_slices=args.parallel.dcn_slices)
+            if ep_report:
+                # which chip of the ep group each device is: a trace names
+                # its planes by device id, the log line its chips by index
+                from hetu_galvatron_tpu.runtime.mesh import (
+                    devices_along,
+                    lower_strategy,
+                )
+
+                ep_report["chip_devices"] = devices_along(
+                    mesh, lower_strategy(exchanged[0], mesh).ep_axes)
+                for chip, ids in enumerate(ep_report["chip_devices"]):
+                    for device in ids:
+                        get_registry().gauge(
+                            "ep/chip_of_device",
+                            device=str(device)).set(chip)
             # donation halves live model-state memory but is only safe when
             # the rerun machine will never re-call the step on pre-update
             # buffers
@@ -1296,6 +1330,9 @@ def train(args) -> Dict[str, Any]:
                        ", flash/transposed_calls "
                        f"{step_report['transposed_calls']}"
                        if "row_layout_calls" in step_report else "")
+                    + (", ep {axes} first-chunk rows {first_chunk_rows} "
+                       "pass rows {pass_rows}".format(**ep_report)
+                       if ep_report else "")
                     + f", {step_report['cores_recomputed']} cores recomputed,"
                     f" {step_report['scans_recomputed']} scans recomputed,"
                     f" static live peak "
@@ -1344,7 +1381,9 @@ def train(args) -> Dict[str, Any]:
             "attention_cores": attention_cores,
             # the expert exchange: the ep degree of the blocks inside it,
             # how many there are, bytes a chip sends around them a step
-            # (gauges ep/axes, ep/exchange_bytes_per_step); None without
+            # (gauges ep/axes, ep/exchange_bytes_per_step), and the rows of
+            # a chip's first chunk and of one counted pass behind it, a
+            # microbatch (ep/first_chunk_rows, ep/pass_rows); None without
             "ep": ep_report or None,
             # blocks by "<mixer>/<feed-forward>" kind (step/blocks gauges)
             "blocks": blocks,

@@ -106,10 +106,15 @@ class RuntimeProfiler:
     iteration log line."""
 
     def __init__(self, args: CoreArgs, world_size: int = 1, rank: int = 0,
-                 registry: Optional[MetricsRegistry] = None):
+                 registry: Optional[MetricsRegistry] = None,
+                 pass_rows: Optional[int] = None):
         self.args = args
         self.world_size = world_size
         self.rank = rank
+        # rows of one counted pass of a layer inside the expert exchange
+        # (``moe.overflow_rows``, the launcher's ``ep`` report): what the
+        # log line takes off ``rows_computed`` to find the first chunk's
+        self.pass_rows = pass_rows
         # None = late-bind the process default at USE time, so a profiler
         # constructed before the train launcher configures sinks still
         # lands its metrics in the configured stream
@@ -241,6 +246,7 @@ class RuntimeProfiler:
         if self.time_samples:
             bits.append(f"iter-time {self.time_samples[-1]:.1f}ms")
         if "moe" in metrics:
+            step_passes = None
             # per-layer balance tracker (reference moe_utils.py:608-644
             # track_moe_metrics log lines): aux/z-loss per MoE layer plus
             # the tokens-per-expert imbalance max/mean; the converted
@@ -257,12 +263,30 @@ class RuntimeProfiler:
                     # the first chunk, the share of the microbatches that
                     # took none, and the balance over the HELD experts,
                     # whose rows are the matmuls' groups
-                    held, computed, passes = (
-                        float(st[k]) for k in (
-                            "rows_held", "rows_computed", "overflow_chunks"))
-                    local = 100.0 * held / max(tpe.sum(), 1e-9)
+                    all_routes = tpe.sum()
                     tpe = np.asarray(st["held_tokens_per_expert"],
                                      dtype=float)
+                    if "rows_by_chip" in st:
+                        # inside the expert exchange the layer's counts
+                        # are MEANS over the chips, and a chip's rows are
+                        # its experts' (``held_tokens_per_expert`` is every
+                        # expert's, chip by chip): the line converts the
+                        # one new leaf, the passes by chip, and takes the
+                        # rest from what it converts anyway. A vector that
+                        # lies a part a chip costs the host of a chip 0.1
+                        # to 0.2 ms to read (PERF.md section 6, PR 54)
+                        by_chip = np.asarray(st["passes_by_chip"],
+                                             dtype=float)
+                        chips = tpe.reshape(len(by_chip), -1).sum(axis=1)
+                        held, passes = float(chips.mean()), float(
+                            by_chip.mean())
+                        computed = float(st["rows_computed"])
+                    else:
+                        held, computed, passes = (
+                            float(st[k]) for k in (
+                                "rows_held", "rows_computed",
+                                "overflow_chunks"))
+                    local = 100.0 * held / max(all_routes, 1e-9)
                     bits.append(f"moe[{name}] local {local:.2f}% rows "
                                 f"{held:.0f}/{computed:.0f} "
                                 f"+{passes:.0f} chunks")
@@ -277,21 +301,31 @@ class RuntimeProfiler:
                         "moe/short_dispatch_pct", layer=name).set(
                             100.0 * float(st["short_dispatch"]))
                 if "rows_by_chip" in st:
-                    # a layer inside the expert exchange: the routes that
-                    # fell on each chip's experts, the fullest chip's over
-                    # the mean, and the share of chips and microbatches
-                    # that stopped at the first chunk
-                    chips = np.asarray(st["rows_by_chip"], dtype=float)
-                    for r, rows in enumerate(chips):
+                    # a layer inside the expert exchange, chip by chip: the
+                    # routes that fell on each chip's experts and the
+                    # counted passes it took behind its first chunk, the
+                    # fullest chip's routes over the mean and over the
+                    # first chunk's rows (100 is the line behind which a
+                    # pass is taken). The layer's ``rows_computed`` and
+                    # ``overflow_chunks`` above are MEANS over the chips;
+                    # the step waits for the chip that took the most
+                    for r, (rows, took) in enumerate(zip(chips, by_chip)):
                         self.registry.gauge("moe/chip_rows", layer=name,
                                             chip=str(r)).set(float(rows))
+                        self.registry.gauge("moe/chip_passes", layer=name,
+                                            chip=str(r)).set(float(took))
                     chip_imb = float(chips.max() / max(chips.mean(), 1e-9))
-                    bits.append(f"moe[{name}] chips {chip_imb:.3f}")
+                    bits.append(
+                        f"moe[{name}] chips {chip_imb:.3f} passes "
+                        + "/".join(f"{took:.0f}" for took in by_chip))
                     self.registry.gauge("moe/chip_imbalance",
                                         layer=name).set(chip_imb)
-                    self.registry.gauge("moe/short_dispatch",
-                                        layer=name).set(
-                                            float(st["short_dispatch"]))
+                    if self.pass_rows is not None or not passes:
+                        first = computed - passes * (self.pass_rows or 0)
+                        self.registry.gauge(
+                            "moe/fullest_chip_pct", layer=name).set(
+                                100.0 * float(chips.max()) / max(first, 1e-9))
+                    step_passes = (step_passes or 0.0) + float(by_chip.max())
                 imb = float(tpe.max() / max(tpe.mean(), 1e-9))
                 aux = float(st["load_balance_loss"])
                 z = float(st["z_loss"])
@@ -302,6 +336,11 @@ class RuntimeProfiler:
                 self.registry.gauge("moe/imbalance", layer=name).set(imb)
                 self.registry.gauge("moe/rows_per_expert", layer=name,
                                     stat="max").set(float(tpe.max()))
+            if step_passes is not None:
+                # the passes the step waited for: every exchanged layer's
+                # fullest chip's, added up
+                self.registry.histogram("moe/step_passes").observe(
+                    step_passes)
         line = " | ".join(bits)
         print(line, flush=True)
         return line

@@ -649,10 +649,14 @@ def make_expert_exchange(mesh, dp_axes: Tuple[str, ...],
     experts a token are fewer than the chips.
 
     ``stats`` are the group's: ``rows_held`` / ``rows_computed`` /
-    ``overflow_chunks`` / ``short_dispatch`` the mean over its chips (the
-    share of them that took no pass), ``held_tokens_per_expert``
-    every expert's rows, ``rows_by_chip`` [ep] the routes that fell on each
-    chip's experts."""
+    ``overflow_chunks`` / ``short_dispatch`` the MEAN over its chips (0.25
+    ``overflow_chunks`` over four chips is one chip that took one pass; the
+    share of them that took no pass), ``held_tokens_per_expert`` every
+    expert's rows, and by chip, [ep] each: ``rows_by_chip`` the routes that
+    fell on a chip's experts, ``passes_by_chip`` the counted passes it took
+    behind its first chunk. The step runs at the pace of the MAX over the
+    chips, not of the mean: the others wait for the fullest at the
+    reduce-scatter."""
     from jax.sharding import PartitionSpec as P
 
     from hetu_galvatron_tpu.ops.pallas.common import on_shards
@@ -690,7 +694,8 @@ def make_expert_exchange(mesh, dp_axes: Tuple[str, ...],
             "short_dispatch": stats["short_dispatch"].mean(),
             "held_tokens_per_expert":
                 by_chip["held_tokens_per_expert"].reshape(-1),
-            "rows_by_chip": by_chip["rows_held"]}
+            "rows_by_chip": by_chip["rows_held"],
+            "passes_by_chip": by_chip["overflow_chunks"]}
 
     return exchange
 

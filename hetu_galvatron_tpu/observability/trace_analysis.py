@@ -954,13 +954,42 @@ def step_scopes() -> Dict[str, Any]:
     return dict(_STEP_SCOPES)
 
 
+def chip_counts() -> Dict[str, Dict[str, List[float]]]:
+    """What the log line last wrote chip by chip for the layers inside the
+    expert exchange: ``{"rows": {layer: [by chip]}, "passes": {...}}`` from
+    the gauges ``moe/chip_rows{layer,chip}`` and ``moe/chip_passes``, and
+    ``"devices": {device id: chip}`` from ``ep/chip_of_device{device}``
+    (a trace's planes are named by device id); empty where no layer ran
+    inside one."""
+    out: Dict[str, Dict[str, Dict[int, float]]] = {}
+    devices: Dict[str, int] = {}
+    for m in get_registry().metrics():
+        kind = {"moe/chip_rows": "rows", "moe/chip_passes": "passes"}.get(
+            m.name)
+        if kind:
+            out.setdefault(kind, {}).setdefault(m.labels["layer"], {})[
+                int(m.labels["chip"])] = m.value
+        elif m.name == "ep/chip_of_device":
+            devices[m.labels["device"]] = int(m.value)
+    found: Dict[str, Any] = {
+        kind: {layer: [by[c] for c in sorted(by)]
+               for layer, by in sorted(layers.items())}
+        for kind, layers in out.items()}
+    if found and devices:
+        found["devices"] = devices
+    return found
+
+
 def write_step_map(trace_dir: str) -> Optional[str]:
     """Write the recorded step's ``map`` beside a trace
     (``<trace_dir>/step_map.json``), so that the trace is joined to scopes,
     phases and collective classes later without the process that made it
-    (``tools/trace_by_scope.py``). Nothing is written, and None returned,
-    where no step has reported or the directory cannot be written: a
-    profiler window closes on crash paths too."""
+    (``tools/trace_by_scope.py``); with it, as ``chips``, the rows and
+    passes the last logged step (a traced one: the window has just closed)
+    handed each chip of an expert exchange (:func:`chip_counts`). Nothing
+    is written, and None returned, where no step has reported or the
+    directory cannot be written: a profiler window closes on crash paths
+    too."""
     kept = _STEP_SCOPES.get("map")
     if not kept or not trace_dir:
         return None
@@ -968,7 +997,8 @@ def write_step_map(trace_dir: str) -> Optional[str]:
     try:
         os.makedirs(trace_dir, exist_ok=True)
         with open(path, "w") as f:
-            json.dump({"vocabulary": SCOPES, "phases": PHASES, **kept}, f)
+            json.dump({"vocabulary": SCOPES, "phases": PHASES, **kept,
+                       "chips": chip_counts()}, f)
     except OSError:
         return None
     return path

@@ -31,7 +31,7 @@ from __future__ import annotations
 
 import math
 from dataclasses import dataclass
-from typing import Any, Optional, Sequence, Tuple
+from typing import Any, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -405,6 +405,18 @@ def lower_strategy(s: LayerStrategy, mesh: Mesh) -> LayerSharding:
         ep_axes=dp_axes[:kep],
         etp_axes=tp_axes[len(tp_axes) - ketp:] if ketp else (),
     )
+
+
+def devices_along(mesh: Mesh, axes: Tuple[str, ...]) -> List[List[int]]:
+    """The ids of the devices at each index along ``axes`` (row-major over
+    them, as ``jax.lax.axis_index(axes)`` counts inside a ``shard_map``):
+    ``[index][...]``. A device trace names its planes by device id, and a
+    mesh need not hold its devices in the order of their ids."""
+    names = list(mesh.axis_names)
+    lead = [names.index(a) for a in axes]
+    ids = np.vectorize(lambda d: d.id, otypes=[int])(mesh.devices)
+    ids = ids.transpose(lead + [i for i in range(ids.ndim) if i not in lead])
+    return ids.reshape(math.prod(ids.shape[:len(lead)]), -1).tolist()
 
 
 def lower_vocab_strategy(

@@ -75,8 +75,8 @@ def make_train_step(
     stats land in metrics["moe"] — the reference's per-layer aux-losses
     tracker (moe_utils.py:547-644). Loss-like stats are token-weighted
     across microbatches (so a 0/1 flag such as "short_dispatch" reads as a
-    share of the microbatches); "tokens_per_expert", "rows_*" and
-    "overflow_chunks" leaves are summed.
+    share of the microbatches); "tokens_per_expert", "rows_*",
+    "overflow_chunks" and "passes_by_chip" leaves are summed.
 
     ``hier`` (an ``ops.hier_reduce.HierDpReducer``) swaps the implicit
     GSPMD dp gradient all-reduce for the explicit hierarchical path:
@@ -124,7 +124,8 @@ def make_train_step(
         def red(path, s):
             # counts add up over the microbatches; the rest are means
             if any(name in str(k) for k in path for name in (
-                    "tokens_per_expert", "rows_", "overflow_chunks")):
+                    "tokens_per_expert", "rows_", "overflow_chunks",
+                    "passes_by_chip")):
                 return jnp.sum(s, axis=0)
             w = weights.reshape((-1,) + (1,) * (s.ndim - 1))
             return jnp.sum(w * s, axis=0)

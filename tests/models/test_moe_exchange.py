@@ -138,6 +138,100 @@ def test_a_chip_that_receives_every_route_drops_none(ep, cpu_devices):
     np.testing.assert_allclose(y, want, rtol=1e-5, atol=1e-6)
 
 
+def _routes_with_one_chip_over_the_line(fullest: int):
+    """[8 x 16, K] chosen experts for the 8-device mesh under ep = 4: the
+    ep group of the even token blocks sends 44 of its 128 routes to chip
+    ``fullest``'s two experts and 28 to each other chip's, the group of the
+    odd blocks 32 to each. A chip's first chunk is 40 rows and a pass 8."""
+    pairs = [(2 * c, 2 * c + 1) for c in range(4)]
+    others = [c for c in range(4) if c != fullest]
+    skewed = [pairs[fullest]] * 22 + [pairs[c] for c in others for _ in
+                                      range(14)]
+    even_group = [pairs[c] for c in range(4) for _ in range(16)]
+    idx = np.zeros((8, 16, K), np.int32)
+    for block in range(8):
+        source = skewed if block % 2 == 0 else even_group
+        at = (block // 2) * 16
+        idx[block] = source[at:at + 16]
+    return jnp.asarray(idx.reshape(8 * 16, K))
+
+
+@pytest.mark.usefixtures("highest")
+@pytest.mark.parametrize("fullest", [0, 1, 2, 3])
+def test_the_passes_are_handed_out_chip_by_chip(fullest, cpu_devices):
+    """One chip of four is four routes past its first chunk in one of its two
+    expert-dp groups: ``passes_by_chip`` says which chip took the pass,
+    its mean is the ``overflow_chunks`` the layer always gave, and the
+    layer's output and every gradient are bit for bit those of the exchange
+    that hands out the means alone (the parent's)."""
+    ep = 4
+    mesh = build_mesh(8, 1)
+    exchange = moe.make_expert_exchange(mesh, DP, DP[:2])
+    p, x = _layer("balanced", ep)
+    xt = x.reshape(-1, H)
+    idx = _routes_with_one_chip_over_the_line(fullest)
+    w = jax.nn.softmax(jax.random.normal(jax.random.key(2), idx.shape))
+
+    def means_alone(*a):
+        y, stats = exchange(*a)
+        return y, {k: v for k, v in stats.items() if k != "passes_by_chip"}
+
+    def run(layer):
+        def loss(p, xt, w):
+            y, stats = layer(p, xt, idx, w, LAYER, jnp.float32)
+            return jnp.sum(jnp.sin(y)), (y, stats)
+        shd = lambda spec: NamedSharding(mesh, spec)
+        return jax.jit(
+            jax.value_and_grad(loss, argnums=(0, 1, 2), has_aux=True),
+            in_shardings=({"router": shd(P()), "win": shd(P(DP[:2])),
+                           "wout": shd(P(DP[:2]))}, shd(P(DP)),
+                          shd(P(DP))))(p, xt, w)
+
+    (got, (y, stats)), grads = run(exchange)
+    (was, (y_was, stats_was)), grads_was = run(means_alone)
+    want = [44.0 + 32.0 if c == fullest else 28.0 + 32.0 for c in range(ep)]
+    assert np.asarray(stats["rows_by_chip"]).tolist() == want
+    passes = np.asarray(stats["passes_by_chip"])
+    assert passes.tolist() == [float(c == fullest) for c in range(ep)]
+    assert float(stats["overflow_chunks"]) == passes.mean() == 0.25
+    assert float(stats["rows_computed"]) == 2 * 40 + 8 / ep
+    assert "passes_by_chip" not in stats_was
+    assert float(got) == float(was)
+    np.testing.assert_array_equal(y, y_was)
+    for (path, a), (_, b) in zip(
+            jax.tree_util.tree_leaves_with_path(grads_was),
+            jax.tree_util.tree_leaves_with_path(grads)):
+        np.testing.assert_array_equal(b, a,
+                                      err_msg=jax.tree_util.keystr(path))
+    # and it is the layer: every route computed, none twice
+    one = moe._held_dispatch(p, xt, idx, w, LAYER, jnp.float32)[0]
+    np.testing.assert_allclose(y, one, rtol=1e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize("ep", [2, 4, 8])
+def test_the_devices_of_a_chip_are_where_axis_index_counts_them(
+        ep, cpu_devices):
+    """``devices_along(mesh, ep_axes)[r]`` are the ids of the devices on
+    which ``axis_index(ep_axes)`` is ``r``: what lays a trace's planes
+    (device ids) beside the log line's chips (places in the group), on a
+    mesh that holds its devices in another order than their ids."""
+    from hetu_galvatron_tpu.ops.pallas.common import on_shards
+    from hetu_galvatron_tpu.runtime.mesh import devices_along
+
+    order = [3, 1, 7, 5, 0, 2, 6, 4]
+    mesh = build_mesh(8, 1, devices=[cpu_devices[i] for i in order])
+    ep_axes = DP[:ep.bit_length() - 1]
+    got = devices_along(mesh, ep_axes)
+    assert len(got) == ep and sorted(d for ids in got for d in ids) == \
+        sorted(d.id for d in cpu_devices[:8])
+    where = on_shards(
+        lambda x: x + jax.lax.axis_index(ep_axes), mesh, (P(DP),),
+        P(DP))(jnp.zeros(8, jnp.int32))
+    by_device = {s.device.id: int(s.data[0])
+                 for s in where.addressable_shards}
+    assert by_device == {d: r for r, ids in enumerate(got) for d in ids}
+
+
 def _reference_weights(p):
     """The layer's weights under the names benchmark/reference/mellum.py
     reads."""

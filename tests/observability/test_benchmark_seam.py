@@ -37,6 +37,7 @@ lfm2_gauges = manifest.load_python(os.path.join(READERS, "lfm2_gauges.py"))
 granite_scopes = manifest.load_python(
     os.path.join(READERS, "granite_scopes.py"))
 step_map = manifest.load_python(os.path.join(READERS, "step_map.py"))
+chip_skew = manifest.load_python(os.path.join(READERS, "chip_skew.py"))
 
 TRACED, MEASURED = 3, 2
 ITERS = window.WARMUP_STEPS + TRACED + MEASURED
@@ -196,6 +197,144 @@ def test_the_rows_gauges_show_the_body_taken(run):
     assert gauge["moe/short_dispatch_pct"] == 100.0
     assert gauge["moe/overflow_chunks"] == 0
     assert 0 < held <= computed == 2 * short_rows(2 * 16 * 2, 2, 8) == 48
+
+
+# the expert preset across four devices, its experts inside the exchange:
+# the plan of ``mellum2_c4_ep4`` (ep 4 carved from dp 4, a sequence a device
+# in one microbatch), whose log line counts chip by chip
+EXCHANGED = PRESETS["moe"] + [
+    "model.num_experts=8", "parallel.num_devices=4", "parallel.chunks=1",
+    "parallel.global_ep_deg=4", "train.train_iters=2"]
+
+
+@pytest.fixture(scope="module")
+def exchange_run():
+    import jax
+
+    if len(jax.devices()) < 4:
+        pytest.skip("needs four devices")
+    yaml, *size = EXCHANGED
+    with _launched([os.path.join(ZOO, yaml)] + size) as ran:
+        yield ran
+
+
+@pytest.mark.parametrize("name", chip_skew.CHIP_GAUGES + (
+    chip_skew.FULLEST_CHIP_GAUGE, chip_skew.STEP_PASSES_HISTOGRAM,
+    "ep/first_chunk_rows", "ep/pass_rows"))
+def test_a_layer_inside_the_exchange_counts_chip_by_chip(exchange_run, name):
+    """What ``chip_skew.py``'s readers and ``tools/trace_by_scope.py``'s
+    table look up (names imported, never copied): a gauge a layer and chip,
+    one histogram observation a logged step, and the log line and the step
+    report saying the same. Four devices of two experts each over 16 tokens
+    at top-2: a chip's first chunk is 40 rows of 128 slots, a pass 8."""
+    from hetu_galvatron_tpu.models.moe import overflow_rows, short_rows
+
+    found = [m for m in exchange_run["registry"].metrics()
+             if m.name == name]
+    first, chunk = short_rows(128, 2, 8), overflow_rows(128, 2, 8)
+    assert (first, chunk) == (40, 8)
+    assert get_registry() is exchange_run["registry"]
+    lines = [line for line in exchange_run["log"].splitlines()
+             if "expert exchange:" in line or "step report:" in line]
+    if name in chip_skew.CHIP_GAUGES:
+        # one a layer and chip; every route is some chip's
+        assert sorted((m.labels["layer"], m.labels["chip"])
+                      for m in found) == [
+            (f"layer{i}", str(c)) for i in range(2) for c in range(4)]
+        if name == "moe/chip_rows":
+            assert sum(m.value for m in found) == 2 * 4 * 16 * 2
+        else:
+            rows = {(m.labels["layer"], m.labels["chip"]): m.value
+                    for m in exchange_run["registry"].metrics()
+                    if m.name == "moe/chip_rows"}
+            for m in found:
+                took = -(-max(rows[(m.labels["layer"], m.labels["chip"])]
+                              - first, 0) // chunk)
+                assert m.value == took
+    elif name == chip_skew.FULLEST_CHIP_GAUGE:
+        assert sorted(m.labels["layer"] for m in found) == [
+            "layer0", "layer1"]
+        fullest = max(m.value for m in exchange_run["registry"].metrics()
+                      if m.name == "moe/chip_rows")
+        assert chip_skew.fullest_chip_pct({}) == 100.0 * fullest / first
+    elif name == chip_skew.STEP_PASSES_HISTOGRAM:
+        (h,) = found
+        assert h.count == 2             # one a logged step
+        assert chip_skew.step_passes({}) == h.total / 2
+        last = sum(max(m.value for m in exchange_run["registry"].metrics()
+                       if m.name == "moe/chip_passes"
+                       and m.labels["layer"] == layer)
+                   for layer in ("layer0", "layer1"))
+        assert h.snapshot()["max"] >= last
+    else:
+        (g,) = found
+        assert g.value == {"ep/first_chunk_rows": first,
+                           "ep/pass_rows": chunk}[name]
+        assert exchange_run["result"]["ep"][name[3:]] == g.value
+        assert all(f"{name} {g.value:.0f}" in lines[0]
+                   or f"{name[3:].replace('_', ' ')}" in line
+                   for line in lines)
+    # which device each chip of the group is, for the trace's planes
+    chips = exchange_run["result"]["ep"]["chip_devices"]
+    assert sorted(d for ids in chips for d in ids) == [0, 1, 2, 3]
+    assert {int(m.labels["device"]): m.value
+            for m in exchange_run["registry"].metrics()
+            if m.name == "ep/chip_of_device"} == {
+                d: r for r, ids in enumerate(chips) for d in ids}
+    assert f"ep 4 first-chunk rows {first} pass rows {chunk}" in lines[1]
+    assert f"ep/first_chunk_rows {first}, ep/pass_rows {chunk}" in lines[0]
+
+
+def test_the_log_line_says_the_passes_by_chip():
+    """``moe[layer0] chips 1.188 passes 0/1/0/0``, and the gauges and the
+    one histogram observation of a step that the line is formatted from."""
+    from hetu_galvatron_tpu.core.args_schema import CoreArgs
+    from hetu_galvatron_tpu.core.profiler.runtime_profiler import (
+        RuntimeProfiler,
+    )
+    import numpy as np
+
+    reg = MetricsRegistry()
+    stats = {"tokens_per_expert": np.full(8, 32.0),
+             # every expert's rows, chip by chip: chip 1's add up to 76
+             "held_tokens_per_expert": np.array(
+                 [30.0, 30.0, 40.0, 36.0, 30.0, 30.0, 30.0, 30.0]),
+             "load_balance_loss": 1.0, "z_loss": 0.0,
+             "rows_held": 64.0, "rows_computed": 42.0,
+             "overflow_chunks": 0.25, "short_dispatch": 0.75,
+             "rows_by_chip": np.array([60.0, 76.0, 60.0, 60.0]),
+             "passes_by_chip": np.array([0.0, 1.0, 0.0, 0.0])}
+    line = RuntimeProfiler(CoreArgs(), registry=reg, pass_rows=8
+                           ).iteration_log(0, {"moe": {"layer0": stats,
+                                                       "layer1": stats}})
+    assert "moe[layer0] chips 1.188 passes 0/1/0/0" in line
+    gauges = {(m.name, m.labels.get("layer")): m.value
+              for m in reg.metrics() if m.kind == "gauge"}
+    # the first chunk's rows a step: rows_computed less the passes' rows
+    assert gauges[("moe/fullest_chip_pct", "layer0")] == 100.0 * 76 / 40
+    assert "moe/short_dispatch" not in {name for name, _ in gauges}
+    assert gauges[("moe/short_dispatch_pct", "layer0")] == 75.0
+    (h,) = [m for m in reg.metrics()
+            if m.name == chip_skew.STEP_PASSES_HISTOGRAM]
+    # two layers whose fullest chip took one pass each
+    assert (h.count, h.total) == (1, 2.0)
+    # without the pass's rows and with a pass taken the first chunk's rows
+    # are not known: the gauge is left as it was, never guessed
+    RuntimeProfiler(CoreArgs(), registry=reg).iteration_log(
+        0, {"moe": {"layer0": {**stats, "held_tokens_per_expert": np.array(
+            [30.0, 30.0, 40.0, 40.0, 30.0, 30.0, 30.0, 30.0])}}})
+    assert [m.value for m in reg.metrics()
+            if m.name == "moe/fullest_chip_pct"
+            and m.labels["layer"] == "layer0"] == [100.0 * 76 / 40]
+
+
+@pytest.mark.parametrize("name", ["step_passes", "fullest_chip_pct"])
+def test_without_an_exchange_the_chip_readers_publish_nothing(run, name):
+    assert get_registry() is run["registry"]
+    assert getattr(chip_skew, name)(run["facts"]) is None
+    assert run["result"]["ep"] is None
+    assert not [m for m in run["registry"].metrics()
+                if m.name.startswith(("ep/", "moe/chip_"))]
 
 
 @pytest.mark.parametrize("mixer,ff,blocks", [

@@ -446,6 +446,42 @@ def test_the_map_is_written_beside_a_trace(tmp_path):
         trace_analysis.record_step_scopes(before)
 
 
+def test_the_map_keeps_what_each_chip_was_handed(tmp_path):
+    """``chips`` of ``step_map.json``: the last logged step's
+    ``moe/chip_rows`` and ``moe/chip_passes`` by layer and chip, for the
+    operator's by-chip table; empty where no layer ran inside an
+    exchange."""
+    from hetu_galvatron_tpu.observability.registry import (
+        MetricsRegistry,
+        get_registry,
+        set_registry,
+    )
+
+    before, kept = get_registry(), trace_analysis.step_scopes()
+    reg = set_registry(MetricsRegistry())
+    try:
+        trace_analysis.record_step_scopes(step_hlo(FOUR_CHIPS))
+        assert trace_analysis.chip_counts() == {}
+        with open(trace_analysis.write_step_map(str(tmp_path / "a"))) as f:
+            assert json.load(f)["chips"] == {}
+        for layer, rows in (("layer1", (7.0, 9.0)), ("layer0", (8.0, 8.0))):
+            for chip, n in enumerate(rows):
+                reg.gauge("moe/chip_rows", layer=layer,
+                          chip=str(chip)).set(n)
+                reg.gauge("moe/chip_passes", layer=layer,
+                          chip=str(chip)).set(float(n > 8))
+        for device, chip in ((0, 0), (1, 1)):
+            reg.gauge("ep/chip_of_device", device=str(device)).set(chip)
+        with open(trace_analysis.write_step_map(str(tmp_path / "b"))) as f:
+            assert json.load(f)["chips"] == {
+                "rows": {"layer0": [8.0, 8.0], "layer1": [7.0, 9.0]},
+                "passes": {"layer0": [0.0, 0.0], "layer1": [0.0, 1.0]},
+                "devices": {"0": 0, "1": 1}}
+    finally:
+        set_registry(before)
+        trace_analysis.record_step_scopes(kept)
+
+
 def _reduced(rows, steps):
     ms = 1e6
     leaves = [(n, s * ms, e * ms) for n, s, e in rows]

@@ -28,7 +28,16 @@ It prints
   ends before it and the one that starts after it (scope, phase and
   collective class of both), with how often it occurs and its mean length;
 * the traced names that are no instruction of the map (none, when trace
-  and map are of one program).
+  and map are of one program);
+* where the trace holds several chips, one row a chip: its compute, the
+  collectives' transfer (the least duration of each occurrence over the
+  chips) and its wait for the latest chip (its durations less that), the
+  wait cut by starts beside it, and the rows and counted passes the expert
+  exchange handed it in the last logged step (``chips`` of
+  ``step_map.json``); under it how far apart one reduce-scatter's ends lie
+  on the chips, which says whether the planes share a clock. The function
+  is the benchmark's (``benchmark/layer_metrics/chip_skew.py``:
+  ``collective_wait_ms``, ``collective_transfer_ms``, ``chip_skew_ms``).
 """
 
 from __future__ import annotations
@@ -155,6 +164,41 @@ def print_tables(t, file=None):
             say(f"  {ms:9.3f} ms  {n}")
 
 
+def by_chip(reduced, step_map):
+    """The chips side by side (``chip_skew.py``'s table): ``None`` for one
+    chip, a step map without collectives, or chips that hold different
+    collective occurrences in every traced step."""
+    from benchmark import manifest
+
+    skew = manifest.load_python(os.path.join(
+        ROOT, "benchmark", "layer_metrics", "chip_skew.py"))
+    table = skew.side_by_side(reduced, step_map["instructions"])
+    if table is None:
+        return None
+    apart = skew.clock_check(table)
+    return {"steps": table["steps"],
+            "chips": skew.by_chip_rows(table, step_map.get("chips")),
+            "reduce_scatter_ends_apart_us": (
+                None if apart is None else [ns / 1e3 for ns in apart])}
+
+
+def print_by_chip(t, file=None):
+    say = lambda *a: print(*a, file=file)
+    say(f"the chips side by side, ms a step over {t['steps']} traced steps:")
+    say(f"  {'chip':>4s} {'compute':>9s} {'transfer':>9s} {'wait':>9s} "
+        f"{'by starts':>9s} {'rows':>9s} {'passes':>6s}")
+    show = lambda v: "-" if v is None else f"{v:.0f}"
+    for row in t["chips"]:
+        say(f"  {row['chip']:4d} {row['compute_ms']:9.3f} "
+            f"{row['transfer_ms']:9.3f} {row['wait_ms']:9.3f} "
+            f"{row['wait_by_starts_ms']:9.3f} {show(row['rows']):>9s} "
+            f"{show(row['passes']):>6s}")
+    if t["reduce_scatter_ends_apart_us"]:
+        say("  one reduce-scatter's ends on the chips lie {:.3f} us apart "
+            "(median), {:.3f} at most".format(
+                *t["reduce_scatter_ends_apart_us"]))
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("trace_dir")
@@ -176,11 +220,15 @@ def main(argv=None) -> int:
     with open(map_path) as f:
         step_map = json.load(f)
     devices = [d for d in xplane.read_devices(path) if d.ops]
-    tables = join(xplane.reduce_device(devices[a.device]), step_map)
+    reduced = [xplane.reduce_device(d) for d in devices]
+    tables = join(reduced[a.device], step_map)
+    tables["by_chip"] = by_chip(reduced, step_map)
     if a.json:
         with open(a.json, "w") as f:
             json.dump(tables, f, indent=1)
     print_tables(tables)
+    if tables["by_chip"]:
+        print_by_chip(tables["by_chip"])
     return 0
 
 
