@@ -229,6 +229,8 @@ def train(args) -> Dict[str, Any]:
         from hetu_galvatron_tpu.analysis import eligibility
         from hetu_galvatron_tpu.models.moe import (
             exchange_bytes,
+            held_range,
+            layer_body,
             overflow_rows,
             short_rows,
         )
@@ -266,6 +268,18 @@ def train(args) -> Dict[str, Any]:
             for k in ("axes", "exchange_bytes_per_step", "first_chunk_rows",
                       "pass_rows"):
                 get_registry().gauge(f"ep/{k}").set(ep_report[k])
+        # which body each sorted expert layer's dispatcher compiles to, from
+        # the routes of a microbatch it walks (an exchanged layer: its ep
+        # group's, for a chip's share of the experts)
+        micro_slots = (hpc.global_bsz // max(hpc.chunks, 1) * cfg.seq_length
+                       * cfg.moe_topk)
+        expert_bodies = {}
+        for i, (s, (_, ff)) in enumerate(zip(hpc.layers, kinds)):
+            if ff == "experts" and cfg.moe_dispatcher == "dropless":
+                ep = s.ep_size if s in exchanged else 1
+                expert_bodies[f"layer{i}"] = layer_body(
+                    micro_slots // (s.dp_size if ep > 1 else 1) * ep,
+                    held_range(cfg, ep)[0], cfg.num_experts)
         unserved = eligibility.ep_plan_reason(cfg, hpc.layers, hpc.pp_deg)
         if unserved:
             state.log(f"expert exchange not taken: {unserved}")
@@ -1225,6 +1239,10 @@ def train(args) -> Dict[str, Any]:
                         step_report[f"{part}_recomputed"] = count(found)
                         get_registry().gauge(f"step/{part}_recomputed").set(
                             step_report[f"{part}_recomputed"])
+                    if expert_bodies:
+                        get_registry().gauge("moe/whole_body_layers").set(
+                            sum(body == "whole"
+                                for body in expert_bodies.values()))
                     step_report["step_map"] = {
                         "instructions": len(found["map"]["instructions"]),
                         "inferred": len(found["map"]["inferred"]),
@@ -1333,6 +1351,8 @@ def train(args) -> Dict[str, Any]:
                     + (", ep {axes} first-chunk rows {first_chunk_rows} "
                        "pass rows {pass_rows}".format(**ep_report)
                        if ep_report else "")
+                    + "".join(f", moe[{name}] {body}"
+                              for name, body in expert_bodies.items())
                     + f", {step_report['cores_recomputed']} cores recomputed,"
                     f" {step_report['scans_recomputed']} scans recomputed,"
                     f" static live peak "
@@ -1385,6 +1405,11 @@ def train(args) -> Dict[str, Any]:
             # a chip's first chunk and of one counted pass behind it, a
             # microbatch (ep/first_chunk_rows, ep/pass_rows); None without
             "ep": ep_report or None,
+            # the body each sorted expert layer's dispatcher compiled to:
+            # "whole" (the first chunk is every route, no loop; their count
+            # is the gauge moe/whole_body_layers) or "counted <first
+            # chunk's rows>/<routes> +<a pass's rows>"
+            "expert_bodies": expert_bodies,
             # blocks by "<mixer>/<feed-forward>" kind (step/blocks gauges)
             "blocks": blocks,
             # Mosaic kernels in the compiled step's HLO (pp=1), or summed

@@ -14,21 +14,22 @@ dispatchers —
   mesh axes makes GSPMD insert the token all-to-alls the reference issues by
   hand. Over-capacity tokens are dropped (weights renormalized). This is the
   expert-parallel mode — every shape is static and ep/etp-shardable.
-* ``dropless`` (sort + ``lax.ragged_dot``): token slots are sorted by expert
-  and the expert MLPs run as grouped ragged matmuls — no token is ever
-  dropped and no capacity buffer is materialized (the reference's alltoall
-  dropless dispatcher, token_dispatcher.py:287). Static [T*K] shapes keep it
+* ``dropless`` (sort + ``lax.ragged_dot``, :func:`_held_dispatch`): token
+  slots are sorted by expert and the MLPs of the experts the layer holds, all
+  or a share, run as grouped ragged matmuls — no token is ever dropped and no
+  capacity buffer is materialized (the reference's alltoall dropless
+  dispatcher, token_dispatcher.py:287). Static [T*K] shapes keep it
   jit-clean; HF Mixtral numerics reproduce exactly (see
   tests/models/test_moe.py Mixtral parity).
 
 Across chips (``parallel.global_ep_deg``, the ``ep`` axes a plan carves from
 dp): the ``capacity`` einsums are left to GSPMD, which turns their sharded
-``expert`` axis into all-to-alls; the sorted dispatchers (``dropless`` and
-the held share) run inside :func:`make_expert_exchange`'s ``shard_map``: a
-chip's tokens, choices and weights are all-gathered over ``ep``, each chip
-runs :func:`_held_dispatch` over the group's tokens for the experts it
-holds, and the partial results are reduce-scattered back to the tokens'
-owners. Tokens move, expert weights never do.
+``expert`` axis into all-to-alls; the sorted dispatcher runs inside
+:func:`make_expert_exchange`'s ``shard_map``: a chip's tokens, choices and
+weights are all-gathered over ``ep``, each chip runs :func:`_held_dispatch`
+over the group's tokens for the experts it holds, and the partial results
+are reduce-scattered back to the tokens' owners. Tokens move, expert weights
+never do.
 
 Routers: softmax top-k (optionally with the DeepSeek-style expert-bias
 selection correction, reference router.py expert_bias) and sinkhorn load
@@ -365,38 +366,6 @@ def _capacity_dispatch(
                       preferred_element_type=jnp.float32)
 
 
-def _dropless_dispatch(
-    p: Params, xt: jax.Array, topk_idx: jax.Array, w: jax.Array,
-    cfg: ModelArgs, compute_dtype,
-) -> jax.Array:
-    """Dropless grouped-matmul dispatch (reference alltoall dropless
-    dispatcher, token_dispatcher.py:287, re-designed for XLA): the [T*K]
-    token slots sort by expert id (stable, so intra-expert order is token
-    order), the expert MLPs run as ``lax.ragged_dot`` grouped matmuls over
-    the sorted buffer, and a scatter-add combines weighted outputs. Every
-    shape is static; no token is dropped; renormalized top-k weights make
-    HF Mixtral numerics exact."""
-    T, H = xt.shape
-    E, K = cfg.num_experts, cfg.moe_topk
-    with jax.named_scope("moe/dispatch"):
-        eid = topk_idx.reshape(T * K)
-        order = jnp.argsort(eid, stable=True)
-        tok = jnp.arange(T * K, dtype=jnp.int32) // K  # slot -> token
-        tok_sorted = tok[order]
-        xs = xt[tok_sorted].astype(compute_dtype)  # [T*K, H]
-        group_sizes = jnp.bincount(eid, length=E).astype(jnp.int32)
-    with jax.named_scope("moe/experts"):
-        hproj = _grouped_matmul(xs, M.weight_view(p["win"], compute_dtype),
-                                group_sizes, compute_dtype)
-        hproj = _expert_act(hproj, cfg, compute_dtype)
-        ys = _grouped_matmul(hproj, M.weight_view(p["wout"], compute_dtype),
-                             group_sizes, jnp.float32)
-    with jax.named_scope("moe/combine"):
-        ws = w.reshape(T * K)[order]
-        return jnp.zeros((T, H), jnp.float32).at[tok_sorted].add(
-            ys * ws[:, None])
-
-
 # a layer that holds a share computes the sorted slots in chunks: the first,
 # which always runs, is the expected share of the routes times _FIRST_MARGIN,
 # and each counted pass behind it the expected share times _CHUNK_SHARE (both
@@ -434,21 +403,34 @@ def _sorted_rows_mlp(cfg: ModelArgs, compute_dtype, rows, win, wout, w_rows,
     """The expert MLPs over one chunk of the sorted slots, gathered: ``rows``
     [R, H] through the grouped matmuls (``win`` / ``wout`` in the compute
     dtype) and the activation, times the routes' weights; [R, H] float32 for
-    the scatter-add. Rows of the chunk that belong to no group are zeroed
-    going in and masked coming out, so that nothing the grouped matmuls
-    leave there reaches the result or a gradient. The mask is on ``ys``
+    the combine. Rows of the chunk that belong to no group (``mine`` false;
+    None where every row has one) are zeroed going in and masked coming out,
+    so that nothing the grouped matmuls leave there reaches the result or a
+    gradient. The mask is on ``ys``
     itself, BEFORE the weights: behind the product its transpose hands the
     weights ``0 * ys``, which is NaN where the chip left an inf or a NaN in
     such a row, and from there the router's gradient and every block before
     it (PERF.md section 6, PR 40)."""
     with jax.named_scope("moe/dispatch"):
-        xs = jnp.where(mine, rows.astype(compute_dtype), 0)
+        xs = rows.astype(compute_dtype)
+        if mine is not None:
+            xs = jnp.where(mine, xs, 0)
     with jax.named_scope("moe/experts"):
         hproj = _grouped_matmul(xs, win, group_sizes, compute_dtype)
         hproj = _expert_act(hproj, cfg, compute_dtype)
         ys = _grouped_matmul(hproj, wout, group_sizes, jnp.float32)
     with jax.named_scope("moe/combine"):
-        return jnp.where(mine, ys, 0.0) * w_rows[:, None]
+        if mine is not None:
+            ys = jnp.where(mine, ys, 0.0)
+        return ys * w_rows[:, None]
+
+
+def layer_body(slots: int, held: int, num_experts: int) -> str:
+    """The body a sorted layer of ``slots`` routes compiles to: ``whole`` (no
+    loop) or ``counted <first chunk's rows>/<slots> +<a pass's rows>``."""
+    first = short_rows(slots, held, num_experts)
+    return "whole" if first >= slots else (
+        f"counted {first}/{slots} +{overflow_rows(slots, held, num_experts)}")
 
 
 class _Sorted(NamedTuple):
@@ -458,6 +440,8 @@ class _Sorted(NamedTuple):
     ws: jax.Array       # [T*K] the routes' weights in sorted order
     ends: jax.Array     # [held] the sorted slot a held expert's group ends at
     passes: jax.Array   # [] the counted passes behind the first chunk
+    # [T*K] the sorted slot of each slot, where the first chunk is them all
+    inv: Optional[jax.Array]
 
 
 def _counted_rows_mlp(cfg: ModelArgs, compute_dtype, first_len: int,
@@ -470,6 +454,10 @@ def _counted_rows_mlp(cfg: ModelArgs, compute_dtype, first_len: int,
     ones clipped to it, so a chunk that ends inside a group computes the
     part of the group it holds; the last pass starts where it still fits the
     slots, and the rows it shares with the pass before are masked out of it.
+    Where the first chunk is every slot (``slots.inv``) there is the one
+    body, no loop and no scatter-add: the sort permutes all ``T*K`` slots, so
+    a token's ``K`` rows are gathered by its inverse and summed in float32,
+    the result and the rows' cotangent alike.
 
     One backward pass of its own, which keeps the operands alone: the first
     chunk's pull-back, then the same counted loop, each pass recomputing its
@@ -483,7 +471,9 @@ def _counted_rows_mlp(cfg: ModelArgs, compute_dtype, first_len: int,
         ends = slots.ends
         at = jnp.minimum(lo, slots.order.shape[0] - length)
         sorted_slot = at + jnp.arange(length)
-        mine = ((sorted_slot >= lo) & (sorted_slot < ends[-1]))[:, None]
+        # every slot of a layer that holds every expert has a group: no mask
+        mine = None if slots.inv is not None and len(ends) == cfg.num_experts \
+            else ((sorted_slot >= lo) & (sorted_slot < ends[-1]))[:, None]
         starts = jnp.concatenate([jnp.zeros_like(ends[:1]), ends[:-1]])
         sizes = (jnp.clip(ends, at, at + length)
                  - jnp.clip(starts, at, at + length))
@@ -498,11 +488,18 @@ def _counted_rows_mlp(cfg: ModelArgs, compute_dtype, first_len: int,
                 jax.lax.dynamic_slice(ws, (at,), (length,)), mine, sizes)
         return tok, rows, mlp
 
+    def to_tokens(acc, tok, rows, slots):
+        """``acc`` (zeros where ``inv``) + the sorted ``rows`` by token."""
+        if slots.inv is None:
+            return acc.at[tok].add(rows)
+        by_token = rows[slots.inv].reshape(len(acc), cfg.moe_topk, -1)
+        return by_token.sum(1, dtype=jnp.float32).astype(acc.dtype)
+
     def add_chunk(y, lo, length, xt, win, wout, slots):
         tok, rows, mlp = chunk_of(xt, slots, lo, length)
         out = mlp(rows, win, wout, slots.ws)
         with jax.named_scope("moe/combine"):
-            return y.at[tok].add(out)
+            return to_tokens(y, tok, out, slots)
 
     def pull_chunk(cots, g, lo, length, xt, win, wout, slots):
         tok, rows, mlp = chunk_of(xt, slots, lo, length)
@@ -510,7 +507,7 @@ def _counted_rows_mlp(cfg: ModelArgs, compute_dtype, first_len: int,
         with jax.named_scope("moe/combine"):
             d_rows, *d_rest = pull(g[tok])
         with jax.named_scope("moe/dispatch"):
-            d_xt = cots[0].at[tok].add(d_rows)
+            d_xt = to_tokens(cots[0], tok, d_rows, slots)
         with jax.named_scope("moe/experts"):
             return (d_xt, *(c + d.astype(c.dtype)
                             for c, d in zip(cots[1:], d_rest)))
@@ -522,7 +519,7 @@ def _counted_rows_mlp(cfg: ModelArgs, compute_dtype, first_len: int,
 
     def counted(first, chunk, slots):
         """``chunk`` folded over the passes behind ``first``."""
-        if first_len >= slots.order.shape[0]:
+        if slots.inv is not None:
             return first
         return jax.lax.fori_loop(
             0, slots.passes,
@@ -562,11 +559,11 @@ def _held_dispatch(
     p: Params, xt: jax.Array, topk_idx: jax.Array, w: jax.Array,
     cfg: ModelArgs, compute_dtype, ep: int = 1, index: Any = 0,
 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-    """The dropless dispatch of a layer that holds experts ``[first, first
-    + held)`` of the router's ``E``: it computes exactly the routes that
-    fall on them and leaves out what the absent experts would have added
-    (under an expert exchange, ``ep`` and ``index``: what the other chips of
-    the group add, :func:`held_range`).
+    """The dropless dispatcher, sorted: a layer that holds experts ``[first,
+    first + held)`` of the router's ``E``, all or a share, computes exactly
+    the routes that fall on them and leaves out what absent experts would
+    have added (under an expert exchange, ``ep`` and ``index``: what the
+    other chips of the group add, :func:`held_range`).
 
     The static ``T*K`` slots sort by LOCAL expert id with every route to an
     absent expert keyed ``held``, so the held experts' routes come first, in
@@ -582,13 +579,15 @@ def _held_dispatch(
     as many further chunks of :func:`overflow_rows` as the count asks for
     (decided on the device, a step and microbatch at a time: none on a
     balanced step, ``T*K`` rows in all where every route fell here). A layer
-    whose first chunk would be all ``T*K`` slots has the one body and no
-    loop. Returns (y [T, H] float32, stats): ``rows_held`` routes that fell
-    on a held expert, ``overflow_chunks`` passes taken behind the first
-    chunk, ``rows_computed`` rows handed to the grouped matmuls (the first
-    chunk and the passes), ``short_dispatch`` 1.0 where the first chunk was
-    shorter than ``T*K`` and no pass was taken, ``held_tokens_per_expert``
-    [held]."""
+    whose first chunk is all ``T*K`` slots (every expert held, or four
+    fifths of them) has the one body and no loop, and moves its rows by the
+    permutation and its inverse (a second sort, of ``(order, iota)``).
+    Returns (y [T, H] float32, stats), none where every expert is held, else
+    ``rows_held`` routes that fell on a held expert, ``overflow_chunks``
+    passes taken behind the first chunk, ``rows_computed`` rows handed to the
+    grouped matmuls (first chunk and passes), ``short_dispatch`` 1.0 where
+    the first chunk was shorter than ``T*K`` and no pass was taken,
+    ``held_tokens_per_expert`` [held]."""
     T, _ = xt.shape
     K = cfg.moe_topk
     held, first = held_range(cfg, ep, index)
@@ -596,12 +595,15 @@ def _held_dispatch(
     chunk_len = overflow_rows(T * K, held, cfg.num_experts)
     w = w.reshape(T * K)
     with jax.named_scope("moe/dispatch"):
-        local = topk_idx.reshape(T * K) - first
-        key = jnp.where((local >= 0) & (local < held), local, held).astype(
-            jnp.int32)
-        _, order, ws = jax.lax.sort(
-            (key, jnp.arange(T * K, dtype=jnp.int32),
-             jax.lax.stop_gradient(w)), num_keys=1, is_stable=True)
+        key = topk_idx.reshape(T * K) - first
+        if held < cfg.num_experts:
+            key = jnp.where((key >= 0) & (key < held), key, held)
+        key = key.astype(jnp.int32)
+        slot = jnp.arange(T * K, dtype=jnp.int32)
+        _, order, ws = jax.lax.sort((key, slot, jax.lax.stop_gradient(w)),
+                                    num_keys=1, is_stable=True)
+        inv = (jax.lax.sort((order, slot), num_keys=1)[1]
+               if first_len >= T * K else None)
         ends = jnp.sum(key[:, None] <= jnp.arange(held, dtype=jnp.int32),
                        axis=0, dtype=jnp.int32)
         group_sizes = jnp.diff(ends, prepend=0)
@@ -609,7 +611,9 @@ def _held_dispatch(
         passes = (jnp.maximum(rows_held - first_len, 0) + chunk_len - 1
                   ) // chunk_len
     y = _counted_rows_mlp(cfg, compute_dtype, first_len, chunk_len)(
-        (xt, p["win"], p["wout"], w), _Sorted(order, ws, ends, passes))
+        (xt, p["win"], p["wout"], w), _Sorted(order, ws, ends, passes, inv))
+    if held == cfg.num_experts:
+        return y, {}
     stats = {
         "rows_held": rows_held.astype(jnp.float32),
         "rows_computed": (first_len + passes * chunk_len).astype(
@@ -725,25 +729,21 @@ def apply_moe_mlp(
     Router per ``cfg.moe_router_type`` (see :func:`route_tokens`), dispatch
     per ``cfg.moe_dispatcher``: "capacity" (GShard one-hot einsums; across
     chips GSPMD shards their expert axis over ``ep`` and inserts the
-    all-to-alls) or "dropless" (ragged grouped matmuls, exact numerics;
-    across chips the sorted dispatchers, this and the held share, run inside
-    ``exchange``, what a plan with ``ep`` axes hands the block as
-    ``LayerOps.exchange``: :func:`make_expert_exchange`). The router is
-    replicated and routes the chip's own tokens either way.
-    """
+    all-to-alls) or "dropless": the sorted dispatcher at whatever share of
+    its experts the layer holds (:func:`_held_dispatch`: ragged grouped
+    matmuls, exact numerics; across chips it runs inside ``exchange``, what
+    a plan with ``ep`` axes hands the block as ``LayerOps.exchange``:
+    :func:`make_expert_exchange`). The router is replicated and routes the
+    chip's own tokens either way."""
     B, S, H = x.shape
     xt = x.reshape(B * S, H)
     with jax.named_scope("moe/route"):
         topk_idx, w, aux, stats = route_tokens(p, xt, cfg, compute_dtype)
-    if exchange is not None:
-        y, share_stats = exchange(p, xt, topk_idx, w, cfg, compute_dtype)
+    if exchange is not None or held_range(cfg)[0] < cfg.num_experts \
+            or cfg.moe_dispatcher == "dropless":
+        y, share_stats = (exchange or _held_dispatch)(
+            p, xt, topk_idx, w, cfg, compute_dtype)
         stats = {**stats, **share_stats}
-    elif held_range(cfg)[0] < cfg.num_experts:
-        y, share_stats = _held_dispatch(p, xt, topk_idx, w, cfg,
-                                        compute_dtype)
-        stats = {**stats, **share_stats}
-    elif cfg.moe_dispatcher == "dropless":
-        y = _dropless_dispatch(p, xt, topk_idx, w, cfg, compute_dtype)
     else:
         y = _capacity_dispatch(p, xt, topk_idx, w, cfg, compute_dtype,
                                capacity_factor)

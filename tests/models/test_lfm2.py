@@ -605,8 +605,8 @@ def test_only_a_share_under_a_half_has_a_second_body(layer, bodies):
     grouped matmuls (the first chunk's and a pass's) where the first chunk
     is shorter than ``T*K``; a layer whose first chunk reaches ``T*K`` (four
     fifths of the experts and more) and a layer that holds every expert
-    (``_dropless_dispatch``; the OLMoE preset at a tiny size) hold one pair
-    and no loop, forward and backward. No layer holds a conditional."""
+    (the same ``_held_dispatch``; the OLMoE preset at a tiny size) hold one
+    pair and no loop, forward and backward. No layer holds a conditional."""
     from hetu_galvatron_tpu.core.arguments import args_from_cli
 
     if layer == "olmoe":
@@ -997,9 +997,9 @@ def test_a_conv_block_refuses_packed_documents_and_sigmoid_an_aux_loss():
 @pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
 def test_a_held_shares_forward_and_loss_are_the_parents_formulation(
         dtype, forward_and_loss_as_before_pr38):
-    """The stack with four expert layers that each hold 2 of 8 experts: both
-    bodies of ``_held_dispatch`` in the program, the one the count picks
-    taken."""
+    """The stack with four expert layers that each hold 2 of 8 experts: the
+    first chunk and the counted loop of ``_held_dispatch`` in the program,
+    as many passes taken as the count asks for."""
     cfg = ModelArgs(**{**TINY, "moe_held_experts": 2,
                        "moe_first_held_expert": 2})
     forward_and_loss_as_before_pr38(cfg, _seeded(cfg), dtype)

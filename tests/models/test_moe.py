@@ -449,7 +449,8 @@ GROUPED_CFG = ModelArgs(
     moe_score_function="sigmoid", moe_router_enable_expert_bias=True,
     moe_dispatcher="dropless", moe_aux_loss_coeff=0.0)
 # mode -> (experts held, the selection bias on the held pair, the counted
-# passes behind the first chunk: 24 of the 64 slots, then 8 a pass)
+# passes behind the first chunk: 24 of the 64 slots, then 8 a pass);
+# ``dropless``: every expert held, the one body of all 64 slots
 GROUPED_MODES = {"dropless": (0, 0.0, None),
                  "held_short_body": (2, 0.0, 0.0),
                  "held_full_body": (2, 10.0, 5.0)}
@@ -474,31 +475,33 @@ def _grouped_case(mode):
 
     def dispatch(win, wout, xt, w, dtype):
         q = {**p, "win": win, "wout": wout}
-        if held:
-            return moe._held_dispatch(q, xt.astype(dtype), topk_idx, w, cfg,
-                                      dtype)
-        return moe._dropless_dispatch(q, xt.astype(dtype), topk_idx, w, cfg,
-                                      dtype), {}
+        return moe._held_dispatch(q, xt.astype(dtype), topk_idx, w, cfg,
+                                  dtype)
     return cfg, dispatch, (rounded(p["win"] * 8), rounded(p["wout"] * 8), xt,
                            w)
 
 
-def _grouped_matmuls(jaxpr, found=None):
-    """(lhs aval, rhs aval, result aval, dimension numbers) of every
-    ``ragged_dot_general`` of a jaxpr, in order, conditionals' bodies and
-    custom derivatives included."""
+def _equations(jaxpr, found=None):
+    """Every equation of ``jaxpr``, in order, the bodies of its calls,
+    custom derivatives, conditionals and loops included."""
     found = [] if found is None else found
     for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "ragged_dot_general":
-            found.append((*(v.aval for v in eqn.invars[:2]),
-                          eqn.outvars[0].aval,
-                          str(eqn.params["ragged_dot_dimension_numbers"])))
+        found.append(eqn)
         for value in eqn.params.values():
             for sub in value if isinstance(value, (tuple, list)) else [value]:
                 sub = getattr(sub, "jaxpr", sub)
                 if hasattr(sub, "eqns"):
-                    _grouped_matmuls(sub, found)
+                    _equations(sub, found)
     return found
+
+
+def _grouped_matmuls(jaxpr):
+    """(lhs aval, rhs aval, result aval, dimension numbers) of every
+    ``ragged_dot_general`` of a jaxpr, in order."""
+    return [(*(v.aval for v in eqn.invars[:2]), eqn.outvars[0].aval,
+             str(eqn.params["ragged_dot_dimension_numbers"]))
+            for eqn in _equations(jaxpr)
+            if eqn.primitive.name == "ragged_dot_general"]
 
 
 def _sum_sq_and_grads(dispatch, dtype):
@@ -514,8 +517,8 @@ def test_grouped_matmuls_read_and_write_the_compute_dtype(
     """What stands in for a counter of engagement: the gradient of the
     expert layer at bfloat16 holds no grouped matmul with a float32 operand,
     and none with a float32 result but the forward ``wout`` product (rows
-    [*, F] through [*, F, H]; a layer that holds a share has it in its first
-    chunk and in a counted pass, forward and recomputed). At float32 the
+    [*, F] through [*, F, H], forward and recomputed; a layer that holds a
+    share has it in its first chunk and in a counted pass). At float32 the
     grouped matmuls are the parent's, in the parent's order, and so are the
     loss and the four gradients, bit for bit, on the chunks the mode
     takes."""
@@ -526,10 +529,11 @@ def test_grouped_matmuls_read_and_write_the_compute_dtype(
 
     calls = _grouped_matmuls(jax.make_jaxpr(grad_of(jnp.bfloat16))(
         *operands).jaxpr)
-    # forward 2 and 4 transposes; a held share: the first chunk and the
-    # loop's pass forward, and the two again backward, where each recomputes
-    # its 2 and transposes each twice
-    assert len(calls) == (16 if held else 6)
+    # the one body of a layer that holds every expert: forward 2, and its
+    # own backward recomputes the 2 and transposes each twice; a held share:
+    # the first chunk and the loop's pass forward, and the two again
+    # backward, each as the one body
+    assert len(calls) == (16 if held else 8)
     forward_wout = 0
     for lhs, rhs, out, _ in calls:
         assert lhs.dtype == rhs.dtype == jnp.bfloat16, (lhs, rhs, out)
@@ -537,7 +541,7 @@ def test_grouped_matmuls_read_and_write_the_compute_dtype(
         forward_wout += is_forward_wout
         assert out.dtype == (jnp.float32 if is_forward_wout
                              else jnp.bfloat16), (lhs, rhs, out)
-    assert forward_wout == (4 if held else 1)
+    assert forward_wout == (4 if held else 2)
 
     mine = jax.make_jaxpr(grad_of(jnp.float32))(*operands)
     (loss, (_, stats)), grads = jax.jit(grad_of(jnp.float32))(*operands)
@@ -580,3 +584,194 @@ def test_bf16_expert_layer_against_the_parents_and_the_f32_layer(
         tol = 2 * 2.0 ** -7 * np.abs(g32).max()
         assert np.abs(g - g32).max() <= tol, (name, np.abs(g - g32).max(), tol)
         assert np.abs(pg - g32).max() <= tol, name
+
+
+# the layer that holds every expert: the held share's one body (PR 56)
+# ---------------------------------------------------------------------------
+
+def _parents_dropless_dispatch(p, xt, topk_idx, w, cfg, compute_dtype):
+    """The dispatcher a layer that holds every expert ran until PR 56
+    (``models/moe.py::_dropless_dispatch``, word for word): an ``argsort``,
+    three gathers and a ``bincount`` a slot at a time, plain reverse mode, a
+    row scatter-add for the combine. Kept here as the oracle."""
+    from hetu_galvatron_tpu.models import modules as M
+    from hetu_galvatron_tpu.models import moe
+
+    T, H = xt.shape
+    E, K = cfg.num_experts, cfg.moe_topk
+    eid = topk_idx.reshape(T * K)
+    order = jnp.argsort(eid, stable=True)
+    tok = jnp.arange(T * K, dtype=jnp.int32) // K  # slot -> token
+    tok_sorted = tok[order]
+    xs = xt[tok_sorted].astype(compute_dtype)  # [T*K, H]
+    group_sizes = jnp.bincount(eid, length=E).astype(jnp.int32)
+    hproj = moe._grouped_matmul(xs, M.weight_view(p["win"], compute_dtype),
+                                group_sizes, compute_dtype)
+    hproj = moe._expert_act(hproj, cfg, compute_dtype)
+    ys = moe._grouped_matmul(hproj, M.weight_view(p["wout"], compute_dtype),
+                             group_sizes, jnp.float32)
+    ws = w.reshape(T * K)[order]
+    return jnp.zeros((T, H), jnp.float32).at[tok_sorted].add(
+        ys * ws[:, None])
+
+
+def _every_expert_of_every_token(win, wout, xt, w, topk_idx):
+    """The plain ``jax.numpy`` layer: every token through every expert's
+    SwiGLU in float32, and a token's result the weighted sum over the
+    experts it chose."""
+    gate, up = jnp.split(jnp.einsum("th,ehf->tef", xt, win), 2, axis=-1)
+    ye = jnp.einsum("tef,efh->teh", jax.nn.silu(gate) * up, wout)
+    chosen = (jax.nn.one_hot(topk_idx, win.shape[0]) * w[..., None]).sum(1)
+    return jnp.einsum("te,teh->th", chosen, ye)
+
+
+# the selection bias a case adds to the router's: expert 3 is never chosen,
+# expert 5 by every token
+FULL_HOLDER_ROUTES = {"the_routers_own": {}, "an_expert_without_a_route":
+                      {3: -10.0}, "an_expert_with_every_route": {5: 10.0}}
+
+
+@pytest.mark.parametrize("routes", sorted(FULL_HOLDER_ROUTES))
+@pytest.mark.parametrize("norm_topk", [True, False])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_a_layer_that_holds_every_expert_is_the_dropless_layer_it_was(
+        dtype, norm_topk, routes):
+    """Result and the four gradients (``win``, ``wout``, the tokens, the
+    routes' weights) of ``_held_dispatch`` at a share of all: against the
+    plain layer within the dtype's rounding, and at float32 against the
+    parent's ``_dropless_dispatch`` to the last bit (two routes a token: the
+    sum over them has one order)."""
+    from hetu_galvatron_tpu.models import moe
+
+    dtype = jnp.dtype(dtype)
+    cfg = GROUPED_CFG.model_copy(update=dict(moe_norm_topk_prob=norm_topk))
+    p = _moe_params(cfg, seed=5)
+    bias = jnp.zeros(8)
+    for expert, b in FULL_HOLDER_ROUTES[routes].items():
+        bias = bias.at[expert].set(b)
+    p["expert_bias"] = bias
+    rounded = lambda a: a.astype(jnp.bfloat16).astype(jnp.float32)  # noqa: E731
+    xt = rounded(jax.random.normal(jax.random.key(8), (32, 32)))
+    topk_idx, w, _, stats = moe.route_tokens(p, xt, cfg, jnp.float32)
+    counts = np.asarray(stats["tokens_per_expert"])
+    assert counts.sum() == 64 and (counts[3] == 0) == (
+        routes == "an_expert_without_a_route") and (counts[5] == 32) == (
+        routes == "an_expert_with_every_route")
+    assert np.allclose(np.asarray(w).sum(-1), 1.0) == norm_topk
+    operands = (rounded(p["win"] * 8), rounded(p["wout"] * 8), xt, w)
+
+    def of(dispatch, jit=jax.jit):
+        def loss(win, wout, xt, w):
+            y = dispatch(win, wout, xt, w)
+            return jnp.sum(jnp.square(y)), y
+        return jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3),
+                                      has_aux=True))(*operands)
+
+    def held(win, wout, xt, w):
+        y, share_stats = moe._held_dispatch(
+            {"win": win, "wout": wout}, xt.astype(dtype), topk_idx, w, cfg,
+            dtype)
+        assert share_stats == {}
+        return y
+
+    (_, y), grads = of(held)
+    (_, want_y), want = of(lambda win, wout, xt, w:
+                           _every_expert_of_every_token(win, wout, xt, w,
+                                                        topk_idx))
+    # float32: sums in another order; bfloat16: the flash kernels' rule, two
+    # roundings of the reference's largest magnitude
+    eps = 2.0 ** -7 if dtype == jnp.bfloat16 else 2.0 ** -20
+    for name, got, ref in zip(("y", "win", "wout", "xt", "w"), (y, *grads),
+                              (want_y, *want)):
+        assert got.dtype == jnp.float32 and got.shape == ref.shape
+        tol = 2 * eps * np.abs(ref).max()
+        assert np.abs(got - ref).max() <= tol, (name, np.abs(got - ref).max(),
+                                                tol)
+    if dtype == jnp.float32:
+        # an operation at a time: inside one compiled program the CPU
+        # contracts ``a * wa + b * wb`` of the gathered sum into a fused
+        # multiply-add, which the scatter-add's rounded products are not
+        eager = lambda f: f  # noqa: E731
+        (_, y), grads = of(held, eager)
+        (_, was_y), was = of(lambda win, wout, xt, w:
+                             _parents_dropless_dispatch(
+            {"win": win, "wout": wout}, xt, topk_idx, w, cfg, dtype), eager)
+        for name, got, ref in zip(("y", "win", "wout", "xt", "w"),
+                                  (y, *grads), (was_y, *was)):
+            assert np.array_equal(got, ref), name
+
+
+def test_a_layer_that_holds_every_expert_traces_one_body():
+    """The differentiated layer under ``modules.remat``: no loop and no
+    conditional; eight grouped matmuls (forward 2; the rule's own backward
+    recomputes its one chunk, 2, and transposes each twice; the remat's
+    recomputed forward is dead); no row is scatter-added into ``[T, H]``
+    (a token's rows are gathered by the inverse permutation and summed); and
+    nothing is gathered from a ``[T*K]`` vector a slot at a time (the sort
+    carries its payloads, the weights' cotangent goes back by a sort)."""
+    from hetu_galvatron_tpu.models import modules as M
+    from hetu_galvatron_tpu.models.moe import init_moe_mlp
+
+    cfg = GROUPED_CFG
+    p = jax.eval_shape(lambda k: init_moe_mlp(k, cfg)[0], jax.random.key(0))
+    x = jax.ShapeDtypeStruct((2, 16, 32), jnp.bfloat16)
+    T, H, K = 32, 32, cfg.moe_topk
+
+    def layer_sum(p, x):
+        y, aux, stats = apply_moe_mlp(p, x, cfg)
+        return jnp.sum(y.astype(jnp.float32)) + aux
+
+    eqns = _equations(jax.make_jaxpr(jax.grad(
+        M.remat(layer_sum, cfg), argnums=(0, 1)))(p, x).jaxpr)
+    names = [e.primitive.name for e in eqns]
+    assert "while" not in names and "cond" not in names
+    assert names.count("ragged_dot_general") == 8
+    # the sort and its inverse, forward and in the remat's recomputation
+    # (one program to the compiler), and the weights' cotangent's way back
+    assert names.count("sort") == 5
+    for e in eqns:
+        if e.primitive.name.startswith("scatter"):
+            assert e.invars[0].aval.shape != (T, H), e
+        if e.primitive.name == "gather":
+            assert e.invars[0].aval.shape != (T * K,), e
+    # the rows move by gathers: the tokens' rows to the sorted slots (and
+    # the result's cotangent), the sorted rows back by the inverse
+    moved = [e.invars[0].aval.shape for e in eqns
+             if e.primitive.name == "gather"
+             and e.invars[0].aval.shape in ((T, H), (T * K, H))]
+    assert sorted(set(moved)) == [(T, H), (T * K, H)]
+
+
+def test_a_layer_that_holds_every_expert_tells_no_share(capsys):
+    """``apply_moe_mlp``'s stats are the router's alone, and the log line of
+    a step with such a layer is the line it was: no ``local ... rows``
+    group, no share gauge."""
+    from hetu_galvatron_tpu.core.profiler.runtime_profiler import (
+        RuntimeProfiler,
+    )
+    from hetu_galvatron_tpu.observability.registry import MetricsRegistry
+
+    cfg = GROUPED_CFG
+    p = _moe_params(cfg, seed=5)
+    x = jax.random.normal(jax.random.key(8), (2, 16, 32))
+    _, _, stats = apply_moe_mlp(p, x, cfg, compute_dtype=jnp.float32)
+    assert sorted(stats) == ["load_balance_loss", "tokens_per_expert",
+                             "z_loss"]
+    held = cfg.model_copy(update=dict(moe_held_experts=2))
+    _, _, share = apply_moe_mlp(
+        {**p, "win": p["win"][:2], "wout": p["wout"][:2]}, x, held,
+        compute_dtype=jnp.float32)
+    assert set(share) - set(stats) == {
+        "rows_held", "rows_computed", "overflow_chunks", "short_dispatch",
+        "held_tokens_per_expert"}
+
+    reg = MetricsRegistry()
+    prof = RuntimeProfiler(CoreArgs(model=cfg.model_dump()), registry=reg)
+    line = prof.iteration_log(0, {"loss": jnp.float32(1.5),
+                                  "moe": {"layer0": stats}})
+    tpe = np.asarray(stats["tokens_per_expert"])
+    assert line == capsys.readouterr().out.strip() == (
+        "iter 0 | loss 1.5000 | moe[layer0] aux 0.000e+00 z 0.000e+00 "
+        f"imb {tpe.max() / tpe.mean():.2f}")
+    assert sorted({m.name for m in reg.metrics()}) == [
+        "moe/aux_loss", "moe/imbalance", "moe/rows_per_expert", "moe/z_loss"]

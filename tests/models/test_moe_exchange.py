@@ -1,6 +1,7 @@
 """The expert exchange (``models/moe.py::make_expert_exchange``): the sorted
 dispatchers across the chips of an ``ep`` group, on the 8-CPU mesh. The
-exchanged layer against ``_dropless_dispatch`` on one device, the shares
+exchanged layer against the layer that holds every expert on one device
+(``_held_dispatch`` at a share of all), the shares
 against the uncut reference's layer, a chip that receives every route, what
 the compiled step moves, which plans take the exchange, and the expert layer
 of the cells that have no ``ep`` axes as the program it was."""
@@ -89,9 +90,11 @@ def _exchanged(ep: int):
 def test_the_exchanged_layer_is_the_dropless_layer_on_one_device(
         ep, router, cpu_devices):
     """Output and every gradient (router, both expert matrices, the tokens)
-    of the layer inside the exchange against ``_dropless_dispatch`` on one
-    device; with ep < dp the expert-dp groups exchange nothing and their
-    weight gradients add up."""
+    of the layer inside the exchange against the layer that holds every
+    expert on one device (``_held_dispatch`` at a share of all, which
+    ``tests/models/test_moe.py`` holds to the plain layer and to the
+    parent's ``_dropless_dispatch``); with ep < dp the expert-dp groups
+    exchange nothing and their weight gradients add up."""
     p, x = _layer(router, ep)
     (want, (want_y, _)), want_g = jax.value_and_grad(
         _loss, argnums=(0, 1), has_aux=True)(p, x)
@@ -417,14 +420,15 @@ def test_an_ep_that_does_not_divide_the_experts_is_refused():
 
 # sha256 of str(jaxpr) of the first expert block's layer, value and
 # gradient, at the cell's own sizes (traced on shapes alone). ``olmoe``'s
-# (``_dropless_dispatch``) is recorded from PR 51's parent: ``exchange`` is
-# None there and ``held_range`` adds a Python 0. ``laguna``'s (a held share)
-# from PR 52, which gave the share its chunks
+# (every expert held) is recorded from PR 56, which gave the full holder the
+# held share's one body: one sort with its payloads, rows moved by the
+# permutation and its inverse. ``laguna``'s (a held share) from PR 52, which
+# gave the share its chunks, and PR 56 left it where it was
 RECORDED = {
     "laguna_c1_b1":
         "d51ba2076e4f176e20963d36bc4e426883f317bf1dd699b14618ef1554823be9",
     "olmoe_c1_s4k":
-        "a37cb13be146c7c1a96dcdc450b53c221589e441c8ab1ceba25115bf4bbbb8ba",
+        "cfe56334df25bea2f7aa050187f3598623885a5db84ea5fcd627601554119981",
 }
 
 

@@ -199,6 +199,27 @@ def test_the_rows_gauges_show_the_body_taken(run):
     assert 0 < held <= computed == 2 * short_rows(2 * 16 * 2, 2, 8) == 48
 
 
+def test_the_step_report_says_each_expert_layers_body(run):
+    """``step report: ..., moe[layer0] whole`` and ``train()``'s
+    ``expert_bodies``: a layer that holds every expert compiled to the one
+    body (its first chunk is every route of a microbatch), one that holds a
+    quarter to a first chunk of 24 of the microbatch's 64 routes and passes
+    of 8; the gauge ``moe/whole_body_layers`` counts the first kind."""
+    want = {"moe": {"layer0": "whole", "layer1": "whole"},
+            "lfm2": {"layer1": "counted 24/64 +8",
+                     "layer2": "counted 24/64 +8"}}.get(run["preset"], {})
+    assert run["result"]["expert_bodies"] == want
+    (line,) = [line for line in run["log"].splitlines()
+               if "step report:" in line]
+    assert ("moe[" in line) == bool(want)
+    for name, body in want.items():
+        assert f", moe[{name}] {body}," in line
+    gauges = [m.value for m in run["registry"].metrics()
+              if m.name == "moe/whole_body_layers"]
+    assert gauges == ([sum(b == "whole" for b in want.values())]
+                      if want else [])
+
+
 # the expert preset across four devices, its experts inside the exchange:
 # the plan of ``mellum2_c4_ep4`` (ep 4 carved from dp 4, a sequence a device
 # in one microbatch), whose log line counts chip by chip
@@ -282,6 +303,10 @@ def test_a_layer_inside_the_exchange_counts_chip_by_chip(exchange_run, name):
             if m.name == "ep/chip_of_device"} == {
                 d: r for r, ids in enumerate(chips) for d in ids}
     assert f"ep 4 first-chunk rows {first} pass rows {chunk}" in lines[1]
+    # the body a chip's share compiled to, over its group's routes
+    assert f", moe[layer0] counted {first}/128 +{chunk}," in lines[1]
+    assert exchange_run["result"]["expert_bodies"] == {
+        f"layer{i}": f"counted {first}/128 +{chunk}" for i in range(2)}
     assert f"ep/first_chunk_rows {first}, ep/pass_rows {chunk}" in lines[0]
 
 
