@@ -37,13 +37,13 @@ GiB = 1024.0 ** 3
 
 def compile_cell(cell, topo_devices):
     import jax
-    import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec
 
     from hetu_galvatron_tpu.core.arguments import args_from_cli
     from hetu_galvatron_tpu.models.builder import init_causal_lm
     from hetu_galvatron_tpu.models.modules import compute_dtype_of
     from hetu_galvatron_tpu.parallel.spmd import make_spmd_train_step
+    from hetu_galvatron_tpu.runtime.dataloader import get_data_iterator
     from hetu_galvatron_tpu.runtime.hybrid_config import (
         get_hybrid_parallel_config,
     )
@@ -77,10 +77,13 @@ def compile_cell(cell, topo_devices):
 
     sp = shaped(pspecs, params)
     so = shaped(ospecs, jax.eval_shape(tx.init, params))
-    B, S = hpc.global_bsz, cfg.seq_length
-    batch = {k: jax.ShapeDtypeStruct((B, S), dt, sharding=batch_shd)
-             for k, dt in (("tokens", jnp.int32), ("labels", jnp.int32),
-                           ("loss_mask", jnp.float32))}
+    # the shapes and dtypes of the program's own first batch, every field,
+    # as the launcher hands it to the step (made on the host; nothing of it
+    # reaches a device)
+    batch = {k: jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=batch_shd)
+             for k, v in next(get_data_iterator(
+                 args, global_batch_size=hpc.global_bsz, hpc=hpc)).items()}
+    B, S = batch["tokens"].shape
     t0 = time.perf_counter()
     compiled = step.lower(sp, so, batch).compile()
     secs = time.perf_counter() - t0

@@ -7,7 +7,9 @@
 2. every loss is finite;
 3. the loss of step 0 agrees with the configuration's reference family
    (``benchmark/reference/<family>.py``) on the same weights and the same
-   first global batch, within the tolerance the configuration's file states;
+   first global batch, every field of it, over the positions its
+   ``loss_mask`` marks, within the tolerance the configuration's file
+   states;
 4. every layer ran on an attention core the configuration's file allows
    (``program.expects.attention_cores``; the flash core alone where it says
    nothing) and the compiled step holds at least
@@ -60,10 +62,11 @@ def _code_hash(root: str) -> str:
     return h.hexdigest()[:16]
 
 
-def first_batch_and_weights(argv: List[str]):
+def first_batch(argv: List[str]):
     """The program's own weights for this seed under their public Hugging
     Face names (through its public exporter), and the first global batch of
-    its random dataset. Nothing else is taken from the program."""
+    its dataset as ``get_data_iterator`` yields it, every field. Nothing
+    else is taken from the program."""
     import jax
     import numpy as np
 
@@ -84,14 +87,29 @@ def first_batch_and_weights(argv: List[str]):
         weights["extra_vocab_rows"] = np.asarray(
             params["embed"]["wte"][cfg.vocab_size:])
     del params
-    batch = next(get_data_iterator(args))
-    return weights, batch["tokens"], batch["labels"]
+    return weights, dict(next(get_data_iterator(args)))
+
+
+def first_batch_and_weights(argv: List[str]):
+    """``first_batch`` for a comparison on ids alone: the weights, the
+    tokens and the labels, and an error where the batch holds another field
+    that says something."""
+    from benchmark import reference
+
+    weights, batch = first_batch(argv)
+    tokens, labels = batch.pop("tokens"), batch.pop("labels")
+    more = reference.beyond_ids(batch)
+    if more:
+        raise ValueError(f"the first batch holds {sorted(more)} beside "
+                         "tokens and labels: take check.first_batch")
+    return weights, tokens, labels
 
 
 def reference_loss(cell, argv: List[str], seed: int, root: str,
                    out_dir: str, **variant) -> Dict[str, Any]:
-    """The reference's step-0 loss for this cell and seed, from the file
-    kept under ``out_dir`` when the code has not changed since."""
+    """The reference's step-0 loss for this cell and seed, and the positions
+    it is the mean over, from the file kept under ``out_dir`` when the code
+    has not changed since."""
     from benchmark import reference
 
     key = f"{cell.name}.seed{seed}.{_code_hash(root)}"
@@ -99,12 +117,14 @@ def reference_loss(cell, argv: List[str], seed: int, root: str,
     if not variant and os.path.isfile(path):
         with open(path) as f:
             return {**json.load(f), "cached": True}
-    weights, tokens, labels = first_batch_and_weights(argv)
+    weights, batch = first_batch(argv)
+    tokens, labels = batch.pop("tokens"), batch.pop("labels")
     loss = reference.mean_loss(
         cell.config["reference"]["family"], weights, cell.config,
-        tokens, labels, root=root, rows_per_call=REFERENCE_ROWS_PER_CALL,
-        **variant)
-    res = {"loss": loss, "tokens": int(labels.size)}
+        tokens, labels, batch=batch, root=root,
+        rows_per_call=REFERENCE_ROWS_PER_CALL, **variant)
+    res = {"loss": loss,
+           "tokens": int(reference.loss_positions(labels, batch))}
     if not variant:
         os.makedirs(os.path.dirname(path), exist_ok=True)
         with open(path, "w") as f:

@@ -12,7 +12,10 @@ to these rules, the one here and the one a reference family exports
 * attention is counted CAUSAL, and only where a stack has it: a query at
   position i meets min(i + 1, window) keys, so (S + 1) / 2 on average and
   not S where no window bites, in the blocks that attend and in no other
-  (``Sizes.attention``, from the family's ``attention_blocks``);
+  (``Sizes.attention``, from the family's ``attention_blocks``). A block
+  whose mask is not that (a stack beside the decoder's that attends both
+  ways inside an image, over positions of its own) states its positions
+  and the (query, key) pairs its mask leaves, and is counted as those;
 * recomputation (per-layer remat, the flash backward's recomputed scores)
   is not counted: it is work the implementation chose, not work the model
   needs.
@@ -45,13 +48,19 @@ from typing import Any, Dict, Iterable, Mapping, Optional, Tuple
 class Attention:
     """One block that attends, as a family's ``attention_blocks`` states it.
     0 = the model's own (``Sizes``): the whole causal span, its heads, its
-    key-value heads, its head width."""
+    key-value heads, its head width, its positions, its hidden width."""
 
     window: int = 0        # keys a query meets at most, itself included
     heads: int = 0
     kv_heads: int = 0
     qk_head_dim: int = 0   # what q.k^T contracts
     v_head_dim: int = 0    # what p.v produces
+    # a block of a stack beside the decoder's (a tower before it), from the
+    # configuration's own keys and its traffic:
+    positions: int = 0     # of ONE sequence as this block sees them
+    pairs: int = 0         # (query, key) pairs of one sequence its mask
+    #                        leaves; 0 = causal over ``positions``, ``window``
+    hidden: int = 0        # the width its four projections read and write
 
     @classmethod
     def of(cls, entry: Mapping[str, int]) -> "Attention":
@@ -94,14 +103,19 @@ class Sizes:
             vocab=cfg.vocab_size, seq=cfg.seq_length,
             experts=cfg.num_experts)
 
-    def with_attention(self, entries: Iterable[Mapping[str, int]]) -> "Sizes":
+    def with_attention(self, entries: Iterable[Mapping[str, int]],
+                       beside: int = 0) -> "Sizes":
         """With a family's ``attention_blocks(config)``: one entry a block
-        of the stack as it is run that attends."""
+        that attends, of the stack as it is run and of a second stack of
+        ``beside`` blocks that the program runs beside it (what the
+        configuration's file states and ``program.equals`` ties to the
+        program; ``manifest.second_stack_depth``)."""
         blocks = tuple(Attention.of(e) for e in entries)
-        if len(blocks) > self.layers:
+        if len(blocks) > self.layers + beside:
             raise ValueError(
                 f"attention_blocks describes {len(blocks)} blocks that "
-                f"attend and the program runs {self.layers} blocks in all")
+                f"attend and the program runs {self.layers + beside} blocks "
+                "in all")
         return replace(self, attention=blocks)
 
     def attention_blocks(self) -> Tuple[Attention, ...]:
@@ -128,18 +142,29 @@ def _block(s: Sizes, a: Attention) -> Tuple[int, int, int, int]:
             a.qk_head_dim or s.head_dim, a.v_head_dim or s.head_dim)
 
 
+def _span(s: Sizes, a: Attention) -> Tuple[int, float]:
+    """Positions of one sequence as the block sees them, and the (query,
+    key) pairs of one sequence that its mask leaves."""
+    positions = a.positions or s.seq
+    return positions, a.pairs or causal_pairs(positions, a.window)
+
+
 def attention_flops_per_token(s: Sizes, entry: Attention = Attention()
                               ) -> float:
-    """q, k, v and output projections and causal attention, one block:
-    ``entry`` is the block's line of ``Sizes.attention_blocks()`` where it
-    differs from the model's sizes (a window, other heads or widths)."""
+    """q, k, v and output projections and attention, one block, a token of
+    the step (one of ``Sizes.seq`` positions): ``entry`` is the block's line
+    of ``Sizes.attention_blocks()`` where it differs from the model's sizes
+    (a window, other heads or widths; positions, pairs and a hidden width of
+    its own, whose projections run ``positions / seq`` times a token)."""
     heads, kv_heads, qk, v = _block(s, entry)
-    qkv = 2 * s.hidden * (heads * qk + kv_heads * qk + kv_heads * v)
-    out = 2 * heads * v * s.hidden
+    hidden = entry.hidden or s.hidden
+    positions, pairs = _span(s, entry)
+    qkv = 2 * hidden * (heads * qk + kv_heads * qk + kv_heads * v)
+    out = 2 * heads * v * hidden
     # q.k^T contracts qk and p.v produces v, 2 each per (query, key) pair
     # and head
-    attn = 2 * heads * (qk + v) * (causal_pairs(s.seq, entry.window) / s.seq)
-    return qkv + out + attn
+    attn = 2 * heads * (qk + v) * (pairs / s.seq)
+    return (qkv + out) * (positions / s.seq) + attn
 
 
 def head_flops_per_token(s: Sizes) -> float:
@@ -183,9 +208,10 @@ def mfu_pct(tokens_per_s: float, train_flops_per_token: float, chips: int,
 
 def flash_step_cost(s: Sizes, sequences: int, bytes_per_el: int = 2
                     ) -> Dict[str, float]:
-    """Operations and HBM bytes that causal attention needs in one training
-    step over ``sequences`` sequences, every block that attends
-    (``Sizes.attention_blocks()``), forward and backward.
+    """Operations and HBM bytes that attention needs in one training step
+    over ``sequences`` sequences, every block that attends
+    (``Sizes.attention_blocks()``: causal over the sequence, or over the
+    positions and pairs an entry states), forward and backward.
 
     Forward: two matmuls (q.k^T, p.v). Backward: five (the scores again,
     dp = do.v^T, dv = p^T.do, dq = ds.k, dk = ds^T.q); the recomputed
@@ -197,14 +223,16 @@ def flash_step_cost(s: Sizes, sequences: int, bytes_per_el: int = 2
     the backward into a dq kernel and a dk/dv kernel.
 
     Bytes: every operand read once and every result written once, whatever
-    the window. Forward reads q, k, v and writes o and the row statistics;
-    backward reads q, k, v, o, do and the statistics and writes dq, dk, dv.
+    the mask, over the block's own positions. Forward reads q, k, v and
+    writes o and the row statistics; backward reads q, k, v, o, do and the
+    statistics and writes dq, dk, dv.
     """
-    tokens = sequences * s.seq
     cost = {"flops": 0.0, "bytes": 0}
     for a in s.attention_blocks():
         heads, kv_heads, qk, v = _block(s, a)
-        pairs = sequences * causal_pairs(s.seq, a.window)   # (q, k) pairs
+        positions, pairs = _span(s, a)
+        tokens = sequences * positions
+        pairs = sequences * pairs                    # (q, k) pairs
         # q and k are qk wide, v and o are v wide
         io_el = tokens * (heads * qk + kv_heads * qk + kv_heads * v
                           + heads * v)

@@ -132,6 +132,28 @@ def train_argv(cell: Cell, seed: int, root: str = ROOT) -> List[str]:
             + [f"parallel.num_devices={cell.chips}", f"train.seed={seed}"])
 
 
+def second_stack_depth(config: Dict[str, Any]) -> int:
+    """The blocks of a stack that the program runs beside the decoder's (a
+    tower in front of it), which a family's ``attention_blocks`` may
+    describe beyond ``num_hidden_layers``: the configuration's own key that
+    ``reference.second_stack_depth_key`` names, which ``program.equals`` has
+    to tie to the program's resolved settings. 0 where nothing is named."""
+    key = config.get("reference", {}).get("second_stack_depth_key")
+    if not key:
+        return 0
+    depth = config.get(key)
+    if (key not in config["program"]["equals"].values()
+            or not isinstance(depth, int) or isinstance(depth, bool)
+            or depth < 0):
+        raise ValueError(
+            f"reference.second_stack_depth_key names {key!r}: it has to be "
+            "a key of the configuration's file that holds a whole number "
+            "from 0 on, and a value of program.equals, so that the program "
+            f"is held to it (the file has {key}={depth!r}, program.equals "
+            f"ties {sorted(config['program']['equals'].values())})")
+    return depth
+
+
 # ---------------------------------------------------------------------------
 # the manifest check (the contract's static rules, as far as a file shows)
 # ---------------------------------------------------------------------------
@@ -217,6 +239,10 @@ def check_manifest(manifest: Dict[str, Any], root: str = ROOT) -> List[str]:
             where = os.path.relpath(family_path(root, family), root)
             bad.append(f"{c['name']}: reference family {family!r} has no "
                        f"file {where}")
+        try:
+            second_stack_depth(body)
+        except ValueError as e:
+            bad.append(f"{c['name']}: {e}")
     # cells
     cells = manifest["workloads"]
     if not 2 <= len(cells) <= MAX_CELLS:

@@ -47,7 +47,8 @@ def main() -> int:
 
     cell = manifest.resolve_cell(manifest.load_manifest(), a.workload)
     argv = manifest.train_argv(cell, a.seed)
-    weights, tokens, labels = check.first_batch_and_weights(argv)
+    weights, batch = check.first_batch(argv)
+    tokens, labels = batch.pop("tokens"), batch.pop("labels")
     family = cell.config["reference"]["family"]
     depth = cell.config[depth_key(cell.config)]
     out = {"cell": cell.name, "seed": a.seed}
@@ -55,7 +56,7 @@ def main() -> int:
                      ("one_block_fewer", {"layers": depth - 1}),
                      ("bfloat16", {"dtype": jnp.bfloat16})):
         out[name] = reference.mean_loss(family, weights, cell.config,
-                                        tokens, labels, **kw)
+                                        tokens, labels, batch=batch, **kw)
         print(json.dumps(out), flush=True)
     return 0
 

@@ -70,13 +70,20 @@ def measure(cell, *, seed: int, seconds: float, trace: int, chip: dict,
     sizes = flops.Sizes.of(cfg)
     # the model's FLOPs are its family's; a family or an export that is not
     # there, a dense count for a program with experts, or more attending
-    # blocks described than the program runs, stops the run here
+    # blocks described than the program runs (in its decoder and in a stack
+    # beside it whose depth the configuration's file states), stops the run
+    # here
     family = reference.load_family(cell.config["reference"]["family"], root)
     if hasattr(family, "attention_blocks"):
-        sizes = sizes.with_attention(family.attention_blocks(cell.config))
+        sizes = sizes.with_attention(
+            family.attention_blocks(cell.config),
+            beside=manifest.second_stack_depth(cell.config))
     train_flops = flops.train_from_forward(
         family.forward_flops_per_token(sizes, cell.config))
     sequences = args.parallel.global_train_batch_size
+    # every position of the decoder's sequence is a token of the step, one
+    # that holds an image's merged patches or that ``loss_mask`` leaves out
+    # of the loss too
     tokens_per_step = sequences * cfg.seq_length
 
     os.makedirs(out_dir, exist_ok=True)
@@ -113,7 +120,8 @@ def measure(cell, *, seed: int, seconds: float, trace: int, chip: dict,
               "kind": devices[0].device_kind, "count": len(devices),
               "memory_peak_bytes": facts["memory"]["peak_bytes"]}
     setup_s = win["start"] - t_process_start
-    tokens_per_s = tokens_per_step / win["median_period_s"]
+    # all the whole periods of the window over all of their time
+    tokens_per_s = tokens_per_step / win["mean_period_s"]
     e2e = {"tokens_per_s": tokens_per_s,
            "mfu_pct": flops.mfu_pct(tokens_per_s, train_flops, cell.chips,
                                     chip["bf16_flops_per_s"]),
@@ -161,8 +169,8 @@ def measure(cell, *, seed: int, seconds: float, trace: int, chip: dict,
         "cell": cell.name, "seed": seed, "seconds": seconds,
         "trace": trace, "argv": facts["argv"][1:],
         "end_to_end": e2e, "window": win, "steps_ms": steps_ms,
-        "tokens_per_s_mean_over_window":
-            tokens_per_step * win["steps"] / win["wall_s"],
+        "tokens_per_s_at_median_period":
+            tokens_per_step / win["median_period_s"],
         "losses": facts["losses"], "reference": ref,
         "checks": verdict["checks"],
         "setup_split_s": {

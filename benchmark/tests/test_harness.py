@@ -42,7 +42,7 @@ def test_cell_runs_through_train_dist_and_ends_its_own_window(
     assert w["wall_s"] - report["steps_ms"][-1] / 1e3 < 0.5
     assert math.isclose(
         line["metrics"]["tokens_per_s"]["value"],
-        report["tokens_per_step"] / w["median_period_s"])
+        report["tokens_per_step"] / w["mean_period_s"])
     # warm-up steps are not in the window; their losses are kept
     assert len(report["losses"]) == window.WARMUP_STEPS + line["attempted"]
     checks = report["checks"]
@@ -219,21 +219,47 @@ def test_window_signals_once_at_the_first_sample_past_its_length():
     assert w.start == pytest.approx(10.0) and w.end == 11.7
 
 
-def test_one_stalled_step_does_not_move_the_rate():
+def test_the_rate_is_over_every_period_of_the_window_a_stalled_one_too():
     steps = [(k * 0.23, k * 0.23 + 0.222) for k in range(44)]
     calm = window.steady_rate(steps)
+    assert calm["mean_period_s"] == pytest.approx(0.23)
     assert calm["median_period_s"] == pytest.approx(0.23)
     assert calm["loop_overhead_ms"] == pytest.approx(8.0)
     assert calm["stall_pct"] == pytest.approx(0.0, abs=1e-9)
-    # step 7 stalls for 1.1 s (seen on the chip in gpt2xl_c1_b4)
+    # step 7 stalls for 1.1 s (seen on the chip in gpt2xl_c1_b4): the rate
+    # holds it, the median period beside it does not, and stall_pct is the
+    # difference
     late = [(s + (1.1 if k > 7 else 0), e + (1.1 if k >= 7 else 0))
             for k, (s, e) in enumerate(steps)]
     stalled = window.steady_rate(late)
+    assert stalled["mean_period_s"] == pytest.approx((43 * 0.23 + 1.1) / 43)
     assert stalled["median_period_s"] == pytest.approx(0.23)
     assert stalled["stall_pct"] == pytest.approx(
         100 * 1.1 / (43 * 0.23 + 1.1))
     with pytest.raises(ValueError):
         window.steady_rate(steps[:1])
+
+
+def test_steps_on_levels_give_a_rate_that_moves_with_their_shares():
+    """``mellum2_c4_ep4``: a step takes 368.6, 381.3 or 394.9 ms by the
+    passes its fullest chip counts. With 13 or 14 of 27 periods on the lowest
+    level the median period is one level or the next (3.4 % apart), and the
+    mean period moves by one step's 12.7 ms over 27."""
+    def window_of(levels):
+        t, steps = 0.0, []
+        for ms in levels:
+            steps.append((t, t + ms / 1e3 - 0.004))
+            t += ms / 1e3
+        return window.steady_rate(steps + [(t, t + 0.36)])
+
+    a = window_of([368.6] * 14 + [381.3] * 9 + [394.9] * 4)
+    b = window_of([368.6] * 13 + [381.3] * 10 + [394.9] * 4)
+    assert a["median_period_s"] == pytest.approx(0.3686)
+    assert b["median_period_s"] == pytest.approx(0.3813)
+    assert b["mean_period_s"] - a["mean_period_s"] == pytest.approx(
+        0.0127 / 27)
+    assert a["mean_period_s"] == pytest.approx(
+        (14 * 368.6 + 9 * 381.3 + 4 * 394.9) / 27e3)
 
 
 def test_command_refuses_a_cpu():

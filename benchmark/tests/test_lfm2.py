@@ -6,7 +6,6 @@ widths and both depths, ``attention_blocks``, the manifest, and that every
 file the benchmark had is as it was."""
 
 import os
-import subprocess
 
 import pytest
 
@@ -136,23 +135,25 @@ def test_the_family_adds_its_blocks_up_by_kind(depth, attending, forward):
 def test_the_manifest_holds_six_cells_and_the_new_metrics_are_the_cell_s():
     man = manifest.load_manifest()
     assert manifest.check_manifest(man) == []
-    assert len(man["workloads"]) == 6
-    assert [w["name"] for w in man["workloads"] if w["chips"] == 4] == [
+    # the cell's own entries and the manifest's beginning, not its length:
+    # a later PR adds cells after these
+    assert [w["name"] for w in man["workloads"] if w["chips"] == 4][:1] == [
         "mistral7b_c4_tp2dp2z3"]
-    assert man["workloads"][-1]["name"] == CELL
+    assert man["workloads"][5]["name"] == CELL
     mine = [m for m in man["per_layer"] if m.get("workloads") == [CELL]]
-    assert [m["name"] for m in mine] == [
+    assert [m["name"] for m in mine][:5] == [
         "lfm2_experts_ms", "lfm2_experts_time_share_pct",
         "lfm2_experts_roofline", "lfm2_local_routes_pct",
         "lfm2_moe_imbalance"]
     assert all(m["moves"] == "tokens_per_s" and m["layer"] == "experts"
-               for m in mine)
+               for m in mine[:5])
     cell = manifest.resolve_cell(man, CELL)
     names = {m["name"] for m in cell.per_layer}
     assert {"flash_roofline", "static_hbm_GiB", "device_idle_pct"} < names
     assert not names & {"experts_ms", "moe_imbalance"}
     body = cell.config
-    assert sorted(body["reduced_from"]) == sorted(man["configs"][-1]["reduced"])
+    assert sorted(body["reduced_from"]) == sorted(
+        man["configs"][4]["reduced"])
     assert (body["hidden_size"], body["intermediate_size"],
             body["moe_intermediate_size"], body["num_experts_per_tok"],
             body["num_routed_experts"], body["conv_L_cache"],
@@ -174,17 +175,7 @@ def test_the_experts_cost_is_the_expected_share_of_the_rows():
 
 def test_every_file_the_benchmark_had_is_as_it_was():
     """Against the parent commit, where git and the commit are at hand:
-    every file it has under ``benchmark/`` is here byte for byte (what this
-    PR brings under ``benchmark/`` are new files)."""
-    def git(*words):
-        return subprocess.run(["git", *words], cwd=manifest.ROOT,
-                              capture_output=True, check=True).stdout
-    try:
-        had = git("ls-tree", "-r", "--name-only", PARENT, "--",
-                  "benchmark").decode().split()
-    except (OSError, subprocess.CalledProcessError):
-        pytest.skip("no git, or the parent commit is not in this checkout")
-    assert len(had) > 60
-    for rel in had:
-        with open(os.path.join(manifest.ROOT, rel), "rb") as f:
-            assert f.read() == git("show", f"{PARENT}:{rel}"), rel
+    every data file it has under ``benchmark/`` is here byte for byte (what
+    this PR brings under ``benchmark/`` are new files; the harness's own
+    Python is a ``benchmark`` PR's to change, ``tiny.DATA_DIRS``)."""
+    tiny.data_files_as_they_were_at(PARENT, 60)

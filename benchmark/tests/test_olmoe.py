@@ -133,12 +133,13 @@ def test_the_family_counts_eight_experts_and_the_router_at_published_widths():
 def test_the_manifest_holds_five_cells_and_the_expert_metrics_are_the_cell_s():
     man = manifest.load_manifest()
     assert manifest.check_manifest(man) == []
-    assert len(man["workloads"]) == 5
-    assert [w["name"] for w in man["workloads"] if w["chips"] == 4] == [
+    # the cell's own entries and the manifest's beginning, not its length:
+    # a later PR adds cells after these
+    assert [w["name"] for w in man["workloads"] if w["chips"] == 4][:1] == [
         "mistral7b_c4_tp2dp2z3"]
-    assert man["workloads"][-1] == {
+    assert man["workloads"][4] == {
         "name": CELL, "config": "olmoe-1b-7b-d1", "traffic": "c1_s4k",
-        "chips": 1, "why": man["workloads"][-1]["why"]}
+        "chips": 1, "why": man["workloads"][4]["why"]}
     mine = [m for m in man["per_layer"] if m.get("workloads") == [CELL]]
     assert {m["name"] for m in mine} >= {
         "experts_ms", "experts_time_share_pct", "experts_roofline",

@@ -111,6 +111,23 @@ TINY_HYBRID = {
 }
 HYBRID_CELL = ("tiny_hybrid_c1", "tiny-hybrid", "tiny_c1", 1)
 
+# a batch that holds more than ids: documents packed into the tiny Mistral
+# program's sequences from an indexed corpus that a test writes, under a
+# family that takes the batch's other fields (tests/new_family/
+# tiny_packed.py). The corpus's path is the test's, so the traffic file is
+# written with it (``add_packed_family``)
+PACKED_EOD = 63          # of the 64 ids; no other token of a document is it
+PACKED_TRAFFIC = [o for o in COMMON if o != "data.dataset=random"] + [
+    "data.dataset=indexed", "data.reset_position_ids=true",
+    "data.reset_attention_mask=true", "data.eod_mask_loss=true",
+    "parallel.global_train_batch_size=4", "parallel.chunks=2"]
+PACKED_CELL = ("tiny_packed_c1", "tiny-packed", "tiny_c1_packed", 1)
+# at this size attention moves the loss little, so the fixture states a
+# tolerance of its own: the program's step 0 lay within 6.8e-5 of the family
+# on seeds 7 to 12, and a family that leaves ``segment_ids`` unread 7.3e-4
+# (seed 7) to 2.5e-3 (seeds 8, 9) from it
+PACKED_TOLERANCE = 3e-4
+
 # a stand-in row of peaks so the arithmetic runs; nothing is reported
 FAKE_CHIP = {"bf16_flops_per_s": 1e12, "hbm_bytes_per_s": 1e11,
              "hbm_bytes": 1e9, "ici_bits_per_s": 1e9}
@@ -162,6 +179,51 @@ def assert_nothing_that_was_there_is_edited(root: str) -> None:
             assert a.read() == b.read(), rel
 
 
+# what a later PR may not edit, whatever its kind but ``benchmark``: the
+# data, and each family's reference. The harness's own Python, its README and
+# its tests are what a ``benchmark`` PR changes (PR 58 did), so a test that
+# holds a commit's files against today's holds these alone
+DATA_DIRS = ("configs", "workloads", "layer_metrics", "reference")
+NOT_DATA = ("benchmark/reference/__init__.py",)
+
+
+def data_files_as_they_were_at(commit: str, least: int):
+    """Against ``commit``, where git and the commit are at hand (else the
+    test is skipped): every data file it has under ``benchmark/`` is here
+    byte for byte, of ``least`` files or more under ``benchmark/`` in all.
+    Returns its ``BENCHMARK.json``."""
+    import subprocess
+
+    import pytest
+
+    def git(*words):
+        return subprocess.run(["git", *words], cwd=manifest.ROOT,
+                              capture_output=True, check=True).stdout
+    try:
+        had = git("ls-tree", "-r", "--name-only", commit, "--",
+                  "benchmark").decode().split()
+        was = json.loads(git("show", f"{commit}:BENCHMARK.json"))
+    except (OSError, subprocess.CalledProcessError):
+        pytest.skip("no git, or the commit is not in this checkout")
+    assert len(had) > least
+    for rel in had:
+        if rel.split("/")[1] in DATA_DIRS and rel not in NOT_DATA:
+            with open(os.path.join(manifest.ROOT, rel), "rb") as f:
+                assert f.read() == git("show", f"{commit}:{rel}"), rel
+    return was
+
+
+def assert_the_manifest_begins_with(was) -> None:
+    """``BENCHMARK.json`` still begins with what it held: every list of
+    entries with the entries it had, every other key as it was."""
+    now = manifest.load_manifest()
+    for key, value in was.items():
+        if isinstance(value, list) and value and isinstance(value[0], dict):
+            assert now[key][:len(value)] == value, key
+        else:
+            assert now[key] == value, key
+
+
 def _add_config(root, man, name, body):
     _write(os.path.join(root, "benchmark", "configs", name + ".json"), body)
     man["configs"].append({
@@ -208,4 +270,45 @@ def add_hybrid_family(root: str, **config) -> None:
     man = manifest.load_manifest(root)
     _add_config(root, man, "tiny-hybrid", {**TINY_HYBRID, **config})
     _add_cell(man, *HYBRID_CELL)
+    _write(os.path.join(root, "BENCHMARK.json"), man)
+
+
+def write_packed_corpus(prefix: str, seed: int = 0, documents: int = 600
+                        ) -> None:
+    """An indexed corpus in the program's own format (its public writer and
+    the ``.meta.json`` its preprocessor leaves beside it): documents of 2 to
+    11 ids below ``PACKED_EOD``, each ended by it, so that a sequence of 16
+    holds the ends of one to five."""
+    import numpy as np
+
+    from hetu_galvatron_tpu.data.indexed_dataset import write_indexed_dataset
+
+    rng = np.random.default_rng(seed)
+    write_indexed_dataset(prefix, [
+        list(rng.integers(0, PACKED_EOD, rng.integers(2, 12))) + [PACKED_EOD]
+        for _ in range(documents)])
+    _write(prefix + ".meta.json", {"vocab_size": TINY_MISTRAL["vocab_size"],
+                                   "eod_id": PACKED_EOD})
+
+
+def add_packed_family(root: str, corpus: str, family: str = "tiny_packed",
+                      source: str = "") -> None:
+    """A family that takes the batch's other fields, its configuration, the
+    traffic that packs documents from ``corpus`` and a cell: new files and
+    new entries only. A control names a ``family`` that is there already,
+    or gives the ``source`` of another."""
+    path = manifest.family_path(root, family)
+    if not os.path.isfile(path) and source:
+        with open(path, "w") as f:
+            f.write(source)
+    elif not os.path.isfile(path):
+        shutil.copy(os.path.join(NEW_FAMILY_DIR, "tiny_packed.py"), path)
+    _write(manifest.traffic_path(root, PACKED_CELL[2]),
+           {"overrides": PACKED_TRAFFIC + [f"data.data_path=[{corpus}]"]})
+    man = manifest.load_manifest(root)
+    _add_config(root, man, PACKED_CELL[1], {
+        **TINY_MISTRAL,
+        "reference": {"family": family,
+                      "loss_tolerance": PACKED_TOLERANCE}})
+    _add_cell(man, *PACKED_CELL)
     _write(os.path.join(root, "BENCHMARK.json"), man)

@@ -237,15 +237,18 @@ def run_window(argv: List[str], *, seconds: float,
 
 
 def steady_rate(steps: Sequence[tuple]) -> Dict[str, float]:
-    """The window's steady step period and what the window lost to stalls.
+    """The window's rate, and what of it is stalls.
 
     A period runs from one measured step's start to the next one's, so it
     holds the step and the rest of the loop body. The rate is taken from the
-    MEDIAN period: on a one-chip machine the host's cores are shared, and one
-    stalled step of a second (seen on the chip, PR 22) would move a mean
-    over a ten-second window by a tenth. What the median leaves out is kept
-    as ``stall_pct``: the share of the window beyond what the same number
-    of median periods would have taken."""
+    MEAN period: every whole period of the window and all of their time, a
+    stalled step's too. (Until PR 58 it was the median period, which left
+    stalls out and, where a cell's steps stand on levels as
+    ``mellum2_c4_ep4``'s do by their counted passes, fell on one level or
+    the next by a step or two.) The median period stays beside it, and
+    ``stall_pct`` is the share of the window beyond what the same number of
+    median periods would have taken: what the mean holds and the median
+    does not."""
     if len(steps) < 2:
         raise ValueError("a window of fewer than two steps has no period")
     starts = [s for s, _ in steps]
@@ -253,6 +256,7 @@ def steady_rate(steps: Sequence[tuple]) -> Dict[str, float]:
     tails = [p - (e - s) for p, (s, e) in zip(periods, steps)]
     med = statistics.median(periods)
     return {
+        "mean_period_s": (starts[-1] - starts[0]) / len(periods),
         "median_period_s": med,
         "loop_overhead_ms": 1e3 * statistics.median(tails),
         "stall_pct": 100.0 * (1.0 - med * len(periods)
