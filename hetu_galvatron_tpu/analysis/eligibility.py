@@ -353,6 +353,44 @@ def residual_streams_reason(cfg: Any, what: str) -> Optional[str]:
             "(builder.forward_causal_lm / causal_lm_loss) runs")
 
 
+TOWER_REASON = ("a model with a tower of image patches in front of its "
+                "decoder (model.tower_layers) runs the tower whole on every "
+                "device, data parallel, at tp=1, cp=1 and pp=1: its "
+                "parameters carry no axis tensor parallelism cuts, its "
+                "patches are no positions of the sequence a cp plan cuts "
+                "(the ring and Ulysses cores take a causal span, not an "
+                "image's square), and no pipeline stage, cache or decoding "
+                "path hands patches to it (eligibility.tower_plan_reason)")
+
+
+def tower_reason(cfg: Any, what: str) -> Optional[str]:
+    """Why ``what`` (a pipeline engine, ``generate()``, the serving engine,
+    a profiler: anything that embeds ids alone) cannot take a model with a
+    tower; None for a model without one."""
+    if not getattr(cfg, "tower_layers", 0):
+        return None
+    return (f"{what} embeds token ids and nothing else; this model states "
+            f"tower_layers={cfg.tower_layers}: {TOWER_REASON}")
+
+
+def tower_plan_reason(cfg: Any, layers: Any, pp_deg: int = 1
+                      ) -> Optional[str]:
+    """Why a training plan cannot run this model's tower; None when it can
+    (or the model has none). The tower takes the first decoder block's plan:
+    that block's tp and cp, and the plan's pp, have to be 1."""
+    if not getattr(cfg, "tower_layers", 0):
+        return None
+    if pp_deg > 1:
+        return (f"the plan has pp={pp_deg} and the model a tower: "
+                + TOWER_REASON)
+    for i, s in enumerate(layers):
+        cut = _cut_said(s)
+        if cut:
+            return (f"block {i}'s plan has {cut} and the model a tower: "
+                    + TOWER_REASON)
+    return None
+
+
 def own_multipliers_reason(cfg: Any, what: str) -> Optional[str]:
     """Why ``what`` (a decoding path with its own attention core and its
     own embedding and residual adds) cannot take a model that states a

@@ -185,9 +185,11 @@ def train(args) -> Dict[str, Any]:
 
         from hetu_galvatron_tpu.ops.pallas.flash_attention import (
             LAYOUT_CALLS,
+            TWO_WAY_CALLS,
             WINDOWED_CALLS,
             band_tiles,
             effective_window,
+            two_way_tiles,
         )
 
         kinds = cfg.block_kinds(len(hpc.layers))
@@ -200,8 +202,38 @@ def train(args) -> Dict[str, Any]:
             + (f"[w{window}]" if window and mixer == "sliding_attention"
                else "")
             for s, (mixer, _) in zip(hpc.layers, kinds)]
+        tower_report: Dict[str, Any] = {}
+        if cfg.tower_layers and cfg.image_grids:
+            # a tower of image patches in front of the decoder: its blocks
+            # attend on the first block's plan's core and come first in the
+            # list; what a step holds of images is fixed by the traffic's
+            # grids (facts of the run: tower/* gauges)
+            from hetu_galvatron_tpu.models.tower import grids_of, pairs_masked
+            from hetu_galvatron_tpu.runtime.dataloader import image_layout
+
+            attention_cores = ([attention_core(False, False, use_flash)]
+                               * cfg.tower_layers + attention_cores)
+            rows = hpc.global_bsz
+            tower_report = {
+                "blocks": cfg.tower_layers,
+                "patches": rows * sum(cfg.image_patches),
+                "image_positions": rows * cfg.image_positions,
+                # the positions whose label is no image position
+                "marked_positions": rows * int(cfg.seq_length - image_layout(
+                    cfg, args.data.image_text_spans)[1:].sum()),
+                # the (query, key) pairs the mask leaves, a block and head
+                # (what the cores' tiles cover is read off the calls once
+                # the step is built: pairs_tiled, in the step report)
+                "pairs_masked": rows * pairs_masked(grids_of(cfg))}
+            for k, v in tower_report.items():
+                get_registry().gauge(f"tower/{k}").set(v)
         state.log("attention cores: " + ", ".join(
-            f"{n} x {core}" for core, n in Counter(attention_cores).items()))
+            f"{n} x {core}" for core, n in Counter(attention_cores).items())
+            + (" (the first {blocks} the tower's: {patches} patches, "
+               "{image_positions} image positions and {marked_positions} "
+               "marked positions a step, {pairs_masked} pairs inside "
+               "images)".format(**tower_report)
+               if tower_report else ""))
         if "sliding_attention" in (cfg.layer_types or ()):
             # each attending block's window (0 = the causal span) and query
             # heads, as the configuration gives them
@@ -218,6 +250,7 @@ def train(args) -> Dict[str, Any]:
         # index (the step report reads them)
         WINDOWED_CALLS.clear()
         LAYOUT_CALLS.clear()
+        TWO_WAY_CALLS.clear()
         # how many blocks of each mixer and feed-forward kind the step holds
         blocks = {}
         for (m, ff), n in Counter(kinds).items():
@@ -309,6 +342,30 @@ def train(args) -> Dict[str, Any]:
         host_lr = HostSchedule(args.train)
         base_iter, valid_iter, test_iter = get_train_valid_test_data_iterators(
             args, global_batch_size=hpc.global_bsz, hpc=hpc)
+        place_box: Dict[str, Any] = {}
+        if cfg.tower_layers and cfg.image_grids:
+            # pixels are megabytes a sequence where ids are kilobytes: they
+            # go to the device from a thread of their own, a batch ahead, so
+            # the copy runs under the previous step and train/h2d finds them
+            # there (a batch drawn before the step's sharding is known goes
+            # as it is). ``patch_grids`` says what the loader packed: held
+            # here to the grids the step's tables were built from, and not
+            # sent (the tower reads ``model.image_grids``)
+            from hetu_galvatron_tpu.runtime.dataloader import one_ahead
+
+            def placed(batches):
+                for b in batches:
+                    b = dict(b)
+                    if b.pop("patch_grids")[0].tolist() != cfg.image_grids:
+                        raise ValueError(
+                            "the loader packed images of other grids than "
+                            f"model.image_grids {cfg.image_grids}")
+                    if "batch_shd" in place_box:
+                        b["patches"] = jax.device_put(
+                            b["patches"], place_box["batch_shd"])
+                    yield b
+
+            base_iter = one_ahead(placed(base_iter))
         data_iter = RerunDataIterator(base_iter)
         # unified telemetry (observability/): configures the process-wide
         # registry with JSONL (+optional TensorBoard) sinks, so the profiler's
@@ -1177,6 +1234,7 @@ def train(args) -> Dict[str, Any]:
                 donate=not rerun.enabled, tp_overlap=tp_overlap_on,
                 hier_dp=hier_dp_on, dcn_slices=args.parallel.dcn_slices,
                 hier_bucket_mb=hier_bucket_mb, dp_schedule=dp_schedule_on)
+            place_box["batch_shd"] = batch_shd
             nshd = lambda specs: jax.tree.map(
                 lambda s: NamedSharding(mesh, s), specs,
                 is_leaf=lambda x: isinstance(x, PartitionSpec))
@@ -1298,6 +1356,19 @@ def train(args) -> Dict[str, Any]:
                             100.0 * visited / triangle if triangle else 100.0)
                         get_registry().gauge("flash/band_tiles_pct").set(
                             step_report["band_tiles_pct"])
+                    if tower_report:
+                        # the pairs the tower's cores compute, a block and
+                        # head: the tiles the flash kernels' loops visit in
+                        # the calls that are not causal, by the lengths and
+                        # tiles those calls were built with
+                        # (``flash_attention.TWO_WAY_CALLS``); the XLA core
+                        # makes the square of a sequence's patches
+                        tower_report["pairs_tiled"] = hpc.global_bsz * (
+                            sum(two_way_tiles(*c) * c[2] * c[3]
+                                for c in TWO_WAY_CALLS)
+                            if use_flash else sum(cfg.image_patches) ** 2)
+                        get_registry().gauge("tower/pairs_tiled").set(
+                            tower_report["pairs_tiled"])
                     # the distinct flash calls the step was built with, by
                     # what the kernels index: the projections' own rows, or
                     # head-major copies between transposes
@@ -1343,6 +1414,8 @@ def train(args) -> Dict[str, Any]:
                     + (", flash/band_tiles_pct "
                        f"{step_report['band_tiles_pct']:.1f}"
                        if "band_tiles_pct" in step_report else "")
+                    + (f", tower/pairs_tiled {tower_report['pairs_tiled']}"
+                       if tower_report else "")
                     + (", flash/row_layout_calls "
                        f"{step_report['row_layout_calls']}"
                        ", flash/transposed_calls "
@@ -1399,6 +1472,12 @@ def train(args) -> Dict[str, Any]:
                         "restarts_survived": goodput.restarts_survived},
             "flight_dumps": list(recorder.dumped) if recorder else [],
             "attention_cores": attention_cores,
+            # a model with a tower and a traffic with images: the tower's
+            # blocks (their cores are the first of attention_cores), and a
+            # step's patches, image positions, marked positions and the
+            # (query, key) pairs the tower's mask leaves and its cores'
+            # tiles cover (the tower/* gauges); None without
+            "tower": tower_report or None,
             # the expert exchange: the ep degree of the blocks inside it,
             # how many there are, bytes a chip sends around them a step
             # (gauges ep/axes, ep/exchange_bytes_per_step), and the rows of

@@ -259,6 +259,105 @@ class ModelArgs(BaseModel):
     # ``mtp_loss_coeff`` times the mean of their cross-entropies. 0 or 1
     num_nextn_predict_layers: int = 0
     mtp_loss_coeff: float = 0.3
+    # a tower of image patches in front of the decoder (Kimi-VL's MoonViT,
+    # arXiv:2504.07491 2.1; models/tower.py): ``tower_layers`` pre-norm
+    # blocks of ``tower_hidden_size`` whose ``tower_num_heads`` heads attend
+    # both ways inside an image, rotated on two axes; a patch is
+    # ``tower_in_channels x tower_patch_size^2`` pixel values, its position a
+    # row of a learned ``tower_pos_emb_height x tower_pos_emb_width`` table
+    # interpolated to the image's grid; ``tower_merge_kernel`` patches side
+    # by side go through the projector to one row of ``hidden_size``, which
+    # takes the embedding's place where a token is ``image_token_id``.
+    # ``image_grids``: the (rows, columns) in patches of every image of ONE
+    # sequence, in order: the traffic's, static, so the step's tables are
+    # constants. 0 layers = no tower, the program every other model has
+    tower_layers: int = 0
+    tower_hidden_size: int = 1152
+    tower_num_heads: int = 16
+    tower_ffn_hidden_size: int = 4304
+    tower_patch_size: int = 14
+    tower_in_channels: int = 3
+    tower_pos_emb_height: int = 64
+    tower_pos_emb_width: int = 64
+    tower_merge_kernel: List[int] = Field(default_factory=lambda: [2, 2])
+    tower_layernorm_epsilon: float = 1e-5
+    tower_rope_theta: float = 10000.0
+    image_token_id: Optional[int] = None
+    image_grids: Optional[List[List[int]]] = None
+    # initial values a configuration states beside the tower's plain draw
+    # (``tower.init_tower``: every matrix and the table N(0, 0.02)): the
+    # fused q | k | v maps' and the position table's standard deviation,
+    # and the maps that read a GELU centred over their inputs
+    tower_qkv_init_std: Optional[float] = None
+    tower_pos_emb_init_std: Optional[float] = None
+    tower_centred_init: bool = False
+
+    @model_validator(mode="after")
+    def _check_tower(self):
+        if not self.tower_layers:
+            if self.image_grids:
+                raise ValueError(
+                    "model.image_grids names images and the model has no "
+                    "tower (model.tower_layers is 0)")
+            return self
+        head = self.tower_hidden_size // self.tower_num_heads
+        if self.tower_hidden_size % self.tower_num_heads or head % 4:
+            raise ValueError(
+                f"model.tower_hidden_size {self.tower_hidden_size} over "
+                f"{self.tower_num_heads} heads: a head rotated on two axes "
+                "is a whole number of pairs of pairs")
+        if self.image_token_id is None or not (
+                0 <= self.image_token_id < self.vocab_size):
+            raise ValueError(
+                f"model.image_token_id {self.image_token_id!r}: a model "
+                "with a tower names the id that marks an image position, "
+                f"one of its {self.vocab_size} rows")
+        mh, mw = self.tower_merge_kernel
+        for h, w in self.image_grids or ():
+            if h < mh or w < mw or h % mh or w % mw:
+                raise ValueError(
+                    f"model.image_grids: an image of {h} x {w} patches is "
+                    f"no whole number of {mh} x {mw} merges")
+        if self.image_positions > self.seq_length:
+            raise ValueError(
+                f"model.image_grids: {self.image_positions} image positions "
+                f"in a sequence of {self.seq_length}")
+        return self
+
+    @property
+    def tower_head_dim(self) -> int:
+        return self.tower_hidden_size // self.tower_num_heads
+
+    @property
+    def tower_patch_dim(self) -> int:
+        """Pixel values of one patch, channel-major."""
+        return self.tower_in_channels * self.tower_patch_size ** 2
+
+    @property
+    def image_patches(self) -> List[int]:
+        """Patches of each image of one sequence."""
+        return [h * w for h, w in self.image_grids or ()]
+
+    @property
+    def image_positions(self) -> int:
+        """Positions of one sequence that hold an image's merged patches."""
+        mh, mw = self.tower_merge_kernel
+        return sum(self.image_patches) // (mh * mw)
+
+    @property
+    def vision_config(self) -> Dict[str, Any]:
+        """The tower's widths under the published group's keys
+        (``MoonViTConfig``), the depth apart (``tower_layers``)."""
+        return {"hidden_size": self.tower_hidden_size,
+                "num_attention_heads": self.tower_num_heads,
+                "intermediate_size": self.tower_ffn_hidden_size,
+                "patch_size": self.tower_patch_size,
+                "num_channels": self.tower_in_channels,
+                "init_pos_emb_height": self.tower_pos_emb_height,
+                "init_pos_emb_width": self.tower_pos_emb_width,
+                "merge_kernel_size": list(self.tower_merge_kernel),
+                "layer_norm_eps": self.tower_layernorm_epsilon,
+                "rope_theta": self.tower_rope_theta}
 
     @model_validator(mode="after")
     def _check_attention_kinds(self):
@@ -627,6 +726,11 @@ class DataArgs(BaseModel):
     reset_position_ids: bool = False
     reset_attention_mask: bool = False
     eod_mask_loss: bool = False
+    # a model with a tower (model.tower_layers, model.image_grids): the
+    # text positions before the first image, between the images and after
+    # the last, one more than the images; with the images' own positions
+    # they are the sequence. None = the text in equal parts
+    image_text_spans: Optional[List[int]] = None
 
 
 class ProfileArgs(BaseModel):

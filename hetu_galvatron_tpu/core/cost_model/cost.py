@@ -891,6 +891,27 @@ def embed_memory_cost(
 # ---------------------------------------------------------------------------
 
 
+def tower_flops_per_sequence(model: Any) -> float:
+    """Forward matmul FLOPs of a tower of image patches in front of the
+    decoder (models/tower.py) over the images of ONE sequence
+    (``model.image_grids``): its blocks' four projections and two-matrix MLP
+    over the patches, attention both ways inside an image (the squares of
+    the images' patches, dense), the patch map, and the projector's two
+    maps over the merged rows. 0 for a model without a tower or a traffic
+    without images."""
+    if not (getattr(model, "tower_layers", 0)
+            and getattr(model, "image_grids", None)):
+        return 0.0
+    c, f = model.tower_hidden_size, model.tower_ffn_hidden_size
+    patches = sum(model.image_patches)
+    merged = c * model.tower_merge_kernel[0] * model.tower_merge_kernel[1]
+    return (model.tower_layers * (
+        patches * 2 * c * (4 * c + 2 * f)
+        + 2 * 2 * c * sum(n * n for n in model.image_patches))
+        + patches * 2 * model.tower_patch_dim * c
+        + model.image_positions * 2 * merged * (merged + model.hidden_size))
+
+
 def model_flops_per_token(model: Any, seq_length: Optional[int] = None
                           ) -> float:
     """Matmul FLOPs per token for one training step (forward + backward,
@@ -994,7 +1015,8 @@ def model_flops_per_token(model: Any, seq_length: Optional[int] = None
                  + (experts_ff if ff == "experts" else dense_ff)
                  for i, (m, ff) in enumerate(kinds)]
     head = 2 * h * model.padded_vocab_size  # LM head
-    fwd = sum(per_block) + head
+    # (a tower's work a sequence falls on the sequence's tokens)
+    fwd = sum(per_block) + head + tower_flops_per_sequence(model) / s
     if getattr(model, "num_nextn_predict_layers", 0):
         # one further prediction depth: eh_proj, one more block of the last
         # block's kind, the head again

@@ -92,6 +92,13 @@ def init_causal_lm(key: jax.Array, cfg: ModelArgs) -> Tuple[Params, Params]:
         # are without it
         params["mtp"], axes["mtp"] = init_mtp(
             jax.random.fold_in(key, n + 2), cfg)
+    if cfg.tower_layers:
+        # the tower of image patches and its projector (models/tower.py),
+        # from a key of its own too
+        from hetu_galvatron_tpu.models.tower import init_tower
+
+        params["tower"], axes["tower"] = init_tower(
+            jax.random.fold_in(key, n + 3), cfg)
     return params, axes
 
 
@@ -174,8 +181,18 @@ def forward_causal_lm(
     segment_ids: Optional[jax.Array] = None,
     mrope_position_ids: Optional[jax.Array] = None,
     mtp_labels: Optional[jax.Array] = None,
+    patches: Optional[jax.Array] = None,
+    tower_remat_flags: Optional[Sequence[bool]] = None,
+    tower_ops: Optional[M.LayerOps] = None,
 ) -> jax.Array:
     """tokens [B, S] -> logits [B, S, V].
+
+    ``patches`` [B, P, patch_dim] (a model with ``params["tower"]``; the
+    images of ``cfg.image_grids`` packed in order): the tower and the
+    projector run first (models/tower.py, its blocks rematerialized by
+    ``tower_remat_flags`` and its attention core ``tower_ops.sdpa``), and
+    their rows take the embedding's place where a token is
+    ``cfg.image_token_id``. Without ``patches`` the sequence is text.
 
     ``mtp_labels`` [B, S] (the token after each position; a model with
     ``params["mtp"]``): also run the further prediction depth
@@ -218,11 +235,18 @@ def forward_causal_lm(
                                scaling=cfg.rope_scaling)
     elif cfg.position_embedding_type == "rope":
         rope = rope_table(cfg, S, None, position_ids)
-    x = M.streams_in(M.apply_embedding(
+    x = M.apply_embedding(
         params["embed"], tokens, cfg, compute_dtype=compute_dtype,
         dropout_rng=M.fold_dropout_rng(dropout_rng, cfg,
                                        M.DROPOUT_STREAM_EMBED),
-        position_ids=position_ids), cfg)
+        position_ids=position_ids)
+    if patches is not None:
+        from hetu_galvatron_tpu.models.tower import apply_tower, place_images
+
+        x = place_images(x, tokens, apply_tower(
+            params["tower"], patches, cfg, compute_dtype=compute_dtype,
+            remat_flags=tower_remat_flags, ops=tower_ops), cfg)
+    x = M.streams_in(x, cfg)
     aux_total = jnp.zeros((), jnp.float32)
     moe_stats: Dict[str, Dict[str, jax.Array]] = {}
     kinds = cfg.block_kinds()
@@ -331,6 +355,8 @@ def causal_lm_loss(
     enc_boundary_fn: Optional[Callable[[int, jax.Array], jax.Array]] = None,
     fused_ce: Union[None, bool, Callable] = None,
     with_moe_stats: bool = False,
+    tower_remat_flags: Optional[Sequence[bool]] = None,
+    tower_ops: Optional[M.LayerOps] = None,
 ) -> jax.Array:
     """batch: tokens [B,S], labels [B,S], optional loss_mask [B,S] -> scalar
     (or (scalar, per-layer MoE stats dict) with ``with_moe_stats=True`` —
@@ -369,6 +395,8 @@ def causal_lm_loss(
         segment_ids=batch.get("segment_ids"),
         mrope_position_ids=batch.get("mrope_position_ids"),
         mtp_labels=batch["labels"] if mtp else None,
+        patches=batch.get("patches"), tower_remat_flags=tower_remat_flags,
+        tower_ops=tower_ops,
     )
     ce = M.cross_entropy_loss(logits, batch["labels"], batch.get("loss_mask"),
                               fused=fused)
@@ -408,4 +436,10 @@ def model_flops_per_token(cfg: ModelArgs, seq_len: Optional[int] = None) -> floa
         dense += 2 * nq * hd * h  # proj
         dense += 2 * 2 * span * nq * hd  # qk^T + pv per token
         dense += mlp
+    # a tower's work a sequence falls on the sequence's tokens
+    from hetu_galvatron_tpu.core.cost_model.cost import (
+        tower_flops_per_sequence,
+    )
+
+    dense += tower_flops_per_sequence(cfg) / s
     return 3.0 * dense  # fwd + bwd(2x)

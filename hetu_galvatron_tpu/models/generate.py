@@ -33,12 +33,17 @@ Params = Dict[str, Any]
 
 
 def _check_supported(cfg: ModelArgs, params: Params) -> None:
+    from hetu_galvatron_tpu.analysis.eligibility import tower_reason
+
     if cfg.post_norm or cfg.model_type == "bert":
         raise NotImplementedError("generate(): causal decoder families only")
     if cfg.model_type == "t5":
         raise NotImplementedError(
             "generate() is the causal-decoder path; use generate_encdec() "
             "for t5 (encoder once + cached cross-attention decode)")
+    reason = tower_reason(cfg, "generate()")
+    if reason is not None:
+        raise NotImplementedError(reason)
     if any("moe" in lp for lp in params["layers"]):
         raise NotImplementedError("generate(): dense layers only")
     from hetu_galvatron_tpu.analysis.eligibility import (

@@ -473,6 +473,10 @@ SCOPES: Tuple[str, ...] = (
     "attn/core", "attn/window_core", "attn/gate", "attn/out_proj",
     "attn/latent_proj", "hc/maps", "hc/mix",
     "mtp/embed_proj", "mtp/block", "mtp/head",
+    # (a tower's own before the names they end in: of two names that end at
+    # one place in a name stack the first listed is taken)
+    "tower/patch_embed", "tower/attn_proj", "tower/attention", "tower/mlp",
+    "tower/merge_project", "embed/place_images",
     "mlp", "head", "param_view",
     "grad/accumulate", "grad/clip", "optimizer/update",
     "moe/route", "moe/dispatch", "moe/experts", "moe/combine",

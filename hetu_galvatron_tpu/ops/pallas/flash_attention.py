@@ -1168,6 +1168,21 @@ def band_tiles(S: int, block_q: int, block_k: int,
 # is compiled (``cli/train_dist.py``, the gauge ``flash/band_tiles_pct``)
 WINDOWED_CALLS: "set[tuple[int, int, int, int, int]]" = set()
 
+
+def two_way_tiles(S: int, Sk: int, block_q: int, block_k: int) -> int:
+    """Score tiles the three kernels visit of one head of a call that is
+    not causal: every k chunk for every q tile (``_for_k_chunks``' first
+    branch and its mirror in the dk/dv kernel; ``_needed_k_major`` keeps
+    every major block), whatever the segments say: a tile in which no query
+    meets a key is computed and masked."""
+    return (S // block_q) * (Sk // block_k)
+
+
+# every call that is not causal, as the kernels were handed it: (q length,
+# k length, block_q, block_k). A launcher reads the pairs its tiles cover
+# (``cli/train_dist.py``, the gauge ``tower/pairs_tiled``)
+TWO_WAY_CALLS: "set[tuple[int, int, int, int]]" = set()
+
 # and every call, as the kernels were handed it (on a shard of a mesh: its
 # shard's): ("rows" | "transposed", q length, k length, query heads, key/value
 # heads, q/k width, v width, window). Which layout the kernels indexed is
@@ -1314,6 +1329,9 @@ def flash_sdpa(q, k, v, *, causal: bool = True, interpret: bool = False,
     if window is not None:
         WINDOWED_CALLS.add((S, q.shape[2], block_q or bq, block_k or bk,
                             window))
+    if not causal:
+        TWO_WAY_CALLS.add((S, Sk, min(block_q or bq, S),
+                           min(block_k or bk, Sk)))
     seed = None
     if dropout_rate > 0.0:
         if dropout_rng is None:
@@ -1388,6 +1406,8 @@ def make_flash_sdpa(mesh, dp_axes=(), tp_axes=(), *, interpret: bool = False,
         if window is not None:
             WINDOWED_CALLS.add((q.shape[s_dim], q.shape[s_dim + 1], bq, bk,
                                 window))
+        if not causal:
+            TWO_WAY_CALLS.add((q.shape[s_dim], k.shape[s_dim], bq, bk))
         seed = None
         if dropout_rate > 0.0:
             if dropout_rng is None:

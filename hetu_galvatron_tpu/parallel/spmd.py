@@ -77,6 +77,10 @@ def param_specs(
     if "mtp" in axes_tree:
         # the further prediction depth's block is sharded as the last block
         out["mtp"] = _spec_tree(axes_tree["mtp"], per_layer[-1], opt)
+    if "tower" in axes_tree:
+        # the tower in front of the decoder takes the first block's plan
+        # (whole on every device: eligibility.tower_plan_reason)
+        out["tower"] = _spec_tree(axes_tree["tower"], per_layer[0], opt)
     if "enc_layers" in axes_tree:
         enc = (enc_per_layer if enc_per_layer is not None
                else [per_layer[0]] * len(axes_tree["enc_layers"]))
@@ -613,6 +617,16 @@ def build_spmd_loss_fn(
     constrain_embed = make_embed_use_constraint(
         axes_tree["embed"], vocab, mesh)
     view_here = None if hoist_view else param_view
+    tower_kwargs = {}
+    if cfg.tower_layers:
+        # the tower's blocks run under the first decoder block's plan: its
+        # remat flag, and the attention core that plan gives a block that
+        # attends (the flash kernels on a TPU)
+        tower_kwargs = dict(
+            tower_remat_flags=[per_layer[0].checkpoint] * cfg.tower_layers,
+            tower_ops=({} if lane_dp else attention_overrides(
+                b_layers[:1], mesh, use_flash=use_flash,
+                flash_interpret=kernel_interpret)).get(0))
 
     def loss_fn(p, batch):
         if view_here is not None:
@@ -622,7 +636,8 @@ def build_spmd_loss_fn(
             p, batch, cfg, compute_dtype=compute_dtype,
             remat_flags=remat if any(remat) else None,
             layer_overrides=layer_overrides, boundary_fn=boundary,
-            fused_ce=fused_ce, with_moe_stats=with_moe_stats, **enc_kwargs)
+            fused_ce=fused_ce, with_moe_stats=with_moe_stats, **enc_kwargs,
+            **tower_kwargs)
 
     return (loss_fn, pspecs, batch_shd, per_layer, vocab, enc_per,
             param_view if hoist_view else None)

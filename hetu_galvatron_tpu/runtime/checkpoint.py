@@ -1038,6 +1038,79 @@ def _latent_rope_orders(cfg: ModelArgs, to_hf: bool) -> Dict[str, Any]:
     return {"wq_b" if cfg.q_lora_rank else "wq": q, "wkv_a": kv}
 
 
+# a model with a tower (Kimi-VL's ``KimiVLForConditionalGeneration``): the
+# decoder's names under ``language_model.``, the tower's under
+# ``vision_tower.`` and the projector's under ``multi_modal_projector.``
+_DECODER_PREFIX = "language_model."
+_TOWER_BLOCK = "vision_tower.encoder.blocks.{i}."
+# a tower block's leaves (models/tower.py) and their public stems
+_TOWER_BLOCK_HF = {"ln0": "norm0", "ln1": "norm1", "qkv": "wqkv",
+                   "out": "wo", "fc0": "mlp.fc0", "fc1": "mlp.fc1"}
+_TOWER_NORMS_HF = {"final_norm": "vision_tower.encoder.final_layernorm",
+                   "pre_norm": "multi_modal_projector.pre_norm"}
+_PROJECTOR_HF = {"fc1": "multi_modal_projector.linear_1",
+                 "fc2": "multi_modal_projector.linear_2"}
+_PATCH_HF = "vision_tower.patch_embed."
+
+
+def _tower_hf_leaves(cfg: ModelArgs):
+    """(path in ``params["tower"]``, public stem, kind) of every leaf pair:
+    ``norm`` = scale and bias, ``linear`` = w [in, out] and b."""
+    for i in range(cfg.tower_layers):
+        for leaf, stem in _TOWER_BLOCK_HF.items():
+            yield (("blocks", i, leaf), _TOWER_BLOCK.format(i=i) + stem,
+                   "norm" if leaf.startswith("ln") else "linear")
+    yield ("final_norm",), _TOWER_NORMS_HF["final_norm"], "norm"
+    yield ("projector", "pre_norm"), _TOWER_NORMS_HF["pre_norm"], "norm"
+    for leaf, stem in _PROJECTOR_HF.items():
+        yield ("projector", leaf), stem, "linear"
+
+
+def _tower_to_hf(tower: Params, cfg: ModelArgs) -> Dict[str, np.ndarray]:
+    get = lambda t: np.asarray(jax.device_get(t))
+    pe = tower["patch_embed"]
+    side = cfg.tower_patch_size
+    sd = {
+        # a patch's numbers channel-major, as a convolution's weight
+        # [out, channels, side, side] flattens
+        _PATCH_HF + "proj.weight": get(pe["w"]).T.reshape(
+            -1, cfg.tower_in_channels, side, side),
+        _PATCH_HF + "proj.bias": get(pe["b"]),
+        _PATCH_HF + "pos_emb.weight": get(pe["pos_emb"])}
+    for path, stem, kind in _tower_hf_leaves(cfg):
+        node = tower
+        for k in path:
+            node = node[k]
+        if kind == "norm":
+            sd[stem + ".weight"] = get(node["scale"])
+            sd[stem + ".bias"] = get(node["bias"])
+        else:
+            sd[stem + ".weight"] = get(node["w"]).T
+            sd[stem + ".bias"] = get(node["b"])
+    return sd
+
+
+def _tower_from_hf(sd: Dict[str, Any], cfg: ModelArgs) -> Params:
+    leaves: Dict[Any, Any] = {}
+    for path, stem, kind in _tower_hf_leaves(cfg):
+        leaves[path] = (
+            {"scale": sd[stem + ".weight"], "bias": sd[stem + ".bias"]}
+            if kind == "norm" else
+            {"w": sd[stem + ".weight"].T, "b": sd[stem + ".bias"]})
+    return {
+        "patch_embed": {
+            "w": sd[_PATCH_HF + "proj.weight"].reshape(
+                cfg.tower_hidden_size, -1).T,
+            "b": sd[_PATCH_HF + "proj.bias"],
+            "pos_emb": sd[_PATCH_HF + "pos_emb.weight"]},
+        "blocks": tuple({leaf: leaves[("blocks", i, leaf)]
+                         for leaf in _TOWER_BLOCK_HF}
+                        for i in range(cfg.tower_layers)),
+        "final_norm": leaves[("final_norm",)],
+        "projector": {leaf: leaves[("projector", leaf)]
+                      for leaf in ("pre_norm", *_PROJECTOR_HF)}}
+
+
 def _unknown_mixer(i: int, mixer: str) -> ValueError:
     from hetu_galvatron_tpu.models.modules import MIXERS
 
@@ -1058,6 +1131,9 @@ def hf_to_params(state_dict: Dict[str, Any], cfg: ModelArgs) -> Params:
 
     sd = {k: arr(v) for k, v in state_dict.items()}
     n = cfg.num_hidden_layers
+    if cfg.tower_layers:
+        # the decoder's names without their prefix; the tower's as they are
+        sd = {k.removeprefix(_DECODER_PREFIX): v for k, v in sd.items()}
     if cfg.model_type == "gpt" or "transformer.wte.weight" in sd:
         layers = []
         for i in range(n):
@@ -1247,6 +1323,8 @@ def hf_to_params(state_dict: Dict[str, Any], cfg: ModelArgs) -> Params:
             whead = np.concatenate(
                 [whead, np.zeros((whead.shape[0], pad), whead.dtype)], axis=1)
         out["head"] = {"whead": whead}
+    if cfg.tower_layers:
+        out["tower"] = _tower_from_hf(sd, cfg)
     return out
 
 
@@ -1672,4 +1750,7 @@ def params_to_hf(params: Params, cfg: ModelArgs) -> Dict[str, np.ndarray]:
     sd[names["final"]] = get(params["prenorm"]["scale"])
     if not cfg.tie_word_embeddings and params.get("head"):
         sd["lm_head.weight"] = get(params["head"]["whead"]).T[:V]
+    if "tower" in params:
+        sd = {_DECODER_PREFIX + k: v for k, v in sd.items()}
+        sd.update(_tower_to_hf(params["tower"], cfg))
     return sd

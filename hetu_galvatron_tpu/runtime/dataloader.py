@@ -20,7 +20,7 @@ program via shardings, not in the loader.
 
 from __future__ import annotations
 
-from typing import Dict, Iterator, Optional
+from typing import Dict, Iterator, List, Optional
 
 import numpy as np
 
@@ -114,6 +114,90 @@ def synthetic_batches(
         i += 1
 
 
+def image_layout(model: ModelArgs, spans: Optional[List[int]]
+                 ) -> np.ndarray:
+    """``[S + 1]`` bool: which tokens of one sample (``seq_length`` and the
+    one more the label shift reads) are image positions. Text span, image,
+    text span, ..., image, text span: ``spans`` (``data.image_text_spans``)
+    are the text spans' lengths, one more than the images, and sum, with
+    the images' merged patches, to ``seq_length``; the token after the
+    sequence is text."""
+    mh, mw = model.tower_merge_kernel
+    rows = [n // (mh * mw) for n in model.image_patches]
+    text = model.seq_length - sum(rows)
+    if (spans is None or len(spans) != len(rows) + 1 or sum(spans) != text
+            or min(spans) < 0):
+        raise ValueError(
+            f"data.image_text_spans {spans}: {len(rows) + 1} text spans "
+            f"that sum to {text} = model.seq_length {model.seq_length} less "
+            f"the images' {sum(rows)} positions")
+    parts = []
+    for span, n in zip(spans, rows + [1]):
+        parts += [np.zeros(span, bool), np.ones(n, bool)]
+    layout = np.concatenate(parts)
+    layout[-1] = False   # the last "image" is the one text token after S
+    return layout
+
+
+def image_text_batches(model: ModelArgs, global_batch_size: int, *,
+                       spans: Optional[List[int]], seed: int = 1234
+                       ) -> Iterator[Dict[str, np.ndarray]]:
+    """Infinite iterator of global batches of a model with a tower: beside
+    ``tokens`` and ``labels``, ``patches`` [B, P, patch_dim] (every image of
+    ``model.image_grids`` packed in order, seeded N(0, 1) pixel values),
+    ``patch_grids`` [B, images, 2] and a ``loss_mask`` with zeros where the
+    LABEL is an image position (``model.image_token_id``; the projector's
+    rows are no targets). Text ids are uniform over the other rows of the
+    vocabulary. Every field leads with the batch's rows, so the microbatch
+    split and the host-to-device copy take them as they take ``tokens``."""
+    from hetu_galvatron_tpu.observability.tracing import span
+
+    image = image_layout(model, spans)
+    grids = np.asarray(model.image_grids, np.int32)
+    marker, patches = model.image_token_id, sum(model.image_patches)
+    rng = np.random.default_rng(seed)
+    B = global_batch_size
+    while True:
+        with span("train/data/images"):
+            pixels = rng.standard_normal(
+                (B, patches, model.tower_patch_dim), dtype=np.float32)
+        ids = rng.integers(0, model.vocab_size - 1, (B, image.size),
+                           dtype=np.int32)
+        ids += ids >= marker   # every row of the vocabulary but the marker
+        sample = np.where(image[None, :], np.int32(marker), ids)
+        batch = make_batch(sample)
+        batch["loss_mask"] = (batch["labels"] != marker).astype(np.float32)
+        batch["patches"] = pixels
+        batch["patch_grids"] = np.broadcast_to(
+            grids, (B,) + grids.shape).copy()
+        yield batch
+
+
+def one_ahead(it: Iterator) -> Iterator:
+    """``it`` made one item ahead on a thread of its own: the next batch is
+    drawn while the device runs the step (the loop waits for the step's
+    loss before it asks for data, and ``numpy``'s generators release the
+    interpreter). The items and their order are ``it``'s."""
+    import queue
+    import threading
+
+    box: "queue.Queue" = queue.Queue(maxsize=1)
+
+    def fill():
+        try:
+            for item in it:
+                box.put((item, None))
+        except BaseException as e:  # noqa: BLE001 — raised where it is read
+            box.put((None, e))
+
+    threading.Thread(target=fill, daemon=True).start()
+    while True:
+        item, err = box.get()
+        if err is not None:
+            raise err
+        yield item
+
+
 def skip_batches(it: Iterator, n: int) -> None:
     """Fast-forward ``n`` global batches — the full-state-resume replay of
     the data stream. Replaying (rather than seeking) keeps every stateful
@@ -182,7 +266,13 @@ def get_data_iterator(
     data: DataArgs = args.data
     meta: Dict = {}
     split_idx = _SPLIT_INDEX[split]
-    if data.dataset == "random":
+    if data.dataset == "random" and args.model.image_grids:
+        # a model with a tower and a traffic that names images: patches
+        # beside ids (a model with a tower and no images reads text alone)
+        it = one_ahead(image_text_batches(
+            args.model, gbs, spans=data.image_text_spans,
+            seed=args.train.seed + 101 * split_idx))
+    elif data.dataset == "random":
         it = synthetic_batches(args.model, gbs,
                                seed=args.train.seed + 101 * split_idx)
     elif data.dataset == "indexed":

@@ -207,6 +207,7 @@ def get_hybrid_parallel_config(
             eligibility.latent_plan_reason(args.model, layers),
             eligibility.kda_plan_reason(args.model, layers),
             eligibility.window_plan_reason(args.model, layers),
+            eligibility.tower_plan_reason(args.model, layers, pp_deg),
             eligibility.ep_divides_reason(args.model, layers),
             (eligibility.residual_streams_reason(
                 args.model, f"a pipelined plan (pp={pp_deg})")

@@ -126,13 +126,16 @@ class PipelineEngine:
         from hetu_galvatron_tpu.analysis.eligibility import (
             mixed_stack_reason,
             residual_streams_reason,
+            tower_reason,
         )
 
         reason = mixed_stack_reason(
             cfg, "the host pipeline engine (its stage programs tell dense "
             "and expert blocks apart by their trees and attend in every "
             "block)", feed_forward_may_differ=True
-        ) or residual_streams_reason(cfg, "the host pipeline engine")
+        ) or residual_streams_reason(
+            cfg, "the host pipeline engine") or tower_reason(
+            cfg, "the host pipeline engine")
         if reason is not None:
             raise NotImplementedError(reason + "; run it at pp_deg=1")
         self.cfg = cfg

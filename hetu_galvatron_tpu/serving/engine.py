@@ -100,10 +100,15 @@ class WeightSwapError(ValueError):
 
 
 def _check_supported(cfg: ModelArgs, params: Params) -> None:
+    from hetu_galvatron_tpu.analysis.eligibility import tower_reason
+
     if cfg.post_norm or cfg.model_type in ("bert", "t5"):
         raise NotImplementedError(
             "ServingEngine serves dense causal decoder families; bert/t5 "
             "have no paged decode path")
+    reason = tower_reason(cfg, "ServingEngine")
+    if reason is not None:
+        raise NotImplementedError(reason)
     if any("moe" in lp for lp in params["layers"]):
         raise NotImplementedError("ServingEngine: dense layers only")
     from hetu_galvatron_tpu.analysis.eligibility import (

@@ -42,6 +42,11 @@ _XING = "xing4_0"
 # Kimi Linear (moonshotai; ``modeling_kimi.py``): Kimi Delta Attention and
 # latent-attention blocks by number, DeepSeek-V3's expert layer
 _KIMI_LINEAR = "kimi_linear"
+# Kimi-VL (moonshotai; ``KimiVLConfig``): ``text_config`` holds
+# DeepseekV3Config's keys (latent attention without a low-rank query, shared
+# beside sigmoid-routed experts), ``vision_config`` MoonViTConfig's (the
+# tower of image patches in front of it, models/tower.py)
+_KIMI_VL = "kimi_vl"
 # Laguna (poolside; ``LagunaConfig``): window and full attention blocks by
 # ``layer_types`` with query heads, a rotation and a gate a head of their
 # own, a dense block then softmax-free top-k experts beside a shared one
@@ -54,12 +59,13 @@ _MELLUM = "mellum"
 # families that state for themselves whether they have positions
 _OWN_POSITIONS = {_GRANITE_HYBRID, _KIMI_LINEAR}
 _ROPE_FAMILIES = {"llama", "qwen2", "mistral", "mixtral", "olmoe",
-                  "qwen", _XING, _LAGUNA, _MELLUM} | _GEMMA_FAMILIES \
+                  "qwen", _XING, _LAGUNA, _MELLUM, _KIMI_VL} \
+    | _GEMMA_FAMILIES \
     | _LFM2_FAMILIES
 _RMS_FAMILIES = _ROPE_FAMILIES | {"t5", _GRANITE_HYBRID, _KIMI_LINEAR}
 _SWIGLU_FAMILIES = {"llama", "qwen2", "mistral", "mixtral", "olmoe",
                     "qwen", _GRANITE_HYBRID, _XING, _KIMI_LINEAR,
-                    _LAGUNA, _MELLUM} | _LFM2_FAMILIES
+                    _LAGUNA, _MELLUM, _KIMI_VL} | _LFM2_FAMILIES
 # gemma-2/3 add sandwich norms (a norm after each sub-layer as well as
 # before it), logit softcapping (attention and final) and a softmax scale
 # from query_pre_attn_scalar; v3 also a q/k norm a head with zero-centred
@@ -92,6 +98,10 @@ def populate_model_args_from_hf(
             "query_pre_attn_scalar; its sliding windows are not the "
             "obstacle: layer_types with sliding_attention blocks are run); "
             "refusing rather than producing silently-wrong numerics")
+    if family == _KIMI_VL:
+        # the decoder's keys are a group of their own; read them where the
+        # other families' lie, the tower's group beside them
+        d = {**d, **_cfg_to_dict(d["text_config"]), "model_type": family}
     values: Dict[str, Any] = dict(base.model_dump() if base else {})
     for ours, theirs in _FIELD_MAP.items():
         for key in theirs:
@@ -178,6 +188,8 @@ def populate_model_args_from_hf(
         values.update(_xing_values(d))
     if family == _KIMI_LINEAR:
         values.update(_kimi_linear_values(d))
+    if family == _KIMI_VL:
+        values.update(_kimi_vl_values(d))
     if family == _LAGUNA:
         values.update(_laguna_values(d))
     if family == _MELLUM:
@@ -250,7 +262,8 @@ def _xing_values(d: Dict[str, Any]) -> Dict[str, Any]:
         model_type="moe", hf_layout="llama", moe_hf_layout="deepseek",
         layer_types=["latent_attention"] * n,
         num_dense_layers=int(d.get("first_k_dense_replace", 0)),
-        q_lora_rank=int(d["q_lora_rank"]),
+        q_lora_rank=(None if d.get("q_lora_rank") is None
+                     else int(d["q_lora_rank"])),
         kv_lora_rank=int(d["kv_lora_rank"]),
         qk_nope_head_dim=int(d["qk_nope_head_dim"]),
         qk_rope_head_dim=int(d["qk_rope_head_dim"]),
@@ -272,6 +285,29 @@ def _xing_values(d: Dict[str, Any]) -> Dict[str, Any]:
         hc_res_clamp_min=float(d.get("mhc_h_res_clamp_min", -30.0)),
         hc_res_clamp_max=float(d.get("mhc_h_res_clamp_max", 30.0)),
         num_nextn_predict_layers=int(d.get("num_nextn_predict_layers", 0)))
+
+
+def _kimi_vl_values(d: Dict[str, Any]) -> Dict[str, Any]:
+    """Kimi-VL (``KimiVLConfig``): the decoder is DeepSeek-V3's
+    (:func:`_xing_values` with one residual stream and no further depth),
+    the tower ``vision_config``'s (``MoonViTConfig``: ``num_hidden_layers``
+    blocks of ``hidden_size``, a ``patch_size`` square of 3 channels a
+    patch, a position table of ``init_pos_emb_height x
+    init_pos_emb_width``, ``merge_kernel_size``), and
+    ``media_placeholder_token_id`` marks an image position. A key the
+    file does not hold raises: no size of the tower is filled in here."""
+    v = _cfg_to_dict(d["vision_config"])
+    return dict(
+        _xing_values(d),
+        tower_layers=int(v["num_hidden_layers"]),
+        tower_hidden_size=int(v["hidden_size"]),
+        tower_num_heads=int(v["num_attention_heads"]),
+        tower_ffn_hidden_size=int(v["intermediate_size"]),
+        tower_patch_size=int(v["patch_size"]),
+        tower_pos_emb_height=int(v["init_pos_emb_height"]),
+        tower_pos_emb_width=int(v["init_pos_emb_width"]),
+        tower_merge_kernel=list(v["merge_kernel_size"]),
+        image_token_id=int(d["media_placeholder_token_id"]))
 
 
 def _laguna_values(d: Dict[str, Any]) -> Dict[str, Any]:

@@ -79,7 +79,9 @@ MOE_ONLY = {"moe_imbalance": ("moe",),
             "kimi_moe_imbalance": ("lfm2",),
             "kimi_local_routes_pct": ("lfm2",),
             "laguna_moe_imbalance": ("lfm2",),
-            "laguna_local_routes_pct": ("lfm2",)}
+            "laguna_local_routes_pct": ("lfm2",),
+            "kimivl_moe_imbalance": ("lfm2",),
+            "kimivl_local_routes_pct": ("lfm2",)}
 # the named scopes the HLO-metadata join will look for (PERF.md section 7)
 SCOPES = ("mixer/short_conv/in_proj", "mixer/short_conv/gate_conv",
           "mixer/short_conv/out_proj", "attn/qk_norm", "moe/route",
