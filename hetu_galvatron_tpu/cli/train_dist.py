@@ -51,6 +51,29 @@ def _flight_dump_elastic(args, reason: str, live_world: int,
     return rec.dump(kind)
 
 
+def tower_pairs_tiled(cfg, use_flash: bool) -> int:
+    """The pairs the tower's cores compute, a sequence, block and head: the
+    tiles the flash kernels' loops visit in the calls that are not causal,
+    by the lengths and tiles those calls were built with
+    (``flash_attention.TWO_WAY_CALLS``) and, for the calls over a sequence's
+    patches, by the ids the tower hands them (static: the traffic's grids),
+    which bound those loops; the XLA core makes the square of a sequence's
+    patches."""
+    from hetu_galvatron_tpu.models.tower import grids_of, image_of_patch
+    from hetu_galvatron_tpu.ops.pallas.flash_attention import (
+        TWO_WAY_CALLS,
+        two_way_tiles,
+    )
+
+    if not use_flash:
+        return sum(cfg.image_patches) ** 2
+    images = image_of_patch(grids_of(cfg))
+    return sum(
+        two_way_tiles(S, Sk, bq, bk,
+                      images if S == Sk == len(images) else None) * bq * bk
+        for S, Sk, bq, bk in TWO_WAY_CALLS)
+
+
 def train(args) -> Dict[str, Any]:
     from hetu_galvatron_tpu.observability.tracing import span
 
@@ -189,7 +212,6 @@ def train(args) -> Dict[str, Any]:
             WINDOWED_CALLS,
             band_tiles,
             effective_window,
-            two_way_tiles,
         )
 
         kinds = cfg.block_kinds(len(hpc.layers))
@@ -1357,16 +1379,8 @@ def train(args) -> Dict[str, Any]:
                         get_registry().gauge("flash/band_tiles_pct").set(
                             step_report["band_tiles_pct"])
                     if tower_report:
-                        # the pairs the tower's cores compute, a block and
-                        # head: the tiles the flash kernels' loops visit in
-                        # the calls that are not causal, by the lengths and
-                        # tiles those calls were built with
-                        # (``flash_attention.TWO_WAY_CALLS``); the XLA core
-                        # makes the square of a sequence's patches
-                        tower_report["pairs_tiled"] = hpc.global_bsz * (
-                            sum(two_way_tiles(*c) * c[2] * c[3]
-                                for c in TWO_WAY_CALLS)
-                            if use_flash else sum(cfg.image_patches) ** 2)
+                        tower_report["pairs_tiled"] = (
+                            hpc.global_bsz * tower_pairs_tiled(cfg, use_flash))
                         get_registry().gauge("tower/pairs_tiled").set(
                             tower_report["pairs_tiled"])
                     # the distinct flash calls the step was built with, by

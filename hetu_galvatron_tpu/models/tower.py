@@ -118,8 +118,9 @@ def pairs_masked(grids: Grids) -> int:
     """The (query, key) pairs one sequence's tower attention leaves, a
     block and head: a patch meets its own image both ways. (What its core
     computes for them is the core's to say: the flash kernels' tiles by the
-    calls as built, ``flash_attention.TWO_WAY_CALLS``; the XLA core makes
-    the whole square of the packed patches.)"""
+    calls as built, ``flash_attention.TWO_WAY_CALLS``, and the chunk ranges
+    ``image_of_patch``'s ids leave their loops; the XLA core makes the
+    whole square of the packed patches.)"""
     return sum((h * w) ** 2 for h, w in grids)
 
 
@@ -280,7 +281,9 @@ def apply_tower_block(p: Params, x: jax.Array, cfg: ModelArgs, *,
         # tile, so the flash kernels run head-major between transposes);
         # padded to 128 lanes at the call they index the rows as they lie
         # and the step is 25 ms SLOWER (432.0 against 407.3 ms on the v5e,
-        # the forward kernel 69.8 against 49.1: PERF.md section 6, PR 59)
+        # the forward kernel 69.8 against 49.1: PERF.md section 6, PR 59).
+        # The images' ids bound the kernels' loops: a tile pair in which no
+        # patch meets one of its own image is neither copied nor computed
         o = sdpa(q, k, v, causal=False, segment_ids=segments)
     with jax.named_scope("tower/attn_proj"):
         x = x + _dense(p["out"], o.reshape(B, P, C), compute_dtype)

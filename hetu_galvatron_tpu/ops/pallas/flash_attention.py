@@ -46,7 +46,16 @@ image: a k/v tile stays, q / dO / lse / delta come as a major block and the
 loop runs over q chunks from the diagonal down; its score tiles are [k, q]
 (keys along sublanes), so that p^T.dO and ds^T.q are plain products and lse
 and delta broadcast along sublanes from (1, block_q) rows. Segment and
-dropout masks are applied on every tile they are given for.
+dropout masks are applied on every tile they are given for, and segment
+ids bound the loops as the diagonal and a window do: from the ids' least
+and greatest value a tile and a chunk (``segment_chunk_ranges``, a few
+reductions outside the kernels) every q tile has a range of k chunks and
+every k tile one of q chunks outside which no query shares an id with a
+key; the ranges ride in as prefetched scalars, the loops run over their
+intersection with the causal or banded range, and the index maps clamp the
+major block to it, so that a tile pair the segments empty (the images of a
+tower's packed patches, packed documents) is neither copied nor computed.
+A call without ids is the ``pallas_call`` it always was.
 
 Arithmetic: matmul operands stay in the inputs' dtype with f32 accumulation
 (``preferred_element_type``); scores, the softmax statistics, exp, lse,
@@ -233,44 +242,107 @@ def _for_banded(chunk, lo, chunks: int, first, clear_from, clear_to, end):
     _for(c, d, lambda i: chunk(i, True))
 
 
+def segment_chunk_ranges(segments, rows_block: int, cols_block: int):
+    """``(first, end)``, int32 ``[..., S // rows_block]``: the chunks of
+    ``cols_block`` positions that each tile of ``rows_block`` positions of
+    the segment ids ``[..., S]`` can meet, as the range ``[first, end)``
+    that a kernel loop runs over. A chunk is needed by a tile iff the closed
+    intervals of their least and greatest ids overlap, and the range spans
+    the first needed chunk to the last. An id that a query of the tile
+    shares with a key of the chunk lies in both intervals, so for ANY ids
+    the range holds every chunk in which ``_scores``' segment mask leaves a
+    pair (a chunk outside it would be masked whole); for ids that do not
+    decrease along the sequence (images one after the other, packed
+    documents) it is exactly those. A tile's own positions lie in chunks it
+    needs, so no range is empty. Array methods only: a NumPy row (a
+    launcher's count, ``two_way_tiles``) and a traced array (the kernels'
+    bounds) go the same way."""
+    tiles = segments.reshape(*segments.shape[:-1], -1, rows_block)
+    chunks = segments.reshape(*segments.shape[:-1], -1, cols_block)
+    tile_lo, tile_hi = tiles.min(-1)[..., None], tiles.max(-1)[..., None]
+    chunk_lo = chunks.min(-1)[..., None, :]
+    chunk_hi = chunks.max(-1)[..., None, :]
+    needed = (tile_lo <= chunk_hi) & (chunk_lo <= tile_hi)
+    first = needed.argmax(-1)
+    end = needed.shape[-1] - needed[..., ::-1].argmax(-1)
+    return first.astype("int32"), end.astype("int32")
+
+
+def _for_two_way(chunk, lo, chunks: int, seg):
+    """``chunk(c, False)`` over the ``chunks`` chunks from global chunk
+    ``lo`` on of a call that is not causal: all of them, or those of them
+    in ``seg``'s ``[first, end)``."""
+    if seg is None:
+        _for(0, chunks, lambda c: chunk(c, False))
+    else:
+        _for(jnp.clip(seg[0] - lo, 0, chunks),
+             jnp.clip(seg[1] - lo, 0, chunks), lambda c: chunk(c, False))
+
+
 def _for_k_chunks(chunk, q0, block_q: int, block_k: int, lo, chunks: int,
-                  causal: bool, window: "int | None" = None):
+                  causal: bool, window: "int | None" = None, seg=None):
     """``chunk(c, masked)`` over those of the ``chunks`` k chunks from global
     chunk ``lo`` on that the q rows [q0, q0 + block_q) see: first the ones
     wholly at or below the diagonal, without the mask, then the ones that
     cross it, with it; all of them, unmasked, when not causal. With a
     ``window`` the range starts at the chunk that holds the oldest key the
     tile's first query meets, and the chunks the band's lower edge cuts are
-    masked as well."""
+    masked as well. ``seg``, the tile's ``(first, end)`` of
+    ``segment_chunk_ranges``, bounds every one of these ranges: a chunk
+    whose ids none of the tile's queries can share is not visited (the
+    chunks of the tile's own positions always are, so the diagonal's end
+    stands)."""
     if not causal:
-        _for(0, chunks, lambda c: chunk(c, False))
+        _for_two_way(chunk, lo, chunks, seg)
         return
     if window is not None:
-        _for_banded(
-            chunk, lo, chunks,
-            jnp.maximum(q0 - window + 1, 0) // block_k,
-            # the first chunk the tile's LAST query still holds whole
-            (jnp.maximum(q0 + block_q - window, 0) + block_k - 1) // block_k,
-            (q0 + 1) // block_k, (q0 + block_q - 1) // block_k + 1)
+        first = jnp.maximum(q0 - window + 1, 0) // block_k
+        # the first chunk the tile's LAST query still holds whole
+        clear_from = ((jnp.maximum(q0 + block_q - window, 0) + block_k - 1)
+                      // block_k)
+        clear_to = (q0 + 1) // block_k
+        end = (q0 + block_q - 1) // block_k + 1
+        if seg is not None:
+            first, end = jnp.maximum(first, seg[0]), jnp.minimum(end, seg[1])
+        _for_banded(chunk, lo, chunks, first, clear_from, clear_to, end)
         return
     full = jnp.clip((q0 + 1) // block_k - lo, 0, chunks)
     some = jnp.clip((q0 + block_q - 1) // block_k + 1 - lo, 0, chunks)
-    _for(0, full, lambda c: chunk(c, False))
+    first = 0
+    if seg is not None:
+        first = jnp.clip(seg[0] - lo, 0, chunks)
+        full = jnp.maximum(full, first)
+    _for(first, full, lambda c: chunk(c, False))
     _for(full, some, lambda c: chunk(c, True))
 
 
+def _held_to(j, lo, hi, seg):
+    """Major block index ``j`` held to ``[lo, hi]`` (None: no bound on that
+    side) and to ``seg``, a tile's ``(first, end)`` of
+    ``segment_chunk_ranges`` in major blocks (None: no segments): a block
+    outside repeats the nearest inside, and an unchanged block index elides
+    the copy."""
+    if seg is not None:
+        lo = seg[0] if lo is None else jnp.maximum(lo, seg[0])
+        hi = seg[1] - 1 if hi is None else jnp.minimum(hi, seg[1] - 1)
+    if lo is None:
+        return j if hi is None else jnp.minimum(j, hi)
+    return jnp.maximum(j, lo) if hi is None else jnp.clip(j, lo, hi)
+
+
 def _needed_k_major(qi, kj, block_q: int, major: int, causal: bool,
-                    window: "int | None" = None):
+                    window: "int | None" = None, seg=None):
     """Index map of a k major block: one wholly past the diagonal repeats
     the last that is needed, and an unchanged block index elides the copy;
-    with a ``window`` one wholly before the band repeats the first."""
+    with a ``window`` one wholly before the band repeats the first. ``seg``,
+    the q tile's range in major blocks, clamps the same way: a major block
+    none of whose chunks the segments leave the tile is not copied."""
+    lo = hi = None
     if causal and window is not None:
-        return jnp.clip(
-            kj, jnp.maximum(qi * block_q - window + 1, 0) // major,
-            (qi * block_q + block_q - 1) // major)
+        lo = jnp.maximum(qi * block_q - window + 1, 0) // major
     if causal:
-        return jnp.minimum(kj, (qi * block_q + block_q - 1) // major)
-    return kj
+        hi = (qi * block_q + block_q - 1) // major
+    return _held_to(kj, lo, hi, seg)
 
 
 def _tile(ref, rows=slice(None)):
@@ -382,8 +454,42 @@ def _of(ref, i: int, pair):
     return ref if pair is None else ref.at[i]
 
 
-def _flash_kernel(q_ref, k_ref, v_ref, *rest,
-                  block_q: int, block_k: int, chunks: int, num_major: int,
+def _seg_range(refs, has_seg: bool):
+    """``((first, end), the other refs)`` of a kernel with segments: its
+    two leading refs are the prefetched scalars, ``segment_chunk_ranges`` of
+    every (batch row, tile) flat, and the grid step's tile is the grid's
+    third axis in all three kernels. ``(None, refs)`` without segments,
+    whose call has no such operand. ``program_id``: call at kernel top
+    level."""
+    if not has_seg:
+        return None, refs
+    first_ref, end_ref, *refs = refs
+    at = pl.program_id(0) * pl.num_programs(2) + pl.program_id(2)
+    return (first_ref[at], end_ref[at]), refs
+
+
+def _major_range(bounds, at, chunks: int):
+    """An index map's ``(first, end)`` in major blocks of ``chunks`` chunks
+    for tile ``at`` (flat over batch rows) from the prefetched scalar refs
+    it is handed after the grid's indices; None where there are none."""
+    if not bounds:
+        return None
+    first_ref, end_ref = bounds
+    return first_ref[at] // chunks, (end_ref[at] - 1) // chunks + 1
+
+
+def _grid(bounds, **spec):
+    """``pallas_call``'s grid arguments: ``spec`` as it is for a call
+    without segments; with them a scalar-prefetch grid whose scalar
+    operands, first in the call, are the ``bounds``."""
+    if bounds is None:
+        return spec
+    return {"grid_spec": pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=len(bounds), **spec)}
+
+
+def _flash_kernel(*refs, block_q: int, block_k: int, chunks: int,
+                  num_major: int,
                   causal: bool, scale: float, has_seg: bool = False,
                   dropout_rate: float = 0.0, window: "int | None" = None,
                   pair: "_Pairs | None" = None):
@@ -395,7 +501,10 @@ def _flash_kernel(q_ref, k_ref, v_ref, *rest,
     and a step serves both: each head's query view (zeros in the other
     head's lanes, so the 128-deep contraction adds exact zeros) is made
     once a q tile, and each head has statistics and an accumulator of its
-    own, whose half under its key/value head is the result."""
+    own, whose half under its key/value head is the result. With segments
+    the loop's range is cut to the chunks the q tile's ids can meet
+    (``_seg_range``)."""
+    seg, (q_ref, k_ref, v_ref, *rest) = _seg_range(refs, has_seg)
     if dropout_rate > 0.0:
         seed_ref, rest = rest[0], rest[1:]
     else:
@@ -462,7 +571,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, *rest,
             acc_i[...] = (acc_i[...] * _across(corr, acc_i.shape[1])
                           + _dot(p.astype(v.dtype), v, _NN))
 
-    _for_k_chunks(chunk, q0, block_q, block_k, lo, chunks, causal, window)
+    _for_k_chunks(chunk, q0, block_q, block_k, lo, chunks, causal, window,
+                  seg)
 
     @pl.when(kj == num_major - 1)
     def _finalize():
@@ -526,6 +636,16 @@ def _segment_operands(segments, block: int):
     along lanes in chunks of ``block``."""
     seg = segments.astype(jnp.int32)
     return seg[:, :, None], _chunk_rows(seg, block)
+
+
+def _segment_bounds(segments, rows_block: int, cols_block: int):
+    """``segment_chunk_ranges`` as the scalar operands a call with segments
+    prefetches: (first, end), each flat over (batch row, tile); None
+    without segments."""
+    if segments is None:
+        return None
+    return tuple(x.reshape(-1) for x in segment_chunk_ranges(
+        segments.astype(jnp.int32), rows_block, cols_block))
 
 
 def row_layout(N: int, K: int, D: int, Dv: int) -> "int | None":
@@ -594,15 +714,20 @@ def _forward_call(q, k, v, segments, dropout_seed, *, heads, causal, block_q,
         scale=1.0 / math.sqrt(D) if scale is None else scale,
         has_seg=has_seg, dropout_rate=dropout_rate, window=window, pair=pair)
     block = functools.partial(_block, heads is not None)
+    # with segments: each q tile's range of k chunks, prefetched scalars
+    # that every index map is handed after the grid's indices
+    bounds = _segment_bounds(segments, block_q, block_k)
 
-    def kj_of(qi, kj):
-        return _needed_k_major(qi, kj, block_q, major, causal, window)
+    def kj_of(b, qi, kj, bounds):
+        return _needed_k_major(
+            qi, kj, block_q, major, causal, window,
+            _major_range(bounds, b * (S // block_q) + qi, chunks))
 
-    def q_tile(b, n, qi, kj):
+    def q_tile(b, n, qi, kj, *bounds):
         return b, n, qi
 
-    def kv_rows(b, n, qi, kj):
-        return b, _kv_col(n, G, pair), kj_of(qi, kj)
+    def kv_rows(b, n, qi, kj, *bounds):
+        return b, _kv_col(n, G, pair), kj_of(b, qi, kj, bounds)
 
     in_specs = [block(block_q, D * per, q_tile),
                 block(major, D * per, kv_rows),
@@ -614,35 +739,39 @@ def _forward_call(q, k, v, segments, dropout_seed, *, heads, causal, block_q,
         operands.append(dropout_seed.astype(jnp.int32).reshape(1))
     if has_seg:
         in_specs += [
-            pl.BlockSpec((1, block_q, 1), lambda b, n, qi, kj: (b, qi, 0)),
+            pl.BlockSpec((1, block_q, 1),
+                         lambda b, n, qi, kj, *bounds: (b, qi, 0)),
             pl.BlockSpec((1, chunks, 1, block_k),
-                         lambda b, n, qi, kj: (b, kj_of(qi, kj), 0, 0)),
+                         lambda b, n, qi, kj, *bounds: (
+                             b, kj_of(b, qi, kj, bounds), 0, 0)),
         ]
-        operands += list(_segment_operands(segments, block_k))
+        operands = [*bounds, *operands, *_segment_operands(segments, block_k)]
     # statistics and accumulator of each head of a block, and of a pair the
     # two query views
     of_head = () if pair is None else (per,)
     return pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=[
-            block(block_q, Dv * per, q_tile),
-            pl.BlockSpec((1, per, block_q, 1),
-                         lambda b, n, qi, kj: (b, n, qi, 0)),
-        ],
+        **_grid(
+            bounds,
+            grid=grid,
+            in_specs=in_specs,
+            out_specs=[
+                block(block_q, Dv * per, q_tile),
+                pl.BlockSpec((1, per, block_q, 1),
+                             lambda b, n, qi, kj, *bounds: (b, n, qi, 0)),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((*of_head, block_q, LANES), jnp.float32),
+                pltpu.VMEM((*of_head, block_q, LANES), jnp.float32),
+                pltpu.VMEM((*of_head, block_q, Dv * per), jnp.float32),
+            ] + ([] if pair is None else [
+                pltpu.VMEM((per, block_q, LANES), q.dtype)])),
         out_shape=[
             jax.ShapeDtypeStruct(
                 (B, N, S, Dv) if heads is None else (B, S, N * Dv), q.dtype),
             # (an odd count of paired heads has the statistics of one more)
             jax.ShapeDtypeStruct((B, cols * per, S, 1), jnp.float32),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((*of_head, block_q, LANES), jnp.float32),
-            pltpu.VMEM((*of_head, block_q, LANES), jnp.float32),
-            pltpu.VMEM((*of_head, block_q, Dv * per), jnp.float32),
-        ] + ([] if pair is None else [
-            pltpu.VMEM((per, block_q, LANES), q.dtype)]),
         # only the k axis carries loop state (the online softmax);
         # everything else may be reordered/partitioned by Mosaic
         compiler_params=pltpu.CompilerParams(
@@ -725,8 +854,7 @@ def _p_and_ds(q, k, v, do, lse, delta, q0, k0, qseg, kseg, seed_ref, bn, *,
     return pd, p * (dp - delta) * scale
 
 
-def _flash_bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                           *rest, block_q: int, block_k: int, chunks: int,
+def _flash_bwd_dkdv_kernel(*refs, block_q: int, block_k: int, chunks: int,
                            num_major: int, G: int, causal: bool,
                            scale: float, has_seg: bool = False,
                            dropout_rate: float = 0.0,
@@ -741,7 +869,10 @@ def _flash_bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     the second axis runs over pairs of key/value heads and the fourth over
     the G query pairs of theirs: each query head's q and dO chunk is viewed
     at its key/value head's half, so ``p^T.dO`` and ``ds^T.q`` land there
-    in the one accumulator and add zeros to the other half."""
+    in the one accumulator and add zeros to the other half. With segments
+    the loop's range is cut to the q chunks the k tile's ids can meet."""
+    seg, (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest) = (
+        _seg_range(refs, has_seg))
     if dropout_rate > 0.0:
         seed_ref, rest = rest[0], rest[1:]
     else:
@@ -797,21 +928,29 @@ def _flash_bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         # the tile's last key; clear of the diagonal from the chunk below
         # it, and of the band's edge while the chunk's last query still
         # meets the tile's first key
-        _for_banded(
-            chunk, lo, chunks, k0 // block_q,
-            (k0 + block_k + block_q - 2) // block_q,
-            (k0 + window) // block_q,
-            (k0 + block_k + window - 2) // block_q + 1)
+        first = k0 // block_q
+        clear_from = (k0 + block_k + block_q - 2) // block_q
+        clear_to = (k0 + window) // block_q
+        end = (k0 + block_k + window - 2) // block_q + 1
+        if seg is not None:
+            first, end = jnp.maximum(first, seg[0]), jnp.minimum(end, seg[1])
+        _for_banded(chunk, lo, chunks, first, clear_from, clear_to, end)
     elif causal:
-        # q chunks from the first with a visible row; those from ``clear``
-        # on lie wholly at or below the diagonal
+        # q chunks from the first with a visible row (the tile's own
+        # positions': no segment moves it); those from ``clear`` on lie
+        # wholly at or below the diagonal, up to the last the segments
+        # leave
         first = jnp.clip(k0 // block_q - lo, 0, chunks)
         clear = jnp.clip((k0 + block_k + block_q - 2) // block_q - lo,
                          0, chunks)
+        end = chunks
+        if seg is not None:
+            end = jnp.clip(seg[1] - lo, 0, chunks)
+            clear = jnp.minimum(clear, end)
         _for(first, clear, lambda c: chunk(c, True))
-        _for(clear, chunks, lambda c: chunk(c, False))
+        _for(clear, end, lambda c: chunk(c, False))
     else:
-        _for(0, chunks, lambda c: chunk(c, False))
+        _for_two_way(chunk, lo, chunks, seg)
 
     @pl.when((g == G - 1) & (qj == num_major - 1))
     def _finalize():
@@ -819,8 +958,7 @@ def _flash_bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         _put(dv_ref, dv_acc[...].astype(dv_ref.dtype))
 
 
-def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, o_ref,
-                         *rest, block_q: int, block_k: int, chunks: int,
+def _flash_bwd_dq_kernel(*refs, block_q: int, block_k: int, chunks: int,
                          num_major: int, causal: bool, scale: float,
                          has_seg: bool = False, dropout_rate: float = 0.0,
                          window: "int | None" = None,
@@ -834,6 +972,8 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, o_ref,
     is a second result, as the (1, block_q) row the dk/dv kernel reads: on
     the projections' rows XLA would relayout dO . O whole to sum it by
     head with the positions along lanes."""
+    seg, (q_ref, k_ref, v_ref, do_ref, lse_ref, o_ref, *rest) = _seg_range(
+        refs, has_seg)
     if dropout_rate > 0.0:
         seed_ref, rest = rest[0], rest[1:]
     else:
@@ -893,7 +1033,8 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, o_ref,
                 window=window)
             _of(dq_acc, i, pair)[...] += _dot(ds.astype(k.dtype), k, _NN)
 
-    _for_k_chunks(chunk, q0, block_q, block_k, lo, chunks, causal, window)
+    _for_k_chunks(chunk, q0, block_q, block_k, lo, chunks, causal, window,
+                  seg)
 
     @pl.when(kj == num_major - 1)
     def _finalize():
@@ -944,28 +1085,33 @@ def _backward_call(q, k, v, o, lse, do, segments, dropout_seed, *, heads,
     q_chunks = _major_chunks(S, block_q, max(D, Dv) * q.dtype.itemsize)
     q_major = q_chunks * block_q
 
-    def qj_of(kb, qj):
+    # with segments: each k tile's range of q chunks, prefetched scalars
+    dkdv_bounds = _segment_bounds(segments, block_k, block_q)
+
+    def qj_of(b, kb, qj, bounds):
         # a q major block wholly above the diagonal repeats the first one
         # that is needed (no copy for an unchanged block index), and one
-        # wholly past the band the last
-        if causal and window is not None:
-            return jnp.clip(
-                qj, (kb * block_k) // q_major,
-                (kb * block_k + block_k + window - 2) // q_major)
+        # wholly past the band the last; so does one none of whose chunks
+        # the segments leave the k tile
+        lo = hi = None
         if causal:
-            return jnp.maximum(qj, (kb * block_k) // q_major)
-        return qj
+            lo = (kb * block_k) // q_major
+        if causal and window is not None:
+            hi = (kb * block_k + block_k + window - 2) // q_major
+        return _held_to(qj, lo, hi, _major_range(
+            bounds, b * (Sk // block_k) + kb, q_chunks))
 
     # (the query heads of key/value head kh are kh * G + g, and so are the
     # query pairs of a key/value pair)
-    def q_rows(b, kh, kb, g, qj):
-        return b, kh * G + g, qj_of(kb, qj)
+    def q_rows(b, kh, kb, g, qj, *bounds):
+        return b, kh * G + g, qj_of(b, kb, qj, bounds)
 
     q_stat = pl.BlockSpec(
         (1, per, q_chunks, 1, block_q),
-        lambda b, kh, kb, g, qj: (b, kh * G + g, qj_of(kb, qj), 0, 0))
+        lambda b, kh, kb, g, qj, *bounds: (
+            b, kh * G + g, qj_of(b, kb, qj, bounds), 0, 0))
 
-    def k_tile(b, kh, kb, g, qj):
+    def k_tile(b, kh, kb, g, qj, *bounds):
         return b, kh, kb
 
     dkdv_in_specs = [block(q_major, D * per, q_rows),
@@ -979,9 +1125,10 @@ def _backward_call(q, k, v, o, lse, do, segments, dropout_seed, *, heads,
     if has_seg:
         dkdv_in_specs += [
             pl.BlockSpec((1, q_chunks, 1, block_q),
-                         lambda b, kh, kb, g, qj: (b, qj_of(kb, qj), 0, 0)),
+                         lambda b, kh, kb, g, qj, *bounds: (
+                             b, qj_of(b, kb, qj, bounds), 0, 0)),
             pl.BlockSpec((1, block_k, 1),
-                         lambda b, kh, kb, g, qj: (b, kb, 0)),
+                         lambda b, kh, kb, g, qj, *bounds: (b, kb, 0)),
         ]
         dkdv_operands += [qseg_rows, seg_col]
 
@@ -992,17 +1139,19 @@ def _backward_call(q, k, v, o, lse, do, segments, dropout_seed, *, heads,
                           scale=scale, has_seg=has_seg,
                           dropout_rate=dropout_rate, window=window,
                           pair=pair),
-        grid=(B, -(-KV // per), Sk // block_k, G, S // q_major),
-        in_specs=dkdv_in_specs,
-        out_specs=[block(block_k, D * per, k_tile),
-                   block(block_k, Dv * per, k_tile)],
+        **_grid(
+            dkdv_bounds,
+            grid=(B, -(-KV // per), Sk // block_k, G, S // q_major),
+            in_specs=dkdv_in_specs,
+            out_specs=[block(block_k, D * per, k_tile),
+                       block(block_k, Dv * per, k_tile)],
+            scratch_shapes=[
+                pltpu.VMEM((block_k, D * per), jnp.float32),
+                pltpu.VMEM((block_k, Dv * per), jnp.float32),
+            ]),
         out_shape=[
             jax.ShapeDtypeStruct(k.shape, k.dtype),
             jax.ShapeDtypeStruct(v.shape, v.dtype),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_k, D * per), jnp.float32),
-            pltpu.VMEM((block_k, Dv * per), jnp.float32),
         ],
         # dk/dv accumulate across the (g, q) axes; k tiles are independent
         compiler_params=pltpu.CompilerParams(
@@ -1010,7 +1159,7 @@ def _backward_call(q, k, v, o, lse, do, segments, dropout_seed, *, heads,
                                  "arbitrary", "arbitrary")),
         interpret=interpret,
         name="flash_attention_bwd_dkv",
-    )(*dkdv_operands[:5], delta, *dkdv_operands[5:])
+    )(*(dkdv_bounds or ()), *dkdv_operands[:5], delta, *dkdv_operands[5:])
     if heads is None:
         dk, dv = dkdv(delta)
 
@@ -1018,21 +1167,25 @@ def _backward_call(q, k, v, o, lse, do, segments, dropout_seed, *, heads,
     k_chunks = _major_chunks(Sk, block_k, max(D, Dv) * k.dtype.itemsize)
     k_major = k_chunks * block_k
 
-    def kj_of(qi, kj):
-        return _needed_k_major(qi, kj, block_q, k_major, causal, window)
+    dq_bounds = _segment_bounds(segments, block_q, block_k)
 
-    def q_tile(b, n, qi, kj):
+    def kj_of(b, qi, kj, bounds):
+        return _needed_k_major(
+            qi, kj, block_q, k_major, causal, window,
+            _major_range(bounds, b * (S // block_q) + qi, k_chunks))
+
+    def q_tile(b, n, qi, kj, *bounds):
         return b, n, qi
 
-    def kv_rows(b, n, qi, kj):
-        return b, _kv_col(n, G, pair), kj_of(qi, kj)
+    def kv_rows(b, n, qi, kj, *bounds):
+        return b, _kv_col(n, G, pair), kj_of(b, qi, kj, bounds)
 
     dq_in_specs = [block(block_q, D * per, q_tile),
                    block(k_major, D * per, kv_rows),
                    block(k_major, Dv * per, kv_rows),
                    block(block_q, Dv * per, q_tile),
                    pl.BlockSpec((1, per, block_q, 1),
-                                lambda b, n, qi, kj: (b, n, qi, 0)),
+                                lambda b, n, qi, kj, *bounds: (b, n, qi, 0)),
                    block(block_q, Dv * per, q_tile)]
     dq_operands = [q, k, v, do, lse, o]
     if dropout_rate > 0.0:
@@ -1040,18 +1193,21 @@ def _backward_call(q, k, v, o, lse, do, segments, dropout_seed, *, heads,
         dq_operands.append(seed_arr)
     if has_seg:
         dq_in_specs += [
-            pl.BlockSpec((1, block_q, 1), lambda b, n, qi, kj: (b, qi, 0)),
+            pl.BlockSpec((1, block_q, 1),
+                         lambda b, n, qi, kj, *bounds: (b, qi, 0)),
             pl.BlockSpec((1, k_chunks, 1, block_k),
-                         lambda b, n, qi, kj: (b, kj_of(qi, kj), 0, 0)),
+                         lambda b, n, qi, kj, *bounds: (
+                             b, kj_of(b, qi, kj, bounds), 0, 0)),
         ]
-        dq_operands += [seg_col, kseg_rows]
+        dq_operands = [*dq_bounds, *dq_operands, seg_col, kseg_rows]
     dq_spec = block(block_q, D * per, q_tile)
     dq_shape = jax.ShapeDtypeStruct(q.shape, q.dtype)
     if heads is not None:
         # on rows the dq kernel writes delta's rows as well, in the chunks
         # ``q_stat`` reads (what ``_chunk_rows`` makes of [B, N, S])
         dq_spec = [dq_spec, pl.BlockSpec(
-            (1, per, 1, 1, block_q), lambda b, n, qi, kj: (b, n, qi, 0, 0))]
+            (1, per, 1, 1, block_q),
+            lambda b, n, qi, kj, *bounds: (b, n, qi, 0, 0))]
         dq_shape = [dq_shape, jax.ShapeDtypeStruct(
             (B, lse.shape[1], S // block_q, 1, block_q), jnp.float32)]
     dq = pl.pallas_call(
@@ -1061,16 +1217,19 @@ def _backward_call(q, k, v, o, lse, do, segments, dropout_seed, *, heads,
                           scale=scale, has_seg=has_seg,
                           dropout_rate=dropout_rate, window=window,
                           pair=pair, delta_out=heads is not None),
-        grid=(B, -(-N // per), S // block_q, Sk // k_major),
-        in_specs=dq_in_specs,
-        out_specs=dq_spec,
+        **_grid(
+            dq_bounds,
+            grid=(B, -(-N // per), S // block_q, Sk // k_major),
+            in_specs=dq_in_specs,
+            out_specs=dq_spec,
+            # the accumulator; of a pair one a head, and the views of q and
+            # dO
+            scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)]
+            if pair is None else [
+                pltpu.VMEM((per, block_q, LANES), jnp.float32),
+                pltpu.VMEM((per, block_q, LANES), q.dtype),
+                pltpu.VMEM((per, block_q, LANES), do.dtype)]),
         out_shape=dq_shape,
-        # the accumulator; of a pair one a head, and the views of q and dO
-        scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)]
-        if pair is None else [
-            pltpu.VMEM((per, block_q, LANES), jnp.float32),
-            pltpu.VMEM((per, block_q, LANES), q.dtype),
-            pltpu.VMEM((per, block_q, LANES), do.dtype)],
         # dq accumulates across k only
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
@@ -1169,13 +1328,20 @@ def band_tiles(S: int, block_q: int, block_k: int,
 WINDOWED_CALLS: "set[tuple[int, int, int, int, int]]" = set()
 
 
-def two_way_tiles(S: int, Sk: int, block_q: int, block_k: int) -> int:
+def two_way_tiles(S: int, Sk: int, block_q: int, block_k: int,
+                  segments=None) -> int:
     """Score tiles the three kernels visit of one head of a call that is
-    not causal: every k chunk for every q tile (``_for_k_chunks``' first
-    branch and its mirror in the dk/dv kernel; ``_needed_k_major`` keeps
-    every major block), whatever the segments say: a tile in which no query
-    meets a key is computed and masked."""
-    return (S // block_q) * (Sk // block_k)
+    not causal, by the bounds of their own loops: every k chunk for every q
+    tile without segments (``_for_two_way``; ``_needed_k_major`` keeps every
+    major block); with the call's segment ids (a NumPy row ``[S]``, or
+    ``[B, S]`` for the rows' sum) the chunks of each q tile's range,
+    ``segment_chunk_ranges``, which is where the kernels' prefetched bounds
+    come from: a tile in which no query's ids can meet a key's is not
+    visited."""
+    if segments is None:
+        return (S // block_q) * (Sk // block_k)
+    first, end = segment_chunk_ranges(segments, block_q, block_k)
+    return int((end - first).sum())
 
 
 # every call that is not causal, as the kernels were handed it: (q length,

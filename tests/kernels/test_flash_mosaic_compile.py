@@ -86,9 +86,17 @@ _CASES = {
                               False, 0.0),
     "rows_256_v128": (1, 1024, 1024, 4, 2, (256, 128), jnp.bfloat16, True,
                       False, 0.0),
+    # kimivl_c1_b1_s4k's tower: not causal, the images as segments, whose
+    # chunk ranges the three kernels prefetch as scalars (every case with
+    # segments above compiles that path too: causal, window, dropout, pairs)
+    "tower_cell_72_segments": (1, 8192, 8192, 16, 16, 72, jnp.bfloat16,
+                               False, True, 0.0),
+    "two_way_f32_segments_two_major_blocks": (2, 4096, 4096, 4, 2, 128,
+                                              jnp.float32, False, True, 0.0),
 }
 # the cases whose call keeps the head-major kernels between transposes
-_TRANSPOSED = {"latent_cell_192_128", "latent_f32_segments"}
+_TRANSPOSED = {"latent_cell_192_128", "latent_f32_segments",
+               "tower_cell_72_segments"}
 
 
 @pytest.mark.parametrize("case", sorted(_CASES))

@@ -591,7 +591,14 @@ def test_the_flop_counts_hold_the_tower():
 def test_the_tiles_a_two_way_call_covers_are_read_off_the_call():
     """``tower/pairs_tiled`` is the flash kernels' own loop bounds over the
     calls as built, not the traffic's arithmetic: a call that is not causal
-    records its lengths and tiles, and every tile of it is visited."""
+    records its lengths and tiles; without ids every tile of it is visited,
+    with them the chunks of each q tile's ``segment_chunk_ranges``, which
+    the kernels prefetch as their loops' bounds. A tiny tower on the flash
+    path (interpret) is the XLA core's tower and leaves the launcher the
+    calls to read; the XLA core's reading stays the square."""
+    from functools import partial
+
+    from hetu_galvatron_tpu.cli.train_dist import tower_pairs_tiled
     from hetu_galvatron_tpu.ops.pallas import flash_attention as F
 
     F.TWO_WAY_CALLS.clear()
@@ -603,7 +610,37 @@ def test_the_tiles_a_two_way_call_covers_are_read_off_the_call():
     assert F.TWO_WAY_CALLS == {(1024, 1024, 512, 512)}
     (call,) = F.TWO_WAY_CALLS
     assert F.two_way_tiles(*call) * call[2] * call[3] == 1024 * 1024
+    # the cell's call and images: three squares on the diagonal, 8 x 8 +
+    # 6 x 6 + 3 x 3 tiles less the one the second boundary puts in two
+    cell = T.image_of_patch(((64, 64), (36, 80), (32, 38)))
     assert F.two_way_tiles(8192, 8192, 512, 512) == 256
+    assert F.two_way_tiles(8192, 8192, 512, 512, cell) == 108
+    F.TWO_WAY_CALLS.clear()
+
+    # a tower of 1024 patches: the first image fills the first 512 x 512
+    # tile's rows, the other two the second's
+    cfg = ModelArgs(**{**TINY, "seq_length": 512,
+                       "max_position_embeddings": 512,
+                       "image_grids": [[32, 16], [16, 16], [16, 16]]})
+    tower = _seeded(cfg)["tower"]
+    patches = jax.random.normal(jax.random.key(3), (1, 1024, 12))
+    sdpa = partial(F.flash_sdpa, interpret=True)
+    sdpa.supports_segments = True
+    flash = M.LayerOps(sdpa=sdpa)
+
+    def rows(ops):
+        return jax.value_and_grad(lambda t: jnp.sum(T.apply_tower(
+            t, patches, cfg, compute_dtype=jnp.float32, ops=ops) ** 2))(tower)
+
+    (got, got_grads), (want, want_grads) = rows(flash), rows(None)
+    np.testing.assert_allclose(got, want, rtol=1e-4)
+    for a, b in zip(jax.tree.leaves(got_grads), jax.tree.leaves(want_grads)):
+        np.testing.assert_allclose(a, b, rtol=2e-3, atol=1e-3 * float(
+            jnp.abs(b).max()))
+    assert F.TWO_WAY_CALLS == {(1024, 1024, 512, 512)}
+    assert tower_pairs_tiled(cfg, True) == 2 * 512 * 512
+    assert T.pairs_masked(T.grids_of(cfg)) == 512 ** 2 + 2 * 256 ** 2
+    assert tower_pairs_tiled(cfg, False) == 1024 ** 2
     F.TWO_WAY_CALLS.clear()
 
 
