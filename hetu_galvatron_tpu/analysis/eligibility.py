@@ -154,8 +154,23 @@ KDA_REASON = ("kda block: the delta-rule block's projections are not cut "
               "for the ring all-gather / reduce-scatter matmuls, and its "
               "recurrence runs over the whole sequence on one shard")
 
-WINDOW_REASON = ("a block with a window, query heads of its own or a gate a "
-                 "head attends through the XLA core or the Pallas flash "
+MAMBA1_REASON = ("mamba1 block: the selective-scan block's projections are "
+                 "not cut for the ring all-gather / reduce-scatter matmuls, "
+                 "and its recurrence runs over the whole sequence on one "
+                 "shard")
+
+SHARED_REASON = ("a block that reads the scan output or the keys and values "
+                 "an earlier block left (a gmu or cross_attention block) "
+                 "runs at tp=1, cp=1 and pp=1, data parallel: the walk of "
+                 "builder.forward_causal_lm carries those values from block "
+                 "to block sharded as the residual stream is, no pipeline "
+                 "stage hands them on, no cache holds one set of keys and "
+                 "values for many blocks, and neither is cut over heads or "
+                 "sequence (eligibility.shared_plan_reason)")
+
+WINDOW_REASON = ("a block with a window, query heads of its own, a gate a "
+                 "head or differential attention "
+                 "attends through the XLA core or the Pallas flash "
                  "kernels with its projections whole on a device: the ring "
                  "and Ulysses cores take no window (a band over ring "
                  "attention's block schedule is not written), and the tp "
@@ -169,13 +184,16 @@ WINDOW_REASON = ("a block with a window, query heads of its own or a gate a "
 MIXER_OVERLAP_REASON = {"conv": CONV_REASON, "mamba": MAMBA_REASON,
                         "latent_attention": LATENT_REASON,
                         "kda": KDA_REASON,
-                        "sliding_attention": WINDOW_REASON}
+                        "sliding_attention": WINDOW_REASON,
+                        "mamba1": MAMBA1_REASON, "gmu": SHARED_REASON,
+                        "cross_attention": SHARED_REASON}
 
 # the fields of ``ModelArgs`` by which a block's attention differs from the
 # model-wide description: its window, its own query heads, its own rotation,
 # its gate
 BLOCK_ATTENTION_FIELDS = ("sliding_window", "num_attention_heads_per_layer",
-                          "rope_parameters", "gating")
+                          "rope_parameters", "gating",
+                          "differential_attention")
 
 
 def block_attention_stated(cfg: Any) -> List[str]:
@@ -184,7 +202,7 @@ def block_attention_stated(cfg: Any) -> List[str]:
     windowed = "sliding_attention" in (getattr(cfg, "layer_types", None)
                                        or ())
     return [f"{k}={getattr(cfg, k)}" for k in BLOCK_ATTENTION_FIELDS
-            if getattr(cfg, k, None) is not None
+            if getattr(cfg, k, None) not in (None, False)
             and (k != "sliding_window" or windowed)]
 
 
@@ -254,17 +272,53 @@ def kda_plan_reason(cfg: Any, layers: Any) -> Optional[str]:
         "model")
 
 
+def mamba1_plan_reason(cfg: Any, layers: Any) -> Optional[str]:
+    """Why a plan cannot run this model's mamba1 blocks; None when it can
+    (or the model has none). The Mamba-1 block's parameters carry no axis
+    that tensor parallelism shards (channels on the tp axis with B and C
+    all-reduced is not written), and its convolution and recurrence run
+    over the whole sequence, so a block whose plan cuts the sequence (cp,
+    Ulysses) would carry a state across shards it cannot see."""
+    return _uncut_mixer_reason(
+        cfg, layers, "mamba1", "the selective-scan block",
+        "its channels are not cut over the tp axis and its recurrence needs "
+        "the whole sequence on one shard); use dp / ZeRO for this model")
+
+
+def shared_plan_reason(cfg: Any, layers: Any, pp_deg: int = 1
+                       ) -> Optional[str]:
+    """Why a training plan cannot run this model's gmu and cross_attention
+    blocks; None when it can (or the model has none): the plan's pp, and
+    the tp and cp of every block from the first that leaves a value to the
+    last that reads one, have to be 1 (:data:`SHARED_REASON`)."""
+    shares = cfg.block_shares(len(layers))
+    held = [i for i, (leaves, takes) in enumerate(shares) if leaves or takes]
+    if not held:
+        return None
+    if pp_deg > 1:
+        return (f"the plan has pp={pp_deg} and blocks {held[0]} to "
+                f"{held[-1]} hand values from block to block: "
+                + SHARED_REASON)
+    for i in held:
+        cut = _cut_said(layers[i])
+        if cut:
+            return (f"block {i} ({cfg.block_kinds(len(layers))[i][0]}) has "
+                    f"{cut} in its plan: " + SHARED_REASON)
+    return None
+
+
 def window_plan_reason(cfg: Any, layers: Any) -> Optional[str]:
     """Why a plan cannot run this model's attention blocks; None when it
-    can, or the model states no window, no query heads of a block's own and
-    no gate. Such a block runs with tp = 1 and cp = 1: see
-    :data:`WINDOW_REASON`."""
+    can, or the model states no window, no query heads of a block's own, no
+    gate and no differential attention. Such a block runs with tp = 1 and
+    cp = 1: see :data:`WINDOW_REASON`."""
     stated = block_attention_stated(cfg)
     if not stated:
         return None
     kinds = cfg.block_kinds(len(layers))
     for i, (s, (kind, _)) in enumerate(zip(layers, kinds)):
-        if kind not in ("full_attention", "sliding_attention"):
+        if kind not in ("full_attention", "sliding_attention",
+                        "cross_attention"):
             continue
         cut = _cut_said(s)
         if cut:

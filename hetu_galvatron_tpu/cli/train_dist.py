@@ -348,6 +348,29 @@ def train(args) -> Dict[str, Any]:
                                      ).set(4 * cfg.mamba_d_inner
                                            * cfg.mamba_d_state)
 
+        # what a stack whose blocks read earlier blocks' values holds: its
+        # blocks by kind and the bytes a step's microbatch keeps of the
+        # memory and of the keys and values between the block that leaves
+        # them and the blocks that take them
+        shared_report: Dict[str, int] = {}
+        if any(leaves or takes for leaves, takes in cfg.block_shares(
+                len(hpc.layers))):
+            rows = hpc.global_bsz // max(hpc.chunks, 1) * cfg.seq_length
+            width = {"fp32": 4}.get(args.parallel.mixed_precision, 2)
+            count = Counter(m for m, _ in kinds)
+            shared_report = {
+                "mamba1/blocks": count["mamba1"],
+                "gmu/blocks": count["gmu"],
+                "cross/blocks": count["cross_attention"],
+                "shared/memory_bytes": rows * cfg.mamba1_d_inner * width
+                * (count["gmu"] > 0),
+                "shared/kv_bytes": rows * 2 * cfg.kv_heads * cfg.head_dim
+                * width * (count["cross_attention"] > 0)}
+            for name, v in shared_report.items():
+                get_registry().gauge(name).set(v)
+            state.log("shared values: " + ", ".join(
+                f"{k} {v}" for k, v in shared_report.items()))
+
         # abstract init first: the plan's shardings are derived from SHAPES, so
         # no device materializes the unsharded tree before they exist (the
         # pp=1 path then initializes straight into its shards)
@@ -1540,6 +1563,10 @@ def train(args) -> Dict[str, Any]:
             # gauges kda/blocks, kda/chunk and kda/mosaic_calls); None for
             # a model without such a block
             "kda": step_report.get("kda"),
+            # a stack whose blocks read earlier blocks' values: its blocks
+            # by kind, the selective scan's chunk and the bytes kept between
+            # blocks (the gauges of the same names); {} for any other
+            "shared_values": shared_report,
             # the convolution's kernel calls of that step by phase (the
             # gauges conv/kernel_calls{phase=...}; zeros = the jax.numpy
             # form); None for a model no block of which convolves

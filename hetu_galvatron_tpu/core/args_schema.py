@@ -19,6 +19,14 @@ from typing import Any, Dict, List, Literal, Optional, Tuple
 
 from pydantic import BaseModel, Field, field_validator, model_validator
 
+# what a block may leave for later blocks, by the kind that reads it: (the
+# names of the values, the kind that makes them). ``ModelArgs.block_shares``
+# says which block of a stack makes and which reads
+SHARED_VALUES: Dict[str, Tuple[Tuple[str, ...], str]] = {
+    "gmu": (("memory",), "mamba1"),
+    "cross_attention": (("keys", "values"), "full_attention"),
+}
+
 
 class ModelArgs(BaseModel):
     """Architecture hyperparameters for the generic causal-LM decoder stack
@@ -145,13 +153,18 @@ class ModelArgs(BaseModel):
     # low-rank q and kv projections, modules.apply_latent_attention) or
     # "kda" (Kimi Delta Attention: a gated delta rule with a decay a
     # channel, modules.apply_kda) or "sliding_attention" (attention over the
-    # ``sliding_window`` newest keys of the causal span); None = every block
-    # attends.
+    # ``sliding_window`` newest keys of the causal span), "mamba1" (a Mamba-1
+    # selective-scan block, modules.apply_mamba1), "gmu" (a gated memory
+    # unit: it reads the scan output an earlier "mamba1" block left,
+    # modules.apply_gmu) or "cross_attention" (its own queries over the keys
+    # and values an earlier "full_attention" block left; which block leaves
+    # what: :meth:`block_shares`); None = every block attends.
     # ``num_dense_layers``: so many leading blocks of an expert model keep
     # a dense MLP of ``ffn_hidden_size``
     layer_types: Optional[
         List[Literal["full_attention", "conv", "mamba",
-                     "latent_attention", "kda", "sliding_attention"]]] = None
+                     "latent_attention", "kda", "sliding_attention",
+                     "mamba1", "gmu", "cross_attention"]]] = None
     num_dense_layers: int = 0
     # what a stack of window and full attention blocks publishes beside
     # ``layer_types`` (HF ``LagunaConfig``). ``sliding_window``: the keys a
@@ -196,7 +209,11 @@ class ModelArgs(BaseModel):
     # feed_forward.w1,w3,w2 / model.embedding_norm
     # "granite" = llama's norms and attention, shared_mlp.input_linear
     # (gate | up in one matrix) / shared_mlp.output_linear, mamba.*
-    hf_layout: Literal["llama", "lfm2", "granite"] = "llama"
+    # "phi4flash" = input_layernorm / post_attention_layernorm /
+    # attn.{Wqkv,out_proj,lambda_*,subln} or attn.{in_proj,conv1d,x_proj,
+    # dt_proj,out_proj,A_log,D} / mlp.{gate_up_proj,down_proj} /
+    # model.final_layernorm
+    hf_layout: Literal["llama", "lfm2", "granite", "phi4flash"] = "llama"
     # a "mamba" block (Mamba-2 / SSD; HF ``GraniteMoeHybridMambaLayer``):
     # ``mamba_n_heads`` heads of ``mamba_d_head`` channels each carry a
     # state of ``mamba_d_head x mamba_d_state`` over the sequence; B and C
@@ -242,6 +259,24 @@ class ModelArgs(BaseModel):
     kda_head_dim: int = 128
     kda_conv_kernel: int = 4
     kda_chunk_size: int = 64
+    # a "mamba1" block (Mamba-1's selective scan, arXiv:2312.00752; HF
+    # ``MambaMixer``): ``mamba1_expand x hidden_size`` channels each carry a
+    # state of ``mamba1_d_state`` values over the sequence, decayed by
+    # ``exp(dt[t, channel] * A[channel, state])``; dt comes through a
+    # bottleneck of ``mamba1_dt_rank`` (None: ceil(hidden_size / 16)); a
+    # depthwise causal convolution of ``mamba1_d_conv`` taps runs over the
+    # channels before the recurrence (modules.selective_scan: no matmul
+    # form exists)
+    mamba1_d_state: int = 16
+    mamba1_d_conv: int = 4
+    mamba1_expand: int = 2
+    mamba1_dt_rank: Optional[int] = None
+    # differential attention (arXiv:2410.05258) in every block that attends:
+    # query heads 2j and 2j + 1 are one pair, two softmax maps over a value
+    # two heads wide, subtracted under a learned scalar, then an RMSNorm a
+    # pair and the constant ``1 - lambda_init``, the block's own
+    # (modules.diff_lambda_init)
+    differential_attention: bool = False
     # the residual as ``hc_mult`` streams [B, S, hc_mult, H], mixed around
     # every sub-layer by maps that depend on the token (manifold-constrained
     # hyper-connections, arXiv:2512.24880; modules.residual): the
@@ -432,7 +467,8 @@ class ModelArgs(BaseModel):
                     ) -> Tuple[Tuple[str, str], ...]:
         """The one per-layer description of a decoder stack: for each block
         its mixer kind ("full_attention", "conv", "mamba",
-        "latent_attention", "kda", "sliding_attention") and its
+        "latent_attention", "kda", "sliding_attention", "mamba1", "gmu",
+        "cross_attention") and its
         feed-forward kind ("dense", "experts"). The builder, the exporter, the launcher's
         report and every engine's refusal read this and nothing else.
         ``n``: the blocks a plan lists where that is not
@@ -450,6 +486,42 @@ class ModelArgs(BaseModel):
             (m, "experts" if self.num_experts and i >= self.num_dense_layers
              and (i + 1) % freq == 0 else "dense")
             for i, m in enumerate(mixers))
+
+    def block_shares(self, n: Optional[int] = None
+                     ) -> Tuple[Tuple[Tuple[str, ...], Tuple[str, ...]], ...]:
+        """For each block (what it leaves for later blocks, what it reads
+        of an earlier one), by the names of :data:`SHARED_VALUES`: a block
+        of a reading kind reads what the LAST block of the making kind
+        before the first reader left (the second half of a
+        decoder-hybrid-decoder stack reads the first half's last scan and
+        its one full attention, arXiv:2507.06607). A reader with no maker
+        before it is a ``ValueError``."""
+        mixers = [m for m, _ in self.block_kinds(n)]
+        makes: List[Tuple[str, ...]] = [()] * len(mixers)
+        for reader, (names, maker) in SHARED_VALUES.items():
+            if reader not in mixers:
+                continue
+            first = mixers.index(reader)
+            before = [i for i in range(first) if mixers[i] == maker]
+            if not before:
+                raise ValueError(
+                    f"model.layer_types: block {first} is a {reader} block "
+                    f"and reads the {' and '.join(names)} of an earlier "
+                    f"{maker} block; there is none before it")
+            makes[before[-1]] = makes[before[-1]] + names
+        return tuple(
+            (made, SHARED_VALUES[m][0] if m in SHARED_VALUES else ())
+            for made, m in zip(makes, mixers))
+
+    @property
+    def mamba1_d_inner(self) -> int:
+        """Channels of a mamba1 block's scan, gate and memory."""
+        return self.mamba1_expand * self.hidden_size
+
+    @property
+    def mamba1_rank(self) -> int:
+        """The width of a mamba1 block's dt bottleneck."""
+        return self.mamba1_dt_rank or -(-self.hidden_size // 16)
 
     @property
     def mamba_d_inner(self) -> int:
@@ -515,6 +587,9 @@ class ModelArgs(BaseModel):
     # bias flags (HF adapter detects these per family, e.g. qwen2 qkv bias)
     add_bias_linear: bool = True
     add_qkv_bias: bool = False
+    # a bias on attention's output projection where ``add_bias_linear`` (it
+    # and the MLP's two) is off
+    add_attn_out_bias: bool = False
 
 
 class ParallelArgs(BaseModel):

@@ -930,12 +930,18 @@ def model_flops_per_token(model: Any, seq_length: Optional[int] = None
     s = seq_length or model.seq_length
     kd = model.kv_heads * model.head_dim
 
-    def attention(nq: int, span: int) -> float:
-        # q/k/v/out projections + the two batched matmuls (QK^T, PV) over
+    # under differential attention PV runs over the pair's value, two
+    # heads wide
+    pv = 2 if getattr(model, "differential_attention", False) else 1
+
+    def attention(nq: int, span: int, own_kv: bool = True) -> float:
+        # q/k/v/out projections (no k and v where the block reads an
+        # earlier block's) + the two batched matmuls (QK^T, PV) over
         # the ``span`` keys a query meets (a window block's band, else the
         # whole [S, S]) + a gate a head where the model has one
         nd = nq * model.head_dim
-        return (2 * h * nd + 2 * 2 * h * kd + 2 * nd * h + 2 * 2 * span * nd
+        return (2 * h * nd + (2 * 2 * h * kd if own_kv else 0) + 2 * nd * h
+                + 2 * (1 + pv) * span * nd
                 + (2 * h * nq if getattr(model, "gating", None) else 0))
 
     attn = attention(model.num_attention_heads, s)
@@ -984,6 +990,17 @@ def model_flops_per_token(model: Any, seq_length: Optional[int] = None
         mixer["kda"] = (
             2 * h * (3 * inner + 2 * d + model.kda_num_heads)
             + 2 * 2 * d * inner + 2 * inner * h + 6 * inner * d)
+    if any(m in ("mamba1", "gmu") for m, _ in kinds):
+        # a Mamba-1 block's four projections (the recurrence is no matmul:
+        # a decay a channel and state index, on the vector unit) and a
+        # gated memory unit's two
+        inner, state = model.mamba1_d_inner, model.mamba1_d_state
+        rank = model.mamba1_rank
+        mixer["mamba1"] = 2 * (h * 2 * inner + inner * (rank + 2 * state)
+                               + rank * inner + inner * h)
+        mixer["gmu"] = 2 * 2 * h * inner
+    mixer["cross_attention"] = attention(model.num_attention_heads, s,
+                                         own_kv=False)
     if getattr(model, "kv_lora_rank", 0):
         # latent attention: the projections as they are (q through its
         # low-rank step where the model has one), and the two batched

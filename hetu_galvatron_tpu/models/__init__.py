@@ -4,6 +4,5 @@ from hetu_galvatron_tpu.models.builder import (  # noqa: F401
     causal_lm_loss,
     forward_causal_lm,
     init_causal_lm,
-    model_flops_per_token,
     param_count,
 )

@@ -63,6 +63,7 @@ def init_causal_lm(key: jax.Array, cfg: ModelArgs) -> Tuple[Params, Params]:
         return init_encdec(key, cfg)
 
     n = cfg.num_hidden_layers
+    cfg.block_shares()   # a reader with no maker before it: a ValueError
     keys = jax.random.split(key, n + 2)
     embed_p, embed_a = M.init_embedding(keys[0], cfg)
     layers = [init_block(keys[1 + i], cfg.for_block(i), kind)
@@ -152,16 +153,25 @@ def rope_table(cfg: ModelArgs, seq: int, kind: Optional[str],
 
 
 def make_block(cfg: ModelArgs, kind: Tuple[str, str],
-               kwargs: Dict[str, Any], remat: bool):
-    """``fn(block params, x) -> (x, aux loss, router stats)`` of one block
-    of ``kind`` with its keyword arguments, rematerialized where asked."""
+               kwargs: Dict[str, Any], remat: bool, leaves: bool = False):
+    """``fn(block params, x, shared) -> (x, aux loss, router stats, made)``
+    of one block of ``kind`` with its keyword arguments, rematerialized
+    where asked. ``shared`` holds what the block reads of earlier blocks
+    (an argument of the rematerialized function, so its cotangent flows
+    back to the block that made it) and ``made`` what it ``leaves`` for
+    later ones (an output of it); both empty for most blocks."""
     from hetu_galvatron_tpu.models.moe import apply_moe_decoder_layer
 
-    if kind[1] == "experts":
-        fn = lambda p, h: apply_moe_decoder_layer(p, h, cfg, **kwargs)
-    else:
-        fn = lambda p, h: (M.apply_decoder_layer(p, h, cfg, **kwargs),
-                           jnp.zeros((), jnp.float32), {})
+    def fn(p, h, shared):
+        made: Dict[str, jax.Array] = {}
+        handed = dict(kwargs, shared=shared) if shared else kwargs
+        if leaves:
+            handed = dict(handed, made=made)
+        if kind[1] == "experts":
+            return apply_moe_decoder_layer(p, h, cfg, **handed) + (made,)
+        return (M.apply_decoder_layer(p, h, cfg, **handed),
+                jnp.zeros((), jnp.float32), {}, made)
+
     return M.remat(fn, cfg) if remat else fn
 
 
@@ -261,6 +271,11 @@ def forward_causal_lm(
         raise ValueError(
             f"the parameters hold {len(params['layers'])} blocks and the "
             f"configuration describes {len(kinds)}")
+    # what a block left for later blocks (a mamba1 block's scan output, a
+    # full_attention block's keys and values), by name; which block leaves
+    # and which takes is the per-layer description's
+    shares = cfg.block_shares()
+    shared: Dict[str, jax.Array] = {}
     for i, lp in enumerate(params["layers"]):
         if boundary_fn is not None:
             x = boundary_fn(i, x)
@@ -272,9 +287,16 @@ def forward_causal_lm(
             kwargs["segment_ids"] = segment_ids
         if dropout_rng is not None:
             kwargs["dropout_rng"] = M.fold_dropout_rng(dropout_rng, cfg, i)
-        x, aux, stats = make_block(
+        if cfg.differential_attention:
+            kwargs["lambda_init"] = M.diff_lambda_init(i)
+        leaves, takes = shares[i]
+        x, aux, stats, made = make_block(
             cfg.for_block(i), kinds[i], kwargs,
-            remat_flags is not None and bool(remat_flags[i]))(lp, x)
+            remat_flags is not None and bool(remat_flags[i]),
+            leaves=bool(leaves))(lp, x, {k: shared[k] for k in takes})
+        # sharded as the stream is
+        shared.update(made if boundary_fn is None else {
+            k: boundary_fn(i, v) for k, v in made.items()})
         aux_total = aux_total + aux
         if stats:
             # per-layer balance tracker (reference moe_utils.py:547-644)
@@ -332,7 +354,7 @@ def forward_mtp(params: Params, h: jax.Array, next_tokens: jax.Array,
                        preferred_element_type=jnp.float32
                        ).astype(compute_dtype)
     with jax.named_scope("mtp/block"):
-        x, aux, stats = block_fn(mp["layer"], M.streams_in(x, cfg))
+        x, aux, stats, _ = block_fn(mp["layer"], M.streams_in(x, cfg), {})
         x = M.streams_out(x, cfg)
     with jax.named_scope("mtp/head"):
         logits = M.apply_lm_head(
@@ -417,29 +439,3 @@ def causal_lm_loss(
 
 def param_count(params: Params) -> int:
     return sum(p.size for p in jax.tree.leaves(params))
-
-
-def model_flops_per_token(cfg: ModelArgs, seq_len: Optional[int] = None) -> float:
-    """Approximate training FLOPs per token (6*N params + attention term),
-    used by the MFU computation in bench/profilers."""
-    s = seq_len or cfg.seq_length
-    h, f, v = cfg.hidden_size, cfg.ffn_dim, cfg.padded_vocab_size
-    nkv, hd = cfg.kv_heads, cfg.head_dim
-    mlp = 2 * h * f * (3 if M._is_gated(cfg.hidden_act) else 2)
-    dense = 2 * h * v
-    for i, (mixer, _) in enumerate(cfg.block_kinds()):
-        nq = cfg.block_heads(i)
-        # a window block's queries meet its band, the others the sequence
-        span = (min(s, cfg.sliding_window) if mixer == "sliding_attention"
-                else s)
-        dense += 2 * h * (nq + 2 * nkv) * hd  # qkv
-        dense += 2 * nq * hd * h  # proj
-        dense += 2 * 2 * span * nq * hd  # qk^T + pv per token
-        dense += mlp
-    # a tower's work a sequence falls on the sequence's tokens
-    from hetu_galvatron_tpu.core.cost_model.cost import (
-        tower_flops_per_sequence,
-    )
-
-    dense += tower_flops_per_sequence(cfg) / s
-    return 3.0 * dense  # fwd + bwd(2x)

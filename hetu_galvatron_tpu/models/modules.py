@@ -45,7 +45,7 @@ from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from hetu_galvatron_tpu.core.args_schema import ModelArgs
+from hetu_galvatron_tpu.core.args_schema import SHARED_VALUES, ModelArgs
 
 Params = Dict[str, Any]
 Axes = Dict[str, Any]
@@ -332,9 +332,13 @@ def init_attention(key: jax.Array, cfg: ModelArgs) -> Tuple[Params, Axes]:
     if cfg.add_qkv_bias:
         p["bqkv"] = jnp.zeros(((nq + 2 * nkv) * hd,), jnp.float32)
         a["bqkv"] = ("qkv",)
-    if cfg.add_bias_linear:
+    if cfg.add_bias_linear or cfg.add_attn_out_bias:
         p["bo"] = jnp.zeros((h,), jnp.float32)
         a["bo"] = ("embed",)
+    if cfg.differential_attention:
+        dp, da = init_differential(jax.random.fold_in(key, 3), cfg)
+        p.update(dp)
+        a.update(da)
     if cfg.gating:
         # a logit a query head from the block's normed input; a leaf of its
         # own, replicated (a model with a gate runs with tp = 1,
@@ -358,6 +362,87 @@ def init_attention(key: jax.Array, cfg: ModelArgs) -> Tuple[Params, Axes]:
             p[name] = {"scale": jnp.ones((width,), jnp.float32)}
             a[name] = {"scale": ("qk_norm",)}
     return p, a
+
+
+# ---------------------------------------------------------------------------
+# differential attention (two softmax maps a head pair, subtracted)
+# ---------------------------------------------------------------------------
+
+
+def init_differential(key: jax.Array, cfg: ModelArgs) -> Tuple[Params, Axes]:
+    """What differential attention (arXiv:2410.05258) adds to a block that
+    attends: ``lambdas`` [4, head_dim], the rows ``lambda_q1``,
+    ``lambda_k1``, ``lambda_q2``, ``lambda_k2`` (N(0, 0.1)), and ``subln``,
+    the scale of the RMSNorm over a pair's ``2 head_dim`` values.
+    Replicated: a model with it runs with tp = 1
+    (eligibility.window_plan_reason)."""
+    if cfg.num_attention_heads % 2 or cfg.kv_heads % 2:
+        raise ValueError(
+            "model.differential_attention pairs heads: "
+            f"{cfg.num_attention_heads} query and {cfg.kv_heads} key-value "
+            "heads are not both even")
+    return ({"lambdas": _normal(key, (4, cfg.head_dim), 0.1),
+             "subln": {"scale": jnp.ones((2 * cfg.head_dim,), jnp.float32)}},
+            {"lambdas": ("diff_lambda", "diff_width"),
+             "subln": {"scale": ("diff_width",)}})
+
+
+def pair_values(v: jax.Array) -> jax.Array:
+    """[B, S, K, D] -> [B, S, K, 2 D]: key-value heads ``2g`` and ``2g + 1``
+    are one pair, and both of its key heads are handed the pair's value
+    ``[v_2g | v_2g+1]``, so that ONE core call over all score heads gives
+    each softmax map of a pair over the value two heads wide."""
+    B, S, K, D = v.shape
+    return jnp.broadcast_to(v.reshape(B, S, K // 2, 1, 2 * D),
+                            (B, S, K // 2, 2, 2 * D)).reshape(B, S, K, 2 * D)
+
+
+def diff_lambda_init(i: int) -> float:
+    """Block ``i``'s constant of differential attention."""
+    return 0.8 - 0.6 * math.exp(-0.3 * i)
+
+
+def differential(p: Params, out: jax.Array, cfg: ModelArgs,
+                 lam0: Optional[float]) -> jax.Array:
+    """``a_j = P_1 V - lambda P_2 V`` a query pair, ``lambda =
+    exp(lq1 . lk1) - exp(lq2 . lk2) + lambda_init``, then ``(1 -
+    lambda_init) RMSNorm(a_j) w``: float32, under ``attn/diff``. ``out`` [B,
+    S, N, 2 D] is the core's, its heads in the order the block's query
+    projection keeps them (:func:`diff_core_order`): ``(key-value pair,
+    map, query pair of it)``; returns [B, S, N / 2, 2 D], the query pairs in
+    their published order. ``lam0``: the block's ``lambda_init``
+    (:func:`diff_lambda_init` of its index, handed down by the walk)."""
+    B, S, N, W = out.shape
+    pairs = cfg.kv_heads // 2
+    if lam0 is None:
+        raise ValueError(
+            "differential attention reads its block's lambda_init: hand the "
+            "block lambda_init=diff_lambda_init(i)")
+    f32 = jnp.float32
+    with jax.named_scope("attn/diff"):
+        lq1, lk1, lq2, lk2 = p["lambdas"].astype(f32)
+        lam = jnp.exp(jnp.sum(lq1 * lk1)) - jnp.exp(jnp.sum(lq2 * lk2)) + lam0
+        maps = out.astype(f32).reshape(B, S, pairs, 2, N // (2 * pairs), W)
+        a = (maps[:, :, :, 0] - lam * maps[:, :, :, 1]).reshape(
+            B, S, N // 2, W)
+        var = jnp.mean(jnp.square(a), axis=-1, keepdims=True)
+        a = (a * jax.lax.rsqrt(var + cfg.layernorm_epsilon)
+             * p["subln"]["scale"] * (1.0 - lam0))
+        return a.astype(out.dtype)
+
+
+def diff_core_order(nq: int, nkv: int) -> Tuple[int, ...]:
+    """The published index of the query head the core sees at each place.
+    Published, query heads ``2j`` and ``2j + 1`` are the two maps of pair
+    ``j``, and pair ``j`` reads key-value pair ``j // G`` (``G = nq /
+    nkv``). The core's grouped heads want the queries of one key head side
+    by side, so a block keeps its query projection's heads as ``(key-value
+    pair g, map c, query pair t of it)``: place ``(2g + c) G + t`` holds
+    published head ``2 (g G + t) + c``. The exporter permutes
+    (runtime/checkpoint.py)."""
+    G = nq // nkv
+    return tuple(2 * (g * G + t) + c for g in range(nkv // 2)
+                 for c in range(2) for t in range(G))
 
 
 def remat(fn, cfg: ModelArgs):
@@ -506,13 +591,21 @@ def apply_attention(
     matmul_fns: Optional[Dict[str, Callable]] = None,
     shard_fn: Optional[Callable[[jax.Array, int], jax.Array]] = None,
     windowed: bool = False,
+    made: Optional[Dict[str, jax.Array]] = None,
+    lambda_init: Optional[float] = None,
 ) -> jax.Array:
     """``cfg`` is the block's (``ModelArgs.for_block``: its own query
     heads). ``windowed`` (a "sliding_attention" block): the core attends
     over the ``cfg.sliding_window`` newest keys of the causal span, under
     the named scope ``attn/window_core``. A model with ``cfg.gating``
     multiplies each head's output by the sigmoid of the block's ``wg``
-    logit for it, under ``attn/gate``.
+    logit for it, under ``attn/gate``. Under
+    ``cfg.differential_attention`` the core is called once over all score
+    heads with the pair's value (:func:`pair_values`) and
+    :func:`differential` joins the pairs under ``lambda_init``. ``made``
+    (the block that leaves its keys and values for later blocks,
+    ``ModelArgs.block_shares``) is written ``keys`` and ``values`` [B, S,
+    kv heads x head_dim] as the core read them.
 
     ``shard_fn(a, axis)`` (a layer whose plan has tp > 1,
     parallel/spmd.py::interior_sharding) pins an interior activation to
@@ -565,6 +658,11 @@ def apply_attention(
             q = apply_rope(q, cos, sin)
             k = apply_rope(k, cos, sin)
     core_kwargs: Dict[str, Any] = {}
+    if made is not None:
+        made.update(keys=k.reshape(B, S, nkv * hd),
+                    values=v.reshape(B, S, nkv * hd))
+    if cfg.differential_attention:
+        v = pair_values(v)
 
     def core(*a, **kw):
         if windowed:
@@ -573,7 +671,7 @@ def apply_attention(
         with jax.named_scope("attn/core"):
             return sdpa_fn(*a, **kw)
 
-    if (windowed or "wg" in p
+    if (windowed or "wg" in p or cfg.differential_attention
             or cfg.num_attention_heads_per_layer is not None):
         from hetu_galvatron_tpu.analysis.eligibility import WINDOW_REASON
 
@@ -645,6 +743,8 @@ def apply_attention(
                               weight_view(p["wg"], compute_dtype),
                               preferred_element_type=jnp.float32)
             out = out * jax.nn.sigmoid(gate).astype(compute_dtype)[..., None]
+    if cfg.differential_attention:
+        out = differential(p, out, cfg, lambda_init)
     with jax.named_scope("attn/out_proj"):
         out = out.reshape(B, S, nq * hd)
         if group_major:
@@ -1340,6 +1440,301 @@ def apply_mamba2(
 
 
 # ---------------------------------------------------------------------------
+# Mamba-1 selective scan (a state a channel, decayed a channel and state
+# index), and the gated memory unit that reads one block's scan output
+# ---------------------------------------------------------------------------
+
+
+def init_mamba1(key: jax.Array, cfg: ModelArgs) -> Tuple[Params, Axes]:
+    """HF ``MambaMixer`` (state-spaces/mamba ``Mamba``): ``win`` is
+    ``in_proj`` with its columns ``[u | z]``, ``taps`` / ``conv_bias`` the
+    depthwise kernel (``conv1d.weight[:, 0, :]``) and its bias, ``wx`` is
+    ``x_proj`` (columns ``[dt bottleneck | B | C]``), ``wdt`` / ``dt_bias``
+    are ``dt_proj``, ``A_log`` [channels, state] and ``D`` [channels],
+    ``wout`` is ``out_proj``; no bias on ``in_proj``, ``x_proj`` and
+    ``out_proj``. No leaf carries an axis name that tensor parallelism
+    shards: a plan with tp > 1 over such a block is refused by name
+    (``eligibility.mamba1_plan_reason``).
+
+    ``dt_bias``, ``A_log``, ``D`` and ``wdt`` start as state-spaces/mamba
+    starts them: ``dt`` log-uniform in [1e-3, 1e-1] and ``dt_bias`` its
+    inverse softplus, ``A = 1..d_state`` in every channel, ``D`` one,
+    ``wdt`` uniform in +-rank^-0.5."""
+    h, di, N = cfg.hidden_size, cfg.mamba1_d_inner, cfg.mamba1_d_state
+    R, L = cfg.mamba1_rank, cfg.mamba1_d_conv
+    k1, k2, k3, k4, k5, k6 = jax.random.split(key, 6)
+    std = 0.02
+    dt = jnp.exp(jax.random.uniform(k5, (di,), jnp.float32,
+                                    math.log(1e-3), math.log(1e-1)))
+    p: Params = {
+        "win": _normal(k1, (h, 2 * di), std),
+        # the variance of torch's Conv1d default, as the conv block's taps
+        "taps": _normal(k2, (di, L), 1.0 / math.sqrt(3 * L)),
+        "conv_bias": jnp.zeros((di,), jnp.float32),
+        "wx": _normal(k3, (di, R + 2 * N), std),
+        "wdt": jax.random.uniform(k4, (R, di), jnp.float32,
+                                  -R ** -0.5, R ** -0.5),
+        "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+        "A_log": jnp.log(jnp.broadcast_to(
+            jnp.arange(1, N + 1, dtype=jnp.float32), (di, N))),
+        "D": jnp.ones((di,), jnp.float32),
+        "wout": _normal(k6, (di, h), std / math.sqrt(2 * cfg.num_hidden_layers)),
+    }
+    a: Axes = {"win": ("embed", "mamba1_proj"),
+               "taps": ("mamba1_inner", "conv_tap"),
+               "conv_bias": ("mamba1_inner",),
+               "wx": ("mamba1_inner", "mamba1_low"),
+               "wdt": ("mamba1_low", "mamba1_inner"),
+               "dt_bias": ("mamba1_inner",),
+               "A_log": ("mamba1_inner", "mamba1_state"),
+               "D": ("mamba1_inner",),
+               "wout": ("mamba1_inner", "embed")}
+    return p, a
+
+
+def _selective_chunk(state, u, dt, Bm, Cm, At):
+    """One chunk of :func:`selective_scan`, a position at a time with the
+    loop unrolled whole: ``state`` [B, N, C], ``u`` and ``dt`` [B, Q, C],
+    ``Bm`` and ``Cm`` [B, Q, N], ``At`` [N, C] -> (the state after the
+    chunk, ``y`` [B, Q, C])."""
+    def step(s, at):
+        u_t, dt_t, b_t, c_t = at
+        s = (jnp.exp(dt_t[:, None, :] * At) * s
+             + (dt_t * u_t)[:, None, :] * b_t[..., None])
+        return s, jnp.sum(s * c_t[..., None], axis=1)
+
+    state, y = jax.lax.scan(
+        step, state, tuple(jnp.moveaxis(t, 1, 0) for t in (u, dt, Bm, Cm)),
+        unroll=u.shape[1])
+    return state, jnp.moveaxis(y, 0, 1)
+
+
+# positions of a chunk of :func:`selective_scan`, all of them unrolled: the
+# fastest of the forms probed on a v5e (PR 61; the numbers are below), and
+# what bounds the backward pass's memory
+SELECTIVE_CHUNK = 16
+
+
+def selective_scan(u: jax.Array, dt: jax.Array, A: jax.Array,
+                   Bm: jax.Array, Cm: jax.Array,
+                   chunk: int = SELECTIVE_CHUNK) -> jax.Array:
+    """Mamba-1's recurrence (Gu & Dao 2023). A channel ``c`` carries a state
+    ``s`` of ``N`` values, zero before the sequence::
+
+        s_t[c] = exp(dt_t[c] A[c]) * s_(t-1)[c] + dt_t[c] u_t[c] B_t
+        y_t[c] = s_t[c] . C_t
+
+    ``u`` [B, S, C]; ``dt`` [B, S, C], after softplus; ``A`` [C, N],
+    negative; ``Bm``, ``Cm`` [B, S, N], shared by the channels. Returns
+    ``y`` [B, S, C] float32 (without the ``D u`` skip). Everything float32.
+
+    The decay is a number a channel AND state index, so no chunked matmul
+    form exists (Mamba-2's is one scalar a head): this is 2 ``C N``
+    multiply-adds a position on the vector unit. A ``lax.scan`` over chunks
+    of ``chunk`` positions carries the state [B, N, C] (the channels along
+    lanes); inside a chunk the positions run one after another, unrolled, so
+    that a chunk is one fused loop body whose operands are the chunk's rows
+    of ``u``, ``dt``, ``B`` and ``C`` and never ``[S, C, N]``; the chunk's
+    body is rematerialized, so the backward pass holds the states that
+    entered the chunks (``S / chunk`` of them) and one chunk's
+    intermediates. (On a v5e at [1, 8192, 5120] x 16, forward and backward:
+    41 ms so at 16 positions a chunk, 78 ms at 64 not unrolled, 56 ms and
+    182 ms with ``lax.associative_scan`` inside chunks of 16 and 64;
+    PERF.md section 6, PR 61.) A sequence that ``chunk`` does not divide is
+    padded with ``dt = 0`` (no decay, no input) and the padding cut off."""
+    f32 = jnp.float32
+    B_, S, C = u.shape
+    pad = -S % chunk
+    u, dt, Bm, Cm = (jnp.pad(t.astype(f32), ((0, 0), (0, pad), (0, 0)))
+                     for t in (u, dt, Bm, Cm))
+    nC = (S + pad) // chunk
+
+    def chunks(t):    # [B, S, W] -> [nC, B, chunk, W]
+        return jnp.moveaxis(t.reshape(B_, nC, chunk, t.shape[-1]), 1, 0)
+
+    At = A.astype(f32).T
+    body = jax.checkpoint(_selective_chunk)
+    _, y = jax.lax.scan(
+        lambda s, at: body(s, *at, At),
+        jnp.zeros((B_, A.shape[1], C), f32),
+        tuple(chunks(t) for t in (u, dt, Bm, Cm)))
+    return jnp.moveaxis(y, 0, 1).reshape(B_, nC * chunk, C)[:, :S]
+
+
+def apply_mamba1(
+    p: Params,
+    x: jax.Array,
+    cfg: ModelArgs,
+    compute_dtype=jnp.bfloat16,
+    conv_fn: Optional[Callable[..., Optional[jax.Array]]] = None,
+    made: Optional[Dict[str, jax.Array]] = None,
+) -> jax.Array:
+    """``[u | z] = x W_in``; ``u = silu(conv1d_causal(u) + b)`` (depthwise,
+    ``mamba1_d_conv`` taps, zero history before the sequence); ``[d | B | C]
+    = u W_x``; ``dt = softplus(d W_dt + b_dt)``, ``A = -exp(A_log)``; ``y =
+    scan(u, dt, A, B, C) + D u`` (:func:`selective_scan`); ``(y * silu(z))
+    W_out``. No softmax, no positions, no norm. The four projections run in
+    ``compute_dtype`` with float32 accumulation; ``dt``, the decays, the
+    state, the convolution and the gate are float32. ``conv_fn``: the
+    kernels for the convolution, its bias and SiLU
+    (:func:`causal_depthwise_conv`). ``made`` (the block whose scan output
+    later blocks read, ``ModelArgs.block_shares``) is written ``memory`` =
+    ``y`` [B, S, channels], after the ``D`` skip and before the gate."""
+    N, R = cfg.mamba1_d_state, cfg.mamba1_rank
+    f32 = jnp.float32
+
+    def proj(a, w):
+        return jnp.einsum("bsh,hc->bsc", a.astype(compute_dtype),
+                          weight_view(w, compute_dtype),
+                          preferred_element_type=f32)
+
+    with jax.named_scope("mixer/mamba1"):
+        with jax.named_scope("in_proj"):
+            u, z = jnp.split(proj(x, p["win"]).astype(compute_dtype), 2,
+                             axis=-1)
+        with jax.named_scope("conv"):
+            u = causal_depthwise_conv(
+                u, p["taps"], p["conv_bias"], silu=True,
+                out_dtype=compute_dtype, conv_fn=conv_fn,
+                scope="mixer/mamba1/conv")
+        with jax.named_scope("x_proj"):
+            d, Bm, Cm = jnp.split(proj(u, p["wx"]), [R, R + N], axis=-1)
+            dt = jax.nn.softplus(proj(d, p["wdt"]) + p["dt_bias"])
+        with jax.named_scope("scan"):
+            y = selective_scan(u, dt, -jnp.exp(p["A_log"].astype(f32)),
+                               Bm, Cm)
+            y = y + p["D"] * u.astype(f32)
+        if made is not None:
+            made["memory"] = y.astype(compute_dtype)
+        with jax.named_scope("gate"):
+            y = (y * jax.nn.silu(z.astype(f32))).astype(compute_dtype)
+        with jax.named_scope("out_proj"):
+            out = proj(y, p["wout"])
+    return out.astype(compute_dtype)
+
+
+def init_gmu(key: jax.Array, cfg: ModelArgs) -> Tuple[Params, Axes]:
+    """A gated memory unit (arXiv:2507.06607, section 2): ``win`` [hidden,
+    memory width] and ``wout`` back, no bias; the memory is a "mamba1"
+    block's scan output, ``mamba1_d_inner`` wide."""
+    h, di = cfg.hidden_size, cfg.mamba1_d_inner
+    k1, k2 = jax.random.split(key)
+    std = 0.02
+    return ({"win": _normal(k1, (h, di), std),
+             "wout": _normal(k2, (di, h),
+                             std / math.sqrt(2 * cfg.num_hidden_layers))},
+            {"win": ("embed", "mamba1_inner"),
+             "wout": ("mamba1_inner", "embed")})
+
+
+def apply_gmu(p: Params, x: jax.Array, cfg: ModelArgs,
+              compute_dtype=jnp.bfloat16, *,
+              shared: Dict[str, jax.Array]) -> jax.Array:
+    """``(M * silu(x W_in)) W_out`` with ``M = shared["memory"]`` [B, S,
+    memory width], an earlier block's scan output: no convolution, no scan,
+    no norm on ``M`` or on the product. The gate is float32."""
+    f32 = jnp.float32
+    with jax.named_scope("mixer/gmu"):
+        with jax.named_scope("in_proj"):
+            g = jnp.einsum("bsh,hc->bsc", x.astype(compute_dtype),
+                           weight_view(p["win"], compute_dtype),
+                           preferred_element_type=f32)
+        with jax.named_scope("gate"):
+            y = (shared["memory"].astype(f32) * jax.nn.silu(g)
+                 ).astype(compute_dtype)
+        with jax.named_scope("out_proj"):
+            out = jnp.einsum("bsc,ch->bsh", y,
+                             weight_view(p["wout"], compute_dtype),
+                             preferred_element_type=f32)
+    return out.astype(compute_dtype)
+
+
+# ---------------------------------------------------------------------------
+# cross-attention over an earlier block's keys and values
+# ---------------------------------------------------------------------------
+
+
+def init_cross_attention(key: jax.Array,
+                         cfg: ModelArgs) -> Tuple[Params, Axes]:
+    """A block of the second half of a decoder-hybrid-decoder stack
+    (arXiv:2405.05254, arXiv:2507.06607): ``wq`` and ``wo`` (biases as
+    :func:`init_attention` decides them) and, under differential attention,
+    its own ``lambdas`` and ``subln``; it owns no key and no value."""
+    h, hd, nq = cfg.hidden_size, cfg.head_dim, cfg.num_attention_heads
+    k1, k2 = jax.random.split(key)
+    std = 0.02
+    p: Params = {
+        "wq": _normal(k1, (h, nq * hd), std),
+        "wo": _normal(k2, (nq * hd, h),
+                      std / math.sqrt(2 * cfg.num_hidden_layers))}
+    a: Axes = {"wq": ("embed", "cross_q"), "wo": ("cross_q", "embed")}
+    if cfg.add_qkv_bias:
+        p["bq"] = jnp.zeros((nq * hd,), jnp.float32)
+        a["bq"] = ("cross_q",)
+    if cfg.add_bias_linear or cfg.add_attn_out_bias:
+        p["bo"] = jnp.zeros((h,), jnp.float32)
+        a["bo"] = ("embed",)
+    if cfg.differential_attention:
+        dp, da = init_differential(jax.random.fold_in(key, 3), cfg)
+        p.update(dp)
+        a.update(da)
+    return p, a
+
+
+def apply_cross_attention(
+    p: Params,
+    x: jax.Array,
+    cfg: ModelArgs,
+    rope: Optional[Tuple[jax.Array, jax.Array]] = None,
+    sdpa_fn: Callable[..., jax.Array] = xla_sdpa,
+    compute_dtype=jnp.bfloat16,
+    causal: bool = True,
+    dropout_rng: Optional[jax.Array] = None,
+    segment_ids: Optional[jax.Array] = None,
+    *,
+    shared: Dict[str, jax.Array],
+    lambda_init: Optional[float] = None,
+) -> jax.Array:
+    """The block's own queries over ``shared["keys"]`` and
+    ``shared["values"]`` [B, S, kv heads x head_dim] as an earlier block's
+    core read them, causal over the whole span, the core under
+    ``attn/cross_core``; differential as :func:`apply_attention`. Of what
+    a kind that attends is handed it takes the causal flag alone."""
+    if rope is not None or segment_ids is not None or (
+            dropout_rng is not None and cfg.attention_dropout > 0.0):
+        raise NotImplementedError(
+            "a cross_attention block takes no rotation, no packed "
+            "documents (segment_ids) and no dropout of probabilities: set "
+            "model.position_embedding_type=nope, "
+            "data.reset_attention_mask=false, model.attention_dropout=0")
+    B, S, _ = x.shape
+    hd, nq, nkv = cfg.head_dim, cfg.num_attention_heads, cfg.kv_heads
+    f32 = jnp.float32
+    with jax.named_scope("attn/qkv_proj"):
+        q = jnp.einsum("bsh,hf->bsf", x.astype(compute_dtype),
+                       weight_view(p["wq"], compute_dtype),
+                       preferred_element_type=f32)
+        if "bq" in p:
+            q = q + p["bq"]
+        q = q.astype(compute_dtype).reshape(B, S, nq, hd)
+        k = shared["keys"].reshape(B, S, nkv, hd)
+        v = shared["values"].reshape(B, S, nkv, hd)
+    if cfg.differential_attention:
+        v = pair_values(v)
+    with jax.named_scope("attn/cross_core"):
+        out = sdpa_fn(q, k, v, causal=causal)
+    if cfg.differential_attention:
+        out = differential(p, out, cfg, lambda_init)
+    with jax.named_scope("attn/out_proj"):
+        y = jnp.einsum("bsf,fh->bsh", out.reshape(B, S, nq * hd),
+                       weight_view(p["wo"], compute_dtype),
+                       preferred_element_type=f32)
+        if "bo" in p:
+            y = y + p["bo"]
+        return y.astype(compute_dtype)
+
+
+# ---------------------------------------------------------------------------
 # Kimi Delta Attention (a mixer that carries a matrix-valued state, decayed a
 # channel and updated by the delta rule)
 # ---------------------------------------------------------------------------
@@ -1743,9 +2138,15 @@ class Mixer(NamedTuple):
     whether it attends (takes positions, a causal flag, packed documents'
     ``segment_ids`` and a dropout of probabilities), the fields of
     :class:`LayerOps` it reads by the keyword ``apply`` takes each as, what
-    the launcher logs for a block of it (None: its attention core) and, for
-    a kind whose projections no plan may cut over tp, the name of the reason
-    in analysis/eligibility.py."""
+    the launcher logs for a block of it (None: its attention core), for
+    a kind whose projections no plan may cut over tp the name of the reason
+    in analysis/eligibility.py, for a kind that does not attend what of it
+    would cross the boundaries of packed documents, and the values a block
+    of it may leave for later blocks (``leaves``; ``apply`` then takes
+    ``made=``) or takes of an earlier one (``takes``; ``apply`` takes
+    ``shared=``):
+    ``args_schema.SHARED_VALUES`` by kind, ``ModelArgs.block_shares`` by
+    block."""
 
     key: str
     init: Callable
@@ -1754,33 +2155,54 @@ class Mixer(NamedTuple):
     ops: Dict[str, str]
     logged: Optional[str] = None
     uncut_reason: Optional[str] = None
+    crosses_documents: Optional[str] = None
+    leaves: Tuple[str, ...] = ()
+    takes: Tuple[str, ...] = ()
 
     def reads(self, field: str) -> bool:
         return field in self.ops.values()
 
 
+_CARRIED = "the convolution's history and the carried state"
 # a row a mixer kind (``ModelArgs.layer_types``); a new kind is its ``init``
 # and ``apply`` and a row here
 MIXERS: Dict[str, Mixer] = {
     "full_attention": Mixer(
         "attn", init_attention, apply_attention, True,
-        {"sdpa_fn": "sdpa", "matmul_fns": "matmuls", "shard_fn": "shard"}),
+        {"sdpa_fn": "sdpa", "matmul_fns": "matmuls", "shard_fn": "shard"},
+        leaves=SHARED_VALUES["cross_attention"][0]),
     "conv": Mixer(
         "conv", init_short_conv, apply_short_conv, False,
-        {"shard_fn": "shard", "conv_fn": "conv"}, "short_conv"),
+        {"shard_fn": "shard", "conv_fn": "conv"}, "short_conv",
+        crosses_documents="the convolution's two tokens of history"),
     "mamba": Mixer(
         "mamba", init_mamba2, apply_mamba2, False,
-        {"ssd_fn": "ssd", "conv_fn": "conv"}, "mamba2"),
+        {"ssd_fn": "ssd", "conv_fn": "conv"}, "mamba2",
+        crosses_documents=_CARRIED),
     "latent_attention": Mixer(
         "attn", init_latent_attention, apply_latent_attention, True,
         {"sdpa_fn": "sdpa"}, uncut_reason="latent_plan_reason"),
     "kda": Mixer(
         "kda", init_kda, apply_kda, False,
         {"kda_fn": "kda", "conv_fn": "conv"}, "kda",
-        uncut_reason="kda_plan_reason"),
+        uncut_reason="kda_plan_reason", crosses_documents=_CARRIED),
     "sliding_attention": Mixer(
         "attn", init_attention, partial(apply_attention, windowed=True),
         True, {"sdpa_fn": "sdpa"}, uncut_reason="window_plan_reason"),
+    "mamba1": Mixer(
+        "mamba1", init_mamba1, apply_mamba1, False, {"conv_fn": "conv"},
+        "mamba1", uncut_reason="mamba1_plan_reason",
+        crosses_documents=_CARRIED,
+        leaves=SHARED_VALUES["gmu"][0]),
+    "gmu": Mixer(
+        "gmu", init_gmu, apply_gmu, False, {}, "gmu",
+        uncut_reason="shared_plan_reason",
+        crosses_documents="the memory it reads, an earlier block's carried "
+        "state,", takes=SHARED_VALUES["gmu"][0]),
+    "cross_attention": Mixer(
+        "attn", init_cross_attention, apply_cross_attention, True,
+        {"sdpa_fn": "sdpa"}, uncut_reason="shared_plan_reason",
+        takes=SHARED_VALUES["cross_attention"][0]),
 }
 
 
@@ -1803,21 +2225,25 @@ def apply_mixer(
     causal: bool = True,
     dropout_rng: Optional[jax.Array] = None,
     segment_ids: Optional[jax.Array] = None,
+    shared: Optional[Dict[str, jax.Array]] = None,
+    made: Optional[Dict[str, jax.Array]] = None,
+    lambda_init: Optional[float] = None,
 ) -> jax.Array:
     """A block's operator on its normed input, by the block's mixer kind
     (``ModelArgs.block_kinds``, a row of :data:`MIXERS`), from the block's
     parameters under the row's key. Here ``ops`` is taken apart: each kind
     is handed the fields its row names, as the keywords its ``apply``
     takes, and no other. A kind that does not attend takes no rope, no
-    attention core and no dropout of probabilities."""
+    attention core and no dropout of probabilities. ``shared`` holds what
+    earlier blocks left (a kind that reads is handed the values its row
+    names) and ``made``, where this block leaves something, is the dict
+    its ``apply`` writes it into. ``lambda_init``: the block's constant
+    under ``cfg.differential_attention`` (:func:`diff_lambda_init`)."""
     row = mixer_of(mixer)
     if not row.attends and segment_ids is not None:
         raise NotImplementedError(
             f"packed documents (segment_ids) through a {mixer} block: "
-            + ("the convolution's two tokens of history"
-               if mixer == "conv" else
-               "the convolution's history and the carried state")
-            + " would cross document boundaries; set "
+            f"{row.crosses_documents} would cross document boundaries; set "
             "data.reset_attention_mask=false")
     if row.uncut_reason and (ops.shard is not None or ops.matmuls):
         raise NotImplementedError(
@@ -1829,6 +2255,21 @@ def apply_mixer(
     if row.attends:
         kwargs.update(rope=rope, causal=causal, dropout_rng=dropout_rng,
                       segment_ids=segment_ids)
+        if cfg.differential_attention:
+            kwargs["lambda_init"] = lambda_init
+    if row.takes:
+        missing = [name for name in row.takes if name not in (shared or {})]
+        if missing:
+            raise ValueError(
+                f"a {mixer} block reads the {' and '.join(missing)} an "
+                "earlier block left, and none is handed to it (the walk of "
+                "builder.forward_causal_lm carries them)")
+        kwargs["shared"] = {name: shared[name] for name in row.takes}
+    if made is not None:
+        if not row.leaves:
+            raise ValueError(f"a {mixer} block leaves nothing for later "
+                             "blocks")
+        kwargs["made"] = made
     return row.apply(p[row.key], h, cfg, compute_dtype=compute_dtype,
                      **kwargs)
 
@@ -1988,6 +2429,9 @@ def apply_decoder_layer(
     segment_ids: Optional[jax.Array] = None,
     mixer: str = "full_attention",
     feed_forward: Optional[Callable[[jax.Array], jax.Array]] = None,
+    shared: Optional[Dict[str, jax.Array]] = None,
+    made: Optional[Dict[str, jax.Array]] = None,
+    lambda_init: Optional[float] = None,
 ) -> jax.Array:
     """Pre-norm residual block (reference GalvatronDecoderLayer,
     modules.py:233). Encoder families (bert, t5 encoder stack) run the same
@@ -2000,7 +2444,10 @@ def apply_decoder_layer(
     branch on its normed input where the block's is not the dense MLP of
     ``p["mlp"]`` (models/moe.py::apply_moe_decoder_layer). A model of several
     residual streams (``cfg.hc_mult``) hands ``x`` [B, S, n, H] and the
-    block's maps ``hc1`` / ``hc2`` (:func:`residual`)."""
+    block's maps ``hc1`` / ``hc2`` (:func:`residual`). ``shared`` / ``made``
+    / ``lambda_init``: what the block's operator reads of earlier blocks,
+    the dict it writes what it leaves into and its constant of differential
+    attention (:func:`apply_mixer`)."""
     if causal is None:
         causal = cfg.model_type != "bert"
     r_attn = r_res1 = r_res2 = None
@@ -2014,7 +2461,9 @@ def apply_decoder_layer(
         return drop_h(apply_mixer(p, h, cfg, mixer, ops=ops, rope=rope,
                                   compute_dtype=compute_dtype, causal=causal,
                                   dropout_rng=r_attn,
-                                  segment_ids=segment_ids), r_res1)
+                                  segment_ids=segment_ids, shared=shared,
+                                  made=made, lambda_init=lambda_init),
+                      r_res1)
 
     def fed(h):
         return drop_h(

@@ -470,7 +470,8 @@ COLLECTIVE_OPS = ("all-to-all", "all-gather", "all-reduce", "reduce-scatter",
 # literal of those files in it.
 SCOPES: Tuple[str, ...] = (
     "embed", "norm", "attn/qkv_proj", "attn/qk_norm", "attn/rope",
-    "attn/core", "attn/window_core", "attn/gate", "attn/out_proj",
+    "attn/core", "attn/window_core", "attn/cross_core", "attn/diff",
+    "attn/gate", "attn/out_proj",
     "attn/latent_proj", "hc/maps", "hc/mix",
     "mtp/embed_proj", "mtp/block", "mtp/head",
     # (a tower's own before the names they end in: of two names that end at
@@ -486,20 +487,26 @@ SCOPES: Tuple[str, ...] = (
     "mixer/mamba/in_proj", "mixer/mamba/conv", "mixer/mamba/ssd",
     "mixer/mamba/gated_norm", "mixer/mamba/out_proj",
     "mixer/kda/in_proj", "mixer/kda/conv", "mixer/kda/gates",
-    "mixer/kda/scan", "mixer/kda/gated_norm", "mixer/kda/out_proj")
+    "mixer/kda/scan", "mixer/kda/gated_norm", "mixer/kda/out_proj",
+    "mixer/mamba1/in_proj", "mixer/mamba1/conv", "mixer/mamba1/x_proj",
+    "mixer/mamba1/scan", "mixer/mamba1/gate", "mixer/mamba1/out_proj",
+    "mixer/gmu/in_proj", "mixer/gmu/gate", "mixer/gmu/out_proj")
 PHASES = ("forward", "recompute", "backward", "update", "other")
 # the scopes ``step_scopes()["scopes"]`` lists by instruction, by mixer kind
 # (what the ``granite_*`` readers join a trace to, by PR 35's rule: an
 # instruction by its own ``op_name``)
-MIXER_SCOPES = {kind: tuple(s for s in SCOPES
-                            if s.startswith(f"mixer/{kind}/"))
-                for kind in ("mamba", "kda")}
+MIXER_SCOPES: Dict[str, Tuple[str, ...]] = {
+    kind: tuple(s for s in SCOPES if s.startswith(f"mixer/{kind}/"))
+    for kind in dict.fromkeys(s.split("/")[1] for s in SCOPES
+                              if s.startswith("mixer/"))}
 # the further prediction depth's three parts: what is under them by the
 # deepest scope is the block's own (``attn/core``, ``moe/experts``, ``head``),
 # so a reader that wants the depth's whole time takes these lists
 MTP_SCOPES = tuple(s for s in SCOPES if s.startswith("mtp/"))
-# what ``step_scopes()["scopes"]`` lists by instruction
-OWN_SCOPES = MIXER_SCOPES["mamba"] + MTP_SCOPES + MIXER_SCOPES["kda"]
+# what ``step_scopes()["scopes"]`` lists by instruction: every mixer's
+# parts and the further depth's
+OWN_SCOPES = MTP_SCOPES + tuple(
+    s for scopes in MIXER_SCOPES.values() for s in scopes)
 # the scope of the state-space scan, whose Mosaic calls the step report
 # counts (``ssd/mosaic_calls``; ops/pallas/ssd.py traces under it)
 SSD_SCOPE = "mixer/mamba/ssd"
@@ -520,7 +527,7 @@ _ARRAY_DIMS = re.compile(r"\b(?:pred|[sufb]\w*\d+)\[([\d,]+)\]")
 # the scopes a causal depthwise convolution is entered under, one a mixer
 # kind, and its kernels' names there (ops/pallas/conv.py), which the step
 # report counts by phase (:func:`conv_kernel_calls`)
-CONV_SCOPES = ("mixer/kda/conv", "mixer/mamba/conv",
+CONV_SCOPES = ("mixer/kda/conv", "mixer/mamba/conv", "mixer/mamba1/conv",
                "mixer/short_conv/gate_conv")
 CONV_CALLS = ("causal_conv_fwd", "causal_conv_bwd")
 

@@ -1111,6 +1111,135 @@ def _tower_from_hf(sd: Dict[str, Any], cfg: ModelArgs) -> Params:
                       for leaf in ("pre_norm", *_PROJECTOR_HF)}}
 
 
+# the ``phi4flash`` layout (``cfg.hf_layout``; microsoft/Phi-4-mini-flash-
+# reasoning's ``modeling_phi4flash.py``): every block's operator under
+# ``attn``, LayerNorms with a bias, gate | up in one matrix. A mamba1 or gmu
+# block's leaves -> their names under ``attn.``; Conv1d's depthwise kernel
+# is [channels, 1, taps]
+_PHI4FLASH_MIXER_NAMES = {
+    "mamba1": {"win": "in_proj.weight", "taps": "conv1d.weight",
+               "conv_bias": "conv1d.bias", "wx": "x_proj.weight",
+               "wdt": "dt_proj.weight", "dt_bias": "dt_proj.bias",
+               "A_log": "A_log", "D": "D", "wout": "out_proj.weight"},
+    "gmu": {"win": "in_proj.weight", "wout": "out_proj.weight"}}
+_PHI4FLASH_LAMBDAS = ("lambda_q1", "lambda_k1", "lambda_q2", "lambda_k2")
+_PHI4FLASH_NORMS = {"ln1": "input_layernorm", "ln2": "post_attention_layernorm"}
+
+
+def _diff_query_columns(cfg: ModelArgs, i: int, to_hf: bool) -> np.ndarray:
+    """The order of a query projection's columns on the other side: a block
+    under differential attention keeps its query heads as the core reads
+    them (``modules.diff_core_order``), the public layout pairs heads ``2j``
+    and ``2j + 1``; the identity without it."""
+    from hetu_galvatron_tpu.models.modules import diff_core_order
+
+    nq, hd = cfg.block_heads(i), cfg.head_dim
+    if not cfg.differential_attention:
+        return np.arange(nq * hd)
+    heads = np.asarray(diff_core_order(nq, cfg.kv_heads))
+    if to_hf:
+        heads = np.argsort(heads)
+    return (heads[:, None] * hd + np.arange(hd)).reshape(-1)
+
+
+def _phi4flash_params_to_hf(params: Params, cfg: ModelArgs
+                            ) -> Dict[str, np.ndarray]:
+    get = lambda t: np.asarray(jax.device_get(t))
+    sd = {"model.embed_tokens.weight":
+          get(params["embed"]["wte"])[:cfg.vocab_size]}
+
+    def put_norm(name, p):
+        sd[name + ".weight"] = get(p["scale"])
+        sd[name + ".bias"] = get(p["bias"])
+
+    for i, (lp, (mixer, ff)) in enumerate(zip(params["layers"],
+                                              cfg.block_kinds())):
+        pre = f"model.layers.{i}."
+        if ff != "dense":
+            raise _unknown_mixer(i, f"{mixer}/{ff}")
+        for leaf, name in _PHI4FLASH_NORMS.items():
+            put_norm(pre + name, lp[leaf])
+        sd[pre + "mlp.gate_up_proj.weight"] = get(lp["mlp"]["win"]).T
+        sd[pre + "mlp.down_proj.weight"] = get(lp["mlp"]["wout"]).T
+        pre += "attn."
+        if mixer in _PHI4FLASH_MIXER_NAMES:
+            mp = lp[mixer]
+            for leaf, name in _PHI4FLASH_MIXER_NAMES[mixer].items():
+                w = get(mp[leaf])
+                sd[pre + name] = (w.T if leaf.startswith("w")
+                                  else w[:, None, :] if leaf == "taps" else w)
+            continue
+        if mixer not in ("full_attention", "sliding_attention",
+                         "cross_attention"):
+            raise _unknown_mixer(i, mixer)
+        ap = lp["attn"]
+        cols = _diff_query_columns(cfg, i, to_hf=True)
+        if mixer == "cross_attention":
+            w, b = get(ap["wq"])[:, cols], get(ap["bq"])[cols]
+        else:
+            w, b = get(ap["wqkv"]), get(ap["bqkv"])
+            w = np.concatenate([w[:, :cols.size][:, cols], w[:, cols.size:]],
+                               axis=1)
+            b = np.concatenate([b[:cols.size][cols], b[cols.size:]])
+        sd[pre + "Wqkv.weight"], sd[pre + "Wqkv.bias"] = w.T, b
+        sd[pre + "out_proj.weight"] = get(ap["wo"]).T
+        sd[pre + "out_proj.bias"] = get(ap["bo"])
+        if cfg.differential_attention:
+            for name, row in zip(_PHI4FLASH_LAMBDAS, get(ap["lambdas"])):
+                sd[pre + name] = row
+            sd[pre + "subln.weight"] = get(ap["subln"]["scale"])
+    put_norm("model.final_layernorm", params["prenorm"])
+    return sd
+
+
+def _phi4flash_hf_to_params(sd: Dict[str, Any], cfg: ModelArgs) -> Params:
+    def norm(name):
+        return {"scale": sd[name + ".weight"], "bias": sd[name + ".bias"]}
+
+    layers = []
+    for i, (mixer, ff) in enumerate(cfg.block_kinds()):
+        pre = f"model.layers.{i}."
+        if ff != "dense":
+            raise _unknown_mixer(i, f"{mixer}/{ff}")
+        lp: Params = {leaf: norm(pre + name)
+                      for leaf, name in _PHI4FLASH_NORMS.items()}
+        lp["mlp"] = {"win": sd[pre + "mlp.gate_up_proj.weight"].T,
+                     "wout": sd[pre + "mlp.down_proj.weight"].T}
+        pre += "attn."
+        if mixer in _PHI4FLASH_MIXER_NAMES:
+            lp[mixer] = {
+                leaf: (w.T if leaf.startswith("w")
+                       else w[:, 0, :] if leaf == "taps" else w)
+                for leaf, w in ((leaf, sd[pre + name]) for leaf, name in
+                                _PHI4FLASH_MIXER_NAMES[mixer].items())}
+        elif mixer in ("full_attention", "sliding_attention",
+                       "cross_attention"):
+            cols = _diff_query_columns(cfg, i, to_hf=False)
+            w, b = sd[pre + "Wqkv.weight"].T, sd[pre + "Wqkv.bias"]
+            ap: Params = {"wo": sd[pre + "out_proj.weight"].T,
+                          "bo": sd[pre + "out_proj.bias"]}
+            if mixer == "cross_attention":
+                ap.update(wq=w[:, cols], bq=b[cols])
+            else:
+                ap.update(
+                    wqkv=np.concatenate(
+                        [w[:, :cols.size][:, cols], w[:, cols.size:]], axis=1),
+                    bqkv=np.concatenate([b[:cols.size][cols],
+                                         b[cols.size:]]))
+            if cfg.differential_attention:
+                ap["lambdas"] = np.stack(
+                    [sd[pre + name] for name in _PHI4FLASH_LAMBDAS])
+                ap["subln"] = {"scale": sd[pre + "subln.weight"]}
+            lp["attn"] = ap
+        else:
+            raise _unknown_mixer(i, mixer)
+        layers.append(lp)
+    return {"embed": {"wte": _pad_vocab(sd["model.embed_tokens.weight"],
+                                        cfg)},
+            "layers": tuple(layers),
+            "prenorm": norm("model.final_layernorm"), "head": {}}
+
+
 def _unknown_mixer(i: int, mixer: str) -> ValueError:
     from hetu_galvatron_tpu.models.modules import MIXERS
 
@@ -1172,6 +1301,8 @@ def hf_to_params(state_dict: Dict[str, Any], cfg: ModelArgs) -> Params:
             "head": head,
         }
 
+    if cfg.hf_layout == "phi4flash":
+        return _phi4flash_hf_to_params(sd, cfg)
     if cfg.model_type == "bert" or "bert.embeddings.word_embeddings.weight" in sd:
         return _bert_hf_to_params(sd, cfg)
     if cfg.model_type == "t5" or "encoder.final_layer_norm.weight" in sd:
@@ -1600,6 +1731,8 @@ def params_to_hf(params: Params, cfg: ModelArgs) -> Dict[str, np.ndarray]:
         return _bert_params_to_hf(params, cfg)
     if cfg.model_type == "t5":
         return _t5_params_to_hf(params, cfg)
+    if cfg.hf_layout == "phi4flash":
+        return _phi4flash_params_to_hf(params, cfg)
     if cfg.model_type == "gpt":
         sd["transformer.wte.weight"] = get(params["embed"]["wte"])[:V]
         sd["transformer.wpe.weight"] = get(params["embed"]["wpe"])

@@ -540,7 +540,7 @@ class PipelineEngine:
                 sh.checkpoint)
             # per-layer router stats are an spmd-path feature; the stage
             # programs fold only the aux scalar into the loss
-            x, aux, _ = fn(lp, x)
+            x, aux, _, _ = fn(lp, x, {})
             aux_total = aux_total + aux
         if not st.has_head:
             # a stage may carry zero decoder layers (embed-only stage 0)

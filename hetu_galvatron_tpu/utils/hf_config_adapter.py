@@ -56,8 +56,14 @@ _LAGUNA = "laguna"
 # attention blocks with a q/k norm a head, softmax top-k experts in every
 # block, no shared expert
 _MELLUM = "mellum"
+# Phi-4-mini-flash-reasoning (microsoft; ``modeling_phi4flash.py``, SambaY,
+# arXiv:2507.06607): Mamba-1 and window-attention blocks in turn, then one
+# Mamba-1 and one full-attention block whose scan output and keys and values
+# the second half's gated memory units and cross-attention blocks read;
+# differential attention; LayerNorms; no positions
+_PHI4FLASH = "phi4flash"
 # families that state for themselves whether they have positions
-_OWN_POSITIONS = {_GRANITE_HYBRID, _KIMI_LINEAR}
+_OWN_POSITIONS = {_GRANITE_HYBRID, _KIMI_LINEAR, _PHI4FLASH}
 _ROPE_FAMILIES = {"llama", "qwen2", "mistral", "mixtral", "olmoe",
                   "qwen", _XING, _LAGUNA, _MELLUM, _KIMI_VL} \
     | _GEMMA_FAMILIES \
@@ -65,7 +71,7 @@ _ROPE_FAMILIES = {"llama", "qwen2", "mistral", "mixtral", "olmoe",
 _RMS_FAMILIES = _ROPE_FAMILIES | {"t5", _GRANITE_HYBRID, _KIMI_LINEAR}
 _SWIGLU_FAMILIES = {"llama", "qwen2", "mistral", "mixtral", "olmoe",
                     "qwen", _GRANITE_HYBRID, _XING, _KIMI_LINEAR,
-                    _LAGUNA, _MELLUM, _KIMI_VL} | _LFM2_FAMILIES
+                    _LAGUNA, _MELLUM, _KIMI_VL, _PHI4FLASH} | _LFM2_FAMILIES
 # gemma-2/3 add sandwich norms (a norm after each sub-layer as well as
 # before it), logit softcapping (attention and final) and a softmax scale
 # from query_pre_attn_scalar; v3 also a q/k norm a head with zero-centred
@@ -194,6 +200,8 @@ def populate_model_args_from_hf(
         values.update(_laguna_values(d))
     if family == _MELLUM:
         values.update(_mellum_values(d))
+    if family == _PHI4FLASH:
+        values.update(_phi4flash_values(d))
     if family == "bert":
         # HF bert uses erf gelu everywhere (BertIntermediate + the MLM
         # transform); our "gelu" is the tanh approximation (gpt2's gelu_new)
@@ -431,6 +439,46 @@ def _mellum_values(d: Dict[str, Any]) -> Dict[str, Any]:
     if by_kind:
         out["rope_parameters"] = by_kind
     return out
+
+
+def phi4flash_layer_types(n: int, mb_per_layer: int = 2) -> List[str]:
+    """The kinds of a ``phi4flash`` stack of ``n`` blocks
+    (``configuration_phi4flash.py``; SambaY, arXiv:2507.06607): a Mamba-1
+    block at every ``mb_per_layer``-th index; in the first half window
+    attention between them; block ``n / 2`` is the Mamba-1 block whose scan
+    output is kept and ``n / 2 + 1`` the full attention whose keys and
+    values are kept; after them gated memory units at the Mamba indices and
+    cross-attention between."""
+    if mb_per_layer != 2 or n % 2 or n < 4:
+        raise NotImplementedError(
+            f"{_PHI4FLASH} mb_per_layer={mb_per_layer} over {n} blocks: a "
+            "Mamba block at every other index of an even stack of at least "
+            "four is implemented")
+    half = n // 2
+    return [("mamba1" if i <= half else "gmu") if i % 2 == 0
+            else "sliding_attention" if i < half
+            else "full_attention" if i == half + 1 else "cross_attention"
+            for i in range(n)]
+
+
+def _phi4flash_values(d: Dict[str, Any]) -> Dict[str, Any]:
+    """``config.json`` gives the sizes; the state-space sizes are the
+    family's defaults (``configuration_phi4flash.py``: ``mamba_d_state`` 16,
+    ``mamba_d_conv`` 4, ``mamba_expand`` 2, ``mamba_dt_rank`` "auto" =
+    ceil(hidden_size / 16))."""
+    rank = d.get("mamba_dt_rank", "auto")
+    return dict(
+        model_type="llama", hf_layout="phi4flash", num_experts=0, moe_topk=2,
+        position_embedding_type="nope", normalization="layernorm",
+        layer_types=phi4flash_layer_types(int(d["num_hidden_layers"]),
+                                          int(d.get("mb_per_layer", 2))),
+        sliding_window=int(d["sliding_window"]),
+        differential_attention=True, add_attn_out_bias=True,
+        mamba1_d_state=int(d.get("mamba_d_state", 16)),
+        mamba1_d_conv=int(d.get("mamba_d_conv", 4)),
+        mamba1_expand=int(d.get("mamba_expand", 2)),
+        mamba1_dt_rank=None if rank in (None, "auto") else int(rank),
+        tie_word_embeddings=bool(d.get("tie_word_embeddings", True)))
 
 
 def _kimi_linear_values(d: Dict[str, Any]) -> Dict[str, Any]:

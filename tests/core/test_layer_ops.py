@@ -43,7 +43,13 @@ def test_the_table_names_fields_the_record_has_and_keywords_the_leaves_take():
         assert row.attends == ({"rope", "causal", "dropout_rng",
                                 "segment_ids"} <= takes), kind
     assert [k for k, row in M.MIXERS.items() if row.attends] == [
-        "full_attention", "latent_attention", "sliding_attention"]
+        "full_attention", "latent_attention", "sliding_attention",
+        "cross_attention"]
+    # a kind that leaves or takes values of other blocks takes the keyword
+    for kind, row in M.MIXERS.items():
+        takes = set(inspect.signature(row.apply).parameters)
+        assert "made" in takes or not row.leaves, kind
+        assert ("shared" in takes) == bool(row.takes), kind
 
 
 @pytest.mark.parametrize("kernels", [True, None])
@@ -79,10 +85,13 @@ def test_a_kind_is_handed_its_rows_fields_under_its_own_keywords(
     monkeypatch.setitem(M.MIXERS, kind, row._replace(apply=leaf))
     full = M.LayerOps(**{f: f for f in FIELDS if f not in (
         ("matmuls", "shard") if row.uncut_reason else ())})
+    # what earlier blocks left: a kind is handed the values its row names
+    left = {name: name for name in ("memory", "keys", "values")}
     M.apply_mixer({row.key: "mine"}, jnp.zeros((1, 2, 4)), ModelArgs(), kind,
-                  ops=full, compute_dtype=jnp.float32)
+                  ops=full, compute_dtype=jnp.float32, shared=left)
     assert seen.pop("p") == "mine" and seen.pop(
         "compute_dtype") == jnp.float32
+    assert seen.pop("shared", {}) == {name: name for name in row.takes}
     if row.attends:
         assert (seen.pop("rope"), seen.pop("causal"), seen.pop("dropout_rng"),
                 seen.pop("segment_ids")) == (None, True, None, None)
@@ -90,7 +99,8 @@ def test_a_kind_is_handed_its_rows_fields_under_its_own_keywords(
                     if getattr(full, field) is not None}
     # and an empty record leaves every leaf its own defaults
     seen.clear()
-    M.apply_mixer({row.key: "mine"}, jnp.zeros((1, 2, 4)), ModelArgs(), kind)
+    M.apply_mixer({row.key: "mine"}, jnp.zeros((1, 2, 4)), ModelArgs(), kind,
+                  shared=left)
     assert not set(seen) & set(row.ops)
 
 

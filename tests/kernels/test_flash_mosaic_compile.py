@@ -93,10 +93,19 @@ _CASES = {
                                False, True, 0.0),
     "two_way_f32_segments_two_major_blocks": (2, 4096, 4096, 4, 2, 128,
                                               jnp.float32, False, True, 0.0),
+    # phi4flash_c1_b1: differential attention's one core call a block, 40
+    # score heads at q/k 64 over the pair's value of 128 handed to both of
+    # its 20 key heads; whole and under the window of 512. 64 / 128 is
+    # outside the rows form (``row_layout``: whole lane tiles, or 64 / 64)
+    "phi4flash_cell_64_128": (1, 8192, 8192, 40, 20, (64, 128), jnp.bfloat16,
+                              True, False, 0.0),
+    "phi4flash_cell_64_128_window_512": (1, 8192, 8192, 40, 20, (64, 128),
+                                         jnp.bfloat16, True, False, 0.0, 512),
 }
 # the cases whose call keeps the head-major kernels between transposes
 _TRANSPOSED = {"latent_cell_192_128", "latent_f32_segments",
-               "tower_cell_72_segments"}
+               "tower_cell_72_segments", "phi4flash_cell_64_128",
+               "phi4flash_cell_64_128_window_512"}
 
 
 @pytest.mark.parametrize("case", sorted(_CASES))
@@ -239,6 +248,8 @@ _CONV_CASES = {
     "two_rows_f32_a_ragged_tile": (
         2, 1100, 768, 4, True, True, (128, 1e-6, (1.0, None, 0.5)),
         jnp.float32, "mixer/kda/conv"),
+    "phi4flash_cell": (1, 8192, 5120, 4, True, False, None, jnp.bfloat16,
+                       "mixer/mamba1/conv"),
 }
 
 
