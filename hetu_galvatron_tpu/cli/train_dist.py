@@ -1418,6 +1418,23 @@ def train(args) -> Dict[str, Any]:
                             step_report[gauge])
                     step_report["static_memory"] = compiled_memory_bytes(
                         compiled)
+                    # which of the blocks whose plan bit is set hold their
+                    # values and which make them again, and the bytes the
+                    # step program counted for the kept against the budget
+                    # the device left (parallel/spmd.py::KeptStep)
+                    from hetu_galvatron_tpu.parallel.spmd import kept_report
+
+                    kept_blocks = kept_report(fn, cfg, hpc)
+                    step_report["kept_blocks"] = kept_blocks
+                    for name in ("blocks_kept", "blocks_recomputed"):
+                        for stack, n in kept_blocks[name].items():
+                            get_registry().gauge(f"step/{name}",
+                                                 stack=stack).set(n)
+                    for name, key in (("kept_bytes", "kept_bytes"),
+                                      ("kept_budget_bytes", "budget_bytes"),
+                                      ("kept_fallback", "fallback")):
+                        get_registry().gauge(f"step/{name}").set(
+                            kept_blocks[key])
                     for part, v in step_report["static_memory"].items():
                         get_registry().gauge("step/static_bytes",
                                              part=part).set(v)
@@ -1463,6 +1480,17 @@ def train(args) -> Dict[str, Any]:
                        if ep_report else "")
                     + "".join(f", moe[{name}] {body}"
                               for name, body in expert_bodies.items())
+                    + ", kept {} of {} blocks, {:.2f} of {:.2f} GiB".format(
+                        sum(kept_blocks["blocks_kept"].values()),
+                        sum(kept_blocks["blocks_kept"].values())
+                        + sum(kept_blocks["blocks_recomputed"].values()),
+                        kept_blocks["kept_bytes"] / 2**30,
+                        kept_blocks["budget_bytes"] / 2**30)
+                    # (what the choice cost the set-up: the count's traces,
+                    # and the chosen step's compile before its first call,
+                    # which that call then finds cached)
+                    + " (counted in {count_s:.2f} s, checked in {check_s:.2f}"
+                      " s)".format(**kept_blocks)
                     + f", {step_report['cores_recomputed']} cores recomputed,"
                     f" {step_report['scans_recomputed']} scans recomputed,"
                     f" static live peak "
@@ -1540,6 +1568,13 @@ def train(args) -> Dict[str, Any]:
             # step/scans_recomputed; 0 = every scan's output and entering
             # states are kept); None for the pp engines
             "scans_recomputed": step_report.get("scans_recomputed"),
+            # of the blocks whose plan bit is set, how many hold their
+            # values and how many make them again, by stack
+            # (step/blocks_kept, step/blocks_recomputed), the bytes counted
+            # for the kept and the budget (step/kept_bytes,
+            # step/kept_budget_bytes), and whether the step fell back to
+            # the plan's flags (step/kept_fallback); None for the pp engines
+            "kept_blocks": step_report.get("kept_blocks"),
             # XLA's static memory of the compiled pp=1 step, per device, in
             # bytes (the step/static_bytes gauges); None for the pp engines
             "static_memory": step_report.get("static_memory"),

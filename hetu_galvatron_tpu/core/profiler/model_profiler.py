@@ -154,8 +154,11 @@ class ModelProfiler:
         mesh = build_mesh(world, 1, devices=devices)
         params, axes = init_causal_lm(jax.random.key(0), cfg)
         tx = make_optimizer(self.args.train)
+        # (the tables say what a layer's checkpoint bit costs: every block
+        # whose bit is set recomputes, whatever the device has left)
         step, pspecs, _, batch_shd = make_spmd_train_step(
-            cfg, hpc, mesh, axes, tx, params, donate=False)
+            cfg, hpc, mesh, axes, tx, params, donate=False,
+            keep_blocks=False)
         tokens = jax.ShapeDtypeStruct((bsz, cfg.seq_length), jnp.int32)
         batch = {"tokens": tokens, "labels": tokens}
         if cfg.model_type == "t5":

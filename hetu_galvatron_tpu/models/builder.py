@@ -153,10 +153,11 @@ def rope_table(cfg: ModelArgs, seq: int, kind: Optional[str],
 
 
 def make_block(cfg: ModelArgs, kind: Tuple[str, str],
-               kwargs: Dict[str, Any], remat: bool, leaves: bool = False):
+               kwargs: Dict[str, Any], remat, leaves: bool = False):
     """``fn(block params, x, shared) -> (x, aux loss, router stats, made)``
     of one block of ``kind`` with its keyword arguments, rematerialized
-    where asked. ``shared`` holds what the block reads of earlier blocks
+    where asked (``remat``: the block's flag, :func:`modules.recomputed`).
+    ``shared`` holds what the block reads of earlier blocks
     (an argument of the rematerialized function, so its cotangent flows
     back to the block that made it) and ``made`` what it ``leaves`` for
     later ones (an output of it); both empty for most blocks."""
@@ -172,7 +173,7 @@ def make_block(cfg: ModelArgs, kind: Tuple[str, str],
         return (M.apply_decoder_layer(p, h, cfg, **handed),
                 jnp.zeros((), jnp.float32), {}, made)
 
-    return M.remat(fn, cfg) if remat else fn
+    return M.recomputed(fn, cfg, remat)
 
 
 def forward_causal_lm(
@@ -218,7 +219,11 @@ def forward_causal_lm(
     block-diagonalized per document (dataloader.packed_doc_fields).
 
     ``remat_flags[i]`` turns on `jax.checkpoint` for layer i (the reference's
-    per-layer checkpoint_flags_enc, parallel.py:213-243). ``layer_overrides``
+    per-layer checkpoint_flags_enc, parallel.py:213-243). A flag, here and
+    in ``tower_remat_flags``, is a bool or, while the step program counts
+    what a block would hold, a callable ``flag(fn, cfg) -> fn`` that stands
+    in for the block (:func:`modules.recomputed`, its one reader;
+    ``parallel/kept.py::Probe``, its one writer). ``layer_overrides``
     maps layer index -> what the layer's plan swaps in the block
     (:class:`modules.LayerOps`, e.g. the attention core of a Ulysses or ring
     layer; a layer without an entry runs the ``jax.numpy`` / XLA forms).
@@ -292,7 +297,7 @@ def forward_causal_lm(
         leaves, takes = shares[i]
         x, aux, stats, made = make_block(
             cfg.for_block(i), kinds[i], kwargs,
-            remat_flags is not None and bool(remat_flags[i]),
+            remat_flags is not None and remat_flags[i],
             leaves=bool(leaves))(lp, x, {k: shared[k] for k in takes})
         # sharded as the stream is
         shared.update(made if boundary_fn is None else {
@@ -314,7 +319,7 @@ def forward_causal_lm(
         mtp_logits, aux, stats = forward_mtp(
             params, x, mtp_labels, cfg, block_fn=make_block(
                 cfg.for_block(len(kinds) - 1), mtp_block_kind(cfg), kwargs,
-                remat_flags is not None and bool(remat_flags[-1])),
+                remat_flags is not None and remat_flags[-1]),
             compute_dtype=compute_dtype)
         aux_total = aux_total + aux
         if stats:

@@ -247,7 +247,9 @@ def forward_encdec(
     layers; the ``enc_*`` triplet indexes ENCODER layers (heterogeneous
     per-layer encoder plans — the combined-stack strategy list of
     runtime/hybrid_config.py). When ``enc_remat_flags`` is None the encoder
-    falls back to ``remat_flags[0]`` uniformly (legacy behavior)."""
+    falls back to ``remat_flags[0]`` uniformly (legacy behavior). A flag of
+    either list is a bool, or the step program's probe
+    (:func:`modules.recomputed`)."""
     rope_enc = rope_dec = None
     if cfg.position_embedding_type == "rope":
         rope_enc = M.rope_cos_sin(enc_tokens.shape[1], cfg.head_dim,
@@ -274,9 +276,9 @@ def forward_encdec(
             kwargs["dropout_rng"] = M.fold_dropout_rng(
                 dropout_rng, cfg, M.DROPOUT_STREAM_ENC + i)
         fn = lambda p, h, kw=kwargs: M.apply_decoder_layer(p, h, cfg, **kw)
-        if enc_remat_flags is not None and enc_remat_flags[i]:
-            fn = M.remat(fn, cfg)
-        mem = fn(lp, mem)
+        mem = M.recomputed(
+            fn, cfg, enc_remat_flags is not None and enc_remat_flags[i])(
+                lp, mem)
     if enc_boundary_fn is not None:
         mem = enc_boundary_fn(len(params["enc_layers"]), mem)
     mem = M.apply_norm(params["enc_norm"], mem, cfg)
@@ -293,9 +295,8 @@ def forward_encdec(
             kwargs["dropout_rng"] = M.fold_dropout_rng(dropout_rng, cfg, i)
         fn = lambda p, h, m, kw=kwargs: apply_cross_decoder_layer(
             p, h, m, cfg, **kw)
-        if remat_flags is not None and remat_flags[i]:
-            fn = M.remat(fn, cfg)
-        x = fn(lp, x, mem)
+        x = M.recomputed(
+            fn, cfg, remat_flags is not None and remat_flags[i])(lp, x, mem)
     if boundary_fn is not None:
         x = boundary_fn(len(params["layers"]), x)
     x = M.apply_norm(params["prenorm"], x, cfg)

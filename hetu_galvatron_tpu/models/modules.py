@@ -451,6 +451,13 @@ def remat(fn, cfg: ModelArgs):
     TPU lever is WHICH values the backward may keep — saving MXU outputs
     ("dots") trades a little memory for skipping matmul recompute).
 
+    A plan's ``checkpoint`` bit means a block MAY be recomputed; it is,
+    under this wrapper, where its values do not fit: the step program
+    (parallel/spmd.py) counts what each such block would hold and leaves
+    as many of them unwrapped as the device's memory takes
+    (parallel/kept.py). The pipeline engines wrap every block whose bit is
+    set.
+
     Under every policy the results a forward kernel's differentiated rule
     names are kept (each kernel file's ``KEPT``): a flash attention core's
     output and row statistics, a delta-rule or Mamba-2 scan's output and the
@@ -477,6 +484,20 @@ def remat(fn, cfg: ModelArgs):
         policy = policies.save_from_both_policies(base[cfg.remat_policy],
                                                   policy)
     return jax.checkpoint(fn, policy=policy)
+
+
+def recomputed(fn, cfg: ModelArgs, flag):
+    """``fn`` as a block whose remat flag is ``flag`` runs it: as it stands
+    (clear), under :func:`remat` (set), or as ``flag(fn, cfg)`` where the
+    flag is the step program's probe, which counts what the block would hold
+    (parallel/kept.py::Probe). The one place a flag of the stacks' lists
+    (``forward_causal_lm``, ``apply_tower``, ``forward_encdec``) is read:
+    the probe rides in the lists the plan's bits ride in, so that it meets
+    each block where the model builds it, with the arguments it builds it
+    with, and no stack's walk knows of the count."""
+    if callable(flag):
+        return flag(fn, cfg)
+    return remat(fn, cfg) if flag else fn
 
 
 # fold_in stream bases partitioning one per-step dropout key into disjoint

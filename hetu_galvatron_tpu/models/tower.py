@@ -299,7 +299,8 @@ def apply_tower(params: Params, patches: jax.Array, cfg: ModelArgs, *,
                 ops: Optional[M.LayerOps] = None) -> jax.Array:
     """patches ``[B, P, patch_dim]`` (the images of ``cfg.image_grids``
     packed in order) -> the projector's rows ``[B, P / merge, hidden_size]``,
-    in the order of the sequence's image positions."""
+    in the order of the sequence's image positions. ``remat_flags[i]``: a
+    bool, or the step program's probe (:func:`modules.recomputed`)."""
     grids = grids_of(cfg)
     B, P, _ = patches.shape
     if P != sum(cfg.image_patches):
@@ -323,9 +324,8 @@ def apply_tower(params: Params, patches: jax.Array, cfg: ModelArgs, *,
         fn = lambda p, h: apply_tower_block(
             p, h, cfg, rope=rope, segments=segments, sdpa=sdpa,
             compute_dtype=compute_dtype)
-        if remat_flags is not None and bool(remat_flags[i]):
-            fn = M.remat(fn, cfg)
-        x = fn(bp, x)
+        x = M.recomputed(
+            fn, cfg, remat_flags is not None and remat_flags[i])(bp, x)
     with jax.named_scope("tower/merge_project"):
         eps = cfg.tower_layernorm_epsilon
         pp = params["projector"]

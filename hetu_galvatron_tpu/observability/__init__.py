@@ -14,7 +14,10 @@ The measurement substrate the ROADMAP's "measurably faster" contract needs:
   ``train/check`` (``span_ms{path=...}``, and TraceMes with the iteration
   as ``step``); its set-up by ``setup/imports|runtime|init|resume|
   step_report``; the compiled step's static memory is the gauges
-  ``step/static_bytes{part=...}`` (listed in :mod:`tracing`'s docstring).
+  ``step/static_bytes{part=...}``, and which blocks hold their values
+  ``step/blocks_kept{stack=...}``, ``step/blocks_recomputed{stack=...}``,
+  ``step/kept_bytes``, ``step/kept_budget_bytes`` (listed in
+  :mod:`tracing`'s docstring).
 * :mod:`telemetry` — derived training stats: tokens/sec, step-time
   percentiles, model-FLOPs utilization (FLOPs accounting lives in
   ``core/cost_model/cost.py``), device memory gauges, and per-strategy

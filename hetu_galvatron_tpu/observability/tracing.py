@@ -42,7 +42,14 @@ The spans of the launcher (``cli/train_dist.py``), all flat siblings:
   ``step/cores_recomputed`` and ``step/scans_recomputed`` (the flash
   forward kernels, and the recurrent mixers' scan forward kernels,
   per-layer remat runs a second time; 0 where ``modules.remat`` keeps every
-  kernel's results), and keeps every instruction's scope, phase and
+  kernel's results) and ``step/blocks_kept{stack=decoder|tower|encoder}``,
+  ``step/blocks_recomputed{stack=...}``, ``step/kept_bytes``,
+  ``step/kept_budget_bytes`` and ``step/kept_fallback`` (of the blocks
+  whose plan bit is set, how many hold their forward's values and how many
+  make them again; the bytes the step program counted for the kept, of the
+  budget the devices left; 1 where the chosen step did not fit and the
+  plan's flags ran: ``parallel/spmd.py::KeptStep``), and keeps every
+  instruction's scope, phase and
   collective class for a reader of a trace:
   ``trace_analysis.step_hlo``; :class:`TraceCapture` writes them beside the
   trace as ``step_map.json`` when its window closes).

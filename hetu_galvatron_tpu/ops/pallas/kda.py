@@ -584,6 +584,10 @@ def _shapes(q, v, cols):
     return B, S, H, nC, C, hb, P, d, v.shape[-1]
 
 
+# (jitted, as the flash kernels' calls are: blocks of one shape share one
+# trace of the call, the kernel's body included, which is most of what
+# tracing a KDA block costs)
+@functools.partial(jax.jit, static_argnames=("interpret", "keep_states"))
 def _scan_call(q, k, v, G, cols, rows, interpret: bool, keep_states: bool):
     B, S, H, nC, C, hb, P, d, dv = _shapes(q, v, cols)
     wide, wide_v, cols_at, rows_at, states = _specs(nC, C, d, dv, hb, P,
@@ -609,6 +613,7 @@ def _scan_call(q, k, v, G, cols, rows, interpret: bool, keep_states: bool):
     )(q, k, v, G, cols, rows)
 
 
+@functools.partial(jax.jit, static_argnames=("interpret",))
 def _scan_bwd_call(q, k, v, G, cols, rows, entering, do, interpret: bool):
     B, S, H, nC, C, hb, P, d, dv = _shapes(q, v, cols)
     wide, wide_v, cols_at, rows_at, states = _specs(nC, C, d, dv, hb, P,

@@ -13,6 +13,8 @@ through NCCL.
 
 from __future__ import annotations
 
+import logging
+import time
 from dataclasses import replace
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
@@ -24,10 +26,12 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from hetu_galvatron_tpu.core.args_schema import ModelArgs
 from hetu_galvatron_tpu.models.builder import causal_lm_loss
 from hetu_galvatron_tpu.models.modules import LayerOps
+from hetu_galvatron_tpu.parallel import kept
 from hetu_galvatron_tpu.runtime.hybrid_config import HybridParallelConfig
 from hetu_galvatron_tpu.runtime.mesh import (
     LayerSharding,
     attention_core,
+    axes_size,
     flash_kernel_runs,
     lower_strategy,
     lower_vocab_strategy,
@@ -498,6 +502,18 @@ def _lower_specs(hpc: HybridParallelConfig, mesh: Mesh, axes_tree: Params):
     return enc_per, per_layer, vocab, pspecs
 
 
+def plan_remat_flags(cfg: ModelArgs, per_layer: Sequence[Any],
+                     enc_per: Sequence[Any]) -> Dict[str, List[bool]]:
+    """The plan's ``checkpoint`` bits as the model's stacks read them: the
+    decoder's blocks, the tower's (all under the first decoder block's plan)
+    and a t5's encoder's (``per_layer``, ``enc_per``: the layers' lowered
+    shardings, or their strategies). A set bit says the block MAY be
+    recomputed."""
+    return {"decoder": [bool(sh.checkpoint) for sh in per_layer],
+            "tower": [bool(per_layer[0].checkpoint)] * cfg.tower_layers,
+            "encoder": [bool(sh.checkpoint) for sh in enc_per]}
+
+
 def build_spmd_loss_fn(
     cfg: ModelArgs,
     hpc: HybridParallelConfig,
@@ -511,6 +527,7 @@ def build_spmd_loss_fn(
     lane_dp: bool = False,
     kernel_interpret: bool = False,
     hoist_view: bool = False,
+    remat_flags: Optional[Dict[str, Sequence[Any]]] = None,
 ):
     """The plan-lowered loss closure shared by the train and eval steps:
     per-layer shardings, boundary constraints, attention-impl dispatch,
@@ -526,6 +543,9 @@ def build_spmd_loss_fn(
     ineligible layers silently keep GSPMD — the launcher logs the reasons.
     ``kernel_interpret`` runs the Pallas kernels (flash, fused CE) in
     interpret mode: CPU tests pass it, nothing infers it.
+    ``remat_flags`` (:func:`plan_remat_flags`'s lists, by stack) says which
+    blocks are rematerialized; None = every block whose plan bit is set
+    (the train step hands the lists it chose, :class:`KeptStep`).
 
     ``lane_dp`` builds the hierarchical-dp LANE variant: the interior
     activation constraints drop the dp axes (each lane's batch slice lives
@@ -582,8 +602,8 @@ def build_spmd_loss_fn(
     interior, param_view = interior_sharding(b_layers, mesh, cfg,
                                              layer_overrides)
     layer_overrides = merge_ops(interior, layer_overrides)
-    remat = [sh.checkpoint for sh in per_layer]
-    enc_remat = [sh.checkpoint for sh in enc_per]
+    flags = remat_flags or plan_remat_flags(cfg, per_layer, enc_per)
+    remat, enc_remat = flags["decoder"], flags["encoder"]
     batch_shd = batch_sharding(per_layer, mesh)
 
     enc_kwargs = {}
@@ -620,10 +640,10 @@ def build_spmd_loss_fn(
     tower_kwargs = {}
     if cfg.tower_layers:
         # the tower's blocks run under the first decoder block's plan: its
-        # remat flag, and the attention core that plan gives a block that
-        # attends (the flash kernels on a TPU)
+        # remat flag (plan_remat_flags), and the attention core that plan
+        # gives a block that attends (the flash kernels on a TPU)
         tower_kwargs = dict(
-            tower_remat_flags=[per_layer[0].checkpoint] * cfg.tower_layers,
+            tower_remat_flags=flags["tower"],
             tower_ops=({} if lane_dp else attention_overrides(
                 b_layers[:1], mesh, use_flash=use_flash,
                 flash_interpret=kernel_interpret)).get(0))
@@ -670,6 +690,172 @@ def make_spmd_eval_step(
     return jax.jit(loss_fn, in_shardings=(nshd, batch_shd)), batch_shd
 
 
+def _plan_report(plan: Dict[str, List[bool]]) -> Dict[str, Any]:
+    """What :class:`KeptStep` reports where every block whose plan bit is
+    set is recomputed."""
+    return {"blocks_kept": {stack: 0 for stack in plan},
+            "blocks_recomputed": {stack: sum(f) for stack, f in plan.items()},
+            "kept_bytes": 0, "budget_bytes": 0, "fallback": 0,
+            "count_s": 0.0, "check_s": 0.0}
+
+
+class KeptStep:
+    """The train step of a plan whose blocks may be recomputed, on devices
+    that say how much they hold: built at its first call (or ``lower``),
+    when the state and the batch it is handed say what the step's arguments
+    take. Then the loss is traced once with a :class:`kept.Probe` for each
+    block whose bit is set (two traces a kind of block, the rest stubs),
+    the step's static bytes are estimated with every flag as the plan gave
+    it, and as many blocks as ``kept.FILL`` of the devices' ``bytes_limit``
+    leaves room for hold their values (:func:`kept.choose`); a block whose
+    bit is clear is never touched. ``report`` says what was chosen.
+
+    A job that ran before still runs: the chosen step is compiled before
+    its first call, and where the compiler answers ``RESOURCE_EXHAUSTED``,
+    or XLA's own count of the executable passes ``kept.FILL`` of the limit,
+    the step is built again with the plan's flags, one warning names the
+    byte counts and ``report["fallback"]`` reads 1. Every run of a job
+    counts, chooses and looks afresh: the choice is a function of the job
+    and the devices' limit alone, never of what an earlier run left."""
+
+    # where each stack's blocks keep their parameters
+    _params_of = {"decoder": lambda p, i: p["layers"][i],
+                  "tower": lambda p, i: p["tower"]["blocks"][i],
+                  "encoder": lambda p, i: p["enc_layers"][i]}
+
+    def __init__(self, make_step, count, plan, groups, shards, *, limit,
+                 accumulates):
+        self._make, self._count = make_step, count
+        self._plan, self._groups, self._shards = plan, groups, shards
+        self._limit, self._accumulates = limit, accumulates
+        self._step = None
+        self._settled = False
+        self.report: Dict[str, Any] = {}
+
+    def _choose(self, params, opt_state, batch) -> Dict[str, List[bool]]:
+        t0 = time.perf_counter()
+        plan = self._plan
+        f32 = lambda tree: kept.device_bytes(jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(
+                a.shape, jnp.float32, sharding=getattr(a, "sharding", None)),
+            tree))
+        args, grads = kept.device_bytes((params, opt_state, batch)), f32(params)
+        accumulator = 2 * grads if self._accumulates else 0
+        room = int(self._limit * kept.FILL)
+        self.report = {**_plan_report(plan), "limit_bytes": self._limit,
+                       "estimate_bytes": args + accumulator + grads}
+        if args + accumulator + grads >= room:
+            # the arguments and the gradients alone leave nothing: no trace
+            return plan
+        cache: Dict[Any, Any] = {}
+        probes = {stack: [kept.Probe(g, n, cache) if bit else False
+                          for bit, g, n in zip(plan[stack],
+                                               self._groups[stack],
+                                               self._shards[stack])]
+                  for stack in plan}
+        jaxpr, n_out, n_params = self._count(probes, params, opt_state, batch)
+        # in the order the forward runs them: of two blocks of equal worth
+        # the later one's values are held the shorter
+        order = [(stack, i) for stack in ("tower", "encoder", "decoder")
+                 for i, pr in enumerate(probes[stack]) if pr and pr.counts]
+        counts = [probes[stack][i].counts for stack, i in order]
+        outer = kept.residual_bytes(jaxpr, n_out, n_params) // max(
+            self._shards["decoder"][0], 1)
+        estimate = kept.plan_peak(args, accumulator, grads, [
+            (sum(c.input_bytes for c in cs),
+             f32(self._params_of[stack](params, i)),
+             max(c.whole_bytes for c in cs))
+            for (stack, i), cs in zip(order, counts)], outer)
+        budget = max(room - estimate, 0)
+        blocks = [(sum(c.held_bytes for c in cs),
+                   sum(c.forward_flops for c in cs)) for cs in counts]
+        keep = kept.choose(blocks, budget)
+        flags = {stack: list(f) for stack, f in plan.items()}
+        for (stack, i), k in zip(order, keep):
+            flags[stack][i] = not k
+        self.report.update(
+            blocks_kept={k: sum(plan[k]) - sum(flags[k]) for k in plan},
+            blocks_recomputed={k: sum(flags[k]) for k in plan},
+            kept_bytes=sum(b[0] for b, k in zip(blocks, keep) if k),
+            budget_bytes=budget, estimate_bytes=estimate,
+            count_s=time.perf_counter() - t0,
+            counted={"args": args, "grads": grads, "outer": outer,
+                     "blocks": [(stack, i, b[0], cs[0].input_bytes,
+                                 cs[0].whole_bytes)
+                                for (stack, i), b, cs
+                                in zip(order, blocks, counts)]})
+        return flags
+
+    def _built(self, params, opt_state, batch):
+        if self._step is None:
+            self._flags = self._choose(params, opt_state, batch)
+            self._step = self._make(
+                None if self._flags == self._plan else self._flags)
+        return self._step
+
+    def _checked(self, params, opt_state, batch):
+        """The chosen step, compiled and held to ``kept.FILL`` of the
+        devices' memory by XLA's own count (the call that follows finds the
+        executable cached); the plan's step where it does not compile for
+        memory or compiles to more."""
+        step = self._built(params, opt_state, batch)
+        if (self._settled or self._flags == self._plan
+                # (under a trace there is nothing to compile)
+                or any(isinstance(a, jax.core.Tracer)
+                       for a in jax.tree.leaves((params, opt_state)))):
+            return step
+        from hetu_galvatron_tpu.core.profiler.runtime_profiler import (
+            compiled_memory_bytes,
+        )
+
+        r, t0 = self.report, time.perf_counter()
+        try:
+            peak = compiled_memory_bytes(step.lower(
+                params, opt_state, batch).compile()).get("live_peak", 0)
+            r["check_s"] = time.perf_counter() - t0
+            if peak <= self._limit * kept.FILL:
+                return step
+            why = f"compiled to {peak} bytes"
+        except jax.errors.JaxRuntimeError as e:
+            if "RESOURCE_EXHAUSTED" not in str(e):
+                raise
+            why = "did not compile for memory"
+
+        logging.getLogger(__name__).warning(
+            "the step with %d blocks kept whole (%d bytes counted beside an "
+            "estimate of %d, of the devices' %d) %s; building it with the "
+            "plan's flags", sum(r["blocks_kept"].values()), r["kept_bytes"],
+            r["estimate_bytes"], r["limit_bytes"], why)
+        r.update(blocks_recomputed={
+            k: r["blocks_recomputed"][k] + r["blocks_kept"][k]
+            for k in self._plan}, blocks_kept={k: 0 for k in self._plan},
+            kept_bytes=0, fallback=1)
+        self._flags = self._plan
+        self._step = self._make(None)
+        return self._step
+
+    def __call__(self, params, opt_state, batch):
+        out = self._checked(params, opt_state, batch)(
+            params, opt_state, batch)
+        self._settled = True
+        return out
+
+    def lower(self, params, opt_state, batch):
+        return self._built(params, opt_state, batch).lower(
+            params, opt_state, batch)
+
+
+def kept_report(step, cfg: ModelArgs, hpc: HybridParallelConfig
+                ) -> Dict[str, Any]:
+    """What a step of :func:`make_spmd_train_step` chose
+    (:attr:`KeptStep.report`, once it ran), or, for the plain jitted step
+    of a device that reports no limit, that every block whose plan bit is
+    set is recomputed."""
+    n_enc = hpc.num_encoder_layers
+    return getattr(step, "report", None) or _plan_report(plan_remat_flags(
+        cfg, hpc.layers[n_enc:], hpc.layers[:n_enc]))
+
+
 def make_spmd_train_step(
     cfg: ModelArgs,
     hpc: HybridParallelConfig,
@@ -688,6 +874,7 @@ def make_spmd_train_step(
     hier_bucket_mb: float = 0.0,
     dp_schedule: Optional[str] = None,
     kernel_interpret: bool = False,
+    keep_blocks: bool = True,
 ):
     """Build the jitted hybrid-parallel train step (no pipeline; pp=1).
 
@@ -709,7 +896,11 @@ def make_spmd_train_step(
     verified, emitted collective schedule (``collectives/``) — the plan
     JSON records the family the search priced cheapest.
     ``kernel_interpret`` (CPU tests) runs the Pallas kernels in interpret
-    mode.
+    mode. On devices that state how much they hold, a block whose plan bit
+    is set is rematerialized only where its values do not fit
+    (:class:`KeptStep`, built at the step's first call); ``keep_blocks=
+    False`` is for the caller that measures what the plan's bit costs (the
+    model profiler's memory tables): every such block is rematerialized.
     """
     if hpc.pp_deg != 1:
         raise ValueError("make_spmd_train_step is the pp=1 path; use the "
@@ -731,14 +922,17 @@ def make_spmd_train_step(
             reason = HIER_KERNEL_REASON  # vocab-parallel CE is a shard_map
         if reason is not None:
             raise ValueError(f"hier_dp unsupported: {reason}")
-    loss_fn, pspecs, batch_shd, per_layer, vocab, enc_per, param_view = (
-        build_spmd_loss_fn(
+    def lowered(flags):
+        return build_spmd_loss_fn(
             cfg, hpc, mesh, axes_tree, compute_dtype=compute_dtype,
             layer_overrides=layer_overrides, with_moe_stats=moe_stats,
             tp_overlap=tp_overlap, lane_dp=hier_dp,
             kernel_interpret=kernel_interpret,
             # (the lane reducer takes gradients in the stored layout)
-            hoist_view=not hier_dp))
+            hoist_view=not hier_dp, remat_flags=flags)
+
+    loss_fn, pspecs, batch_shd, per_layer, vocab, enc_per, param_view = (
+        lowered(None))
     opt_pspecs = param_specs(axes_tree, per_layer, vocab, opt=True,
                              enc_per_layer=enc_per or None)
     opt_specs = opt_state_specs(tx, params, opt_pspecs)
@@ -770,15 +964,22 @@ def make_spmd_train_step(
             return jax.tree.map(
                 lambda x: jax.lax.with_sharding_constraint(x, mb_spec), mbs)
 
-    step = make_train_step(loss_fn, tx, chunks=chunks, aux_stats=moe_stats,
-                           hier=hier, constrain_microbatches=constrain_mbs,
-                           param_view=param_view)
-
     nshd = lambda tree: jax.tree.map(
         lambda s: NamedSharding(mesh, s), tree,
         is_leaf=lambda x: isinstance(x, P))
     use_dropout = cfg.hidden_dropout > 0.0 or cfg.attention_dropout > 0.0
-    if use_dropout:
+
+    def jitted_step(loss_fn):
+        step = make_train_step(
+            loss_fn, tx, chunks=chunks, aux_stats=moe_stats, hier=hier,
+            constrain_microbatches=constrain_mbs, param_view=param_view)
+        if not use_dropout:
+            return jax.jit(
+                step,
+                in_shardings=(nshd(pspecs), nshd(opt_specs), batch_shd),
+                out_shardings=(nshd(pspecs), nshd(opt_specs), None),
+                donate_argnums=(0, 1) if donate else (),
+            )
         # the rng key can't ride inside the batch at the jit boundary: the
         # batch in-sharding is ONE NamedSharding broadcast over every leaf,
         # and a scalar key has no batch axes. Jit a 4-arg step (key
@@ -808,14 +1009,52 @@ def make_spmd_train_step(
             return jitted.lower(params, opt_state, batch, rng)
 
         train_step.lower = lower  # same inspection surface as a bare jit
-    else:
-        train_step = jax.jit(
-            step,
-            in_shardings=(nshd(pspecs), nshd(opt_specs), batch_shd),
-            out_shardings=(nshd(pspecs), nshd(opt_specs), None),
-            donate_argnums=(0, 1) if donate else (),
-        )
-    return train_step, pspecs, opt_specs, batch_shd
+        return train_step
+
+    plan = plan_remat_flags(cfg, per_layer, enc_per)
+    limit = keep_blocks and kept.bytes_limit(list(mesh.devices.flat))
+    if not limit or hier is not None or not any(map(any, plan.values())):
+        # nothing to spend (the CPU reports no limit) or nothing to choose:
+        # the flags are the plan's, the step the one it always was
+        return jitted_step(loss_fn), pspecs, opt_specs, batch_shd
+
+    def count(flags, p, o, batch):
+        """The loss's trace with ``flags`` (probes) for one microbatch of
+        ``batch``: its jaxpr, how many outputs are the primal's, how many
+        inputs the parameters."""
+        mb = {k: (v if k == "dropout_rng" else jax.ShapeDtypeStruct(
+            (v.shape[0] // chunks,) + v.shape[1:], v.dtype))
+            for k, v in batch.items()}
+        probe_loss, *_, view = lowered(flags)
+
+        def outer(p, mb):
+            q = p if view is None else view(p)
+            if moe_stats:
+                out, pull, stats = jax.vjp(
+                    lambda q: probe_loss(q, mb), q, has_aux=True)
+                return (out, stats), pull
+            return jax.vjp(lambda q: probe_loss(q, mb), q)
+
+        closed, shape = jax.make_jaxpr(outer, return_shape=True)(p, mb)
+        return (closed.jaxpr, len(jax.tree.leaves(shape[0])),
+                len(jax.tree.leaves(p)))
+
+    def shards(sh):
+        return axes_size(mesh, sh.dp_axes + sh.cp_axes)
+
+    kinds, shares = cfg.block_kinds(len(per_layer)), None
+    if cfg.model_type != "t5":
+        shares = cfg.block_shares(len(per_layer))
+    # blocks the same trace serves: of one kind, one plan, one width
+    groups = {
+        "decoder": [(kinds[i], cfg.block_heads(i), shares and shares[i], sh)
+                    for i, sh in enumerate(per_layer)],
+        "tower": [("tower", per_layer[0])] * cfg.tower_layers,
+        "encoder": [("encoder", sh) for sh in enc_per]}
+    return (KeptStep(
+        lambda flags: jitted_step(lowered(flags)[0]), count, plan, groups,
+        {stack: [shards(g[-1]) for g in gs] for stack, gs in groups.items()},
+        limit=limit, accumulates=chunks > 1), pspecs, opt_specs, batch_shd)
 
 
 def make_spmd_generate(

@@ -169,6 +169,10 @@ def get_hybrid_parallel_config(
             raise ValueError(r)
         default_dp = DPType.from_name(par.default_dp_type)
         dp_type = DPType.ZERO3 if par.sdp else default_dp
+        # checkpoint: a block MAY be recomputed; it is, where its values do
+        # not fit (the pp=1 step keeps as many blocks whole as the device's
+        # memory leaves, parallel/spmd.py::KeptStep; the pipeline engines
+        # recompute every block whose bit is set)
         base = LayerStrategy(
             pp_deg=pp_deg, tp_size=tp, cp_size=cp, dp_size=stage // (tp * cp),
             sp=par.use_ulysses, tp_consecutive=bool(par.global_tp_consec),

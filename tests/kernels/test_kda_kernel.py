@@ -295,3 +295,44 @@ def test_forward_and_backward_are_traced_under_the_scans_scope():
     # so the step report's reader finds no kernel, and no loop either
     assert trace_analysis.kda_kernel_calls(text) == {
         "mosaic_calls": 0, "blocks": 0, "chunk": 0}
+
+
+def test_blocks_of_one_shape_share_one_trace_of_the_kernels(monkeypatch):
+    """Tracing a kernel's body is most of what tracing a KDA block costs
+    (and the step program traces every kind of block once more to count
+    what it holds, ``parallel/kept.py``): a second scan of the same shapes,
+    in another trace of the same kind, runs no kernel's Python again,
+    forward or backward."""
+    traced = {"fwd": 0, "bwd": 0}
+
+    def counting(name, kernel):
+        def body(*refs, **statics):
+            traced[name] += 1
+            return kernel(*refs, **statics)
+        return body
+
+    monkeypatch.setattr(kda, "_fwd_kernel", counting("fwd", kda._fwd_kernel))
+    monkeypatch.setattr(kda, "_bwd_kernel", counting("bwd", kda._bwd_kernel))
+    kda._scan_call.clear_cache()
+    kda._scan_bwd_call.clear_cache()
+    args = _inputs(CHUNK, 2, DECAYS["strongest_init"], seed=3)
+    scan = lambda *a: kda.kda_scan(*a, CHUNK, interpret=True)
+    trace = lambda block, scale: jax.make_jaxpr(jax.grad(
+        lambda *a: scale * jnp.sum(block(*a)), argnums=(0, 1, 2, 3, 4)))(*args)
+    try:
+        trace(scan, 1.0)
+        # (the forward that keeps its states, and the backward)
+        assert traced == {"fwd": 1, "bwd": 1}
+        trace(scan, 2.0)
+        assert traced == {"fwd": 1, "bwd": 1}
+        # a recomputed block's: the primal call, which keeps no states, and
+        # the keeping forward as ``jax.checkpoint`` traces it, once each
+        trace(jax.checkpoint(scan), 1.0)
+        assert traced == {"fwd": 3, "bwd": 1}
+        trace(jax.checkpoint(scan), 2.0)
+        trace(scan, 3.0)
+        assert traced == {"fwd": 3, "bwd": 1}
+    finally:
+        # (what was traced through the counting bodies is not left behind)
+        kda._scan_call.clear_cache()
+        kda._scan_bwd_call.clear_cache()
