@@ -46,8 +46,7 @@ CALLBACK_PRIMS = ("pure_callback", "io_callback", "debug_callback",
 # attribution (observability/trace_analysis.py _PERMUTE_MARKERS) can bill
 # them to the right plan component; the census fails unmarked permutes so
 # the attribution can never silently regress
-PERMUTE_MARKERS: Tuple[str, ...] = ("tp_ring", "cp_ring", "pp_rotate",
-                                    "dp_sched")
+PERMUTE_MARKERS: Tuple[str, ...] = ("tp_ring", "cp_ring", "pp_rotate")
 
 
 @dataclass
@@ -251,16 +250,10 @@ def census_compiled_step(cfg: Any, hpc: Any, train: Any, *,
 
 
 def trace_spmd_step(cfg: Any, hpc: Any, train: Any, mesh: Any,
-                    *, tp_overlap: bool = True, hier_dp: bool = False,
-                    dcn_slices: int = 1, hier_bucket_mb: float = 0.0,
-                    dp_schedule: Optional[str] = None):
+                    *, tp_overlap: bool = True):
     """ClosedJaxpr of the pp=1 SPMD train step (``parallel.spmd``) —
     tracing only, nothing executes. Shared by the count census and the
-    sharding-flow byte census; ``hier_dp`` traces the hierarchical dp
-    gradient-reduction variant (``ops/hier_reduce.py``),
-    ``hier_bucket_mb`` its bucketed software-pipelined flavour, and
-    ``dp_schedule`` the synthesized-collective backend
-    (``collectives/``) whose ppermutes carry the ``dp_sched`` marker."""
+    sharding-flow byte census."""
     import jax
     import jax.numpy as jnp
 
@@ -272,9 +265,7 @@ def trace_spmd_step(cfg: Any, hpc: Any, train: Any, mesh: Any,
     tx = make_optimizer(train)
     step, pspecs, ospecs, _ = make_spmd_train_step(
         cfg, hpc, mesh, axes, tx, params, compute_dtype=jnp.float32,
-        donate=True, tp_overlap=tp_overlap, hier_dp=hier_dp,
-        dcn_slices=dcn_slices, hier_bucket_mb=hier_bucket_mb,
-        dp_schedule=dp_schedule)
+        donate=True, tp_overlap=tp_overlap)
     sp_shape = jax.tree.map(
         lambda p: jax.ShapeDtypeStruct(p.shape, p.dtype), params)
     so_shape = jax.eval_shape(tx.init, sp_shape)
@@ -283,14 +274,10 @@ def trace_spmd_step(cfg: Any, hpc: Any, train: Any, mesh: Any,
 
 
 def census_spmd_step(cfg: Any, hpc: Any, train: Any, mesh: Any,
-                     *, tp_overlap: bool = True, hier_dp: bool = False,
-                     dcn_slices: int = 1, hier_bucket_mb: float = 0.0,
-                     dp_schedule: Optional[str] = None) -> CensusResult:
+                     *, tp_overlap: bool = True) -> CensusResult:
     """Trace the pp=1 SPMD train step (``parallel.spmd``) and census it."""
     return census_jaxpr(trace_spmd_step(
-        cfg, hpc, train, mesh, tp_overlap=tp_overlap, hier_dp=hier_dp,
-        dcn_slices=dcn_slices, hier_bucket_mb=hier_bucket_mb,
-        dp_schedule=dp_schedule))
+        cfg, hpc, train, mesh, tp_overlap=tp_overlap))
 
 
 def trace_serving_programs(cfg: Any, *, mesh: Any = None, hpc: Any = None,
@@ -355,7 +342,7 @@ def check_census(
         where = "; ".join(sorted(set(census.unmarked_permutes))[:4])
         problems.append(
             f"{program}: {n_unmarked} collective-permute(s) carry no "
-            f"tp_ring/cp_ring/pp_rotate/dp_sched named_scope marker "
+            f"tp_ring/cp_ring/pp_rotate named_scope marker "
             f"(trace attribution would mis-bill them) — name stacks: "
             f"{where}")
     if census.callbacks and not allow_callbacks:
@@ -364,7 +351,7 @@ def check_census(
             + "; ".join(sorted(set(census.callbacks))[:4]))
     if predicted is not None:
         marker_of = {"ppermute_tp": "tp_ring", "ppermute_cp": "cp_ring",
-                     "ppermute_pp": "pp_rotate", "ppermute_dp": "dp_sched"}
+                     "ppermute_pp": "pp_rotate"}
         for key, want in sorted(predicted.items()):
             if key in marker_of:
                 got = census.permutes_by_marker.get(marker_of[key], 0)

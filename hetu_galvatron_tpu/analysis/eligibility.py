@@ -575,158 +575,6 @@ def search_tp_overlap_expressible(tp: int, cp: int, enabled: bool) -> bool:
 
 
 # ---------------------------------------------------------------------------
-# hierarchical dp/sdp gradient reduction eligibility (ops/hier_reduce.py)
-# ---------------------------------------------------------------------------
-
-# shared reason strings (launcher logging + plan doctor + engine ctors)
-HIER_KERNEL_REASON = ("shard_map kernels (tp_overlap rings / flash / "
-                      "ring-cp / ulysses a2a) cannot nest under the "
-                      "hierarchical path's per-lane vmap")
-HIER_DROPOUT_REASON = ("dropout: per-lane rng streams would draw masks "
-                       "the flat path never draws (trajectories diverge "
-                       "beyond reduction reassociation)")
-HIER_ZIGZAG_REASON = ("zigzag-cp: sequences arrive pre-permuted for the "
-                      "ring kernel's layout, and the lane path's GSPMD "
-                      "attention would causally mask them by array order")
-
-
-def hier_dp_unsupported_reason(
-    *,
-    dp: int,
-    cp: int = 1,
-    ulysses: bool = False,
-    tp: int = 1,
-    tp_consecutive: bool = True,
-    uniform_strategies: bool = True,
-    model_type: str = "gpt",
-    num_experts: int = 0,
-    dropout: float = 0.0,
-    vtp: int = 1,
-    vcp: int = 1,
-    cp_zigzag: bool = False,
-) -> Optional[str]:
-    """None when the hierarchical dp gradient-reduction path can run this
-    plan; otherwise the reason the launcher logs before keeping the flat
-    GSPMD all-reduce. The same predicate gates the runtime engines, the
-    cost model's hierarchical dp term
-    (:func:`search_hier_dp_expressible`), and the count/byte predictions
-    (``telemetry.plan_collective_counts/bytes``).
-
-    cp/Ulysses-bearing sdp groups ARE eligible at the plan level: the lane
-    vmap covers the dp axes (``spmd_axis_name`` takes the full dp-axis
-    tuple) and the per-lane grads stay partial over the cp/sequence axes,
-    which the in-lane partitioner reduces over the small ICI-local group —
-    the big once-per-microbatch dp ring is still what moves out of the
-    scan. The REMAINING cp/sp gate is a kernel-dispatch property: the
-    pp>1 engines keep their stage-stacked ring/a2a kernels (cannot nest
-    under the lane vmap — they raise :data:`HIER_KERNEL_REASON`), while
-    the pp=1 SPMD path swaps those layers to the GSPMD attention core.
-    Zigzag-cp stays ineligible here (:data:`HIER_ZIGZAG_REASON`): its
-    dataloader-permuted layout is only correct under the ring kernel."""
-    if not uniform_strategies:
-        return ("heterogeneous per-layer strategies (one dp lane split "
-                "must cover every layer)")
-    if dp < 2:
-        return "dp == 1 (no data-parallel gradient ring to decompose)"
-    if cp_zigzag:
-        return HIER_ZIGZAG_REASON
-    if not tp_consecutive:
-        return ("non-consecutive tp: the dp axes are not a contiguous "
-                "leading mesh run, so they cannot regroup into "
-                "slice x host sub-axes")
-    if model_type == "t5":
-        return "t5 encoder-decoder stacks keep the flat GSPMD reduction"
-    if num_experts:
-        return ("MoE layers: expert grads ride the ep/edp axes, not the "
-                "plain dp lane split")
-    if dropout > 0.0:
-        return HIER_DROPOUT_REASON
-    if vtp * vcp > tp * cp:
-        return (f"vocab tp/cp degree {vtp * vcp} exceeds the layer "
-                f"tp*cp {tp * cp}: the vocab weight axes would overlap "
-                "the dp lane axes")
-    return None
-
-
-def plan_hier_dp_reason(cfg: Any, hpc: Any) -> Optional[str]:
-    """Plan-level adapter: (ModelArgs, HybridParallelConfig) -> reason
-    (None = the hierarchical path can run). Kernel nesting (tp_overlap /
-    flash / ring) is a runtime dispatch property checked by the engines —
-    this is the pure plan-shape half."""
-    s = hpc.layers[0]
-    return hier_dp_unsupported_reason(
-        dp=s.dp_size,
-        cp=s.cp_size,
-        ulysses=s.sp,
-        tp=s.tp_size,
-        tp_consecutive=s.tp_consecutive,
-        uniform_strategies=all(l == s for l in hpc.layers),
-        model_type=cfg.model_type,
-        num_experts=cfg.num_experts,
-        dropout=max(cfg.hidden_dropout, cfg.attention_dropout),
-        vtp=hpc.vocab.vtp,
-        vcp=hpc.vocab.vcp,
-        cp_zigzag=bool(getattr(hpc, "cp_zigzag", False)),
-    )
-
-
-def search_hier_dp_expressible(s: Any, enabled: bool) -> bool:
-    """Cost-model adapter (``cost_model.cost``): can this candidate layer
-    earn the hierarchical dp pricing? The degree-level half of
-    :func:`hier_dp_unsupported_reason` — dp > 1; cp/Ulysses layers
-    qualify on the pp=1 SPMD path only (the pp engines keep their
-    stage-stacked ring/a2a kernels, which cannot nest under the lane vmap
-    — :data:`HIER_KERNEL_REASON` — so the search must not price what the
-    runtime will reject: search==runtime parity). The model-level gates
-    (t5/MoE/dropout/zigzag/vocab overlap) are resolved by the runtime and
-    the plan doctor."""
-    if not (bool(enabled) and s.dp > 1):
-        return False
-    if s.cp == 1 and s.sp == 1:
-        return True
-    return s.pp == 1
-
-
-DP_SCHEDULE_FAMILIES = ("ring", "tree_hd", "tree_bcast", "torus2d",
-                        "hier_rings")
-# the hand-built reference backends (collectives/reference.py) ride the
-# same reducer seam for the bit-parity drills; they are not searched
-DP_SCHEDULE_HANDBUILT = ("ring_handbuilt", "tree_handbuilt")
-
-
-def dp_schedule_unsupported_reason(name: str, lanes: int, cross: int = 1,
-                                   bucket_mb: float = 0.0
-                                   ) -> Optional[str]:
-    """Can an emitted collective schedule ``name``
-    (``collectives/synthesize.py``) replace the hand-implemented
-    hierarchical rs/ar/ag program for a ``lanes``-wide dp group split
-    over ``cross`` slices? Pure shape arithmetic — the synthesis itself
-    re-validates via the static verifier before emission."""
-    if name not in DP_SCHEDULE_FAMILIES + DP_SCHEDULE_HANDBUILT:
-        return (f"unknown dp schedule family {name!r} (expected one of "
-                f"{DP_SCHEDULE_FAMILIES + DP_SCHEDULE_HANDBUILT})")
-    if lanes < 2:
-        return f"dp schedule needs dp > 1, got dp degree {lanes}"
-    if bucket_mb > 0:
-        return ("emitted dp schedules are monolithic; hier_bucket_mb > 0 "
-                "only composes with the hand-implemented wavefront "
-                "schedule")
-    pow2 = lanes >= 2 and (lanes & (lanes - 1)) == 0
-    if name in ("tree_hd", "tree_bcast", "ring_handbuilt",
-                "tree_handbuilt") and not pow2:
-        return (f"{name} needs a power-of-two dp group, got {lanes}")
-    if name == "torus2d" and not (
-            (cross >= 2 and lanes // cross >= 2)
-            or (lanes >= 4 and lanes % 2 == 0)):
-        return (f"torus2d needs a 2D-factorable dp group, got {lanes} "
-                f"(cross {cross})")
-    if name == "hier_rings" and not (cross >= 2 and lanes // cross >= 2):
-        return (f"hier_rings needs cross >= 2 and intra >= 2, got dp "
-                f"{lanes} over cross {cross}")
-    return None
-
-
-# ---------------------------------------------------------------------------
 # plan structure (divisibility / stage sums / axis products)
 # ---------------------------------------------------------------------------
 

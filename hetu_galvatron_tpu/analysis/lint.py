@@ -11,8 +11,7 @@ Six rules encode repo invariants that no off-the-shelf linter knows:
   ``for``/``while`` body: a recompile (or retrace) hazard when the loop is
   a step loop. Init-time loops are baselined with a justification.
 * **GAL003 mesh-axis canon** — mesh axis-name string literals outside the
-  ``runtime/mesh.py`` canon (``pp``, the binary ``d0..dk``, and the
-  hierarchical dp reduction's ``slice``/``host`` sub-axes) in
+  ``runtime/mesh.py`` canon (``pp`` and the binary ``d0..dk``) in
   collective/PartitionSpec positions: a typo'd axis name fails at trace
   time with an opaque error, or silently shards nothing.
 * **GAL004 dynamic named_scope** — f-strings/computed names in
@@ -63,11 +62,9 @@ HOT_PATH_MODULES = (
     "observability/recorder.py",
 )
 
-# mesh axis-name canon (runtime/mesh.py): 'pp' + binary d-axes, plus the
-# hierarchical dp reduction's slice/host sub-axes (mesh.hier_submesh /
-# HIER_SLICE_AXIS / HIER_HOST_AXIS) — any other hand-rolled axis literal
-# in the hierarchical path (or anywhere else) is a finding
-_AXIS_CANON = re.compile(r"^(pp|d\d+|host|slice)$")
+# mesh axis-name canon (runtime/mesh.py): 'pp' + binary d-axes — any
+# other hand-rolled axis literal is a finding
+_AXIS_CANON = re.compile(r"^(pp|d\d+)$")
 
 # modules where GAL006 permits ambient-environment reads: the schema is
 # where config is DEFINED, and cli/ is the process boundary that feeds it
@@ -224,31 +221,11 @@ class _Visitor(ast.NodeVisitor):
                 self._add("GAL004", node,
                           "f-string named_scope breaks trace-marker "
                           "matching (use a module-level constant)")
-            elif not (isinstance(a, (ast.Constant, ast.Name, ast.Attribute))
-                      or self._is_marker_preserving_scope(a)):
+            elif not isinstance(a, (ast.Constant, ast.Name, ast.Attribute)):
                 self._add("GAL004", node,
                           "computed named_scope name breaks trace-marker "
                           "matching (use a module-level constant)")
         self.generic_visit(node)
-
-    @staticmethod
-    def _is_marker_preserving_scope(a: ast.AST) -> bool:
-        """``hier_stage_scope(CONSTANT-or-NAME, ...)`` calls are
-        marker-preserving by contract (ops/hier_reduce.py): the base scope
-        stays a PREFIX of the returned name (bare at one bucket,
-        ``_b{i}``-suffixed otherwise), so every substring consumer — trace
-        attribution's ``_HIER_MARKERS``, the flow pass's ``hier_dp_ag``
-        gather exemption — still matches. Only the first argument being a
-        constant/name matters; a computed BASE would break matching and
-        stays a finding."""
-        if not (isinstance(a, ast.Call) and isinstance(
-                a.func, (ast.Name, ast.Attribute))):
-            return False
-        fn = (a.func.id if isinstance(a.func, ast.Name)
-              else a.func.attr)
-        return (fn == "hier_stage_scope" and bool(a.args)
-                and isinstance(a.args[0], (ast.Constant, ast.Name,
-                                           ast.Attribute)))
 
     def _check_axis_literals(self, node: ast.AST) -> None:
         lits: List[Tuple[ast.AST, str]] = []

@@ -250,13 +250,6 @@ def reshard_findings(jaxpr: Any, *, program: str,
         name = eqn.primitive.name
         where = f"{_path}eqn {i} ({name})"
         if name == "all_gather":
-            # the hierarchical dp reduction's gather-back is DELIBERATE
-            # re-materialization (the summed grads return to the params'
-            # layout); its named_scope marker exempts it — anything else
-            # weight-sized is still a finding
-            stack = str(getattr(eqn.source_info, "name_stack", ""))
-            if "hier_dp_ag" in stack:
-                continue
             out_mb = sum(_aval_mb(v) for v in eqn.outvars)
             if out_mb >= gather_mb:
                 aval = getattr(eqn.outvars[0], "aval", None)
@@ -291,7 +284,7 @@ def reshard_findings(jaxpr: Any, *, program: str,
 # ---------------------------------------------------------------------------
 
 _MARKER_OF = {"ppermute_tp": "tp_ring", "ppermute_cp": "cp_ring",
-              "ppermute_pp": "pp_rotate", "ppermute_dp": "dp_sched"}
+              "ppermute_pp": "pp_rotate"}
 
 
 def check_flow(
@@ -374,21 +367,13 @@ def flow_compiled_step(cfg: Any, hpc: Any, train: Any, *,
 
 
 def flow_spmd_step(cfg: Any, hpc: Any, train: Any, mesh: Any, *,
-                   tp_overlap: bool = True, hier_dp: bool = False,
-                   dcn_slices: int = 1, hier_bucket_mb: float = 0.0,
-                   dp_schedule: Optional[str] = None,
+                   tp_overlap: bool = True,
                    gather_mb: float = 1.0) -> ProgramFlow:
     """Trace the pp=1 SPMD train step (``census.trace_spmd_step``) and run
-    the full byte-side analysis — the hook the hierarchical-dp drill uses
-    to cross-check the reduce-scatter/all-reduce/all-gather payloads
-    (per-bucket under ``hier_bucket_mb``) against
-    ``plan_collective_bytes`` exactly."""
+    the full byte-side analysis."""
     from hetu_galvatron_tpu.analysis.census import trace_spmd_step
 
-    jaxpr = trace_spmd_step(cfg, hpc, train, mesh, tp_overlap=tp_overlap,
-                            hier_dp=hier_dp, dcn_slices=dcn_slices,
-                            hier_bucket_mb=hier_bucket_mb,
-                            dp_schedule=dp_schedule)
+    jaxpr = trace_spmd_step(cfg, hpc, train, mesh, tp_overlap=tp_overlap)
     return ProgramFlow(
         name="spmd_step", flow=flow_jaxpr(jaxpr),
         donation=donation_report(jaxpr),

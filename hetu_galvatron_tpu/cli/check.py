@@ -30,12 +30,6 @@ no training step — BEFORE burning accelerator time:
 * ``--calibration`` — Pass 6: the calibration self-check — synthetic
   residual store -> α-β re-fit -> plan-regret sentinel round-trip with
   known ground truth.
-* ``--schedules`` — Pass 7: the collective-schedule self-check —
-  synthesize the ``collectives/`` schedule space over a set of group
-  shapes, statically verify every schedule, price the space with
-  synthetic link curves (ring-fit inversion exactness, the
-  small/large-payload winner flip, missing-curve family drop), and
-  probe that a mutated schedule is rejected diagnostically.
 * ``--all`` — every pass on the committed examples. This is the CI step
   (``__graft_entry__.dryrun_multichip`` runs it and tier-1 asserts it
   green). The partition-time HLO walk (``sharding_flow.hlo_collectives``)
@@ -437,111 +431,6 @@ def run_calibration() -> int:
     return 0 if not failures else 1
 
 
-def run_schedules() -> int:
-    """Pass 7 — collective-schedule self-check (``collectives/``): for a
-    set of (group, cross) shapes covering the dryrun mesh and the odd /
-    hierarchical corners, synthesize the full schedule space, run every
-    schedule through the static verifier, and price it with synthetic
-    link curves — asserting the ring-fit inversion reproduces the fitted
-    curve on the ring schedule it was inverted from, every synthesized
-    family prices (min-over-curves never silently shrinks), the
-    latency/bandwidth regimes really flip the winner (trees at tiny
-    payloads, ring/torus at bulk), a missing link curve DROPS a family
-    rather than inventing a number, and a mutated schedule is rejected
-    with a diagnostic naming the offending step — never a traceback."""
-    import dataclasses
-
-    from hetu_galvatron_tpu.collectives.ir import ScheduleError
-    from hetu_galvatron_tpu.collectives.pricing import (
-        invert_ring_fit,
-        price_schedule_ms,
-        price_space,
-    )
-    from hetu_galvatron_tpu.collectives.synthesize import (
-        ring_all_reduce,
-        synthesize_space,
-    )
-    from hetu_galvatron_tpu.collectives.verify import verify
-
-    print("== collective-schedule self-check ==")
-    failures: List[str] = []
-
-    def check(ok: bool, what: str) -> None:
-        print(f"  {'ok' if ok else 'FAIL'}: {what}")
-        if not ok:
-            failures.append(what)
-
-    shapes = ((2, 1), (4, 1), (6, 1), (8, 1), (8, 2), (12, 3), (16, 4))
-    for n, cross in shapes:
-        space = synthesize_space(n, cross=cross)
-        bad: List[str] = []
-        for name, sched in space.items():
-            try:
-                verify(sched)
-            except ScheduleError as e:
-                bad.append(f"{name}: {e}")
-        check(not bad,
-              f"n={n} cross={cross}: all {len(space)} schedules verify"
-              + (f" — {bad[0]}" if bad else ""))
-        intra = n // cross if cross > 1 else n
-        curves = {"ici": invert_ring_fit(0.05, 10.0, max(intra, 2))}
-        if cross > 1:
-            curves["dcn"] = invert_ring_fit(0.5, 1.0, max(cross, 2))
-        prices = price_space(space, 8.0, curves)
-        check(set(prices) == set(space)
-              and all(v > 0 for v in prices.values()),
-              f"n={n} cross={cross}: every family prices > 0 "
-              f"({len(prices)}/{len(space)})")
-
-    # ring-fit inversion exactness: pricing the ring schedule with the
-    # link curve inverted from its own fit must give back the fit
-    a_fit, b_fit = 0.05, 10.0
-    ici8 = {"ici": invert_ring_fit(a_fit, b_fit, 8)}
-    exact = True
-    for mb in (0.001, 1.0, 64.0):
-        got = price_schedule_ms(ring_all_reduce(8), mb, ici8)
-        want = a_fit + mb / b_fit
-        exact = exact and got is not None and abs(got - want) <= 1e-9 * want
-    check(exact, "ring-fit inversion is exact on the ring schedule")
-
-    # the plan flip the search keys on: α-dominated tiny payloads go to
-    # a tree family, bandwidth-dominated bulk to ring/torus
-    space8 = synthesize_space(8)
-    tiny = price_space(space8, 0.0005, ici8)
-    bulk = price_space(space8, 64.0, ici8)
-    check(min(tiny, key=tiny.get) in ("tree_hd", "tree_bcast"),
-          f"tiny payload winner is a tree ({min(tiny, key=tiny.get)})")
-    check(min(bulk, key=bulk.get) in ("ring", "torus2d"),
-          f"bulk payload winner is ring/torus ({min(bulk, key=bulk.get)})")
-
-    # a link class with no curve drops the family, never invents a price:
-    # on the 4x2 hierarchical group the flat ring's seam hops tag every
-    # step dcn, while the trees also touch ici — a dcn-only curve set
-    # must price the ring and drop the trees
-    dcn_only = price_space(synthesize_space(8, cross=2), 8.0,
-                           {"dcn": invert_ring_fit(0.5, 1.0, 2)})
-    check("ring" in dcn_only and "tree_hd" not in dcn_only,
-          "missing ici curve drops the trees, keeps the dcn-only ring")
-
-    # the verifier has teeth: duplicate one step's source rank in a
-    # verified ring schedule and it must be rejected naming the step
-    sched = ring_all_reduce(4)
-    step0 = sched.steps[0]
-    mutated = dataclasses.replace(
-        sched, steps=(dataclasses.replace(
-            step0, xfers=step0.xfers + (step0.xfers[0],)),)
-        + sched.steps[1:])
-    try:
-        verify(mutated)
-        check(False, "mutated schedule (duplicate source) is rejected")
-    except ScheduleError as e:
-        check("step 0" in str(e),
-              f"duplicate-source rejection names the step ({e})")
-
-    print(f"schedules: {'OK' if not failures else 'FAILED'}")
-    return 0 if not failures else 1
-
-
 def run_all(hbm_gb: Optional[float] = None,
             schedule_impl: str = "compiled") -> int:
     """The CI gate: plan doctor over every committed example plan, the
@@ -561,8 +450,6 @@ def run_all(hbm_gb: Optional[float] = None,
     rc |= run_lint()
     print()
     rc |= run_calibration()
-    print()
-    rc |= run_schedules()
     print()
     print(f"check --all: {'OK' if rc == 0 else 'FAILED'}")
     return rc
@@ -612,12 +499,6 @@ def main(argv=None) -> int:
                    help="run the calibration self-check (Pass 6): "
                    "synthetic residual store -> α-β re-fit -> plan-regret "
                    "sentinel round-trip with known ground truth")
-    p.add_argument("--schedules", action="store_true",
-                   help="run the collective-schedule self-check (Pass 7): "
-                   "synthesize -> verify -> price over a set of group "
-                   "shapes, with the ring-fit inversion exactness, the "
-                   "small/large-payload plan flip, and a mutated-schedule "
-                   "rejection probe")
     p.add_argument("--all", action="store_true",
                    help="every pass on the committed examples (the CI "
                    "step)")
@@ -640,8 +521,6 @@ def main(argv=None) -> int:
         rc = (rc or 0) | run_flow()
     if a.calibration:
         rc = (rc or 0) | run_calibration()
-    if a.schedules:
-        rc = (rc or 0) | run_schedules()
     if a.lint or a.update_baseline or a.prune_baseline:
         rc = (rc or 0) | run_lint(update_baseline=a.update_baseline,
                                   prune_stale=a.prune_baseline)

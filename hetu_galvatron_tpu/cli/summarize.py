@@ -24,7 +24,6 @@ metric snapshot; a torn dump degrades to a warning.
 from __future__ import annotations
 
 import json
-import re
 import sys
 from typing import Any, Dict, List, Optional, Tuple
 
@@ -433,15 +432,6 @@ def summarize(path: str, out=None,
         t = audits[-1].get("data", {})
         rows = [r for r in t.get("rows", []) if isinstance(r, dict)]
         headline["audit_components"] = len(rows)
-        # per-bucket-stage rows of the bucketed hierarchical reduction
-        # (trace_analysis audit_plan "dp[hier_rs_b0]"-style components):
-        # surfaced in the headline so a bucketed run is recognizable from
-        # the one-line summary, rendered like any other audit row below
-        n_bucket_rows = sum(
-            1 for r in rows
-            if re.search(r"\[hier_\w+_b\d+\]", str(r.get("component", ""))))
-        if n_bucket_rows:
-            headline["audit_hier_bucket_rows"] = n_bucket_rows
         w()
         w(f"-- plan audit: predicted vs actual (per step, per device; "
           f"{t.get('steps', '?')} steps, {t.get('tracks', '?')} device "

@@ -188,6 +188,10 @@ def train(args) -> Dict[str, Any]:
         world = state.world_size
         hpc = get_hybrid_parallel_config(args, world)
         state.log(f"parallel plan: {hpc.describe()}")
+        if hpc.ignored_plan_keys:
+            state.log("plan keys " + ", ".join(hpc.ignored_plan_keys)
+                      + " are ignored: gradients are reduced over dp by "
+                      "XLA's partitioner (the flat reduction)")
 
         cfg = args.model
         # which attention core each layer runs — decided ONCE from the plan
@@ -492,86 +496,6 @@ def train(args) -> Dict[str, Any]:
             state.log("tp_overlap.enable set but no layer is eligible; "
                       "running the GSPMD path")
             tp_overlap_on = False
-
-    # hierarchical dp/sdp gradient reduction (parallel.hier_dp or the
-    # plan's "hier_dp": 1 key, ops/hier_reduce.py): resolve eligibility
-    # once, log the fallback reason, remember the slice/host split
-    hier_dp_on = bool(args.parallel.hier_dp or hpc.hier_dp)
-    # bucketed pipelining granularity: an explicit parallel setting wins,
-    # else the searched plan's recorded size (cost.hier_dp_best_bucket).
-    # The RESOLVED size is written back onto hpc so every downstream
-    # consumer that reads the plan (the exit audit's
-    # predicted_comm_per_step prices hpc.hier_bucket_mb) sees the
-    # granularity the runtime actually pipelines at, not just the plan's
-    hier_bucket_mb = float(args.parallel.hier_bucket_mb
-                           or hpc.hier_bucket_mb)
-    hpc.hier_bucket_mb = hier_bucket_mb
-    if hier_dp_on:
-        from hetu_galvatron_tpu.analysis.eligibility import (
-            HIER_KERNEL_REASON,
-            plan_hier_dp_reason,
-        )
-
-        hier_reason = plan_hier_dp_reason(cfg, hpc)
-        if hier_reason is None and tp_overlap_on:
-            hier_reason = HIER_KERNEL_REASON
-        if hier_reason is None and hpc.pp_deg > 1 and any(
-                s.cp_size > 1 or s.sp for s in hpc.layers):
-            # the pp engines keep their stage-stacked ring-cp/ulysses
-            # kernels (the pp=1 SPMD path swaps them for the GSPMD core)
-            hier_reason = HIER_KERNEL_REASON
-        if hier_reason is None and use_flash:
-            hier_reason = HIER_KERNEL_REASON
-        if hier_reason is None and cfg.use_fused_ce and world > 1:
-            hier_reason = HIER_KERNEL_REASON  # vocab-parallel CE shard_map
-        if hier_reason is not None:
-            state.log("hier_dp: falling back to the flat GSPMD gradient "
-                      f"all-reduce ({hier_reason})")
-            hier_dp_on = False
-        else:
-            from hetu_galvatron_tpu.runtime.mesh import hier_cross_degree
-
-            _dp = hpc.layers[0].dp_size
-            _cross = hier_cross_degree(hpc.pp_deg, _dp,
-                                       args.parallel.dcn_slices)
-            _bkt = (f"; {hier_bucket_mb:g} MB buckets, pipelined"
-                    if hier_bucket_mb > 0 else "")
-            state.log("hier_dp: hierarchical gradient reduction on "
-                      f"(dp {_dp} = {_cross} slice x {_dp // _cross} host;"
-                      f" rs-intra / ar-cross / ag-intra, once per step{_bkt})")
-
-    # synthesized collective schedule (collectives/): an explicit
-    # parallel.dp_schedule wins, else the searched plan's recorded family
-    # (engine.save_results "dp_schedule"). Only the pp=1 SPMD hier path
-    # executes emitted programs; anything inexpressible falls back to the
-    # hand-implemented three-stage reduction with a logged reason.
-    dp_schedule_on = None
-    _want_sched = str(getattr(args.parallel, "dp_schedule", "") or
-                      hpc.dp_schedule or "")
-    if _want_sched and hier_dp_on:
-        if hpc.pp_deg > 1:
-            state.log(f"dp_schedule: {_want_sched!r} needs the pp=1 SPMD "
-                      "path (pp engines keep the hand-built reduction)")
-        else:
-            from hetu_galvatron_tpu.analysis.eligibility import (
-                dp_schedule_unsupported_reason,
-            )
-            from hetu_galvatron_tpu.runtime.mesh import hier_cross_degree
-
-            _dp = hpc.layers[0].dp_size
-            _cross = hier_cross_degree(hpc.pp_deg, _dp,
-                                       args.parallel.dcn_slices)
-            _sr = dp_schedule_unsupported_reason(
-                _want_sched, _dp, _cross, hier_bucket_mb)
-            if _sr is not None:
-                state.log(f"dp_schedule: falling back to the hand-built "
-                          f"reduction ({_sr})")
-            else:
-                dp_schedule_on = _want_sched
-                state.log(f"dp_schedule: executing the synthesized "
-                          f"{_want_sched!r} program (collectives/emit.py)")
-    elif _want_sched:
-        state.log(f"dp_schedule: {_want_sched!r} ignored without hier_dp")
 
     def finish_tp_overlap_setup(step_fn):
         """Once the engine choice has settled: emit the coverage gauge and
@@ -1114,8 +1038,7 @@ def train(args) -> Dict[str, Any]:
                         alpha_beta_algos=ab_algos,
                         mixed_precision=(
                             args.parallel.mixed_precision != "fp32"),
-                        predicted_layer_s=pred_s,
-                        dcn_slices=args.parallel.dcn_slices)
+                        predicted_layer_s=pred_s)
                     if table:
                         state.log(
                             f"plan audit: {len(table['rows'])} components "
@@ -1214,8 +1137,7 @@ def train(args) -> Dict[str, Any]:
                     compute_dtype=compute_dtype,
                     dcn_slices=args.parallel.dcn_slices,
                     donate=not rerun.enabled,
-                    tp_overlap=tp_overlap_on,
-                    hier_dp=hier_dp_on, hier_bucket_mb=hier_bucket_mb)
+                    tp_overlap=tp_overlap_on)
                 if tp_overlap_on and not eng.tp_overlap:
                     state.log("tp_overlap: no eligible layer under the "
                               f"compiled schedule ({eng.overlap_reason}); "
@@ -1230,9 +1152,7 @@ def train(args) -> Dict[str, Any]:
             eng = PipelineEngine(cfg, hpc, args.train, devices=state.devices,
                                  compute_dtype=compute_dtype,
                                  dcn_slices=args.parallel.dcn_slices,
-                                 tp_overlap=tp_overlap_on,
-                                 hier_dp=hier_dp_on,
-                                 hier_bucket_mb=hier_bucket_mb)
+                                 tp_overlap=tp_overlap_on)
         # the engines slice a whole tree per stage: it lives on the default
         # device only until every stage holds its shards
         with span("setup/init"):
@@ -1276,9 +1196,7 @@ def train(args) -> Dict[str, Any]:
             step, pspecs, ospecs, batch_shd = make_spmd_train_step(
                 cfg, hpc, mesh, axes, tx, params,
                 compute_dtype=compute_dtype,
-                donate=not rerun.enabled, tp_overlap=tp_overlap_on,
-                hier_dp=hier_dp_on, dcn_slices=args.parallel.dcn_slices,
-                hier_bucket_mb=hier_bucket_mb, dp_schedule=dp_schedule_on)
+                donate=not rerun.enabled, tp_overlap=tp_overlap_on)
             place_box["batch_shd"] = batch_shd
             nshd = lambda specs: jax.tree.map(
                 lambda s: NamedSharding(mesh, s), specs,
@@ -1297,10 +1215,7 @@ def train(args) -> Dict[str, Any]:
                     cfg, hpc, mesh, axes, tx, params,
                     compute_dtype=compute_dtype,
                     donate=not rerun.enabled, chunks=ch,
-                    tp_overlap=tp_overlap_on, hier_dp=hier_dp_on,
-                    dcn_slices=args.parallel.dcn_slices,
-                    hier_bucket_mb=hier_bucket_mb,
-                    dp_schedule=dp_schedule_on)[0]
+                    tp_overlap=tp_overlap_on)[0]
             return step_cache[ch]
 
         def spmd_step(sp, so, raw):

@@ -646,52 +646,11 @@ class ParallelArgs(BaseModel):
     # DCN topology: number of ICI slices (pods) the job spans; >1 arranges
     # the mesh so pp + outer dp axes cross DCN and tp/cp stay ICI-local
     dcn_slices: int = 1
-    # hierarchical dp/sdp gradient reduction (ops/hier_reduce.py): swap the
-    # flat GSPMD dp grad all-reduce for the explicit two-level schedule —
-    # reduce-scatter intra-host at full volume, all-reduce across slices on
-    # the 1/k shard, all-gather back — with the slice/host split derived
-    # from dcn_slices (pp-first absorption). Per-dp-lane grads accumulate
-    # reduction-free through the microbatch scan, so the dp traffic is paid
-    # ONCE per step instead of once per microbatch. Ineligible plans
-    # (cp/ulysses/MoE/t5/dropout/non-uniform; shard_map kernels under the
-    # lane vmap) fall back to the flat path with a logged reason. A
-    # searched plan may also carry "hier_dp": 1 (either source enables it)
-    hier_dp: bool = False
-    # bucketed software pipelining of the hierarchical reduction
-    # (ops/hier_reduce.py hier_bucket_layout): the concatenated grad
-    # payload splits into <=hier_bucket_mb-MB buckets whose rs-intra /
-    # ar-cross / ag-intra chains are emitted in wavefront order, so bucket
-    # i's DCN stage overlaps bucket i±1's ICI stages — steady state
-    # approaches max(sum T_ici, T_dcn) instead of their sum. 0 (default)
-    # keeps today's single monolithic bucket, byte-identical program. A
-    # searched plan may carry "hier_bucket_mb" (parallel setting wins when
-    # nonzero); results are bit-consistent across bucket sizes (each
-    # element rides the same three-collective association)
-    hier_bucket_mb: float = 0.0
-    # synthesized collective schedule for the hierarchical dp reduction
-    # (collectives/: "ring", "tree_hd", "tree_bcast", "torus2d",
-    # "hier_rings", or the "*_handbuilt" reference bodies): the reduction
-    # executes through the verified emitted program instead of the
-    # hand-implemented three-stage path. "" (default) = hand-implemented;
-    # a searched plan may carry "dp_schedule" (parallel setting wins when
-    # nonempty). Inexpressible combinations (pp > 1, bucketed pipelining,
-    # non-power-of-two lanes for the tree families) fall back with a
-    # logged reason — eligibility.dp_schedule_unsupported_reason
-    dp_schedule: str = ""
 
     @model_validator(mode="after")
     def _check(self):
         if self.config_mode == "json" and not self.galvatron_config_path:
             raise ValueError("config_mode=json requires galvatron_config_path")
-        if self.hier_bucket_mb < 0:
-            # the <0 auto-sweep convention is SEARCH-side only
-            # (search.hier_bucket_mb); the runtime needs an explicit size,
-            # and a truthy negative would silently override a plan's
-            # recorded bucket size into the monolithic schedule
-            raise ValueError(
-                "parallel.hier_bucket_mb must be >= 0 (the < 0 auto-sweep "
-                "mode lives in search.hier_bucket_mb; the winning plan "
-                "records the chosen size)")
         return self
 
 
@@ -1169,24 +1128,6 @@ class SearchArgs(BaseModel):
     # and falls back to the legacy latency tables otherwise, so legacy
     # profiles reproduce golden costs exactly.
     tp_overlap: int = 0
-    # Hierarchical dp gradient-reduction pricing (ops/hier_reduce.py + the
-    # per-algorithm/per-level α-β curves): 1 prices eligible candidates'
-    # dp term as min(flat overlapped ring, hierarchical rs-intra +
-    # ar-cross-on-shard + ag-intra) using the per-level fitted curves
-    # (hardware_profiler.profile_alpha_beta_algos). Without per-level
-    # curves in the bandwidth JSON the hierarchical term is unavailable
-    # and every golden cost stays byte-identical. The winning plan records
-    # "hier_dp": 1 when the hierarchical term priced its dp reduction.
-    hier_dp: int = 0
-    # Bucketed software-pipelining granularity for the hierarchical dp
-    # pricing (cost_model.cost.hier_dp_reduce_ms): > 0 prices the
-    # pipelined schedule at that bucket size (fill-drain: first bucket
-    # pays the full rs+ar+ag chain, the rest pay the bottleneck stage —
-    # per-bucket α overhead vs overlap win); < 0 sweeps power-of-two
-    # bucket sizes (1..64 MB) and records the argmin in the winning plan
-    # ("hier_bucket_mb"); 0 keeps the monolithic three-collective price,
-    # byte-identical goldens.
-    hier_bucket_mb: float = 0.0
     # Plan-regret sentinel support (observability/calibration.py): embed
     # this many runner-up candidates — the feasible plans the search
     # almost picked, deduped + throughput-ordered, each with its priced

@@ -22,7 +22,7 @@ from __future__ import annotations
 import os
 import time
 import warnings
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -91,6 +91,68 @@ def _group_devices(devices: Sequence, size: int, consecutive: bool,
         return list(devices[:size])
     stride = world // size
     return [devices[i * stride] for i in range(size)]
+
+
+def _allreduce_body(alg: str, n: int, axis: str) -> Callable:
+    """The explicit all-reduce program for ``alg`` (``ring`` | ``tree``)
+    over an ``n``-rank group on ``axis``: a function of one flat per-device
+    vector (length divisible by ``n`` for ring, by 2 per halving round for
+    tree), returning the group sum — called inside a full-manual shard_map
+    over ``axis``. ``n`` is a power of two."""
+    if alg == "ring":
+        def body(v):
+            r = jax.lax.axis_index(axis)
+            c = v.shape[0] // n
+            chunks = v.reshape(n, c)
+            perm = [(i, (i + 1) % n) for i in range(n)]
+            # reduce-scatter ring: the accumulator for chunk k starts
+            # at rank (k+1)%n and collects each rank's share en route
+            acc = None
+            for t in range(n):
+                k = (r - 1 - t) % n
+                part = jnp.take(chunks, k, axis=0)
+                acc = part if acc is None else (
+                    jax.lax.ppermute(acc, axis, perm) + part)
+            # all-gather ring: rotate the owned chunk n-1 hops
+            out = jnp.zeros((n, c), v.dtype)
+            cur = acc
+            for t in range(n):
+                k = (r - t) % n
+                out = jax.lax.dynamic_update_index_in_dim(out, cur, k, 0)
+                if t < n - 1:
+                    cur = jax.lax.ppermute(cur, axis, perm)
+            return out.reshape(-1)
+        return body
+
+    if alg == "tree":
+        rounds = n.bit_length() - 1
+
+        def body(v):
+            r = jax.lax.axis_index(axis)
+            cur = v
+            # recursive halving reduce-scatter: round k exchanges half
+            # the live payload with the rank at distance 2^k
+            for k in range(rounds):
+                perm = [(i, i ^ (1 << k)) for i in range(n)]
+                half = cur.shape[0] // 2
+                bit = (r >> k) & 1
+                lo, hi = cur[:half], cur[half:]
+                send = jnp.where(bit == 0, hi, lo)
+                recv = jax.lax.ppermute(send, axis, perm)
+                cur = jnp.where(bit == 0, lo, hi) + recv
+            # recursive doubling all-gather: reverse rounds, payload
+            # doubling back to full size
+            for k in range(rounds - 1, -1, -1):
+                perm = [(i, i ^ (1 << k)) for i in range(n)]
+                bit = (r >> k) & 1
+                recv = jax.lax.ppermute(cur, axis, perm)
+                cur = jnp.where(bit == 0,
+                                jnp.concatenate([cur, recv]),
+                                jnp.concatenate([recv, cur]))
+            return cur
+        return body
+
+    raise ValueError(f"unknown collective algorithm {alg!r} (ring | tree)")
 
 
 def _dcn_group_devices(devices: Sequence, size: int, world: int
@@ -323,13 +385,8 @@ class HardwareProfiler:
 
         The two schedules have materially different (α, β) regimes; the
         fitted pairs let the cost model price each collective as the MIN
-        over algorithms at its message size and level. The bodies are the
-        canonical hand-built programs in ``collectives.reference`` — the
-        collective compiler's emitted ring / halving-doubling schedules
-        are pinned bit-identical to them."""
-        from hetu_galvatron_tpu.collectives.reference import (
-            handbuilt_allreduce_body,
-        )
+        over algorithms at its message size and level
+        (:func:`_allreduce_body`)."""
         n = len(group)
         if n < 2 or (n & (n - 1)):
             raise ValueError(f"algorithm schedules need a power-of-two "
@@ -341,7 +398,7 @@ class HardwareProfiler:
                            NamedSharding(mesh, P(None)))
         from hetu_galvatron_tpu.ops.pallas.common import on_shards
 
-        body = handbuilt_allreduce_body(alg, n, _G_AXIS)
+        body = _allreduce_body(alg, n, _G_AXIS)
         fn = jax.jit(on_shards(body, mesh, P(None), P(None)))
         return _time_fn(fn, x, warmup=self.args.warmup_iters,
                         iters=self.args.profile_iters)

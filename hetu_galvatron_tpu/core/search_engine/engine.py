@@ -213,11 +213,6 @@ class SearchEngine:
             tp_alpha_beta=hw.alpha_beta,
             tp_overlap=bool(self.args.tp_overlap),
             alpha_beta_algos=hw.alpha_beta_algos,
-            hier_dp=bool(self.args.hier_dp),
-            hier_bucket_mb=float(getattr(self.args, "hier_bucket_mb", 0.0)),
-            # the search's topology model: nodes are the cross-DCN level
-            # (mesh.dcn_factor_shape's slice granularity)
-            dcn_slices=max(self.args.num_nodes, 1),
         )
 
     # ---------------- outer loop ----------------
@@ -758,56 +753,6 @@ class SearchEngine:
                     best.strategy_list[li], ctx, best.bsz, best.chunks)
                 pred_ms.append(round(comp["fct_ms"] + comp["bct_ms"], 6))
                 li += 1
-        # record the hierarchical dp choice when the hierarchical term
-        # priced EVERY layer's dp reduction (cost.hier_dp_wins) — the
-        # runtime then enables the matching ops/hier_reduce.py path
-        hier_chosen = False
-        hier_bucket = 0.0
-        dp_sched_name = None
-        dp_sched_ranks = None
-        if self.args.hier_dp:
-            from hetu_galvatron_tpu.core.cost_model.cost import (
-                dp_schedule_choice,
-                hier_dp_best_bucket,
-                hier_dp_wins,
-                hier_grad_payload_mb,
-            )
-
-            li = 0
-            flags = []
-            for lt, n in enumerate(self.layernum_list):
-                for _ in range(n):
-                    flags.append(hier_dp_wins(
-                        best.strategy_list[li], self.contexts[lt],
-                        best.bsz, best.chunks))
-                    li += 1
-            hier_chosen = bool(flags) and all(flags)
-            if hier_chosen:
-                # record the bucket granularity the price assumed: the
-                # configured size, or — auto mode (hier_bucket_mb < 0) —
-                # the sweep's argmin over the first layertype's whole
-                # grad payload, so the runtime pipelines at exactly the
-                # granularity the search paid for
-                ctx0 = self.contexts[0]
-                s0 = best.strategy_list[0]
-                if ctx0.hier_bucket_mb < 0:
-                    _, hier_bucket = hier_dp_best_bucket(
-                        s0, ctx0, hier_grad_payload_mb(s0, ctx0))
-                else:
-                    hier_bucket = max(ctx0.hier_bucket_mb, 0.0)
-                # collective-compiler record: price the synthesized
-                # schedule space for the winning plan's dp group and name
-                # the cheapest family (cost.dp_schedule_choice). The
-                # emitted programs are monolithic, so a bucketed plan
-                # keeps the hand-implemented pipelined path instead.
-                if hier_bucket == 0.0:
-                    choice = dp_schedule_choice(
-                        s0, ctx0, hier_grad_payload_mb(s0, ctx0))
-                    if choice is not None:
-                        dp_sched_name, ranks = choice
-                        dp_sched_ranks = {
-                            k: round(v, 6) for k, v in sorted(
-                                ranks.items(), key=lambda kv: kv[1])}
         cfg = strategy_list2config(
             runtime, global_bsz=best.bsz, chunks=best.chunks,
             pipeline_type=self.pipeline_type,
@@ -817,13 +762,7 @@ class SearchEngine:
                 embed_sdp=bool(best.vocab_sdp)),
             pp_division=best.pp_stage_list,
             num_encoder_layers=getattr(self, "num_encoder_layers", None),
-            predicted_layer_compute_ms=pred_ms,
-            hier_dp=hier_chosen, hier_bucket_mb=hier_bucket,
-            dp_schedule=dp_sched_name)
-        if dp_sched_ranks:
-            # the full priced space rides along (cheapest first) so plan
-            # readers can see HOW the family won, not just that it did
-            cfg["dp_schedule_rankings"] = dp_sched_ranks
+            predicted_layer_compute_ms=pred_ms)
         if best.time_cost != float("inf"):
             cfg["predicted_time_cost_ms"] = round(best.time_cost * 1e3, 6)
         if runner_ups:

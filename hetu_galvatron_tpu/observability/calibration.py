@@ -234,11 +234,7 @@ def calibration_points(table: Dict[str, Any], hpc: Any, model: Any, *,
     by the group's message count — one point per group on the
     ``"{tp}_1"`` curve, attributed to the algorithm the audit chose
     (``flat`` when no per-algorithm curves priced it). dp: same, per
-    flat-ring gradient buffer on ``"{sdp}_{consec}"``. A plan running the
-    hierarchical dp reduction contributes no dp points — its measured dp
-    time is one concatenated three-collective schedule, not the per-layer
-    flat rings these curves model (the hier decomposition rows stay
-    audit-only)."""
+    flat-ring gradient buffer on ``"{sdp}_{consec}"``."""
     from hetu_galvatron_tpu.observability.telemetry import layer_param_mb
 
     rows = [r for r in (table.get("rows") or []) if isinstance(r, dict)]
@@ -293,23 +289,22 @@ def calibration_points(table: Dict[str, Any], hpc: Any, model: Any, *,
         _apportion(tp_groups, float(trow["measured_ms"]), chosen_tp_alg,
                    lambda key: f"{key[0]}_1")
 
-    # dp (flat per-layer gradient rings; hier plans contribute nothing)
-    if "dp[hier]" not in by_comp:
-        dp_groups: Dict[Tuple, List[float]] = {}
-        for s in layers:
-            tp = 1 if s.sp else s.tp_size
-            sdp = max(s.dp_size * s.cp_size * (s.tp_size if s.sp else 1), 1)
-            if sdp <= 1:
-                continue
-            grad_mb = param_mb / max(tp, 1) * \
-                (0.5 if mixed_precision else 1.0)
-            key = (sdp, 1 if tp == 1 else 0, round(grad_mb, 9))
-            g = dp_groups.setdefault(key, [grad_mb, 0.0])
-            g[1] += 1.0 / pp
-        drow = by_comp.get("dp")
-        if dp_groups and drow and drow.get("measured_ms"):
-            _apportion(dp_groups, float(drow["measured_ms"]), "flat",
-                       lambda key: f"{key[0]}_{key[1]}")
+    # dp (per-layer gradient rings)
+    dp_groups: Dict[Tuple, List[float]] = {}
+    for s in layers:
+        tp = 1 if s.sp else s.tp_size
+        sdp = max(s.dp_size * s.cp_size * (s.tp_size if s.sp else 1), 1)
+        if sdp <= 1:
+            continue
+        grad_mb = param_mb / max(tp, 1) * \
+            (0.5 if mixed_precision else 1.0)
+        key = (sdp, 1 if tp == 1 else 0, round(grad_mb, 9))
+        g = dp_groups.setdefault(key, [grad_mb, 0.0])
+        g[1] += 1.0 / pp
+    drow = by_comp.get("dp")
+    if dp_groups and drow and drow.get("measured_ms"):
+        _apportion(dp_groups, float(drow["measured_ms"]), "flat",
+                   lambda key: f"{key[0]}_{key[1]}")
     return points
 
 

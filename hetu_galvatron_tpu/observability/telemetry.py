@@ -303,98 +303,6 @@ def plan_comm_volume(
     return out
 
 
-def _hier_payload_elems_from_plan(hpc, model, *, cross: int
-                                  ) -> Tuple[int, int, int]:
-    """(local, padded, intra) per-device payload element counts of the
-    hierarchical dp reduction for a pp=1 plan — built from THE SAME spec
-    arithmetic the runtime reducer uses (``ops.hier_reduce``: eval-shaped
-    params, ``grad_reduce_specs``, ``hier_payload_elems``), so the byte
-    prediction cannot drift from the traced program."""
-    from types import SimpleNamespace
-
-    import jax
-
-    from hetu_galvatron_tpu.models.builder import init_causal_lm
-    from hetu_galvatron_tpu.ops.hier_reduce import (
-        grad_reduce_specs,
-        hier_payload_elems,
-    )
-    from hetu_galvatron_tpu.runtime.mesh import (
-        lower_strategy,
-        lower_vocab_strategy,
-    )
-
-    if hpc.pp_deg > 1:
-        raise ValueError("hier_dp payload prediction models pp=1 plans; "
-                         "pp>1 engines pass their stacked payload via "
-                         "the engine's reducer")
-    # shape-only mesh stand-in: the prediction needs axis NAMES and SIZES
-    # (lower_strategy / axes_size are shape arithmetic), never devices —
-    # a plan for 8 chips stays predictable on a 1-device analysis host
-    stage = hpc.world_size
-    k = stage.bit_length() - 1
-    if (1 << k) != stage:
-        raise ValueError(f"world {stage} is not a power of two")
-    mesh = SimpleNamespace(
-        axis_names=("pp",) + tuple(f"d{i}" for i in range(k)),
-        shape={"pp": 1, **{f"d{i}": 2 for i in range(k)}})
-    per_layer = [lower_strategy(s, mesh) for s in hpc.layers]
-    vocab = lower_vocab_strategy(hpc.vocab, mesh, hpc.default_dp_type)
-    # eval_shape the params (no arrays materialize); the logical-axes tree
-    # is static python built during the trace, captured via the closure
-    box = {}
-
-    def only_params(k):
-        p, a = init_causal_lm(k, model)
-        box["axes"] = a
-        return p
-
-    params_shapes = jax.eval_shape(only_params, jax.random.key(0))
-    axes_tree = box["axes"]
-    specs = grad_reduce_specs(axes_tree, per_layer, vocab)
-    dp_deg = max(hpc.layers[0].dp_size, 1)
-    if cross < 1 or dp_deg % cross:
-        raise ValueError(f"cross-slice degree {cross} does not divide the "
-                         f"dp degree {dp_deg}")
-    intra = dp_deg // cross
-    from jax.sharding import PartitionSpec as P
-
-    shape_leaves = [tuple(s.shape)
-                    for s in jax.tree_util.tree_leaves(params_shapes)]
-    spec_leaves = jax.tree_util.tree_leaves(
-        specs, is_leaf=lambda x: isinstance(x, P))
-    # the grad specs never mention the dp (lane) axes, so the flat
-    # shape-only view prices the per-device leaf sizes exactly
-    local, padded = hier_payload_elems(shape_leaves, spec_leaves, mesh,
-                                       intra)
-    return local, padded, intra
-
-
-def _dp_schedule_from_plan(name: str, lanes: int, cross: int,
-                           bucket_mb: float):
-    """Verified :class:`~hetu_galvatron_tpu.collectives.ir.Schedule` the
-    runtime reducer would execute for ``dp_schedule=name`` — the shared
-    count/byte prediction source. Hand-built reference backends
-    (``*_handbuilt``) predict through their emitted twin: the reference
-    bodies are pinned bit- and byte-identical to the emitted programs
-    (same hop count, same per-hop payload), so one schedule prices
-    both."""
-    from hetu_galvatron_tpu.analysis.eligibility import (
-        dp_schedule_unsupported_reason,
-    )
-    from hetu_galvatron_tpu.collectives.synthesize import (
-        synthesize_dp_schedule,
-    )
-    from hetu_galvatron_tpu.collectives.verify import verify
-
-    reason = dp_schedule_unsupported_reason(name, lanes, cross, bucket_mb)
-    if reason is not None:
-        raise ValueError(f"dp schedule unsupported: {reason}")
-    fam = {"ring_handbuilt": "ring",
-           "tree_handbuilt": "tree_hd"}.get(name, name)
-    return verify(synthesize_dp_schedule(fam, lanes, cross))
-
-
 def _remat_rings(model) -> int:
     """Rings the per-layer remat recompute re-runs in the backward unit.
     The recompute is dead-code-eliminated down to what the backward reads:
@@ -410,10 +318,6 @@ def plan_collective_counts(
     *,
     num_microbatches: Optional[int] = None,
     tp_overlap: bool = True,
-    hier_dp: bool = False,
-    hier_bucket_mb: float = 0.0,
-    hier_cross: int = 1,
-    dp_schedule: Optional[str] = None,
 ) -> Dict[str, int]:
     """Predicted EXECUTED explicit-collective counts for the compiled
     single-program 1F1B step — the count-side companion of
@@ -438,25 +342,6 @@ def plan_collective_counts(
     The stage rotations add 2 ppermutes per tick (activations forward,
     cotangents backward).
 
-    ``hier_dp=True`` adds the hierarchical dp gradient reduction's
-    explicit collectives (``ops/hier_reduce.py``): the whole grad tree
-    flattens into ONE payload per step, split into ``B`` buckets by
-    ``hier_bucket_layout`` (``hier_bucket_mb``; B = 1 at the 0 default),
-    so exactly B ``reduce_scatter`` (psum_scatter over the host
-    sub-axis), B ``all_reduce`` (psum over the slice sub-axis) and B
-    ``all_gather`` — independent of the microbatch count (lane
-    accumulation is reduction-free in-scan). Bucketed counts need the
-    payload size, so ``hier_bucket_mb > 0`` models pp = 1 plans only
-    (``hier_cross`` fixes the slice/host split, as in
-    :func:`plan_collective_bytes`); pp > 1 engines predict from their
-    own reducer's ``bucket_layout``.
-
-    ``dp_schedule`` (with ``hier_dp=True``) predicts the synthesized
-    collective-compiler backend instead: the rs/ar/ag triple is replaced
-    by ``ppermute_dp`` — one count per exchange step of the verified
-    schedule (``collectives.synthesize`` + ``Schedule.n_exchanges``),
-    which the census matches under the ``dp_sched`` scope marker.
-
     Raises ValueError for plan shapes the prediction does not model
     (non-uniform strategies, Ulysses/cp layers — the census still counts
     those programs, there is just no exact-count prediction to pin them
@@ -466,15 +351,8 @@ def plan_collective_counts(
     if any(l != s for l in hpc.layers):
         raise ValueError("collective-count prediction needs a uniform "
                          "per-layer strategy (the compiled engine's gate)")
-    if (s.sp or s.cp_size > 1) and (
-            not hier_dp or tp_overlap or max(hpc.pp_deg, 1) > 1):
-        # the flat path's cp-ring / ulysses-a2a kernel hops have no exact
-        # prediction; the hier LANE path swaps those kernels for GSPMD
-        # (partition-time, invisible to the jaxpr), so its explicit
-        # collectives ARE predictable — but only at pp = 1 with
-        # tp_overlap off (the pp engines keep their stage-stacked
-        # ring/a2a kernels and reject hier for cp/sp layers, and rings
-        # cannot nest under the lane vmap anyway)
+    if s.sp or s.cp_size > 1:
+        # the cp-ring / ulysses-a2a kernel hops have no exact prediction
         raise ValueError("collective-count prediction models Megatron-TP "
                          "plans only (no Ulysses / cp ring layers)")
     m = max(num_microbatches if num_microbatches is not None
@@ -489,28 +367,6 @@ def plan_collective_counts(
     if tp_overlap and tp > 1:
         rings_per_tick = 4 + 8 + (_remat_rings(model) if s.checkpoint else 0)
         out["ppermute_tp"] = T * lps * rings_per_tick * (tp - 1)
-    if hier_dp:
-        if s.dp_size < 2:
-            raise ValueError("hier_dp prediction needs dp > 1 "
-                             "(eligibility.hier_dp_unsupported_reason)")
-        if dp_schedule:
-            sched = _dp_schedule_from_plan(
-                dp_schedule, s.dp_size, hier_cross, hier_bucket_mb)
-            out["ppermute_dp"] = sched.n_exchanges
-            return out
-        n_buckets = 1
-        if hier_bucket_mb > 0:
-            from hetu_galvatron_tpu.ops.hier_reduce import (
-                hier_bucket_layout,
-            )
-
-            local, _, intra = _hier_payload_elems_from_plan(
-                hpc, model, cross=hier_cross)
-            n_buckets = len(hier_bucket_layout(local, intra,
-                                               hier_bucket_mb))
-        out["reduce_scatter"] = n_buckets
-        out["all_reduce"] = n_buckets
-        out["all_gather"] = n_buckets
     return out
 
 
@@ -521,10 +377,6 @@ def plan_collective_bytes(
     num_microbatches: Optional[int] = None,
     tp_overlap: bool = True,
     elem_bytes: int = 4,
-    hier_dp: bool = False,
-    hier_cross: int = 1,
-    hier_bucket_mb: float = 0.0,
-    dp_schedule: Optional[str] = None,
 ) -> Dict[str, float]:
     """Predicted per-device EXECUTED explicit-collective megabytes for the
     compiled single-program 1F1B step — the byte-side companion of
@@ -561,11 +413,7 @@ def plan_collective_bytes(
     if any(l != s for l in hpc.layers):
         raise ValueError("collective-byte prediction needs a uniform "
                          "per-layer strategy (the compiled engine's gate)")
-    if (s.sp or s.cp_size > 1) and (
-            not hier_dp or tp_overlap or max(hpc.pp_deg, 1) > 1):
-        # same relaxation (and same pp = 1 bound) as
-        # plan_collective_counts: the hier lane path carries no
-        # cp/ulysses kernels, so its explicit bytes are exact
+    if s.sp or s.cp_size > 1:
         raise ValueError("collective-byte prediction models Megatron-TP "
                          "plans only (no Ulysses / cp ring layers)")
     m = max(num_microbatches if num_microbatches is not None
@@ -583,41 +431,6 @@ def plan_collective_bytes(
         rings_per_tick = 4 + 8 + (_remat_rings(model) if s.checkpoint else 0)
         out["ppermute_tp"] = (T * lps * rings_per_tick * (tp - 1)
                               * act_mb / tp)
-    if hier_dp:
-        # hierarchical dp reduction payloads (fp32 accumulators — the
-        # reduce casts every leaf to f32, independent of elem_bytes): the
-        # concatenated per-device grad vector split into buckets by the
-        # SAME hier_bucket_layout the runtime slices with (one bucket at
-        # the 0 default), each independently zero-padded to the
-        # intra-host degree. Input-aval convention, matching the flow
-        # pass: rs moves each bucket's padded vector, ar and ag its
-        # 1/intra shard — summed per collective kind.
-        if s.dp_size < 2:
-            raise ValueError("hier_dp prediction needs dp > 1 "
-                             "(eligibility.hier_dp_unsupported_reason)")
-        from hetu_galvatron_tpu.ops.hier_reduce import hier_bucket_layout
-
-        local, _, intra = _hier_payload_elems_from_plan(
-            hpc, model, cross=hier_cross)
-        if dp_schedule:
-            # synthesized-schedule path: every exchange step is one
-            # ppermute whose traced input aval is [K, c] on EVERY rank
-            # (uniform SPMD tables; K = the step's widest transfer, c =
-            # the chunk size after the emitter's pad to n_chunks) — so
-            # the flow pass's summed input megabytes are Σ_steps K·c·4.
-            # The hand-built reference bodies move the identical per-hop
-            # payloads (that is the byte half of the parity contract).
-            sched = _dp_schedule_from_plan(
-                dp_schedule, s.dp_size, hier_cross, hier_bucket_mb)
-            c = sched.chunk_elems(local)
-            sent = sum(sched.step_max_chunks_sent(st)
-                       for st in sched.steps if st.op == "exchange")
-            out["ppermute_dp"] = sent * c * 4 / MB
-            return out
-        layout = hier_bucket_layout(local, intra, hier_bucket_mb)
-        out["reduce_scatter"] = sum(p for _, p in layout) * 4 / MB
-        out["all_reduce"] = sum(p // intra for _, p in layout) * 4 / MB
-        out["all_gather"] = sum(p // intra for _, p in layout) * 4 / MB
     return out
 
 

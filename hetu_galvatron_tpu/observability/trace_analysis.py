@@ -160,18 +160,6 @@ _PERMUTE_MARKERS: Tuple[Tuple[str, str], ...] = (
     ("tp_ring", "permute_tp"),
     ("cp_ring", "permute_cp"),
     ("pp_rotate", "permute_pp"),
-    # synthesized dp gradient schedules (collectives/emit.py scopes all
-    # start with dp_sched_): every hop is dp traffic
-    ("dp_sched", "permute_dp"),
-)
-# hierarchical dp reduction markers (ops/hier_reduce.py scopes): the three
-# collectives bill to the dp component — without the markers, the
-# reduce-scatter/all-gather halves would land in the tp bucket (the
-# Megatron-SP heuristic) on any plan that runs the hierarchical path
-_HIER_MARKERS: Tuple[Tuple[str, str], ...] = (
-    ("hier_dp_rs", "hier_rs"),
-    ("hier_dp_ar", "hier_ar"),
-    ("hier_dp_ag", "hier_ag"),
 )
 # device-propagated span() names whose covered permute time belongs to tp
 # (the overlapped-TP step annotation, cli/train_dist.py)
@@ -223,11 +211,6 @@ class Attribution:
     bubble_ms: float = 0.0            # per-device idle inside the wall window
     bubble_frac: float = 0.0
     categories_ms: Dict[str, float] = field(default_factory=dict)  # per-device
-    # per-BUCKET-stage detail of the hierarchical dp reduction
-    # ("hier_rs_b0", "hier_ar_b3", ... from the bucketed named scopes,
-    # ops/hier_reduce.hier_stage_scope); kept OUT of categories_ms so
-    # collective_ms never double-counts a marked op with its bucket row
-    hier_bucket_ms: Dict[str, float] = field(default_factory=dict)
     per_module_ms: Dict[str, float] = field(default_factory=dict)  # per-device
     host_span_ms: Dict[str, float] = field(default_factory=dict)   # host wall
     device_annotation_ms: Dict[str, float] = field(default_factory=dict)
@@ -288,7 +271,6 @@ def attribute(trace: TraceData,
     # tp/overlap_step annotation-coverage rebilling below
     bare_permutes: Dict[Tuple[int, int], List[Tuple[float, float]]] = {}
     cats: Dict[str, float] = {}
-    hier_buckets: Dict[str, float] = {}
     mods: Dict[str, float] = {}
     for pid, tid, ts, dur, name, mod, hint in dev_events:
         by_track.setdefault((pid, tid), []).append((ts, dur, name, mod))
@@ -302,19 +284,6 @@ def attribute(trace: TraceData,
                 if cat == "permute":
                     bare_permutes.setdefault((pid, tid), []).append(
                         (ts, ts + dur))
-        elif cat in ("allgather", "reducescatter", "allreduce"):
-            for marker, key in _HIER_MARKERS:
-                if marker in hint:
-                    cat = key
-                    # bucketed schedules suffix a per-bucket stage id
-                    # (hier_stage_scope "hier_dp_rs_b3"): keep the
-                    # per-bucket split as DETAIL next to the base total
-                    mb = re.search(re.escape(marker) + r"_b(\d+)", hint)
-                    if mb is not None:
-                        bk = f"{key}_b{mb.group(1)}"
-                        hier_buckets[bk] = (hier_buckets.get(bk, 0.0)
-                                            + dur / 1000.0)
-                    break
         cats[cat] = cats.get(cat, 0.0) + dur / 1000.0
         if mod:
             mods[mod] = mods.get(mod, 0.0) + dur / 1000.0
@@ -414,8 +383,6 @@ def attribute(trace: TraceData,
             cats.pop("permute", None)
     if attr.tracks:
         attr.categories_ms = {k: v / attr.tracks for k, v in cats.items()}
-        attr.hier_bucket_ms = {k: v / attr.tracks
-                               for k, v in hier_buckets.items()}
     for name in step_spans:  # first marker that fired wins
         if step_counts.get(name):
             attr.steps = max(step_counts[name].values())
@@ -1070,23 +1037,6 @@ def _ab_for(alpha_beta: Dict[str, Tuple[float, float]], size: int,
             or alpha_beta.get(f"{size}_1") or alpha_beta.get(f"{size}_0"))
 
 
-def _merge_algo(d: Dict[str, Any], cands: Dict[str, float],
-                choices: Optional[Tuple[str, ...]] = None) -> None:
-    """Accumulate per-curve candidate ms into a component dict and keep
-    ``predicted_ms`` at the summed MIN choice (``choices`` restricts which
-    keys compete — decomposition entries like hier_intra ride along as
-    detail only)."""
-    algs = d.setdefault("algorithms", {})
-    for k, v in cands.items():
-        algs[k] = algs.get(k, 0.0) + v
-    pool = {k: v for k, v in algs.items()
-            if choices is None or k in choices}
-    if pool:
-        best = min(pool, key=pool.get)
-        d["algorithm"] = best
-        d["predicted_ms"] = pool[best]
-
-
 def predicted_comm_per_step(
     hpc: Any,
     model: Any,
@@ -1095,7 +1045,6 @@ def predicted_comm_per_step(
     alpha_beta_algos: Optional[Dict[str, Dict[str, Tuple[float, float]]]]
     = None,
     mixed_precision: bool = True,
-    dcn_slices: int = 1,
 ) -> Dict[str, Dict[str, float]]:
     """Per component (tp/dp/sp/cp/pp): the plan's predicted per-step MB
     (``plan_comm_volume``) and — for the allreduce-derived collectives,
@@ -1114,14 +1063,11 @@ def predicted_comm_per_step(
     per-device average; volumes stay whole-plan MB).
 
     ``alpha_beta_algos`` (``profiles.read_alpha_beta_algos``) adds the
-    PER-ALGORITHM view: each component dict gains an ``algorithms`` map of
+    PER-ALGORITHM view of tp: its dict gains an ``algorithms`` map of
     candidate-curve predicted ms (``flat`` plus each fitted
-    ``{ring|tree}_{ici|dcn}`` curve for tp; ``flat`` / ``hier`` /
-    ``hier_intra`` / ``hier_cross`` for dp when the plan runs the
-    hierarchical reduction), an ``algorithm`` key naming the winner, and
-    ``predicted_ms`` = the min — EXACTLY the choice the cost model priced
-    (cost._tp_message_ms / cost.hier_dp_reduce_ms, called here so the two
-    can never drift). ``audit_plan`` renders these as per-algorithm
+    ``{ring|tree}_ici`` curve), an ``algorithm`` key naming the winner,
+    and ``predicted_ms`` = the min — the choice the cost model priced
+    (cost._tp_message_ms). ``audit_plan`` renders these as per-algorithm
     rows."""
     from hetu_galvatron_tpu.observability.telemetry import (
         layer_param_mb,
@@ -1135,8 +1081,6 @@ def predicted_comm_per_step(
     ab = alpha_beta or {}
     abalgos = alpha_beta_algos or {}
     param_mb = layer_param_mb(model)
-    # whole-plan accumulator for the once-per-step hierarchical payload
-    hier_acc = {"mb": 0.0, "dp": 1, "tp": 1}
     seq, h = model.seq_length, model.hidden_size
     elem = 2 if mixed_precision else 4
     out: Dict[str, Dict[str, float]] = {
@@ -1181,77 +1125,15 @@ def predicted_comm_per_step(
                         algs[k] = algs.get(k, 0.0) + v
         sdp = max(s.dp_size * s.cp_size * ulysses, 1)
         if sdp > 1:
-            cands = {}
             # dc_key convention (cost.py): tp>1 groups leave dp strided
             pair = _ab_for(ab, sdp, tp == 1)
             grad_mb = param_mb / max(tp, 1) * \
                 (0.5 if mixed_precision else 1.0)
             if pair is not None:
-                cands["flat"] = (pair[0] + grad_mb / pair[1]) / pp
-            if getattr(hpc, "hier_dp", False):
-                # the hierarchical reduction runs ONCE per step over the
-                # CONCATENATED grad payload — its α must not be charged
-                # per layer (unlike the flat per-buffer rings above), so
-                # only the volume accumulates here; priced after the loop
-                hier_acc["mb"] += grad_mb
-                hier_acc["dp"] = s.dp_size
-                hier_acc["tp"] = tp
-            if cands:
                 out["dp"]["predicted_ms"] = out["dp"].get(
-                    "predicted_ms", 0.0) + cands["flat"]
-                algs = out["dp"].setdefault("algorithms", {})
-                algs["flat"] = algs.get("flat", 0.0) + cands["flat"]
-    if hier_acc["mb"] and abalgos:
-        # price the hierarchical schedule through the cost model's OWN
-        # arithmetic (parity by construction): one schedule, whole-plan
-        # volume, α counted once — matching both the runtime (one
-        # three-collective program per step) and the summed layer costs
-        # (layer_time_cost's hier_ms uses the layertype total then
-        # divides by layer count)
-        from hetu_galvatron_tpu.core.cost_model.cost import (
-            CostContext,
-            _algo_min_ms,
-            _hier_dp_split,
-            hier_dp_reduce_ms,
-        )
-        from hetu_galvatron_tpu.core.search_engine.strategies import (
-            SearchStrategy,
-        )
-
-        cctx = CostContext(alpha_beta_algos=abalgos, hier_dp=True,
-                           dcn_slices=dcn_slices,
-                           # price the bucketed pipelined schedule the
-                           # plan actually runs (0 = monolithic)
-                           hier_bucket_mb=max(float(
-                               getattr(hpc, "hier_bucket_mb", 0.0)), 0.0))
-        ss = SearchStrategy(pp=pp, tp=hier_acc["tp"], dp=hier_acc["dp"])
-        gmb = hier_acc["mb"]
-        cands = {}
-        hier = hier_dp_reduce_ms(ss, cctx, gmb)
-        if hier is not None:
-            cands["hier"] = hier / pp
-            split = _hier_dp_split(ss, cctx)
-            if split is not None:
-                cross, intra = split
-                if intra > 1:
-                    cands["hier_intra"] = _algo_min_ms(
-                        cctx, intra, 1, "ici", gmb) / pp
-                if cross > 1:
-                    ar = (_algo_min_ms(cctx, cross, 0, "dcn", gmb / intra)
-                          or _algo_min_ms(cctx, cross, 1, "dcn",
-                                          gmb / intra))
-                    if ar is not None:
-                        cands["hier_cross"] = ar / pp
-        if cands:
-            # hier_intra/hier_cross are the DECOMPOSITION of "hier", not
-            # competing candidates — the min runs over flat/hier
-            _merge_algo(out["dp"], cands, choices=("flat", "hier"))
-    # prune the algorithms scaffolding when only the flat pair priced dp
-    # (the legacy single-curve output shape); flag tp's accumulated
-    # argmin as indicative (exact when curve coverage is layer-uniform)
-    if set(out["dp"].get("algorithms", ())) == {"flat"}:
-        del out["dp"]["algorithms"]
-        out["dp"].pop("algorithm", None)
+                    "predicted_ms", 0.0) + (pair[0] + grad_mb / pair[1]) / pp
+    # tp's accumulated argmin is indicative (exact when curve coverage is
+    # layer-uniform)
     tp_algs = out["tp"].get("algorithms")
     if tp_algs:
         out["tp"]["algorithm"] = min(tp_algs, key=tp_algs.get)
@@ -1298,10 +1180,6 @@ def measured_components(attr: Attribution, hpc: Any) -> Dict[str, float]:
     add("cp", cat.get("permute_cp", 0.0))
     add("pp", cat.get("permute_pp", 0.0))
     add("dp" if any_sdp else "tp", cat.get("allreduce", 0.0))
-    # hierarchical dp reduction (marker-billed in attribute()): all three
-    # collectives are dp traffic regardless of the ag/rs heuristics above
-    add("dp", cat.get("hier_rs", 0.0) + cat.get("hier_ar", 0.0)
-        + cat.get("hier_ag", 0.0) + cat.get("permute_dp", 0.0))
     add(permute_to, cat.get("permute", 0.0) + cat.get("p2p", 0.0)
         + cat.get("broadcast", 0.0))
     return out
@@ -1319,7 +1197,6 @@ def audit_plan(
     mixed_precision: bool = True,
     predicted_layer_s: Optional[Sequence[float]] = None,
     steps: Optional[int] = None,
-    dcn_slices: int = 1,
 ) -> Dict[str, Any]:
     """Diff the active plan's predictions against the measured attribution
     and emit the calibration data: per component, predicted MB + (α-β)
@@ -1331,12 +1208,8 @@ def audit_plan(
     against the 1F1B analytical ``2(pp−1)/(m+2(pp−1))``.
 
     With ``alpha_beta_algos``, per-ALGORITHM rows follow each priced
-    component (``tp[ring_ici]``, ``dp[hier]``, ...): every candidate
-    curve's predicted ms, the chosen one flagged — measured-vs-predicted
-    per algorithm is exactly the signal that says whether the
-    per-algorithm model beats the single curve. The hierarchical dp
-    sub-collectives additionally carry their own MEASURED ms (the
-    ``hier_dp_*`` scope markers bill them separately in ``attribute``).
+    component (``tp[ring_ici]``, ...): every candidate curve's predicted
+    ms, the chosen one flagged.
 
     Emits ``audit/*`` gauges (labelled ``component=``) into ``registry``
     (the process default when omitted) plus one ``plan_audit`` event
@@ -1349,16 +1222,7 @@ def audit_plan(
     predicted = predicted_comm_per_step(
         hpc, model, alpha_beta=alpha_beta,
         alpha_beta_algos=alpha_beta_algos,
-        mixed_precision=mixed_precision, dcn_slices=dcn_slices)
-    # measured counterparts of the hierarchical decomposition rows
-    hier_measured = {
-        "hier_intra": (attr.categories_ms.get("hier_rs", 0.0)
-                       + attr.categories_ms.get("hier_ag", 0.0)) / n_steps,
-        "hier_cross": attr.categories_ms.get("hier_ar", 0.0) / n_steps,
-        "hier": (attr.categories_ms.get("hier_rs", 0.0)
-                 + attr.categories_ms.get("hier_ar", 0.0)
-                 + attr.categories_ms.get("hier_ag", 0.0)) / n_steps,
-    }
+        mixed_precision=mixed_precision)
 
     rows: List[Dict[str, Any]] = []
     for comp in ("tp", "dp", "sp", "cp", "pp"):
@@ -1383,28 +1247,7 @@ def audit_plan(
                                     "predicted_ms": round(alg_ms, 4)}
             if alg == chosen:
                 arow["chosen"] = True
-            a_meas = (hier_measured.get(alg) if comp == "dp" else None)
-            if a_meas:
-                arow["measured_ms"] = round(a_meas, 4)
-                if alg_ms:
-                    arow["ratio"] = round(a_meas / alg_ms, 4)
-                    arow["residual_ms"] = round(a_meas - alg_ms, 4)
             rows.append(arow)
-        if comp == "dp" and attr.hier_bucket_ms:
-            # per-bucket-stage rows (the bucketed pipelined schedule's
-            # hier_dp_{rs,ar,ag}_b{i} scopes): measured-only detail under
-            # the dp component — the per-bucket split is what shows
-            # whether the DCN stage really hid behind the ICI stages
-            _stage_rank = {"hier_rs": 0, "hier_ar": 1, "hier_ag": 2}
-
-            def _bkey(k: str) -> Tuple[int, int, str]:
-                stem, _, idx = k.rpartition("_b")
-                return (int(idx), _stage_rank.get(stem, 9), stem)
-
-            for bk in sorted(attr.hier_bucket_ms, key=_bkey):
-                rows.append({"component": f"dp[{bk}]",
-                             "measured_ms": round(
-                                 attr.hier_bucket_ms[bk] / n_steps, 4)})
 
     compute_row: Dict[str, Any] = {
         "component": "compute",
@@ -1468,7 +1311,6 @@ def analyze_and_audit(
     mixed_precision: bool = True,
     predicted_layer_s: Optional[Sequence[float]] = None,
     step_spans: Sequence[str] = STEP_SPANS,
-    dcn_slices: int = 1,
 ) -> Optional[Dict[str, Any]]:
     """One-call closed loop for the launchers: parse the newest capture
     under ``trace_dir``, attribute it, audit it against the plan. Thread
@@ -1489,8 +1331,7 @@ def analyze_and_audit(
                           alpha_beta=alpha_beta,
                           alpha_beta_algos=alpha_beta_algos,
                           mixed_precision=mixed_precision,
-                          predicted_layer_s=predicted_layer_s,
-                          dcn_slices=dcn_slices)
+                          predicted_layer_s=predicted_layer_s)
     except FileNotFoundError:
         return None
     except Exception:  # noqa: BLE001 — post-mortem helper, never fatal

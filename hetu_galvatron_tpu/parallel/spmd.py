@@ -355,10 +355,10 @@ def interior_sharding(
     gated MLP's gate | up as ``[H, 2, F]`` with F on tp
     (modules.gate_up_pairs), the biases likewise. The step applies it once,
     outside the microbatch scan (trainer.make_train_step; the eval step's
-    and the hier_dp lanes' loss apply it themselves); what is stored
-    stays ``[q | k | v]`` and ``[gate | up]``, which on this TPU is the
-    layout a tp = 1 layer's one fused matmul wants (a stored ``[H, 2, F]``
-    is no bitcast of ``[H, 2F]`` under (8, 128) tiling: it cost
+    loss applies it itself); what is stored stays ``[q | k | v]`` and
+    ``[gate | up]``, which on this TPU is the layout a tp = 1 layer's one
+    fused matmul wants (a stored ``[H, 2, F]`` is no bitcast of
+    ``[H, 2F]`` under (8, 128) tiling: it cost
     ``mistral7b_c1_s4k`` 14 % more estimated cycles, AOT, PR 28). Only
     what the plan says decides: layers with no weight-tp axes (tp = 1,
     Ulysses), MoE and t5 layers are left as they are, and so are layers
@@ -524,7 +524,6 @@ def build_spmd_loss_fn(
     layer_overrides: Optional[Dict[int, LayerOps]] = None,
     with_moe_stats: bool = False,
     tp_overlap: bool = False,
-    lane_dp: bool = False,
     kernel_interpret: bool = False,
     hoist_view: bool = False,
     remat_flags: Optional[Dict[str, Sequence[Any]]] = None,
@@ -545,61 +544,29 @@ def build_spmd_loss_fn(
     interpret mode: CPU tests pass it, nothing infers it.
     ``remat_flags`` (:func:`plan_remat_flags`'s lists, by stack) says which
     blocks are rematerialized; None = every block whose plan bit is set
-    (the train step hands the lists it chose, :class:`KeptStep`).
-
-    ``lane_dp`` builds the hierarchical-dp LANE variant: the interior
-    activation constraints drop the dp axes (each lane's batch slice lives
-    entirely inside one dp group, so a dp-sharded constraint under the
-    per-lane vmap would force a per-layer reshard of every lane), and the
-    lane axis itself is pinned to the dp mesh axes by the caller's
-    ``jax.vmap(..., spmd_axis_name=dp_axes)``. Param specs and the
-    returned batch sharding stay the FLAT plan's (params are unmapped;
-    the lane reshape happens inside the step). cp/Ulysses layers keep
-    their GSPMD attention core under ``lane_dp`` instead of the ring /
-    a2a shard_map kernels (which cannot nest under the lane vmap,
-    eligibility.HIER_KERNEL_REASON): the partitioner inserts the
-    sequence collectives inside each lane — same math, collective
-    association differs within float tolerance."""
+    (the train step hands the lists it chose, :class:`KeptStep`)."""
     enc_per, per_layer, vocab, pspecs = _lower_specs(hpc, mesh, axes_tree)
-    if lane_dp:
-        lane = lambda sh: replace(sh, dp_axes=())
-        b_layers = [lane(sh) for sh in per_layer]
-        b_vocab = lane(vocab)
-        b_enc = [lane(sh) for sh in enc_per]
-    else:
-        b_layers, b_vocab, b_enc = per_layer, vocab, enc_per
-    boundary = make_boundary_fn(b_layers, b_vocab, mesh)
-    enc_boundary = (make_boundary_fn(b_enc, b_vocab, mesh)
-                    if b_enc else None)
+    boundary = make_boundary_fn(per_layer, vocab, mesh)
+    enc_boundary = (make_boundary_fn(enc_per, vocab, mesh)
+                    if enc_per else None)
     use_flash = None if cfg.use_flash_attn else False
-    if lane_dp:
-        # no shard_map kernels under the lane vmap: cp/ulysses layers run
-        # the XLA core (GSPMD partitions the sequence-sharded softmax per
-        # lane); flash/fused-CE/tp_overlap are gated off by the callers
-        # (make_spmd_train_step raises HIER_KERNEL_REASON first)
-        ring = {}
-        enc_overrides = None
-    else:
-        ring = attention_overrides(
-            b_layers, mesh, use_flash=use_flash,
-            with_cross=cfg.model_type == "t5",
-            cp_zigzag=getattr(hpc, "cp_zigzag", False),
-            flash_interpret=kernel_interpret,
-            mixers=[m for m, _ in cfg.block_kinds(len(b_layers))])
-        enc_overrides = (attention_overrides(
-            b_enc, mesh, use_flash=use_flash,
-            flash_interpret=kernel_interpret) if b_enc else None)
+    ring = attention_overrides(
+        per_layer, mesh, use_flash=use_flash,
+        with_cross=cfg.model_type == "t5",
+        cp_zigzag=getattr(hpc, "cp_zigzag", False),
+        flash_interpret=kernel_interpret,
+        mixers=[m for m, _ in cfg.block_kinds(len(per_layer))])
+    enc_overrides = (attention_overrides(
+        enc_per, mesh, use_flash=use_flash,
+        flash_interpret=kernel_interpret) if enc_per else None)
     if tp_overlap:
         # under the plan's kernels, and both under the caller's
         ring = merge_ops(tp_overlap_overrides(per_layer, mesh, cfg)[0], ring)
-    if not lane_dp and cfg.num_experts and cfg.model_type != "t5":
-        # (no shard_map under the lane vmap)
+    if cfg.num_experts and cfg.model_type != "t5":
         ring = merge_ops(ring, expert_exchange_overrides(
             per_layer, mesh, cfg, hpc))
     layer_overrides = merge_ops(ring, layer_overrides)
-    # b_layers: under the lane vmap the dp axes are the vmap's, in the
-    # interior's constraints as at the boundaries
-    interior, param_view = interior_sharding(b_layers, mesh, cfg,
+    interior, param_view = interior_sharding(per_layer, mesh, cfg,
                                              layer_overrides)
     layer_overrides = merge_ops(interior, layer_overrides)
     flags = remat_flags or plan_remat_flags(cfg, per_layer, enc_per)
@@ -644,9 +611,9 @@ def build_spmd_loss_fn(
         # gives a block that attends (the flash kernels on a TPU)
         tower_kwargs = dict(
             tower_remat_flags=flags["tower"],
-            tower_ops=({} if lane_dp else attention_overrides(
-                b_layers[:1], mesh, use_flash=use_flash,
-                flash_interpret=kernel_interpret)).get(0))
+            tower_ops=attention_overrides(
+                per_layer[:1], mesh, use_flash=use_flash,
+                flash_interpret=kernel_interpret).get(0))
 
     def loss_fn(p, batch):
         if view_here is not None:
@@ -869,10 +836,6 @@ def make_spmd_train_step(
     donate: bool = True,
     chunks: Optional[int] = None,
     tp_overlap: bool = False,
-    hier_dp: bool = False,
-    dcn_slices: int = 1,
-    hier_bucket_mb: float = 0.0,
-    dp_schedule: Optional[str] = None,
     kernel_interpret: bool = False,
     keep_blocks: bool = True,
 ):
@@ -884,17 +847,8 @@ def make_spmd_train_step(
     ``chunks`` overrides the plan's microbatch count (batch-size ramp:
     the launcher rebuilds the step per chunk count at a fixed micro size).
     ``tp_overlap`` runs eligible TP layers' projections as decomposed
-    ring-collective matmuls (ops/overlap.py). ``hier_dp`` swaps the
-    implicit GSPMD dp gradient all-reduce for the explicit hierarchical
-    reduce-scatter/all-reduce/all-gather path (ops/hier_reduce.py), with
-    the slice/host split taken from ``dcn_slices`` and the bucketed
-    software-pipelining granularity from ``hier_bucket_mb``
-    (``parallel.hier_bucket_mb``; 0 = one monolithic bucket); ineligible
-    plans raise with the shared eligibility reason (the launcher logs and
-    falls back). ``dp_schedule`` (``parallel.dp_schedule``, hier_dp only)
-    swaps the hand-implemented rs/ar/ag program for a synthesized,
-    verified, emitted collective schedule (``collectives/``) — the plan
-    JSON records the family the search priced cheapest.
+    ring-collective matmuls (ops/overlap.py). Gradients are reduced over
+    dp by XLA's partitioner, once a microbatch.
     ``kernel_interpret`` (CPU tests) runs the Pallas kernels in interpret
     mode. On devices that state how much they hold, a block whose plan bit
     is set is rematerialized only where its values do not fit
@@ -906,30 +860,13 @@ def make_spmd_train_step(
         raise ValueError("make_spmd_train_step is the pp=1 path; use the "
                          "pipeline engine for pp>1")
     moe_stats = bool(cfg.num_experts)
-    if hier_dp:
-        from hetu_galvatron_tpu.analysis.eligibility import (
-            HIER_KERNEL_REASON,
-            plan_hier_dp_reason,
-        )
 
-        reason = plan_hier_dp_reason(cfg, hpc)
-        if reason is None and tp_overlap:
-            reason = HIER_KERNEL_REASON
-        if reason is None and flash_kernel_runs(cfg.use_flash_attn,
-                                                mesh.devices.flat):
-            reason = HIER_KERNEL_REASON
-        if reason is None and cfg.use_fused_ce and mesh.size > 1:
-            reason = HIER_KERNEL_REASON  # vocab-parallel CE is a shard_map
-        if reason is not None:
-            raise ValueError(f"hier_dp unsupported: {reason}")
     def lowered(flags):
         return build_spmd_loss_fn(
             cfg, hpc, mesh, axes_tree, compute_dtype=compute_dtype,
             layer_overrides=layer_overrides, with_moe_stats=moe_stats,
-            tp_overlap=tp_overlap, lane_dp=hier_dp,
-            kernel_interpret=kernel_interpret,
-            # (the lane reducer takes gradients in the stored layout)
-            hoist_view=not hier_dp, remat_flags=flags)
+            tp_overlap=tp_overlap, kernel_interpret=kernel_interpret,
+            hoist_view=True, remat_flags=flags)
 
     loss_fn, pspecs, batch_shd, per_layer, vocab, enc_per, param_view = (
         lowered(None))
@@ -937,17 +874,9 @@ def make_spmd_train_step(
                              enc_per_layer=enc_per or None)
     opt_specs = opt_state_specs(tx, params, opt_pspecs)
     chunks = max(chunks if chunks is not None else hpc.chunks, 1)
-    hier = None
-    if hier_dp:
-        from hetu_galvatron_tpu.ops.hier_reduce import make_hier_reducer
-
-        hier = make_hier_reducer(mesh, per_layer, vocab, axes_tree,
-                                 dcn_slices=dcn_slices,
-                                 bucket_mb=hier_bucket_mb,
-                                 schedule=dp_schedule or None)
     constrain_mbs = None
-    if hier is None and chunks > 1:
-        # flat-path microbatch pin (ROADMAP embed-ZeRO-3 BUG, fixed): the
+    if chunks > 1:
+        # microbatch pin (ROADMAP embed-ZeRO-3 BUG, fixed): the
         # [B] -> [chunks, B/chunks] reshape naturally absorbs the OUTER dp
         # mesh axis into the chunk dim, so every scanned microbatch arrives
         # batch-sharded over only the inner dp axes — a layout whose
@@ -956,8 +885,7 @@ def make_spmd_train_step(
         # drifts). Pin the chunk axis replicated and the sample axis to
         # the plan's own batch sharding: each microbatch's embed-grad
         # reduce-scatter then materializes per microbatch in the correct
-        # layout — the same pinning discipline hier.lane_batch always had
-        # (which is why the hier path was exact where flat drifted).
+        # layout.
         mb_spec = NamedSharding(mesh, P(None, *per_layer[0].batch_spec()))
 
         def constrain_mbs(mbs):
@@ -971,7 +899,7 @@ def make_spmd_train_step(
 
     def jitted_step(loss_fn):
         step = make_train_step(
-            loss_fn, tx, chunks=chunks, aux_stats=moe_stats, hier=hier,
+            loss_fn, tx, chunks=chunks, aux_stats=moe_stats,
             constrain_microbatches=constrain_mbs, param_view=param_view)
         if not use_dropout:
             return jax.jit(
@@ -1013,7 +941,7 @@ def make_spmd_train_step(
 
     plan = plan_remat_flags(cfg, per_layer, enc_per)
     limit = keep_blocks and kept.bytes_limit(list(mesh.devices.flat))
-    if not limit or hier is not None or not any(map(any, plan.values())):
+    if not limit or not any(map(any, plan.values())):
         # nothing to spend (the CPU reports no limit) or nothing to choose:
         # the flags are the plan's, the step the one it always was
         return jitted_step(loss_fn), pspecs, opt_specs, batch_shd

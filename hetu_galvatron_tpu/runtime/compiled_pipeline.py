@@ -178,8 +178,6 @@ class CompiledPipelineEngine:
         tp_overlap: bool = False,
         use_flash: Optional[bool] = None,
         flash_interpret: bool = False,
-        hier_dp: bool = False,
-        hier_bucket_mb: float = 0.0,
     ):
         """``tp_overlap`` swaps the (uniform) layer's projection matmuls for
         the stage-stacked ring ag/rs kernels (ops/overlap.py) when the layer
@@ -187,16 +185,7 @@ class CompiledPipelineEngine:
         ``use_flash`` mirrors the host engine's attention dispatch: None =
         the platform default (Pallas flash on TPU when cfg.use_flash_attn),
         an explicit bool forces it; ``flash_interpret`` runs the Pallas
-        kernels in interpret mode (CPU parity drills). ``hier_dp`` runs
-        the backward units per dp LANE (a vmap over the lane-split batch
-        slices of the per-tick vjp) with lane-local grad accumulation
-        through the tick scan, and reduces ONCE after it via the explicit
-        hierarchical reduce-scatter/all-reduce/all-gather program
-        (ops/hier_reduce.py) — the dp traffic leaves the scan and the
-        cross-slice hop carries only the 1/intra shard. Ineligible plans
-        (eligibility.hier_dp_unsupported_reason; any shard_map kernel —
-        rings/flash/cp/ulysses — cannot nest under the lane vmap) raise,
-        mirroring the unsupported-plan ctor contract."""
+        kernels in interpret mode (CPU parity drills)."""
         reason = self.unsupported_reason(cfg, hpc)
         if reason is not None:
             raise ValueError(f"compiled pipeline schedule unsupported: "
@@ -247,25 +236,6 @@ class CompiledPipelineEngine:
                 self.tp_overlap = True
             else:
                 self.overlap_reason = reason
-        # hierarchical dp gradient reduction (ops/hier_reduce.py): validate
-        # eligibility here (ctor contract); the reducer itself binds to the
-        # grad specs, which need the axes tree — built in split_params
-        self.hier_dp = bool(hier_dp)
-        self._dcn_slices = dcn_slices
-        self._hier_bucket_mb = float(hier_bucket_mb)
-        self._hier = None
-        if self.hier_dp:
-            from hetu_galvatron_tpu.analysis.eligibility import (
-                HIER_KERNEL_REASON,
-                plan_hier_dp_reason,
-            )
-
-            reason = plan_hier_dp_reason(cfg, hpc)
-            if reason is None and (self._ops.matmuls or
-                                   self._ops.sdpa is not None):
-                reason = HIER_KERNEL_REASON
-            if reason is not None:
-                raise ValueError(f"hier_dp unsupported: {reason}")
         # jit caches keyed by microbatch count (a batch-size ramp compiles
         # one program per distinct count; a fixed plan compiles exactly once)
         self._step_jits: Dict[int, Any] = {}
@@ -347,37 +317,6 @@ class CompiledPipelineEngine:
         return jax.tree.map(lambda s: NamedSharding(self.mesh, s),
                             spec_tree_, is_leaf=lambda x: isinstance(x, P))
 
-    def _stacked_grad_specs(self, axes: Params) -> Params:
-        """Grad-layout spec tree for the hierarchical reducer: the stacked
-        param specs with ZeRO-3 dp-sharding overridden OFF (the reduction's
-        lane axis owns the dp mesh axes — ops/hier_reduce.py)."""
-        isP = lambda x: isinstance(x, P)
-        is_axes = lambda x: (isinstance(x, tuple)
-                             and all(isinstance(s, str) for s in x))
-        tree = lambda a, sh: jax.tree.map(
-            lambda la: sh.param_spec(la, zero3_override=False), a,
-            is_leaf=is_axes)
-        out: Params = {"stages": tuple(
-            jax.tree.map(stacked_spec,
-                         tree(self._slot_axes(axes, j), self.layer_sh),
-                         is_leaf=isP)
-            for j in range(self.lps))}
-        for k in ("embed", "prenorm", "head"):
-            out[k] = tree(axes[k], self.vocab_sh)
-        return out
-
-    def _build_hier(self, axes: Params) -> None:
-        from hetu_galvatron_tpu.ops.hier_reduce import HierDpReducer
-        from hetu_galvatron_tpu.runtime.mesh import hier_cross_degree
-
-        dp_axes = self.layer_sh.dp_axes
-        dp_deg = axes_size(self.mesh, dp_axes)
-        cross = hier_cross_degree(self.pp, dp_deg, self._dcn_slices)
-        self._hier = HierDpReducer(
-            mesh=self.mesh, dp_axes=dp_axes, cross=cross,
-            intra=dp_deg // cross, specs=self._stacked_grad_specs(axes),
-            bucket_mb=self._hier_bucket_mb)
-
     def split_params(self, params: Params, axes: Params) -> Params:
         """Full (host/single-device) params tree -> the stacked layout:
         decoder layer ``s*lps + j`` becomes row s of ``stages[j]``; the
@@ -399,8 +338,6 @@ class CompiledPipelineEngine:
         # make_embed_use_constraint); without it the program is still
         # correct, just chattier to partition
         self._embed_axes = axes["embed"]
-        if self.hier_dp and self._hier is None:
-            self._build_hier(axes)
         specs = self.stacked_param_specs(axes)
         self._param_shardings = self._nshd(specs)
         # stage through a host copy: device_put of a fully-replicated leaf
@@ -717,8 +654,6 @@ class CompiledPipelineEngine:
         vfwd = self._stacked_fwd
         vfull = self._stacked_full
 
-        hier = self._hier
-
         def step(sp, opt, batch, step_rng):
             tokens = batch["tokens"]            # [m, B, S] int32
             labels = batch["labels"]            # [m, B, S] int32
@@ -730,18 +665,9 @@ class CompiledPipelineEngine:
             b, s = tokens.shape[1], tokens.shape[2]
             zero_act = jnp.zeros((pp, b, s, cfg.hidden_size),
                                  self.compute_dtype)
-            if hier is None:
-                gacc0 = jax.tree.map(
-                    lambda p: jnp.zeros(p.shape, p.dtype),
-                    {"stages": stages_w, **shared})
-            else:
-                # lane-stacked fp32 accumulators: [L, ...] with the lane
-                # dim on the dp mesh axes — per-device memory equals the
-                # flat accumulator's (each device holds one lane's slice)
-                gacc0 = hier.constrain_stacked(jax.tree.map(
-                    lambda p: jnp.zeros((hier.lanes,) + p.shape,
-                                        jnp.float32),
-                    {"stages": stages_w, **shared}))
+            gacc0 = jax.tree.map(
+                lambda p: jnp.zeros(p.shape, p.dtype),
+                {"stages": stages_w, **shared})
             buf0 = jnp.zeros((pp, D, b, s, cfg.hidden_size),
                              self.compute_dtype)
             lanes_a = jnp.asarray(lanes)
@@ -781,76 +707,19 @@ class CompiledPipelineEngine:
                 # make EVERY grad they emit exactly zero (vjp linearity)
                 dy_in = jnp.where(bwd_valid[:, None, None, None], bwd_dy,
                                   jnp.zeros_like(bwd_dy))
-                if hier is None:
-                    (y_re, losses), vjp_fn = jax.vjp(
-                        lambda ws, sh, xs: vfull(
-                            ws, sh, xs, tok_b, lbl_b, msk_b,
-                            jnp.clip(bj, 0, m - 1), step_rng),
-                        stages_w, shared, x_st)
-                    dl_in = jnp.where(
-                        (lanes_a == pp - 1) & bwd_valid,
-                        w_b.astype(jnp.float32), 0.0)
-                    dws, dsh, dxs = vjp_fn((dy_in, dl_in))
-                    gacc = jax.tree.map(jnp.add, gacc,
-                                        {"stages": dws, **dsh})
-                    loss_acc = loss_acc + jnp.where(
-                        bwd_valid[pp - 1], w_b * losses[pp - 1], 0.0)
-                else:
-                    # per-dp-lane backward: the vjp runs vmapped over the
-                    # lane-split batch slices (stage weights unmapped), so
-                    # per-lane grads stack [L, ...] and accumulate with
-                    # ZERO cross-dp bytes; the hierarchical reduce after
-                    # the scan performs the only dp communication
-                    L = hier.lanes
-                    bl = b // L
-
-                    def lanes_in(a):  # [pp, b, ...] -> [L, pp, b/L, ...]
-                        y = a.reshape((a.shape[0], L, bl) + a.shape[2:])
-                        return jnp.moveaxis(y, 1, 0)
-
-                    def lanes_out(a):  # inverse of lanes_in
-                        y = jnp.moveaxis(a, 0, 1)
-                        return y.reshape((y.shape[0], b) + y.shape[3:])
-
-                    tok_l = tok_b.reshape((L, bl) + tok_b.shape[1:])
-                    lbl_l = lbl_b.reshape((L, bl) + lbl_b.shape[1:])
-                    msk_l = (msk_b.reshape((L, bl) + msk_b.shape[1:])
-                             if msk_b is not None else None)
-                    # per-lane token share of THIS microbatch: the
-                    # weighted lane means recombine to the flat path's
-                    # microbatch masked mean exactly
-                    share = microbatch_weights(msk_l, L)
-                    w_lane = w_b.astype(jnp.float32) * share
-
-                    def lane_bwd(xs_l, dy_l, tok_i, lbl_i, msk_i, w_i):
-                        (y_re, losses), vjp_fn = jax.vjp(
-                            lambda ws, sh, xs: vfull(
-                                ws, sh, xs, tok_i, lbl_i, msk_i,
-                                jnp.clip(bj, 0, m - 1), step_rng),
-                            stages_w, shared, xs_l)
-                        dl = jnp.where((lanes_a == pp - 1) & bwd_valid,
-                                       w_i, 0.0)
-                        dws, dsh, dxs = vjp_fn((dy_l, dl))
-                        return dws, dsh, dxs, losses
-
-                    # spmd_axis_name pins the lane axis of every batched
-                    # intermediate onto the dp mesh axes (ops/hier_reduce
-                    # lane discipline — the per-lane slices never leave
-                    # their dp group)
-                    dws, dsh, dxs_l, losses = jax.vmap(
-                        lane_bwd,
-                        in_axes=(0, 0, 0, 0,
-                                 0 if msk_l is not None else None, 0),
-                        spmd_axis_name=tuple(self.layer_sh.dp_axes))(
-                        lanes_in(x_st), lanes_in(dy_in), tok_l, lbl_l,
-                        msk_l, w_lane)
-                    gacc = hier.constrain_stacked(jax.tree.map(
-                        lambda a, g: a + g.astype(jnp.float32), gacc,
-                        {"stages": dws, **dsh}))
-                    dxs = lanes_out(dxs_l).astype(self.compute_dtype)
-                    loss_acc = loss_acc + jnp.where(
-                        bwd_valid[pp - 1],
-                        jnp.sum(w_lane * losses[:, pp - 1]), 0.0)
+                (y_re, losses), vjp_fn = jax.vjp(
+                    lambda ws, sh, xs: vfull(
+                        ws, sh, xs, tok_b, lbl_b, msk_b,
+                        jnp.clip(bj, 0, m - 1), step_rng),
+                    stages_w, shared, x_st)
+                dl_in = jnp.where(
+                    (lanes_a == pp - 1) & bwd_valid,
+                    w_b.astype(jnp.float32), 0.0)
+                dws, dsh, dxs = vjp_fn((dy_in, dl_in))
+                gacc = jax.tree.map(jnp.add, gacc,
+                                    {"stages": dws, **dsh})
+                loss_acc = loss_acc + jnp.where(
+                    bwd_valid[pp - 1], w_b * losses[pp - 1], 0.0)
                 # ---- rotate: activations s->s+1, cotangents s->s-1 ----
                 fwd_x = rot_fwd(y)
                 dxs = jax.lax.with_sharding_constraint(dxs, act_shd)
@@ -861,10 +730,6 @@ class CompiledPipelineEngine:
                       jnp.zeros((), jnp.float32))
             (_, _, _, grads, loss), _ = jax.lax.scan(
                 tick, carry0, jnp.arange(T))
-            if hier is not None:
-                # the ONLY dp communication of the step: rs-intra at full
-                # volume, ar-cross on the 1/intra shard, ag-intra back
-                grads = hier.reduce(grads)
 
             # global grad-norm clip fused into the program (host engine:
             # _gnorm_jit/_clip_jit across submeshes). The single wte already

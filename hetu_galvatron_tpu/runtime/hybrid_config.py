@@ -13,7 +13,7 @@ microbatch count.
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import List, Optional
+from typing import List, Optional, Tuple
 
 import math
 
@@ -60,18 +60,9 @@ class HybridParallelConfig:
     # (fct+bct, ms) so the plan audit can diff the exact model that picked
     # the plan; None for GLOBAL-mode or pre-audit plan files.
     predicted_layer_compute_ms: Optional[List[float]] = None
-    # The search priced this plan's dp gradient reduction hierarchically
-    # ("hier_dp": 1 in the plan JSON) — the launcher enables the matching
-    # runtime path (ops/hier_reduce.py; args.parallel.hier_dp ORs in).
-    hier_dp: bool = False
-    # Bucketed software-pipelining granularity the search priced it at
-    # ("hier_bucket_mb" in the plan JSON; 0 = monolithic). The runtime
-    # buckets at the same size; a nonzero parallel.hier_bucket_mb wins.
-    hier_bucket_mb: float = 0.0
-    # Synthesized collective schedule family the search priced the dp
-    # reduction with ("dp_schedule" in the plan JSON; collectives/); None
-    # runs the hand-implemented three-stage hierarchical path.
-    dp_schedule: Optional[str] = None
+    # Keys of the removed hierarchical dp reduction that the plan file still
+    # carried (strategy.IGNORED_PLAN_KEYS); the launcher names them once.
+    ignored_plan_keys: Tuple[str, ...] = ()
 
     @property
     def enc_strategies(self) -> List[LayerStrategy]:
@@ -153,9 +144,7 @@ def get_hybrid_parallel_config(
         pp_division = extras["pp_division"] or default_pp_division(
             n_layers, pp_deg * vpp)
         pred_layer_ms = extras.get("predicted_layer_compute_ms")
-        hier_dp = bool(extras.get("hier_dp", False))
-        hier_bucket_mb = float(extras.get("hier_bucket_mb", 0.0) or 0.0)
-        dp_schedule = extras.get("dp_schedule") or None
+        ignored_keys = extras["ignored_keys"]
     else:
         pp_deg = par.pp_deg
         r = eligibility.pp_world_reason(world_size, pp_deg)
@@ -193,9 +182,7 @@ def get_hybrid_parallel_config(
         pp_division = default_pp_division(n_layers, pp_deg * vpp)
         chunks = get_chunks(args, world_size)
         pred_layer_ms = None
-        hier_dp = False
-        hier_bucket_mb = 0.0
-        dp_schedule = None
+        ignored_keys = ()
 
     # guard both branches (a JSON plan with pp*vpp > layers would otherwise
     # slip through as zero-layer chunks from default_pp_division): the
@@ -250,6 +237,5 @@ def get_hybrid_parallel_config(
         pipeline_type=pipeline_type, default_dp_type=default_dp,
         world_size=world_size, num_encoder_layers=n_enc, vpp_deg=vpp,
         cp_zigzag=cp_zigzag, predicted_layer_compute_ms=pred_layer_ms,
-        hier_dp=hier_dp, hier_bucket_mb=hier_bucket_mb,
-        dp_schedule=dp_schedule,
+        ignored_plan_keys=ignored_keys,
     )

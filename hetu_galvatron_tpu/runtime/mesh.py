@@ -47,15 +47,6 @@ from hetu_galvatron_tpu.utils.strategy import (
 # logical param-axis names sharded by tensor parallelism
 _TP_LOGICAL = ("qkv", "mlp", "heads", "vocab")
 
-# Canonical sub-axis names for the hierarchical dp/sdp gradient reduction
-# (ops/hier_reduce.py): the dp mesh axes are regrouped into an outer
-# cross-slice (DCN) sub-axis and an inner intra-host (ICI) sub-axis. These
-# names are part of the mesh-axis canon (analysis/lint.py GAL003) — any
-# other hand-rolled axis literal in the hierarchical path gets flagged.
-HIER_SLICE_AXIS = "slice"  # cross-slice / DCN level (outer dp axes)
-HIER_HOST_AXIS = "host"    # intra-host / ICI level (inner dp axes)
-
-
 def flash_kernel_runs(use_flash_attn: bool, devices: Sequence[Any]) -> bool:
     """THE attention-kernel rule, shared by the SPMD path, both pipeline
     engines and the launcher's eligibility checks: the Pallas flash kernel
@@ -176,54 +167,6 @@ def build_mesh(
 def stage_axes(mesh: Mesh) -> Tuple[str, ...]:
     """The binary intra-stage axes, outermost first."""
     return tuple(n for n in mesh.axis_names if n != "pp")
-
-
-def hier_cross_degree(pp_deg: int, dp_deg: int, dcn_slices: int) -> int:
-    """How much of a layer's dp degree crosses DCN slice boundaries,
-    mirroring :func:`dcn_factor_shape`'s pp-first absorption order: the
-    slices land on pp first, the remainder on the outer (dp) mesh axes.
-    Returns the cross-slice factor of dp (1 when the job spans one slice);
-    raises when the leftover slices cannot divide dp — the same plans
-    :func:`dcn_factor_shape` rejects."""
-    if dcn_slices <= 1:
-        return 1
-    left = dcn_slices // math.gcd(dcn_slices, max(pp_deg, 1))
-    if max(dp_deg, 1) % left:
-        raise ValueError(
-            f"dcn_slices {dcn_slices} does not factor over pp {pp_deg} x "
-            f"dp {dp_deg} (pp * outer-dp must absorb the slices)")
-    return left
-
-
-def hier_submesh(mesh: Mesh, dp_axes: Sequence[str], cross: int) -> Mesh:
-    """Reshaped VIEW of ``mesh`` for the hierarchical dp gradient reduction
-    (ops/hier_reduce.py): the (contiguous, leading-stage) ``dp_axes`` are
-    regrouped into two axes — :data:`HIER_SLICE_AXIS` of size ``cross``
-    (outermost: crosses DCN) and :data:`HIER_HOST_AXIS` of size
-    ``dp_deg // cross`` (inner: ICI-local) — while every other axis keeps
-    its name and extent. The flat device order is unchanged (adjacent
-    binary axes merge), so the view coexists with the global mesh inside
-    one jitted program."""
-    names = list(mesh.axis_names)
-    dp_axes = tuple(dp_axes)
-    if not dp_axes:
-        raise ValueError("hier_submesh needs at least one dp axis")
-    idx = [names.index(a) for a in dp_axes]
-    if idx != list(range(idx[0], idx[0] + len(idx))):
-        raise ValueError(
-            f"dp axes {dp_axes} are not a contiguous run of mesh axes "
-            f"{tuple(names)} (non-consecutive tp plans cannot hier-split)")
-    dp_deg = axes_size(mesh, dp_axes)
-    if cross < 1 or dp_deg % cross:
-        raise ValueError(f"cross-slice degree {cross} does not divide the "
-                         f"dp degree {dp_deg}")
-    lo = idx[0]
-    shape = [mesh.shape[n] for n in names]
-    new_shape = (tuple(shape[:lo]) + (cross, dp_deg // cross)
-                 + tuple(shape[lo + len(dp_axes):]))
-    new_names = (tuple(names[:lo]) + (HIER_SLICE_AXIS, HIER_HOST_AXIS)
-                 + tuple(names[lo + len(dp_axes):]))
-    return Mesh(mesh.devices.reshape(new_shape), new_names)
 
 
 def axes_size(mesh: Mesh, axes: Sequence[str]) -> int:

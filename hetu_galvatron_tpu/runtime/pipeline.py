@@ -55,7 +55,6 @@ from hetu_galvatron_tpu.runtime.mesh import (
     LayerSharding,
     build_mesh,
     device_array,
-    flash_kernel_runs,
     lower_strategy,
     lower_vocab_strategy,
     spec_tree as _spec_tree,
@@ -120,8 +119,6 @@ class PipelineEngine:
         tp_overlap: bool = False,
         use_flash: Optional[bool] = None,
         flash_interpret: bool = False,
-        hier_dp: bool = False,
-        hier_bucket_mb: float = 0.0,
     ):
         from hetu_galvatron_tpu.analysis.eligibility import (
             mixed_stack_reason,
@@ -146,36 +143,6 @@ class PipelineEngine:
         if len(devices) < hpc.world_size:
             raise ValueError(
                 f"need {hpc.world_size} devices, have {len(devices)}")
-        self._hier_bucket_mb = float(hier_bucket_mb)
-        # hierarchical dp gradient reduction (ops/hier_reduce.py): stage
-        # backwards run per dp LANE (vmap over the lane-split microbatch)
-        # so grads accumulate lane-stacked across the schedule, and ONE
-        # per-stage three-collective reduce runs before the tied-embedding
-        # exchange / global clip — which therefore stay unchanged.
-        self.hier_dp = bool(hier_dp)
-        self._dcn_slices = dcn_slices
-        self._axes_tree: Optional[Params] = None
-        if self.hier_dp:
-            from hetu_galvatron_tpu.analysis.eligibility import (
-                HIER_KERNEL_REASON,
-                plan_hier_dp_reason,
-            )
-
-            _reason = plan_hier_dp_reason(cfg, hpc)
-            if _reason is None and tp_overlap:
-                _reason = HIER_KERNEL_REASON
-            if _reason is None and any(s.cp_size > 1 or s.sp
-                                       for s in hpc.layers):
-                # the stage programs keep their ring-cp / ulysses-a2a
-                # shard_map kernels (unlike the pp=1 SPMD path, which
-                # swaps them for the GSPMD core under the lane vmap)
-                _reason = HIER_KERNEL_REASON
-            if _reason is None and (use_flash or (
-                    use_flash is None
-                    and flash_kernel_runs(cfg.use_flash_attn, devices))):
-                _reason = HIER_KERNEL_REASON
-            if _reason is not None:
-                raise ValueError(f"hier_dp unsupported: {_reason}")
         # overlapped-TP projection matmuls inside the stage programs
         # (ops/overlap.py); eligible layers only — same dispatch as the
         # SPMD path's tp_overlap_overrides, per stage submesh
@@ -344,7 +311,6 @@ class PipelineEngine:
     def split_params(self, params: Params, axes: Params) -> List[Params]:
         """Slice a full (host/single-device) params tree into per-stage
         sharded trees (reference stage slicing, pipeline.py:104-106)."""
-        self._axes_tree = axes  # the hier reducers' grad specs need it
         out = []
         for s, st in enumerate(self.stages):
             lo, hi = st.layer_range
@@ -415,59 +381,6 @@ class PipelineEngine:
     # ------------------------------------------------------------------
     # stage programs
     # ------------------------------------------------------------------
-
-    def _stage_grad_specs(self, axes: Params, s: int) -> Params:
-        """Grad-layout specs for the hierarchical reducer: the stage's
-        param specs with ZeRO-3 dp-sharding overridden OFF (the reduction's
-        lane axis owns the dp mesh axes — ops/hier_reduce.py)."""
-        st = self.stages[s]
-        saxes = self.stage_param_axes(axes, s)
-        is_axes = lambda x: (isinstance(x, tuple)
-                             and all(isinstance(a, str) for a in x))
-        no3 = lambda a, sh: jax.tree.map(
-            lambda la: sh.param_spec(la, zero3_override=False), a,
-            is_leaf=is_axes)
-        out: Params = {"layers": tuple(
-            no3(a, sh) for a, sh in zip(saxes["layers"], st.shardings))}
-        for k in ("embed", "prenorm", "head"):
-            if k in saxes:
-                out[k] = no3(saxes[k], st.vocab)
-        return out
-
-    def _make_hier_reduce(self, s: int) -> Callable:
-        """One stage's jitted hierarchical reduce: lane-stacked grad tree
-        -> summed tree, three explicit collectives on the stage submesh."""
-        from hetu_galvatron_tpu.ops.hier_reduce import HierDpReducer
-        from hetu_galvatron_tpu.runtime.mesh import (
-            axes_size,
-            hier_cross_degree,
-        )
-
-        if self._axes_tree is None:
-            raise RuntimeError("split_params must run before the first "
-                               "hier_dp train_step (it records the logical "
-                               "axes tree the reducer specs derive from)")
-        st = self.stages[s]
-        # uniform-plan gate: every layer shares one dp assignment; stages
-        # without decoder layers still lower the plan's first layer
-        sh0 = (st.shardings[0] if st.shardings
-               else lower_strategy(self.hpc.layers[0], st.mesh))
-        dp_deg = axes_size(st.mesh, sh0.dp_axes)
-        # slice absorption is pp-first (mesh.dcn_factor_shape): the stage
-        # groups already sit on slice boundaries, the leftover slices split
-        # each stage's dp internally
-        cross = hier_cross_degree(self.pp, dp_deg, self._dcn_slices)
-        reducer = HierDpReducer(
-            mesh=st.mesh, dp_axes=sh0.dp_axes, cross=cross,
-            intra=dp_deg // cross,
-            specs=self._stage_grad_specs(self._axes_tree, s),
-            bucket_mb=self._hier_bucket_mb)
-        return jax.jit(reducer.reduce)
-
-    @property
-    def _hier_jits(self) -> List[Callable]:
-        return self._jit("hier", lambda: [self._make_hier_reduce(s)
-                                          for s in range(len(self.stages))])
 
     def _stage_apply(self, st: _Stage, sp: Params, x: jax.Array,
                      labels=None, loss_mask=None, dropout_rng=None,
@@ -668,15 +581,7 @@ class PipelineEngine:
         The head stage returns the (unweighted) loss alongside grads so the
         forward never runs separately just for the metric. ``rng`` is the
         same per-(microbatch, stage) key the forward ran with, so the remat
-        recomputation reuses the identical dropout masks.
-
-        Under ``hier_dp`` the backward runs vmapped over the dp lane split
-        of the microbatch (params unmapped), returning LANE-STACKED
-        ``dparams`` with per-lane token-share seeding — the per-device
-        contractions are identical to the flat form, only the cross-lane
-        summation moves into the post-schedule hierarchical reduce."""
-        if self.hier_dp:
-            return self._make_bwd_lanes(st)
+        recomputation reuses the identical dropout masks."""
         if st.has_head:
             def g(sp, x, labels, mask, seed, rng, pos, seg):
                 def lf(sp_, x_):
@@ -700,81 +605,6 @@ class PipelineEngine:
                     st, sp_, x_, dropout_rng=rng, pos=pos, seg=seg), sp, x)
             dp, dx = vjp((dy, seed))
             return dp, dx, aux
-        return jax.jit(g)
-
-    def _make_bwd_lanes(self, st: _Stage) -> Callable:
-        """The hier_dp backward variants (see :meth:`_make_bwd`): the
-        stage runs with dp-FREE interior constraints (each lane's batch
-        slice lives inside one dp group) and the lane axis pinned to the
-        dp mesh axes via ``spmd_axis_name`` — without both, the
-        partitioner re-shards every lane at every per-layer constraint."""
-        from dataclasses import replace as _replace
-
-        from hetu_galvatron_tpu.runtime.trainer import microbatch_weights
-
-        L = max(self.hpc.layers[0].dp_size, 1)
-        _nodp = lambda sh: _replace(sh, dp_axes=())
-        st = _replace(
-            st,
-            shardings=[_nodp(s) for s in st.shardings],
-            vocab=_nodp(st.vocab) if st.vocab is not None else None,
-            enc_shardings=[_nodp(s) for s in (st.enc_shardings or [])])
-        spmd_axes = tuple(lower_strategy(self.hpc.layers[0],
-                                         st.mesh).dp_axes)
-
-        def vmap_lanes(fn, in_axes):
-            return jax.vmap(fn, in_axes=in_axes, spmd_axis_name=spmd_axes)
-
-        def split(a):
-            return (None if a is None
-                    else a.reshape((L, a.shape[0] // L) + a.shape[1:]))
-
-        def ax(a):
-            return None if a is None else 0
-
-        if st.has_head:
-            def g(sp, x, labels, mask, seed, rng, pos, seg):
-                xl, lbl, mskl = split(x), split(labels), split(mask)
-                posl, segl = split(pos), split(seg)
-                # per-lane token share: weighted lane masked-means
-                # recombine to the flat microbatch mean exactly
-                share = microbatch_weights(mskl, L)
-
-                def lane(x_i, lbl_i, msk_i, pos_i, seg_i, w_i):
-                    def lf(sp_, x_):
-                        return self._apply_with_extras(
-                            st, sp_, x_, lbl_i, msk_i, dropout_rng=rng,
-                            pos=pos_i, seg=seg_i)
-                    loss, (dp, dx) = jax.value_and_grad(
-                        lf, argnums=(0, 1))(sp, x_i)
-                    dp = jax.tree.map(lambda t: w_i * t, dp)
-                    return dp, (w_i * dx).astype(dx.dtype), loss
-
-                dp_l, dx_l, loss_l = vmap_lanes(
-                    lane, (0, 0, ax(mskl), ax(posl), ax(segl), 0))(
-                    xl, lbl, mskl, posl, segl, seed * share)
-                dx = dx_l.reshape((x.shape[0],) + dx_l.shape[2:])
-                return dp_l, dx, jnp.sum(share * loss_l)
-            return jax.jit(g)
-
-        def g(sp, x, dy, seed, rng, pos, seg):
-            xl, dyl = split(x), split(dy)
-            posl, segl = split(pos), split(seg)
-
-            def lane(x_i, dy_i, pos_i, seg_i):
-                (_, aux), vjp = jax.vjp(
-                    lambda sp_, x_: self._apply_with_extras(
-                        st, sp_, x_, dropout_rng=rng, pos=pos_i,
-                        seg=seg_i), sp, x_i)
-                # aux cotangent seed/L: the flat form seeds the microbatch
-                # MEAN aux with `seed`; each lane holds an equal-share mean
-                dp, dx = vjp((dy_i, seed / L))
-                return dp, dx, aux
-
-            dp_l, dx_l, aux_l = vmap_lanes(
-                lane, (0, 0, ax(posl), ax(segl)))(xl, dyl, posl, segl)
-            dx = dx_l.reshape((x.shape[0],) + dx_l.shape[2:])
-            return dp_l, dx, jnp.mean(aux_l)
         return jax.jit(g)
 
     def _make_eval(self, st: _Stage) -> Callable:
@@ -1081,16 +911,6 @@ class PipelineEngine:
                     self._fwd_microbatch(stage_params, mbs[next_fwd], ctx,
                                          next_fwd)
                     next_fwd += 1
-
-        # hierarchical dp reduction (hier_dp): the schedule accumulated
-        # LANE-STACKED grads with zero cross-dp bytes; one per-stage
-        # three-collective program (rs-intra / ar-cross / ag-intra) sums
-        # them HERE, so the tied exchange / global clip / updates below
-        # run on ordinary reduced grads, unchanged
-        if self.hier_dp:
-            with span("pp/hier_reduce"):
-                for s in range(len(self.stages)):
-                    grad_acc[s] = self._hier_jits[s](grad_acc[s])
 
         # tied-embedding grad sum across first/last stages (pipeline.py:1042);
         # transposes run jitted on the owning submesh and the sum crosses

@@ -62,7 +62,6 @@ def make_train_step(
     *,
     chunks: int = 1,
     aux_stats: bool = False,
-    hier: Optional[Any] = None,
     constrain_microbatches: Optional[Callable[[Any], Any]] = None,
     param_view: Optional[Callable[[Any], Any]] = None,
 ) -> Callable:
@@ -78,17 +77,9 @@ def make_train_step(
     share of the microbatches); "tokens_per_expert", "rows_*",
     "overflow_chunks" and "passes_by_chip" leaves are summed.
 
-    ``hier`` (an ``ops.hier_reduce.HierDpReducer``) swaps the implicit
-    GSPMD dp gradient all-reduce for the explicit hierarchical path:
-    per-dp-lane grads accumulate lane-local through the microbatch scan
-    (zero cross-dp bytes in-scan) and reduce ONCE per step via the
-    reducer's three-collective reduce-scatter/all-reduce/all-gather
-    program. Per-(microbatch, lane) token-share weighting keeps the
-    result equal to the flat path up to reduction reassociation.
-
     ``constrain_microbatches`` is an optional hook applied to the
     ``[chunks, B/chunks, ...]``-stacked batch tree right after the
-    reshape on the flat scanned path. The SPMD path pins the stack so
+    reshape on the scanned path. The SPMD path pins the stack so
     the CHUNK axis is replicated and the sample axis keeps the plan's
     batch sharding: without the pin, the reshape naturally absorbs the
     outer dp mesh axis into the chunk dim, every scanned microbatch
@@ -97,19 +88,12 @@ def make_train_step(
     (the ROADMAP embed-ZeRO-3 + vtp>1 + chunks>1 bug: wrong wte rows at
     grad magnitude — and in fact every dp-sharded grad leaf drifts).
     The pin makes each microbatch's embed-grad reduce-scatter
-    materialize per microbatch in the plan's own layout; the hier path
-    has always pinned (``hier.lane_batch``), which is why it was exact
-    where flat drifted.
+    materialize per microbatch in the plan's own layout.
 
     ``param_view`` (parallel/spmd.py::interior_sharding) maps the stored
     parameters to the tree ``loss_fn`` takes. It runs once a step, outside
     the microbatch scan: gradients accumulate in the view's layout and are
     pulled back through it once, before the norm and the update."""
-
-    if hier is not None and aux_stats:
-        raise ValueError(
-            "hier_dp does not compose with aux-stats (MoE) steps; see "
-            "eligibility.hier_dp_unsupported_reason")
 
     if aux_stats:
         grad_fn = jax.value_and_grad(loss_fn, has_aux=True)
@@ -131,59 +115,6 @@ def make_train_step(
             return jnp.sum(w * s, axis=0)
         return jax.tree_util.tree_map_with_path(red, stacked)
 
-    def _hier_grads(params, batch):
-        """Per-lane grads + one hierarchical reduce (hier is not None).
-        Returns (loss, grads); stats are {} (aux gated above)."""
-        L = hier.lanes
-        # spmd_axis_name pins the lane axis of every batched intermediate
-        # (and of constraints inside the loss, which the lane_dp loss
-        # variant builds dp-free) onto the dp mesh axes — without it the
-        # partitioner re-shards each lane's slice at every interior
-        # constraint (measured 3-6x step-time blowup on the CPU mesh)
-        vgrad = jax.vmap(grad_fn, in_axes=(None, 0),
-                         spmd_axis_name=tuple(hier.dp_axes))
-        if chunks <= 1:
-            mbl = hier.lane_batch(batch)
-            w = microbatch_weights(mbl.get("loss_mask"), L)
-            (losses, _), g = vgrad(params, mbl)
-            acc = hier.constrain_stacked(jax.tree.map(
-                lambda gg: (gg.astype(jnp.float32)
-                            * w.reshape((L,) + (1,) * (gg.ndim - 1))), g))
-            return jnp.sum(w * losses), hier.reduce(acc)
-        bsz = batch["tokens"].shape[0]
-        if bsz % chunks:
-            raise ValueError(
-                f"batch size {bsz} is not divisible by chunks={chunks}; "
-                f"adjust global_train_batch_size or chunks")
-        mbs = jax.tree.map(
-            lambda x: x.reshape((chunks, x.shape[0] // chunks)
-                                + x.shape[1:]), batch)
-        # per-(microbatch, lane) token shares of the GLOBAL batch: the
-        # weighted per-lane masked means recombine to the flat path's
-        # token-weighted accumulation exactly
-        mask = mbs.get("loss_mask")
-        if mask is None:
-            w_cl = jnp.full((chunks, L), 1.0 / (chunks * L), jnp.float32)
-        else:
-            ml = mask.reshape((chunks, L, mask.shape[1] // L)
-                              + mask.shape[2:]).astype(jnp.float32)
-            counts = jnp.sum(ml, axis=tuple(range(2, ml.ndim)))
-            w_cl = counts / jnp.maximum(jnp.sum(counts), 1.0)
-
-        def microbatch(acc, xs):
-            mb, w = xs
-            mbl = hier.lane_batch(mb)
-            (losses, _), g = vgrad(params, mbl)
-            acc = jax.tree.map(
-                lambda a, b: a + (w.reshape((L,) + (1,) * (b.ndim - 1))
-                                  * b.astype(jnp.float32)), acc, g)
-            return hier.constrain_stacked(acc), jnp.sum(w * losses)
-
-        zeros = hier.constrain_stacked(jax.tree.map(
-            lambda p: jnp.zeros((L,) + p.shape, jnp.float32), params))
-        acc, wlosses = jax.lax.scan(microbatch, zeros, (mbs, w_cl))
-        return jnp.sum(wlosses), hier.reduce(acc)
-
     def step(params, opt_state, batch):
         # a "dropout_rng" key rides in the batch dict (so every execution
         # path — single-device, SPMD, chunked — keeps one step signature);
@@ -195,15 +126,7 @@ def make_train_step(
         if param_view is not None:
             with jax.named_scope("param_view"):
                 params, pull_back = jax.vjp(param_view, stored)
-        if hier is not None:
-            if rng is not None:
-                raise ValueError(
-                    "hier_dp requires dropout disabled (eligibility."
-                    "HIER_DROPOUT_REASON): per-lane rng streams would "
-                    "draw masks the flat path never draws")
-            loss, grads = _hier_grads(params, batch)
-            stats = {}
-        elif chunks <= 1:
+        if chunks <= 1:
             if rng is not None:
                 batch["dropout_rng"] = rng
             (loss, stats), grads = grad_fn(params, batch)

@@ -157,6 +157,13 @@ def _enc(values: Sequence[Any]) -> str:
     return ",".join(str(int(v)) for v in values)
 
 
+# What a plan written before the hierarchical dp reduction was removed may
+# still hold. Gradients are reduced over dp by XLA's partitioner whatever
+# these say.
+IGNORED_PLAN_KEYS = ("hier_dp", "hier_bucket_mb", "dp_schedule",
+                     "dp_schedule_rankings")
+
+
 def _dec(s: str) -> List[int]:
     return [int(x) for x in str(s).split(",") if x != ""]
 
@@ -173,9 +180,6 @@ def strategy_list2config(
     num_encoder_layers: Optional[int] = None,
     vpp_deg: Optional[int] = None,
     predicted_layer_compute_ms: Optional[Sequence[float]] = None,
-    hier_dp: Optional[bool] = None,
-    hier_bucket_mb: float = 0.0,
-    dp_schedule: Optional[str] = None,
 ) -> Dict[str, Any]:
     """Serialize per-layer strategies to the interchange dict.
 
@@ -246,21 +250,6 @@ def strategy_list2config(
                 f"{len(strategies)} layers")
         cfg["predicted_layer_compute_ms"] = [
             float(x) for x in predicted_layer_compute_ms]
-    if hier_dp:
-        # the search priced this plan's dp gradient reduction with the
-        # hierarchical two-level schedule (ops/hier_reduce.py); the runtime
-        # enables the matching execution path (args.parallel.hier_dp ORs in)
-        cfg["hier_dp"] = 1
-        if hier_bucket_mb > 0:
-            # ...and pipelined it at this bucket granularity
-            # (cost.hier_dp_best_bucket); the runtime buckets identically
-            cfg["hier_bucket_mb"] = float(hier_bucket_mb)
-        if dp_schedule:
-            # ...and the synthesized collective schedule family whose α-β
-            # price won the space (cost.dp_schedule_choice over
-            # collectives.synthesize_space); the runtime executes the
-            # reduction through the matching emitted program
-            cfg["dp_schedule"] = str(dp_schedule)
     return cfg
 
 
@@ -395,16 +384,9 @@ def config2strategy(
         "num_encoder_layers": (_int_field(cfg, "num_encoder_layers")
                                if "num_encoder_layers" in cfg else None),
         "vpp_deg": _int_field(cfg, "vpp_deg", 1),
-        "hier_dp": bool(_int_field(cfg, "hier_dp", 0)),
-        # bucketed software-pipelining granularity the search priced the
-        # hierarchical reduction at (0 = monolithic); the runtime
-        # pipelines at the same size unless parallel.hier_bucket_mb
-        # overrides
-        "hier_bucket_mb": float(cfg.get("hier_bucket_mb", 0.0) or 0.0),
-        # synthesized collective schedule family the search priced the dp
-        # reduction with (collectives/); None = the hand-implemented
-        # three-stage hierarchical path
-        "dp_schedule": str(cfg.get("dp_schedule") or "") or None,
+        # keys of a removed gradient reduction that an older plan file may
+        # still carry: read past, and named once by the launcher
+        "ignored_keys": tuple(k for k in IGNORED_PLAN_KEYS if k in cfg),
         # optional per-layer compute prediction (see strategy_list2config);
         # a hand-edited plan whose vector no longer matches the layer count
         # is dropped rather than mis-attributed to the wrong layers

@@ -178,26 +178,21 @@ def test_plan_collective_counts_rejects_unmodeled_shapes():
     hpc = get_hybrid_parallel_config(args, 8)
     with pytest.raises(ValueError):
         plan_collective_counts(hpc, args.model)
-    # the hier lane-path relaxation (cp/sp predictable with tp_overlap
-    # off) is a pp = 1 property: the pp engines keep their ring/a2a
-    # kernels and reject hier for cp/sp layers, so a pp > 1 cp plan has
-    # no hier program to predict — it must still raise, not return
-    # counts no engine can census-match
-    assert plan_collective_counts(hpc, args.model, tp_overlap=False,
-                                  hier_dp=True)["reduce_scatter"] == 1
+    # with the rings off too, and pipelined: a cp plan's hops have no
+    # exact prediction
+    with pytest.raises(ValueError):
+        plan_collective_counts(hpc, args.model, tp_overlap=False)
     args_pp = tiny_args(global_tp_deg=1, global_cp_deg=2, pp_deg=2,
                         chunks=2, global_train_batch_size=8)
     hpc_pp = get_hybrid_parallel_config(args_pp, 8)
     with pytest.raises(ValueError):
-        plan_collective_counts(hpc_pp, args_pp.model, tp_overlap=False,
-                               hier_dp=True)
+        plan_collective_counts(hpc_pp, args_pp.model, tp_overlap=False)
     from hetu_galvatron_tpu.observability.telemetry import (
         plan_collective_bytes,
     )
 
     with pytest.raises(ValueError):
-        plan_collective_bytes(hpc_pp, args_pp.model, tp_overlap=False,
-                              hier_dp=True)
+        plan_collective_bytes(hpc_pp, args_pp.model, tp_overlap=False)
 
 
 @pytest.mark.slow

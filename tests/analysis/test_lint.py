@@ -106,29 +106,14 @@ def test_gal004_dynamic_named_scope(tmp_path):
     assert rules(lint_src(tmp_path, bad, hot_path=False)) == \
         ["GAL004", "GAL004"]
     assert lint_src(tmp_path, good, hot_path=False) == []
-    # hier_stage_scope(CONSTANT/NAME, ...) is marker-preserving by
-    # contract (the base scope stays a prefix of the returned name) —
-    # exempt; a COMPUTED base would break matching and stays a finding
-    preserving = """
+    # a call is a computed name whatever it returns
+    called = """
     import jax
-    from hetu_galvatron_tpu.ops.hier_reduce import (
-        HIER_DP_RS_SCOPE, hier_stage_scope)
     def f(i, B):
-        with jax.named_scope(hier_stage_scope(HIER_DP_RS_SCOPE, i, B)):
-            pass
-        with jax.named_scope(hier_stage_scope("hier_dp_ag", i, B)):
+        with jax.named_scope(scope_of("tp_ring", i, B)):
             pass
     """
-    assert lint_src(tmp_path, preserving, hot_path=False) == []
-    computed_base = """
-    import jax
-    from hetu_galvatron_tpu.ops.hier_reduce import hier_stage_scope
-    def f(i, B):
-        with jax.named_scope(hier_stage_scope("x" + str(i), i, B)):
-            pass
-    """
-    assert rules(lint_src(tmp_path, computed_base, hot_path=False)) == \
-        ["GAL004"]
+    assert rules(lint_src(tmp_path, called, hot_path=False)) == ["GAL004"]
 
 
 def test_gal005_exception_swallowing(tmp_path):

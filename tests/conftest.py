@@ -50,18 +50,6 @@ _SLOW_TESTS = {
     "test_bert_mlm_training_step_tp8",
     "test_bert_mlm_loss_trajectory_matches_hf",
     "test_bidirectional_attention",
-    # hierarchical dp reduction: the engine parity drills compile two full
-    # engines each; the single-device zero3 reference adds a third build
-    "test_hier_compiled_engine_parity",
-    "test_hier_host_engine_parity",
-    "test_hier_zero3_matches_single_device_where_flat_drifts",
-    # spmd / pipeline parity
-    "test_mixed_per_layer_strategies",
-    "test_pipeline_matches_single_device",
-    "test_pipeline_tied_embeddings",
-    "test_interleaved_virtual_stages_match_single_device",
-    "test_interleaved_tied_embeddings",
-    "test_uneven_pp_division",
     # ring attention, flash dropout and flash segment ids: context
     # parallelism, dropout and packed documents are on in no cell
     "test_ring_flash_gradients_match",
@@ -89,12 +77,6 @@ _SLOW_TESTS = {
     "test_search_then_train_the_searched_plan",
     "test_train_dist_cli_pipeline_compiled",
     "test_train_dist_cli_compiled_falls_back",
-    # compiled-pipeline secondary parity legs (the tier-1 acceptance drill
-    # test_compiled_matches_host_engine_three_steps + recompile pinning
-    # stay fast-tier)
-    "test_compiled_untied_and_uniform_dp",
-    "test_compiled_dropout_replays_host_masks",
-    "test_compiled_ramp_caches_one_program_per_chunk_count",
     "test_train_dist_rampup_cli",
     "test_train_dist_rampup_pipeline_cli",
     "test_train_dist_cli_pipeline",

@@ -342,7 +342,6 @@ def test_compiled_cp_plan_matches_host(cpu_devices):
             err_msg=f"param {jax.tree_util.keystr(path)}")
 
 
-@pytest.mark.slow
 def test_compiled_zigzag_cp_plan_matches_host(cpu_devices):
     """Zigzag-cp composes with the compiled schedule too (the balanced
     causal layout's entry/exit permutes run inside the program)."""

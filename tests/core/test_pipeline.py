@@ -73,7 +73,6 @@ CASES = [
 @pytest.mark.parametrize(
     "pkw", CASES,
     ids=lambda d: ",".join(f"{k}={v}" for k, v in d.items()))
-@pytest.mark.slow
 def test_pipeline_matches_single_device(pkw, cpu_devices):
     params, axes = init_causal_lm(jax.random.key(0), CFG)
     batch = _batch()
@@ -170,7 +169,6 @@ def test_engine_builds_jits_lazily(cpu_devices):
     assert {"fwd", "bwd", "update", "gnorm", "clip"} <= set(eng._lazy_jits)
 
 
-@pytest.mark.slow
 @pytest.mark.parametrize("pipeline_type", ["gpipe", "pipedream_flush"])
 def test_interleaved_virtual_stages_match_single_device(pipeline_type,
                                                         cpu_devices):
@@ -194,7 +192,6 @@ def test_interleaved_virtual_stages_match_single_device(pipeline_type,
             err_msg=f"param {jax.tree_util.keystr(pa)}")
 
 
-@pytest.mark.slow
 def test_interleaved_tied_embeddings(cpu_devices):
     """Tied wte with vpp=2: embed chunk and head chunk live on DIFFERENT
     physical groups (chunk 0 -> group 0, chunk 3 -> group 1) and the grad

@@ -121,7 +121,10 @@ def _batch(seed=3, rows=2, seq=40):
 # (a) the program against the plain reference, and the controls
 # ---------------------------------------------------------------------------
 
-CONTROLS = ["as_published", "beta_left_out", "decay_left_out",
+# the program in bfloat16 (what the cell computes in) against the float32
+# reference: the loss alone, of order 4.2, within bf16's eight bits
+BF16_LOSS = 2e-2
+CONTROLS = ["as_published", "as_published_bf16", "beta_left_out", "decay_left_out",
             "l2_norm_left_out", "convolution_left_out",
             "output_gate_left_out", "delta_term_left_out",
             "shared_expert_left_out", "latent_scale_of_another_width"]
@@ -153,7 +156,10 @@ def program():
             p, batch["tokens"], cfg, compute_dtype=jnp.float32))(params)
         weights = {k: jnp.asarray(v)
                    for k, v in params_to_hf(params, cfg).items()}
+        loss_bf16 = jax.jit(lambda p: causal_lm_loss(
+            p, batch, cfg, compute_dtype=jnp.bfloat16))(params)
         return dict(cfg=cfg, batch=batch, weights=weights, loss=float(loss),
+                    loss_bf16=float(loss_bf16),
                     grads=params_to_hf(grads, cfg), logits=logits)
 
 
@@ -202,6 +208,11 @@ def test_program_matches_plain_reference(case, program, monkeypatch):
     # operation order only (chunks, sub-blocks and a triangular inverse
     # against one position at a time; grouped against all-experts matmuls).
     # The loss is of order 4.2, gradients up to 0.1
+    if case == "as_published_bf16":
+        want = float(jax.jit(ref_loss)(weights))
+        assert abs(program["loss_bf16"] - want) < BF16_LOSS, (
+            program["loss_bf16"], want)
+        return
     if case != "as_published":
         want = float(jax.jit(ref_loss)(weights))
         assert abs(program["loss"] - want) > 2e-5, (case, want)

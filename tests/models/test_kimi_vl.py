@@ -120,6 +120,9 @@ def _batch(cfg, seed=5, rows=2):
 
 CONTROLS = [None, "tower_block_fewer", "no_rotation", "across_images",
             "causal_tower", "no_interpolation", "merge_order"]
+# the program in bfloat16 (what the cell computes in) against the float32
+# reference: the loss alone, of order 4.3, within bf16's eight bits
+BF16_LOSS = 2e-2
 
 
 @pytest.fixture(scope="module")
@@ -193,6 +196,19 @@ def test_bf16_compute_stays_near_the_fp32_reference(sides):
     got = causal_lm_loss(_seeded(cfg), batch, cfg,
                          compute_dtype=jnp.bfloat16)
     assert abs(float(got) - loss) < 2e-2
+
+
+def test_program_in_bf16_matches_plain_reference(sides):
+    """``test_program_matches_plain_reference``'s ``as_published`` with the
+    program in bfloat16, the reference as it is: the loss alone."""
+    cfg, _, _, weights, batch = sides
+    marked = float(batch["loss_mask"].sum())
+    want = _family().nll_sum(weights, REF_CFG, batch["tokens"],
+                             batch["labels"], batch=batch) / marked
+    got = causal_lm_loss(_seeded(cfg), batch, cfg,
+                         compute_dtype=jnp.bfloat16)
+    assert abs(float(got) - float(want)) < BF16_LOSS, (
+        float(got), float(want))
 
 
 # ---------------------------------------------------------------------------

@@ -113,7 +113,10 @@ def _batch(seed=3, rows=2, seq=16):
 # (a) the program against the plain reference, and the controls
 # ---------------------------------------------------------------------------
 
-CONTROLS = ["as_published", "no_window", "no_gate", "head_counts_swapped",
+# the program in bfloat16 (what the cell computes in) against the float32
+# reference: the loss alone, of order 4.2, within bf16's eight bits
+BF16_LOSS = 2e-2
+CONTROLS = ["as_published", "as_published_bf16", "no_window", "no_gate", "head_counts_swapped",
             "one_rotation_for_both_kinds", "whole_head_rotated_in_a_full_block",
             "yarn_dropped", "shared_expert_left_out",
             "scaling_factor_left_out"]
@@ -132,7 +135,10 @@ def program():
             p, batch["tokens"], cfg, compute_dtype=jnp.float32))(params)
         weights = {k: jnp.asarray(v)
                    for k, v in params_to_hf(params, cfg).items()}
+        loss_bf16 = jax.jit(lambda p: causal_lm_loss(
+            p, batch, cfg, compute_dtype=jnp.bfloat16))(params)
         return dict(cfg=cfg, batch=batch, weights=weights, loss=float(loss),
+                    loss_bf16=float(loss_bf16),
                     grads=params_to_hf(grads, cfg), logits=logits)
 
 
@@ -185,6 +191,11 @@ def test_program_matches_plain_reference(case, program, monkeypatch):
     # tolerance: both sides are fp32 at highest on the CPU and differ in
     # operation order only (one fused qkv product against three, grouped
     # against all-experts matmuls). The loss is of order 4.2
+    if case == "as_published_bf16":
+        want = float(jax.jit(ref_loss)(weights))
+        assert abs(program["loss_bf16"] - want) < BF16_LOSS, (
+            program["loss_bf16"], want)
+        return
     if case != "as_published":
         want = float(jax.jit(ref_loss)(weights))
         assert abs(program["loss"] - want) > 2e-5, (case, want)

@@ -199,7 +199,11 @@ def test_exporter_names_the_kinds_it_knows():
 # (b) the program against the benchmark's plain reference, and the controls
 # ---------------------------------------------------------------------------
 
-CONTROLS = ["as_published", "taps_reversed", "gate_c_left_out",
+# the program in bfloat16 (what the cell computes in) against the float32
+# reference: the loss alone, of order 4.2, within bf16's eight bits
+BF16_LOSS = 2e-2
+CONTROLS = ["as_published", "as_published_bf16", "taps_reversed",
+            "gate_c_left_out",
             "qk_norm_over_the_whole_width", "softmax_for_sigmoid",
             "weights_not_renormalised", "top3_for_top4", "bias_left_out"]
 
@@ -262,6 +266,11 @@ def test_program_matches_plain_reference(case, monkeypatch):
         monkeypatch.setitem(REF_CFG, "use_expert_bias", False)
 
     want, want_grads = _reference_loss_and_grads(ref, REF_CFG, weights, batch)
+    if case == "as_published_bf16":
+        got = causal_lm_loss(params, batch, cfg, compute_dtype=jnp.bfloat16)
+        assert abs(float(got) - float(want)) < BF16_LOSS, (
+            float(got), float(want))
+        return
     got, got_grads = jax.value_and_grad(lambda p: causal_lm_loss(
         p, batch, run_cfg, compute_dtype=jnp.float32))(run_params)
     # tolerance: both sides are fp32 on the CPU and differ in operation

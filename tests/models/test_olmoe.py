@@ -144,8 +144,13 @@ def _without_qk_norm(params):
         for lp in params["layers"])}
 
 
-@pytest.mark.parametrize("case", ["as_published", "topk_renormalised",
-                                  "no_qk_norm"])
+# the program in bfloat16 (what the cells compute in) against the float32
+# reference: the loss alone, of order 4.2, within bf16's eight bits
+BF16_LOSS = 2e-2
+
+
+@pytest.mark.parametrize("case", ["as_published", "as_published_bf16",
+                                  "topk_renormalised", "no_qk_norm"])
 def test_program_matches_plain_reference(case):
     """Loss (cross-entropy and both router terms) and gradients of the
     program against ``benchmark/reference/olmoe.py`` on seeded random
@@ -178,6 +183,12 @@ def test_program_matches_plain_reference(case):
         run_cfg = cfg.model_copy(update=dict(moe_norm_topk_prob=True))
     if case == "no_qk_norm":
         run_params = _without_qk_norm(params)
+
+    if case == "as_published_bf16":
+        got = causal_lm_loss(params, batch, cfg, compute_dtype=jnp.bfloat16)
+        assert abs(float(got) - float(want)) < BF16_LOSS, (
+            float(got), float(want))
+        return
 
     def prog_loss(p):
         return causal_lm_loss(p, batch, run_cfg, compute_dtype=jnp.float32)

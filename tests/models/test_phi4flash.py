@@ -170,6 +170,21 @@ def test_every_gradient_leaf_matches_the_plain_reference():
                                    rtol=0, err_msg=name)
 
 
+def test_the_loss_in_bfloat16_matches_the_plain_reference():
+    """The program in bfloat16 (what the cell computes in) against the
+    float32 reference: the loss alone, of order 4.2, within bf16's eight
+    bits (its logits fail the float32 tolerance, next test)."""
+    cfg = ModelArgs(**TINY)
+    params, batch = _seeded(cfg), _batch()
+    family = _family()
+    want = jax.jit(lambda w: family.nll_sum(
+        w, REF_CFG, batch["tokens"], batch["labels"])
+        / batch["labels"].size)(_reference_weights(params, cfg))
+    got = jax.jit(lambda p: causal_lm_loss(
+        p, batch, cfg, compute_dtype=jnp.bfloat16))(params)
+    assert abs(float(got) - float(want)) < 2e-2, (float(got), float(want))
+
+
 def test_a_bfloat16_computation_fails_the_tolerance():
     cfg = ModelArgs(**TINY)
     params, batch = _seeded(cfg), _batch()

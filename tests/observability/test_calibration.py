@@ -197,18 +197,6 @@ def test_calibration_points_tp_dp_arithmetic():
     assert dp["ms"] == pytest.approx(2.0)
 
 
-def test_calibration_points_hier_dp_contributes_nothing():
-    table = {"rows": [
-        {"component": "dp", "measured_ms": 2.0, "predicted_ms": 1.0},
-        {"component": "dp[hier]", "measured_ms": 2.0},
-    ]}
-    pts = calibration_points(table, _hpc(tp=1), _model(),
-                             mixed_precision=False)
-    # hier-measured dp is one concatenated schedule, not per-layer flat
-    # rings: no dp point may be attributed to the flat curve
-    assert pts == []
-
-
 def test_drift_score_excludes_decomposition_rows():
     table = {"rows": [
         {"component": "tp", "measured_ms": 3.0, "predicted_ms": 2.0},

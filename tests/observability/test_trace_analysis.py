@@ -611,7 +611,7 @@ def test_summarize_renders_calibration_table(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# per-algorithm / hierarchical-dp audit rows
+# per-algorithm audit rows
 # ---------------------------------------------------------------------------
 
 
@@ -637,51 +637,6 @@ def test_predicted_comm_per_algorithm_min_choice():
     assert "algorithms" not in flat_only["tp"]
 
 
-def test_predicted_comm_hier_dp_decomposition():
-    """A hier_dp plan prices dp as min(flat, hier) and reports the
-    rs+ag/cross decomposition, through the cost model's own arithmetic."""
-    from hetu_galvatron_tpu.core.cost_model.cost import (
-        CostContext,
-        hier_dp_reduce_ms,
-    )
-    from hetu_galvatron_tpu.core.search_engine.strategies import (
-        SearchStrategy,
-    )
-    from hetu_galvatron_tpu.observability.telemetry import layer_param_mb
-
-    ab = {"4_1": (0.5, 50.0)}
-    algos = {"2_1": {"ring_ici": (0.05, 200.0)},
-             "2_0": {"ring_dcn": (0.3, 20.0)}}
-    hpc = _hpc([LayerStrategy(tp_size=1, dp_size=4)])
-    hpc.hier_dp = True
-    out = predicted_comm_per_step(hpc, CFG, alpha_beta=ab,
-                                  alpha_beta_algos=algos, dcn_slices=2)
-    dp = out["dp"]
-    assert {"flat", "hier", "hier_intra", "hier_cross"} <= set(
-        dp["algorithms"])
-    grad_mb = layer_param_mb(CFG) * 0.5
-    want_hier = hier_dp_reduce_ms(
-        SearchStrategy(pp=1, tp=1, dp=4),
-        CostContext(alpha_beta_algos=algos, hier_dp=True, dcn_slices=2),
-        grad_mb)
-    assert dp["algorithms"]["hier"] == pytest.approx(want_hier)
-    assert dp["predicted_ms"] == pytest.approx(
-        min(dp["algorithms"]["flat"], dp["algorithms"]["hier"]))
-    # the decomposition entries never compete in the min
-    assert dp["algorithm"] in ("flat", "hier")
-
-
-def test_measured_components_bills_hier_markers_to_dp():
-    attr = Attribution(categories_ms={
-        "allgather": 2.0, "reducescatter": 1.0, "hier_rs": 3.0,
-        "hier_ar": 0.5, "hier_ag": 2.5})
-    m = measured_components(attr, _hpc([LayerStrategy(tp_size=2,
-                                                      dp_size=4)]))
-    # the marked hier collectives are dp; the unmarked ag/rs stay tp
-    assert m["dp"] == pytest.approx(6.0)
-    assert m["tp"] == pytest.approx(3.0)
-
-
 def test_audit_plan_emits_per_algorithm_rows(tmp_path):
     path = str(tmp_path / "m.jsonl")
     reg = MetricsRegistry([JsonlSink(path)])
@@ -689,13 +644,10 @@ def test_audit_plan_emits_per_algorithm_rows(tmp_path):
     algos = {"2_1": {"tree_ici": (0.01, 100.0),
                      "ring_ici": (0.2, 400.0)}}
     attr = _measured_attr()
-    attr.categories_ms.update({"hier_rs": 1.0, "hier_ar": 0.2,
-                               "hier_ag": 0.8})
     hpc = _hpc([LayerStrategy(tp_size=2, dp_size=2)] * 2)
-    hpc.hier_dp = True
     algos.update({"1_1": {}})
     table = audit_plan(attr, hpc, CFG, registry=reg, alpha_beta=ab,
-                       alpha_beta_algos=algos, dcn_slices=1)
+                       alpha_beta_algos=algos)
     comps = {r["component"]: r for r in table["rows"]}
     # per-algorithm candidate rows ride along, exactly one chosen
     for name in ("tp[flat]", "tp[tree_ici]", "tp[ring_ici]"):
@@ -703,75 +655,10 @@ def test_audit_plan_emits_per_algorithm_rows(tmp_path):
     chosen = [r for c, r in comps.items()
               if c.startswith("tp[") and r.get("chosen")]
     assert len(chosen) == 1
-    # the hier sub-collectives carry MEASURED ms from their markers even
-    # when no hier curves are fitted (dp[...] rows need fitted dcn/ici
-    # curves to exist; the dp component row still measures the traffic)
-    assert comps["dp"]["measured_ms"] == pytest.approx(
-        (2.0 + 1.0 + 0.2 + 0.8) / attr.steps)
+    # dp is priced on the one fitted pair: no per-algorithm rows
+    assert not [c for c in comps if c.startswith("dp[")]
+    assert comps["dp"]["measured_ms"] == pytest.approx(2.0 / attr.steps)
     reg.flush()
-
-
-def test_attribute_bucketed_hier_markers(tmp_path):
-    """Bucketed hier scopes (hier_stage_scope ``hier_dp_rs_b{i}``) bill to
-    the SAME hier_* categories as the monolithic markers (the base scope
-    stays a prefix — substring match) AND surface the per-bucket split in
-    ``Attribution.hier_bucket_ms``, which never double-counts against
-    categories_ms (it is detail, not a category)."""
-    run = str(tmp_path / "plugins" / "profile" / "2026_01_01_00_00_00")
-    events = [
-        _ev(1, 1, 0, 1000, "reduce-scatter.1", hlo_op="reduce-scatter.1",
-            tf_op="hier_dp_rs_b0/psum_scatter"),
-        _ev(1, 1, 1000, 2000, "all-reduce.2", hlo_op="all-reduce.2",
-            tf_op="hier_dp_ar_b0/psum"),
-        _ev(1, 1, 3000, 500, "reduce-scatter.3", hlo_op="reduce-scatter.3",
-            tf_op="hier_dp_rs_b1/psum_scatter"),
-        _ev(1, 1, 3500, 700, "all-gather.4", hlo_op="all-gather.4",
-            tf_op="hier_dp_ag_b1/all_gather"),
-        # a monolithic (un-suffixed) marker: base category only, no bucket
-        _ev(1, 1, 4200, 300, "all-gather.5", hlo_op="all-gather.5",
-            tf_op="hier_dp_ag/all_gather"),
-    ]
-    _write_trace(run, events, procs={1: "/device:TPU:0"})
-    attr = attribute(load_trace(run))
-    assert attr.categories_ms["hier_rs"] == pytest.approx(1.5)
-    assert attr.categories_ms["hier_ar"] == pytest.approx(2.0)
-    assert attr.categories_ms["hier_ag"] == pytest.approx(1.0)
-    assert attr.hier_bucket_ms == pytest.approx({
-        "hier_rs_b0": 1.0, "hier_ar_b0": 2.0,
-        "hier_rs_b1": 0.5, "hier_ag_b1": 0.7})
-
-
-def test_audit_plan_per_bucket_rows_and_summarize(tmp_path):
-    """audit_plan emits measured-only ``dp[hier_rs_b0]``-style rows in
-    wavefront order (bucket index, then rs->ar->ag), and summarize
-    renders them + headlines the count (audit_hier_bucket_rows)."""
-    from hetu_galvatron_tpu.cli.summarize import summarize
-
-    path = str(tmp_path / "m.jsonl")
-    reg = MetricsRegistry([JsonlSink(path)])
-    ab = {"2_1": (0.05, 100.0), "2_0": (0.07, 80.0)}
-    attr = _measured_attr()
-    attr.categories_ms.update({"hier_rs": 1.0, "hier_ar": 0.2,
-                               "hier_ag": 0.8})
-    attr.hier_bucket_ms = {"hier_rs_b1": 0.4, "hier_rs_b0": 0.6,
-                           "hier_ar_b0": 0.2, "hier_ag_b1": 0.8}
-    hpc = _hpc([LayerStrategy(tp_size=2, dp_size=2)] * 2)
-    hpc.hier_dp = True
-    table = audit_plan(attr, hpc, CFG, registry=reg, alpha_beta=ab,
-                       alpha_beta_algos={"1_1": {}}, dcn_slices=1)
-    names = [r["component"] for r in table["rows"]]
-    assert [n for n in names if "_b" in n] == [
-        "dp[hier_rs_b0]", "dp[hier_ar_b0]",
-        "dp[hier_rs_b1]", "dp[hier_ag_b1]"]
-    rows = {r["component"]: r for r in table["rows"]}
-    assert rows["dp[hier_rs_b0]"]["measured_ms"] == pytest.approx(
-        0.6 / attr.steps)
-    assert "predicted_ms" not in rows["dp[hier_rs_b0]"]
-    reg.close()
-    buf = io.StringIO()
-    headline = summarize(path, out=buf)
-    assert headline["audit_hier_bucket_rows"] == 4
-    assert "dp[hier_rs_b0]" in buf.getvalue()
 
 
 def test_summarize_hardware_renders_algo_columns(tmp_path, capsys):
@@ -801,31 +688,3 @@ def test_summarize_hardware_renders_algo_columns(tmp_path, capsys):
                        out=buf2)
     assert "ring_ici" not in buf2.getvalue()
 
-
-def test_predicted_comm_hier_alpha_counted_once_across_layers():
-    """The hierarchical schedule runs ONCE per step over the concatenated
-    payload: an L-layer plan's dp[hier] prediction must charge the α
-    terms once (whole-plan volume through one schedule), not L times —
-    matching both the runtime and the summed layer costs."""
-    from hetu_galvatron_tpu.core.cost_model.cost import (
-        CostContext,
-        hier_dp_reduce_ms,
-    )
-    from hetu_galvatron_tpu.core.search_engine.strategies import (
-        SearchStrategy,
-    )
-    from hetu_galvatron_tpu.observability.telemetry import layer_param_mb
-
-    algos = {"2_1": {"ring_ici": (0.05, 200.0)},
-             "2_0": {"ring_dcn": (0.3, 20.0)}}
-    L = 4
-    hpc = _hpc([LayerStrategy(tp_size=1, dp_size=4)] * L)
-    hpc.hier_dp = True
-    out = predicted_comm_per_step(hpc, CFG, alpha_beta_algos=algos,
-                                  dcn_slices=2)
-    grad_total = L * layer_param_mb(CFG) * 0.5
-    want = hier_dp_reduce_ms(
-        SearchStrategy(pp=1, tp=1, dp=4),
-        CostContext(alpha_beta_algos=algos, hier_dp=True, dcn_slices=2),
-        grad_total)
-    assert out["dp"]["algorithms"]["hier"] == pytest.approx(want)

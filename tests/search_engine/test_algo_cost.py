@@ -1,9 +1,7 @@
-"""Per-algorithm / per-level collective pricing + the hierarchical dp
-term: the min-over-curves choice must pick the right algorithm per
-message size, the hierarchical term must be able to flip the chosen plan,
-and EMPTY per-algorithm data must leave every cost byte-identical (the
-golden search regressions pin the full-plan version against the legacy
-fixtures)."""
+"""Per-algorithm / per-level collective pricing: the min-over-curves
+choice must pick the right algorithm per message size, and EMPTY
+per-algorithm data must leave every cost byte-identical (the golden search
+regressions pin the full-plan version against the legacy fixtures)."""
 
 import numpy as np
 import pytest
@@ -12,10 +10,7 @@ from hetu_galvatron_tpu.core.cost_model.cost import (
     CostContext,
     _algo_min_ms,
     _tp_message_ms,
-    hier_dp_reduce_ms,
-    hier_dp_wins,
     layer_time_cost,
-    layer_time_components,
 )
 from hetu_galvatron_tpu.core.search_engine.strategies import SearchStrategy
 
@@ -89,11 +84,10 @@ def test_tp_message_prices_min_of_flat_and_algo_curves():
 
 
 def test_empty_algo_data_costs_byte_identical():
-    """The golden-cost discipline: alpha_beta_algos={} and hier_dp=False
-    (the defaults) reproduce today's costs bit-for-bit."""
+    """The golden-cost discipline: alpha_beta_algos={} (the default)
+    reproduces today's costs bit-for-bit."""
     for s in (TP2, TP4, DP8):
-        assert _cost(s, _ctx()) == _cost(
-            s, _ctx(alpha_beta_algos={}, hier_dp=False))
+        assert _cost(s, _ctx()) == _cost(s, _ctx(alpha_beta_algos={}))
 
 
 def test_algo_pairs_flip_the_chosen_plan():
@@ -110,178 +104,3 @@ def test_algo_pairs_flip_the_chosen_plan():
     ctx_a = _ctx(comm_coe_dict=coe, alpha_beta_algos=algos)
     assert _cost(TP2, ctx_a) < _cost(TP4, ctx_a)
 
-
-# ---------------------------------------------------------------------------
-# hierarchical dp term
-# ---------------------------------------------------------------------------
-
-
-def _hier_algos():
-    return {
-        # intra-host level: size 4 ICI ring/tree curves
-        "4_1": {"ring_ici": (0.1, 200.0), "tree_ici": (0.2, 100.0)},
-        # cross-slice level: size 2 DCN curves (slow links)
-        "2_0": {"ring_dcn": (1.0, 10.0)},
-        "2_1": {"ring_ici": (0.05, 300.0)},
-        "8_1": {"ring_ici": (0.2, 150.0)},
-    }
-
-
-def test_hier_dp_reduce_ms_hand_math():
-    """dp8 over 2 slices: intra=4, cross=2. Time = allreduce_ici(4, V)
-    (the rs+ag halves) + allreduce_dcn(2, V/4)."""
-    ctx = _ctx(hier_dp=True, dcn_slices=2, alpha_beta_algos=_hier_algos())
-    V = 12.0
-    want = (0.1 + V / 200.0) + (1.0 + (V / 4) / 10.0)
-    assert hier_dp_reduce_ms(DP8, ctx, V) == pytest.approx(want)
-    # missing dcn curve -> None (flat pricing stays)
-    algos = {"4_1": _hier_algos()["4_1"]}
-    ctx2 = _ctx(hier_dp=True, dcn_slices=2, alpha_beta_algos=algos)
-    assert hier_dp_reduce_ms(DP8, ctx2, V) is None
-    # disabled -> None regardless of curves
-    ctx3 = _ctx(hier_dp=False, alpha_beta_algos=_hier_algos())
-    assert hier_dp_reduce_ms(DP8, ctx3, V) is None
-
-
-def test_hier_dp_cp_sp_layers_priced_on_spmd_path():
-    """cp/Ulysses-bearing sdp groups are now eligible at pp=1: the hier
-    term splits the DP group (not sdp) and adds the in-lane cp/sp
-    residual (one ICI allreduce-curve hit at full grad volume). pp>1
-    cp/sp plans stay inexpressible — the pp engines keep their ring/a2a
-    kernels (search==runtime parity)."""
-    ctx = _ctx(hier_dp=True, dcn_slices=2, alpha_beta_algos=_hier_algos())
-    V = 12.0
-    s_cp = SearchStrategy(pp=1, tp=1, cp=2, dp=4)
-    # dp=4 over 2 slices: cross=2, intra=2 -> "2_1" ici curve at V, "2_0"
-    # dcn curve at V/2; residual csp=2 -> "2_1" at V
-    want = ((0.05 + V / 300.0) + (1.0 + (V / 2) / 10.0)
-            + (0.05 + V / 300.0))
-    assert hier_dp_reduce_ms(s_cp, ctx, V) == pytest.approx(want)
-    s_sp = SearchStrategy(pp=1, tp=1, sp=2, dp=4)
-    assert hier_dp_reduce_ms(s_sp, ctx, V) == pytest.approx(want)
-    # pp>1 cp plans: the engines would raise HIER_KERNEL_REASON, so the
-    # search must not price them
-    assert hier_dp_reduce_ms(
-        SearchStrategy(pp=2, tp=1, cp=2, dp=4), ctx, V) is None
-    # missing residual curve -> None (flat pricing stays)
-    algos = {k: v for k, v in _hier_algos().items() if k != "2_1"}
-    ctx2 = _ctx(hier_dp=True, dcn_slices=2, alpha_beta_algos=algos)
-    assert hier_dp_reduce_ms(s_cp, ctx2, V) is None
-
-
-# pipelining-friendly curves for the bucketed tests: β-bound ICI and DCN
-# stages of comparable size, tiny α — the regime where hiding the slow
-# link behind the fast ones pays
-_PIPE_ALGOS = {"4_1": {"ring_ici": (0.01, 5.0)},
-               "2_0": {"ring_dcn": (0.01, 1.0)},
-               "2_1": {"ring_ici": (0.05, 300.0)},
-               "8_1": {"ring_ici": (0.2, 150.0)}}
-
-
-def test_hier_dp_bucketed_hand_math():
-    """Fill-drain pipeline price: V=96 at 8-MB buckets -> B=12, per-bucket
-    msg 8 MB; T = t_ici + t_dcn + 11 * max(t_ici, t_dcn). The 0-default
-    reproduces the monolithic sum exactly (golden discipline)."""
-    V = 96.0
-    mono = _ctx(hier_dp=True, dcn_slices=2, alpha_beta_algos=_PIPE_ALGOS)
-    want_mono = (0.01 + V / 5.0) + (0.01 + (V / 4) / 1.0)
-    assert hier_dp_reduce_ms(DP8, mono, V) == pytest.approx(want_mono)
-    bkt = _ctx(hier_dp=True, dcn_slices=2, alpha_beta_algos=_PIPE_ALGOS,
-               hier_bucket_mb=8.0)
-    t_ici = 0.01 + 8.0 / 5.0
-    t_dcn = 0.01 + 2.0 / 1.0
-    want_bkt = t_ici + t_dcn + 11 * max(t_ici, t_dcn)
-    assert hier_dp_reduce_ms(DP8, bkt, V) == pytest.approx(want_bkt)
-    assert want_bkt < want_mono  # the pipelined schedule hides the ICI time
-
-
-def test_hier_dp_bucket_auto_sweep_picks_argmin():
-    """hier_bucket_mb < 0 (auto): the price is the candidate sweep's min
-    and hier_dp_best_bucket reports the chosen granularity for the plan
-    record ("hier_bucket_mb" in the plan JSON)."""
-    from hetu_galvatron_tpu.core.cost_model.cost import (
-        _BUCKET_SWEEP_MB,
-        hier_dp_best_bucket,
-    )
-
-    V = 96.0
-    auto = _ctx(hier_dp=True, dcn_slices=2, alpha_beta_algos=_PIPE_ALGOS,
-                hier_bucket_mb=-1.0)
-    ms, bucket = hier_dp_best_bucket(DP8, auto, V)
-    per_cand = {c: hier_dp_reduce_ms(
-        DP8, _ctx(hier_dp=True, dcn_slices=2, alpha_beta_algos=_PIPE_ALGOS,
-                  hier_bucket_mb=c), V) for c in _BUCKET_SWEEP_MB}
-    assert ms == pytest.approx(min(per_cand.values()))
-    assert per_cand[bucket] == pytest.approx(ms)
-    assert bucket > 0  # at these curves, bucketing beats monolithic
-    # and the plain reduce-ms entry returns the same auto price
-    assert hier_dp_reduce_ms(DP8, auto, V) == pytest.approx(ms)
-
-
-def test_hier_bucketing_flips_the_plan_record():
-    """THE pinned bucketing flip: at a flat dp coefficient where the
-    MONOLITHIC hier schedule loses to the flat overlapped ring
-    (hier_dp_wins False -> the plan records no "hier_dp"), pricing the
-    bucketed pipelined schedule wins (hier_dp_wins True -> the plan
-    records hier_dp + hier_bucket_mb and the runtime flips paths)."""
-    coe = {"8_1": 0.25, "8_0": 0.25, "4_1": 0.25, "4_0": 0.25,
-           "2_1": 0.25, "2_0": 0.25, "1": 0.0, "1_1": 0.0}
-    mono = _ctx(comm_coe_dict=coe, hier_dp=True, dcn_slices=2,
-                alpha_beta_algos=_PIPE_ALGOS)
-    bkt = _ctx(comm_coe_dict=coe, hier_dp=True, dcn_slices=2,
-               alpha_beta_algos=_PIPE_ALGOS, hier_bucket_mb=12.0)
-    assert not hier_dp_wins(DP8, mono, 64, 1)
-    assert hier_dp_wins(DP8, bkt, 64, 1)
-    assert (layer_time_cost(DP8, bkt, 64, 1)[0]
-            < layer_time_cost(DP8, mono, 64, 1)[0])
-
-
-def test_hier_dp_term_flips_the_chosen_plan():
-    """PINNED hier flip: with a slow flat dp coefficient, tp4xdp2 beats
-    tp1xdp8 (less dp traffic); the hierarchical curves make the big dp
-    group cheap (fast intra-host level + tiny cross shard), flipping the
-    winner to dp8 — and hier_dp_wins records the choice for the plan."""
-    coe = {"8_1": 0.4, "8_0": 0.4, "4_1": 0.4, "4_0": 0.4,
-           "2_1": 0.4, "2_0": 0.4, "1": 0.0, "1_1": 0.0}
-    ctx = _ctx(comm_coe_dict=coe)
-    assert _cost(TP4, ctx) < _cost(DP8, ctx)
-    ctx_h = _ctx(comm_coe_dict=coe, hier_dp=True, dcn_slices=2,
-                 alpha_beta_algos=_hier_algos())
-    assert _cost(DP8, ctx_h) < _cost(TP4, ctx_h)
-    assert hier_dp_wins(DP8, ctx_h, 64, 1)
-    assert not hier_dp_wins(DP8, ctx, 64, 1)
-
-
-def test_hier_enabled_never_raises_cost():
-    """min(flat, hier): at FIXED curves, turning the hier pricing on can
-    only lower a cost (the algo curves themselves may reprice tp either
-    way — that's the min-over-curves tests' subject, not this one)."""
-    for s in (TP2, TP4, DP8):
-        flat = _cost(s, _ctx(alpha_beta_algos=_hier_algos()))
-        hier = _cost(s, _ctx(hier_dp=True, dcn_slices=2,
-                             alpha_beta_algos=_hier_algos()))
-        assert hier <= flat + 1e-15
-
-
-def test_components_reflect_hier_choice():
-    """When the hierarchical term priced the layer, the audit-facing
-    decomposition reports the hierarchical dp time."""
-    coe = {"8_1": 0.4, "8_0": 0.4, "4_1": 0.4, "4_0": 0.4,
-           "2_1": 0.4, "2_0": 0.4, "1": 0.0, "1_1": 0.0}
-    ctx_h = _ctx(comm_coe_dict=coe, hier_dp=True, dcn_slices=2,
-                 alpha_beta_algos=_hier_algos())
-    comp = layer_time_components(DP8, ctx_h, 64, 1)
-    V = 48.0 / 1 * 4 * 0.5  # param_mb * n * mixed
-    want = hier_dp_reduce_ms(DP8, ctx_h, V)
-    assert comp["dp_ms"] * 4 == pytest.approx(want)  # scale = coe/n
-
-
-def test_hier_split_absorbs_pp_first():
-    """dcn_slices absorb pp before dp (mesh.dcn_factor_shape parity):
-    pp2 x dp4 under 2 slices has NO cross-slice dp level — the hier term
-    needs only the intra curves."""
-    s = SearchStrategy(pp=2, tp=1, dp=4)
-    algos = {"4_1": {"ring_ici": (0.1, 200.0)}}
-    ctx = _ctx(hier_dp=True, dcn_slices=2, alpha_beta_algos=algos)
-    V = 10.0
-    assert hier_dp_reduce_ms(s, ctx, V) == pytest.approx(0.1 + V / 200.0)

@@ -4,6 +4,7 @@ YAML + override round-trips through the validated schema)."""
 import pytest
 
 from hetu_galvatron_tpu.core.arguments import load_config, parse_overrides, args_from_cli
+from hetu_galvatron_tpu.utils.strategy import IGNORED_PLAN_KEYS
 
 pytestmark = pytest.mark.utils
 
@@ -62,13 +63,38 @@ def test_derived_model_fields():
     assert args.model.kv_heads == 8
 
 
-def test_negative_hier_bucket_mb_rejected():
-    """parallel.hier_bucket_mb < 0 is a config error: the auto-sweep
-    convention is search-side only (search.hier_bucket_mb < 0) — a truthy
-    negative runtime value would silently override a plan's recorded
-    bucket size into the monolithic schedule."""
-    with pytest.raises(Exception, match="hier_bucket_mb"):
-        load_config({"parallel": {"hier_bucket_mb": -1.0}})
-    # the search-side auto mode stays accepted
-    assert load_config(
-        {"search": {"hier_bucket_mb": -1.0}}).search.hier_bucket_mb == -1.0
+
+# the five options of the hierarchical dp reduction, by the names the plan
+# loader still reads past: three of ``parallel``, the first two of ``search``
+REMOVED_OPTIONS = ([("parallel", key, value) for key, value in zip(
+    IGNORED_PLAN_KEYS, (True, 4.0, "ring"))] + [("search", key, value)
+    for key, value in zip(IGNORED_PLAN_KEYS, (1, -1.0))])
+
+
+@pytest.mark.parametrize("section,option,value", REMOVED_OPTIONS,
+                         ids=[f"{s}.{o}" for s, o, _ in REMOVED_OPTIONS])
+def test_a_removed_option_is_no_field_and_moves_nothing(section, option,
+                                                        value):
+    """The options of the hierarchical dp reduction are gone: a file that
+    still sets one resolves to the configuration it is without the line
+    (the schema reads past unknown keys), on the one reduction there is."""
+    args = load_config({section: {option: value}})
+    assert not hasattr(getattr(args, section), option)
+    assert args == load_config()
+
+
+def test_the_schema_holds_370_options():
+    """What a reader of ``core/args_schema.py`` has to know: the annotated
+    fields of its 18 classes (375 before the hierarchical dp reduction and
+    its five options went). A PR that adds an option says so here."""
+    import ast
+    import inspect
+
+    from hetu_galvatron_tpu.core import args_schema
+
+    classes = [n for n in ast.parse(inspect.getsource(args_schema)).body
+               if isinstance(n, ast.ClassDef)
+               and any(isinstance(x, ast.AnnAssign) for x in n.body)]
+    assert len(classes) == 18
+    assert sum(isinstance(x, ast.AnnAssign)
+               for c in classes for x in c.body) == 370

@@ -155,3 +155,80 @@ def test_config2strategy_validates_world_size():
     }
     with pytest.raises(ValueError):
         config2strategy(cfg, world_size=8)
+
+
+# what a plan file written while the hierarchical dp reduction existed may
+# still carry, one case a key
+REMOVED_PLAN_KEYS = [("hier_dp", 1), ("hier_bucket_mb", 4.0),
+                     ("dp_schedule", "ring"),
+                     ("dp_schedule_rankings", {"ring": 0.5, "tree_hd": 0.7})]
+
+
+@pytest.mark.parametrize("key,value", REMOVED_PLAN_KEYS,
+                         ids=[k for k, _ in REMOVED_PLAN_KEYS])
+def test_a_removed_plan_key_is_read_past_and_named_once(key, value, tmp_path,
+                                                        capsys):
+    """The plan loads as the plan without the key does, the key changes
+    nothing of what is built from it, and the launcher trains it on the one
+    gradient reduction there is, saying so in one line."""
+    import json
+    import os
+
+    from hetu_galvatron_tpu.cli import train_dist
+    from hetu_galvatron_tpu.core.args_schema import CoreArgs
+    from hetu_galvatron_tpu.runtime.hybrid_config import (
+        get_hybrid_parallel_config,
+    )
+    from hetu_galvatron_tpu.utils.strategy import IGNORED_PLAN_KEYS
+
+    assert key in IGNORED_PLAN_KEYS
+    plain = strategy_list2config(
+        [LayerStrategy(pp_deg=1, tp_size=1, dp_size=1)] * 2,
+        global_bsz=4, chunks=2)
+    assert not set(IGNORED_PLAN_KEYS) & set(plain)   # nothing writes them
+    paths = {}
+    for name, cfg in (("plain", plain), ("old", {**plain, key: value})):
+        paths[name] = str(tmp_path / f"{name}.json")
+        with open(paths[name], "w") as f:
+            json.dump(cfg, f)
+
+    layers, vocab, extras = config2strategy({**plain, key: value},
+                                            world_size=1)
+    layers0, vocab0, extras0 = config2strategy(plain, world_size=1)
+    assert (layers, vocab) == (layers0, vocab0)
+    assert extras == {**extras0, "ignored_keys": (key,)}
+
+    def hpc_of(path):
+        args = CoreArgs()
+        args.model.num_hidden_layers = 2
+        args.parallel.config_mode = "json"
+        args.parallel.galvatron_config_path = path
+        return get_hybrid_parallel_config(args, 1)
+
+    old, new = hpc_of(paths["old"]), hpc_of(paths["plain"])
+    assert old.ignored_plan_keys == (key,) and not new.ignored_plan_keys
+    old.ignored_plan_keys = ()
+    assert old == new
+
+    yaml = os.path.join(os.path.dirname(train_dist.__file__), "..", "models",
+                        "configs", "gpt2-small.yaml")
+    size = ["model.hidden_size=32", "model.num_hidden_layers=2",
+            "model.num_attention_heads=2", "model.vocab_size=64",
+            "model.seq_length=8", "model.max_position_embeddings=16",
+            "model.make_vocab_size_divisible_by=1", "train.train_iters=2",
+            "parallel.mixed_precision=fp32", "parallel.num_devices=1",
+            "data.dataset=random", "parallel.config_mode=json"]
+    losses = {}
+    for name, path in paths.items():
+        out = {}
+        assert train_dist.main(
+            [yaml] + size + [f"parallel.galvatron_config_path={path}"],
+            result=out) == 0
+        said = capsys.readouterr()
+        losses[name] = (out["losses"], said.out + said.err)
+    assert losses["old"][0] == losses["plain"][0]
+    said = [line for line in losses["old"][1].splitlines()
+            if "are ignored" in line]
+    assert len(said) == 1 and f"plan keys {key} are ignored" in said[0]
+    assert "flat reduction" in said[0]
+    assert "are ignored" not in losses["plain"][1]
