@@ -789,6 +789,7 @@ def train(args) -> Dict[str, Any]:
     drop_key = jax.random.key(args.train.seed) if use_dropout else None
     # what the compiled step contains (filled after the first step)
     from hetu_galvatron_tpu.observability.trace_analysis import (
+        SELECTIVE_SCOPE,
         SSD_SCOPE,
         conv_kernel_calls,
         cores_recomputed,
@@ -1281,14 +1282,18 @@ def train(args) -> Dict[str, Any]:
                                         // max(loops["chunks"], 1)))
                         for part, v in step_report["kda"].items():
                             get_registry().gauge(f"kda/{part}").set(v)
-                    if any(m == "mamba" for m, _ in kinds):
-                        # whether the scan's kernels engaged: the Mosaic
-                        # calls under its scope, 0 = the jax.numpy form
-                        step_report["ssd_mosaic_calls"] = sum(
-                            n in found["mosaic_calls"]
-                            for n in found["scopes"].get(SSD_SCOPE, ()))
-                        get_registry().gauge("ssd/mosaic_calls").set(
-                            step_report["ssd_mosaic_calls"])
+                    for kind, scope, name in (
+                            ("mamba", SSD_SCOPE, "ssd"),
+                            ("mamba1", SELECTIVE_SCOPE, "selective")):
+                        if any(m == kind for m, _ in kinds):
+                            # whether the scan's kernels engaged: the
+                            # Mosaic calls under its scope, 0 = the
+                            # jax.numpy form
+                            step_report[f"{name}_mosaic_calls"] = sum(
+                                n in found["mosaic_calls"]
+                                for n in found["scopes"].get(scope, ()))
+                            get_registry().gauge(f"{name}/mosaic_calls").set(
+                                step_report[f"{name}_mosaic_calls"])
                     if any(MIXERS[m].reads("conv") for m, _ in kinds):
                         # whether the convolution's kernels engaged: their
                         # calls by phase, one a block in each where they
@@ -1372,6 +1377,9 @@ def train(args) -> Dict[str, Any]:
                     + (f" ({step_report['ssd_mosaic_calls']} under "
                        f"{SSD_SCOPE})" if "ssd_mosaic_calls" in step_report
                        else "")
+                    + (", selective/mosaic_calls "
+                       f"{step_report['selective_mosaic_calls']}"
+                       if "selective_mosaic_calls" in step_report else "")
                     + (", kda/blocks {blocks} kda/chunk {chunk} "
                        "kda/mosaic_calls {mosaic_calls}".format(
                         **step_report["kda"]) if "kda" in step_report
@@ -1507,6 +1515,10 @@ def train(args) -> Dict[str, Any]:
             # the Mosaic calls among those under mixer/mamba/ssd (the gauge
             # ssd/mosaic_calls): 0 where the scan ran in its jax.numpy form
             "ssd_mosaic_calls": step_report.get("ssd_mosaic_calls"),
+            # the same under mixer/mamba1/scan (the gauge
+            # selective/mosaic_calls); None for a model without such a block
+            "selective_mosaic_calls": step_report.get(
+                "selective_mosaic_calls"),
             # the blocks that run Kimi Delta Attention, their chunk length
             # and the Mosaic calls under mixer/kda/scan, by the compiled
             # step's kernel calls or, where that is 0, its loops (the

@@ -61,8 +61,9 @@ class LayerOps:
     mapping (x, w) to the fp32 product the default einsum would produce);
     ``shard(a, axis)`` pins an interior activation
     of a tp > 1 layer (parallel/spmd.py::interior_sharding); ``ssd``,
-    ``kda`` and ``conv`` are the kernels of a mamba block's chunked scan, a
-    kda block's chunked delta rule and the causal depthwise convolution
+    ``kda``, ``selective`` and ``conv`` are the kernels of a mamba block's
+    chunked scan, a kda block's chunked delta rule, a mamba1 block's
+    selective scan and the causal depthwise convolution
     (ops/pallas/); ``exchange`` runs an expert block's sorted dispatcher
     across the chips of its ``ep`` group (models/moe.py::
     make_expert_exchange). Which kinds of block read which field:
@@ -74,6 +75,7 @@ class LayerOps:
     shard: Optional[Callable[[jax.Array, int], jax.Array]] = None
     ssd: Optional[Callable[..., jax.Array]] = None
     kda: Optional[Callable[..., jax.Array]] = None
+    selective: Optional[Callable[..., Optional[jax.Array]]] = None
     conv: Optional[Callable[..., Optional[jax.Array]]] = None
     exchange: Optional[Callable[..., Any]] = None
 
@@ -460,15 +462,20 @@ def remat(fn, cfg: ModelArgs):
 
     Under every policy the results a forward kernel's differentiated rule
     names are kept (each kernel file's ``KEPT``): a flash attention core's
-    output and row statistics, a delta-rule or Mamba-2 scan's output and the
-    states that entered its chunks. They are what the rest of the step reads
+    output and row statistics, a delta-rule, Mamba-2 or selective scan's
+    output and the states that entered its chunks. They are what the rest of the step reads
     of the kernel, and producing them again is the kernel's whole run. So
     ``full`` keeps the block's input and what its forward kernels named; the
     recomputed forward then holds none of them. A block that ran no such
     kernel (the XLA core, a mixer in its ``jax.numpy`` form, an MLP alone)
     traces no name and is recomputed whole; the convolution's kernels name
     nothing either."""
-    from hetu_galvatron_tpu.ops.pallas import flash_attention, kda, ssd
+    from hetu_galvatron_tpu.ops.pallas import (
+        flash_attention,
+        kda,
+        selective_scan,
+        ssd,
+    )
 
     policies = jax.checkpoint_policies
     base = {"full": None, "dots": policies.checkpoint_dots,
@@ -479,7 +486,7 @@ def remat(fn, cfg: ModelArgs):
         raise ValueError(f"unknown remat_policy {cfg.remat_policy!r} "
                          "(full | dots | dots_no_batch)")
     policy = policies.save_only_these_names(
-        *flash_attention.KEPT, *kda.KEPT, *ssd.KEPT)
+        *flash_attention.KEPT, *kda.KEPT, *ssd.KEPT, *selective_scan.KEPT)
     if base[cfg.remat_policy] is not None:
         policy = policies.save_from_both_policies(base[cfg.remat_policy],
                                                   policy)
@@ -1531,8 +1538,10 @@ def _selective_chunk(state, u, dt, Bm, Cm, At):
 
 
 # positions of a chunk of :func:`selective_scan`, all of them unrolled: the
-# fastest of the forms probed on a v5e (PR 61; the numbers are below), and
-# what bounds the backward pass's memory
+# fastest of the ``jax.numpy`` forms probed on a v5e (PR 61; the numbers are
+# below), and what bounds the backward pass's memory. A TPU runs the
+# kernels of ``ops/pallas/selective_scan.py`` (PR 64), whose chunk is their
+# own; this is the chunk of the oracle, of the CPU and of shapes no tile fits
 SELECTIVE_CHUNK = 16
 
 
@@ -1549,6 +1558,12 @@ def selective_scan(u: jax.Array, dt: jax.Array, A: jax.Array,
     negative; ``Bm``, ``Cm`` [B, S, N], shared by the channels. Returns
     ``y`` [B, S, C] float32 (without the ``D u`` skip). Everything float32.
 
+    This is the oracle of the recurrence, the form the CPU runs and the form
+    of shapes that fit no tile of the kernels; on a TPU a ``mamba1`` block
+    runs ``ops/pallas/selective_scan.py`` (``apply_mamba1``'s ``scan_fn``),
+    which keeps the state in VMEM and is held to this function by
+    ``tests/kernels/test_selective_scan_kernel.py``.
+
     The decay is a number a channel AND state index, so no chunked matmul
     form exists (Mamba-2's is one scalar a head): this is 2 ``C N``
     multiply-adds a position on the vector unit. A ``lax.scan`` over chunks
@@ -1559,9 +1574,10 @@ def selective_scan(u: jax.Array, dt: jax.Array, A: jax.Array,
     body is rematerialized, so the backward pass holds the states that
     entered the chunks (``S / chunk`` of them) and one chunk's
     intermediates. (On a v5e at [1, 8192, 5120] x 16, forward and backward:
-    41 ms so at 16 positions a chunk, 78 ms at 64 not unrolled, 56 ms and
-    182 ms with ``lax.associative_scan`` inside chunks of 16 and 64;
-    PERF.md section 6, PR 61.) A sequence that ``chunk`` does not divide is
+    41 ms so at 16 positions a chunk (6.4 ms forward alone), 78 ms at 64 not
+    unrolled, 56 ms and 182 ms with ``lax.associative_scan`` inside chunks
+    of 16 and 64; PERF.md section 6, PR 61: the baseline the kernels of
+    PR 64 are measured against.) A sequence that ``chunk`` does not divide is
     padded with ``dt = 0`` (no decay, no input) and the padding cut off."""
     f32 = jnp.float32
     B_, S, C = u.shape
@@ -1587,6 +1603,7 @@ def apply_mamba1(
     x: jax.Array,
     cfg: ModelArgs,
     compute_dtype=jnp.bfloat16,
+    scan_fn: Optional[Callable[..., Optional[jax.Array]]] = None,
     conv_fn: Optional[Callable[..., Optional[jax.Array]]] = None,
     made: Optional[Dict[str, jax.Array]] = None,
 ) -> jax.Array:
@@ -1596,8 +1613,12 @@ def apply_mamba1(
     scan(u, dt, A, B, C) + D u`` (:func:`selective_scan`); ``(y * silu(z))
     W_out``. No softmax, no positions, no norm. The four projections run in
     ``compute_dtype`` with float32 accumulation; ``dt``, the decays, the
-    state, the convolution and the gate are float32. ``conv_fn``: the
-    kernels for the convolution, its bias and SiLU
+    state, the convolution and the gate are float32. ``scan_fn``: the
+    kernels for the recurrence, where the caller's devices run them
+    (``ops/pallas/selective_scan.py``, handed down by
+    ``parallel/spmd.attention_overrides``; it answers None for shapes that
+    fit no tile, and :func:`selective_scan` runs), and ``conv_fn`` those for
+    the convolution, its bias and SiLU
     (:func:`causal_depthwise_conv`). ``made`` (the block whose scan output
     later blocks read, ``ModelArgs.block_shares``) is written ``memory`` =
     ``y`` [B, S, channels], after the ``D`` skip and before the gate."""
@@ -1622,8 +1643,10 @@ def apply_mamba1(
             d, Bm, Cm = jnp.split(proj(u, p["wx"]), [R, R + N], axis=-1)
             dt = jax.nn.softplus(proj(d, p["wdt"]) + p["dt_bias"])
         with jax.named_scope("scan"):
-            y = selective_scan(u, dt, -jnp.exp(p["A_log"].astype(f32)),
-                               Bm, Cm)
+            A = -jnp.exp(p["A_log"].astype(f32))
+            y = scan_fn(u, dt, A, Bm, Cm) if scan_fn is not None else None
+            if y is None:
+                y = selective_scan(u, dt, A, Bm, Cm)
             y = y + p["D"] * u.astype(f32)
         if made is not None:
             made["memory"] = y.astype(compute_dtype)
@@ -2211,8 +2234,8 @@ MIXERS: Dict[str, Mixer] = {
         "attn", init_attention, partial(apply_attention, windowed=True),
         True, {"sdpa_fn": "sdpa"}, uncut_reason="window_plan_reason"),
     "mamba1": Mixer(
-        "mamba1", init_mamba1, apply_mamba1, False, {"conv_fn": "conv"},
-        "mamba1", uncut_reason="mamba1_plan_reason",
+        "mamba1", init_mamba1, apply_mamba1, False,
+        {"scan_fn": "selective", "conv_fn": "conv"}, "mamba1", uncut_reason="mamba1_plan_reason",
         crosses_documents=_CARRIED,
         leaves=SHARED_VALUES["gmu"][0]),
     "gmu": Mixer(

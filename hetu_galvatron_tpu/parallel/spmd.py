@@ -145,16 +145,20 @@ def block_kernels():
     The kinds of block that take a field are the rows of ``modules.MIXERS``
     that name it; whether the shapes fit a kernel's tiles is the kernel's
     caller's to see (``modules.ssd_chunked``, ``kda_chunked``,
-    ``causal_depthwise_conv``), which keeps its ``jax.numpy`` form where they
-    do not. A mamba or kda block cut any other way than over dp is refused by
+    ``apply_mamba1``, ``causal_depthwise_conv``), which keeps its
+    ``jax.numpy`` form where they do not. A mamba, mamba1 or kda block cut any other way than over dp is refused by
     name (analysis/eligibility.py); a depthwise convolution is local to a
     channel shard."""
     from hetu_galvatron_tpu.ops.pallas.conv import make_causal_conv
     from hetu_galvatron_tpu.ops.pallas.kda import make_kda_scan
+    from hetu_galvatron_tpu.ops.pallas.selective_scan import (
+        make_selective_scan,
+    )
     from hetu_galvatron_tpu.ops.pallas.ssd import make_ssd_scan
 
     return (("ssd", make_ssd_scan, False, None),
             ("kda", make_kda_scan, False, None),
+            ("selective", make_selective_scan, False, None),
             ("conv", make_causal_conv, True, "weight_tp_axes"))
 
 
@@ -197,8 +201,9 @@ def attention_overrides(
     every layer attends): a layer whose kind does not attend gets no core, and
     a layer whose kind reads a field of :func:`block_kernels` (a ``mamba``
     layer ``ssd`` and ``conv``, a ``kda`` layer ``kda`` and ``conv``, a
-    ``conv`` layer ``conv``) gets that kernel when ``kernels`` (None = the
-    same rule: every mesh device is a TPU)."""
+    ``mamba1`` layer ``selective`` and ``conv``, a ``conv`` layer ``conv``)
+    gets that kernel when ``kernels`` (None = the same rule: every mesh
+    device is a TPU)."""
     from functools import partial as _partial
 
     from hetu_galvatron_tpu.models.modules import MIXERS, xla_sdpa

@@ -13,7 +13,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from hetu_galvatron_tpu.ops.pallas import conv, kda, ssd
+from hetu_galvatron_tpu.ops.pallas import conv, kda, selective_scan, ssd
 from hetu_galvatron_tpu.ops.pallas.flash_attention import flash_sdpa
 
 pytestmark = pytest.mark.kernels
@@ -233,6 +233,56 @@ def test_kda_scan_forward_and_backward_compile_for_v5e(one_chip, case):
     assert kda_kernel_calls(text) == {"mosaic_calls": 3, "blocks": 1,
                                       "chunk": C}
     assert kda_loops(text) == {"blocks": 0, "chunks": 0}
+
+
+# B, S, channels, state, u's dtype
+_SELECTIVE_CASES = {
+    "phi4flash_cell": (1, 8192, 5120, 16, jnp.bfloat16),
+    "two_rows_f32_a_ragged_chunk": (2, 300, 384, 16, jnp.float32),
+    "a_wider_state": (1, 1024, 512, 64, jnp.bfloat16),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_SELECTIVE_CASES))
+def test_selective_scan_forward_and_backward_compile_for_v5e(one_chip, case):
+    """Both kernels compile at the cell's shapes, and under per-layer remat
+    a block is two instructions under ``mixer/mamba1/scan`` in the map a
+    trace is laid over (the forward by the scope it was called in, the
+    backward by the scope its rule opens; ``modules.remat`` keeps what the
+    forward named, so none is made again)."""
+    from hetu_galvatron_tpu.core.args_schema import ModelArgs
+    from hetu_galvatron_tpu.models import modules
+    from hetu_galvatron_tpu.observability.trace_analysis import (
+        scans_recomputed,
+        step_hlo,
+    )
+
+    B, S, C, N, dtype = _SELECTIVE_CASES[case]
+
+    def spec(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def block(*a):
+        with jax.named_scope("mixer/mamba1"):
+            with jax.named_scope("scan"):
+                return selective_scan.selective_scan(*a)
+
+    block = modules.remat(block, ModelArgs(
+        hidden_size=32, num_hidden_layers=1, num_attention_heads=2,
+        vocab_size=64))
+    text = jax.jit(jax.grad(
+        lambda *a: jnp.sum(jnp.square(block(*a))),
+        argnums=(0, 1, 2, 3, 4))).lower(
+            spec((B, S, C), dtype), spec((B, S, C), jnp.float32),
+            spec((C, N), jnp.float32), spec((B, S, N), jnp.float32),
+            spec((B, S, N), jnp.float32)).compile().as_text()
+    found = step_hlo(text, (selective_scan.SCOPE,))
+    calls = sorted(found["mosaic_calls"])
+    assert len(calls) == 2, calls
+    assert "selective_scan_bwd" in calls[0], calls
+    assert "selective_scan_fwd" in calls[1], calls
+    assert set(calls) <= set(found["scopes"][selective_scan.SCOPE])
+    assert scans_recomputed(found) == 0
 
 
 # B, S, channels, taps, bias, gates, head_norm, dtype, the caller's scope

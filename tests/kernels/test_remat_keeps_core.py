@@ -3,9 +3,10 @@
 names), so a block's recomputed forward holds no forward kernel: one
 ``flash_attention_fwd`` call a block in the gradient's jaxpr where plain
 ``jax.checkpoint`` with the same base policy has two, and the same numbers to
-the last bit. The same of a delta-rule block's ``kda_scan_fwd`` and a
-Mamba-2 block's ``ssd_scan_fwd``, whose differentiated forward names its
-output and the states that entered the chunks (each kernel file's ``KEPT``).
+the last bit. The same of a delta-rule block's ``kda_scan_fwd``, a Mamba-2
+block's ``ssd_scan_fwd`` and a Mamba-1 block's ``selective_scan_fwd``, whose
+differentiated forward names its output and the states that entered the
+chunks (each kernel file's ``KEPT``).
 Interpret-mode kernels on the CPU; the compile of the real kernels for a
 described v5e is in ``test_flash_mosaic_compile.py``.
 
@@ -25,7 +26,7 @@ import jax.numpy as jnp
 
 from hetu_galvatron_tpu.core.args_schema import ModelArgs
 from hetu_galvatron_tpu.models import modules as M
-from hetu_galvatron_tpu.ops.pallas import kda, ssd
+from hetu_galvatron_tpu.ops.pallas import kda, selective_scan, ssd
 from hetu_galvatron_tpu.ops.pallas.flash_attention import (
     flash_sdpa,
     make_flash_sdpa,
@@ -36,8 +37,9 @@ pytestmark = pytest.mark.kernels
 B, S, H, N = 2, 128, 64, 2
 # the recurrent mixers at shapes their kernels' tiles fit (``tile_plan``),
 # two chunks a sequence so that a state enters the second: 2 heads of 128
-# at the cell's chunk of 64, and 8 heads of 16 with a state of 128 at a
-# chunk of one lane tile over twice the positions
+# at the cell's chunk of 64, 8 heads of 16 with a state of 128 at a
+# chunk of one lane tile over twice the positions, and 128 channels with a
+# state of 16 over two of the selective scan's chunks
 CFG = ModelArgs(
     hidden_size=H, num_hidden_layers=2, num_attention_heads=N, vocab_size=64,
     max_position_embeddings=2 * S, seq_length=S, hidden_act="swiglu",
@@ -56,6 +58,11 @@ SCANS = {
     "mamba": ("ssd", ssd.ssd_scan, ssd.make_ssd_scan, "ssd_scan_fwd",
               "ssd_scan_bwd", 2 * S, dict(zip(ssd.KEPT, (
                   (B, 2 * S, 128), (B, 2 * S // 128, 128, 128))))),
+    "mamba1": ("selective", selective_scan.selective_scan,
+               selective_scan.make_selective_scan, "selective_scan_fwd",
+               "selective_scan_bwd", 2 * S, dict(zip(selective_scan.KEPT, (
+                   (B, 2 * S, 128),
+                   (B, 2 * S // selective_scan.CHUNK, 16, 128))))),
 }
 BASE_POLICIES = {
     "full": None,
@@ -290,7 +297,9 @@ def _named(jaxpr):
     ("kda", "full"), ("kda_shard_map", "full"), ("kda_numpy", "full"),
     ("kda", "dots_no_batch"),
     ("mamba", "full"), ("mamba_shard_map", "full"), ("mamba_numpy", "full"),
-    ("mamba", "dots_no_batch")])
+    ("mamba", "dots_no_batch"),
+    ("mamba1", "full"), ("mamba1_shard_map", "full"),
+    ("mamba1_numpy", "full"), ("mamba1", "dots_no_batch")])
 def test_what_a_rematted_block_keeps(cpu_devices, capsys, core, policy):
     """``full`` keeps the block's input and, of a flash core, the output as
     [B, S, N * Dv] rows (the bytes of the input; at heads of 64 and of 128
@@ -314,7 +323,7 @@ def test_what_a_rematted_block_keeps(cpu_devices, capsys, core, policy):
                                   or kept == [])
         assert not set(_named(jax.make_jaxpr(jax.grad(
             lambda p, h: jnp.sum(block(0)(p, h))))(params[0], x).jaxpr)) & {
-                *kda.KEPT, *ssd.KEPT}
+                *kda.KEPT, *ssd.KEPT, *selective_scan.KEPT}
         return
     if kind in SCANS:
         # what the differentiated forward names, shard by shard under

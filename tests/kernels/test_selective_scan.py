@@ -1,5 +1,7 @@
 """Mamba-1's selective scan in its chunked ``jax.numpy`` form
-(``modules.selective_scan``: the oracle a later kernel is tested against)
+(``modules.selective_scan``: the oracle the kernels of
+``ops/pallas/selective_scan.py`` are tested against in
+``test_selective_scan_kernel.py``)
 against the recurrence one position at a time, and the names the step
 carries for what phi4flash adds: the scopes in the compiled step's HLO, the
 gauges and the cores ``train_dist`` logs for the cut model through its
@@ -215,6 +217,17 @@ def test_the_gauges_say_what_crosses_blocks(launched, gauge, value):
     reg, out, _ = launched
     assert [m.value for m in reg.metrics() if m.name == gauge] == [value]
     assert out["shared_values"][gauge] == value
+
+
+def test_the_gauge_says_the_scans_kernels_did_not_engage(launched):
+    """On a CPU (and at 64 channels) the ``jax.numpy`` form ran: no Mosaic
+    call under ``mixer/mamba1/scan``, said by the gauge, ``train()``'s
+    result and no scan made twice."""
+    reg, out, _ = launched
+    assert [m.value for m in reg.metrics()
+            if m.name == "selective/mosaic_calls"] == [0]
+    assert out["selective_mosaic_calls"] == 0
+    assert out["scans_recomputed"] == 0
 
 
 @pytest.mark.parametrize("scope", NEW_SCOPES + ("attn/window_core",

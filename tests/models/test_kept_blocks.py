@@ -4,6 +4,7 @@ function, the byte count against a hand count, the plan's flags and program
 where the device leaves nothing, gradients with mixed flags against
 all-recomputed, the fall-back, and what the launcher says of the choice."""
 
+import functools
 import io
 import logging
 import os
@@ -157,6 +158,40 @@ def test_the_byte_count_is_the_hand_count(name, whole, again):
         kept.BlockCount(held_bytes=(whole - again) // 4,
                         forward_flops=count.forward_flops,
                         input_bytes=again // 4, whole_bytes=whole // 4))
+
+
+def test_a_mamba1_blocks_count_reads_what_its_scans_kernels_keep():
+    """A block that runs the selective scan in its kernels holds, recomputed
+    or not, what the differentiated forward names (``selective_scan.KEPT``:
+    the output and the entering states, float32): they are ``input_bytes``
+    beside the block's input, where the ``jax.numpy`` form keeps the input
+    alone; and what keeping the block whole adds is the less for them."""
+    from hetu_galvatron_tpu.ops.pallas import selective_scan
+
+    rows, seq = 2, 256
+    cfg = ModelArgs(hidden_size=64, num_hidden_layers=2,
+                    num_attention_heads=2, vocab_size=64, seq_length=seq,
+                    max_position_embeddings=seq, hidden_act="swiglu",
+                    make_vocab_size_divisible_by=1)
+    channels, state = cfg.mamba1_d_inner, cfg.mamba1_d_state
+    assert selective_scan.tile_plan(channels, state, seq) == 128
+    params, _ = M.init_decoder_layer(jax.random.key(0), cfg, mixer="mamba1")
+    x = jnp.zeros((rows, seq, cfg.hidden_size), jnp.float32)
+
+    def block(ops):
+        return lambda p, h: M.apply_decoder_layer(
+            p, h, cfg, mixer="mamba1", ops=ops, compute_dtype=jnp.float32)
+
+    plain, _ = kept.count_block(block(M.LayerOps()), cfg, (params, x))
+    kernels, _ = kept.count_block(block(M.LayerOps(
+        selective=functools.partial(selective_scan.selective_scan,
+                                    interpret=True))), cfg, (params, x))
+    named = 4 * rows * (seq * channels
+                        + seq // selective_scan.CHUNK * state * channels)
+    assert plain.input_bytes == 4 * x.size
+    assert kernels.input_bytes == plain.input_bytes + named
+    assert kernels.forward_flops == plain.forward_flops
+    assert 0 < kernels.held_bytes < plain.held_bytes
 
 
 def test_a_chain_of_cheap_operations_is_held_once_at_its_cheapest():

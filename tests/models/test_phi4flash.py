@@ -435,6 +435,51 @@ def test_remat_on_and_off_give_the_same_gradients(policy="full"):
                                    rtol=1e-5, err_msg=name)
 
 
+def test_the_stack_through_the_scans_kernels_is_the_stack():
+    """The tiny stack at a width whose scan fits the kernels' tiles (128
+    channels, a state of 16, 21 positions padded to one chunk) with
+    ``ops/pallas/selective_scan.py`` handed to its two mamba1 blocks
+    (interpret mode) against the stack in its ``jax.numpy`` form, under
+    per-layer remat as the cell runs it: the logits, the loss and every
+    gradient leaf, none of them zero."""
+    from hetu_galvatron_tpu.ops.pallas import selective_scan
+
+    cfg = ModelArgs(**{**TINY, "hidden_size": 64, "remat_policy": "full"})
+    assert selective_scan.tile_plan(
+        cfg.mamba1_d_inner, cfg.mamba1_d_state, cfg.seq_length) == 128
+    params, batch = _seeded(cfg), _batch()
+    calls = []
+
+    def scan(*a):
+        calls.append(a)
+        return selective_scan.selective_scan(*a, interpret=True)
+
+    with_kernels = {i: M.LayerOps(selective=scan)
+                    for i, kind in enumerate(TYPES) if kind == "mamba1"}
+    sides = {}
+    for name, overrides in (("kernels", with_kernels), ("numpy", None)):
+        logits = jax.jit(lambda p, t: forward_causal_lm(
+            p, t, cfg, compute_dtype=jnp.float32,
+            layer_overrides=overrides))(params, batch["tokens"])
+        loss, grads = jax.jit(jax.value_and_grad(lambda p, b: causal_lm_loss(
+            p, b, cfg, compute_dtype=jnp.float32, remat_flags=[True] * 6,
+            layer_overrides=overrides)))(params, batch)
+        sides[name] = (logits, loss, grads)
+    # (two blocks, traced for the logits and for the loss; the recomputed
+    # forward of a rematted block is the trace of its first)
+    assert len(calls) == 4
+    scale = float(jnp.abs(sides["numpy"][0]).max())
+    assert scale > 0.1
+    np.testing.assert_allclose(np.asarray(sides["kernels"][0]),
+                               np.asarray(sides["numpy"][0]),
+                               atol=1e-5 * scale, rtol=0)
+    assert abs(float(sides["kernels"][1]) - float(sides["numpy"][1])) < 1e-5
+    for name, x, y in _leafwise(sides["kernels"][2], sides["numpy"][2]):
+        assert np.abs(y).max() > 0, name
+        np.testing.assert_allclose(x, y, atol=1e-4 * np.abs(y).max(), rtol=0,
+                                   err_msg=name)
+
+
 # ---------------------------------------------------------------------------
 # (d) names, configuration, counts
 # ---------------------------------------------------------------------------
