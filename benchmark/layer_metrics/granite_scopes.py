@@ -19,7 +19,7 @@ join would then be of two different programs.
 import importlib
 import os
 
-from benchmark import flops, manifest
+from benchmark import manifest, readers
 
 SCOPES = tuple(f"mixer/mamba/{part}" for part in (
     "in_proj", "conv", "ssd", "gated_norm", "out_proj"))
@@ -80,10 +80,7 @@ def ssd_roofline(facts):
     got = scope_ns(facts, SSD_SCOPES)
     if got is None or got[0] <= 0:
         return None
-    cost = getattr(manifest.load_python(os.path.join(
-        os.path.dirname(os.path.abspath(__file__)), COST_FILE)), COST)
-    least = flops.roofline_least_s(
-        cost(facts["sizes"], facts["sequences_per_step"]), facts["peaks"],
-        facts["chips"])
-    facts.setdefault("roofline_bounds", {})[COST] = least["bound"]
-    return 100.0 * least["least_s"] / (got[0] / got[1].periods / 1e9)
+    return readers.roofline_pct(
+        facts, got[0] / got[1].periods / 1e9, COST,
+        manifest.load_python(os.path.join(
+            os.path.dirname(os.path.abspath(__file__)), COST_FILE)))

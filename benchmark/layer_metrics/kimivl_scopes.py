@@ -22,7 +22,7 @@ a step is no instruction of the step's HLO.
 
 import os
 
-from benchmark import flops, manifest
+from benchmark import manifest, readers
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _step_map = manifest.load_python(os.path.join(_HERE, "step_map.py"))
@@ -85,9 +85,6 @@ def tower_core_roofline(facts):
     ms = tower_core_ms(facts)
     if not ms:
         return None
-    cost = getattr(manifest.load_python(os.path.join(_HERE, COST_FILE)), COST)
-    least = flops.roofline_least_s(
-        cost(facts["sizes"], facts["sequences_per_step"]), facts["peaks"],
-        facts["chips"])
-    facts.setdefault("roofline_bounds", {})[COST] = least["bound"]
-    return 100.0 * least["least_s"] / (ms / 1e3)
+    return readers.roofline_pct(
+        facts, ms / 1e3, COST,
+        manifest.load_python(os.path.join(_HERE, COST_FILE)))

@@ -1,8 +1,9 @@
 """Device time of what ``laguna-s-2.1-ep32`` adds to a step, by named scope:
-the attention cores of its window blocks (``attn/window_core``) and of its
-full blocks (``attn/core``), the gate a head (``attn/gate``), and the share
-of the causal triangle's score tiles its windowed kernels visit (the gauge
-``flash/band_tiles_pct``).
+the gate a head (``attn/gate``), and the share of the causal triangle's
+score tiles its windowed kernels visit (the gauge ``flash/band_tiles_pct``).
+(The attention cores of its window and full blocks are read by the shared
+entries ``window_core_ms``, ``window_roofline`` and ``full_core_ms``, which
+list the cell.)
 
 The times read ``step_map.py``'s join (each traced instruction's deepest
 scope, from the map the step report keeps), so a metric reads the same work
@@ -18,25 +19,14 @@ operation traced inside a step is no instruction of the step's HLO.
 
 import os
 
-from benchmark import flops, manifest
+from benchmark import manifest
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _step_map = manifest.load_python(os.path.join(_HERE, "step_map.py"))
 _gauges = manifest.load_python(os.path.join(_HERE, "program_gauges.py"))
 
-WINDOW_CORE_SCOPES = ("attn/window_core",)
-FULL_CORE_SCOPES = ("attn/core",)
 GATE_SCOPES = ("attn/gate",)
 BAND_TILES_GAUGE = "flash/band_tiles_pct"
-COST_FILE, COST = "laguna_window_cost.py", "laguna_window_step_cost"
-
-
-def window_core_ms(facts):
-    return _step_map._ms_a_step(facts, _step_map.SCOPE, WINDOW_CORE_SCOPES)
-
-
-def full_core_ms(facts):
-    return _step_map._ms_a_step(facts, _step_map.SCOPE, FULL_CORE_SCOPES)
 
 
 def gate_ms(facts):
@@ -46,17 +36,3 @@ def gate_ms(facts):
 def band_tiles_pct(facts):
     g = _gauges.written(BAND_TILES_GAUGE)
     return None if g is None else g.value
-
-
-def window_roofline(facts):
-    """Least time by the roofline (``laguna_window_cost.py``) over the
-    measured time under ``attn/window_core``, in percent."""
-    ms = window_core_ms(facts)
-    if not ms:
-        return None
-    cost = getattr(manifest.load_python(os.path.join(_HERE, COST_FILE)), COST)
-    least = flops.roofline_least_s(
-        cost(facts["sizes"], facts["sequences_per_step"]), facts["peaks"],
-        facts["chips"])
-    facts.setdefault("roofline_bounds", {})[COST] = least["bound"]
-    return 100.0 * least["least_s"] / (ms / 1e3)

@@ -31,10 +31,6 @@ def _value(name):
     return None if g is None else g.value
 
 
-def local_routes_pct(facts):
-    return _value(LOCAL_ROUTES_GAUGE)
-
-
 def moe_imbalance(facts):
     # only where the layer holds a share: the gauge of a layer that holds
     # every expert is ``moe_imbalance``'s

@@ -1,11 +1,12 @@
 """Device time of what ``mellum2-12b-a2.5b-p1`` adds to a step, by named
 scope, and what its expert exchange wrote into the program's registry: the
 two collectives around an expert layer (``moe/exchange/gather``,
-``moe/exchange/scatter``), the attention cores of its window blocks
-(``attn/window_core``) and of its full block (``attn/core``), and the first
-expert block's balance over the chips of the ``ep`` group
-(``moe/chip_imbalance{layer=layer0}``) and over its experts
-(``moe/imbalance{layer=layer0}``).
+``moe/exchange/scatter``) and the first expert block's balance over the
+chips of the ``ep`` group (``moe/chip_imbalance{layer=layer0}``). (Its
+window and full cores, its experts and the balance over its experts are
+read by the shared entries ``window_core_ms``, ``window_roofline``,
+``full_core_ms``, ``experts_*`` and ``moe_imbalance``, which list the
+cell.)
 
 The times read ``step_map.py``'s join (each traced instruction's deepest
 scope, from the map the step report keeps) on the cell's FIRST device, as
@@ -21,20 +22,16 @@ a step is no instruction of the step's HLO.
 
 import os
 
-from benchmark import flops, manifest, xplane
+from benchmark import manifest, xplane
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _step_map = manifest.load_python(os.path.join(_HERE, "step_map.py"))
 _gauges = manifest.load_python(os.path.join(_HERE, "program_gauges.py"))
 
 EXCHANGE_SCOPES = ("moe/exchange/gather", "moe/exchange/scatter")
-WINDOW_CORE_SCOPES = ("attn/window_core",)
-FULL_CORE_SCOPES = ("attn/core",)
 # every block has experts: the first expert layer is block 0
 FIRST_EXPERT_LAYER = "layer0"
 CHIP_IMBALANCE_GAUGE = "moe/chip_imbalance"
-IMBALANCE_GAUGE = "moe/imbalance"
-COST_FILE, COST = "mellum_window_cost.py", "mellum_window_step_cost"
 
 
 def exchange_ms(facts):
@@ -59,28 +56,6 @@ def exchange_exposed_pct(facts):
     return 100.0 * (covered - hidden) / (r.busy_s * 1e9)
 
 
-def window_core_ms(facts):
-    return _step_map._ms_a_step(facts, _step_map.SCOPE, WINDOW_CORE_SCOPES)
-
-
-def full_core_ms(facts):
-    return _step_map._ms_a_step(facts, _step_map.SCOPE, FULL_CORE_SCOPES)
-
-
-def window_roofline(facts):
-    """Least time by the roofline (``mellum_window_cost.py``) over the
-    measured time under ``attn/window_core``, in percent."""
-    ms = window_core_ms(facts)
-    if not ms:
-        return None
-    cost = getattr(manifest.load_python(os.path.join(_HERE, COST_FILE)), COST)
-    least = flops.roofline_least_s(
-        cost(facts["sizes"], facts["sequences_per_step"]), facts["peaks"],
-        facts["chips"])
-    facts.setdefault("roofline_bounds", {})[COST] = least["bound"]
-    return 100.0 * least["least_s"] / (ms / 1e3)
-
-
 def _gauge(name):
     g = _gauges.written(name, layer=FIRST_EXPERT_LAYER)
     return None if g is None else g.value
@@ -88,11 +63,3 @@ def _gauge(name):
 
 def chip_imbalance(facts):
     return _gauge(CHIP_IMBALANCE_GAUGE)
-
-
-def moe_imbalance(facts):
-    # only where the layer ran inside the exchange: the gauge of a layer on
-    # one chip is ``moe_imbalance``'s
-    if _gauge(CHIP_IMBALANCE_GAUGE) is None:
-        return None
-    return _gauge(IMBALANCE_GAUGE)

@@ -1,10 +1,10 @@
 """Device time of what ``xing4.0-29b-a4b-ep8`` adds to a step, by named
-scope: the latent projections, the residual streams' maps and mixes, and the
-further prediction depth.
+scope: the residual streams' maps and mixes, and the further prediction
+depth. (Its latent projections are read by the shared entry
+``latent_proj_ms``, which lists the cell.)
 
-The first two read ``step_map.py``'s join (each traced instruction's deepest
-scope, from the map the step report keeps: ``attn/latent_proj``, ``hc/maps``,
-``hc/mix``). The further depth's block runs the same scopes as every other
+The first reads ``step_map.py``'s join (each traced instruction's deepest
+scope, from the map the step report keeps: ``hc/maps``, ``hc/mix``). The further depth's block runs the same scopes as every other
 block (``attn/core``, ``moe/experts``, ``hc/mix``, ``head``), so its time is
 read from the lists the program keeps of every instruction UNDER
 ``mtp/embed_proj``, ``mtp/block`` and ``mtp/head``, whatever deeper scope it
@@ -25,13 +25,8 @@ from benchmark import manifest
 _step_map = manifest.load_python(os.path.join(
     os.path.dirname(os.path.abspath(__file__)), "step_map.py"))
 
-LATENT_SCOPES = ("attn/latent_proj",)
 HC_SCOPES = ("hc/maps", "hc/mix")
 MTP_SCOPES = ("mtp/embed_proj", "mtp/block", "mtp/head")
-
-
-def latent_proj_ms(facts):
-    return _step_map._ms_a_step(facts, _step_map.SCOPE, LATENT_SCOPES)
 
 
 def hc_ms(facts):

@@ -302,6 +302,10 @@ def check_manifest(manifest: Dict[str, Any], root: str = ROOT) -> List[str]:
                 os.path.join(os.path.dirname(path), beside)):
             bad.append(f"{m['name']}: its reader names {beside!r}, which is "
                        f"not beside it in {BENCH_DIR}/layer_metrics")
+        if reader["kind"] == "roofline" and ("pattern" in reader) == (
+                "scopes" in reader):
+            bad.append(f"{m['name']}: a roofline reader names a pattern or "
+                       "scopes, one of the two")
         if (reader["kind"] == "roofline" and not beside
                 and not callable(getattr(flops, reader["cost"], None))):
             bad.append(f"{m['name']}: cost {reader['cost']!r} is no function "

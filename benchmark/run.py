@@ -114,7 +114,8 @@ def measure(cell, *, seed: int, seconds: float, trace: int, chip: dict,
                      if fullest["peak_bytes_in_use"] and fullest["bytes_limit"]
                      else None)}
     facts.update(sizes=sizes, sequences_per_step=sequences,
-                 chips=cell.chips, peaks=chip)
+                 microbatches_per_step=max(args.parallel.chunks, 1),
+                 config=cell.config, chips=cell.chips, peaks=chip)
     devices = facts.pop("devices")
     device = {"platform": devices[0].platform,
               "kind": devices[0].device_kind, "count": len(devices),

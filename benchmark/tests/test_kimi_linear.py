@@ -199,25 +199,26 @@ def test_the_kda_cost_against_a_hand_count():
 
 
 def test_the_experts_cost_counts_the_rows_held_against_a_hand_count():
-    """``kimi_experts_roofline``'s operations and bytes: four expert blocks
+    """``experts_roofline``'s operations and bytes here: four expert blocks
     over 8192 positions, a quarter of a route a position on the eight held
     of 256 (top-8)."""
-    _, sizes = _published()
-    cost = manifest.load_python(os.path.join(METRICS,
-                                             "kimi_experts_cost.py"))
+    cell, sizes = _published()
+    cost = tiny.cost_beside_the_metrics("experts_cost.py",
+                                        "experts_step_cost")
     rows = 4 * 8192 * 8 * 8 / 256
     assert rows == 8192
     matrices = 4 * 8 * 3 * 2304 * 1024 * 2
     row_bytes = rows * (2304 + 2 * 1024 + 1024 + 2304) * 2
-    assert cost.kimi_experts_step_cost(sizes, 1) == {
+    assert cost(sizes, 1, cell.config, 1) == {
         "flops": 3 * rows * 3 * 2 * 2304 * 1024,
         "bytes": 3 * (matrices + row_bytes)}
-    for name, kind in (("kimi_experts_roofline", "roofline"),
-                       ("kimi_experts_ms", "op_time")):
-        reader = manifest.read_json(manifest.layer_metric_path(
-            manifest.ROOT, name))["reader"]
-        assert (reader["kind"], reader["pattern"]) == (
-            kind, "^ragged-dot-none"), name
+    # which key is which is the configuration file's to say: this model
+    # spells the experts a token its own way
+    assert cell.config["reference"]["experts"] == {
+        "held": "num_experts", "routed": "num_routed_experts",
+        "per_token": "num_experts_per_token",
+        "width": "moe_intermediate_size",
+        "dense_blocks": "first_k_dense_replace"}
 
 
 def test_the_cells_own_entries_of_the_manifest():
@@ -232,22 +233,24 @@ def test_the_cells_own_entries_of_the_manifest():
     (entry,) = [c for c in man["configs"] if c["name"] == CONFIG]
     assert entry["reduced"] == ["num_hidden_layers", "linear_attn_config",
                                 "num_experts", "vocab_size"]
-    mine = [m for m in man["per_layer"] if m.get("workloads") == [CELL]]
-    assert [m["name"] for m in mine] == [
+    # the set it lists, the shared entries among it (PR 65): where an entry
+    # stands in the list says nothing
+    assert tiny.listed_for(man, CELL) == {
         "kimi_kda_ms", "kimi_kda_time_share_pct", "kimi_kda_mixer_ms",
-        "kimi_kda_roofline", "kimi_latent_proj_ms", "kimi_mlp_ms",
-        "kimi_experts_ms", "kimi_experts_roofline", "kimi_moe_imbalance",
-        "kimi_local_routes_pct"]
+        "kimi_kda_roofline", "kimi_moe_imbalance", "latent_proj_ms",
+        "mlp_ms", "experts_ms", "experts_time_share_pct", "experts_roofline",
+        "local_routes_pct", "scan_mosaic_calls"}
+    mine = [m for m in man["per_layer"] if CELL in m.get("workloads", ())]
     assert all(m["moves"] == "tokens_per_s" for m in mine)
     assert {m["layer"] for m in mine} == {"delta-rule blocks", "dense blocks",
-                                          "experts"}
+                                          "experts", "kernels"}
     cell = manifest.resolve_cell(man, CELL)
     names = {m["name"] for m in cell.per_layer}
     assert {"flash_roofline", "flash_fwd_ms", "flash_dq_ms", "flash_dkv_ms",
             "static_hbm_GiB", "device_idle_pct", "attn_proj_ms", "head_ms",
             "phase_recompute_ms", "scope_unnamed_pct",
             "gap_dispatch_ms"} < names
-    assert not names & {"experts_ms", "xing_experts_ms", "mlp_ms",
+    assert not names & {"moe_imbalance", "xing_hc_ms", "window_core_ms",
                         "granite_ssd_ms", "moe_route_ms"}
     assert cell.traffic["overrides"] == [
         "data.dataset=random", "parallel.mixed_precision=bf16",
@@ -324,7 +327,7 @@ def test_the_readers_on_a_synthetic_step_map(monkeypatch):
     scopes = manifest.load_python(os.path.join(METRICS, "kimi_scopes.py"))
     latent_proj_ms, mlp_ms = (
         (lambda f, name=name: readers.read_metric(name, f))
-        for name in ("kimi_latent_proj_ms", "kimi_mlp_ms"))
+        for name in ("latent_proj_ms", "mlp_ms"))
     instructions = {
         "fusion.1": ("mixer/kda/in_proj", "forward", None),
         "fusion.2": ("mixer/kda/conv", "forward", None),

@@ -23,14 +23,17 @@ CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
 METRICS = os.path.join(manifest.ROOT, "benchmark", "layer_metrics")
 REDUCED = ["num_hidden_layers", "n_routed_experts", "vocab_size",
            "media_placeholder_token_id", "tower_layers"]
-MINE = [
+# the tower's readers and the decoder's, the first nine by scope; the
+# decoder's are shared entries that list the cell since PR 65
+TOWER = [
     "kimivl_tower_ms", "kimivl_tower_share_pct", "kimivl_tower_core_ms",
     "kimivl_tower_core_roofline", "kimivl_tower_pairs_pct",
     "kimivl_tower_mlp_ms", "kimivl_merge_project_ms",
-    "kimivl_place_images_ms", "kimivl_latent_proj_ms", "kimivl_experts_ms",
-    "kimivl_experts_roofline", "kimivl_moe_imbalance",
-    "kimivl_local_routes_pct", "kimivl_mlp_ms", "kimivl_moe_dispatch_ms",
-    "kimivl_moe_combine_ms"]
+    "kimivl_place_images_ms"]
+MINE = set(TOWER) | {
+    "latent_proj_ms", "experts_ms", "experts_time_share_pct",
+    "experts_roofline", "kimivl_moe_imbalance", "local_routes_pct", "mlp_ms",
+    "moe_dispatch_ms", "moe_combine_ms"}
 GRIDS = [[64, 64], [36, 80], [32, 38]]
 
 TINY_GRIDS = [[4, 4], [2, 6], [6, 4]]
@@ -240,8 +243,9 @@ def test_the_cost_functions_against_hand_counts():
     both = flops.flash_step_cost(sizes, 1)
     decoder = 5 * 2 * 16 * (4 * 192 + 3 * 128) * flops.causal_pairs(4096)
     assert both["flops"] == core["flops"] + decoder
-    experts = load("kimivl_experts_cost.py").kimivl_experts_step_cost(
-        sizes, 1)
+    cell, _ = _published()
+    experts = tiny.cost_beside_the_metrics(
+        "experts_cost.py", "experts_step_cost")(sizes, 1, cell.config, 1)
     rows = 4 * 4096 * 6 * 8 / 64
     assert rows == 4 * 3072
     assert experts["flops"] == 3 * rows * 3 * 2 * 2048 * 1408
@@ -265,8 +269,8 @@ def test_the_cells_own_entries_of_the_manifest():
     (entry,) = [c for c in man["configs"] if c["name"] == CONFIG]
     assert entry["reduced"] == REDUCED
     assert not any(manifest.WIDTH_RE.search(k) for k in entry["reduced"])
-    mine = [m for m in man["per_layer"] if m.get("workloads") == [CELL]]
-    assert [m["name"] for m in mine][:len(MINE)] == MINE
+    assert tiny.listed_for(man, CELL) == MINE
+    mine = [m for m in man["per_layer"] if CELL in m.get("workloads", ())]
     assert {m["layer"] for m in mine} == {"image tower", "dense blocks",
                                           "experts"}
     cell = manifest.resolve_cell(man, CELL)
@@ -275,7 +279,8 @@ def test_the_cells_own_entries_of_the_manifest():
     assert {m["name"] for m in man["per_layer"]
             if "workloads" not in m} <= names
     assert {"flash_roofline", "flash_time_share_pct"} <= names
-    assert not names & {"experts_ms", "kimi_experts_ms", "mlp_ms"}
+    assert not names & {"moe_imbalance", "kimi_kda_ms", "moe_route_ms",
+                        "window_core_ms"}
     assert cell.traffic["overrides"] == [
         "data.dataset=random", "parallel.mixed_precision=bf16",
         "parallel.global_checkpoint=1", "parallel.global_train_batch_size=1",
@@ -368,7 +373,7 @@ def test_the_readers_on_a_synthetic_step_map(monkeypatch):
     assert read("kimivl_tower_mlp_ms")(facts) == 4.0
     assert read("kimivl_merge_project_ms")(facts) == 1.0
     assert read("kimivl_place_images_ms")(facts) == 0.5
-    assert read("kimivl_latent_proj_ms")(facts) == 2.0
+    assert read("latent_proj_ms")(facts) == 2.0
     cost = manifest.load_python(os.path.join(
         METRICS, "kimivl_tower_cost.py")).kimivl_tower_step_cost(sizes, 1)
     assert read("kimivl_tower_core_roofline")(facts) == pytest.approx(
@@ -384,7 +389,7 @@ def test_the_readers_on_a_synthetic_step_map(monkeypatch):
         "inferred": [], "tails": {}}}
     monkeypatch.setattr(trace_analysis, "step_scopes", lambda: plain)
     facts.pop("step_map_join")
-    for name in MINE[:4] + MINE[5:9]:
+    for name in TOWER[:4] + TOWER[5:] + ["latent_proj_ms"]:
         assert read(name)(facts) is None, name
     monkeypatch.setattr(trace_analysis, "step_scopes", lambda: {})
     assert scopes.tower_ms(_facts([], [], 0.0, sizes)) is None
@@ -428,6 +433,6 @@ def test_every_file_the_benchmark_had_is_as_it_was():
     now = manifest.load_manifest()
     assert now["configs"][len(was["configs"])]["name"] == CONFIG
     assert now["workloads"][len(was["workloads"])]["name"] == CELL
-    assert [m["name"] for m in now["per_layer"][len(was["per_layer"]):]][
-        :len(MINE)] == MINE
-    assert (len(now["configs"]), len(now["workloads"])) == (11, 12)
+    assert MINE <= tiny.listed_for(now, CELL)
+    # the eleventh configuration and the twelfth cell; PR 61 came after
+    assert (len(was["configs"]), len(was["workloads"])) == (10, 11)

@@ -173,17 +173,17 @@ def test_the_family_adds_its_blocks_up_against_a_hand_count():
 
 
 def test_the_window_cost_counts_the_band_against_a_hand_count():
-    """``laguna_window_roofline``'s operations and bytes: three blocks of
+    """``window_roofline``'s operations and bytes here: three blocks of
     72 heads over the band of 512, seven matmuls, q, k, v, o three times."""
     _, sizes = _published()
-    cost = manifest.load_python(os.path.join(METRICS,
-                                             "laguna_window_cost.py"))
+    cost = manifest.load_python(os.path.join(
+        METRICS, "window_cost.py")).window_step_cost
     band = 4_063_488
     io = 8192 * (72 * 128 + 2 * 8 * 128 + 72 * 128)
-    got = cost.laguna_window_step_cost(sizes, 1)
+    got = cost(sizes, 1)
     assert got == {"flops": 3 * 2 * 72 * 7 * 128 * band,
                    "bytes": 3 * (3 * io * 2 + 2 * 8192 * 72 * 4)}
-    assert cost.laguna_window_step_cost(sizes, 2)["flops"] == 2 * got["flops"]
+    assert cost(sizes, 2)["flops"] == 2 * got["flops"]
     # compute-bound on a v5e: 8.0 ms for the three blocks
     least = flops.roofline_least_s(
         got, {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9})
@@ -191,29 +191,29 @@ def test_the_window_cost_counts_the_band_against_a_hand_count():
     assert round(1e3 * least["least_s"], 1) == 8.0
     # a model without a window block costs nothing here
     from dataclasses import replace
-    assert cost.laguna_window_step_cost(
-        replace(sizes, attention=None), 1) == {"flops": 0.0, "bytes": 0}
+    assert cost(replace(sizes, attention=None), 1) == {"flops": 0.0,
+                                                       "bytes": 0}
 
 
 def test_the_experts_cost_counts_the_rows_held_against_a_hand_count():
-    """``laguna_experts_roofline``'s operations and bytes: four expert
+    """``experts_roofline``'s operations and bytes here: four expert
     blocks over 8192 positions, 10 x 8 / 256 of a route a position."""
-    _, sizes = _published()
-    cost = manifest.load_python(os.path.join(METRICS,
-                                             "laguna_experts_cost.py"))
+    cell, sizes = _published()
+    cost = tiny.cost_beside_the_metrics("experts_cost.py",
+                                        "experts_step_cost")
     rows = 4 * 8192 * 10 * 8 / 256
     assert rows == 4 * 2560
     matrices = 4 * 8 * 3 * 3072 * 1024 * 2
     row_bytes = rows * (3072 + 2 * 1024 + 1024 + 3072) * 2
-    assert cost.laguna_experts_step_cost(sizes, 1) == {
+    assert cost(sizes, 1, cell.config, 1) == {
         "flops": 3 * rows * 3 * 2 * 3072 * 1024,
         "bytes": 3 * (matrices + row_bytes)}
-    for name, kind in (("laguna_experts_roofline", "roofline"),
-                       ("laguna_experts_ms", "op_time")):
-        reader = manifest.read_json(manifest.layer_metric_path(
-            manifest.ROOT, name))["reader"]
-        assert (reader["kind"], reader["pattern"]) == (
-            kind, "^ragged-dot-none"), name
+    # which key is which is the configuration file's to say: this model
+    # lists its dense blocks
+    assert cell.config["reference"]["experts"] == {
+        "held": "num_experts", "routed": "num_routed_experts",
+        "per_token": "num_experts_per_tok", "width": "moe_intermediate_size",
+        "dense_blocks": "mlp_only_layers"}
 
 
 def test_the_cells_own_entries_of_the_manifest():
@@ -231,14 +231,15 @@ def test_the_cells_own_entries_of_the_manifest():
         "gating_types", "num_attention_heads_per_layer", "num_experts",
         "vocab_size"]
     assert not any(manifest.WIDTH_RE.search(k) for k in entry["reduced"])
-    mine = [m for m in man["per_layer"] if m.get("workloads") == [CELL]]
-    assert [m["name"] for m in mine] == [
-        "laguna_window_core_ms", "laguna_window_roofline",
-        "laguna_full_core_ms", "laguna_gate_ms", "laguna_band_tiles_pct",
-        "laguna_mlp_ms", "laguna_experts_ms", "laguna_experts_roofline",
-        "laguna_moe_route_ms", "laguna_moe_dispatch_ms",
-        "laguna_moe_combine_ms", "laguna_moe_imbalance",
-        "laguna_local_routes_pct"]
+    # the set it lists, the shared entries among it (PR 65): where an entry
+    # stands in the list says nothing
+    assert tiny.listed_for(man, CELL) == {
+        "laguna_gate_ms", "laguna_band_tiles_pct", "laguna_moe_imbalance",
+        "window_core_ms", "window_roofline", "full_core_ms", "mlp_ms",
+        "experts_ms", "experts_time_share_pct", "experts_roofline",
+        "moe_route_ms", "moe_dispatch_ms", "moe_combine_ms",
+        "local_routes_pct"}
+    mine = [m for m in man["per_layer"] if CELL in m.get("workloads", ())]
     assert all(m["moves"] == "tokens_per_s" for m in mine)
     assert {m["layer"] for m in mine} == {"kernels", "dense blocks",
                                           "experts"}
@@ -252,8 +253,8 @@ def test_the_cells_own_entries_of_the_manifest():
     # every metric that names no cells is the new cell's too
     assert {m["name"] for m in man["per_layer"]
             if "workloads" not in m} <= names
-    assert not names & {"experts_ms", "kimi_experts_ms", "mlp_ms",
-                        "granite_ssd_ms", "moe_route_ms"}
+    assert not names & {"moe_imbalance", "kimi_kda_ms", "latent_proj_ms",
+                        "granite_ssd_ms", "scan_mosaic_calls"}
     assert cell.traffic["overrides"] == [
         "data.dataset=random", "parallel.mixed_precision=bf16",
         "parallel.global_checkpoint=1",
@@ -321,9 +322,10 @@ def test_the_readers_on_a_synthetic_step_map(monkeypatch):
     from hetu_galvatron_tpu.observability.registry import get_registry
 
     scopes = manifest.load_python(os.path.join(METRICS, "laguna_scopes.py"))
-    mlp_ms, route_ms = (
+    mlp_ms, route_ms, window_core_ms, full_core_ms, window_roofline = (
         (lambda f, name=name: readers.read_metric(name, f))
-        for name in ("laguna_mlp_ms", "laguna_moe_route_ms"))
+        for name in ("mlp_ms", "moe_route_ms", "window_core_ms",
+                     "full_core_ms", "window_roofline"))
     instructions = {
         "flash_attention_fwd.1": ("attn/window_core", "forward", None),
         "flash_attention_bwd_dq.2": ("attn/window_core", "backward", None),
@@ -345,14 +347,14 @@ def test_the_readers_on_a_synthetic_step_map(monkeypatch):
     facts = {**_facts(step(0) + step(30 * ms),
                       [(0, 27 * ms), (30 * ms, 57 * ms)], busy_s=0.054),
              "sizes": sizes}
-    assert scopes.window_core_ms(facts) == 16.0
-    assert scopes.full_core_ms(facts) == 5.0
+    assert window_core_ms(facts) == 16.0
+    assert full_core_ms(facts) == 5.0
     assert scopes.gate_ms(facts) == 1.0
     assert mlp_ms(facts) == 3.0 and route_ms(facts) == 2.0
     # 8.0 ms by the roofline over the 16 measured
-    assert scopes.window_roofline(facts) == pytest.approx(
+    assert window_roofline(facts) == pytest.approx(
         100 * 7.984 / 16, rel=1e-3)
-    assert facts["roofline_bounds"] == {"laguna_window_step_cost": "compute"}
+    assert facts["roofline_bounds"] == {"window_step_cost": "compute"}
     # the gauge: nothing where the program wrote none, its value where it did
     assert scopes.band_tiles_pct(facts) is None or isinstance(
         scopes.band_tiles_pct(facts), float)
@@ -365,12 +367,12 @@ def test_the_readers_on_a_synthetic_step_map(monkeypatch):
         "inferred": [], "tails": {}}}
     monkeypatch.setattr(trace_analysis, "step_scopes", lambda: plain)
     facts.pop("step_map_join")
-    assert scopes.window_core_ms(facts) is None
-    assert scopes.window_roofline(facts) is None
+    assert window_core_ms(facts) is None
+    assert window_roofline(facts) is None
     assert scopes.gate_ms(facts) is None
-    assert scopes.full_core_ms(facts) == 27.0
+    assert full_core_ms(facts) == 27.0
     monkeypatch.setattr(trace_analysis, "step_scopes", lambda: {})
-    assert scopes.window_core_ms(_facts([], [], 0.0)) is None
+    assert window_core_ms(_facts([], [], 0.0)) is None
     assert scopes.gate_ms({}) is None and mlp_ms({}) is None
 
 

@@ -77,7 +77,7 @@ def test_a_tiny_lfm2_runs_and_meets_its_reference(tmp_path):
     assert line["correct"] is True, checks
     assert report["attention_cores"].count("short_conv") == 4
     # the tracker's gauges are what the cell's two counter metrics read
-    share = readers.read_metric("lfm2_local_routes_pct", {}, root)
+    share = readers.read_metric("local_routes_pct", {}, root)
     assert 30.0 < share < 70.0          # 4 of 8 held: half of the routes
     assert readers.read_metric("lfm2_moe_imbalance", {}, root) >= 1.0
     # one attending block of five, in the FLOPs the run was scored by
@@ -140,17 +140,18 @@ def test_the_manifest_holds_six_cells_and_the_new_metrics_are_the_cell_s():
     assert [w["name"] for w in man["workloads"] if w["chips"] == 4][:1] == [
         "mistral7b_c4_tp2dp2z3"]
     assert man["workloads"][5]["name"] == CELL
-    mine = [m for m in man["per_layer"] if m.get("workloads") == [CELL]]
-    assert [m["name"] for m in mine][:5] == [
-        "lfm2_experts_ms", "lfm2_experts_time_share_pct",
-        "lfm2_experts_roofline", "lfm2_local_routes_pct",
-        "lfm2_moe_imbalance"]
+    # what the cell came with, under the names PR 65 left (the shared
+    # entries list it), and what later PRs read of its trace beside them
+    new = {"experts_ms", "experts_time_share_pct", "experts_roofline",
+           "local_routes_pct", "lfm2_moe_imbalance"}
+    assert new <= tiny.listed_for(man, CELL)
     assert all(m["moves"] == "tokens_per_s" and m["layer"] == "experts"
-               for m in mine[:5])
+               for m in man["per_layer"] if m["name"] in new)
     cell = manifest.resolve_cell(man, CELL)
     names = {m["name"] for m in cell.per_layer}
     assert {"flash_roofline", "static_hbm_GiB", "device_idle_pct"} < names
-    assert not names & {"experts_ms", "moe_imbalance"}
+    # the first expert layer's balance is read behind a dense block here
+    assert "moe_imbalance" not in names
     body = cell.config
     assert sorted(body["reduced_from"]) == sorted(
         man["configs"][4]["reduced"])
@@ -162,9 +163,8 @@ def test_the_manifest_holds_six_cells_and_the_new_metrics_are_the_cell_s():
 
 def test_the_experts_cost_is_the_expected_share_of_the_rows():
     config, sizes = _published(5)
-    cost = manifest.load_python(os.path.join(
-        manifest.ROOT, "benchmark", "layer_metrics", "lfm2_experts_cost.py"))
-    need = cost.lfm2_experts_step_cost(sizes, 2)
+    need = tiny.cost_beside_the_metrics(
+        "experts_cost.py", "experts_step_cost")(sizes, 2, config, 2)
     rows = 8192 * 4 * 8 // 64          # 4096 a microbatch
     assert need["flops"] == 4 * 2 * 3 * rows * 3 * 2 * 2048 * 1536
     assert need["bytes"] == 4 * 2 * 3 * (

@@ -128,7 +128,17 @@ def _roofline_cost_nowhere(root):
         json.dump(body, f)
 
 
+def _roofline_of_nothing(root):
+    path = manifest.layer_metric_path(root, "router_roofline")
+    body = manifest.read_json(path)
+    body["reader"]["scopes"] = ["moe/route"]      # beside its pattern
+    with open(path, "w") as f:
+        json.dump(body, f)
+
+
 @pytest.mark.parametrize("how,says", [
+    (_roofline_of_nothing, "router_roofline: a roofline reader names a "
+                           "pattern or scopes, one of the two"),
     (_unknown_family, "reference family 'never_written' has no file "
                       "benchmark/reference/never_written.py"),
     (lambda root: os.remove(os.path.join(

@@ -30,14 +30,15 @@ ROPE = {
         "original_max_position_embeddings": 8, "beta_fast": 32,
         "beta_slow": 1, "attention_factor": 1.2},
     "sliding_attention": {"rope_type": "default", "rope_theta": 100.0}}
-MINE = [
+# what the cell came with, under the names PR 65 left: its own entries and
+# the shared ones that list it (a set: where an entry stands says nothing)
+MINE = {
     "mellum_exchange_ms", "mellum_exchange_exposed_pct",
-    "mellum_collective_all_ms", "mellum_collective_all_exposed_pct",
-    "mellum_experts_ms", "mellum_experts_roofline", "mellum_moe_route_ms",
-    "mellum_moe_dispatch_ms", "mellum_moe_combine_ms",
-    "mellum_chip_imbalance", "mellum_moe_imbalance",
-    "mellum_window_core_ms", "mellum_window_roofline",
-    "mellum_full_core_ms"]
+    "collective_all_ms", "collective_all_exposed_pct",
+    "experts_ms", "experts_roofline", "moe_route_ms",
+    "moe_dispatch_ms", "moe_combine_ms",
+    "mellum_chip_imbalance", "moe_imbalance",
+    "window_core_ms", "window_roofline", "full_core_ms"}
 
 TINY_MELLUM = {
     "model_type": "mellum", "hidden_size": 32, "intermediate_size": 48,
@@ -230,44 +231,44 @@ def test_the_parameter_counts():
 
 def test_the_window_cost_counts_the_band_against_a_hand_count():
     _, sizes = _published()
-    cost = manifest.load_python(os.path.join(METRICS,
-                                             "mellum_window_cost.py"))
+    cost = manifest.load_python(os.path.join(
+        METRICS, "window_cost.py")).window_step_cost
     band = 1024 * 1025 // 2 + (4096 - 1024) * 1024
     io = 4096 * (32 * 128 + 2 * 4 * 128 + 32 * 128)
-    got = cost.mellum_window_step_cost(sizes, 1)
+    got = cost(sizes, 1)
     assert got == {"flops": 3 * 2 * 32 * 7 * 128 * band,
                    "bytes": 3 * (3 * io * 2 + 2 * 4096 * 32 * 4)}
-    assert cost.mellum_window_step_cost(sizes, 4)["flops"] == 4 * got["flops"]
+    assert cost(sizes, 4)["flops"] == 4 * got["flops"]
     from dataclasses import replace
-    assert cost.mellum_window_step_cost(
-        replace(sizes, attention=None), 1) == {"flops": 0.0, "bytes": 0}
+    assert cost(replace(sizes, attention=None), 1) == {"flops": 0.0,
+                                                       "bytes": 0}
 
 
 def test_the_experts_cost_counts_the_groups_rows_against_a_hand_count():
-    """``mellum_experts_roofline``'s operations and bytes: four blocks over
+    """``experts_roofline``'s operations and bytes here: four blocks over
     the group's 4 x 4096 positions at 8 routes each, 32768 rows a chip and
     block; every expert's matrices once a pass over the group."""
-    _, sizes = _published()
-    cost = manifest.load_python(os.path.join(METRICS,
-                                             "mellum_experts_cost.py"))
+    cell, sizes = _published()
+    cost = tiny.cost_beside_the_metrics("experts_cost.py",
+                                        "experts_step_cost")
     rows = 4 * 4 * 4096 * 8
     assert rows / 4 / 4 == 32768 and rows / 4 / 64 == 2048
     matrices = 4 * 64 * 3 * 2304 * 896 * 2
     row_bytes = rows * (2304 + 2 * 896 + 896 + 2304) * 2
-    got = cost.mellum_experts_step_cost(sizes, 4)
+    # four sequences in ONE microbatch: every matrix once a pass
+    got = cost(sizes, 4, cell.config, 1)
     assert got == {"flops": 3 * rows * 3 * 2 * 2304 * 896,
                    "bytes": 3 * (matrices + row_bytes)}
+    assert cost(sizes, 4, cell.config, 4)["bytes"] == 3 * (
+        4 * matrices + row_bytes)
+    assert cell.config["reference"]["experts"] == {
+        "held": "num_experts", "per_token": "num_experts_per_tok",
+        "width": "moe_intermediate_size"}
     # over four chips at the peak: 24.7 ms a step, compute-bound
     least = flops.roofline_least_s(
         got, {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}, 4)
     assert least["bound"] == "compute"
     assert round(1e3 * least["least_s"], 1) == 24.7
-    for name, kind in (("mellum_experts_roofline", "roofline"),
-                       ("mellum_experts_ms", "op_time")):
-        reader = manifest.read_json(manifest.layer_metric_path(
-            manifest.ROOT, name))["reader"]
-        assert (reader["kind"], reader["pattern"]) == (
-            kind, "^ragged-dot-none"), name
 
 
 def test_the_cells_own_entries_of_the_manifest():
@@ -284,20 +285,19 @@ def test_the_cells_own_entries_of_the_manifest():
     assert entry["reduced"] == ["num_hidden_layers", "layer_types",
                                 "mlp_layer_types"]
     assert not any(manifest.WIDTH_RE.search(k) for k in entry["reduced"])
-    # the cell's own metrics begin with those it came with; later PRs read
-    # more of its trace
-    mine = [m for m in man["per_layer"] if m.get("workloads") == [CELL]]
-    assert [m["name"] for m in mine][:len(MINE)] == MINE
+    # the cell lists what it came with; later PRs read more of its trace
+    assert MINE <= tiny.listed_for(man, CELL)
+    mine = [m for m in man["per_layer"] if CELL in m.get("workloads", ())]
     assert all(m["moves"] == "tokens_per_s" for m in mine)
     assert {m["layer"] for m in mine} == {"collectives", "experts",
-                                          "kernels"}
+                                          "kernels", "step program"}
     cell = manifest.resolve_cell(man, CELL)
     names = {m["name"] for m in cell.per_layer}
     # every metric that names no cells is the new cell's too
     assert {m["name"] for m in man["per_layer"]
             if "workloads" not in m} <= names
-    assert not names & {"experts_ms", "laguna_experts_ms", "mlp_ms",
-                        "moe_route_ms", "collective_all_ms"}
+    assert not names & {"laguna_gate_ms", "mlp_ms", "local_routes_pct",
+                        "latent_proj_ms", "collective_overlapped_ms"}
     assert cell.traffic["overrides"] == [
         "data.dataset=random", "parallel.mixed_precision=bf16",
         "parallel.global_checkpoint=1", "parallel.global_ep_deg=4",
@@ -393,27 +393,25 @@ def test_the_readers_on_a_synthetic_step_map(monkeypatch):
     # 5 of the 6 ms a step with nothing beside them, over 27 ms busy a step
     assert scopes.exchange_exposed_pct(facts) == pytest.approx(
         100 * 2 * 5 / 54)
-    assert read("mellum_collective_all_ms")(facts) == 9.0
-    assert read("mellum_collective_all_exposed_pct")(facts) == \
+    assert read("collective_all_ms")(facts) == 9.0
+    assert read("collective_all_exposed_pct")(facts) == \
         pytest.approx(100 * 2 * 8 / 54)
-    assert scopes.window_core_ms(facts) == 8.0
-    assert scopes.full_core_ms(facts) == 5.0
-    assert read("mellum_moe_route_ms")(facts) == 1.0
-    assert read("mellum_moe_dispatch_ms")(facts) == 3.0
-    assert read("mellum_moe_combine_ms")(facts) == 2.0
+    assert read("window_core_ms")(facts) == 8.0
+    assert read("full_core_ms")(facts) == 5.0
+    assert read("moe_route_ms")(facts) == 1.0
+    assert read("moe_dispatch_ms")(facts) == 3.0
+    assert read("moe_combine_ms")(facts) == 2.0
     cost = manifest.load_python(os.path.join(
-        METRICS, "mellum_window_cost.py")).mellum_window_step_cost(sizes, 4)
+        METRICS, "window_cost.py")).window_step_cost(sizes, 4)
     least = cost["flops"] / (4 * 197e12)
-    assert scopes.window_roofline(facts) == pytest.approx(
+    assert read("window_roofline")(facts) == pytest.approx(
         100 * least / 8e-3)
-    assert facts["roofline_bounds"] == {"mellum_window_step_cost": "compute"}
+    assert facts["roofline_bounds"] == {"window_step_cost": "compute"}
     # the gauges: nothing where the program wrote none
-    if scopes.chip_imbalance(facts) is None:
-        assert scopes.moe_imbalance(facts) is None
     get_registry().gauge("moe/imbalance", layer="layer0").set(1.17)
     get_registry().gauge("moe/chip_imbalance", layer="layer0").set(1.02)
     assert scopes.chip_imbalance(facts) == 1.02
-    assert scopes.moe_imbalance(facts) == 1.17
+    assert read("moe_imbalance")(facts) == 1.17
     # the parent: no moe/exchange scope, no window scope in its map
     plain = {"map": {"instructions": {
         n: ("attn/core", p, None) for n, (_, p, _) in instructions.items()},
@@ -422,13 +420,13 @@ def test_the_readers_on_a_synthetic_step_map(monkeypatch):
     facts.pop("step_map_join")
     assert scopes.exchange_ms(facts) is None
     assert scopes.exchange_exposed_pct(facts) is None
-    assert scopes.window_core_ms(facts) is None
-    assert scopes.window_roofline(facts) is None
-    assert read("mellum_collective_all_ms")(facts) is None
+    assert read("window_core_ms")(facts) is None
+    assert read("window_roofline")(facts) is None
+    assert read("collective_all_ms")(facts) is None
     monkeypatch.setattr(trace_analysis, "step_scopes", lambda: {})
     assert scopes.exchange_ms(_facts([], [], 0.0)) is None
     assert scopes.exchange_exposed_pct({}) is None
-    assert scopes.full_core_ms({}) is None
+    assert read("full_core_ms")({}) is None
 
 
 def test_every_file_the_benchmark_had_is_as_it_was():
@@ -443,5 +441,4 @@ def test_every_file_the_benchmark_had_is_as_it_was():
     # what came first after it is this PR's; later PRs add after these
     assert now["configs"][len(was["configs"])]["name"] == CONFIG
     assert now["workloads"][len(was["workloads"])]["name"] == CELL
-    assert [m["name"] for m in now["per_layer"][len(was["per_layer"]):]][
-        :len(MINE)] == MINE
+    assert MINE <= tiny.listed_for(now, CELL)

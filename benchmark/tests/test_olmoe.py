@@ -140,7 +140,10 @@ def test_the_manifest_holds_five_cells_and_the_expert_metrics_are_the_cell_s():
     assert man["workloads"][4] == {
         "name": CELL, "config": "olmoe-1b-7b-d1", "traffic": "c1_s4k",
         "chips": 1, "why": man["workloads"][4]["why"]}
-    mine = [m for m in man["per_layer"] if m.get("workloads") == [CELL]]
+    # the cell's expert metrics are the shared entries' since PR 65: the
+    # first cell each of them lists
+    mine = [m for m in man["per_layer"]
+            if (m.get("workloads") or [None])[0] == CELL]
     assert {m["name"] for m in mine} >= {
         "experts_ms", "experts_time_share_pct", "experts_roofline",
         "moe_imbalance"}
@@ -161,10 +164,9 @@ def test_the_manifest_holds_five_cells_and_the_expert_metrics_are_the_cell_s():
 
 
 def test_the_experts_cost_is_three_passes_of_rows_through_three_matrices():
-    _, sizes = _published_sizes()
-    cost = manifest.load_python(os.path.join(
-        manifest.ROOT, "benchmark", "layer_metrics", "experts_cost.py"))
-    need = cost.experts_step_cost(sizes, 4)
+    cell, sizes = _published_sizes()
+    need = tiny.cost_beside_the_metrics(
+        "experts_cost.py", "experts_step_cost")(sizes, 4, cell.config, 4)
     rows = 4 * 4096 * 8
     assert need["flops"] == 3 * rows * 3 * 2 * 2048 * 1024
     assert need["bytes"] == 3 * (4 * 64 * 3 * 2048 * 1024 * 2
@@ -176,8 +178,8 @@ def test_the_experts_cost_is_three_passes_of_rows_through_three_matrices():
 
 def test_on_a_trace_without_experts_the_readers_say_so_and_do_not_raise(
         tmp_path):
-    """``mistral7b_c1_s4k``'s recorded trace has no grouped matmul: the time
-    readers give 0, the roofline nothing (no time to divide by)."""
+    """``mistral7b_c1_s4k``'s recorded trace has no grouped matmul and no
+    map of its step to join: the readers of the scope say nothing."""
     path = str(tmp_path / "t.xplane.pb")
     with gzip.open(os.path.join(
             HERE, "mistral7b_c1_s4k.seed1.xplane.pb.gz")) as src, \
@@ -187,7 +189,7 @@ def test_on_a_trace_without_experts_the_readers_say_so_and_do_not_raise(
     facts = {"trace": xplane.facts_of(path, chips=1), "sizes": sizes,
              "sequences_per_step": 4, "chips": 1,
              "peaks": peaks.peaks_of("TPU v5 lite")}
-    assert readers.read_metric("experts_ms", facts) == 0.0
-    assert readers.read_metric("experts_time_share_pct", facts) == 0.0
+    assert readers.read_metric("experts_ms", facts) is None
+    assert readers.read_metric("experts_time_share_pct", facts) is None
     assert readers.read_metric("experts_roofline", facts) is None
     assert readers.read_metric("experts_ms", {}) is None

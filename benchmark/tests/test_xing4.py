@@ -14,7 +14,7 @@ from types import SimpleNamespace
 
 import pytest
 
-from benchmark import flops, manifest, reference, run
+from benchmark import flops, manifest, readers, reference, run
 from benchmark.tests import tiny
 
 CELL, CONFIG = "xing4_c1_b1_s4k", "xing4.0-29b-a4b-ep8"
@@ -179,38 +179,34 @@ def test_the_family_adds_its_blocks_up_against_a_hand_count():
 
 
 def test_the_experts_cost_counts_the_rows_held_against_a_hand_count():
-    """``xing_experts_roofline``'s operations and bytes: four expert blocks
+    """``experts_roofline``'s operations and bytes here: four expert blocks
     of the stack over 4096 positions and the further depth's over 4095, a
     half of a route a position on the eight held of 64 (top-4)."""
-    _, sizes = _published()
-    cost = manifest.load_python(os.path.join(
-        manifest.ROOT, "benchmark", "layer_metrics", "xing_experts_cost.py"))
+    cell, sizes = _published()
+    cost = tiny.cost_beside_the_metrics("experts_cost.py",
+                                        "experts_step_cost")
     rows = (4 * 4096 + 4095) * 4 * 8 / 64
     assert rows == 10239.5
     matrices = 5 * 8 * 3 * 3584 * 1024 * 2          # bf16, every held expert
     row_bytes = rows * (3584 + 2 * 1024 + 1024 + 3584) * 2
-    assert cost.xing_experts_step_cost(sizes, 1) == {
+    assert cost(sizes, 1, cell.config, 1) == {
         "flops": 3 * rows * 3 * 2 * 3584 * 1024,
         "bytes": 3 * (matrices + row_bytes)}
-    two = cost.xing_experts_step_cost(sizes, 2)
+    two = cost(sizes, 2, cell.config, 2)
     assert two["flops"] == 2 * 3 * rows * 3 * 2 * 3584 * 1024
+    assert two["bytes"] == 2 * 3 * (matrices + row_bytes)
     # the family's FLOP count takes the same share of the routes
     assert 3 * rows * 3 * 2 * 3584 * 1024 == pytest.approx(
         3 * (4 * 4096 + 4095) * (4 * 8 / 64) * 2 * 3 * 3584 * 1024)
-    man = manifest.load_manifest()
-    for name, kind in (("xing_experts_roofline", "roofline"),
-                       ("xing_experts_time_share_pct", "op_time"),
-                       ("xing_experts_ms", "op_time")):
-        reader = manifest.read_json(manifest.layer_metric_path(
-            manifest.ROOT, name))["reader"]
-        assert (reader["kind"], reader["pattern"]) == (
-            kind, "^ragged-dot-none"), name
-    reader = manifest.read_json(manifest.layer_metric_path(
-        manifest.ROOT, "xing_local_routes_pct"))["reader"]
-    assert reader == {"kind": "python", "file": "lfm2_gauges.py",
-                      "function": "local_routes_pct"}
-    assert any(m["name"] == "xing_local_routes_pct"
-               and m["source"] == "program_counter" for m in man["per_layer"])
+    # which key is which is the configuration file's to say
+    assert cell.config["reference"]["experts"] == {
+        "held": "n_routed_experts", "routed": "num_routed_experts",
+        "per_token": "num_experts_per_tok", "width": "moe_intermediate_size",
+        "dense_blocks": "first_k_dense_replace",
+        "further_depths": "num_nextn_predict_layers"}
+    assert any(m["name"] == "local_routes_pct" and CELL in m["workloads"]
+               and m["source"] == "program_counter"
+               for m in manifest.load_manifest()["per_layer"])
 
 
 def test_the_fall_back_describes_five_cores():
@@ -233,21 +229,23 @@ def test_the_cells_own_entries_of_the_manifest():
     (entry,) = [c for c in man["configs"] if c["name"] == CONFIG]
     assert entry["reduced"] == ["num_hidden_layers", "first_k_dense_replace",
                                 "n_routed_experts", "vocab_size"]
-    mine = [m for m in man["per_layer"] if m.get("workloads") == [CELL]]
-    assert [m["name"] for m in mine] == [
-        "xing_latent_proj_ms", "xing_hc_ms", "xing_hc_time_share_pct",
-        "xing_mtp_ms", "xing_experts_ms", "xing_moe_imbalance",
-        "xing_experts_roofline", "xing_experts_time_share_pct",
-        "xing_local_routes_pct"]
-    assert all(m["moves"] == "tokens_per_s" for m in mine)
+    # the set it lists, the shared entries among it (PR 65): where an entry
+    # stands in the list says nothing
+    assert tiny.listed_for(man, CELL) == {
+        "xing_hc_ms", "xing_hc_time_share_pct", "xing_mtp_ms",
+        "xing_moe_imbalance", "latent_proj_ms", "mlp_ms", "experts_ms",
+        "experts_time_share_pct", "experts_roofline", "local_routes_pct",
+        "moe_route_ms", "moe_dispatch_ms", "moe_combine_ms"}
+    assert all(m["moves"] == "tokens_per_s" for m in man["per_layer"]
+               if CELL in m.get("workloads", ()))
     cell = manifest.resolve_cell(man, CELL)
     names = {m["name"] for m in cell.per_layer}
     assert {"flash_roofline", "flash_fwd_ms", "flash_dq_ms", "flash_dkv_ms",
             "static_hbm_GiB", "device_idle_pct", "attn_proj_ms", "head_ms",
             "mlp_ms", "moe_route_ms", "moe_dispatch_ms",
             "moe_combine_ms", "scope_unnamed_pct"} < names
-    assert not names & {"experts_ms", "lfm2_experts_ms", "collective_ms",
-                        "granite_ssd_ms"}
+    assert not names & {"moe_imbalance", "collective_all_ms",
+                        "granite_ssd_ms", "window_core_ms"}
     assert cell.traffic["overrides"] == [
         "data.dataset=random", "parallel.mixed_precision=bf16",
         "parallel.global_checkpoint=1",
@@ -321,7 +319,7 @@ def test_the_readers_on_a_synthetic_step_map(monkeypatch):
                        ("fusion.5", t0 + 12 * ms, t0 + 13 * ms)]
     facts = _facts(step(0) + step(20 * ms),
                    [(0, 13 * ms), (20 * ms, 33 * ms)], busy_s=0.026)
-    assert scopes.latent_proj_ms(facts) == 3.0
+    assert readers.read_metric("latent_proj_ms", facts) == 3.0
     assert scopes.hc_ms(facts) == 2.0 + 4.0 + 1.0
     assert scopes.hc_time_share_pct(facts) == pytest.approx(100 * 7 / 13)
     assert scopes.mtp_ms(facts) == 1.0 + 2.0 + 1.0
@@ -334,7 +332,8 @@ def test_the_readers_on_a_synthetic_step_map(monkeypatch):
     assert scopes.hc_ms(facts) == 7.0
     monkeypatch.setattr(trace_analysis, "step_scopes", lambda: {})
     assert scopes.hc_ms(_facts([], [], 0.0)) is None
-    assert scopes.mtp_ms({}) is None and scopes.latent_proj_ms({}) is None
+    assert scopes.mtp_ms({}) is None
+    assert readers.read_metric("latent_proj_ms", {}) is None
 
 
 def test_every_file_the_benchmark_had_is_as_it_was():

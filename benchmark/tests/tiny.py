@@ -7,6 +7,7 @@ import os
 import shutil
 
 from benchmark import manifest
+from benchmark.tests import pr65
 
 YAMLS = os.path.join(manifest.ROOT, "hetu_galvatron_tpu", "models", "configs")
 
@@ -190,7 +191,9 @@ NOT_DATA = ("benchmark/reference/__init__.py",)
 def data_files_as_they_were_at(commit: str, least: int):
     """Against ``commit``, where git and the commit are at hand (else the
     test is skipped): every data file it has under ``benchmark/`` is here
-    byte for byte, of ``least`` files or more under ``benchmark/`` in all.
+    byte for byte, of ``least`` files or more under ``benchmark/`` in all,
+    but those a ``benchmark`` PR has since rewritten or taken away
+    (``pr65.REWRITTEN``, ``pr65.RETIRED_FILES``: only such a PR may).
     Returns its ``BENCHMARK.json``."""
     import subprocess
 
@@ -207,18 +210,63 @@ def data_files_as_they_were_at(commit: str, least: int):
         pytest.skip("no git, or the commit is not in this checkout")
     assert len(had) > least
     for rel in had:
+        if rel in pr65.REWRITTEN or rel in pr65.RETIRED_FILES:
+            continue
         if rel.split("/")[1] in DATA_DIRS and rel not in NOT_DATA:
             with open(os.path.join(manifest.ROOT, rel), "rb") as f:
                 assert f.read() == git("show", f"{commit}:{rel}"), rel
     return was
 
 
+def listed_for(man, cell: str):
+    """The per-layer metrics whose ``workloads`` name ``cell``: what a cell
+    lists beside the metrics of every cell. A set: where an entry stands in
+    the list says nothing, and a shared entry is several cells'."""
+    return {m["name"] for m in man["per_layer"]
+            if cell in m.get("workloads", ())}
+
+
+def cost_beside_the_metrics(file: str, function: str):
+    """A cost function of ``benchmark/layer_metrics/<file>``, called as the
+    roofline reader calls it: ``cost(sizes, sequences, config=,
+    microbatches=)``, of which the function is handed what it names."""
+    from benchmark import readers
+
+    fn = getattr(manifest.load_python(os.path.join(
+        manifest.ROOT, "benchmark", "layer_metrics", file)), function)
+    return lambda sizes, sequences, config=None, microbatches=None: \
+        readers.cost_of(fn, {"sizes": sizes, "sequences_per_step": sequences,
+                             "config": config,
+                             "microbatches_per_step": microbatches})
+
+
 def assert_the_manifest_begins_with(was) -> None:
-    """``BENCHMARK.json`` still begins with what it held: every list of
-    entries with the entries it had, every other key as it was."""
+    """``BENCHMARK.json`` still begins with what it held: the lists of
+    configurations and cells with the entries they had, every other key as
+    it was. Of the metrics, which only a ``benchmark`` PR may change: an
+    end-to-end metric is as it was but for its bound (PR 58 widened two);
+    a per-layer metric that PR 65 did not fold into a shared entry or
+    retire (``pr65.RETIRED``) is as it was, but that its ``workloads`` may
+    have grown (a later cell appends itself to a shared entry)."""
     now = manifest.load_manifest()
     for key, value in was.items():
-        if isinstance(value, list) and value and isinstance(value[0], dict):
+        if key == "end_to_end":
+            strip = lambda ms: [{k: v for k, v in m.items() if k != "bound"}
+                                for m in ms]
+            assert strip(now[key][:len(value)]) == strip(value), key
+        elif key == "per_layer":
+            listed = {m["name"]: m for m in now[key]}
+            for m in value:
+                if m["name"] in pr65.RETIRED:
+                    assert m["name"] not in listed, m["name"]
+                    continue
+                got = dict(listed[m["name"]])
+                assert ("workloads" in got) == ("workloads" in m), m["name"]
+                assert set(got.pop("workloads", ())) >= set(
+                    m.get("workloads", ())), m["name"]
+                assert got == {k: v for k, v in m.items()
+                               if k != "workloads"}, m["name"]
+        elif isinstance(value, list) and value and isinstance(value[0], dict):
             assert now[key][:len(value)] == value, key
         else:
             assert now[key] == value, key
