@@ -179,6 +179,12 @@ WINDOW_REASON = ("a block with a window, query heads of its own, a gate a "
                  "model-wide head count and no gate "
                  "(eligibility.window_plan_reason)")
 
+ONE_BRANCH_REASON = ("a block of one branch (a stack whose layer_types name "
+                     "feed-forward blocks: a mixer OR a feed-forward a "
+                     "block, one norm, one add): the ring all-gather / "
+                     "reduce-scatter matmuls are wired for a block that "
+                     "holds both an attention and an MLP")
+
 # why a block whose mixer is not plain attention keeps its matmuls on GSPMD,
 # by mixer kind
 MIXER_OVERLAP_REASON = {"conv": CONV_REASON, "mamba": MAMBA_REASON,
@@ -483,11 +489,11 @@ def mixed_stack_reason(cfg: Any, what: str, *,
                 "path (builder.forward_causal_lm) gives each block")
     from collections import Counter
 
-    said = ", ".join(f"{n} x {m}/{ff}"
+    said = ", ".join(f"{n} x {m or '-'}/{ff or '-'}"
                      for (m, ff), n in Counter(kinds).items())
     return (f"{what} takes a stack of one kind of block, with attention as "
             f"its mixer; this model's per-layer description holds {said} "
-            "(mixer/feed-forward)")
+            "(mixer/feed-forward; '-' = a block of one branch has none)")
 
 
 def overlap_unsupported_reason(
@@ -554,6 +560,9 @@ def plan_overlap_reasons(cfg: Any, hpc: Any) -> List:
             continue
         if is_moe_layer(cfg, i):
             out.append((i, MOE_REASON))
+            continue
+        if getattr(cfg, "one_branch_blocks", False):
+            out.append((i, ONE_BRANCH_REASON))
             continue
         mixer = cfg.block_kinds(len(hpc.layers))[i][0]
         if mixer != "full_attention":

@@ -227,7 +227,9 @@ def train(args) -> Dict[str, Any]:
                 s.cp_size > 1, bool(s.sp and s.tp_size > 1), use_flash)
             + (f"[w{window}]" if window and mixer == "sliding_attention"
                else "")
-            for s, (mixer, _) in zip(hpc.layers, kinds)]
+            for s, (mixer, _) in zip(hpc.layers, kinds)
+            # a feed-forward block of a one-branch stack has no core
+            if mixer is not None]
         tower_report: Dict[str, Any] = {}
         if cfg.tower_layers and cfg.image_grids:
             # a tower of image patches in front of the decoder: its blocks
@@ -280,8 +282,15 @@ def train(args) -> Dict[str, Any]:
         # how many blocks of each mixer and feed-forward kind the step holds
         blocks = {}
         for (m, ff), n in Counter(kinds).items():
+            # '-': a block of one branch has no mixer, or no feed-forward
+            m, ff = m or "-", ff or "-"
             blocks[f"{m}/{ff}"] = n
             get_registry().gauge("step/blocks", mixer=m, ff=ff).set(n)
+        if cfg.one_branch_blocks:
+            get_registry().gauge("blocks/mixer_only").set(
+                sum(ff is None for _, ff in kinds))
+            get_registry().gauge("blocks/ff_only").set(
+                sum(m is None for m, _ in kinds))
         # expert blocks across chips: which take the exchange, what a chip
         # sends around them a step (from the shapes), and by name any sorted
         # dispatcher under ep > 1 that the exchange does not serve
@@ -316,7 +325,7 @@ def train(args) -> Dict[str, Any]:
                      * cfg.seq_length * first.ep_size * cfg.moe_topk)
             held = cfg.num_experts // first.ep_size
             ep_report["first_chunk_rows"] = short_rows(
-                slots, held, cfg.num_experts)
+                slots, held, cfg.num_experts, cfg.moe_capacity_factor)
             ep_report["pass_rows"] = overflow_rows(
                 slots, held, cfg.num_experts)
             state.log("expert exchange: ep/axes {axes} over {blocks} expert "
@@ -338,12 +347,16 @@ def train(args) -> Dict[str, Any]:
                 ep = s.ep_size if s in exchanged else 1
                 expert_bodies[f"layer{i}"] = layer_body(
                     micro_slots // (s.dp_size if ep > 1 else 1) * ep,
-                    held_range(cfg, ep)[0], cfg.num_experts)
+                    held_range(cfg, ep)[0], cfg.num_experts,
+                    cfg.moe_capacity_factor)
         unserved = eligibility.ep_plan_reason(cfg, hpc.layers, hpc.pp_deg)
         if unserved:
             state.log(f"expert exchange not taken: {unserved}")
         # what a state-space block carries: the chunks of a sequence and
-        # the float32 state one sequence hands from chunk to chunk
+        # the float32 state one sequence hands from chunk to chunk; the
+        # groups of B and C its heads read (one: shared by all heads)
+        if any(m == "mamba" for m, _ in kinds):
+            get_registry().gauge("ssd/groups").set(cfg.mamba_n_groups)
         for i, (m, _) in enumerate(kinds):
             if m == "mamba":
                 get_registry().gauge("ssd/chunks", layer=f"layer{i}").set(
@@ -1294,7 +1307,7 @@ def train(args) -> Dict[str, Any]:
                                 for n in found["scopes"].get(scope, ()))
                             get_registry().gauge(f"{name}/mosaic_calls").set(
                                 step_report[f"{name}_mosaic_calls"])
-                    if any(MIXERS[m].reads("conv") for m, _ in kinds):
+                    if any(m and MIXERS[m].reads("conv") for m, _ in kinds):
                         # whether the convolution's kernels engaged: their
                         # calls by phase, one a block in each where they
                         # did, zeros = the jax.numpy form
@@ -1375,8 +1388,8 @@ def train(args) -> Dict[str, Any]:
                           **step_report["step_map"])
                     + f", {step_report['mosaic_custom_calls']} Mosaic calls"
                     + (f" ({step_report['ssd_mosaic_calls']} under "
-                       f"{SSD_SCOPE})" if "ssd_mosaic_calls" in step_report
-                       else "")
+                       f"{SSD_SCOPE}), ssd/groups {cfg.mamba_n_groups}"
+                       if "ssd_mosaic_calls" in step_report else "")
                     + (", selective/mosaic_calls "
                        f"{step_report['selective_mosaic_calls']}"
                        if "selective_mosaic_calls" in step_report else "")

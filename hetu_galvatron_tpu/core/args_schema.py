@@ -22,6 +22,10 @@ from pydantic import BaseModel, Field, field_validator, model_validator
 # what a block may leave for later blocks, by the kind that reads it: (the
 # names of the values, the kind that makes them). ``ModelArgs.block_shares``
 # says which block of a stack makes and which reads
+# the entries of ``layer_types`` that are a feed-forward and no mixer: a block
+# of a stack whose blocks have one branch each
+FEED_FORWARD_KINDS = ("experts", "dense")
+
 SHARED_VALUES: Dict[str, Tuple[Tuple[str, ...], str]] = {
     "gmu": (("memory",), "mamba1"),
     "cross_attention": (("keys", "values"), "full_attention"),
@@ -44,7 +48,9 @@ class ModelArgs(BaseModel):
     vocab_size: int = 50257
     max_position_embeddings: int = 1024
     seq_length: int = 1024
-    hidden_act: Literal["gelu", "gelu_exact", "swiglu", "geglu", "relu", "silu"] = "gelu"
+    # "relu2": relu(x)^2, ungated (Nemotron-H's MLPs and experts)
+    hidden_act: Literal["gelu", "gelu_exact", "swiglu", "geglu", "relu",
+                        "silu", "relu2"] = "gelu"
     normalization: Literal["layernorm", "rmsnorm"] = "layernorm"
     # None derives from the family: "post" for bert (HF BertLayer applies
     # LN after each residual; embeddings get their own LN and the final
@@ -112,6 +118,11 @@ class ModelArgs(BaseModel):
     # make_expert_exchange: tokens all-gathered over ep, partial results
     # reduce-scattered back, at tp = cp = etp = 1 on the pp = 1 path)
     moe_dispatcher: Literal["capacity", "dropless"] = "capacity"
+    # rows provisioned over the expected share of the routes: an expert's
+    # buffer under the "capacity" dispatcher (what is over is dropped), and
+    # the first chunk of a layer that holds a share of its experts under
+    # the sorted one (moe.short_rows: nothing is dropped, what is over takes
+    # counted passes behind it, and a step's time follows their count)
     moe_capacity_factor: float = 1.25
     # router: softmax topk (optionally expert-bias-corrected selection) or
     # sinkhorn load balancing (reference router.py:98)
@@ -159,12 +170,21 @@ class ModelArgs(BaseModel):
     # modules.apply_gmu) or "cross_attention" (its own queries over the keys
     # and values an earlier "full_attention" block left; which block leaves
     # what: :meth:`block_shares`); None = every block attends.
+    # A stack of blocks of ONE branch (Nemotron-H's ``hybrid_override_
+    # pattern``: one norm and one residual add a block, around a mixer OR a
+    # feed-forward) states its feed-forward blocks in the same list:
+    # "experts" (the routed experts of models/moe.py) or "dense" (the MLP of
+    # ``ffn_hidden_size``) is a block that is that feed-forward and no
+    # mixer, and a mixer entry of such a stack is a block without a
+    # feed-forward. A stack without such entries is a mixer AND a
+    # feed-forward a block, the feed-forward's kind derived as below.
     # ``num_dense_layers``: so many leading blocks of an expert model keep
     # a dense MLP of ``ffn_hidden_size``
     layer_types: Optional[
         List[Literal["full_attention", "conv", "mamba",
                      "latent_attention", "kda", "sliding_attention",
-                     "mamba1", "gmu", "cross_attention"]]] = None
+                     "mamba1", "gmu", "cross_attention",
+                     "experts", "dense"]]] = None
     num_dense_layers: int = 0
     # what a stack of window and full attention blocks publishes beside
     # ``layer_types`` (HF ``LagunaConfig``). ``sliding_window``: the keys a
@@ -213,11 +233,19 @@ class ModelArgs(BaseModel):
     # attn.{Wqkv,out_proj,lambda_*,subln} or attn.{in_proj,conv1d,x_proj,
     # dt_proj,out_proj,A_log,D} / mlp.{gate_up_proj,down_proj} /
     # model.final_layernorm
-    hf_layout: Literal["llama", "lfm2", "granite", "phi4flash"] = "llama"
+    # "nemotron_h" = backbone.embeddings / backbone.layers.{i}.norm and
+    # .mixer.* (a Mamba-2 mixer, q,k,v,o_proj, up,down_proj, or gate with
+    # e_score_correction_bias / experts.{e} / shared_experts: blocks of one
+    # branch) / backbone.norm_f / lm_head
+    hf_layout: Literal["llama", "lfm2", "granite", "phi4flash",
+                       "nemotron_h"] = "llama"
     # a "mamba" block (Mamba-2 / SSD; HF ``GraniteMoeHybridMambaLayer``):
     # ``mamba_n_heads`` heads of ``mamba_d_head`` channels each carry a
     # state of ``mamba_d_head x mamba_d_state`` over the sequence; B and C
-    # are shared by the heads of a group; a depthwise causal convolution of
+    # come in ``mamba_n_groups`` groups of ``mamba_d_state``, head ``j``
+    # reading group ``j // (heads / groups)`` (Granite-4.0-H publishes one
+    # group, Nemotron-H eight), and the gated norm's mean square is taken
+    # over each group's channels; a depthwise causal convolution of
     # ``mamba_d_conv`` taps runs over x, B and C before the recurrence,
     # which is computed ``mamba_chunk_size`` positions at a time
     mamba_n_heads: int = 0
@@ -397,6 +425,17 @@ class ModelArgs(BaseModel):
     @model_validator(mode="after")
     def _check_attention_kinds(self):
         kinds = self.layer_types or []
+        if "experts" in kinds and not self.num_experts:
+            raise ValueError(
+                "model.layer_types names experts blocks and "
+                "model.num_experts is 0")
+        if "mamba" in kinds and (self.mamba_n_groups < 1 or
+                                 self.mamba_n_heads % self.mamba_n_groups):
+            raise ValueError(
+                f"model.mamba_n_groups={self.mamba_n_groups}: the groups of "
+                f"B and C must divide the {self.mamba_n_heads} heads "
+                "(model.mamba_n_heads), head j reading group "
+                "j // (heads / groups)")
         if "sliding_attention" in kinds and not (
                 self.sliding_window and self.sliding_window > 0):
             raise ValueError(
@@ -464,13 +503,16 @@ class ModelArgs(BaseModel):
                     * float(entry.get("partial_rotary_factor", 1.0))))
 
     def block_kinds(self, n: Optional[int] = None
-                    ) -> Tuple[Tuple[str, str], ...]:
+                    ) -> Tuple[Tuple[Optional[str], Optional[str]], ...]:
         """The one per-layer description of a decoder stack: for each block
         its mixer kind ("full_attention", "conv", "mamba",
         "latent_attention", "kda", "sliding_attention", "mamba1", "gmu",
         "cross_attention") and its
         feed-forward kind ("dense", "experts"). The builder, the exporter, the launcher's
         report and every engine's refusal read this and nothing else.
+        A stack of one-branch blocks (``layer_types`` names "experts" or
+        "dense" blocks) gives each block one of the two and None for the
+        other: (mixer, None) or (None, feed-forward).
         ``n``: the blocks a plan lists where that is not
         ``num_hidden_layers`` (t5's two stacks, a pipeline stage's slice),
         which only a model without ``layer_types`` can have."""
@@ -481,11 +523,20 @@ class ModelArgs(BaseModel):
                 f"model.layer_types names {len(mixers)} blocks and "
                 f"the stack has {n} (model.num_hidden_layers is "
                 f"{self.num_hidden_layers})")
+        if self.one_branch_blocks:
+            return tuple((None, m) if m in FEED_FORWARD_KINDS else (m, None)
+                         for m in mixers)
         freq = max(self.moe_layer_freq, 1)
         return tuple(
             (m, "experts" if self.num_experts and i >= self.num_dense_layers
              and (i + 1) % freq == 0 else "dense")
             for i, m in enumerate(mixers))
+
+    @property
+    def one_branch_blocks(self) -> bool:
+        """Whether the stack's blocks have one branch each: ``layer_types``
+        names a feed-forward kind as a block of its own."""
+        return any(m in FEED_FORWARD_KINDS for m in self.layer_types or ())
 
     def block_shares(self, n: Optional[int] = None
                      ) -> Tuple[Tuple[Tuple[str, ...], Tuple[str, ...]], ...]:

@@ -785,15 +785,19 @@ def model_flops_per_token(model: Any, seq_length: Optional[int] = None
     own_heads = getattr(model, "num_attention_heads_per_layer", None)
     band = min(s, getattr(model, "sliding_window", None) or s)
 
-    def mixer_flops(i: int, m: str) -> float:
+    def mixer_flops(i: int, m: Optional[str]) -> float:
+        if m is None:   # a feed-forward block of a one-branch stack
+            return 0.0
         if m == "sliding_attention" or (m == "full_attention" and own_heads):
             return attention(
                 own_heads[i] if own_heads else model.num_attention_heads,
                 band if m == "sliding_attention" else s)
         return mixer[m]
 
-    per_block = [mixer_flops(i, m) + maps
-                 + (experts_ff if ff == "experts" else dense_ff)
+    # (a block of one branch has one of the two and one set of maps)
+    ff_flops = {"experts": experts_ff, "dense": dense_ff, None: 0.0}
+    per_block = [mixer_flops(i, m) + ff_flops[ff]
+                 + (maps if m and ff else maps / 2)
                  for i, (m, ff) in enumerate(kinds)]
     head = 2 * h * model.padded_vocab_size  # LM head
     # (a tower's work a sequence falls on the sequence's tokens)

@@ -36,14 +36,18 @@ MODULE_REGISTRY: Dict[str, Tuple[Callable, Callable]] = {
 }
 
 
-def init_block(key: jax.Array, cfg: ModelArgs, kind: Tuple[str, str]
+def init_block(key: jax.Array, cfg: ModelArgs,
+               kind: Tuple[Optional[str], Optional[str]]
                ) -> Tuple[Params, Params]:
-    """(params, axes) of one block of ``kind``, (mixer, feed-forward)."""
+    """(params, axes) of one block of ``kind``, (mixer, feed-forward); one
+    of the two is None in a block of one branch."""
     from hetu_galvatron_tpu.models.moe import init_moe_decoder_layer
 
     mixer, ff = kind
-    return (init_moe_decoder_layer if ff == "experts"
-            else M.init_decoder_layer)(key, cfg, mixer)
+    if ff == "experts":
+        return init_moe_decoder_layer(key, cfg, mixer)
+    return M.init_decoder_layer(
+        key, cfg, mixer, ("mlp", M.init_mlp) if ff else None)
 
 
 def build_causal_lm_arch(cfg: ModelArgs) -> List[str]:

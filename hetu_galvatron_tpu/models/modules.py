@@ -1085,13 +1085,16 @@ def streams_out(x: jax.Array, cfg: ModelArgs) -> jax.Array:
 def init_block_maps(key: jax.Array, cfg: ModelArgs
                     ) -> Tuple[Params, Axes]:
     """A block's two sets of maps (``hc1`` around the mixer, ``hc2`` around
-    the feed-forward), drawn from the block's key folded once more so that
-    the block's other leaves are what they are without them; empty for a
-    model of one stream."""
+    the feed-forward; ``hc1`` alone for a block of one branch), drawn from
+    the block's key folded once more so that the block's other leaves are
+    what they are without them; empty for a model of one stream."""
     if cfg.hc_mult <= 1:
         return {}, {}
     k1, k2 = jax.random.split(jax.random.fold_in(key, 2))
-    (p1, a1), (p2, a2) = init_hyper_maps(k1, cfg), init_hyper_maps(k2, cfg)
+    p1, a1 = init_hyper_maps(k1, cfg)
+    if cfg.one_branch_blocks:
+        return {"hc1": p1}, {"hc1": a1}
+    p2, a2 = init_hyper_maps(k2, cfg)
     return {"hc1": p1, "hc2": p2}, {"hc1": a1, "hc2": a2}
 
 
@@ -1243,7 +1246,10 @@ def init_mamba2(key: jax.Array, cfg: ModelArgs) -> Tuple[Params, Axes]:
     ``conv_bias`` the depthwise kernel ``[x | B | C channels, taps]``
     (``conv1d.weight[:, 0, :]``) and its bias, ``dt_bias``, ``A_log`` and
     ``D`` one value a head, ``norm`` the gated RMSNorm's scale over all
-    ``mamba_d_inner`` channels, ``wout`` is ``out_proj``. No leaf carries an
+    ``mamba_d_inner`` channels, ``wout`` is ``out_proj``. ``B`` and ``C``
+    are ``mamba_n_groups`` groups of ``mamba_d_state`` columns each, group
+    by group (Granite-4.0-H publishes one group, Nemotron-H eight); head
+    ``j`` reads group ``j // (heads / groups)``. No leaf carries an
     axis name that tensor parallelism shards: a plan with tp > 1 over a
     mamba block is refused by name (``eligibility.mamba_plan_reason``).
 
@@ -1254,15 +1260,15 @@ def init_mamba2(key: jax.Array, cfg: ModelArgs) -> Tuple[Params, Axes]:
     under which a head forgets within a token or two.)"""
     if cfg.mamba_n_heads <= 0:
         raise ValueError("a mamba block needs model.mamba_n_heads > 0")
-    if cfg.mamba_n_groups != 1:
-        raise NotImplementedError(
-            f"model.mamba_n_groups={cfg.mamba_n_groups}: the mamba block is "
-            "written for B and C shared by all heads (one group), which is "
-            "what Granite-4.0-H publishes")
+    if cfg.mamba_n_groups < 1 or cfg.mamba_n_heads % cfg.mamba_n_groups:
+        raise ValueError(
+            f"model.mamba_n_groups={cfg.mamba_n_groups} does not divide "
+            f"the {cfg.mamba_n_heads} heads of a mamba block")
     if cfg.mamba_proj_bias:
         raise NotImplementedError(
             "model.mamba_proj_bias: the mamba block's projections are "
-            "written without biases (Granite-4.0-H publishes false)")
+            "written without biases (Granite-4.0-H and Nemotron-H publish "
+            "false)")
     h, nh, L = cfg.hidden_size, cfg.mamba_n_heads, cfg.mamba_d_conv
     di, cd = cfg.mamba_d_inner, cfg.mamba_conv_dim
     k1, k2, k3, k4, k5 = jax.random.split(key, 5)
@@ -1312,8 +1318,8 @@ def ssd_chunks_a_group(batch: int, chunks: int, heads: int,
 def ssd_chunked(x: jax.Array, dt: jax.Array, A: jax.Array, Bm: jax.Array,
                 Cm: jax.Array, chunk: int,
                 compute_dtype=jnp.bfloat16,
-                scan_fn: Optional[Callable[..., jax.Array]] = None
-                ) -> jax.Array:
+                scan_fn: Optional[Callable[..., jax.Array]] = None,
+                groups: int = 1) -> jax.Array:
     """The selective state-space recurrence of Mamba-2 in its chunked,
     matmul form (Dao & Gu 2024, "SSD"). Per head, with state ``S`` [P, N],
     zero before the sequence::
@@ -1321,8 +1327,9 @@ def ssd_chunked(x: jax.Array, dt: jax.Array, A: jax.Array, Bm: jax.Array,
         S_t = exp(dt_t A) S_(t-1) + dt_t x_t B_t^T ;   y_t = S_t C_t
 
     ``x`` [B, S, H, P]; ``dt`` [B, S, H] float32, after softplus; ``A`` [H]
-    float32, negative; ``Bm``, ``Cm`` [B, S, N], shared by the heads.
-    Returns ``y`` [B, S, H, P] float32 (without the ``D x`` skip).
+    float32, negative; ``Bm``, ``Cm`` [B, S, groups * N], group by group:
+    head ``j`` reads group ``j // (H / groups)`` (one group: shared by the
+    heads). Returns ``y`` [B, S, H, P] float32 (without the ``D x`` skip).
 
     With ``cs`` the running sum of ``dt A`` inside a chunk of ``chunk``
     positions: inside a chunk ``y_i += sum_(j<=i) (C_i . B_j) exp(cs_i -
@@ -1342,7 +1349,10 @@ def ssd_chunked(x: jax.Array, dt: jax.Array, A: jax.Array, Bm: jax.Array,
     tiles (``ssd.tile_plan``): the decay matrix, ``C B^T * L`` and the
     carried state then live in VMEM. Otherwise it is ``jax.numpy``, the
     chunks in groups whose decay matrices fit ``SSD_DECAY_BYTES``, each
-    group's made again in the backward pass."""
+    group's made again in the backward pass. The heads of different groups
+    of B and C share nothing, so the ``jax.numpy`` form takes several
+    groups as further batch rows of one group each (B and C are never
+    copied a head); the kernels index a head's group themselves."""
     from hetu_galvatron_tpu.ops.pallas.ssd import tile_plan
 
     f32 = jnp.float32
@@ -1353,18 +1363,31 @@ def ssd_chunked(x: jax.Array, dt: jax.Array, A: jax.Array, Bm: jax.Array,
             jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
             for t in (x, dt, Bm, Cm))
     Q, nC = chunk, (S + pad) // chunk
-    if scan_fn is not None and tile_plan(Q, H, P, Bm.shape[-1]) is not None:
+    if scan_fn is not None and tile_plan(
+            Q, H, P, Bm.shape[-1] // groups, groups) is not None:
         return scan_fn(x.astype(compute_dtype), dt, A,
                        Bm.astype(compute_dtype), Cm.astype(compute_dtype),
-                       Q)[:, :S]
+                       Q, groups=groups)[:, :S]
+    if groups > 1:
+        def fold(t):    # [B, S, G * k, ...] -> [B * G, S, k, ...]
+            t = t.reshape((B_, t.shape[1], groups, -1) + t.shape[3:])
+            t = jnp.moveaxis(t, 2, 1)
+            return t.reshape((B_ * groups,) + t.shape[2:])
+
+        y = ssd_chunked(
+            fold(x), fold(dt),
+            jnp.tile(A.reshape(groups, 1, 1, -1), (B_, 1, 1, 1)),
+            fold(Bm), fold(Cm), chunk, compute_dtype)
+        y = jnp.moveaxis(y.reshape((B_, groups) + y.shape[1:]), 1, 2)
+        return y.reshape(B_, nC * Q, H, P)[:, :S]
     size = ssd_chunks_a_group(B_, nC, H, Q)
-    groups = nC // size
+    parts = nC // size
 
-    def grouped(t):   # [B, S, ...] -> [groups, B, size, Q, ...]
+    def grouped(t):   # [B, S, ...] -> [parts, B, size, Q, ...]
         return jnp.moveaxis(
-            t.reshape((B_, groups, size, Q) + t.shape[2:]), 1, 0)
+            t.reshape((B_, parts, size, Q) + t.shape[2:]), 1, 0)
 
-    def whole(t):     # [groups, B, size, ...] -> [B, nC, ...]
+    def whole(t):     # [parts, B, size, ...] -> [B, nC, ...]
         t = jnp.moveaxis(t, 0, 1)
         return t.reshape((B_, nC) + t.shape[3:])
 
@@ -1419,10 +1442,12 @@ def apply_mamba2(
 ) -> jax.Array:
     """``[z | xBC | dt] = x W_in``; ``xBC = silu(conv1d_causal(xBC) + b)``
     (depthwise, ``mamba_d_conv`` taps, zero history before the sequence);
-    ``[x | B | C] = xBC``; ``dt = softplus(dt + dt_bias)``, ``A =
+    ``[x | B | C] = xBC``, B and C ``mamba_n_groups`` groups of
+    ``mamba_d_state``; ``dt = softplus(dt + dt_bias)``, ``A =
     -exp(A_log)``; ``y = SSD(x, dt, A, B, C) + D x``
-    (:func:`ssd_chunked`); ``y = RMSNorm(y * silu(z)) * w`` over all
-    channels; ``y W_out``. No softmax, no positions. The two projections
+    (:func:`ssd_chunked`); ``y = RMSNorm(y * silu(z)) * w``, the mean
+    square over each group's ``mamba_d_inner / mamba_n_groups`` channels
+    (one group: over all channels); ``y W_out``. No softmax, no positions. The two projections
     and the recurrence's matmuls run in ``compute_dtype`` with float32
     accumulation; ``dt``, the decays, the state, the convolution and the
     gated norm are float32. ``ssd_fn``: the kernels for the
@@ -1430,7 +1455,8 @@ def apply_mamba2(
     (:func:`ssd_chunked`'s ``scan_fn``), and ``conv_fn`` those for the
     convolution, its bias and SiLU (:func:`causal_depthwise_conv`)."""
     B, S, _ = x.shape
-    nh, hp, N = cfg.mamba_n_heads, cfg.mamba_d_head, cfg.mamba_d_state
+    nh, hp = cfg.mamba_n_heads, cfg.mamba_d_head
+    G, GN = cfg.mamba_n_groups, cfg.mamba_n_groups * cfg.mamba_d_state
     di, f32 = cfg.mamba_d_inner, jnp.float32
     with jax.named_scope("mixer/mamba"):
         with jax.named_scope("in_proj"):
@@ -1444,22 +1470,26 @@ def apply_mamba2(
             xs, Bm, Cm = jnp.split(causal_depthwise_conv(
                 xbc, p["taps"], p.get("conv_bias"), silu=True,
                 out_dtype=compute_dtype, conv_fn=conv_fn,
-                scope="mixer/mamba/conv"), [di, di + N], axis=-1)
+                scope="mixer/mamba/conv"), [di, di + GN], axis=-1)
         with jax.named_scope("ssd"):
             dt = jax.nn.softplus(dt + p["dt_bias"])
             y = ssd_chunked(xs.reshape(B, S, nh, hp), dt,
                             -jnp.exp(p["A_log"].astype(f32)), Bm, Cm,
                             cfg.mamba_chunk_size, compute_dtype,
-                            scan_fn=ssd_fn)
+                            scan_fn=ssd_fn, groups=G)
             # a head is hp of a row's lanes, here as in the kernels: a
             # [.., heads, hp] view of a row is no bitcast on a TPU
             y = (y.reshape(B, S, di)
                  + jnp.repeat(p["D"], hp) * xs.astype(f32))
         with jax.named_scope("gated_norm"):
             y = y * jax.nn.silu(z.astype(f32))
+            if G > 1:   # the mean square a group of channels
+                y = y.reshape(B, S, G, di // G)
             var = jnp.mean(jnp.square(y), axis=-1, keepdims=True)
-            y = (y * jax.lax.rsqrt(var + cfg.layernorm_epsilon)
-                 * p["norm"]["scale"]).astype(compute_dtype)
+            y = y * jax.lax.rsqrt(var + cfg.layernorm_epsilon)
+            if G > 1:
+                y = y.reshape(B, S, di)
+            y = (y * p["norm"]["scale"]).astype(compute_dtype)
         with jax.named_scope("out_proj"):
             out = jnp.einsum("bsc,ch->bsh", y,
                              p["wout"].astype(compute_dtype),
@@ -2352,6 +2382,7 @@ _ACTS = {
     "gelu": partial(jax.nn.gelu, approximate=True),
     "gelu_exact": partial(jax.nn.gelu, approximate=False),  # HF BERT erf gelu
     "relu": jax.nn.relu,
+    "relu2": lambda x: jnp.square(jax.nn.relu(x)),  # ungated (Nemotron-H)
     "silu": jax.nn.silu,
     "swiglu": jax.nn.silu,  # gate activation
     "geglu": partial(jax.nn.gelu, approximate=True),
@@ -2433,23 +2464,27 @@ def apply_mlp(p: Params, x: jax.Array, cfg: ModelArgs,
 
 
 def init_decoder_layer(key: jax.Array, cfg: ModelArgs,
-                       mixer: str = "full_attention",
-                       ff: Tuple[str, Callable] = ("mlp", init_mlp)
+                       mixer: Optional[str] = "full_attention",
+                       ff: Optional[Tuple[str, Callable]] = ("mlp", init_mlp)
                        ) -> Tuple[Params, Axes]:
     """A block of one mixer kind and one feed-forward: ``ff`` is (the
     block's key for it, ``init(key, cfg)``), a dense MLP unless the caller
-    says otherwise (models/moe.py::init_moe_decoder_layer)."""
+    says otherwise (models/moe.py::init_moe_decoder_layer). A block of one
+    branch (``ModelArgs.block_kinds`` of a stack whose ``layer_types`` name
+    feed-forward blocks) has ``mixer`` None or ``ff`` None, one norm
+    (``ln1``) and, over several streams, one set of maps (``hc1``)."""
     k1, k2 = jax.random.split(key)
-    row = mixer_of(mixer)
-    mix_p, mix_a = row.init(k1, cfg)
-    ff_p, ff_a = ff[1](k2, cfg)
-    ln1_p, ln1_a = init_norm(cfg)
-    ln2_p, ln2_a = init_norm(cfg)
+    p, a = {}, {}
+    both = mixer is not None and ff is not None
+    for name in ("ln1", "ln2") if both else ("ln1",):
+        p[name], a[name] = init_norm(cfg)
+    if mixer is not None:
+        row = mixer_of(mixer)
+        p[row.key], a[row.key] = row.init(k1, cfg)
+    if ff is not None:
+        p[ff[0]], a[ff[0]] = ff[1](k2, cfg)
     hc_p, hc_a = init_block_maps(key, cfg)
-    return (
-        {"ln1": ln1_p, row.key: mix_p, "ln2": ln2_p, ff[0]: ff_p, **hc_p},
-        {"ln1": ln1_a, row.key: mix_a, "ln2": ln2_a, ff[0]: ff_a, **hc_a},
-    )
+    return {**p, **hc_p}, {**a, **hc_a}
 
 
 def residual_branch(y: jax.Array, cfg: ModelArgs) -> jax.Array:
@@ -2471,7 +2506,7 @@ def apply_decoder_layer(
     causal: Optional[bool] = None,
     dropout_rng: Optional[jax.Array] = None,
     segment_ids: Optional[jax.Array] = None,
-    mixer: str = "full_attention",
+    mixer: Optional[str] = "full_attention",
     feed_forward: Optional[Callable[[jax.Array], jax.Array]] = None,
     shared: Optional[Dict[str, jax.Array]] = None,
     made: Optional[Dict[str, jax.Array]] = None,
@@ -2491,7 +2526,10 @@ def apply_decoder_layer(
     block's maps ``hc1`` / ``hc2`` (:func:`residual`). ``shared`` / ``made``
     / ``lambda_init``: what the block's operator reads of earlier blocks,
     the dict it writes what it leaves into and its constant of differential
-    attention (:func:`apply_mixer`)."""
+    attention (:func:`apply_mixer`). A block of a stack of one-branch
+    blocks (``cfg.one_branch_blocks``) is ``x + F(ln1(x))``, one norm and
+    one add: ``F`` the mixer, or the feed-forward where ``mixer`` is
+    None."""
     if causal is None:
         causal = cfg.model_type != "bert"
     r_attn = r_res1 = r_res2 = None
@@ -2516,7 +2554,7 @@ def apply_decoder_layer(
                 matmul_fns=ops.matmuls, shard_fn=ops.shard), r_res2)
 
     if cfg.post_norm:
-        if mixer != "full_attention":
+        if mixer != "full_attention" or cfg.one_branch_blocks:
             raise NotImplementedError(
                 f"a post-norm block with a {mixer!r} mixer: post-norm "
                 "families (bert) attend in every block")
@@ -2524,6 +2562,12 @@ def apply_decoder_layer(
         # output.LayerNorm)
         x = block_norm(p["ln1"], x + mixed(x), cfg)
         return block_norm(p["ln2"], x + fed(x), cfg)
+
+    if cfg.one_branch_blocks:
+        branch = fed if mixer is None else mixed
+        return residual(
+            p.get("hc1"), x, cfg, lambda a: residual_branch(
+                branch(block_norm(p["ln1"], a, cfg)), cfg), compute_dtype)
 
     def mixer_branch(a):
         return residual_branch(mixed(block_norm(p["ln1"], a, cfg)), cfg)

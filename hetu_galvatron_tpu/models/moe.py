@@ -41,6 +41,7 @@ from __future__ import annotations
 
 import functools
 import math
+from fractions import Fraction
 from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
@@ -367,13 +368,16 @@ def _capacity_dispatch(
 
 
 # a layer that holds a share computes the sorted slots in chunks: the first,
-# which always runs, is the expected share of the routes times _FIRST_MARGIN,
-# and each counted pass behind it the expected share times _CHUNK_SHARE (both
-# as (numerator, denominator)), rounded up to a sublane tile of rows. On the
+# which always runs, is the expected share of the routes times the model's
+# ``moe_capacity_factor`` (5/4 unless a configuration states another: rows
+# provisioned over the expected share, as for the capacity dispatcher, with
+# nothing dropped behind them), and each counted pass behind it the expected
+# share times _CHUNK_SHARE (as (numerator, denominator)), rounded up to a
+# sublane tile of rows. On the
 # chip a pass of 512 or of 1,024 rows costs 1.6 ms at 8 held experts (their
 # weights read and their float32 gradients added once more) and one of 2,048
 # rows 3.9 (the scatter-adds, by the row): PERF.md section 6, PR 52
-_FIRST_MARGIN, _CHUNK_SHARE, _ROW_TILE = (5, 4), (1, 4), 8
+_CHUNK_SHARE, _ROW_TILE = (1, 4), 8
 
 
 def _share_rows(slots: int, held: int, num_experts: int,
@@ -383,19 +387,54 @@ def _share_rows(slots: int, held: int, num_experts: int,
                * _ROW_TILE)
 
 
-def short_rows(slots: int, held: int, num_experts: int) -> int:
+def short_rows(slots: int, held: int, num_experts: int,
+               margin: float = 1.25) -> int:
     """Rows of the first chunk of a layer that holds ``held`` of
     ``num_experts`` experts and has ``slots`` = T*K routes: the expected
-    share of the routes and a quarter over, rounded up to a row tile, and
-    never above ``slots`` (where it reaches ``slots`` the layer has the one
-    body and no loop)."""
-    return _share_rows(slots, held, num_experts, _FIRST_MARGIN)
+    share of the routes times ``margin`` (``cfg.moe_capacity_factor``: a
+    quarter over unless the model states another), rounded up to a row tile,
+    and never above ``slots`` (where it reaches ``slots`` the layer has the
+    one body and no loop)."""
+    share = Fraction(margin).limit_denominator(64)
+    return _share_rows(slots, held, num_experts,
+                       (share.numerator, share.denominator))
 
 
 def overflow_rows(slots: int, held: int, num_experts: int) -> int:
     """Rows of one counted pass over what the first chunk did not reach: a
     quarter of the expected share of the routes, rounded up to a row tile."""
     return _share_rows(slots, held, num_experts, _CHUNK_SHARE)
+
+
+# the grouped matmuls' widths are padded with zeros to what libtpu's kernel
+# runs fast at (my chip runs, PR 66, 6,144 rows over 8 groups, forward and
+# both gradients of the two products): an expert width that is no whole
+# number of lane tiles to a multiple of 256 (Nemotron-H's 1856: 23.5 ms as it
+# is, 23.4 at 1920, 9.6 at 2048), a hidden width that is no multiple of 256 to
+# a multiple of 512 (its 2688: 9.6 ms, 7.5 at 2816, 6.6 at 3072). Every
+# activation maps 0 to 0, so the padding adds nothing to a result or a
+# gradient; widths under a lane tile are a test's model and are left alone.
+# (The rules leave every width the benchmark's other cells run as it is:
+# whether 1408 and 896 columns gain as well is PERF.md section 7's.)
+_LANES = 128
+
+
+def _padded_width(n: int, unless: int, to: int) -> int:
+    return n if n < _LANES or n % unless == 0 else -(-n // to) * to
+
+
+def _whole_tiles(xs, win, wout):
+    """``xs`` [R, H], ``win`` [held, H, F] (or gate | up side by side, 2 F)
+    and ``wout`` [held, F, H] with ``F`` and ``H`` padded with
+    zeros as the comment above says; as they are where nothing is padded."""
+    held, F, H = wout.shape
+    f, h = _padded_width(F, _LANES, 256) - F, _padded_width(H, 256, 512) - H
+    if not (f or h):
+        return xs, win, wout
+    win = jnp.pad(win.reshape(held, H, -1, F),
+                  ((0, 0), (0, h), (0, 0), (0, f))).reshape(held, H + h, -1)
+    return (jnp.pad(xs, ((0, 0), (0, h))), win,
+            jnp.pad(wout, ((0, 0), (0, f), (0, h))))
 
 
 def _sorted_rows_mlp(cfg: ModelArgs, compute_dtype, rows, win, wout, w_rows,
@@ -416,19 +455,23 @@ def _sorted_rows_mlp(cfg: ModelArgs, compute_dtype, rows, win, wout, w_rows,
         if mine is not None:
             xs = jnp.where(mine, xs, 0)
     with jax.named_scope("moe/experts"):
+        hidden = xs.shape[1]
+        xs, win, wout = _whole_tiles(xs, win, wout)
         hproj = _grouped_matmul(xs, win, group_sizes, compute_dtype)
         hproj = _expert_act(hproj, cfg, compute_dtype)
-        ys = _grouped_matmul(hproj, wout, group_sizes, jnp.float32)
+        ys = _grouped_matmul(hproj, wout, group_sizes,
+                             jnp.float32)[:, :hidden]
     with jax.named_scope("moe/combine"):
         if mine is not None:
             ys = jnp.where(mine, ys, 0.0)
         return ys * w_rows[:, None]
 
 
-def layer_body(slots: int, held: int, num_experts: int) -> str:
+def layer_body(slots: int, held: int, num_experts: int,
+               margin: float = 1.25) -> str:
     """The body a sorted layer of ``slots`` routes compiles to: ``whole`` (no
     loop) or ``counted <first chunk's rows>/<slots> +<a pass's rows>``."""
-    first = short_rows(slots, held, num_experts)
+    first = short_rows(slots, held, num_experts, margin)
     return "whole" if first >= slots else (
         f"counted {first}/{slots} +{overflow_rows(slots, held, num_experts)}")
 
@@ -591,7 +634,8 @@ def _held_dispatch(
     T, _ = xt.shape
     K = cfg.moe_topk
     held, first = held_range(cfg, ep, index)
-    first_len = short_rows(T * K, held, cfg.num_experts)
+    first_len = short_rows(T * K, held, cfg.num_experts,
+                           cfg.moe_capacity_factor)
     chunk_len = overflow_rows(T * K, held, cfg.num_experts)
     w = w.reshape(T * K)
     with jax.named_scope("moe/dispatch"):
@@ -754,10 +798,11 @@ def apply_moe_mlp(
 
 
 def init_moe_decoder_layer(key: jax.Array, cfg: ModelArgs,
-                           mixer: str = "full_attention"
+                           mixer: Optional[str] = "full_attention"
                            ) -> Tuple[Params, Params]:
     """modules.init_decoder_layer with the expert layer, under ``"moe"``,
-    for the dense MLP."""
+    for the dense MLP (``mixer`` None: a block of one branch, the experts
+    alone)."""
     return M.init_decoder_layer(key, cfg, mixer, ff=("moe", init_moe_mlp))
 
 

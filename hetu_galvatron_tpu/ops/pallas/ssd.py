@@ -44,6 +44,14 @@ D[i, j] - sum_j D[j, i]``, the first is ``sum_p dy[i, p] y_intra[i, p]`` and
 the second ``sum_p (x dt)[i, p] (M^T dy)[i, p]``: row sums over a head's
 ``P`` lanes. ``dB`` and ``dC`` leave a step summed over its ``hb`` heads.
 
+Groups. ``B`` and ``C`` may come in ``groups`` groups of ``N`` columns,
+``[B, S, groups * N]``, head ``j`` reading group ``j // (H / groups)``
+(Nemotron-H: eight; Granite-4.0-H: one, shared by all heads). A grid step
+then holds heads of ONE group (``hb`` divides ``H / groups``) and its block
+of ``B`` and ``C`` is that group's ``N`` columns, picked by the block index:
+nothing is copied a head in HBM. ``dB`` and ``dC`` leave a step in their
+group's columns and are summed over the steps of a group outside.
+
 Arithmetic is ``ssd_chunked``'s: ``cs``, ``L``, every exp, the carried
 state and every accumulator float32; the matmul operands (``M`` after ``G *
 L``, ``x dt`` and ``x w`` after the float32 product, the state where it is
@@ -87,19 +95,20 @@ _DT, _CS, _W, _GROW = range(4)
 
 
 def tile_plan(chunk: int, heads: int, head_dim: int,
-              state: int) -> Optional[int]:
+              state: int, groups: int = 1) -> Optional[int]:
     """The heads a grid step holds where the kernels' tiles fit these
     shapes, else None (the caller keeps the ``jax.numpy`` form): the chunk
     and the state whole lane tiles, a head a whole number of lane tiles or
-    a whole fraction of one, the heads a whole number of steps of at most
-    ``STEP_LANES`` lanes."""
-    if chunk % LANES or state % LANES or head_dim < 16:
+    a whole fraction of one, the heads OF A GROUP of B and C a whole number
+    of steps of at most ``STEP_LANES`` lanes."""
+    if chunk % LANES or state % LANES or head_dim < 16 or heads % groups:
         return None
     if head_dim % LANES and LANES % head_dim:
         return None
     pack = max(1, LANES // head_dim)    # heads a lane tile
     for hb in HEADS_A_STEP:
-        if not (heads % hb or hb % pack or hb * head_dim > STEP_LANES):
+        if not (heads // groups % hb or hb % pack
+                or hb * head_dim > STEP_LANES):
             return hb
     return None
 
@@ -243,17 +252,28 @@ def _bwd_kernel(x_ref, col_ref, csr_ref, a_ref, b_ref, c_ref, y_ref,
     dc_ref[0, 0] = dC + _dot(dg, Bc, _TN)
 
 
-def _specs(nC: int, Q: int, P: int, N: int, hb: int, reverse: bool):
+def _specs(nC: int, Q: int, P: int, N: int, hb: int, reverse: bool,
+           steps: int):
+    """``steps``: the grid steps a group of B and C spans along the head
+    axis (all of them where there is one group). Step ``g`` reads columns
+    ``g // steps`` of B and C and writes its part of their cotangents as
+    part ``g % steps`` of those columns."""
     at = (lambda c: nC - 1 - c) if reverse else (lambda c: c)
     wide = pl.BlockSpec((1, Q, hb * P), lambda b, g, c: (b, at(c), g))
     cols = pl.BlockSpec((1, 1, 1, Q, 4 * hb),
                         lambda b, g, c: (b, at(c), g, 0, 0))
     rows = pl.BlockSpec((1, 1, hb, Q), lambda b, g, c: (b, at(c), g, 0))
     a = pl.BlockSpec((1, 1, 1, hb * P), lambda b, g, c: (b, at(c), 0, g))
-    shared = pl.BlockSpec((1, Q, N), lambda b, g, c: (b, at(c), 0))
     states = pl.BlockSpec((1, 1, N, hb * P),
                           lambda b, g, c: (b, at(c), 0, g))
-    part = pl.BlockSpec((1, 1, Q, N), lambda b, g, c: (b, g, at(c), 0))
+    if steps is None:   # one group: the index maps they always were
+        shared = pl.BlockSpec((1, Q, N), lambda b, g, c: (b, at(c), 0))
+        part = pl.BlockSpec((1, 1, Q, N), lambda b, g, c: (b, g, at(c), 0))
+    else:
+        shared = pl.BlockSpec((1, Q, N),
+                              lambda b, g, c: (b, at(c), g // steps))
+        part = pl.BlockSpec((1, 1, Q, N),
+                            lambda b, g, c: (b, g % steps, at(c), g // steps))
     return wide, cols, rows, a, shared, states, part
 
 
@@ -261,12 +281,18 @@ def _specs(nC: int, Q: int, P: int, N: int, hb: int, reverse: bool):
 _SEMANTICS = ("parallel", "parallel", "arbitrary")
 
 
-def _scan_call(x, cols, csr, a, Bm, Cm, interpret: bool, keep_states: bool):
+def _group_steps(H: int, hb: int, groups: int) -> Optional[int]:
+    """``_specs``' ``steps``: None for one group."""
+    return None if groups == 1 else H // groups // hb
+
+
+def _scan_call(x, cols, csr, a, Bm, Cm, interpret: bool, keep_states: bool,
+               groups: int = 1):
     B, S, HP = x.shape
     nC, H, Q = csr.shape[1:]
-    P, N, hb = HP // H, Bm.shape[-1], cols.shape[-1] // 4
-    wide, col, rows, a_spec, shared, states, _ = _specs(nC, Q, P, N, hb,
-                                                       reverse=False)
+    P, N, hb = HP // H, Bm.shape[-1] // groups, cols.shape[-1] // 4
+    wide, col, rows, a_spec, shared, states, _ = _specs(
+        nC, Q, P, N, hb, False, _group_steps(H, hb, groups))
     y_shape = jax.ShapeDtypeStruct((B, S, HP), jnp.float32)
     kept = jax.ShapeDtypeStruct((B, nC, N, HP), jnp.float32)
     return pl.pallas_call(
@@ -285,13 +311,15 @@ def _scan_call(x, cols, csr, a, Bm, Cm, interpret: bool, keep_states: bool):
 
 
 def _scan_bwd_call(x, cols, csr, a, Bm, Cm, y, entering, dy,
-                   interpret: bool):
+                   interpret: bool, groups: int = 1):
     B, S, HP = x.shape
     nC, H, Q = csr.shape[1:]
-    P, N, hb = HP // H, Bm.shape[-1], cols.shape[-1] // 4
+    P, N, hb = HP // H, Bm.shape[-1] // groups, cols.shape[-1] // 4
     wide, col, rows, a_spec, shared, states, part = _specs(
-        nC, Q, P, N, hb, reverse=True)
-    parts = jax.ShapeDtypeStruct((B, H // hb, S, N), jnp.float32)
+        nC, Q, P, N, hb, True, _group_steps(H, hb, groups))
+    # a group's steps side by side, each over its group's columns
+    parts = jax.ShapeDtypeStruct((B, H // groups // hb, S, groups * N),
+                                 jnp.float32)
     dx, dcols, da, dB, dC = pl.pallas_call(
         functools.partial(_bwd_kernel, hb=hb, P=P),
         grid=(B, H // hb, nC),
@@ -312,14 +340,15 @@ def _scan_bwd_call(x, cols, csr, a, Bm, Cm, y, entering, dy,
             jnp.sum(dC, axis=1).astype(Cm.dtype))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
-def _scan(x, cols, csr, a, Bm, Cm, interpret):
-    return _scan_call(x, cols, csr, a, Bm, Cm, interpret, keep_states=False)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
+def _scan(x, cols, csr, a, Bm, Cm, interpret, groups):
+    return _scan_call(x, cols, csr, a, Bm, Cm, interpret, keep_states=False,
+                      groups=groups)
 
 
-def _scan_fwd(x, cols, csr, a, Bm, Cm, interpret):
+def _scan_fwd(x, cols, csr, a, Bm, Cm, interpret, groups):
     y, entering = _scan_call(x, cols, csr, a, Bm, Cm, interpret,
-                             keep_states=True)
+                             keep_states=True, groups=groups)
     # the pair per-layer remat keeps (``modules.remat``), as the kernel
     # wrote them: a block's recomputed forward then holds no scan kernel
     y = checkpoint_name(y, KEPT_OUT)
@@ -327,29 +356,30 @@ def _scan_fwd(x, cols, csr, a, Bm, Cm, interpret):
     return y, (x, cols, csr, a, Bm, Cm, y, entering)
 
 
-def _scan_bwd(interpret, res, dy):
+def _scan_bwd(interpret, groups, res, dy):
     with jax.named_scope(SCOPE):
-        return _scan_bwd_call(*res, dy, interpret)
+        return _scan_bwd_call(*res, dy, interpret, groups)
 
 
 _scan.defvjp(_scan_fwd, _scan_bwd)
 
 
 def ssd_scan(x: jax.Array, dt: jax.Array, A: jax.Array, Bm: jax.Array,
-             Cm: jax.Array, chunk: int, *,
+             Cm: jax.Array, chunk: int, *, groups: int = 1,
              interpret: bool = False) -> jax.Array:
     """``modules.ssd_chunked`` for shapes that fit :func:`tile_plan`: ``x``
-    [B, S, H, P] and ``Bm``, ``Cm`` [B, S, N] in the compute dtype, ``dt``
-    [B, S, H] and ``A`` [H] float32, ``S`` a multiple of ``chunk`` -> ``y``
-    [B, S, H, P] float32, differentiable in all five. ``interpret`` comes
-    only from the caller."""
+    [B, S, H, P] and ``Bm``, ``Cm`` [B, S, groups * N] in the compute dtype,
+    ``dt`` [B, S, H] and ``A`` [H] float32, ``S`` a multiple of ``chunk`` ->
+    ``y`` [B, S, H, P] float32, differentiable in all five. ``interpret``
+    comes only from the caller."""
     B, S, H, P = x.shape
     Q, nC = chunk, S // chunk
-    hb = tile_plan(Q, H, P, Bm.shape[-1])
+    hb = tile_plan(Q, H, P, Bm.shape[-1] // groups, groups)
     if hb is None or S % Q:
         raise ValueError(
-            f"{S} positions in chunks of {Q}, {H} heads of {P} and a state "
-            f"of {Bm.shape[-1]} fit no tile of the ssd kernels")
+            f"{S} positions in chunks of {Q}, {H} heads of {P} in {groups} "
+            f"groups and a state of {Bm.shape[-1] // groups} fit no tile of "
+            "the ssd kernels")
     rows = jnp.swapaxes(dt.reshape(B, nC, Q, H), 2, 3)      # [B, nC, H, Q]
     cs = jnp.cumsum(rows * A[:, None], axis=-1)
     last = cs[..., -1:]
@@ -360,7 +390,7 @@ def ssd_scan(x: jax.Array, dt: jax.Array, A: jax.Array, Bm: jax.Array,
                                                     4 * hb)
     a = jnp.repeat(jnp.exp(last[..., 0]), P, axis=-1)[:, :, None, :]
     y = _scan(x.reshape(B, S, H * P), cols, cs, a, Bm.astype(x.dtype),
-              Cm.astype(x.dtype), interpret)
+              Cm.astype(x.dtype), interpret, groups)
     return y.reshape(B, S, H, P)
 
 
@@ -372,8 +402,9 @@ def make_ssd_scan(mesh, dp_axes=(), *, interpret: bool = False):
 
     wide, flat = batch_spec(4, dp_axes), batch_spec(3, dp_axes)
 
-    def scan(x, dt, A, Bm, Cm, chunk):
+    def scan(x, dt, A, Bm, Cm, chunk, groups=1):
         return on_shards(
-            lambda *a: ssd_scan(*a, chunk, interpret=interpret), mesh,
+            lambda *a: ssd_scan(*a, chunk, groups=groups,
+                                interpret=interpret), mesh,
             (wide, flat, PartitionSpec(), flat, flat), wide)(x, dt, A, Bm, Cm)
     return scan

@@ -198,7 +198,8 @@ def attention_overrides(
     compiled-vs-host kernel drills run the SAME kernel on both sides).
 
     ``mixers`` (the layers' mixer kinds, ``ModelArgs.block_kinds``; None =
-    every layer attends): a layer whose kind does not attend gets no core, and
+    every layer attends): a layer whose kind does not attend, or that has no
+    mixer (a feed-forward block of a one-branch stack), gets no core, and
     a layer whose kind reads a field of :func:`block_kernels` (a ``mamba``
     layer ``ssd`` and ``conv``, a ``kda`` layer ``kda`` and ``conv``, a
     ``mamba1`` layer ``selective`` and ``conv``, a ``conv`` layer ``conv``)
@@ -216,7 +217,8 @@ def attention_overrides(
         kernels = on_tpu if kernels is None else kernels
     out: Dict[int, LayerOps] = {}
     for i, sh in enumerate(per_layer):
-        if mixers is not None and not MIXERS[mixers[i]].attends:
+        if mixers is not None and (mixers[i] is None
+                                   or not MIXERS[mixers[i]].attends):
             continue
         core = attention_core(bool(sh.cp_axes),
                               bool(sh.ulysses and sh.tp_axes), use_flash)
@@ -250,6 +252,8 @@ def attention_overrides(
     for field, make, whole, cut in block_kernels() if kernels else ():
         for i, mixer in enumerate(mixers or ()):
             sh = per_layer[i]
+            if mixer is None:   # a feed-forward block: no mixer's kernel
+                continue
             if MIXERS[mixer].reads(field) and not (whole and sh.cp_axes):
                 out[i] = replace(out.get(i, LayerOps()), **{field: make(
                     mesh, dp_axes=sh.dp_axes, interpret=flash_interpret,
@@ -303,6 +307,7 @@ def tp_overlap_overrides(
     from hetu_galvatron_tpu.analysis.eligibility import (
         MIXER_OVERLAP_REASON,
         MOE_REASON,
+        ONE_BRANCH_REASON,
         T5_REASON,
         layer_overlap_reason,
     )
@@ -321,6 +326,9 @@ def tp_overlap_overrides(
             continue
         if moe_of(cfg, i):
             fallbacks.append((i, MOE_REASON))
+            continue
+        if cfg.one_branch_blocks:
+            fallbacks.append((i, ONE_BRANCH_REASON))
             continue
         if kinds[i][0] != "full_attention":
             fallbacks.append((i, MIXER_OVERLAP_REASON[kinds[i][0]]))

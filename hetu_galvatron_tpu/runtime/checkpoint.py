@@ -966,6 +966,19 @@ _MAMBA_HF_NAMES = {"win": "mamba.in_proj.weight",
                    "dt_bias": "mamba.dt_bias", "A_log": "mamba.A_log",
                    "D": "mamba.D", "norm": "mamba.norm.weight",
                    "wout": "mamba.out_proj.weight"}
+
+
+def _mamba_to_hf(mp: Params, get) -> Dict[str, np.ndarray]:
+    """A mamba block's leaves by ``_MAMBA_HF_NAMES``' public names: the two
+    projections transposed, Conv1d's depthwise kernel [channels, 1, taps]."""
+    out = {}
+    for leaf, name in _MAMBA_HF_NAMES.items():
+        if leaf not in mp:
+            continue
+        w = get(mp[leaf]["scale"] if leaf == "norm" else mp[leaf])
+        out[name] = (w.T if leaf in ("win", "wout")
+                     else w[:, None, :] if leaf == "taps" else w)
+    return out
 # a ``latent_attention`` block's names are DeepSeek-V3's
 # (``DeepseekV3Attention``): the program's leaf -> the public name (a block
 # holds ``wq`` or the three of the low-rank step, ``ModelArgs.q_lora_rank``)
@@ -1240,6 +1253,140 @@ def _phi4flash_hf_to_params(sd: Dict[str, Any], cfg: ModelArgs) -> Params:
             "prenorm": norm("model.final_layernorm"), "head": {}}
 
 
+# the ``nemotron_h`` layout (``cfg.hf_layout``; nvidia's NemotronH,
+# ``model_type`` ``nemotron_h``): blocks of ONE branch under
+# ``backbone.layers.{i}``, the block's norm ``norm`` and whatever the block
+# is under ``mixer``: a Mamba-2 mixer (Granite's leaf names without their
+# ``mamba.`` prefix), an attention (q, k, v, o), an ungated MLP (up, down)
+# or the experts (``gate`` with its ``e_score_correction_bias``,
+# ``experts.{e}``, ``shared_experts``)
+_NEMOTRON_H_EMBED = "backbone.embeddings.weight"
+_NEMOTRON_H_FINAL = "backbone.norm_f.weight"
+
+
+def _refuse_one_branch(cfg: ModelArgs) -> None:
+    if cfg.one_branch_blocks:
+        raise NotImplementedError(
+            f"the {cfg.hf_layout} HF layout names a mixer and a "
+            "feed-forward in every block; a stack of one-branch blocks "
+            "(model.layer_types with experts / dense entries) goes out "
+            "under model.hf_layout=nemotron_h")
+
+
+def _nemotron_h_check(cfg: ModelArgs) -> None:
+    from hetu_galvatron_tpu.models.modules import _is_gated
+
+    if not cfg.one_branch_blocks or _is_gated(cfg.hidden_act) \
+            or cfg.hc_mult > 1:
+        raise NotImplementedError(
+            "the nemotron_h HF layout names blocks of one branch "
+            "(model.layer_types with experts / dense entries), ungated "
+            "MLPs (two matrices) and one residual stream")
+
+
+def _nemotron_h_params_to_hf(params: Params, cfg: ModelArgs
+                             ) -> Dict[str, np.ndarray]:
+    _nemotron_h_check(cfg)
+    get = lambda t: np.asarray(jax.device_get(t))
+    V, hd, nkv = cfg.vocab_size, cfg.head_dim, cfg.kv_heads
+    sd = {_NEMOTRON_H_EMBED: get(params["embed"]["wte"])[:V]}
+    for i, (lp, (mixer, ff)) in enumerate(zip(params["layers"],
+                                              cfg.block_kinds())):
+        pre = f"backbone.layers.{i}."
+        sd[pre + "norm.weight"] = get(lp["ln1"]["scale"])
+        pre += "mixer."
+        if mixer == "mamba":
+            sd.update({pre + name.removeprefix("mamba."): w for name, w in
+                       _mamba_to_hf(lp["mamba"], get).items()})
+        elif mixer == "full_attention":
+            nq = cfg.block_heads(i)
+            q, k, v = np.split(get(lp["attn"]["wqkv"]),
+                               [nq * hd, (nq + nkv) * hd], axis=1)
+            for name, w in (("q", q), ("k", k), ("v", v),
+                            ("o", get(lp["attn"]["wo"]))):
+                sd[pre + f"{name}_proj.weight"] = w.T
+        elif ff == "dense":
+            sd[pre + "up_proj.weight"] = get(lp["mlp"]["win"]).T
+            sd[pre + "down_proj.weight"] = get(lp["mlp"]["wout"]).T
+        elif ff == "experts":
+            mp = lp["moe"]
+            sd[pre + "gate.weight"] = get(mp["router"]).T
+            if "expert_bias" in mp:
+                sd[pre + "gate.e_score_correction_bias"] = get(
+                    mp["expert_bias"])
+            win, wout = get(mp["win"]), get(mp["wout"])
+            # held experts go out under their published indices
+            for j in range(win.shape[0]):
+                e = cfg.moe_first_held_expert + j
+                sd[pre + f"experts.{e}.up_proj.weight"] = win[j].T
+                sd[pre + f"experts.{e}.down_proj.weight"] = wout[j].T
+            if "shared" in mp:
+                sd[pre + "shared_experts.up_proj.weight"] = get(
+                    mp["shared"]["win"]).T
+                sd[pre + "shared_experts.down_proj.weight"] = get(
+                    mp["shared"]["wout"]).T
+        else:
+            raise _unknown_mixer(i, f"{mixer}/{ff}")
+    sd[_NEMOTRON_H_FINAL] = get(params["prenorm"]["scale"])
+    if not cfg.tie_word_embeddings and params.get("head"):
+        sd["lm_head.weight"] = get(params["head"]["whead"]).T[:V]
+    return sd
+
+
+def _nemotron_h_hf_to_params(sd: Dict[str, Any], cfg: ModelArgs) -> Params:
+    _nemotron_h_check(cfg)
+    layers = []
+    for i, (mixer, ff) in enumerate(cfg.block_kinds()):
+        pre = f"backbone.layers.{i}."
+        lp: Params = {"ln1": {"scale": sd[pre + "norm.weight"]}}
+        pre += "mixer."
+        if mixer == "mamba":
+            mp = {}
+            for leaf, name in _MAMBA_HF_NAMES.items():
+                name = pre + name.removeprefix("mamba.")
+                if name not in sd:
+                    continue
+                w = sd[name]
+                mp[leaf] = (w.T if leaf in ("win", "wout")
+                            else w[:, 0, :] if leaf == "taps"
+                            else {"scale": w} if leaf == "norm" else w)
+            lp["mamba"] = mp
+        elif mixer == "full_attention":
+            lp["attn"] = {
+                "wqkv": np.concatenate(
+                    [sd[pre + f"{n}_proj.weight"].T for n in "qkv"], axis=1),
+                "wo": sd[pre + "o_proj.weight"].T}
+        elif ff == "dense":
+            lp["mlp"] = {"win": sd[pre + "up_proj.weight"].T,
+                         "wout": sd[pre + "down_proj.weight"].T}
+        elif ff == "experts":
+            held = range(cfg.moe_first_held_expert,
+                         cfg.moe_first_held_expert + cfg.held_experts)
+            mp = {"router": sd[pre + "gate.weight"].T,
+                  "win": np.stack([sd[pre + f"experts.{e}.up_proj.weight"].T
+                                   for e in held]),
+                  "wout": np.stack(
+                      [sd[pre + f"experts.{e}.down_proj.weight"].T
+                       for e in held])}
+            if cfg.moe_router_enable_expert_bias:
+                mp["expert_bias"] = sd[pre + "gate.e_score_correction_bias"]
+            if cfg.num_shared_experts:
+                mp["shared"] = {
+                    "win": sd[pre + "shared_experts.up_proj.weight"].T,
+                    "wout": sd[pre + "shared_experts.down_proj.weight"].T}
+            lp["moe"] = mp
+        else:
+            raise _unknown_mixer(i, f"{mixer}/{ff}")
+        layers.append(lp)
+    wte = _pad_vocab(sd[_NEMOTRON_H_EMBED], cfg)
+    head: Params = {}
+    if not cfg.tie_word_embeddings:
+        head = {"whead": (_pad_vocab(sd["lm_head.weight"], cfg).T
+                          if "lm_head.weight" in sd else wte.T)}
+    return {"embed": {"wte": wte}, "layers": tuple(layers),
+            "prenorm": {"scale": sd[_NEMOTRON_H_FINAL]}, "head": head}
+
+
 def _unknown_mixer(i: int, mixer: str) -> ValueError:
     from hetu_galvatron_tpu.models.modules import MIXERS
 
@@ -1303,6 +1450,9 @@ def hf_to_params(state_dict: Dict[str, Any], cfg: ModelArgs) -> Params:
 
     if cfg.hf_layout == "phi4flash":
         return _phi4flash_hf_to_params(sd, cfg)
+    if cfg.hf_layout == "nemotron_h":
+        return _nemotron_h_hf_to_params(sd, cfg)
+    _refuse_one_branch(cfg)
     if cfg.model_type == "bert" or "bert.embeddings.word_embeddings.weight" in sd:
         return _bert_hf_to_params(sd, cfg)
     if cfg.model_type == "t5" or "encoder.final_layer_norm.weight" in sd:
@@ -1733,6 +1883,9 @@ def params_to_hf(params: Params, cfg: ModelArgs) -> Dict[str, np.ndarray]:
         return _t5_params_to_hf(params, cfg)
     if cfg.hf_layout == "phi4flash":
         return _phi4flash_params_to_hf(params, cfg)
+    if cfg.hf_layout == "nemotron_h":
+        return _nemotron_h_params_to_hf(params, cfg)
+    _refuse_one_branch(cfg)
     if cfg.model_type == "gpt":
         sd["transformer.wte.weight"] = get(params["embed"]["wte"])[:V]
         sd["transformer.wpe.weight"] = get(params["embed"]["wpe"])
@@ -1781,13 +1934,8 @@ def params_to_hf(params: Params, cfg: ModelArgs) -> Dict[str, np.ndarray]:
             sd[n_taps] = get(lp["conv"]["taps"])[:, None, :]
             sd[n_out] = get(lp["conv"]["wout"]).T
         elif mixer == "mamba":
-            mp = lp["mamba"]
-            for leaf, name in _MAMBA_HF_NAMES.items():
-                if leaf not in mp:
-                    continue
-                w = get(mp[leaf]["scale"] if leaf == "norm" else mp[leaf])
-                sd[pre + name] = (w.T if leaf in ("win", "wout")
-                                  else w[:, None, :] if leaf == "taps" else w)
+            sd.update({pre + name: w for name, w in
+                       _mamba_to_hf(lp["mamba"], get).items()})
         elif mixer in ("full_attention", "sliding_attention"):
             wqkv = get(lp["attn"]["wqkv"])
             q, k, v = np.split(wqkv, [nq * hd, (nq + nkv) * hd], axis=1)

@@ -62,13 +62,19 @@ _MELLUM = "mellum"
 # the second half's gated memory units and cross-attention blocks read;
 # differential attention; LayerNorms; no positions
 _PHI4FLASH = "phi4flash"
+# NemotronH (nvidia; ``model_type`` ``nemotron_h``): blocks of ONE branch by
+# ``hybrid_override_pattern`` (M a Mamba-2 mixer with groups of B and C, *
+# attention without positions, - an ungated squared-ReLU MLP, E
+# sigmoid-routed experts of the same beside a shared one)
+_NEMOTRON_H = "nemotron_h"
 # families that state for themselves whether they have positions
-_OWN_POSITIONS = {_GRANITE_HYBRID, _KIMI_LINEAR, _PHI4FLASH}
+_OWN_POSITIONS = {_GRANITE_HYBRID, _KIMI_LINEAR, _PHI4FLASH, _NEMOTRON_H}
 _ROPE_FAMILIES = {"llama", "qwen2", "mistral", "mixtral", "olmoe",
                   "qwen", _XING, _LAGUNA, _MELLUM, _KIMI_VL} \
     | _GEMMA_FAMILIES \
     | _LFM2_FAMILIES
-_RMS_FAMILIES = _ROPE_FAMILIES | {"t5", _GRANITE_HYBRID, _KIMI_LINEAR}
+_RMS_FAMILIES = _ROPE_FAMILIES | {"t5", _GRANITE_HYBRID, _KIMI_LINEAR,
+                                  _NEMOTRON_H}
 _SWIGLU_FAMILIES = {"llama", "qwen2", "mistral", "mixtral", "olmoe",
                     "qwen", _GRANITE_HYBRID, _XING, _KIMI_LINEAR,
                     _LAGUNA, _MELLUM, _KIMI_VL, _PHI4FLASH} | _LFM2_FAMILIES
@@ -202,6 +208,8 @@ def populate_model_args_from_hf(
         values.update(_mellum_values(d))
     if family == _PHI4FLASH:
         values.update(_phi4flash_values(d))
+    if family == _NEMOTRON_H:
+        values.update(_nemotron_h_values(d))
     if family == "bert":
         # HF bert uses erf gelu everywhere (BertIntermediate + the MLM
         # transform); our "gelu" is the tanh approximation (gpt2's gelu_new)
@@ -233,7 +241,8 @@ def populate_model_args_from_hf(
     # bias detection (reference hf_config_adapter.py:196-290 reads
     # attention_bias / mlp_bias / family defaults)
     # llama-likes and t5 default to no biases
-    bias_free = _ROPE_FAMILIES | {"t5", _GRANITE_HYBRID, _KIMI_LINEAR}
+    bias_free = _ROPE_FAMILIES | {"t5", _GRANITE_HYBRID, _KIMI_LINEAR,
+                                  _NEMOTRON_H}
     # (_LAGUNA is one of _ROPE_FAMILIES)
     if "attention_bias" in d:
         values["add_qkv_bias"] = bool(d["attention_bias"])
@@ -479,6 +488,86 @@ def _phi4flash_values(d: Dict[str, Any]) -> Dict[str, Any]:
         mamba1_expand=int(d.get("mamba_expand", 2)),
         mamba1_dt_rank=None if rank in (None, "auto") else int(rank),
         tie_word_embeddings=bool(d.get("tie_word_embeddings", True)))
+
+
+# ``hybrid_override_pattern``'s letters -> the words of ``layer_types``
+NEMOTRON_H_PATTERN = {"M": "mamba", "*": "full_attention", "-": "dense",
+                      "E": "experts"}
+
+
+def nemotron_h_layer_types(pattern: str) -> List[str]:
+    """``hybrid_override_pattern`` a letter a block -> ``layer_types``: a
+    stack of one-branch blocks (``ModelArgs.block_kinds``)."""
+    unknown = sorted(set(pattern) - set(NEMOTRON_H_PATTERN))
+    if unknown:
+        raise NotImplementedError(
+            f"nemotron_h hybrid_override_pattern holds {unknown}: "
+            f"{' '.join(NEMOTRON_H_PATTERN)} are implemented")
+    return [NEMOTRON_H_PATTERN[c] for c in pattern]
+
+
+def _nemotron_h_values(d: Dict[str, Any]) -> Dict[str, Any]:
+    """NemotronH's keys. A shared expert is stated as a multiple of the
+    routed width (``moe_shared_expert_intermediate_size`` over
+    ``moe_intermediate_size``, 3712 / 1856 = 2 as published); attention has
+    no positions (arXiv:2504.03624 2.1: ``rope_theta`` is read by no
+    layer); ``mamba_proj_bias`` / ``use_bias`` true is refused where the
+    block is built."""
+    family = _NEMOTRON_H
+    pattern = d.get("hybrid_override_pattern")
+    if not pattern:
+        raise NotImplementedError(
+            f"{family}: config.json names no hybrid_override_pattern")
+    types = nemotron_h_layer_types(pattern)
+    if len(types) != int(d["num_hidden_layers"]):
+        raise ValueError(
+            f"{family}: hybrid_override_pattern names {len(types)} blocks "
+            f"and num_hidden_layers is {d['num_hidden_layers']}")
+    act = d.get("mlp_hidden_act", "relu2")
+    if act != "relu2" or d.get("mamba_hidden_act", "silu") != "silu":
+        raise NotImplementedError(
+            f"{family} mlp_hidden_act={act!r}, mamba_hidden_act="
+            f"{d.get('mamba_hidden_act')!r}: relu2 and silu are implemented")
+    if d.get("n_group", 1) != 1 or d.get("topk_group", 1) != 1:
+        raise NotImplementedError(
+            f"{family} n_group={d.get('n_group')}, topk_group="
+            f"{d.get('topk_group')}: routing over groups of experts is not "
+            "implemented (the published model has one group)")
+    out: Dict[str, Any] = dict(
+        model_type="moe" if "experts" in types else "llama",
+        hf_layout="nemotron_h", hidden_act="relu2",
+        position_embedding_type="nope", layer_types=types,
+        tie_word_embeddings=bool(d.get("tie_word_embeddings", False)),
+        mamba_n_heads=int(d["mamba_num_heads"]),
+        mamba_d_head=int(d["mamba_head_dim"]),
+        mamba_d_state=int(d.get("ssm_state_size", 128)),
+        mamba_n_groups=int(d.get("n_groups", 8)),
+        mamba_d_conv=int(d.get("conv_kernel", 4)),
+        mamba_chunk_size=int(d.get("chunk_size", 128)),
+        mamba_conv_bias=bool(d.get("use_conv_bias", True)),
+        mamba_proj_bias=bool(d.get("mamba_proj_bias",
+                                   d.get("use_bias", False))))
+    if "experts" in types:
+        width = int(d["moe_intermediate_size"])
+        shared = int(d.get("n_shared_experts", 0)) * int(
+            d.get("moe_shared_expert_intermediate_size", width))
+        if shared % width:
+            raise NotImplementedError(
+                f"{family}: a shared expert of {shared} is no multiple of "
+                f"the routed width {width} (model.num_shared_experts counts "
+                "routed widths)")
+        out.update(
+            num_experts=int(d["n_routed_experts"]),
+            moe_ffn_hidden_size=width, num_shared_experts=shared // width,
+            moe_score_function="sigmoid", moe_dispatcher="dropless",
+            moe_norm_topk_prob=bool(d.get("norm_topk_prob", True)),
+            moe_norm_topk_eps=1e-20,
+            moe_routed_scaling_factor=float(
+                d.get("routed_scaling_factor", 1.0)),
+            moe_router_enable_expert_bias=True,
+            # trained with cross-entropy alone: no balancing-loss key
+            moe_aux_loss_coeff=0.0)
+    return out
 
 
 def _kimi_linear_values(d: Dict[str, Any]) -> Dict[str, Any]:

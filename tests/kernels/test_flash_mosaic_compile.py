@@ -141,12 +141,15 @@ def test_flash_forward_and_backward_compile_for_v5e(one_chip, case):
     assert hlo.count('custom_call_target="tpu_custom_call"') == 3
 
 
-# B, S, heads, head dim, state, chunk, dtype
+# B, S, heads, head dim, state, chunk, dtype[, groups of B and C]
 _SSD_CASES = {
     "granite_cell": (1, 8192, 64, 64, 128, 256, jnp.bfloat16),
     "two_rows_f32": (2, 1024, 16, 64, 128, 256, jnp.float32),
     "a_head_a_lane_tile": (1, 1024, 8, 128, 128, 128, jnp.bfloat16),
     "four_heads_a_lane_tile": (1, 512, 16, 32, 256, 256, jnp.bfloat16),
+    "nemotronh_cell_eight_groups": (2, 8192, 64, 64, 128, 128, jnp.bfloat16,
+                                    8),
+    "three_steps_a_group_f32": (1, 512, 48, 64, 128, 128, jnp.float32, 2),
 }
 
 
@@ -160,7 +163,8 @@ def test_ssd_scan_forward_and_backward_compile_for_v5e(one_chip, case):
         scope_instructions,
     )
 
-    B, S, H, P, N, Q, dtype = _SSD_CASES[case]
+    B, S, H, P, N, Q, dtype, *groups = _SSD_CASES[case]
+    G = groups[0] if groups else 1
 
     def spec(shape, dt):
         return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
@@ -168,13 +172,13 @@ def test_ssd_scan_forward_and_backward_compile_for_v5e(one_chip, case):
     def block(*a):
         with jax.named_scope("mixer/mamba"):
             with jax.named_scope("ssd"):
-                return ssd.ssd_scan(*a, Q)
+                return ssd.ssd_scan(*a, Q, groups=G)
 
     compiled = jax.jit(jax.grad(
         lambda *a: jnp.sum(block(*a)), argnums=(0, 1, 2, 3, 4))).lower(
             spec((B, S, H, P), dtype), spec((B, S, H), jnp.float32),
-            spec((H,), jnp.float32), spec((B, S, N), dtype),
-            spec((B, S, N), dtype)).compile()
+            spec((H,), jnp.float32), spec((B, S, G * N), dtype),
+            spec((B, S, G * N), dtype)).compile()
     found = scope_instructions(compiled.as_text(), (ssd.SCOPE,))
     calls = sorted(found["mosaic_calls"])
     assert len(calls) == 2, calls

@@ -134,6 +134,116 @@ def test_the_tile_plan_is_a_function_of_shapes(chunk, heads, head_dim, state,
     assert ssd.tile_plan(chunk, heads, head_dim, state) == plan
 
 
+# ---------------------------------------------------------------------------
+# B and C in several groups (Nemotron-H: head j reads group j // (H / G))
+# ---------------------------------------------------------------------------
+
+GROUPED_CHUNK = 128     # Nemotron-H's
+# as LIMITS; with bfloat16 operands the gradient to A, one number a head
+# summed over every position, stands 0.053 from the float32 recurrence's at
+# forty-eight heads (the chunked jax.numpy form stands as far)
+GROUPED_LIMITS = {**LIMITS, ("bfloat16", "sequential"): (2e-2, 7e-2)}
+
+
+def _grouped_inputs(seq, dtype, heads, groups):
+    k = jax.random.split(jax.random.key(3), 5)
+    return (jax.random.normal(k[0], (BATCH, seq, heads, HEAD_DIM)
+                              ).astype(dtype),
+            jax.nn.softplus(jax.random.normal(k[1], (BATCH, seq, heads)) - 3),
+            -jnp.exp(jax.random.normal(k[2], (heads,))),
+            jax.random.normal(k[3], (BATCH, seq, groups * STATE)
+                              ).astype(dtype),
+            jax.random.normal(k[4], (BATCH, seq, groups * STATE)
+                              ).astype(dtype))
+
+
+@functools.lru_cache(maxsize=None)
+def _grouped_sides(seq, dtype_name, heads, groups):
+    """``_sides`` with ``groups`` groups of B and C: the kernels (a grid
+    step's heads of one group, its B and C picked by the block index), the
+    ``jax.numpy`` form (groups as further batch rows) and the recurrence of
+    ``benchmark/reference/nemotron_h.py``, one position at a time."""
+    dtype = jnp.dtype(dtype_name)
+    args = _grouped_inputs(seq, dtype, heads, groups)
+    kernel = lambda *a: M.ssd_chunked(
+        *a, GROUPED_CHUNK, dtype, groups=groups,
+        scan_fn=functools.partial(ssd.ssd_scan, interpret=True))
+    chunked = lambda *a: M.ssd_chunked(*a, GROUPED_CHUNK, dtype,
+                                       groups=groups)
+    a_group = lambda t: t.reshape(t.shape[:2] + (groups, STATE))
+    sequential = lambda x, dt, A, Bm, Cm: reference.load_family(
+        "nemotron_h").selective_scan(x, dt, A, a_group(Bm), a_group(Cm))
+    as_f32 = lambda side: tuple(np.asarray(t, np.float32) for t in side)
+    return {"kernel": as_f32(_value_and_grads(kernel, args)),
+            "chunked": as_f32(_value_and_grads(chunked, args)),
+            "sequential": as_f32(_value_and_grads(
+                sequential, tuple(t.astype(jnp.float32) for t in args)))}
+
+
+@pytest.mark.parametrize("quantity", NAMES)
+@pytest.mark.parametrize("against", ["chunked", "sequential"])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+# two groups of eight heads (a grid step a group, as the cell's eight
+# groups of eight); two groups of twenty-four (three steps of eight a
+# group: their parts of dB and dC are summed outside), the last chunk padded
+@pytest.mark.parametrize("seq,heads,groups", [(256, 16, 2), (300, 48, 2)])
+def test_grouped_kernel_scan_is_the_chunked_and_the_sequential_one(
+        seq, heads, groups, dtype, against, quantity):
+    sides = _grouped_sides(seq, dtype, heads, groups)
+    at = NAMES.index(quantity)
+    got, want = sides["kernel"][at], sides[against][at]
+    assert got.shape == want.shape and np.isfinite(got).all()
+    limit = GROUPED_LIMITS[dtype, against][min(at, 1)]
+    assert _apart(got, want) < limit, (_apart(got, want), limit)
+
+
+@pytest.mark.parametrize("form", ["kernel", "chunked"])
+def test_a_group_is_the_one_group_scan_of_its_heads(form):
+    """Several groups are the one-group scan of each group's heads and
+    columns, bit for bit, values and gradients: the code one group runs is
+    the code every group runs. (The ``jax.numpy`` form takes the groups as
+    further batch rows, so its gradient to ``A``, one number a head summed
+    over the rows, adds the same terms up in another order.)"""
+    heads, groups = 16, 2
+    args = _grouped_inputs(256, jnp.float32, heads, groups)
+    scan_fn = (functools.partial(ssd.ssd_scan, interpret=True)
+               if form == "kernel" else None)
+    scan = lambda *a, **kw: M.ssd_chunked(  # noqa: E731
+        *a, GROUPED_CHUNK, jnp.float32, scan_fn=scan_fn, **kw)
+    whole = _value_and_grads(lambda *a: scan(*a, groups=groups), args)
+    per = heads // groups
+    for g in range(groups):
+        hs, cols = slice(g * per, (g + 1) * per), slice(g * STATE,
+                                                        (g + 1) * STATE)
+        x, dt, A, Bm, Cm = args
+        mine = (x[:, :, hs], dt[:, :, hs], A[hs], Bm[..., cols],
+                Cm[..., cols])
+        y, vjp = jax.vjp(scan, *mine)
+        alone = (y,) + vjp(jnp.cos(whole[0])[:, :, hs])
+        for name, got, want in zip(
+                NAMES, alone, (whole[0][:, :, hs], whole[1][:, :, hs],
+                               whole[2][:, :, hs], whole[3][hs],
+                               whole[4][..., cols], whole[5][..., cols])):
+            if (form, name) == ("chunked", "dA"):
+                np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                           rtol=1e-5)
+                continue
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(want),
+                                          err_msg=name)
+
+
+@pytest.mark.parametrize("chunk,heads,head_dim,state,groups,plan", [
+    (128, 64, 64, 128, 8, 8),     # the cell: a grid step a group
+    (128, 64, 64, 128, 4, 16),    # sixteen heads a group: the widest step
+    (128, 48, 64, 128, 2, 8),     # twenty-four a group: three steps of 8
+    (128, 64, 64, 128, 16, None),   # four heads a group fill no step
+    (128, 64, 64, 128, 3, None),    # groups that do not divide the heads
+])
+def test_the_tile_plan_keeps_a_step_inside_a_group(chunk, heads, head_dim,
+                                                   state, groups, plan):
+    assert ssd.tile_plan(chunk, heads, head_dim, state, groups) == plan
+
+
 def test_shapes_that_fit_no_tile_take_the_jax_numpy_form():
     """Chunk 8 with the kernels handed in: they are not called, nothing is
     raised, and the result is the plain one's bits."""

@@ -585,13 +585,17 @@ def test_decoding_paths_refuse_a_stated_multiplier():
 
 
 def test_the_block_says_what_it_is_not_written_for():
-    for key, value, said in (("mamba_n_groups", 2, "mamba_n_groups=2"),
+    # (groups of B and C that do not divide the heads are refused where the
+    # model is described; two groups over eight heads are run since PR 66)
+    for key, value, said in (("mamba_n_groups", 3, "mamba_n_groups=3"),
                              ("mamba_proj_bias", True, "mamba_proj_bias"),
                              ("mamba_n_heads", 0, "mamba_n_heads > 0")):
-        cfg = ModelArgs(**{**TINY, key: value})
         with pytest.raises((NotImplementedError, ValueError)) as err:
-            M.init_mamba2(jax.random.key(0), cfg)
+            M.init_mamba2(jax.random.key(0),
+                          ModelArgs(**{**TINY, key: value}))
         assert said in str(err.value)
+    M.init_mamba2(jax.random.key(0), ModelArgs(**{**TINY,
+                                                  "mamba_n_groups": 2}))
     with pytest.raises(ValueError, match="full_attention | conv | mamba"):
         M.apply_mixer({}, jnp.ones((1, 2, 32)), ModelArgs(**TINY), "window")
 

@@ -490,7 +490,8 @@ def test_both_bodies_of_the_held_dispatch(case, passes, monkeypatch):
     np.testing.assert_allclose(y, _whole_layer_by_the_reference(p, x, 2, 2),
                                rtol=1e-5, atol=2e-6)
     want = jax.grad(reference, argnums=(0, 1, 2, 3))(*args)
-    monkeypatch.setattr(moe, "short_rows", lambda slots, held, experts: slots)
+    monkeypatch.setattr(moe, "short_rows",
+                        lambda slots, held, experts, margin=1.25: slots)
     one_body, (_, stats) = grad(*args)
     assert float(stats["rows_computed"]) == slots
     assert float(stats["overflow_chunks"]) == 0.0
@@ -723,7 +724,8 @@ def test_a_skewed_router_drops_no_route_and_the_gauge_says_so(held, skewed,
 TODAY = sorted(os.path.basename(p) for p in glob.glob(
     os.path.join(ZOO, "*.yaml"))
     if not any(word in p for word in ("lfm2", "t5", "granite", "xing",
-                                      "kimi", "laguna", "mellum", "phi-4")))
+                                      "kimi", "laguna", "mellum", "phi-4",
+                                      "nemotron")))
 
 
 def _the_parents_tree(key, cfg):
