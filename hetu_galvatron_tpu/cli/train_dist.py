@@ -806,6 +806,7 @@ def train(args) -> Dict[str, Any]:
         SSD_SCOPE,
         conv_kernel_calls,
         cores_recomputed,
+        experts_kernel_calls,
         kda_kernel_calls,
         kda_loops,
         record_step_scopes,
@@ -1307,6 +1308,14 @@ def train(args) -> Dict[str, Any]:
                                 for n in found["scopes"].get(scope, ()))
                             get_registry().gauge(f"{name}/mosaic_calls").set(
                                 step_report[f"{name}_mosaic_calls"])
+                    if any(ff == "experts" for _, ff in kinds):
+                        # whether the grouped matmuls' kernels engaged:
+                        # their Mosaic calls under moe/experts, 0 =
+                        # lax.ragged_dot
+                        step_report["experts_mosaic_calls"] = (
+                            experts_kernel_calls(found))
+                        get_registry().gauge("experts/mosaic_calls").set(
+                            step_report["experts_mosaic_calls"])
                     if any(m and MIXERS[m].reads("conv") for m, _ in kinds):
                         # whether the convolution's kernels engaged: their
                         # calls by phase, one a block in each where they
@@ -1393,6 +1402,9 @@ def train(args) -> Dict[str, Any]:
                     + (", selective/mosaic_calls "
                        f"{step_report['selective_mosaic_calls']}"
                        if "selective_mosaic_calls" in step_report else "")
+                    + (", experts/mosaic_calls "
+                       f"{step_report['experts_mosaic_calls']}"
+                       if "experts_mosaic_calls" in step_report else "")
                     + (", kda/blocks {blocks} kda/chunk {chunk} "
                        "kda/mosaic_calls {mosaic_calls}".format(
                         **step_report["kda"]) if "kda" in step_report
@@ -1532,6 +1544,10 @@ def train(args) -> Dict[str, Any]:
             # selective/mosaic_calls); None for a model without such a block
             "selective_mosaic_calls": step_report.get(
                 "selective_mosaic_calls"),
+            # the program's own grouped-matmul kernels under moe/experts
+            # (the gauge experts/mosaic_calls): 0 where lax.ragged_dot ran;
+            # None for a model without an expert block
+            "experts_mosaic_calls": step_report.get("experts_mosaic_calls"),
             # the blocks that run Kimi Delta Attention, their chunk length
             # and the Mosaic calls under mixer/kda/scan, by the compiled
             # step's kernel calls or, where that is 0, its loops (the

@@ -66,8 +66,11 @@ class LayerOps:
     selective scan and the causal depthwise convolution
     (ops/pallas/); ``exchange`` runs an expert block's sorted dispatcher
     across the chips of its ``ep`` group (models/moe.py::
-    make_expert_exchange). Which kinds of block read which field:
-    :data:`MIXERS`; ``exchange`` is the expert feed-forward's."""
+    make_expert_exchange); ``grouped(mode, a, b, group_sizes, out_dtype)``
+    is the kernels of an expert block's grouped matmuls, forward and both
+    gradients (ops/pallas/grouped_matmul.py), None for shapes that fit them
+    no tile. Which kinds of block read which field: :data:`MIXERS`;
+    ``exchange`` and ``grouped`` are the expert feed-forward's."""
 
     sdpa: Optional[Callable[..., jax.Array]] = None
     cross_sdpa: Optional[Callable[..., jax.Array]] = None
@@ -78,6 +81,7 @@ class LayerOps:
     selective: Optional[Callable[..., Optional[jax.Array]]] = None
     conv: Optional[Callable[..., Optional[jax.Array]]] = None
     exchange: Optional[Callable[..., Any]] = None
+    grouped: Optional[Callable[..., Optional[jax.Array]]] = None
 
     def given(self) -> Dict[str, Any]:
         """The fields that are set, by name."""

@@ -14,13 +14,21 @@ dispatchers —
   mesh axes makes GSPMD insert the token all-to-alls the reference issues by
   hand. Over-capacity tokens are dropped (weights renormalized). This is the
   expert-parallel mode — every shape is static and ep/etp-shardable.
-* ``dropless`` (sort + ``lax.ragged_dot``, :func:`_held_dispatch`): token
+* ``dropless`` (sort + grouped matmuls, :func:`_held_dispatch`): token
   slots are sorted by expert and the MLPs of the experts the layer holds, all
   or a share, run as grouped ragged matmuls — no token is ever dropped and no
   capacity buffer is materialized (the reference's alltoall dropless
   dispatcher, token_dispatcher.py:287). Static [T*K] shapes keep it
   jit-clean; HF Mixtral numerics reproduce exactly (see
-  tests/models/test_moe.py Mixtral parity).
+  tests/models/test_moe.py Mixtral parity). The grouped matmuls
+  (:func:`_grouped_matmul`) are ``lax.ragged_dot``, the XLA form: every CPU
+  run, and on a TPU any plan that leaves a block's rows or experts to GSPMD
+  to cut. Where a block's rows and weights are whole on the chip that runs
+  it (a mesh of one TPU, or inside :func:`make_expert_exchange`) the plan
+  hands the block ``LayerOps.grouped``, the Pallas kernels of
+  ops/pallas/grouped_matmul.py, which run the forward product and both
+  gradients at every shape that fits their tiles (widths of whole lane
+  tiles) and leave the rest to ``lax.ragged_dot``.
 
 Across chips (``parallel.global_ep_deg``, the ``ep`` axes a plan carves from
 dp): the ``capacity`` einsums are left to GSPMD, which turns their sharded
@@ -283,13 +291,21 @@ def _expert_act(hproj: jax.Array, cfg: ModelArgs,
     return act(hproj)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
 def _grouped_matmul(rows: jax.Array, weights: jax.Array,
-                    group_sizes: jax.Array, out_dtype) -> jax.Array:
+                    group_sizes: jax.Array, out_dtype,
+                    grouped=None) -> jax.Array:
     """The expert layer's grouped matmul: sorted ``rows`` [M, K] through
     ``weights`` [G, K, N] by ``group_sizes`` [G]. ``lax.ragged_dot``
     accumulates in float32 and writes once, in ``out_dtype``: the dtype the
     product's first consumer reads. The compute dtype is the operands' own.
+
+    ``grouped`` (``LayerOps.grouped``: ops/pallas/grouped_matmul.py, where a
+    plan hands it) runs each of the three products, this one and the
+    backward pass's two, at the same dtypes with the same float32
+    accumulation and one rounding, and writes zeros in the rows of no
+    group; a product whose shapes fit it no tile (it answers None), and
+    every product where it is None, is ``lax.ragged_dot``'s.
 
     The backward pass keeps both transposed products in the compute dtype:
     the cotangent is rounded to it going in (as a dense matmul's is at
@@ -300,28 +316,41 @@ def _grouped_matmul(rows: jax.Array, weights: jax.Array,
     product asked for in float32 makes both of them mixed bfloat16 x float32
     kernels that write float32 for the next instruction to round. At
     float32 both passes are plain reverse mode's, number for number."""
-    return jax.lax.ragged_dot(rows, weights, group_sizes,
-                              preferred_element_type=out_dtype)
+    out = grouped and grouped("fwd", rows, weights, group_sizes, out_dtype)
+    if out is None:
+        out = jax.lax.ragged_dot(rows, weights, group_sizes,
+                                 preferred_element_type=out_dtype)
+    return out
 
 
-def _grouped_matmul_fwd(rows, weights, group_sizes, out_dtype):
-    return (_grouped_matmul(rows, weights, group_sizes, out_dtype),
+def _grouped_matmul_fwd(rows, weights, group_sizes, out_dtype, grouped):
+    return (_GROUPED_MATMUL(rows, weights, group_sizes, out_dtype, grouped),
             (rows, weights, group_sizes))
 
 
-def _grouped_matmul_bwd(out_dtype, saved, g):
+def _grouped_matmul_bwd(out_dtype, grouped, saved, g):
     rows, weights, group_sizes = saved
     # JAX's own transposes of the product in the compute dtype: the modes
     # and dimension numbers plain reverse mode would have emitted
     product = functools.partial(jax.lax.ragged_dot, group_sizes=group_sizes,
                                 preferred_element_type=rows.dtype)
     g = g.astype(rows.dtype)
-    d_weights, = jax.linear_transpose(lambda w: product(rows, w), weights)(g)
-    d_rows, = jax.linear_transpose(lambda r: product(r, weights), rows)(g)
+    kernel = lambda mode, a, b: grouped and grouped(  # noqa: E731
+        mode, a, b, group_sizes, rows.dtype)
+    d_weights = kernel("dweights", rows, g)
+    if d_weights is None:
+        d_weights, = jax.linear_transpose(
+            lambda w: product(rows, w), weights)(g)
+    d_rows = kernel("drows", g, weights)
+    if d_rows is None:
+        d_rows, = jax.linear_transpose(lambda r: product(r, weights), rows)(g)
     return d_rows, d_weights, None
 
 
 _grouped_matmul.defvjp(_grouped_matmul_fwd, _grouped_matmul_bwd)
+# the rule's forward calls the product it belongs to, whatever a test has put
+# under the module's name
+_GROUPED_MATMUL = _grouped_matmul
 
 
 def _capacity_dispatch(
@@ -438,7 +467,7 @@ def _whole_tiles(xs, win, wout):
 
 
 def _sorted_rows_mlp(cfg: ModelArgs, compute_dtype, rows, win, wout, w_rows,
-                     mine, group_sizes):
+                     mine, group_sizes, grouped=None):
     """The expert MLPs over one chunk of the sorted slots, gathered: ``rows``
     [R, H] through the grouped matmuls (``win`` / ``wout`` in the compute
     dtype) and the activation, times the routes' weights; [R, H] float32 for
@@ -457,10 +486,13 @@ def _sorted_rows_mlp(cfg: ModelArgs, compute_dtype, rows, win, wout, w_rows,
     with jax.named_scope("moe/experts"):
         hidden = xs.shape[1]
         xs, win, wout = _whole_tiles(xs, win, wout)
-        hproj = _grouped_matmul(xs, win, group_sizes, compute_dtype)
+        # (without kernels the call is the one it was, argument for
+        # argument: tests put their own product in its place)
+        product = _grouped_matmul if grouped is None else (
+            lambda *a: _grouped_matmul(*a, grouped))
+        hproj = product(xs, win, group_sizes, compute_dtype)
         hproj = _expert_act(hproj, cfg, compute_dtype)
-        ys = _grouped_matmul(hproj, wout, group_sizes,
-                             jnp.float32)[:, :hidden]
+        ys = product(hproj, wout, group_sizes, jnp.float32)[:, :hidden]
     with jax.named_scope("moe/combine"):
         if mine is not None:
             ys = jnp.where(mine, ys, 0.0)
@@ -488,7 +520,7 @@ class _Sorted(NamedTuple):
 
 
 def _counted_rows_mlp(cfg: ModelArgs, compute_dtype, first_len: int,
-                      chunk_len: int):
+                      chunk_len: int, grouped=None):
     """``layer((xt, win, wout, w), slots: _Sorted) -> y [T, H] float32``:
     :func:`_sorted_rows_mlp` over the first ``first_len`` sorted slots, then
     ``slots.passes`` (counted on the device) times over the next
@@ -528,7 +560,8 @@ def _counted_rows_mlp(cfg: ModelArgs, compute_dtype, first_len: int,
         def mlp(rows, win, wout, ws):
             return _sorted_rows_mlp(
                 cfg, compute_dtype, rows, win, wout,
-                jax.lax.dynamic_slice(ws, (at,), (length,)), mine, sizes)
+                jax.lax.dynamic_slice(ws, (at,), (length,)), mine, sizes,
+                grouped)
         return tok, rows, mlp
 
     def to_tokens(acc, tok, rows, slots):
@@ -601,6 +634,7 @@ def _counted_rows_mlp(cfg: ModelArgs, compute_dtype, first_len: int,
 def _held_dispatch(
     p: Params, xt: jax.Array, topk_idx: jax.Array, w: jax.Array,
     cfg: ModelArgs, compute_dtype, ep: int = 1, index: Any = 0,
+    grouped: Optional[Any] = None,
 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """The dropless dispatcher, sorted: a layer that holds experts ``[first,
     first + held)`` of the router's ``E``, all or a share, computes exactly
@@ -654,7 +688,7 @@ def _held_dispatch(
         rows_held = ends[-1]
         passes = (jnp.maximum(rows_held - first_len, 0) + chunk_len - 1
                   ) // chunk_len
-    y = _counted_rows_mlp(cfg, compute_dtype, first_len, chunk_len)(
+    y = _counted_rows_mlp(cfg, compute_dtype, first_len, chunk_len, grouped)(
         (xt, p["win"], p["wout"], w), _Sorted(order, ws, ends, passes, inv))
     if held == cfg.num_experts:
         return y, {}
@@ -672,7 +706,8 @@ def _held_dispatch(
 def make_expert_exchange(mesh, dp_axes: Tuple[str, ...],
                          ep_axes: Tuple[str, ...]):
     """The sorted dispatchers across the chips of an ``ep`` group: returns
-    ``exchange(p, xt, topk_idx, w, cfg, compute_dtype) -> (y, stats)``, the
+    ``exchange(p, xt, topk_idx, w, cfg, compute_dtype, grouped=None) -> (y,
+    stats)``, the
     signature of :func:`_held_dispatch`, for a plan that shards the batch
     over ``dp_axes`` and the experts over their leading ``ep_axes``
     (``runtime/mesh.py::lower_strategy``; the rest of dp is expert-dp, whose
@@ -712,7 +747,7 @@ def make_expert_exchange(mesh, dp_axes: Tuple[str, ...],
     ep = math.prod(mesh.shape[a] for a in ep_axes)
     tokens, weights = P(dp_axes, None), P(ep_axes, None, None)
 
-    def exchange(p, xt, topk_idx, w, cfg, compute_dtype):
+    def exchange(p, xt, topk_idx, w, cfg, compute_dtype, grouped=None):
         def on_chip(win, wout, xt, topk_idx, w):
             with jax.named_scope("moe/exchange/gather"):
                 xt, topk_idx, w = (
@@ -720,7 +755,7 @@ def make_expert_exchange(mesh, dp_axes: Tuple[str, ...],
                     for a in (xt, topk_idx, w))
             y, stats = _held_dispatch(
                 {"win": win, "wout": wout}, xt, topk_idx, w, cfg,
-                compute_dtype, ep, jax.lax.axis_index(ep_axes))
+                compute_dtype, ep, jax.lax.axis_index(ep_axes), grouped)
             with jax.named_scope("moe/exchange/scatter"):
                 y = jax.lax.psum_scatter(y, ep_axes, scatter_dimension=0,
                                          tiled=True)
@@ -767,6 +802,7 @@ def apply_moe_mlp(
     compute_dtype=jnp.bfloat16,
     capacity_factor: Optional[float] = None,
     exchange: Optional[Any] = None,
+    grouped: Optional[Any] = None,
 ) -> Tuple[jax.Array, jax.Array, Dict[str, jax.Array]]:
     """x [B,S,H] -> (y [B,S,H], aux_loss scalar, router stats dict).
 
@@ -777,8 +813,9 @@ def apply_moe_mlp(
     its experts the layer holds (:func:`_held_dispatch`: ragged grouped
     matmuls, exact numerics; across chips it runs inside ``exchange``, what
     a plan with ``ep`` axes hands the block as ``LayerOps.exchange``:
-    :func:`make_expert_exchange`). The router is replicated and routes the
-    chip's own tokens either way."""
+    :func:`make_expert_exchange`; ``grouped``, its ``LayerOps.grouped``, are
+    the sorted dispatcher's grouped-matmul kernels, :func:`_grouped_matmul`).
+    The router is replicated and routes the chip's own tokens either way."""
     B, S, H = x.shape
     xt = x.reshape(B * S, H)
     with jax.named_scope("moe/route"):
@@ -786,7 +823,7 @@ def apply_moe_mlp(
     if exchange is not None or held_range(cfg)[0] < cfg.num_experts \
             or cfg.moe_dispatcher == "dropless":
         y, share_stats = (exchange or _held_dispatch)(
-            p, xt, topk_idx, w, cfg, compute_dtype)
+            p, xt, topk_idx, w, cfg, compute_dtype, grouped=grouped)
         stats = {**stats, **share_stats}
     else:
         y = _capacity_dispatch(p, xt, topk_idx, w, cfg, compute_dtype,
@@ -819,11 +856,12 @@ def apply_moe_decoder_layer(
     router stats) — stats feed the per-layer balance tracker (reference
     moe_utils.py:547-644)."""
     routed: Dict[str, Any] = {}
-    exchange = block.get("ops", M.LayerOps()).exchange
+    ops = block.get("ops", M.LayerOps())
 
     def experts(h):
         y, routed["aux"], routed["stats"] = apply_moe_mlp(
-            p["moe"], h, cfg, compute_dtype=compute_dtype, exchange=exchange)
+            p["moe"], h, cfg, compute_dtype=compute_dtype,
+            exchange=ops.exchange, grouped=ops.grouped)
         return y
 
     x = M.apply_decoder_layer(p, x, cfg, compute_dtype=compute_dtype,

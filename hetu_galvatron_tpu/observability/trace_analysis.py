@@ -503,6 +503,14 @@ CONV_SCOPES = ("mixer/kda/conv", "mixer/mamba/conv", "mixer/mamba1/conv",
                "mixer/short_conv/gate_conv")
 CONV_CALLS = ("causal_conv_fwd", "causal_conv_bwd")
 
+# the scope of an expert block's grouped matmuls and the names of the
+# program's own kernels there (ops/pallas/grouped_matmul.py traces under it),
+# whose Mosaic calls the step report counts (``experts/mosaic_calls``:
+# :func:`experts_kernel_calls`)
+EXPERTS_SCOPE = "moe/experts"
+EXPERTS_CALLS = ("grouped_matmul_fwd", "grouped_matmul_drows",
+                 "grouped_matmul_dweights")
+
 # the flash forward kernel's name (ops/pallas/flash_attention.py), which its
 # instruction in the compiled step carries (``flash_attention_fwd.3``)
 FLASH_FWD_CALL = "flash_attention_fwd"
@@ -919,6 +927,19 @@ def conv_kernel_calls(found: Dict[str, Any]) -> Dict[str, int]:
             if scope in CONV_SCOPES and phase in out:
                 out[phase] += 1
     return out
+
+
+def experts_kernel_calls(found: Dict[str, Any]) -> int:
+    """The program's own grouped-matmul kernels of a step (``found``:
+    :func:`step_hlo`'s answer): the Mosaic calls named ``EXPERTS_CALLS``
+    that the map puts under ``EXPERTS_SCOPE`` by their name stack, forward,
+    recomputed and backward, a loop's body counted once. 0 where
+    ``lax.ragged_dot`` ran (libtpu's own calls for it carry no name stack
+    and none of these names; the gauge ``experts/mosaic_calls``)."""
+    placed = found["map"]["instructions"]
+    return sum(name.startswith(EXPERTS_CALLS)
+               and placed[name][0] == EXPERTS_SCOPE
+               for name in found["mosaic_calls"])
 
 
 # what ``step_hlo`` found in the step program this process last reported

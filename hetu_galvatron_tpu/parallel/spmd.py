@@ -291,6 +291,45 @@ def expert_exchange_overrides(
     return out
 
 
+def expert_kernel_overrides(
+    per_layer: List[LayerSharding],
+    mesh: Mesh,
+    cfg: ModelArgs,
+    hpc: HybridParallelConfig,
+    *,
+    kernels: Optional[bool] = None,
+    interpret: bool = False,
+) -> Dict[int, LayerOps]:
+    """``LayerOps.grouped``, the Pallas kernels of the sorted dispatcher's
+    grouped matmuls (ops/pallas/grouped_matmul.py), for every expert block
+    whose rows and expert weights are whole on the device that runs the
+    block: a mesh of one device, or a block inside the expert exchange
+    (``eligibility.takes_exchange``: a chip on its own experts, inside a
+    ``shard_map``). A Pallas call is a custom call GSPMD cannot partition,
+    so a block whose experts or rows a mesh of several devices cuts any
+    other way (``ep`` without the exchange, ``etp > 1``, plain dp) keeps
+    ``lax.ragged_dot``, as does the ``capacity`` dispatcher, which has no
+    grouped matmul. ``kernels`` None = the shared rule of
+    :func:`attention_overrides`: every mesh device is a TPU."""
+    from hetu_galvatron_tpu.analysis.eligibility import takes_exchange
+    from hetu_galvatron_tpu.ops.pallas.grouped_matmul import (
+        make_grouped_matmul,
+    )
+
+    if kernels is None:
+        kernels = flash_kernel_runs(True, mesh.devices.flat)
+    sorted_rows = (cfg.moe_dispatcher == "dropless"
+                   or cfg.held_experts < cfg.num_experts)
+    if not (kernels and sorted_rows):
+        return {}
+    ops = LayerOps(grouped=make_grouped_matmul(mesh, interpret=interpret))
+    strategies = hpc.layers[hpc.num_encoder_layers:]
+    kinds = cfg.block_kinds(len(per_layer))
+    return {i: ops for i, (_, s) in enumerate(zip(per_layer, strategies))
+            if kinds[i][1] == "experts"
+            and (mesh.size == 1 or takes_exchange(cfg, s, hpc.pp_deg))}
+
+
 def tp_overlap_overrides(
     per_layer: List[LayerSharding],
     mesh: Mesh,
@@ -578,6 +617,8 @@ def build_spmd_loss_fn(
     if cfg.num_experts and cfg.model_type != "t5":
         ring = merge_ops(ring, expert_exchange_overrides(
             per_layer, mesh, cfg, hpc))
+        ring = merge_ops(ring, expert_kernel_overrides(
+            per_layer, mesh, cfg, hpc, interpret=kernel_interpret))
     layer_overrides = merge_ops(ring, layer_overrides)
     interior, param_view = interior_sharding(per_layer, mesh, cfg,
                                              layer_overrides)

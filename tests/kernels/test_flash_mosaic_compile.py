@@ -1,5 +1,6 @@
 """The flash kernels, the kernels of the Mamba-2 scan, those of the
-chunked delta rule and those of the causal depthwise convolution, compiled by the real Mosaic / XLA:TPU compilers for a
+chunked delta rule, those of the causal depthwise convolution and those of
+the experts' grouped matmuls, compiled by the real Mosaic / XLA:TPU compilers for a
 described (not attached) TPU v5e, at
 the widths the benchmark's cells run and with every optional operand: what interpret mode cannot refuse (a slice off
 the tiling, a relayout Mosaic has no rule for, too much VMEM) fails here, on
@@ -353,6 +354,56 @@ def test_causal_conv_forward_and_backward_compile_for_v5e(one_chip, case):
     assert set(calls) <= set(found["scopes"][scope])
     assert conv_kernel_calls(found) == {"forward": 1, "recompute": 0,
                                         "backward": 1}
+
+
+# rows, groups, contracted width, columns, dtype: the product forward
+# (float32 out, as the second product of the layer is) and both gradients
+_GROUPED_CASES = {
+    # OLMoE's first product: the whole [2048, 2048] of an expert in VMEM
+    "olmoe_win": (32768, 64, 2048, 2048, jnp.bfloat16),
+    # Mellum2's second: 7 and 18 lane tiles; Laguna's first chunk of 3,200
+    # rows, whose last row tile is a quarter inside the array
+    "odd_widths_ragged_rows": (3200, 16, 896, 2304, jnp.bfloat16),
+    "f32": (640, 5, 256, 384, jnp.float32),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_GROUPED_CASES))
+def test_grouped_matmuls_three_kernels_compile_for_v5e(one_chip, case):
+    """``moe._grouped_matmul`` handed the kernels, value and both gradients
+    in one program: the three Mosaic calls, each under ``moe/experts`` by
+    its name stack (the backward rule opens the scope itself), and no
+    ``ragged-dot`` call of libtpu's."""
+    from hetu_galvatron_tpu.models import moe
+    from hetu_galvatron_tpu.observability.trace_analysis import (
+        EXPERTS_CALLS,
+        experts_kernel_calls,
+        step_hlo,
+    )
+    from hetu_galvatron_tpu.ops.pallas import grouped_matmul as gm
+
+    M, G, K, N, dtype = _GROUPED_CASES[case]
+    assert gm.tile_plan(M, G, K, N, dtype) is not None
+    spec = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt,
+                                                  sharding=one_chip)
+
+    def loss(rows, weights, sizes):
+        with jax.named_scope("moe/experts"):
+            y = moe._grouped_matmul(rows, weights, sizes, jnp.float32,
+                                    gm.grouped_matmul)
+        return jnp.sum(jnp.square(y))
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        spec((M, K), dtype), spec((G, K, N), dtype),
+        spec((G,), jnp.int32)).compile().as_text()
+    found = step_hlo(text)
+    calls = sorted(found["mosaic_calls"])
+    assert [c.split(".")[0] for c in calls] == sorted(EXPERTS_CALLS), calls
+    assert experts_kernel_calls(found) == 3
+    assert "ragged-dot" not in text
+    placed = found["map"]["instructions"]
+    assert {placed[c][:2] for c in calls} == {
+        ("moe/experts", "forward"), ("moe/experts", "backward")}
 
 
 @pytest.mark.parametrize("wrapper,forwards,recomputed", [

@@ -401,6 +401,128 @@ def test_ep_plan_reason_names_each_plan_the_exchange_does_not_serve(
     assert eligibility.ep_plan_reason(dense, layers, pp) is None
 
 
+# ---------------------------------------------------------------------------
+# which expert blocks get the grouped matmuls' kernels
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("devices, degrees, kernels, gets", [
+    # a mesh of one device: the block's rows and experts are whole on it
+    (1, {}, True, True),
+    # inside the exchange: a chip on its own experts, under a shard_map
+    (8, {"global_ep_deg": 4}, True, True),
+    # plain dp: GSPMD cuts the rows, and a Pallas call is none of its own
+    (8, {}, True, False),
+    # ep beside tp, and etp > 1: the exchange is not taken, the experts
+    # are GSPMD's to cut
+    (8, {"global_ep_deg": 2, "global_tp_deg": 2}, True, False),
+    (8, {"global_ep_deg": 2, "global_tp_deg": 2, "global_etp_deg": 2},
+     True, False),
+    # the rule of the devices (None: every device a TPU; here none is)
+    (1, {}, None, False),
+    (8, {"global_ep_deg": 4}, None, False),
+])
+def test_who_knows_the_plan_hands_an_expert_block_the_kernels(
+        devices, degrees, kernels, gets, cpu_devices):
+    """``spmd.expert_kernel_overrides``: ``LayerOps.grouped`` for an expert
+    block whose operands are local to the device that runs it, on a mesh of
+    TPUs (here: where a test says so); the capacity dispatcher and a dense
+    block never."""
+    from hetu_galvatron_tpu.parallel import spmd
+
+    args = CoreArgs(model=STACK.model_dump())
+    for k, v in degrees.items():
+        setattr(args.parallel, k, v)
+    args.parallel.global_train_batch_size = 8
+    hpc = get_hybrid_parallel_config(args, devices)
+    mesh = build_mesh(devices, 1, devices=cpu_devices[:devices])
+    _, axes = init_causal_lm_shapes(STACK)
+    per_layer = spmd._lower_specs(hpc, mesh, axes)[1]
+    got = spmd.expert_kernel_overrides(per_layer, mesh, STACK, hpc,
+                                       kernels=kernels, interpret=True)
+    assert {i: list(ops.given()) for i, ops in got.items()} == (
+        {0: ["grouped"], 1: ["grouped"]} if gets else {})
+    capacity = STACK.model_copy(update=dict(moe_dispatcher="capacity"))
+    assert spmd.expert_kernel_overrides(per_layer, mesh, capacity, hpc,
+                                        kernels=kernels) == {}
+    # and the loss the plan builds hands them on only there: the kernels'
+    # calls are in its program, or ragged_dot is
+    if kernels is None:
+        params = jax.eval_shape(
+            lambda k: init_causal_lm(k, STACK)[0], jax.random.key(0))
+        loss = spmd.build_spmd_loss_fn(STACK, hpc, mesh, axes,
+                                       compute_dtype=jnp.float32)[0]
+        batch = {k: jnp.zeros((8, 16), dt) for k, dt in (
+            ("tokens", jnp.int32), ("labels", jnp.int32),
+            ("loss_mask", jnp.float32))}
+        text = str(jax.make_jaxpr(loss)(params, batch))
+        assert "pallas_call" not in text and "ragged_dot" in text
+
+
+def init_causal_lm_shapes(cfg):
+    box = {}
+
+    def init(key):
+        p, box["axes"] = init_causal_lm(key, cfg)
+        return p
+
+    return jax.eval_shape(init, jax.random.key(0)), box["axes"]
+
+
+WIDE = LAYER.model_copy(update=dict(hidden_size=128, ffn_hidden_size=128))
+
+
+@pytest.mark.usefixtures("highest")
+def test_the_exchanged_layer_with_the_kernels_is_the_layer_without(
+        cpu_devices):
+    """Inside the exchange over four chips, at widths of a lane tile and a
+    first chunk of 1,280 rows a chip: the layer handed the kernels
+    (interpret mode: each chip's own ``pallas_call`` under the
+    ``shard_map``) against the same exchange on ``lax.ragged_dot``, output
+    and every gradient; a counted pass of 256 rows stays ``ragged_dot``'s
+    under the plan's kernels too (``grouped_matmul.PLAN_ROWS``)."""
+    from hetu_galvatron_tpu.ops.pallas.grouped_matmul import (
+        CALLS,
+        make_grouped_matmul,
+    )
+
+    mesh = build_mesh(8, 1)
+    ep_axes = DP[:2]
+    exchange = moe.make_expert_exchange(mesh, DP, ep_axes)
+    p, _ = moe.init_moe_mlp(jax.random.key(0), WIDE)
+    p = {**p, "win": 4.0 * p["win"], "wout": 4.0 * p["wout"]}
+    x = jax.random.normal(jax.random.key(1), (8, 512, 128))
+    shd = lambda spec: NamedSharding(mesh, spec)
+
+    def loss(p, x, grouped):
+        y, _, _ = moe.apply_moe_mlp(p, x, WIDE, compute_dtype=jnp.float32,
+                                    exchange=exchange, grouped=grouped)
+        return jnp.sum(jnp.sin(y)), y
+
+    def run(grouped):
+        fn = jax.jit(
+            jax.value_and_grad(lambda p, x: loss(p, x, grouped),
+                               argnums=(0, 1), has_aux=True),
+            in_shardings=({"router": shd(P()), "win": shd(P(ep_axes)),
+                           "wout": shd(P(ep_axes))}, shd(P(DP))))
+        return fn, fn(p, x)
+
+    _, ((want, want_y), want_g) = run(None)
+    fn, ((got, got_y), got_g) = run(make_grouped_matmul(mesh,
+                                                        interpret=True))
+    text = str(jax.make_jaxpr(fn)(p, x))
+    assert all(f"name={name}" in text for name in CALLS)
+    assert "ragged_dot_general[" in text      # the pass of 256 rows
+    np.testing.assert_allclose(got_y, want_y, rtol=1e-5, atol=1e-6)
+    assert abs(float(got) - float(want)) < 1e-3
+    for (path, a), (_, b) in zip(
+            jax.tree_util.tree_leaves_with_path(want_g),
+            jax.tree_util.tree_leaves_with_path(got_g)):
+        np.testing.assert_allclose(
+            b, a, rtol=1e-4, atol=1e-5 * float(jnp.abs(a).max()) + 1e-9,
+            err_msg=jax.tree_util.keystr(path))
+
+
 def test_an_ep_that_does_not_divide_the_experts_is_refused():
     odd = LAYER.model_copy(update=dict(num_experts=6))
     args = CoreArgs(model=odd.model_dump())

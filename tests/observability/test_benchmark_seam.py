@@ -222,6 +222,24 @@ def test_the_step_report_says_each_expert_layers_body(run):
                       if want else [])
 
 
+def test_the_step_report_says_whether_the_experts_kernels_engaged(run):
+    """``experts/mosaic_calls``: the program's own grouped-matmul kernels
+    under ``moe/experts`` in the compiled step. A step compiled for a CPU
+    holds none (``lax.ragged_dot`` ran), and the gauge, ``train()``'s
+    ``experts_mosaic_calls`` and the ``step report:`` line say 0; a model
+    without an expert block writes none of the three."""
+    gauges = [m.value for m in run["registry"].metrics()
+              if m.name == "experts/mosaic_calls"]
+    (line,) = [line for line in run["log"].splitlines()
+               if "step report:" in line]
+    if run["result"]["expert_bodies"]:
+        assert gauges == [0] and run["result"]["experts_mosaic_calls"] == 0
+        assert ", experts/mosaic_calls 0" in line
+    else:
+        assert not gauges and run["result"]["experts_mosaic_calls"] is None
+        assert "experts/mosaic_calls" not in line
+
+
 # the expert preset across four devices, its experts inside the exchange:
 # the plan of ``mellum2_c4_ep4`` (ep 4 carved from dp 4, a sequence a device
 # in one microbatch), whose log line counts chip by chip

@@ -191,6 +191,73 @@ def test_an_instruction_without_a_name_stack_asks_its_operands(name,
     assert scope_and_phase("ragged-dot-none") == ("moe/experts", "other")
 
 
+# the program's own grouped-matmul kernels (ops/pallas/grouped_matmul.py)
+# carry the name stack they were traced under; libtpu's for lax.ragged_dot
+# carry none
+EXPERT_KERNELS = """HloModule jit_step
+
+%body.1 (t: (f32[8])) -> (f32[8]) {
+  %t = (f32[8]{0}) parameter(0)
+  %gte.1 = f32[8]{0} get-tuple-element(%t), index=0
+  %grouped_matmul_fwd.7 = f32[8]{0} custom-call(%gte.1), custom_call_target="tpu_custom_call", metadata={op_name="jit(step)/transpose(jvp())/while/body/checkpoint/rematted_computation/moe/experts/jit(rows_call)/grouped_matmul_fwd/pallas_call"}
+  ROOT %tuple.1 = (f32[8]{0}) tuple(%grouped_matmul_fwd.7)
+}
+
+ENTRY %main (a: f32[8]) -> f32[8] {
+  %a = f32[8]{0} parameter(0)
+  %grouped_matmul_fwd.1 = f32[8]{0} custom-call(%a), custom_call_target="tpu_custom_call", metadata={op_name="jit(step)/jvp(moe/experts)/moe/experts/jit(rows_call)/grouped_matmul_fwd/pallas_call"}
+  %grouped_matmul_fwd.2 = f32[8]{0} custom-call(%a), custom_call_target="tpu_custom_call", metadata={op_name="jit(step)/transpose(jvp())/checkpoint/rematted_computation/moe/experts/jit(rows_call)/grouped_matmul_fwd/pallas_call"}
+  %grouped_matmul_drows.3 = f32[8]{0} custom-call(%a), custom_call_target="tpu_custom_call", metadata={op_name="jit(step)/transpose(jvp(moe/experts))/moe/combine/moe/experts/jit(rows_call)/grouped_matmul_drows/pallas_call"}
+  %grouped_matmul_dweights.4 = f32[8]{0} custom-call(%a), custom_call_target="tpu_custom_call", metadata={op_name="jit(step)/transpose(jvp(moe/experts))/moe/combine/moe/experts/jit(dweights_call)/grouped_matmul_dweights/pallas_call"}
+  %ragged-dot-none.5 = f32[8]{0} custom-call(%grouped_matmul_fwd.1, %a), custom_call_target="tpu_custom_call", metadata={op_name="ragged-dot-none"}
+  %flash_attention_fwd.6 = f32[8]{0} custom-call(%a), custom_call_target="tpu_custom_call", metadata={op_name="jit(step)/jvp(attn/core)/flash_attention_fwd/pallas_call"}
+  %grouped_matmul_fwd.8 = f32[8]{0} custom-call(%a), custom_call_target="tpu_custom_call", metadata={op_name="jit(step)/jvp(mlp)/grouped_matmul_fwd/pallas_call"}
+  %t.1 = (f32[8]{0}) tuple(%a)
+  %while.1 = (f32[8]{0}) while(%t.1), condition=%cond.1, body=%body.1
+  ROOT %add.1 = f32[8]{0} add(%grouped_matmul_drows.3, %grouped_matmul_dweights.4)
+}
+"""
+
+
+@pytest.mark.parametrize("name,expected", [
+    ("grouped_matmul_fwd.1", ("moe/experts", "forward", None)),
+    ("grouped_matmul_fwd.2", ("moe/experts", "recompute", None)),
+    # the backward rule runs under the scope the pull-back was called in
+    # (the combine's) and opens the experts' itself: the deepest is taken
+    ("grouped_matmul_drows.3", ("moe/experts", "backward", None)),
+    ("grouped_matmul_dweights.4", ("moe/experts", "backward", None)),
+    # a counted pass's body
+    ("grouped_matmul_fwd.7", ("moe/experts", "recompute", None)),
+    ("ragged-dot-none.5", ("moe/experts", "forward", None)),
+])
+def test_the_experts_own_kernels_are_placed_by_their_name_stack(name,
+                                                               expected):
+    assert step_hlo(EXPERT_KERNELS)["map"]["instructions"][name] == expected
+
+
+def test_experts_mosaic_calls_counts_the_programs_kernels_alone():
+    """``experts/mosaic_calls``: the Mosaic calls named as the program's
+    grouped-matmul kernels that the map puts under ``moe/experts``, every
+    phase, a loop's body once; not libtpu's ``ragged-dot`` calls (which the
+    scope's time still holds, through ``KERNEL_SCOPES``), not another
+    scope's kernel, not a call of the name outside the scope; 0 for a step
+    that ran ``lax.ragged_dot``."""
+    from hetu_galvatron_tpu.ops.pallas import grouped_matmul
+
+    assert trace_analysis.EXPERTS_SCOPE == grouped_matmul.SCOPE
+    assert trace_analysis.EXPERTS_SCOPE in SCOPES
+    assert trace_analysis.EXPERTS_CALLS == grouped_matmul.CALLS
+    # no kernel of the program's own is in the table of libtpu's names,
+    # whose comment stays true: ragged_dot is the experts' alone
+    assert set(trace_analysis.KERNEL_SCOPES) == {
+        "ragged-dot-none", "ragged-dot-metadata"}
+    found = step_hlo(EXPERT_KERNELS)
+    assert found["mosaic_custom_calls"] == 8
+    assert trace_analysis.experts_kernel_calls(found) == 5
+    assert trace_analysis.experts_kernel_calls(step_hlo(NAMELESS)) == 0
+    assert trace_analysis.experts_kernel_calls(step_hlo(ONE_CHIP)) == 0
+
+
 def test_the_map_holds_the_events_of_a_trace_and_nothing_else():
     """Instructions of a fused computation and of a reduction's applied
     computation are no events; the entry's and a loop body's are, each

@@ -60,6 +60,7 @@ def main() -> int:
     )
     from hetu_galvatron_tpu.models.modules import LayerOps
     from hetu_galvatron_tpu.ops.pallas.flash_attention import flash_sdpa
+    from hetu_galvatron_tpu.ops.pallas.grouped_matmul import grouped_matmul
     from hetu_galvatron_tpu.utils.hf_config_adapter import resolve_model_config
 
     cell = manifest.resolve_cell(manifest.load_manifest(), a.workload)
@@ -91,7 +92,9 @@ def main() -> int:
 
     params = jax.jit(lambda k: init_causal_lm(k, cfg)[0])(
         jax.random.key(a.seed))
-    sdpa = ({i: LayerOps(sdpa=flash_sdpa)
+    # the kernels the cell's plan hands its blocks on one chip: the flash
+    # core and the experts' grouped matmuls
+    sdpa = ({i: LayerOps(sdpa=flash_sdpa, grouped=grouped_matmul)
              for i in range(cfg.num_hidden_layers)}
             if dev.platform == "tpu" else None)
 
