@@ -1263,7 +1263,10 @@ def train(args) -> Dict[str, Any]:
                     step_report.update(
                         mosaic_custom_calls=found["mosaic_custom_calls"],
                         collectives=found["collectives"],
+                        relayouts=found["relayouts"],
                         scope_instructions=found["scopes"])
+                    get_registry().gauge("step/relayout_bytes").set(
+                        found["relayouts"]["bytes"])
                     # attention cores and recurrent scans run again under
                     # per-layer remat: the flash / scan forward calls the map
                     # puts in the recompute phase
@@ -1395,6 +1398,11 @@ def train(args) -> Dict[str, Any]:
                     + ", {instructions} instructions mapped ({unnamed} "
                       "under no scope, {inferred} by what they fuse)".format(
                           **step_report["step_map"])
+                    + ", step/relayout_bytes {bytes} in {count}".format(
+                        **step_report["relayouts"])
+                    + (" (the largest {opcode} {shape} <- {op_name})".format(
+                        **step_report["relayouts"]["largest"])
+                       if step_report["relayouts"]["largest"] else "")
                     + f", {step_report['mosaic_custom_calls']} Mosaic calls"
                     + (f" ({step_report['ssd_mosaic_calls']} under "
                        f"{SSD_SCOPE}), ssd/groups {cfg.mamba_n_groups}"
@@ -1529,6 +1537,12 @@ def train(args) -> Dict[str, Any]:
             # collective instructions in that step's HLO, by opcode (the
             # step/collectives gauges); None for the pp engines
             "collectives": step_report.get("collectives"),
+            # the reshape, copy and transpose instructions that step's HLO
+            # holds outside its fusions, passes over an array that compute
+            # nothing: their result bytes summed (the gauge
+            # step/relayout_bytes), their count and the largest one's
+            # opcode, shape and op_name tail; None for the pp engines
+            "relayouts": step_report.get("relayouts"),
             # instruction names of that step's HLO under each named scope a
             # state-space block has (what the granite_* readers join a
             # trace's events to; empty lists for a model without one), and

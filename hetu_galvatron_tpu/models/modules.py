@@ -2692,6 +2692,53 @@ def apply_lm_head(
         return logits
 
 
+def _at_label(logits: jax.Array, labels: jax.Array) -> jax.Array:
+    """Where a logit is its row's label's: a vocabulary iota against the
+    label, in the logits' own layout."""
+    columns = jax.lax.broadcasted_iota(labels.dtype, logits.shape,
+                                       logits.ndim - 1)
+    return columns == labels[..., None]
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _token_nll(logits: jax.Array, labels: jax.Array,
+               z_loss: float) -> jax.Array:
+    """``logsumexp - the label's logit (+ z_loss * logsumexp^2)`` a token,
+    of float32 logits: the XLA path of :func:`cross_entropy_loss`.
+
+    The label's logit is taken by compare-and-sum and the backward is
+    written out, ``exp(logits - lse) * d_lse - onehot * d_gold``: an
+    elementwise pass over the logits that XLA fuses into the operand side
+    of the head's two backward matmuls, so the logits' gradient is never
+    written. A gather's transpose is a scatter-add, which XLA:TPU ran on a
+    FLATTENED copy of a one-sequence microbatch's f32 gradient (two
+    relayouts of 824 MB each way to add 4096 numbers: 18 ms a step of
+    ``olmoe_c1_s4k``, PR 68); and autodiff through this same forward
+    compiles to an operand side that costs the weights' matmul 6 ms a step
+    more there than this one ``exp`` does (PERF.md section 6, PR 68)."""
+    return _token_nll_fwd(logits, labels, z_loss)[0]
+
+
+def _token_nll_fwd(logits, labels, z_loss):
+    lse = jax.scipy.special.logsumexp(logits, axis=-1)
+    gold = jnp.sum(jnp.where(_at_label(logits, labels), logits, 0.0),
+                   axis=-1)
+    nll = lse - gold
+    if z_loss:
+        nll = nll + z_loss * jnp.square(lse)
+    return nll, (logits, labels, lse)
+
+
+def _token_nll_bwd(z_loss, kept, g):
+    logits, labels, lse = kept
+    d_lse = g * (1.0 + 2.0 * z_loss * lse) if z_loss else g
+    return (jnp.exp(logits - lse[..., None]) * d_lse[..., None]
+            - jnp.where(_at_label(logits, labels), g[..., None], 0.0)), None
+
+
+_token_nll.defvjp(_token_nll_fwd, _token_nll_bwd)
+
+
 def cross_entropy_loss(
     logits: jax.Array,
     labels: jax.Array,
@@ -2702,9 +2749,13 @@ def cross_entropy_loss(
     """Stable mean CE over masked tokens; fp32 throughout.
 
     Vocab-parallel ready: under GSPMD a vocab-sharded logits array flows
-    through logsumexp/take with XLA-inserted collectives, replacing the
-    reference's hand-written fused_vocab_parallel_cross_entropy
-    (tensor_parallel/triton_cross_entropy.py:219-270).
+    through logsumexp and the label's compare-and-sum (:func:`_token_nll`,
+    an elementwise pass and two reduces over the sharded axis) with
+    XLA-inserted collectives, replacing the reference's hand-written
+    fused_vocab_parallel_cross_entropy
+    (tensor_parallel/triton_cross_entropy.py:219-270). That path is a
+    ``custom_vjp``: reverse mode only (``jax.grad``/``vjp``; no ``jvp``,
+    ``jacfwd`` or ``linearize`` through the loss).
 
     ``fused=True`` routes the per-token NLL through the Pallas online
     logsumexp+gather kernel (ops/pallas/cross_entropy.py) on one device;
@@ -2723,13 +2774,7 @@ def cross_entropy_loss(
 
             nll = fused_ce_nll(logits, labels, z_loss=z_loss)
         if nll is None:
-            logits = logits.astype(jnp.float32)
-            lse = jax.scipy.special.logsumexp(logits, axis=-1)
-            gold = jnp.take_along_axis(logits, labels[..., None],
-                                       axis=-1)[..., 0]
-            nll = lse - gold
-            if z_loss:
-                nll = nll + z_loss * jnp.square(lse)
+            nll = _token_nll(logits.astype(jnp.float32), labels, z_loss)
         if loss_mask is None:
             return jnp.mean(nll)
         loss_mask = loss_mask.astype(jnp.float32)

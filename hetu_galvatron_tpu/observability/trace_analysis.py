@@ -494,7 +494,15 @@ SCAN_FWD_CALLS = (KDA_FWD_CALL, "ssd_scan_fwd", "selective_scan_fwd")
 _OPERAND_SHAPES = "operand_layout_constraints="
 _TRIP_COUNT = re.compile(r'"known_trip_count":\{"n":"(\d+)"')
 _BODY = re.compile(r"body=%?([\w.\-]+)")
-_ARRAY_DIMS = re.compile(r"\b(?:pred|[sufb]\w*\d+)\[([\d,]+)\]")
+# an array as HLO prints it: its element type, that type's bits (``f32``,
+# 32; none for pred) and its dimensions (none for a scalar)
+_ARRAY = re.compile(r"\b(pred|[sufb]\w*?(\d+)\w*)\[([\d,]*)\]")
+
+
+def _array_dims(text: str, at: int = 0) -> List[str]:
+    """The printed dimensions (``"1,4096,50304"``) of the arrays of rank
+    one and more in ``text`` from ``at`` on."""
+    return [dims for _, _, dims in _ARRAY.findall(text, at) if dims]
 
 # the scopes a causal depthwise convolution is entered under, one a mixer
 # kind, and its kernels' names there (ops/pallas/conv.py), which the step
@@ -534,6 +542,10 @@ KERNEL_SCOPES = {"ragged-dot-none": "moe/experts",
                  "ragged-dot-metadata": "moe/experts"}
 # a pass of a step needs the ones before it
 _PASS_ORDER = ("forward", "recompute", "backward")
+# opcodes that, left in an OPTIMIZED HLO outside a fusion, are a pass over
+# their operand that computes nothing: XLA has made every reshape and
+# transpose that is one a ``bitcast`` by then
+RELAYOUT_OPS = ("reshape", "copy", "transpose")
 
 
 def walk_hlo(hlo_text: str):
@@ -612,12 +624,39 @@ def _op_tail(op_name: str, parts: int = 3) -> str:
     return "/".join(op_name.split("/")[-parts:])
 
 
+def result_type(line: str, opcode: str) -> str:
+    """The result type an instruction's line prints before its opcode
+    (``line`` and ``opcode`` as :func:`walk_hlo` yields them)."""
+    return line.split(" = ", 1)[1].split(f" {opcode}(", 1)[0]
+
+
+def result_bytes(shape: str) -> int:
+    """Bytes of the arrays a printed result type names
+    (``f32[1,4096,50304]{2,1,0:T(8,128)}``; a tuple's summed)."""
+    total = 0
+    for _, bits, dims in _ARRAY.findall(shape):
+        n = int(bits or 8)      # (pred: a byte)
+        for d in dims.split(","):
+            n *= int(d) if d else 1
+        total += n // 8
+    return total
+
+
 def step_hlo(hlo_text: str, own_scopes: Sequence[str] = OWN_SCOPES
              ) -> Dict[str, Any]:
     """Everything the program keeps of its compiled step's optimized HLO,
     from one walk over the text.
 
     ``mosaic_custom_calls`` and ``collectives``: see :func:`hlo_counts`.
+    ``relayouts``: the ``reshape``, ``copy`` and ``transpose`` instructions
+    (``RELAYOUT_OPS``) the optimized step still holds outside its fusions,
+    each a pass over an array that computes nothing: ``{"bytes": their
+    result bytes summed (the gauge ``step/relayout_bytes``; a loop body's
+    counted once however often it runs), "count", "largest": {"bytes",
+    "opcode", "shape", "op_name" (its tail, ``(none)`` where XLA gave it
+    none)} or None}``. (Found in PR 68:
+    ``reshape f32[206045184]`` and back, 18 ms a step of ``olmoe_c1_s4k``,
+    around the scatter-add a gathered label's logit transposes to.)
     ``scopes``, ``instructions``, ``mosaic_calls``: see
     :func:`scope_instructions` (``own_scopes`` are its ``scopes``).
 
@@ -683,7 +722,7 @@ def _step_hlo(hlo_text: str, own_scopes: Sequence[str]) -> Dict[str, Any]:
         c = comps.get(comp)
         if c is None:
             c = comps[comp] = {"rows": [], "held": {}, "inside": {},
-                               "half": None, "applies": []}
+                               "half": None, "applies": [], "relayouts": []}
         if op_name not in classify:
             classify[op_name] = scope_and_phase(op_name)
             path = _TRANSFORM.sub("", op_name) + "/"
@@ -698,6 +737,10 @@ def _step_hlo(hlo_text: str, own_scopes: Sequence[str]) -> Dict[str, Any]:
         elif opcode == "fusion":
             if calls:
                 fused.add(calls)
+        elif opcode in RELAYOUT_OPS:
+            shape = result_type(line, opcode)
+            c["relayouts"].append((result_bytes(shape), opcode, shape,
+                                   _op_tail(op_name) or "(none)"))
         elif opcode != "call" and "to_apply=" in line:
             reduction = _APPLIES.search(line).group(1)
             applied.add(reduction)
@@ -756,9 +799,11 @@ def _step_hlo(hlo_text: str, own_scopes: Sequence[str]) -> Dict[str, Any]:
     instructions: Dict[str, Tuple[Optional[str], str, Optional[str]]] = {}
     inferred: List[str] = []
     tails: Dict[str, str] = {}
+    relayouts: List[Tuple[int, str, str, str]] = []
     for comp, c in comps.items():
         if comp in fused or comp in applied:
             continue
+        relayouts += c["relayouts"]
         for name, opcode, op_name, calls, operands in c["rows"]:
             scope, phase = classify[op_name]
             if not op_name and calls in comps:
@@ -783,7 +828,13 @@ def _step_hlo(hlo_text: str, own_scopes: Sequence[str]) -> Dict[str, Any]:
             instructions[name] = (scope, phase, collective_of(opcode, calls))
             if scope is None:
                 tails[name] = _op_tail(op_name) if op_name else opcode
+    largest = max(relayouts, default=None)
     return {"mosaic_custom_calls": len(mosaic), "collectives": counts,
+            "relayouts": {
+                "bytes": sum(r[0] for r in relayouts),
+                "count": len(relayouts),
+                "largest": largest and dict(zip(
+                    ("bytes", "opcode", "shape", "op_name"), largest))},
             "scopes": found, "instructions": frozenset(names),
             "mosaic_calls": frozenset(n for n in mosaic if n in names),
             "map": {"instructions": instructions, "inferred": inferred,
@@ -830,7 +881,7 @@ def _loop_trips(line: str) -> int:
     if known:
         return int(known.group(1))
     leads = [int(dims.split(",")[0])
-             for dims in _ARRAY_DIMS.findall(line[:line.find(" while(")])
+             for dims in _array_dims(line[:line.find(" while(")])
              if dims.count(",") >= 3]
     return max(set(leads), key=leads.count) if leads else 0
 
@@ -881,7 +932,7 @@ def kda_kernel_calls(hlo_text: str) -> Dict[str, int]:
         if phase == "forward" and name.startswith(KDA_FWD_CALL):
             out["blocks"] += 1
             at = line.find(_OPERAND_SHAPES)
-            shapes = _ARRAY_DIMS.findall(line, at) if at >= 0 else ()
+            shapes = _array_dims(line, at) if at >= 0 else ()
             if len(shapes) >= 5:
                 out["chunk"] = int(shapes[4].split(",")[-2])
     return out

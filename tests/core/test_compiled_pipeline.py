@@ -55,6 +55,16 @@ def _engines(cpu_devices, cfg=CFG, **pkw):
     return host, comp, params, axes, hpc
 
 
+# to what the engines' Adam first moments (their gradients, before the
+# division by sqrt(nu)) are held equal; the most they differ by is 8e-7
+_MOMENT_ATOL = 1e-6
+
+
+def _adam(opt_state):
+    """The ScaleByAdamState (mu, nu) of an engine's optimizer state."""
+    return opt_state.inner_states["adam"].inner_state[0]
+
+
 def _batch(bsz=16, seed=0, cfg=CFG):
     data = np.random.RandomState(seed).randint(
         0, cfg.padded_vocab_size, (bsz, cfg.seq_length + 1))
@@ -77,13 +87,30 @@ def test_compiled_matches_host_engine_three_steps(cpu_devices):
         assert abs(float(cm["grad_norm"]) - hm["grad_norm"]) < 1e-4, step
     # post-step params are step-for-step equal (fp32 ulp tolerance only);
     # the compiled tree keeps ONE wte — merge_params drops the host's
-    # transposed tied copy too, so the structures line up exactly
+    # transposed tied copy too, so the structures line up exactly.
+    # Adam divides by sqrt(nu), so the one or two elements of a leaf whose
+    # gradient is itself no larger than what the engines' gradients differ
+    # by move by a visible share of lr on a rounding (layers[1].attn.wqkv
+    # [6, 35]: sqrt(nu) 1.6e-7, the leaf's least, 140 times under its
+    # median). The gradients are therefore held equal BEFORE the division,
+    # as Adam's first moments, on every element; and the parameters' own
+    # tolerance holds wherever sqrt(nu) stands above the moments' atol
     hp, cp = host.merge_params(hsp), comp.merge_params(csp)
-    for (path, a), (_, b) in zip(jax.tree_util.tree_leaves_with_path(hp),
-                                 jax.tree_util.tree_leaves_with_path(cp)):
+    hmu = host.merge_params([_adam(s).mu for s in hso])
+    hnu = host.merge_params([_adam(s).nu for s in hso])
+    cmu = comp.merge_params(_adam(cso).mu)
+    leaves = jax.tree_util.tree_leaves_with_path
+    for (path, a), (_, b), (_, ma), (_, mb), (_, nu) in zip(
+            leaves(hp), leaves(cp), leaves(hmu), leaves(cmu), leaves(hnu)):
+        key = jax.tree_util.keystr(path)
         np.testing.assert_allclose(
-            np.asarray(a), np.asarray(b), rtol=5e-4, atol=5e-5,
-            err_msg=f"param {jax.tree_util.keystr(path)}")
+            np.asarray(ma), np.asarray(mb), rtol=5e-4, atol=_MOMENT_ATOL,
+            err_msg=f"first moment {key}")
+        live = np.sqrt(np.asarray(nu)) >= _MOMENT_ATOL
+        assert live.mean() > 0.998, (key, int((~live).sum()))
+        np.testing.assert_allclose(
+            np.asarray(a)[live], np.asarray(b)[live], rtol=5e-4, atol=5e-5,
+            err_msg=f"param {key}")
     # held-out eval under the same plan agrees too
     ev = _batch(seed=99)
     assert abs(comp.eval_step(csp, ev)["loss"]

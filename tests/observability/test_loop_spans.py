@@ -231,6 +231,27 @@ def test_tp2_step_has_collectives_and_one_chip_step_has_none(tp2_run, traced):
     assert traced["result"]["collectives"] == dict.fromkeys(COLLECTIVES, 0)
 
 
+@pytest.mark.parametrize("run", ["one_device", "tp2"])
+def test_relayout_gauge_is_a_number_read_off_the_steps_hlo(
+        request, traced, run):
+    """``step/relayout_bytes``: the result bytes of the reshape, copy and
+    transpose instructions the compiled step holds outside its fusions,
+    with the largest one named in ``train()``'s result."""
+    from hetu_galvatron_tpu.observability.trace_analysis import step_hlo
+
+    reg, out = ((traced["registry"], traced["result"]) if run == "one_device"
+                else request.getfixturevalue("tp2_run"))
+    (g,) = [m for m in reg.metrics() if m.name == "step/relayout_bytes"]
+    moved = out["relayouts"]
+    assert isinstance(g.value, float) and g.value == moved["bytes"] >= 0
+    assert moved == step_hlo(out["compiled_hlo"])["relayouts"]
+    assert (moved["largest"] is None) == (moved["count"] == 0)
+    if moved["largest"]:
+        assert moved["largest"]["opcode"] in ("reshape", "copy", "transpose")
+        assert 0 < moved["largest"]["bytes"] <= moved["bytes"]
+        assert moved["largest"]["shape"] in out["compiled_hlo"]
+
+
 # (d) spans change nothing on the device -------------------------------------
 
 def test_lowered_step_is_the_same_with_and_without_a_trace_window(traced):

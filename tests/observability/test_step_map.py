@@ -607,3 +607,37 @@ def test_the_tool_says_what_is_missing(tmp_path, capsys):
     (tmp_path / trace_analysis.STEP_MAP_FILE).write_text("{}")
     assert trace_by_scope.main([str(tmp_path)]) == 2
     assert "no .xplane.pb" in capsys.readouterr().err
+
+
+def test_relayouts_are_the_passes_left_outside_fusions():
+    """A reshape, a copy and a transpose of the entry and of a loop's body
+    are counted by their result bytes, each once; a bitcast, the copy
+    inside a fusion and a ``copy-start`` prefetch are not."""
+    hlo = (
+        "HloModule jit_step\n\n"
+        "%fused_computation.1 (p: f32[8,16]) -> f32[16,8] {\n"
+        "  %p = f32[8,16]{1,0} parameter(0)\n"
+        "  ROOT %copy.9 = f32[16,8]{0,1} copy(%p)\n}\n\n"
+        "%body (q: f32[4,4096,512]) -> f32[4,4096,512] {\n"
+        "  %q = f32[4,4096,512]{2,1,0} parameter(0)\n"
+        "  ROOT %transpose.3 = bf16[4096,4,512]{2,1,0} transpose(%q), "
+        "dimensions={1,0,2}\n}\n\n"
+        "ENTRY %main (a: f32[1,4096,512]) -> f32[8] {\n"
+        "  %a = f32[1,4096,512]{2,1,0:T(8,128)} parameter(0)\n"
+        "  %reshape.1455 = f32[2097152]{0:T(1024)} reshape(%a), metadata="
+        '{op_name="jit(step)/transpose(jvp(head))/jit(take_along_axis)/'
+        'scatter-add"}\n'
+        "  %bitcast.1 = f32[4096,512]{1,0} bitcast(%a)\n"
+        "  %copy.2 = (pred[16]{0}, s8[3,5]{1,0}) copy(%a)\n"
+        "  %copy-start.1 = (f32[64]{0}, f32[64]{0}, u32[]) copy-start(%a)\n"
+        "  %fusion.1 = f32[16,8]{0,1} fusion(%a), kind=kLoop, "
+        "calls=%fused_computation.1\n"
+        "  ROOT %w = f32[4,4096,512]{2,1,0} while(%a), body=%body\n}\n")
+    moved = step_hlo(hlo)["relayouts"]
+    assert moved["count"] == 3
+    assert moved["bytes"] == 4 * 2097152 + (16 + 15) + 2 * 4096 * 4 * 512
+    assert moved["largest"] == {
+        "bytes": 2 * 4096 * 4 * 512, "opcode": "transpose",
+        "shape": "bf16[4096,4,512]{2,1,0}", "op_name": "(none)"}
+    assert trace_analysis.result_bytes("f32[1,4096,50304]{2,1,0}") == \
+        824180736

@@ -8,8 +8,10 @@ are, read from the optimized HLO of a chipless compile.
 
 The step is the one ``benchmark/aot_check.py`` compiles (the cell's own
 command line, ``make_spmd_train_step`` on a described ``v5e:2x2``); nothing
-runs. Per computation of the optimized HLO (the entry, each while body, ...)
-it prints
+runs. It prints the bytes of the ``reshape``, ``copy`` and ``transpose``
+instructions left outside fusions (``step_hlo``'s ``relayouts``: the gauge
+``step/relayout_bytes`` of a run) and, per computation of the optimized HLO
+(the entry, each while body, ...),
 
 * XLA:TPU's own ``estimated_cycles`` summed by instruction stem
   (``fusion.123`` -> ``fusion``) and by the tail of ``op_name`` (the jax
@@ -49,6 +51,7 @@ sys.path.insert(0, ROOT)
 
 from hetu_galvatron_tpu.observability.trace_analysis import (  # noqa: E402
     COLLECTIVE_OPS,
+    result_type,
     step_hlo,
     walk_hlo,
 )
@@ -77,7 +80,7 @@ def parse_hlo(text: str):
         if comp != last:
             out.append((comp, []))
             last = comp
-        shape = line.split(" = ", 1)[1].split(f" {opcode}(", 1)[0]
+        shape = result_type(line, opcode)
         cyc = _CYCLES.search(line)
         grp = _GROUPS.search(line)
         out[-1][1].append({
@@ -107,7 +110,8 @@ def report(text: str, top: int = 12):
     estimated cycle, the cycle sums and the collectives."""
     parsed = parse_hlo(text)
     owners = _owners(parsed)
-    classes = step_hlo(text, ())["map"]["instructions"]
+    found = step_hlo(text, ())
+    classes = found["map"]["instructions"]
     cycles = {c: (collections.Counter(), collections.Counter())
               for c, _ in parsed}
     coll = {c: collections.Counter() for c, _ in parsed}
@@ -164,10 +168,15 @@ def report(text: str, top: int = 12):
                 for (op, shape, grp, tail), n in sorted(
                     coll[comp].items(), key=lambda kv: (kv[0][0], -kv[1]))]})
     comps.sort(key=lambda c: -c["estimated_cycles"])
-    return {"computations": comps}
+    return {"computations": comps, "relayouts": found["relayouts"]}
 
 
 def print_report(rep, file=None):
+    moved = rep["relayouts"]
+    print("relayouts outside fusions (the gauge step/relayout_bytes): "
+          f"{moved['bytes']} bytes in {moved['count']} instructions"
+          + (", the largest {opcode} {shape} <- {op_name}".format(
+              **moved["largest"]) if moved["largest"] else ""), file=file)
     for c in rep["computations"]:
         print(f"== {c['computation']}: {c['instructions']} instructions, "
               f"{c['estimated_cycles'] / 1e6:.1f} M estimated cycles, "
