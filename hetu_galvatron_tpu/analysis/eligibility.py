@@ -154,6 +154,11 @@ KDA_REASON = ("kda block: the delta-rule block's projections are not cut "
               "for the ring all-gather / reduce-scatter matmuls, and its "
               "recurrence runs over the whole sequence on one shard")
 
+GDN_REASON = ("linear_attention block: the Gated DeltaNet block's "
+              "projections are not cut for the ring all-gather / "
+              "reduce-scatter matmuls, and its recurrence runs over the "
+              "whole sequence on one shard")
+
 MAMBA1_REASON = ("mamba1 block: the selective-scan block's projections are "
                  "not cut for the ring all-gather / reduce-scatter matmuls, "
                  "and its recurrence runs over the whole sequence on one "
@@ -190,26 +195,32 @@ ONE_BRANCH_REASON = ("a block of one branch (a stack whose layer_types name "
 MIXER_OVERLAP_REASON = {"conv": CONV_REASON, "mamba": MAMBA_REASON,
                         "latent_attention": LATENT_REASON,
                         "kda": KDA_REASON,
+                        "linear_attention": GDN_REASON,
                         "sliding_attention": WINDOW_REASON,
                         "mamba1": MAMBA1_REASON, "gmu": SHARED_REASON,
                         "cross_attention": SHARED_REASON}
 
 # the fields of ``ModelArgs`` by which a block's attention differs from the
 # model-wide description: its window, its own query heads, its own rotation,
-# its gate
+# its gate; and where its two norms sit, where that is not on the branches'
+# inputs or is a kind's own (every engine but the pp=1 training path builds
+# a pre-norm block, or BERT's)
 BLOCK_ATTENTION_FIELDS = ("sliding_window", "num_attention_heads_per_layer",
                           "rope_parameters", "gating",
-                          "differential_attention")
+                          "differential_attention", "norm_positions")
 
 
 def block_attention_stated(cfg: Any) -> List[str]:
     """``field=value`` of each of :data:`BLOCK_ATTENTION_FIELDS` the model
-    states (a window only where a block has one)."""
+    states (a window only where a block has one), and of a model-wide norm
+    on the branches' outputs."""
     windowed = "sliding_attention" in (getattr(cfg, "layer_types", None)
                                        or ())
     return [f"{k}={getattr(cfg, k)}" for k in BLOCK_ATTENTION_FIELDS
             if getattr(cfg, k, None) not in (None, False)
-            and (k != "sliding_window" or windowed)]
+            and (k != "sliding_window" or windowed)] + (
+        ["norm_position=branch"]
+        if getattr(cfg, "norm_position", None) == "branch" else [])
 
 
 def _cut_said(s: Any) -> str:
@@ -276,6 +287,22 @@ def kda_plan_reason(cfg: Any, layers: Any) -> Optional[str]:
         "its heads are not cut over the tp axis and its recurrence needs "
         "the whole sequence on one shard); use dp / ZeRO and ep for this "
         "model")
+
+
+def gdn_plan_reason(cfg: Any, layers: Any) -> Optional[str]:
+    """Why a plan cannot run this model's linear_attention blocks; None
+    when it can (or the model has none). As a kda block's: no leaf of the
+    Gated DeltaNet block carries an axis that tensor parallelism shards
+    (heads on the tp axis is not written), and its convolutions and its
+    matrix-valued state run over the whole sequence, so a plan that cuts
+    the sequence (cp, Ulysses) would carry a state across shards it cannot
+    see. A pipelined plan, ``generate()`` and the serving engine refuse the
+    mixed stack by name (:func:`mixed_stack_reason`), packed documents the
+    block itself (``modules.apply_mixer``)."""
+    return _uncut_mixer_reason(
+        cfg, layers, "linear_attention", "the Gated DeltaNet block",
+        "its heads are not cut over the tp axis and its recurrence needs "
+        "the whole sequence on one shard); use dp / ZeRO for this model")
 
 
 def mamba1_plan_reason(cfg: Any, layers: Any) -> Optional[str]:

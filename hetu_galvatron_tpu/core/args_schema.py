@@ -55,7 +55,14 @@ class ModelArgs(BaseModel):
     # None derives from the family: "post" for bert (HF BertLayer applies
     # LN after each residual; embeddings get their own LN and the final
     # norm lives in the MLM transform head), "pre" for everything else
-    norm_position: Optional[Literal["pre", "post"]] = None
+    # "branch" = the norm on a branch's OUTPUT, ``h + Norm(F(h))`` (OLMo 2
+    # and 3's blocks, HF ``Olmo3DecoderLayer``); the stack keeps its final
+    # norm. ``norm_positions``: a placement a mixer kind where the blocks of
+    # one stack differ, {"linear_attention": "pre", "full_attention":
+    # "branch"} (Olmo Hybrid); a kind without an entry takes
+    # ``norm_position``. A block reads its own through :meth:`for_block`
+    norm_position: Optional[Literal["pre", "post", "branch"]] = None
+    norm_positions: Optional[Dict[str, Literal["pre", "branch"]]] = None
     layernorm_epsilon: float = 1e-5
     # "nope" = no positions at all: no table, no rotation (a stack whose
     # state-space blocks carry the order, Granite-4.0-H)
@@ -169,7 +176,9 @@ class ModelArgs(BaseModel):
     # unit: it reads the scan output an earlier "mamba1" block left,
     # modules.apply_gmu) or "cross_attention" (its own queries over the keys
     # and values an earlier "full_attention" block left; which block leaves
-    # what: :meth:`block_shares`); None = every block attends.
+    # what: :meth:`block_shares`) or "linear_attention" (the published word
+    # of a Gated DeltaNet block: a gated delta rule with a decay a head,
+    # modules.apply_gated_delta); None = every block attends.
     # A stack of blocks of ONE branch (Nemotron-H's ``hybrid_override_
     # pattern``: one norm and one residual add a block, around a mixer OR a
     # feed-forward) states its feed-forward blocks in the same list:
@@ -184,7 +193,7 @@ class ModelArgs(BaseModel):
         List[Literal["full_attention", "conv", "mamba",
                      "latent_attention", "kda", "sliding_attention",
                      "mamba1", "gmu", "cross_attention",
-                     "experts", "dense"]]] = None
+                     "linear_attention", "experts", "dense"]]] = None
     num_dense_layers: int = 0
     # what a stack of window and full attention blocks publishes beside
     # ``layer_types`` (HF ``LagunaConfig``). ``sliding_window``: the keys a
@@ -237,8 +246,13 @@ class ModelArgs(BaseModel):
     # .mixer.* (a Mamba-2 mixer, q,k,v,o_proj, up,down_proj, or gate with
     # e_score_correction_bias / experts.{e} / shared_experts: blocks of one
     # branch) / backbone.norm_f / lm_head
+    # "olmo_hybrid" = llama's names with a linear_attention block's
+    # linear_attn.{q,k,v,a,b,g,o}_proj / {q,k,v}_conv1d / A_log / dt_bias /
+    # o_norm under attention_layer_norm / feedforward_layer_norm, and an
+    # attention block's two norms as post_attention_layernorm /
+    # post_feedforward_layernorm (Olmo 3's)
     hf_layout: Literal["llama", "lfm2", "granite", "phi4flash",
-                       "nemotron_h"] = "llama"
+                       "nemotron_h", "olmo_hybrid"] = "llama"
     # a "mamba" block (Mamba-2 / SSD; HF ``GraniteMoeHybridMambaLayer``):
     # ``mamba_n_heads`` heads of ``mamba_d_head`` channels each carry a
     # state of ``mamba_d_head x mamba_d_state`` over the sequence; B and C
@@ -287,6 +301,24 @@ class ModelArgs(BaseModel):
     kda_head_dim: int = 128
     kda_conv_kernel: int = 4
     kda_chunk_size: int = 64
+    # a "linear_attention" block (Gated DeltaNet, arXiv:2412.06464; HF
+    # ``Qwen3NextGatedDeltaNet`` under the same published keys):
+    # ``linear_num_value_heads`` heads each carry a state of
+    # ``linear_key_head_dim x linear_value_head_dim`` (keys x values) over
+    # the sequence, decayed by ONE number a head and token and updated by
+    # the delta rule with ``beta = sigmoid`` (times 2 where
+    # ``linear_allow_neg_eigval``: ``I - beta k k^T`` then has its
+    # eigenvalue along ``k`` in (-1, 1), arXiv:2411.12537); q, k and v each
+    # pass a depthwise causal convolution of ``linear_conv_kernel_dim``
+    # taps; the recurrence is computed ``linear_chunk_size`` positions at a
+    # time (fla's 64; no published key)
+    linear_num_key_heads: int = 0
+    linear_num_value_heads: int = 0
+    linear_key_head_dim: int = 128
+    linear_value_head_dim: int = 128
+    linear_conv_kernel_dim: int = 4
+    linear_allow_neg_eigval: bool = False
+    linear_chunk_size: int = 64
     # a "mamba1" block (Mamba-1's selective scan, arXiv:2312.00752; HF
     # ``MambaMixer``): ``mamba1_expand x hidden_size`` channels each carry a
     # state of ``mamba1_d_state`` values over the sequence, decayed by
@@ -442,6 +474,24 @@ class ModelArgs(BaseModel):
                 "model.layer_types names sliding_attention blocks: "
                 "model.sliding_window (the keys a query meets, its own "
                 f"included) must be positive, got {self.sliding_window!r}")
+        if "linear_attention" in kinds:
+            nk, nv = self.linear_num_key_heads, self.linear_num_value_heads
+            if nk < 1 or nv % nk:
+                raise ValueError(
+                    f"model.linear_num_value_heads={nv}: a multiple of the "
+                    f"{nk} key heads (model.linear_num_key_heads)")
+            if nv != nk:
+                raise ValueError(
+                    f"model.linear_num_value_heads={nv} over "
+                    f"model.linear_num_key_heads={nk}: a key head repeated "
+                    "for several value heads is not written (Olmo Hybrid "
+                    "publishes equal counts)")
+        for kind, where in (self.norm_positions or {}).items():
+            if kind not in kinds or self.one_branch_blocks:
+                raise ValueError(
+                    f"model.norm_positions[{kind!r}]={where!r}: a placement "
+                    "a mixer kind names a kind of model.layer_types, of a "
+                    "stack whose blocks hold a mixer and a feed-forward")
         heads = self.num_attention_heads_per_layer
         if heads is not None:
             if len(heads) != self.num_hidden_layers:
@@ -478,12 +528,17 @@ class ModelArgs(BaseModel):
 
     def for_block(self, i: int) -> "ModelArgs":
         """The model's arguments as block ``i`` reads them: its own query
-        heads as ``num_attention_heads``. The model's own where no block
+        heads as ``num_attention_heads`` and its mixer kind's norm
+        placement as ``norm_position``. The model's own where no block
         differs."""
-        if self.num_attention_heads_per_layer is None:
-            return self
-        return self.model_copy(
-            update={"num_attention_heads": self.block_heads(i)})
+        update: Dict[str, Any] = {}
+        if self.num_attention_heads_per_layer is not None:
+            update["num_attention_heads"] = self.block_heads(i)
+        if self.norm_positions:
+            where = self.norm_positions.get(self.block_kinds()[i][0])
+            if where is not None:
+                update["norm_position"] = where
+        return self.model_copy(update=update) if update else self
 
     def rope_of(self, kind: Optional[str]
                 ) -> Tuple[float, Optional[Dict[str, Any]], int]:
@@ -507,7 +562,7 @@ class ModelArgs(BaseModel):
         """The one per-layer description of a decoder stack: for each block
         its mixer kind ("full_attention", "conv", "mamba",
         "latent_attention", "kda", "sliding_attention", "mamba1", "gmu",
-        "cross_attention") and its
+        "cross_attention", "linear_attention") and its
         feed-forward kind ("dense", "experts"). The builder, the exporter, the launcher's
         report and every engine's refusal read this and nothing else.
         A stack of one-branch blocks (``layer_types`` names "experts" or
@@ -591,6 +646,16 @@ class ModelArgs(BaseModel):
         return self.kda_num_heads * self.kda_head_dim
 
     @property
+    def linear_key_dim(self) -> int:
+        """Channels of a linear_attention block's q and of its k."""
+        return self.linear_num_key_heads * self.linear_key_head_dim
+
+    @property
+    def linear_value_dim(self) -> int:
+        """Channels of a linear_attention block's v, gate and output."""
+        return self.linear_num_value_heads * self.linear_value_head_dim
+
+    @property
     def held_experts(self) -> int:
         return self.moe_held_experts or self.num_experts
 
@@ -634,6 +699,11 @@ class ModelArgs(BaseModel):
         pos = self.norm_position or (
             "post" if self.model_type == "bert" else "pre")
         return pos == "post"
+
+    @property
+    def branch_norm(self) -> bool:
+        """True = the norm on each branch's output, ``h + Norm(F(h))``."""
+        return self.norm_position == "branch"
 
     # bias flags (HF adapter detects these per family, e.g. qwen2 qkv bias)
     add_bias_linear: bool = True

@@ -754,6 +754,14 @@ def model_flops_per_token(model: Any, seq_length: Optional[int] = None
         mixer["kda"] = (
             2 * h * (3 * inner + 2 * d + model.kda_num_heads)
             + 2 * 2 * d * inner + 2 * inner * h + 6 * inner * d)
+    if getattr(model, "linear_num_value_heads", 0):
+        # Gated DeltaNet: q | k | v, the decay's and beta's one value a
+        # head, the full-rank output gate, out_proj, and the recurrence as
+        # the recurrence (2 each per state element, three times)
+        kd, vd = model.linear_key_dim, model.linear_value_dim
+        mixer["linear_attention"] = (
+            2 * h * (2 * kd + 2 * vd + 2 * model.linear_num_value_heads)
+            + 2 * vd * h + 6 * vd * model.linear_key_head_dim)
     if any(m in ("mamba1", "gmu") for m, _ in kinds):
         # a Mamba-1 block's four projections (the recurrence is no matmul:
         # a decay a channel and state index, on the vector unit) and a

@@ -254,6 +254,14 @@ class RuntimeProfiler:
             # most loaded expert's rows (the longest grouped matmul)
             for name in sorted(metrics["moe"]):
                 st = metrics["moe"][name]
+                if "beta_over_one" in st:
+                    # a Gated DeltaNet block's one count: the share of
+                    # (position, head) whose delta step overshoots
+                    over = 100.0 * float(st["beta_over_one"])
+                    bits.append(f"gdn[{name}] beta>1 {over:.1f}%")
+                    self.registry.gauge("gated_delta/beta_over_one_pct",
+                                        layer=name).set(over)
+                    continue
                 tpe = np.asarray(st["tokens_per_expert"], dtype=float)
                 if "rows_held" in st:
                     # a layer that holds a share of its experts: the routes

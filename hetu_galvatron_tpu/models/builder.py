@@ -158,7 +158,7 @@ def rope_table(cfg: ModelArgs, seq: int, kind: Optional[str],
 
 def make_block(cfg: ModelArgs, kind: Tuple[str, str],
                kwargs: Dict[str, Any], remat, leaves: bool = False):
-    """``fn(block params, x, shared) -> (x, aux loss, router stats, made)``
+    """``fn(block params, x, shared) -> (x, aux loss, the block's stats, made)``
     of one block of ``kind`` with its keyword arguments, rematerialized
     where asked (``remat``: the block's flag, :func:`modules.recomputed`).
     ``shared`` holds what the block reads of earlier blocks
@@ -174,8 +174,13 @@ def make_block(cfg: ModelArgs, kind: Tuple[str, str],
             handed = dict(handed, made=made)
         if kind[1] == "experts":
             return apply_moe_decoder_layer(p, h, cfg, **handed) + (made,)
+        # the counts a mixer kind writes for a logged step's line ride
+        # where an expert layer's do
+        stats: Dict[str, jax.Array] = {}
+        if kind[0] is not None and M.mixer_of(kind[0]).counts:
+            handed = dict(handed, stats=stats)
         return (M.apply_decoder_layer(p, h, cfg, **handed),
-                jnp.zeros((), jnp.float32), {}, made)
+                jnp.zeros((), jnp.float32), stats, made)
 
     return M.recomputed(fn, cfg, remat)
 

@@ -473,7 +473,10 @@ def remat(fn, cfg: ModelArgs):
     recomputed forward then holds none of them. A block that ran no such
     kernel (the XLA core, a mixer in its ``jax.numpy`` form, an MLP alone)
     traces no name and is recomputed whole; the convolution's kernels name
-    nothing either."""
+    nothing either. A linear_attention block's scan has no kernel
+    (:func:`gated_delta_chunked`) and names nothing: its backward is the
+    scan's own transpose, which wants every chunk's operands again, so a
+    kept output would save no pass."""
     from hetu_galvatron_tpu.ops.pallas import (
         flash_attention,
         kda,
@@ -2024,6 +2027,30 @@ def kda_pairs(q: jax.Array, k: jax.Array, G: jax.Array, sub: int,
     return tuple(out)
 
 
+def delta_carry(state: jax.Array, chunk_of, compute_dtype=jnp.bfloat16):
+    """One chunk of a delta rule's scan over chunks, what :func:`kda_chunked`
+    and :func:`gated_delta_chunked` carry alike: the state ``S`` [B, H, d,
+    dv] float32 enters, ``V' = U - W S``, ``o = (Q * exp(G)) S + A_qk V'``,
+    and ``S' = decay * S + (K * exp(G_last - G))^T V'`` leaves. ``chunk_of``
+    = (``w``, ``u``, ``a_qk``, ``q_in``, ``k_out``, ``decay``) of the chunk,
+    what does not depend on ``S``; ``decay`` [B, H, d] a channel, or [B, H,
+    1] where a head decays as one. Matmul operands ``compute_dtype``,
+    accumulation and the state float32."""
+    f32 = jnp.float32
+    w, u, a_qk, q_in, k_out, decay = chunk_of
+    s = state.astype(compute_dtype)
+    v_new = u - jnp.einsum("bhcd,bhde->bhce", w, s,
+                           preferred_element_type=f32)
+    vc = v_new.astype(compute_dtype)
+    o = (jnp.einsum("bhcd,bhde->bhce", q_in, s,
+                    preferred_element_type=f32)
+         + jnp.einsum("bhij,bhje->bhie", a_qk, vc,
+                      preferred_element_type=f32))
+    state = decay[..., None] * state + jnp.einsum(
+        "bhcd,bhce->bhde", k_out, vc, preferred_element_type=f32)
+    return state, o
+
+
 def kda_chunked(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
                 beta: jax.Array, chunk: int,
                 compute_dtype=jnp.bfloat16,
@@ -2107,19 +2134,7 @@ def kda_chunked(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
                 (kf * jnp.exp(last - G)).astype(compute_dtype),
                 jnp.exp(last[..., 0, :]))
 
-    def carry(state, chunk_of):
-        w, u, a_qk, q_in, k_out, decay = chunk_of
-        s = state.astype(compute_dtype)
-        v_new = u - jnp.einsum("bhcd,bhde->bhce", w, s,
-                               preferred_element_type=f32)
-        vc = v_new.astype(compute_dtype)
-        o = (jnp.einsum("bhcd,bhde->bhce", q_in, s,
-                        preferred_element_type=f32)
-             + jnp.einsum("bhij,bhje->bhie", a_qk, vc,
-                          preferred_element_type=f32))
-        state = decay[..., None] * state + jnp.einsum(
-            "bhcd,bhce->bhde", k_out, vc, preferred_element_type=f32)
-        return state, o
+    carry = partial(delta_carry, compute_dtype=compute_dtype)
 
     def group(state, args):
         return jax.lax.scan(carry, state, tuple(
@@ -2210,6 +2225,210 @@ def apply_kda(
     return out.astype(compute_dtype)
 
 
+# ---------------------------------------------------------------------------
+# Gated DeltaNet (the published ``linear_attention``: a delta rule whose state
+# decays by one number a head)
+# ---------------------------------------------------------------------------
+
+
+def init_gated_delta(key: jax.Array, cfg: ModelArgs) -> Tuple[Params, Axes]:
+    """HF ``Qwen3NextGatedDeltaNet``'s parameters under Olmo Hybrid's
+    published keys (fla's ``GatedDeltaNet``, arXiv:2412.06464): ``wqkv`` is
+    ``q_proj | k_proj | v_proj`` side by side, ``taps`` the three depthwise
+    kernels ``[q | k | v channels, taps]`` (``{q,k,v}_conv1d.weight[:, 0,
+    :]``, no bias), ``wab`` is ``a_proj | b_proj`` (the decay's and
+    ``beta``'s one value a head), ``wg`` the output gate's full-rank
+    ``g_proj``, ``dt_bias`` and ``A_log`` one value a head, ``norm`` the
+    output norm's scale of ``linear_value_head_dim`` shared by the heads,
+    ``wout`` is ``o_proj``. No leaf carries an axis name that tensor
+    parallelism shards (``eligibility.gdn_plan_reason``).
+
+    ``A_log`` and ``dt_bias`` start as fla's layer starts them: ``A``
+    uniform in (0, 16], ``dt`` log-uniform in [1e-3, 1e-1] and ``dt_bias``
+    its inverse softplus."""
+    if cfg.normalization != "rmsnorm":
+        raise ValueError("a linear_attention block's output norm is an "
+                         "RMSNorm (normalization=rmsnorm)")
+    kda_sub_blocks(cfg.linear_chunk_size)   # raises for a chunk it cannot cut
+    h, nh = cfg.hidden_size, cfg.linear_num_value_heads
+    kd, vd = cfg.linear_key_dim, cfg.linear_value_dim
+    k1, k2, k3, k4, k5, k6, k7 = jax.random.split(key, 7)
+    std = 0.02
+    dt = jnp.exp(jax.random.uniform(k6, (nh,), jnp.float32,
+                                    math.log(1e-3), math.log(1e-1)))
+    p: Params = {
+        "wqkv": _normal(k1, (h, 2 * kd + vd), std),
+        # the variance of torch's Conv1d default, as the conv block's taps
+        "taps": _normal(k2, (2 * kd + vd, cfg.linear_conv_kernel_dim),
+                        1.0 / math.sqrt(3 * cfg.linear_conv_kernel_dim)),
+        "wab": _normal(k3, (h, 2 * nh), std),
+        "wg": _normal(k4, (h, vd), std),
+        "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+        "A_log": jnp.log(16.0 * (1.0 - jax.random.uniform(
+            k7, (nh,), jnp.float32))),
+        "norm": {"scale": jnp.ones((cfg.linear_value_head_dim,),
+                                   jnp.float32)},
+        "wout": _normal(k5, (vd, h),
+                        std / math.sqrt(2 * cfg.num_hidden_layers)),
+    }
+    a: Axes = {"wqkv": ("embed", "gdn_proj"),
+               "taps": ("gdn_proj", "conv_tap"),
+               "wab": ("embed", "gdn_low"), "wg": ("embed", "gdn_inner"),
+               "dt_bias": ("gdn_head",), "A_log": ("gdn_head",),
+               "norm": {"scale": ("gdn_width",)},
+               "wout": ("gdn_inner", "embed")}
+    return p, a
+
+
+def gated_delta_chunked(q: jax.Array, k: jax.Array, v: jax.Array,
+                        g: jax.Array, beta: jax.Array, chunk: int,
+                        compute_dtype=jnp.bfloat16) -> jax.Array:
+    """The gated delta rule with a decay a HEAD (Gated DeltaNet,
+    arXiv:2412.06464) in its chunked form. Per head, with the state ``S``
+    [d, dv] (keys x values), zero before the sequence::
+
+        S~  = exp(g_t) S_(t-1)
+        S_t = S~ + beta_t k_t (v_t - S~^T k_t)^T ;   o_t = S_t^T q_t
+
+    ``q``, ``k`` [B, S, H, d] (``k`` of unit length, ``q`` with its scale);
+    ``v`` [B, S, H, dv]; ``g`` [B, S, H] float32, the log decay, <= 0;
+    ``beta`` [B, S, H] float32, in (0, 2). Returns ``o`` [B, S, H, dv]
+    float32.
+
+    :func:`kda_chunked`'s lines with ``G``, the running sum of ``g`` inside
+    a chunk, one number for all of a head's channels: the decay between two
+    positions then leaves the contraction, and a chunk's pair matrices are
+    ONE matmul each times the ``[C, C]`` matrix ``exp(G_i - G_j)`` (masked
+    BEFORE the exp: above the diagonal the exponent is positive), with none
+    of :func:`kda_pairs`' sub-blocks. ``A_ij = beta_i (k_i . k_j) exp(G_i -
+    G_j)`` (j < i), ``T = (I + A)^-1 Diag(beta)``
+    (:func:`unit_lower_inverse`), ``W = T (K * exp(G))``, ``U = T V``; then
+    the scan over the chunks that carries ``S`` (:func:`delta_carry`). What
+    does not depend on ``S`` is made for all chunks at once: at one number
+    a head it is ``[B, chunks, H, C, C]`` float32 and a few arrays of the
+    inputs' size, 31 MB a sequence of 4096 at 30 heads. Sums of ``g``,
+    every exp, the inverse and the carried state are float32; the matmul
+    operands are ``compute_dtype`` with float32 accumulation. A sequence
+    that ``chunk`` does not divide is padded with ``g = 0`` and ``beta = 0``
+    (no decay, no update), and the padding cut off."""
+    f32 = jnp.float32
+    B_, S, H, _ = q.shape
+    pad = -S % chunk
+    if pad:
+        q, k, v, g, beta = (
+            jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
+            for t in (q, k, v, g, beta))
+    C, nC = chunk, (S + pad) // chunk
+    sub, _ = kda_sub_blocks(C)
+
+    def chunks(t):   # [B, S, H, ...] -> [chunks, B, H, C, ...]
+        t = jnp.swapaxes(t.reshape((B_, nC, C) + t.shape[2:]), 2, 3)
+        return jnp.moveaxis(t, 1, 0)
+
+    qc, kc, vc = (chunks(t.astype(compute_dtype)) for t in (q, k, v))
+    G = jnp.cumsum(chunks(g), axis=-1)                       # [.., C]
+    bc = chunks(beta)
+    decay = jnp.exp(jnp.where(jnp.tril(jnp.ones((C, C), bool)),
+                              G[..., :, None] - G[..., None, :], -jnp.inf))
+    pairs = lambda a: jnp.einsum("...id,...jd->...ij", a, kc,
+                                 preferred_element_type=f32) * decay
+    # the inverse reads the strictly lower triangle
+    T = (unit_lower_inverse(pairs(kc) * bc[..., None], sub)
+         * bc[..., None, :]).astype(compute_dtype)
+    kf, last = kc.astype(f32), G[..., -1:]
+    grown = jnp.exp(G)[..., None]
+    w = jnp.einsum("...ij,...jd->...id", T,
+                   (kf * grown).astype(compute_dtype),
+                   preferred_element_type=f32).astype(compute_dtype)
+    u = jnp.einsum("...ij,...je->...ie", T, vc, preferred_element_type=f32)
+    _, o = jax.lax.scan(
+        partial(delta_carry, compute_dtype=compute_dtype),
+        jnp.zeros((B_, H, q.shape[-1], v.shape[-1]), f32),
+        (w, u, pairs(qc).astype(compute_dtype),
+         (qc.astype(f32) * grown).astype(compute_dtype),
+         (kf * jnp.exp(last - G)[..., None]).astype(compute_dtype),
+         jnp.exp(last)))
+    # [chunks, B, H, C, dv] -> [B, chunks, C, H, dv]
+    o = jnp.swapaxes(jnp.moveaxis(o, 0, 1), 2, 3)
+    return o.reshape(B_, nC * C, H, -1)[:, :S]
+
+
+def apply_gated_delta(
+    p: Params,
+    x: jax.Array,
+    cfg: ModelArgs,
+    compute_dtype=jnp.bfloat16,
+    conv_fn: Optional[Callable[..., Optional[jax.Array]]] = None,
+    stats: Optional[Dict[str, jax.Array]] = None,
+) -> jax.Array:
+    """``[q~ | k~ | v] = silu(conv1d_causal(x W_qkv))`` (depthwise,
+    ``linear_conv_kernel_dim`` taps, zero history before the sequence, no
+    bias); a head's ``q = q~ / sqrt(|q~|^2 + 1e-6) * d^-0.5`` and ``k = k~ /
+    sqrt(|k~|^2 + 1e-6)``; the log decay a head ``g = -exp(A_log) softplus(x
+    W_a + dt_bias)``; ``beta = sigmoid(x W_b)`` a head, times 2 under
+    ``linear_allow_neg_eigval``; ``o`` by the gated delta rule
+    (:func:`gated_delta_chunked`); ``y = RMSNorm(o) * w * silu(x W_g)`` a
+    head, the norm BEFORE the gate (HF ``Qwen3NextRMSNormGated``); ``y
+    W_out``. No softmax, no positions. The projections and the recurrence's
+    matmuls run in ``compute_dtype`` with float32 accumulation; the
+    convolution, the L2 norms, the decay, ``beta``, the state and the gated
+    norm are float32. ``conv_fn``: the kernels for the convolution of the
+    three at once and its SiLU (:func:`causal_depthwise_conv`); a head of
+    96 is no lane tile, so the L2 norms do not ride in its pass. ``stats``
+    takes ``beta_over_one``, the share of (position, head) whose delta step
+    overshoots (``beta > 1``)."""
+    B, S, _ = x.shape
+    nh, dk, dv = (cfg.linear_num_value_heads, cfg.linear_key_head_dim,
+                  cfg.linear_value_head_dim)
+    kd = cfg.linear_key_dim
+    f32 = jnp.float32
+
+    def proj(a, w):
+        return jnp.einsum("bsh,hc->bsc", a.astype(compute_dtype),
+                          weight_view(w, compute_dtype),
+                          preferred_element_type=f32)
+
+    def unit(t):    # a head of unit length
+        t = t.astype(f32).reshape(B, S, nh, dk)
+        return t * jax.lax.rsqrt(
+            jnp.sum(jnp.square(t), axis=-1, keepdims=True) + KDA_L2_EPS)
+
+    with jax.named_scope("mixer/gdn"):
+        with jax.named_scope("in_proj"):
+            qkv = proj(x, p["wqkv"]).astype(compute_dtype)
+            a, b = jnp.split(proj(x, p["wab"]), 2, axis=-1)
+            z = proj(x, p["wg"]).astype(compute_dtype)
+        with jax.named_scope("conv"):
+            q, k, v = jnp.split(
+                causal_depthwise_conv(
+                    qkv, p["taps"], silu=True, out_dtype=compute_dtype,
+                    conv_fn=conv_fn, scope="mixer/gdn/conv"),
+                [kd, 2 * kd], axis=-1)
+        with jax.named_scope("gates"):
+            q = (unit(q) * dk ** -0.5).astype(compute_dtype)
+            k = unit(k).astype(compute_dtype)
+            g = (-jnp.exp(p["A_log"].astype(f32))
+                 * jax.nn.softplus(a + p["dt_bias"]))
+            beta = jax.nn.sigmoid(b)
+            if cfg.linear_allow_neg_eigval:
+                beta = 2.0 * beta
+            if stats is not None:
+                stats["beta_over_one"] = jnp.mean((beta > 1.0).astype(f32))
+        with jax.named_scope("scan"):
+            o = gated_delta_chunked(q, k, v.reshape(B, S, nh, dv), g, beta,
+                                    cfg.linear_chunk_size, compute_dtype)
+        with jax.named_scope("gated_norm"):
+            var = jnp.mean(jnp.square(o), axis=-1, keepdims=True)
+            y = (o * jax.lax.rsqrt(var + cfg.layernorm_epsilon)
+                 * p["norm"]["scale"]).reshape(B, S, nh * dv)
+            y = (y * jax.nn.silu(z.astype(f32))).astype(compute_dtype)
+        with jax.named_scope("out_proj"):
+            out = jnp.einsum("bsc,ch->bsh", y,
+                             weight_view(p["wout"], compute_dtype),
+                             preferred_element_type=f32)
+    return out.astype(compute_dtype)
+
+
 class Mixer(NamedTuple):
     """One kind of block operator: the block's key for its parameters,
     ``init(key, cfg) -> (params, axes)``, ``apply(params, h, cfg, ...)``,
@@ -2224,7 +2443,9 @@ class Mixer(NamedTuple):
     ``made=``) or takes of an earlier one (``takes``; ``apply`` takes
     ``shared=``):
     ``args_schema.SHARED_VALUES`` by kind, ``ModelArgs.block_shares`` by
-    block."""
+    block; and the counts a block of it writes for the log line of a logged
+    step (``counts``; ``apply`` then takes ``stats=``, a dict, and they ride
+    out of the step where an expert layer's do)."""
 
     key: str
     init: Callable
@@ -2236,6 +2457,7 @@ class Mixer(NamedTuple):
     crosses_documents: Optional[str] = None
     leaves: Tuple[str, ...] = ()
     takes: Tuple[str, ...] = ()
+    counts: Tuple[str, ...] = ()
 
     def reads(self, field: str) -> bool:
         return field in self.ops.values()
@@ -2264,6 +2486,11 @@ MIXERS: Dict[str, Mixer] = {
         "kda", init_kda, apply_kda, False,
         {"kda_fn": "kda", "conv_fn": "conv"}, "kda",
         uncut_reason="kda_plan_reason", crosses_documents=_CARRIED),
+    "linear_attention": Mixer(
+        "gdn", init_gated_delta, apply_gated_delta, False,
+        {"conv_fn": "conv"}, "gdn",
+        uncut_reason="gdn_plan_reason", crosses_documents=_CARRIED,
+        counts=("beta_over_one",)),
     "sliding_attention": Mixer(
         "attn", init_attention, partial(apply_attention, windowed=True),
         True, {"sdpa_fn": "sdpa"}, uncut_reason="window_plan_reason"),
@@ -2282,6 +2509,12 @@ MIXERS: Dict[str, Mixer] = {
         {"sdpa_fn": "sdpa"}, uncut_reason="shared_plan_reason",
         takes=SHARED_VALUES["cross_attention"][0]),
 }
+
+
+def writes_counts(cfg: ModelArgs) -> bool:
+    """Whether a block of the stack writes counts (:class:`Mixer`)."""
+    return any(m is not None and MIXERS[m].counts
+               for m, _ in cfg.block_kinds())
 
 
 def mixer_of(mixer: str) -> Mixer:
@@ -2306,6 +2539,7 @@ def apply_mixer(
     shared: Optional[Dict[str, jax.Array]] = None,
     made: Optional[Dict[str, jax.Array]] = None,
     lambda_init: Optional[float] = None,
+    stats: Optional[Dict[str, jax.Array]] = None,
 ) -> jax.Array:
     """A block's operator on its normed input, by the block's mixer kind
     (``ModelArgs.block_kinds``, a row of :data:`MIXERS`), from the block's
@@ -2316,7 +2550,8 @@ def apply_mixer(
     earlier blocks left (a kind that reads is handed the values its row
     names) and ``made``, where this block leaves something, is the dict
     its ``apply`` writes it into. ``lambda_init``: the block's constant
-    under ``cfg.differential_attention`` (:func:`diff_lambda_init`)."""
+    under ``cfg.differential_attention`` (:func:`diff_lambda_init`).
+    ``stats``: the dict a kind that writes counts writes them into."""
     row = mixer_of(mixer)
     if not row.attends and segment_ids is not None:
         raise NotImplementedError(
@@ -2348,6 +2583,8 @@ def apply_mixer(
             raise ValueError(f"a {mixer} block leaves nothing for later "
                              "blocks")
         kwargs["made"] = made
+    if stats is not None and row.counts:
+        kwargs["stats"] = stats
     return row.apply(p[row.key], h, cfg, compute_dtype=compute_dtype,
                      **kwargs)
 
@@ -2515,6 +2752,7 @@ def apply_decoder_layer(
     shared: Optional[Dict[str, jax.Array]] = None,
     made: Optional[Dict[str, jax.Array]] = None,
     lambda_init: Optional[float] = None,
+    stats: Optional[Dict[str, jax.Array]] = None,
 ) -> jax.Array:
     """Pre-norm residual block (reference GalvatronDecoderLayer,
     modules.py:233). Encoder families (bert, t5 encoder stack) run the same
@@ -2528,12 +2766,15 @@ def apply_decoder_layer(
     ``p["mlp"]`` (models/moe.py::apply_moe_decoder_layer). A model of several
     residual streams (``cfg.hc_mult``) hands ``x`` [B, S, n, H] and the
     block's maps ``hc1`` / ``hc2`` (:func:`residual`). ``shared`` / ``made``
-    / ``lambda_init``: what the block's operator reads of earlier blocks,
-    the dict it writes what it leaves into and its constant of differential
-    attention (:func:`apply_mixer`). A block of a stack of one-branch
+    / ``lambda_init`` / ``stats``: what the block's operator reads of earlier
+    blocks, the dict it writes what it leaves into, its constant of
+    differential attention and the dict it writes its counts into
+    (:func:`apply_mixer`). A block of a stack of one-branch
     blocks (``cfg.one_branch_blocks``) is ``x + F(ln1(x))``, one norm and
     one add: ``F`` the mixer, or the feed-forward where ``mixer`` is
-    None."""
+    None. A block whose ``cfg.norm_position`` is "branch" (``cfg`` is the
+    block's, ``ModelArgs.for_block``) is ``h = x + ln1(F(x))``, ``h +
+    ln2(FF(h))``: the same two norms on the branches' outputs."""
     if causal is None:
         causal = cfg.model_type != "bert"
     r_attn = r_res1 = r_res2 = None
@@ -2543,19 +2784,26 @@ def apply_decoder_layer(
     def drop_h(y, rng):
         return dropout(y, cfg.hidden_dropout, rng)
 
+    # where the block's two norms sit (``ModelArgs.for_block``: the block's
+    # kind's): on a branch's input, or (Olmo 2 and 3) on its output
+    on_output = cfg.branch_norm
+
+    def norm(name, a, here):
+        return block_norm(p[name], a, cfg) if here else a
+
     def mixed(h):
-        return drop_h(apply_mixer(p, h, cfg, mixer, ops=ops, rope=rope,
-                                  compute_dtype=compute_dtype, causal=causal,
-                                  dropout_rng=r_attn,
-                                  segment_ids=segment_ids, shared=shared,
-                                  made=made, lambda_init=lambda_init),
-                      r_res1)
+        return drop_h(norm("ln1", apply_mixer(
+            p, h, cfg, mixer, ops=ops, rope=rope,
+            compute_dtype=compute_dtype, causal=causal, dropout_rng=r_attn,
+            segment_ids=segment_ids, shared=shared, made=made,
+            lambda_init=lambda_init, stats=stats), on_output), r_res1)
 
     def fed(h):
-        return drop_h(
+        return drop_h(norm("ln2", (
             feed_forward(h) if feed_forward is not None else apply_mlp(
                 p["mlp"], h, cfg, compute_dtype=compute_dtype,
-                matmul_fns=ops.matmuls, shard_fn=ops.shard), r_res2)
+                matmul_fns=ops.matmuls, shard_fn=ops.shard)), on_output),
+            r_res2)
 
     if cfg.post_norm:
         if mixer != "full_attention" or cfg.one_branch_blocks:
@@ -2574,10 +2822,10 @@ def apply_decoder_layer(
                 branch(block_norm(p["ln1"], a, cfg)), cfg), compute_dtype)
 
     def mixer_branch(a):
-        return residual_branch(mixed(block_norm(p["ln1"], a, cfg)), cfg)
+        return residual_branch(mixed(norm("ln1", a, not on_output)), cfg)
 
     def ff_branch(a):
-        return residual_branch(fed(block_norm(p["ln2"], a, cfg)), cfg)
+        return residual_branch(fed(norm("ln2", a, not on_output)), cfg)
 
     x = residual(p.get("hc1"), x, cfg, mixer_branch, compute_dtype)
     return residual(p.get("hc2"), x, cfg, ff_branch, compute_dtype)

@@ -457,7 +457,9 @@ SCOPES: Tuple[str, ...] = (
     "mixer/kda/scan", "mixer/kda/gated_norm", "mixer/kda/out_proj",
     "mixer/mamba1/in_proj", "mixer/mamba1/conv", "mixer/mamba1/x_proj",
     "mixer/mamba1/scan", "mixer/mamba1/gate", "mixer/mamba1/out_proj",
-    "mixer/gmu/in_proj", "mixer/gmu/gate", "mixer/gmu/out_proj")
+    "mixer/gmu/in_proj", "mixer/gmu/gate", "mixer/gmu/out_proj",
+    "mixer/gdn/in_proj", "mixer/gdn/conv", "mixer/gdn/gates",
+    "mixer/gdn/scan", "mixer/gdn/gated_norm", "mixer/gdn/out_proj")
 PHASES = ("forward", "recompute", "backward", "update", "other")
 # the scopes ``step_scopes()["scopes"]`` lists by instruction, by mixer kind
 # (what the ``granite_*`` readers join a trace to, by PR 35's rule: an
@@ -508,7 +510,7 @@ def _array_dims(text: str, at: int = 0) -> List[str]:
 # kind, and its kernels' names there (ops/pallas/conv.py), which the step
 # report counts by phase (:func:`conv_kernel_calls`)
 CONV_SCOPES = ("mixer/kda/conv", "mixer/mamba/conv", "mixer/mamba1/conv",
-               "mixer/short_conv/gate_conv")
+               "mixer/short_conv/gate_conv", "mixer/gdn/conv")
 CONV_CALLS = ("causal_conv_fwd", "causal_conv_bwd")
 
 # the scope of an expert block's grouped matmuls and the names of the

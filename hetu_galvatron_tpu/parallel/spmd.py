@@ -146,7 +146,8 @@ def block_kernels():
     that name it; whether the shapes fit a kernel's tiles is the kernel's
     caller's to see (``modules.ssd_chunked``, ``kda_chunked``,
     ``apply_mamba1``, ``causal_depthwise_conv``), which keeps its
-    ``jax.numpy`` form where they do not. A mamba, mamba1 or kda block cut any other way than over dp is refused by
+    ``jax.numpy`` form where they do not. A mamba, mamba1, kda or
+    linear_attention block cut any other way than over dp is refused by
     name (analysis/eligibility.py); a depthwise convolution is local to a
     channel shard."""
     from hetu_galvatron_tpu.ops.pallas.conv import make_causal_conv
@@ -202,7 +203,8 @@ def attention_overrides(
     mixer (a feed-forward block of a one-branch stack), gets no core, and
     a layer whose kind reads a field of :func:`block_kernels` (a ``mamba``
     layer ``ssd`` and ``conv``, a ``kda`` layer ``kda`` and ``conv``, a
-    ``mamba1`` layer ``selective`` and ``conv``, a ``conv`` layer ``conv``)
+    ``mamba1`` layer ``selective`` and ``conv``, a ``conv`` and a
+    ``linear_attention`` layer ``conv``)
     gets that kernel when ``kernels`` (None = the same rule: every mesh
     device is a TPU)."""
     from functools import partial as _partial
@@ -913,7 +915,11 @@ def make_spmd_train_step(
     if hpc.pp_deg != 1:
         raise ValueError("make_spmd_train_step is the pp=1 path; use the "
                          "pipeline engine for pp>1")
-    moe_stats = bool(cfg.num_experts)
+    from hetu_galvatron_tpu.models.modules import writes_counts
+
+    # an expert layer's routing counts, or a mixer kind's own
+    moe_stats = bool(cfg.num_experts) or (
+        cfg.model_type != "t5" and writes_counts(cfg))
 
     def lowered(flags):
         return build_spmd_loss_fn(

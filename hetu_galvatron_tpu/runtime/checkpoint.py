@@ -955,6 +955,19 @@ _BLOCK_HF_NAMES["granite"] = {
        if k not in ("gate", "up")},
     "gate_up": "shared_mlp.input_linear.weight",
     "down": "shared_mlp.output_linear.weight"}
+# olmo_hybrid: llama's names; an attention block's two norms sit on its
+# branches' outputs under Olmo 3's names, a linear_attention block's on the
+# inputs under the released block's (``_BLOCK_NORM_HF_NAMES``)
+_BLOCK_HF_NAMES["olmo_hybrid"] = {
+    **_BLOCK_HF_NAMES["llama"],
+    "ln1": "post_attention_layernorm.weight",
+    "ln2": "post_feedforward_layernorm.weight"}
+# the names of a block's two norms where they differ by the block's mixer
+# kind: (hf_layout, mixer) -> the entries of ``_BLOCK_HF_NAMES`` it replaces
+_BLOCK_NORM_HF_NAMES = {
+    ("olmo_hybrid", "linear_attention"): {
+        "ln1": "attention_layer_norm.weight",
+        "ln2": "feedforward_layer_norm.weight"}}
 _CONV_HF_NAMES = ("conv.in_proj.weight", "conv.conv.weight",
                   "conv.out_proj.weight")
 # a ``mamba`` block's names are Granite-4.0-H's (HF
@@ -1012,6 +1025,21 @@ _KDA_HF_THIRDS = {
     "wqkv": tuple(f"self_attn.{m}_proj.weight" for m in "qkv"),
     "taps": tuple(f"self_attn.{m}_conv1d.weight" for m in "qkv"),
     "wlow": tuple(f"self_attn.{m}_proj.weight" for m in ("f_a", "g_a", "b"))}
+
+
+# a ``linear_attention`` block's names are HF ``Qwen3NextGatedDeltaNet``'s
+# projections one by one, as Olmo Hybrid's released block holds them (under
+# ``linear_attn``): the program's leaf -> the public name; ``wqkv`` and
+# ``taps`` hold three public tensors each and ``wab`` two, side by side
+_GDN_HF_NAMES = {"wg": "linear_attn.g_proj.weight",
+                 "dt_bias": "linear_attn.dt_bias",
+                 "A_log": "linear_attn.A_log",
+                 "norm": "linear_attn.o_norm.weight",
+                 "wout": "linear_attn.o_proj.weight"}
+_GDN_HF_PARTS = {
+    "wqkv": tuple(f"linear_attn.{m}_proj.weight" for m in "qkv"),
+    "taps": tuple(f"linear_attn.{m}_conv1d.weight" for m in "qkv"),
+    "wab": tuple(f"linear_attn.{m}_proj.weight" for m in "ab")}
 
 
 def _rope_columns_to_hf(width: int) -> np.ndarray:
@@ -1467,8 +1495,10 @@ def hf_to_params(state_dict: Dict[str, Any], cfg: ModelArgs) -> Params:
 
     def read_block(i, mixer, ff):
         pre = f"model.layers.{i}."
-        lp = {"ln1": {"scale": sd[pre + names["ln1"]]},
-              "ln2": {"scale": sd[pre + names["ln2"]]}}
+        norms = {**names,
+                 **_BLOCK_NORM_HF_NAMES.get((cfg.hf_layout, mixer), {})}
+        lp = {"ln1": {"scale": sd[pre + norms["ln1"]]},
+              "ln2": {"scale": sd[pre + norms["ln2"]]}}
         if cfg.hc_mult > 1:
             for leaf, part in _HC_HF_NAMES.items():
                 lp[leaf] = {"phi": lin(pre + part + "phi.weight"),
@@ -1524,6 +1554,17 @@ def hf_to_params(state_dict: Dict[str, Any], cfg: ModelArgs) -> Params:
                 "A_log": sd[pre + _KDA_HF_NAMES["A_log"]].reshape(-1),
                 "norm": {"scale": sd[pre + _KDA_HF_NAMES["norm"]]},
                 "wout": lin(pre + _KDA_HF_NAMES["wout"])}
+        elif mixer == "linear_attention":
+            lp["gdn"] = {
+                leaf: np.concatenate(
+                    [sd[pre + nm][:, 0, :] if leaf == "taps"
+                     else lin(pre + nm) for nm in parts],
+                    axis=0 if leaf == "taps" else 1)
+                for leaf, parts in _GDN_HF_PARTS.items()}
+            for leaf, name in _GDN_HF_NAMES.items():
+                w = sd[pre + name]
+                lp["gdn"][leaf] = ({"scale": w} if leaf == "norm"
+                                   else w.T if leaf.startswith("w") else w)
         else:
             raise _unknown_mixer(i, mixer)
         if ff == "experts":
@@ -1980,6 +2021,19 @@ def params_to_hf(params: Params, cfg: ModelArgs) -> Dict[str, np.ndarray]:
                 sd[pre + name] = (w.T if leaf.startswith("w")
                                   else w.reshape(1, 1, -1, 1)
                                   if leaf == "A_log" else w)
+        elif mixer == "linear_attention":
+            gp = lp["gdn"]
+            qkv = [cfg.linear_key_dim, 2 * cfg.linear_key_dim]
+            for leaf, cuts in (("wqkv", qkv), ("taps", qkv), ("wab", 2)):
+                w = get(gp[leaf])
+                for nm, part in zip(_GDN_HF_PARTS[leaf], np.split(
+                        w, cuts, axis=0 if leaf == "taps" else 1)):
+                    # Conv1d's depthwise kernel is [channels, 1, taps]
+                    sd[pre + nm] = (part[:, None, :] if leaf == "taps"
+                                    else part.T)
+            for leaf, name in _GDN_HF_NAMES.items():
+                w = get(gp[leaf]["scale"] if leaf == "norm" else gp[leaf])
+                sd[pre + name] = w.T if leaf.startswith("w") else w
         else:
             raise _unknown_mixer(i, mixer)
         if ff == "experts":
@@ -2017,8 +2071,10 @@ def params_to_hf(params: Params, cfg: ModelArgs) -> Dict[str, np.ndarray]:
                 sd[pre + names["gate"]] = gate.T
                 sd[pre + names["up"]] = up.T
             sd[pre + names["down"]] = get(lp["mlp"]["wout"]).T
-        sd[pre + names["ln1"]] = get(lp["ln1"]["scale"])
-        sd[pre + names["ln2"]] = get(lp["ln2"]["scale"])
+        norms = {**names,
+                 **_BLOCK_NORM_HF_NAMES.get((cfg.hf_layout, mixer), {})}
+        sd[pre + norms["ln1"]] = get(lp["ln1"]["scale"])
+        sd[pre + norms["ln2"]] = get(lp["ln2"]["scale"])
 
     for i, (lp, (mixer, ff)) in enumerate(zip(params["layers"], kinds)):
         put_block(i, lp, mixer, ff)

@@ -197,6 +197,7 @@ def get_hybrid_parallel_config(
             eligibility.mamba_plan_reason(args.model, layers),
             eligibility.latent_plan_reason(args.model, layers),
             eligibility.kda_plan_reason(args.model, layers),
+            eligibility.gdn_plan_reason(args.model, layers),
             eligibility.mamba1_plan_reason(args.model, layers),
             eligibility.shared_plan_reason(args.model, layers, pp_deg),
             eligibility.window_plan_reason(args.model, layers),

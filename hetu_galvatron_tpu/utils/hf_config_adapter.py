@@ -67,17 +67,24 @@ _PHI4FLASH = "phi4flash"
 # attention without positions, - an ungated squared-ReLU MLP, E
 # sigmoid-routed experts of the same beside a shared one)
 _NEMOTRON_H = "nemotron_h"
+# Olmo Hybrid (allenai; ``model_type`` ``olmo_hybrid``): Gated DeltaNet
+# blocks (``linear_attention``, HF ``Qwen3NextGatedDeltaNet``'s ``linear_*``
+# keys) three to one with Olmo 3's attention block (an RMSNorm over the whole
+# q and k widths, the block's norms on its branches' outputs), dense SwiGLU
+_OLMO_HYBRID = "olmo_hybrid"
 # families that state for themselves whether they have positions
-_OWN_POSITIONS = {_GRANITE_HYBRID, _KIMI_LINEAR, _PHI4FLASH, _NEMOTRON_H}
+_OWN_POSITIONS = {_GRANITE_HYBRID, _KIMI_LINEAR, _PHI4FLASH, _NEMOTRON_H,
+                  _OLMO_HYBRID}
 _ROPE_FAMILIES = {"llama", "qwen2", "mistral", "mixtral", "olmoe",
                   "qwen", _XING, _LAGUNA, _MELLUM, _KIMI_VL} \
     | _GEMMA_FAMILIES \
     | _LFM2_FAMILIES
 _RMS_FAMILIES = _ROPE_FAMILIES | {"t5", _GRANITE_HYBRID, _KIMI_LINEAR,
-                                  _NEMOTRON_H}
+                                  _NEMOTRON_H, _OLMO_HYBRID}
 _SWIGLU_FAMILIES = {"llama", "qwen2", "mistral", "mixtral", "olmoe",
                     "qwen", _GRANITE_HYBRID, _XING, _KIMI_LINEAR,
-                    _LAGUNA, _MELLUM, _KIMI_VL, _PHI4FLASH} | _LFM2_FAMILIES
+                    _LAGUNA, _MELLUM, _KIMI_VL, _PHI4FLASH,
+                    _OLMO_HYBRID} | _LFM2_FAMILIES
 # gemma-2/3 add sandwich norms (a norm after each sub-layer as well as
 # before it), logit softcapping (attention and final) and a softmax scale
 # from query_pre_attn_scalar; v3 also a q/k norm a head with zero-centred
@@ -210,6 +217,8 @@ def populate_model_args_from_hf(
         values.update(_phi4flash_values(d))
     if family == _NEMOTRON_H:
         values.update(_nemotron_h_values(d))
+    if family == _OLMO_HYBRID:
+        values.update(_olmo_hybrid_values(d))
     if family == "bert":
         # HF bert uses erf gelu everywhere (BertIntermediate + the MLM
         # transform); our "gelu" is the tanh approximation (gpt2's gelu_new)
@@ -242,7 +251,7 @@ def populate_model_args_from_hf(
     # attention_bias / mlp_bias / family defaults)
     # llama-likes and t5 default to no biases
     bias_free = _ROPE_FAMILIES | {"t5", _GRANITE_HYBRID, _KIMI_LINEAR,
-                                  _NEMOTRON_H}
+                                  _NEMOTRON_H, _OLMO_HYBRID}
     # (_LAGUNA is one of _ROPE_FAMILIES)
     if "attention_bias" in d:
         values["add_qkv_bias"] = bool(d["attention_bias"])
@@ -255,6 +264,52 @@ def populate_model_args_from_hf(
     else:
         values["add_bias_linear"] = family not in bias_free
     return ModelArgs.model_validate(values)
+
+
+def _olmo_hybrid_values(d: Dict[str, Any]) -> Dict[str, Any]:
+    """Olmo Hybrid: ``layer_types`` names each block ``linear_attention`` (a
+    Gated DeltaNet block, the ``linear_*`` keys) or ``full_attention``
+    (Olmo 3's block). ``rope_parameters.rope_theta`` null is no rotation
+    anywhere; a number there is a rotation of the attending blocks. The
+    norms' placement has no key: the attention block's on its branches'
+    outputs is the family's (HF ``Olmo3DecoderLayer``), the linear block's
+    on the inputs the released block's."""
+    family = _OLMO_HYBRID
+    types = d.get("layer_types")
+    unknown = sorted(set(types or ()) - {"linear_attention",
+                                         "full_attention"})
+    if types is None or unknown:
+        raise NotImplementedError(
+            f"{family} layer_types={'none' if types is None else unknown}: "
+            "linear_attention and full_attention are implemented")
+    if d.get("hidden_act", "silu") != "silu":
+        raise NotImplementedError(
+            f"{family} hidden_act={d['hidden_act']!r}: silu (SwiGLU) is "
+            "implemented")
+    rope = d.get("rope_parameters") or {}
+    if (d.get("sliding_window") or d.get("rope_scaling")
+            or rope.get("rope_type", "default") != "default"):
+        raise NotImplementedError(
+            f"{family}: a sliding window or a scaled rotation is not "
+            "written for this family (it publishes neither)")
+    theta = rope.get("rope_theta", d.get("rope_theta"))
+    out: Dict[str, Any] = dict(
+        model_type="llama", hf_layout="olmo_hybrid", num_experts=0,
+        layer_types=list(types), qk_norm=True,
+        norm_positions={"linear_attention": "pre",
+                        "full_attention": "branch"},
+        position_embedding_type="nope" if theta is None else "rope",
+        tie_word_embeddings=bool(d.get("tie_word_embeddings", False)),
+        linear_num_key_heads=int(d["linear_num_key_heads"]),
+        linear_num_value_heads=int(d["linear_num_value_heads"]),
+        linear_key_head_dim=int(d["linear_key_head_dim"]),
+        linear_value_head_dim=int(d["linear_value_head_dim"]),
+        linear_conv_kernel_dim=int(d.get("linear_conv_kernel_dim", 4)),
+        linear_allow_neg_eigval=bool(d.get("linear_allow_neg_eigval",
+                                           False)))
+    if theta is not None:
+        out["rope_theta"] = float(theta)
+    return out
 
 
 def _xing_values(d: Dict[str, Any]) -> Dict[str, Any]:

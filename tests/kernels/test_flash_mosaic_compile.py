@@ -305,6 +305,11 @@ _CONV_CASES = {
         jnp.float32, "mixer/kda/conv"),
     "phi4flash_cell": (1, 8192, 5120, 4, True, False, None, jnp.bfloat16,
                        "mixer/mamba1/conv"),
+    # q | k | v of 2880 | 2880 | 5760: 90 lane tiles side by side, no L2
+    # norm in the pass (a head of 96 is no lane tile)
+    "olmohybrid_cell_three_at_once": (
+        1, 4096, 11520, 4, False, False, None, jnp.bfloat16,
+        "mixer/gdn/conv"),
 }
 
 

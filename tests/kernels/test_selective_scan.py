@@ -152,7 +152,7 @@ def test_a_mixers_scopes_are_read_off_the_vocabulary():
     """One place a kind: ``MIXER_SCOPES`` and ``OWN_SCOPES`` follow from the
     ``mixer/<kind>/`` names of ``SCOPES``."""
     assert set(trace_analysis.MIXER_SCOPES) == {
-        "short_conv", "mamba", "kda", "mamba1", "gmu"}
+        "short_conv", "mamba", "kda", "mamba1", "gmu", "gdn"}
     for kind, scopes in trace_analysis.MIXER_SCOPES.items():
         assert scopes == tuple(s for s in trace_analysis.SCOPES
                                if s.startswith(f"mixer/{kind}/"))

@@ -725,7 +725,7 @@ TODAY = sorted(os.path.basename(p) for p in glob.glob(
     os.path.join(ZOO, "*.yaml"))
     if not any(word in p for word in ("lfm2", "t5", "granite", "xing",
                                       "kimi", "laguna", "mellum", "phi-4",
-                                      "nemotron")))
+                                      "nemotron", "olmo-hybrid")))
 
 
 def _the_parents_tree(key, cfg):
