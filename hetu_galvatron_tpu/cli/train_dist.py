@@ -802,6 +802,7 @@ def train(args) -> Dict[str, Any]:
     drop_key = jax.random.key(args.train.seed) if use_dropout else None
     # what the compiled step contains (filled after the first step)
     from hetu_galvatron_tpu.observability.trace_analysis import (
+        GDN_SCAN_SCOPE,
         SELECTIVE_SCOPE,
         SSD_SCOPE,
         conv_kernel_calls,
@@ -1301,7 +1302,8 @@ def train(args) -> Dict[str, Any]:
                             get_registry().gauge(f"kda/{part}").set(v)
                     for kind, scope, name in (
                             ("mamba", SSD_SCOPE, "ssd"),
-                            ("mamba1", SELECTIVE_SCOPE, "selective")):
+                            ("mamba1", SELECTIVE_SCOPE, "selective"),
+                            ("linear_attention", GDN_SCAN_SCOPE, "gdn")):
                         if any(m == kind for m, _ in kinds):
                             # whether the scan's kernels engaged: the
                             # Mosaic calls under its scope, 0 = the
@@ -1410,6 +1412,8 @@ def train(args) -> Dict[str, Any]:
                     + (", selective/mosaic_calls "
                        f"{step_report['selective_mosaic_calls']}"
                        if "selective_mosaic_calls" in step_report else "")
+                    + (f", gdn/mosaic_calls {step_report['gdn_mosaic_calls']}"
+                       if "gdn_mosaic_calls" in step_report else "")
                     + (", experts/mosaic_calls "
                        f"{step_report['experts_mosaic_calls']}"
                        if "experts_mosaic_calls" in step_report else "")
@@ -1558,6 +1562,9 @@ def train(args) -> Dict[str, Any]:
             # selective/mosaic_calls); None for a model without such a block
             "selective_mosaic_calls": step_report.get(
                 "selective_mosaic_calls"),
+            # the same under mixer/gdn/scan (the gauge gdn/mosaic_calls);
+            # None for a model without a linear_attention block
+            "gdn_mosaic_calls": step_report.get("gdn_mosaic_calls"),
             # the program's own grouped-matmul kernels under moe/experts
             # (the gauge experts/mosaic_calls): 0 where lax.ragged_dot ran;
             # None for a model without an expert block

@@ -61,16 +61,17 @@ class LayerOps:
     mapping (x, w) to the fp32 product the default einsum would produce);
     ``shard(a, axis)`` pins an interior activation
     of a tp > 1 layer (parallel/spmd.py::interior_sharding); ``ssd``,
-    ``kda``, ``selective`` and ``conv`` are the kernels of a mamba block's
-    chunked scan, a kda block's chunked delta rule, a mamba1 block's
-    selective scan and the causal depthwise convolution
-    (ops/pallas/); ``exchange`` runs an expert block's sorted dispatcher
-    across the chips of its ``ep`` group (models/moe.py::
-    make_expert_exchange); ``grouped(mode, a, b, group_sizes, out_dtype)``
-    is the kernels of an expert block's grouped matmuls, forward and both
-    gradients (ops/pallas/grouped_matmul.py), None for shapes that fit them
-    no tile. Which kinds of block read which field: :data:`MIXERS`;
-    ``exchange`` and ``grouped`` are the expert feed-forward's."""
+    ``kda``, ``gdn``, ``selective`` and ``conv`` are the kernels of a mamba
+    block's chunked scan, a kda block's chunked delta rule, a
+    linear_attention block's (a decay a head), a mamba1 block's selective
+    scan and the causal depthwise convolution (ops/pallas/); ``exchange``
+    runs an expert block's sorted dispatcher across the chips of its ``ep``
+    group (models/moe.py::make_expert_exchange); ``grouped(mode, a, b,
+    group_sizes, out_dtype)`` is the kernels of an expert block's grouped
+    matmuls, forward and both gradients (ops/pallas/grouped_matmul.py), None
+    for shapes that fit them no tile. Which kinds of block read which field:
+    :data:`MIXERS`; ``exchange`` and ``grouped`` are the expert
+    feed-forward's."""
 
     sdpa: Optional[Callable[..., jax.Array]] = None
     cross_sdpa: Optional[Callable[..., jax.Array]] = None
@@ -78,6 +79,7 @@ class LayerOps:
     shard: Optional[Callable[[jax.Array, int], jax.Array]] = None
     ssd: Optional[Callable[..., jax.Array]] = None
     kda: Optional[Callable[..., jax.Array]] = None
+    gdn: Optional[Callable[..., jax.Array]] = None
     selective: Optional[Callable[..., Optional[jax.Array]]] = None
     conv: Optional[Callable[..., Optional[jax.Array]]] = None
     exchange: Optional[Callable[..., Any]] = None
@@ -473,12 +475,10 @@ def remat(fn, cfg: ModelArgs):
     recomputed forward then holds none of them. A block that ran no such
     kernel (the XLA core, a mixer in its ``jax.numpy`` form, an MLP alone)
     traces no name and is recomputed whole; the convolution's kernels name
-    nothing either. A linear_attention block's scan has no kernel
-    (:func:`gated_delta_chunked`) and names nothing: its backward is the
-    scan's own transpose, which wants every chunk's operands again, so a
-    kept output would save no pass."""
+    nothing either."""
     from hetu_galvatron_tpu.ops.pallas import (
         flash_attention,
+        gdn,
         kda,
         selective_scan,
         ssd,
@@ -493,7 +493,8 @@ def remat(fn, cfg: ModelArgs):
         raise ValueError(f"unknown remat_policy {cfg.remat_policy!r} "
                          "(full | dots | dots_no_batch)")
     policy = policies.save_only_these_names(
-        *flash_attention.KEPT, *kda.KEPT, *ssd.KEPT, *selective_scan.KEPT)
+        *flash_attention.KEPT, *kda.KEPT, *gdn.KEPT, *ssd.KEPT,
+        *selective_scan.KEPT)
     if base[cfg.remat_policy] is not None:
         policy = policies.save_from_both_policies(base[cfg.remat_policy],
                                                   policy)
@@ -2360,6 +2361,7 @@ def apply_gated_delta(
     compute_dtype=jnp.bfloat16,
     conv_fn: Optional[Callable[..., Optional[jax.Array]]] = None,
     stats: Optional[Dict[str, jax.Array]] = None,
+    gdn_fn: Optional[Callable[..., jax.Array]] = None,
 ) -> jax.Array:
     """``[q~ | k~ | v] = silu(conv1d_causal(x W_qkv))`` (depthwise,
     ``linear_conv_kernel_dim`` taps, zero history before the sequence, no
@@ -2374,9 +2376,17 @@ def apply_gated_delta(
     convolution, the L2 norms, the decay, ``beta``, the state and the gated
     norm are float32. ``conv_fn``: the kernels for the convolution of the
     three at once and its SiLU (:func:`causal_depthwise_conv`); a head of
-    96 is no lane tile, so the L2 norms do not ride in its pass. ``stats``
-    takes ``beta_over_one``, the share of (position, head) whose delta step
-    overshoots (``beta > 1``)."""
+    96 is no lane tile, so the L2 norms do not ride in its pass. ``gdn_fn``:
+    the kernels for the recurrence (``ops/pallas/gdn.py``, which whoever
+    knows the devices hands down: ``parallel/spmd.attention_overrides``);
+    they run where the shapes fit their tiles (``gdn.tile_plan``) and the
+    chunk divides the sequence: a chunk's pair matrices, the inverse, ``W``,
+    ``U`` and the carried state then live in VMEM. Otherwise (the CPU, a
+    chunk or widths that fit no tile, a ragged length) it is
+    :func:`gated_delta_chunked`. ``stats`` takes ``beta_over_one``, the
+    share of (position, head) whose delta step overshoots (``beta > 1``)."""
+    from hetu_galvatron_tpu.ops.pallas.gdn import tile_plan
+
     B, S, _ = x.shape
     nh, dk, dv = (cfg.linear_num_value_heads, cfg.linear_key_head_dim,
                   cfg.linear_value_head_dim)
@@ -2415,8 +2425,12 @@ def apply_gated_delta(
             if stats is not None:
                 stats["beta_over_one"] = jnp.mean((beta > 1.0).astype(f32))
         with jax.named_scope("scan"):
-            o = gated_delta_chunked(q, k, v.reshape(B, S, nh, dv), g, beta,
-                                    cfg.linear_chunk_size, compute_dtype)
+            C = cfg.linear_chunk_size
+            scan = partial(gated_delta_chunked, compute_dtype=compute_dtype)
+            if (gdn_fn is not None and S % C == 0
+                    and tile_plan(C, nh, dk, dv) is not None):
+                scan = gdn_fn
+            o = scan(q, k, v.reshape(B, S, nh, dv), g, beta, C)
         with jax.named_scope("gated_norm"):
             var = jnp.mean(jnp.square(o), axis=-1, keepdims=True)
             y = (o * jax.lax.rsqrt(var + cfg.layernorm_epsilon)
@@ -2488,7 +2502,7 @@ MIXERS: Dict[str, Mixer] = {
         uncut_reason="kda_plan_reason", crosses_documents=_CARRIED),
     "linear_attention": Mixer(
         "gdn", init_gated_delta, apply_gated_delta, False,
-        {"conv_fn": "conv"}, "gdn",
+        {"gdn_fn": "gdn", "conv_fn": "conv"}, "gdn",
         uncut_reason="gdn_plan_reason", crosses_documents=_CARRIED,
         counts=("beta_over_one",)),
     "sliding_attention": Mixer(

@@ -489,10 +489,15 @@ KDA_FWD_CALL = "kda_scan_fwd"
 # report counts (``selective/mosaic_calls``;
 # ops/pallas/selective_scan.py traces under it)
 SELECTIVE_SCOPE = "mixer/mamba1/scan"
+# the scope of a linear_attention block's recurrence, whose Mosaic calls the
+# step report counts (``gdn/mosaic_calls``; ops/pallas/gdn.py traces under
+# it)
+GDN_SCAN_SCOPE = "mixer/gdn/scan"
 # the forward kernels of the recurrent mixers' scans (ops/pallas/kda.py,
-# ops/pallas/ssd.py, ops/pallas/selective_scan.py), which
-# :func:`scans_recomputed` counts
-SCAN_FWD_CALLS = (KDA_FWD_CALL, "ssd_scan_fwd", "selective_scan_fwd")
+# ops/pallas/gdn.py, ops/pallas/ssd.py, ops/pallas/selective_scan.py),
+# which :func:`scans_recomputed` counts
+SCAN_FWD_CALLS = (KDA_FWD_CALL, "gdn_scan_fwd", "ssd_scan_fwd",
+                  "selective_scan_fwd")
 _OPERAND_SHAPES = "operand_layout_constraints="
 _TRIP_COUNT = re.compile(r'"known_trip_count":\{"n":"(\d+)"')
 _BODY = re.compile(r"body=%?([\w.\-]+)")
@@ -957,9 +962,10 @@ def cores_recomputed(found: Dict[str, Any]) -> int:
 
 def scans_recomputed(found: Dict[str, Any]) -> int:
     """The same of the recurrent mixers' scans: the Mosaic calls named
-    ``kda_scan_fwd``, ``ssd_scan_fwd`` or ``selective_scan_fwd`` that the
-    step's map puts in the ``recompute`` phase. 0 where ``modules.remat`` keeps every scan's output
-    and entering states (the gauge ``step/scans_recomputed``), and in a step
+    ``kda_scan_fwd``, ``gdn_scan_fwd``, ``ssd_scan_fwd`` or
+    ``selective_scan_fwd`` that the step's map puts in the ``recompute``
+    phase. 0 where ``modules.remat`` keeps every scan's output and entering
+    states (the gauge ``step/scans_recomputed``), and in a step
     without the kernels."""
     return _recomputed(found, SCAN_FWD_CALLS)
 

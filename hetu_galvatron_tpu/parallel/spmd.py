@@ -145,12 +145,14 @@ def block_kernels():
     The kinds of block that take a field are the rows of ``modules.MIXERS``
     that name it; whether the shapes fit a kernel's tiles is the kernel's
     caller's to see (``modules.ssd_chunked``, ``kda_chunked``,
-    ``apply_mamba1``, ``causal_depthwise_conv``), which keeps its
-    ``jax.numpy`` form where they do not. A mamba, mamba1, kda or
+    ``apply_gated_delta``, ``apply_mamba1``, ``causal_depthwise_conv``),
+    which keeps its ``jax.numpy`` form where they do not (for ``gdn`` also
+    where the chunk does not divide the sequence). A mamba, mamba1, kda or
     linear_attention block cut any other way than over dp is refused by
     name (analysis/eligibility.py); a depthwise convolution is local to a
     channel shard."""
     from hetu_galvatron_tpu.ops.pallas.conv import make_causal_conv
+    from hetu_galvatron_tpu.ops.pallas.gdn import make_gdn_scan
     from hetu_galvatron_tpu.ops.pallas.kda import make_kda_scan
     from hetu_galvatron_tpu.ops.pallas.selective_scan import (
         make_selective_scan,
@@ -159,6 +161,7 @@ def block_kernels():
 
     return (("ssd", make_ssd_scan, False, None),
             ("kda", make_kda_scan, False, None),
+            ("gdn", make_gdn_scan, False, None),
             ("selective", make_selective_scan, False, None),
             ("conv", make_causal_conv, True, "weight_tp_axes"))
 
@@ -203,8 +206,8 @@ def attention_overrides(
     mixer (a feed-forward block of a one-branch stack), gets no core, and
     a layer whose kind reads a field of :func:`block_kernels` (a ``mamba``
     layer ``ssd`` and ``conv``, a ``kda`` layer ``kda`` and ``conv``, a
-    ``mamba1`` layer ``selective`` and ``conv``, a ``conv`` and a
-    ``linear_attention`` layer ``conv``)
+    ``mamba1`` layer ``selective`` and ``conv``, a ``linear_attention``
+    layer ``gdn`` and ``conv``, a ``conv`` layer ``conv``)
     gets that kernel when ``kernels`` (None = the same rule: every mesh
     device is a TPU)."""
     from functools import partial as _partial

@@ -1,5 +1,6 @@
 """The flash kernels, the kernels of the Mamba-2 scan, those of the
-chunked delta rule, those of the causal depthwise convolution and those of
+chunked delta rule (a decay a channel, and a decay a head), those of the
+causal depthwise convolution and those of
 the experts' grouped matmuls, compiled by the real Mosaic / XLA:TPU compilers for a
 described (not attached) TPU v5e, at
 the widths the benchmark's cells run and with every optional operand: what interpret mode cannot refuse (a slice off
@@ -14,7 +15,13 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from hetu_galvatron_tpu.ops.pallas import conv, kda, selective_scan, ssd
+from hetu_galvatron_tpu.ops.pallas import (
+    conv,
+    gdn,
+    kda,
+    selective_scan,
+    ssd,
+)
 from hetu_galvatron_tpu.ops.pallas.flash_attention import flash_sdpa
 
 pytestmark = pytest.mark.kernels
@@ -240,6 +247,54 @@ def test_kda_scan_forward_and_backward_compile_for_v5e(one_chip, case):
     assert kda_loops(text) == {"blocks": 0, "chunks": 0}
 
 
+# B, S, heads, keys and values a head, chunk, dtype (``g`` and ``beta``, one
+# number a head and position, are float32 whatever the operands are)
+_GDN_CASES = {
+    "olmohybrid_cell": (1, 4096, 30, 96, 192, 64, jnp.bfloat16),
+    "two_rows_f32": (2, 512, 6, 96, 192, 64, jnp.float32),
+    "four_heads_a_pack": (1, 256, 8, 32, 48, 32, jnp.bfloat16),
+    "a_head_a_pack_two_steps": (1, 512, 64, 128, 256, 128, jnp.bfloat16),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_GDN_CASES))
+def test_gdn_scan_forward_and_backward_compile_for_v5e(one_chip, case):
+    """The kernels compile at the cell's shapes (30 heads of 96 keys under
+    192 values: no lane tile asked of either), and under plain
+    ``jax.checkpoint`` a block is three Mosaic calls under
+    ``mixer/gdn/scan`` (the forward, the forward made again with the
+    entering states kept, the backward by the scope its rule opens), which
+    is what the step report's ``gdn/mosaic_calls`` counts."""
+    from hetu_galvatron_tpu.observability.trace_analysis import (
+        scope_instructions,
+    )
+
+    B, S, H, dk, dv, C, dtype = _GDN_CASES[case]
+    assert gdn.tile_plan(C, H, dk, dv) is not None
+
+    def spec(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    @jax.checkpoint
+    def block(*a):
+        with jax.named_scope("mixer/gdn"):
+            with jax.named_scope("scan"):
+                return gdn.gdn_scan(*a, C)
+
+    compiled = jax.jit(jax.grad(
+        lambda *a: jnp.sum(jnp.square(block(*a))),
+        argnums=(0, 1, 2, 3, 4))).lower(
+            spec((B, S, H, dk), dtype), spec((B, S, H, dk), dtype),
+            spec((B, S, H, dv), dtype), spec((B, S, H), jnp.float32),
+            spec((B, S, H), jnp.float32)).compile()
+    found = scope_instructions(compiled.as_text(), (gdn.SCOPE,))
+    calls = sorted(found["mosaic_calls"])
+    assert len(calls) == 3, calls
+    assert "gdn_scan_bwd" in calls[0], calls
+    assert all("gdn_scan_fwd" in c for c in calls[1:]), calls
+    assert set(calls) <= set(found["scopes"][gdn.SCOPE])
+
+
 # B, S, channels, state, u's dtype
 _SELECTIVE_CASES = {
     "phi4flash_cell": (1, 8192, 5120, 16, jnp.bfloat16),
@@ -460,11 +515,17 @@ def test_a_rematted_block_holds_one_forward_kernel(one_chip, wrapper,
 
 # a recurrent block at its cell's widths: mixer kind, the field of
 # ``LayerOps`` its scan goes in, the scan, its forward kernel, the model's
-# sizes (``kimilin_c1_b1_s8k``, ``granite4h_c1_b1``: one sequence of 8192)
+# sizes (``kimilin_c1_b1_s8k``, ``olmohybrid_c1_b1``, ``granite4h_c1_b1``;
+# one sequence of 8192)
 _SCAN_BLOCKS = {
     "kda": ("kda", kda.kda_scan, "kda_scan_fwd", dict(
         hidden_size=2304, ffn_hidden_size=9216, kda_num_heads=32,
         kda_head_dim=128, kda_chunk_size=64)),
+    "linear_attention": ("gdn", gdn.gdn_scan, "gdn_scan_fwd", dict(
+        hidden_size=3840, ffn_hidden_size=11008, linear_num_key_heads=30,
+        linear_num_value_heads=30, linear_key_head_dim=96,
+        linear_value_head_dim=192, linear_chunk_size=64,
+        linear_allow_neg_eigval=True)),
     "mamba": ("ssd", ssd.ssd_scan, "ssd_scan_fwd", dict(
         hidden_size=2048, ffn_hidden_size=8192, mamba_n_heads=64,
         mamba_d_head=64, mamba_d_state=128, mamba_chunk_size=256)),
@@ -478,7 +539,8 @@ def test_a_rematted_recurrent_block_holds_one_scan_forward(
         one_chip, kind, wrapper, forwards, recomputed):
     """The real scan and convolution kernels under per-layer remat, at the
     cells' widths: the gradient of a block wrapped by ``modules.remat``
-    compiles to one ``kda_scan_fwd`` / ``ssd_scan_fwd``, none of it in the
+    compiles to one ``kda_scan_fwd`` / ``gdn_scan_fwd`` / ``ssd_scan_fwd``,
+    none of it in the
     recompute phase (the convolution's forward names nothing and is there
     twice); under plain ``jax.checkpoint`` there are two, and
     ``trace_analysis.scans_recomputed`` counts the second."""

@@ -3,7 +3,8 @@
 names), so a block's recomputed forward holds no forward kernel: one
 ``flash_attention_fwd`` call a block in the gradient's jaxpr where plain
 ``jax.checkpoint`` with the same base policy has two, and the same numbers to
-the last bit. The same of a delta-rule block's ``kda_scan_fwd``, a Mamba-2
+the last bit. The same of a delta-rule block's ``kda_scan_fwd`` (a decay a
+head: ``gdn_scan_fwd``), a Mamba-2
 block's ``ssd_scan_fwd`` and a Mamba-1 block's ``selective_scan_fwd``, whose
 differentiated forward names its output and the states that entered the
 chunks (each kernel file's ``KEPT``).
@@ -26,7 +27,7 @@ import jax.numpy as jnp
 
 from hetu_galvatron_tpu.core.args_schema import ModelArgs
 from hetu_galvatron_tpu.models import modules as M
-from hetu_galvatron_tpu.ops.pallas import kda, selective_scan, ssd
+from hetu_galvatron_tpu.ops.pallas import gdn, kda, selective_scan, ssd
 from hetu_galvatron_tpu.ops.pallas.flash_attention import (
     flash_sdpa,
     make_flash_sdpa,
@@ -37,15 +38,18 @@ pytestmark = pytest.mark.kernels
 B, S, H, N = 2, 128, 64, 2
 # the recurrent mixers at shapes their kernels' tiles fit (``tile_plan``),
 # two chunks a sequence so that a state enters the second: 2 heads of 128
-# at the cell's chunk of 64, 8 heads of 16 with a state of 128 at a
-# chunk of one lane tile over twice the positions, and 128 channels with a
-# state of 16 over two of the selective scan's chunks
+# at the cell's chunk of 64 (a decay a head: keys of 32 under values of 48),
+# 8 heads of 16 with a state of 128 at a chunk of one lane tile over twice
+# the positions, and 128 channels with a state of 16 over two of the
+# selective scan's chunks
 CFG = ModelArgs(
     hidden_size=H, num_hidden_layers=2, num_attention_heads=N, vocab_size=64,
     max_position_embeddings=2 * S, seq_length=S, hidden_act="swiglu",
     normalization="rmsnorm", position_embedding_type="rope",
     add_bias_linear=False, add_qkv_bias=False, make_vocab_size_divisible_by=1,
     kda_num_heads=2, kda_head_dim=128, kda_chunk_size=64,
+    linear_num_key_heads=2, linear_num_value_heads=2, linear_key_head_dim=32,
+    linear_value_head_dim=48, linear_chunk_size=64,
     mamba_n_heads=8, mamba_d_head=16, mamba_d_state=128,
     mamba_chunk_size=128)
 # mixer kind -> (the field of ``LayerOps`` its kernels go in, the kernels,
@@ -55,6 +59,11 @@ SCANS = {
     "kda": ("kda", kda.kda_scan, kda.make_kda_scan, "kda_scan_fwd",
             "kda_scan_bwd", S, dict(zip(kda.KEPT, (
                 (B, S, 2, 128), (B, S // 64, 2, 128, 128))))),
+    # (the kernels' own output, head-major)
+    "linear_attention": ("gdn", gdn.gdn_scan, gdn.make_gdn_scan,
+                         "gdn_scan_fwd", "gdn_scan_bwd", S, dict(zip(
+                             gdn.KEPT, ((B, 2, S, 48),
+                                        (B, S // 64, 2, 32, 48))))),
     "mamba": ("ssd", ssd.ssd_scan, ssd.make_ssd_scan, "ssd_scan_fwd",
               "ssd_scan_bwd", 2 * S, dict(zip(ssd.KEPT, (
                   (B, 2 * S, 128), (B, 2 * S // 128, 128, 128))))),
@@ -296,6 +305,9 @@ def _named(jaxpr):
     ("xla", "dots_no_batch"),
     ("kda", "full"), ("kda_shard_map", "full"), ("kda_numpy", "full"),
     ("kda", "dots_no_batch"),
+    ("linear_attention", "full"), ("linear_attention_shard_map", "full"),
+    ("linear_attention_numpy", "full"),
+    ("linear_attention", "dots_no_batch"),
     ("mamba", "full"), ("mamba_shard_map", "full"), ("mamba_numpy", "full"),
     ("mamba", "dots_no_batch"),
     ("mamba1", "full"), ("mamba1_shard_map", "full"),
@@ -309,7 +321,10 @@ def test_what_a_rematted_block_keeps(cpu_devices, capsys, core, policy):
     kernel wrote them, by the names of the kernel file's ``KEPT``; a block on
     the XLA core or on a mixer's ``jax.numpy`` form names nothing and keeps
     what the base policy keeps."""
-    kind, _, how = core.partition("_")
+    kind = next((k for k in SCANS if core in (k, f"{k}_shard_map",
+                                              f"{k}_numpy")),
+                core.partition("_")[0])
+    how = core[len(kind) + 1:]
     extras = {"flash": (), "xla": ("xla",)}.get(kind, (kind,)) + (
         (how,) if how else ())
     _, params, x, cfg, block = _stack(1, policy, extras, cpu_devices)
@@ -323,7 +338,7 @@ def test_what_a_rematted_block_keeps(cpu_devices, capsys, core, policy):
                                   or kept == [])
         assert not set(_named(jax.make_jaxpr(jax.grad(
             lambda p, h: jnp.sum(block(0)(p, h))))(params[0], x).jaxpr)) & {
-                *kda.KEPT, *ssd.KEPT, *selective_scan.KEPT}
+                *kda.KEPT, *gdn.KEPT, *ssd.KEPT, *selective_scan.KEPT}
         return
     if kind in SCANS:
         # what the differentiated forward names, shard by shard under

@@ -84,9 +84,10 @@ def _seeded(cfg, key=7):
         if "norm" in name or "ln" in name:
             return x + 0.3 * jax.random.normal(k, x.shape)
         if "A_log" in name:
-            return jnp.log(jnp.asarray([16.0, 1.0, 0.05])[:x.size])
+            return jnp.log(jnp.asarray([16.0, 1.0, 0.05, 4.0])[:x.size])
         if "dt_bias" in name:
-            return jnp.log(jnp.expm1(jnp.asarray([0.1, 0.03, 1e-3])[:x.size]))
+            return jnp.log(jnp.expm1(
+                jnp.asarray([0.1, 0.03, 1e-3, 0.01])[:x.size]))
         if "wab" in name or "wg'" in name:
             return 30.0 * x
         if "wqkv" in name:
@@ -223,6 +224,67 @@ def test_program_matches_plain_reference(case, program, monkeypatch):
         np.testing.assert_allclose(
             got_grads[k], want_grads[k], rtol=5e-4,
             atol=1e-4 * scale + 1e-9, err_msg=k)
+
+
+@pytest.fixture(scope="module")
+def with_and_without_kernels():
+    """The stack at shapes the gdn kernels' tiles fit (4 heads, keys of 32
+    under values of 48, two chunks of 32), its three linear blocks handed
+    the kernels in interpret mode and not: logits, loss and gradients of
+    both, under per-layer remat as the cell runs, and how often the kernels
+    were called."""
+    from hetu_galvatron_tpu.ops.pallas import gdn
+
+    with jax.default_matmul_precision("highest"):
+        cfg = _cfg(seq_length=64, linear_num_key_heads=4,
+                   linear_num_value_heads=4, linear_key_head_dim=32,
+                   linear_value_head_dim=48)
+        assert gdn.tile_plan(32, 4, 32, 48) is not None
+        params, batch = _seeded(cfg), _batch(seq=64)
+        called = []
+
+        def kernels(*a):
+            called.append(a[0].shape)
+            return gdn.gdn_scan(*a, interpret=True)
+
+        sides = {}
+        for name, ops in (("kernels", {i: M.LayerOps(gdn=kernels)
+                                       for i in range(3)}), ("plain", None)):
+            loss, grads = jax.jit(jax.value_and_grad(lambda p: causal_lm_loss(
+                p, batch, cfg, compute_dtype=jnp.float32,
+                remat_flags=[True] * 4, layer_overrides=ops)))(params)
+            logits = jax.jit(lambda p: forward_causal_lm(
+                p, batch["tokens"], cfg, compute_dtype=jnp.float32,
+                layer_overrides=ops))(params)
+            sides[name] = dict(loss=float(loss), grads=grads, logits=logits)
+        return dict(sides, called=called)
+
+
+@pytest.mark.parametrize("quantity", ["called", "logits", "loss",
+                                      "gradients"])
+def test_linear_blocks_handed_the_kernels_are_the_blocks_without_them(
+        quantity, with_and_without_kernels):
+    """``LayerOps.gdn`` swaps the recurrence's implementation and nothing
+    else: within the limits the program is held to the plain reference by
+    (both sides float32 at highest, differing in operation order alone)."""
+    got, want = (with_and_without_kernels[s] for s in ("kernels", "plain"))
+    if quantity == "called":
+        # three blocks in the loss's trace and in the logits', no other
+        assert len(with_and_without_kernels["called"]) >= 6
+        assert set(with_and_without_kernels["called"]) == {(2, 64, 4, 32)}
+    if quantity == "logits":
+        np.testing.assert_allclose(got["logits"], want["logits"], rtol=1e-4,
+                                   atol=1e-4)
+    if quantity == "loss":
+        assert abs(got["loss"] - want["loss"]) < 2e-5
+    if quantity == "gradients":
+        for (path, a), b in zip(
+                jax.tree_util.tree_flatten_with_path(got["grads"])[0],
+                jax.tree.leaves(want["grads"])):
+            scale = float(jnp.max(jnp.abs(b)))
+            np.testing.assert_allclose(
+                a, b, rtol=5e-4, atol=1e-4 * scale + 1e-9,
+                err_msg=jax.tree_util.keystr(path))
 
 
 # ---------------------------------------------------------------------------

@@ -535,6 +535,58 @@ def test_a_model_without_a_kda_block_reports_none(run):
                 if m.name.startswith("kda/")]
 
 
+# a tiny Olmo Hybrid: a linear_attention block (Gated DeltaNet) and an
+# attention block; the cell ``olmohybrid_c1_b1``'s step report
+OLMO_HYBRID = ["olmo-hybrid-7b.yaml"] + SIZE + [
+    "model.layer_types=[linear_attention,full_attention]",
+    "model.num_key_value_heads=2", "model.ffn_hidden_size=32",
+    "model.linear_num_key_heads=2", "model.linear_num_value_heads=2",
+    "model.linear_key_head_dim=8", "model.linear_value_head_dim=16",
+    "model.linear_chunk_size=8", "train.train_iters=2"]
+
+
+@pytest.fixture(scope="module")
+def olmo_hybrid_run():
+    yaml, *size = OLMO_HYBRID
+    with _launched([os.path.join(ZOO, yaml)] + size) as ran:
+        yield ran
+
+
+@pytest.mark.parametrize("said_by", ["gauge", "result", "report", "scans",
+                                     "cores_line"])
+def test_the_step_report_says_whether_the_gdn_kernels_engaged(
+        olmo_hybrid_run, said_by):
+    """``gdn/mosaic_calls``: the Mosaic calls among the instructions under
+    ``mixer/gdn/scan``, beside ``ssd/mosaic_calls`` and
+    ``selective/mosaic_calls``. A step compiled for a CPU holds none (the
+    recurrence ran as ``modules.gated_delta_chunked``): the gauge,
+    ``train()``'s result and the ``step report:`` line say 0, no scan is
+    made twice, and the block is ``gdn`` on the ``attention cores:`` line
+    whichever way its recurrence runs."""
+    ran = olmo_hybrid_run
+    (report,) = [line for line in ran["log"].splitlines()
+                 if "step report:" in line]
+    if said_by == "gauge":
+        assert [m.value for m in ran["registry"].metrics()
+                if m.name == "gdn/mosaic_calls"] == [0]
+    if said_by == "result":
+        assert ran["result"]["gdn_mosaic_calls"] == 0
+        assert ran["result"]["ssd_mosaic_calls"] is None
+    if said_by == "report":
+        assert ", gdn/mosaic_calls 0" in report
+    if said_by == "scans":
+        assert ran["result"]["scans_recomputed"] == 0
+        assert ", 0 scans recomputed, static live peak " in report
+    if said_by == "cores_line":
+        assert "attention cores: 1 x gdn, 1 x xla" in ran["log"]
+
+
+def test_a_model_without_a_linear_block_reports_no_gdn_calls(run):
+    assert run["result"]["gdn_mosaic_calls"] is None
+    assert not [m for m in run["registry"].metrics()
+                if m.name.startswith("gdn/")]
+
+
 def test_the_step_report_counts_the_cores_remat_runs_again(run):
     """``step/cores_recomputed``: the flash forward kernels the compiled
     step holds in its recompute phase. The gauge, ``train()``'s result and

@@ -318,12 +318,13 @@ def test_cores_recomputed_counts_the_flash_forwards_of_the_recompute_phase(
     assert trace_analysis.cores_recomputed(found) == expected
 
 
-# two recurrent blocks' scan kernels as a step holds them: each forward in
+# three recurrent blocks' scan kernels as a step holds them: each forward in
 # the forward pass, a second one in the recomputed forward (what plain
 # jax.checkpoint leaves there), the backward by the scope its rule opens
 _SCANS = {
     f"{call}_scan_{way}": stack.format(scope=scope, inner=inner, call=call)
     for call, scope, inner in (("kda", "mixer/kda", "scan"),
+                               ("gdn", "mixer/gdn", "scan"),
                                ("ssd", "mixer/mamba", "ssd"))
     for way, stack in (
         ("fwd.1", "jvp({scope})/{inner}/{call}_scan_fwd"),
@@ -334,9 +335,10 @@ _SCANS = {
 
 
 @pytest.mark.parametrize("left_out,expected", [
-    ((), 2),                                        # both scans run again
-    (("kda_scan_fwd.2",), 1),                       # the delta rule's kept
-    (("kda_scan_fwd.2", "ssd_scan_fwd.2"), 0),      # both kept
+    ((), 3),                                        # every scan runs again
+    (("kda_scan_fwd.2",), 2),                       # the delta rule's kept
+    (("kda_scan_fwd.2", "ssd_scan_fwd.2"), 1),      # a decay a head's is not
+    (("kda_scan_fwd.2", "gdn_scan_fwd.2", "ssd_scan_fwd.2"), 0),  # all kept
     (tuple(_SCANS), 0),                             # a step without kernels
 ])
 def test_scans_recomputed_counts_the_scan_forwards_of_the_recompute_phase(

@@ -23,8 +23,9 @@ last block left out. Each has to lie further from the reference than the
 tolerance.
 
 ``--workload olmohybrid_c1_b1`` (family ``olmo_hybrid``): 4096 positions
-through the Gated DeltaNet blocks in their chunked form (the convolution's
-kernels on a TPU) and the attention block's flash core, against
+through the Gated DeltaNet blocks in their chunked form (on a TPU the
+kernels of ``ops/pallas/gdn.py`` and the convolution's, what the cell
+trains with) and the attention block's flash core, against
 ``benchmark/reference/olmo_hybrid.py``, which runs the recurrence one
 position at a time; the controls are ``beta`` without its 2, no decay, the
 two norm placements swapped, no q/k norm and the last block left out.
@@ -141,6 +142,7 @@ def main() -> int:
     from hetu_galvatron_tpu.models.modules import LayerOps
     from hetu_galvatron_tpu.ops.pallas.conv import causal_conv
     from hetu_galvatron_tpu.ops.pallas.flash_attention import flash_sdpa
+    from hetu_galvatron_tpu.ops.pallas.gdn import gdn_scan
     from hetu_galvatron_tpu.utils.hf_config_adapter import resolve_model_config
 
     cell = manifest.resolve_cell(manifest.load_manifest(), a.workload)
@@ -172,7 +174,8 @@ def main() -> int:
 
     def program_logits(p, run_cfg):
         # (a block takes the fields its kind reads)
-        sdpa = ({i: LayerOps(sdpa=flash_sdpa, conv=causal_conv)
+        sdpa = ({i: LayerOps(sdpa=flash_sdpa, conv=causal_conv,
+                             gdn=gdn_scan)
                  for i in range(run_cfg.num_hidden_layers)}
                 if dev.platform == "tpu" else None)
         return jax.jit(lambda p, t: forward_causal_lm(
