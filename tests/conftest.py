@@ -24,8 +24,14 @@ jax.config.update("jax_enable_compilation_cache", False)
 
 # ---------------------------------------------------------------------------
 # Fast/slow tiers. ``-m "not slow"`` is tier-1, what the driver runs after
-# every PR: six xdist workers (``--dist loadfile``) under a 1,470 s limit,
-# held by ISSUE 30 to under 600 s. Nobody runs the slow tier as a matter of
+# every PR: six xdist workers under a 1,470 s limit, asked for with ``--dist
+# load``, which would hand a module's cases to all six. The test files build
+# what is costly once a module (an ``lru_cache``d pair of sides, a
+# module-scoped model, a compiled interpret-mode kernel), so
+# ``pytest_xdist_make_scheduler`` below keeps a module on one worker whatever
+# ``--dist`` says: the longest file is then the floor of the wall time (no
+# file over 240 s alone; PERF.md, "What tier-1 costs", has the budget a new
+# file may take). Nobody runs the slow tier as a matter of
 # course, so what guards a path one of the benchmark's cells executes (the
 # dropless MoE layer, per-layer remat, GPT-2's shape, tp2 x dp2 ZeRO-3 SPMD
 # parity, the flash kernels, the fused cross-entropy, XLA's full-remat
@@ -169,6 +175,25 @@ def pytest_collection_modifyitems(config, items):
                       f"{sorted(stale)}", stacklevel=1)
 
 
+@pytest.hookimpl(optionalhook=True)
+def pytest_xdist_make_scheduler(config, log):
+    """A module's cases run on one worker, in the file's order (``optional``:
+    a run with ``-p no:xdist`` has no such hook and still collects)."""
+    from xdist.scheduler import LoadFileScheduling
+
+    return LoadFileScheduling(config, log)
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _a_module_leaves_no_executables():
+    """Every live XLA:CPU executable holds some ninety memory mappings and a
+    worker runs some five hundred cases under ``vm.max_map_count`` = 65,530
+    (PR 67 lost workers to a segmentation fault in the compiler); what is
+    alive at the end is also what a worker tears down after ``[100%]``."""
+    yield
+    jax.clear_caches()
+
+
 @pytest.fixture(scope="session")
 def cpu_devices():
     devs = jax.devices("cpu")
@@ -268,9 +293,10 @@ def conv_kernels_are_the_block(monkeypatch):
             y = apply(p, a, conv_fn=conv_fn).astype(jnp.float32)
             return jnp.mean(jnp.sin(3.0 * y))
 
-        sides = [jax.value_and_grad(functools.partial(loss, fn),
-                                    argnums=(0, 1))(params, x.astype(dtype))
-                 for fn in (kernels, None)]
+        # (one program a side: op by op a block is hundreds of compiles)
+        sides = [jax.jit(jax.value_and_grad(
+            functools.partial(loss, fn), argnums=(0, 1)))(
+                params, x.astype(dtype)) for fn in (kernels, None)]
         assert ran and all(out is not None for out in ran)
         (got, ggrads), (want, wgrads) = sides
         assert abs(float(got) - float(want)) < loss_band

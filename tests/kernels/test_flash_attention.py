@@ -952,283 +952,3 @@ def test_a_call_on_rows_holds_no_transpose_around_its_kernels(case):
         ("transposed" if relayouts else "rows", S, S, N, K, D, Dv, window)}
     assert len(moved) == relayouts
     LAYOUT_CALLS.clear()
-
-
-# ---------------------------------------------------------------------------
-# the projections' own rows: at head widths of whole lane tiles and at width
-# 64 the kernels index [B, S, N * D] as it is (one head a 128-lane column
-# block, or two), and nothing is transposed around a call
-# ---------------------------------------------------------------------------
-
-
-# B, S, heads, kv heads, q/k width, v width, dtype, blocks, window,
-# segments, dropout
-_ROWS = {
-    "w128_mha": (2, 256, 2, 2, 128, 128, jnp.float32, None, None, False, 0.0),
-    "w128_g4": (1, 256, 8, 2, 128, 128, jnp.float32, (128, 128), None, False,
-                0.0),
-    "w128_g4_bf16": (1, 256, 4, 1, 128, 128, jnp.bfloat16, (128, 128), None,
-                     False, 0.0),
-    "w256_v128": (1, 128, 2, 2, 256, 128, jnp.float32, None, None, False,
-                  0.0),
-    "w128_v256_g2": (1, 128, 4, 2, 128, 256, jnp.float32, (64, 64), None,
-                     False, 0.0),
-    "w128_window": (1, 256, 4, 2, 128, 128, jnp.float32, (64, 64), 100,
-                    False, 0.0),
-    "w128_segments_dropout": (2, 128, 2, 1, 128, 128, jnp.float32, (64, 32),
-                              None, True, 0.2),
-    "w64_even_heads": (2, 256, 4, 4, 64, 64, jnp.float32, (128, 128), None,
-                       False, 0.0),
-    "w64_even_heads_bf16": (1, 256, 2, 2, 64, 64, jnp.bfloat16, None, None,
-                            False, 0.0),
-    "w64_5_heads": (2, 128, 5, 5, 64, 64, jnp.float32, (64, 64), None, False,
-                    0.0),
-    "w64_5_heads_bf16": (1, 256, 5, 5, 64, 64, jnp.bfloat16, (128, 128),
-                         None, False, 0.0),
-    "w64_one_head": (1, 128, 1, 1, 64, 64, jnp.float32, None, None, False,
-                     0.0),
-    "w64_g2": (1, 128, 4, 2, 64, 64, jnp.float32, (64, 64), None, False,
-               0.0),
-    "w64_g4": (1, 256, 8, 2, 64, 64, jnp.float32, (128, 128), None, False,
-               0.0),
-    "w64_g4_bf16": (1, 128, 8, 2, 64, 64, jnp.bfloat16, None, None, False,
-                    0.0),
-    "w64_g5": (1, 128, 10, 2, 64, 64, jnp.float32, (64, 64), None, False,
-               0.0),
-    "w64_g3_four_kv": (1, 128, 12, 4, 64, 64, jnp.float32, (64, 64), None,
-                       False, 0.0),
-    "w64_window": (1, 256, 4, 4, 64, 64, jnp.float32, (64, 64), 70, False,
-                   0.0),
-    "w64_5_heads_window_segments": (2, 128, 5, 5, 64, 64, jnp.float32,
-                                    (32, 64), 50, True, 0.0),
-    "w64_segments": (2, 128, 4, 2, 64, 64, jnp.float32, (64, 32), None, True,
-                     0.0),
-    "w64_dropout": (2, 128, 4, 4, 64, 64, jnp.float32, (64, 64), None, False,
-                    0.25),
-    "w64_5_heads_dropout": (1, 128, 5, 5, 64, 64, jnp.float32, (32, 32),
-                            None, False, 0.25),
-    "w64_g4_segments_dropout": (2, 128, 8, 2, 64, 64, jnp.float32, (64, 64),
-                                None, True, 0.1),
-    "w64_noncausal_unequal_lengths": (1, 128, 4, 2, 64, 64, jnp.float32,
-                                      (64, 64), None, False, 0.0),
-}
-
-
-def _rows_case(case):
-    """Operands of a ``_ROWS`` case and its call's keywords (``Sk`` twice
-    ``S`` and no causal mask where the case's name says so)."""
-    from hetu_galvatron_tpu.ops.pallas.flash_attention import (
-        choose_blocks, seed_from_key)
-
-    B, S, N, K, D, Dv, dtype, blocks, window, seg, rate = _ROWS[case]
-    Sk = 2 * S if "unequal" in case else S
-    ks = jax.random.split(jax.random.key(13), 4)
-    q = jax.random.normal(ks[0], (B, S, N, D), dtype)
-    k = jax.random.normal(ks[1], (B, Sk, K, D), dtype)
-    v = jax.random.normal(ks[2], (B, Sk, K, Dv), dtype)
-    do = jax.random.normal(ks[3], (B, S, N, Dv), dtype)
-    segs = ((jnp.arange(S)[None, :] >= jnp.array([[S // 3], [S // 2]])[:B]
-             ).astype(jnp.int32) if seg else None)
-    bq, bk = blocks or choose_blocks(D, S, Sk)
-    call = dict(causal="noncausal" not in case, block_q=bq, block_k=bk,
-                interpret=True, dropout_rate=rate, window=window)
-    seed = seed_from_key(jax.random.key(5)) if rate else None
-    return (q, k, v, do), segs, seed, call
-
-
-@pytest.mark.parametrize("case", sorted(_ROWS))
-def test_flash_on_rows_is_the_head_major_call(case):
-    """Output, statistics and the three gradients of the kernels on the
-    projections' rows against the same kernels on head-major copies
-    (``flash_attention_hmajor`` between transposes, the only path there
-    was): float32 to the accumulation order of a 128-deep contraction that
-    adds exact zeros, bf16 to a rounding of the result."""
-    from hetu_galvatron_tpu.ops.pallas.flash_attention import (
-        flash_attention_bwd_hmajor, flash_attention_bwd_rows,
-        flash_attention_hmajor, flash_attention_rows, row_layout)
-
-    (q, k, v, do), segs, seed, call = _rows_case(case)
-    (B, S, N, D), K, Dv = q.shape, k.shape[2], v.shape[3]
-    assert row_layout(N, K, D, Dv) == (2 if D == 64 else 1)
-    t = lambda a: a.transpose(0, 2, 1, 3)  # noqa: E731
-    o_h, lse_h = flash_attention_hmajor(t(q), t(k), t(v), segs, seed, **call)
-    want = (t(o_h),) + tuple(t(g) for g in flash_attention_bwd_hmajor(
-        t(q), t(k), t(v), o_h, lse_h, t(do), segs, seed, **call))
-    flat = lambda a: a.reshape(*a.shape[:2], -1)  # noqa: E731
-    o_r, lse_r = flash_attention_rows(flat(q), flat(k), flat(v), segs, seed,
-                                      heads=(N, K), **call)
-    assert o_r.shape == (B, S, N * Dv)
-    # (an odd count of paired heads carries the statistics of one more)
-    assert lse_r.shape == (B, N + (N % 2 if D == 64 else 0), S, 1)
-    got = (o_r,) + flash_attention_bwd_rows(
-        flat(q), flat(k), flat(v), o_r, lse_r, flat(do), segs, seed,
-        heads=(N, K), **call)
-    tol = (dict(rtol=2e-2, atol=2e-2) if q.dtype == jnp.bfloat16
-           else dict(rtol=1e-5, atol=1e-5))
-    np.testing.assert_allclose(np.asarray(lse_r[:, :N]), np.asarray(lse_h),
-                               rtol=1e-5, atol=1e-5)
-    for name, g, w in zip(("out", "dq", "dk", "dv"), got, want):
-        g = np.asarray(g.reshape(w.shape).astype(jnp.float32))
-        assert np.all(np.isfinite(g)), name
-        np.testing.assert_allclose(g, np.asarray(w.astype(jnp.float32)),
-                                   err_msg=name, **tol)
-
-
-# ---------------------------------------------------------------------------
-# segments bound the loops: a chunk range a tile from the ids' bounds
-# ---------------------------------------------------------------------------
-
-
-def _ids(kind: str, S: int = 256, seed: int = 0) -> np.ndarray:
-    """Segment ids [B, S] of one kind (document lengths from ``seed``)."""
-    rng = np.random.default_rng(seed)
-
-    def docs(n, total):
-        cuts = np.sort(rng.choice(np.arange(1, total), n - 1, replace=False))
-        return np.diff(np.concatenate([[0], cuts, [total]]))
-
-    if kind == "sorted":
-        return np.repeat(np.arange(5), docs(5, S))[None].astype(np.int32)
-    if kind == "unsorted":
-        return np.repeat(rng.permutation(7), docs(7, S))[None].astype(
-            np.int32)
-    if kind == "padding_tail":
-        # documents 1.., then the padding's id 0: sorted no longer
-        body = np.repeat(1 + np.arange(3), docs(3, S - 37))
-        return np.concatenate([body, np.zeros(37, body.dtype)])[None].astype(
-            np.int32)
-    if kind == "two_rows":
-        return np.stack([np.repeat(np.arange(4), docs(4, S)),
-                         np.repeat(np.arange(2), docs(2, S))]).astype(
-                             np.int32)
-    if kind == "every_position_its_own":
-        return np.arange(S, dtype=np.int32)[None]
-    raise KeyError(kind)
-
-
-@pytest.mark.parametrize("blocks", [(32, 32), (64, 16), (16, 64)],
-                         ids=lambda b: f"{b[0]}x{b[1]}")
-@pytest.mark.parametrize("kind", ["sorted", "unsorted", "padding_tail",
-                                  "two_rows", "every_position_its_own"])
-def test_segment_chunk_ranges_hold_every_pair_the_mask_leaves(kind, blocks):
-    """Against brute force: every (tile, chunk) in which some query's id
-    equals some key's lies inside the tile's range whatever the ids, and
-    for ids that do not decrease the range is exactly those; a traced
-    array and a NumPy row go the same way."""
-    from hetu_galvatron_tpu.ops.pallas.flash_attention import (
-        segment_chunk_ranges)
-
-    rows, cols = blocks
-    for seed in range(4):
-        ids = _ids(kind, seed=seed)
-        first, end = segment_chunk_ranges(ids, rows, cols)
-        traced = jax.jit(functools.partial(
-            segment_chunk_ranges, rows_block=rows, cols_block=cols))(ids)
-        np.testing.assert_array_equal(first, traced[0])
-        np.testing.assert_array_equal(end, traced[1])
-        assert first.dtype == end.dtype == np.int32
-        assert first.shape == (ids.shape[0], ids.shape[1] // rows)
-        same = ids[:, :, None] == ids[:, None, :]
-        meets = same.reshape(ids.shape[0], ids.shape[1] // rows, rows,
-                             ids.shape[1] // cols, cols).any((2, 4))
-        chunk = np.arange(meets.shape[-1])
-        inside = ((first[..., None] <= chunk) & (chunk < end[..., None]))
-        assert np.all(inside | ~meets)
-        assert np.all(end > first)
-        if np.all(np.diff(ids, axis=1) >= 0):
-            np.testing.assert_array_equal(inside, meets)
-    assert kind not in ("sorted", "two_rows") or np.all(
-        np.diff(ids, axis=1) >= 0)
-
-
-def test_the_cells_images_leave_108_of_256_tiles():
-    """kimivl_c1_b1_s4k's tower call: three images one after the other at
-    512 x 512 tiles, 8 x 8 + 6 x 6 + 3 x 3 less the tile the second
-    boundary (patch 6,976, inside tile 13) puts in two squares."""
-    from hetu_galvatron_tpu.ops.pallas.flash_attention import (
-        segment_chunk_ranges, two_way_tiles)
-
-    ids = np.repeat(np.arange(3), [64 * 64, 36 * 80, 32 * 38])
-    first, end = segment_chunk_ranges(ids, 512, 512)
-    assert first.tolist() == [0] * 8 + [8] * 6 + [13] * 2
-    assert end.tolist() == [8] * 8 + [14] * 5 + [16] * 3
-    assert two_way_tiles(8192, 8192, 512, 512, ids) == 108
-    assert two_way_tiles(8192, 8192, 512, 512) == 256
-    # the dk/dv kernel's ranges (a k tile's q chunks) are the same count
-    assert two_way_tiles(8192, 8192, 256, 512, ids) == int(np.sum(np.subtract(
-        *segment_chunk_ranges(ids, 512, 256)[::-1])))
-
-
-# ids of two batch rows, segmented differently, by what the boundaries do
-# at q tiles of 32 and k chunks of 16 (S = 128; 160 where the case shrinks
-# the residency budget to two chunks a major block)
-_SEGMENTATIONS = {
-    "on_tile_edges": ([32, 64, 32], [64, 64]),
-    "inside_tiles": ([40, 35, 53], [10, 90, 20, 8]),
-    "across_major_blocks": ([50, 60, 50], [90, 70]),
-    "unsorted_ids": ([(2, 30), (0, 50), (2, 20), (1, 28)],
-                     [(1, 64), (0, 64)]),
-    "a_document_under_a_tile": ([60, 5, 63], [3, 125]),
-}
-_LAYOUTS = {"head_major_72": 72, "rows_128": 128, "pairs_64": 64}
-_MASKS = {"two_way": (False, None), "causal": (True, None),
-          "window_24": (True, 24)}
-
-
-@pytest.mark.parametrize("segmentation", sorted(_SEGMENTATIONS))
-@pytest.mark.parametrize("layout", sorted(_LAYOUTS))
-@pytest.mark.parametrize("mask", sorted(_MASKS))
-def test_flash_with_segments_visits_what_the_dense_mask_leaves(
-        monkeypatch, mask, layout, segmentation):
-    """out, dq, dk and dv against the dense core for ids of every kind: the
-    kernels' loops and index maps run over each tile's prefetched chunk
-    range alone, and what they skip the mask would have emptied."""
-    from hetu_galvatron_tpu.ops.pallas import flash_attention as fa
-
-    causal, window = _MASKS[mask]
-    D = _LAYOUTS[layout]
-    assert fa.row_layout(4, 2, D, D) == {72: None, 128: 1, 64: 2}[D]
-    rows = []
-    for row in _SEGMENTATIONS[segmentation]:
-        docs = [d if isinstance(d, tuple) else (i, d)
-                for i, d in enumerate(row)]
-        rows.append(np.repeat([i for i, _ in docs], [n for _, n in docs]))
-    seg = jnp.asarray(np.stack(rows), jnp.int32)
-    S = seg.shape[1]
-    if segmentation == "across_major_blocks":
-        # two k chunks a major block, one q chunk: 5 major blocks each way
-        # (a length no other case traces, so no trace with the real budget
-        # is reused)
-        monkeypatch.setattr(fa, "_RESIDENT_BYTES", 2 * 16 * 128 * 4)
-        assert S == 160 and fa._major_chunks(S, 16, D * 4) == 2
-    q, k, v = _qkv(B=2, S=S, N=4, K=2, D=D, seed=21)
-    do = jax.random.normal(jax.random.key(22), q.shape, q.dtype)
-    ref = _fwd_and_grads(
-        lambda a, b, c: xla_sdpa(a, b, c, causal=causal, window=window,
-                                 segment_ids=seg), q, k, v, do)
-    got = _fwd_and_grads(
-        lambda a, b, c: flash_sdpa(a, b, c, causal=causal, window=window,
-                                   segment_ids=seg, interpret=True,
-                                   block_q=32, block_k=16), q, k, v, do)
-    _assert_f32_parity(got, ref)
-
-
-@pytest.mark.parametrize("causal", [False, True])
-def test_a_chunk_outside_a_tiles_range_is_never_read(causal):
-    """The second document's q, k, v and dO are NaN. A kernel that visited
-    its chunks for the first document's tiles would spread them (a masked
-    probability is 0, and 0 . NaN is NaN, in p . v, ds . k, p^T . dO and
-    ds^T . q alike); the first document's rows come out as those of the
-    document alone."""
-    q, k, v = _qkv(B=1, S=128, N=2, K=2, D=32, seed=31)
-    do = jax.random.normal(jax.random.key(32), q.shape, q.dtype)
-    seg = jnp.asarray(np.repeat([0, 1], 64)[None], jnp.int32)
-    alone = _fwd_and_grads(
-        lambda a, b, c: xla_sdpa(a, b, c, causal=causal),
-        *(x[:, :64] for x in (q, k, v, do)))
-    nan = lambda x: x.at[:, 64:].set(jnp.nan)  # noqa: E731
-    got = _fwd_and_grads(
-        lambda a, b, c: flash_sdpa(a, b, c, causal=causal, segment_ids=seg,
-                                   interpret=True, block_q=32, block_k=16),
-        nan(q), nan(k), nan(v), nan(do))
-    _assert_f32_parity([g[:, :64] for g in got], alone)

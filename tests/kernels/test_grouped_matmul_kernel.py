@@ -20,16 +20,6 @@ from hetu_galvatron_tpu.ops.pallas import grouped_matmul as gm
 pytestmark = pytest.mark.kernels
 
 
-@pytest.fixture(scope="module", autouse=True)
-def _compiled_programs_go_with_the_module():
-    """Interpret mode compiles a CPU program a case, and every live
-    executable holds some ninety memory mappings: a tier-1 worker ends a
-    run within a few thousand of ``vm.max_map_count`` (65,530), past which
-    XLA:CPU's next compile dies of a segmentation fault. What this module
-    compiled is dropped when its last case on a worker is done."""
-    yield
-    jax.clear_caches()
-
 # rows, contracted width of the forward product, its columns
 M, K, N = 640, 256, 384
 # group sizes: a tile is 512 rows here (then one of 128), a piece 128

@@ -50,10 +50,12 @@ def _inputs(strength, seed=0, seq=SEQ):
 
 def _values_and_gradients(fn, args):
     weight = jax.random.normal(jax.random.key(9), args[2].shape)
-    out = fn(*args)
-    grads = jax.grad(lambda *a: jnp.sum(fn(*a) * weight),
-                     argnums=(0, 1, 2, 3, 4))(*args)
-    return (out,) + grads
+
+    # (one program a side: op by op a form is hundreds of compiles)
+    def both(*a):
+        return (fn(*a),) + jax.grad(lambda *b: jnp.sum(fn(*b) * weight),
+                                    argnums=(0, 1, 2, 3, 4))(*a)
+    return jax.jit(both)(*args)
 
 
 @pytest.mark.parametrize("chunk", [32, 64])

@@ -11,10 +11,9 @@ chunks (each kernel file's ``KEPT``).
 Interpret-mode kernels on the CPU; the compile of the real kernels for a
 described v5e is in ``test_flash_mosaic_compile.py``.
 
-Bit-equality is asserted op by op (no outer ``jit``): each primitive then
-runs as its own program on both sides, and what is compared is the
-arithmetic, not which elementwise neighbours XLA:CPU chose to fuse into a
-matmul in two differently shaped programs."""
+Here the kernels are counted in jaxprs, which costs no compile; that the
+numbers are plain ``jax.checkpoint``'s to the last bit is in
+``test_remat_keeps_numbers.py``."""
 
 import functools
 import re
@@ -218,14 +217,6 @@ def test_a_rematted_block_runs_its_attention_core_once(cpu_devices, case):
         "kept": blocks, "plain": 2 * blocks}
     for kernel in backward:
         assert _kernel_calls(jaxprs["kept"], kernel) == blocks
-    ours = jax.value_and_grad(kept, argnums=(0, 1))(params, x)
-    theirs = jax.value_and_grad(plain, argnums=(0, 1))(params, x)
-    assert np.isfinite(float(ours[0]))
-    paths = jax.tree_util.tree_flatten_with_path(ours)[0]
-    for (path, a), b in zip(paths, jax.tree.leaves(theirs)):
-        np.testing.assert_array_equal(
-            np.asarray(a), np.asarray(b),
-            err_msg=f"{case}: {jax.tree_util.keystr(path)}")
 
 
 def _transposes(jaxpr, found=None):

@@ -162,8 +162,8 @@ def test_granite_hf_logit_parity():
     tokens = np.random.RandomState(1).randint(0, 64, (2, 21))
     with torch.no_grad():
         want = hf(torch.tensor(tokens)).logits.numpy()
-    got = forward_causal_lm(params, jnp.asarray(tokens), cfg,
-                            compute_dtype=jnp.float32)
+    got = jax.jit(lambda p, t: forward_causal_lm(
+        p, t, cfg, compute_dtype=jnp.float32))(params, jnp.asarray(tokens))
     np.testing.assert_allclose(np.asarray(got), want, rtol=2e-4, atol=2e-5)
 
 
@@ -301,18 +301,25 @@ def test_program_matches_plain_reference(case, monkeypatch):
     def ref_loss(w):
         return ref.nll_sum(w, ref_cfg, batch["tokens"],
                            batch["labels"]) / batch["labels"].size
+    # (one program a side: op by op they are some thousands of compiles)
     if case == "as_published_bf16":
-        got = causal_lm_loss(params, batch, cfg, compute_dtype=jnp.bfloat16)
-        assert abs(float(got) - float(ref_loss(weights))) < BF16_LOSS, (float(got), float(ref_loss(weights)))
+        got = jax.jit(lambda p: causal_lm_loss(
+            p, batch, cfg, compute_dtype=jnp.bfloat16))(params)
+        assert abs(float(got) - float(jax.jit(ref_loss)(weights))) \
+            < BF16_LOSS, float(got)
         return
-    want, want_grads = jax.value_and_grad(ref_loss)(weights)
-    got, got_grads = jax.value_and_grad(lambda p: causal_lm_loss(
-        p, batch, run_cfg, compute_dtype=jnp.float32))(run_params)
+    def run_loss(p):
+        return causal_lm_loss(p, batch, run_cfg, compute_dtype=jnp.float32)
+    loss_close = abs(float(jax.jit(run_loss)(run_params))
+                     - float(jax.jit(ref_loss)(weights))) < 2e-5
+    if case != "as_published" and not loss_close:
+        return   # told by the loss: the gradients' programs are not built
+    want, want_grads = jax.jit(jax.value_and_grad(ref_loss))(weights)
+    got, got_grads = jax.jit(jax.value_and_grad(run_loss))(run_params)
     # tolerance: both sides are fp32 on the CPU and differ in operation
     # order (the recurrence as three chunked matmuls and a scan over chunks
     # against 21 steps; fused qkv and in_proj products). The loss is of
     # order 4.2, gradients up to 0.1
-    loss_close = abs(float(got) - float(want)) < 2e-5
     got_grads = params_to_hf(got_grads, cfg)
     assert sorted(got_grads) == sorted(want_grads)
     apart = [k for k in want_grads if not np.allclose(
@@ -349,13 +356,14 @@ def test_chunked_recurrence_is_the_sequential_one(seq, chunk, bytes_,
     Bm = jax.random.normal(k[3], (2, seq, 16))
     Cm = jax.random.normal(k[4], (2, seq, 16))
     args = (x, dt, A, Bm, Cm)
-    chunked = lambda *a: M.ssd_chunked(*a, chunk, jnp.float32)
-    np.testing.assert_allclose(chunked(*args), ref.selective_scan(*args),
+    chunked = jax.jit(lambda *a: M.ssd_chunked(*a, chunk, jnp.float32))
+    sequential = jax.jit(ref.selective_scan)
+    np.testing.assert_allclose(chunked(*args), sequential(*args),
                                rtol=1e-4, atol=1e-4)
-    got = jax.grad(lambda *a: jnp.sum(jnp.sin(chunked(*a))),
-                   argnums=range(5))(*args)
-    want = jax.grad(lambda *a: jnp.sum(jnp.sin(ref.selective_scan(*a))),
-                    argnums=range(5))(*args)
+    got = jax.jit(jax.grad(lambda *a: jnp.sum(jnp.sin(chunked(*a))),
+                           argnums=range(5)))(*args)
+    want = jax.jit(jax.grad(lambda *a: jnp.sum(jnp.sin(sequential(*a))),
+                            argnums=range(5)))(*args)
     for g, w in zip(got, want):
         np.testing.assert_allclose(g, w, rtol=2e-3, atol=2e-3)
 

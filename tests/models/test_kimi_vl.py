@@ -133,8 +133,8 @@ def sides():
         cfg = ModelArgs(**TINY)
         params, batch = _seeded(cfg), _batch(cfg)
         on_device = jax.tree.map(jnp.asarray, batch)
-        loss, grads = jax.value_and_grad(lambda p: causal_lm_loss(
-            p, on_device, cfg, compute_dtype=jnp.float32))(params)
+        loss, grads = jax.jit(jax.value_and_grad(lambda p: causal_lm_loss(
+            p, on_device, cfg, compute_dtype=jnp.float32)))(params)
         weights = {k: jnp.asarray(v)
                    for k, v in params_to_hf(params, cfg).items()}
         return (cfg, float(loss), params_to_hf(grads, cfg), weights,
@@ -156,7 +156,7 @@ def test_program_matches_plain_reference(control, sides):
         return ref.nll_sum(w, REF_CFG, batch["tokens"], batch["labels"],
                            batch=batch, control=control) / marked
 
-    ref_loss, ref_grads = jax.value_and_grad(mean)(weights)
+    ref_loss, ref_grads = jax.jit(jax.value_and_grad(mean))(weights)
     assert set(ref_grads) == set(grads)
     # tolerances: fp32 sums in another order, a loss of 4.3 and gradient
     # leaves from 1e-4 up
@@ -193,8 +193,8 @@ def test_the_position_table_is_read_as_it_is_at_its_own_grid():
 
 def test_bf16_compute_stays_near_the_fp32_reference(sides):
     cfg, loss, _, _, batch = sides
-    got = causal_lm_loss(_seeded(cfg), batch, cfg,
-                         compute_dtype=jnp.bfloat16)
+    got = jax.jit(lambda p: causal_lm_loss(
+        p, batch, cfg, compute_dtype=jnp.bfloat16))(_seeded(cfg))
     assert abs(float(got) - loss) < 2e-2
 
 
@@ -203,10 +203,11 @@ def test_program_in_bf16_matches_plain_reference(sides):
     program in bfloat16, the reference as it is: the loss alone."""
     cfg, _, _, weights, batch = sides
     marked = float(batch["loss_mask"].sum())
-    want = _family().nll_sum(weights, REF_CFG, batch["tokens"],
-                             batch["labels"], batch=batch) / marked
-    got = causal_lm_loss(_seeded(cfg), batch, cfg,
-                         compute_dtype=jnp.bfloat16)
+    want = jax.jit(lambda w: _family().nll_sum(
+        w, REF_CFG, batch["tokens"], batch["labels"],
+        batch=batch))(weights) / marked
+    got = jax.jit(lambda p: causal_lm_loss(
+        p, batch, cfg, compute_dtype=jnp.bfloat16))(_seeded(cfg))
     assert abs(float(got) - float(want)) < BF16_LOSS, (
         float(got), float(want))
 

@@ -259,11 +259,11 @@ def test_the_flash_kernels_run_the_blocks_as_the_xla_core_does():
     flash = partial(flash_sdpa, interpret=True, block_q=8, block_k=8)
     flash.supports_window = True
     ops = {i: M.LayerOps(sdpa=flash) for i in range(4)}
-    want, want_g = jax.value_and_grad(lambda p: causal_lm_loss(
-        p, batch, cfg, compute_dtype=jnp.float32))(params)
-    got, got_g = jax.value_and_grad(lambda p: causal_lm_loss(
+    want, want_g = jax.jit(jax.value_and_grad(lambda p: causal_lm_loss(
+        p, batch, cfg, compute_dtype=jnp.float32)))(params)
+    got, got_g = jax.jit(jax.value_and_grad(lambda p: causal_lm_loss(
         p, batch, cfg, compute_dtype=jnp.float32, layer_overrides=ops,
-        remat_flags=[True] * 4))(params)
+        remat_flags=[True] * 4)))(params)
     assert abs(float(got) - float(want)) < 2e-5
     for a, b in zip(jax.tree.leaves(got_g), jax.tree.leaves(want_g)):
         np.testing.assert_allclose(a, b, rtol=5e-4, atol=5e-5)

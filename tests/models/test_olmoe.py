@@ -83,15 +83,16 @@ def test_olmoe_hf_logit_parity(norm_topk_prob):
     tokens_np = np.random.RandomState(0).randint(0, 64, (2, 16))
     with torch.no_grad():
         ref = hf(torch.tensor(tokens_np)).logits.numpy()
-    ours = forward_causal_lm(params, jnp.asarray(tokens_np), cfg,
-                             compute_dtype=jnp.float32)
+    ours = jax.jit(lambda p, t: forward_causal_lm(
+        p, t, cfg, compute_dtype=jnp.float32))(params, jnp.asarray(tokens_np))
     # tolerance: fp32 torch against fp32 XLA through two blocks (softmax,
     # three RMSNorms a block); the logits are of order 0.1
     np.testing.assert_allclose(np.asarray(ours), ref, rtol=2e-4, atol=2e-5)
     flipped = cfg.model_copy(update=dict(
         moe_norm_topk_prob=not norm_topk_prob))
-    wrong = forward_causal_lm(params, jnp.asarray(tokens_np), flipped,
-                              compute_dtype=jnp.float32)
+    wrong = jax.jit(lambda p, t: forward_causal_lm(
+        p, t, flipped, compute_dtype=jnp.float32))(
+            params, jnp.asarray(tokens_np))
     assert np.abs(np.asarray(wrong) - ref).max() > 1e-3
 
 
@@ -176,7 +177,8 @@ def test_program_matches_plain_reference(case):
         return ref.nll_sum(w, REF_CFG, batch["tokens"],
                            batch["labels"]) / batch["labels"].size
 
-    want, want_grads = jax.value_and_grad(ref_loss)(weights)
+    # (one program a side: op by op they are some thousands of compiles)
+    want, want_grads = jax.jit(jax.value_and_grad(ref_loss))(weights)
 
     run_cfg, run_params = cfg, params
     if case == "topk_renormalised":
@@ -185,7 +187,8 @@ def test_program_matches_plain_reference(case):
         run_params = _without_qk_norm(params)
 
     if case == "as_published_bf16":
-        got = causal_lm_loss(params, batch, cfg, compute_dtype=jnp.bfloat16)
+        got = jax.jit(lambda p: causal_lm_loss(
+            p, batch, cfg, compute_dtype=jnp.bfloat16))(params)
         assert abs(float(got) - float(want)) < BF16_LOSS, (
             float(got), float(want))
         return
@@ -193,7 +196,7 @@ def test_program_matches_plain_reference(case):
     def prog_loss(p):
         return causal_lm_loss(p, batch, run_cfg, compute_dtype=jnp.float32)
 
-    got, got_grads = jax.value_and_grad(prog_loss)(run_params)
+    got, got_grads = jax.jit(jax.value_and_grad(prog_loss))(run_params)
     if case == "no_qk_norm":   # give the exporter the norms' (absent) slots
         got_grads = {**got_grads, "layers": tuple(
             {**lp, "attn": {**lp["attn"],

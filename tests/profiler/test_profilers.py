@@ -71,14 +71,25 @@ def test_sp_time_profile_feeds_latency_tables(hw_args, cpu_devices):
     assert not any(isinstance(k, int) and k > 128 for k in tables[4])
 
 
-def test_alpha_beta_fit_roundtrips_into_cost_model(cpu_devices):
+def test_alpha_beta_fit_roundtrips_into_cost_model(cpu_devices, monkeypatch):
     """profile_alpha_beta fits (α ms, β MB/ms) per (size, consec) from the
     sub-MB + MB allreduce points; the pairs merge into the bandwidth JSON,
-    profiles.read_alpha_beta parses them, and a legacy JSON yields {}."""
+    profiles.read_alpha_beta parses them, and a legacy JSON yields {}. The
+    collectives run, on a clock they are handed (α + MB / β of the message:
+    a loaded host's own readings fit a slope of any sign), and the fit gives
+    the pair back."""
+    from hetu_galvatron_tpu.core.profiler import hardware_profiler
     from hetu_galvatron_tpu.core.search_engine.profiles import (
         read_alpha_beta,
     )
 
+    alpha_ms, beta_mb_per_ms = 0.05, 2.0
+
+    def handed(fn, arg, **_):
+        jax.block_until_ready(fn(arg))
+        return alpha_ms + arg.size * 4 / 2 ** 20 / beta_mb_per_ms
+
+    monkeypatch.setattr(hardware_profiler, "_time_fn", handed)
     args = HardwareProfileArgs(num_nodes=1, num_devices_per_node=4,
                                start_mb=1, end_mb=4, scale=2,
                                warmup_iters=1, profile_iters=1)
@@ -87,8 +98,10 @@ def test_alpha_beta_fit_roundtrips_into_cost_model(cpu_devices):
     ab = prof.profile_alpha_beta(sp)
     for size, consec in ((4, 1), (2, 1), (2, 0)):
         assert f"allreduce_size_{size}_consec_{consec}_alpha_ms" in ab
-        beta = ab[f"allreduce_size_{size}_consec_{consec}_beta_mb_per_ms"]
-        assert beta > 0
+        assert ab[f"allreduce_size_{size}_consec_{consec}_alpha_ms"] == \
+            pytest.approx(alpha_ms, rel=1e-3)
+        assert ab[f"allreduce_size_{size}_consec_{consec}_beta_mb_per_ms"] \
+            == pytest.approx(beta_mb_per_ms, rel=1e-3)
     # merged with the bandwidth keys, the reader recovers the pairs...
     bw = prof.profile_allreduce_bandwidth(message_mb=1)
     bw.update(ab)

@@ -7,6 +7,7 @@ step, and the flight dump on engine abort."""
 import io
 import json
 import os
+import time
 
 import jax
 import jax.numpy as jnp
@@ -52,7 +53,29 @@ def _traced_engine(tmp_path, params, cfg, **sv_kw):
     return eng, reg, metrics_path
 
 
-def test_complete_timelines_and_additive_ttft_split(tmp_path):
+class _Ticks:
+    """The ``time`` the scheduler and the engine read in a test that compares
+    their readings: each reading of either clock is one tick after the last,
+    whatever else the host was running."""
+    TICK_MS = 0.1
+    sleep = staticmethod(time.sleep)
+
+    def __init__(self):
+        self.now = 0.0
+
+    def monotonic(self):
+        self.now += self.TICK_MS / 1000.0
+        return self.now
+
+    perf_counter = monotonic
+
+
+def test_complete_timelines_and_additive_ttft_split(tmp_path, monkeypatch):
+    from hetu_galvatron_tpu.serving import engine, scheduler
+
+    clock = _Ticks()
+    monkeypatch.setattr(engine, "time", clock)
+    monkeypatch.setattr(scheduler, "time", clock)
     cfg = _cfg()
     params, _ = init_causal_lm(jax.random.key(0), cfg)
     eng, reg, metrics_path = _traced_engine(tmp_path, params, cfg)
@@ -94,12 +117,13 @@ def test_complete_timelines_and_additive_ttft_split(tmp_path):
         assert q + p + d == pytest.approx(t, abs=1e-6)
         assert p > 0  # cold requests really paid a prefill
 
-    # ... and the handle-side TTFT agrees with the event's within jitter
+    # ... and the handle-side TTFT is the event's, a reading or two later
     by_rid = {h.request.rid: h for h in handles}
     for rid, evs in timelines.items():
         ft = next(e for e in evs if e["ev"] == "first_token")
         assert ft["ttft_ms"] == pytest.approx(
-            by_rid[rid].ttft_s() * 1000.0, rel=0.05, abs=0.5)
+            by_rid[rid].ttft_s() * 1000.0, abs=2.5 * _Ticks.TICK_MS)
+        assert ft["ttft_ms"] > 10 * _Ticks.TICK_MS
 
     # queue-wait histogram (satellite): one observation per admission
     qw = [r for r in records if r.get("name") == "serve/queue_wait_ms"]
