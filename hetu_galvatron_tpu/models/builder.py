@@ -274,9 +274,12 @@ def forward_causal_lm(
     aux_total = jnp.zeros((), jnp.float32)
     moe_stats: Dict[str, Dict[str, jax.Array]] = {}
     kinds = cfg.block_kinds()
-    # a table a mixer kind where the model states a rotation a kind
+    # a table a mixer kind where the model states a rotation a kind (in one
+    # order in every process: a set's order is the hash seed's, and the
+    # tables' order in the program is part of the compile cache's key)
     ropes = {m: rope_table(cfg, S, m, position_ids)
-             for m in {m for m, _ in kinds} & set(cfg.rope_parameters or {})}
+             for m in sorted({m for m, _ in kinds}
+                             & set(cfg.rope_parameters or {}))}
     if ropes and cfg.mrope_section:
         raise NotImplementedError(
             "model.rope_parameters (a rotation a mixer kind) with "

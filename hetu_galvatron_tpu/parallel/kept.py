@@ -19,7 +19,16 @@ from __future__ import annotations
 
 import math
 from dataclasses import dataclass
-from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
+from typing import (
+    Any,
+    Callable,
+    Dict,
+    List,
+    NamedTuple,
+    Optional,
+    Sequence,
+    Tuple,
+)
 
 import jax
 import jax.numpy as jnp
@@ -31,8 +40,13 @@ from hetu_galvatron_tpu.models import modules as M
 # batches held ahead and what the count cannot see
 FILL = 0.92
 
-# the compiled step's own code, which XLA counts among its static bytes
-CODE = 256 * 2 ** 20
+# what XLA counts among a step's static bytes beside its arguments and the
+# values its schedule shows live at the fullest moment: the step's own code
+# (0.03 to 0.45 GB), its scratch in VMEM (0.13), the padding of its
+# arguments and what the packing of the temporaries loses. 0.20 to 0.40 GB
+# in all in the four cells whose step runs no loop over its gradients; the
+# least that leaves none of them under XLA's count (PERF.md section 6, PR 72)
+CODE = 320 * 2 ** 20
 
 # operations XLA:TPU duplicates into the fusion that reads their result
 # rather than hold it: a residual one of these derives from values that are
@@ -55,12 +69,17 @@ class BlockCount:
     of the pass a recomputed block runs again (orders blocks; no time).
     ``input_bytes``: what a recomputed block holds: its input and the
     ``KEPT`` names of its forward kernels. ``whole_bytes``: all of it, the
-    working set of the one block whose backward is running."""
+    working set of the one block whose backward is running. ``grad_share``:
+    what the gradient of its parameters takes as the backward makes it,
+    over the parameters as stored; ``carries``: a rule's backward carries
+    part of it through a loop (:func:`made_bytes`)."""
 
     held_bytes: int
     forward_flops: int
     input_bytes: int
     whole_bytes: int
+    grad_share: float = 1.0
+    carries: bool = False
 
 
 # what the allocator of an attached chip of the kind reports as its
@@ -149,20 +168,28 @@ def matmul_flops(jaxpr) -> int:
 _READ_THROUGH = frozenset({"jit", "pjit", "closed_call", "custom_jvp_call"})
 
 
+def _body(eqn, through=_READ_THROUGH):
+    """The jaxpr a call of ``through`` runs, where its inputs are the
+    equation's one for one; else None."""
+    if eqn.primitive.name not in through:
+        return None
+    inner = eqn.params.get("jaxpr", eqn.params.get("call_jaxpr"))
+    inner = getattr(inner, "jaxpr", inner)
+    if inner is None or len(inner.invars) != len(eqn.invars):
+        return None
+    return inner
+
+
 def _producers(jaxpr, made: Dict[Any, Tuple[str, Tuple]], free: set) -> None:
     """``made[v] = (primitive, inputs)`` for every variable of ``jaxpr``,
     the bodies of :data:`_READ_THROUGH` calls read as if written in place
     (their inputs and outputs ``alias`` the caller's)."""
     free.update(jaxpr.constvars)
     for eqn in jaxpr.eqns:
-        name = eqn.primitive.name
-        inner = None
-        if name in _READ_THROUGH:
-            inner = eqn.params.get("jaxpr", eqn.params.get("call_jaxpr"))
-            inner = getattr(inner, "jaxpr", inner)
-        if inner is None or len(inner.invars) != len(eqn.invars):
+        inner = _body(eqn)
+        if inner is None:
             for v in eqn.outvars:
-                made[v] = (name, tuple(eqn.invars))
+                made[v] = (eqn.primitive.name, tuple(eqn.invars))
             continue
         for iv, ov in zip(inner.invars, eqn.invars):
             made[iv] = ("alias", (ov,))
@@ -205,6 +232,64 @@ def residual_bytes(jaxpr, n_out: int, n_params: int) -> int:
     return sum(held.values())
 
 
+# who reads a parameter is asked of an exchange's body too (its shapes are a
+# shard's, so the byte count does not read through it)
+_READERS_THROUGH = _READ_THROUGH | {"shard_map"}
+
+
+def _readers(jaxpr, root: Dict[Any, Any], readers: Dict[Any, List]) -> None:
+    """``readers[v]``: the equations that read variable ``v`` of ``jaxpr``,
+    the bodies of :data:`_READERS_THROUGH` calls read as if written in
+    place (``root``: a body's input -> the caller's variable)."""
+    for eqn in jaxpr.eqns:
+        inner = _body(eqn, _READERS_THROUGH)
+        if inner is None:
+            for v in eqn.invars:
+                if hasattr(v, "count"):
+                    readers.setdefault(root.get(v, v), []).append(eqn)
+            continue
+        for iv, ov in zip(inner.invars, eqn.invars):
+            if hasattr(ov, "count"):
+                root[iv] = root.get(ov, ov)
+        _readers(inner, root, readers)
+
+
+# a rule with a backward of its own (``jax.custom_vjp``), by its equation
+_RULES = ("custom_vjp_call", "custom_vjp_call_jaxpr")
+
+
+def made_bytes(jaxpr, n_params: int) -> Tuple[int, int, bool]:
+    """Of the function's first ``n_params`` inputs that the trace reads at
+    all: (bytes of their gradients as its backward makes them, bytes of the
+    inputs as stored, whether a rule with a backward of its own reads one).
+
+    A parameter every reader of which is a cast to a narrower dtype
+    (:func:`modules.weight_view`) has its gradient made in that dtype: the
+    cast back is the transpose of the cast, and XLA fuses it into what
+    reads the gradient (the norm, the update), so the step holds the narrow
+    one. A parameter anything else reads counts as stored: a gather's
+    scatter-add lands in the stored dtype, and a rule (the expert layer's,
+    models/moe.py) hands back cotangents of its operands' dtype, which its
+    backward carries through the passes of a loop."""
+    readers: Dict[Any, List] = {}
+    _readers(jaxpr, {}, readers)
+    made = stored = 0
+    carries = False
+    for v in jaxpr.invars[:n_params]:
+        eqns = readers.get(v)
+        if not eqns:
+            continue
+        size = jnp.dtype(v.aval.dtype).itemsize
+        as_made = size
+        if all(e.primitive.name == "convert_element_type" for e in eqns):
+            as_made = min(size, max(
+                jnp.dtype(e.outvars[0].aval.dtype).itemsize for e in eqns))
+        carries |= any(e.primitive.name in _RULES for e in eqns)
+        made += math.prod(v.aval.shape) * as_made
+        stored += math.prod(v.aval.shape) * size
+    return made, stored, carries
+
+
 def trace_vjp(fn: Callable, args: Tuple) -> Tuple[Any, int, Any]:
     """(jaxpr of ``jax.vjp(fn, *args)``, how many of its outputs are the
     primal's, the primal output's shapes)."""
@@ -228,42 +313,84 @@ def count_block(fn: Callable, cfg, args: Tuple, shards: int = 1
     again, _, _ = trace_vjp(M.remat(fn, cfg), args)
     whole = residual_bytes(plain, n_out, n_params)
     kept = residual_bytes(again, n_out, n_params)
+    made, stored, carries = made_bytes(plain, n_params)
     return BlockCount(
         held_bytes=max(whole - kept, 0) // shards,
         forward_flops=matmul_flops(plain),
-        input_bytes=kept // shards, whole_bytes=whole // shards), out
+        input_bytes=kept // shards, whole_bytes=whole // shards,
+        grad_share=made / stored if stored else 1.0, carries=carries), out
+
+
+class BlockTerms(NamedTuple):
+    """One block in :func:`plan_peak`: what it holds until its backward
+    ran, one float32 gradient of its parameters, its backward's working
+    set, and of its count ``grad_share`` and ``carries``
+    (:class:`BlockCount`)."""
+
+    held: int
+    grad: int
+    working: int
+    grad_share: float = 1.0
+    carries: bool = False
 
 
 def plan_peak(args: int, accumulator: int, grads: int,
-              blocks: Sequence[Tuple[int, int, int]], outer: int) -> int:
+              blocks: Sequence[Tuple], outer: int,
+              rest: Tuple[float, bool] = (1.0, False)) -> int:
     """The step's static bytes a device, estimated for the flags the
     blocks were counted under. ``args``: parameters, optimizer state and
-    batch; ``accumulator``: the gradient's float32 accumulator of a step of
-    several microbatches (0 for one); ``grads``: one gradient of every
-    parameter; ``blocks``: in the order the forward runs them, ``(what the
-    block holds until its backward ran, its parameters' gradient, its
-    backward's working set)``; ``outer``: what the embedding, the head and
-    the loss hold. The most of: the loss's backward (every block holds,
-    the logits' cotangent is as large again as what the loss holds), each
-    block's backward (the blocks before it hold, it works, the gradients
-    of everything after it are made) and the update (every gradient).
+    batch; ``accumulator``: what a step of several microbatches holds to
+    accumulate its gradient (0 for one); ``grads``: one float32 gradient of
+    every parameter; ``blocks``: in the order the forward runs them, each a
+    :class:`BlockTerms`; ``outer``: what the embedding, the head and the
+    loss hold; ``rest``: ``grad_share`` and ``carries`` of the parameters
+    outside the blocks. The most of: the loss's backward (every block
+    holds, the logits' cotangent is as large again as what the loss holds),
+    each block's backward (the blocks before it hold; it works, on a
+    working set that counts its own held values already; the gradients of
+    everything after it are made) and the update (every gradient).
+
+    What each term is, from the buffer assignment of the plan's step in the
+    benchmark's cells (PERF.md section 6, PR 72). The gradient: a step that
+    runs no loop over its gradients holds a weight's gradient in the dtype
+    the backward makes it in (``grad_share``, :func:`made_bytes`: bfloat16
+    under mixed precision, half of what was counted until PR 72). A loop
+    that carries gradients, the scan over microbatches or the passes of the
+    expert rule's backward (``carries``), carries float32, and XLA's count
+    of such a step stands 0.3 to 2.4 GB over the bytes its schedule shows
+    live: there every gradient is counted in float32 (and ``accumulator``
+    at two gradients' worth, PR 62), which over-counts what is made outside
+    the loop by about what the loop costs. The working set is the block's
+    whole residual count: XLA fuses part of it away and holds cotangents
+    and wide intermediate results of its own in its place, from 0.66 of the
+    count (a Mamba-1 block) over 1.0 (Mamba-2 with its MLP) to 1.6 (a
+    Mamba-2 mixer alone at two sequences), which no trace of the forward
+    shows.
 
     An estimate, where XLA's own count of the plan's step would be exact:
     that count exists once the plan's step is compiled, a step that a job
     which keeps blocks never runs (one to two minutes of a first run on the
     chip), and a budget read off what the compile cache happens to hold
     would give one job two programs, its first run's and its later ones'.
-    The estimate reads from 12 % under to 9.5 % over XLA's count in the
-    benchmark's cells (PERF.md section 6, PR 62): over keeps fewer blocks,
-    which is the safe side, and under is caught, since XLA's count of the
-    CHOSEN step is read before that step runs (``KeptStep._checked``)."""
-    held = sum(b[0] for b in blocks)
-    made = grads - sum(b[1] for b in blocks)   # the head's and the rest's
-    peak = max(held + 2 * outer, grads)
-    for res, grad, working in reversed(blocks):
+    Against XLA's count of the plan's step the estimate reads 0.88 to 1.15
+    in the benchmark's cells, and 1.00 to 1.05 in the four whose step runs
+    no such loop (``tools/kept_report.py``; PERF.md section 6, PR 72): over
+    keeps fewer blocks, which is the safe side, and under is caught, since
+    XLA's count of the CHOSEN step is read before that step runs
+    (``KeptStep._checked``)."""
+    blocks = [BlockTerms(*b) for b in blocks]
+    loops = bool(accumulator) or rest[1] or any(b.carries for b in blocks)
+    as_held = [b.grad if loops else int(b.grad * b.grad_share)
+               for b in blocks]
+    made = grads - sum(b.grad for b in blocks)   # the head's and the rest's
+    if not loops:
+        made = int(made * rest[0])
+    held = sum(b.held for b in blocks)
+    peak = max(held + 2 * outer, made + sum(as_held))
+    for b, grad in zip(reversed(blocks), reversed(as_held)):
         made += grad
-        peak = max(peak, held + made + working)
-        held -= res
+        peak = max(peak, held - b.held + made + max(b.working, b.held))
+        held -= b.held
     return CODE + args + accumulator + peak
 
 

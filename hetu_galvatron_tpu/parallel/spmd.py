@@ -787,11 +787,15 @@ class KeptStep:
         counts = [probes[stack][i].counts for stack, i in order]
         outer = kept.residual_bytes(jaxpr, n_out, n_params) // max(
             self._shards["decoder"][0], 1)
+        made, stored, carries = kept.made_bytes(jaxpr, n_params)
         estimate = kept.plan_peak(args, accumulator, grads, [
-            (sum(c.input_bytes for c in cs),
-             f32(self._params_of[stack](params, i)),
-             max(c.whole_bytes for c in cs))
-            for (stack, i), cs in zip(order, counts)], outer)
+            kept.BlockTerms(
+                sum(c.input_bytes for c in cs),
+                f32(self._params_of[stack](params, i)),
+                max(c.whole_bytes for c in cs), cs[0].grad_share,
+                any(c.carries for c in cs))
+            for (stack, i), cs in zip(order, counts)], outer,
+            (made / stored if stored else 1.0, carries))
         budget = max(room - estimate, 0)
         blocks = [(sum(c.held_bytes for c in cs),
                    sum(c.forward_flops for c in cs)) for cs in counts]

@@ -86,10 +86,11 @@ def test_the_kept_bytes_never_pass_the_budget(budget):
 
 @pytest.mark.parametrize("case,peak", [
     # one microbatch, blocks that hold little: the first block's backward,
-    # every gradient made, its own values and its working set
+    # every gradient made and its working set, which counts what the block
+    # itself holds already
     (dict(args=1000, accumulator=0, grads=300,
           blocks=[(10, 100, 40), (10, 100, 40)], outer=20),
-     1000 + 300 + 10 + 40),
+     1000 + 300 + 40),
     # blocks that hold much: the loss's backward, before any gradient, the
     # logits' cotangent as large again as what the loss holds
     (dict(args=1000, accumulator=0, grads=300,
@@ -100,9 +101,40 @@ def test_the_kept_bytes_never_pass_the_budget(budget):
      1000 + 300),
     # several microbatches: the accumulator beside everything
     (dict(args=1000, accumulator=600, grads=300,
-          blocks=[(10, 100, 40)], outer=20), 1000 + 600 + 300 + 10 + 40),
+          blocks=[(10, 100, 40)], outer=20), 1000 + 600 + 300 + 40),
+    # a working set smaller than what the block holds anyway (a block of
+    # kernels' kept names): the held values stand for it
+    (dict(args=1000, accumulator=0, grads=300,
+          blocks=[(60, 100, 40), (60, 100, 40)], outer=20),
+     1000 + 300 + 60),
+    # one microbatch and no loop over gradients: each gradient as its
+    # backward makes it, the blocks' at a half, the rest's at three quarters
+    (dict(args=1000, accumulator=0, grads=300,
+          blocks=[(10, 100, 40, 0.5), (10, 100, 40, 0.5)], outer=20,
+          rest=(0.75, False)), 1000 + 75 + 50 + 50 + 40),
+    # the same counts where microbatches accumulate: float32 throughout
+    (dict(args=1000, accumulator=600, grads=300,
+          blocks=[(10, 100, 40, 0.5), (10, 100, 40, 0.5)], outer=20,
+          rest=(0.75, False)), 1000 + 600 + 300 + 40),
+    # a block whose rule carries its cotangents through a loop: float32
+    # for every gradient of the step, the other block's and the rest's too
+    (dict(args=1000, accumulator=0, grads=300,
+          blocks=[(10, 100, 40, 0.5), (10, 100, 40, 0.8, True)], outer=20,
+          rest=(0.75, False)), 1000 + 300 + 40),
+    # and where the rule stands outside the blocks (a further depth's layer)
+    (dict(args=1000, accumulator=0, grads=300,
+          blocks=[(10, 100, 40, 0.5), (10, 100, 40, 0.5)], outer=20,
+          rest=(0.75, True)), 1000 + 300 + 40),
+    # narrow gradients move the fullest moment to the last block's
+    # backward: the blocks before it hold more than their gradients take
+    (dict(args=1000, accumulator=0, grads=300,
+          blocks=[(80, 100, 90, 0.5), (80, 100, 90, 0.5)], outer=20,
+          rest=(0.5, False)), 1000 + 80 + 50 + 50 + 90),
 ], ids=["a_blocks_backward", "the_losss_backward", "the_update",
-        "the_accumulator"])
+        "the_accumulator", "held_stands_for_the_working_set",
+        "one_microbatch_narrow", "accumulating_float32",
+        "an_expert_block_float32", "a_rule_outside_the_blocks",
+        "the_last_blocks_backward"])
 def test_the_plans_peak_is_the_fullest_moment(case, peak):
     assert kept.plan_peak(**case) == kept.CODE + peak
 
@@ -158,6 +190,65 @@ def test_the_byte_count_is_the_hand_count(name, whole, again):
         kept.BlockCount(held_bytes=(whole - again) // 4,
                         forward_flops=count.forward_flops,
                         input_bytes=again // 4, whole_bytes=whole // 4))
+
+
+def _dense_block(dtype):
+    """Two projections whose weights are read through ``weight_view`` and
+    a scale the block multiplies by as stored."""
+    def block(p, x):
+        h = jnp.tanh(x.astype(dtype) @ M.weight_view(p["w1"], dtype))
+        return (h @ M.weight_view(p["w2"], dtype)) * p["scale"]
+
+    p = {"scale": jnp.zeros((ROWS,)), "w1": jnp.zeros((ROWS, WIDE)),
+         "w2": jnp.zeros((WIDE, ROWS))}
+    return block, (p, jnp.zeros((ROWS, ROWS)))
+
+
+def _expert_block(dtype):
+    """A projection, then a router and experts behind the sorted
+    dispatcher, whose rule reads the expert weights as stored
+    (models/moe.py)."""
+    from hetu_galvatron_tpu.models import moe
+
+    cfg = TINY_GPT.model_copy(update=dict(
+        num_experts=4, moe_topk=2, moe_ffn_hidden_size=32,
+        moe_dispatcher="dropless", hidden_act="swiglu"))
+    p = {"moe": moe.init_moe_mlp(jax.random.key(0), cfg)[0],
+         "w": jnp.zeros((cfg.hidden_size, cfg.hidden_size))}
+
+    def block(p, x):
+        h = x.astype(dtype) @ M.weight_view(p["w"], dtype)
+        return moe.apply_moe_mlp(p["moe"], h, cfg, compute_dtype=dtype)[0]
+
+    return block, (p, jnp.zeros((2, 8, cfg.hidden_size)))
+
+
+W = 4 * ROWS * WIDE            # a projection's weight, float32 [64, 256]
+
+
+@pytest.mark.parametrize("make,dtype,made,stored,carries", [
+    # both projections' gradients are made in bfloat16, the scale's as stored
+    (_dense_block, jnp.bfloat16, W + 4 * ROWS, 2 * W + 4 * ROWS, False),
+    # full precision: a cast to the stored dtype narrows nothing
+    (_dense_block, jnp.float32, 2 * W + 4 * ROWS, 2 * W + 4 * ROWS, False),
+    # the projection's gradient is made in bfloat16; the router's product
+    # runs in float32 on the weight as stored, and the experts' weights are
+    # the rule's operands
+    (_expert_block, jnp.bfloat16, None, None, True),
+], ids=["dense_bf16", "dense_f32", "experts_bf16"])
+def test_a_gradient_is_counted_in_the_dtype_its_backward_makes_it(
+        make, dtype, made, stored, carries):
+    block, args = make(dtype)
+    n_params = len(jax.tree.leaves(args[0]))
+    jaxpr, _, _ = kept.trace_vjp(jax.jit(block), args)
+    got = kept.made_bytes(jaxpr, n_params)
+    if made is None:
+        stored = 4 * sum(a.size for a in jax.tree.leaves(args[0]))
+        made = stored - 2 * args[0]["w"].size
+    assert got == (made, stored, carries)
+    count, _ = kept.count_block(block, TINY_GPT, args)
+    assert count.grad_share == made / stored
+    assert count.carries == carries
 
 
 def test_a_mamba1_blocks_count_reads_what_its_scans_kernels_keep():

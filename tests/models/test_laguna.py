@@ -493,6 +493,25 @@ def test_the_step_names_the_new_parts():
     assert {"attn/window_core", "attn/gate"} <= set(trace_analysis.SCOPES)
 
 
+def test_the_rotations_tables_are_traced_in_one_order(monkeypatch):
+    """The order a set of kinds iterates in is the process's hash seed's;
+    the tables' order is the order of the program's constants, which the
+    persistent compile cache keys on (the cell's second run compiled again
+    every other time: chip runs, PR 72)."""
+    from hetu_galvatron_tpu.models import builder
+
+    seen = []
+    table = builder.rope_table
+    monkeypatch.setattr(builder, "rope_table", lambda cfg, S, m, *ids: (
+        seen.append(m), table(cfg, S, m, *ids))[1])
+    cfg = ModelArgs(**TINY)
+    params = jax.eval_shape(
+        lambda k: init_causal_lm(k, cfg)[0], jax.random.key(0))
+    jax.eval_shape(lambda p: forward_causal_lm(
+        p, _batch()["tokens"], cfg, compute_dtype=jnp.float32), params)
+    assert [m for m in seen if m] == sorted(ROPE)
+
+
 def test_the_flop_counts_take_a_band_and_a_blocks_own_heads():
     from hetu_galvatron_tpu.core.cost_model.cost import model_flops_per_token
 

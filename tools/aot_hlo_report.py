@@ -200,13 +200,14 @@ def print_report(rep, file=None):
                   f"{row['replica_groups']}  <- {row['op_name']}", file=file)
 
 
-def compile_cell_hlo(name: str) -> str:
-    """The optimized HLO of the cell's step, compiled as
-    ``benchmark/aot_check.py`` compiles it."""
+def cell_step(name: str, keep_blocks: bool = True):
+    """(the cell's train step, the shapes of its three arguments), built as
+    ``benchmark/aot_check.py`` builds it: the cell's own command line,
+    ``make_spmd_train_step`` on a described ``v5e:2x2``, the fields of the
+    program's own first batch. Nothing is compiled yet."""
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
     import jax
-    import jax.numpy as jnp
     from jax.experimental import topologies
     from jax.sharding import NamedSharding, PartitionSpec
 
@@ -215,6 +216,7 @@ def compile_cell_hlo(name: str) -> str:
     from hetu_galvatron_tpu.models.builder import init_causal_lm
     from hetu_galvatron_tpu.models.modules import compute_dtype_of
     from hetu_galvatron_tpu.parallel.spmd import make_spmd_train_step
+    from hetu_galvatron_tpu.runtime.dataloader import get_data_iterator
     from hetu_galvatron_tpu.runtime.hybrid_config import (
         get_hybrid_parallel_config,
     )
@@ -241,7 +243,8 @@ def compile_cell_hlo(name: str) -> str:
     mesh = build_mesh(world, 1, devices=list(topo.devices)[:world])
     step, pspecs, ospecs, batch_shd = make_spmd_train_step(
         cfg, hpc, mesh, box["axes"], tx, params,
-        compute_dtype=compute_dtype_of(args.parallel.mixed_precision))
+        compute_dtype=compute_dtype_of(args.parallel.mixed_precision),
+        keep_blocks=keep_blocks)
 
     def shaped(specs, tree):
         return jax.tree.map(
@@ -249,13 +252,17 @@ def compile_cell_hlo(name: str) -> str:
                 a.shape, a.dtype, sharding=NamedSharding(mesh, s)),
             specs, tree, is_leaf=lambda x: isinstance(x, PartitionSpec))
 
-    batch = {k: jax.ShapeDtypeStruct((hpc.global_bsz, cfg.seq_length), dt,
-                                     sharding=batch_shd)
-             for k, dt in (("tokens", jnp.int32), ("labels", jnp.int32),
-                           ("loss_mask", jnp.float32))}
-    return step.lower(shaped(pspecs, params),
-                      shaped(ospecs, jax.eval_shape(tx.init, params)),
-                      batch).compile().as_text()
+    batch = {k: jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=batch_shd)
+             for k, v in next(get_data_iterator(
+                 args, global_batch_size=hpc.global_bsz, hpc=hpc)).items()}
+    return step, (shaped(pspecs, params),
+                  shaped(ospecs, jax.eval_shape(tx.init, params)), batch)
+
+
+def compile_cell_hlo(name: str) -> str:
+    """The optimized HLO of the cell's step."""
+    step, shapes = cell_step(name)
+    return step.lower(*shapes).compile().as_text()
 
 
 def main() -> int:
