@@ -1268,6 +1268,13 @@ def train(args) -> Dict[str, Any]:
                         scope_instructions=found["scopes"])
                     get_registry().gauge("step/relayout_bytes").set(
                         found["relayouts"]["bytes"])
+                    # the step's data, followed: the prefetches XLA made
+                    # (copy-start / slice-start pairs), their bytes, and the
+                    # instructions under no scope that no scoped one uses
+                    # or feeds
+                    step_report["flow"] = found["flow"]
+                    for what, n in found["flow"].items():
+                        get_registry().gauge(f"step/{what}").set(n)
                     # attention cores and recurrent scans run again under
                     # per-layer remat: the flash / scan forward calls the map
                     # puts in the recompute phase
@@ -1405,6 +1412,10 @@ def train(args) -> Dict[str, Any]:
                     + (" (the largest {opcode} {shape} <- {op_name})".format(
                         **step_report["relayouts"]["largest"])
                        if step_report["relayouts"]["largest"] else "")
+                    + ", step/prefetches {prefetches} of "
+                      "step/prefetch_bytes {prefetch_bytes}, "
+                      "step/unowned_instructions "
+                      "{unowned_instructions}".format(**step_report["flow"])
                     + f", {step_report['mosaic_custom_calls']} Mosaic calls"
                     + (f" ({step_report['ssd_mosaic_calls']} under "
                        f"{SSD_SCOPE}), ssd/groups {cfg.mamba_n_groups}"
@@ -1547,6 +1558,12 @@ def train(args) -> Dict[str, Any]:
             # step/relayout_bytes), their count and the largest one's
             # opcode, shape and op_name tail; None for the pp engines
             "relayouts": step_report.get("relayouts"),
+            # the asynchronous copies and slices that step's HLO holds
+            # (XLA's prefetches), their destinations' bytes, and the
+            # instructions under no scope that the map found no owner for
+            # (the gauges step/prefetches, step/prefetch_bytes and
+            # step/unowned_instructions); None for the pp engines
+            "flow": step_report.get("flow"),
             # instruction names of that step's HLO under each named scope a
             # state-space block has (what the granite_* readers join a
             # trace's events to; empty lists for a model without one), and

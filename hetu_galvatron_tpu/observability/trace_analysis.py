@@ -553,6 +553,25 @@ _PASS_ORDER = ("forward", "recompute", "backward")
 # their operand that computes nothing: XLA has made every reshape and
 # transpose that is one a ``bitcast`` by then
 RELAYOUT_OPS = ("reshape", "copy", "transpose")
+# the asynchronous pairs that move an array between memory spaces on one
+# chip (XLA:TPU prints a prefetch into ``S(1)`` as one of these two, or, an
+# attached chip's text for the slices, as an ``async-start`` / ``async-done``
+# pair that calls a computation holding the ``slice``: found on the chip,
+# PR 73); every other ``-start`` is half of a collective
+PREFETCH_STARTS = ("copy-start", "slice-start")
+_TARGET = re.compile(r'custom_call_target="([^"]*)"')
+_INDEX = re.compile(r"index=(\d+)")
+_SPACE = re.compile(r"S\(\d+\)")
+# how far the step's data is followed from an instruction that has no scope
+# to one that has (a copy, its ``-start`` and ``-done``, a bitcast and a
+# ``ConcatBitcast`` are four)
+OWNER_HOPS = 8
+# what packs values so that a user further on takes one of many: the walk
+# to an owner does not pass through them
+_PACKING = ("tuple", "while", "conditional", "call")
+# what hands a value on unchanged: a transfer's producer and a custom
+# call's operands are looked for behind these
+_VIEWS = ("bitcast", "get-tuple-element")
 
 
 def walk_hlo(hlo_text: str):
@@ -666,11 +685,51 @@ def step_hlo(hlo_text: str, own_scopes: Sequence[str] = OWN_SCOPES
     around the scatter-add a gathered label's logit transposes to.)
     ``scopes``, ``instructions``, ``mosaic_calls``: see
     :func:`scope_instructions` (``own_scopes`` are its ``scopes``).
+    ``flow``: ``{"prefetches", "prefetch_bytes", "unowned_instructions"}``,
+    the counts of the map's ``transfers`` of kind ``prefetch``, their bytes
+    and the instructions under no scope that are not in its ``owners`` (the
+    gauges ``step/prefetches``, ``step/prefetch_bytes``,
+    ``step/unowned_instructions``).
 
     ``map``: each instruction of a computation that is no fusion's and no
     reduction's (an event of a TPU trace is one of these, named by it),
     ``{"instructions": {name: (scope, phase, collective)}, "inferred":
-    [names], "tails": {name: op_name tail}}``:
+    [names], "tails": {name: op_name tail}, "transfers", "calls", "owners",
+    "relayouts"}``. The last four follow the step's data: the walk keeps
+    each such instruction's operands and, from them, its users, in the order
+    the program runs them; what goes into a ``while``'s tuple at a place,
+    and what its body's root holds there, is used by the body's
+    ``get-tuple-element`` of that place (a prefetch started in one trip of a
+    loop over the layers feeds the next).
+
+    * ``transfers``: one entry for each asynchronous pair, keyed by the
+      starting instruction's name (what a trace's ``Async XLA Ops`` line
+      calls it): ``{"done": its other half's name, "kind": ``prefetch`` for
+      a ``copy-start`` / ``slice-start`` (how XLA:TPU prints a move between
+      memory spaces on one chip), else the collective (``all-gather``),
+      "bytes": of the destination (the ``-done``'s result), "space": the
+      destination layout's memory space (``S(1)``) or None, "from": (name,
+      scope, phase) of what made the moved array, "feeds": (name, scope,
+      phase) of the first instruction with a scope that uses the result,
+      reached through instructions that have none (a ``ConcatBitcast``, a
+      bitcast, a further copy), or None}``.
+    * ``calls``: for each ``custom-call``, ``{"target": its
+      ``custom_call_target``, "transfers": the transfers whose results are
+      among its operands}``: a ``ConcatBitcast`` of four prefetched slices
+      names the four ``slice-start``s.
+    * ``owners``: for each instruction whose scope is None, ``(scope,
+      phase, "user" | "operand", hops)``: the nearest instruction with a
+      scope forward through its users (of several as near, the earliest in
+      program order), else backward through its operands (the latest),
+      ``OWNER_HOPS`` at most and never through a ``tuple`` or a loop other
+      than by the place; absent where there is none. A copy XLA made on the
+      way into a matmul is that matmul's.
+    * ``relayouts``: the names of the ``RELAYOUT_OPS`` instructions counted
+      above.
+
+    The triples, ``inferred`` and ``tails`` are what they were before the
+    step's data was followed (PR 73); the phase rule below asks the same
+    operands.
 
     * ``scope`` and ``phase`` by :func:`scope_and_phase` from the
       instruction's OWN ``op_name`` (a fusion carries its root's; every
@@ -758,10 +817,9 @@ def _step_hlo(hlo_text: str, own_scopes: Sequence[str]) -> Dict[str, Any]:
         if op_name:
             key = classify[op_name]
             c["inside"][key] = c["inside"].get(key, 0) + 1
-        # (operands are asked only where the op_name is no name stack)
-        c["rows"].append((name, opcode, op_name, calls,
-                          () if "/" in op_name else _OPERAND.findall(
-                              line, at, line.find(")", at))))
+        # (operands are read below, and only in the computations whose
+        # instructions are a trace's events)
+        c["rows"].append((name, opcode, op_name, calls, line, at))
         if is_mosaic:
             mosaic.add(name)
         if "fused_computation" not in comp:
@@ -807,11 +865,15 @@ def _step_hlo(hlo_text: str, own_scopes: Sequence[str]) -> Dict[str, Any]:
     inferred: List[str] = []
     tails: Dict[str, str] = {}
     relayouts: List[Tuple[int, str, str, str]] = []
+    # the step's data, followed: each event's operands and, from them, its
+    # users, in the order the program runs them
+    flow = _Flow()
     for comp, c in comps.items():
         if comp in fused or comp in applied:
             continue
         relayouts += c["relayouts"]
-        for name, opcode, op_name, calls, operands in c["rows"]:
+        for name, opcode, op_name, calls, line, at in c["rows"]:
+            operands = flow.add(comp, name, opcode, line, at)
             scope, phase = classify[op_name]
             if not op_name and calls in comps:
                 inside = comps[calls]["inside"]
@@ -836,7 +898,14 @@ def _step_hlo(hlo_text: str, own_scopes: Sequence[str]) -> Dict[str, Any]:
             if scope is None:
                 tails[name] = _op_tail(op_name) if op_name else opcode
     largest = max(relayouts, default=None)
+    followed = flow.read(instructions, lambda comp: comps[comp]["held"])
+    prefetches = [t["bytes"] for t in followed["transfers"].values()
+                  if t["kind"] == "prefetch"]
     return {"mosaic_custom_calls": len(mosaic), "collectives": counts,
+            "flow": {"prefetches": len(prefetches),
+                     "prefetch_bytes": sum(prefetches),
+                     "unowned_instructions": len(tails) - len(
+                         followed["owners"])},
             "relayouts": {
                 "bytes": sum(r[0] for r in relayouts),
                 "count": len(relayouts),
@@ -845,7 +914,198 @@ def _step_hlo(hlo_text: str, own_scopes: Sequence[str]) -> Dict[str, Any]:
             "scopes": found, "instructions": frozenset(names),
             "mosaic_calls": frozenset(n for n in mosaic if n in names),
             "map": {"instructions": instructions, "inferred": inferred,
-                    "tails": tails}}
+                    "tails": tails, **followed}}
+
+
+class _Flow:
+    """The def-use structure of the step's events (:func:`step_hlo`'s
+    ``transfers``, ``calls``, ``owners`` and ``relayouts``): each
+    instruction's operands and users, in program order."""
+
+    def __init__(self):
+        self.order: Dict[str, int] = {}
+        self.opcode: Dict[str, str] = {}
+        self.operands: Dict[str, List[str]] = {}
+        self.users: Dict[str, List[str]] = {}
+        # (read again for the halves of an asynchronous pair, the moved
+        # array's type, and for the custom calls, their target)
+        self.lines: Dict[str, str] = {}
+        self.relayouts: List[str] = []
+        # {(a tuple's instruction, a place in it): what takes that place}
+        self.elements: Dict[Tuple[str, int], List[str]] = {}
+        self.loops: List[Tuple[str, str]] = []
+        self.carried = False
+        # a computation's parameter and its root (its last instruction)
+        self.params: Dict[str, str] = {}
+        self.roots: Dict[str, str] = {}
+        # the computation an instruction is in, and for one that a generic
+        # ``async-start`` calls, that start
+        self.comp: Dict[str, str] = {}
+        self.wrapped: Dict[str, str] = {}
+
+    def add(self, comp: str, name: str, opcode: str, line: str,
+            at: int) -> List[str]:
+        operands = _OPERAND.findall(line, at, line.find(")", at))
+        self.roots[comp] = name
+        self.comp[name] = comp
+        self.order[name] = len(self.order)
+        self.opcode[name] = opcode
+        self.operands[name] = operands
+        for o in operands:
+            self.users.setdefault(o, []).append(name)
+        self.lines[name] = line
+        if opcode in RELAYOUT_OPS:
+            self.relayouts.append(name)
+        elif opcode == "get-tuple-element":
+            index = int(_INDEX.search(line, at).group(1))
+            self.elements.setdefault((operands[0], index), []).append(name)
+        elif opcode == "while":
+            self.loops.append((name, _BODY.search(line, at).group(1)))
+        elif opcode == "parameter":
+            self.params.setdefault(comp, name)
+        elif opcode == "async-start":
+            called = _CALLS.search(line, at)
+            if called:
+                self.wrapped[called.group(1)] = name
+        return operands
+
+    def _carry(self, name: str, body: str) -> None:
+        """The edges a loop hides: what goes into ``name``'s tuple at a
+        place, and what the ``body``'s root tuple holds there, is used by
+        the body's ``get-tuple-element`` of that place (the next trip's) and
+        by the loop's own after it."""
+        entering = self._behind(self.operands[name][0])
+        param, root = self.params.get(body), self.roots.get(body)
+        if self.opcode.get(entering) != "tuple" or param is None \
+                or self.opcode.get(root) != "tuple":
+            return
+        for at, (first, again) in enumerate(zip(
+                self.operands[entering], self.operands[root])):
+            inside = self.elements.get((param, at), [])
+            for made, taken in ((first, inside),
+                                (again, inside + self.elements.get(
+                                    (name, at), []))):
+                if taken:
+                    self.users.setdefault(made, []).extend(taken)
+                for n in taken:
+                    self.operands[n].append(made)
+            self.carried = self.carried or bool(inside)
+
+    def _nearest(self, instructions, edges, names, latest=False):
+        """{name: (hops, place, scoped instruction)}: along ``edges`` (users
+        or operands) the nearest instruction that has a scope, through
+        instructions that have none and pack nothing, ``OWNER_HOPS`` at
+        most; of several as near the earliest in program order (the
+        ``latest`` of operands). ``names`` run so that an instruction comes
+        after what its edges lead to."""
+        sign, found = (-1 if latest else 1), {}
+        # (an edge a loop carries leads against the order: a second and a
+        # third sweep take what the one before found across it)
+        for _ in range(3 if self.carried else 1):
+            moved = False
+            for name in names:
+                best = before = found.get(name)
+                for n in edges.get(name, ()):
+                    if n not in instructions:
+                        continue
+                    if instructions[n][0] is not None:
+                        reached = (1, sign * self.order[n], n)
+                    else:
+                        via = found.get(n)
+                        if via is None or via[0] >= OWNER_HOPS \
+                                or self.opcode[n] in _PACKING:
+                            continue
+                        reached = (via[0] + 1, via[1], via[2])
+                    if best is None or reached < best:
+                        best = reached
+                if best is not before:
+                    found[name], moved = best, True
+            if not moved:
+                break
+        return found
+
+    def _behind(self, name: str) -> str:
+        """``name``, or what it is a view of."""
+        while self.opcode.get(name) in _VIEWS and self.operands[name]:
+            name = self.operands[name][0]
+        return name
+
+    def _done_of(self, start: str, instructions) -> Optional[str]:
+        """The ``-done`` half of ``start``: its user, or (XLA:TPU's three
+        fusions) what follows the overlapped fusion that rides on it."""
+        front = [start]
+        for _ in range(6):
+            ahead = []
+            for n in front:
+                for u in self.users.get(n, ()):
+                    cls = instructions[u][2] or ""
+                    if self.opcode[u].endswith("-done") \
+                            or cls.endswith(".done"):
+                        return u
+                    if self.opcode[u] in _VIEWS or cls == "overlapped":
+                        ahead.append(u)
+            front = ahead
+        return None
+
+    def read(self, instructions, held) -> Dict[str, Any]:
+        """``held(computation)``: the collectives it holds, by opcode."""
+        for loop, body in self.loops:
+            self._carry(loop, body)
+        program = list(self.order)
+        ahead = self._nearest(instructions, self.users, program[::-1])
+        back = self._nearest(instructions, self.operands, program,
+                             latest=True)
+        place = lambda n: (n,) + instructions[n][:2]
+        owners = {}
+        for name, (scope, _, _) in instructions.items():
+            if scope is not None:
+                continue
+            for via, found in (("user", ahead), ("operand", back)):
+                if name in found:
+                    hops, _, owner = found[name]
+                    owners[name] = instructions[owner][:2] + (via, hops)
+                    break
+        starts = {start: comp for comp, start in self.wrapped.items()}
+        for name, comp in self.comp.items():
+            # (what an async-start wraps is the start's)
+            if comp in self.wrapped and self.wrapped[comp] in owners \
+                    and instructions[name][0] is None:
+                owners[name] = owners[self.wrapped[comp]]
+        transfers, made = {}, {}
+        for name in program:
+            opcode, cls = self.opcode[name], instructions[name][2] or ""
+            if not (opcode.endswith("-start") or cls.endswith(".start")):
+                continue
+            done = self._done_of(name, instructions)
+            moved = result_type(self.lines[done], self.opcode[done]) \
+                if done else ""
+            space = _SPACE.search(moved)
+            feeds = ahead.get(done or name)
+            source = self._behind(self.operands[name][0]) \
+                if self.operands[name] else None
+            moves = next(iter(held(starts[name])), None) \
+                if name in starts else cls.rsplit(".", 1)[0] or opcode[:-6]
+            transfers[name] = {
+                "done": done,
+                "kind": ("prefetch" if opcode in PREFETCH_STARTS
+                         or moves is None else moves),
+                "bytes": result_bytes(moved),
+                "space": space and space.group(0),
+                "from": place(source) if source in instructions else None,
+                "feeds": place(feeds[2]) if feeds else None}
+            if done:
+                made[done] = name
+        calls = {}
+        for name, line in self.lines.items():
+            if self.opcode[name] != "custom-call":
+                continue
+            target = _TARGET.search(line)
+            behind = (self._behind(o) for o in self.operands[name])
+            calls[name] = {
+                "target": target.group(1) if target else "",
+                "transfers": [made[o] for o in behind if o in made]}
+        return {"transfers": transfers, "calls": calls, "owners": owners,
+                "relayouts": self.relayouts}
 
 
 def hlo_counts(hlo_text: str) -> Dict[str, Any]:

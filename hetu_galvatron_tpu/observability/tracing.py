@@ -48,11 +48,19 @@ The spans of the launcher (``cli/train_dist.py``), all flat siblings:
   whose plan bit is set, how many hold their forward's values and how many
   make them again; the bytes the step program counted for the kept, of the
   budget the devices left; 1 where the chosen step did not fit and the
-  plan's flags ran: ``parallel/spmd.py::KeptStep``), and keeps every
-  instruction's scope, phase and
-  collective class for a reader of a trace:
-  ``trace_analysis.step_hlo``; :class:`TraceCapture` writes them beside the
-  trace as ``step_map.json`` when its window closes).
+  plan's flags ran: ``parallel/spmd.py::KeptStep``) and
+  ``step/relayout_bytes``, ``step/prefetches``, ``step/prefetch_bytes`` and
+  ``step/unowned_instructions`` (the passes over an array left outside
+  fusions; the asynchronous copies and slices XLA made, and their bytes;
+  the instructions under no scope that no scoped one uses or feeds), and
+  keeps every instruction's scope, phase and collective class for a reader
+  of a trace, with every asynchronous transfer (``transfers``: kind, bytes,
+  memory space, what made it and what it feeds), every custom call's target
+  and the transfers behind its operands (``calls``), the owner of every
+  instruction under no scope (``owners``) and the names of the layout
+  passes (``relayouts``): ``trace_analysis.step_hlo``;
+  :class:`TraceCapture` writes them beside the trace as ``step_map.json``
+  when its window closes).
 """
 
 from __future__ import annotations

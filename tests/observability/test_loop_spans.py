@@ -263,6 +263,30 @@ def test_relayout_gauge_is_a_number_read_off_the_steps_hlo(
         assert moved["largest"]["shape"] in out["compiled_hlo"]
 
 
+@pytest.mark.parametrize("run", ["one_device", "tp2"])
+def test_the_flow_gauges_are_counts_of_the_recorded_map(request, traced, run):
+    """``step/prefetches``, ``step/prefetch_bytes``,
+    ``step/unowned_instructions``: set beside ``step/relayout_bytes`` from
+    the same walk, in ``train()``'s ``flow``, and counts of the map a reader
+    finds in the process and ``step_map.json`` carries."""
+    from hetu_galvatron_tpu.observability.trace_analysis import step_hlo
+
+    reg, out = ((traced["registry"], traced["result"]) if run == "one_device"
+                else request.getfixturevalue("tp2_run"))
+    found = step_hlo(out["compiled_hlo"])
+    assert out["flow"] == found["flow"]
+    assert {m.name[len("step/"):]: m.value for m in reg.metrics()
+            if m.name in ("step/prefetches", "step/prefetch_bytes",
+                          "step/unowned_instructions")} == out["flow"]
+    kept = found["map"]
+    assert out["flow"]["unowned_instructions"] == sum(
+        n not in kept["owners"] for n in kept["tails"])
+    assert set(kept["relayouts"]) <= set(kept["instructions"])
+    assert len(kept["relayouts"]) == out["relayouts"]["count"]
+    assert all(t["done"] in kept["instructions"]
+               for t in kept["transfers"].values())
+
+
 # (d) spans change nothing on the device -------------------------------------
 
 def test_lowered_step_is_the_same_with_and_without_a_trace_window(traced):

@@ -599,7 +599,7 @@ def test_the_tool_lays_a_trace_over_the_map(capsys):
     assert t["not_in_the_map"] == [["stranger.1", pytest.approx(0.1)]]
     trace_by_scope.print_tables(t)
     out = capsys.readouterr().out
-    assert "0.500 ms x 2  after async-collective-done.1" in out
+    assert "0.500 ms x 2  collective after async-collective-done.1" in out
     assert "NO instruction of the map" in out
 
 
@@ -643,3 +643,283 @@ def test_relayouts_are_the_passes_left_outside_fusions():
         "shape": "bf16[4096,4,512]{2,1,0}", "op_name": "(none)"}
     assert trace_analysis.result_bytes("f32[1,4096,50304]{2,1,0}") == \
         824180736
+
+
+# ---------------------------------------------------------------------------
+# the step's data, followed (PR 73): transfers, calls, owners, relayouts
+# ---------------------------------------------------------------------------
+
+FIXTURES = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                        "fixtures")
+_FUSION = ('  %{name} = f32[8]{{0}} fusion({operands}), kind=kLoop, '
+           'calls=%fused_computation.1, metadata={{op_name="jit(step)/'
+           '{stack}/dot_general"}}\n')
+
+
+def _fusion(name, operands, stack):
+    return _FUSION.format(name=name, operands=operands, stack=stack)
+
+
+# a prefetch by copy into S(1), one in two slices under a ConcatBitcast, an
+# unnamed copy between two scoped fusions, copies whose users differ, one
+# nobody uses, one that goes nowhere, and a loop over the layers whose
+# weight is prefetched before it and, in each trip, for the next
+FLOW = (
+    "HloModule jit_step\n\n"
+    "%fused_computation.1 (p: f32[8]) -> f32[8] {\n"
+    "  %p = f32[8]{0} parameter(0)\n"
+    "  ROOT %neg.1 = f32[8]{0} negate(%p)\n}\n\n"
+    "%body.1 (t: (f32[8], f32[8])) -> (f32[8], f32[8]) {\n"
+    "  %t = (f32[8]{0}, f32[8]{0:S(1)}) parameter(0)\n"
+    "  %gte.w = f32[8]{0:S(1)} get-tuple-element(%t), index=1\n"
+    "  %gte.x = f32[8]{0} get-tuple-element(%t), index=0\n"
+    + _fusion("fusion.20", "%gte.x, %gte.w", "while/body/jvp(mlp)") +
+    "  %copy-start.9 = (f32[8]{0:S(1)}, f32[8]{0}, u32[]{:S(2)}) "
+    "copy-start(%gte.x)\n"
+    "  %copy-done.9 = f32[8]{0:S(1)} copy-done(%copy-start.9)\n"
+    "  ROOT %tuple.9 = (f32[8]{0}, f32[8]{0:S(1)}) tuple(%fusion.20, "
+    "%copy-done.9)\n}\n\n"
+    "ENTRY %main (a: f32[16,8], b: f32[8]) -> f32[8] {\n"
+    "  %a = f32[16,8]{1,0} parameter(0)\n"
+    "  %b = f32[8]{0} parameter(1)\n"
+    "  %copy-start.1 = (f32[8]{0:T(8)S(1)}, f32[8]{0:T(8)}, u32[]{:S(2)}) "
+    "copy-start(%b)\n"
+    "  %slice-start.2 = ((f32[16,8]{1,0}), f32[8,8]{1,0:T(8,128)S(1)}, "
+    "s32[]{:S(2)}) slice-start(%a), slice={[0:8], [0:8]}\n"
+    "  %slice-start.3 = ((f32[16,8]{1,0}), f32[8,8]{1,0:T(8,128)S(1)}, "
+    "s32[]{:S(2)}) slice-start(%a), slice={[8:16], [0:8]}\n"
+    + _fusion("fusion.1", "%b", "jvp(attn/qkv_proj)") +
+    "  %copy.4 = f32[8]{0} copy(%fusion.1)\n"
+    + _fusion("fusion.2", "%copy.4", "jvp(attn/out_proj)") +
+    "  %copy.5 = f32[8]{0} copy(%fusion.2)\n"
+    + _fusion("fusion.3", "%copy.5", "jvp(norm)")
+    + _fusion("fusion.4", "%copy.5", "jvp(mlp)") +
+    "  %copy.6 = f32[8]{0} copy(%fusion.2)\n"
+    "  %bitcast.6 = f32[8]{0} bitcast(%copy.6)\n"
+    + _fusion("fusion.5", "%bitcast.6", "jvp(head)")
+    + _fusion("fusion.6", "%copy.6", "jvp(mlp)") +
+    "  %slice-done.2 = f32[8,8]{1,0:T(8,128)S(1)} slice-done("
+    "%slice-start.2)\n"
+    "  %slice-done.3 = f32[8,8]{1,0:T(8,128)S(1)} slice-done("
+    "%slice-start.3)\n"
+    "  %copy-done.1 = f32[8]{0:T(8)S(1)} copy-done(%copy-start.1)\n"
+    "  %custom-call.7 = f32[16,8]{1,0:T(8,128)S(1)} custom-call("
+    "%slice-done.2, %slice-done.3), custom_call_target=\"ConcatBitcast\"\n"
+    + _fusion("fusion.7", "%custom-call.7, %copy-done.1",
+              "transpose(jvp(mlp))") +
+    "  %copy.8 = f32[16,8]{0,1} copy(%a)\n"
+    "  %copy.12 = f32[8]{0} copy(%fusion.7)\n"
+    "  %copy-start.11 = (f32[8]{0:S(1)}, f32[8]{0}, u32[]{:S(2)}) "
+    "copy-start(%fusion.6)\n"
+    "  %copy-done.11 = f32[8]{0:S(1)} copy-done(%copy-start.11)\n"
+    "  %tuple.10 = (f32[8]{0}, f32[8]{0:S(1)}) tuple(%fusion.7, "
+    "%copy-done.11)\n"
+    "  %while.1 = (f32[8]{0}, f32[8]{0:S(1)}) while(%tuple.10), "
+    "condition=%cond.1, body=%body.1\n"
+    "  %gte.9 = f32[8]{0} get-tuple-element(%while.1), index=0\n"
+    "  %out = (f32[8]{0}, f32[16,8]{0,1}) tuple(%gte.9, %copy.8)\n"
+    + _fusion("fusion.8", "%gte.9", "optimizer/update").replace(
+        "  %fusion.8", "  ROOT %fusion.8") + "}\n")
+
+
+@pytest.mark.parametrize("start,expected", [
+    # a copy into S(1): the bytes and the space of the destination, the
+    # parameter it reads, the fusion that reads the result
+    ("copy-start.1", {
+        "done": "copy-done.1", "kind": "prefetch", "bytes": 32,
+        "space": "S(1)", "from": ("b", None, "other"),
+        "feeds": ("fusion.7", "mlp", "backward")}),
+    # a slice: the destination is the tuple's second part; what it feeds is
+    # behind the ConcatBitcast
+    ("slice-start.2", {
+        "done": "slice-done.2", "kind": "prefetch", "bytes": 256,
+        "space": "S(1)", "from": ("a", None, "other"),
+        "feeds": ("fusion.7", "mlp", "backward")}),
+    # before a loop, into its tuple: the body's fusion reads that place
+    ("copy-start.11", {
+        "done": "copy-done.11", "kind": "prefetch", "bytes": 32,
+        "space": "S(1)", "from": ("fusion.6", "mlp", "forward"),
+        "feeds": ("fusion.20", "mlp", "forward")}),
+    # in a trip, for the next one: through the body's root and parameter
+    ("copy-start.9", {
+        "done": "copy-done.9", "kind": "prefetch", "bytes": 32,
+        "space": "S(1)", "from": ("t", None, "other"),
+        "feeds": ("fusion.20", "mlp", "forward")}),
+])
+def test_a_transfer_says_what_it_moves_and_what_it_feeds(start, expected):
+    found = step_hlo(FLOW)
+    assert found["map"]["transfers"][start] == expected
+    assert list(found["map"]["transfers"]) == [
+        "copy-start.9", "copy-start.1", "slice-start.2", "slice-start.3",
+        "copy-start.11"]
+    assert found["flow"] == {
+        "prefetches": 5, "prefetch_bytes": 3 * 32 + 2 * 256,
+        "unowned_instructions": len(found["map"]["tails"]) - len(
+            found["map"]["owners"])}
+
+
+@pytest.mark.parametrize("name,expected", [
+    # between two scoped fusions: the one that uses it, not the one it reads
+    ("copy.4", ("attn/out_proj", "forward", "user", 1)),
+    # two users as near: the earlier in program order
+    ("copy.5", ("norm", "forward", "user", 1)),
+    # a nearer user wins over an earlier one behind a bitcast
+    ("copy.6", ("mlp", "forward", "user", 1)),
+    ("bitcast.6", ("head", "forward", "user", 1)),
+    # no user: what made its operand
+    ("copy.12", ("mlp", "backward", "operand", 1)),
+    # a ConcatBitcast and the halves behind it are the reader's
+    ("custom-call.7", ("mlp", "backward", "user", 1)),
+    ("slice-start.3", ("mlp", "backward", "user", 3)),
+    # through a loop by the place alone: the weight's, not the carry's
+    ("copy-done.11", ("mlp", "forward", "user", 2)),
+    # a copy of a parameter that only leaves the program: nobody's
+    ("copy.8", None),
+    # after a loop: what the body's root holds at the place
+    ("gte.9", ("optimizer/update", "update", "user", 1)),
+    ("out", ("mlp", "forward", "operand", 2)),
+])
+def test_an_unnamed_instruction_gets_the_scope_that_consumes_it(name,
+                                                                expected):
+    kept = step_hlo(FLOW)["map"]
+    assert kept["instructions"][name][0] is None
+    assert kept["owners"].get(name) == expected
+    assert set(kept["owners"]) <= set(kept["tails"])
+
+
+def test_a_custom_call_names_its_target_and_the_transfers_behind_it():
+    kept = step_hlo(FLOW)["map"]
+    assert kept["calls"] == {"custom-call.7": {
+        "target": "ConcatBitcast",
+        "transfers": ["slice-start.2", "slice-start.3"]}}
+    assert kept["relayouts"] == ["copy.4", "copy.5", "copy.6", "copy.8",
+                                 "copy.12"]
+    assert len(kept["relayouts"]) == step_hlo(FLOW)["relayouts"]["count"]
+    # four chips: the halves of XLA:TPU's three fusions are one transfer,
+    # its kind the collective; an asynchronous pair under its own names
+    # too; a Mosaic call is a custom call like another
+    four = step_hlo(FOUR_CHIPS)["map"]
+    assert {n: (t["done"], t["kind"]) for n, t in
+            four["transfers"].items()} == {
+        "async-collective-start.1": ("async-collective-done.1",
+                                     "all-gather"),
+        "collective-permute-start.1": ("collective-permute-done.1",
+                                       "collective-permute")}
+    # (fusion.12 rides on the transfer; fusion.13 reads what it brought)
+    assert four["transfers"]["async-collective-start.1"]["feeds"] == (
+        "fusion.13", "attn/qkv_proj", "backward")
+    assert step_hlo(ONE_CHIP)["map"]["calls"] == {
+        "flash_attention_fwd.1": {"target": "tpu_custom_call",
+                                  "transfers": []}}
+
+
+def _as_an_attached_chip_prints_it(hlo):
+    """The slices' asynchronous pairs in the generic form: ``async-start``
+    / ``async-done`` around a computation that holds the ``slice``. (An
+    attached chip's ``compiled.as_text()`` prints them so where a described
+    one's prints ``slice-start`` / ``slice-done``: the first chip run of
+    PR 73 read ``gpt2xl_c1_b4``'s 1,088 slices as kind ``async`` and its map
+    held 2,176 instructions more than the chipless compile's.)"""
+    import re
+
+    wrapped = []
+
+    def start(m):
+        n = len(wrapped)
+        wrapped.append(
+            f"%async_computation.{n} (p: f32[16,8]) -> f32[8,8] {{\n"
+            f"  %param_0.{n} = f32[16,8]{{1,0}} parameter(0)\n"
+            f"  ROOT %slice.{n} = f32[8,8]{{1,0}} slice(%param_0.{n}), "
+            f"slice={m.group(2)}\n}}\n\n")
+        return f" async-start({m.group(1)}), calls=%async_computation.{n}"
+    hlo = re.sub(r" slice-start\(([^)]*)\), slice=(\{[^}]*\})", start, hlo)
+    head, rest = hlo.replace(" slice-done(", " async-done(").split(
+        "\n\n", 1)
+    return head + "\n\n" + "".join(wrapped) + rest
+
+
+def test_a_slice_in_the_generic_asynchronous_form_is_a_prefetch_too():
+    sugared, generic = step_hlo(FLOW), step_hlo(
+        _as_an_attached_chip_prints_it(FLOW))
+    assert generic["map"]["transfers"] == sugared["map"]["transfers"]
+    assert generic["map"]["calls"] == sugared["map"]["calls"]
+    assert generic["flow"] == sugared["flow"]
+    # what the start wraps is an instruction of the map (as before) and is
+    # the start's
+    more = set(generic["map"]["instructions"]) - set(
+        sugared["map"]["instructions"])
+    assert more == {"param_0.0", "slice.0", "param_0.1", "slice.1"}
+    assert all(generic["map"]["owners"][n] == generic["map"]["owners"][
+        "slice-start.2"] for n in more)
+    assert {n: c for n, c in generic["map"]["instructions"].items()
+            if n not in more} == sugared["map"]["instructions"]
+
+
+def test_the_parser_reads_what_xla_tpu_prints():
+    """Thirty-four lines of ``gpt2xl_c1_b4``'s optimized step as
+    ``tools/aot_hlo_report.py``'s chipless compile printed them (PR 73):
+    layer 0's fused projection weight prefetched in four slices of 7.68 MB
+    into ``S(1)``, the ``ConcatBitcast`` of them, the fusion that reads it,
+    the three small copies beside it, copies OUT of ``S(1)`` and the
+    ``copy-start`` after the fusion."""
+    with open(os.path.join(
+            FIXTURES, "gpt2xl_c1_b4.concat_bitcast.hlo.txt")) as f:
+        found = step_hlo(f.read())
+    kept = found["map"]
+    for n in range(648, 652):
+        assert kept["transfers"][f"slice-start.{n}"] == {
+            "done": f"slice-done.{n}", "kind": "prefetch",
+            "bytes": 400 * 4800 * 4, "space": "S(1)", "from": None,
+            "feeds": ("fusion.65", "attn/qkv_proj", "forward")}
+        assert kept["owners"][f"slice-done.{n}"] == (
+            "attn/qkv_proj", "forward", "user", 2)
+    assert kept["calls"] == {"custom-call.164": {
+        "target": "ConcatBitcast",
+        "transfers": [f"slice-start.{n}" for n in range(648, 652)]}}
+    # out of S(1): the destination's layout names no space
+    assert kept["transfers"]["copy-start.192"] == {
+        "done": "copy-done.192", "kind": "prefetch", "bytes": 4 * 1024 * 4,
+        "space": None,
+        "from": ("broadcast_multiply_fusion.32", "norm", "forward"),
+        "feeds": None}
+    assert kept["owners"]["copy-done.192"] == (
+        "norm", "forward", "operand", 2)
+    # (its other half lies outside the excerpt)
+    assert kept["transfers"]["copy-start.1005"]["done"] is None
+    assert kept["relayouts"] == ["copy.742"]
+    assert kept["instructions"]["copy.742"] == ("embed", "forward", None)
+    assert found["flow"]["prefetches"] == 12
+    # the same thirty-four lines as the ATTACHED chip's compile printed them
+    # (my chip run, PR 73, seed 2147483869: ``compiled.as_text()`` in the
+    # trainer's process): the slices are ``async-start(...), calls=
+    # %async_computation.648`` and ``async-done(...)``, nothing else differs
+    with open(os.path.join(
+            FIXTURES, "gpt2xl_c1_b4.chip.concat_bitcast.hlo.txt")) as f:
+        chip = step_hlo(f.read())
+    assert "async-start(%params__layers___0___attn____wqkv__.1), calls=" \
+        in open(f.name).read()
+    for key in ("transfers", "calls", "relayouts"):
+        assert chip["map"][key] == kept[key]
+    assert chip["flow"] == found["flow"]
+    assert chip["map"]["owners"]["slice.1299"] == chip["map"]["owners"][
+        "slice-start.648"] == ("attn/qkv_proj", "forward", "user", 3)
+
+
+@pytest.mark.parametrize("text", [
+    "ONE_CHIP", "NAMELESS", "EXPERT_KERNELS", "FOUR_CHIPS", "FLOW",
+    "gpt2xl_c1_b4.concat_bitcast.hlo.txt",
+    "gpt2xl_c1_b4.chip.concat_bitcast.hlo.txt"])
+def test_the_triples_are_what_they_were_before_the_data_was_followed(text):
+    """``instructions``, ``inferred`` and ``tails`` as the parent commit's
+    walk (PR 72, ``a1b7eb5``) gave them for the same texts, kept in
+    ``fixtures/step_map_triples.pr72.json``: every metric of
+    ``step_map.py`` reads what it read."""
+    with open(os.path.join(FIXTURES, "step_map_triples.pr72.json")) as f:
+        before = json.load(f)[text]
+    if text in globals():
+        hlo = globals()[text]
+    else:
+        with open(os.path.join(FIXTURES, text)) as f:
+            hlo = f.read()
+    kept = json.loads(json.dumps(step_hlo(hlo)["map"]))
+    assert {k: kept[k] for k in before} == before

@@ -10,8 +10,11 @@ The step is the one ``benchmark/aot_check.py`` compiles (the cell's own
 command line, ``make_spmd_train_step`` on a described ``v5e:2x2``); nothing
 runs. It prints the bytes of the ``reshape``, ``copy`` and ``transpose``
 instructions left outside fusions (``step_hlo``'s ``relayouts``: the gauge
-``step/relayout_bytes`` of a run) and, per computation of the optimized HLO
-(the entry, each while body, ...),
+``step/relayout_bytes`` of a run), on the same line the prefetches XLA made
+(asynchronous copies and slices), their bytes and the instructions under no
+scope that no scoped one uses or feeds (``step_hlo``'s ``flow``: the gauges
+``step/prefetches``, ``step/prefetch_bytes``, ``step/unowned_instructions``)
+and, per computation of the optimized HLO (the entry, each while body, ...),
 
 * XLA:TPU's own ``estimated_cycles`` summed by instruction stem
   (``fusion.123`` -> ``fusion``) and by the tail of ``op_name`` (the jax
@@ -168,7 +171,8 @@ def report(text: str, top: int = 12):
                 for (op, shape, grp, tail), n in sorted(
                     coll[comp].items(), key=lambda kv: (kv[0][0], -kv[1]))]})
     comps.sort(key=lambda c: -c["estimated_cycles"])
-    return {"computations": comps, "relayouts": found["relayouts"]}
+    return {"computations": comps, "relayouts": found["relayouts"],
+            "flow": found["flow"]}
 
 
 def print_report(rep, file=None):
@@ -176,7 +180,10 @@ def print_report(rep, file=None):
     print("relayouts outside fusions (the gauge step/relayout_bytes): "
           f"{moved['bytes']} bytes in {moved['count']} instructions"
           + (", the largest {opcode} {shape} <- {op_name}".format(
-              **moved["largest"]) if moved["largest"] else ""), file=file)
+              **moved["largest"]) if moved["largest"] else "")
+          + "; step/prefetches {prefetches} of step/prefetch_bytes "
+            "{prefetch_bytes}, step/unowned_instructions "
+            "{unowned_instructions}".format(**rep["flow"]), file=file)
     for c in rep["computations"]:
         print(f"== {c['computation']}: {c['instructions']} instructions, "
               f"{c['estimated_cycles'] / 1e6:.1f} M estimated cycles, "

@@ -23,10 +23,23 @@ It prints
   share of the summed leaf time;
 * the collectives by class and phase, ms a step and events a step;
 * the twenty heaviest instructions under no scope, with the tail of their
-  ``op_name`` (their opcode where they have none);
+  ``op_name`` (their opcode where they have none) and, from the map's
+  ``owners``, the scope and phase of the nearest scoped instruction that
+  uses the result (``user``) or made an operand (``operand``) and how many
+  instructions away it is (``hops``): whose code the copy belongs to;
 * each idle gap inside a step longer than 0.2 ms, by the instruction that
   ends before it and the one that starts after it (scope, phase and
   collective class of both), with how often it occurs and its mean length;
+  and, by ``benchmark/layer_metrics/step_flow.py``'s rules (the per-layer
+  metrics ``idle_*_ms`` sum the same records), its class: ``hidden`` (no
+  gap: the named operation ran, and an event of no length at its own start
+  took it out of the trace's leaves), ``collective``, ``prefetch`` or
+  ``unexplained``; the custom call that says so with its target
+  (``ConcatBitcast``), and the transfers behind it (the prefetches whose
+  results it concatenates, or the one in flight that feeds the next
+  operation) with their bytes and the time from their start on the ``Async
+  XLA Ops`` line to the gap's end: bytes over that time is the rate the
+  wait implies;
 * the traced names that are no instruction of the map (none, when trace
   and map are of one program);
 * where the trace holds several chips, one row a chip: its compute, the
@@ -95,14 +108,36 @@ def join(reduced, step_map):
         scopes[scope] += ns
     describe = lambda n: "/".join(
         str(x) for x in classes.get(n, ("?",)) if x) or NO_SCOPE
+    owners = step_map.get("owners", {})
+    # (a map written before PR 73 follows no data: every gap is then
+    # hidden, a collective's by its class, or unexplained)
+    followed = {"transfers": {}, "calls": {}, **step_map}
+    flow = _reader("step_flow.py").laid(reduced, followed)
     gaps = collections.defaultdict(list)
-    last_end, last = None, None     # the latest end so far, and whose
-    for s, e, n in leaves:
-        if (last is not None and s - last_end > GAP_MS * 1e6
-                and step_of(last_end, s) is not None):
-            gaps[(last, n)].append(s - last_end)
-        if last is None or e > last_end:
-            last_end, last = e, n
+    for g in flow["gaps"]:
+        if g["end"] - g["start"] > GAP_MS * 1e6:
+            gaps[(g["after"], g["before"], g["class"])].append(g)
+    for n, s, e in flow["hidden"]:
+        if e - s > GAP_MS * 1e6:
+            gaps[(n, n, "hidden")].append(
+                {"start": s, "end": e, "by": None, "waits_for": []})
+
+    def gap_row(n0, n1, cls, found):
+        by, waits = found[0]["by"], collections.defaultdict(list)
+        for g in found:
+            for n, size, ns in g["waits_for"]:
+                waits[(n, size)].append(ns)
+        return {
+            "after": n0, "after_is": describe(n0), "before": n1,
+            "before_is": describe(n1), "times": len(found),
+            "mean_ms": sum(g["end"] - g["start"] for g in found)
+            / len(found) / 1e6,
+            "class": cls, "by": by,
+            "target": followed["calls"].get(by, {}).get("target"),
+            "waits_for": [
+                {"transfer": n, "bytes": size,
+                 "us_from_its_start": sum(ns) / len(ns) / 1e3}
+                for (n, size), ns in waits.items()]}
     return {
         "periods": periods,
         "leaf_ms_a_step": per(total),
@@ -120,14 +155,15 @@ def join(reduced, step_map):
             for (cls, phase), ns in sorted(by_class.items())],
         "unnamed": [
             {"instruction": n, "ms": per(ns), "phase": classes[n][1],
-             "op_name_tail": tails.get(n, "")}
+             "op_name_tail": tails.get(n, ""),
+             **dict(zip(("owner", "owner_phase", "via", "hops"),
+                        owners.get(n) or (None,) * 4))}
             for n, ns in unnamed.most_common(HEAVIEST)],
-        "gaps": sorted((
-            {"after": n0, "after_is": describe(n0), "before": n1,
-             "before_is": describe(n1), "times": len(ns),
-             "mean_ms": sum(ns) / len(ns) / 1e6}
-            for (n0, n1), ns in gaps.items()),
-            key=lambda g: -g["mean_ms"] * g["times"]),
+        "idle_inside_ms": {
+            "all": per(flow["inside_ns"]), "hidden": per(flow["hidden_ns"]),
+            **{cls: per(ns) for cls, ns in flow["by_class_ns"].items()}},
+        "gaps": sorted((gap_row(*key, found) for key, found in gaps.items()),
+                       key=lambda g: -g["mean_ms"] * g["times"]),
         "not_in_the_map": [[n, per(ns)] for n, ns in strangers.most_common()],
     }
 
@@ -150,13 +186,29 @@ def print_tables(t, file=None):
             f"{row['events_a_step']:7.1f} a step")
     say(f"the {len(t['unnamed'])} heaviest instructions under no scope:")
     for row in t["unnamed"]:
+        owner = (f"  owner {row['owner']}/{row['owner_phase']} via "
+                 f"{row['via']}, {row['hops']} hops" if row["owner"]
+                 else "  no owner")
         say(f"  {row['ms']:9.3f} ms  {row['instruction']:32s} "
-            f"{row['phase']:10s} {row['op_name_tail']}")
-    say(f"idle gaps inside a step over {GAP_MS} ms:"
-        + ("" if t["gaps"] else " none"))
+            f"{row['phase']:10s} {row['op_name_tail']}{owner}")
+    idle = t["idle_inside_ms"]
+    say(f"idle inside a step {idle['all']:.3f} ms: " + ", ".join(
+        f"{k} {v:.3f}" for k, v in idle.items() if k != "all")
+        + f"; the gaps over {GAP_MS} ms:" + ("" if t["gaps"] else " none"))
     for g in t["gaps"]:
-        say(f"  {g['mean_ms']:7.3f} ms x {g['times']}  after {g['after']} "
-            f"[{g['after_is']}], before {g['before']} [{g['before_is']}]")
+        if g["class"] == "hidden":
+            say(f"  {g['mean_ms']:7.3f} ms x {g['times']}  hidden    no gap: "
+                f"{g['after']} [{g['after_is']}] ran, an empty event at its "
+                "start took it out of the leaves")
+            continue
+        say(f"  {g['mean_ms']:7.3f} ms x {g['times']}  {g['class']:9s} after "
+            f"{g['after']} [{g['after_is']}], before {g['before']} "
+            f"[{g['before_is']}]"
+            + (f"; by {g['by']}" + (f" ({g['target']})" if g["target"]
+                                    else "") if g["by"] else "")
+            + "".join(f"; {w['transfer']} {w['bytes'] / 1e6:.2f} MB "
+                      f"{w['us_from_its_start']:.0f} us"
+                      for w in g["waits_for"]))
     if t["not_in_the_map"]:
         say("traced inside a step and NO instruction of the map (trace and "
             "map are of two programs?):")
@@ -164,14 +216,18 @@ def print_tables(t, file=None):
             say(f"  {ms:9.3f} ms  {n}")
 
 
+def _reader(name):
+    from benchmark import manifest
+
+    return manifest.load_python(os.path.join(
+        ROOT, "benchmark", "layer_metrics", name))
+
+
 def by_chip(reduced, step_map):
     """The chips side by side (``chip_skew.py``'s table): ``None`` for one
     chip, a step map without collectives, or chips that hold different
     collective occurrences in every traced step."""
-    from benchmark import manifest
-
-    skew = manifest.load_python(os.path.join(
-        ROOT, "benchmark", "layer_metrics", "chip_skew.py"))
+    skew = _reader("chip_skew.py")
     table = skew.side_by_side(reduced, step_map["instructions"])
     if table is None:
         return None
