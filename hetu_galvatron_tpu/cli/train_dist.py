@@ -802,6 +802,7 @@ def train(args) -> Dict[str, Any]:
     drop_key = jax.random.key(args.train.seed) if use_dropout else None
     # what the compiled step contains (filled after the first step)
     from hetu_galvatron_tpu.observability.trace_analysis import (
+        GATED_NORM_SCOPE,
         GDN_SCAN_SCOPE,
         SELECTIVE_SCOPE,
         SSD_SCOPE,
@@ -1309,12 +1310,13 @@ def train(args) -> Dict[str, Any]:
                             get_registry().gauge(f"kda/{part}").set(v)
                     for kind, scope, name in (
                             ("mamba", SSD_SCOPE, "ssd"),
+                            ("mamba", GATED_NORM_SCOPE, "gated_norm"),
                             ("mamba1", SELECTIVE_SCOPE, "selective"),
                             ("linear_attention", GDN_SCAN_SCOPE, "gdn")):
                         if any(m == kind for m, _ in kinds):
-                            # whether the scan's kernels engaged: the
-                            # Mosaic calls under its scope, 0 = the
-                            # jax.numpy form
+                            # whether the kernels of the scan (of a mamba
+                            # block's gated norm) engaged: the Mosaic
+                            # calls under its scope, 0 = the jax.numpy form
                             step_report[f"{name}_mosaic_calls"] = sum(
                                 n in found["mosaic_calls"]
                                 for n in found["scopes"].get(scope, ()))
@@ -1418,7 +1420,9 @@ def train(args) -> Dict[str, Any]:
                       "{unowned_instructions}".format(**step_report["flow"])
                     + f", {step_report['mosaic_custom_calls']} Mosaic calls"
                     + (f" ({step_report['ssd_mosaic_calls']} under "
-                       f"{SSD_SCOPE}), ssd/groups {cfg.mamba_n_groups}"
+                       f"{SSD_SCOPE}), ssd/groups {cfg.mamba_n_groups}, "
+                       "gated_norm/mosaic_calls "
+                       f"{step_report['gated_norm_mosaic_calls']}"
                        if "ssd_mosaic_calls" in step_report else "")
                     + (", selective/mosaic_calls "
                        f"{step_report['selective_mosaic_calls']}"
@@ -1575,6 +1579,10 @@ def train(args) -> Dict[str, Any]:
             # the Mosaic calls among those under mixer/mamba/ssd (the gauge
             # ssd/mosaic_calls): 0 where the scan ran in its jax.numpy form
             "ssd_mosaic_calls": step_report.get("ssd_mosaic_calls"),
+            # the same under mixer/mamba/gated_norm (the gauge
+            # gated_norm/mosaic_calls): the skip and the gated norm
+            "gated_norm_mosaic_calls": step_report.get(
+                "gated_norm_mosaic_calls"),
             # the same under mixer/mamba1/scan (the gauge
             # selective/mosaic_calls); None for a model without such a block
             "selective_mosaic_calls": step_report.get(

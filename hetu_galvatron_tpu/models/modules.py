@@ -29,7 +29,7 @@ TPU-first design, deliberately unlike the torch reference:
   ``ops`` and hands it on, and only :func:`apply_mixer` and the block's
   feed-forward branch take it apart, into the keywords of the functions
   that call an operator (``apply_attention(sdpa_fn=, matmul_fns=,
-  shard_fn=)``, ``apply_mamba2(ssd_fn=, conv_fn=)``, ...).
+  shard_fn=)``, ``apply_mamba2(ssd_fn=, conv_fn=, norm_fn=)``, ...).
 * **One table of mixer kinds.** :data:`MIXERS` has a row a kind of block
   operator: its parameter key, ``init``, ``apply``, whether it attends and
   which fields of the record it reads.
@@ -61,11 +61,12 @@ class LayerOps:
     mapping (x, w) to the fp32 product the default einsum would produce);
     ``shard(a, axis)`` pins an interior activation
     of a tp > 1 layer (parallel/spmd.py::interior_sharding); ``ssd``,
-    ``kda``, ``gdn``, ``selective`` and ``conv`` are the kernels of a mamba
-    block's chunked scan, a kda block's chunked delta rule, a
-    linear_attention block's (a decay a head), a mamba1 block's selective
-    scan and the causal depthwise convolution (ops/pallas/); ``exchange``
-    runs an expert block's sorted dispatcher across the chips of its ``ep``
+    ``kda``, ``gdn``, ``selective``, ``conv`` and ``gated_norm`` are the
+    kernels of a mamba block's chunked scan, a kda block's chunked delta
+    rule, a linear_attention block's (a decay a head), a mamba1 block's
+    selective scan, the causal depthwise convolution and a mamba block's
+    skip and gated norm (ops/pallas/); ``exchange`` runs an expert block's
+    sorted dispatcher across the chips of its ``ep``
     group (models/moe.py::make_expert_exchange); ``grouped(mode, a, b,
     group_sizes, out_dtype)`` is the kernels of an expert block's grouped
     matmuls, forward and both gradients (ops/pallas/grouped_matmul.py), None
@@ -82,6 +83,7 @@ class LayerOps:
     gdn: Optional[Callable[..., jax.Array]] = None
     selective: Optional[Callable[..., Optional[jax.Array]]] = None
     conv: Optional[Callable[..., Optional[jax.Array]]] = None
+    gated_norm: Optional[Callable[..., Optional[jax.Array]]] = None
     exchange: Optional[Callable[..., Any]] = None
     grouped: Optional[Callable[..., Optional[jax.Array]]] = None
 
@@ -1440,6 +1442,47 @@ def ssd_chunked(x: jax.Array, dt: jax.Array, A: jax.Array, Bm: jax.Array,
     return y.reshape(B_, nC * Q, H, P)[:, :S]
 
 
+def mamba_gated_norm(y: jax.Array, x: jax.Array, z: jax.Array, D: jax.Array,
+                     scale: jax.Array, groups: int, eps: float,
+                     out_dtype=jnp.bfloat16,
+                     norm_fn: Optional[Callable[..., Optional[jax.Array]]]
+                     = None) -> jax.Array:
+    """What lies between a Mamba-2 block's scan and its ``out_proj``: the
+    skip ``u = y + D x`` (``D`` one number a head), the gate ``a = u *
+    silu(z)`` and ``RMSNorm(a) * scale``, the mean square over each of the
+    ``groups`` groups of channels; ``y`` [B, S, C] float32, ``x`` and ``z``
+    [B, S, C], all float32 inside, ``out_dtype`` out.
+
+    One algorithm run one of two ways, by what the caller hands in and the
+    shapes alone. ``norm_fn`` (the Pallas kernels of
+    ``ops/pallas/gated_norm.py``, which whoever knows the devices hands
+    down: ``parallel/spmd.attention_overrides``) is ONE pass over the rows
+    as they lie, a group a range of whole lane tiles, with a backward of
+    its own that keeps no float32 value: one path for every group count
+    (PERF.md section 6, PR 75: at eight groups ``jax.numpy`` took twice the
+    time and 20 ms a step more in relayouts; at one group, which XLA had
+    fused into its neighbours, the step is level). Where it
+    answers None (a group that is no whole number of lane tiles) or none is
+    handed in, it is ``jax.numpy``, a group a minor dimension of its own:
+    on a TPU that view and the view back are a relayout each."""
+    if norm_fn is not None:
+        out = norm_fn(y, x, z, D, scale, groups=groups, eps=eps,
+                      out_dtype=out_dtype, scope="mixer/mamba/gated_norm")
+        if out is not None:
+            return out
+    B, S, C = y.shape
+    f32 = jnp.float32
+    y = y + jnp.repeat(D, C // D.shape[0]) * x.astype(f32)
+    y = y * jax.nn.silu(z.astype(f32))
+    if groups > 1:   # the mean square a group of channels
+        y = y.reshape(B, S, groups, C // groups)
+    var = jnp.mean(jnp.square(y), axis=-1, keepdims=True)
+    y = y * jax.lax.rsqrt(var + eps)
+    if groups > 1:
+        y = y.reshape(B, S, C)
+    return (y * scale).astype(out_dtype)
+
+
 def apply_mamba2(
     p: Params,
     x: jax.Array,
@@ -1447,6 +1490,7 @@ def apply_mamba2(
     compute_dtype=jnp.bfloat16,
     ssd_fn: Optional[Callable[..., jax.Array]] = None,
     conv_fn: Optional[Callable[..., Optional[jax.Array]]] = None,
+    norm_fn: Optional[Callable[..., Optional[jax.Array]]] = None,
 ) -> jax.Array:
     """``[z | xBC | dt] = x W_in``; ``xBC = silu(conv1d_causal(xBC) + b)``
     (depthwise, ``mamba_d_conv`` taps, zero history before the sequence);
@@ -1455,13 +1499,15 @@ def apply_mamba2(
     -exp(A_log)``; ``y = SSD(x, dt, A, B, C) + D x``
     (:func:`ssd_chunked`); ``y = RMSNorm(y * silu(z)) * w``, the mean
     square over each group's ``mamba_d_inner / mamba_n_groups`` channels
-    (one group: over all channels); ``y W_out``. No softmax, no positions. The two projections
+    (one group: over all channels; :func:`mamba_gated_norm`, which adds the
+    skip too); ``y W_out``. No softmax, no positions. The two projections
     and the recurrence's matmuls run in ``compute_dtype`` with float32
     accumulation; ``dt``, the decays, the state, the convolution and the
     gated norm are float32. ``ssd_fn``: the kernels for the
     recurrence, where the caller's devices run them
-    (:func:`ssd_chunked`'s ``scan_fn``), and ``conv_fn`` those for the
-    convolution, its bias and SiLU (:func:`causal_depthwise_conv`)."""
+    (:func:`ssd_chunked`'s ``scan_fn``), ``conv_fn`` those for the
+    convolution, its bias and SiLU (:func:`causal_depthwise_conv`) and
+    ``norm_fn`` those for the skip and the gated norm."""
     B, S, _ = x.shape
     nh, hp = cfg.mamba_n_heads, cfg.mamba_d_head
     G, GN = cfg.mamba_n_groups, cfg.mamba_n_groups * cfg.mamba_d_state
@@ -1485,19 +1531,12 @@ def apply_mamba2(
                             -jnp.exp(p["A_log"].astype(f32)), Bm, Cm,
                             cfg.mamba_chunk_size, compute_dtype,
                             scan_fn=ssd_fn, groups=G)
+        with jax.named_scope("gated_norm"):
             # a head is hp of a row's lanes, here as in the kernels: a
             # [.., heads, hp] view of a row is no bitcast on a TPU
-            y = (y.reshape(B, S, di)
-                 + jnp.repeat(p["D"], hp) * xs.astype(f32))
-        with jax.named_scope("gated_norm"):
-            y = y * jax.nn.silu(z.astype(f32))
-            if G > 1:   # the mean square a group of channels
-                y = y.reshape(B, S, G, di // G)
-            var = jnp.mean(jnp.square(y), axis=-1, keepdims=True)
-            y = y * jax.lax.rsqrt(var + cfg.layernorm_epsilon)
-            if G > 1:
-                y = y.reshape(B, S, di)
-            y = (y * p["norm"]["scale"]).astype(compute_dtype)
+            y = mamba_gated_norm(
+                y.reshape(B, S, di), xs, z, p["D"], p["norm"]["scale"], G,
+                cfg.layernorm_epsilon, compute_dtype, norm_fn)
         with jax.named_scope("out_proj"):
             out = jnp.einsum("bsc,ch->bsh", y,
                              p["wout"].astype(compute_dtype),
@@ -2491,7 +2530,8 @@ MIXERS: Dict[str, Mixer] = {
         crosses_documents="the convolution's two tokens of history"),
     "mamba": Mixer(
         "mamba", init_mamba2, apply_mamba2, False,
-        {"ssd_fn": "ssd", "conv_fn": "conv"}, "mamba2",
+        {"ssd_fn": "ssd", "conv_fn": "conv", "norm_fn": "gated_norm"},
+        "mamba2",
         crosses_documents=_CARRIED),
     "latent_attention": Mixer(
         "attn", init_latent_attention, apply_latent_attention, True,

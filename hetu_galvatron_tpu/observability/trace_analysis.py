@@ -479,6 +479,10 @@ OWN_SCOPES = MTP_SCOPES + tuple(
 # the scope of the state-space scan, whose Mosaic calls the step report
 # counts (``ssd/mosaic_calls``; ops/pallas/ssd.py traces under it)
 SSD_SCOPE = "mixer/mamba/ssd"
+# the scope of a mamba block's skip and gated norm, whose Mosaic calls the
+# step report counts (``gated_norm/mosaic_calls``;
+# ops/pallas/gated_norm.py's backward traces under the scope it is told)
+GATED_NORM_SCOPE = "mixer/mamba/gated_norm"
 
 # the scope of a kda block's recurrence, whose loops or kernels the step
 # report counts (:func:`kda_loops`, :func:`kda_kernel_calls`;

@@ -145,13 +145,15 @@ def block_kernels():
     The kinds of block that take a field are the rows of ``modules.MIXERS``
     that name it; whether the shapes fit a kernel's tiles is the kernel's
     caller's to see (``modules.ssd_chunked``, ``kda_chunked``,
-    ``apply_gated_delta``, ``apply_mamba1``, ``causal_depthwise_conv``),
+    ``apply_gated_delta``, ``apply_mamba1``, ``causal_depthwise_conv``,
+    ``mamba_gated_norm``),
     which keeps its ``jax.numpy`` form where they do not (for ``gdn`` also
     where the chunk does not divide the sequence). A mamba, mamba1, kda or
     linear_attention block cut any other way than over dp is refused by
     name (analysis/eligibility.py); a depthwise convolution is local to a
     channel shard."""
     from hetu_galvatron_tpu.ops.pallas.conv import make_causal_conv
+    from hetu_galvatron_tpu.ops.pallas.gated_norm import make_gated_norm
     from hetu_galvatron_tpu.ops.pallas.gdn import make_gdn_scan
     from hetu_galvatron_tpu.ops.pallas.kda import make_kda_scan
     from hetu_galvatron_tpu.ops.pallas.selective_scan import (
@@ -163,7 +165,8 @@ def block_kernels():
             ("kda", make_kda_scan, False, None),
             ("gdn", make_gdn_scan, False, None),
             ("selective", make_selective_scan, False, None),
-            ("conv", make_causal_conv, True, "weight_tp_axes"))
+            ("conv", make_causal_conv, True, "weight_tp_axes"),
+            ("gated_norm", make_gated_norm, False, None))
 
 
 def attention_overrides(
@@ -205,7 +208,8 @@ def attention_overrides(
     every layer attends): a layer whose kind does not attend, or that has no
     mixer (a feed-forward block of a one-branch stack), gets no core, and
     a layer whose kind reads a field of :func:`block_kernels` (a ``mamba``
-    layer ``ssd`` and ``conv``, a ``kda`` layer ``kda`` and ``conv``, a
+    layer ``ssd``, ``conv`` and ``gated_norm``, a ``kda`` layer ``kda`` and
+    ``conv``, a
     ``mamba1`` layer ``selective`` and ``conv``, a ``linear_attention``
     layer ``gdn`` and ``conv``, a ``conv`` layer ``conv``)
     gets that kernel when ``kernels`` (None = the same rule: every mesh

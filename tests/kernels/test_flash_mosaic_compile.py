@@ -1,4 +1,5 @@
-"""The flash kernels, the kernels of the Mamba-2 scan, those of the
+"""The flash kernels, the kernels of the Mamba-2 scan and of its gated
+norm, those of the
 chunked delta rule (a decay a channel, and a decay a head), those of the
 causal depthwise convolution and those of
 the experts' grouped matmuls, compiled by the real Mosaic / XLA:TPU compilers for a
@@ -11,12 +12,15 @@ All such compiles live in THIS file and describe the topology inside a
 fixture: one process may hold libtpu at a time, and a module that touched it
 while being imported would do so in every xdist worker."""
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
 
 from hetu_galvatron_tpu.ops.pallas import (
     conv,
+    gated_norm,
     gdn,
     kda,
     selective_scan,
@@ -586,3 +590,82 @@ def test_a_rematted_recurrent_block_holds_one_scan_forward(
                                         "backward": 1}
     assert scans_recomputed(found) == recomputed
     assert cores_recomputed(found) == 0
+
+
+# groups of B, C and the gated norm's channels, the rows a step, the dtype:
+# Nemotron-H's mixer (two sequences in one microbatch), Granite's, and
+# float32 operands over a ragged last tile (rows of 80 forward)
+_GATED_NORM_BLOCKS = {"nemotronh_cell_8_groups": (8, 2, 8192, jnp.bfloat16),
+                      "granite_cell_1_group": (1, 1, 8192, jnp.bfloat16),
+                      "f32_a_ragged_tile": (2, 1, 1792, jnp.float32)}
+
+
+@pytest.mark.parametrize("norm", ["kernels", "jax_numpy"])
+@pytest.mark.parametrize("case", sorted(_GATED_NORM_BLOCKS))
+def test_a_mamba_blocks_gated_norm_is_one_pass_a_phase(one_chip, case, norm):
+    """A mamba block at the cells' widths under per-layer remat, handed the
+    scan's, the convolution's and the gated norm's kernels: the norm is ONE
+    Mosaic call in each of the forward pass, the forward made again and the
+    backward pass, each under ``mixer/mamba/gated_norm`` (the backward by
+    the scope its rule is told), which ``gated_norm/mosaic_calls`` counts,
+    while the scan's two stay what ``ssd/mosaic_calls`` counts; and no
+    ``reshape`` or ``copy`` of the rows outside a fusion is that scope's,
+    by its own ``op_name`` or by the map's ``owners``. The control: in
+    ``jax.numpy`` several groups are a minor dimension of their own, and the compiled
+    block holds such relayouts (one group holds none either way)."""
+    from hetu_galvatron_tpu.core.args_schema import ModelArgs
+    from hetu_galvatron_tpu.models import modules as M
+    from hetu_galvatron_tpu.observability.trace_analysis import (
+        GATED_NORM_SCOPE,
+        SSD_SCOPE,
+        step_hlo,
+    )
+
+    G, B, S, dtype = _GATED_NORM_BLOCKS[case]
+    cfg = ModelArgs(num_hidden_layers=1, num_attention_heads=32,
+                    vocab_size=128, max_position_embeddings=S, seq_length=S,
+                    hidden_act="swiglu", normalization="rmsnorm",
+                    add_bias_linear=False, hidden_size=2048,
+                    ffn_hidden_size=8192, mamba_n_heads=64, mamba_d_head=64,
+                    mamba_d_state=128, mamba_chunk_size=256,
+                    mamba_n_groups=G)
+    shapes = jax.eval_shape(
+        lambda k: M.init_decoder_layer(k, cfg, mixer="mamba")[0],
+        jax.random.key(0))
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        shapes)
+    x = jax.ShapeDtypeStruct((B, S, cfg.hidden_size), dtype,
+                             sharding=one_chip)
+    ops = M.LayerOps(
+        conv=conv.causal_conv, ssd=ssd.ssd_scan,
+        gated_norm=gated_norm.gated_norm if norm == "kernels" else None)
+    wrapped = M.remat(lambda p, h: M.apply_decoder_layer(
+        p, h, cfg, ops=ops, mixer="mamba", compute_dtype=dtype), cfg)
+    text = jax.jit(jax.grad(
+        lambda p, h: jnp.sum(wrapped(p, h).astype(jnp.float32) ** 2),
+        argnums=(0, 1))).lower(params, x).compile().as_text()
+    found = step_hlo(text)
+    placed, owners = found["map"]["instructions"], found["map"]["owners"]
+    calls = [n for n in found["scopes"][GATED_NORM_SCOPE]
+             if n in found["mosaic_calls"]]
+    # the scope's relayouts of an array of the rows' size (``D`` spread
+    # over its head's lanes is a reshape of 16 KB either way)
+    relayouts = [n for n in found["map"]["relayouts"]
+                 if GATED_NORM_SCOPE in (placed[n][0],
+                                         owners.get(n, (None,))[0])
+                 and re.search(rf"%{re.escape(n)} = \w+\[{B},{S},", text)]
+    phases = {phase for n, (scope, phase, _) in placed.items()
+              if scope == GATED_NORM_SCOPE}
+    assert {"forward", "recompute", "backward"} <= phases, phases
+    assert sum(n in found["mosaic_calls"]
+               for n in found["scopes"][SSD_SCOPE]) == 2
+    if norm == "kernels":
+        assert sorted(placed[n][1] for n in calls) == [
+            "backward", "forward", "recompute"], calls
+        assert sorted(n.split(".")[0] for n in calls) == [
+            "gated_norm_bwd", "gated_norm_fwd", "gated_norm_fwd"]
+        assert relayouts == []
+    else:
+        assert calls == []
+        assert bool(relayouts) == (G > 1), relayouts

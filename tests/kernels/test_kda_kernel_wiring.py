@@ -54,7 +54,8 @@ def test_who_knows_the_devices_hands_the_kernels_down(forced):
         mixers=["latent_attention", "kda", "mamba", "kda"],
         kernels=forced)
     assert {i: list(ops.given()) for i, ops in got.items()} == (
-        {1: ["kda", "conv"], 2: ["ssd", "conv"], 3: ["kda", "conv"]}
+        {1: ["kda", "conv"], 2: ["ssd", "conv", "gated_norm"],
+         3: ["kda", "conv"]}
         if forced else {})
     if forced:
         # and what it hands down is the scan, under shard_map over dp

@@ -212,7 +212,8 @@ def test_who_knows_the_devices_hands_the_kernels_down(forced):
         per_layer, mesh, use_flash=False, flash_interpret=True,
         mixers=["mamba1", "full_attention", "mamba", "gmu"], kernels=forced)
     assert {i: list(ops.given()) for i, ops in got.items()} == (
-        {0: ["selective", "conv"], 2: ["ssd", "conv"]} if forced else {})
+        {0: ["selective", "conv"], 2: ["ssd", "conv", "gated_norm"]}
+        if forced else {})
     if forced:
         # and what it hands down is the scan, under shard_map
         *args, _ = _inputs("short_state_of_eight")

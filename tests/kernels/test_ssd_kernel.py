@@ -276,7 +276,7 @@ def test_who_knows_the_devices_hands_the_kernels_down(forced):
         per_layer, mesh, use_flash=False, flash_interpret=True,
         mixers=["mamba", "full_attention", "conv"], kernels=forced)
     assert {i: list(ops.given()) for i, ops in got.items()} == (
-        {0: ["ssd", "conv"], 2: ["conv"]} if forced else {})
+        {0: ["ssd", "conv", "gated_norm"], 2: ["conv"]} if forced else {})
     if forced:
         # and what it hands down is the scan, under shard_map
         args = _inputs(256, jnp.float32)

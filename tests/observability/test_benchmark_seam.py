@@ -675,6 +675,101 @@ ENTRY %main (a: f32[8]) -> f32[8] {
                for n in found["scopes"][SSD_SCOPE]) == 2
 
 
+# a tiny mamba stack at TWO groups of B, C and the gated norm's channels
+# under per-layer remat (``nemotronh_c1_s8k``'s mixer: eight there)
+TWO_GROUPS = PRESETS["granite"] + ["model.mamba_n_groups=2",
+                                   "parallel.global_checkpoint=1",
+                                   "train.train_iters=2"]
+
+
+@pytest.fixture(scope="module")
+def two_group_run():
+    yaml, *size = TWO_GROUPS
+    with _launched([os.path.join(ZOO, yaml)] + size) as ran:
+        yield ran
+
+
+@pytest.mark.parametrize("said_by", ["phases", "relayouts", "gauge",
+                                     "result", "report"])
+def test_the_gated_norm_is_one_scope_in_every_phase(two_group_run, said_by):
+    """``mixer/mamba/gated_norm`` holds instructions of the compiled step
+    in the forward pass, the forward made again and the backward pass (a
+    reader of the scope finds all three: ``mamba_gated_norm_ms``,
+    ``granite_mamba_ms``), and no ``reshape`` or ``copy`` outside a fusion
+    is the scope's, by its ``op_name`` or by the map's ``owners``.
+    ``gated_norm/mosaic_calls`` counts the Mosaic calls under it beside
+    ``ssd/mosaic_calls``, which counts ``mixer/mamba/ssd`` alone: 0 and 0
+    on a step compiled for a CPU, where both ran in ``jax.numpy`` (what
+    they are on a step that holds the kernels, and that the several groups
+    of the ``jax.numpy`` form ARE relayouts on a TPU: the compile for a
+    described v5e, ``tests/kernels/test_flash_mosaic_compile.py::
+    test_a_mamba_blocks_gated_norm_is_one_pass_a_phase``)."""
+    from hetu_galvatron_tpu.observability import trace_analysis
+
+    ran = two_group_run
+    scope = trace_analysis.GATED_NORM_SCOPE
+    held = trace_analysis.step_scopes()["map"]
+    (report,) = [line for line in ran["log"].splitlines()
+                 if "step report:" in line]
+    if said_by == "phases":
+        assert {"forward", "recompute", "backward"} <= {
+            phase for s, phase, _ in held["instructions"].values()
+            if s == scope}
+        assert ran["result"]["scope_instructions"][scope]
+    if said_by == "relayouts":
+        assert [n for n in held["relayouts"] if scope in (
+            held["instructions"][n][0],
+            held["owners"].get(n, (None,))[0])] == []
+    if said_by == "gauge":
+        assert {m.name: m.value for m in ran["registry"].metrics()
+                if m.name in ("gated_norm/mosaic_calls",
+                              "ssd/mosaic_calls")} == {
+                    "gated_norm/mosaic_calls": 0, "ssd/mosaic_calls": 0}
+    if said_by == "result":
+        assert ran["result"]["gated_norm_mosaic_calls"] == 0
+        assert ran["result"]["ssd_mosaic_calls"] == 0
+    if said_by == "report":
+        assert ("(0 under mixer/mamba/ssd), ssd/groups 2, "
+                "gated_norm/mosaic_calls 0") in report
+
+
+def test_a_model_without_a_mamba_block_reports_no_gated_norm_calls(
+        olmo_hybrid_run):
+    assert olmo_hybrid_run["result"]["gated_norm_mosaic_calls"] is None
+    assert not [m for m in olmo_hybrid_run["registry"].metrics()
+                if m.name.startswith("gated_norm/")]
+
+
+def test_the_norms_calls_and_the_scans_are_counted_apart():
+    """A step's lines in the cell's own form, shortened: three calls of
+    the norm's kernels (forward, the forward made again, backward) and the
+    scan's two are each their own scope's."""
+    from hetu_galvatron_tpu.observability.trace_analysis import (
+        GATED_NORM_SCOPE,
+        SSD_SCOPE,
+        step_hlo,
+    )
+
+    call = 'custom-call(%a), custom_call_target="tpu_custom_call"'
+    remat = "jit(step)/transpose(jvp())/checkpoint"
+    found = step_hlo(f"""ENTRY %main (a: f32[8]) -> f32[8] {{
+  %ssd_scan_fwd.3 = f32[8]{{0}} {call}, metadata={{op_name="jit(step)/jvp(mixer/mamba)/ssd/ssd_scan_fwd/pallas_call"}}
+  %gated_norm_fwd.1 = bf16[8]{{0}} {call}, metadata={{op_name="jit(step)/jvp(mixer/mamba)/gated_norm/gated_norm_fwd/pallas_call"}}
+  %gated_norm_fwd.2 = bf16[8]{{0}} {call}, metadata={{op_name="{remat}/rematted_computation/mixer/mamba/gated_norm/gated_norm_fwd/pallas_call"}}
+  %gated_norm_bwd.1 = f32[8]{{0}} {call}, metadata={{op_name="{remat}/mixer/mamba/gated_norm/gated_norm_bwd/pallas_call"}}
+  %ssd_scan_bwd.4 = f32[8]{{0}} {call}, metadata={{op_name="{remat}/mixer/mamba/ssd/mixer/mamba/ssd/ssd_scan_bwd/pallas_call"}}
+}}
+""")
+    under = lambda scope: sorted(  # noqa: E731
+        n for n in found["scopes"][scope] if n in found["mosaic_calls"])
+    assert under(SSD_SCOPE) == ["ssd_scan_bwd.4", "ssd_scan_fwd.3"]
+    assert under(GATED_NORM_SCOPE) == [
+        "gated_norm_bwd.1", "gated_norm_fwd.1", "gated_norm_fwd.2"]
+    assert [found["map"]["instructions"][n][1]
+            for n in under(GATED_NORM_SCOPE)] == [
+                "backward", "forward", "recompute"]
+
+
 def _traced_by_hand(names, known_only=True, each_ms=0.1):
     """A steady window of two periods in which every named instruction ran
     ``each_ms`` a step, one after the other: what ``xplane.reduce_device``

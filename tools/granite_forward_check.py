@@ -108,6 +108,7 @@ def main() -> int:
     )
     from hetu_galvatron_tpu.ops.pallas.conv import causal_conv
     from hetu_galvatron_tpu.ops.pallas.flash_attention import flash_sdpa
+    from hetu_galvatron_tpu.ops.pallas.gated_norm import gated_norm
     from hetu_galvatron_tpu.ops.pallas.ssd import ssd_scan
     from hetu_galvatron_tpu.utils.hf_config_adapter import resolve_model_config
 
@@ -169,9 +170,10 @@ def main() -> int:
     params = jax.jit(lambda k: init_causal_lm(k, cfg)[0])(
         jax.random.key(a.seed))
     on_tpu = dev.platform == "tpu"
-    # on a TPU what the cell trains with: the flash core, and the scan's
-    # and the convolution's kernels in the mamba blocks
-    mamba_ops = (M.LayerOps(ssd=ssd_scan, conv=causal_conv) if on_tpu
+    # on a TPU what the cell trains with: the flash core, and the scan's,
+    # the convolution's and the gated norm's kernels in the mamba blocks
+    mamba_ops = (M.LayerOps(ssd=ssd_scan, conv=causal_conv,
+                            gated_norm=gated_norm) if on_tpu
                  else M.LayerOps())
     sdpa = ({i: mamba_ops if mixer == "mamba"
              else M.LayerOps(sdpa=flash_sdpa)
@@ -190,7 +192,8 @@ def main() -> int:
         p = {**params["layers"][mamba_at]["mamba"], **leaves}
         return jax.jit(lambda p, x: M.apply_mamba2(
             p, x.astype(jnp.bfloat16), cfg, compute_dtype=jnp.bfloat16,
-            ssd_fn=mamba_ops.ssd, conv_fn=mamba_ops.conv))(p, mamba_in)
+            ssd_fn=mamba_ops.ssd, conv_fn=mamba_ops.conv,
+            norm_fn=mamba_ops.gated_norm))(p, mamba_in)
 
     def attention_operator(run_cfg):
         rope = None
