@@ -29,9 +29,10 @@ jax.config.update("jax_enable_compilation_cache", False)
 # what is costly once a module (an ``lru_cache``d pair of sides, a
 # module-scoped model, a compiled interpret-mode kernel), so
 # ``pytest_xdist_make_scheduler`` below keeps a module on one worker whatever
-# ``--dist`` says: the longest file is then the floor of the wall time (no
-# file over 240 s alone; PERF.md, "What tier-1 costs", has the budget a new
-# file may take). Nobody runs the slow tier as a matter of
+# ``--dist`` says. What tier-1 may cost is the SUM's: at most 5,000
+# worker-seconds (the junit XML's ``time`` summed) and 950 s of wall here;
+# PERF.md, "What tier-1 costs", has the rule for a PR that adds to it.
+# Nobody runs the slow tier as a matter of
 # course, so what guards a path one of the benchmark's cells executes (the
 # dropless MoE layer, per-layer remat, GPT-2's shape, tp2 x dp2 ZeRO-3 SPMD
 # parity, the flash kernels, the fused cross-entropy, XLA's full-remat
@@ -39,7 +40,8 @@ jax.config.update("jax_enable_compilation_cache", False)
 # trajectory tests of features no cell turns on, and drills that train real
 # checkpoints; a test that alone takes over 60 s on six workers stays here
 # too. Marking is centralized in this hook so test files stay unannotated
-# (a few carry their own ``@pytest.mark.slow``).
+# (a few carry their own ``@pytest.mark.slow``, or mark one parameter of a
+# function that stays where it is listed).
 _SLOW_TESTS = {
     # moe / t5 / bert parity
     "test_expert_parallel_matches_single_device",
@@ -86,9 +88,9 @@ _SLOW_TESTS = {
     "test_train_dist_rampup_cli",
     "test_train_dist_rampup_pipeline_cli",
     "test_train_dist_cli_pipeline",
-    # tp-overlap secondary legs (the acceptance drill
-    # test_trajectory_drill_searched_tp2_dp2_plan + recompile pinning stay
-    # fast-tier)
+    # tp-overlap secondary legs, and since ISSUE 76 the acceptance drill
+    # (recompile pinning stays fast-tier)
+    "test_trajectory_drill_searched_tp2_dp2_plan",
     "test_train_dist_cli_tp_overlap",
     "test_tp_overlap_cli_fallback_reasons",
     "test_host_pipeline_engine_tp_overlap_parity",
@@ -154,6 +156,48 @@ _SLOW_TESTS = {
     "test_t5_decode_eos_masking_and_sampling_shapes",
     "test_cross_attention_biases_honored",
     "test_alpha_beta_algos_roundtrip",
+    # moved out by ISSUE 76, when the sum stood over the limit: features no
+    # cell turns on, the cheapest case of each that shows it wired kept in
+    # tier-1. Pipeline parallelism (pp > 1), the host engine's plans and
+    # the compiled engine's parity with it (tier-1 keeps pp 2 under both
+    # schedules, the lazy jits, the untied pure-dp parity, the one compile
+    # and the rotation's collective-permute)
+    "test_pipeline_tied_embeddings",
+    "test_uneven_pp_division",
+    "test_interleaved_virtual_stages_match_single_device",
+    "test_interleaved_tied_embeddings",
+    "test_compiled_matches_host_engine_three_steps",
+    "test_compiled_dropout_replays_host_masks",
+    "test_compiled_kernels_acceptance_drill",
+    "test_compiled_cp_plan_matches_host",
+    "test_compiled_zigzag_cp_plan_matches_host",
+    "test_compiled_ramp_caches_one_program_per_chunk_count",
+    # context parallelism's zigzag layout (its units stay)
+    "test_cp_zigzag_loss_matches_sequence_order",
+    "test_cp_zigzag_e2e_cli_with_packed_docs",
+    # serving, which the benchmark measures no path of (cancellation, the
+    # background thread's stream, eviction, the model draft and the
+    # shape-drift refusal stay)
+    "test_continuous_batching_drill_mesh8",
+    "test_single_device_parity_ragged_and_zero_recompiles",
+    "test_eos_retirement_matches_offline_and_recycles",
+    "test_sampling_is_batch_composition_invariant",
+    "test_prefix_hits_bit_identical_and_skip_prefill",
+    "test_swap_weights_flips_to_new_checkpoint_without_recompiles",
+    "test_spec_streams_bit_identical_with_accepts",
+    # dropout (the SPMD step's determinism stays), generation (the jitted
+    # loop and its EOS masks stay), T5 (its CLI smoke stays)
+    "test_forward_eval_identity_and_train_stochasticity",
+    "test_train_step_rng_in_batch_and_chunks",
+    "test_dropout_grads_flow_and_masked_positions_get_zero_grad",
+    "test_encdec_dropout_paths",
+    "test_generate_pad_id_masks_retired_rows",
+    "test_spmd_generate_matches_single_device",
+    "test_generate_never_samples_vocab_padding",
+    "test_generate_sampling_shapes_and_topk",
+    "test_decoder_causal_encoder_bidirectional",
+    "test_t5_flash_attention_overrides",
+    "test_t5_cross_attention_dropout_with_capable_kernel",
 }
 
 
@@ -248,12 +292,15 @@ def forward_and_loss_as_before_pr38(grouped_matmul_as_before_pr38):
         batch = jax.tree.map(jnp.asarray, make_batch(
             np.random.RandomState(3).randint(0, 64, (2, 17))))
 
+        # (one program a side: op by op a stack is thousands of compiles)
         def run():
-            logits = forward_causal_lm(params, batch["tokens"], cfg,
-                                       compute_dtype=dt)
-            loss, grads = jax.value_and_grad(lambda p: causal_lm_loss(
-                p, batch, cfg, compute_dtype=dt))(params)
-            return logits, loss, jax.tree.leaves(grads)
+            def side(params):
+                logits = forward_causal_lm(params, batch["tokens"], cfg,
+                                           compute_dtype=dt)
+                loss, grads = jax.value_and_grad(lambda p: causal_lm_loss(
+                    p, batch, cfg, compute_dtype=dt))(params)
+                return logits, loss, jax.tree.leaves(grads)
+            return jax.jit(side)(params)
 
         logits, loss, grads = run()
         grouped_matmul_as_before_pr38()

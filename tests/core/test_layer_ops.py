@@ -228,8 +228,10 @@ def test_a_block_of_every_kind_is_the_parents_block(kind):
     p, axes = init(jax.random.key(3), cfg, mixer)
     assert jax.tree.structure(p) == jax.tree.structure(
         axes, is_leaf=lambda a: isinstance(a, tuple))
-    got = apply(p, x, cfg, rope=None, compute_dtype=jnp.float32,
-                dropout_rng=jax.random.key(11), mixer=mixer)
+    # (one program: op by op a block is hundreds of compiles)
+    got = jax.jit(lambda p, x, key: apply(
+        p, x, cfg, rope=None, compute_dtype=jnp.float32, dropout_rng=key,
+        mixer=mixer))(p, x, jax.random.key(11))
     y, aux = (got[0], float(got[1])) if ff == "experts" else (got, 0.0)
     want = PARENTS[kind]
     leaves = jax.tree.leaves(p)

@@ -3,7 +3,10 @@ reproduce the single-device loss and gradients bit-for-tolerance (the
 reference's tier-2 tests compare loss trajectories vs HF across tp/sp/fsdp/
 hybrid configs — tests/core/test_tp.py, test_fsdp.py, test_hybrid.py)."""
 
+import functools
+
 import numpy as np
+import optax
 import pytest
 
 import jax
@@ -52,18 +55,43 @@ def _batch(bsz=8, seed=0):
     return jax.tree.map(jnp.asarray, make_batch(data))
 
 
+@functools.lru_cache(maxsize=None)
+def _reference_program(dtype):
+    """``(params, optimizer state, batch) -> (loss, params, state)`` on one
+    device in ``dtype``, as ONE program (op by op the model and Adam are
+    some forty seconds of compiles, which the file's first case paid)."""
+    tx = make_optimizer(TRAIN)
+
+    def step(params, opt, batch):
+        loss, grads = jax.value_and_grad(lambda p: causal_lm_loss(
+            p, batch, CFG, compute_dtype=dtype))(params)
+        upd, opt = tx.update(grads, opt, params)
+        return loss, optax.apply_updates(params, upd), opt
+    return jax.jit(step)
+
+
 def _reference_step(params, batch, dtype=jnp.float32):
     """Single-device train step in ``dtype`` used as ground truth."""
-    tx = make_optimizer(TRAIN)
-    loss_fn = lambda p: causal_lm_loss(p, batch, CFG, compute_dtype=dtype)
-    loss, grads = jax.value_and_grad(loss_fn)(params)
-    import optax
-    upd, _ = tx.update(grads, tx.init(params), params)
-    return loss, optax.apply_updates(params, upd)
+    loss, new_params, _ = _reference_program(jnp.dtype(dtype))(
+        params, make_optimizer(TRAIN).init(params), batch)
+    return loss, new_params
+
+
+_BUILT = {}
 
 
 def _build_spmd(args, params, axes, cpu_devices, dtype=jnp.float32):
-    """(step, sharded params, optimizer state, batch sharding) of a plan."""
+    """(step, sharded params, optimizer state, batch sharding) of a plan on
+    the file's parameters (every case's: ``init_causal_lm`` at key 0); the
+    cases of one plan and dtype share its one compile (nothing is donated,
+    so the placed state is theirs to read too)."""
+    plan = (args.parallel.model_dump_json(), jnp.dtype(dtype).name)
+    if plan not in _BUILT:
+        _BUILT[plan] = _build(args, params, axes, cpu_devices, dtype)
+    return _BUILT[plan]
+
+
+def _build(args, params, axes, cpu_devices, dtype):
     world = 8
     hpc = get_hybrid_parallel_config(args, world)
     mesh = build_mesh(world, hpc.pp_deg, devices=cpu_devices)
@@ -280,24 +308,19 @@ def test_multi_step_trajectory_matches_single_device(dtype, cpu_devices):
     each step: the losses are 6e-5, 5e-5, 4e-5, 1.6e-3 and 3.1e-3 apart
     (held within 1e-2), the parameters a mean 1.2e-3 (held under 4e-3) and
     at most 0.046 (held to 5 steps of 2 lr)."""
-    import optax
-
     params, axes = init_causal_lm(jax.random.key(0), CFG)
     args = _args(global_tp_deg=2, default_dp_type="zero3",
                  global_train_batch_size=8)
     step, sp, opt, batch_shd = _build_spmd(args, params, axes, cpu_devices,
                                            dtype)
-    tx = make_optimizer(TRAIN)
+    ref_step = _reference_program(jnp.dtype(dtype))
     ref_p = params
-    ref_o = tx.init(params)
-    ref_loss_fn = lambda p, b: causal_lm_loss(p, b, CFG, compute_dtype=dtype)
+    ref_o = make_optimizer(TRAIN).init(params)
     f32 = dtype == jnp.float32
 
     for it in range(5):
         batch = _batch(seed=it)
-        loss, grads = jax.value_and_grad(ref_loss_fn)(ref_p, batch)
-        upd, ref_o = tx.update(grads, ref_o, ref_p)
-        ref_p = optax.apply_updates(ref_p, upd)
+        loss, ref_p, ref_o = ref_step(ref_p, ref_o, batch)
         sp, opt, metrics = step(sp, opt, jax.device_put(batch, batch_shd))
         assert abs(float(metrics["loss"]) - float(loss)) < (
             5e-5 if f32 else 1e-2), \

@@ -39,12 +39,19 @@ def _reference_step(params, batch, cfg=CFG, train=TRAIN):
     from hetu_galvatron_tpu.runtime.optimizer import make_optimizer
     import optax
 
-    jb = jax.tree.map(jnp.asarray, batch)
     tx = make_optimizer(train)
-    loss_fn = lambda p: causal_lm_loss(p, jb, cfg, compute_dtype=jnp.float32)
-    loss, grads = jax.value_and_grad(loss_fn)(params)
-    upd, _ = tx.update(grads, tx.init(params), params)
-    return float(loss), optax.apply_updates(params, upd)
+
+    # (one program: op by op the model and Adam are some forty seconds of
+    # compiles, which the file's first case paid)
+    @jax.jit
+    def step(params, jb):
+        loss, grads = jax.value_and_grad(lambda p: causal_lm_loss(
+            p, jb, cfg, compute_dtype=jnp.float32))(params)
+        upd, _ = tx.update(grads, tx.init(params), params)
+        return loss, optax.apply_updates(params, upd)
+
+    loss, new_params = step(params, jax.tree.map(jnp.asarray, batch))
+    return float(loss), new_params
 
 
 def _pipeline_step(cfg, params, axes, batch, cpu_devices, **pkw):
@@ -60,13 +67,16 @@ def _pipeline_step(cfg, params, axes, batch, cpu_devices, **pkw):
     return metrics, eng.merge_params(new_sp)
 
 
+# pp > 1 is on in no cell of the benchmark: one case of each schedule is
+# tier-1's, the other plans the slow tier's
+_slow = lambda **pkw: pytest.param(pkw, marks=pytest.mark.slow)  # noqa: E731
 CASES = [
     dict(pp_deg=2, pipeline_type="gpipe", chunks=2),
     dict(pp_deg=2, pipeline_type="pipedream_flush", chunks=4),
-    dict(pp_deg=4, pipeline_type="gpipe", chunks=4),
-    dict(pp_deg=4, pipeline_type="pipedream_flush", chunks=2),
-    dict(pp_deg=2, pipeline_type="gpipe", chunks=2, global_tp_deg=2),
-    dict(pp_deg=2, pipeline_type="pipedream_flush", chunks=2, sdp=1),
+    _slow(pp_deg=4, pipeline_type="gpipe", chunks=4),
+    _slow(pp_deg=4, pipeline_type="pipedream_flush", chunks=2),
+    _slow(pp_deg=2, pipeline_type="gpipe", chunks=2, global_tp_deg=2),
+    _slow(pp_deg=2, pipeline_type="pipedream_flush", chunks=2, sdp=1),
 ]
 
 

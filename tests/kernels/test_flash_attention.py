@@ -407,8 +407,12 @@ def test_keep_mask_tile_invariance_property():
 
 
 def _fwd_and_grads(fn, q, k, v, do):
-    out, vjp = jax.vjp(fn, q, k, v)
-    return (out,) + tuple(vjp(do))
+    """``fn``'s output and its three gradients as ONE program (op by op the
+    dense side is some fifty compiles a case)."""
+    def side(q, k, v, do):
+        out, vjp = jax.vjp(fn, q, k, v)
+        return (out,) + tuple(vjp(do))
+    return jax.jit(side)(q, k, v, do)
 
 
 def _assert_f32_parity(got, ref):
@@ -652,13 +656,15 @@ def test_flash_with_a_value_width_of_its_own(case):
     segs = ((jnp.arange(S)[None, :] // 200).astype(jnp.int32).repeat(B, 0)
             if seg else None)
     kw = dict(causal=causal, segment_ids=segs, scale=scale)
-    want, want_vjp = jax.vjp(lambda a, b, c: xla_sdpa(a, b, c, **kw), q, k, v)
-    got, got_vjp = jax.vjp(
-        lambda a, b, c: flash_sdpa(a, b, c, interpret=True, **kw), q, k, v)
+    want, *want_grads = _fwd_and_grads(
+        lambda a, b, c: xla_sdpa(a, b, c, **kw), q, k, v, do)
+    got, *got_grads = _fwd_and_grads(
+        lambda a, b, c: flash_sdpa(a, b, c, interpret=True, **kw), q, k, v,
+        do)
     assert got.shape == (B, S, N, Dv)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
-    for name, g, w in zip(("dq", "dk", "dv"), got_vjp(do), want_vjp(do)):
+    for name, g, w in zip(("dq", "dk", "dv"), got_grads, want_grads):
         assert g.shape == w.shape, name
         np.testing.assert_allclose(np.asarray(g), np.asarray(w),
                                    rtol=2e-4, atol=2e-4, err_msg=name)

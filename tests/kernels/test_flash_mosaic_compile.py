@@ -12,6 +12,7 @@ All such compiles live in THIS file and describe the topology inside a
 fixture: one process may hold libtpu at a time, and a module that touched it
 while being imported would do so in every xdist worker."""
 
+import functools
 import re
 
 import jax
@@ -479,61 +480,101 @@ def test_a_rematted_block_holds_one_forward_kernel(one_chip, wrapper,
     ``flash_attention_fwd`` (three Mosaic calls: what
     ``benchmark/check.py`` asks of a layer), none of it in the recompute
     phase; under plain ``jax.checkpoint`` there are two, and
-    ``trace_analysis.cores_recomputed`` counts the second."""
+    ``trace_analysis.cores_recomputed`` counts the second (the control,
+    at two heads: the count is the wrapper's, not the widths')."""
     from hetu_galvatron_tpu.core.args_schema import ModelArgs
-    from hetu_galvatron_tpu.models import modules as M
     from hetu_galvatron_tpu.observability.trace_analysis import (
         FLASH_FWD_CALL,
         cores_recomputed,
-        step_hlo,
     )
 
-    B, S, H, N = 2, 1024, 1600, 25
+    B, S, H, N = (2, 1024, 1600, 25) if wrapper == "remat" else (
+        1, 1024, 128, 2)
     cfg = ModelArgs(hidden_size=H, num_hidden_layers=1,
                     num_attention_heads=N, vocab_size=128,
                     max_position_embeddings=S, seq_length=S)
-    shapes = jax.eval_shape(
-        lambda k: M.init_decoder_layer(k, cfg)[0], jax.random.key(0))
-    params = jax.tree.map(
-        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
-        shapes)
-    x = jax.ShapeDtypeStruct((B, S, H), jnp.bfloat16, sharding=one_chip)
-
-    def block(p, h):
-        return M.apply_decoder_layer(p, h, cfg,
-                                     ops=M.LayerOps(sdpa=flash_sdpa))
-
-    wrapped = M.remat(block, cfg) if wrapper == "remat" else jax.checkpoint(
-        block)
-    # (a loss whose gradient needs the block's output: under a plain sum
-    # the first forward pass is dead code and only the recomputed one stays)
-    compiled = jax.jit(jax.grad(
-        lambda p, h: jnp.sum(wrapped(p, h).astype(jnp.float32) ** 2),
-        argnums=(0, 1))).lower(params, x).compile()
-    found = step_hlo(compiled.as_text())
+    _, found = _block_step(one_chip, cfg, "full_attention", wrapper, B,
+                           sdpa=flash_sdpa)
     fwd = [n for n in found["mosaic_calls"] if n.startswith(FLASH_FWD_CALL)]
     assert len(fwd) == forwards, sorted(found["mosaic_calls"])
     assert found["mosaic_custom_calls"] == forwards + 2
     assert cores_recomputed(found) == recomputed
 
 
-# a recurrent block at its cell's widths: mixer kind, the field of
-# ``LayerOps`` its scan goes in, the scan, its forward kernel, the model's
-# sizes (``kimilin_c1_b1_s8k``, ``olmohybrid_c1_b1``, ``granite4h_c1_b1``;
-# one sequence of 8192)
+def _block_step(one_chip, cfg, kind, wrapper, B, dtype=jnp.bfloat16, **ops):
+    """(optimized HLO, ``step_hlo`` of it) of the gradient, to the
+    parameters and to the input, of one block of ``cfg`` handed the kernels
+    ``ops`` under ``modules.remat`` or plain ``jax.checkpoint``."""
+    from hetu_galvatron_tpu.models import modules as M
+    from hetu_galvatron_tpu.observability.trace_analysis import step_hlo
+
+    shapes = jax.eval_shape(
+        lambda k: M.init_decoder_layer(k, cfg, mixer=kind)[0],
+        jax.random.key(0))
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        shapes)
+    x = jax.ShapeDtypeStruct((B, cfg.seq_length, cfg.hidden_size), dtype,
+                             sharding=one_chip)
+
+    def block(p, h):
+        return M.apply_decoder_layer(p, h, cfg, ops=M.LayerOps(**ops),
+                                     mixer=kind, compute_dtype=dtype)
+
+    wrapped = M.remat(block, cfg) if wrapper == "remat" else jax.checkpoint(
+        block)
+    # (a loss whose gradient needs the block's output: under a plain sum
+    # the first forward pass is dead code and only the recomputed one stays)
+    text = jax.jit(jax.grad(
+        lambda p, h: jnp.sum(wrapped(p, h).astype(jnp.float32) ** 2),
+        argnums=(0, 1))).lower(params, x).compile().as_text()
+    return text, step_hlo(text)
+
+
+# a recurrent block at its cell's widths: the field of ``LayerOps`` its scan
+# goes in, the scan, its forward kernel, the model's sizes
+# (``kimilin_c1_b1_s8k``, ``olmohybrid_c1_b1``, ``granite4h_c1_b1``; one
+# sequence of 8192), and the control's: the kinds' own head widths and chunks
+# at two heads (eight of mamba's: a grid step) over 1024 positions
 _SCAN_BLOCKS = {
     "kda": ("kda", kda.kda_scan, "kda_scan_fwd", dict(
         hidden_size=2304, ffn_hidden_size=9216, kda_num_heads=32,
-        kda_head_dim=128, kda_chunk_size=64)),
+        kda_head_dim=128, kda_chunk_size=64), dict(
+        hidden_size=256, ffn_hidden_size=512, kda_num_heads=2)),
     "linear_attention": ("gdn", gdn.gdn_scan, "gdn_scan_fwd", dict(
         hidden_size=3840, ffn_hidden_size=11008, linear_num_key_heads=30,
         linear_num_value_heads=30, linear_key_head_dim=96,
         linear_value_head_dim=192, linear_chunk_size=64,
-        linear_allow_neg_eigval=True)),
+        linear_allow_neg_eigval=True), dict(
+        hidden_size=384, ffn_hidden_size=512, linear_num_key_heads=2,
+        linear_num_value_heads=2)),
     "mamba": ("ssd", ssd.ssd_scan, "ssd_scan_fwd", dict(
         hidden_size=2048, ffn_hidden_size=8192, mamba_n_heads=64,
-        mamba_d_head=64, mamba_d_state=128, mamba_chunk_size=256)),
+        mamba_d_head=64, mamba_d_state=128, mamba_chunk_size=256), dict(
+        hidden_size=256, ffn_hidden_size=512, mamba_n_heads=8)),
 }
+
+
+@functools.lru_cache(maxsize=None)
+def _scan_block_step(one_chip, kind, wrapper, B, S, dtype, groups,
+                     norm_kernels, control):
+    """``_block_step`` of a recurrent block at its cell's widths (the
+    control's where ``control``) handed its scan's and the convolution's
+    kernels and, where ``norm_kernels``, the gated norm's; what two cases
+    both read (a mamba block of one group under ``modules.remat``) is
+    compiled once."""
+    from hetu_galvatron_tpu.core.args_schema import ModelArgs
+
+    field, scan, _, sizes, small = _SCAN_BLOCKS[kind]
+    cfg = ModelArgs(num_hidden_layers=1, num_attention_heads=32,
+                    vocab_size=128, max_position_embeddings=S, seq_length=S,
+                    hidden_act="swiglu", normalization="rmsnorm",
+                    add_bias_linear=False, mamba_n_groups=groups,
+                    **{**sizes, **(small if control else {})})
+    return _block_step(
+        one_chip, cfg, kind, wrapper, B, dtype, conv=conv.causal_conv,
+        gated_norm=gated_norm.gated_norm if norm_kernels else None,
+        **{field: scan})
 
 
 @pytest.mark.parametrize("wrapper,forwards,recomputed", [
@@ -547,41 +588,19 @@ def test_a_rematted_recurrent_block_holds_one_scan_forward(
     none of it in the
     recompute phase (the convolution's forward names nothing and is there
     twice); under plain ``jax.checkpoint`` there are two, and
-    ``trace_analysis.scans_recomputed`` counts the second."""
-    from hetu_galvatron_tpu.core.args_schema import ModelArgs
-    from hetu_galvatron_tpu.models import modules as M
+    ``trace_analysis.scans_recomputed`` counts the second (the control, a
+    few heads over 1024 positions: the count is the wrapper's)."""
     from hetu_galvatron_tpu.observability.trace_analysis import (
         conv_kernel_calls,
         cores_recomputed,
         scans_recomputed,
-        step_hlo,
     )
 
-    field, scan, forward, sizes = _SCAN_BLOCKS[kind]
-    B, S = 1, 8192
-    cfg = ModelArgs(num_hidden_layers=1, num_attention_heads=32,
-                    vocab_size=128, max_position_embeddings=S, seq_length=S,
-                    hidden_act="swiglu", normalization="rmsnorm",
-                    add_bias_linear=False, **sizes)
-    shapes = jax.eval_shape(
-        lambda k: M.init_decoder_layer(k, cfg, mixer=kind)[0],
-        jax.random.key(0))
-    params = jax.tree.map(
-        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
-        shapes)
-    x = jax.ShapeDtypeStruct((B, S, cfg.hidden_size), jnp.bfloat16,
-                             sharding=one_chip)
-    ops = M.LayerOps(conv=conv.causal_conv, **{field: scan})
-
-    def block(p, h):
-        return M.apply_decoder_layer(p, h, cfg, ops=ops, mixer=kind)
-
-    wrapped = M.remat(block, cfg) if wrapper == "remat" else jax.checkpoint(
-        block)
-    compiled = jax.jit(jax.grad(
-        lambda p, h: jnp.sum(wrapped(p, h).astype(jnp.float32) ** 2),
-        argnums=(0, 1))).lower(params, x).compile()
-    found = step_hlo(compiled.as_text())
+    forward = _SCAN_BLOCKS[kind][2]
+    control = wrapper != "remat"
+    _, found = _scan_block_step(one_chip, kind, wrapper, 1,
+                                1024 if control else 8192, jnp.bfloat16, 1,
+                                False, control)
     fwd = [n for n in found["mosaic_calls"] if n.startswith(forward)]
     assert len(fwd) == forwards, sorted(found["mosaic_calls"])
     # the scan's backward, and the convolution's three either way
@@ -613,39 +632,14 @@ def test_a_mamba_blocks_gated_norm_is_one_pass_a_phase(one_chip, case, norm):
     by its own ``op_name`` or by the map's ``owners``. The control: in
     ``jax.numpy`` several groups are a minor dimension of their own, and the compiled
     block holds such relayouts (one group holds none either way)."""
-    from hetu_galvatron_tpu.core.args_schema import ModelArgs
-    from hetu_galvatron_tpu.models import modules as M
     from hetu_galvatron_tpu.observability.trace_analysis import (
         GATED_NORM_SCOPE,
         SSD_SCOPE,
-        step_hlo,
     )
 
     G, B, S, dtype = _GATED_NORM_BLOCKS[case]
-    cfg = ModelArgs(num_hidden_layers=1, num_attention_heads=32,
-                    vocab_size=128, max_position_embeddings=S, seq_length=S,
-                    hidden_act="swiglu", normalization="rmsnorm",
-                    add_bias_linear=False, hidden_size=2048,
-                    ffn_hidden_size=8192, mamba_n_heads=64, mamba_d_head=64,
-                    mamba_d_state=128, mamba_chunk_size=256,
-                    mamba_n_groups=G)
-    shapes = jax.eval_shape(
-        lambda k: M.init_decoder_layer(k, cfg, mixer="mamba")[0],
-        jax.random.key(0))
-    params = jax.tree.map(
-        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
-        shapes)
-    x = jax.ShapeDtypeStruct((B, S, cfg.hidden_size), dtype,
-                             sharding=one_chip)
-    ops = M.LayerOps(
-        conv=conv.causal_conv, ssd=ssd.ssd_scan,
-        gated_norm=gated_norm.gated_norm if norm == "kernels" else None)
-    wrapped = M.remat(lambda p, h: M.apply_decoder_layer(
-        p, h, cfg, ops=ops, mixer="mamba", compute_dtype=dtype), cfg)
-    text = jax.jit(jax.grad(
-        lambda p, h: jnp.sum(wrapped(p, h).astype(jnp.float32) ** 2),
-        argnums=(0, 1))).lower(params, x).compile().as_text()
-    found = step_hlo(text)
+    text, found = _scan_block_step(one_chip, "mamba", "remat", B, S, dtype,
+                                   G, norm == "kernels", False)
     placed, owners = found["map"]["instructions"], found["map"]["owners"]
     calls = [n for n in found["scopes"][GATED_NORM_SCOPE]
              if n in found["mosaic_calls"]]

@@ -8,6 +8,8 @@ chunk does not divide and over several chunks, ``beta`` from the middle of
 its range to hard against 2, decays from next to none to a state gone within
 a token. CPU, float32 at ``highest``."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -57,12 +59,19 @@ def _inputs(strength, push, seed=0, seq=SEQ):
     return q, k, v, g, beta
 
 
-def _values_and_gradients(fn, args):
-    weight = jax.random.normal(jax.random.key(9), args[2].shape)
-    out = fn(*args)
-    grads = jax.grad(lambda *a: jnp.sum(fn(*a) * weight),
-                     argnums=(0, 1, 2, 3, 4))(*args)
-    return (out,) + grads
+@functools.lru_cache(maxsize=None)
+def _values_and_gradients(chunk):
+    """The program of a side's value and five gradients, the recurrence's
+    where ``chunk`` is None: the cases that differ in their data alone (the
+    decays, the betas) are one compile of it."""
+    fn = _recurrence() if chunk is None else _chunked(chunk)
+
+    def side(*args):
+        weight = jax.random.normal(jax.random.key(9), args[2].shape)
+        return (fn(*args),) + jax.grad(
+            lambda *a: jnp.sum(fn(*a) * weight),
+            argnums=(0, 1, 2, 3, 4))(*args)
+    return jax.jit(side)
 
 
 @pytest.mark.parametrize("chunk", [32, 64])
@@ -78,8 +87,8 @@ def test_chunked_is_the_recurrence(decay, beta, chunk):
     args = _inputs(DECAYS[decay], BETAS[beta])
     if beta == "near_two":
         assert float(jnp.mean(args[4] > 1.99)) > 0.3
-    want = jax.jit(lambda *a: _values_and_gradients(_recurrence(), a))(*args)
-    got = jax.jit(lambda *a: _values_and_gradients(_chunked(chunk), a))(*args)
+    want = _values_and_gradients(None)(*args)
+    got = _values_and_gradients(chunk)(*args)
     for name, a, b in zip(NAMES, got, want):
         scale = float(jnp.max(jnp.abs(b)))
         np.testing.assert_allclose(a, b, rtol=1e-3, atol=3e-4 * scale + 1e-9,

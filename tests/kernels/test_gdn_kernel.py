@@ -77,10 +77,15 @@ def _inputs(rows, seq, heads, strength, seed=0):
     return q, k, v, g, beta
 
 
-def _values_and_gradients(fn, args):
-    out, vjp = jax.vjp(fn, *args)
-    grads = vjp(jax.random.normal(jax.random.key(9), out.shape, out.dtype))
-    return tuple(np.asarray(t, np.float32) for t in (out,) + grads)
+def _values_and_gradients(fn):
+    """``fn``'s value and its gradients to its arguments as ONE program (a
+    compile a side, not one an operation), each in float32."""
+    def side(*a):
+        out, vjp = jax.vjp(fn, *a)
+        return (out,) + vjp(jax.random.normal(jax.random.key(9), out.shape,
+                                              out.dtype))
+    program = jax.jit(side)
+    return lambda *a: tuple(np.asarray(t, np.float32) for t in program(*a))
 
 
 def _kernels(dtype, chunk=CHUNK):
@@ -90,19 +95,26 @@ def _kernels(dtype, chunk=CHUNK):
 
 
 @functools.lru_cache(maxsize=None)
+def _side(name, dtype, chunk):
+    """A side's program: the cases that differ in their data alone (the
+    decays) are one compile of it."""
+    dtype = jnp.dtype(dtype)
+    return _values_and_gradients({
+        "kernel": _kernels(dtype, chunk),
+        "chunked": lambda *a: M.gated_delta_chunked(*a, chunk, dtype),
+        "sequential": _recurrence()}[name])
+
+
+@functools.lru_cache(maxsize=None)
 def _sides(case):
     """(kernels, chunked in jax.numpy, sequential in float32) on one set of
     inputs, each as (o, dq, dk, dv, dg, dbeta) in float32."""
     rows, seq, heads, dtype, decay, chunk = CASES[case]
-    dtype = jnp.dtype(dtype)
     args = _inputs(rows, seq, heads, DECAYS[decay])
     assert gdn.tile_plan(chunk, heads, DK, DV) is not None
     with jax.default_matmul_precision("highest"):
-        return {
-            "kernel": _values_and_gradients(_kernels(dtype, chunk), args),
-            "chunked": _values_and_gradients(
-                lambda *a: M.gated_delta_chunked(*a, chunk, dtype), args),
-            "sequential": _values_and_gradients(_recurrence(), args)}
+        return {name: _side(name, dtype, chunk)(*args)
+                for name in ("kernel", "chunked", "sequential")}
 
 
 def _apart(a, b):
@@ -174,12 +186,12 @@ def test_a_bf16_state_or_inverse_is_told_from_the_kernels(case, monkeypatch):
     rows, seq, heads, _, decay, _ = CASES[on]
     args = _inputs(rows, seq, heads, DECAYS[decay])
     if case == "state_carried_in_bf16":
-        rounded = _values_and_gradients(_recurrence_with_a_bf16_state, args)
+        rounded = _values_and_gradients(_recurrence_with_a_bf16_state)(*args)
     else:
         monkeypatch.setattr(M, "unit_lower_inverse",
                             _inverse_of_bf16_operands)
         rounded = _values_and_gradients(
-            lambda *a: M.gated_delta_chunked(*a, CHUNK, jnp.float32), args)
+            lambda *a: M.gated_delta_chunked(*a, CHUNK, jnp.float32))(*args)
     far = max(_apart(g, r) for g, r in zip(kernel, rounded))
     assert far > 100 * near and far > 3e-4, (case, near, far)
 
@@ -237,8 +249,10 @@ def test_shapes_that_fit_no_tile_take_the_jax_numpy_form(
         called.append(a[0].shape)
         return gdn.gdn_scan(*a, interpret=True)
 
-    got = M.apply_gated_delta(p, x, cfg, jnp.float32, gdn_fn=kernels)
-    want = M.apply_gated_delta(p, x, cfg, jnp.float32)
+    # (one program a side; where the kernels are not called the two sides
+    # trace to one jaxpr)
+    got, want = (jax.jit(lambda p, x, fn=fn: M.apply_gated_delta(
+        p, x, cfg, jnp.float32, gdn_fn=fn))(p, x) for fn in (kernels, None))
     assert bool(called) == taken
     if taken:
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),

@@ -7,6 +7,8 @@ gradients to all five inputs, at a sequence the chunk does not divide, at
 two chunk lengths, from a mild decay to the strongest the initialisation
 draws and well past it. CPU, float32 at ``highest``."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -48,23 +50,30 @@ def _inputs(strength, seed=0, seq=SEQ):
     return q, k, v, g, beta
 
 
-def _values_and_gradients(fn, args):
-    weight = jax.random.normal(jax.random.key(9), args[2].shape)
-
-    # (one program a side: op by op a form is hundreds of compiles)
+def _values_and_gradients(fn):
+    """The program of ``fn``'s value and five gradients (one a side: op by
+    op a form is hundreds of compiles)."""
     def both(*a):
+        weight = jax.random.normal(jax.random.key(9), a[2].shape)
         return (fn(*a),) + jax.grad(lambda *b: jnp.sum(fn(*b) * weight),
                                     argnums=(0, 1, 2, 3, 4))(*a)
-    return jax.jit(both)(*args)
+    return jax.jit(both)
+
+
+@functools.lru_cache(maxsize=None)
+def _side(chunk):
+    """The chunked form's program, the recurrence's where ``chunk`` is
+    None: the decays differ in their data alone and are one compile."""
+    return _values_and_gradients(
+        _recurrence() if chunk is None
+        else lambda *a: M.kda_chunked(*a, chunk, jnp.float32))
 
 
 @pytest.mark.parametrize("chunk", [32, 64])
 @pytest.mark.parametrize("decay", list(DECAYS))
 def test_chunked_form_is_the_recurrence(decay, chunk):
     args = _inputs(DECAYS[decay])
-    got = _values_and_gradients(
-        lambda *a: M.kda_chunked(*a, chunk, jnp.float32), args)
-    want = _values_and_gradients(_recurrence(), args)
+    got, want = _side(chunk)(*args), _side(None)(*args)
     for name, a, b in zip(NAMES, got, want):
         assert bool(jnp.all(jnp.isfinite(a))), (name, decay, chunk)
         # tolerance: float32 on both sides, sums in another order; relative
@@ -127,12 +136,14 @@ def test_the_triangular_inverse_and_its_cotangent(size, sub):
     strict = np.tril(np.ones((size, size), bool), -1)
     exact = np.linalg.inv(np.eye(size) + np.where(
         strict, np.asarray(N, np.float64), 0))
-    X = M.unit_lower_inverse(N, sub)
+    # (under ``jit``: op by op the inverse's rows compile one by one)
+    X = jax.jit(lambda n: M.unit_lower_inverse(n, sub))(N)
     assert float(jnp.max(jnp.abs(jnp.triu(X, 1)))) == 0.0
     np.testing.assert_allclose(X, exact, rtol=0,
                                atol=1e-5 * np.abs(exact).max())
     w = jax.random.normal(jax.random.key(1), X.shape)
-    got = jax.grad(lambda n: jnp.sum(M.unit_lower_inverse(n, sub) * w))(N)
+    got = jax.jit(jax.grad(
+        lambda n: jnp.sum(M.unit_lower_inverse(n, sub) * w)))(N)
     Xt = np.swapaxes(exact, -1, -2)
     want = np.where(strict, -Xt @ np.asarray(w, np.float64) @ Xt, 0)
     np.testing.assert_allclose(got, want, rtol=0,

@@ -78,16 +78,28 @@ def _inputs(seq, heads, strength, seed=0):
     return q, k, v, g, beta
 
 
-def _values_and_gradients(fn, args):
-    out, vjp = jax.vjp(fn, *args)
-    grads = vjp(jax.random.normal(jax.random.key(9), out.shape, out.dtype))
-    return tuple(np.asarray(t, np.float32) for t in (out,) + grads)
+def _values_and_gradients(fn):
+    """``fn``'s value and its gradients to its arguments as ONE program (a
+    compile a side, not one an operation), each in float32."""
+    def side(*a):
+        out, vjp = jax.vjp(fn, *a)
+        return (out,) + vjp(jax.random.normal(jax.random.key(9), out.shape,
+                                              out.dtype))
+    program = jax.jit(side)
+    return lambda *a: tuple(np.asarray(t, np.float32) for t in program(*a))
 
 
-def _kernels(dtype, chunk=CHUNK):
-    return lambda *a: M.kda_chunked(
-        *a, chunk, dtype, scan_fn=functools.partial(kda.kda_scan,
-                                                    interpret=True))
+@functools.lru_cache(maxsize=None)
+def _side(name, dtype, chunk):
+    """A side's program: the cases that differ in their data alone (the
+    three decays) are one compile of it."""
+    dtype = jnp.dtype(dtype)
+    return _values_and_gradients({
+        "kernel": lambda *a: M.kda_chunked(
+            *a, chunk, dtype, scan_fn=functools.partial(kda.kda_scan,
+                                                        interpret=True)),
+        "chunked": lambda *a: M.kda_chunked(*a, chunk, dtype),
+        "sequential": _recurrence()}[name])
 
 
 @functools.lru_cache(maxsize=None)
@@ -95,16 +107,12 @@ def _sides(case):
     """(kernels, chunked in jax.numpy, sequential in float32) on one set of
     inputs, each as (o, dq, dk, dv, dg, dbeta) in float32."""
     seq, heads, dtype, decay, chunk = CASES[case]
-    dtype = jnp.dtype(dtype)
     args = _inputs(seq, heads, DECAYS[decay])
     # (shapes that fit no tile would fall back, and compare nothing)
     assert kda.tile_plan(chunk, heads, WIDTH, WIDTH) is not None
     with jax.default_matmul_precision("highest"):
-        return {
-            "kernel": _values_and_gradients(_kernels(dtype, chunk), args),
-            "chunked": _values_and_gradients(
-                lambda *a: M.kda_chunked(*a, chunk, dtype), args),
-            "sequential": _values_and_gradients(_recurrence(), args)}
+        return {name: _side(name, dtype, chunk)(*args)
+                for name in ("kernel", "chunked", "sequential")}
 
 
 def _apart(a, b):
@@ -148,12 +156,13 @@ def _inverse_of_bf16_operands(N, sub):
     r = lambda t: t.astype(jnp.bfloat16).astype(jnp.float32)
     N = jnp.where(strict, N, 0.0)
     eye = jnp.eye(N.shape[-1], dtype=N.dtype)
-    rows = [jnp.broadcast_to(eye[0], N.shape[:-2] + eye[0].shape)]
-    for i in range(1, N.shape[-1]):
-        X = jnp.stack(rows, axis=-2)
-        rows.append(eye[i] - jnp.einsum("...j,...jk->...k",
-                                        r(N[..., i, :i]), r(X)))
-    return jnp.stack(rows, axis=-2)
+
+    def row(X, i):  # the rows from ``i`` on are still zero, as ``N[i, i:]``
+        return X.at[..., i, :].set(eye[i] - jnp.einsum(
+            "...j,...jk->...k", r(N[..., i, :]), r(X))), None
+
+    return jax.lax.scan(row, jnp.zeros_like(N),
+                        jnp.arange(N.shape[-1]))[0]
 
 
 @pytest.mark.parametrize("case", ["as_published", "state_carried_in_bf16",
@@ -177,12 +186,12 @@ def test_a_bf16_state_or_inverse_is_told_from_the_kernels(case, monkeypatch):
     seq, heads, _, decay, _ = CASES[on]
     args = _inputs(seq, heads, DECAYS[decay])
     if case == "state_carried_in_bf16":
-        rounded = _values_and_gradients(_recurrence_with_a_bf16_state, args)
+        rounded = _values_and_gradients(_recurrence_with_a_bf16_state)(*args)
     else:
         monkeypatch.setattr(M, "unit_lower_inverse",
                             _inverse_of_bf16_operands)
         rounded = _values_and_gradients(
-            lambda *a: M.kda_chunked(*a, CHUNK, jnp.float32), args)
+            lambda *a: M.kda_chunked(*a, CHUNK, jnp.float32))(*args)
     far = max(_apart(g, r) for g, r in zip(kernel, rounded))
     assert far > 100 * near and far > 3e-4, (case, near, far)
 

@@ -56,6 +56,13 @@ def _value_and_grads(scan, args):
     return (y,) + vjp(jnp.cos(y))
 
 
+def _a_side(scan, args):
+    """``_value_and_grads`` as ONE program (a compile a side, not one an
+    operation), in float32."""
+    side = jax.jit(lambda *a: _value_and_grads(scan, a))(*args)
+    return tuple(np.asarray(t, np.float32) for t in side)
+
+
 @functools.lru_cache(maxsize=None)
 def _sides(seq, dtype_name, heads=8):
     """(kernels, chunked in jax.numpy, sequential in float32) on one set of
@@ -66,12 +73,11 @@ def _sides(seq, dtype_name, heads=8):
         *a, CHUNK, dtype, scan_fn=functools.partial(ssd.ssd_scan,
                                                     interpret=True))
     chunked = lambda *a: M.ssd_chunked(*a, CHUNK, dtype)
-    as_f32 = lambda side: tuple(np.asarray(t, np.float32) for t in side)
-    return {"kernel": as_f32(_value_and_grads(kernel, args)),
-            "chunked": as_f32(_value_and_grads(chunked, args)),
-            "sequential": as_f32(_value_and_grads(
+    return {"kernel": _a_side(kernel, args),
+            "chunked": _a_side(chunked, args),
+            "sequential": _a_side(
                 _family().selective_scan,
-                tuple(t.astype(jnp.float32) for t in args)))}
+                tuple(t.astype(jnp.float32) for t in args))}
 
 
 def _apart(a, b):
@@ -173,11 +179,10 @@ def _grouped_sides(seq, dtype_name, heads, groups):
     a_group = lambda t: t.reshape(t.shape[:2] + (groups, STATE))
     sequential = lambda x, dt, A, Bm, Cm: reference.load_family(
         "nemotron_h").selective_scan(x, dt, A, a_group(Bm), a_group(Cm))
-    as_f32 = lambda side: tuple(np.asarray(t, np.float32) for t in side)
-    return {"kernel": as_f32(_value_and_grads(kernel, args)),
-            "chunked": as_f32(_value_and_grads(chunked, args)),
-            "sequential": as_f32(_value_and_grads(
-                sequential, tuple(t.astype(jnp.float32) for t in args)))}
+    return {"kernel": _a_side(kernel, args),
+            "chunked": _a_side(chunked, args),
+            "sequential": _a_side(
+                sequential, tuple(t.astype(jnp.float32) for t in args))}
 
 
 @pytest.mark.parametrize("quantity", NAMES)
@@ -255,9 +260,10 @@ def test_shapes_that_fit_no_tile_take_the_jax_numpy_form():
             -jnp.exp(jax.random.normal(k[2], (4,))),
             jax.random.normal(k[3], (2, 21, 16)),
             jax.random.normal(k[4], (2, 21, 16)))
-    np.testing.assert_array_equal(
-        np.asarray(M.ssd_chunked(*args, 8, jnp.float32, scan_fn=never)),
-        np.asarray(M.ssd_chunked(*args, 8, jnp.float32)))
+    # (one program a side: they trace to one jaxpr)
+    got, want = (jax.jit(lambda *a, fn=fn: M.ssd_chunked(
+        *a, 8, jnp.float32, scan_fn=fn))(*args) for fn in (never, None))
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
     with pytest.raises(ValueError, match="fit no tile"):
         ssd.ssd_scan(*args, 8, interpret=True)
 

@@ -447,10 +447,16 @@ def test_conv_block_and_per_head_norm_at_tp2_equal_one_device(cpu_devices):
     batch = jax.tree.map(jnp.asarray, make_batch(
         np.random.RandomState(0).randint(0, 64, (4, 17))))
     tx = make_optimizer(train)
-    ref_loss, ref_grads = jax.value_and_grad(lambda p: causal_lm_loss(
-        p, batch, cfg, compute_dtype=jnp.float32))(params)
-    upd, _ = tx.update(ref_grads, tx.init(params), params)
-    ref_params = optax.apply_updates(params, upd)
+
+    # (one program: op by op the model and Adam are hundreds of compiles)
+    @jax.jit
+    def ref_step(params):
+        loss, grads = jax.value_and_grad(lambda p: causal_lm_loss(
+            p, batch, cfg, compute_dtype=jnp.float32))(params)
+        upd, _ = tx.update(grads, tx.init(params), params)
+        return loss, optax.apply_updates(params, upd)
+
+    ref_loss, ref_params = ref_step(params)
 
     args = CoreArgs(model=cfg.model_dump(), train=train.model_dump())
     args.parallel.global_tp_deg = 2

@@ -49,13 +49,15 @@ def test_forward_shapes_and_loss():
         axes, is_leaf=lambda x: isinstance(x, tuple) and all(
             isinstance(s, str) for s in x))
     tokens = jnp.zeros((2, 16), jnp.int32)
-    logits = forward_causal_lm(params, tokens, TINY_GPT)
+    # (one program each: op by op a model is hundreds of compiles)
+    logits = jax.jit(lambda p, t: forward_causal_lm(p, t, TINY_GPT))(
+        params, tokens)
     assert logits.shape == (2, 16, 128)
     assert logits.dtype == jnp.float32
     batch = {"tokens": tokens, "labels": tokens}
-    loss = causal_lm_loss(params, batch, TINY_GPT)
+    loss, grads = jax.jit(jax.value_and_grad(
+        lambda p: causal_lm_loss(p, batch, TINY_GPT)))(params)
     assert np.isfinite(float(loss))
-    grads = jax.grad(lambda p: causal_lm_loss(p, batch, TINY_GPT))(params)
     leaves = jax.tree.leaves(grads)
     assert all(np.all(np.isfinite(g)) for g in leaves)
     # loss at init is ~ log(V)
@@ -66,15 +68,12 @@ def test_remat_same_loss():
     params, _ = init_causal_lm(jax.random.key(0), TINY_LLAMA)
     tokens = jax.random.randint(jax.random.key(1), (2, 16), 0, 128)
     batch = {"tokens": tokens, "labels": tokens}
-    l0 = causal_lm_loss(params, batch, TINY_LLAMA, compute_dtype=jnp.float32)
-    l1 = causal_lm_loss(params, batch, TINY_LLAMA, compute_dtype=jnp.float32,
-                        remat_flags=[True, True])
+    # (one program a side)
+    (l0, g0), (l1, g1) = (jax.jit(jax.value_and_grad(
+        lambda p, flags=flags: causal_lm_loss(
+            p, batch, TINY_LLAMA, compute_dtype=jnp.float32,
+            remat_flags=flags)))(params) for flags in (None, [True, True]))
     assert abs(float(l0) - float(l1)) < 1e-6
-    g0 = jax.grad(lambda p: causal_lm_loss(p, batch, TINY_LLAMA,
-                                           compute_dtype=jnp.float32))(params)
-    g1 = jax.grad(lambda p: causal_lm_loss(p, batch, TINY_LLAMA,
-                                           compute_dtype=jnp.float32,
-                                           remat_flags=[True, True]))(params)
     for a, b in zip(jax.tree.leaves(g0), jax.tree.leaves(g1)):
         np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
 
@@ -84,8 +83,9 @@ def test_causal_masking():
     params, _ = init_causal_lm(jax.random.key(0), TINY_LLAMA)
     t1 = jax.random.randint(jax.random.key(1), (1, 16), 0, 128)
     t2 = t1.at[0, -1].set((t1[0, -1] + 1) % 128)
-    l1 = forward_causal_lm(params, t1, TINY_LLAMA, compute_dtype=jnp.float32)
-    l2 = forward_causal_lm(params, t2, TINY_LLAMA, compute_dtype=jnp.float32)
+    forward = jax.jit(lambda p, t: forward_causal_lm(
+        p, t, TINY_LLAMA, compute_dtype=jnp.float32))
+    l1, l2 = forward(params, t1), forward(params, t2)
     np.testing.assert_allclose(l1[:, :-1], l2[:, :-1], atol=1e-6)
     assert not np.allclose(l1[:, -1], l2[:, -1])
 
@@ -294,9 +294,9 @@ def test_remat_policy_parity():
     flags = [True] * base.num_hidden_layers
 
     def loss_grads(cfg, remat_flags):
-        l, g = jax.value_and_grad(lambda p: causal_lm_loss(
+        l, g = jax.jit(jax.value_and_grad(lambda p: causal_lm_loss(
             p, batch, cfg, compute_dtype=jnp.float32,
-            remat_flags=remat_flags))(params)
+            remat_flags=remat_flags)))(params)
         return float(l), g
 
     l_ref, g_ref = loss_grads(base, None)

@@ -64,7 +64,7 @@ def test_moe_model_trains():
         np.random.RandomState(0).randint(0, 64, (4, 17))))
     loss_fn = lambda p: causal_lm_loss(p, batch, MOE_CFG,
                                        compute_dtype=jnp.float32)
-    loss, grads = jax.value_and_grad(loss_fn)(params)
+    loss, grads = jax.jit(jax.value_and_grad(loss_fn))(params)
     assert np.isfinite(float(loss))
     # router + expert weights all get gradients
     g = grads["layers"][0]["moe"]
@@ -225,7 +225,7 @@ def test_dropless_grads_flow():
         y, aux, _ = apply_moe_mlp(p_, x, cfg, compute_dtype=jnp.float32)
         return jnp.sum(jnp.square(y)) + aux
 
-    g = jax.grad(loss)(p)
+    g = jax.jit(jax.grad(loss))(p)
     for path, leaf in jax.tree_util.tree_leaves_with_path(g):
         assert np.all(np.isfinite(leaf)), path
     # router gets gradient through the combine weights

@@ -96,8 +96,8 @@ def test_the_exchanged_layer_is_the_dropless_layer_on_one_device(
     parent's ``_dropless_dispatch``); with ep < dp the expert-dp groups
     exchange nothing and their weight gradients add up."""
     p, x = _layer(router, ep)
-    (want, (want_y, _)), want_g = jax.value_and_grad(
-        _loss, argnums=(0, 1), has_aux=True)(p, x)
+    (want, (want_y, _)), want_g = jax.jit(jax.value_and_grad(
+        _loss, argnums=(0, 1), has_aux=True))(p, x)
     (got, (got_y, stats)), got_g = _exchanged(ep)(p, x)
     # tolerance: fp32, four partial sums in another order
     np.testing.assert_allclose(got_y, want_y, rtol=1e-5, atol=1e-6)
